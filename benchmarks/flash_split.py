@@ -39,6 +39,9 @@ def chain_bwd(fn, q, k, v, iters=50):
 
 
 def main():
+    from ray_tpu.utils.backend import open_backend
+
+    open_backend()
     q = jax.random.normal(jax.random.key(0), (B, S, H, D), jnp.bfloat16)
     k = jax.random.normal(jax.random.key(1), (B, S, KV, D), jnp.bfloat16)
     v = jax.random.normal(jax.random.key(2), (B, S, KV, D), jnp.bfloat16)

@@ -40,8 +40,15 @@ logger = get_logger("ray_tpu.cluster.client")
 
 
 class ClusterTaskError(Exception):
-    def __init__(self, desc: str, cause: BaseException, tb: str):
-        super().__init__(f"{desc} failed: {cause!r}\n{tb}")
+    def __init__(self, desc: str, cause: Optional[BaseException] = None,
+                 tb: str = ""):
+        # cause/tb are optional so the error survives pickling when it
+        # travels on as another task's cause: Exception.__reduce__ replays
+        # args == (message,), and a constructor that refused that hid the
+        # real failure behind a TypeError
+        super().__init__(
+            desc if cause is None else f"{desc} failed: {cause!r}\n{tb}"
+        )
         self.cause = cause
 
 

@@ -187,9 +187,11 @@ class LocalCluster:
         self._spawn_gcs(port=self.gcs_addr[1])
 
     def _child_env(self, extra: Optional[dict] = None) -> dict:
-        env = dict(os.environ)
-        # control-plane processes must never touch a TPU plugin
-        env["JAX_PLATFORMS"] = "cpu"
+        from ray_tpu.utils.env import pin_control_plane_to_cpu
+
+        # GCS and daemons never open an accelerator; a daemon gives the
+        # chips only to workers whose lease holds TPU
+        env = pin_control_plane_to_cpu(dict(os.environ))
         repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
         env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
         env.update(extra or {})

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import functools
 import os
 import queue as queue_mod
 import subprocess
@@ -47,6 +48,9 @@ from ray_tpu.cluster.rpc import (
 from ray_tpu.utils.logging import get_logger
 
 logger = get_logger("ray_tpu.cluster.node")
+
+# _try_grant's answer for a lease whose response comes from another thread
+_ANSWERED_LATER: dict = {}
 
 CHUNK = 4 << 20  # object transfer chunk size
 
@@ -548,6 +552,16 @@ class NodeDaemon:
         self._res_lock = threading.RLock()
         self._leases: dict[str, dict] = {}  # lease_id -> {resources, worker}
         self._bundles: dict[tuple, dict] = {}  # (pg_id, idx) -> reserved resources
+        # chip ids this node can hand to TPU leases (guarded by _res_lock).
+        # A chip belongs to ONE process: a lease that holds TPU gets a
+        # worker of its own, isolated to its chips and never pooled, and
+        # chips and resources return together once that process is gone
+        from ray_tpu.core.accelerators import TpuAcceleratorManager
+
+        self._node_chips = TpuAcceleratorManager.node_chip_ids(
+            int(self.total.get("TPU", 0))
+        )
+        self._free_chips = list(self._node_chips)
         # idle pool keyed by runtime-env hash: a worker only ever runs
         # tasks of ONE runtime env (reference: worker_pool.h dedicated
         # workers per runtime env)
@@ -910,9 +924,21 @@ class NodeDaemon:
 
     # -- worker pool ----------------------------------------------------------
 
-    def _spawn_worker(self, runtime_env: Optional[dict] = None) -> WorkerHandle:
+    def _spawn_worker(self, runtime_env: Optional[dict] = None,
+                      chips: tuple = ()) -> WorkerHandle:
         worker_id = f"w-{uuid.uuid4().hex[:8]}"
         env = dict(os.environ)
+        if chips:
+            from ray_tpu.core.accelerators import TpuAcceleratorManager
+            from ray_tpu.utils.env import unpin_for_accelerator_worker
+
+            unpin_for_accelerator_worker(env).update(
+                TpuAcceleratorManager.visible_chips_env(
+                    list(chips), self._node_chips
+                )
+            )
+        else:
+            env["JAX_PLATFORMS"] = "cpu"  # no TPU lease, no accelerator
         env.update(self.worker_env)
         # the worker must import ray_tpu REGARDLESS of its cwd: a
         # runtime_env working_dir changes cwd to the materialized
@@ -1065,9 +1091,12 @@ class NodeDaemon:
     # -- lease protocol -------------------------------------------------------
 
     def _try_grant(self, payload, allow_spillback: bool = True,
-                   block_spawn: bool = True) -> Optional[dict]:
+                   block_spawn: bool = True, deliver=None) -> Optional[dict]:
         """One grant attempt. Returns a response dict, or None when the
         request should QUEUE here (no capacity now, no better node).
+        With `deliver` (the granter, which must not block), a lease that
+        acquired chips returns _ANSWERED_LATER and hands its response to
+        `deliver` once its worker is up.
 
         allow_spillback=False on queue retries: recomputing spillback
         candidates means a GCS list_nodes per waiter per wakeup — a
@@ -1093,39 +1122,21 @@ class NodeDaemon:
             acquired = (not self._draining) and self._try_acquire(res)
         if acquired:
             try:
-                w = self._lease_worker(
-                    block=block_spawn, runtime_env=payload.get("runtime_env")
-                )
-            except Exception as e:  # noqa: BLE001 - incl. runtime_env failures
-                with self._res_lock:
-                    self._release(
-                        res, self._bundles.get(pg_key) if pg_key else None
-                    )
+                chips = self._take_chips(res.get("TPU", 0.0))
+            except RpcError as e:
+                self._give_back(res, pg_key)
                 return {"error": str(e)}
-            if w is None:  # spawn in flight; re-queue until it registers
-                with self._res_lock:
-                    self._release(
-                        res, self._bundles.get(pg_key) if pg_key else None
-                    )
-                return None
-            lease_id = uuid.uuid4().hex
-            self._leases[lease_id] = {
-                "resources": res, "worker": w, "pg_key": pg_key,
-                "t": time.monotonic(),  # newest-first OOM kill policy
-                "retriable": bool(payload.get("retriable", True)),
-            }
-            return {
-                "grant": {
-                    "lease_id": lease_id,
-                    "worker_addr": w.addr,
-                    "worker_id": w.worker_id,
-                    "node_id": self.node_id,
-                    # the address release_lease must go to — without it a
-                    # remote actor's lease could only ever be released at
-                    # the driver's local daemon (leaking worker+resources)
-                    "node_addr": self.addr,
-                }
-            }
+            if chips and deliver is not None:
+                # the chip worker's spawn takes seconds and is this
+                # lease's alone: it answers the waiter from its own thread
+                threading.Thread(
+                    target=lambda: deliver(
+                        self._grant(payload, res, pg_key, chips, True)
+                    ),
+                    name="chip-worker-spawn", daemon=True,
+                ).start()
+                return _ANSWERED_LATER
+            return self._grant(payload, res, pg_key, chips, block_spawn)
         # no local capacity: pg/pinned requests always queue here
         if pg_key is not None or payload.get("pinned") or not allow_spillback:
             return None
@@ -1165,6 +1176,104 @@ class NodeDaemon:
             return {"retry_after": 0.25, "node_id": self.node_id,
                     "draining": True}
         return None  # saturated cluster: queue here
+
+    def _grant(self, payload, res: dict, pg_key, chips: tuple,
+               block_spawn: bool) -> Optional[dict]:
+        """Second half of a grant: `res` (and `chips`) are held; find the
+        worker and write the lease, or give everything back."""
+        runtime_env = payload.get("runtime_env")
+        w = None
+        try:
+            if chips:  # a worker of its own, never from or to the pool
+                w = self._spawn_worker(runtime_env, chips)
+                if not w.ready.wait(timeout=60):
+                    raise RpcError("worker failed to start in 60s")
+            else:
+                w = self._lease_worker(block=block_spawn, runtime_env=runtime_env)
+        except Exception as e:  # noqa: BLE001 - incl. runtime_env failures
+            if chips and w is not None:
+                self._give_back_after_exit(w, res, pg_key, chips)
+            else:
+                self._give_back(res, pg_key, chips)
+            return {"error": str(e)}
+        if w is None:  # spawn in flight; re-queue until it registers
+            self._give_back(res, pg_key)
+            return None
+        lease_id = uuid.uuid4().hex
+        self._leases[lease_id] = {
+            "resources": res, "worker": w, "pg_key": pg_key,
+            "chips": chips,
+            "t": time.monotonic(),  # newest-first OOM kill policy
+            "retriable": bool(payload.get("retriable", True)),
+        }
+        return {
+            "grant": {
+                "lease_id": lease_id,
+                "worker_addr": w.addr,
+                "worker_id": w.worker_id,
+                "node_id": self.node_id,
+                # the address release_lease must go to — without it a
+                # remote actor's lease could only ever be released at
+                # the driver's local daemon (leaking worker+resources)
+                "node_addr": self.addr,
+            }
+        }
+
+    def _give_back(self, res: dict, pg_key, chips: tuple = ()) -> None:
+        """Return a lease's resources (to its bundle's pool when it came
+        from one) and its chip ids."""
+        with self._res_lock:
+            self._free_chips.extend(chips)
+            self._release(res, self._bundles.get(pg_key) if pg_key else None)
+
+    def _give_back_after_exit(self, w: WorkerHandle, res: dict, pg_key,
+                              chips: tuple) -> None:
+        """Kill a chip worker and free its lease only once the process
+        is gone: until then the chips are still its own, and the next
+        worker given them would fail to open the device. Waits on a
+        thread of its own — a process stuck in a device call can take a
+        while to die."""
+        with self._wlock:
+            self._all_workers.pop(w.worker_id, None)
+
+        def run():
+            w.kill()
+            while True:
+                try:
+                    w.proc.wait(timeout=30)
+                    break
+                except subprocess.TimeoutExpired:
+                    logger.warning(
+                        "worker %s (pid %s) still holds chips %s after SIGKILL",
+                        w.worker_id, w.proc.pid, list(chips),
+                    )
+            self._give_back(res, pg_key, chips)
+            self._notify_capacity()
+
+        threading.Thread(target=run, name="chip-worker-reap", daemon=True).start()
+
+    def _take_chips(self, n_tpu: float) -> tuple:
+        """Reserve the chip ids of a lease holding `n_tpu` TPU, lowest
+        free first. A process cannot share a chip, so fractions are
+        refused rather than run on the CPU behind the caller's back."""
+        if not n_tpu:
+            return ()
+        n = int(n_tpu)
+        if n != n_tpu:
+            raise RpcError(
+                f"a TPU lease is whole chips, got TPU={n_tpu}: a chip "
+                "belongs to one worker process"
+            )
+        with self._res_lock:
+            if len(self._free_chips) < n:
+                raise RpcError(
+                    f"node {self.node_id} has no {n} free TPU chip(s) to "
+                    f"give a worker (free: {sorted(self._free_chips)})"
+                )
+            self._free_chips.sort()
+            chips = tuple(self._free_chips[:n])
+            del self._free_chips[:n]
+        return chips
 
     async def rpc_request_worker_lease(self, payload, peer):
         """Grant a worker, spill back, or QUEUE the request server-side
@@ -1258,7 +1367,8 @@ class NodeDaemon:
                     probe = {k: v for k, v in payload.items() if k != "exclude"}
                 try:
                     r = self._try_grant(
-                        probe, allow_spillback=spill, block_spawn=False
+                        probe, allow_spillback=spill, block_spawn=False,
+                        deliver=functools.partial(self._deliver, loop, fut),
                     )
                 except Exception as e:  # noqa: BLE001 - must not kill the granter
                     logger.exception("lease grant attempt failed")
@@ -1273,19 +1383,8 @@ class NodeDaemon:
                     still.append(waiter)
                     continue
                 progressed = True
-
-                def _finish(f=fut, rr=r):
-                    if f.cancelled():
-                        # requester vanished after we granted: reclaim the
-                        # lease or it (worker + resources) leaks forever
-                        self._reclaim_grant(rr)
-                        return
-                    f.set_result(rr)
-
-                try:
-                    loop.call_soon_threadsafe(_finish)
-                except RuntimeError:
-                    self._reclaim_grant(r)  # connection's loop is gone
+                if r is not _ANSWERED_LATER:
+                    self._deliver(loop, fut, r)
             waiters = still
             self._num_queued = len(waiters)
             # autoscaler demand feed: specs of leases parked here, shipped
@@ -1297,6 +1396,22 @@ class NodeDaemon:
             if waiters and not progressed:
                 self._capacity_signal.wait(timeout=0.1)
                 self._capacity_signal.clear()
+
+    def _deliver(self, loop, fut, r: dict) -> None:
+        """Answer a queued lease request from the granter's side."""
+
+        def _finish():
+            if fut.cancelled():
+                # requester vanished after we granted: reclaim the
+                # lease or it (worker + resources) leaks forever
+                self._reclaim_grant(r)
+                return
+            fut.set_result(r)
+
+        try:
+            loop.call_soon_threadsafe(_finish)
+        except RuntimeError:
+            self._reclaim_grant(r)  # connection's loop is gone
 
     def _reclaim_grant(self, response: dict) -> None:
         """Release a lease whose grant could not be delivered."""
@@ -1317,11 +1432,18 @@ class NodeDaemon:
         lease = self._leases.pop(payload["lease_id"], None)
         if lease is None:
             return {"ok": False}
+        w: WorkerHandle = lease["worker"]
+        if lease.get("chips"):
+            # a worker that held chips dies with its lease, and the lease
+            # is free for the next one only when the process has exited
+            self._give_back_after_exit(
+                w, lease["resources"], lease["pg_key"], lease["chips"]
+            )
+            return {"ok": True}
         # worker back to the idle pool BEFORE freeing resources: the
         # granter races on freed capacity, and losing this race makes it
         # spawn a brand-new worker process (seconds) instead of reusing
         # the one we are returning right now
-        w: WorkerHandle = lease["worker"]
         if payload.get("kill") or not w.alive():
             w.kill()
             with self._wlock:
@@ -1330,9 +1452,7 @@ class NodeDaemon:
             w.idle_since = time.monotonic()
             with self._wlock:
                 self._idle_workers.setdefault(w.env_key, []).append(w)
-        with self._res_lock:
-            pool = self._bundles.get(lease["pg_key"]) if lease["pg_key"] else None
-            self._release(lease["resources"], pool)
+        self._give_back(lease["resources"], lease["pg_key"])
         self._notify_capacity()
         return {"ok": True}
 

@@ -2,8 +2,10 @@
 
 Analog of the reference's TPUAcceleratorManager
 (python/ray/_private/accelerators/tpu.py:70): detection via environment
-(GKE-style vars; no metadata-server probe here — zero-egress safe),
-`TPU_VISIBLE_CHIPS` isolation (tpu.py:154), valid per-host chip counts
+and the accelerator device nodes (GKE-style vars; no metadata-server
+probe and no backend init here — zero-egress safe, and the caller's
+process never takes the chip by asking), `TPU_VISIBLE_CHIPS` isolation
+(tpu.py:154), valid per-host chip counts
 {1,2,4,8} (tpu.py:14,140-148), and the pod-slice resource pattern
 (tpu.py:330-393): every worker of a slice advertises `{slice_name}: 1`
 and worker 0 additionally `TPU-{pod_type}-head: 1`, which is the gang-
@@ -13,6 +15,7 @@ scheduling hook `slice_run` builds on.
 from __future__ import annotations
 
 import dataclasses
+import glob
 import os
 import re
 from typing import Optional
@@ -65,23 +68,55 @@ def parse_pod_type(pod_type: str) -> TpuTopology:
 
 
 class TpuAcceleratorManager:
-    """Per-node TPU detection + isolation (env-driven)."""
+    """Per-node TPU detection + isolation. Never opens a backend."""
 
     @staticmethod
-    def detect_num_chips() -> int:
+    def device_chip_ids() -> list[int]:
+        """Ids of the accelerator device nodes this process can see:
+        `/dev/accel<N>` (v2-v4) or `/dev/vfio/<N>` (v5e and newer)."""
+        ids = [
+            int(p[len("/dev/accel"):]) for p in glob.glob("/dev/accel*")
+            if p[len("/dev/accel"):].isdigit()
+        ]
+        if not ids:
+            try:
+                ids = [int(n) for n in os.listdir("/dev/vfio") if n.isdigit()]
+            except OSError:
+                ids = []
+        return sorted(ids)
+
+    @classmethod
+    def detect_num_chips(cls) -> int:
+        """Chips this node should advertise. An explicit statement wins
+        (`TPU_VISIBLE_CHIPS` isolation, `RAY_TPU_NUM_CHIPS`); otherwise
+        the device nodes are counted — they are what the process can
+        actually open — and only a machine that shows none falls back to
+        the host topology in `TPU_CHIPS_PER_HOST_BOUNDS`, which describes
+        the whole host even inside a container that was given one chip."""
         visible = os.environ.get("TPU_VISIBLE_CHIPS")
         if visible:
             return len([c for c in visible.split(",") if c.strip()])
+        explicit = os.environ.get("RAY_TPU_NUM_CHIPS")
+        if explicit:
+            return int(explicit)
+        nodes = cls.device_chip_ids()
+        if nodes:
+            return len(nodes)
         chips = os.environ.get("TPU_CHIPS_PER_HOST_BOUNDS")  # e.g. "2,2,1"
         if chips:
             n = 1
             for part in chips.split(","):
                 n *= int(part)
             return n
-        explicit = os.environ.get("RAY_TPU_NUM_CHIPS")
-        if explicit:
-            return int(explicit)
         return 0
+
+    @classmethod
+    def node_chip_ids(cls, num_chips: int) -> list[int]:
+        """The ids a node advertising `num_chips` hands out to leases:
+        its device nodes' own numbers where it has that many, else
+        0..num_chips-1 (a node whose count was stated, not detected)."""
+        nodes = cls.device_chip_ids()
+        return nodes[:num_chips] if len(nodes) >= num_chips else list(range(num_chips))
 
     @staticmethod
     def detect_pod_type() -> Optional[str]:
@@ -100,13 +135,24 @@ class TpuAcceleratorManager:
         return 0
 
     @staticmethod
-    def set_visible_chips(chip_ids: list[int]) -> None:
-        """Isolate a worker to specific chips (reference tpu.py:154)."""
+    def visible_chips_env(chip_ids: list[int], node_chip_ids: list[int]) -> dict:
+        """Environment that isolates a worker to `chip_ids` of a node
+        holding `node_chip_ids` (reference tpu.py:154-196). A worker
+        that owns the whole node gets nothing: the runtime's defaults
+        already describe it."""
         if len(chip_ids) not in VALID_CHIPS_PER_HOST:
             raise ValueError(
                 f"TPU workers may own {VALID_CHIPS_PER_HOST} chips, not {len(chip_ids)}"
             )
-        os.environ["TPU_VISIBLE_CHIPS"] = ",".join(str(c) for c in chip_ids)
+        if sorted(chip_ids) == sorted(node_chip_ids):
+            return {}
+        env = {"TPU_VISIBLE_CHIPS": ",".join(str(c) for c in chip_ids)}
+        bounds = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1"}.get(len(chip_ids))
+        if bounds:
+            # a sub-host worker is its own one-process "host" of that shape
+            env["TPU_CHIPS_PER_HOST_BOUNDS"] = bounds
+            env["TPU_HOST_BOUNDS"] = "1,1,1"
+        return env
 
     @classmethod
     def node_resources(cls) -> dict:

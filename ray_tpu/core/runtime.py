@@ -50,7 +50,11 @@ class Runtime:
             env_cpus = os.environ.get("RAY_TPU_NUM_CPUS")
             num_cpus = float(env_cpus) if env_cpus else float(os.cpu_count() or 1)
         if num_tpus is None:
-            num_tpus = _detect_tpu_chips()
+            # env vars, then the accelerator device nodes — never a
+            # backend: asking must not take the chip from the caller
+            from ray_tpu.core.accelerators import TpuAcceleratorManager
+
+            num_tpus = float(TpuAcceleratorManager.detect_num_chips())
         total = dict(resources or {})
         total["CPU"] = num_cpus
         if num_tpus:
@@ -222,18 +226,6 @@ class Runtime:
         self.scheduler.shutdown()
         if self._process_pool is not None:
             self._process_pool.shutdown()
-
-
-def _detect_tpu_chips() -> float:
-    """Count local TPU chips without initializing a backend (env-driven,
-    mirroring the detection ladder of the reference's TPUAcceleratorManager,
-    python/ray/_private/accelerators/tpu.py:14-68)."""
-    env = os.environ.get("TPU_VISIBLE_CHIPS") or os.environ.get("TPU_CHIPS")
-    if env:
-        return float(len([c for c in env.split(",") if c.strip()]))
-    # Explicit opt-in count (set by tests / launchers); never probe hardware
-    # here — backend init is expensive and may not be safe at import time.
-    return float(os.environ.get("RAY_TPU_NUM_CHIPS", 0) or 0)
 
 
 def get_runtime() -> Runtime:

@@ -106,7 +106,7 @@ def current_fingerprint() -> dict:
     """Hardware fingerprint of THIS process's JAX backend.
 
     Importing jax here initializes a backend — only call from a process
-    that is allowed to (bench children, never bench.py's parent)."""
+    that is allowed to hold the device (never a JAX-free parent)."""
     import jax
 
     dev = jax.devices()[0]

@@ -65,6 +65,46 @@ def xla_attention(
     return out.reshape(B, Sq, H, D)
 
 
+def _flash_over_mesh(q, k, v, segment_ids, **kw) -> jax.Array:
+    """The flash kernel, one call per shard under an ambient mesh.
+
+    A pallas_call has no GSPMD partitioning rule (the TPU compiler
+    refuses to partition a Mosaic kernel), so on a multi-device mesh the
+    kernel runs inside a shard_map: batch over the data axes and heads
+    over the tensor axis, as the rules table lays them out. The sequence
+    stays whole in every shard — splitting it is ring attention's job.
+
+    Called from inside another shard_map (the pipeline's, manual over
+    `pp`), only the axes that are still automatic are taken over; with
+    none left the kernel is already per shard and runs as it is."""
+    from ray_tpu.ops.flash import flash_attention
+    from ray_tpu.parallel.context import current_mesh, current_rules
+
+    mesh = current_mesh()
+    manual = jax.sharding.get_abstract_mesh().manual_axes
+    auto = frozenset() if mesh is None else frozenset(
+        a for a in mesh.axis_names if a not in manual
+    )
+    if all(mesh.shape[a] == 1 for a in auto):
+        return flash_attention(q, k, v, segment_ids=segment_ids, **kw)
+    rules = current_rules()
+    qspec = rules.spec(("batch", None, "heads", None))
+    kvspec = rules.spec(("batch", None, "kv_heads", None))
+    args, in_specs = (q, k, v), (qspec, kvspec, kvspec)
+    if segment_ids is not None:
+        args += (segment_ids,)
+        in_specs += (rules.spec(("batch", None)),)
+
+    def per_shard(q, k, v, seg=None):
+        return flash_attention(q, k, v, segment_ids=seg, **kw)
+
+    # nested, the mesh has to be the context's own (it marks `manual`)
+    return jax.shard_map(
+        per_shard, mesh=None if manual else mesh, in_specs=in_specs,
+        out_specs=qspec, axis_names=auto, check_vma=False,
+    )(*args)
+
+
 def attention(
     q: jax.Array,
     k: jax.Array,
@@ -82,11 +122,9 @@ def attention(
             q_offset=q_offset, softmax_scale=softmax_scale,
         )
     if impl == "flash":
-        from ray_tpu.ops.flash import flash_attention
-
-        return flash_attention(
-            q, k, v, causal=causal, segment_ids=segment_ids,
-            q_offset=q_offset, softmax_scale=softmax_scale,
+        return _flash_over_mesh(
+            q, k, v, segment_ids, causal=causal, q_offset=q_offset,
+            softmax_scale=softmax_scale,
         )
     if impl in ("ring", "ulysses"):
         # Context-parallel paths: sequence sharded over the mesh `sp` axis

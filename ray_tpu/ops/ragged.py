@@ -108,19 +108,20 @@ def _ragged_attn_kernel(
     # output
     o_ref,       # [1, TG_pad, D] VMEM (revisited across the whole h slice)
     # scratch
-    acc_ref,     # [MAXQ*G, D] fp32
-    m_ref,       # [MAXQ*G, 128] running max
-    l_ref,       # [MAXQ*G, 128] running denom
+    acc_ref,     # [W, D] fp32 — W = the aligned row window (see below)
+    m_ref,       # [W, 128] running max
+    l_ref,       # [W, 128] running denom
     *,
     block_size: int,
     group: int,  # G: query heads folded per kv head
+    tile: int,   # sublane tile of q's dtype: window starts are multiples of it
 ):
     from jax.experimental import pallas as pl
 
     b = pl.program_id(1)
     i = pl.program_id(2)  # page index within this sequence
     n_pages = pl.num_programs(2)
-    MQG, D = acc_ref.shape
+    W, D = acc_ref.shape
 
     @pl.when((b == 0) & (i == 0))
     def _():
@@ -137,34 +138,42 @@ def _ragged_attn_kernel(
     ctx = context_lens_ref[b]
     q_start = cu_q_lens_ref[b] * group
     q_len = cu_q_lens_ref[b + 1] - cu_q_lens_ref[b]
+    # Mosaic only takes a dynamic sublane slice whose start it can prove
+    # tile-aligned, and cu_q_lens[b] * G is an arbitrary run-time row. So
+    # the window starts at the aligned row at or below q_start and is one
+    # tile wider than MAXQ*G; the sequence's rows sit `lead` rows in.
+    w_start = pl.multiple_of((q_start // tile) * tile, tile)
+    lead = q_start - w_start
 
-    # packed row r of this sequence's window is query j = r // group;
-    # its absolute causal position is ctx - q_len + j
-    row = jax.lax.broadcasted_iota(jnp.int32, (MQG, block_size), 0)
-    row_q = row // group
+    # window row r is folded row r - lead of this sequence, i.e. query
+    # j = (r - lead) // group at absolute causal position ctx - q_len + j;
+    # rows before `lead` or at/after q_len * group belong to neighbours
+    row = jax.lax.broadcasted_iota(jnp.int32, (W, block_size), 0) - lead
+    mine = (row >= 0) & (row < q_len * group)
+    row_q = jnp.where(mine, row, 0) // group
 
     @pl.when((i * block_size < ctx) & (q_len > 0))
     def _():
-        q = q_ref[0, pl.ds(q_start, MQG)].astype(jnp.float32) * (
+        q = q_ref[0, pl.ds(w_start, W)].astype(jnp.float32) * (
             1.0 / (D ** 0.5)
-        )  # [MQG, D]
+        )  # [W, D]
         k = k_ref[0, 0].astype(jnp.float32)  # [bs, D]
         v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(  # [MQG, bs]
+        s = jax.lax.dot_general(  # [W, bs]
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
         kv_pos = i * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (MQG, block_size), 1
+            jnp.int32, (W, block_size), 1
         )
         q_pos = ctx - q_len + row_q
-        ok = (kv_pos <= q_pos) & (kv_pos < ctx) & (row_q < q_len)
+        ok = (kv_pos <= q_pos) & (kv_pos < ctx) & mine
         s = jnp.where(ok, s, NEG_INF)
 
         # online softmax update
-        m_prev = m_ref[:, :1]                      # [MQG, 1]
+        m_prev = m_ref[:, :1]                      # [W, 1]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                     # [MQG, bs]
-        alpha = jnp.exp(m_prev - m_new)            # [MQG, 1]
+        p = jnp.exp(s - m_new)                     # [W, bs]
+        alpha = jnp.exp(m_prev - m_new)            # [W, 1]
         l_new = alpha * l_ref[:, :1] + p.sum(axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -177,14 +186,13 @@ def _ragged_attn_kernel(
         l = l_ref[:, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)
         vals = (acc_ref[...] / safe_l).astype(o_ref.dtype)
-        # masked read-modify-write: this sequence's window may overlap
-        # the next sequence's rows (the window is MAXQ*G wide, the
-        # sequence only q_len*G) — rows past q_len keep their current
-        # contents. Safe because the output block stays VMEM-resident
-        # for the whole (b, i) sweep of this head.
-        cur = o_ref[0, pl.ds(q_start, MQG)]
-        keep = (row_q < q_len)[:, :1]  # [MQG, 1]
-        o_ref[0, pl.ds(q_start, MQG)] = jnp.where(keep, vals, cur)
+        # masked read-modify-write: the window overlaps the neighbouring
+        # sequences' rows on both sides — rows that are not this
+        # sequence's keep their current contents. Safe because the
+        # output block stays VMEM-resident for the whole (b, i) sweep of
+        # this head.
+        cur = o_ref[0, pl.ds(w_start, W)]
+        o_ref[0, pl.ds(w_start, W)] = jnp.where(mine[:, :1], vals, cur)
 
 
 def ragged_attention_pallas(
@@ -214,17 +222,21 @@ def ragged_attention_pallas(
         )
     if max_q_len < 1:
         raise ValueError(f"max_q_len must be >= 1, got {max_q_len}")
-    MQG = max_q_len * G
+    # sublane tile of q's dtype (8 rows fp32, 16 bf16): the kernel's row
+    # window starts on one and spans W = MAXQ*G rounded up, plus the tile
+    # it may have stepped back to get aligned
+    tile = 8 * max(1, 4 // q.dtype.itemsize)
+    W = -(-max_q_len * G // tile) * tile + tile
 
     # GQA folded on the HOST: [T, H, D] -> [KVH, T*G, D] so sequence
     # b's rows occupy the contiguous window [cu[b]*G, cu[b+1]*G) of one
     # clean 2D MXU operand per kv head — no in-kernel reshape. The row
-    # axis is over-padded by max_q_len*G extra rows so the kernel's
-    # fixed-size dynamic slice q[cu[b]*G : cu[b]*G + MQG] never runs
-    # off the end for the last sequence.
+    # axis is padded to a tile multiple plus W rows so the kernel's
+    # fixed-size slice q[w_start : w_start + W] never runs off the end
+    # for the last sequence.
     qf = q.reshape(T, KVH, G, D).swapaxes(0, 1).reshape(KVH, T * G, D)
-    qf = jnp.pad(qf, ((0, 0), (0, MQG), (0, 0)))
-    TG_pad = qf.shape[1]
+    TG_pad = -(-T * G // tile) * tile + W
+    qf = jnp.pad(qf, ((0, 0), (0, TG_pad - T * G), (0, 0)))
 
     # caches viewed pre-blocked [KVH, num_blocks, block_size, D]: each
     # grid step's index map picks page bt[b, i] straight from the
@@ -254,14 +266,14 @@ def ragged_attention_pallas(
         ],
         out_specs=pl.BlockSpec((1, TG_pad, D), q_index),
         scratch_shapes=[
-            pltpu.VMEM((MQG, D), jnp.float32),
-            pltpu.VMEM((MQG, 128), jnp.float32),
-            pltpu.VMEM((MQG, 128), jnp.float32),
+            pltpu.VMEM((W, D), jnp.float32),
+            pltpu.VMEM((W, 128), jnp.float32),
+            pltpu.VMEM((W, 128), jnp.float32),
         ],
     )
     kernel = pl.pallas_call(
         functools.partial(
-            _ragged_attn_kernel, block_size=block_size, group=G
+            _ragged_attn_kernel, block_size=block_size, group=G, tile=tile
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((KVH, TG_pad, D), q.dtype),

@@ -37,14 +37,6 @@ from ray_tpu.ops.attention import xla_attention
 from ray_tpu.ops.flash import NEG_INF as FLASH_NEG_INF, flash_attention
 
 
-
-def _axis_size(axis_name) -> int:
-    """jax.lax.axis_size appeared after 0.4.x; psum of 1 is the classic
-    spelling and resolves to the same static mesh-axis size."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
 def ring_attention_spmd(
     q: jax.Array,  # [B, Sq_local, H, D]  (local sequence shard)
     k: jax.Array,  # [B, Sk_local, K, D]
@@ -68,7 +60,7 @@ def ring_attention_spmd(
     Sk = k.shape[1]
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
 
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     # kv arrives from the next-higher rank each step: after t rotations the
     # local buffer holds block (my + t) mod n.
@@ -161,7 +153,7 @@ def ulysses_attention_spmd(
     softmax_scale: Optional[float] = None,
 ) -> jax.Array:
     """All-to-all head/sequence swap: full attention runs locally per head group."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     H, Kh = q.shape[2], k.shape[2]
     if H % n or Kh % n:
         raise ValueError(f"ulysses needs heads ({H}/{Kh}) divisible by axis size {n}")
@@ -226,9 +218,7 @@ def _cp_wrapper(spmd_fn, seg_kwargs):
                 softmax_scale=softmax_scale, **kw,
             )
 
-        from ray_tpu.parallel.sharding import shard_map_compat
-
-        return shard_map_compat(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=in_specs, out_specs=qspec, check_vma=False
         )(*args)
 

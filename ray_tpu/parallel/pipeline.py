@@ -30,7 +30,6 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from ray_tpu.parallel.sharding import shard_map_compat
 from jax.sharding import PartitionSpec as P
 
 
@@ -95,7 +94,7 @@ def pipeline_apply(
         )
         return out_buf[None]  # [1, mb, S, D], sharded back over pp
 
-    out = shard_map_compat(
+    out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis), P(axis)),

@@ -81,32 +81,3 @@ def constrain(x, mesh: Mesh, rules: ShardingRules, logical_axes: tuple[str | Non
     """with_sharding_constraint by logical axes (no-op outside jit)."""
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, rules.spec(logical_axes)))
 
-
-def shard_map_compat(f, *, mesh: Mesh, in_specs, out_specs,
-                     axis_names=None, check_vma: bool = True):
-    """`jax.shard_map` across jax versions.
-
-    New jax exposes `jax.shard_map(f, mesh=, in_specs=, out_specs=,
-    axis_names=, check_vma=)`; 0.4.x has `jax.experimental.shard_map`
-    with `check_rep=` (the old name for check_vma) and `auto=` (the
-    COMPLEMENT of axis_names: axes left to the compiler). Manual mesh
-    axes and replication checking mean the same thing in both.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = {"check_vma": check_vma}
-        if axis_names is not None:
-            kwargs["axis_names"] = frozenset(axis_names)
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    # 0.4.x fallback: always FULLY manual. The partial-manual form
-    # (auto = complement of axis_names) lowers to a PartitionId HLO the
-    # 0.4.x SPMD partitioner rejects; with full manual, axes the specs
-    # don't mention are simply replicated through the body — numerically
-    # identical, at worst redundant compute on those axes.
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )

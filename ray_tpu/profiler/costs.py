@@ -3,10 +3,10 @@
 Costs come from XLA's own compiler estimate —
 ``jax.jit(fn).lower(*args).compile().cost_analysis()`` — so they track
 the program XLA actually emits (remat re-computation, fused epilogues,
-layout copies), not a hand-derived formula. The chip-peak table turns
-those counts into roofline coordinates; on CPU the nominal fallback
-peaks keep the arithmetic well-defined so tier-1 tests run under
-``JAX_PLATFORMS=cpu``.
+layout copies), not a hand-derived formula. The chip-peak table — the
+only one in the tree — turns those counts into roofline coordinates. An
+accelerator it does not list is an error; the CPU backend gets a
+labelled nominal row so tier-1 tests run under ``JAX_PLATFORMS=cpu``.
 """
 
 from __future__ import annotations
@@ -15,8 +15,7 @@ import dataclasses
 from typing import Any, Callable, Optional
 
 # bf16 peak matmul FLOP/s and HBM bandwidth (bytes/s) by device
-# generation. FLOPs numbers match bench.py's PEAK_FLOPS ladder; HBM
-# figures are the published per-chip memory bandwidths.
+# generation: the published per-chip figures (Google Cloud TPU docs).
 CHIP_PEAKS = [
     # (device_kind substring, flops/s, HBM bytes/s)
     ("v5 lite", 197e12, 819e9),
@@ -28,9 +27,9 @@ CHIP_PEAKS = [
     ("v3", 123e12, 900e9),
 ]
 
-# Nominal CPU/unknown peaks: a laptop-class core's ~1 TFLOP/s and
-# ~50 GB/s memory bus. Deliberately round numbers — the CPU profile is
-# for exercising the machinery, not for publishing attainment.
+# Nominal CPU peaks: a laptop-class core's ~1 TFLOP/s and ~50 GB/s
+# memory bus. Deliberately round numbers — the CPU profile is for
+# exercising the machinery, not for publishing attainment.
 CPU_PEAKS = (1e12, 50e9)
 
 
@@ -39,6 +38,7 @@ class ChipPeaks:
     device_kind: str
     flops: float      # peak FLOP/s
     hbm_bytes_s: float  # peak memory bandwidth, bytes/s
+    nominal: bool = False  # the CPU stand-in row, not a published peak
 
     @property
     def ridge_intensity(self) -> float:
@@ -47,17 +47,25 @@ class ChipPeaks:
 
 
 def chip_peaks(device=None) -> ChipPeaks:
-    """Peak table lookup for a jax device (default: devices()[0])."""
+    """Peak table lookup for a jax device (default: devices()[0]).
+
+    Raises for an accelerator whose ``device_kind`` the table does not
+    list: a utilization against a made-up peak is not a measurement."""
     if device is None:
         import jax
 
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "cpu") or "cpu"
+    kind = device.device_kind
+    if device.platform == "cpu":
+        return ChipPeaks(kind, *CPU_PEAKS, nominal=True)
     low = kind.lower()
     for key, fl, bw in CHIP_PEAKS:
         if key in low:
             return ChipPeaks(kind, fl, bw)
-    return ChipPeaks(kind, *CPU_PEAKS)
+    raise ValueError(
+        f"no peak FLOP/s / bandwidth row for device_kind {kind!r} "
+        f"(platform {device.platform!r}); add it to CHIP_PEAKS with its source"
+    )
 
 
 @dataclasses.dataclass

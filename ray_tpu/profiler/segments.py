@@ -593,7 +593,6 @@ def allreduce_overlap_segments(
     from jax.sharding import PartitionSpec as P
 
     from ray_tpu.models import llama
-    from ray_tpu.parallel.sharding import shard_map_compat
 
     c = config
     devices = jax.devices()
@@ -607,7 +606,7 @@ def allreduce_overlap_segments(
                 lambda x: jax.lax.psum(x, "dp") / n_dev, g
             )
 
-        return shard_map_compat(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=P(), out_specs=P()
         )(grads)
 

@@ -81,8 +81,11 @@ def _spawn(cmd, env, log_name: str) -> subprocess.Popen:
 
 def cmd_start(args) -> int:
     state = _load_state()
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")  # control plane never grabs a TPU
+    from ray_tpu.utils.env import pin_control_plane_to_cpu
+
+    # control plane never grabs a TPU; the daemon hands the chips only to
+    # workers whose lease holds TPU
+    env = pin_control_plane_to_cpu(dict(os.environ))
     if args.head:
         cmd = [
             sys.executable, "-m", "ray_tpu.cluster.gcs_service",

@@ -308,7 +308,7 @@ class ElasticResult:
 
 
 def _classify(err: BaseException) -> Optional[str]:
-    """Fault taxonomy for a failed rank ref. Returns None for errors that
+    """Fault classes for a failed rank ref. Returns None for errors that
     mean 'collateral of someone else's fault' (aborted round, stale
     generation, a survivor's own expired wait) — those ranks SURVIVED."""
     # both actor runtimes wrap task-side exceptions with the original in

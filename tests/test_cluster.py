@@ -32,6 +32,79 @@ def cluster():
     c.shutdown()
 
 
+def test_cluster_task_error_survives_pickling():
+    """It travels on as the cause of other errors (a gang member's
+    failure reaching the trainer's driver): unpickling must not replace
+    it with a TypeError."""
+    import pickle
+
+    err = ClusterTaskError("step()", ValueError("boom"), "Traceback ...")
+    back = pickle.loads(pickle.dumps(err))
+    assert isinstance(back, ClusterTaskError)
+    assert str(back) == str(err) and "boom" in str(back)
+
+
+def _worker_env():
+    import os
+
+    keys = ("JAX_PLATFORMS", "TPU_VISIBLE_CHIPS", "TPU_CHIPS_PER_HOST_BOUNDS")
+    return {k: os.environ.get(k) for k in keys}, os.getpid()
+
+
+def _hold_chips(pidfile, hold_s):
+    """-> (pid, was the chips' previous holder gone when this one started?)"""
+    import os
+    import time
+
+    prev = open(pidfile).read() if os.path.exists(pidfile) else ""
+    prev_gone = not prev or not os.path.exists(f"/proc/{prev}")
+    with open(pidfile, "w") as f:
+        f.write(str(os.getpid()))
+    time.sleep(hold_s)
+    return os.getpid(), prev_gone
+
+
+def test_tpu_lease_gets_a_worker_of_its_own(tmp_path):
+    """A chip belongs to one process: a lease that holds TPU gets its own
+    worker, isolated to its chips, with the launch environment's platform
+    choice (the CPU here) instead of the daemon's pin; it dies with the
+    lease, and a lease waiting for its chips gets them only once that
+    process is gone. Workers without a TPU lease stay pooled and pinned
+    to the CPU."""
+    c = LocalCluster(node_death_timeout_s=5.0)
+    try:
+        c.start()
+        c.add_node({"num_cpus": 2, "TPU": 2}, node_id="chips")
+        c.wait_for_nodes(1)
+        client = c.client()
+        cpu_env, cpu_pid = client.get(client.submit(_worker_env), timeout=60)
+        assert cpu_env == {"JAX_PLATFORMS": "cpu", "TPU_VISIBLE_CHIPS": None,
+                           "TPU_CHIPS_PER_HOST_BOUNDS": None}
+        one_env, one_pid = client.get(
+            client.submit(_worker_env, resources={"TPU": 1}), timeout=60)
+        assert one_env["TPU_VISIBLE_CHIPS"] == "0"
+        assert one_env["TPU_CHIPS_PER_HOST_BOUNDS"] == "1,1,1"
+        assert one_env["JAX_PLATFORMS"] == "cpu"  # what this test process runs under
+        all_env, all_pid = client.get(
+            client.submit(_worker_env, resources={"TPU": 2}), timeout=60)
+        assert all_env["TPU_VISIBLE_CHIPS"] is None  # whole node: runtime defaults
+        assert len({cpu_pid, one_pid, all_pid}) == 3  # TPU workers are not reused
+        # three leases for the whole node, two of them queued behind the
+        # first: each starts in a new process after the last one was reaped
+        pidfile = str(tmp_path / "holder.pid")
+        refs = [client.submit(_hold_chips, (pidfile, 0.5), resources={"TPU": 2})
+                for _ in range(3)]
+        held = [client.get(r, timeout=120) for r in refs]
+        assert len({pid for pid, _ in held}) == 3
+        assert all(prev_gone for _, prev_gone in held), held
+        with pytest.raises(ClusterTaskError, match="whole chips"):
+            client.get(client.submit(_worker_env, resources={"TPU": 0.5}), timeout=60)
+        _, again = client.get(client.submit(_worker_env), timeout=60)
+        assert again == cpu_pid  # the CPU worker went back to the pool
+    finally:
+        c.shutdown()
+
+
 def _whoami():
     import os
 

@@ -47,6 +47,40 @@ def test_node_resources_pattern(monkeypatch):
     assert "TPU-v5p-16-head" not in res
 
 
+def test_chip_detection_counts_device_nodes(monkeypatch):
+    """No env var says how many chips: count the accelerator device
+    nodes (never a backend). The host-topology variable describes the
+    whole host even in a container that was given one chip (seen on the
+    one-chip v5e machine: bounds 2,2,1 beside a single /dev/vfio/2)."""
+    for var in ("TPU_VISIBLE_CHIPS", "RAY_TPU_NUM_CHIPS", "TPU_CHIPS_PER_HOST_BOUNDS"):
+        monkeypatch.delenv(var, raising=False)
+    monkeypatch.setattr(TpuAcceleratorManager, "device_chip_ids", staticmethod(lambda: []))
+    assert TpuAcceleratorManager.detect_num_chips() == 0
+    monkeypatch.setenv("TPU_CHIPS_PER_HOST_BOUNDS", "2,2,1")
+    assert TpuAcceleratorManager.detect_num_chips() == 4  # nothing better known
+    monkeypatch.setattr(TpuAcceleratorManager, "device_chip_ids", staticmethod(lambda: [2]))
+    assert TpuAcceleratorManager.detect_num_chips() == 1
+    assert TpuAcceleratorManager.node_chip_ids(1) == [2]
+    assert TpuAcceleratorManager.node_chip_ids(4) == [0, 1, 2, 3]  # stated, not detected
+    monkeypatch.setenv("RAY_TPU_NUM_CHIPS", "8")
+    assert TpuAcceleratorManager.detect_num_chips() == 8
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "0,1")
+    assert TpuAcceleratorManager.detect_num_chips() == 2
+
+
+def test_visible_chips_env():
+    env = TpuAcceleratorManager.visible_chips_env
+    assert env([0, 1, 2, 3], [0, 1, 2, 3]) == {}  # the whole node: defaults
+    assert env([2], [2]) == {}
+    assert env([3], [0, 1, 2, 3]) == {
+        "TPU_VISIBLE_CHIPS": "3", "TPU_CHIPS_PER_HOST_BOUNDS": "1,1,1",
+        "TPU_HOST_BOUNDS": "1,1,1",
+    }
+    assert env([0, 1], [0, 1, 2, 3])["TPU_CHIPS_PER_HOST_BOUNDS"] == "1,2,1"
+    with pytest.raises(ValueError):
+        env([0, 1, 2], [0, 1, 2, 3])
+
+
 def test_collective_allreduce_actors(ray_start):
     @ray_tpu.remote
     class Worker:

@@ -171,3 +171,33 @@ def test_fold_heads_parity(nk_blocks):
     for a, b, name in zip(g4, g1, "qkv"):
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4,
                                    err_msg=f"d{name} fold mismatch")
+
+
+@pytest.mark.parametrize("with_segments", [False, True])
+def test_attention_flash_under_mesh_matches_xla(cpu_devices, with_segments):
+    """Under a multi-device mesh `attention(impl="flash")` runs one
+    kernel per shard inside a shard_map (a Mosaic kernel has no
+    partitioning rule): batch over fsdp, heads over tp, values and
+    gradients as the unsharded composite."""
+    from ray_tpu.ops.attention import attention
+    from ray_tpu.parallel.context import parallel_context
+    from ray_tpu.parallel.mesh import MeshSpec, make_mesh
+
+    mesh = make_mesh(MeshSpec(fsdp=2, tp=2), devices=cpu_devices[:4])
+    B, S, H, KVH, D = 4, 64, 4, 2, 32
+    q, k, v = make_qkv(jax.random.key(7), B, S, S, H, KVH, D)
+    seg = (jnp.arange(S)[None, :] >= S // 2).astype(jnp.int32).repeat(B, 0)
+    seg = seg if with_segments else None
+
+    def loss(impl, q, k, v):
+        with parallel_context(mesh):
+            o = attention(q, k, v, causal=True, segment_ids=seg, impl=impl)
+        return (o * o).sum(), o
+
+    (_, ref), gref = jax.value_and_grad(
+        lambda *a: loss("xla", *a), argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, out), gout = jax.jit(jax.value_and_grad(
+        lambda *a: loss("flash", *a), argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    for a, b in zip(gout, gref):
+        np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)

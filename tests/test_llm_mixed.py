@@ -164,13 +164,16 @@ def _prompts():
     ]
 
 
-def test_mixed_greedy_token_identical():
+@pytest.mark.parametrize("attn_impl", ["auto", "pallas_interpret"])
+def test_mixed_greedy_token_identical(attn_impl):
     """Chunked long prompts + short prompts through the ragged dispatch
-    must be BITWISE identical to the split engine."""
+    must be BITWISE identical to the split engine — also with the Pallas
+    ragged kernel inside the engine's mixed step (pad sequences of
+    q_len 0 included, which no standalone kernel test packs)."""
     prompts = _prompts()
     sp = SamplingParams(max_tokens=16, temperature=0.0, ignore_eos=True)
     ref = _engine(False).generate(prompts, sp)
-    eng = _engine(True)
+    eng = _engine(True, attn_impl=attn_impl)
     assert eng.generate(prompts, sp) == ref
     st = eng.stats()["mixed"]
     assert st["dispatches"] > 0 and st["prefill_tokens"] > 0

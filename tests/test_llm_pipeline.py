@@ -393,7 +393,7 @@ def test_chunk_controller_deterministic_and_bounded():
             picks.append(n)
         return picks
 
-    # a tunneled-device-shaped trace: huge host overhead, cheap chunks
+    # a host-bound trace: huge host overhead, cheap chunks
     # -> the controller ratchets UP (and deterministically)
     trace_up = [(70.0, 30.0, 40.0, 8)] * 6
     picks = replay(trace_up)

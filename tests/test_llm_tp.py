@@ -82,3 +82,13 @@ def test_tp_engine_rejects_indivisible_heads():
     bad = dataclasses.replace(llama.LLAMA_TINY, n_kv_heads=3)
     with pytest.raises(ValueError, match="not divisible"):
         LLMEngine(EngineConfig(model=bad, mesh_spec=MeshSpec(tp=2, dp=-1)))
+
+
+def test_tp_engine_rejects_unpartitionable_pallas_kernel():
+    # a Mosaic kernel cannot be partitioned by the TPU compiler: the
+    # combination must fail at construction, not at the first compile
+    with pytest.raises(ValueError, match="pallas"):
+        LLMEngine(EngineConfig(
+            model=llama.LLAMA_TINY, attn_impl="pallas",
+            mesh_spec=MeshSpec(tp=2, dp=-1),
+        ))

@@ -3,6 +3,8 @@ must reproduce the sequential layer stack exactly — forward AND backward
 (reference role: vLLM PP via compiled graphs, compiled_dag_node.py:795;
 here it's ppermute + lax.scan inside one jitted program)."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -113,3 +115,35 @@ def test_pipeline_training_reduces_loss():
         state, metrics = step(state, batch)
         losses.append(float(metrics["loss"]))
     assert losses[-1] < losses[0] / 1.5, losses
+
+
+@pytest.mark.parametrize(
+    "spec", [MeshSpec(pp=2, fsdp=2), MeshSpec(pp=2)], ids=["pp_fsdp", "pp_only"]
+)
+def test_pipeline_train_step_with_flash_matches_xla(spec):
+    """`attention(impl="flash")` shards its kernel over the mesh itself,
+    and under pp it is already inside the pipeline's shard_map: it takes
+    over only the axes that are still automatic (or none)."""
+    import dataclasses
+
+    import optax
+
+    from ray_tpu.train.step import TrainState, make_train_step
+
+    mesh = make_mesh(spec, devices=jax.devices()[: math.prod(spec.sizes())])
+    rules = default_rules(layers="pp")
+    opt = optax.adamw(1e-2)
+    batch = _batch(llama.LLAMA_TINY)
+    losses = {}
+    for impl in ("xla", "flash"):
+        cfg = dataclasses.replace(llama.LLAMA_TINY, attention_impl=impl)
+        state = TrainState.create(llama.init_params(cfg, jax.random.key(0)), opt)
+        step = make_train_step(
+            lambda p, b, cfg=cfg: llama.loss_fn(p, b, cfg), opt, mesh=mesh, rules=rules
+        )
+        losses[impl] = []
+        for _ in range(3):
+            state, metrics = step(state, batch)
+            losses[impl].append(float(metrics["loss"]))
+    np.testing.assert_allclose(losses["flash"], losses["xla"], rtol=5e-3)
+    assert losses["flash"][-1] < losses["flash"][0]

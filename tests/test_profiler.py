@@ -239,8 +239,20 @@ def test_chip_peaks_cpu_fallback():
     from ray_tpu.profiler import chip_peaks
 
     peaks = chip_peaks()
+    assert peaks.nominal
     assert peaks.flops > 0 and peaks.hbm_bytes_s > 0
     assert peaks.ridge_intensity > 0
+
+
+def test_chip_peaks_unknown_accelerator_raises():
+    from types import SimpleNamespace
+
+    from ray_tpu.profiler import chip_peaks
+
+    v5e = chip_peaks(SimpleNamespace(platform="tpu", device_kind="TPU v5 lite"))
+    assert v5e.flops == 197e12 and v5e.hbm_bytes_s == 819e9 and not v5e.nominal
+    with pytest.raises(ValueError, match="TPU v99"):
+        chip_peaks(SimpleNamespace(platform="tpu", device_kind="TPU v99"))
 
 
 def test_compiled_cost_populated_on_cpu():

@@ -1,0 +1,232 @@
+"""What the CPU sandbox can say about the chip without one.
+
+The TPU's compiler is installed beside the CPU backend and compiles for
+a chip that is described, not attached (on-chip-measurement guide, §2):
+every Pallas kernel the trainer or the engine can select is compiled
+here for a `v5e:2x2` at Mistral-7B head shapes (32 heads / 8 KV heads /
+head_dim 128, 16-row pages). Interpret mode cannot see what this sees —
+`ragged_attention_pallas` passed every interpret test while Mosaic
+refused its unaligned row window. Nothing runs: a compile that passes
+is not a chip run.
+
+Plus the two host-side contracts of the bring-up: `chip_smoke.py` runs
+no phase without a TPU, and the compile-cache helper's placement rule.
+"""
+
+import json
+import os
+import subprocess
+import sys
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+H, KVH, D, PAGE, NUM_PAGES = 32, 8, 128, 16, 512
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Devices of a described v5e:2x2, with the persistent compile cache
+    off around the module: an entry written for a described chip cannot
+    be read back without one, and the next compile would warn."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e!r}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes, sharding):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in shapes]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo  # the kernel itself, not a fallback
+    return hlo
+
+
+def _one_chip(devices):
+    return jax.sharding.SingleDeviceSharding(devices[0])
+
+
+_BF16, _I32 = jnp.bfloat16, jnp.int32
+_CACHE = ((KVH, NUM_PAGES * PAGE + PAGE, D), _BF16)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
+def test_flash_attention_compiles_for_v5e(v5e, grad):
+    from ray_tpu.ops.flash import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    def bwd(q, k, v):
+        return jax.grad(
+            lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    B, S = 2, 1024
+    _compile(bwd if grad else fwd,
+             ((B, S, H, D), _BF16), ((B, S, KVH, D), _BF16), ((B, S, KVH, D), _BF16),
+             sharding=_one_chip(v5e))
+
+
+@pytest.mark.parametrize("in_pipeline", [False, True], ids=["fsdp_tp", "pp_fsdp"])
+def test_flash_attention_compiles_under_a_mesh(v5e, in_pipeline):
+    """A Mosaic kernel cannot be partitioned by the compiler: under a
+    multi-device mesh `attention(impl="flash")` must run it per shard,
+    also from inside the pipeline's own shard_map over `pp`."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ray_tpu.ops.attention import attention
+    from ray_tpu.parallel.context import parallel_context
+    from ray_tpu.parallel.mesh import MESH_AXES
+    from ray_tpu.parallel.pipeline import pipeline_apply
+
+    B, S = 4, 1024
+    shape = (1, 2, 2, 1, 1, 1) if in_pipeline else (1, 1, 2, 1, 1, 2)
+    mesh = Mesh(np.asarray(v5e).reshape(shape), MESH_AXES)
+
+    def attend(q, k, v):
+        return attention(q, k, v, causal=True, impl="flash")
+
+    def stage(_, x):  # one "layer" per stage: attention over its microbatch
+        q = x.reshape(x.shape[:2] + (H, D))
+        return attend(q, q[:, :, :KVH], q[:, :, :KVH]).reshape(x.shape)
+
+    def fn(*args):
+        with parallel_context(mesh):
+            if in_pipeline:
+                return pipeline_apply(mesh, stage, *args)
+            return attend(*args)
+
+    if in_pipeline:
+        shapes = ((2, 1), jnp.float32), ((B, S, H * D), _BF16)
+        sharding = NamedSharding(mesh, P(("dp", "fsdp")))
+    else:
+        shapes = (((B, S, H, D), _BF16),) + (((B, S, KVH, D), _BF16),) * 2
+        sharding = NamedSharding(mesh, P(("dp", "fsdp"), None, "tp", None))
+    # flash picks interpret mode from the default backend, which is the
+    # CPU here: steer it in the test, not through an option of the program
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        hlo = _compile(fn, *shapes, sharding=sharding)
+    assert "all-gather" not in hlo  # nothing replicated to dodge the kernel
+
+
+def test_paged_attention_pallas_compiles_for_v5e(v5e):
+    from ray_tpu.ops.paged_attention import paged_attention_pallas
+
+    B, MB = 16, 16
+    _compile(
+        lambda q, k, v, bt, ctx: paged_attention_pallas(
+            q, k, v, bt, ctx, block_size=PAGE),
+        ((B, H, D), _BF16), _CACHE, _CACHE, ((B, MB), _I32), ((B,), _I32),
+        sharding=_one_chip(v5e),
+    )
+
+
+@pytest.mark.parametrize(
+    "T,B,MB,max_q_len",
+    [(16, 16, 16, 1), (512, 16, 32, 256)],
+    ids=["decode_only", "mixed_prefill_decode"],
+)
+@pytest.mark.parametrize("dtype", [_BF16, jnp.float32], ids=["bf16", "fp32"])
+def test_ragged_attention_pallas_compiles_for_v5e(v5e, T, B, MB, max_q_len, dtype):
+    """The packed row window starts at a run-time row, cu_q_lens[b] * G:
+    the kernel must slice from a start Mosaic can prove tile-aligned
+    (16 rows for bf16, 8 for fp32)."""
+    from ray_tpu.ops.ragged import ragged_attention_pallas
+
+    cache = (_CACHE[0], dtype)
+    _compile(
+        lambda q, k, v, bt, cu, ctx: ragged_attention_pallas(
+            q, k, v, bt, cu, ctx, block_size=PAGE, max_q_len=max_q_len),
+        ((T, H, D), dtype), cache, cache, ((B, MB), _I32), ((B + 1,), _I32),
+        ((B,), _I32),
+        sharding=_one_chip(v5e),
+    )
+
+
+def test_engine_programs_with_pallas_compile_for_v5e(v5e):
+    """`EngineConfig(mixed_batch=True, attn_impl="pallas")` is accepted,
+    so its own programs must compile: the decode step around the paged
+    kernel and the mixed step around the ragged one, Mistral-7B wide,
+    one layer deep."""
+    import dataclasses
+
+    from ray_tpu.llm.engine import EngineConfig, LLMEngine
+    from ray_tpu.models import llama
+    from ray_tpu.models.registry import get_model_config
+
+    cfg = dataclasses.replace(get_model_config("mistral-7b"), n_layers=1)
+    eng = LLMEngine(
+        EngineConfig(model=cfg, mixed_batch=True, attn_impl="pallas", block_size=PAGE),
+        params=jax.eval_shape(lambda: llama.init_params(cfg, jax.random.key(0))),
+    )
+    one = _one_chip(v5e)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, _I32, sharding=one)
+
+    params, cache = on_chip(eng.params), on_chip(eng.cache)
+    B, MB, T = 4, 16, 256
+    for lowered in (
+        eng._decode.lower(params, i32(B), i32(B), i32(B), i32(B, MB), i32(B), cache, None),
+        eng._mixed_fn.lower(params, i32(T), i32(T), i32(T), i32(B, MB), i32(B + 1),
+                            i32(B), cache, None),
+    ):
+        assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+def test_chip_smoke_runs_no_phase_without_a_tpu():
+    p = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=120,
+    )
+    assert p.returncode != 0
+    lines = [json.loads(l) for l in p.stdout.splitlines() if l.startswith("{")]
+    assert lines[-1]["ok"] is False
+    assert lines[-1]["device"]["platform"] == "cpu"
+    # the first child refused at the device check; nothing after it ran
+    assert [l.get("phase") for l in lines[:-1]] == ["train"]
+    assert "error" in lines[0] and "losses" not in lines[0]
+
+
+def test_compile_cache_placement(monkeypatch):
+    from ray_tpu.utils import compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        # placed from outside: the helper sets nothing
+        monkeypatch.setenv(compile_cache.ENV_VAR, "/somewhere/else")
+        assert compile_cache.configure_compile_cache() == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir == was
+        # a process that asked for the CPU by name: no cache at all
+        monkeypatch.delenv(compile_cache.ENV_VAR)
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        assert compile_cache.configure_compile_cache() is None
+        assert jax.config.jax_compilation_cache_dir == was
+        # otherwise: the fixed path under the checkout, the same every call
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+        want = os.path.join(REPO, ".jax_cache")
+        assert compile_cache.configure_compile_cache() == want
+        assert compile_cache.configure_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
