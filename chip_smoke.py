@@ -29,6 +29,13 @@ paths and what they are compared with, in one process that drives all
 four chips: the tensor-parallel engine, the fsdp x tp train step, and
 the one-chip train step on the same seed and batch.
 
+`--phase serve --obs capture|trace` (by hand) repeats the serve phase
+with `ray_tpu.obs.capture()` open around the traffic, so its `run_s`
+against a plain run is what recording layer spans costs; `trace` also
+takes a JAX profiler trace of the default engine's warm pass and reports
+the program's spans and program names found in it, and how far the
+recorder's clock is from the profiler's.
+
 Every phase prints one JSON line. The last line of stdout is
 `{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}`;
 on any failure `"ok": false` and a non-zero exit. Without a TPU no
@@ -302,17 +309,60 @@ def free_port() -> int:
         return s.getsockname()[1]
 
 
+def trace_digest(trace_dir: str, spans: list) -> dict:
+    """What a profiler trace of the serve leg shows of the program: its
+    layer spans among the host events, the names on the device's XLA
+    Modules line, and the distance between each recorded engine.step's
+    start (recorder clock + the clock markers' offset) and the nearest
+    engine.step event in the trace."""
+    import glob
+    import shutil
+
+    import jax
+
+    from ray_tpu import obs
+
+    path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    host, modules = [], set()
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            if plane.name.startswith("/device:"):
+                if line.name == "XLA Modules":
+                    modules.update(e.name.split("(")[0] for e in line.events)
+            else:
+                host.extend((e.name, e.start_ns * 1e-9) for e in line.events)
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    by_name: dict = {}
+    for name, _ in host:
+        if name.startswith(("runner.", "engine.")):
+            by_name[name] = by_name.get(name, 0) + 1
+    offset = obs.clock_offset(host)
+    steps = sorted(t for name, t in host if name == "engine.step")
+    errs = sorted(
+        min(abs(s.start + offset - t) for t in steps)
+        for s in spans if s.name == "engine.step"
+    ) if offset is not None and steps else []
+    return {"host_spans": by_name, "xla_modules": sorted(modules),
+            "clock_offset_s": offset, "spans_compared": len(errs),
+            "clock_err_ms_median": 1e3 * errs[len(errs) // 2] if errs else None,
+            "clock_err_ms_max": 1e3 * errs[-1] if errs else None}
+
+
 def serve_variant(name: str, engine_kwargs: dict, cfg, params, ref_logits,
-                  seed: int) -> dict:
+                  seed: int, observe: str = "off") -> dict:
     """Deploy the OpenAI app with this engine, POST the prompts twice
     (cold, then fresh prompts of the same lengths warm), hold every
-    returned token to the reference, read /v1/stats."""
+    returned token to the reference, read /v1/stats. `observe`:
+    "capture" keeps obs.capture() open around both passes, "trace" also
+    takes a profiler trace of the warm pass."""
     import concurrent.futures as cf
+    import contextlib
 
+    import jax
     import numpy as np
     import requests
 
-    from ray_tpu import serve
+    from ray_tpu import obs, serve
     from ray_tpu.llm.engine import EngineConfig
     from ray_tpu.llm.openai_api import LLMConfig, build_openai_app
 
@@ -338,15 +388,27 @@ def serve_variant(name: str, engine_kwargs: dict, cfg, params, ref_logits,
 
     rng = np.random.default_rng(seed)
     worst_gap, n_tokens, pass_s = 0.0, 0, []
+    spans_before = obs.layer_counters()
+    trace_dir = os.path.join("chiprun_out", "chip_smoke_trace")
+    digest = None
     try:
-        for _ in range(2):
+        for warm in (False, True):
             # ids below the tokenizer's EOS (vocab_size - 1)
             prompts = [rng.integers(3, cfg.vocab_size - 1, n).tolist()
                        for n in PROMPT_LENS]
+            tracing = observe == "trace" and warm
+            if tracing:
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level, opts.host_tracer_level = 0, 2
+                jax.profiler.start_trace(trace_dir, profiler_options=opts)
+            capture = obs.capture() if observe != "off" else contextlib.nullcontext([])
             t0 = time.perf_counter()
-            with cf.ThreadPoolExecutor(len(prompts)) as pool:
+            with capture as spans, cf.ThreadPoolExecutor(len(prompts)) as pool:
                 outs = list(pool.map(complete, prompts))
             pass_s.append(time.perf_counter() - t0)
+            if tracing:
+                jax.profiler.stop_trace()
+                digest = trace_digest(trace_dir, spans)
             for prompt, out in zip(prompts, outs):
                 if not 1 <= len(out) <= NEW_TOKENS:
                     raise RuntimeError(f"{len(out)} tokens returned, want 1..{NEW_TOKENS}")
@@ -358,6 +420,12 @@ def serve_variant(name: str, engine_kwargs: dict, cfg, params, ref_logits,
         stats = requests.get(f"{base}/v1/stats", timeout=60).json()
     finally:
         serve.shutdown()
+    # what this variant added to the process's layer spans: count, busy ms
+    trace_row = {
+        n: [c["count"] - spans_before.get(n, {"count": 0})["count"],
+            round(1e3 * (c["busy_s"] - spans_before.get(n, {"busy_s": 0.0})["busy_s"]), 2)]
+        for n, c in sorted(stats["trace"].items()) if n.startswith(("runner.", "engine."))
+    }
     kernels = engine_kernels_in_hlo(cfg, params, engine_kwargs)
     checks = {
         "logit_agreement": worst_gap <= LOGIT_TOL,
@@ -374,7 +442,10 @@ def serve_variant(name: str, engine_kwargs: dict, cfg, params, ref_logits,
             "kernel_in_hlo": kernels,
             "max_logit_gap": round(worst_gap, 4), "tokens_checked": n_tokens,
             "compile_s": round(pass_s[0] - pass_s[1], 2),
-            "run_s": round(pass_s[1], 3)}
+            "run_s": round(pass_s[1], 3), "observe": observe,
+            "trace": trace_row, "counters": stats["counters"],
+            "runner_lock": stats["runner_lock"],
+            **({"profile": digest} if digest else {})}
 
 
 def engine_kernels_in_hlo(cfg, params, engine_kwargs: dict) -> dict:
@@ -502,7 +573,9 @@ def phase_serve(args) -> bool:
     for name, kw in (("default", {}), ("mixed", {"mixed_batch": True}),
                      ("pallas", {"attn_impl": PALLAS}),
                      ("mixed_pallas", {"mixed_batch": True, "attn_impl": PALLAS})):
-        rec = serve_variant(name, kw, cfg, params, ref_logits, args.seed)
+        rec = serve_variant(name, kw, cfg, params, ref_logits, args.seed,
+                            args.obs if name == "default" or args.obs != "trace"
+                            else "capture")
         ok &= rec["ok"]
         emit({"phase": "serve", **model, **rec,
               "logit_tolerance": LOGIT_TOL,
@@ -703,6 +776,8 @@ def main() -> int:
                     help="4: only the cross-chip paths (run by hand on a four-chip host)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--phase", choices=sorted(PHASES), help=argparse.SUPPRESS)
+    ap.add_argument("--obs", choices=("off", "capture", "trace"), default="off",
+                    help="with --phase serve, by hand: what recording layer spans costs")
     args = ap.parse_args()
     if args.phase:  # child
         try:

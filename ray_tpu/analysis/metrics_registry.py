@@ -21,9 +21,8 @@ import re
 INSTRUMENTED = [
     ("ray_tpu.obs.slo", "register_all"),
     ("ray_tpu.obs.telemetry", "register_metrics"),
+    ("ray_tpu.obs.recorder", "register_metrics"),
     ("ray_tpu.profiler.trace", None),
-    ("ray_tpu.llm.decode_loop", "chunk_histogram"),
-    ("ray_tpu.llm.pipeline", "register_metrics"),
     ("ray_tpu.llm.spec.stats", "_spec_metrics"),
     ("ray_tpu.llm.admission", "register_metrics"),
     ("ray_tpu.llm.engine", "register_metrics"),

@@ -8,14 +8,19 @@ needs distributions, not vibes: how long do callers WAIT for the lock,
 how long does the holder KEEP it, and which RPC methods pay. This
 module provides:
 
- * ``TimedRLock`` — a thin wrapper around ``threading.RLock`` that
-   feeds wait-time (outermost acquire) and hold-time (outermost
-   release) histograms, tagged by lock domain. When timing is disabled
-   (the default) acquire/release cost one attribute load and an integer
-   add on top of the raw RLock — no clock reads, no histogram locks.
-   The wrapper implements the ``_release_save`` / ``_acquire_restore``
-   / ``_is_owned`` protocol so ``threading.Condition(TimedRLock(...))``
-   works unchanged (the GCS event pubsub builds exactly that).
+ * ``TimedRLock`` — a thin wrapper around ``threading.RLock`` (or,
+   with ``reentrant=False``, a plain ``threading.Lock``: the serving
+   runner's) that feeds wait-time (outermost acquire) and hold-time
+   (outermost release) histograms, tagged by lock domain, and keeps
+   plain totals a stats surface reads without taking the lock. When
+   timing is disabled (the default) acquire/release cost one attribute
+   load and an integer add on top of the raw lock — no clock reads, no
+   histogram locks; a lock built with ``always=True`` is timed whatever
+   the switch says (one lock a request's latency hangs on, taken a few
+   times per engine step). The wrapper implements the
+   ``_release_save`` / ``_acquire_restore`` / ``_is_owned`` protocol so
+   ``threading.Condition(TimedRLock(...))`` works unchanged (the GCS
+   event pubsub builds exactly that).
  * per-RPC-method server latency histograms (``RpcServer._dispatch``
    observes them), pricing each control-plane method end to end —
    executor queueing included, response write excluded.
@@ -111,16 +116,30 @@ class TimedRLock:
     acquire/release — reentrant hops stay free.
     """
 
-    def __init__(self, domain: str):
-        self._lk = threading.RLock()
+    def __init__(self, domain: str, reentrant: bool = True,
+                 always: bool = False):
+        self._lk = threading.RLock() if reentrant else threading.Lock()
+        self._always = always
         self._domain = domain
         self._depth = 0        # mutated only by the current holder
         self._t_hold0 = 0.0    # outermost-acquire timestamp (0 = untimed)
+        # totals of the timed acquires, written only by the holder (the
+        # lock itself serializes them) and read without it
+        self.acquires = 0
+        self.wait_s = 0.0
+        self.hold_s = 0.0
+        self.last_wait_s = 0.0  # the current holder's own wait
+
+    def totals(self) -> dict:
+        """{"acquires", "wait_s", "hold_s"} over the timed outermost
+        acquires so far; takes no lock."""
+        return {"acquires": self.acquires, "wait_s": self.wait_s,
+                "hold_s": self.hold_s}
 
     # -- core lock protocol ---------------------------------------------------
 
     def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
-        if not _ENABLED[0]:
+        if not (_ENABLED[0] or self._always):
             ok = self._lk.acquire(blocking, timeout)
             if ok:
                 self._depth += 1
@@ -132,21 +151,28 @@ class TimedRLock:
         self._depth += 1
         if self._depth == 1:
             now = time.perf_counter()
-            lock_wait_histogram().observe(
-                (now - t0) * 1e3, {"domain": self._domain}
-            )
-            self._t_hold0 = now
+            self._note_wait(now - t0, now)
         return True
 
-    def release(self) -> None:
-        if self._depth == 1 and self._t_hold0:
-            # timing may have been disabled mid-hold: the observe is
-            # gated on the recorded start, not on the current switch
-            lock_hold_histogram().observe(
-                (time.perf_counter() - self._t_hold0) * 1e3,
-                {"domain": self._domain},
-            )
+    def _note_wait(self, wait_s: float, now: float) -> None:
+        self.acquires += 1
+        self.wait_s += wait_s
+        self.last_wait_s = wait_s
+        self._t_hold0 = now
+        lock_wait_histogram().observe(wait_s * 1e3, {"domain": self._domain})
+
+    def _note_hold(self) -> None:
+        # timing may have been disabled mid-hold: gated on the recorded
+        # start, not on the current switch
+        if self._t_hold0:
+            held = time.perf_counter() - self._t_hold0
             self._t_hold0 = 0.0
+            self.hold_s += held
+            lock_hold_histogram().observe(held * 1e3, {"domain": self._domain})
+
+    def release(self) -> None:
+        if self._depth == 1:
+            self._note_hold()
         self._depth -= 1
         self._lk.release()
 
@@ -160,29 +186,23 @@ class TimedRLock:
     # -- Condition protocol ---------------------------------------------------
     # threading.Condition(lock) delegates to these when present; wait()
     # fully releases a reentrant lock and restores its depth after.
+    # For the reentrant form only: nothing builds a Condition over a
+    # plain-Lock TimedRLock.
 
     def _release_save(self):
-        if self._t_hold0:
-            lock_hold_histogram().observe(
-                (time.perf_counter() - self._t_hold0) * 1e3,
-                {"domain": self._domain},
-            )
-            self._t_hold0 = 0.0
+        self._note_hold()
         depth, self._depth = self._depth, 0
         return (self._lk._release_save(), depth)
 
     def _acquire_restore(self, saved) -> None:
         state, depth = saved
-        timing = _ENABLED[0]
+        timing = _ENABLED[0] or self._always
         t0 = time.perf_counter() if timing else 0.0
         self._lk._acquire_restore(state)
         self._depth = depth
         if timing:
             now = time.perf_counter()
-            lock_wait_histogram().observe(
-                (now - t0) * 1e3, {"domain": self._domain}
-            )
-            self._t_hold0 = now
+            self._note_wait(now - t0, now)
 
     def _is_owned(self) -> bool:
         return self._lk._is_owned()

@@ -15,6 +15,7 @@ import functools
 import inspect
 from typing import Any, Optional, Sequence, Union
 
+from ray_tpu import obs
 from ray_tpu.core import errors, runtime as rt
 from ray_tpu.core.actor_runtime import Actor, ActorState
 from ray_tpu.core.placement import PlacementGroup, create_placement_group
@@ -94,30 +95,33 @@ def init(
     (python/ray/_private/client_mode_hook.py, ray client server) is
     just the normal attach path here.
     """
-    if address is not None:
-        if address.startswith("ray://"):
-            address = address[len("ray://"):]
-        if _CLUSTER[0] is not None:
-            if ignore_reinit_error:
-                return _CLUSTER[0]
-            raise RuntimeError(
-                "ray_tpu.init(address=...) called twice; pass ignore_reinit_error=True"
-            )
-        from ray_tpu.core.cluster_backend import ClusterBackend
+    # layer span runtime.init: what a process's start-up pays before it
+    # can submit anything (chipbench's setup_runtime_s.train reads it)
+    with obs.layer_span("runtime.init"):
+        if address is not None:
+            if address.startswith("ray://"):
+                address = address[len("ray://"):]
+            if _CLUSTER[0] is not None:
+                if ignore_reinit_error:
+                    return _CLUSTER[0]
+                raise RuntimeError(
+                    "ray_tpu.init(address=...) called twice; pass ignore_reinit_error=True"
+                )
+            from ray_tpu.core.cluster_backend import ClusterBackend
 
-        _CLUSTER[0] = ClusterBackend(address, namespace=namespace)
-        return _CLUSTER[0]
-    if rt.is_initialized():
-        if ignore_reinit_error:
-            return rt.get_runtime()
-        raise RuntimeError("ray_tpu.init() called twice; pass ignore_reinit_error=True")
-    return rt.init_runtime(
-        num_cpus=num_cpus,
-        num_tpus=num_tpus,
-        resources=resources,
-        worker_mode=worker_mode,
-        namespace=namespace,
-    )
+            _CLUSTER[0] = ClusterBackend(address, namespace=namespace)
+            return _CLUSTER[0]
+        if rt.is_initialized():
+            if ignore_reinit_error:
+                return rt.get_runtime()
+            raise RuntimeError("ray_tpu.init() called twice; pass ignore_reinit_error=True")
+        return rt.init_runtime(
+            num_cpus=num_cpus,
+            num_tpus=num_tpus,
+            resources=resources,
+            worker_mode=worker_mode,
+            namespace=namespace,
+        )
 
 
 def shutdown() -> None:

@@ -16,71 +16,11 @@ engine makes the same trade in its multi-step scheduling mode.
 
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.llm.sampling import sample_tokens
 from ray_tpu.models.llama_decode import decode_step
-
-
-_chunk_hist = None
-_runtime_hooks = None  # (get_runtime, TaskState), resolved once
-
-
-def _timeline_hooks():
-    """One-time resolution of the timeline-export hooks: the runtime
-    import is heavyweight and record_chunk sits on the decode hot path
-    (it used to pay these imports EVERY chunk)."""
-    global _runtime_hooks
-    if _runtime_hooks is None:
-        from ray_tpu.core import runtime as rt
-        from ray_tpu.core.events import TaskState
-
-        _runtime_hooks = (rt.get_runtime, TaskState)
-    return _runtime_hooks
-
-
-def chunk_histogram():
-    """Per-chunk wall-time histogram (engine hook, EngineConfig.profile):
-    one observation per decode round trip, tagged by device-side step
-    count and sampler mode, on the dashboard /metrics endpoint. Cached —
-    re-registering per chunk would take the process-wide registry lock
-    on the decode hot path."""
-    global _chunk_hist
-    if _chunk_hist is None:
-        from ray_tpu.util.metrics import Histogram
-
-        _chunk_hist = Histogram(
-            "llm_decode_chunk_ms",
-            description="profiler: wall ms per decode chunk round trip "
-            "(dispatch + device steps + host sync)",
-            boundaries=[0.5, 1, 2, 5, 10, 25, 50, 100, 250, 500, 1000, 5000],
-            tag_keys=("n_steps", "mode"),
-        )
-    return _chunk_hist
-
-
-def record_chunk(ms: float, n_steps: int, mode: str, batch_size: int) -> None:
-    """Publish one decode-chunk measurement: histogram + timeline span.
-    Observability must not break decode: every failure mode (metric name
-    registered with another type, runtime init, ...) is swallowed."""
-    try:
-        chunk_histogram().observe(
-            ms, tags={"n_steps": str(n_steps), "mode": mode}
-        )
-        get_runtime, TaskState = _timeline_hooks()
-        buf = get_runtime().task_events
-        end = time.time()
-        span = f"profile-decode-chunk-{time.monotonic_ns()}"
-        name = f"profile:decode_chunk:{n_steps}x{batch_size}"
-        buf.record(span, name, TaskState.RUNNING, kind="profile",
-                   worker="llm-engine", ts=end - ms / 1e3)
-        buf.record(span, name, TaskState.FINISHED, kind="profile",
-                   worker="llm-engine", ts=end)
-    except Exception:  # noqa: BLE001 — observability must not break decode
-        pass
 
 
 def decode_chunk(

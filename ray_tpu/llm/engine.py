@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu import obs
 from ray_tpu.chaos import harness as _chaos
 from ray_tpu.llm.kv_cache import (
     BlockAllocator,
@@ -43,6 +44,15 @@ from ray_tpu.obs import recorder as trace_recorder
 from ray_tpu.utils.logging import get_logger
 
 logger = get_logger("ray_tpu.llm.engine")
+
+
+def _named(name: str, fn):
+    """``fn`` under a stable name: jax.jit names the compiled module
+    after the function (``jit_llm_prefill``), which is what the
+    device's line of a profiler trace and the compile log show — a
+    lambda would read ``jit__lambda`` for every class of program."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 def prefix_cache_hit_counter():
@@ -188,10 +198,6 @@ class EngineConfig:
     # > pipeline.STOP_WIDTH_CAP stop ids, and by spec decoding, which
     # has its own round structure).
     pipeline_decode: bool = True
-    # profile=True: every decode round trip lands in the
-    # llm_decode_chunk_ms histogram + timeline (ray_tpu.profiler
-    # surfaces); profile_decode() gives the full roofline breakdown
-    profile: bool = False
     # speculative decoding (llm/spec/): a SpecConfig turns each decode
     # round into draft -> one batched verify pass (k+1 tokens per row
     # through the paged prefill path) -> distribution-preserving
@@ -288,6 +294,17 @@ class EngineConfig:
     def max_blocks_per_seq(self) -> int:
         return -(-self.model.max_seq // self.block_size)
 
+    def bt_widths(self) -> list[int]:
+        """Every block-table width LLMEngine._bt_width can return: powers
+        of two from the floor (16, or the model's maximum if that is
+        smaller) up to the maximum itself."""
+        top = self.max_blocks_per_seq
+        out, w = [], min(16, top)
+        while w < top:
+            out.append(w)
+            w *= 2
+        return out + [top]
+
 
 class RequestStatus:
     WAITING = "waiting"
@@ -336,6 +353,10 @@ class Request:
     t_first_token: Optional[float] = None
     t_span_cursor: Optional[float] = None
     _prefill_cached: int = 0
+    # seconds the request spent in front of the engine before
+    # add_request (the serving runner's lock): `arrival` starts the
+    # engine's clock, this is what a client's TTFT has on top of it
+    pre_engine_wait_s: float = 0.0
 
     @property
     def num_tokens(self) -> int:
@@ -387,15 +408,15 @@ class LLMEngine:
                 self.mesh, default_rules(), llama.logical_axes(c.model)
             )
         if params is None:
-            def init():
+            def llm_init_params():
                 return llama.init_params(c.model, jax.random.key(seed))
 
             # under a mesh the tree is born sharded (the pattern of
             # train/step.init_sharded_params): a model that only fits
             # spread over the chips must never exist whole on one first
             params = (
-                init() if param_shardings is None
-                else jax.jit(init, out_shardings=param_shardings)()
+                llm_init_params() if param_shardings is None
+                else jax.jit(llm_init_params, out_shardings=param_shardings)()
             )
         elif param_shardings is not None:
             params = jax.device_put(params, param_shardings)
@@ -450,20 +471,32 @@ class LLMEngine:
 
         # jitted entry points; cache buffers are donated so XLA updates pages
         # in place instead of copying the whole cache every step
-        self._prefill = jax.jit(
-            lambda params, t, p, sl, sm, bt, cl, cache, lora: prefill(
+        def llm_prefill(params, t, p, sl, sm, bt, cl, cache, lora):
+            return prefill(
                 params, t, p, sl, sm, bt, cl, cache, c.model,
                 block_size=c.block_size, lora=lora,
-            ),
-            donate_argnums=(7,),
-        )
-        self._decode = jax.jit(
-            lambda params, t, p, sm, bt, cl, cache, lora: decode_step(
+            )
+
+        def llm_decode(params, t, p, sm, bt, cl, cache, lora):
+            return decode_step(
                 params, t, p, sm, bt, cl, cache, c.model,
                 block_size=c.block_size, attn_impl=c.attn_impl, lora=lora,
-            ),
-            donate_argnums=(6,),
-        )
+            )
+
+        self._prefill = jax.jit(llm_prefill, donate_argnums=(7,))
+        self._decode = jax.jit(llm_decode, donate_argnums=(6,))
+        # always-on counters (counters()): plain ints written by the
+        # engine's own thread and read by anyone without a lock.
+        # _seen_programs: (class, static arguments and padded shapes)
+        # of every program dispatched so far — a new one is a compile
+        # or a cache load
+        self._n = {
+            "decode_steps": 0, "decode_row_steps": 0, "decode_tokens": 0,
+            "prefill_tokens": 0, "prefill_cached_tokens": 0,
+            "dispatches": {}, "first_calls": {},
+        }
+        self._seen_programs: set = set()
+        self._step_kind = "idle"
         self._decode_chunks: dict[tuple, Any] = {}  # (n_steps, mode) -> jitted
         # disaggregated serving: jitted KV-page scatter per padded width
         # (import_handoff), and prefix-cache accounting for stats()/the
@@ -531,14 +564,15 @@ class LLMEngine:
             from ray_tpu.models.llama_decode import mixed_step
 
             maxq = c.mixed_prefill_chunk
-            self._mixed_fn = jax.jit(
-                lambda params, t, p, sl, bt, cu, cl, cache, lora: mixed_step(
+
+            def llm_mixed(params, t, p, sl, bt, cu, cl, cache, lora):
+                return mixed_step(
                     params, t, p, sl, bt, cu, cl, cache, c.model,
                     block_size=c.block_size, max_q_len=maxq,
                     attn_impl=c.attn_impl, lora=lora,
-                ),
-                donate_argnums=(7,),
-            )
+                )
+
+            self._mixed_fn = jax.jit(llm_mixed, donate_argnums=(7,))
             self._mixed_stats = MixedStats()
 
     def _init_kv_cache(self):
@@ -546,20 +580,20 @@ class LLMEngine:
         crash-recovery rebuild path: recover(rebuild_kv=True))."""
         c = self.config
 
-        def alloc():
+        def llm_init_kv():
             return init_cache(
                 c.model, c.num_blocks * c.block_size, dtype=c.cache_dtype,
                 trash_slots=c.block_size,
             )
 
         if self.mesh is None:
-            return alloc()
+            return llm_init_kv()
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         # cache [L, kv_heads, slots, hd]: heads across tp, allocated
         # under jit so each chip only ever holds its own heads
         kv_sharding = NamedSharding(self.mesh, P(None, "tp", None, None))
-        return jax.jit(alloc, out_shardings=kv_sharding)()
+        return jax.jit(llm_init_kv, out_shardings=kv_sharding)()
 
     @staticmethod
     def _assert_chunk_bucket(n_steps: int) -> None:
@@ -580,16 +614,18 @@ class LLMEngine:
         if fn is None:
             from ray_tpu.llm.decode_loop import decode_chunk
 
-            fn = jax.jit(
-                lambda params, t, p, bt, cl, cache, temps, tks, tps, keys,
-                starts, remaining, lora:
-                decode_chunk(
+            def chunk(params, t, p, bt, cl, cache, temps, tks, tps, keys,
+                      starts, remaining, lora):
+                return decode_chunk(
                     params, t, p, bt, cl, cache, temps, tks, tps, keys,
                     starts, remaining,
                     c.model, n_steps=n_steps, block_size=c.block_size,
                     trash_slot=c.num_blocks * c.block_size,
                     attn_impl=c.attn_impl, sample_mode=sample_mode, lora=lora,
-                ),
+                )
+
+            fn = jax.jit(
+                _named(f"llm_decode_chunk_n{n_steps}_{sample_mode}", chunk),
                 donate_argnums=(5,),
             )
             self._decode_chunks[(n_steps, sample_mode)] = fn
@@ -609,17 +645,19 @@ class LLMEngine:
         key = (n_steps, sample_mode, "masked", stop_w)
         fn = self._decode_chunks.get(key)
         if fn is None:
-            fn = jax.jit(
-                lambda params, t, p, bt, cl, cache, temps, tks, tps, keys,
-                starts, max_toks, done, stop_ids, stop_on_eos, lora:
-                decode_chunk_masked(
+            def chunk(params, t, p, bt, cl, cache, temps, tks, tps, keys,
+                      starts, max_toks, done, stop_ids, stop_on_eos, lora):
+                return decode_chunk_masked(
                     params, t, p, bt, cl, cache, temps, tks, tps, keys,
                     starts, max_toks, done, stop_ids, stop_on_eos,
                     c.model, n_steps=n_steps, block_size=c.block_size,
                     trash_slot=c.num_blocks * c.block_size,
                     eos_id=c.eos_token_id, attn_impl=c.attn_impl,
                     sample_mode=sample_mode, lora=lora,
-                ),
+                )
+
+            fn = jax.jit(
+                _named(f"llm_pipe_chunk_n{n_steps}_w{stop_w}_{sample_mode}", chunk),
                 donate_argnums=(5,),
             )
             self._decode_chunks[key] = fn
@@ -633,13 +671,14 @@ class LLMEngine:
         if fn is None:
             from ray_tpu.models.llama_decode import verify_tokens
 
-            fn = jax.jit(
-                lambda params, t, p, sm, bt, cl, cache, lora: verify_tokens(
+            def verify(params, t, p, sm, bt, cl, cache, lora):
+                return verify_tokens(
                     params, t, p, sm, bt, cl, cache, c.model,
                     block_size=c.block_size, lora=lora,
-                ),
-                donate_argnums=(6,),
-            )
+                )
+
+            fn = jax.jit(_named(f"llm_verify_w{width}", verify),
+                         donate_argnums=(6,))
             self._verify_fns[width] = fn
         return fn
 
@@ -653,15 +692,15 @@ class LLMEngine:
             from ray_tpu.models.llama_decode import verify_tokens_ragged
 
             maxq = c.spec.num_draft_tokens + 1
-            self._verify_ragged = jax.jit(
-                lambda params, t, p, sl, bt, cu, cl, gi, cache, lora:
-                verify_tokens_ragged(
+
+            def llm_verify_ragged(params, t, p, sl, bt, cu, cl, gi, cache, lora):
+                return verify_tokens_ragged(
                     params, t, p, sl, bt, cu, cl, gi, cache, c.model,
                     block_size=c.block_size, max_q_len=maxq,
                     attn_impl=c.attn_impl, lora=lora,
-                ),
-                donate_argnums=(8,),
-            )
+                )
+
+            self._verify_ragged = jax.jit(llm_verify_ragged, donate_argnums=(8,))
         return self._verify_ragged
 
     @staticmethod
@@ -915,7 +954,36 @@ class LLMEngine:
 
         ALL admissible prefills are dispatched back-to-back and sampled
         in one batch with a single host sync, so the device queue stays
-        full across the whole admission burst."""
+        full across the whole admission burst.
+
+        Layer span engine.step (attrs kind = prefill | decode | mixed |
+        flush | idle, rows and waiting as the step found them), with
+        children engine.schedule, engine.prefill_dispatch,
+        engine.decode_dispatch (engine.mixed_dispatch for the ragged
+        program), engine.sync and engine.append: each idle gap of the
+        device falls inside one of them."""
+        with obs.layer_span("engine.step") as sp:
+            sp.attrs["rows"] = len(self.running)
+            sp.attrs["waiting"] = len(self.waiting)
+            self._step_kind = "idle"
+            outputs = self._step()
+            sp.attrs["kind"] = self._step_kind
+            return outputs
+
+    def _call(self, cls: str, key: tuple, fn, *args):
+        """Dispatch one engine program, counted by class; the first
+        dispatch of a (class, key) — the static arguments and padded
+        shapes that select the compiled program — is a compile or a
+        cache load, counted under first_calls."""
+        d = self._n["dispatches"]
+        d[cls] = d.get(cls, 0) + 1
+        if (cls, key) not in self._seen_programs:
+            self._seen_programs.add((cls, key))
+            f = self._n["first_calls"]
+            f[cls] = f.get(cls, 0) + 1
+        return fn(*args)
+
+    def _step(self) -> list[RequestOutput]:
         if _chaos.ACTIVE is not None:
             for _f in _chaos.fire(
                 "llm.engine.step",
@@ -945,6 +1013,7 @@ class LLMEngine:
             # handoff / recovery forced a sync outside step()): deliver
             # before doing anything else so no finish event is dropped
             out, self._pending_outputs = self._pending_outputs, []
+            self._step_kind = "flush"
             return out
         if self.kvfetch is not None:
             # land completed prefetches BEFORE the admission check: the
@@ -953,33 +1022,34 @@ class LLMEngine:
             # and _admission_need discounts the live-shared blocks
             self.kvfetch.tick()
         if self.waiting:
-            # QoS admission order: the highest-priority waiting request
-            # is admitted first (stable — strictly FIFO when priorities
-            # are uniform, i.e. every pre-fleet deployment)
-            self._promote_priority()
-            head = self.waiting[0]
-            if head.priority > 0 and self.running and (
-                len(self.running) >= self.config.max_num_seqs
-                or self._admission_need(head) > self.allocator.num_free
-            ):
-                # priority preemption: a paying tenant's request blocked
-                # on batch-slot or KV pressure displaces the lowest-
-                # priority running request (a batch tenant's decode /
-                # prefill) through the normal preempt/recover ladder —
-                # the victim recomputes, nothing is lost
-                victim = min(
-                    self.running, key=lambda r: (r.priority, -r.arrival)
-                )
-                if victim.priority < head.priority:
-                    flushed = self._pipe_flush()
-                    if flushed:
-                        return flushed
-                    self._preempt_one(
-                        below_priority=head.priority, reason="priority"
+            with obs.layer_span("engine.schedule"):
+                # QoS admission order: the highest-priority waiting request
+                # is admitted first (stable — strictly FIFO when priorities
+                # are uniform, i.e. every pre-fleet deployment)
+                self._promote_priority()
+                head = self.waiting[0]
+                if head.priority > 0 and self.running and (
+                    len(self.running) >= self.config.max_num_seqs
+                    or self._admission_need(head) > self.allocator.num_free
+                ):
+                    # priority preemption: a paying tenant's request blocked
+                    # on batch-slot or KV pressure displaces the lowest-
+                    # priority running request (a batch tenant's decode /
+                    # prefill) through the normal preempt/recover ladder —
+                    # the victim recomputes, nothing is lost
+                    victim = min(
+                        self.running, key=lambda r: (r.priority, -r.arrival)
                     )
-                    # the victim re-queued at the head: restore QoS order
-                    # so the admission check below sees the paying tenant
-                    self._promote_priority()
+                    if victim.priority < head.priority:
+                        flushed = self._pipe_flush()
+                        if flushed:
+                            return flushed
+                        self._preempt_one(
+                            below_priority=head.priority, reason="priority"
+                        )
+                        # the victim re-queued at the head: restore QoS order
+                        # so the admission check below sees the paying tenant
+                        self._promote_priority()
         if self.config.mixed_batch:
             # unified dispatch: admission + in-flight prefill chunks +
             # every decode row in ONE ragged program (llm/mixed.py);
@@ -1013,11 +1083,14 @@ class LLMEngine:
                     break  # no cache room: decode to free blocks
                 admitted.append(got)
             if admitted:
+                self._step_kind = "prefill"
                 reqs = [r for r, _ in admitted]
-                logits = jnp.concatenate([l for _, l in admitted], axis=0)
-                tok, logprob = self._sample_batch(logits, reqs)
+                with obs.layer_span("engine.sync"):
+                    logits = jnp.concatenate([l for _, l in admitted], axis=0)
+                    tok, logprob = self._sample_batch(logits, reqs)
                 t1 = time.time()  # host sync done: first token exists
-                outputs = self._append_tokens(reqs, tok, logprob)
+                with obs.layer_span("engine.append"):
+                    outputs = self._append_tokens(reqs, tok, logprob)
                 for r in reqs:
                     self._obs_span(
                         r, "engine.prefill",
@@ -1262,13 +1335,13 @@ class LLMEngine:
     def _kv_import_fn(self, width: int):
         fn = self._kv_imports.get(width)
         if fn is None:
-            fn = jax.jit(
-                lambda cache, k, v, slots: {
+            def llm_kv_scatter(cache, k, v, slots):
+                return {
                     "k": cache["k"].at[:, :, slots, :].set(k),
                     "v": cache["v"].at[:, :, slots, :].set(v),
-                },
-                donate_argnums=(0,),
-            )
+                }
+
+            fn = jax.jit(llm_kv_scatter, donate_argnums=(0,))
             self._kv_imports[width] = fn
         return fn
 
@@ -1293,7 +1366,8 @@ class LLMEngine:
         vp = np.zeros_like(kp)
         kp[:, :, :n_kv] = k
         vp[:, :, :n_kv] = v
-        self.cache = self._kv_import_fn(width)(
+        self.cache = self._call(
+            "kv_scatter", (width,), self._kv_import_fn(width),
             self.cache, jnp.asarray(kp, dt), jnp.asarray(vp, dt),
             jnp.asarray(sl),
         )
@@ -1474,6 +1548,54 @@ class LLMEngine:
             out["mixed"] = self._mixed_stats.to_dict()
         return out
 
+    def warmup(self, sample_modes: tuple = ("greedy",),
+               stop_widths: tuple = (1,)) -> dict:
+        """Run every program of this engine's own tables once, so that
+        none compiles (or loads) under traffic: prefill_buckets() x
+        bt_widths(); decode_buckets() x CHUNK_BUCKETS x bt_widths() x
+        ``stop_widths`` x ``sample_modes`` for the decode path the
+        config selects (the defaults are what requests without stop ids
+        at temperature 0 use); the mixed and verify programs where the
+        config has them. Writes only the cache's trash page. Call it
+        before traffic, from the thread that owns the engine. Returns
+        {class: {"programs", "seconds", "compiled", "loaded"}}, the last
+        two from the compile log (ray_tpu.obs.compile_log())."""
+        from ray_tpu.llm.warmup import warm_engine
+
+        return warm_engine(self, sample_modes, stop_widths)
+
+    def counters(self) -> dict:
+        """Always-on counts since the engine was built, as plain numbers
+        read WITHOUT any lock (the serving runner's included): take two
+        snapshots and subtract. decode_steps are device decode steps,
+        decode_row_steps the live rows summed over them, so the decode
+        batch's occupancy over an interval is
+        d(decode_row_steps) / (d(decode_steps) x max_num_seqs);
+        prefill_tokens were computed, prefill_cached_tokens came from the
+        prefix cache; a first_call is a program (class, static
+        arguments, padded shapes) dispatched for the first time, so a
+        compile or a cache load."""
+        n = self._n
+        ps = self._pipe_stats
+        steps = n["decode_steps"]
+        return {
+            "decode_steps": steps,
+            "decode_row_steps": n["decode_row_steps"],
+            "decode_tokens": n["decode_tokens"],
+            "prefill_tokens": n["prefill_tokens"],
+            "prefill_cached_tokens": n["prefill_cached_tokens"],
+            "dispatches": dict(n["dispatches"]),
+            "first_calls": dict(n["first_calls"]),
+            "flushes": ps.flushes if ps is not None else 0,
+            "rebuilds": ps.rebuilds if ps is not None else 0,
+            "preemptions": self.num_preemptions,
+            "max_num_seqs": self.config.max_num_seqs,
+            "decode_occupancy": (
+                n["decode_row_steps"] / (steps * self.config.max_num_seqs)
+                if steps else 0.0
+            ),
+        }
+
     def profile_decode(
         self,
         *,
@@ -1563,6 +1685,7 @@ class LLMEngine:
         """Record one decode round for every participating request, then
         finalize the ones that finished. ``extra`` maps request_id ->
         additional span attrs (spec rounds attach draft/accept counts)."""
+        self._n["decode_tokens"] += sum(len(o.new_token_ids) for o in outputs)
         try:
             t1 = time.time()
             active_ms = round((t1 - wall0) * 1e3, 3)
@@ -1620,6 +1743,7 @@ class LLMEngine:
                     "output_tokens": n_out,
                     "num_preemptions": r.num_preemptions,
                     "e2e_s": round(e2e, 6),
+                    "pre_engine_wait_s": round(r.pre_engine_wait_s, 6),
                 }
                 if ttft is not None:
                     attrs["ttft_s"] = round(ttft, 6)
@@ -1669,6 +1793,11 @@ class LLMEngine:
         return buckets[-1]
 
     def _admit_one(self):
+        """_admit_head inside the layer span engine.schedule."""
+        with obs.layer_span("engine.schedule"):
+            return self._admit_head()
+
+    def _admit_head(self):
         """Admit the head of the waiting queue: prefix match (+ tiered
         resurrection), capacity reservation for the FULL recompute
         prompt, queue/hit bookkeeping — everything up to (but not
@@ -1726,6 +1855,8 @@ class LLMEngine:
             return None  # no room: fall through to decode; retry later
         self.waiting.popleft()
         self.num_prefill_batches += 1
+        self._n["prefill_tokens"] += len(prompt) - matched
+        self._n["prefill_cached_tokens"] += matched
         if self.kvfetch is not None and matched:
             # blocks the prefetch tick scattered ahead of admission
             # match as HBM residents; re-attribute their hits to the
@@ -1782,35 +1913,37 @@ class LLMEngine:
         req, seq, prompt, matched = got
         c = self.config
 
-        num_slots = c.num_blocks * c.block_size
-        bt = np.zeros((1, self._bt_width([len(seq.blocks)])), np.int32)
-        bt[0, : len(seq.blocks)] = seq.blocks
-        bt = jnp.asarray(bt)
+        with obs.layer_span("engine.prefill_dispatch"):
+            num_slots = c.num_blocks * c.block_size
+            bt = np.zeros((1, self._bt_width([len(seq.blocks)])), np.int32)
+            bt[0, : len(seq.blocks)] = seq.blocks
+            bt = jnp.asarray(bt)
 
-        # chunked prefill: preemption recompute can exceed max_prefill_len;
-        # each chunk extends context_lens, only the last chunk's logits count
-        logits = None
-        for start in range(matched, len(prompt), c.max_prefill_len):
-            chunk = prompt[start : start + c.max_prefill_len]
-            S_pad = self._pad_to_bucket(len(chunk), c.prefill_buckets())
-            tokens = np.zeros((1, S_pad), np.int32)
-            tokens[0, : len(chunk)] = chunk
-            positions = np.zeros((1, S_pad), np.int32)
-            positions[0, : len(chunk)] = np.arange(start, start + len(chunk))
-            slots = np.full((1, S_pad), num_slots, np.int32)  # trash by default
-            for i, p in enumerate(range(start, start + len(chunk))):
-                slots[0, i] = seq.slot(p)
-            logits, self.cache = self._prefill(
-                self.params,
-                jnp.asarray(tokens),
-                jnp.asarray(positions),
-                jnp.asarray([len(chunk)], jnp.int32),
-                jnp.asarray(slots),
-                bt,
-                jnp.asarray([start + len(chunk)], jnp.int32),
-                self.cache,
-                self._lora_arg(np.asarray([req.lora_slot], np.int32)),
-            )
+            # chunked prefill: preemption recompute can exceed max_prefill_len;
+            # each chunk extends context_lens, only the last chunk's logits count
+            logits = None
+            for start in range(matched, len(prompt), c.max_prefill_len):
+                chunk = prompt[start : start + c.max_prefill_len]
+                S_pad = self._pad_to_bucket(len(chunk), c.prefill_buckets())
+                tokens = np.zeros((1, S_pad), np.int32)
+                tokens[0, : len(chunk)] = chunk
+                positions = np.zeros((1, S_pad), np.int32)
+                positions[0, : len(chunk)] = np.arange(start, start + len(chunk))
+                slots = np.full((1, S_pad), num_slots, np.int32)  # trash by default
+                for i, p in enumerate(range(start, start + len(chunk))):
+                    slots[0, i] = seq.slot(p)
+                logits, self.cache = self._call(
+                    "prefill", (S_pad, bt.shape[1]), self._prefill,
+                    self.params,
+                    jnp.asarray(tokens),
+                    jnp.asarray(positions),
+                    jnp.asarray([len(chunk)], jnp.int32),
+                    jnp.asarray(slots),
+                    bt,
+                    jnp.asarray([start + len(chunk)], jnp.int32),
+                    self.cache,
+                    self._lora_arg(np.asarray([req.lora_slot], np.int32)),
+                )
         seq.num_tokens = len(prompt)
         if c.enable_prefix_caching:
             seq.seal_full_blocks(prompt)
@@ -1881,33 +2014,36 @@ class LLMEngine:
         flushed = self._pipe_flush()
         if flushed:
             return flushed
+        self._step_kind = "mixed"
         wall0 = time.time()
-        # KV for this step's writes: mid-prompt rows reserved their full
-        # recompute prompt at admission; decode rows grow one position
-        while True:
-            try:
-                for r in self.running:
-                    if r.request_id not in self._mixed_prefills:
-                        r.seq.ensure_capacity(r.num_tokens + 1)
-                break
-            except NoFreeBlocksError:
-                if not self._preempt_one():
-                    raise  # single running request can't fit: cache too small
-        from ray_tpu.llm.mixed import MixedBatchPlan
+        with obs.layer_span("engine.mixed_dispatch"):
+            # KV for this step's writes: mid-prompt rows reserved their full
+            # recompute prompt at admission; decode rows grow one position
+            while True:
+                try:
+                    for r in self.running:
+                        if r.request_id not in self._mixed_prefills:
+                            r.seq.ensure_capacity(r.num_tokens + 1)
+                    break
+                except NoFreeBlocksError:
+                    if not self._preempt_one():
+                        raise  # single running request can't fit: cache too small
+            from ray_tpu.llm.mixed import MixedBatchPlan
 
-        plan = MixedBatchPlan.build(self)
-        logits, self.cache = self._mixed_fn(
-            self.params,
-            jnp.asarray(plan.tokens),
-            jnp.asarray(plan.positions),
-            jnp.asarray(plan.slots),
-            jnp.asarray(plan.bt),
-            jnp.asarray(plan.cu_q_lens),
-            jnp.asarray(plan.context_lens),
-            self.cache,
-            self._lora_arg(plan.lora_ids),
-        )
-        plan.note(self._mixed_stats)
+            plan = MixedBatchPlan.build(self)
+            logits, self.cache = self._call(
+                "mixed", (len(plan.tokens), plan.bt.shape), self._mixed_fn,
+                self.params,
+                jnp.asarray(plan.tokens),
+                jnp.asarray(plan.positions),
+                jnp.asarray(plan.slots),
+                jnp.asarray(plan.bt),
+                jnp.asarray(plan.cu_q_lens),
+                jnp.asarray(plan.context_lens),
+                self.cache,
+                self._lora_arg(plan.lora_ids),
+            )
+            plan.note(self._mixed_stats)
 
         # advance prefill cursors; a finishing prompt seals its full
         # blocks (the _prefill_one contract) and becomes a decode row
@@ -1932,11 +2068,13 @@ class LLMEngine:
         outputs: list[RequestOutput] = []
         if plan.emit_rows:
             emit_reqs = [plan.reqs[i] for i in plan.emit_rows]
-            tok, logprob = self._sample_batch(
-                logits[np.asarray(plan.emit_rows)], emit_reqs
-            )
+            with obs.layer_span("engine.sync"):
+                tok, logprob = self._sample_batch(
+                    logits[np.asarray(plan.emit_rows)], emit_reqs
+                )
             t1 = time.time()  # host sync done
-            outputs = self._append_tokens(emit_reqs, tok, logprob)
+            with obs.layer_span("engine.append"):
+                outputs = self._append_tokens(emit_reqs, tok, logprob)
             for r in prompt_done:
                 self._obs_span(
                     r, "engine.prefill",
@@ -1957,6 +2095,8 @@ class LLMEngine:
                 if plan.kinds[i] == "decode"
             ]
             if dec:
+                self._n["decode_steps"] += 1
+                self._n["decode_row_steps"] += len(dec)
                 self._obs_decode_round(
                     [emit_reqs[j] for j in dec], [outputs[j] for j in dec],
                     wall0, "engine.mixed_round", 1,
@@ -2138,6 +2278,7 @@ class LLMEngine:
         return max(1, r.sampling_params.max_tokens - len(r.output_token_ids))
 
     def _decode_step(self) -> list[RequestOutput]:
+        self._step_kind = "decode"
         if self.config.spec is not None:
             return self._spec_decode_step()
         if self.config.pipeline_decode:
@@ -2173,6 +2314,8 @@ class LLMEngine:
         if deliver and outs:
             self._pending_outputs.extend(outs)
             return []
+        if outs:
+            self._step_kind = "flush"  # what the step that asked for it returns
         return outs
 
     def _pipe_drop(self) -> None:
@@ -2201,6 +2344,25 @@ class LLMEngine:
             outs = self._pipe_flush()
             return outs if outs else self._plain_decode_step()
 
+        # the host work the in-flight chunk's device time hides
+        with obs.layer_span("engine.decode_dispatch"):
+            prev = self._dispatch_pipe_chunk()
+        if isinstance(prev, list):
+            return prev  # flushed, or preempted: no dispatch this round
+        if prev is None:
+            # cold start: nothing to overlap with yet; the next step()
+            # dispatches chunk 2 and syncs this one
+            return []
+        return self._pipe_sync(prev)
+
+    def _dispatch_pipe_chunk(self):
+        """Prepare and dispatch the next pipelined chunk. Returns the
+        chunk that was in flight before it (None on a cold start), or a
+        list of outputs when the round ended in a flush or a preemption
+        instead of a dispatch."""
+        from ray_tpu.llm import pipeline as pl
+
+        c = self.config
         t_prep0 = time.perf_counter()
         wall0 = time.time()
         prev = self._pipe_inflight
@@ -2261,22 +2423,11 @@ class LLMEngine:
 
         # dispatch chunk N+1 from the device-resident carry (async: this
         # does NOT wait for chunk N)
-        fn = self._pipe_chunk_fn(n_steps, state.sample_mode, state.stop_w)
-        lora = None
-        if self._lora is not None:
-            lora = {"ids": state.lora_ids, **self._lora}
         t_dispatch = time.perf_counter()
-        toks, lps, n_emit, steps_run, carry, self.cache = fn(
-            self.params, state.tokens, state.positions, state.block_tables,
-            state.context_lens, self.cache, state.temps, state.top_ks,
-            state.top_ps, state.keys, state.starts, state.max_toks,
-            state.done, state.stop_ids, state.stop_on_eos, lora,
-        )
+        toks, lps, n_emit, steps_run, carry = self._run_pipe_chunk(state, n_steps)
         state.adopt_carry(carry)
         host_prep_ms = (t_dispatch - t_prep0) * 1e3
         self._pipe_stats.record_dispatch(n_steps, host_prep_ms)
-        if c.profile:
-            pl.record_host_prep(host_prep_ms)
         self._pipe_inflight = {
             "batch": list(self.running),
             "row_of": dict(state.row_of),
@@ -2285,23 +2436,35 @@ class LLMEngine:
             "sample_mode": state.sample_mode,
             "t_dispatch": t_dispatch, "wall0": wall0, "gap_ms": gap_ms,
         }
-        if prev is None:
-            # cold start: nothing to overlap with yet; the next step()
-            # dispatches chunk 2 and syncs this one
-            return []
-        return self._pipe_sync(prev)
+        return prev
+
+    def _run_pipe_chunk(self, state, n_steps: int) -> tuple:
+        """One pipelined chunk from ``state`` (async): the single call
+        site of the masked chunk programs, shared with warmup()."""
+        fn = self._pipe_chunk_fn(n_steps, state.sample_mode, state.stop_w)
+        lora = None
+        if self._lora is not None:
+            lora = {"ids": state.lora_ids, **self._lora}
+        *out, self.cache = self._call(
+            "pipe_chunk",
+            (n_steps, state.sample_mode, state.stop_w, state.B_pad, state.bt_width),
+            fn,
+            self.params, state.tokens, state.positions, state.block_tables,
+            state.context_lens, self.cache, state.temps, state.top_ks,
+            state.top_ps, state.keys, state.starts, state.max_toks,
+            state.done, state.stop_ids, state.stop_on_eos, lora,
+        )
+        return tuple(out)
 
     def _pipe_sync(self, rec) -> list[RequestOutput]:
         """Sync one dispatched chunk's tokens and run the host
         bookkeeping ladder for the rows still alive."""
-        from ray_tpu.llm import pipeline as pl
-
-        c = self.config
         t0 = time.perf_counter()
-        toks = np.asarray(rec["toks"])          # the host sync
-        lps = np.asarray(rec["lps"])
-        n_emit = np.asarray(rec["n_emit"])
-        steps_run = int(rec["steps_run"])
+        with obs.layer_span("engine.sync"):
+            toks = np.asarray(rec["toks"])          # the host sync
+            lps = np.asarray(rec["lps"])
+            n_emit = np.asarray(rec["n_emit"])
+            steps_run = int(rec["steps_run"])
         t1 = time.perf_counter()
         self._pipe_last_sync_t = t1
         sync_wait_ms = (t1 - t0) * 1e3
@@ -2311,12 +2474,7 @@ class LLMEngine:
         self._pipe_stats.record_sync(
             steps_run=steps_run, sync_wait_ms=sync_wait_ms, chunk_ms=chunk_ms
         )
-        if c.profile:
-            pl.record_sync_wait(sync_wait_ms)
-            from ray_tpu.llm.decode_loop import record_chunk
-
-            record_chunk(chunk_ms, rec["n_steps"], rec["sample_mode"],
-                         len(rec["batch"]))
+        self._n["decode_steps"] += steps_run
         # rows that finished in an earlier sync are done on device and
         # emitted nothing; only live rows get bookkeeping (their seq is
         # released on finish)
@@ -2327,10 +2485,13 @@ class LLMEngine:
         if not live:
             return []
         cols = [rec["row_of"][r.request_id] for r in live]
-        outputs = self._append_chunk(
-            live, toks[:, cols], lps[:, cols],
-            row_counts=[int(n_emit[j]) for j in cols],
-        )
+        row_counts = [int(n_emit[j]) for j in cols]
+        # a row is live in a step exactly when it emits a token there
+        self._n["decode_row_steps"] += sum(row_counts)
+        with obs.layer_span("engine.append"):
+            outputs = self._append_chunk(
+                live, toks[:, cols], lps[:, cols], row_counts=row_counts,
+            )
         return self._obs_decode_round(
             live, outputs, rec["wall0"], "engine.decode_chunk",
             rec["n_steps"],
@@ -2348,7 +2509,6 @@ class LLMEngine:
         paying the (k+1)-wide program for zero drafts would be pure
         overhead."""
         c = self.config
-        t0 = time.perf_counter() if c.profile else None
         wall0 = time.time()
         k = c.spec.num_draft_tokens
         batch = list(self.running)
@@ -2372,117 +2532,120 @@ class LLMEngine:
         if not any(draft_by_rid.values()):
             return self._plain_decode_step()
 
-        # reserve KV for the drafted positions (verify scatters K/V at
-        # num_tokens-1 .. num_tokens-1+L); preempt on real pressure only
-        while True:
-            try:
-                for r in self.running:
-                    r.seq.ensure_capacity(
-                        r.num_tokens + len(draft_by_rid[r.request_id])
-                    )
-                break
-            except NoFreeBlocksError:
-                if not self._preempt_one():
-                    raise
+        with obs.layer_span("engine.decode_dispatch"):
+            # reserve KV for the drafted positions (verify scatters K/V at
+            # num_tokens-1 .. num_tokens-1+L); preempt on real pressure only
+            while True:
+                try:
+                    for r in self.running:
+                        r.seq.ensure_capacity(
+                            r.num_tokens + len(draft_by_rid[r.request_id])
+                        )
+                    break
+                except NoFreeBlocksError:
+                    if not self._preempt_one():
+                        raise
 
-        batch = list(self.running)
-        drafts = [draft_by_rid[r.request_id] for r in batch]
-        B = len(batch)
-        B_pad = self._pad_to_bucket(B, c.decode_buckets())
-        K1 = k + 1
-        num_slots = c.num_blocks * c.block_size
+            batch = list(self.running)
+            drafts = [draft_by_rid[r.request_id] for r in batch]
+            B = len(batch)
+            B_pad = self._pad_to_bucket(B, c.decode_buckets())
+            K1 = k + 1
+            num_slots = c.num_blocks * c.block_size
 
-        context_lens = np.zeros(B_pad, np.int32)
-        draft_tokens = np.zeros((B_pad, k), np.int32)
-        draft_lens = np.zeros(B_pad, np.int32)
-        bt = np.zeros(
-            (B_pad, self._bt_width([len(r.seq.blocks) for r in batch])),
-            np.int32,
-        )
-        for i, r in enumerate(batch):
-            d = drafts[i]
-            context_lens[i] = r.num_tokens + len(d)
-            draft_tokens[i, : len(d)] = d
-            draft_lens[i] = len(d)
-            bt[i, : len(r.seq.blocks)] = r.seq.blocks
-
-        if c.mixed_batch:
-            # ragged verify (ops/ragged via verify_tokens_ragged): pack
-            # only the REAL 1 + draft_len tokens per row instead of
-            # padding every row to a k+1 trash-slot rectangle — the
-            # per-row bucket waste ROADMAP item 1 named. gather_idx
-            # recovers the [B, K+1] logits layout accept_draft expects;
-            # positions past a row's draft clamp to its last token and
-            # are masked by draft_lens, so duplicated logits are never
-            # consumed. Acceptance math downstream is unchanged.
-            from ray_tpu.llm.mixed import token_bucket
-
-            T_pad = token_bucket(sum(1 + len(d) for d in drafts))
-            p_tokens = np.zeros(T_pad, np.int32)
-            p_positions = np.zeros(T_pad, np.int32)
-            p_slots = np.full(T_pad, num_slots, np.int32)
-            p_lora = np.zeros(T_pad, np.int32)  # per-TOKEN adapter slots
-            cu = np.zeros(B_pad + 1, np.int32)
-            gather = np.zeros((B_pad, K1), np.int32)
-            t = 0
-            for i, r in enumerate(batch):
-                row = [
-                    r.output_token_ids[-1] if r.output_token_ids
-                    else r.prompt_token_ids[-1]
-                ] + drafts[i]
-                pos0 = r.num_tokens - 1  # position of the token being fed
-                p_tokens[t : t + len(row)] = row
-                p_positions[t : t + len(row)] = np.arange(
-                    pos0, pos0 + len(row)
-                )
-                for j in range(len(row)):
-                    p_slots[t + j] = r.seq.slot(pos0 + j)
-                p_lora[t : t + len(row)] = r.lora_slot
-                gather[i] = t + np.minimum(np.arange(K1), len(row) - 1)
-                t += len(row)
-                cu[i + 1] = t
-            cu[B + 1 :] = t  # pad sequences: q_len 0
-            logits, self.cache = self._verify_ragged_fn()(
-                self.params,
-                jnp.asarray(p_tokens),
-                jnp.asarray(p_positions),
-                jnp.asarray(p_slots),
-                jnp.asarray(bt),
-                jnp.asarray(cu),
-                jnp.asarray(context_lens),
-                jnp.asarray(gather),
-                self.cache,
-                self._lora_arg(p_lora),
+            context_lens = np.zeros(B_pad, np.int32)
+            draft_tokens = np.zeros((B_pad, k), np.int32)
+            draft_lens = np.zeros(B_pad, np.int32)
+            bt = np.zeros(
+                (B_pad, self._bt_width([len(r.seq.blocks) for r in batch])),
+                np.int32,
             )
-        else:
-            tokens = np.zeros((B_pad, K1), np.int32)
-            positions = np.zeros((B_pad, K1), np.int32)
-            slots = np.full((B_pad, K1), num_slots, np.int32)  # trash default
-            lora_ids = np.zeros(B_pad, np.int32)
             for i, r in enumerate(batch):
                 d = drafts[i]
-                last_tok = (
-                    r.output_token_ids[-1] if r.output_token_ids
-                    else r.prompt_token_ids[-1]
-                )
-                pos0 = r.num_tokens - 1  # position of the token being fed
-                row = [last_tok] + d
-                tokens[i, : len(row)] = row
-                positions[i, : len(row)] = np.arange(pos0, pos0 + len(row))
-                for j in range(len(row)):
-                    slots[i, j] = r.seq.slot(pos0 + j)
-                lora_ids[i] = r.lora_slot
+                context_lens[i] = r.num_tokens + len(d)
+                draft_tokens[i, : len(d)] = d
+                draft_lens[i] = len(d)
+                bt[i, : len(r.seq.blocks)] = r.seq.blocks
 
-            logits, self.cache = self._verify_fn(K1)(
-                self.params,
-                jnp.asarray(tokens),
-                jnp.asarray(positions),
-                jnp.asarray(slots),
-                jnp.asarray(bt),
-                jnp.asarray(context_lens),
-                self.cache,
-                self._lora_arg(lora_ids),
-            )
+            if c.mixed_batch:
+                # ragged verify (ops/ragged via verify_tokens_ragged): pack
+                # only the REAL 1 + draft_len tokens per row instead of
+                # padding every row to a k+1 trash-slot rectangle — the
+                # per-row bucket waste ROADMAP item 1 named. gather_idx
+                # recovers the [B, K+1] logits layout accept_draft expects;
+                # positions past a row's draft clamp to its last token and
+                # are masked by draft_lens, so duplicated logits are never
+                # consumed. Acceptance math downstream is unchanged.
+                from ray_tpu.llm.mixed import token_bucket
+
+                T_pad = token_bucket(sum(1 + len(d) for d in drafts))
+                p_tokens = np.zeros(T_pad, np.int32)
+                p_positions = np.zeros(T_pad, np.int32)
+                p_slots = np.full(T_pad, num_slots, np.int32)
+                p_lora = np.zeros(T_pad, np.int32)  # per-TOKEN adapter slots
+                cu = np.zeros(B_pad + 1, np.int32)
+                gather = np.zeros((B_pad, K1), np.int32)
+                t = 0
+                for i, r in enumerate(batch):
+                    row = [
+                        r.output_token_ids[-1] if r.output_token_ids
+                        else r.prompt_token_ids[-1]
+                    ] + drafts[i]
+                    pos0 = r.num_tokens - 1  # position of the token being fed
+                    p_tokens[t : t + len(row)] = row
+                    p_positions[t : t + len(row)] = np.arange(
+                        pos0, pos0 + len(row)
+                    )
+                    for j in range(len(row)):
+                        p_slots[t + j] = r.seq.slot(pos0 + j)
+                    p_lora[t : t + len(row)] = r.lora_slot
+                    gather[i] = t + np.minimum(np.arange(K1), len(row) - 1)
+                    t += len(row)
+                    cu[i + 1] = t
+                cu[B + 1 :] = t  # pad sequences: q_len 0
+                logits, self.cache = self._call(
+                    "verify", ("ragged", T_pad, bt.shape), self._verify_ragged_fn(),
+                    self.params,
+                    jnp.asarray(p_tokens),
+                    jnp.asarray(p_positions),
+                    jnp.asarray(p_slots),
+                    jnp.asarray(bt),
+                    jnp.asarray(cu),
+                    jnp.asarray(context_lens),
+                    jnp.asarray(gather),
+                    self.cache,
+                    self._lora_arg(p_lora),
+                )
+            else:
+                tokens = np.zeros((B_pad, K1), np.int32)
+                positions = np.zeros((B_pad, K1), np.int32)
+                slots = np.full((B_pad, K1), num_slots, np.int32)  # trash default
+                lora_ids = np.zeros(B_pad, np.int32)
+                for i, r in enumerate(batch):
+                    d = drafts[i]
+                    last_tok = (
+                        r.output_token_ids[-1] if r.output_token_ids
+                        else r.prompt_token_ids[-1]
+                    )
+                    pos0 = r.num_tokens - 1  # position of the token being fed
+                    row = [last_tok] + d
+                    tokens[i, : len(row)] = row
+                    positions[i, : len(row)] = np.arange(pos0, pos0 + len(row))
+                    for j in range(len(row)):
+                        slots[i, j] = r.seq.slot(pos0 + j)
+                    lora_ids[i] = r.lora_slot
+
+                logits, self.cache = self._call(
+                    "verify", (K1, bt.shape), self._verify_fn(K1),
+                    self.params,
+                    jnp.asarray(tokens),
+                    jnp.asarray(positions),
+                    jnp.asarray(slots),
+                    jnp.asarray(bt),
+                    jnp.asarray(context_lens),
+                    self.cache,
+                    self._lora_arg(lora_ids),
+                )
 
         from ray_tpu.llm.spec.accept import accept_draft
 
@@ -2505,26 +2668,30 @@ class LLMEngine:
         keys = [
             jax.random.fold_in(r._key, len(r.output_token_ids)) for r in batch
         ] + [jax.random.key(0)] * (B_pad - B)
-        out_toks, out_lps, accepted = accept_draft(
-            logits,
-            jnp.asarray(draft_tokens),
-            jnp.asarray(draft_lens),
-            jnp.asarray(temps),
-            jnp.asarray(top_ks),
-            jnp.asarray(top_ps),
-            jnp.stack(keys),
-            mode=mode,
-        )
-        out_toks = np.asarray(out_toks)   # host sync
-        out_lps = np.asarray(out_lps)
-        accepted = np.asarray(accepted)
+        with obs.layer_span("engine.sync"):
+            out_toks, out_lps, accepted = accept_draft(
+                logits,
+                jnp.asarray(draft_tokens),
+                jnp.asarray(draft_lens),
+                jnp.asarray(temps),
+                jnp.asarray(top_ks),
+                jnp.asarray(top_ps),
+                jnp.stack(keys),
+                mode=mode,
+            )
+            out_toks = np.asarray(out_toks)   # host sync
+            out_lps = np.asarray(out_lps)
+            accepted = np.asarray(accepted)
         t_verified = time.time()
 
         # keep accepted+1 tokens per row, run the usual stop ladder
         counts = (accepted[:B] + 1).tolist()
-        outputs = self._append_chunk(
-            batch, out_toks[:B].T, out_lps[:B].T, row_counts=counts
-        )
+        self._n["decode_steps"] += 1
+        self._n["decode_row_steps"] += B
+        with obs.layer_span("engine.append"):
+            outputs = self._append_chunk(
+                batch, out_toks[:B].T, out_lps[:B].T, row_counts=counts
+            )
 
         # KV rollback: blocks reserved for rejected draft positions are
         # returned; the stale K/V device-side is masked by context_lens
@@ -2543,13 +2710,9 @@ class LLMEngine:
         st.drafted += n_drafted
         st.accepted += n_accepted
         st.emitted += n_emitted
-        from ray_tpu.llm.spec.stats import export_spec_stats, record_spec_chunk
+        from ray_tpu.llm.spec.stats import export_spec_stats
 
         export_spec_stats(st, n_drafted, n_accepted, n_emitted)
-        if t0 is not None:
-            record_spec_chunk(
-                1e3 * (time.perf_counter() - t0), k, n_accepted, B
-            )
         draft_ms = round((t_drafted - wall0) * 1e3, 3)
         verify_ms = round((t_verified - t_drafted) * 1e3, 3)
         extra = {
@@ -2568,7 +2731,6 @@ class LLMEngine:
 
     def _plain_decode_step(self) -> list[RequestOutput]:
         c = self.config
-        t0 = time.perf_counter() if c.profile else None
         wall0 = time.time()
         n_steps = self._chunk_steps()
         # grow each sequence by the chunk's slots it can actually USE —
@@ -2603,31 +2765,30 @@ class LLMEngine:
         tokens, positions = a["tokens"], a["positions"]
         context_lens, lora_ids, bt = a["context_lens"], a["lora_ids"], a["bt"]
 
+        self._n["decode_steps"] += n_steps
+        self._n["decode_row_steps"] += B * n_steps
         if n_steps == 1:
-            slot_mapping = np.full(B_pad, num_slots, np.int32)
-            for i, r in enumerate(batch):
-                slot_mapping[i] = r.seq.slot(int(positions[i]))
-            logits, self.cache = self._decode(
-                self.params,
-                jnp.asarray(tokens),
-                jnp.asarray(positions),
-                jnp.asarray(slot_mapping),
-                jnp.asarray(bt),
-                jnp.asarray(context_lens),
-                self.cache,
-                self._lora_arg(lora_ids),
-            )
-            tok, logprob = self._sample_batch(logits[:B], batch)
-            if t0 is not None:
-                from ray_tpu.llm.decode_loop import record_chunk
-
-                record_chunk(
-                    1e3 * (time.perf_counter() - t0), 1,
-                    self._sample_mode(batch), B,
+            with obs.layer_span("engine.decode_dispatch"):
+                slot_mapping = np.full(B_pad, num_slots, np.int32)
+                for i, r in enumerate(batch):
+                    slot_mapping[i] = r.seq.slot(int(positions[i]))
+                logits, self.cache = self._call(
+                    "decode", (B_pad, bt.shape[1]), self._decode,
+                    self.params,
+                    jnp.asarray(tokens),
+                    jnp.asarray(positions),
+                    jnp.asarray(slot_mapping),
+                    jnp.asarray(bt),
+                    jnp.asarray(context_lens),
+                    self.cache,
+                    self._lora_arg(lora_ids),
                 )
+            with obs.layer_span("engine.sync"):
+                tok, logprob = self._sample_batch(logits[:B], batch)
+            with obs.layer_span("engine.append"):
+                outputs = self._append_tokens(batch, tok, logprob)
             return self._obs_decode_round(
-                batch, self._append_tokens(batch, tok, logprob), wall0,
-                "engine.decode_chunk", 1,
+                batch, outputs, wall0, "engine.decode_chunk", 1,
             )
 
         # multi-step chunk: decode+sample n_steps times on device, one
@@ -2635,38 +2796,35 @@ class LLMEngine:
         # index — a["starts"]): identical sampling regardless of how
         # co-running requests partition the chunks. remaining = this
         # chunk's keep-capacity (writes past it hit the trash page)
-        remaining = np.zeros(B_pad, np.int32)
-        for i, r in enumerate(batch):
-            remaining[i] = self._remaining(r)
-        toks, logprobs, self.cache = self._decode_chunk_fn(
-            n_steps, self._sample_mode(batch)
-        )(
-            self.params,
-            jnp.asarray(tokens),
-            jnp.asarray(positions),
-            jnp.asarray(bt),
-            jnp.asarray(context_lens),
-            self.cache,
-            jnp.asarray(a["temps"]),
-            jnp.asarray(a["top_ks"]),
-            jnp.asarray(a["top_ps"]),
-            jnp.stack(keys),
-            jnp.asarray(a["starts"]),
-            jnp.asarray(remaining),
-            self._lora_arg(lora_ids),
-        )
-        toks_np, logprobs_np = np.asarray(toks), np.asarray(logprobs)
-        if t0 is not None:
-            from ray_tpu.llm.decode_loop import record_chunk
-
-            # np.asarray is the host sync: this is the full round trip
-            record_chunk(
-                1e3 * (time.perf_counter() - t0), n_steps,
-                self._sample_mode(batch), B,
+        mode = self._sample_mode(batch)
+        with obs.layer_span("engine.decode_dispatch"):
+            remaining = np.zeros(B_pad, np.int32)
+            for i, r in enumerate(batch):
+                remaining[i] = self._remaining(r)
+            toks, logprobs, self.cache = self._call(
+                "decode_chunk", (n_steps, mode, B_pad, bt.shape[1]),
+                self._decode_chunk_fn(n_steps, mode),
+                self.params,
+                jnp.asarray(tokens),
+                jnp.asarray(positions),
+                jnp.asarray(bt),
+                jnp.asarray(context_lens),
+                self.cache,
+                jnp.asarray(a["temps"]),
+                jnp.asarray(a["top_ks"]),
+                jnp.asarray(a["top_ps"]),
+                jnp.stack(keys),
+                jnp.asarray(a["starts"]),
+                jnp.asarray(remaining),
+                self._lora_arg(lora_ids),
             )
+        with obs.layer_span("engine.sync"):
+            # np.asarray is the host sync: the full round trip ends here
+            toks_np, logprobs_np = np.asarray(toks), np.asarray(logprobs)
+        with obs.layer_span("engine.append"):
+            outputs = self._append_chunk(batch, toks_np, logprobs_np)
         return self._obs_decode_round(
-            batch, self._append_chunk(batch, toks_np, logprobs_np), wall0,
-            "engine.decode_chunk", n_steps,
+            batch, outputs, wall0, "engine.decode_chunk", n_steps,
         )
 
     # -- sampling + bookkeeping ----------------------------------------------

@@ -28,6 +28,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
 from ray_tpu import obs
+from ray_tpu.cluster.lockstats import TimedRLock
 from ray_tpu.llm.engine import EngineConfig, LLMEngine, RequestOutput
 from ray_tpu.llm.sampling import SamplingParams
 
@@ -104,7 +105,11 @@ class _EngineRunner:
     def __init__(self, engine: LLMEngine, engine_factory=None):
         self.engine = engine
         self._engine_factory = engine_factory  # full-rebuild fallback
-        self.lock = threading.Lock()
+        # one lock for the engine and the maps below. Timed always (a
+        # request's first seconds hang on it): wait and hold totals are
+        # in stats()["runner_lock"], distributions under lock domain
+        # "llm_runner" in the controlplane_lock_* histograms
+        self.lock = TimedRLock("llm_runner", reentrant=False, always=True)
         self._queues: dict[str, queue.Queue] = {}
         # rid -> {"prompt_ids", "sp", "trace", "delivered"}: enough to
         # re-create the request on a fresh engine AND to dedupe delivery
@@ -129,9 +134,18 @@ class _EngineRunner:
     ) -> tuple[str, queue.Queue]:
         """``add_kwargs`` pass through to ``engine.add_request`` (the
         fleet plane rides lora_id / priority / tenant / slo_tag here)
-        and are replayed by the full-rebuild recovery rung."""
+        and are replayed by the full-rebuild recovery rung.
+
+        Layer span runner.submit (a child of the request's context,
+        attr lock_wait_ms), entry to return on the CALLER's thread —
+        the replica's event loop, which is blocked for as long. The
+        engine's own clock (Request.arrival, queue_wait) starts only at
+        add_request below; what the request lost before it is handed to
+        the engine as pre_engine_wait_s."""
         q: queue.Queue = queue.Queue()
-        with self.lock:
+        with obs.layer_span("runner.submit", ctx=trace) as span, self.lock:
+            span.attrs["lock_wait_ms"] = self.lock.last_wait_s * 1e3
+            waited = time.time() - span.start
             # checked under the lock: the death handler drains _queues under
             # it, so an insert after the drain would hang its caller forever
             if self._dead is not None:
@@ -142,6 +156,7 @@ class _EngineRunner:
                 prompt_ids, sp, request_id=request_id, trace=trace,
                 **add_kwargs,
             )
+            self.engine.requests[rid].pre_engine_wait_s = waited
             self._queues[rid] = q
             # "tokens" holds the DELIVERED output prefix (not just a
             # count): the full-rebuild recovery rung seeds the fresh
@@ -190,10 +205,19 @@ class _EngineRunner:
                 self._wake.clear()
                 continue
             try:
-                with self.lock:
-                    outputs = self.engine.step()
-                    for out in outputs:
-                        self._deliver(out)
+                # layer span runner.step = runner.lock_wait + engine.step
+                # + runner.deliver: how long one turn holds the lock, and
+                # whether the loop gets it back at once
+                with obs.layer_span("runner.step"):
+                    with obs.layer_span("runner.lock_wait"):
+                        self.lock.acquire()
+                    try:
+                        outputs = self.engine.step()
+                        with obs.layer_span("runner.deliver"):
+                            for out in outputs:
+                                self._deliver(out)
+                    finally:
+                        self.lock.release()
             except BaseException as e:  # a wedged step must not hang callers
                 if not self._stop and self._try_recover(e):
                     continue
@@ -614,7 +638,13 @@ class LLMServer:
         LLMEngine.stats(), so operators can read draft quality (and in
         disaggregated mode the per-pool + transfer-plane picture, incl.
         the prefix-cache hit rate the decode pick consumes) without
-        scraping Prometheus."""
+        scraping Prometheus.
+
+        Never takes the runner's lock (under load that is a wait of
+        whole engine steps, and this is the surface one reads under
+        load): `counters` is LLMEngine.counters(), `trace` the
+        process's layer spans (count and busy seconds by name),
+        `runner_lock` the lock's own wait and hold totals."""
         from ray_tpu.util.metrics import snapshot_meta
 
         if self.orchestrator is not None:
@@ -628,8 +658,19 @@ class LLMServer:
             # restart-detection header; free here via the same API)
             out["telemetry"] = snapshot_meta()
             return out
-        with self.runner.lock:
-            out = {"model_id": self.config.model_id, **self.engine.stats()}
+        engine = self.engine
+        out = {"model_id": self.config.model_id}
+        for _ in range(8):
+            # engine.stats() copies a few small dicts that the loop
+            # thread may be resizing mid-step: read again
+            try:
+                out.update(engine.stats())
+                break
+            except RuntimeError:
+                continue
+        out["counters"] = engine.counters()
+        out["trace"] = obs.layer_counters()
+        out["runner_lock"] = self.runner.lock.totals()
         out["admission"] = self.admission.stats()
         out["engine_recoveries"] = self.runner.num_recoveries
         out["telemetry"] = snapshot_meta()

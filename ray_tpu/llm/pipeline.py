@@ -88,67 +88,9 @@ def stop_width(n: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# observability: host/device time split histograms + /v1/stats row
+# observability: the /v1/stats row (per round, the same two times are the
+# engine's layer spans engine.decode_dispatch and engine.sync)
 # ---------------------------------------------------------------------------
-
-_host_prep_hist = None
-_sync_wait_hist = None
-
-_SPLIT_BOUNDARIES = [0.05, 0.1, 0.25, 0.5, 1, 2, 5, 10, 25, 50, 100, 500]
-
-
-def host_prep_histogram():
-    """Host-side prep ms per pipelined round (state refresh + KV
-    reservation + dispatch) — the work the double-buffered dispatch
-    hides under device compute. Beside llm_decode_chunk_ms it makes the
-    overlap win measurable per-round, not just end-to-end."""
-    global _host_prep_hist
-    if _host_prep_hist is None:
-        from ray_tpu.util.metrics import Histogram
-
-        _host_prep_hist = Histogram(
-            "llm_decode_host_prep_ms",
-            description="profiler: host ms per pipelined decode round "
-            "spent preparing + dispatching the next chunk (overlapped "
-            "with the in-flight chunk's device compute)",
-            boundaries=_SPLIT_BOUNDARIES,
-        )
-    return _host_prep_hist
-
-
-def sync_wait_histogram():
-    global _sync_wait_hist
-    if _sync_wait_hist is None:
-        from ray_tpu.util.metrics import Histogram
-
-        _sync_wait_hist = Histogram(
-            "llm_decode_sync_wait_ms",
-            description="profiler: host ms per pipelined decode round "
-            "blocked in the device->host token sync (the un-hidden "
-            "remainder of the round trip)",
-            boundaries=_SPLIT_BOUNDARIES,
-        )
-    return _sync_wait_hist
-
-
-def register_metrics() -> None:
-    """scripts/check_metrics.py hook: force lazy metrics to register."""
-    host_prep_histogram()
-    sync_wait_histogram()
-
-
-def record_host_prep(ms: float) -> None:
-    try:
-        host_prep_histogram().observe(ms)
-    except Exception:  # noqa: BLE001 — observability must not break decode
-        pass
-
-
-def record_sync_wait(ms: float) -> None:
-    try:
-        sync_wait_histogram().observe(ms)
-    except Exception:  # noqa: BLE001
-        pass
 
 
 @dataclasses.dataclass
@@ -221,8 +163,8 @@ class ChunkController:
 
     The signal pair: per-round HOST OVERHEAD (the r08 ``sched_gap_ms``
     between a sync landing and the next dispatch, plus the un-hidden
-    sync wait) versus the measured chunk wall (the
-    ``llm_decode_chunk_ms`` histogram's observation). A chunk must be
+    sync wait) versus the measured chunk wall (dispatch to sync). A
+    chunk must be
     long enough that overhead hides under device compute with
     ``target_ratio`` headroom — when it isn't, step up one bucket. The
     only downward pressure is SYSTEMATIC early exit (the while_loop
@@ -385,14 +327,22 @@ class DeviceBatchState:
     _nblocks: list = dataclasses.field(default_factory=list)
 
     @classmethod
-    def build(cls, engine, batch: list) -> "DeviceBatchState":
+    def build(cls, engine, batch: list, shape: Optional[tuple] = None,
+              ) -> "DeviceBatchState":
+        """``shape`` = (B_pad, block-table width, stop width, sample
+        mode) overrides what the batch would select: warmup() builds the
+        state of an empty batch at every shape of the tables."""
         c = engine.config
         B = len(batch)
-        B_pad = engine._pad_to_bucket(B, c.decode_buckets())
-        btw = engine._bt_width([len(r.seq.blocks) for r in batch])
-        sw = stop_width(max(
-            (len(r.sampling_params.stop_token_ids) for r in batch), default=0
-        ))
+        if shape is not None:
+            B_pad, btw, sw, sample_mode = shape
+        else:
+            B_pad = engine._pad_to_bucket(B, c.decode_buckets())
+            btw = engine._bt_width([len(r.seq.blocks) for r in batch])
+            sw = stop_width(max(
+                (len(r.sampling_params.stop_token_ids) for r in batch), default=0
+            ))
+            sample_mode = engine._sample_mode(batch)
         a, keys = assemble_batch_arrays(batch, B_pad, btw)
         # pipeline-only rows the sync path evaluates host-side instead:
         # the padded stop-id sets and the per-row EOS policy
@@ -410,7 +360,7 @@ class DeviceBatchState:
             rids=rids,
             row_of={rid: i for i, rid in enumerate(rids)},
             B=B, B_pad=B_pad, bt_width=btw, stop_w=sw,
-            sample_mode=engine._sample_mode(batch),
+            sample_mode=sample_mode,
             tokens=jnp.asarray(a["tokens"]),
             positions=jnp.asarray(a["positions"]),
             context_lens=jnp.asarray(a["context_lens"]),

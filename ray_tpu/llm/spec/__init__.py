@@ -34,7 +34,7 @@ from ray_tpu.llm.spec.drafter import (
     DraftModelDrafter,
     PromptLookupDrafter,
 )
-from ray_tpu.llm.spec.stats import SpecStats, record_spec_chunk
+from ray_tpu.llm.spec.stats import SpecStats
 
 __all__ = [
     "Drafter",
@@ -43,5 +43,4 @@ __all__ = [
     "SpecConfig",
     "SpecStats",
     "accept_draft",
-    "record_spec_chunk",
 ]

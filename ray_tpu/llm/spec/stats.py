@@ -1,13 +1,14 @@
 """Acceptance-rate accounting + observability export.
 
-Three surfaces, mirroring the decode-chunk profiling hooks
-(llm/decode_loop.py):
+Two surfaces:
 
  * SpecStats — host counters the engine folds into ``stats()``;
  * Prometheus — counters/gauges on the dashboard /metrics route
-   (util/metrics.py process-wide registry);
- * timeline — per-verify-chunk spans (kind="profile") next to task
-   spans on the dashboard /timeline route, when EngineConfig.profile.
+   (util/metrics.py process-wide registry).
+
+A verify round's time is the engine's layer span ``engine.step`` with
+its children (``engine.decode_dispatch`` = reserve + verify dispatch,
+``engine.sync`` = accept + host sync).
 """
 
 from __future__ import annotations
@@ -108,37 +109,5 @@ def export_spec_stats(stats: SpecStats, drafted: int, accepted: int,
             m["emitted"].inc(emitted)
         m["acceptance_rate"].set(stats.acceptance_rate)
         m["mean_accepted_len"].set(stats.mean_accepted_len)
-    except Exception:  # noqa: BLE001 — observability must not break decode
-        pass
-
-
-def record_spec_chunk(ms: float, k: int, accepted: int, batch_size: int) -> None:
-    """Timeline span + latency histogram for one draft->verify->accept
-    round trip (EngineConfig.profile path — the spec analog of
-    decode_loop.record_chunk)."""
-    try:
-        import time
-
-        from ray_tpu.util.metrics import Histogram
-
-        Histogram(
-            "llm_spec_chunk_ms",
-            description="profiler: wall ms per speculative verify chunk "
-            "(draft + verify + accept + rollback + host sync)",
-            boundaries=[0.5, 1, 2, 5, 10, 25, 50, 100, 250, 500, 1000, 5000],
-            tag_keys=("k",),
-        ).observe(ms, tags={"k": str(k)})
-
-        from ray_tpu.core import runtime as rt
-        from ray_tpu.core.events import TaskState
-
-        buf = rt.get_runtime().task_events
-        end = time.time()
-        span = f"profile-spec-chunk-{time.monotonic_ns()}"
-        name = f"profile:spec_chunk:{k}x{batch_size}:acc{accepted}"
-        buf.record(span, name, TaskState.RUNNING, kind="profile",
-                   worker="llm-engine", ts=end - ms / 1e3)
-        buf.record(span, name, TaskState.FINISHED, kind="profile",
-                   worker="llm-engine", ts=end)
     except Exception:  # noqa: BLE001 — observability must not break decode
         pass

@@ -8,6 +8,12 @@ Three pieces:
    across API -> router -> engine -> cluster workers;
  * recorder — ``SpanRecorder``, a bounded flight recorder of the last N
    requests' spans (``obs.span(...)`` records + propagates in one call);
+ * layer spans — ``obs.layer_span(name)`` where a LAYER does its work
+   (the runner's turn, the engine's step, a trainer's report): an
+   annotation in the JAX profiler's trace, always-on count and busy
+   seconds (``obs.layer_counters()``), and a ``Span`` in the recorder's
+   layer ring while ``obs.capture()`` is open; ``obs.compile_log()`` is
+   the process's compiles and cache loads (utils/compile_cache.py);
  * slo — serving SLO histograms (TTFT / TPOT / queue-wait / e2e +
    router dispatch latency) on the util/metrics Prometheus registry;
  * telemetry — the CLUSTER-WIDE metrics plane (import
@@ -31,7 +37,37 @@ from ray_tpu.obs.context import (
     new_context,
     use,
 )
-from ray_tpu.obs.recorder import Span, SpanRecorder, get_recorder, span
+from ray_tpu.obs.recorder import (
+    Span,
+    SpanRecorder,
+    clock_marker,
+    clock_offset,
+    get_recorder,
+    layer_record,
+    layer_span,
+    span,
+)
+
+
+def layer_counters() -> dict:
+    """{name: {"count", "busy_s"}} of this process's layer spans; no lock."""
+    return get_recorder().layer_counters()
+
+
+def capture():
+    """``with obs.capture() as spans:`` — layer spans become ``Span``s
+    while the block runs; ``spans`` holds them once it has ended."""
+    return get_recorder().capture()
+
+
+def compile_log(since: float = 0.0) -> list:
+    """[(time.time(), program name, seconds, "compiled" | "loaded")] of
+    this process from ``since`` on, oldest first
+    (ray_tpu.utils.compile_cache)."""
+    from ray_tpu.utils.compile_cache import compile_log as log
+
+    return log(since)
+
 
 __all__ = [
     "TraceContext",
@@ -42,6 +78,13 @@ __all__ = [
     "use",
     "Span",
     "SpanRecorder",
+    "capture",
+    "clock_marker",
+    "clock_offset",
+    "compile_log",
     "get_recorder",
+    "layer_counters",
+    "layer_record",
+    "layer_span",
     "span",
 ]

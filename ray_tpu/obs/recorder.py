@@ -12,15 +12,25 @@ Reads: ``get(trace_id)`` raw spans, ``traces()`` the flight-recorder
 listing, ``summary(trace_id)`` e2e + span coverage honesty metrics,
 ``chrome_trace()`` Perfetto-ready events merged with the profiler/task
 timeline by the dashboard ``/api/trace`` route.
+
+Layer spans (``layer_span``) are the second kind of record: not "what
+happened to request X" but "what was this layer doing" — the runner's
+turn, the engine's step, a trainer's report. Each use is an annotation
+in the JAX profiler's trace (so it sits beside the device's ops, on the
+profiler's clock) plus two always-on numbers per name (count, busy
+seconds). Only while a ``capture()`` is open do they also become
+``Span``s, in a bounded ring of their own — never in the per-request
+store, so they cannot evict a request.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import sys
 import threading
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from typing import Optional
 
 from ray_tpu.obs import context as trace_context
@@ -58,7 +68,8 @@ class Span:
 class SpanRecorder:
     """Thread-safe ring of the last ``max_traces`` traces."""
 
-    def __init__(self, max_traces: int = 256, max_spans_per_trace: int = 512):
+    def __init__(self, max_traces: int = 256, max_spans_per_trace: int = 512,
+                 max_layer_spans: int = 65536):
         self.max_traces = max_traces
         self.max_spans_per_trace = max_spans_per_trace
         self._lock = threading.Lock()
@@ -67,19 +78,31 @@ class SpanRecorder:
         self._by_request: dict[str, str] = {}  # request_id -> trace_id
         self.num_dropped_traces = 0
         self.num_dropped_spans = 0
+        # layer spans: name -> [count, busy_s], added to under
+        # _layer_lock (two additions) and READ WITHOUT IT; the ring
+        # fills only while a capture is open
+        self._layer_lock = threading.Lock()
+        self._layer_counts: dict[str, list] = {}
+        self._layers: "deque[Span]" = deque(maxlen=max_layer_spans)
+        self._captures = 0
+        self._layer_local = threading.local()
+        self.num_dropped_layer_spans = 0
 
     # -- writes ---------------------------------------------------------------
+
+    def _evict_oldest(self) -> None:
+        old_tid, _ = self._traces.popitem(last=False)
+        meta = self._meta.pop(old_tid, None)
+        for rid in (meta or {}).get("request_ids", ()):
+            self._by_request.pop(rid, None)
+        self.num_dropped_traces += 1
 
     def add(self, span: Span) -> None:
         with self._lock:
             spans = self._traces.get(span.trace_id)
             if spans is None:
                 while len(self._traces) >= self.max_traces:
-                    old_tid, _ = self._traces.popitem(last=False)
-                    meta = self._meta.pop(old_tid, None)
-                    for rid in (meta or {}).get("request_ids", ()):
-                        self._by_request.pop(rid, None)
-                    self.num_dropped_traces += 1
+                    self._evict_oldest()
                 spans = self._traces[span.trace_id] = []
                 self._meta[span.trace_id] = {
                     "trace_id": span.trace_id,
@@ -143,6 +166,22 @@ class SpanRecorder:
         self.add(span)
         return span
 
+    def resize(self, max_traces: Optional[int] = None,
+               max_layer_spans: Optional[int] = None) -> None:
+        """Set how many traces (and layer spans) are kept: a client that
+        reads one window's requests at its end sizes the recorder to the
+        window first. Shrinking drops the oldest, counted."""
+        if max_traces is not None:
+            with self._lock:
+                self.max_traces = max(1, int(max_traces))
+                while len(self._traces) > self.max_traces:
+                    self._evict_oldest()
+        if max_layer_spans is not None:
+            with self._layer_lock:
+                kept = deque(self._layers, maxlen=max(1, int(max_layer_spans)))
+                self.num_dropped_layer_spans += len(self._layers) - len(kept)
+                self._layers = kept
+
     def clear(self) -> None:
         with self._lock:
             self._traces.clear()
@@ -150,6 +189,68 @@ class SpanRecorder:
             self._by_request.clear()
             self.num_dropped_traces = 0
             self.num_dropped_spans = 0
+        with self._layer_lock:
+            self._layers.clear()
+            self._layer_counts.clear()
+            self.num_dropped_layer_spans = 0
+
+    # -- layer spans ----------------------------------------------------------
+
+    def layer_counters(self) -> dict:
+        """{name: {"count", "busy_s"}} of every layer span used in this
+        process, capture or not. Takes no lock: a reader may see the
+        count of a span whose seconds land an instant later."""
+        return {name: {"count": c[0], "busy_s": c[1]}
+                for name, c in list(self._layer_counts.items())}
+
+    def layer_spans(self, since: float = 0.0) -> list[Span]:
+        """Captured layer spans that ended at or after ``since``
+        (time.time()), in the order they ended."""
+        with self._layer_lock:
+            return [s for s in self._layers if s.end >= since]
+
+    @contextlib.contextmanager
+    def capture(self):
+        """While open, layer spans are recorded as ``Span``s as well as
+        counted. Yields a list that holds this capture's layer spans
+        once the block has ended. A clock marker is written at both
+        edges, so a profiler trace taken around the block can be placed
+        on the recorder's clock (``clock_offset``)."""
+        spans: list = []
+        t0 = time.time()
+        with self._layer_lock:
+            self._captures += 1
+        clock_marker()
+        try:
+            yield spans
+        finally:
+            clock_marker()
+            with self._layer_lock:
+                self._captures -= 1
+            spans.extend(self.layer_spans(since=t0))
+
+    def _layer_done(self, name: str, start: float, end: float,
+                    ids: Optional[tuple], attrs: Optional[dict],
+                    status: str = "ok") -> None:
+        with self._layer_lock:
+            cell = self._layer_counts.get(name)
+            first = cell is None
+            if first:
+                cell = self._layer_counts[name] = [0, 0.0]
+            cell[0] += 1
+            cell[1] += max(0.0, end - start)
+            if ids is not None:
+                if len(self._layers) == self._layers.maxlen:
+                    self.num_dropped_layer_spans += 1
+                trace_id, span_id, parent_id = ids
+                self._layers.append(Span(trace_id, span_id, parent_id, name,
+                                         start, end, dict(attrs or {}), status))
+        if first and self is _RECORDER:
+            # a new name in the process-wide recorder: make sure /metrics
+            # carries the counters (outside the lock: this imports)
+            from ray_tpu.util.metrics import register_collector
+
+            register_collector(_export_layer_counters)
 
     # -- reads ----------------------------------------------------------------
 
@@ -160,6 +261,14 @@ class SpanRecorder:
     def get(self, trace_id: str) -> list[Span]:
         with self._lock:
             return list(self._traces.get(trace_id, ()))
+
+    def since(self, t: float) -> dict:
+        """{trace_id: [Span]} of every trace with a span that ended at
+        or after ``t`` (time.time()), oldest trace first: one window's
+        requests, taken at its end."""
+        with self._lock:
+            return {tid: list(spans) for tid, spans in self._traces.items()
+                    if self._meta[tid]["end"] >= t}
 
     def find_by_request(self, request_id: str) -> Optional[str]:
         with self._lock:
@@ -318,3 +427,144 @@ def span(name: str, attrs: Optional[dict] = None,
             attrs=dict(attrs or {}),
             status=status,
         ))
+
+
+# -- layer spans ---------------------------------------------------------------
+
+CLOCK_MARKER = "obs.clock:"  # + time.time_ns() at the marker's start
+
+
+def _annotation(name: str):
+    """The profiler's host annotation for ``name``, or None in a process
+    that has not imported jax: no profiler session can be running there,
+    and core/, cluster/ and serve/ import obs without wanting jax. With
+    jax loaded and no session running this costs well under a
+    microsecond."""
+    jax = sys.modules.get("jax")
+    try:
+        return jax.profiler.TraceAnnotation(name) if jax is not None else None
+    except AttributeError:  # another thread is halfway through importing jax
+        return None
+
+
+def clock_marker() -> None:
+    """Write one annotation whose name carries the recorder's clock
+    (``time.time_ns()``) at the instant the annotation starts on the
+    profiler's. ``capture()`` writes one at each edge; call it yourself
+    after ``start_trace`` where no capture is used."""
+    ann = _annotation(f"{CLOCK_MARKER}{time.time_ns()}")
+    if ann is not None:
+        with ann:
+            pass
+
+
+def clock_offset(host_events) -> Optional[float]:
+    """Seconds to ADD to a recorder time (time.time()) to get the same
+    instant on the profiler's clock, from the clock markers among
+    ``host_events`` (an iterable of (name, start_s) in a trace); None
+    when the trace holds no marker. The median over the markers found."""
+    offsets = sorted(
+        start_s - int(name[len(CLOCK_MARKER):]) * 1e-9
+        for name, start_s in host_events if name.startswith(CLOCK_MARKER)
+    )
+    return offsets[len(offsets) // 2] if offsets else None
+
+
+class LayerSpan:
+    """One use of ``layer_span``: a context manager. ``attrs`` may be
+    filled inside the block (a lock wait is known only once the lock is
+    held); they reach the recorded ``Span``, not the profiler's event."""
+
+    __slots__ = ("name", "attrs", "start", "_ctx", "_rec", "_ann", "_ids")
+
+    def __init__(self, name: str, ctx, attrs: Optional[dict],
+                 rec: SpanRecorder):
+        self.name = name
+        self.attrs = attrs if attrs is not None else {}
+        self.start = 0.0
+        self._ctx = ctx
+        self._rec = rec
+        self._ann = None
+        self._ids = None  # (trace_id, span_id, parent_id) while captured
+
+    def __enter__(self) -> "LayerSpan":
+        rec = self._rec
+        local = rec._layer_local
+        stack = getattr(local, "stack", None)
+        if stack is None:
+            stack = local.stack = []
+        if rec._captures:
+            outer = stack[-1]._ids if stack else None
+            if self._ctx is not None:
+                trace_id, parent = self._ctx.trace_id, self._ctx.span_id
+            elif outer is not None:
+                trace_id, parent = outer[0], outer[1]
+            else:
+                trace_id, parent = "layers", None
+            self._ids = (trace_id, trace_context._rand_hex(8), parent)
+        stack.append(self)
+        self._ann = _annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.start = time.time()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        end = time.time()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        stack = self._rec._layer_local.stack
+        if stack and stack[-1] is self:
+            stack.pop()
+        self._rec._layer_done(
+            self.name, self.start, end, self._ids, self.attrs,
+            "ok" if exc_type is None else "error",
+        )
+
+
+def layer_span(name: str, ctx: Optional[trace_context.TraceContext] = None,
+               attrs: Optional[dict] = None,
+               recorder: Optional[SpanRecorder] = None) -> LayerSpan:
+    """A span of a LAYER's work, recorded where the work happens:
+
+        with obs.layer_span("engine.step") as sp:
+            ...
+            sp.attrs["rows"] = n
+
+    Always: an annotation of that name in the profiler's trace, and
+    count + busy seconds under ``layer_counters()[name]``. While a
+    ``capture()`` is open also a ``Span`` in the recorder's layer ring,
+    whose parent is ``ctx`` (work done for one request: that request's
+    TraceContext) or else the layer span enclosing it on this thread."""
+    return LayerSpan(name, ctx, attrs, recorder if recorder is not None else _RECORDER)
+
+
+def layer_record(name: str, start: float, end: Optional[float] = None,
+                 attrs: Optional[dict] = None,
+                 recorder: Optional[SpanRecorder] = None) -> None:
+    """A layer span whose start was stamped elsewhere (another thread or
+    process, as ``time.time()``) and which ends now: counted, and
+    captured while a capture is open, like any other. The profiler
+    cannot be handed a finished event, so it is not in its trace."""
+    rec = recorder if recorder is not None else _RECORDER
+    ids = ("layers", trace_context._rand_hex(8), None) if rec._captures else None
+    rec._layer_done(name, start, time.time() if end is None else end, ids, attrs)
+
+
+def _export_layer_counters() -> None:
+    """util/metrics collector: mirror the layer counters into the
+    registry (``/metrics``) when it is read, not on every span."""
+    from ray_tpu.util.metrics import Counter
+
+    counts = _RECORDER.layer_counters()
+    n = Counter("obs_layer_spans_total",
+                description="layer spans ended, by span name", tag_keys=("name",))
+    busy = Counter("obs_layer_busy_seconds_total",
+                   description="seconds inside layer spans, by span name",
+                   tag_keys=("name",))
+    for name, c in counts.items():
+        n.set_total(c["count"], {"name": name})
+        busy.set_total(c["busy_s"], {"name": name})
+
+
+register_metrics = _export_layer_counters  # scripts/check_metrics.py hook

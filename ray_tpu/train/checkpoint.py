@@ -26,6 +26,8 @@ import tempfile
 import threading
 from typing import Any, Optional
 
+from ray_tpu import obs
+
 _ORBAX_SUBDIR = "sharded_state"
 _PICKLE_FILE = "state.pkl"
 _PARTIAL_SUFFIX = ".tmp"
@@ -85,18 +87,19 @@ class Checkpoint:
         """Crash-atomic: the whole checkpoint is staged in ``path.tmp``
         and renamed into place — readers either see a complete
         checkpoint at ``path`` or nothing."""
-        path = os.path.abspath(path)
-        tmp = path + _PARTIAL_SUFFIX
-        if os.path.exists(tmp):
-            shutil.rmtree(tmp)
-        os.makedirs(tmp)
-        if sharded:
-            save_sharded(state, os.path.join(tmp, _ORBAX_SUBDIR))
-        else:
-            with open(os.path.join(tmp, _PICKLE_FILE), "wb") as f:
-                pickle.dump(state, f)
-        _swap_into_place(tmp, path)
-        return cls(path)
+        with obs.layer_span("train.checkpoint.save", attrs={"sharded": sharded}):
+            path = os.path.abspath(path)
+            tmp = path + _PARTIAL_SUFFIX
+            if os.path.exists(tmp):
+                shutil.rmtree(tmp)
+            os.makedirs(tmp)
+            if sharded:
+                _save_sharded(state, os.path.join(tmp, _ORBAX_SUBDIR), wait=True)
+            else:
+                with open(os.path.join(tmp, _PICKLE_FILE), "wb") as f:
+                    pickle.dump(state, f)
+            _swap_into_place(tmp, path)
+            return cls(path)
 
     def load_state(self, template: Any = None) -> Any:
         orbax_dir = os.path.join(self.path, _ORBAX_SUBDIR)
@@ -122,10 +125,13 @@ class _PendingSave:
         self._finalized = False
 
     def wait_until_finished(self) -> None:
-        self._ckptr.wait_until_finished()
-        if not self._finalized:
-            self._finalized = True
-            _swap_into_place(self._tmp, self._dest)
+        # layer span train.checkpoint.wait: the stall a loop pays for an
+        # async save it has to wait out
+        with obs.layer_span("train.checkpoint.wait"):
+            self._ckptr.wait_until_finished()
+            if not self._finalized:
+                self._finalized = True
+                _swap_into_place(self._tmp, self._dest)
 
     def close(self) -> None:
         self.wait_until_finished()
@@ -137,7 +143,15 @@ def save_sharded(state: Any, path: str, wait: bool = True):
     writes only its shards; async unless wait=True. Crash-atomic: orbax
     writes into ``path.tmp`` and the rename to ``path`` happens only
     after the write completed (a killed rank leaves ``.tmp`` residue,
-    pruned on restore, never a partial checkpoint)."""
+    pruned on restore, never a partial checkpoint). Layer span
+    train.checkpoint.save: the part the caller's thread pays (all of
+    it with wait=True, the dispatch otherwise)."""
+    with obs.layer_span("train.checkpoint.save",
+                        attrs={"sharded": True, "wait": wait}):
+        return _save_sharded(state, path, wait)
+
+
+def _save_sharded(state: Any, path: str, wait: bool):
     import orbax.checkpoint as ocp
 
     path = os.path.abspath(path)

@@ -11,8 +11,10 @@ from __future__ import annotations
 import dataclasses
 import queue
 import threading
+import time
 from typing import Any, Optional
 
+from ray_tpu import obs
 from ray_tpu.train.checkpoint import Checkpoint
 
 _local = threading.local()
@@ -87,11 +89,13 @@ def get_dataset_shard(name: str = "train"):
 
 def report(metrics: dict, checkpoint: Optional[Checkpoint] = None) -> None:
     ctx = get_context()
-    import time
-
-    ctx.report_queue.put(
-        {"rank": ctx.world_rank, "metrics": dict(metrics),
-         "checkpoint": checkpoint, "ts": time.time()}
-    )
+    # layer span train.report: what one report costs the worker's loop (an
+    # actor round trip to the controller's mailbox); chipbench's
+    # report_ms.train reads it from the profiler's trace
+    with obs.layer_span("train.report"):
+        ctx.report_queue.put(
+            {"rank": ctx.world_rank, "metrics": dict(metrics),
+             "checkpoint": checkpoint, "ts": time.time()}
+        )
     if ctx.stop_event is not None and ctx.stop_event.is_set():
         raise StopIteration("controller requested stop")

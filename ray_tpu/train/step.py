@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from ray_tpu import obs
 from ray_tpu.parallel.sharding import ShardingRules, constrain, tree_shardings
 
 
@@ -54,9 +55,12 @@ def init_sharded_params(
     rules: ShardingRules,
     *args,
 ) -> Any:
-    """Run a param initializer with outputs born sharded (no host round-trip)."""
-    shardings = tree_shardings(mesh, rules, logical_tree)
-    return jax.jit(init_fn, out_shardings=shardings)(*args)
+    """Run a param initializer with outputs born sharded (no host round-trip).
+    Layer span train.init_params covers the compile (or cache load) and
+    the dispatch; the device may still be filling the arrays after it."""
+    with obs.layer_span("train.init_params"):
+        shardings = tree_shardings(mesh, rules, logical_tree)
+        return jax.jit(init_fn, out_shardings=shardings)(*args)
 
 
 def make_train_step(

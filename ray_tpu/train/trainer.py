@@ -15,8 +15,10 @@ step.
 
 from __future__ import annotations
 
+import time
 from typing import Callable, Optional
 
+from ray_tpu import obs
 from ray_tpu.core import api, errors
 from ray_tpu.train.checkpoint import Checkpoint, CheckpointManager
 from ray_tpu.train.config import FailureConfig, RunConfig, ScalingConfig
@@ -125,8 +127,16 @@ class _TrainWorker:
         self.ctx.dataset_shards = shards
         return True
 
-    def run(self, fn: Callable, config: dict) -> str:
+    def run(self, fn: Callable, config: dict,
+            fit_started: Optional[float] = None) -> str:
         session_mod._set_session(self.ctx)
+        if fit_started is not None:
+            # layer span train.worker_start: fit() (or this attempt)
+            # began at `fit_started` on the controller; it ends here, as
+            # the loop function is entered — placement group, channel,
+            # worker actors and their set-up calls
+            obs.layer_record("train.worker_start", fit_started,
+                             attrs={"rank": self.ctx.world_rank})
         try:
             fn(config) if _wants_arg(fn) else fn()
             return "done"
@@ -190,6 +200,7 @@ class JaxTrainer:
         )
         failure_cfg = self._run_config.failure_config
         failures = 0
+        started = time.time()
         resume_ckpt: Optional[Checkpoint] = None
         # metrics/history accumulate ACROSS attempts (a restart continues the
         # same logical run, reference Train-v2 controller semantics)
@@ -199,7 +210,8 @@ class JaxTrainer:
         while True:
             try:
                 outcome, error = self._run_attempt(
-                    trial_dir, manager, resume_ckpt, history, last_metrics
+                    trial_dir, manager, resume_ckpt, history, last_metrics,
+                    started,
                 )
             except BaseException as e:  # noqa: BLE001 - setup failure (e.g. infeasible gang)
                 outcome, error = "failed", e
@@ -220,6 +232,7 @@ class JaxTrainer:
                     metrics_history=history,
                 )
             resume_ckpt = manager.latest()
+            started = time.time()  # the next attempt's own start
             logger.warning(
                 "train attempt failed (%s); restarting gang (failure %d/%s)",
                 error, failures, failure_cfg.max_failures,
@@ -264,7 +277,8 @@ class JaxTrainer:
             fits = min(fits, int(a // v))
         return max(mn, min(n, fits))
 
-    def _run_attempt(self, trial_dir, manager, resume_ckpt, history, last_metrics):
+    def _run_attempt(self, trial_dir, manager, resume_ckpt, history, last_metrics,
+                     started: float):
         n = self._gang_size()
         if n < self._scaling.num_workers:
             logger.warning(
@@ -393,7 +407,8 @@ class JaxTrainer:
                     ]
                 )
 
-            run_refs = [w.run.remote(self._fn, self._config) for w in workers]
+            run_refs = [w.run.remote(self._fn, self._config, started)
+                        for w in workers]
 
             pending = set(run_refs)
             while pending:

@@ -122,6 +122,13 @@ class Counter(Metric):
         with self._lock:
             self._series[k] = self._series.get(k, 0.0) + value
 
+    def set_total(self, value: float, tags: Optional[dict] = None) -> None:
+        """Mirror a monotonic total that is kept elsewhere (a collector's
+        write); the series never goes down."""
+        k = self._key(tags)
+        with self._lock:
+            self._series[k] = max(self._series.get(k, 0.0), float(value))
+
 
 class Gauge(Metric):
     TYPE = "gauge"
@@ -175,7 +182,27 @@ class Histogram(Metric):
             }
 
 
+# Collectors: callables run before every read of the registry, for
+# totals that are cheaper to keep outside it (a plain int on a hot path)
+# and mirror in when someone looks. Code, not data: clear_registry()
+# leaves them.
+_COLLECTORS: list[Callable[[], None]] = []
+
+
+def register_collector(fn: Callable[[], None]) -> None:
+    with _REGISTRY_LOCK:
+        if fn not in _COLLECTORS:
+            _COLLECTORS.append(fn)
+
+
 def registry_snapshot() -> list[Metric]:
+    with _REGISTRY_LOCK:
+        collectors = list(_COLLECTORS)
+    for fn in collectors:
+        try:
+            fn()
+        except Exception:  # noqa: BLE001 - a broken collector must not break /metrics
+            pass
     with _REGISTRY_LOCK:
         return list(_REGISTRY.values())
 

@@ -250,8 +250,14 @@ def test_noisy_neighbor_paying_tenant_green():
         def flood():
             while not stop.is_set():
                 try:
+                    # long enough that no single decode chunk (up to 64
+                    # steps once the adaptive controller has ratcheted) can
+                    # finish a batch row: at 24 tokens with EOS allowed, the
+                    # flush that precedes a priority preemption finished the
+                    # whole batch on a fast idle host and nothing was left
+                    # to preempt (the test then failed without any load)
                     t = mgr.submit("batch", "tiny", PROMPT,
-                                   SamplingParams(max_tokens=24))
+                                   SamplingParams(max_tokens=100, ignore_eos=True))
                 except FleetAdmissionRejected:
                     shed[0] += 1
                     time.sleep(0.005)

@@ -423,26 +423,38 @@ def test_chunk_controller_deterministic_and_bounded():
 # ---------------------------------------------------------------------------
 
 
-def test_host_split_histograms_and_stats_row():
-    from ray_tpu.llm.pipeline import host_prep_histogram, sync_wait_histogram
-    from ray_tpu.util import metrics as metrics_mod
+def test_host_split_layer_spans_and_stats_row():
+    """The host/device split of a pipelined round, always on: the
+    engine.decode_dispatch and engine.sync layer spans (what the
+    llm_decode_host_prep_ms / llm_decode_sync_wait_ms histograms timed
+    behind EngineConfig.profile), the engine's counters, the stats row."""
+    from ray_tpu import obs
 
-    metrics_mod.clear_registry()
-    eng = _engine(True, profile=True, decode_chunk=4)
+    before = obs.layer_counters()
+    eng = _engine(True, decode_chunk=4)
     sp = SamplingParams(max_tokens=8, temperature=0.0, ignore_eos=True)
     eng.generate([[1, 2, 3, 4]], sp)
-    assert host_prep_histogram().hist_data(), "no host-prep observations"
-    assert sync_wait_histogram().hist_data(), "no sync-wait observations"
+    after = obs.layer_counters()
+    n = eng.counters()
+    for name in ("engine.decode_dispatch", "engine.sync"):
+        rounds = after[name]["count"] - before.get(name, {"count": 0})["count"]
+        assert rounds >= n["dispatches"]["pipe_chunk"] >= 2, name
+        assert after[name]["busy_s"] > before.get(name, {"busy_s": 0.0})["busy_s"]
+    assert n["decode_tokens"] == 7 and n["decode_steps"] >= 7
     row = eng.stats()["pipeline"]
+    assert row["dispatches"] == n["dispatches"]["pipe_chunk"]
     assert {"chunks_by_steps", "overlap_ratio", "host_prep_ms",
             "sync_wait_ms", "steps_saved_by_early_exit"} <= set(row)
     assert 0.0 <= row["overlap_ratio"] <= 1.0
 
 
-def test_pipeline_module_is_metrics_instrumented():
+def test_layer_span_export_is_metrics_instrumented():
+    """The layer counters that took over from the pipeline's histograms
+    are under the live-registry lint."""
     from ray_tpu.analysis.metrics_registry import INSTRUMENTED
 
-    assert ("ray_tpu.llm.pipeline", "register_metrics") in INSTRUMENTED
+    assert ("ray_tpu.obs.recorder", "register_metrics") in INSTRUMENTED
+    assert ("ray_tpu.llm.pipeline", "register_metrics") not in INSTRUMENTED
 
 
 def test_checked_in_pipeline_capture_gate():

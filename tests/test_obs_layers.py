@@ -1,0 +1,389 @@
+"""Layer spans (ray_tpu.obs.layer_span), the compile log, the serving
+runner's and the engine's spans and counters, engine.warmup()."""
+
+import glob
+import os
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu import obs
+from ray_tpu.chaos import harness as chaos
+from ray_tpu.llm.engine import EngineConfig, LLMEngine, SamplingParams
+from ray_tpu.models import llama
+from ray_tpu.obs.recorder import SpanRecorder, layer_record, layer_span
+
+GREEDY = dict(temperature=0.0, ignore_eos=True)
+
+
+def _engine(**kw):
+    kw.setdefault("num_blocks", 64)
+    kw.setdefault("max_num_seqs", 4)
+    return LLMEngine(EngineConfig(model=llama.LLAMA_TINY, **kw))
+
+
+# -- the primitive ------------------------------------------------------------
+
+
+def test_layer_span_outside_a_capture_leaves_only_counters():
+    rec = SpanRecorder()
+    with layer_span("t.layer", recorder=rec):
+        time.sleep(0.002)
+    with layer_span("t.layer", recorder=rec):
+        pass
+    got = rec.layer_counters()["t.layer"]
+    assert got["count"] == 2 and got["busy_s"] >= 0.002
+    assert rec.layer_spans() == [] and len(rec) == 0
+
+
+def test_layer_span_inside_a_capture_is_a_span_with_the_right_parent():
+    rec = SpanRecorder()
+    request = obs.new_context()
+    with rec.capture() as spans:
+        with layer_span("t.outer", recorder=rec) as outer:
+            with layer_span("t.inner", recorder=rec):
+                pass
+            with layer_span("t.for_request", ctx=request, recorder=rec):
+                pass
+            outer.attrs["rows"] = 3  # known only at the end
+    with layer_span("t.after", recorder=rec):
+        pass
+    by = {s.name: s for s in spans}
+    assert set(by) == {"t.outer", "t.inner", "t.for_request"}
+    assert by["t.outer"].parent_id is None and by["t.outer"].attrs == {"rows": 3}
+    assert by["t.inner"].parent_id == by["t.outer"].span_id
+    assert by["t.inner"].trace_id == by["t.outer"].trace_id
+    # work done for one request hangs under that request's context
+    assert by["t.for_request"].trace_id == request.trace_id
+    assert by["t.for_request"].parent_id == request.span_id
+    assert by["t.outer"].start <= by["t.inner"].start <= by["t.inner"].end <= by["t.outer"].end
+    assert rec.layer_counters()["t.after"]["count"] == 1
+
+
+def test_layer_spans_never_evict_a_request_trace():
+    rec = SpanRecorder(max_traces=2, max_layer_spans=8)
+    rec.record("llm.request", 1.0, 2.0, ctx=obs.new_context(),
+               attrs={"request_id": "r1"})
+    with rec.capture() as spans:
+        for _ in range(50):
+            with layer_span("t.flood", recorder=rec):
+                pass
+    assert rec.find_by_request("r1") is not None and len(rec) == 1
+    assert rec.num_dropped_traces == 0
+    # its own ring is bounded, and says what it dropped
+    assert len(spans) == 8 and rec.num_dropped_layer_spans == 42
+    assert rec.layer_counters()["t.flood"]["count"] == 50
+
+
+def test_layer_record_counts_a_span_started_elsewhere():
+    rec = SpanRecorder()
+    with rec.capture() as spans:
+        layer_record("t.handoff", time.time() - 0.5, attrs={"rank": 0}, recorder=rec)
+    assert 0.5 <= rec.layer_counters()["t.handoff"]["busy_s"] < 5
+    assert [(s.name, s.attrs) for s in spans] == [("t.handoff", {"rank": 0})]
+
+
+def test_recorder_resize_and_since():
+    rec = SpanRecorder(max_traces=2)
+    for i, end in enumerate((10.0, 20.0, 30.0)):
+        rec.record("llm.request", end - 1, end, ctx=obs.new_context(),
+                   attrs={"request_id": f"r{i}"})
+    assert len(rec) == 2 and rec.num_dropped_traces == 1
+    rec.resize(max_traces=8)
+    rec.record("llm.request", 39.0, 40.0, ctx=obs.new_context(),
+               attrs={"request_id": "r3"})
+    assert len(rec) == 3
+    window = rec.since(25.0)  # one window's requests, taken at its end
+    assert [t[0].attrs["request_id"] for t in window.values()] == ["r2", "r3"]
+    rec.resize(max_traces=1)
+    assert len(rec) == 1 and rec.find_by_request("r3") is not None
+
+
+def test_layer_counters_reach_the_metrics_registry():
+    from ray_tpu.util.metrics import prometheus_text
+
+    with obs.layer_span("t.exported"):
+        pass
+    text = prometheus_text()
+    assert 'ray_tpu_obs_layer_spans_total{name="t.exported"}' in text
+    assert 'ray_tpu_obs_layer_busy_seconds_total{name="t.exported"}' in text
+
+
+def test_concurrent_layer_spans_lose_no_count():
+    import sys
+
+    rec = SpanRecorder()
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def work():
+            for _ in range(2000):
+                with layer_span("t.shared", recorder=rec):
+                    pass
+
+        threads = [threading.Thread(target=work) for _ in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert rec.layer_counters()["t.shared"]["count"] == 16000
+
+
+def test_layer_span_is_a_host_event_in_a_real_profiler_trace(tmp_path):
+    """The span is in the .xplane.pb under its own name, and the clock
+    markers place the recorder's times on the profiler's clock."""
+    f = jax.jit(lambda x: x * 2 + 1)
+    f(jnp.ones(8)).block_until_ready()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with obs.capture() as spans:
+            with obs.layer_span("t.traced"):
+                f(jnp.ones(8)).block_until_ready()
+                time.sleep(0.01)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(tmp_path, "plugins", "profile", "*", "*.xplane.pb"))
+    host = [(e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9)
+            for plane in jax.profiler.ProfileData.from_file(path).planes
+            for line in plane.lines for e in line.events]
+    seen = [(s, d) for name, s, d in host if name == "t.traced"]
+    assert len(seen) == 1
+    offset = obs.clock_offset((n, s) for n, s, _ in host)
+    assert offset is not None
+    span, = [s for s in spans if s.name == "t.traced"]
+    assert abs(span.start + offset - seen[0][0]) < 1e-3
+    assert abs(span.duration_s - seen[0][1]) < 1e-3
+
+
+# -- the compile log ------------------------------------------------------------
+
+
+def test_compile_log_tells_a_compile_from_a_cache_load(tmp_path):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from ray_tpu.utils.compile_cache import start_compile_log
+
+    start_compile_log()
+    start_compile_log()  # once a process, however often it is asked
+    keep = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    cc.reset_cache()
+    try:
+        def compile_log_probe(x):
+            return jnp.cos(x) * 3 + 2
+
+        t0 = time.time()
+        jax.jit(compile_log_probe)(jnp.ones(7)).block_until_ready()
+        jax.clear_caches()
+        jax.jit(compile_log_probe)(jnp.ones(7)).block_until_ready()
+    finally:
+        for k, v in keep.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    mine = [e for e in obs.compile_log(since=t0) if "compile_log_probe" in e[1]]
+    assert [e[3] for e in mine] == ["compiled", "loaded"]
+    assert all(e[2] > 0 and e[0] >= t0 for e in mine)
+
+
+# -- the engine -------------------------------------------------------------------
+
+
+def test_every_engine_program_is_lowered_under_its_class_name():
+    """No jit__lambda: the device's line of a trace and the compile log
+    name the class of every program the engine builds."""
+    from ray_tpu.llm.spec import SpecConfig
+
+    t0 = time.time()
+    from ray_tpu.utils.compile_cache import start_compile_log
+
+    start_compile_log()
+    sp = SamplingParams(max_tokens=6, **GREEDY)
+    prompts = [[1, 2, 3, 4], [5, 6, 7]]
+    _engine().generate(prompts, sp)                  # prefill, pipe chunk
+    _engine(mixed_batch=True).generate(prompts, sp)  # mixed
+    # a spec engine's own tables: verify, and the unpipelined decode programs
+    report = _engine(spec=SpecConfig(method="prompt_lookup", num_draft_tokens=2),
+                     max_num_seqs=1, max_prefill_len=16, decode_chunk=4).warmup()
+    assert set(report) == {"prefill", "verify", "decode", "decode_chunk"}
+    eng = _engine()
+    eng._kv_import_fn(16)
+    names = {e[1] for e in obs.compile_log(since=t0)}
+    for cls in ("llm_prefill", "llm_pipe_chunk_n", "llm_decode)", "llm_decode_chunk_n",
+                "llm_mixed", "llm_verify"):
+        assert any(f"jit({cls}" in n for n in names), (cls, sorted(names))
+    lowered = eng._kv_import_fn(16).lower(
+        eng.cache, *(jax.ShapeDtypeStruct(
+            eng.cache["k"].shape[:2] + (16,) + eng.cache["k"].shape[3:],
+            eng.cache["k"].dtype),) * 2,
+        jax.ShapeDtypeStruct((16,), jnp.int32))
+    assert "jit_llm_kv_scatter" in lowered.as_text()[:200]
+    assert not any("lambda" in n for n in names if "llm" in n)
+    import inspect
+
+    from ray_tpu.llm import engine as engine_mod
+
+    src = inspect.getsource(engine_mod)
+    assert "jax.jit(lambda" not in src and "jax.jit(\n            lambda" not in src
+
+
+def test_engine_counters_match_a_hand_counted_run():
+    eng = _engine(max_num_seqs=4)
+    sp = SamplingParams(max_tokens=5, **GREEDY)
+    before = obs.layer_counters()
+    eng.generate([[1, 2, 3, 4], [9, 8, 7]], sp)
+    n = eng.counters()
+    # 2 requests x 5 tokens: the first of each comes from its prefill
+    assert n["prefill_tokens"] == 7 and n["prefill_cached_tokens"] == 0
+    assert n["decode_tokens"] == 8 and n["decode_row_steps"] == 8
+    # both rows decode together: 4 steps with 2 live rows of 4 slots
+    assert n["decode_steps"] == 4
+    assert n["decode_occupancy"] == pytest.approx(8 / (4 * 4))
+    assert n["dispatches"]["prefill"] == 2 and n["first_calls"]["prefill"] == 1
+    assert n["preemptions"] == 0 and n["max_num_seqs"] == 4
+    after = obs.layer_counters()
+    for name in ("engine.step", "engine.schedule", "engine.prefill_dispatch",
+                 "engine.decode_dispatch", "engine.sync", "engine.append"):
+        assert after[name]["count"] > before.get(name, {"count": 0})["count"], name
+    # the same prompt again is served from the prefix cache, by count
+    eng.generate([[1, 2, 3, 4] * 5], sp)
+    eng.generate([[1, 2, 3, 4] * 5], sp)
+    assert eng.counters()["prefill_cached_tokens"] == 16
+
+
+def test_engine_step_span_names_what_the_step_did():
+    eng = _engine()
+    eng.add_request([1, 2, 3, 4], SamplingParams(max_tokens=4, **GREEDY))
+    with obs.capture() as spans:
+        while eng.has_unfinished():
+            eng.step()
+    steps = [s for s in spans if s.name == "engine.step"]
+    assert steps[0].attrs == {"rows": 0, "waiting": 1, "kind": "prefill"}
+    assert {s.attrs["kind"] for s in steps[1:]} == {"decode"}
+    ids = {s.span_id: s.name for s in spans}
+    children = {s.name for s in spans if ids.get(s.parent_id) == "engine.step"}
+    assert children == {"engine.schedule", "engine.prefill_dispatch",
+                        "engine.decode_dispatch", "engine.sync", "engine.append"}
+
+
+def test_warmup_leaves_nothing_to_compile_under_traffic():
+    from ray_tpu.utils.compile_cache import start_compile_log
+
+    start_compile_log()
+    eng = _engine(max_num_seqs=2, max_prefill_len=32, decode_chunk=4)
+    before = obs.layer_counters().get("engine.warmup.prefill", {"count": 0})["count"]
+    report = eng.warmup()
+    c = eng.config
+    assert report["prefill"]["programs"] == len(c.prefill_buckets()) * len(c.bt_widths())
+    assert report["pipe_chunk"]["programs"] == len(c.decode_buckets()) * len(c.bt_widths()) * 7
+    for row in report.values():
+        assert row["compiled"] + row["loaded"] == row["programs"] and row["seconds"] > 0
+    assert obs.layer_counters()["engine.warmup.prefill"]["count"] == before + 1
+    warmed = eng.counters()["first_calls"]
+    t0 = time.time()
+    out = eng.generate([[1, 2, 3, 4], [5, 6, 7]], SamplingParams(max_tokens=9, **GREEDY))
+    assert [len(o) for o in out] == [9, 9]
+    assert eng.counters()["first_calls"] == warmed
+    assert [e for e in obs.compile_log(since=t0) if "llm_" in e[1]] == []
+    # the warm-up wrote the trash page only: the same tokens as a cold engine
+    assert out == _engine(max_num_seqs=2, max_prefill_len=32, decode_chunk=4).generate(
+        [[1, 2, 3, 4], [5, 6, 7]], SamplingParams(max_tokens=9, **GREEDY))
+
+
+# -- the serving runner -------------------------------------------------------------
+
+
+@pytest.fixture
+def runner():
+    from ray_tpu.llm.openai_api import _EngineRunner
+
+    r = _EngineRunner(_engine())
+    yield r
+    r.shutdown()
+    chaos.uninstall()
+
+
+def _drain(q):
+    while True:
+        out = q.get(timeout=120)
+        assert not isinstance(out, BaseException), out
+        if out is None or out.finished:
+            return
+
+
+def test_runner_submit_reports_the_lock_wait_a_held_step_cost(runner):
+    """A second request arrives while the loop holds the lock across a
+    slowed engine step: its runner.submit span carries that wait, the
+    engine's own queue_wait cannot see it, and llm.request hands it on
+    as pre_engine_wait_s. No wall-clock race: the second submit starts
+    only once a step is known to hold the lock."""
+    delay = 0.3
+    sp = SamplingParams(max_tokens=3, **GREEDY)
+    _drain(runner.submit([1, 2, 3], sp)[1])  # compile outside the slowed part
+    chaos.install(chaos.FaultSchedule(5, [
+        chaos.FaultSpec(chaos.DELAY_RPC, site="llm.engine.step", delay_s=delay)]))
+    ctx = obs.new_context()
+    with obs.capture() as spans:
+        _, q1 = runner.submit([4, 5, 6], SamplingParams(max_tokens=8, **GREEDY))
+        # wait until the loop thread is INSIDE a step, holding the lock
+        deadline = time.time() + 30
+        while not (runner.lock._lk.locked() and runner.lock._depth == 1):
+            assert time.time() < deadline
+            time.sleep(0.001)
+        time.sleep(0.01)
+        rid2, q2 = runner.submit([7, 8, 9], sp, trace=ctx)
+        chaos.uninstall()
+        _drain(q1)
+        _drain(q2)
+    second = [s for s in spans if s.name == "runner.submit"][-1]
+    # it waited out (at least) the rest of the slowed step
+    assert second.attrs["lock_wait_ms"] >= 1e3 * (delay - 0.05)
+    assert second.duration_s * 1e3 >= second.attrs["lock_wait_ms"]
+    assert second.trace_id == ctx.trace_id and second.parent_id == ctx.span_id
+    request, = [s for s in obs.get_recorder().get(ctx.trace_id) if s.name == "llm.request"]
+    assert request.attrs["pre_engine_wait_s"] >= delay - 0.05
+    assert request.attrs["queue_wait_s"] < request.attrs["pre_engine_wait_s"]
+    totals = runner.lock.totals()
+    assert totals["wait_s"] >= delay - 0.05 and totals["hold_s"] >= delay
+    turn = {s.name for s in spans if s.name.startswith("runner.")}
+    assert turn == {"runner.submit", "runner.step", "runner.lock_wait", "runner.deliver"}
+
+
+def test_counters_and_served_stats_return_while_the_runner_lock_is_held():
+    from ray_tpu.llm.openai_api import LLMConfig, LLMServer
+
+    server = LLMServer(LLMConfig(model_id="tiny-stats", engine=EngineConfig(
+        model=llama.LLAMA_TINY, num_blocks=64, max_num_seqs=4)))
+    try:
+        _drain(server.runner.submit([1, 2, 3], SamplingParams(max_tokens=3, **GREEDY))[1])
+        got = {}
+        with server.runner.lock:  # what a long engine step looks like to a reader
+
+            def read():
+                got["counters"] = server.engine.counters()
+                got["stats"] = server.stats()
+
+            t = threading.Thread(target=read)
+            t.start()
+            t.join(timeout=20)
+            assert not t.is_alive(), "stats() waited for the runner's lock"
+        assert got["counters"]["decode_tokens"] == 2
+        stats = got["stats"]
+        assert stats["counters"] == got["counters"] and stats["num_preemptions"] == 0
+        assert stats["trace"]["engine.step"]["count"] >= 2
+        assert stats["trace"]["runner.submit"]["busy_s"] > 0
+        assert stats["runner_lock"]["acquires"] >= 3
+    finally:
+        server.shutdown()

@@ -280,22 +280,19 @@ def test_engine_profile_decode_hook():
 
 
 @pytest.mark.slow
-def test_engine_profile_flag_records_chunks():
+def test_engine_counts_decode_chunks_without_a_switch():
+    from ray_tpu import obs
     from ray_tpu.llm.engine import EngineConfig, LLMEngine, SamplingParams
-    from ray_tpu.util import metrics as metrics_mod
 
-    metrics_mod.clear_registry()
     eng = LLMEngine(
-        EngineConfig(model=llama.LLAMA_TINY, num_blocks=64, profile=True,
-                     decode_chunk=4)
+        EngineConfig(model=llama.LLAMA_TINY, num_blocks=64, decode_chunk=4)
     )
+    steps_before = obs.layer_counters().get("engine.step", {"count": 0})["count"]
     out = eng.generate(
         [[1, 2, 3, 4]], SamplingParams(max_tokens=6, ignore_eos=True)
     )
     assert len(out[0]) == 6
-    from ray_tpu.llm.decode_loop import chunk_histogram
-
-    data = chunk_histogram().hist_data()
-    assert data, "no decode chunk observations recorded"
-    total = sum(count for _, (_, _, count) in data.items())
-    assert total >= 1
+    n = eng.counters()
+    assert n["dispatches"]["pipe_chunk"] >= 1, "no decode chunk was counted"
+    assert n["decode_tokens"] == 5 and n["decode_row_steps"] == 5
+    assert obs.layer_counters()["engine.step"]["count"] > steps_before
