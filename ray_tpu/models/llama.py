@@ -34,6 +34,7 @@ from ray_tpu.nn.layers import (
     swiglu,
 )
 from ray_tpu.ops.attention import attention
+from ray_tpu.parallel.context import current_mesh
 
 Params = dict[str, Any]
 
@@ -181,11 +182,24 @@ def _block(
     c = config
     B, S, D = h.shape
     hd = c.head_dim
+    # Under a mesh with tp > 1 the residual stream h stays sharded over
+    # `tp` along the tokens and the four matmul sites gather and scatter
+    # it inside themselves (parallel/tp_overlap.py); otherwise, and
+    # always in llama_decode.py, the plain einsums below.
+    mesh = current_mesh()
+    overlap = mesh is not None and mesh.shape.get("tp", 1) > 1
+    if overlap:
+        from ray_tpu.parallel.tp_overlap import ag_matmul, rs_matmul
 
     x = rms_norm(h, lp["ln1"], c.rms_eps)
-    q = jnp.einsum("bsd,dh->bsh", x, lp["wq"].astype(x.dtype)).reshape(B, S, c.n_heads, hd)
-    k = jnp.einsum("bsd,dh->bsh", x, lp["wk"].astype(x.dtype)).reshape(B, S, c.n_kv_heads, hd)
-    v = jnp.einsum("bsd,dh->bsh", x, lp["wv"].astype(x.dtype)).reshape(B, S, c.n_kv_heads, hd)
+    if overlap:
+        q, k, v = ag_matmul(x, [lp[n].astype(x.dtype) for n in ("wq", "wk", "wv")])
+        q = q.reshape(B, S, c.n_heads, hd)
+        k, v = k.reshape(B, S, c.n_kv_heads, hd), v.reshape(B, S, c.n_kv_heads, hd)
+    else:
+        q = jnp.einsum("bsd,dh->bsh", x, lp["wq"].astype(x.dtype)).reshape(B, S, c.n_heads, hd)
+        k = jnp.einsum("bsd,dh->bsh", x, lp["wk"].astype(x.dtype)).reshape(B, S, c.n_kv_heads, hd)
+        v = jnp.einsum("bsd,dh->bsh", x, lp["wv"].astype(x.dtype)).reshape(B, S, c.n_kv_heads, hd)
     q = apply_rope(q, cos, sin, positions)
     k = apply_rope(k, cos, sin, positions)
     o = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=c.attention_impl)
@@ -194,11 +208,16 @@ def _block(
     # backward pass re-runs the whole flash kernel forward (~25% of a
     # train step) just to rebuild this tensor
     o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
-    o = jnp.einsum("bsh,hd->bsd", o.reshape(B, S, c.n_heads * hd), lp["wo"].astype(x.dtype))
-    h = h + o
+    o, wo = o.reshape(B, S, c.n_heads * hd), lp["wo"].astype(x.dtype)
+    h = h + (rs_matmul(o, wo) if overlap else jnp.einsum("bsh,hd->bsd", o, wo))
 
     x = rms_norm(h, lp["ln2"], c.rms_eps)
-    return h + swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
+    if not overlap:
+        return h + swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
+    # the MLP treats all tokens alike: it can keep the ring's own order
+    gate, up = ag_matmul(
+        x, (lp["w_gate"].astype(x.dtype), lp["w_up"].astype(x.dtype)), token_order=False)
+    return h + rs_matmul(jax.nn.silu(gate) * up, lp["w_down"].astype(x.dtype))
 
 
 def hidden_states(
@@ -236,8 +255,11 @@ def hidden_states(
                 block,
                 policy=jax.checkpoint_policies.save_from_both_policies(
                     jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+                    # tp_rs_out: a row-parallel matmul's output where
+                    # parallel/tp_overlap.py sums it (there the dot the
+                    # first policy sees is only one chip's product)
                     jax.checkpoint_policies.save_only_these_names(
-                        "attn_out", "attn_lse"
+                        "attn_out", "attn_lse", "tp_rs_out"
                     ),
                 ),
             )
@@ -247,8 +269,6 @@ def hidden_states(
             raise ValueError(
                 f"unknown remat_policy {c.remat_policy!r}; 'full' or 'dots'"
             )
-
-    from ray_tpu.parallel.context import current_mesh
 
     mesh = current_mesh()
     pp = mesh.shape.get("pp", 1) if mesh is not None else 1

@@ -70,9 +70,19 @@ def tree_specs(rules: ShardingRules, logical_tree) -> object:
 
 
 def tree_shardings(mesh: Mesh, rules: ShardingRules, logical_tree) -> object:
+    """Shardings to place a pytree by. Trailing replicated dims are left
+    out of each spec: the same layout, in the form in which a jitted
+    step hands its outputs back, so that a state placed by these meets
+    the step's cache on the second call too (`P(None)` and `P()` are
+    different cache keys: a second trace and compile of the program)."""
+    def sharding(spec):
+        parts = list(spec)
+        while parts and parts[-1] is None:
+            parts.pop()
+        return NamedSharding(mesh, P(*parts))
+
     return jax.tree.map(
-        lambda spec: NamedSharding(mesh, spec),
-        tree_specs(rules, logical_tree),
+        sharding, tree_specs(rules, logical_tree),
         is_leaf=lambda x: isinstance(x, PartitionSpec),
     )
 

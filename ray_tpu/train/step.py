@@ -17,6 +17,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import optax
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ray_tpu import obs
 from ray_tpu.parallel.sharding import ShardingRules, constrain, tree_shardings
@@ -37,15 +38,27 @@ class TrainState:
         # without out_shardings the whole state — twice the model — lands
         # unsharded on the default device. Params nobody placed
         # (uncommitted) leave their moments free to follow them later.
+        # The scalars (step, Adam's count) are born replicated over the
+        # params' mesh, which is how a step hands them back: born on the
+        # default device they made the second call of a sharded step a
+        # second trace, lowering and compile of the whole program.
+        meshes = {
+            p.sharding.mesh for p in jax.tree.leaves(params)
+            if getattr(p, "committed", False) and isinstance(p.sharding, NamedSharding)
+        }
+        everywhere = NamedSharding(meshes.pop(), PartitionSpec()) if len(meshes) == 1 else None
         shardings = optax.tree_map_params(
             optimizer,
             lambda _, p: p.sharding if getattr(p, "committed", False) else None,
             jax.eval_shape(optimizer.init, params),
             params,
-            transform_non_params=lambda _: None,
+            transform_non_params=lambda _: everywhere,
         )
         opt_state = jax.jit(optimizer.init, out_shardings=shardings)(params)
-        return cls(params=params, opt_state=opt_state, step=jnp.zeros((), jnp.int32))
+        step = jnp.zeros((), jnp.int32)
+        if everywhere is not None:
+            step = jax.device_put(step, everywhere)
+        return cls(params=params, opt_state=opt_state, step=step)
 
 
 def init_sharded_params(
@@ -107,16 +120,15 @@ def make_train_step(
         return _compute_grads_inner(params, batch)
 
     def _compute_grads_inner(params, batch):
-        returns_weight = isinstance(
-            jax.eval_shape(loss_fn, params, batch), (tuple, list)
-        )
-        if returns_weight:
-            (loss, weight), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, batch
-            )
-        else:
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-            weight = jnp.ones((), jnp.float32)
+        def with_weight(params, batch):
+            # one trace of the model serves both forms of loss_fn (asking
+            # eval_shape first traced it twice: seconds of every start-up)
+            out = loss_fn(params, batch)
+            if isinstance(out, (tuple, list)):
+                return tuple(out)
+            return out, jnp.ones((), jnp.float32)
+
+        (loss, weight), grads = jax.value_and_grad(with_weight, has_aux=True)(params, batch)
         return loss, weight, grads
 
     def step(state: TrainState, batch):

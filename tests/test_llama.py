@@ -168,3 +168,28 @@ def test_fused_ce_matches_naive():
     for a, b, name in zip(g1, g0, ("dh", "dw")):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4,
                                    err_msg=f"{name} mismatch")
+
+
+def test_sharded_train_step_is_traced_once(cpu_devices):
+    """A state placed by init_sharded_params + TrainState.create meets
+    the jitted step's cache again on the second call: norm weights whose
+    spec was P(None, None) where the step hands back P(), and scalars
+    (step, Adam's count) born on the default device where the step hands
+    them back replicated over the mesh, each made the second call a
+    second trace, lowering and compile of the whole step."""
+    mesh = make_mesh(MeshSpec(fsdp=2, tp=2), devices=cpu_devices[:4])
+    rules = default_rules()
+    params = init_sharded_params(
+        lambda k: llama.init_params(CFG, k), llama.logical_axes(CFG), mesh, rules,
+        jax.random.key(0))
+    opt = optax.adamw(1e-3)
+    state = TrainState.create(params, opt)
+    assert state.step.sharding.is_fully_replicated and state.step.committed
+    step = make_train_step(lambda p, b: llama.loss_fn(p, b, CFG), opt, mesh=mesh, rules=rules)
+    batch = jax.device_put(
+        _batch(jax.random.key(1), CFG), jax.sharding.NamedSharding(mesh, rules.spec(("batch", "seq"))))
+    before = [x.sharding for x in jax.tree.leaves(state)]
+    for _ in range(3):
+        state, _ = step(state, batch)
+    assert step._cache_size() == 1
+    assert [x.sharding for x in jax.tree.leaves(state)] == before

@@ -193,6 +193,101 @@ def test_engine_programs_with_pallas_compile_for_v5e(v5e):
         assert "tpu_custom_call" in lowered.compile().as_text()
 
 
+def _train_step_at_mistral_widths(devices, mesh_shape=None, batch=3):
+    """(jitted step, abstract state, abstract batch) of a 2-layer
+    Mistral-7B-wide train step as chipbench's training cells build it,
+    placed on the described devices: one chip, or a 6-axis mesh."""
+    import dataclasses
+
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ray_tpu.models import llama
+    from ray_tpu.models.registry import get_model_config
+    from ray_tpu.parallel.mesh import MESH_AXES
+    from ray_tpu.parallel.sharding import default_rules, tree_shardings
+    from ray_tpu.train.step import TrainState, make_train_step
+
+    cfg = dataclasses.replace(get_model_config("mistral-7b"), n_layers=2,
+                              attention_impl="flash")
+    opt = optax.adamw(3e-4)
+    params = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.key(0)))
+    mesh = rules = None
+    if mesh_shape is None:
+        one = _one_chip(devices)
+        param_shardings = jax.tree.map(lambda _: one, params)
+        scalar = batch_sharding = one
+    else:
+        mesh = Mesh(np.asarray(devices).reshape(mesh_shape), MESH_AXES)
+        rules = default_rules()
+        param_shardings = tree_shardings(mesh, rules, llama.logical_axes(cfg))
+        scalar = NamedSharding(mesh, P())
+        batch_sharding = NamedSharding(mesh, rules.spec(("batch", "seq")))
+
+    def placed(tree, shardings):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s), tree, shardings)
+
+    params = placed(params, param_shardings)
+    opt_state = jax.eval_shape(opt.init, params)
+    opt_state = placed(opt_state, optax.tree_map_params(
+        opt, lambda _, p: p.sharding, opt_state, params,
+        transform_non_params=lambda _: scalar))
+    state = TrainState(params=params, opt_state=opt_state,
+                       step=jax.ShapeDtypeStruct((), _I32, sharding=scalar))
+    tokens = jax.ShapeDtypeStruct((batch, 4096), _I32, sharding=batch_sharding)
+    step = make_train_step(lambda p, b: llama.loss_fn(p, b, cfg), opt, mesh=mesh, rules=rules)
+    return step, state, {"tokens": tokens, "targets": tokens}
+
+
+def test_tp_matmuls_of_the_train_step_overlap_their_transfers(v5e):
+    """The fsdp 2 x tp 2 train step of `m7b-train-4chip` (2 layers):
+    neither layer scan, forward or backward, waits for an all-reduce of
+    the residual stream; the blocks travel by collective-permute, which
+    the compiler starts before a matmul and finishes after it."""
+    import re
+
+    step, state, batch = _train_step_at_mistral_widths(v5e, (1, 1, 2, 1, 1, 2), batch=6)
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        hlo = step.lower(state, batch).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    computations = dict(re.findall(r"^%?([\w.\-]+) \([^\n]*\{\n(.*?)^\}", hlo, re.M | re.S))
+    bodies = [computations[name] for name in set(re.findall(r"body=%?([\w.\-]+)", hlo))
+              if "tpu_custom_call" in computations[name]]  # the two layer scans
+    assert len(bodies) == 2
+    for body in bodies:
+        assert not re.search(r"= bf16\[\d+,4096,4096\]\S* all-reduce(-start)?\(", body)
+        # scheduled text: a matmul fusion between each block's start and its done
+        matmuls = [m.start() for m in re.finditer(r" fusion\([^\n]*calls=%?([\w.\-]+)", body)
+                   if " convolution(" in computations[m.group(1)]]
+        blocks = list(re.finditer(
+            r"%([\w.\-]+) = \(bf16\[3,2048,4096\][^=]*? collective-permute-start\(", body))
+        assert len(blocks) >= 4, "two gathers and two scatters a layer and direction"
+        for start in blocks:
+            done = body.index(f" collective-permute-done(%{start.group(1)})")
+            assert any(start.start() < at < done for at in matmuls), start.group(1)
+
+
+def test_one_chip_train_step_never_asks_for_tp_overlap(v5e, monkeypatch):
+    """No mesh: `_block` takes the plain einsums and does not even import
+    parallel/tp_overlap.py — the lowered step is the same text with the
+    module loaded and with its import made to fail."""
+    import ray_tpu.parallel.tp_overlap  # noqa: F401 - loaded
+
+    def lowered():
+        step, state, batch = _train_step_at_mistral_widths(v5e)
+        with mock.patch("jax.default_backend", return_value="tpu"):
+            return step.lower(state, batch).as_text()
+
+    with_module = lowered()
+    monkeypatch.setitem(sys.modules, "ray_tpu.parallel.tp_overlap", None)
+    with pytest.raises(ImportError):
+        import ray_tpu.parallel.tp_overlap  # noqa: F401,F811
+    assert lowered() == with_module
+    assert "tpu_custom_call" in with_module and "collective_permute" not in with_module
+
+
 def test_chip_smoke_runs_no_phase_without_a_tpu():
     p = subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py")],
