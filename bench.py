@@ -155,7 +155,7 @@ def run_bench():
     total_steps = 4 * iters
     dt = dt_a + dt_b
     tokens_per_sec = B * S * total_steps / dt
-    train_flops_per_token = 3.0 * cfg.flops_per_token()  # fwd + 2x bwd
+    train_flops_per_token = 3.0 * cfg.flops_per_token(S)  # fwd + 2x bwd
     achieved = tokens_per_sec * train_flops_per_token
     mfu = achieved / chip_peaks(dev).flops
 

@@ -41,7 +41,7 @@ def probe(tag, cfg, B, S, K=20):
     fl = [float(x) for x in losses]
     assert fl[-1] < fl[0], (fl[0], fl[-1])
     tok_s = B * S / dt
-    mfu = tok_s * 3.0 * cfg.flops_per_token() / chip_peaks().flops
+    mfu = tok_s * 3.0 * cfg.flops_per_token(S) / chip_peaks().flops
     print(json.dumps({"tag": tag, "ms_per_step": round(dt * 1e3, 2),
                       "tok_s": round(tok_s), "mfu_pct": round(mfu * 100, 2)}),
           flush=True)

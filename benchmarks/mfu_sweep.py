@@ -41,7 +41,7 @@ def bench_config(cfg, B, S, iters=10, tag="", profile=False):
     float(m["loss"])
     dt = (time.perf_counter() - t0) / iters
     tok_s = B * S / dt
-    mfu = tok_s * 3.0 * cfg.flops_per_token() / chip_peaks().flops
+    mfu = tok_s * 3.0 * cfg.flops_per_token(S) / chip_peaks().flops
     row = {
         "tag": tag,
         "ms_per_step": round(dt * 1e3, 2),

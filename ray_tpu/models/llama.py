@@ -66,14 +66,19 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
-    def flops_per_token(self) -> float:
-        """Approximate forward matmul FLOPs per token (2*params-style count)."""
+    def flops_per_token(self, seq_len: int) -> float:
+        """Forward FLOPs a token requires in a sequence of `seq_len`: 2
+        per matmul parameter on the token path (projections, MLP, head)
+        plus causal attention, QK^T and PV at 2 * head_dim per (query,
+        key) pair and head each, (seq_len + 1) / 2 keys a query on
+        average. Training is 3x this; recompute is not counted."""
         d, f, L = self.d_model, self.d_ff, self.n_layers
         hd = self.head_dim
         attn_proj = 2 * d * (self.n_heads * hd + 2 * self.n_kv_heads * hd + self.n_heads * hd)
+        scores = 4 * hd * self.n_heads * (seq_len + 1) / 2
         mlp = 2 * d * f * 3
         emb = 2 * d * self.vocab_size
-        return L * (attn_proj + mlp) + emb
+        return L * (attn_proj + scores + mlp) + emb
 
     def num_params(self) -> int:
         d, f, L, V = self.d_model, self.d_ff, self.n_layers, self.vocab_size
@@ -97,6 +102,17 @@ LLAMA_TINY = LlamaConfig(
 )
 
 
+def _moe(config: LlamaConfig):
+    """models/moe.py when the configuration has experts (a `MoEConfig`),
+    else None. Imported here: that module builds on this one, and a
+    dense configuration never loads it."""
+    if not hasattr(config, "n_experts"):
+        return None
+    from ray_tpu.models import moe
+
+    return moe
+
+
 def logical_axes(config: LlamaConfig) -> Params:
     """Pytree (parallel to params) of logical-axis tuples."""
     layer = {
@@ -106,10 +122,18 @@ def logical_axes(config: LlamaConfig) -> Params:
         "wv": ("layers", "embed", "kv_heads"),
         "wo": ("layers", "heads", "embed"),
         "ln2": ("layers", "norm"),
-        "w_gate": ("layers", "embed", "mlp"),
-        "w_up": ("layers", "embed", "mlp"),
-        "w_down": ("layers", "mlp", "embed"),
     }
+    moe = _moe(config)
+    if moe is None:
+        layer.update(
+            w_gate=("layers", "embed", "mlp"),
+            w_up=("layers", "embed", "mlp"),
+            w_down=("layers", "mlp", "embed"),
+        )
+    else:
+        layer.update(moe.expert_axes())
+        if config.qk_norm:
+            layer.update(q_norm=("layers", "norm"), k_norm=("layers", "norm"))
     axes: Params = {
         "embed": ("vocab", "embed"),
         "layers": layer,
@@ -131,6 +155,18 @@ def init_params(config: LlamaConfig, key: jax.Array) -> Params:
         ks = jax.random.split(k, L)
         return jax.vmap(lambda kk: init_dense(kk, shape, c.param_dtype))(ks)
 
+    moe = _moe(c)
+    if moe is None:
+        ffn = {
+            "w_gate": dense(keys[5], (c.d_model, c.d_ff)),
+            "w_up": dense(keys[6], (c.d_model, c.d_ff)),
+            "w_down": dense(keys[7], (c.d_ff, c.d_model)),
+        }
+    else:
+        ffn = moe.expert_params(c, keys[5])
+        if c.qk_norm:
+            ffn["q_norm"] = jnp.ones((L, c.n_heads * hd), c.param_dtype)
+            ffn["k_norm"] = jnp.ones((L, c.n_kv_heads * hd), c.param_dtype)
     params: Params = {
         "embed": init_dense(keys[0], (c.vocab_size, c.d_model), c.param_dtype, scale=1.0),
         "layers": {
@@ -140,9 +176,7 @@ def init_params(config: LlamaConfig, key: jax.Array) -> Params:
             "wv": dense(keys[3], (c.d_model, c.n_kv_heads * hd)),
             "wo": dense(keys[4], (c.n_heads * hd, c.d_model)),
             "ln2": jnp.ones((L, c.d_model), c.param_dtype),
-            "w_gate": dense(keys[5], (c.d_model, c.d_ff)),
-            "w_up": dense(keys[6], (c.d_model, c.d_ff)),
-            "w_down": dense(keys[7], (c.d_ff, c.d_model)),
+            **ffn,
         },
         "final_norm": jnp.ones((c.d_model,), c.param_dtype),
     }
@@ -178,8 +212,13 @@ def _block(
     sin: jax.Array,
     positions: jax.Array,
     segment_ids: Optional[jax.Array],
-) -> jax.Array:
+) -> tuple[jax.Array, Optional[Params]]:
+    """One decoder layer -> (h, the layer's statistics): attention with
+    the q/k RMSNorm when the configuration has it, then the dense SwiGLU
+    or the expert layer (models/moe.py, whose statistics come back; None
+    for a dense layer) by the configuration's own kind."""
     c = config
+    moe = _moe(c)
     B, S, D = h.shape
     hd = c.head_dim
     # Under a mesh with tp > 1 the residual stream h stays sharded over
@@ -200,6 +239,9 @@ def _block(
         q = jnp.einsum("bsd,dh->bsh", x, lp["wq"].astype(x.dtype)).reshape(B, S, c.n_heads, hd)
         k = jnp.einsum("bsd,dh->bsh", x, lp["wk"].astype(x.dtype)).reshape(B, S, c.n_kv_heads, hd)
         v = jnp.einsum("bsd,dh->bsh", x, lp["wv"].astype(x.dtype)).reshape(B, S, c.n_kv_heads, hd)
+    if moe is not None and c.qk_norm:  # over the whole projected width, before rotary
+        q = rms_norm(q.reshape(B, S, -1), lp["q_norm"], c.rms_eps).reshape(q.shape)
+        k = rms_norm(k.reshape(B, S, -1), lp["k_norm"], c.rms_eps).reshape(k.shape)
     q = apply_rope(q, cos, sin, positions)
     k = apply_rope(k, cos, sin, positions)
     o = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=c.attention_impl)
@@ -212,12 +254,17 @@ def _block(
     h = h + (rs_matmul(o, wo) if overlap else jnp.einsum("bsh,hd->bsd", o, wo))
 
     x = rms_norm(h, lp["ln2"], c.rms_eps)
+    if moe is not None:
+        # the rings of tp_overlap.py are the dense MLP's: under tp > 1
+        # the expert layer's matmuls are the partitioner's to place
+        y, stats = moe.moe_ffn(x, lp, c)
+        return h + y, stats
     if not overlap:
-        return h + swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
+        return h + swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"]), None
     # the MLP treats all tokens alike: it can keep the ring's own order
     gate, up = ag_matmul(
         x, (lp["w_gate"].astype(x.dtype), lp["w_up"].astype(x.dtype)), token_order=False)
-    return h + rs_matmul(jax.nn.silu(gate) * up, lp["w_down"].astype(x.dtype))
+    return h + rs_matmul(jax.nn.silu(gate) * up, lp["w_down"].astype(x.dtype)), None
 
 
 def hidden_states(
@@ -233,6 +280,20 @@ def hidden_states(
     The training loss pairs this with nn.layers.fused_cross_entropy_loss
     so the [T, V] logits never exist as a stored fp32 tensor; serving
     keeps using forward() -> logits."""
+    h, _ = _decoder(params, tokens, config, positions=positions, segment_ids=segment_ids)
+    return h
+
+
+def _decoder(
+    params: Params,
+    tokens: jax.Array,
+    config: LlamaConfig,
+    *,
+    positions: Optional[jax.Array] = None,
+    segment_ids: Optional[jax.Array] = None,
+) -> tuple[jax.Array, Optional[Params]]:
+    """`hidden_states` and the layers' statistics, each leaf stacked
+    over the layers (None for a dense configuration)."""
     c = config
     B, S = tokens.shape
     if S > c.max_seq:
@@ -258,8 +319,11 @@ def hidden_states(
                     # tp_rs_out: a row-parallel matmul's output where
                     # parallel/tp_overlap.py sums it (there the dot the
                     # first policy sees is only one chip's product)
+                    # moe_gate, moe_up: the expert layer's first two
+                    # grouped matmuls (models/moe.py), which are no
+                    # dot_general either
                     jax.checkpoint_policies.save_only_these_names(
-                        "attn_out", "attn_lse", "tp_rs_out"
+                        "attn_out", "attn_lse", "tp_rs_out", "moe_gate", "moe_up"
                     ),
                 ),
             )
@@ -272,7 +336,10 @@ def hidden_states(
 
     mesh = current_mesh()
     pp = mesh.shape.get("pp", 1) if mesh is not None else 1
-    if pp > 1:
+    # an expert configuration is not pipelined: the stages hand on
+    # activations only, and its router losses and counts would be lost
+    # on the way; its layers run as the one scan below on any mesh
+    if pp > 1 and _moe(c) is None:
         # pipeline the layer stack over the mesh `pp` axis (GPipe
         # microbatch schedule inside this jitted program — see
         # parallel/pipeline.py; reference PP is external vLLM stage
@@ -285,18 +352,16 @@ def hidden_states(
         from ray_tpu.parallel.pipeline import pipeline_apply, stack_stages
 
         def stage(stage_params, x):
-            out, _ = jax.lax.scan(
-                lambda carry, lp: (block(carry, lp), None), x, stage_params
-            )
+            out, _ = jax.lax.scan(block, x, stage_params)
             return out
 
-        h = pipeline_apply(
+        h, stats = pipeline_apply(
             mesh, stage, stack_stages(params["layers"], pp), h, n_micro=pp
-        )
+        ), None
     else:
-        h, _ = jax.lax.scan(lambda carry, lp: (block(carry, lp), None), h, params["layers"])
+        h, stats = jax.lax.scan(block, h, params["layers"])
 
-    return rms_norm(h, params["final_norm"], c.rms_eps)
+    return rms_norm(h, params["final_norm"], c.rms_eps), stats
 
 
 def output_weight(params: Params) -> jax.Array:
@@ -328,34 +393,40 @@ def loss_fn(
     batch: dict[str, jax.Array],  # tokens [B,S], targets [B,S], optional mask [B,S]
     config: LlamaConfig,
 ) -> jax.Array:
-    loss, _ = loss_and_weight_fn(params, batch, config)
-    return loss
+    return loss_and_weight_fn(params, batch, config)[0]
 
 
 def loss_and_weight_fn(
     params: Params,
     batch: dict[str, jax.Array],
     config: LlamaConfig,
-) -> tuple[jax.Array, jax.Array]:
+) -> tuple:
     """(mean_loss, valid_token_count) — the weighted form grad-accum needs.
+    An expert configuration adds its two router losses (each the mean
+    over layers, at the configuration's coefficients) to the loss and
+    returns a third element, the layers' statistics (models/moe.py),
+    which train/step.py hands out with the step's metrics.
 
     Uses the fused lm-head + CE (nn/layers.py fused_cross_entropy_loss):
     the [T, V] fp32 logits/softmax pipeline was ~36% of the flagship
     train step before fusion (round-5 profile)."""
     import os
 
+    h, stats = _decoder(
+        params, batch["tokens"], config, segment_ids=batch.get("segment_ids")
+    )
     # A/B probe hook (benchmarks). Read at TRACE time: flipping it in a
     # process that already compiled the step has no effect — set it in a
     # fresh process (the benchmark harnesses fork per variant).
     if os.environ.get("RAY_TPU_NAIVE_CE"):
-        logits = forward(
-            params, batch["tokens"], config,
-            segment_ids=batch.get("segment_ids"),
+        logits = jnp.einsum("bsd,dv->bsv", h, output_weight(params).astype(config.dtype))
+        loss, weight = cross_entropy_loss(logits, batch["targets"], batch.get("mask"))
+    else:
+        loss, weight = fused_cross_entropy_loss(
+            h, output_weight(params), batch["targets"], batch.get("mask")
         )
-        return cross_entropy_loss(logits, batch["targets"], batch.get("mask"))
-    h = hidden_states(
-        params, batch["tokens"], config, segment_ids=batch.get("segment_ids")
-    )
-    return fused_cross_entropy_loss(
-        h, output_weight(params), batch["targets"], batch.get("mask")
-    )
+    if stats is None:
+        return loss, weight
+    router = (config.router_aux_coeff * stats["balance_loss"].mean()
+              + config.router_z_coeff * stats["z_loss"].mean())
+    return loss + router, weight, stats

@@ -1,66 +1,71 @@
-"""Mixture-of-Experts decoder (Mixtral-style) with expert parallelism.
+"""Sparse expert feed-forward layer (Mixtral, OLMoE) for the one decoder.
 
 The reference has NO expert parallelism (SURVEY.md §2.4 — absent from
-python/ray/llm); this is a native capability. Design: Switch/GShard-style
-capacity-bucketed dispatch expressed as einsums over an explicit expert
-axis — the expert dimension carries the logical axis "expert" which the
-sharding rules map to the mesh `ep` axis, so under pjit XLA lowers the
-dispatch/combine einsums to all-to-alls over ICI (no hand-written
-collectives; same rules table as DP/FSDP/TP/SP — parallel/sharding.py).
+python/ray/llm); this is a native capability. This module holds what an
+expert configuration adds to the llama-family decoder: `MoEConfig`, the
+expert layer `moe_ffn`, its parameters and their logical axes. The
+block, the layer scan, the head and the loss are models/llama.py's,
+which calls `moe_ffn` in place of the dense SwiGLU when its
+configuration is a `MoEConfig`.
 
-Attention/norms/embeddings reuse the llama block structure
-(models/llama.py); only the FFN is replaced by the MoE layer.
+The layer is DROPLESS: every chosen (token, expert) pair is computed,
+none is padded to a capacity. The N * top_k pairs are sorted by expert
+(a stable sort, so a group keeps token order), the tokens' rows are
+gathered into that order, gate / up / down run as grouped matmuls over
+the ragged groups (`jax.lax.ragged_dot`, which XLA lowers to a Mosaic
+kernel of its own on a TPU), each row weighted by the router on the way,
+and the rows go back to token order, where a token's are summed. Both
+permutations are gathers, forward and backward (custom VJPs below).
+
+The expert dimension carries the logical axis "expert", which the
+sharding rules map to the mesh `ep` axis; under a mesh the partitioner
+places the grouped matmuls (speed there is not measured yet).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
-from typing import Any, Optional
+from typing import Any
 
 import jax
+import jax.ad_checkpoint
 import jax.numpy as jnp
 
+from ray_tpu import obs
 from ray_tpu.models import llama
-from ray_tpu.nn.layers import (
-    apply_rope,
-    cross_entropy_loss,
-    init_dense,
-    rms_norm,
-    rope_frequencies,
-)
-from ray_tpu.ops.attention import attention
+from ray_tpu.nn.layers import init_dense
 
 Params = dict[str, Any]
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig(llama.LlamaConfig):
+    """`d_ff` is the width of ONE expert."""
+
     n_experts: int = 8
     top_k: int = 2
-    capacity_factor: float = 1.25
+    # the chosen experts' weights renormalised to sum to 1 (Mixtral) or
+    # left as the softmax over all experts gave them (OLMoE)
+    norm_topk_prob: bool = True
+    # RMSNorm with a learned scale over the whole projected q and k,
+    # before the head split and rotary (OLMoE; leaves q_norm, k_norm)
+    qk_norm: bool = False
     router_aux_coeff: float = 0.01  # load-balancing loss weight
+    router_z_coeff: float = 0.0     # router z-loss weight
 
-    def flops_per_token(self) -> float:
-        d, f, L = self.d_model, self.d_ff, self.n_layers
-        hd = self.head_dim
-        attn = 2 * d * (self.n_heads * hd + 2 * self.n_kv_heads * hd + self.n_heads * hd)
-        # only top_k experts run per token
-        mlp = 2 * d * f * 3 * self.top_k
-        emb = 2 * d * self.vocab_size
-        return L * (attn + mlp) + emb
+    def flops_per_token(self, seq_len: int) -> float:
+        """Forward FLOPs a token requires: the dense decoder's count
+        with the `top_k` experts a token runs and the router in place of
+        the one MLP."""
+        dense_mlp = 2 * self.d_model * self.d_ff * 3
+        routed = self.top_k * dense_mlp + 2 * self.d_model * self.n_experts
+        return super().flops_per_token(seq_len) + self.n_layers * (routed - dense_mlp)
 
     def num_params(self) -> int:
-        d, f, L, V, E = self.d_model, self.d_ff, self.n_layers, self.vocab_size, self.n_experts
-        hd = self.head_dim
-        per_layer = (
-            d * hd * (self.n_heads * 2 + self.n_kv_heads * 2)
-            + E * 3 * d * f  # experts
-            + d * E          # router
-            + 2 * d
-        )
-        head = 0 if self.tie_embeddings else d * V
-        return V * d + L * per_layer + d + head
+        d, f, E = self.d_model, self.d_ff, self.n_experts
+        ffn = E * 3 * d * f + d * E  # experts + router
+        qk = d + self.n_kv_heads * self.head_dim if self.qk_norm else 0
+        return super().num_params() + self.n_layers * (ffn + qk - 3 * d * f)
 
 
 MOE_TINY = MoEConfig(
@@ -71,186 +76,177 @@ MIXTRAL_8X7B = MoEConfig(
     vocab_size=32000, d_model=4096, n_layers=32, n_heads=32, n_kv_heads=8,
     d_ff=14336, max_seq=32768, rope_theta=1e6, n_experts=8, top_k=2,
 )
+# allenai/OLMoE-1B-7B-0125-Instruct config.json; what the config lacks is
+# from the OLMoE paper (arXiv:2409.02060) and the HF `olmoe` model code
+OLMOE_1B_7B = MoEConfig(
+    vocab_size=50304, d_model=2048, n_layers=16, n_heads=16, n_kv_heads=16,
+    d_ff=1024, max_seq=4096, rope_theta=10000.0, rms_eps=1e-5,
+    n_experts=64, top_k=8, norm_topk_prob=False, qk_norm=True,
+    router_aux_coeff=0.01, router_z_coeff=0.001,
+)
 
 
-def logical_axes(config: MoEConfig) -> Params:
-    layer = {
-        "ln1": ("layers", "norm"),
-        "wq": ("layers", "embed", "heads"),
-        "wk": ("layers", "embed", "kv_heads"),
-        "wv": ("layers", "embed", "kv_heads"),
-        "wo": ("layers", "heads", "embed"),
-        "ln2": ("layers", "norm"),
+def expert_axes() -> Params:
+    """Logical axes of the leaves `expert_params` makes."""
+    return {
         "router": ("layers", "embed", "expert"),
         "w_gate": ("layers", "expert", "embed", "mlp"),
         "w_up": ("layers", "expert", "embed", "mlp"),
         "w_down": ("layers", "expert", "mlp", "embed"),
     }
-    axes: Params = {
-        "embed": ("vocab", "embed"),
-        "layers": layer,
-        "final_norm": ("norm",),
-    }
-    if not config.tie_embeddings:
-        axes["lm_head"] = ("embed", "vocab")
-    return axes
 
 
-def init_params(config: MoEConfig, key: jax.Array) -> Params:
+def expert_params(config: MoEConfig, key: jax.Array) -> Params:
+    """Router and expert weights of every layer, stacked over layers."""
     c = config
-    keys = jax.random.split(key, 10)
-    hd, L, E = c.head_dim, c.n_layers, c.n_experts
+    L, E = c.n_layers, c.n_experts
+    keys = jax.random.split(key, 4)
 
-    def dense(k, shape):
-        ks = jax.random.split(k, L)
-        return jax.vmap(lambda kk: init_dense(kk, shape, c.param_dtype))(ks)
-
-    def expert_dense(k, shape):
-        # distinct init per (layer, expert)
+    def per_expert(k, shape):  # distinct init per (layer, expert)
         ks = jax.random.split(k, L * E).reshape(L, E)
-        return jax.vmap(
-            jax.vmap(lambda kk: init_dense(kk, shape, c.param_dtype))
-        )(ks)
+        return jax.vmap(jax.vmap(lambda kk: init_dense(kk, shape, c.param_dtype)))(ks)
 
-    params: Params = {
-        "embed": init_dense(keys[0], (c.vocab_size, c.d_model), c.param_dtype, scale=1.0),
-        "layers": {
-            "ln1": jnp.ones((L, c.d_model), c.param_dtype),
-            "wq": dense(keys[1], (c.d_model, c.n_heads * hd)),
-            "wk": dense(keys[2], (c.d_model, c.n_kv_heads * hd)),
-            "wv": dense(keys[3], (c.d_model, c.n_kv_heads * hd)),
-            "wo": dense(keys[4], (c.n_heads * hd, c.d_model)),
-            "ln2": jnp.ones((L, c.d_model), c.param_dtype),
-            "router": dense(keys[5], (c.d_model, E)),
-            "w_gate": expert_dense(keys[6], (c.d_model, c.d_ff)),
-            "w_up": expert_dense(keys[7], (c.d_model, c.d_ff)),
-            "w_down": expert_dense(keys[8], (c.d_ff, c.d_model)),
-        },
-        "final_norm": jnp.ones((c.d_model,), c.param_dtype),
+    return {
+        "router": jax.vmap(lambda kk: init_dense(kk, (c.d_model, E), c.param_dtype))(
+            jax.random.split(keys[0], L)),
+        "w_gate": per_expert(keys[1], (c.d_model, c.d_ff)),
+        "w_up": per_expert(keys[2], (c.d_model, c.d_ff)),
+        "w_down": per_expert(keys[3], (c.d_ff, c.d_model)),
     }
-    if not c.tie_embeddings:
-        params["lm_head"] = init_dense(
-            keys[9], (c.d_model, c.vocab_size), c.param_dtype
-        )
-    return params
 
 
-def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig) -> tuple[jax.Array, jax.Array]:
-    """Capacity-bucketed top-k MoE FFN.
+# -- the two permutations, as gathers in both directions ---------------------
+#
+# `order` [N*K]: the flat pair (token * K + choice) at each row of expert
+# order; `inv` [N, K]: the row of each pair. Each is the other's inverse,
+# and each function below is the other's transpose: AD's own transpose of
+# a gather is a scatter-add, which serialises on the chip.
 
-    x: [B, S, D] -> (out [B, S, D], aux_loss scalar).
-    Dispatch/combine are einsums with an explicit expert dim — sharded
-    over `ep` by the rules table, XLA inserts the all-to-alls.
+
+def _rows_of_pairs(xt, order, inv):
+    return xt[order // inv.shape[1]]
+
+
+def _sum_of_pairs(y, inv):
+    return y[inv].astype(jnp.float32).sum(axis=1).astype(y.dtype)
+
+
+@jax.custom_vjp
+def _to_expert_order(xt, order, inv):
+    """xt [N, D] -> [N*K, D]: the token's row for every pair, pairs
+    sorted by expert."""
+    return _rows_of_pairs(xt, order, inv)
+
+
+def _to_expert_order_fwd(xt, order, inv):
+    return _rows_of_pairs(xt, order, inv), inv
+
+
+def _to_expert_order_bwd(inv, g):
+    with jax.named_scope("moe.dispatch"):
+        return _sum_of_pairs(g, inv), None, None
+
+
+_to_expert_order.defvjp(_to_expert_order_fwd, _to_expert_order_bwd)
+
+
+@jax.custom_vjp
+def _to_token_order(y, order, inv):
+    """y [N*K, D] in expert order -> [N, D]: each token's K rows, summed
+    in float32."""
+    return _sum_of_pairs(y, inv)
+
+
+def _to_token_order_fwd(y, order, inv):
+    return _sum_of_pairs(y, inv), (order, inv)
+
+
+def _to_token_order_bwd(res, g):
+    order, inv = res
+    with jax.named_scope("moe.combine"):
+        return _rows_of_pairs(g, order, inv), None, None
+
+
+_to_token_order.defvjp(_to_token_order_fwd, _to_token_order_bwd)
+
+
+@jax.custom_vjp
+def _pair_weights(w, order, inv):
+    """w [N, K] -> [N*K]: each pair's router weight, in expert order."""
+    return w.reshape(-1)[order]
+
+
+def _pair_weights_fwd(w, order, inv):
+    return w.reshape(-1)[order], inv
+
+
+def _pair_weights_bwd(inv, g):
+    return g[inv], None, None
+
+
+_pair_weights.defvjp(_pair_weights_fwd, _pair_weights_bwd)
+
+
+def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig) -> tuple[jax.Array, Params]:
+    """Dropless top-k expert FFN.
+
+    x [B, S, D] -> (out [B, S, D], statistics of this layer):
+    `tokens_per_expert` int32 [E] (its sum is N * top_k),
+    `dropped_pairs` (pairs that reached no group: 0), `imbalance`
+    (largest over mean of `tokens_per_expert`), `balance_loss`
+    (E * sum_e f_e * P_e with f_e the share of tokens that chose e among
+    their top_k and P_e the mean router probability) and `z_loss`
+    (mean of logsumexp(router logits)^2), both unweighted.
+
+    The router reads the compute-dtype stream but multiplies, takes its
+    softmax and chooses in float32 (`highest`: a float32 matmul is one
+    bf16 pass on the chip otherwise).
     """
     B, S, D = x.shape
     E, K = c.n_experts, c.top_k
     N = B * S
-    C = max(1, int(c.capacity_factor * N * K / E))  # tokens per expert
-
     xt = x.reshape(N, D)
-    logits = jnp.einsum("nd,de->ne", xt.astype(jnp.float32), lp["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)  # [N, E]
-
-    # top-k expert choice per token
-    gate_vals, gate_idx = jax.lax.top_k(probs, K)  # [N, K]
-    gate_vals = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-9)
-
-    # position of each (token, k) within its expert's capacity bucket
-    onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.int32)        # [N, K, E]
-    flatoh = onehot.reshape(N * K, E)
-    pos_in_expert = (jnp.cumsum(flatoh, axis=0) - flatoh).reshape(N, K, E)
-    pos = (pos_in_expert * onehot).sum(-1)                        # [N, K]
-    kept = (pos < C) & (gate_vals > 0)                            # [N, K]
-
-    # dispatch tensor [N, E, C]: token n -> slot (e, c)
-    disp = (
-        jax.nn.one_hot(gate_idx, E, dtype=x.dtype)[..., None]
-        * jax.nn.one_hot(jnp.where(kept, pos, C), C + 1, dtype=x.dtype)[..., :C][:, :, None, :]
-    ).sum(1)  # [N, E, C]
-
-    # expert inputs [E, C, D]
-    xe = jnp.einsum("nec,nd->ecd", disp, xt)
-
-    # expert FFN (swiglu), batched over E: [E, C, D] x [E, D, F]
-    gate = jnp.einsum("ecd,edf->ecf", xe, lp["w_gate"].astype(x.dtype))
-    up = jnp.einsum("ecd,edf->ecf", xe, lp["w_up"].astype(x.dtype))
-    ye = jnp.einsum("ecf,efd->ecd", jax.nn.silu(gate) * up, lp["w_down"].astype(x.dtype))
-
-    # combine weighted by gates: weight for slot (n,e,c) = disp * gate_val
-    gate_per_ne = (
-        jax.nn.one_hot(gate_idx, E, dtype=x.dtype) * (gate_vals * kept).astype(x.dtype)[..., None]
-    ).sum(1)  # [N, E]
-    comb = disp * gate_per_ne[:, :, None]  # [N, E, C]
-    out = jnp.einsum("nec,ecd->nd", comb, ye)
-
-    # load-balancing aux loss (Switch): E * sum_e f_e * p_e
-    frac_tokens = (
-        jax.nn.one_hot(gate_idx[:, 0], E, dtype=jnp.float32).mean(0)
-    )
-    mean_probs = probs.mean(0)
-    aux = c.n_experts * jnp.sum(frac_tokens * mean_probs)
-    return out.reshape(B, S, D), aux.astype(jnp.float32)
-
-
-def _block(h, lp, *, config: MoEConfig, cos, sin, positions, segment_ids):
-    c = config
-    B, S, D = h.shape
-    hd = c.head_dim
-    x = rms_norm(h, lp["ln1"], c.rms_eps)
-    q = jnp.einsum("bsd,dh->bsh", x, lp["wq"].astype(x.dtype)).reshape(B, S, c.n_heads, hd)
-    k = jnp.einsum("bsd,dh->bsh", x, lp["wk"].astype(x.dtype)).reshape(B, S, c.n_kv_heads, hd)
-    v = jnp.einsum("bsd,dh->bsh", x, lp["wv"].astype(x.dtype)).reshape(B, S, c.n_kv_heads, hd)
-    q = apply_rope(q, cos, sin, positions)
-    k = apply_rope(k, cos, sin, positions)
-    o = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=c.attention_impl)
-    o = jnp.einsum("bsh,hd->bsd", o.reshape(B, S, c.n_heads * hd), lp["wo"].astype(x.dtype))
-    h = h + o
-    x = rms_norm(h, lp["ln2"], c.rms_eps)
-    y, aux = moe_ffn(x, lp, c)
-    return h + y, aux
-
-
-def forward(
-    params: Params,
-    tokens: jax.Array,
-    config: MoEConfig,
-    *,
-    positions: Optional[jax.Array] = None,
-    segment_ids: Optional[jax.Array] = None,
-) -> tuple[jax.Array, jax.Array]:
-    """-> (logits [B, S, V], total aux loss)."""
-    c = config
-    B, S = tokens.shape
-    if S > c.max_seq:
-        raise ValueError(f"sequence length {S} > max_seq={c.max_seq}")
-    if positions is None:
-        positions = llama.packed_positions(segment_ids, S)
-    cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
-    h = params["embed"].astype(c.dtype)[tokens]
-
-    block = partial(
-        _block, config=c, cos=cos, sin=sin, positions=positions, segment_ids=segment_ids
-    )
-    if c.remat:
-        block = jax.checkpoint(block)
-
-    def scan_fn(carry, lp):
-        h, aux = carry
-        h, a = block(h, lp)
-        return (h, aux + a), None
-
-    (h, aux), _ = jax.lax.scan(scan_fn, (h, jnp.float32(0.0)), params["layers"])
-    h = rms_norm(h, params["final_norm"], c.rms_eps)
-    w_out = params.get("lm_head", None)
-    if w_out is None:
-        w_out = params["embed"].T
-    logits = jnp.einsum("bsd,dv->bsv", h, w_out.astype(c.dtype))
-    return logits, aux
-
-
-def loss_fn(params: Params, batch: dict, config: MoEConfig) -> jax.Array:
-    logits, aux = forward(
-        params, batch["tokens"], config, segment_ids=batch.get("segment_ids")
-    )
-    ce, _ = cross_entropy_loss(logits, batch["targets"], batch.get("mask"))
-    return ce + config.router_aux_coeff * aux
+    with obs.layer_span("moe.ffn"):  # counts engaged sites, while tracing
+        with jax.named_scope("moe.router"):
+            logits = jnp.einsum(
+                "nd,de->ne", xt.astype(jnp.float32), lp["router"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            probs = jnp.exp(logits - lse[:, None])  # [N, E]
+            w, chosen = jax.lax.top_k(probs, K)     # [N, K]
+            if c.norm_topk_prob:
+                w = w / w.sum(-1, keepdims=True)
+            flat = chosen.reshape(N * K)
+            counts = jnp.sum(flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :],
+                             axis=0, dtype=jnp.int32)
+            balance = E * jnp.sum(counts.astype(jnp.float32) / N * probs.mean(0))
+            z = jnp.mean(jnp.square(lse))
+        with jax.named_scope("moe.dispatch"):
+            pairs = jnp.arange(N * K, dtype=jnp.int32)
+            _, order = jax.lax.sort((flat, pairs), num_keys=1, is_stable=True)
+            _, inv = jax.lax.sort((order, pairs), num_keys=1)
+            inv = inv.reshape(N, K)
+            xs = _to_expert_order(xt, order, inv)
+        with jax.named_scope("moe.experts"):
+            # named for the remat policy (llama._decoder), which knows
+            # dot_general's outputs but not a grouped matmul's
+            name = jax.ad_checkpoint.checkpoint_name
+            gate = name(
+                jax.lax.ragged_dot(xs, lp["w_gate"].astype(x.dtype), counts), "moe_gate")
+            up = name(jax.lax.ragged_dot(xs, lp["w_up"].astype(x.dtype), counts), "moe_up")
+            # the router's weight goes on BEFORE the down projection (the
+            # same sum): the backward then needs no output of `w_down`,
+            # so that matmul is not run again to differentiate the weights
+            act = (jax.nn.silu(gate) * up).astype(jnp.float32)
+            act = (act * _pair_weights(w, order, inv)[:, None]).astype(x.dtype)
+            ys = jax.lax.ragged_dot(act, lp["w_down"].astype(x.dtype), counts)
+        with jax.named_scope("moe.combine"):
+            out = _to_token_order(ys, order, inv)
+    stats = {
+        "tokens_per_expert": counts,
+        "dropped_pairs": N * K - counts.sum(),
+        "imbalance": counts.max() / (N * K / E),
+        "balance_loss": balance,
+        "z_loss": z,
+    }
+    return out.reshape(B, S, D), stats

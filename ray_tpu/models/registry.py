@@ -4,9 +4,11 @@ Reference analog: the reference serves any HF model id by delegating to
 vLLM's model loader (llm/_internal/serve/deployments/llm/vllm/
 vllm_models.py model_id plumbing). This framework's compute path is the
 llama-family decoder (models/llama.py — which covers Llama 1/2/3,
-Mistral, Qwen2, TinyLlama, ... since they share the architecture) and
-the MoE variant (models/moe.py — Mixtral-style). The registry gives
-users the same two entry points they expect:
+Mistral, Qwen2, TinyLlama, ... since they share the architecture), which
+runs a sparse expert layer in place of its MLP for a MoEConfig
+(models/moe.py — Mixtral; OLMoE with its q/k norm and unnormalised
+top-8 of 64, TRAINING only: the serving engine refuses a MoEConfig).
+The registry gives users the same two entry points they expect:
 
   * `get_model_config("llama3-8b")` — named presets;
   * `config_from_hf(json.load(open("config.json")))` — map a HF
@@ -72,6 +74,7 @@ for _name, _cfg in {
         max_seq=2048,
     ),
     "mixtral-8x7b": moe.MIXTRAL_8X7B,
+    "olmoe-1b-7b": moe.OLMOE_1B_7B,
     "moe-tiny": moe.MOE_TINY,
 }.items():
     register_model(_name, _cfg)
@@ -82,7 +85,7 @@ for _name, _cfg in {
 _HF_LLAMA_ARCHS = {
     "LlamaForCausalLM", "MistralForCausalLM", "Qwen2ForCausalLM",
 }
-_HF_MOE_ARCHS = {"MixtralForCausalLM"}
+_HF_MOE_ARCHS = {"MixtralForCausalLM", "OlmoeForCausalLM"}
 
 
 def config_from_hf(hf: dict, **overrides):
@@ -91,13 +94,18 @@ def config_from_hf(hf: dict, **overrides):
     Only architecture hyperparameters travel; framework knobs
     (dtype/remat/attention_impl) keep their TPU defaults unless
     overridden. Raises on architectures outside the llama/mixtral
-    families rather than mis-mapping them.
+    families rather than mis-mapping them. OLMoE (`OlmoeForCausalLM`,
+    or `model_type` "olmoe" in a dict with no architectures field):
+    `intermediate_size` is the width of one expert, q and k are
+    normalised, and the top-k weights are renormalised only if
+    `norm_topk_prob` says so.
     """
     archs = set(hf.get("architectures", ()))
+    is_olmoe = "OlmoeForCausalLM" in archs or (not archs and hf.get("model_type") == "olmoe")
     # the num_local_experts heuristic only applies to config dicts with NO
     # architectures field: PhiMoE/GPT-OSS-style configs also carry it and
     # must be rejected by the whitelist, not mapped onto Mixtral
-    is_moe = bool(archs & _HF_MOE_ARCHS) or (
+    is_moe = is_olmoe or bool(archs & _HF_MOE_ARCHS) or (
         not archs and "num_local_experts" in hf
     )
     if archs and not is_moe and not (archs & _HF_LLAMA_ARCHS):
@@ -133,9 +141,19 @@ def config_from_hf(hf: dict, **overrides):
         rms_eps=float(hf.get("rms_norm_eps", 1e-5)),
         tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
     )
-    if is_moe:
+    if is_olmoe:
+        if hf.get("clip_qkv") is not None or hf.get("attention_bias"):
+            raise ValueError("clip_qkv and attention biases are not supported")
+        common.update(
+            n_experts=hf["num_experts"], top_k=hf["num_experts_per_tok"],
+            norm_topk_prob=bool(hf.get("norm_topk_prob", False)), qk_norm=True,
+            router_aux_coeff=float(hf.get("router_aux_loss_coef", 0.01)),
+            router_z_coeff=0.001,  # arXiv:2409.02060; the HF config has no key for it
+        )
+    elif is_moe:
         common["n_experts"] = hf["num_local_experts"]
         common["top_k"] = hf.get("num_experts_per_tok", 2)
+    if is_moe:
         common.update(overrides)  # caller wins on collisions
         return moe.MoEConfig(**common)
     common.update(overrides)

@@ -29,6 +29,10 @@ The choice is made while tracing. Each traced site is a layer span:
 ``obs.layer_counters()`` counts ``tp_overlap.ag_matmul`` and
 ``tp_overlap.rs_matmul`` (overlapped) against ``tp_overlap.plain``; a
 step program traces a block more than once (shapes, then derivatives).
+The rings are the dense MLP's and the attention projections': an expert
+configuration's FFN (models/moe.py) never comes here, its grouped
+matmuls are the partitioner's to place under ``tp > 1``, and it counts
+its own sites as ``moe.ffn``, not as ``tp_overlap.plain``.
 """
 
 from __future__ import annotations

@@ -88,7 +88,11 @@ def make_train_step(
     """Build `step(state, batch) -> (state, metrics)` as one jitted program.
 
     loss_fn(params, batch) -> scalar loss, or (loss, weight) where weight is
-    the number of valid tokens the mean was taken over. With grad_accum > 1,
+    the number of valid tokens the mean was taken over, or (loss, weight,
+    statistics): a small tree of arrays the model counted on the way (an
+    expert layer's tokens per expert, models/moe.py), which the step
+    returns as metrics["stats"] (stacked over the microbatches when
+    grad_accum > 1). With grad_accum > 1,
     the batch's leading dim is split into microbatches folded through
     `lax.scan` (keeps the compiled program static; no data-dependent
     Python). Microbatch losses/grads are combined weighted by `weight`, so
@@ -108,7 +112,8 @@ def make_train_step(
         rules = default_rules()
 
     def compute_grads(params, batch):
-        """Returns (loss, weight, grads); weight=1 for scalar loss fns."""
+        """Returns (loss, weight, grads, statistics); weight=1 for scalar
+        loss fns, statistics None where loss_fn returns none."""
         from ray_tpu.parallel.context import parallel_context
 
         if mesh is not None:
@@ -124,12 +129,13 @@ def make_train_step(
             # one trace of the model serves both forms of loss_fn (asking
             # eval_shape first traced it twice: seconds of every start-up)
             out = loss_fn(params, batch)
-            if isinstance(out, (tuple, list)):
-                return tuple(out)
-            return out, jnp.ones((), jnp.float32)
+            if not isinstance(out, (tuple, list)):
+                return out, (jnp.ones((), jnp.float32), None)
+            return out[0], (out[1], out[2] if len(out) > 2 else None)
 
-        (loss, weight), grads = jax.value_and_grad(with_weight, has_aux=True)(params, batch)
-        return loss, weight, grads
+        (loss, (weight, stats)), grads = jax.value_and_grad(
+            with_weight, has_aux=True)(params, batch)
+        return loss, weight, grads, stats
 
     def step(state: TrainState, batch):
         if mesh is not None:
@@ -140,7 +146,7 @@ def make_train_step(
                 batch,
             )
         if grad_accum == 1:
-            loss, _, grads = compute_grads(state.params, batch)
+            loss, _, grads, stats = compute_grads(state.params, batch)
         else:
             micro = jax.tree.map(
                 lambda x: x.reshape((grad_accum, x.shape[0] // grad_accum) + x.shape[1:]),
@@ -148,21 +154,21 @@ def make_train_step(
             )
 
             def accum(carry, mb):
-                loss_i, w, g = compute_grads(state.params, mb)
+                loss_i, w, g, stats_i = compute_grads(state.params, mb)
                 acc_loss, acc_w, acc_g = carry
                 new = (
                     acc_loss + loss_i * w,
                     acc_w + w,
                     jax.tree.map(lambda a, b: a + b * w, acc_g, g),
                 )
-                return new, None
+                return new, stats_i
 
             zero = (
                 jnp.zeros((), jnp.float32),
                 jnp.zeros((), jnp.float32),
                 jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), state.params),
             )
-            (loss_sum, w_sum, grad_sum), _ = jax.lax.scan(accum, zero, micro)
+            (loss_sum, w_sum, grad_sum), stats = jax.lax.scan(accum, zero, micro)
             loss = loss_sum / w_sum
             grads = jax.tree.map(lambda g: g / w_sum, grad_sum)
 
@@ -170,7 +176,10 @@ def make_train_step(
         params = optax.apply_updates(state.params, updates)
         grad_norm = optax.global_norm(grads)
         new_state = TrainState(params=params, opt_state=opt_state, step=state.step + 1)
-        return new_state, {"loss": loss, "grad_norm": grad_norm}
+        metrics = {"loss": loss, "grad_norm": grad_norm}
+        if stats is not None:
+            metrics["stats"] = stats
+        return new_state, metrics
 
     jitted = jax.jit(step, donate_argnums=(0,))
     if profile:

@@ -63,6 +63,44 @@ def test_hf_mixtral_mapping():
     assert cfg.n_experts == 4 and cfg.top_k == 2
 
 
+def test_hf_olmoe_mapping_of_the_catalogs_config():
+    """The public config.json of allenai/OLMoE-1B-7B-0125-Instruct as the
+    model-configs catalog has it (no `architectures` key, `model_type`
+    olmoe), and the same with HF's architectures field: both are the
+    registry's preset."""
+    hf = {
+        "attention_bias": False, "clip_qkv": None, "hidden_act": "silu",
+        "hidden_size": 2048, "intermediate_size": 1024,
+        "max_position_embeddings": 4096, "model_type": "olmoe",
+        "norm_topk_prob": False, "num_attention_heads": 16, "num_experts": 64,
+        "num_experts_per_tok": 8, "num_hidden_layers": 16,
+        "num_key_value_heads": 16, "rms_norm_eps": 1e-05, "rope_scaling": None,
+        "rope_theta": 10000, "tie_word_embeddings": False, "vocab_size": 50304,
+    }
+    cfg = config_from_hf(hf)
+    assert cfg == get_model_config("olmoe-1b-7b")
+    assert (cfg.n_experts, cfg.top_k, cfg.d_ff) == (64, 8, 1024)  # d_ff: ONE expert's width
+    assert cfg.qk_norm and not cfg.norm_topk_prob
+    assert (cfg.router_aux_coeff, cfg.router_z_coeff) == (0.01, 0.001)
+    assert cfg.num_params() == pytest.approx(6.92e9, rel=0.01)  # "1B-7B": 6.9B in all
+    assert config_from_hf({**hf, "architectures": ["OlmoeForCausalLM"]}) == cfg
+    assert config_from_hf({**hf, "norm_topk_prob": True}).norm_topk_prob
+    with pytest.raises(ValueError, match="clip_qkv"):
+        config_from_hf({**hf, "clip_qkv": 8.0})
+
+
+def test_flops_per_token_counts_attention_and_only_the_active_experts():
+    """Forward FLOPs a token, by hand: mistral-7b at sequence 4096 and
+    olmoe-1b-7b (a token runs 8 of its 64 experts, and the router)."""
+    m7 = get_model_config("mistral-7b")
+    layer = 2 * 4096 * (4096 + 2 * 1024 + 4096) + 3 * 2 * 4096 * 14336 + 4 * 128 * 32 * 4097 / 2
+    assert m7.flops_per_token(4096) == 32 * layer + 2 * 4096 * 32000
+    ol = get_model_config("olmoe-1b-7b")
+    layer = (2 * 2048 * 4 * 2048 + 8 * 3 * 2 * 2048 * 1024 + 2 * 2048 * 64
+             + 4 * 128 * 16 * 4097 / 2)
+    assert ol.flops_per_token(4096) == 16 * layer + 2 * 2048 * 50304
+
+
 def test_hf_unknown_architecture_rejected():
     with pytest.raises(ValueError, match="unsupported architectures"):
         config_from_hf({

@@ -288,6 +288,34 @@ def test_one_chip_train_step_never_asks_for_tp_overlap(v5e, monkeypatch):
     assert "tpu_custom_call" in with_module and "collective_permute" not in with_module
 
 
+# sha256 of the lowered train step of mistral-7b (2 layers, flash, AdamW), as
+# commit 5b629f1 (the parent of PR 26) lowers it, the flash kernels' serialized
+# bodies taken out (they embed source locations). A change that MEANS to alter
+# the dense step prints the new text's hash in the failure and replaces these.
+_DENSE_STEP = {
+    None: "14345d8a3cbb5701ae05ae6ed72c71171af81f87aca57866109ecb267e64f703",
+    (1, 1, 2, 1, 1, 2): "dd35b02d6d1417352d22657f5af33b251483b34e949bb0de7bcd00e43f2470b5",
+}
+
+
+@pytest.mark.parametrize("mesh_shape,batch", [(None, 3), ((1, 1, 2, 1, 1, 2), 6)],
+                         ids=["one_chip", "fsdp2_tp2"])
+def test_dense_train_step_lowers_to_the_text_it_had_before_the_expert_layer(
+        v5e, mesh_shape, batch):
+    """One block serves dense and expert configurations (PR 26); for a
+    dense one the lowered step is the text it was, which is what keeps
+    `m7b-train` and `m7b-train-4chip` where they are."""
+    import hashlib
+    import re
+
+    step, state, tokens = _train_step_at_mistral_widths(v5e, mesh_shape, batch=batch)
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        text = step.lower(state, tokens).as_text()
+    assert "tpu_custom_call" in text
+    text = re.sub(r'backend_config = "(?:[^"\\]|\\.)*"', 'backend_config = "-"', text)
+    assert hashlib.sha256(text.encode()).hexdigest() == _DENSE_STEP[mesh_shape]
+
+
 def test_chip_smoke_runs_no_phase_without_a_tpu():
     p = subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py")],
