@@ -19,7 +19,8 @@ JAX holds the chip, and every child needs it):
            through attn_impl="pallas" and through both (the Pallas
            ragged kernel in the mixed step). Followed, in the same process,
            by the Pallas paged/ragged kernels against their XLA oracles
-           at the serve shapes.
+           at the serve shapes, and by the expert layer's grouped-matmul
+           kernels against jax.lax.ragged_dot at OLMoE-1B-7B's shapes.
   cluster  a one-node LocalCluster with TPU: 1; the trainer's worker is
            a process of its own that must report the TPU, while GCS,
            daemon and CPU workers stay on the CPU.
@@ -77,6 +78,12 @@ LOGIT_TOL = 0.125
 # kernel vs XLA oracle on bf16 outputs of magnitude < 4: two ulps
 KERNEL_TOL = 2 * 2.0 ** -6
 PALLAS = "pallas"  # the compiled kernel; never the interpreter
+# the expert layer's grouped matmuls (ops/grouped_matmul.py) at OLMoE-1B-7B's
+# widths: 6 x 4096 tokens x 8 experts each, 64 experts, gate/up and down
+GMM_ROWS, GMM_EXPERTS, GMM_SHAPES = 196608, 64, ((2048, 1024), (1024, 2048))
+# of the largest magnitude: both sides accumulate in float32, in another
+# order, and round once to bf16 (one ulp = 2^-8); seen on the chip 0 to 2^-8
+GMM_TOL = 2.0 ** -7
 
 NO_TPU_EXIT = 3
 TOTAL_BUDGET_S = 1140  # the contract is 1200 s, compilation included
@@ -554,6 +561,47 @@ def kernel_checks(cfg, block_size: int, num_blocks: int, seed: int) -> dict:
                        "block_size": block_size, "dtype": str(jnp.dtype(cfg.dtype))}}
 
 
+def grouped_matmul_check(seed: int) -> dict:
+    """ops/grouped_matmul.py as a call site gets it (the Pallas kernels,
+    on a chip with no mesh) against jax.lax.ragged_dot: value and both
+    gradients at the shapes of OLMoE-1B-7B's expert layer in a step of
+    6 x 4096 tokens (196,608 rows, 64 experts, 2048 x 1024 and back),
+    groups uneven and one of them empty."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+
+    P, E = GMM_ROWS, GMM_EXPERTS
+    rng = np.random.default_rng(seed)
+    share = rng.pareto(1.5, E) + 0.2
+    share[rng.integers(E)] = 0.0
+    sizes = np.floor(share / share.sum() * P).astype(np.int64)
+    sizes[np.argmax(sizes)] += P - sizes.sum()
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    errs, kernel_in_hlo = {}, True
+    for K, N in GMM_SHAPES:
+        keys = jax.random.split(jax.random.key(seed + K), 3)
+        lhs = jax.random.normal(keys[0], (P, K), jnp.bfloat16)
+        rhs = (jax.random.normal(keys[1], (E, K, N)) / math.sqrt(K)).astype(jnp.bfloat16)
+        ct = jax.random.normal(keys[2], (P, N), jnp.bfloat16)
+        outs = {}
+        for name, fn in (("ragged_dot", jax.lax.ragged_dot), ("kernel", grouped_matmul)):
+            f = jax.jit(lambda a, b, c, _fn=fn: jax.vjp(
+                lambda x, w: _fn(x, w, group_sizes), a, b)[1](c) + (_fn(a, b, group_sizes),))
+            if name == "kernel":
+                hlo = f.lower(lhs, rhs, ct).as_text()
+                kernel_in_hlo &= "tpu_custom_call" in hlo and "ragged_dot" not in hlo
+            outs[name] = [np.asarray(x.astype(jnp.float32)) for x in f(lhs, rhs, ct)]
+        for part, want, got in zip(("d_lhs", "d_rhs", "value"), outs["ragged_dot"], outs["kernel"]):
+            errs[f"{part}_{K}x{N}"] = float(np.abs(got - want).max() / np.abs(want).max())
+    ok = kernel_in_hlo and all(math.isfinite(e) and e <= GMM_TOL for e in errs.values())
+    return {"ok": ok, "max_rel_err": errs, "tolerance": GMM_TOL, "kernel_in_hlo": kernel_in_hlo,
+            "shapes": {"rows": P, "experts": E, "k_n": GMM_SHAPES, "dtype": "bfloat16",
+                       "largest_over_mean_group": float(sizes.max() / sizes.mean())}}
+
+
 def phase_serve(args) -> bool:
     chip = open_chip("serve", 1)
     import jax
@@ -585,7 +633,10 @@ def phase_serve(args) -> bool:
     defaults = EngineConfig(model=cfg)
     rec = kernel_checks(cfg, defaults.block_size, defaults.num_blocks, args.seed)
     emit({"phase": "kernels", **rec, **cache_report(chip), "device": chip["device"]})
-    return ok and rec["ok"]
+    del params, ref_logits  # 7.7 GiB: the grouped matmuls bring 3 GiB of their own
+    gmm = grouped_matmul_check(args.seed)
+    emit({"phase": "grouped_matmul", **gmm, **cache_report(chip), "device": chip["device"]})
+    return ok and rec["ok"] and gmm["ok"]
 
 
 def proc_env(pid: int) -> dict:

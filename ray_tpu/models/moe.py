@@ -12,14 +12,20 @@ The layer is DROPLESS: every chosen (token, expert) pair is computed,
 none is padded to a capacity. The N * top_k pairs are sorted by expert
 (a stable sort, so a group keeps token order), the tokens' rows are
 gathered into that order, gate / up / down run as grouped matmuls over
-the ragged groups (`jax.lax.ragged_dot`, which XLA lowers to a Mosaic
-kernel of its own on a TPU), each row weighted by the router on the way,
-and the rows go back to token order, where a token's are summed. Both
+the ragged groups, each row weighted by the router on the way, and the
+rows go back to token order, where a token's are summed. Both
 permutations are gathers, forward and backward (custom VJPs below).
 
-The expert dimension carries the logical axis "expert", which the
-sharding rules map to the mesh `ep` axis; under a mesh the partitioner
-places the grouped matmuls (speed there is not measured yet).
+Which kernel multiplies is `ops/grouped_matmul.py`'s to say, from what
+it can observe; this module calls `grouped_matmul` three times and
+knows nothing of the choice. On a TPU with no multi-device mesh it is
+the Pallas kernels there (`ragged-dot-tiled*` in a profile, tiles
+chosen from the shapes: the one-chip training cell). Everywhere else it
+is `jax.lax.ragged_dot`: on the CPU, and under a mesh, where the expert
+dimension carries the logical axis "expert", which the sharding rules
+map to the mesh `ep` axis, and the partitioner places the grouped
+matmuls (XLA's own 512 x 512 x 512 Mosaic kernel on a TPU; speed under
+a mesh is not measured yet).
 """
 
 from __future__ import annotations
@@ -34,6 +40,7 @@ import jax.numpy as jnp
 from ray_tpu import obs
 from ray_tpu.models import llama
 from ray_tpu.nn.layers import init_dense
+from ray_tpu.ops.grouped_matmul import grouped_matmul
 
 Params = dict[str, Any]
 
@@ -231,15 +238,14 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig) -> tuple[jax.Array, Params]:
             # named for the remat policy (llama._decoder), which knows
             # dot_general's outputs but not a grouped matmul's
             name = jax.ad_checkpoint.checkpoint_name
-            gate = name(
-                jax.lax.ragged_dot(xs, lp["w_gate"].astype(x.dtype), counts), "moe_gate")
-            up = name(jax.lax.ragged_dot(xs, lp["w_up"].astype(x.dtype), counts), "moe_up")
+            gate = name(grouped_matmul(xs, lp["w_gate"].astype(x.dtype), counts), "moe_gate")
+            up = name(grouped_matmul(xs, lp["w_up"].astype(x.dtype), counts), "moe_up")
             # the router's weight goes on BEFORE the down projection (the
             # same sum): the backward then needs no output of `w_down`,
             # so that matmul is not run again to differentiate the weights
             act = (jax.nn.silu(gate) * up).astype(jnp.float32)
             act = (act * _pair_weights(w, order, inv)[:, None]).astype(x.dtype)
-            ys = jax.lax.ragged_dot(act, lp["w_down"].astype(x.dtype), counts)
+            ys = grouped_matmul(act, lp["w_down"].astype(x.dtype), counts)
         with jax.named_scope("moe.combine"):
             out = _to_token_order(ys, order, inv)
     stats = {

@@ -193,10 +193,12 @@ def test_engine_programs_with_pallas_compile_for_v5e(v5e):
         assert "tpu_custom_call" in lowered.compile().as_text()
 
 
-def _train_step_at_mistral_widths(devices, mesh_shape=None, batch=3):
+def _train_step_at_mistral_widths(devices, mesh_shape=None, batch=3, *,
+                                  model="mistral-7b", n_layers=2):
     """(jitted step, abstract state, abstract batch) of a 2-layer
     Mistral-7B-wide train step as chipbench's training cells build it,
-    placed on the described devices: one chip, or a 6-axis mesh."""
+    placed on the described devices: one chip, or a 6-axis mesh. With
+    `model`, another registry entry's, cut to `n_layers`."""
     import dataclasses
 
     import numpy as np
@@ -209,7 +211,7 @@ def _train_step_at_mistral_widths(devices, mesh_shape=None, batch=3):
     from ray_tpu.parallel.sharding import default_rules, tree_shardings
     from ray_tpu.train.step import TrainState, make_train_step
 
-    cfg = dataclasses.replace(get_model_config("mistral-7b"), n_layers=2,
+    cfg = dataclasses.replace(get_model_config(model), n_layers=n_layers,
                               attention_impl="flash")
     opt = optax.adamw(3e-4)
     params = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.key(0)))
@@ -237,7 +239,8 @@ def _train_step_at_mistral_widths(devices, mesh_shape=None, batch=3):
     state = TrainState(params=params, opt_state=opt_state,
                        step=jax.ShapeDtypeStruct((), _I32, sharding=scalar))
     tokens = jax.ShapeDtypeStruct((batch, 4096), _I32, sharding=batch_sharding)
-    step = make_train_step(lambda p, b: llama.loss_fn(p, b, cfg), opt, mesh=mesh, rules=rules)
+    loss = llama.loss_fn if model == "mistral-7b" else llama.loss_and_weight_fn
+    step = make_train_step(lambda p, b: loss(p, b, cfg), opt, mesh=mesh, rules=rules)
     return step, state, {"tokens": tokens, "targets": tokens}
 
 
@@ -314,6 +317,68 @@ def test_dense_train_step_lowers_to_the_text_it_had_before_the_expert_layer(
     assert "tpu_custom_call" in text
     text = re.sub(r'backend_config = "(?:[^"\\]|\\.)*"', 'backend_config = "-"', text)
     assert hashlib.sha256(text.encode()).hexdigest() == _DENSE_STEP[mesh_shape]
+
+
+def test_expert_train_step_runs_nine_tiled_grouped_matmuls(v5e):
+    """The OLMoE step of `olmoe-train` (one layer, batch 6) compiled for
+    the described chip: its grouped matmuls are the kernels of
+    ops/grouped_matmul.py, nine of them (forward, input and weight
+    gradient of gate, up and down: none recomputed under remat), under
+    names a profile's reader classes as the expert layer's
+    (`^kernel:ragged-dot` in chipbench/trace_names), and XLA's own
+    512 x 512 x 512 kernel is gone. One tile schedule a layer and
+    direction, not one a call."""
+    import re
+
+    from ray_tpu import obs
+
+    step, state, batch = _train_step_at_mistral_widths(
+        v5e, batch=6, model="olmoe-1b-7b", n_layers=1)
+    before = obs.layer_counters()
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        compiled = step.lower(state, batch).compile()
+    after = obs.layer_counters()
+    engaged = {name: after.get(name, {"count": 0})["count"]
+               - before.get(name, {"count": 0})["count"]
+               for name in ("grouped_matmul.kernel", "grouped_matmul.ragged_dot")}
+    assert engaged["grouped_matmul.kernel"] > 0 and engaged["grouped_matmul.ragged_dot"] == 0
+    hlo = compiled.as_text()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    grouped = sorted(re.sub(r"\.\d+$", "", k) for k in kernels if k.startswith("ragged-dot"))
+    assert grouped == (["ragged-dot-tiled"] * 3 + ["ragged-dot-tiled-dgrad"] * 3
+                       + ["ragged-dot-tiled-wgrad"] * 3), kernels
+    assert "ragged-dot-none" not in hlo and "ragged-dot-metadata" not in hlo
+    # what is no grouped matmul is flash: forward, and backward
+    assert len(kernels) - len(grouped) == 2, kernels
+    # the schedule's three comparisons of visits with groups: one schedule
+    # forward and one backward, where one a call would be nine
+    assert len(re.findall(r"pred\[447,64\]\S* compare\(", hlo)) <= 2 * 3
+    # 7.37 GiB at the parent: past 8 the compiler rematerialises the head
+    assert compiled.memory_analysis().temp_size_in_bytes < 7.6 * 2 ** 30
+
+
+@pytest.mark.parametrize("P,E,K,N", [
+    (8192, 8, 4096, 14336), (8192, 8, 14336, 4096), (768, 4, 384, 128)],
+    ids=["mixtral_up", "mixtral_down", "rows_in_tiles_of_256"])
+def test_grouped_matmul_kernels_compile_wherever_the_tile_rule_accepts(v5e, P, E, K, N):
+    """The three kernels of ops/grouped_matmul.py at shapes other than
+    the cell's: Mixtral-8x7B's widths, where the contraction or the
+    result's width takes several blocks (the float32 accumulator, the
+    weights read transposed block by block), hold the tile rule's count
+    of VMEM to the chip's compiler: what `pick_tiles` accepts must fit."""
+    from ray_tpu.ops.grouped_matmul import grouped_matmul_pallas, pick_tiles
+
+    bf16 = jnp.bfloat16
+    assert pick_tiles(P, K, N, bf16) and pick_tiles(P, N, K, bf16) \
+        and pick_tiles(P, K, N, bf16, wgrad=True)
+
+    def value_and_grads(lhs, rhs, sizes, ct):
+        out, vjp = jax.vjp(lambda a, b: grouped_matmul_pallas(a, b, sizes), lhs, rhs)
+        return (out,) + vjp(ct)
+
+    hlo = _compile(value_and_grads, ((P, K), bf16), ((E, K, N), bf16), ((E,), jnp.int32),
+                   ((P, N), bf16), sharding=_one_chip(v5e))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
 
 
 def test_chip_smoke_runs_no_phase_without_a_tpu():
