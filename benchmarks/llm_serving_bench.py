@@ -1,12 +1,7 @@
 """LLM serving throughput on the local accelerator.
 
 Continuous-batching decode throughput (tokens/s) for the paged-KV
-engine at a fixed concurrency — the serving-side counterpart of
-bench.py's training MFU. Prints one JSON line. --profile additionally
-runs the engine's roofline-attributed decode profile
-(ray_tpu.profiler) and writes it to benchmarks/PROFILE_decode_r24.json
-— the serving analog of PROFILE_taskplane_r05.md the roadmap lacked.
-(r24 adds the ragged_attention / mixed_step probe rungs to the ladder.)
+engine at a fixed concurrency. Prints one JSON line.
 
 --mixed runs the SPLIT-vs-MIXED dispatch A/B: the same decode-heavy
 workload with long prefills arriving mid-flight is served by a split
@@ -37,9 +32,8 @@ stats from engine.stats(); writes benchmarks/SPEC_decode_r07.json.
 --trace additionally writes the per-REQUEST latency breakdown from the
 ray_tpu.obs flight recorder (queue_wait / prefill / decode-chunk phase
 distributions, TTFT/TPOT/queue/e2e SLO percentiles, span-coverage
-honesty) to benchmarks/TRACE_serving_r08.json — --profile answers
-"what is one step bound by", --trace answers "where did request X's
-wall-clock go".
+honesty) to benchmarks/TRACE_serving_r08.json: where did request X's
+wall-clock go.
 
 --disagg runs the MIXED-LOAD prefill-interference benchmark: a fixed
 decode-heavy workload is timed twice per serving mode — idle, then with
@@ -65,9 +59,6 @@ import json
 import os as _os
 import time
 
-_PROFILE_OUT = _os.path.join(
-    _os.path.dirname(_os.path.abspath(__file__)), "PROFILE_decode_r24.json"
-)
 _MIXED_OUT = _os.path.join(
     _os.path.dirname(_os.path.abspath(__file__)), "MIXED_serving_r24.json"
 )
@@ -125,9 +116,7 @@ def build_trace_report(recorder) -> dict:
     """Per-phase latency breakdown from the flight recorder: where did
     the benchmark's requests spend their wall-clock (queue_wait /
     prefill / decode chunks / spec rounds), per-request SLOs
-    (TTFT/TPOT/queue/e2e distributions), and span-coverage honesty —
-    the --profile report says what one STEP is bound by, this says
-    where each REQUEST's time went."""
+    (TTFT/TPOT/queue/e2e distributions), and span-coverage honesty."""
     phases: dict[str, list] = {}
     slos: dict[str, list] = {}
     coverages = []
@@ -265,14 +254,6 @@ def run_spec_bench(args) -> dict:
             "CPU smoke: vs_baseline wall-clock is dispatch-bound noise; "
             "acceptance stats are the capture's contract"
         )
-    if args.profile:
-        prof = eng.profile_spec_decode(
-            batch_size=min(n_requests, 8), iters=6,
-        )
-        result["spec_profile_segments_ms"] = {
-            s.name: s.ms for s in prof.segments if s.in_step
-        }
-        result["spec_profile_coverage_pct"] = prof.coverage_pct
     _write_capture(args.spec_out, result)
     result["spec_out"] = args.spec_out
     return result
@@ -1343,10 +1324,6 @@ def main():
     import jax
 
     ap = argparse.ArgumentParser()
-    ap.add_argument("--profile", action="store_true",
-                    help="also write the roofline-attributed decode "
-                    "StepProfile (ray_tpu.profiler)")
-    ap.add_argument("--profile-out", default=_PROFILE_OUT)
     ap.add_argument("--spec", action="store_true",
                     help="run the speculative-decoding benchmark "
                     "(spec vs baseline on repetitive prompts) instead")
@@ -1512,22 +1489,6 @@ def main():
                 report["phases_ms"].items(),
                 key=lambda kv: kv[1].get("total", 0.0),
             )[0]
-
-    if args.profile:
-        # steady-state engine, same weights/config: where does one decode
-        # step go, and how far off the HBM roofline is it?
-        prof = engine.profile_decode(
-            batch_size=min(n_requests, 16),
-            context_len=min(prompt_len + max_new, cfg.max_seq - 1),
-            iters=8 if on_tpu else 6,
-        )
-        _write_capture(args.profile_out, prof.to_dict())
-        result["profile_out"] = args.profile_out
-        result["profile_coverage_pct"] = prof.coverage_pct
-        result["profile_top_segment"] = max(
-            (s for s in prof.segments if s.in_step), key=lambda s: s.ms
-        ).name
-        print(prof.to_markdown(), flush=True)
 
     print(json.dumps(result))
 

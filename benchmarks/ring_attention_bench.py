@@ -7,7 +7,7 @@ against the Pallas flash kernel and XLA attention at the same shape.
 The multi-chip overlap question needs real ICI; the CPU-mesh tests
 cover numerics, this covers single-chip kernel viability.
 
-Chained fwd+bwd timing, one fence (see benchmarks/chained_probe.py).
+Chained fwd+bwd timing, one fence.
 Prints one JSON line per (S, impl); writes RINGBENCH json artifact.
 """
 

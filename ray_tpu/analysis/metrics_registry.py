@@ -17,12 +17,11 @@ from __future__ import annotations
 import re
 
 # every module that registers metrics, plus the hook that forces lazy
-# singletons to register (None = import alone registers / no hook)
+# singletons to register
 INSTRUMENTED = [
     ("ray_tpu.obs.slo", "register_all"),
     ("ray_tpu.obs.telemetry", "register_metrics"),
     ("ray_tpu.obs.recorder", "register_metrics"),
-    ("ray_tpu.profiler.trace", None),
     ("ray_tpu.llm.spec.stats", "_spec_metrics"),
     ("ray_tpu.llm.admission", "register_metrics"),
     ("ray_tpu.llm.engine", "register_metrics"),
@@ -36,7 +35,6 @@ INSTRUMENTED = [
     ("ray_tpu.rl.post_train.metrics", "register_metrics"),
     ("ray_tpu.autoscale.metrics", "register_metrics"),
     ("ray_tpu.fleet.metrics", "register_metrics"),
-    ("ray_tpu.obs.perfwatch.metrics", "register_metrics"),
     ("ray_tpu.cluster.lockstats", "register_metrics"),
 ]
 
@@ -53,20 +51,9 @@ def register_instrumented_metrics() -> list[str]:
     problems = []
     for mod_name, hook in INSTRUMENTED:
         try:
-            mod = importlib.import_module(mod_name)
-            if hook is not None:
-                getattr(mod, hook)()
+            getattr(importlib.import_module(mod_name), hook)()
         except Exception as e:  # noqa: BLE001
             problems.append(f"{mod_name}: import/registration failed: {e!r}")
-    # profiler.trace registers via explicit constructors
-    try:
-        from ray_tpu.profiler import trace as ptrace
-
-        ptrace.segment_histogram()
-        ptrace.coverage_gauge()
-        ptrace.step_ms_gauge()
-    except Exception as e:  # noqa: BLE001
-        problems.append(f"ray_tpu.profiler.trace hooks failed: {e!r}")
     return problems
 
 

@@ -92,10 +92,6 @@ SCAN_DIRS = (
     # queues and the canary ladder polls SLO grades; both must park in
     # bounded slices
     "ray_tpu/fleet",
-    # r22: the perfwatch sampler — its probe loop parks between ladder
-    # runs and its stop() joins the thread; both must carry bounds (an
-    # observability plane must never be the thing that hangs shutdown)
-    "ray_tpu/obs/perfwatch",
     # r24: the kernel tier (pure jax/pallas — no parks today, but ops
     # code grows host callbacks and test harnesses; scanning from day
     # one keeps the floor in place) and the mixed-batch planner, which

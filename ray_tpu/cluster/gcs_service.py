@@ -420,7 +420,7 @@ class GcsService:
     _FENCE_READS = frozenset({
         "get_actor", "get_named_actor", "list_actors", "list_nodes",
         "list_pgs", "kv_get", "kv_keys", "kv_wait", "locate_object",
-        "locate_many", "telemetry_slo", "telemetry_perf",
+        "locate_many", "telemetry_slo",
         "kvtier_lookup", "kvtier_stats", "cluster_demand",
         "autoscale_signals",
     })
@@ -1089,12 +1089,6 @@ class GcsService:
 
     def rpc_telemetry_prometheus(self, payload, peer):
         return self.telemetry.prometheus_text()
-
-    def rpc_telemetry_perf(self, payload, peer):
-        """Sampled-profiling rollup (obs.perfwatch): per-step times,
-        coverage, MFU, overlap, regression grades — the dashboard
-        /api/perf surface."""
-        return self.telemetry.perf_health()
 
     def rpc_telemetry_status(self, payload, peer):
         """One-query cluster status (scripts/ray_tpu_status.py): node

@@ -61,7 +61,6 @@ class TaskEventBuffer:
         actor_id=None,
         error: Optional[str] = None,
         worker: str = "",
-        ts: Optional[float] = None,
         trace_id: Optional[str] = None,
         span_id: Optional[str] = None,
     ) -> None:
@@ -74,13 +73,11 @@ class TaskEventBuffer:
             ctx = _trace_context.current()
             if ctx is not None:
                 trace_id, span_id = ctx.trace_id, ctx.span_id
-        # explicit ts: reconstructed spans (profiler segment attribution)
-        # land at their measured offsets instead of the record() call time
         ev = TaskEvent(
             task_id=str(task_id),
             name=name,
             state=state,
-            ts=time.time() if ts is None else ts,
+            ts=time.time(),
             kind=kind,
             actor_id=str(actor_id) if actor_id is not None else None,
             error=error,

@@ -9,13 +9,8 @@ aiohttp server exposing
   /metrics                           — Prometheus text (util/metrics.py)
   /timeline                          — Chrome trace JSON (task events)
   /api/trace[?trace_id=]             — task timeline merged with request
-                                       spans + profiler strips (ray_tpu.obs
-                                       flight recorder is the one bounded
-                                       stream; task-buffer profile copies
-                                       are deduped out)
+                                       spans (ray_tpu.obs flight recorder)
   /api/requests                      — flight-recorder trace listing
-  /api/perf                          — sampled step-profiling rollup
-                                       (obs.perfwatch, cluster view)
   /healthz                           — liveness
 
 A React UI is out of scope; the JSON surface is the contract the
@@ -113,7 +108,7 @@ class Dashboard:
 
         async def api_trace(req):
             """Request spans (ray_tpu.obs flight recorder) merged with the
-            task/profiler timeline as one Chrome trace; ?trace_id= narrows
+            task timeline as one Chrome trace; ?trace_id= narrows
             both halves to one request. The response is BOUNDED
             (?limit=, default 50k events) with an explicit truncated flag
             — a runaway trace can't produce an export that nothing can
@@ -127,14 +122,7 @@ class Dashboard:
             def build():
                 from ray_tpu.obs import get_recorder
 
-                # profiler strips reach BOTH sinks (task buffer for the
-                # legacy /timeline, flight recorder for this route); the
-                # bounded recorder copy is authoritative here, so drop
-                # the task-buffer duplicates instead of double-counting
-                events = [
-                    e for e in state.timeline()
-                    if e.get("cat") != "profile"
-                ]
+                events = state.timeline()
                 if trace_id:
                     events = [
                         e for e in events
@@ -290,14 +278,6 @@ class Dashboard:
                 await offload(lambda: _gcs_call("telemetry_slo"))
             )
 
-        async def api_perf(_req):
-            """Sampled step-profiling rollup (obs.perfwatch): per-step
-            segment times, coverage, MFU, overlap, and regression grades
-            vs the best-seen sample."""
-            return web.json_response(
-                await offload(lambda: _gcs_call("telemetry_perf"))
-            )
-
         async def metrics_cluster(_req):
             """Merged Prometheus exposition: the fleet analog of each
             process's /metrics."""
@@ -316,7 +296,6 @@ class Dashboard:
             app.router.add_get("/api/cluster/timeline", cluster_timeline)
             app.router.add_get("/api/metrics/cluster", api_metrics_cluster)
             app.router.add_get("/api/slo", api_slo)
-            app.router.add_get("/api/perf", api_perf)
             app.router.add_get("/metrics/cluster", metrics_cluster)
         app.router.add_get("/api/tasks", tasks)
         app.router.add_get("/api/actors", actors)

@@ -1596,70 +1596,6 @@ class LLMEngine:
             ),
         }
 
-    def profile_decode(
-        self,
-        *,
-        batch_size: Optional[int] = None,
-        context_len: Optional[int] = None,
-        iters: int = 8,
-        warmup: int = 2,
-        include_prefill: bool = True,
-        export_observability: bool = True,
-    ):
-        """Roofline-attributed StepProfile of one decode step of THIS
-        engine (its weights, block size, attention impl), over a scratch
-        paged cache — live sequences and the real KV cache are untouched.
-
-        Segments: embed / qkv_rope / kv_write / kv_read_attn / block_mlp
-        / lm_head / sampling / host_sync (+ standalone prefill probe).
-        The report is the serving-side counterpart of the train-step
-        profile: it shows how far decode sits from the HBM roofline and
-        which slice to attack first."""
-        from ray_tpu.profiler import profile_decode_step
-
-        c = self.config
-        B = batch_size or min(4, c.max_num_seqs)
-        ctx = context_len or min(32, c.model.max_seq - 1)
-        return profile_decode_step(
-            c.model, self.params,
-            batch_size=B, context_len=ctx, block_size=c.block_size,
-            attn_impl=c.attn_impl, iters=iters, warmup=warmup,
-            include_prefill=include_prefill,
-            export_observability=export_observability,
-            meta={"engine_num_blocks": c.num_blocks,
-                  "engine_decode_chunk": c.decode_chunk},
-        )
-
-    def profile_spec_decode(
-        self,
-        *,
-        batch_size: Optional[int] = None,
-        context_len: Optional[int] = None,
-        iters: int = 6,
-        warmup: int = 2,
-        export_observability: bool = True,
-    ):
-        """Roofline-attributed StepProfile of one SPECULATIVE round of
-        this engine (draft -> verify -> accept -> kv_rollback rungs),
-        over a scratch paged cache + allocator — live state untouched.
-        Requires EngineConfig.spec."""
-        if self.config.spec is None:
-            raise ValueError("EngineConfig.spec is None: spec decoding disabled")
-        from ray_tpu.profiler import profile_spec_decode_step
-
-        c = self.config
-        B = batch_size or min(4, c.max_num_seqs)
-        ctx = context_len or min(
-            32, c.model.max_seq - c.spec.num_draft_tokens - 2
-        )
-        return profile_spec_decode_step(
-            c.model, self.params, c.spec,
-            batch_size=B, context_len=ctx, block_size=c.block_size,
-            iters=iters, warmup=warmup,
-            export_observability=export_observability,
-            meta={"engine_num_blocks": c.num_blocks},
-        )
-
     # -- request tracing (ray_tpu.obs) ---------------------------------------
     # Per-request lifecycle spans into the flight recorder + SLO
     # histograms. Phases tile: queue_wait [arrival/preempt -> prefill

@@ -83,7 +83,7 @@ def cross_entropy_loss(
 # HBM-bound, not MXU-bound: XLA materializes the fp32 logits, the
 # logsumexp intermediates, the take_along_axis gather, and the softmax
 # in the backward — ~79 ms of the 221 ms flagship step at B=8/S=1024/
-# V=32000 (benchmarks/profile_step2.py, round 5) against an ~8 ms MXU
+# V=32000 (round 5) against an ~8 ms MXU
 # floor for the three head matmuls. This custom-VJP version:
 #   * forward: ONE [T, V] fp32 materialization (the matmul output),
 #     read twice (lse, gold-via-iota-compare); no gather;

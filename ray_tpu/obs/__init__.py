@@ -24,7 +24,7 @@ Three pieces:
    the ``scripts/ray_tpu_status.py`` one-query status report.
 
 Instrumented surfaces: ``GET /api/trace`` on the dashboard (request
-spans merged with the task/profiler timeline), ``GET /v1/requests`` +
+spans merged with the task timeline), ``GET /v1/requests`` +
 ``GET /v1/requests/{rid}/trace`` on the OpenAI app, and
 ``llm_serving_bench.py --trace``.
 """

@@ -1,20 +1,10 @@
-"""ray_tpu.obs.perfwatch — continuous performance observability.
+"""ray_tpu.obs.perfwatch — the capture ledger and its regression gates.
 
-Three legs:
-
- * **Capture ledger + regression gates** (ledger.py, migrate.py,
-   ray_tpu/analysis/perf_gate.py): every bench capture carries one
-   additive envelope — schema version, hardware fingerprint, metric
-   dict with tolerance bands — and ``scripts/check_perf.py`` gates
-   fresh captures against the most recent same-fingerprint baseline.
- * **Always-on sampled profiling** (sampler.py, metrics.py): a
-   low-duty-cycle ``PerfSampler`` re-runs the chained-probe ladders on
-   live trainer/engine state and exports ``ray_tpu_perf_*`` telemetry
-   series graded through the SLO machinery.
- * **The roadmap's probes**: the profiler's backward split
-   (ce_bwd / mlp_bwd / attention_bwd) + allreduce-overlap probe live in
-   ray_tpu/profiler/segments.py; GCS lock/RPC histograms in
-   ray_tpu/cluster/lockstats.py.
+ledger.py, migrate.py and ray_tpu/analysis/perf_gate.py: every bench
+capture carries one additive envelope — schema version, hardware
+fingerprint, metric dict with tolerance bands — and
+``scripts/check_perf.py`` gates fresh captures against the most recent
+same-fingerprint baseline.
 """
 
 from __future__ import annotations
@@ -34,7 +24,6 @@ from ray_tpu.obs.perfwatch.ledger import (
     wrap,
     write_capture,
 )
-from ray_tpu.obs.perfwatch.sampler import PerfSampler
 
 __all__ = [
     "CaptureLedger",
@@ -45,7 +34,6 @@ __all__ = [
     "load_capture",
     "metric",
     "payload_of",
-    "PerfSampler",
     "save_capture",
     "validate_envelope",
     "wrap",

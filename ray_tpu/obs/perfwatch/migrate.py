@@ -120,7 +120,7 @@ def derive_metrics(payload: dict) -> dict:
         out["measured_step_ms"] = metric(payload["measured_step_ms"], "ms",
                                          better="lower", rel_tol=REL_TIME)
 
-    # bench.py driver records ({n, cmd, rc, parsed:{...}})
+    # driver records ({n, cmd, rc, parsed:{...}})
     parsed = payload.get("parsed")
     if isinstance(parsed, dict) and isinstance(parsed.get("metric"), str) \
             and isinstance(parsed.get("value"), (int, float)):

@@ -10,7 +10,7 @@ counted, never silent.
 
 Reads: ``get(trace_id)`` raw spans, ``traces()`` the flight-recorder
 listing, ``summary(trace_id)`` e2e + span coverage honesty metrics,
-``chrome_trace()`` Perfetto-ready events merged with the profiler/task
+``chrome_trace()`` Perfetto-ready events merged with the task
 timeline by the dashboard ``/api/trace`` route.
 
 Layer spans (``layer_span``) are the second kind of record: not "what

@@ -64,7 +64,6 @@ AGGREGATED_PREFIXES = (
     "ray_tpu_serve_",
     "ray_tpu_telemetry_",
     "ray_tpu_llm_",
-    "ray_tpu_profiler_",
     "ray_tpu_train_",
     "ray_tpu_fabric_",
     # r19: RL post-training actor/learner plane (rl/post_train) — the
@@ -76,9 +75,6 @@ AGGREGATED_PREFIXES = (
     # r21: multi-tenant model fleet (fleet) — adapter residency churn,
     # canary outcomes, per-tenant routing volume behind `== fleet ==`
     "ray_tpu_fleet_",
-    # r22: perfwatch sampled step profiling (obs.perfwatch) — segment
-    # times, coverage, MFU, overlap, regression ratio behind `== perf ==`
-    "ray_tpu_perf_",
 )
 
 _AGGREGATIONS: dict[str, str] = {}
@@ -1285,83 +1281,11 @@ class TelemetryStore:
                 "ray_tpu_llm_preemptions_total", "reason"),
         }
 
-    # regression-ratio grade ladder (latest/best sampled step time):
-    # ≤ 1.25 green, ≤ 2.5 yellow, beyond red — mirrors the SLO grader's
-    # threshold/yellow_factor shape
-    PERF_REGRESSION_GREEN = 1.25
-    PERF_REGRESSION_YELLOW_FACTOR = 2.0
-
-    def perf_health(self, agg: Optional[dict] = None) -> dict:
-        """Sampled-profiling rollup for `ray_tpu status` (r22): per-step
-        latest step time, coverage, MFU, all-reduce overlap, and the
-        regression ratio vs the best-seen sample — graded GREEN/YELLOW/
-        RED so a slowly-regressing step is a status-line fact, not a
-        future bench surprise. Includes the sampler's own duty receipt.
-        All None/empty when no sampler is reporting."""
-        if agg is None:
-            agg = self.cluster_metrics()
-
-        def gauge_by_step(name):
-            g = agg["gauges"].get(_fq(name))
-            out: dict = {}
-            if g:
-                for skey, v in g["series"].items():
-                    step = self._parse_tags_key(skey).get("step", "")
-                    out[step] = max(out[step], v) if step in out else v
-            return out
-
-        step_ms = gauge_by_step("ray_tpu_perf_step_ms")
-        coverage = gauge_by_step("ray_tpu_perf_coverage_pct")
-        mfu = gauge_by_step("ray_tpu_perf_mfu_pct")
-        overlap = gauge_by_step("ray_tpu_perf_overlap_ratio")
-        ratio = gauge_by_step("ray_tpu_perf_step_regression_ratio")
-        samples: dict = {}
-        c = agg["counters"].get(_fq("ray_tpu_perf_samples_total"))
-        if c:
-            for skey, v in c["series"].items():
-                step = self._parse_tags_key(skey).get("step", "")
-                samples[step] = samples.get(step, 0) + int(v)
-        # worst-segment pointer per step from the merged histograms:
-        # where is the sampled time actually going?
-        top_segment: dict = {}
-        h = agg["histograms"].get(_fq("ray_tpu_perf_segment_ms"))
-        if h:
-            for skey, merged in h["series"].items():
-                tags = self._parse_tags_key(skey)
-                step, seg = tags.get("step", ""), tags.get("segment", "")
-                p95 = merged.get("p95")
-                if p95 is None:
-                    continue
-                cur = top_segment.get(step)
-                if cur is None or p95 > cur[1]:
-                    top_segment[step] = (seg, p95)
-        duty = agg["gauges"].get(_fq("ray_tpu_perf_sampler_duty_pct"))
-        steps: dict = {}
-        for step in sorted(set(step_ms) | set(ratio)):
-            steps[step] = {
-                "step_ms": step_ms.get(step),
-                "coverage_pct": coverage.get(step),
-                "mfu_pct": mfu.get(step),
-                "overlap_ratio": overlap.get(step),
-                "regression_ratio": ratio.get(step),
-                "samples": samples.get(step, 0),
-                "top_segment": top_segment.get(step),
-                "grade": grade_value(
-                    ratio.get(step),
-                    self.PERF_REGRESSION_GREEN,
-                    self.PERF_REGRESSION_YELLOW_FACTOR,
-                ),
-            }
-        return {
-            "steps": steps,
-            "sampler_duty_pct": duty["value"] if duty else None,
-        }
-
     def status_payload(self, thresholds: Optional[SLOThresholds] = None) -> dict:
         """Everything `ray_tpu status` needs beyond the node table — the
         GCS assembles this so the CLI is ONE RPC. The full aggregation
-        pass (every series, under the lock) runs ONCE and feeds all
-        eight views."""
+        pass (every series, under the lock) runs ONCE and feeds every
+        view."""
         agg = self.cluster_metrics()
         return {
             "reporters": agg["reporters"],
@@ -1375,7 +1299,6 @@ class TelemetryStore:
             "rl_post": self.rl_post_health(agg),
             "autoscale": self.autoscale_health(agg),
             "fleet": self.fleet_health(agg),
-            "perf": self.perf_health(agg),
         }
 
 
@@ -1662,41 +1585,6 @@ def format_status(report: dict) -> str:
         if dark:
             line += "  GCS DARK (holding)"
         lines.append(line)
-    perf = report.get("perf") or {}
-    if perf.get("steps"):
-        # the sampled-profiling plane must SHOW here: per-step time,
-        # regression grade vs best-seen, where the time goes, and the
-        # sampler's own overhead receipt
-        duty = perf.get("sampler_duty_pct")
-        lines.append(
-            "== perf (sampled) =="
-            + (f"  duty {duty:.2f}%" if duty is not None else "")
-        )
-        for step in sorted(perf["steps"]):
-            e = perf["steps"][step]
-            sm = e.get("step_ms")
-            cov = e.get("coverage_pct")
-            mfu = e.get("mfu_pct")
-            ov = e.get("overlap_ratio")
-            rr = e.get("regression_ratio")
-            top = e.get("top_segment")
-            line = (
-                f"  {step:<14} {e['grade'].upper():<7} "
-                f"{sm:.2f}ms" if sm is not None
-                else f"  {step:<14} {e['grade'].upper():<7} -"
-            )
-            if rr is not None:
-                line += f" ({rr:.2f}x best)"
-            if cov is not None:
-                line += f"  coverage {cov:.1f}%"
-            if mfu is not None:
-                line += f"  mfu {mfu:.1f}%"
-            if ov is not None:
-                line += f"  overlap {ov:.2f}"
-            if top:
-                line += f"  top {top[0]}={top[1]:g}ms"
-            line += f"  (n={e.get('samples', 0)})"
-            lines.append(line)
     u = report.get("utilization", {})
     occ = u.get("kv_page_occupancy")
     lines.append("== utilization ==")

@@ -44,8 +44,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# v5e-tuned (round-5 sweep, benchmarks/flash_tune.py at B=8/H=16/KVH=8/
-# D=64). Two structural facts drive the defaults:
+# v5e-tuned (round-5 sweep at B=8/H=16/KVH=8/D=64). Two structural facts
+# drive the defaults:
 #  * the FUSED backward (whole kv sequence in one block, nk == 1) beats
 #    the two-kernel path at every sequence length once sub-tiling gives
 #    it back block-causal skipping: S=2048 7.06ms vs 8.74, S=4096

@@ -67,9 +67,8 @@ def ring_attention_spmd(
     perm = [(i, (i - 1) % n) for i in range(n)]
 
     # Per-block compute is the FLASH kernel (ops/flash.py) returning
-    # (o, lse); blocks merge through log-sum-exp. The round-5 chip
-    # measurement of the previous raw-XLA online-softmax body was 17x
-    # slower than flash at S=4096 (benchmarks/RINGBENCH_r05.json) — the
+    # (o, lse); blocks merge through log-sum-exp. A raw-XLA
+    # online-softmax body here was far slower than flash on the chip — the
     # ring's job is rotation + merge, the MXU work belongs in the kernel.
     def flash_block(k_cur, v_cur, seg_cur, *, block_causal: bool):
         kw = {}

@@ -30,9 +30,6 @@ class TrainContext:
     group_name: str = "train"
     stop_event: Optional[threading.Event] = None
     dataset_shards: dict = dataclasses.field(default_factory=dict)
-    # set by JaxTrainer(profile=True): user loops check
-    # session.profiling_enabled() to turn on make_train_step(profile=...)
-    profile: bool = False
 
 
 def _set_session(ctx: TrainContext) -> None:
@@ -65,13 +62,6 @@ def get_trial_dir() -> str:
 def get_checkpoint() -> Optional[Checkpoint]:
     """Checkpoint to resume from (set after a failure restart)."""
     return get_context().latest_checkpoint
-
-
-def profiling_enabled() -> bool:
-    """True when the driving JaxTrainer was built with profile=True —
-    the worker-side signal to build its step via
-    make_train_step(..., profile=True) and publish a StepProfile."""
-    return bool(get_context().profile)
 
 
 def get_dataset_shard(name: str = "train"):

@@ -83,7 +83,6 @@ def make_train_step(
     rules: Optional[ShardingRules] = None,
     batch_axes: tuple = ("batch", "seq"),
     grad_accum: int = 1,
-    profile: bool = False,
 ):
     """Build `step(state, batch) -> (state, metrics)` as one jitted program.
 
@@ -99,12 +98,6 @@ def make_train_step(
     masked batches match the unaccumulated result; scalar-returning loss
     fns get uniform weights (exact only when every microbatch has the same
     number of valid tokens).
-
-    profile=True wraps the jitted step in a ProfiledTrainStep: same
-    call signature, plus ``.profile(state, batch)`` which runs the
-    ray_tpu.profiler ladder (forward / backward / optimizer-update) and
-    returns a roofline-attributed StepProfile, exported to the
-    dashboard metrics + timeline surfaces.
     """
     if mesh is not None and rules is None:
         from ray_tpu.parallel.sharding import default_rules
@@ -181,62 +174,4 @@ def make_train_step(
             metrics["stats"] = stats
         return new_state, metrics
 
-    jitted = jax.jit(step, donate_argnums=(0,))
-    if profile:
-        return ProfiledTrainStep(jitted, step, loss_fn, optimizer, grad_accum)
-    return jitted
-
-
-class ProfiledTrainStep:
-    """A jitted train step plus its measurement hook.
-
-    Calls pass straight through to the compiled program (no per-step
-    fencing — a fence would stall the host-side dispatch queue at every
-    step). ``profile()`` runs the subsystem's chained-probe ladder on
-    the SAME loss/optimizer and publishes the StepProfile to the metrics
-    registry and timeline buffer.
-    """
-
-    def __init__(self, jitted, step_body, loss_fn, optimizer, grad_accum=1):
-        self._jitted = jitted
-        self._step_body = step_body
-        self._loss_fn = loss_fn
-        self._optimizer = optimizer
-        self._grad_accum = grad_accum
-        self.last_profile = None
-
-    def __call__(self, state, batch):
-        return self._jitted(state, batch)
-
-    def profile(
-        self,
-        state: TrainState,
-        batch,
-        *,
-        iters: int = 6,
-        warmup: int = 2,
-        export_observability: bool = True,
-    ):
-        """Roofline-attributed StepProfile of this step on (state, batch).
-
-        Uses the generic forward/backward/optimizer ladder (works for
-        any loss_fn); for the finer llama decomposition use
-        ray_tpu.profiler.profile_train_step directly."""
-        from ray_tpu.profiler import StepProfile, profile_segments
-        from ray_tpu.profiler.segments import generic_train_segments
-
-        parts, whole_fn = generic_train_segments(
-            self._loss_fn, self._optimizer, state, batch,
-            step_body=self._step_body, iters=iters, warmup=warmup,
-        )
-        segments = profile_segments(parts, iters=iters, warmup=warmup)
-        prof = StepProfile.build(
-            "train_step", segments, whole_fn(),
-            meta={"ladder": "generic", "grad_accum": self._grad_accum},
-        )
-        if export_observability:
-            from ray_tpu.profiler import export
-
-            export(prof)
-        self.last_profile = prof
-        return prof
+    return jax.jit(step, donate_argnums=(0,))

@@ -87,8 +87,7 @@ class _QueueProxy:
 class _TrainWorker:
     """One gang member (1 per host). Runs the user loop under a session."""
 
-    def __init__(self, rank: int, world_size: int, trial_dir: str, channel,
-                 profile: bool = False):
+    def __init__(self, rank: int, world_size: int, trial_dir: str, channel):
         proxy = _QueueProxy(channel)
         self.ctx = session_mod.TrainContext(
             world_rank=rank,
@@ -96,7 +95,6 @@ class _TrainWorker:
             trial_dir=trial_dir,
             report_queue=proxy,
             stop_event=proxy,
-            profile=profile,
         )
 
     def reserve_coordinator(self, port=None) -> str:
@@ -174,7 +172,6 @@ class JaxTrainer:
         run_config: Optional[RunConfig] = None,
         datasets: Optional[dict] = None,
         backend_config=None,  # JaxDistributedConfig for multi-host SPMD
-        profile: bool = False,
     ):
         self._fn = train_loop_per_worker
         self._config = train_loop_config or {}
@@ -182,10 +179,6 @@ class JaxTrainer:
         self._run_config = run_config or RunConfig()
         self._datasets = datasets or {}
         self._backend_config = backend_config
-        # profile=True: workers see session.profiling_enabled() and the
-        # controller publishes rank-0 report cadence to the metrics
-        # registry (ray_tpu.profiler observability surfaces)
-        self._profile = profile
 
     # -- controller ----------------------------------------------------------
 
@@ -287,17 +280,6 @@ class JaxTrainer:
             )
         channel = None
         cursor = [0]
-        report_hist = None
-        last_report_t = [None]
-        if self._profile:
-            from ray_tpu.util.metrics import Histogram
-
-            report_hist = Histogram(
-                "train_report_interval_ms",
-                description="profiler: wall time between rank-0 session "
-                "reports (the training loop's step cadence)",
-                boundaries=[1, 5, 10, 50, 100, 500, 1000, 5000, 10000, 60000],
-            )
 
         def drain():
             if channel is None:
@@ -309,14 +291,6 @@ class JaxTrainer:
             cursor[0] += len(reports)
             for rep in reports:
                 if rep["rank"] == 0:
-                    if report_hist is not None:
-                        # worker-side timestamps: intervals reflect the
-                        # loop's real cadence, not drain batching
-                        ts = rep.get("ts")
-                        if ts is not None and last_report_t[0] is not None:
-                            report_hist.observe(1e3 * (ts - last_report_t[0]))
-                        if ts is not None:
-                            last_report_t[0] = ts
                     history.append(rep["metrics"])
                     last_metrics.clear()
                     last_metrics.update(rep["metrics"])
@@ -369,7 +343,7 @@ class JaxTrainer:
                         num_tpus=res.get("TPU", 0.0),
                         resources={k: v for k, v in res.items() if k not in ("CPU", "TPU")},
                         scheduling_strategy=strategy,
-                    ).remote(rank, n, trial_dir, channel, self._profile)
+                    ).remote(rank, n, trial_dir, channel)
                 )
             if bc is not None and getattr(bc, "enabled", False):
                 # gang-wide SPMD bootstrap: rank 0 elects the coordinator,

@@ -1,11 +1,10 @@
 """Opening the JAX backend from an entry point that measures.
 
 A benchmark that wanted the chip must not end on the CPU with exit 0:
-`open_backend()` is the one way bench.py and the device benchmarks
-reach a device. It places the compile cache, opens the backend, and
-refuses anything but a TPU unless the caller's environment asked for
-the CPU by name (`JAX_PLATFORMS` set and naming no TPU — the smoke
-shapes tier-1 runs).
+`open_backend()` is the one way the device benchmarks reach a device.
+It places the compile cache, opens the backend, and refuses anything
+but a TPU unless the caller's environment asked for the CPU by name
+(`JAX_PLATFORMS` set and naming no TPU — the smoke shapes tier-1 runs).
 """
 
 from __future__ import annotations

@@ -2,7 +2,7 @@
 
 A cold chip run is mostly compiling, and the cache key includes the
 cache directory's path, so a directory that moves never hits. Every
-entry point that opens a backend (chip_smoke.py, bench.py, the device
+entry point that opens a backend (chip_smoke.py, the device
 benchmarks, cluster workers) calls `configure_compile_cache()` first,
 and nothing else in the tree sets a cache directory:
 

@@ -13,7 +13,7 @@ Contracts under test:
    decode, with full-accept (oracle drafter), full-reject (garbage
    drafter), and prompt-lookup engines;
  * surfaces — stats()/Prometheus//v1/stats export acceptance rates,
-   bench.py --spec runs under JAX_PLATFORMS=cpu.
+   benchmarks/llm_serving_bench.py --spec runs under JAX_PLATFORMS=cpu.
 """
 
 import dataclasses
@@ -590,37 +590,15 @@ def test_draft_model_self_speculation_identical_and_accepted():
 
 
 # ---------------------------------------------------------------------------
-# profiler ladder + benchmark smoke
+# benchmark smoke
 # ---------------------------------------------------------------------------
-
-
-@pytest.mark.slow
-def test_spec_profiler_ladder():
-    from ray_tpu.profiler import profile_spec_decode_step
-
-    prof = profile_spec_decode_step(
-        FP32_TINY, llama.init_params(FP32_TINY, jax.random.key(0)),
-        SpecConfig(num_draft_tokens=4),
-        batch_size=2, context_len=24, block_size=8, iters=4, warmup=1,
-        export_observability=False,
-    )
-    assert prof.step == "spec_decode_step"
-    names = [s.name for s in prof.segments if s.in_step]
-    assert names == ["draft", "verify", "accept", "kv_rollback"]
-    assert prof.measured_step_ms > 0
-    assert prof.coverage_pct >= 70.0, prof.to_markdown()
-
-
-def test_engine_profile_spec_decode_requires_spec():
-    eng = _engine()
-    with pytest.raises(ValueError, match="spec"):
-        eng.profile_spec_decode()
 
 
 def test_checked_in_spec_capture_meets_acceptance_floor():
     """The acceptance-criteria artifact: the checked-in CPU capture must
     report mean accepted length > 1.5 with greedy spec output token-
-    identical to baseline. Regenerate with `python bench.py --spec`."""
+    identical to baseline. Regenerate with
+    `python benchmarks/llm_serving_bench.py --spec`."""
     path = os.path.join(
         os.path.dirname(os.path.dirname(__file__)),
         "benchmarks", "SPEC_decode_r07.json",
@@ -634,11 +612,11 @@ def test_checked_in_spec_capture_meets_acceptance_floor():
 
 
 def test_bench_spec_smoke_cpu():
-    """bench.py --spec must run end to end under JAX_PLATFORMS=cpu (the
-    benchmark script cannot bit-rot). Train steps trimmed via env to
-    keep the tier-1 lane fast; the acceptance floor asserted here is
-    correspondingly loose — the checked-in capture carries the real
-    one."""
+    """benchmarks/llm_serving_bench.py --spec must run end to end under
+    JAX_PLATFORMS=cpu (the benchmark script cannot bit-rot). Train steps
+    trimmed via env to keep the tier-1 lane fast; the acceptance floor
+    asserted here is correspondingly loose — the checked-in capture
+    carries the real one."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out_path = os.path.join("/tmp", f"spec_smoke_{os.getpid()}.json")
     env = dict(os.environ)
@@ -650,8 +628,9 @@ def test_bench_spec_smoke_cpu():
     })
     try:
         p = subprocess.run(
-            [sys.executable, os.path.join(repo, "bench.py"), "--spec",
-             "--spec-out", out_path],
+            [sys.executable,
+             os.path.join(repo, "benchmarks", "llm_serving_bench.py"),
+             "--spec", "--spec-out", out_path],
             env=env, capture_output=True, text=True, timeout=420,
         )
         assert p.returncode == 0, (p.stdout[-800:], p.stderr[-800:])

@@ -263,6 +263,22 @@ def test_engine_counters_match_a_hand_counted_run():
     assert eng.counters()["prefill_cached_tokens"] == 16
 
 
+@pytest.mark.slow
+def test_engine_counts_decode_chunks_without_a_switch():
+    eng = LLMEngine(
+        EngineConfig(model=llama.LLAMA_TINY, num_blocks=64, decode_chunk=4)
+    )
+    steps_before = obs.layer_counters().get("engine.step", {"count": 0})["count"]
+    out = eng.generate(
+        [[1, 2, 3, 4]], SamplingParams(max_tokens=6, ignore_eos=True)
+    )
+    assert len(out[0]) == 6
+    n = eng.counters()
+    assert n["dispatches"]["pipe_chunk"] >= 1, "no decode chunk was counted"
+    assert n["decode_tokens"] == 5 and n["decode_row_steps"] == 5
+    assert obs.layer_counters()["engine.step"]["count"] > steps_before
+
+
 def test_engine_step_span_names_what_the_step_did():
     eng = _engine()
     eng.add_request([1, 2, 3, 4], SamplingParams(max_tokens=4, **GREEDY))
@@ -387,3 +403,62 @@ def test_counters_and_served_stats_return_while_the_runner_lock_is_held():
         assert stats["runner_lock"]["acquires"] >= 3
     finally:
         server.shutdown()
+
+
+# -- the trainer's start-up (what setup_runtime_s.train reads) ------------------------
+
+
+def test_fit_counts_one_worker_start_per_worker_attempt(tmp_path):
+    import ray_tpu
+    from ray_tpu.core import runtime as rt
+    from ray_tpu.train import FailureConfig, JaxTrainer, RunConfig, ScalingConfig, session
+
+    def loop():
+        # the first attempt's rank 1 dies: the whole gang is started again
+        if session.get_world_rank() == 1 and not (tmp_path / "died").exists():
+            (tmp_path / "died").write_text("x")
+            raise RuntimeError("injected worker failure")
+        session.report({"ok": 1})
+
+    if rt.is_initialized():
+        rt.shutdown_runtime()
+    ray_tpu.init(num_cpus=4)
+    try:
+        before = obs.layer_counters().get("train.worker_start", {"count": 0, "busy_s": 0.0})
+        with obs.capture() as spans:
+            result = JaxTrainer(
+                loop, scaling_config=ScalingConfig(num_workers=2),
+                run_config=RunConfig(name="spans", storage_path=str(tmp_path),
+                                     failure_config=FailureConfig(max_failures=1)),
+            ).fit()
+        assert result.error is None and result.metrics == {"ok": 1}
+        after = obs.layer_counters()["train.worker_start"]
+        assert after["count"] == before["count"] + 4  # 2 workers x 2 attempts
+        assert after["busy_s"] > before["busy_s"]
+        starts = [s for s in spans if s.name == "train.worker_start"]
+        assert sorted(s.attrs["rank"] for s in starts) == [0, 0, 1, 1]
+        # an attempt's span starts when the attempt does, not when fit() did
+        assert len({round(s.start, 6) for s in starts}) == 2
+    finally:
+        rt.shutdown_runtime()
+
+
+def test_init_sharded_params_is_one_span_and_every_leaf_is_born_sharded(cpu_devices):
+    from ray_tpu.parallel.mesh import MeshSpec, make_mesh
+    from ray_tpu.parallel.sharding import default_rules, tree_shardings
+    from ray_tpu.train.step import init_sharded_params
+
+    cfg = llama.LLAMA_TINY
+    mesh, rules = make_mesh(MeshSpec(fsdp=4, tp=2)), default_rules()
+    before = obs.layer_counters().get("train.init_params", {"count": 0})["count"]
+    params = init_sharded_params(
+        lambda: llama.init_params(cfg, jax.random.key(0)), llama.logical_axes(cfg), mesh, rules)
+    assert obs.layer_counters()["train.init_params"]["count"] == before + 1
+    want = tree_shardings(mesh, rules, llama.logical_axes(cfg))
+    leaves, wanted = jax.tree.leaves(params), jax.tree.leaves(want)
+    assert len(leaves) == len(wanted) > 0
+    for leaf, sharding in zip(leaves, wanted):
+        assert leaf.committed and leaf.sharding.is_equivalent_to(sharding, leaf.ndim)
+    # sharded for real, not replicated eight times
+    wq = params["layers"]["wq"]
+    assert wq.addressable_shards[0].data.size * 8 == wq.size

@@ -274,7 +274,7 @@ def test_engine_generate_populates_slo_histograms_and_phases():
     from ray_tpu.util import metrics as metrics_mod
 
     eng = _tiny_engine()
-    eng.model_tag = "tiny-test"
+    eng.model_tag = "tiny-slo-phases"
     sp = SamplingParams(max_tokens=8, temperature=0.0, ignore_eos=True)
     rid = eng.add_request([1, 2, 3, 4], sp)
     req = eng.requests[rid]
@@ -292,10 +292,10 @@ def test_engine_generate_populates_slo_histograms_and_phases():
     assert s["attrs"]["ttft_s"] > 0 and s["attrs"]["e2e_s"] >= s["attrs"]["ttft_s"]
 
     text = metrics_mod.prometheus_text()
-    assert 'ray_tpu_llm_ttft_seconds_count{model="tiny-test"} 1' in text
-    assert 'ray_tpu_llm_tpot_seconds_count{model="tiny-test"} 1' in text
-    assert 'ray_tpu_llm_queue_wait_seconds_count{model="tiny-test"} 1' in text
-    assert 'model="tiny-test",finish_reason="length"' in text  # e2e series
+    assert 'ray_tpu_llm_ttft_seconds_count{model="tiny-slo-phases"} 1' in text
+    assert 'ray_tpu_llm_tpot_seconds_count{model="tiny-slo-phases"} 1' in text
+    assert 'ray_tpu_llm_queue_wait_seconds_count{model="tiny-slo-phases"} 1' in text
+    assert 'model="tiny-slo-phases",finish_reason="length"' in text  # e2e series
 
 
 def test_engine_abort_records_root_span():

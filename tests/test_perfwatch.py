@@ -1,23 +1,17 @@
-"""obs.perfwatch: capture ledger + regression gates, the always-on
-sampler, and the GCS lock histograms.
+"""obs.perfwatch: capture ledger + regression gates.
 
 Covers the r22 acceptance surface that doesn't need a bench run:
 tolerance-band math in both directions, the three gate verdicts
 (pass / record-on-fingerprint-mismatch / record-on-missing-baseline),
 a synthetic regression failing WITH the offending metric named, the
-envelope round-trip of a migrated legacy capture, the repo ledger
-passing run_check (the tier-1 check_perf gate), PerfSampler duty/grade
-accounting on fake profiles, and TimedRLock wait/hold histograms
-(≈0 wait uncontended, visible wait under seeded contention).
+envelope round-trip of a migrated legacy capture, and the repo ledger
+passing run_check (the tier-1 check_perf gate).
 """
 
 from __future__ import annotations
 
 import json
 import os
-import threading
-import time
-from types import SimpleNamespace
 
 import pytest
 
@@ -202,218 +196,3 @@ class TestLedgerRoundTrip:
         # schema-valid, self-consistent under the band math
         problems = run_check(os.path.join(REPO, "benchmarks"))
         assert problems == [], "\n".join(problems)
-
-
-# -- PerfSampler --------------------------------------------------------------
-
-
-def _fake_profile(step, step_ms, *, coverage=95.0, overlap=None):
-    segs = [
-        SimpleNamespace(name="fwd", ms=step_ms * 0.4, in_step=True,
-                        flops=1e6, bound="compute"),
-        SimpleNamespace(name="bwd", ms=step_ms * 0.6, in_step=True,
-                        flops=2e6, bound="compute"),
-        SimpleNamespace(name="calib", ms=1.0, in_step=False,
-                        flops=0.0, bound="memory"),
-    ]
-    return SimpleNamespace(
-        step=step, segments=segs, measured_step_ms=step_ms,
-        coverage_pct=coverage, peak_tflops=0.001,
-        meta={"allreduce_overlap_ratio": overlap},
-    )
-
-
-class TestPerfSampler:
-    def test_duty_budget_math(self):
-        from ray_tpu.obs.perfwatch import PerfSampler
-
-        s = PerfSampler(interval_s=1.0, max_duty=0.01)
-        # a 2s probe must earn a ~198s sleep: 2/(2+198) == max_duty
-        assert s._next_sleep(2.0) == pytest.approx(198.0)
-        # a tiny probe still waits at least interval_s
-        assert s._next_sleep(0.001) == pytest.approx(1.0)
-        with pytest.raises(ValueError):
-            PerfSampler(max_duty=0.0)
-
-    def test_sample_once_exports_and_grades(self):
-        from ray_tpu.obs.perfwatch import PerfSampler
-
-        step = f"fake_{time.monotonic_ns()}"  # unique telemetry series
-        profiles = iter([_fake_profile(step, 10.0, overlap=0.8),
-                         _fake_profile(step, 15.0)])
-        s = PerfSampler(interval_s=60.0)
-        s.register("p", lambda: next(profiles))
-        first = s.sample_once("p")
-        assert first["step_ms"] == 10.0
-        assert first["regression_ratio"] == 1.0
-        assert first["overlap_ratio"] == 0.8
-        assert first["mfu_pct"] is not None and first["mfu_pct"] > 0
-        second = s.sample_once("p")
-        # best-seen stays 10ms; the 15ms sample grades 1.5x
-        assert second["best_ms"] == 10.0
-        assert second["regression_ratio"] == pytest.approx(1.5)
-        assert s.summary()["last"]["p"]["step_ms"] == 15.0
-
-    def test_probe_failure_is_contained(self):
-        from ray_tpu.obs.perfwatch import PerfSampler
-
-        s = PerfSampler()
-        s.register("bad", lambda: (_ for _ in ()).throw(RuntimeError("boom")))
-        assert s.sample_once("bad") is None
-        assert "boom" in s.summary()["errors"]["bad"]
-        with pytest.raises(KeyError):
-            s.sample_once("nope")
-
-    def test_loop_samples_and_summary_never_deadlocks(self):
-        from ray_tpu.obs.perfwatch import PerfSampler
-
-        step = f"loop_{time.monotonic_ns()}"
-        s = PerfSampler(interval_s=0.01, max_duty=1.0)
-        s.register("p", lambda: _fake_profile(step, 5.0))
-        s.start()
-        try:
-            deadline = time.monotonic() + 10.0
-            while time.monotonic() < deadline:
-                if s.summary()["last"]:  # summary() under the live loop
-                    break
-                time.sleep(0.01)
-            assert s.summary()["last"]["p"]["step"] == step
-            assert s.duty_pct() > 0.0
-        finally:
-            s.stop()
-        assert not (s._thread and s._thread.is_alive())
-
-    def test_perf_health_grades_through_telemetry(self):
-        from ray_tpu.obs.perfwatch import PerfSampler
-        from ray_tpu.obs.telemetry import (
-            TelemetryStore,
-            annotated_snapshot,
-            format_status,
-        )
-
-        step = f"health_{time.monotonic_ns()}"
-        profiles = iter([_fake_profile(step, 10.0),
-                         _fake_profile(step, 30.0)])  # 3x best => RED
-        s = PerfSampler()
-        s.register("p", lambda: next(profiles))
-        s.sample_once("p")
-        s.sample_once("p")
-        store = TelemetryStore()
-        store.ingest("test-node", annotated_snapshot())
-        perf = store.perf_health()
-        row = perf["steps"][step]
-        assert row["regression_ratio"] == pytest.approx(3.0)
-        assert row["grade"] == "red"
-        status = format_status({**store.status_payload(), "nodes": []})
-        assert "== perf (sampled) ==" in status
-        assert step in status
-
-
-# -- GCS lock histograms ------------------------------------------------------
-
-
-def _wait_stats(domain):
-    from ray_tpu.cluster.lockstats import lock_wait_histogram
-
-    hist = lock_wait_histogram()
-    data = hist.hist_data().get((domain,))
-    if data is None:
-        return 0, 0.0
-    _, total_ms, count = data
-    return count, total_ms
-
-
-class TestTimedRLock:
-    def test_uncontended_wait_is_near_zero(self):
-        from ray_tpu.cluster import lockstats
-
-        domain = f"test_uncontended_{time.monotonic_ns()}"
-        lk = lockstats.TimedRLock(domain)
-        lockstats.enable_lock_timing(True)
-        try:
-            for _ in range(200):
-                with lk:
-                    pass
-        finally:
-            lockstats.enable_lock_timing(False)
-        count, total_ms = _wait_stats(domain)
-        assert count == 200
-        # free acquires: mean wait well under a millisecond
-        assert total_ms / count < 1.0
-
-    def test_seeded_contention_shows_in_wait(self):
-        from ray_tpu.cluster import lockstats
-
-        domain = f"test_contended_{time.monotonic_ns()}"
-        lk = lockstats.TimedRLock(domain)
-        held = threading.Event()
-        release = threading.Event()
-
-        def holder():
-            with lk:
-                held.set()
-                release.wait(timeout=10.0)
-
-        lockstats.enable_lock_timing(True)
-        try:
-            t = threading.Thread(target=holder, daemon=True)
-            t.start()
-            assert held.wait(timeout=10.0)
-            timer = threading.Timer(0.05, release.set)
-            timer.start()
-            with lk:       # blocks ~50ms on the holder
-                pass
-            t.join(timeout=10.0)
-        finally:
-            lockstats.enable_lock_timing(False)
-        count, total_ms = _wait_stats(domain)
-        assert count >= 2  # holder's free acquire + our blocked one
-        assert total_ms >= 20.0, f"expected a visible blocked wait, got {total_ms}ms"
-
-    def test_reentrant_acquire_counts_once(self):
-        from ray_tpu.cluster import lockstats
-
-        domain = f"test_reentrant_{time.monotonic_ns()}"
-        lk = lockstats.TimedRLock(domain)
-        lockstats.enable_lock_timing(True)
-        try:
-            with lk:
-                with lk:   # reentrant hop: no second wait observation
-                    pass
-        finally:
-            lockstats.enable_lock_timing(False)
-        count, _ = _wait_stats(domain)
-        assert count == 1
-
-    def test_timing_off_is_silent(self):
-        from ray_tpu.cluster import lockstats
-
-        domain = f"test_off_{time.monotonic_ns()}"
-        lk = lockstats.TimedRLock(domain)
-        assert not lockstats.lock_timing_enabled()
-        with lk:
-            pass
-        count, _ = _wait_stats(domain)
-        assert count == 0
-
-    def test_condition_wait_restores_depth_and_times(self):
-        from ray_tpu.cluster import lockstats
-
-        domain = f"test_cond_{time.monotonic_ns()}"
-        lk = lockstats.TimedRLock(domain)
-        cond = threading.Condition(lk)
-        lockstats.enable_lock_timing(True)
-        try:
-            def notifier():
-                with cond:
-                    cond.notify_all()
-
-            with cond:
-                threading.Timer(0.02, notifier).start()
-                assert cond.wait(timeout=5.0)
-                assert lk._is_owned()
-        finally:
-            lockstats.enable_lock_timing(False)
-        count, _ = _wait_stats(domain)
-        # outermost acquire + the re-acquire after wait() (+ notifier)
-        assert count >= 2

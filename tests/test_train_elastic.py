@@ -365,6 +365,27 @@ def test_checkpoint_pruning_pins_restoring(tmp_path):
     assert mgr.latest().load_state()["step"] == 4
 
 
+@pytest.mark.parametrize("kw, accs, evicted, best", [
+    ({"score_attribute": "acc", "score_order": "max"}, [0.5, 0.1, 0.3, 0.4], [1, 2], 0),
+    ({"score_attribute": "acc", "score_order": "min"}, [0.5, 0.1, 0.3, 0.2], [0, 2], 1),
+    ({}, [0.5, 0.1, 0.3, 0.4], [0, 1], None),
+], ids=["max", "min", "no_score_attribute"])
+def test_checkpoint_eviction_by_score(tmp_path, kw, accs, evicted, best):
+    """num_to_keep drops the worst score (with no score_attribute the
+    oldest), never reordering: latest() stays the newest."""
+    mgr = CheckpointManager(str(tmp_path), num_to_keep=2, **kw)
+    ckpts = []
+    for step, acc in enumerate(accs):
+        c = Checkpoint.from_state({"step": step}, mgr.new_checkpoint_dir())
+        mgr.register(c, {"acc": acc})
+        ckpts.append(c)
+        assert mgr.latest() is c
+    assert [i for i, c in enumerate(ckpts) if not os.path.isdir(c.path)] == evicted
+    assert mgr.latest().load_state()["step"] == 3
+    if best is not None:
+        assert mgr.best() is ckpts[best]
+
+
 def test_prune_partial_only_touches_residue(tmp_path):
     root = str(tmp_path)
     good = os.path.join(root, "checkpoint_000000")
