@@ -82,15 +82,22 @@ def cross_entropy_loss(
 # The naive path (forward() -> [T, V] logits -> cross_entropy_loss) is
 # HBM-bound, not MXU-bound: XLA materializes the fp32 logits, the
 # logsumexp intermediates, the take_along_axis gather, and the softmax
-# in the backward — ~79 ms of the 221 ms flagship step at B=8/S=1024/
-# V=32000 (round 5) against an ~8 ms MXU
-# floor for the three head matmuls. This custom-VJP version:
+# in the backward. This custom-VJP version:
 #   * forward: ONE [T, V] fp32 materialization (the matmul output),
 #     read twice (lse, gold-via-iota-compare); no gather;
-#   * backward: recomputes logits (one extra matmul — cheaper than
-#     storing [T, V]), forms d_logits = (softmax - onehot) * coef in
-#     one fused pass in bf16, then the two grad matmuls;
-#   * residuals are h, w, lse, gold — O(T) not O(T*V).
+#   * backward: forms d_logits = (softmax - onehot) * coef in bf16 as the
+#     producer of the two grad matmuls' operand, never in HBM;
+#   * residuals are h, w, targets, lse. The backward WRITES the logits as a
+#     second matmul, but in a compiled step XLA merges it with the
+#     forward's: one fp32 [T, V] buffer (4.6 GiB in `olmoe-train`, 1.5 in
+#     `m7b-train`: what caps their batches) is held from forward to
+#     backward and read by the sum pass and by both gradients.
+# The weight gradient stays XLA's: it fuses that matmul with the
+# optimizer's update of the head into one operation of three [D, V]
+# results and never writes the gradient to HBM (a separate kernel's
+# float32 [D, V] result lives through the whole backward pass). How fast
+# that operation runs hangs on the VMEM the step's compile scopes to one
+# operation (train/step.py; PERF.md, PR 29).
 # The reference delegates this to torch CE inside vLLM/torch workers;
 # the TPU design needs it fused for the same reason flash attention
 # does (HBM bandwidth is the ceiling, SURVEY §5.7).
@@ -130,7 +137,7 @@ def _fused_nll_fwd(h, w, targets):
 
 def _fused_nll_bwd(res, g):  # g: [T] f32 cotangent of nll
     h, w, targets, lse = res
-    logits = _logits_f32(h, w)  # recompute: cheaper than storing [T, V]
+    logits = _logits_f32(h, w)  # the forward's buffer again, once compiled
     V = w.shape[1]
     iota = jax.lax.broadcasted_iota(jnp.int32, (1, V), 1)
     p = jnp.exp(logits - lse[:, None])

@@ -76,6 +76,25 @@ def init_sharded_params(
         return jax.jit(init_fn, out_shardings=shardings)(*args)
 
 
+# VMEM one operation of the compiled step may use, by the chip's kind. XLA's
+# default is 16 MiB of the 128 a v5e core has, and it tiles its matmul fusions
+# to fit: the head's weight gradient, fused with the optimizer's update into
+# one operation of three [D, V] results, ran at 48-52% of the MXU's peak and
+# the MLP's matmuls at 75-81. Not more than this: what no operation claims is
+# where XLA keeps whole arrays, and from 40 MiB the expert layer's 96 MiB token
+# table no longer fits beside it; under a mesh 64 MiB loses outright (PERF.md,
+# PR 29, has the sweep). A kind that is not here keeps the compiler's default.
+_SCOPED_VMEM_KIB = {"TPU v5 lite": 32 * 1024}
+
+
+def _compiler_options(mesh) -> Optional[dict]:
+    if jax.default_backend() != "tpu":
+        return None
+    device = jax.devices()[0] if mesh is None else mesh.devices.flat[0]
+    kib = _SCOPED_VMEM_KIB.get(device.device_kind)
+    return None if kib is None else {"xla_tpu_scoped_vmem_limit_kib": kib}
+
+
 def make_train_step(
     loss_fn: Callable[[Any, Any], jax.Array],
     optimizer: optax.GradientTransformation,
@@ -174,4 +193,4 @@ def make_train_step(
             metrics["stats"] = stats
         return new_state, metrics
 
-    return jax.jit(step, donate_argnums=(0,))
+    return jax.jit(step, donate_argnums=(0,), compiler_options=_compiler_options(mesh))

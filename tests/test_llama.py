@@ -170,6 +170,52 @@ def test_fused_ce_matches_naive():
                                    err_msg=f"{name} mismatch")
 
 
+@pytest.mark.parametrize("tied", [False, True], ids=["own_head", "tied_embedding"])
+@pytest.mark.parametrize("masked", [False, True], ids=["no_mask", "mask_with_zeros"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["fp32", "bf16"])
+@pytest.mark.parametrize("V", [13 * 128, 384, 97], ids=["lanes_of_128", "three_lanes", "no_lane_divides_V"])
+def test_fused_ce_gradients_are_softmax_less_onehot(V, dtype, masked, tied):
+    """The fused loss's VJP against `(softmax - onehot) * coef` written out
+    in float32 on the same (rounded) operands: `dw` within one rounding of
+    each `dl` to `h`'s dtype, which is what the backward feeds its two
+    matmuls as their operand's producer, `dh` within one rounding of its
+    own result; a `mask` with zeros and a tied embedding (`embed.T`, as
+    `llama.output_weight` hands it over) go through the same VJP."""
+    from ray_tpu.nn.layers import fused_cross_entropy_loss
+
+    B, S, D = 2, 64, 128
+    k = jax.random.split(jax.random.key(V), 4)
+    h = jax.random.normal(k[0], (B, S, D), dtype)
+    weight = jax.random.normal(k[1], (V, D) if tied else (D, V), jnp.float32) * 0.1
+    tg = jax.random.randint(k[2], (B, S), 0, V)
+    tg = tg.at[0, :2].set(jnp.array([0, V - 1]))  # the first and the last column
+    mask = (jax.random.uniform(k[3], (B, S)) > 0.3).astype(jnp.float32) if masked else None
+
+    def loss(h, weight):
+        return fused_cross_entropy_loss(h, weight.T if tied else weight, tg, mask)[0]
+
+    l1, (dh1, dw1) = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(h, weight)
+    assert dw1.dtype == jnp.float32 and dw1.shape == weight.shape and dh1.dtype == dtype
+    h2 = h.reshape(B * S, D).astype(jnp.float32)
+    w2 = (weight.T if tied else weight).astype(dtype).astype(jnp.float32)
+    logp = jax.nn.log_softmax(jnp.dot(h2, w2, precision="highest"), axis=-1)
+    coef = (jnp.ones((B, S)) if mask is None else mask).reshape(-1, 1)
+    coef = coef / jnp.maximum(coef.sum(), 1.0)
+    gold = jax.nn.one_hot(tg.reshape(-1), V)
+    np.testing.assert_allclose(l1, -(logp * gold * coef).sum(), rtol=2e-6)
+    dl = (jnp.exp(logp) - gold) * coef
+    eps = float(jnp.finfo(dtype).eps)
+    # |sum_t h dl' - sum_t h dl| <= eps/2 x sum_t |h| |dl|; beside it float32's own
+    # roundings, of `exp` and of a sum of 128 terms
+    bound = (0.5 * eps + 64 * 2 ** -24) * jnp.dot(jnp.abs(h2).T, jnp.abs(dl), precision="highest")
+    dw0 = jnp.dot(h2.T, dl, precision="highest")
+    dw0, bound = (dw0.T, bound.T) if tied else (dw0, bound)
+    assert jnp.all(jnp.abs(dw1 - dw0) <= bound + 1e-9), float(jnp.abs(dw1 - dw0).max())
+    assert float(jnp.abs(dw0).max()) > 50 * float(bound.max())  # a bound that can fail
+    dh0 = jnp.dot(dl, w2.T, precision="highest").reshape(B, S, D)
+    np.testing.assert_allclose(dh1.astype(jnp.float32), dh0, rtol=2 * eps, atol=2 * eps * float(jnp.abs(dh0).max()))
+
+
 def test_sharded_train_step_is_traced_once(cpu_devices):
     """A state placed by init_sharded_params + TrainState.create meets
     the jitted step's cache again on the second call: norm weights whose

@@ -15,6 +15,7 @@ no phase without a TPU, and the compile-cache helper's placement rule.
 
 import json
 import os
+import re
 import subprocess
 import sys
 from unittest import mock
@@ -240,7 +241,11 @@ def _train_step_at_mistral_widths(devices, mesh_shape=None, batch=3, *,
                        step=jax.ShapeDtypeStruct((), _I32, sharding=scalar))
     tokens = jax.ShapeDtypeStruct((batch, 4096), _I32, sharding=batch_sharding)
     loss = llama.loss_fn if model == "mistral-7b" else llama.loss_and_weight_fn
-    step = make_train_step(lambda p, b: loss(p, b, cfg), opt, mesh=mesh, rules=rules)
+    # what the step asks of the backend when it is built (its compile options) is
+    # answered by the described chip, as it would be on one
+    with mock.patch("jax.default_backend", return_value="tpu"), \
+            mock.patch("jax.devices", return_value=list(devices)):
+        step = make_train_step(lambda p, b: loss(p, b, cfg), opt, mesh=mesh, rules=rules)
     return step, state, {"tokens": tokens, "targets": tokens}
 
 
@@ -355,6 +360,60 @@ def test_expert_train_step_runs_nine_tiled_grouped_matmuls(v5e):
     assert len(re.findall(r"pred\[447,64\]\S* compare\(", hlo)) <= 2 * 3
     # 7.37 GiB at the parent: past 8 the compiler rematerialises the head
     assert compiled.memory_analysis().temp_size_in_bytes < 7.6 * 2 ** 30
+
+
+@pytest.mark.parametrize("cell,kwargs,temp_gib,tiles_at_16", [
+    ("m7b-train", dict(batch=3), 11.2, 37144),
+    ("olmoe-train", dict(batch=6, model="olmoe-1b-7b", n_layers=1), 6.9, 30468),
+    ("m7b-train-4chip", dict(mesh_shape=(1, 1, 2, 1, 1, 2), batch=6), 5.4, 10532),
+], ids=["m7b_train", "olmoe_train", "m7b_train_4chip"])
+def test_train_steps_compile_with_the_vmem_their_operations_are_given(
+        v5e, cell, kwargs, temp_gib, tiles_at_16):
+    """train/step.py gives one operation of the step 32 MiB of a v5e core's
+    VMEM where XLA's default is 16, which is what the matmul fusions are
+    tiled for (the head's weight gradient with the optimizer's update in
+    it first of all: 84 x 8 x 13 tiles in `m7b-train`, 84 x 4 x 10 now).
+    Every cell's step (2 layers under the mesh), compiled for the
+    described chip: its matmul fusions are cut into fewer than half the
+    tiles they have at 16 MiB; the temporaries stay where they were
+    (10.98, 6.62 and 5.11 GiB at 16 MiB: past 11.2 `m7b-train`
+    rematerialises); and what the limit is bought with is still there:
+    XLA keeps whole arrays in the VMEM no operation claims, and the expert
+    layer's token gathers read their 96 MiB table [24576, 2048] from it,
+    five times as fast as from HBM. From 40 MiB the table no longer fits
+    and `olmoe-train` loses what its matmuls gain (PERF.md, PR 29)."""
+    import math
+
+    step, state, batch = _train_step_at_mistral_widths(v5e, **kwargs)
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        compiled = step.lower(state, batch).compile()
+    hlo = compiled.as_text()
+    tiles = sum(math.prod(int(n) for n in re.findall(r"\d+", bounds))
+                for bounds in re.findall(
+                    r'kind=k(?:Output|Convolution)[^\n]*"iteration_bounds":\[([^\]]+)\]', hlo))
+    assert 0 < tiles < 0.5 * tiles_at_16
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_gib * 2 ** 30
+    if cell == "olmoe-train":
+        in_vmem = [name for name, body in re.findall(
+            r"^%(fused_computation[.\d]*) \([^\n]*\{\n(.*?)^\}", hlo, re.M | re.S)
+            if re.search(r"= bf16\[24576,2048\]\{[^}]*S\(1\)\} parameter\(0\)", body)
+            and " gather(" in body]
+        assert len(in_vmem) >= 2, in_vmem
+
+
+def test_train_step_asks_for_vmem_only_of_a_chip_it_knows(monkeypatch):
+    """The compile option exists on a TPU only, and the number was measured
+    on a v5e only: on the CPU and on another kind of chip the step is
+    compiled with the compiler's defaults."""
+    from ray_tpu.train import step
+
+    kind = mock.Mock(device_kind="TPU v5 lite")
+    assert step._compiler_options(None) is None  # the CPU's tests
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda: [kind])
+    assert step._compiler_options(None) == {"xla_tpu_scoped_vmem_limit_kib": 32 * 1024}
+    mesh = mock.Mock(devices=mock.Mock(flat=[mock.Mock(device_kind="TPU v4")]))
+    assert step._compiler_options(mesh) is None
 
 
 @pytest.mark.parametrize("P,E,K,N", [
