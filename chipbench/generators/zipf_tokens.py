@@ -9,6 +9,7 @@ vocab_size random numbers per token.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import numpy as np
@@ -27,7 +28,7 @@ def entropy_nats(p: np.ndarray) -> float:
 
 
 def batch_fn(params: dict, vocab_size: int, batch: int, seed: int, sharding=None):
-    """-> jitted f(step) = {"tokens": [B, S], "targets": [B, S]} int32,
+    """-> f(step) = {"tokens": [B, S], "targets": [B, S]} int32 from one jitted program,
     born with `sharding` when one is given."""
     import jax
     import jax.numpy as jnp
@@ -36,15 +37,25 @@ def batch_fn(params: dict, vocab_size: int, batch: int, seed: int, sharding=None
     if seq > params["max_context"]:
         raise ValueError(f"sequence of {seq} tokens is over {params['max_context']}")
     cdf = jnp.asarray(np.cumsum(unigram(vocab_size, params["zipf_s"], seed)), jnp.float32)
-    key = jax.random.key(int(seed) % (2 ** 31))
+    seed32 = jnp.int32(int(seed) % (2 ** 31))
+    out = None
+    if sharding is not None:
+        from jax.sharding import NamedSharding, PartitionSpec
 
-    def make(step):
-        u = jax.random.uniform(jax.random.fold_in(key, step), (batch, seq + 1))
+        out = {"tokens": sharding, "targets": sharding}
+        cdf, seed32 = jax.device_put((cdf, seed32), NamedSharding(sharding.mesh, PartitionSpec()))
+
+    # the seed's table and key are ARGUMENTS of the program, not constants in it: one
+    # program for every seed, so the second run of a checkout loads it from the
+    # persistent cache whatever its seed (as constants, every new seed compiled it anew:
+    # the one `setup_cache_misses.train` of every ledger line before PR 31)
+    def make(cdf, seed32, step):
+        key = jax.random.fold_in(jax.random.key(seed32), step)
+        u = jax.random.uniform(key, (batch, seq + 1))
         ids = jnp.clip(jnp.searchsorted(cdf, u), 0, vocab_size - 1).astype(jnp.int32)
         return {"tokens": ids[:, :-1], "targets": ids[:, 1:]}
 
-    out = None if sharding is None else {"tokens": sharding, "targets": sharding}
-    return jax.jit(make, out_shardings=out)
+    return functools.partial(jax.jit(make, out_shardings=out), cdf, seed32)
 
 
 def expected(params: dict, vocab_size: int, seed: int) -> dict:

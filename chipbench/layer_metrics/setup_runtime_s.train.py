@@ -9,4 +9,4 @@ from chipbench import readers_setup
 
 
 def read(run):
-    return readers_setup.layer_busy_s(("runtime.init", "train.worker_start"))
+    return readers_setup.layer_busy_s(readers_setup.RUNTIME_SPANS)

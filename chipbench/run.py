@@ -8,8 +8,12 @@ first and exits non-zero unless JAX reports a TPU with exactly the
 cell's number of chips (JAX_PLATFORMS is never set here, and nothing
 falls back). Weights and inputs come from --seed. Warm-up of this
 cell's shapes counts as set-up; then the window of --seconds; then the
-correctness check. Earlier lines are free text (each names the
-device); the LAST line of stdout is the one JSON object of the
+correctness check. Every stretch of the start-up is timed by name
+(chipbench/phases.py); setup_s is the process's age at the window less
+the machine's phases (interpreter, third-party imports, the TPU
+client's opening). Earlier lines are free text (each names the
+device; the `setup` line has every phase and each program compiled or
+loaded); the LAST line of stdout is the one JSON object of the
 contract. --trace 0 reports the cell's end-to-end metrics, --trace 1
 its per-layer metrics (read from a short profiler trace, the
 program's counters and its spans) plus device busy/idle and a
@@ -23,21 +27,15 @@ generator and each per-layer reader are files found by name
 from __future__ import annotations
 
 import argparse
+import importlib.util
 import json
 import os
 import sys
 import time
 
+from chipbench import phases
+
 NO_TPU_EXIT = 3
-
-
-def process_age_s() -> float:
-    """Seconds since this process was started (the kernel's clock)."""
-    with open("/proc/self/stat") as f:
-        start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
-    with open("/proc/uptime") as f:
-        uptime = float(f.read().split()[0])
-    return uptime - start_ticks / os.sysconf("SC_CLK_TCK")
 
 
 def log(device: dict, **fields) -> None:
@@ -70,17 +68,19 @@ def open_chip(chips: int, what: str):
     """The chip first: cache placement, then the backend, then nothing
     but a TPU with exactly `chips` chips (exit NO_TPU_EXIT otherwise).
     -> (cache directory, CompileCounter, device record)."""
-    from ray_tpu.utils.compile_cache import configure_compile_cache
-
-    cache_dir = configure_compile_cache()
     import jax
 
+    with phases.phase("import_program"):
+        from ray_tpu.utils.compile_cache import configure_compile_cache
+
+    cache_dir = configure_compile_cache()
     # every program, however quick to compile, comes from the cache in
     # the second run of a checkout (JAX's default skips compiles under 1 s)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     compiles = CompileCounter()
-    devs = jax.devices()
+    with phases.phase("backend"):
+        devs = jax.devices()
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs)}
     if devs[0].platform != "tpu" or len(devs) != chips:
@@ -90,6 +90,7 @@ def open_chip(chips: int, what: str):
 
 
 def main(argv=None) -> int:
+    phases.since_process_start("interp")
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -97,10 +98,24 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), required=True)
     args = ap.parse_args(argv)
 
-    from chipbench import costs, manifest as mf
+    from chipbench import manifest as mf  # the standard library only
 
     root = mf.ROOT
     manifest = mf.load_manifest(root)
+    # what cannot run fails here, before seconds of imports: a cell that is not in the
+    # manifest, a directory that holds the benchmark and no program
+    mf.by_name(manifest["workloads"], args.workload, "workload")
+    if importlib.util.find_spec("ray_tpu") is None:
+        print(f"no program to measure: ray_tpu is not importable from {root}", file=sys.stderr)
+        return 1
+    with phases.phase("import"):
+        # before any import of the program or of a plugin, so that none of those pays for them
+        import jax  # noqa: F401
+        import numpy  # noqa: F401
+        import optax  # noqa: F401
+
+    from chipbench import costs, readers_setup
+
     bad = mf.problems(manifest, root)
     if bad:
         print("BENCHMARK.json is not well-formed:\n  " + "\n  ".join(bad), file=sys.stderr)
@@ -115,16 +130,21 @@ def main(argv=None) -> int:
     ctx = {
         "root": root, "manifest": manifest, "args": args, "device": device,
         "peaks": peaks, "out_dir": out_dir, "compiles": compiles,
-        "process_age_s": process_age_s, "log": lambda **f: log(device, **f),
+        "log": lambda **f: log(device, **f),
         "names": mf.trace_names(root), **cell,
     }
     log(device, event="chip open", cache_dir=cache_dir, workload=args.workload,
         seed=args.seed, seconds=args.seconds, trace=args.trace,
-        age_s=round(process_age_s(), 2))
-    runner = mf.load_plugin(root, "runners", cell["config"]["runner"])
+        age_s=round(phases.process_age_s(), 2))
+    with phases.phase("import_program"):
+        runner = mf.load_plugin(root, "runners", cell["config"]["runner"])
     run = runner.run(ctx)
-
     run["compiles_in_window"] = compiles.between(*run["window_wall"])
+    # every run says where its start-up went (a --trace 0 run reports no per-layer metric)
+    log(device, event="setup", setup_s=run["values"]["setup_s"], phases=run["setup_phases"],
+        runtime_s=readers_setup.layer_busy_s(readers_setup.RUNTIME_SPANS),
+        programs=[(name, round(seconds, 3), how) for _, name, seconds, how
+                  in readers_setup.compiles_before_window(run) or ()])
     log(device, event="window done", attempted=run["attempted"], failed=run["failed"],
         correct=run["correct"], checks=run["checks"], values=run["values"],
         compiles_total=len(compiles.events),

@@ -50,33 +50,40 @@ def train_loop(c: dict) -> None:
     import jax
     import optax
 
-    from chipbench import manifest as mf, tracing
-    from chipbench.run import process_age_s
-    from ray_tpu.models import llama
-    from ray_tpu.train import session
-    from ray_tpu.train.step import TrainState, init_sharded_params, make_train_step
+    from chipbench import manifest as mf, phases, tracing
+
+    with phases.phase("import_program"):
+        from ray_tpu.models import llama
+        from ray_tpu.train import session
+        from ray_tpu.train.step import TrainState, init_sharded_params, make_train_step
 
     config, traffic, seed = c["config"], c["traffic"], c["seed"]
     tcfg = config["train"]
-    builder = mf.load_plugin(c["root"], "model_builders", config["model_builder"])
-    gen = mf.load_plugin(c["root"], "generators", traffic["generator"])
-    cfg, init, axes = builder.build(config, attention_impl=tcfg["attention_impl"])
+    with phases.phase("import_program"):
+        # the builder's build() imports the model modules it needs
+        builder = mf.load_plugin(c["root"], "model_builders", config["model_builder"])
+        gen = mf.load_plugin(c["root"], "generators", traffic["generator"])
+        cfg, init, axes = builder.build(config, attention_impl=tcfg["attention_impl"])
     key = jax.random.key(seed % (2 ** 31))
     mesh = rules = sharding = None
     if config.get("mesh"):
         from jax.sharding import NamedSharding
 
-        from ray_tpu.parallel.mesh import MeshSpec, make_mesh
-        from ray_tpu.parallel.sharding import default_rules
+        with phases.phase("import_program"):
+            from ray_tpu.parallel.mesh import MeshSpec, make_mesh
+            from ray_tpu.parallel.sharding import default_rules
 
         mesh, rules = make_mesh(MeshSpec(**config["mesh"])), default_rules()
         sharding = NamedSharding(mesh, rules.spec(("batch", "seq")))
-        params = init_sharded_params(init, axes, mesh, rules, key)
-    else:
-        params = jax.jit(init)(key)
     opt = optax.adamw(tcfg["lr"])
-    state = TrainState.create(params, opt)
-    del params
+    with phases.phase("init_params"):
+        if mesh is not None:
+            params = init_sharded_params(init, axes, mesh, rules, key)
+        else:
+            params = jax.jit(init)(key)
+        state = TrainState.create(params, opt)
+        del params
+        jax.block_until_ready(state)
     step = make_train_step(lambda p, b: llama.loss_fn(p, b, cfg), opt, mesh=mesh, rules=rules)
     batch_size, seq = tcfg["global_batch"], traffic["seq_len"]
     make = gen.batch_fn(traffic, cfg.vocab_size, batch_size, seed, sharding)
@@ -94,16 +101,19 @@ def train_loop(c: dict) -> None:
     del state
     i = 0
     for _ in range(1 + WARM_STEPS):
-        t = time.monotonic()
-        loss = one(i)
-        session.report({"phase": "warm", "step": i, "loss": loss,
-                        "step_s": time.monotonic() - t})
-        i += 1
-    jax.block_until_ready(state_box[0])
+        # step 0 loads the step from the cache, or compiles it, and runs it first
+        with phases.phase("first_step" if i == 0 else "warm_steps"):
+            t = time.monotonic()
+            loss = one(i)
+            session.report({"phase": "warm", "step": i, "loss": loss,
+                            "step_s": time.monotonic() - t})
+            i += 1
+    with phases.phase("warm_steps"):
+        jax.block_until_ready(state_box[0])
     # ---- the window -------------------------------------------------------
     trace_dir = os.path.join(c["out_dir"], "trace")
     trace_steps = TRACED_STEPS if c["trace"] else None
-    setup_s = process_age_s()
+    setup_s, setup_phases = phases.setup_s(), phases.seconds()
     w0, t0 = time.time(), time.monotonic()
     paused = [0.0]  # seconds the profiler took to start and stop: not the step's
 
@@ -155,7 +165,8 @@ def train_loop(c: dict) -> None:
     # puts its own copy of the parameters on the first chip
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for d in jax.local_devices())
-    session.report({"phase": "done", "setup_s": setup_s, "window_wall": (w0, w1),
+    session.report({"phase": "done", "setup_s": setup_s, "setup_phases": setup_phases,
+                    "window_wall": (w0, w1),
                     "memory_analysis": mem, "tokens_per_step": batch_size * seq,
                     "memory_peak_bytes": peak,
                     "platform": jax.devices()[0].platform})
@@ -165,10 +176,12 @@ def train_loop(c: dict) -> None:
 def run(ctx: dict) -> dict:
     import jax
 
-    import ray_tpu
-    from chipbench import manifest as mf, tracing
+    from chipbench import manifest as mf, phases, tracing
     from chipbench.reference import dense_decoder
-    from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
+
+    with phases.phase("import_program"):
+        import ray_tpu
+        from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
 
     args, config, traffic, chips = ctx["args"], ctx["config"], ctx["traffic"], ctx["chips"]
     ray_tpu.init()
@@ -232,6 +245,7 @@ def run(ctx: dict) -> dict:
         "kind": "train", "correct": all(checks.values()), "checks": checks,
         "attempted": len(steps), "failed": 0,
         "values": {"train_tok_s": train_tok_s, "setup_s": done["setup_s"]},
+        "setup_phases": done["setup_phases"],
         "window_wall": tuple(done["window_wall"]), "seconds": span_s,
         "shape": config, "traffic": traffic, "peaks": ctx["peaks"], "chips": chips,
         "steps": steps, "losses": losses, "tokens_per_step": tokens_per_step,

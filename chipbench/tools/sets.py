@@ -22,11 +22,13 @@ import sys
 import time
 
 
-def one(command: list, workload: str, seed: int, seconds: int, trace: int, root: str) -> dict:
+def one(command: list, workload: str, seed: int, seconds: int, trace: int, root: str,
+        env: dict | None = None) -> dict:
+    """One run of the benchmark's command from the checkout `root` (in `env`, where given)."""
     t = time.monotonic()
     p = subprocess.run(
         command + ["--workload", workload, "--seed", str(seed), "--seconds", str(seconds),
-                   "--trace", str(trace)], cwd=root, capture_output=True, text=True)
+                   "--trace", str(trace)], cwd=root, env=env, capture_output=True, text=True)
     lines = [ln for ln in p.stdout.splitlines() if ln.strip()]
     rec = {"seed": seed, "trace": trace, "rc": p.returncode,
            "wall_s": round(time.monotonic() - t, 1), "result": None}

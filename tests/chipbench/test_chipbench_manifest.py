@@ -46,6 +46,22 @@ def test_layer_metric_moves_a_metric_its_cells_report(name):
     assert set(m.get("workloads", CELLS)) <= set(target.get("workloads", CELLS))
 
 
+STARTUP = ["setup_interp_s.train", "setup_import_s.train", "setup_import_program_s.train",
+           "setup_backend_s.train", "setup_init_params_s.train", "setup_first_step_s.train",
+           "setup_warm_steps_s.train", "setup_unnamed_s.train"]
+
+
+@pytest.mark.parametrize("name", STARTUP)
+def test_startup_phase_metric_moves_setup_s_in_every_training_cell(name):
+    """PR 31: every second of setup_s has a name, in each of the cells."""
+    m = mf.by_name(M["per_layer"], name, "metric")
+    assert (m["moves"], m["unit"], m["better"], m["source"]) == (
+        "setup_s", "s", "lower", "host_clock")
+    assert m["workloads"] == [c for c in CELLS if "train_tok_s" in
+                              [e["name"] for e in mf.metrics_of(M, "end_to_end", c)]]
+    assert m["workloads"] == ["m7b-train", "m7b-train-4chip", "olmoe-train"]
+
+
 def test_every_file_on_disk_is_listed_and_every_listed_name_has_its_file():
     """No reader, cell or configuration waits outside BENCHMARK.json."""
     assert set(LAYER) == set(READERS) and set(CELLS) == set(CELL_FILES)
@@ -90,20 +106,34 @@ def test_cell_and_config_files_set_sizes_and_traffic_only(cell):
     assert not path_options & set(got["cell"])
 
 
-PUBLISHED = {"hidden_size": 4096, "intermediate_size": 14336, "num_attention_heads": 32,
-             "num_key_value_heads": 8, "vocab_size": 32000, "rope_theta": 10000.0,
-             "rms_norm_eps": 1e-5, "sliding_window": 4096, "max_position_embeddings": 32768,
-             "hidden_act": "silu", "tie_word_embeddings": False}
+# what each source publishes, by the file's own `source`: a configuration from another
+# source brings its entry here (test_chipbench_olmoe.py holds OLMoE to the catalog's row too)
+MISTRAL = "https://huggingface.co/mistralai/Mistral-7B-v0.1/blob/main/config.json"
+OLMOE = "https://huggingface.co/allenai/OLMoE-1B-7B-0125-Instruct/blob/main/config.json"
+PUBLISHED = {
+    MISTRAL: {"hidden_size": 4096, "intermediate_size": 14336, "num_attention_heads": 32,
+              "num_key_value_heads": 8, "vocab_size": 32000, "rope_theta": 10000.0,
+              "rms_norm_eps": 1e-5, "sliding_window": 4096, "max_position_embeddings": 32768,
+              "hidden_act": "silu", "tie_word_embeddings": False, "num_hidden_layers": 32},
+    OLMOE: {"hidden_size": 2048, "intermediate_size": 1024, "num_attention_heads": 16,
+            "num_key_value_heads": 16, "vocab_size": 50304, "rope_theta": 10000,
+            "rms_norm_eps": 1e-5, "max_position_embeddings": 4096, "hidden_act": "silu",
+            "tie_word_embeddings": False, "num_experts": 64, "num_experts_per_tok": 8,
+            "norm_topk_prob": False, "num_hidden_layers": 16},
+}
 
 
 @pytest.mark.parametrize("name", CONFIG_FILES)
 def test_config_keeps_every_published_width(name):
     cfg = mf.read_json(mf.ROOT, f"chipbench/configs/{name}.json")
-    assert {k: cfg[k] for k in PUBLISHED} == PUBLISHED
+    published = dict(PUBLISHED[cfg["source"]])
+    depth = published.pop("num_hidden_layers")
+    assert {k: cfg[k] for k in published} == published
     assert ["num_hidden_layers"] == list(cfg["reduced"])
-    assert cfg["published"]["num_hidden_layers"] == 32 > cfg["num_hidden_layers"]
+    assert cfg["published"]["num_hidden_layers"] == depth > cfg["num_hidden_layers"]
     assert len(cfg["source"]) <= 200
-    assert {"sliding_window", "param_dtype", "weights"} <= set(cfg["assumed"])
+    assert {"param_dtype", "weights"} <= set(cfg["assumed"])
+    assert ("sliding_window" in cfg["assumed"]) == ("sliding_window" in published)
 
 
 @pytest.mark.parametrize("name", CONFIGS)
@@ -119,7 +149,9 @@ def test_model_builder_refuses_a_changed_width(name):
     cfg = mf.read_json(mf.ROOT, f"chipbench/configs/{name}.json")
     builder = mf.load_plugin(mf.ROOT, "model_builders", cfg["model_builder"])
     model, _, _ = builder.build(cfg)
-    assert (model.d_model, model.d_ff, model.n_layers) == (4096, 14336, cfg["num_hidden_layers"])
+    assert (model.d_model, model.d_ff, model.n_layers) == (
+        cfg["hidden_size"], cfg["intermediate_size"], cfg["num_hidden_layers"])
+    assert cfg["intermediate_size"] == PUBLISHED[cfg["source"]]["intermediate_size"] != 11008
     with pytest.raises(RuntimeError):
         builder.build({**cfg, "intermediate_size": 11008})
 

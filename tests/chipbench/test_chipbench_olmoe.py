@@ -24,7 +24,11 @@ NEW_METRICS = ("train_mfu_pct.moe", "moe_share_pct", "moe_dispatch_pct",
                "expert_matmul_roofline", "expert_imbalance")
 JOINED = ("compiles_in_window.train", "flash_roofline", "device_idle_pct.train",
           "hbm_peak_gib.train", "setup_compile_s.train", "setup_cache_misses.train",
-          "setup_runtime_s.train", "report_ms.train")
+          "setup_runtime_s.train", "report_ms.train",
+          # PR 31: the start-up by phase
+          "setup_interp_s.train", "setup_import_s.train", "setup_import_program_s.train",
+          "setup_backend_s.train", "setup_init_params_s.train", "setup_first_step_s.train",
+          "setup_warm_steps_s.train", "setup_unnamed_s.train")
 PEAKS = costs.load_peaks("TPU v5 lite")
 
 
@@ -56,15 +60,58 @@ def test_new_metric_is_this_cells_alone_and_moves_train_tok_s(name):
     assert reader(name).read.__module__ and reader(name).__doc__
 
 
-def test_every_published_width_is_the_catalogs():
-    if not os.path.exists(CATALOG):
-        pytest.skip("no catalog in this installation")
-    rows = [json.loads(line) for line in open(CATALOG)]
-    row = next(r for r in rows if r["name"] == "OLMoE-1B-7B-0125-Instruct")
-    assert SHAPE["source"] == row["source_url"]
-    differs = {k for k, v in row["config"].items() if SHAPE.get(k, "absent") != v}
-    assert differs == {"num_hidden_layers"}
-    assert SHAPE["published"]["num_hidden_layers"] == row["config"]["num_hidden_layers"]
+# huggingface.co/allenai/OLMoE-1B-7B-0125-Instruct config.json, as the catalog's row gave it
+# when PR 26 read it (the catalog has since dropped the row: 88 rows, none of that name)
+ROW_OF_PR26 = {
+    "name": "OLMoE-1B-7B-0125-Instruct",
+    "source_url": "https://huggingface.co/allenai/OLMoE-1B-7B-0125-Instruct/blob/main/config.json",
+    "config": {"attention_bias": False, "clip_qkv": None, "hidden_act": "silu",
+               "hidden_size": 2048, "intermediate_size": 1024, "max_position_embeddings": 4096,
+               "model_type": "olmoe", "norm_topk_prob": False, "num_attention_heads": 16,
+               "num_experts": 64, "num_experts_per_tok": 8, "num_hidden_layers": 16,
+               "num_key_value_heads": 16, "rms_norm_eps": 1e-05, "rope_scaling": None,
+               "rope_theta": 10000, "tie_word_embeddings": False, "vocab_size": 50304,
+               "router_aux_loss_coef": 0.01}}
+
+
+def catalog_row(path, name):
+    """The catalog's row of that name, or None: the catalog lies outside the repo and
+    changes under it, and an installation may have none."""
+    if not os.path.exists(path):
+        return None
+    with open(path) as f:
+        rows = [json.loads(line) for line in f if line.strip()]
+    return next((r for r in rows if r["name"] == name), None)
+
+
+def keys_that_differ(row):
+    """Keys of the configuration file that are not what it is held to: the catalog's row
+    where there is one, key by key; without it the file's own `source` (the manifest's)
+    and `published` block."""
+    entry = mf.by_name(M["configs"], "olmoe-1b-7b-train", "config")
+    assert SHAPE["source"] == entry["source"] == (row or ROW_OF_PR26)["source_url"]
+    published = row["config"] if row is not None else {**SHAPE, **SHAPE["published"]}
+    assert SHAPE["published"]["num_hidden_layers"] == published["num_hidden_layers"]
+    return {k for k, v in published.items() if SHAPE.get(k, "absent") != v}
+
+
+@pytest.mark.parametrize("catalog", ["installed", "without_the_row", "with_the_row"])
+def test_every_published_width_is_the_catalogs(catalog, tmp_path):
+    """It neither raises nor skips, with and without the row in the catalog."""
+    path = CATALOG
+    if catalog != "installed":
+        path = str(tmp_path / "architectures.jsonl")
+        rows = [{"name": "another-model", "source_url": "https://example.org", "config": {}}]
+        rows += [ROW_OF_PR26] if catalog == "with_the_row" else []
+        with open(path, "w") as f:
+            f.write("".join(json.dumps(r) + "\n" for r in rows))
+    row = catalog_row(path, ROW_OF_PR26["name"])
+    assert (row is not None) == (catalog == "with_the_row") or catalog == "installed"
+    assert keys_that_differ(row) == {"num_hidden_layers"}
+    assert list(SHAPE["reduced"]) == ["num_hidden_layers"] and SHAPE["num_hidden_layers"] == 1
+    # a row that moves a width is seen, so the comparison is one
+    moved = {**ROW_OF_PR26, "config": {**ROW_OF_PR26["config"], "hidden_size": 4096}}
+    assert keys_that_differ(moved) == {"num_hidden_layers", "hidden_size"}
 
 
 def test_builder_builds_the_registry_model_at_the_files_sizes():

@@ -29,6 +29,21 @@ def test_zipf_token_batches(seed):
     assert jax.devices()[0].platform == "cpu"  # counts only: nothing here is a measurement
 
 
+def test_one_batch_program_for_every_seed():
+    """The seed's table and key are arguments, not constants: the program's text is the
+    same for every seed, so a warm checkout loads it from the persistent cache whatever
+    the seed (PR 31: as constants it was the one program every warm run compiled)."""
+    gen = mf.load_plugin(mf.ROOT, "generators", "zipf_tokens")
+    params = {"seq_len": 64, "max_context": 4096, "zipf_s": 1.1}
+    texts = set()
+    for seed in SEEDS:
+        f = gen.batch_fn(params, 512, 4, seed)
+        texts.add(f.func.lower(*f.args, 0).as_text())
+    assert len(texts) == 1
+    tokens = [np.asarray(gen.batch_fn(params, 512, 4, seed)(0)["tokens"]) for seed in SEEDS[:2]]
+    assert not (tokens[0] == tokens[1]).all()
+
+
 def test_every_traffic_file_names_a_generator_and_stays_inside_the_window_of_the_model():
     import os
 
