@@ -237,7 +237,9 @@ class EngineConfig:
             # dense model with the experts' hyperparameters
             raise ValueError(
                 "LLMEngine serves dense llama-family models; MoE serving "
-                "is not implemented (training-side MoE lives in models/moe.py)"
+                "is not implemented (training-side MoE lives in models/moe.py; "
+                "Mixtral, OLMoE and ZAYA1, whose compressed convolutional attention "
+                "has no cache here either, are training-only)"
             )
         # a prefill bucket longer than the context window can never be
         # used; clamping keeps bucket compilation bounded by the model

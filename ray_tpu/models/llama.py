@@ -113,6 +113,23 @@ def _moe(config: LlamaConfig):
     return moe
 
 
+def _cca(config: LlamaConfig):
+    """models/cca.py when the configuration's attention is compressed
+    convolutional attention (a `ZayaConfig`), else None: the seam at
+    which the block's attention sublayer is chosen, at trace time."""
+    if not hasattr(config, "conv_kernels"):
+        return None
+    from ray_tpu.models import cca
+
+    return cca
+
+
+def _carries_router_state(config: LlamaConfig) -> bool:
+    """An MLP router adds the previous layer's state to its own: the
+    layer scan then carries (hidden state, router state)."""
+    return getattr(config, "router_kind", "linear") == "mlp"
+
+
 def logical_axes(config: LlamaConfig) -> Params:
     """Pytree (parallel to params) of logical-axis tuples."""
     layer = {
@@ -123,7 +140,9 @@ def logical_axes(config: LlamaConfig) -> Params:
         "wo": ("layers", "heads", "embed"),
         "ln2": ("layers", "norm"),
     }
-    moe = _moe(config)
+    moe, cca = _moe(config), _cca(config)
+    if cca is not None:
+        layer = {"ln1": layer["ln1"], "ln2": layer["ln2"], **cca.attention_axes()}
     if moe is None:
         layer.update(
             w_gate=("layers", "embed", "mlp"),
@@ -131,7 +150,7 @@ def logical_axes(config: LlamaConfig) -> Params:
             w_down=("layers", "mlp", "embed"),
         )
     else:
-        layer.update(moe.expert_axes())
+        layer.update(moe.expert_axes(config))
         if config.qk_norm:
             layer.update(q_norm=("layers", "norm"), k_norm=("layers", "norm"))
     axes: Params = {
@@ -167,14 +186,21 @@ def init_params(config: LlamaConfig, key: jax.Array) -> Params:
         if c.qk_norm:
             ffn["q_norm"] = jnp.ones((L, c.n_heads * hd), c.param_dtype)
             ffn["k_norm"] = jnp.ones((L, c.n_kv_heads * hd), c.param_dtype)
-    params: Params = {
-        "embed": init_dense(keys[0], (c.vocab_size, c.d_model), c.param_dtype, scale=1.0),
-        "layers": {
-            "ln1": jnp.ones((L, c.d_model), c.param_dtype),
+    cca = _cca(c)
+    if cca is not None:
+        attn = cca.attention_params(c, keys[1])
+    else:
+        attn = {
             "wq": dense(keys[1], (c.d_model, c.n_heads * hd)),
             "wk": dense(keys[2], (c.d_model, c.n_kv_heads * hd)),
             "wv": dense(keys[3], (c.d_model, c.n_kv_heads * hd)),
             "wo": dense(keys[4], (c.n_heads * hd, c.d_model)),
+        }
+    params: Params = {
+        "embed": init_dense(keys[0], (c.vocab_size, c.d_model), c.param_dtype, scale=1.0),
+        "layers": {
+            "ln1": jnp.ones((L, c.d_model), c.param_dtype),
+            **attn,
             "ln2": jnp.ones((L, c.d_model), c.param_dtype),
             **ffn,
         },
@@ -204,61 +230,73 @@ def packed_positions(segment_ids: Optional[jax.Array], seq_len: int) -> jax.Arra
 
 
 def _block(
-    h: jax.Array,  # [B, S, D]
+    carry,  # h [B, S, D]; (h, router state [B, S, R]) for an MLP router
     lp: Params,  # one layer's params (no leading layer dim)
     *,
     config: LlamaConfig,
-    cos: jax.Array,
-    sin: jax.Array,
+    cos: Optional[jax.Array],
+    sin: Optional[jax.Array],
     positions: jax.Array,
     segment_ids: Optional[jax.Array],
-) -> tuple[jax.Array, Optional[Params]]:
-    """One decoder layer -> (h, the layer's statistics): attention with
-    the q/k RMSNorm when the configuration has it, then the dense SwiGLU
-    or the expert layer (models/moe.py, whose statistics come back; None
-    for a dense layer) by the configuration's own kind."""
+) -> tuple[Any, Optional[Params]]:
+    """One decoder layer -> (carry, the layer's statistics): the
+    attention sublayer of the configuration's kind (full causal GQA with
+    rotary, and the q/k RMSNorm when the configuration has it; or
+    compressed convolutional attention, models/cca.py), then the dense
+    SwiGLU or the expert layer (models/moe.py, whose statistics come
+    back; None for a dense layer) by the configuration's own kind."""
     c = config
-    moe = _moe(c)
+    moe, cca = _moe(c), _cca(c)
+    carries_router = _carries_router_state(c)
+    h, router_state = carry if carries_router else (carry, None)
     B, S, D = h.shape
     hd = c.head_dim
     # Under a mesh with tp > 1 the residual stream h stays sharded over
     # `tp` along the tokens and the four matmul sites gather and scatter
     # it inside themselves (parallel/tp_overlap.py); otherwise, and
-    # always in llama_decode.py, the plain einsums below.
+    # always in llama_decode.py, the plain einsums below. (The rings are
+    # the full attention's and the dense MLP's: CCA's and the expert
+    # layer's matmuls are the partitioner's to place.)
     mesh = current_mesh()
-    overlap = mesh is not None and mesh.shape.get("tp", 1) > 1
+    overlap = cca is None and mesh is not None and mesh.shape.get("tp", 1) > 1
     if overlap:
         from ray_tpu.parallel.tp_overlap import ag_matmul, rs_matmul
 
     x = rms_norm(h, lp["ln1"], c.rms_eps)
-    if overlap:
-        q, k, v = ag_matmul(x, [lp[n].astype(x.dtype) for n in ("wq", "wk", "wv")])
-        q = q.reshape(B, S, c.n_heads, hd)
-        k, v = k.reshape(B, S, c.n_kv_heads, hd), v.reshape(B, S, c.n_kv_heads, hd)
+    if cca is not None:
+        h = h + cca.cca_sublayer(x, lp, c, positions=positions, segment_ids=segment_ids)
     else:
-        q = jnp.einsum("bsd,dh->bsh", x, lp["wq"].astype(x.dtype)).reshape(B, S, c.n_heads, hd)
-        k = jnp.einsum("bsd,dh->bsh", x, lp["wk"].astype(x.dtype)).reshape(B, S, c.n_kv_heads, hd)
-        v = jnp.einsum("bsd,dh->bsh", x, lp["wv"].astype(x.dtype)).reshape(B, S, c.n_kv_heads, hd)
-    if moe is not None and c.qk_norm:  # over the whole projected width, before rotary
-        q = rms_norm(q.reshape(B, S, -1), lp["q_norm"], c.rms_eps).reshape(q.shape)
-        k = rms_norm(k.reshape(B, S, -1), lp["k_norm"], c.rms_eps).reshape(k.shape)
-    q = apply_rope(q, cos, sin, positions)
-    k = apply_rope(k, cos, sin, positions)
-    o = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=c.attention_impl)
-    # named so the "dots" remat policy can SAVE it: the policy recognizes
-    # dot_general outputs but not a pallas_call's, so without the name the
-    # backward pass re-runs the whole flash kernel forward (~25% of a
-    # train step) just to rebuild this tensor
-    o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
-    o, wo = o.reshape(B, S, c.n_heads * hd), lp["wo"].astype(x.dtype)
-    h = h + (rs_matmul(o, wo) if overlap else jnp.einsum("bsh,hd->bsd", o, wo))
+        if overlap:
+            q, k, v = ag_matmul(x, [lp[n].astype(x.dtype) for n in ("wq", "wk", "wv")])
+            q = q.reshape(B, S, c.n_heads, hd)
+            k, v = k.reshape(B, S, c.n_kv_heads, hd), v.reshape(B, S, c.n_kv_heads, hd)
+        else:
+            q = jnp.einsum("bsd,dh->bsh", x, lp["wq"].astype(x.dtype)).reshape(
+                B, S, c.n_heads, hd)
+            k = jnp.einsum("bsd,dh->bsh", x, lp["wk"].astype(x.dtype)).reshape(
+                B, S, c.n_kv_heads, hd)
+            v = jnp.einsum("bsd,dh->bsh", x, lp["wv"].astype(x.dtype)).reshape(
+                B, S, c.n_kv_heads, hd)
+        if moe is not None and c.qk_norm:  # over the whole projected width, before rotary
+            q = rms_norm(q.reshape(B, S, -1), lp["q_norm"], c.rms_eps).reshape(q.shape)
+            k = rms_norm(k.reshape(B, S, -1), lp["k_norm"], c.rms_eps).reshape(k.shape)
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
+        o = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=c.attention_impl)
+        # named so the "dots" remat policy can SAVE it: the policy recognizes
+        # dot_general outputs but not a pallas_call's, so without the name the
+        # backward pass re-runs the whole flash kernel forward (~25% of a
+        # train step) just to rebuild this tensor
+        o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
+        o, wo = o.reshape(B, S, c.n_heads * hd), lp["wo"].astype(x.dtype)
+        h = h + (rs_matmul(o, wo) if overlap else jnp.einsum("bsh,hd->bsd", o, wo))
 
     x = rms_norm(h, lp["ln2"], c.rms_eps)
     if moe is not None:
         # the rings of tp_overlap.py are the dense MLP's: under tp > 1
         # the expert layer's matmuls are the partitioner's to place
-        y, stats = moe.moe_ffn(x, lp, c)
-        return h + y, stats
+        y, stats, router_state = moe.moe_ffn(x, lp, c, router_state)
+        return ((h + y, router_state) if carries_router else h + y), stats
     if not overlap:
         return h + swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"]), None
     # the MLP treats all tokens alike: it can keep the ring's own order
@@ -303,7 +341,9 @@ def _decoder(
         )
     if positions is None:
         positions = packed_positions(segment_ids, S)
-    cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
+    cos = sin = None
+    if _cca(c) is None:  # CCA rotates part of a head, from the positions themselves
+        cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
 
     h = params["embed"].astype(c.dtype)[tokens]  # [B, S, D]
 
@@ -337,8 +377,9 @@ def _decoder(
     mesh = current_mesh()
     pp = mesh.shape.get("pp", 1) if mesh is not None else 1
     # an expert configuration is not pipelined: the stages hand on
-    # activations only, and its router losses and counts would be lost
-    # on the way; its layers run as the one scan below on any mesh
+    # activations only, and its router losses and counts (and an MLP
+    # router's state) would be lost on the way; its layers run as the
+    # one scan below on any mesh
     if pp > 1 and _moe(c) is None:
         # pipeline the layer stack over the mesh `pp` axis (GPipe
         # microbatch schedule inside this jitted program — see
@@ -358,6 +399,10 @@ def _decoder(
         h, stats = pipeline_apply(
             mesh, stage, stack_stages(params["layers"], pp), h, n_micro=pp
         ), None
+    elif _carries_router_state(c):
+        # nothing precedes the first layer's router: a state of zeros adds nothing
+        state = jnp.zeros((B, S, c.router_hidden), jnp.float32)
+        (h, _), stats = jax.lax.scan(block, (h, state), params["layers"])
     else:
         h, stats = jax.lax.scan(block, h, params["layers"])
 

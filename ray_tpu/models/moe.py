@@ -1,4 +1,4 @@
-"""Sparse expert feed-forward layer (Mixtral, OLMoE) for the one decoder.
+"""Sparse expert feed-forward layer (Mixtral, OLMoE, ZAYA1) for the one decoder.
 
 The reference has NO expert parallelism (SURVEY.md §2.4 — absent from
 python/ray/llm); this is a native capability. This module holds what an
@@ -26,12 +26,30 @@ dimension carries the logical axis "expert", which the sharding rules
 map to the mesh `ep` axis, and the partitioner places the grouped
 matmuls (XLA's own 512 x 512 x 512 Mosaic kernel on a TPU; speed under
 a mesh is not measured yet).
+
+Two things a configuration may ask of the layer beside that (ZAYA1,
+models/cca.py, asks both):
+
+  * `router_kind` "mlp" (arXiv:2511.17127): the stream is projected down
+    to `router_hidden`, the PREVIOUS layer's router state is added at a
+    learned scale (so `moe_ffn` takes that state and hands on its own:
+    the layer scan carries it beside the hidden state), and a small MLP
+    gives the logits; the expert is chosen by probability plus a
+    selection bias that is no part of the weight;
+  * `experts_held`: this chip's SHARE of an expert-parallel deployment.
+    The parameters hold experts `first_expert_held ..  + experts_held`
+    only; the router still routes over all `n_experts`; pairs of held
+    experts are sorted first and multiplied, pairs routed elsewhere
+    contribute zero to the output and to every gradient (they are not
+    dropped pairs: another chip computes them) and are counted apart.
+    No code stands in for the absent chips or their exchange.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
+import functools
+from typing import Any, Optional
 
 import jax
 import jax.ad_checkpoint
@@ -39,7 +57,7 @@ import jax.numpy as jnp
 
 from ray_tpu import obs
 from ray_tpu.models import llama
-from ray_tpu.nn.layers import init_dense
+from ray_tpu.nn.layers import init_dense, rms_norm
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 
 Params = dict[str, Any]
@@ -59,6 +77,18 @@ class MoEConfig(llama.LlamaConfig):
     qk_norm: bool = False
     router_aux_coeff: float = 0.01  # load-balancing loss weight
     router_z_coeff: float = 0.0     # router z-loss weight
+    # "linear": one [d_model, n_experts] matrix. "mlp": down to
+    # `router_hidden`, plus the previous layer's state, then an MLP (ZAYA1)
+    router_kind: str = "linear"
+    router_hidden: int = 0
+    # this chip's share: experts first_expert_held .. + experts_held of
+    # n_experts are in the parameters (None: all of them)
+    experts_held: Optional[int] = None
+    first_expert_held: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.n_experts if self.experts_held is None else self.experts_held
 
     def flops_per_token(self, seq_len: int) -> float:
         """Forward FLOPs a token requires: the dense decoder's count
@@ -69,8 +99,9 @@ class MoEConfig(llama.LlamaConfig):
         return super().flops_per_token(seq_len) + self.n_layers * (routed - dense_mlp)
 
     def num_params(self) -> int:
-        d, f, E = self.d_model, self.d_ff, self.n_experts
-        ffn = E * 3 * d * f + d * E  # experts + router
+        d, f, E, r = self.d_model, self.d_ff, self.n_experts, self.router_hidden
+        router = d * E if self.router_kind == "linear" else d * r + 2 * r * r + r * E + 2 * r + E
+        ffn = self.n_held * 3 * d * f + router  # the experts held here + router
         qk = d + self.n_kv_heads * self.head_dim if self.qk_norm else 0
         return super().num_params() + self.n_layers * (ffn + qk - 3 * d * f)
 
@@ -93,13 +124,24 @@ OLMOE_1B_7B = MoEConfig(
 )
 
 
-def expert_axes() -> Params:
+def expert_axes(config: Optional[MoEConfig] = None) -> Params:
     """Logical axes of the leaves `expert_params` makes."""
-    return {
-        "router": ("layers", "embed", "expert"),
+    axes = {
         "w_gate": ("layers", "expert", "embed", "mlp"),
         "w_up": ("layers", "expert", "embed", "mlp"),
         "w_down": ("layers", "expert", "mlp", "embed"),
+    }
+    if config is None or config.router_kind == "linear":
+        return {"router": ("layers", "embed", "expert"), **axes}
+    return {
+        "router_down": ("layers", "embed", None),
+        "router_gamma": ("layers", "norm"),
+        "router_norm": ("layers", "norm"),
+        "router_w1": ("layers", None, None),
+        "router_w2": ("layers", None, None),
+        "router_w3": ("layers", None, "expert"),
+        "router_bias": ("layers", "expert"),
+        **axes,
     }
 
 
@@ -107,15 +149,34 @@ def expert_params(config: MoEConfig, key: jax.Array) -> Params:
     """Router and expert weights of every layer, stacked over layers."""
     c = config
     L, E = c.n_layers, c.n_experts
+    held = slice(c.first_expert_held, c.first_expert_held + c.n_held)
     keys = jax.random.split(key, 4)
 
-    def per_expert(k, shape):  # distinct init per (layer, expert)
+    def per_expert(k, shape):  # distinct init per (layer, expert); a share holds its own experts'
         ks = jax.random.split(k, L * E).reshape(L, E)
+        if c.experts_held is not None:
+            ks = ks[:, held]
         return jax.vmap(jax.vmap(lambda kk: init_dense(kk, shape, c.param_dtype)))(ks)
 
+    def per_layer(k, shape):
+        return jax.vmap(lambda kk: init_dense(kk, shape, c.param_dtype))(jax.random.split(k, L))
+
+    if c.router_kind == "linear":
+        router = {"router": per_layer(keys[0], (c.d_model, E))}
+    else:
+        r = c.router_hidden
+        k_down, k1, k2, k3 = jax.random.split(keys[0], 4)
+        router = {
+            "router_down": per_layer(k_down, (c.d_model, r)),
+            "router_gamma": jnp.ones((L, r), c.param_dtype),
+            "router_norm": jnp.ones((L, r), c.param_dtype),
+            "router_w1": per_layer(k1, (r, r)),
+            "router_w2": per_layer(k2, (r, r)),
+            "router_w3": per_layer(k3, (r, E)),
+            "router_bias": jnp.zeros((L, E), c.param_dtype),
+        }
     return {
-        "router": jax.vmap(lambda kk: init_dense(kk, (c.d_model, E), c.param_dtype))(
-            jax.random.split(keys[0], L)),
+        **router,
         "w_gate": per_expert(keys[1], (c.d_model, c.d_ff)),
         "w_up": per_expert(keys[2], (c.d_model, c.d_ff)),
         "w_down": per_expert(keys[3], (c.d_ff, c.d_model)),
@@ -194,16 +255,41 @@ def _pair_weights_bwd(inv, g):
 _pair_weights.defvjp(_pair_weights_fwd, _pair_weights_bwd)
 
 
-def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig) -> tuple[jax.Array, Params]:
+def _mlp_router_logits(xt, lp: Params, c: MoEConfig, r_prev):
+    """ZAYA1's router (arXiv:2511.17127), all in float32 at `highest`:
+    r = xt W_down + gamma * r_prev (the previous layer's r, nothing
+    before the first layer); logits = W_3 gelu(W_2 gelu(W_1 RMSNorm(r))).
+    -> (logits [N, E], r [N, router_hidden])."""
+    f32 = lambda name: lp[name].astype(jnp.float32)  # noqa: E731
+    dot = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+    r = dot(xt.astype(jnp.float32), f32("router_down"))
+    if r_prev is not None:
+        r = r + f32("router_gamma") * r_prev
+    y = rms_norm(r, f32("router_norm"), c.rms_eps)
+    y = jax.nn.gelu(dot(y, f32("router_w1")), approximate=False)
+    y = jax.nn.gelu(dot(y, f32("router_w2")), approximate=False)
+    return dot(y, f32("router_w3")), r
+
+
+def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig,
+            router_state: Optional[jax.Array] = None) -> tuple[jax.Array, Params, Any]:
     """Dropless top-k expert FFN.
 
-    x [B, S, D] -> (out [B, S, D], statistics of this layer):
-    `tokens_per_expert` int32 [E] (its sum is N * top_k),
-    `dropped_pairs` (pairs that reached no group: 0), `imbalance`
-    (largest over mean of `tokens_per_expert`), `balance_loss`
-    (E * sum_e f_e * P_e with f_e the share of tokens that chose e among
-    their top_k and P_e the mean router probability) and `z_loss`
-    (mean of logsumexp(router logits)^2), both unweighted.
+    x [B, S, D] -> (out [B, S, D], statistics of this layer, the
+    router's state [B, S, router_hidden] for the next layer's
+    `router_state`: None for a linear router). The statistics:
+    `tokens_per_expert` int32 [E] over ALL `n_experts` (its sum is N *
+    top_k); `dropped_pairs`: chosen (token, expert) pairs that the
+    routing lost, counted in no expert's group (0: the layer is
+    dropless); `imbalance` (largest over mean of `tokens_per_expert`);
+    `balance_loss` (E * sum_e f_e * P_e with f_e the share of tokens
+    that chose e among their top_k and P_e the mean router probability)
+    and `z_loss` (mean of logsumexp(router logits)^2), both unweighted.
+    A configuration that holds a share (`experts_held`) adds
+    `pairs_elsewhere`: pairs routed to experts another chip holds, which
+    are counted in `tokens_per_expert`, multiplied by nothing here and
+    contribute zero; N * top_k less it is the rows the grouped matmuls
+    really multiplied.
 
     The router reads the compute-dtype stream but multiplies, takes its
     softmax and chooses in float32 (`highest`: a float32 matmul is one
@@ -212,15 +298,28 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig) -> tuple[jax.Array, Params]:
     B, S, D = x.shape
     E, K = c.n_experts, c.top_k
     N = B * S
+    first, held = c.first_expert_held, c.n_held
+    if not 0 < held <= E - first:
+        raise ValueError(f"experts {first} .. {first + held} held of {E}")
     xt = x.reshape(N, D)
     with obs.layer_span("moe.ffn"):  # counts engaged sites, while tracing
         with jax.named_scope("moe.router"):
-            logits = jnp.einsum(
-                "nd,de->ne", xt.astype(jnp.float32), lp["router"].astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST)
+            if c.router_kind == "mlp":
+                prev = None if router_state is None else router_state.reshape(N, -1)
+                logits, router_state = _mlp_router_logits(xt, lp, c, prev)
+                router_state = router_state.reshape(B, S, -1)
+            else:
+                logits = jnp.einsum(
+                    "nd,de->ne", xt.astype(jnp.float32), lp["router"].astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST)
             lse = jax.nn.logsumexp(logits, axis=-1)
             probs = jnp.exp(logits - lse[:, None])  # [N, E]
-            w, chosen = jax.lax.top_k(probs, K)     # [N, K]
+            if c.router_kind == "mlp":
+                # chosen by probability + bias; weighted by the probability alone
+                _, chosen = jax.lax.top_k(probs + lp["router_bias"].astype(jnp.float32), K)
+                w = jnp.take_along_axis(probs, chosen, axis=-1)
+            else:
+                w, chosen = jax.lax.top_k(probs, K)     # [N, K]
             if c.norm_topk_prob:
                 w = w / w.sum(-1, keepdims=True)
             flat = chosen.reshape(N * K)
@@ -230,6 +329,12 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig) -> tuple[jax.Array, Params]:
             z = jnp.mean(jnp.square(lse))
         with jax.named_scope("moe.dispatch"):
             pairs = jnp.arange(N * K, dtype=jnp.int32)
+            sizes, tail = counts, held < E
+            if tail:
+                # the held experts' pairs first, in the experts' order; the rest
+                # follow the last group, where no tile of a grouped matmul goes
+                flat = (flat - first) % E
+                sizes = counts[first:first + held]
             _, order = jax.lax.sort((flat, pairs), num_keys=1, is_stable=True)
             _, inv = jax.lax.sort((order, pairs), num_keys=1)
             inv = inv.reshape(N, K)
@@ -238,14 +343,15 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig) -> tuple[jax.Array, Params]:
             # named for the remat policy (llama._decoder), which knows
             # dot_general's outputs but not a grouped matmul's
             name = jax.ad_checkpoint.checkpoint_name
-            gate = name(grouped_matmul(xs, lp["w_gate"].astype(x.dtype), counts), "moe_gate")
-            up = name(grouped_matmul(xs, lp["w_up"].astype(x.dtype), counts), "moe_up")
+            gmm = functools.partial(grouped_matmul, group_sizes=sizes, tail=tail)
+            gate = name(gmm(xs, lp["w_gate"].astype(x.dtype)), "moe_gate")
+            up = name(gmm(xs, lp["w_up"].astype(x.dtype)), "moe_up")
             # the router's weight goes on BEFORE the down projection (the
             # same sum): the backward then needs no output of `w_down`,
             # so that matmul is not run again to differentiate the weights
             act = (jax.nn.silu(gate) * up).astype(jnp.float32)
             act = (act * _pair_weights(w, order, inv)[:, None]).astype(x.dtype)
-            ys = grouped_matmul(act, lp["w_down"].astype(x.dtype), counts)
+            ys = gmm(act, lp["w_down"].astype(x.dtype))
         with jax.named_scope("moe.combine"):
             out = _to_token_order(ys, order, inv)
     stats = {
@@ -255,4 +361,6 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig) -> tuple[jax.Array, Params]:
         "balance_loss": balance,
         "z_loss": z,
     }
-    return out.reshape(B, S, D), stats
+    if tail:
+        stats["pairs_elsewhere"] = N * K - sizes.sum()
+    return out.reshape(B, S, D), stats, router_state

@@ -7,13 +7,17 @@ llama-family decoder (models/llama.py — which covers Llama 1/2/3,
 Mistral, Qwen2, TinyLlama, ... since they share the architecture), which
 runs a sparse expert layer in place of its MLP for a MoEConfig
 (models/moe.py — Mixtral; OLMoE with its q/k norm and unnormalised
-top-8 of 64, TRAINING only: the serving engine refuses a MoEConfig).
+top-8 of 64; ZAYA1-8B, `zaya1-8b`, with compressed convolutional
+attention in place of the block's own (models/cca.py), an MLP router
+whose state is carried from layer to layer and top-1 of 16 experts, of
+which a chip may hold a share. Every expert configuration is TRAINING
+only: the serving engine refuses a MoEConfig, ZAYA1 by name).
 The registry gives users the same two entry points they expect:
 
   * `get_model_config("llama3-8b")` — named presets;
   * `config_from_hf(json.load(open("config.json")))` — map a HF
-    transformers config dict onto LlamaConfig/MoEConfig (no downloads;
-    weight conversion is a separate concern).
+    transformers config dict onto LlamaConfig/MoEConfig/ZayaConfig (no
+    downloads; weight conversion is a separate concern).
 """
 
 from __future__ import annotations
@@ -21,7 +25,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
-from ray_tpu.models import llama, moe
+from ray_tpu.models import cca, llama, moe
 
 _REGISTRY: dict[str, Any] = {}
 
@@ -76,6 +80,8 @@ for _name, _cfg in {
     "mixtral-8x7b": moe.MIXTRAL_8X7B,
     "olmoe-1b-7b": moe.OLMOE_1B_7B,
     "moe-tiny": moe.MOE_TINY,
+    "zaya1-8b": cca.ZAYA1_8B,
+    "zaya-tiny": cca.ZAYA_TINY,
 }.items():
     register_model(_name, _cfg)
 
@@ -88,8 +94,43 @@ _HF_LLAMA_ARCHS = {
 _HF_MOE_ARCHS = {"MixtralForCausalLM", "OlmoeForCausalLM"}
 
 
+def _zaya_from_hf(hf: dict, **overrides) -> cca.ZayaConfig:
+    """`model_type` "zaya" (Zyphra/ZAYA1-8B): every layer CCA then the
+    routed experts. What this decoder does not implement is refused by
+    name, not mapped onto something near it."""
+    rope = hf.get("rope_parameters", {}).get("hybrid", {})
+    refused = {
+        "sliding_window": hf.get("sliding_window") is not None,
+        "layer_types other than 'hybrid'": any(
+            t != "hybrid" for t in hf.get("layer_types", ())),
+        "attention_bias": bool(hf.get("attention_bias")),
+        "lm_head_bias": bool(hf.get("lm_head_bias")),
+        f"hidden_act {hf.get('hidden_act')!r}": hf.get("hidden_act", "silu") != "silu",
+        f"rope_type {rope.get('rope_type')!r}": rope.get("rope_type", "default") != "default",
+    }
+    if any(refused.values()):
+        raise ValueError("a zaya config with " + ", ".join(k for k, v in refused.items() if v)
+                         + " is not supported")
+    fields = dict(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"],
+        n_layers=hf["num_hidden_layers"], n_heads=hf["num_attention_heads"],
+        n_kv_heads=hf["num_key_value_heads"], d_ff=hf["moe_intermediate_size"],
+        max_seq=hf["max_position_embeddings"],
+        rope_theta=float(rope.get("rope_theta", 10000.0)),
+        rms_eps=float(hf["rms_norm_eps"]),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        n_experts=hf["num_experts"], top_k=hf["num_experts_per_tok"],
+        router_hidden=hf["router_hidden_size"], latent_head_dim=hf["head_dim"],
+        conv_kernels=(hf["cca_time0"], hf["cca_time1"]),
+        rotary_fraction=float(rope.get("partial_rotary_factor",
+                                       hf.get("partial_rotary_factor", 1.0))),
+    )
+    fields.update(overrides)  # caller wins on collisions
+    return dataclasses.replace(cca.ZAYA1_8B, **fields)
+
+
 def config_from_hf(hf: dict, **overrides):
-    """Map a HF `config.json` dict to a LlamaConfig/MoEConfig.
+    """Map a HF `config.json` dict to a LlamaConfig/MoEConfig/ZayaConfig.
 
     Only architecture hyperparameters travel; framework knobs
     (dtype/remat/attention_impl) keep their TPU defaults unless
@@ -98,8 +139,11 @@ def config_from_hf(hf: dict, **overrides):
     or `model_type` "olmoe" in a dict with no architectures field):
     `intermediate_size` is the width of one expert, q and k are
     normalised, and the top-k weights are renormalised only if
-    `norm_topk_prob` says so.
+    `norm_topk_prob` says so. ZAYA1 (`model_type` "zaya"): see
+    `_zaya_from_hf`.
     """
+    if hf.get("model_type") == "zaya":
+        return _zaya_from_hf(hf, **overrides)
     archs = set(hf.get("architectures", ()))
     is_olmoe = "OlmoeForCausalLM" in archs or (not archs and hf.get("model_type") == "olmoe")
     # the num_local_experts heuristic only applies to config dicts with NO

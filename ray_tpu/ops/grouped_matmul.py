@@ -3,7 +3,12 @@
 
 `grouped_matmul(lhs [P, K], rhs [E, K, N], group_sizes [E]) -> [P, N]`:
 rows `sum(group_sizes[:e]) .. sum(group_sizes[:e + 1])` of `lhs` times
-`rhs[e]`; `group_sizes` sums to P (dropless: every row has a group).
+`rhs[e]`; `group_sizes` sums to P (dropless: every row has a group), or,
+with `tail=True`, to less: the rows past the last group belong to no
+group here (a chip that holds a share of the experts: the pairs routed
+to the others, models/moe.py). No tile of any of the three kernels
+visits them; their output is zero and so are their gradients, as
+`ragged_dot` gives them.
 
 XLA lowers `jax.lax.ragged_dot` on a TPU to a Mosaic kernel of its own,
 tiled 512 x 512 x 512, whatever the shapes: at the expert layer's
@@ -372,19 +377,28 @@ _weight_grad = _named(
 # -- the differentiable op ---------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _grouped_matmul_pallas(lhs, rhs, group_sizes, interpret):
+def _zero_tail(out, group_sizes):
+    """Rows past the last group were written by no tile: zero, not what
+    the buffer held."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (out.shape[0], 1), 0)
+    return jnp.where(rows < group_sizes.sum(), out, jnp.zeros((), out.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _grouped_matmul_pallas(lhs, rhs, group_sizes, interpret, tail):
     P, K = lhs.shape
     tiles = _require_tiles(P, K, rhs.shape[2], lhs.dtype)
-    return _forward(lhs, rhs, *_schedule(group_sizes, P, tiles.tm),
-                    tiles=tiles, interpret=interpret)
+    out = _forward(lhs, rhs, *_schedule(group_sizes, P, tiles.tm),
+                   tiles=tiles, interpret=interpret)
+    return _zero_tail(out, group_sizes) if tail else out
 
 
-def _pallas_fwd(lhs, rhs, group_sizes, interpret):
-    return _grouped_matmul_pallas(lhs, rhs, group_sizes, interpret), (lhs, rhs, group_sizes)
+def _pallas_fwd(lhs, rhs, group_sizes, interpret, tail):
+    out = _grouped_matmul_pallas(lhs, rhs, group_sizes, interpret, tail)
+    return out, (lhs, rhs, group_sizes)
 
 
-def _pallas_bwd(interpret, res, g):
+def _pallas_bwd(interpret, tail, res, g):
     lhs, rhs, group_sizes = res
     (P, K), (E, _, N) = lhs.shape, rhs.shape
     g = g.astype(lhs.dtype)
@@ -392,6 +406,8 @@ def _pallas_bwd(interpret, res, g):
     tiles = _require_tiles(P, N, K, lhs.dtype)
     schedule = _schedule(group_sizes, P, tiles.tm)  # `tm` follows from P alone
     d_lhs = _input_grad(g, rhs, *schedule, tiles=tiles, interpret=interpret)
+    if tail:
+        d_lhs = _zero_tail(d_lhs, group_sizes)
     tiles = _require_tiles(P, K, N, lhs.dtype, wgrad=True)
     d_rhs = _weight_grad(lhs, g, *schedule, tiles=tiles, num_groups=E, interpret=interpret)
     return d_lhs.astype(lhs.dtype), d_rhs.astype(rhs.dtype), None
@@ -409,10 +425,10 @@ def _require_tiles(P, K, N, dtype, *, wgrad=False) -> Tiles:
 
 
 def grouped_matmul_pallas(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
-                          interpret: bool = False) -> jax.Array:
+                          interpret: bool = False, tail: bool = False) -> jax.Array:
     """The kernel path, whatever the backend (`interpret` for the CPU's
     tests). Raises where `pick_tiles` refuses the shapes."""
-    return _grouped_matmul_pallas(lhs, rhs, group_sizes.astype(jnp.int32), interpret)
+    return _grouped_matmul_pallas(lhs, rhs, group_sizes.astype(jnp.int32), interpret, tail)
 
 
 def _kernel_serves(lhs: jax.Array, rhs: jax.Array) -> bool:
@@ -426,12 +442,14 @@ def _kernel_serves(lhs: jax.Array, rhs: jax.Array) -> bool:
                for shape, wgrad in (((P, K, N), False), ((P, N, K), False), ((P, K, N), True)))
 
 
-def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> jax.Array:
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
+                   tail: bool = False) -> jax.Array:
     """lhs [P, K] x rhs [E, K, N] over the row groups `group_sizes` [E]
-    -> [P, N], differentiable in lhs and rhs. One layer span per call
-    site WHILE TRACING says which path it took."""
+    -> [P, N], differentiable in lhs and rhs; `tail`: the groups may
+    end before row P, and the rows after them come out zero. One layer
+    span per call site WHILE TRACING says which path it took."""
     if _kernel_serves(lhs, rhs):
         with obs.layer_span("grouped_matmul.kernel"):
-            return grouped_matmul_pallas(lhs, rhs, group_sizes)
+            return grouped_matmul_pallas(lhs, rhs, group_sizes, tail=tail)
     with obs.layer_span("grouped_matmul.ragged_dot"):
         return jax.lax.ragged_dot(lhs, rhs, group_sizes)

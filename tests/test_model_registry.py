@@ -124,3 +124,69 @@ def test_engine_accepts_model_name():
     out = eng.generate([[5, 6, 7]],
                        SamplingParams(max_tokens=4, ignore_eos=True))[0]
     assert len(out) == 4
+
+
+# Zyphra/ZAYA1-8B config.json as the model-configs catalog's row gives it (PR 32)
+ZAYA1_HF = {
+    "attention_bias": False, "cca_time0": 2, "cca_time1": 2, "head_dim": 128,
+    "hidden_act": "silu", "hidden_size": 2048, "layer_types": ["hybrid"] * 40,
+    "lm_head_bias": False, "max_position_embeddings": 131072, "model_type": "zaya",
+    "moe_intermediate_size": 2048, "num_attention_heads": 8, "num_experts": 16,
+    "num_experts_per_tok": 1, "num_hidden_layers": 40, "num_key_value_heads": 2,
+    "partial_rotary_factor": 0.5, "rms_norm_eps": 1e-05,
+    "rope_parameters": {
+        "hybrid": {"partial_rotary_factor": 0.5, "rope_theta": 5000000, "rope_type": "default"},
+        "hybrid_sliding": {"partial_rotary_factor": 0.5, "rope_theta": 10000,
+                           "rope_type": "default"},
+        "rope_type": "default"},
+    "router_hidden_size": 256, "sliding_window": None, "tie_word_embeddings": True,
+    "vocab_size": 262272,
+}
+
+
+def test_hf_zaya_mapping_of_the_catalogs_config():
+    from ray_tpu.models import cca
+
+    cfg = config_from_hf(ZAYA1_HF)
+    assert isinstance(cfg, cca.ZayaConfig) and cfg == get_model_config("zaya1-8b")
+    assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (2048, 8, 2, 128)
+    assert (cfg.n_experts, cfg.top_k, cfg.d_ff, cfg.router_kind, cfg.router_hidden) == (
+        16, 1, 2048, "mlp", 256)
+    assert cfg.conv_kernels == (2, 2) and cfg.rotary_fraction == 0.5 and cfg.rope_theta == 5e6
+    assert cfg.tie_embeddings and cfg.n_layers == 40 and cfg.n_held == 16
+    assert not cfg.norm_topk_prob and cfg.router_aux_coeff == 0.0 == cfg.router_z_coeff
+    # 8.3B without the table, 0.54B in it; 2.9 GFLOP a token forward, the head 36.8% of it
+    assert cfg.num_params() == pytest.approx(8.84e9, rel=2e-3)
+    assert 2 * 2048 * 262272 / cfg.flops_per_token(4096) == pytest.approx(0.368, abs=0.003)
+    share = config_from_hf(ZAYA1_HF, n_layers=6, vocab_size=32896, experts_held=8)
+    assert share.num_params() == pytest.approx(708.8e6, rel=1e-3) and share.n_held == 8
+
+
+@pytest.mark.parametrize("key,value,named", [
+    ("sliding_window", 4096, "sliding_window"),
+    ("layer_types", ["hybrid", "hybrid_sliding"], "layer_types"),
+    ("attention_bias", True, "attention_bias"), ("lm_head_bias", True, "lm_head_bias"),
+    ("hidden_act", "gelu", "hidden_act 'gelu'")])
+def test_hf_zaya_refuses_by_name_what_it_does_not_implement(key, value, named):
+    with pytest.raises(ValueError, match=named):
+        config_from_hf({**ZAYA1_HF, key: value})
+
+
+def test_engine_refuses_zaya_by_name():
+    from ray_tpu.llm.engine import EngineConfig
+
+    with pytest.raises(ValueError, match="ZAYA1"):
+        EngineConfig(model="zaya-tiny")
+
+
+def test_zaya_tiny_runs_forward_and_counts_its_sites():
+    from ray_tpu import obs
+
+    cfg = get_model_config("zaya-tiny")
+    params = llama.init_params(cfg, jax.random.key(0))
+    before = obs.layer_counters()
+    logits = llama.forward(params, jnp.zeros((1, 8), jnp.int32), cfg)
+    after = obs.layer_counters()
+    assert logits.shape == (1, 8, 512)
+    for name in ("cca.attn", "moe.ffn"):  # one site a scanned block, while tracing
+        assert after[name]["count"] - before.get(name, {"count": 0})["count"] >= 1

@@ -89,7 +89,7 @@ def test_moe_ffn_matches_naive_topk(norm_topk_prob):
     lp = jax.tree.map(lambda x: x[0], params["layers"])
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(2, 5, cfg.d_model)), jnp.float32)
-    out, stats = moe.moe_ffn(x, lp, cfg)
+    out, stats, _ = moe.moe_ffn(x, lp, cfg)
     np.testing.assert_allclose(
         np.asarray(out), _naive_moe(x, lp, cfg), rtol=1e-4, atol=1e-4
     )
@@ -110,7 +110,7 @@ def test_moe_is_dropless_when_every_token_meets_the_same_experts():
     lp["router"] = jnp.asarray(router)
     x = jnp.asarray(np.abs(np.random.default_rng(1).normal(size=(2, 16, cfg.d_model))),
                     jnp.float32)
-    out, stats = moe.moe_ffn(x, lp, cfg)
+    out, stats, _ = moe.moe_ffn(x, lp, cfg)
     assert stats["tokens_per_expert"].tolist() == [32, 32, 0, 0]
     assert int(stats["dropped_pairs"]) == 0
     assert float(stats["imbalance"]) == cfg.n_experts / cfg.top_k

@@ -195,7 +195,7 @@ def test_engine_programs_with_pallas_compile_for_v5e(v5e):
 
 
 def _train_step_at_mistral_widths(devices, mesh_shape=None, batch=3, *,
-                                  model="mistral-7b", n_layers=2):
+                                  model="mistral-7b", n_layers=2, **overrides):
     """(jitted step, abstract state, abstract batch) of a 2-layer
     Mistral-7B-wide train step as chipbench's training cells build it,
     placed on the described devices: one chip, or a 6-axis mesh. With
@@ -213,7 +213,7 @@ def _train_step_at_mistral_widths(devices, mesh_shape=None, batch=3, *,
     from ray_tpu.train.step import TrainState, make_train_step
 
     cfg = dataclasses.replace(get_model_config(model), n_layers=n_layers,
-                              attention_impl="flash")
+                              attention_impl="flash", **overrides)
     opt = optax.adamw(3e-4)
     params = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.key(0)))
     mesh = rules = None
@@ -360,6 +360,44 @@ def test_expert_train_step_runs_nine_tiled_grouped_matmuls(v5e):
     assert len(re.findall(r"pred\[447,64\]\S* compare\(", hlo)) <= 2 * 3
     # 7.37 GiB at the parent: past 8 the compiler rematerialises the head
     assert compiled.memory_analysis().temp_size_in_bytes < 7.6 * 2 ** 30
+
+
+def test_zaya_share_train_step_runs_its_kernels_and_skips_the_rows_elsewhere(v5e):
+    """ZAYA1-8B as `zaya1-train` builds it (8 of 16 experts held, an
+    eighth of the vocabulary; ONE layer and one sequence here, the
+    cell's six and its batch are rehearsed in PERF.md), compiled for
+    the described chip: CCA's attention is the two flash kernels, the
+    held experts' nine grouped matmuls are the kernels of
+    ops/grouped_matmul.py with a group's whole [2048, 2048] weight
+    matrix as one block, XLA's own ragged-dot kernel is not there, and
+    both new sublayers count their sites."""
+    from ray_tpu import obs
+
+    step, state, batch = _train_step_at_mistral_widths(
+        v5e, batch=1, model="zaya1-8b", n_layers=1, vocab_size=32896, experts_held=8)
+    before = obs.layer_counters()
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        compiled = step.lower(state, batch).compile()
+    after = obs.layer_counters()
+    engaged = {name: after.get(name, {"count": 0})["count"]
+               - before.get(name, {"count": 0})["count"]
+               for name in ("cca.attn", "moe.ffn", "grouped_matmul.kernel",
+                            "grouped_matmul.ragged_dot")}
+    assert engaged["cca.attn"] > 0 and engaged["moe.ffn"] > 0
+    assert engaged["grouped_matmul.kernel"] > 0 and engaged["grouped_matmul.ragged_dot"] == 0
+    hlo = compiled.as_text()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    grouped = sorted(re.sub(r"\.\d+$", "", k) for k in kernels if k.startswith("ragged-dot"))
+    assert grouped == (["ragged-dot-tiled"] * 3 + ["ragged-dot-tiled-dgrad"] * 3
+                       + ["ragged-dot-tiled-wgrad"] * 3), kernels
+    assert "ragged-dot-none" not in hlo
+    # what is no grouped matmul is flash, named after the scope it is called in
+    rest = [k for k in kernels if not k.startswith("ragged-dot")]
+    assert len(rest) == 2 and all(k.startswith("cca.attend") for k in rest), kernels
+    # the router's state leaves the forward scan beside the hidden state
+    assert re.search(r"f32\[1,4096,256\]", hlo)
+    # 8 held experts' weights and no more: [1, 8, 2048, 2048], never 16
+    assert "8,2048,2048]" in hlo and "16,2048,2048]" not in hlo
 
 
 @pytest.mark.parametrize("cell,kwargs,temp_gib,tiles_at_16", [

@@ -1,0 +1,345 @@
+"""ZAYA1 through the one decoder (PR 32), at a small size on the CPU,
+seeded weights, against the plain reference
+(chipbench/reference/zaya_decoder.py, imported): the CCA sublayer
+alone, the router's carried state, the whole train path in loss and
+gradients, causality through both convolutions and the value shift
+(and across a document boundary), the share of experts that adds up,
+and the grouped matmul whose trailing rows no tile visits."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench.reference import zaya_decoder
+from ray_tpu.models import cca, llama, moe
+from ray_tpu.nn.layers import rms_norm
+from ray_tpu.ops import grouped_matmul as gm
+
+FP32 = dataclasses.replace(cca.ZAYA_TINY, dtype=jnp.float32)
+B, S = 2, 24
+
+
+def shape_of(cfg) -> dict:
+    """A ZayaConfig as the configuration file's dict (HF key names)."""
+    return {
+        "hidden_size": cfg.d_model, "num_attention_heads": cfg.n_heads,
+        "num_key_value_heads": cfg.n_kv_heads, "head_dim": cfg.head_dim,
+        "cca_time0": cfg.conv_kernels[0], "cca_time1": cfg.conv_kernels[1],
+        "partial_rotary_factor": cfg.rotary_fraction,
+        "rope_parameters": {"hybrid": {"rope_theta": cfg.rope_theta}},
+        "rms_norm_eps": cfg.rms_eps, "router_hidden_size": cfg.router_hidden,
+        "num_experts": cfg.n_held, "published": {"num_experts": cfg.n_experts},
+        "deployment": {"first_expert_held": cfg.first_expert_held},
+        "num_experts_per_tok": cfg.top_k, "max_position_embeddings": cfg.max_seq,
+        "num_hidden_layers": cfg.n_layers, "tie_word_embeddings": cfg.tie_embeddings,
+        "vocab_size": cfg.vocab_size,
+    }
+
+
+def seeded_params(cfg, seed=0):
+    """init_params, with the leaves that start at one or zero moved off
+    them, so that a test sees the temperature, the router's carried
+    scale, its norm and its selection bias."""
+    params = llama.init_params(cfg, jax.random.key(seed))
+    keys = iter(jax.random.split(jax.random.key(seed + 100), 8))
+    layers = params["layers"]
+    for name, spread in (("temp", 0.3), ("router_gamma", 0.5), ("router_norm", 0.3),
+                         ("ln1", 0.2), ("ln2", 0.2)):
+        layers[name] = layers[name] + spread * jax.random.normal(next(keys), layers[name].shape)
+    layers["router_bias"] = 0.05 * jax.random.normal(next(keys), layers["router_bias"].shape)
+    return params
+
+
+def layer_of(params, i):
+    return jax.tree.map(lambda x: x[i], params["layers"])
+
+
+def skewed_tokens(cfg, seed=1):
+    rng = np.random.default_rng(seed)
+    p = 1.0 / np.arange(1, cfg.vocab_size + 1) ** 1.1
+    ids = rng.choice(cfg.vocab_size, size=(B, S + 1), p=p / p.sum())
+    return {"tokens": jnp.asarray(ids[:, :-1], jnp.int32),
+            "targets": jnp.asarray(ids[:, 1:], jnp.int32)}
+
+
+# -- the sublayers against the reference ---------------------------------------
+
+
+@pytest.mark.parametrize("kernels", [(2, 2), (3, 2)])
+def test_cca_sublayer_is_the_references(kernels):
+    cfg = dataclasses.replace(FP32, conv_kernels=kernels)
+    lp = layer_of(seeded_params(cfg), 1)
+    h = jax.random.normal(jax.random.key(2), (B, S, cfg.d_model), jnp.float32)
+    x = rms_norm(h, lp["ln1"], cfg.rms_eps)
+    got = h + cca.cca_sublayer(x, lp, cfg, positions=jnp.arange(S), segment_ids=None)
+    with jax.default_matmul_precision("highest"):
+        want = jnp.stack([zaya_decoder.cca(h[b], lp, shape_of(cfg)) for b in range(B)])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+def test_router_state_is_carried_through_three_layers():
+    """moe_ffn three times, each handed the state the one before gave:
+    the states and the counts are the reference's, and the carried
+    state matters (without it the third layer's state is another)."""
+    cfg, shape = FP32, shape_of(FP32)
+    params = seeded_params(cfg)
+    x = jax.random.normal(jax.random.key(3), (1, S, cfg.d_model), jnp.float32)
+    state, ref_state = None, jnp.zeros((S, cfg.router_hidden))
+    with jax.default_matmul_precision("highest"):
+        for i in range(3):
+            lp = layer_of(params, i)
+            out, stats, state = moe.moe_ffn(x, lp, cfg, state)
+            weights, _, ref_state = zaya_decoder.route(x[0], lp, shape, ref_state)
+            np.testing.assert_allclose(np.asarray(state[0]), np.asarray(ref_state),
+                                       rtol=1e-5, atol=1e-5)
+            assert stats["tokens_per_expert"].tolist() == (weights > 0).sum(0).tolist()
+            assert int(stats["tokens_per_expert"].sum()) == S and "pairs_elsewhere" not in stats
+    fresh = moe.moe_ffn(x, layer_of(params, 2), cfg, None)[2]
+    assert not np.allclose(np.asarray(fresh), np.asarray(state), atol=1e-3)
+
+
+def test_top1_weight_is_the_probability_and_the_bias_only_chooses():
+    cfg = FP32
+    lp = layer_of(seeded_params(cfg), 0)
+    x = jax.random.normal(jax.random.key(4), (1, S, cfg.d_model), jnp.float32)
+    plain = moe.moe_ffn(x, {**lp, "router_bias": jnp.zeros(cfg.n_experts)}, cfg)[1]
+    # a bias that forces expert 3: every token goes there, weighted by p_3 < 1
+    forced, stats, _ = moe.moe_ffn(
+        x, {**lp, "router_bias": jnp.zeros(cfg.n_experts).at[3].set(2.0)}, cfg)
+    assert stats["tokens_per_expert"].tolist() == [0, 0, 0, S]
+    assert plain["tokens_per_expert"].tolist() != [0, 0, 0, S]
+    with jax.default_matmul_precision("highest"):
+        _, probs, _ = zaya_decoder.route(x[0], lp, shape_of(cfg), jnp.zeros((S, cfg.router_hidden)))
+        y = (jax.nn.silu(x[0] @ lp["w_gate"][3]) * (x[0] @ lp["w_up"][3])) @ lp["w_down"][3]
+    np.testing.assert_allclose(np.asarray(forced[0]), np.asarray(probs[:, 3:4] * y),
+                               rtol=1e-4, atol=1e-5)
+    # and the bias takes no gradient
+    g = jax.grad(lambda b: moe.moe_ffn(x, {**lp, "router_bias": b}, cfg)[0].sum())(
+        lp["router_bias"])
+    assert float(jnp.abs(g).max()) == 0.0
+
+
+# -- the whole train path --------------------------------------------------------
+
+
+@pytest.mark.parametrize("held", [None, (2, 1)], ids=["all_experts", "a_share"])
+def test_train_path_meets_the_reference_in_loss_and_gradients(held):
+    """llama.loss_fn (the one train path) on a ZAYA1-kind configuration
+    against the plain reference, on seeded weights and skewed tokens: the
+    loss, the tokens per expert of every layer, and every gradient by
+    its worst leaf."""
+    cfg = FP32 if held is None else dataclasses.replace(
+        FP32, experts_held=held[0], first_expert_held=held[1])
+    params, batch, shape = seeded_params(cfg), skewed_tokens(cfg), shape_of(cfg)
+    with jax.default_matmul_precision("highest"):
+        loss, _, stats = llama.loss_and_weight_fn(params, batch, cfg)
+        got = jax.grad(lambda p: llama.loss_fn(p, batch, cfg))(params)
+        ref = zaya_decoder.loss_parts(params, batch["tokens"], batch["targets"], shape)
+        want = jax.grad(lambda p: zaya_decoder.loss(
+            p, batch["tokens"], batch["targets"], shape))(params)
+    assert float(loss) == pytest.approx(float(ref["loss"]), rel=2e-6)
+    assert stats["tokens_per_expert"].tolist() == ref["tokens_per_expert"].tolist()
+    assert int(stats["dropped_pairs"].sum()) == 0
+    if held is not None:
+        first, n = held[1], held[0]
+        elsewhere = B * S - stats["tokens_per_expert"][:, first:first + n].sum(-1)
+        assert stats["pairs_elsewhere"].tolist() == elsewhere.tolist()
+        assert 0 < int(elsewhere.sum()) < cfg.n_layers * B * S
+    worst = {}
+    for path, g in jax.tree_util.tree_leaves_with_path(got):
+        w = want
+        for k in path:
+            w = w[k.key]
+        scale = float(jnp.abs(w).max())
+        worst[jax.tree_util.keystr(path)] = float(jnp.abs(g - w).max()) / max(scale, 1e-12)
+        if "router_bias" in jax.tree_util.keystr(path):
+            assert scale == 0.0 and float(jnp.abs(g).max()) == 0.0
+            worst.pop(jax.tree_util.keystr(path))
+    assert max(worst.values()) < 2e-4, sorted(worst.items(), key=lambda kv: -kv[1])[:3]
+
+
+def test_bf16_compute_stays_near_the_reference():
+    cfg = dataclasses.replace(FP32, dtype=jnp.bfloat16)
+    params, batch = seeded_params(cfg), skewed_tokens(cfg)
+    loss = llama.loss_fn(params, batch, cfg)
+    ref = zaya_decoder.loss(params, batch["tokens"], batch["targets"], shape_of(cfg))
+    assert float(loss) == pytest.approx(float(ref), rel=0.02)
+
+
+@pytest.mark.parametrize("remat_policy", ["dots", "full"])
+def test_remat_gives_the_same_gradients(remat_policy):
+    cfg = dataclasses.replace(FP32, remat=True, remat_policy=remat_policy)
+    params, batch = seeded_params(cfg), skewed_tokens(cfg)
+    got = jax.grad(lambda p: llama.loss_fn(p, batch, cfg))(params)
+    want = jax.grad(lambda p: llama.loss_fn(p, batch, FP32))(params)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=2e-6)
+
+
+# -- causality, through both convolutions and the value shift ------------------
+
+
+def hidden(params, tokens, cfg, segment_ids=None):
+    return llama.hidden_states(params, tokens, cfg, segment_ids=segment_ids)
+
+
+@pytest.mark.parametrize("kernels", [(2, 2), (3, 3)])
+def test_changing_token_t_moves_nothing_before_t(kernels):
+    cfg = dataclasses.replace(FP32, conv_kernels=kernels)
+    params, tokens = seeded_params(cfg), skewed_tokens(cfg)["tokens"]
+    t = 11
+    base = hidden(params, tokens, cfg)
+    moved = hidden(params, tokens.at[:, t].set((tokens[:, t] + 7) % cfg.vocab_size), cfg)
+    assert np.array_equal(np.asarray(base[:, :t]), np.asarray(moved[:, :t]))
+    # and everything from t on does move: t itself, t + 1 through the shifts
+    delta = np.abs(np.asarray(base - moved)).max(axis=-1)
+    assert (delta[:, t:t + 3] > 1e-4).all()
+
+
+@pytest.mark.parametrize("kernels", [(2, 2), (3, 3)])
+def test_nothing_crosses_a_document_boundary(kernels):
+    """Two documents packed in a row: the second's hidden states are
+    those of the second document alone, whatever the first holds. The
+    causal mask alone would pass the first's last tokens through the
+    convolutions and the value shift."""
+    cfg = dataclasses.replace(FP32, conv_kernels=kernels)
+    params, tokens = seeded_params(cfg), skewed_tokens(cfg)["tokens"]
+    cut = 10
+    segments = jnp.asarray(np.r_[np.zeros(cut), np.ones(S - cut)][None].repeat(B, 0), jnp.int32)
+    packed = hidden(params, tokens, cfg, segments)
+    alone = hidden(params, tokens[:, cut:], cfg)
+    np.testing.assert_allclose(np.asarray(packed[:, cut:]), np.asarray(alone),
+                               rtol=1e-5, atol=1e-5)
+    other = tokens.at[:, :cut].set((tokens[:, :cut] + 3) % cfg.vocab_size)
+    assert np.array_equal(np.asarray(hidden(params, other, cfg, segments)[:, cut:]),
+                          np.asarray(packed[:, cut:]))
+    # the mask is what does it: without segment ids the first document leaks
+    assert not np.allclose(np.asarray(hidden(params, tokens, cfg)[:, cut:]),
+                           np.asarray(alone), atol=1e-3)
+
+
+def test_shift_tokens_is_zero_at_a_boundary_and_at_the_start():
+    x = jnp.arange(1.0, 7.0).reshape(1, 6, 1)
+    seg = jnp.asarray([[0, 0, 0, 1, 1, 1]])
+    assert cca.shift_tokens(x, 1, None)[0, :, 0].tolist() == [0, 1, 2, 3, 4, 5]
+    assert cca.shift_tokens(x, 1, seg)[0, :, 0].tolist() == [0, 1, 2, 0, 4, 5]
+    assert cca.shift_tokens(x, 2, seg)[0, :, 0].tolist() == [0, 0, 1, 0, 0, 4]
+    assert cca.shift_tokens(x, 0, seg) is x
+
+
+# -- the share adds up ---------------------------------------------------------------
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_two_shares_of_the_experts_add_up_to_the_uncut_layer(dtype):
+    """Experts 0-1 and 2-3 as two chips' shares of one layer: attention
+    and the router are computed alike on both (counted once); the
+    expert-layer outputs of the two shares sum to the uncut layer's, and
+    so do the gradients of the input; each share's counts are the uncut
+    layer's, and what one share computes the other counts as elsewhere."""
+    whole = dataclasses.replace(FP32, dtype=dtype)
+    lp = layer_of(seeded_params(whole), 0)
+    x = jax.random.normal(jax.random.key(5), (B, S, whole.d_model), jnp.float32).astype(dtype)
+    state = 0.1 * jax.random.normal(jax.random.key(6), (B, S, whole.router_hidden))
+
+    def share(first, n):
+        cfg = dataclasses.replace(whole, experts_held=n, first_expert_held=first)
+        held = {**lp, **{k: lp[k][first:first + n] for k in ("w_gate", "w_up", "w_down")}}
+        out, vjp, (stats, r) = jax.vjp(
+            lambda x: (lambda o, s, r: (o, (s, r)))(*moe.moe_ffn(x, held, cfg, state)),
+            x, has_aux=True)
+        return out, vjp(jnp.ones_like(out))[0], stats, r
+
+    full, full_vjp, (full_stats, full_r) = jax.vjp(
+        lambda x: (lambda o, s, r: (o, (s, r)))(*moe.moe_ffn(x, lp, whole, state)),
+        x, has_aux=True)
+    a, b = share(0, 2), share(2, 2)
+    tol = dict(rtol=1e-5, atol=1e-5) if dtype == jnp.float32 else dict(rtol=0.02, atol=0.02)
+    np.testing.assert_allclose(np.asarray(a[0] + b[0], np.float32),
+                               np.asarray(full, np.float32), **tol)
+    np.testing.assert_allclose(np.asarray(a[1] + b[1], np.float32),
+                               np.asarray(full_vjp(jnp.ones_like(full))[0], np.float32), **tol)
+    for _, _, stats, r in (a, b):
+        assert stats["tokens_per_expert"].tolist() == full_stats["tokens_per_expert"].tolist()
+        assert np.array_equal(np.asarray(r), np.asarray(full_r))
+    counts = full_stats["tokens_per_expert"]
+    assert int(a[2]["pairs_elsewhere"]) == int(counts[2:].sum())
+    assert int(b[2]["pairs_elsewhere"]) == int(counts[:2].sum())
+    assert int(a[2]["dropped_pairs"]) == int(b[2]["dropped_pairs"]) == 0
+
+
+def test_a_share_must_lie_inside_the_experts():
+    cfg = dataclasses.replace(FP32, experts_held=3, first_expert_held=2)
+    x = jnp.zeros((1, 4, cfg.d_model))
+    with pytest.raises(ValueError, match="held of 4"):
+        moe.moe_ffn(x, layer_of(seeded_params(FP32), 0), cfg)
+
+
+def test_a_share_of_a_linear_router_model_adds_up_too():
+    """The share is the expert layer's, not ZAYA1's: OLMoE-kind routing
+    (linear router, top-2) over two shares."""
+    whole = dataclasses.replace(moe.MOE_TINY, dtype=jnp.float32, norm_topk_prob=False)
+    lp = layer_of(llama.init_params(whole, jax.random.key(0)), 0)
+    x = jax.random.normal(jax.random.key(7), (B, S, whole.d_model), jnp.float32)
+    parts = []
+    for first in (0, 2):
+        cfg = dataclasses.replace(whole, experts_held=2, first_expert_held=first)
+        held = {**lp, **{k: lp[k][first:first + 2] for k in ("w_gate", "w_up", "w_down")}}
+        parts.append(moe.moe_ffn(x, held, cfg)[0])
+    np.testing.assert_allclose(np.asarray(parts[0] + parts[1]),
+                               np.asarray(moe.moe_ffn(x, lp, whole)[0]), rtol=1e-5, atol=1e-5)
+
+
+# -- the grouped matmul whose trailing rows no tile visits -----------------------
+
+
+@pytest.mark.parametrize("sizes", [[100, 0, 156, 37], [0, 0, 0, 0], [128, 128, 128, 128],
+                                   [511, 1, 0, 0]])
+def test_grouped_matmul_leaves_the_rows_past_the_last_group_zero(sizes):
+    """Interpret mode, P = 1024 rows of which sum(sizes) have a group:
+    value and both gradients are ragged_dot's, the rows after the last
+    group are zero in the output and in the input gradient though the
+    cotangent there is not, and the buffer they were never written to is
+    not what comes out."""
+    P, K, N = 1024, 128, 256
+    rng = np.random.default_rng(0)
+    lhs = jnp.asarray(rng.normal(size=(P, K)), jnp.float32)
+    rhs = jnp.asarray(rng.normal(size=(4, K, N)), jnp.float32)
+    g = jnp.asarray(rng.normal(size=(P, N)), jnp.float32)
+    gs = jnp.asarray(sizes, jnp.int32)
+    total = sum(sizes)
+
+    def kernel(a, b):
+        return gm.grouped_matmul_pallas(a, b, gs, interpret=True, tail=True)
+
+    def plain(a, b):
+        return jax.lax.ragged_dot(a, b, gs, precision=jax.lax.Precision.HIGHEST)
+
+    out, vjp = jax.vjp(kernel, lhs, rhs)
+    want, want_vjp = jax.vjp(plain, lhs, rhs)
+    d_lhs, d_rhs = vjp(g)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-4, atol=1e-3)
+    for got, ref in zip((d_lhs, d_rhs), want_vjp(g)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-4, atol=1e-3)
+    assert not np.asarray(out[total:]).any() and not np.asarray(d_lhs[total:]).any()
+    assert total == 0 or np.asarray(out[:total]).any()
+
+
+def test_grouped_matmul_tiles_for_the_held_experts_of_zaya1():
+    """From the shapes, as PR 27's: 8 groups over [tokens, 2048] x
+    [2048, 2048] keep a group's whole weight matrix in VMEM while its
+    rows stream past (it fills the budget to the byte: 40 MiB), forward
+    and input gradient; the weight gradient accumulates half of it at a
+    time; OLMoE's are what they were."""
+    for tokens in (12288, 16384):
+        fwd = gm.pick_tiles(tokens, 2048, 2048, jnp.bfloat16)
+        assert fwd == gm.Tiles(512, 2048, 2048)
+        wgrad = gm.pick_tiles(tokens, 2048, 2048, jnp.bfloat16, wgrad=True)
+        assert wgrad.tm == 512 and wgrad.tk * wgrad.tn == 2048 * 1024
+        for t, w in ((fwd, False), (wgrad, True)):
+            assert gm._vmem_bytes(t, 2, wgrad=w) <= gm._VMEM_BUDGET
+    assert gm.pick_tiles(196608, 2048, 1024, jnp.bfloat16) == gm.Tiles(512, 2048, 1024)
+    assert gm.pick_tiles(196608, 1024, 2048, jnp.bfloat16) == gm.Tiles(512, 1024, 2048)
