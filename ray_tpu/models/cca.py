@@ -25,16 +25,38 @@ heads of `head_dim`:
                 (a learned temperature per key-value head);
                 rotary on the first `rotary_fraction` of each head;
   attend        causal softmax attention in the latent, GQA H / G, scale
-                1 / sqrt(hd), through ops/attention.attention: the flash
-                kernel the other configurations use, unchanged;
+                1 / sqrt(hd), through ops/attention.attention_head_major:
+                the flash kernel the other configurations use, unchanged;
   out           hidden += o W_o  ([H * hd] -> d_model).
 
-A convolution of k taps is k shifted multiply-adds (depthwise) or k
-[S, hd] x [hd, hd] matmuls a head (grouped). The shifted operand is
-zero where token t - n belongs to another document (`segment_ids`) or
-does not exist: beside the causal mask, the convolutions and the value
-shift are what must not leak across a document boundary. Everything
-that is neither a matmul nor the kernel is computed in float32.
+A convolution of k taps is k shifted multiply-adds (depthwise) or one
+[S, k hd] x [k hd, hd] matmul a head (grouped: the k shifted operands
+side by side in the contraction). The shifted operand is zero where
+token t - n belongs to another document (`segment_ids`) or does not
+exist: beside the causal mask, the convolutions and the value shift
+are what must not leak across a document boundary. Everything that is
+neither a matmul nor the kernel is computed in float32.
+
+The layout (PR 33). From the projections to the kernel every array is
+HEAD-MAJOR, [B, heads, S, hd] ([B, G, rep, S, hd] where the q-k mean
+meets the key-value head's group): on a TPU an array's last two
+dimensions are its tile, 8 or 16 rows of 128 lanes, so the tile is
+(tokens, a head's channels) and always full. With [B, S, heads, hd]
+the 2, 8 or 10 heads were the tile's rows, padded to 8 or 16 (the
+values at G = 2 four-fifths padding), a matmul a head came back as
+[heads, B, S, hd] to be transposed, and the flash kernel, whose own
+layout is [B, H, S, hd], transposed q, k and v again at its door: a
+dozen copies a layer under `cca.mix`, which ran at five times its
+arithmetic (PERF.md section 6, PR 33). So: the projections write
+head-major (the weight read as [d, heads, hd]); q~ and k~ go through
+the convolutions apart (what is cut out of a joint [B, H + G, S, hd]
+has a padded gradient); the shifts move along axis 2, a row or two of
+the tile; means, norms, the temperature and the rotary act on the last
+axis or on major ones; `ops/attention.attention_head_major` hands q, k
+and v to the kernels as they are, and `cca.out` contracts (H, hd) of
+what comes back. XLA chooses the layout of whatever nothing pins, and
+for these arrays it chose channels-in-sublanes and back by turns, so
+`_head_major` pins the tile where the matmuls write.
 
 What the published config does not fix (no convolution or projection
 bias, which key-value head is the shifted one, the form of the q-k
@@ -52,11 +74,12 @@ from typing import Any, Optional
 import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ray_tpu import obs
 from ray_tpu.models import moe
 from ray_tpu.nn.layers import init_dense
-from ray_tpu.ops.attention import attention
+from ray_tpu.ops.attention import attention_head_major
 
 Params = dict[str, Any]
 _F32 = jnp.float32
@@ -159,26 +182,59 @@ def attention_params(config: ZayaConfig, key: jax.Array) -> Params:
     }
 
 
-def shift_tokens(x: jax.Array, n: int, segment_ids: Optional[jax.Array]) -> jax.Array:
-    """x [B, S, ...] -> x at token t - n: zero where there is no such
-    token or it belongs to another document."""
+def shift_tokens(x: jax.Array, n: int, segment_ids: Optional[jax.Array],
+                 axis: int = 1) -> jax.Array:
+    """x [B, ...] with the tokens along `axis` -> x at token t - n: zero
+    where there is no such token or it belongs to another document."""
     if n == 0:
         return x
-    tail = ((0, 0),) * (x.ndim - 2)
-    y = jnp.pad(x, ((0, 0), (n, 0)) + tail)[:, :-n]
+    S = x.shape[axis]
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (n, 0)
+    y = jax.lax.slice_in_dim(jnp.pad(x, pad), 0, S, axis=axis)
     if segment_ids is None:
         return y
     same = jnp.pad(segment_ids, ((0, 0), (n, 0)), constant_values=-1)[:, :-n] == segment_ids
-    return jnp.where(same.reshape(same.shape + (1,) * (x.ndim - 2)), y, jnp.zeros((), x.dtype))
+    along = [1] * x.ndim
+    along[0], along[axis] = x.shape[0], S
+    return jnp.where(same.reshape(along), y, jnp.zeros((), x.dtype))
+
+
+def _head_major(x: jax.Array) -> jax.Array:
+    """x [B, heads, S, hd], held in memory in that order: the tile is
+    (S, hd). Without it the compiler lays the mix out by what the next
+    operation would like (the tokens in the lanes for a shift, the
+    channels in the sublanes for the rotary's slices) and copies between
+    the two."""
+    return with_layout_constraint(x, Layout(major_to_minor=(0, 1, 2, 3)))
 
 
 def _mix_in_heads(u: jax.Array, w: jax.Array) -> jax.Array:
-    """u [B, S, n, c] x w [n, c, d] -> [B, S, n, d] in float32: a matmul
-    a head. (Spelled as the dot_general itself: the CPU backend refuses
-    the one `jnp.einsum` makes of "bsnc,ncd->bsnd" for bfloat16 operands
-    and a float32 result.)"""
-    out = jax.lax.dot_general(u, w, (((3,), (1,)), ((2,), (0,))), preferred_element_type=_F32)
-    return jnp.moveaxis(out, 0, 2)
+    """u [B, n, S, c] x w [n, c, d] -> [B, n, S, d] in float32: a matmul
+    a head, batched over (B, n) so that the result comes out in the
+    operand's own order of dimensions. (Spelled as the dot_general
+    itself: the CPU backend refuses the one `jnp.einsum` makes for
+    bfloat16 operands and a float32 result.)"""
+    w = jnp.broadcast_to(w, u.shape[:1] + w.shape)
+    return _head_major(jax.lax.dot_general(
+        u, w, (((3,), (2,)), ((0, 1), (0, 1))), preferred_element_type=_F32))
+
+
+def _convolve(u: jax.Array, taps0: jax.Array, taps1: jax.Array,
+              segment_ids: Optional[jax.Array]) -> jax.Array:
+    """Both causal convolutions over heads u [B, n, S, hd]: depthwise
+    taps0 [k0, n, 1, hd] in float32, then the full mix inside each head,
+    taps1 [k1, n, hd, hd], as one matmul a head over (tap, channel).
+    -> float32. (A shift commutes with the conversion to float32, and is
+    made before it: half the bytes.)"""
+    k0, k1 = taps0.shape[0], taps1.shape[0]
+    n, hd = u.shape[1], u.shape[3]
+    taps0 = taps0.astype(_F32)
+    u0 = sum(taps0[j] * shift_tokens(u, k0 - 1 - j, segment_ids, axis=2).astype(_F32)
+             for j in range(k0)).astype(u.dtype)
+    shifted = jnp.concatenate(
+        [shift_tokens(u0, k1 - 1 - j, segment_ids, axis=2) for j in range(k1)], axis=-1)
+    return _mix_in_heads(shifted, jnp.moveaxis(taps1, 0, 1).reshape(n, k1 * hd, hd).astype(u.dtype))
 
 
 def _unit_heads(x: jax.Array, eps: float) -> jax.Array:
@@ -188,12 +244,12 @@ def _unit_heads(x: jax.Array, eps: float) -> jax.Array:
 
 def _partial_rope(x: jax.Array, positions: jax.Array, rot: int, theta: float) -> jax.Array:
     """Rotate the first `rot` channels of each head (half-split pairing)
-    by position; x [B, S, ..., hd] float32, positions [S] or [B, S]."""
+    by position; x [B, ..., S, hd] float32, positions [S] or [B, S]."""
     inv = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=_F32) / rot))
     ang = positions.astype(_F32)[..., None] * inv             # [(B,) S, rot / 2]
     if ang.ndim == 2:
         ang = ang[None]
-    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + ang.shape[-1:])
+    ang = ang.reshape(ang.shape[:1] + (1,) * (x.ndim - 3) + ang.shape[1:])
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2, rest = x[..., :rot // 2], x[..., rot // 2:rot], x[..., rot:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
@@ -202,37 +258,36 @@ def _partial_rope(x: jax.Array, positions: jax.Array, rot: int, theta: float) ->
 def cca_sublayer(x: jax.Array, lp: Params, c: ZayaConfig, *, positions: jax.Array,
                  segment_ids: Optional[jax.Array]) -> jax.Array:
     """x = RMSNorm(hidden) [B, S, D] -> what the sublayer adds to the
-    hidden state, [B, S, D]. The equations are the module's docstring."""
-    B, S, _ = x.shape
+    hidden state, [B, S, D]. The equations and the layout (head-major
+    from the projections to the kernel) are the module's docstring."""
+    B, S, D = x.shape
     H, G, hd = c.n_heads, c.n_kv_heads, c.head_dim
-    rep, dt = H // G, x.dtype
+    n, rep, dt = H + G, H // G, x.dtype
     k0, k1 = c.conv_kernels
     with obs.layer_span("cca.attn"):  # counts engaged sites, while tracing
         with jax.named_scope("cca.proj"):
-            q_lat, k_lat, v_now, v_prev = (
-                jnp.einsum("bsd,dh->bsh", x, lp[n].astype(dt)) for n in ("wq", "wk", "wv1", "wv2"))
+            u_q, u_k, v = (
+                _head_major(jnp.einsum("bsd,dnh->bnsh", x, w.astype(dt).reshape(D, -1, hd)))
+                for w in (lp["wq"], lp["wk"], jnp.concatenate([lp["wv1"], lp["wv2"]], axis=1)))
         with jax.named_scope("cca.mix"):
-            v = jnp.concatenate([v_now, shift_tokens(v_prev, 1, segment_ids)], axis=-1)
-            v = v.reshape(B, S, G, hd)
-            u = jnp.concatenate([q_lat, k_lat], axis=-1).astype(_F32)     # [B, S, (H + G) * hd]
-            taps0 = lp["conv0"].astype(_F32)
-            u0 = sum(taps0[j] * shift_tokens(u, k0 - 1 - j, segment_ids) for j in range(k0))
-            u0 = u0.astype(dt).reshape(B, S, H + G, hd)
-            taps1 = lp["conv1"].astype(dt)
-            u1 = sum(_mix_in_heads(shift_tokens(u0, k1 - 1 - j, segment_ids), taps1[j])
-                     for j in range(k1))
-            q_lat = q_lat.astype(_F32).reshape(B, S, G, rep, hd)
-            m = 0.5 * (q_lat + k_lat.astype(_F32).reshape(B, S, G, 1, hd))
-            q = u1[:, :, :H].reshape(B, S, G, rep, hd) + m
-            k = u1[:, :, H:] + m.mean(axis=3)
+            # the second half of the value CHANNELS (not of the heads) reads token t - 1
+            shifted = (jnp.arange(G * hd) >= G * hd // 2).reshape(G, 1, hd)
+            v = jnp.where(shifted, shift_tokens(v, 1, segment_ids, axis=2), v)
+            taps0 = lp["conv0"].reshape(k0, n, 1, hd)
+            q = _convolve(u_q, taps0[:, :H], lp["conv1"][:, :H], segment_ids)
+            k = _convolve(u_k, taps0[:, H:], lp["conv1"][:, H:], segment_ids)
+            m = 0.5 * (u_q.astype(_F32).reshape(B, G, rep, S, hd) + u_k.astype(_F32)[:, :, None])
+            q = q.reshape(B, G, rep, S, hd) + m
+            k = k + m.mean(axis=2)
             q = _unit_heads(q, c.rms_eps)
-            k = _unit_heads(k, c.rms_eps) * lp["temp"].astype(_F32)[:, None]
+            k = _unit_heads(k, c.rms_eps) * lp["temp"].astype(_F32)[:, None, None]
             rot = int(hd * c.rotary_fraction)
-            q = _partial_rope(q, positions, rot, c.rope_theta).reshape(B, S, H, hd).astype(dt)
+            q = _partial_rope(q, positions, rot, c.rope_theta).reshape(B, H, S, hd).astype(dt)
             k = _partial_rope(k, positions, rot, c.rope_theta).astype(dt)
         with jax.named_scope("cca.attend"):
-            o = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=c.attention_impl)
+            o = attention_head_major(q, k, v, causal=True, segment_ids=segment_ids,
+                                     impl=c.attention_impl)
             # saved by the "dots" remat policy, as llama._block's is
             o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
         with jax.named_scope("cca.out"):
-            return jnp.einsum("bsh,hd->bsd", o.reshape(B, S, H * hd), lp["wo"].astype(dt))
+            return jnp.einsum("bhsk,hkd->bsd", o, lp["wo"].astype(dt).reshape(H, hd, D))

@@ -65,8 +65,10 @@ def xla_attention(
     return out.reshape(B, Sq, H, D)
 
 
-def _flash_over_mesh(q, k, v, segment_ids, **kw) -> jax.Array:
-    """The flash kernel, one call per shard under an ambient mesh.
+def _flash_over_mesh(q, k, v, segment_ids, *, head_axis: int = 2, **kw) -> jax.Array:
+    """The flash kernel, one call per shard under an ambient mesh; the
+    heads are axis `head_axis` of q, k, v and the result (2, or 1 for
+    `attention_head_major`'s callers).
 
     A pallas_call has no GSPMD partitioning rule (the TPU compiler
     refuses to partition a Mosaic kernel), so on a multi-device mesh the
@@ -77,9 +79,10 @@ def _flash_over_mesh(q, k, v, segment_ids, **kw) -> jax.Array:
     Called from inside another shard_map (the pipeline's, manual over
     `pp`), only the axes that are still automatic are taken over; with
     none left the kernel is already per shard and runs as it is."""
-    from ray_tpu.ops.flash import flash_attention
+    from ray_tpu.ops import flash
     from ray_tpu.parallel.context import current_mesh, current_rules
 
+    flash_attention = flash.flash_attention if head_axis == 2 else flash.flash_attention_head_major
     mesh = current_mesh()
     manual = jax.sharding.get_abstract_mesh().manual_axes
     auto = frozenset() if mesh is None else frozenset(
@@ -88,8 +91,10 @@ def _flash_over_mesh(q, k, v, segment_ids, **kw) -> jax.Array:
     if all(mesh.shape[a] == 1 for a in auto):
         return flash_attention(q, k, v, segment_ids=segment_ids, **kw)
     rules = current_rules()
-    qspec = rules.spec(("batch", None, "heads", None))
-    kvspec = rules.spec(("batch", None, "kv_heads", None))
+    qspec, kvspec = (
+        rules.spec(tuple(heads if i == head_axis else axis
+                         for i, axis in enumerate(("batch", None, None, None))))
+        for heads in ("heads", "kv_heads"))
     args, in_specs = (q, k, v), (qspec, kvspec, kvspec)
     if segment_ids is not None:
         args += (segment_ids,)
@@ -156,3 +161,23 @@ def attention(
             softmax_scale=softmax_scale,
         )
     raise ValueError(f"unknown attention impl {impl!r}")
+
+
+def attention_head_major(
+    q: jax.Array,  # [B, H, S, D]
+    k: jax.Array,  # [B, K, S, D]
+    v: jax.Array,  # [B, K, S, D]
+    *,
+    causal: bool = True,
+    segment_ids: Optional[jax.Array] = None,
+    impl: str = "xla",
+) -> jax.Array:
+    """`attention` for a caller whose heads are a major dimension, the
+    tile (S, D): -> [B, H, S, D]. That is the flash kernels' own layout,
+    so `impl="flash"` reaches them with no transpose on the way in or
+    out; every other `impl` is `attention` between its transposes."""
+    if impl == "flash":
+        return _flash_over_mesh(q, k, v, segment_ids, head_axis=1, causal=causal)
+    o = attention(*(jnp.swapaxes(x, 1, 2) for x in (q, k, v)), causal=causal,
+                  segment_ids=segment_ids, impl=impl)
+    return jnp.swapaxes(o, 1, 2)

@@ -671,31 +671,8 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 # ---------------------------------------------------------------------------
 
 
-def flash_attention(
-    q: jax.Array,  # [B, Sq, H, D]
-    k: jax.Array,  # [B, Sk, KVH, D]
-    v: jax.Array,  # [B, Sk, KVH, D]
-    *,
-    causal: bool = True,
-    segment_ids: Optional[jax.Array] = None,  # [B, S] (requires Sq == Sk)
-    kv_segment_ids: Optional[jax.Array] = None,  # [B, Sk] (k/v side override)
-    q_offset: int | jax.Array = 0,
-    softmax_scale: Optional[float] = None,
-    block_q: int = DEFAULT_BLOCK_Q,
-    block_k: Optional[int] = None,  # None = fused whole-sequence (VMEM-capped)
-    interpret: Optional[bool] = None,
-    fold_heads: Optional[int] = None,  # None = auto (largest safe divisor of G)
-    return_lse: bool = False,
-) -> "jax.Array | tuple[jax.Array, jax.Array]":
-    """Drop-in for ops.attention.xla_attention with O(S) memory.
-
-    kv_segment_ids: when the k/v block carries DIFFERENT segments than q
-    (ring attention's rotating kv shards), pass them here; segment_ids
-    then applies to q only. return_lse: also return the per-row
-    log-sum-exp [B, Sq, H] (differentiable) — the merge quantity for
-    blockwise/ring composition."""
-    B, Sq, H, D = q.shape
-    _, Sk, KVH, _ = k.shape
+def _check_shapes(H, KVH, Sq, Sk, q_offset, segment_ids, kv_segment_ids):
+    """What both entries refuse, whichever axis their heads stand on."""
     if H % KVH != 0:
         raise ValueError(f"n_heads {H} not divisible by kv heads {KVH}")
     if not isinstance(q_offset, int):
@@ -711,7 +688,36 @@ def flash_attention(
             "kv_segment_ids with Sq != Sk needs an explicit q-side "
             "segment_ids (the kv array cannot stand in for it)"
         )
-    scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
+
+
+def _fold_scale(q: jax.Array, softmax_scale: Optional[float]) -> jax.Array:
+    """Fold the softmax scale into q OUTSIDE the custom-vjp boundary: the
+    kernels then skip the [rows, Bk] scale multiplies (one in fwd, two
+    in bwd — they're VPU-bound), and the chain rule through this mul
+    restores dq's scale automatically. fp32 mul, then back to input
+    dtype (for D a power of 4 the scale is a power of two and exact)."""
+    scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    return (q.astype(jnp.float32) * scale).astype(q.dtype)
+
+
+def _flash_head_major(
+    qt: jax.Array,  # [B, H, Sq, D], the softmax scale folded in
+    kt: jax.Array,  # [B, KVH, Sk, D]
+    vt: jax.Array,  # [B, KVH, Sk, D]
+    *,
+    causal: bool,
+    segment_ids: Optional[jax.Array],
+    kv_segment_ids: Optional[jax.Array] = None,
+    q_offset: int = 0,
+    block_q: int = DEFAULT_BLOCK_Q,
+    block_k: Optional[int] = None,
+    interpret: Optional[bool] = None,
+    fold_heads: Optional[int] = None,
+) -> tuple[jax.Array, jax.Array]:
+    """The kernels' own layout, which both public forms come down to:
+    -> (o [B, H, Sq, D], lse [B, H, Sq_pad, 1])."""
+    B, H, Sq, _ = qt.shape
+    _, KVH, Sk, _ = kt.shape
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
@@ -725,19 +731,6 @@ def flash_attention(
     bk = min(block_k, _round_up(Sk, 16))
     Sq_pad = _round_up(Sq, bq)
     Sk_pad = _round_up(Sk, bk)
-
-    # Fold the softmax scale into q OUTSIDE the custom-vjp boundary: the
-    # kernels then skip the [rows, Bk] scale multiplies (one in fwd, two
-    # in bwd — they're VPU-bound), and the chain rule through this mul
-    # restores dq's scale automatically. fp32 mul, then back to input
-    # dtype (for D a power of 4 the scale is a power of two and exact).
-    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
-    kernel_scale = 1.0
-
-    # [B, S, H, D] -> [B, H, S, D]
-    qt = jnp.transpose(q, (0, 2, 1, 3))
-    kt = jnp.transpose(k, (0, 2, 1, 3))
-    vt = jnp.transpose(v, (0, 2, 1, 3))
     if Sq_pad != Sq:
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, Sq_pad - Sq), (0, 0)))
     if Sk_pad != Sk:
@@ -761,11 +754,68 @@ def flash_attention(
     kseg = kseg2[:, None, :]   # [B, 1, Sk_pad]
 
     fold = _fold_factor(H // KVH, bq, bk, fold_heads)
-    statics = (kernel_scale, causal, q_offset, bq, bk, Sq, Sk, interpret,
+    # the scale is in q already: the kernels' own is 1
+    statics = (1.0, causal, q_offset, bq, bk, Sq, Sk, interpret,
                has_segments, fold)
     o, lse = _flash_lse(*statics, qt, kt, vt, qseg, kseg)
-    o = jnp.transpose(o[:, :, :Sq, :], (0, 2, 1, 3))
+    return o[:, :, :Sq, :], lse
+
+
+def flash_attention(
+    q: jax.Array,  # [B, Sq, H, D]
+    k: jax.Array,  # [B, Sk, KVH, D]
+    v: jax.Array,  # [B, Sk, KVH, D]
+    *,
+    causal: bool = True,
+    segment_ids: Optional[jax.Array] = None,  # [B, S] (requires Sq == Sk)
+    kv_segment_ids: Optional[jax.Array] = None,  # [B, Sk] (k/v side override)
+    q_offset: int | jax.Array = 0,
+    softmax_scale: Optional[float] = None,
+    block_q: int = DEFAULT_BLOCK_Q,
+    block_k: Optional[int] = None,  # None = fused whole-sequence (VMEM-capped)
+    interpret: Optional[bool] = None,
+    fold_heads: Optional[int] = None,  # None = auto (largest safe divisor of G)
+    return_lse: bool = False,
+) -> "jax.Array | tuple[jax.Array, jax.Array]":
+    """Drop-in for ops.attention.xla_attention with O(S) memory.
+
+    kv_segment_ids: when the k/v block carries DIFFERENT segments than q
+    (ring attention's rotating kv shards), pass them here; segment_ids
+    then applies to q only. return_lse: also return the per-row
+    log-sum-exp [B, Sq, H] (differentiable) — the merge quantity for
+    blockwise/ring composition."""
+    Sq = q.shape[1]
+    _check_shapes(q.shape[2], k.shape[2], Sq, k.shape[1], q_offset, segment_ids,
+                  kv_segment_ids)
+    q = _fold_scale(q, softmax_scale)
+    # [B, S, H, D] -> [B, H, S, D]
+    qt = jnp.transpose(q, (0, 2, 1, 3))
+    kt = jnp.transpose(k, (0, 2, 1, 3))
+    vt = jnp.transpose(v, (0, 2, 1, 3))
+    o, lse = _flash_head_major(
+        qt, kt, vt, causal=causal, segment_ids=segment_ids,
+        kv_segment_ids=kv_segment_ids, q_offset=q_offset, block_q=block_q,
+        block_k=block_k, interpret=interpret, fold_heads=fold_heads)
+    o = jnp.transpose(o, (0, 2, 1, 3))
     if return_lse:
         lse = jnp.transpose(lse[:, :, :Sq, 0], (0, 2, 1))  # [B, Sq, H]
         return o, lse
+    return o
+
+
+def flash_attention_head_major(
+    q: jax.Array,  # [B, H, Sq, D]
+    k: jax.Array,  # [B, KVH, Sk, D]
+    v: jax.Array,  # [B, KVH, Sk, D]
+    *,
+    causal: bool = True,
+    segment_ids: Optional[jax.Array] = None,  # [B, S] (requires Sq == Sk)
+) -> jax.Array:
+    """`flash_attention` for a caller that holds the heads as a major
+    dimension already (models/cca.py): [B, H, Sq, D] in and out, the
+    kernels' own layout, so nothing is transposed on the way in or out.
+    `flash_attention` is its three transposes, this, and one back."""
+    _check_shapes(q.shape[1], k.shape[1], q.shape[2], k.shape[2], 0, segment_ids, None)
+    o, _ = _flash_head_major(_fold_scale(q, None), k, v, causal=causal,
+                             segment_ids=segment_ids)
     return o

@@ -201,3 +201,49 @@ def test_attention_flash_under_mesh_matches_xla(cpu_devices, with_segments):
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
     for a, b in zip(gout, gref):
         np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
+
+
+@pytest.mark.parametrize("mesh_spec", [None, dict(fsdp=2, tp=2)], ids=["no_mesh", "fsdp2_tp2"])
+def test_head_major_entry_is_the_other_entry_without_its_transposes(cpu_devices, mesh_spec):
+    """`attention_head_major` takes and gives [B, H, S, D], the kernels'
+    own layout (models/cca.py holds its heads so); `attention` is its
+    transposes around the same kernels: outputs and gradients are equal
+    to the bit, packed documents and a ragged sequence included, alone
+    and with batch and heads sharded over a mesh."""
+    import contextlib
+
+    from ray_tpu.ops.attention import attention, attention_head_major
+    from ray_tpu.parallel.context import parallel_context
+    from ray_tpu.parallel.mesh import MeshSpec, make_mesh
+
+    B, S, H, KVH, D = 4, 100, 4, 2, 32
+    q, k, v = make_qkv(jax.random.key(11), B, S, S, H, KVH, D)
+    seg = (jnp.arange(S)[None, :] >= 37).astype(jnp.int32).repeat(B, 0)
+    ct = jax.random.normal(jax.random.key(12), (B, S, H, D))
+    mesh = None if mesh_spec is None else make_mesh(MeshSpec(**mesh_spec), devices=cpu_devices[:4])
+
+    def context():
+        return contextlib.nullcontext() if mesh is None else parallel_context(mesh)
+
+    def rows_major(q, k, v):
+        with context():
+            o = attention(q, k, v, causal=True, segment_ids=seg, impl="flash")
+        return (o * ct).sum(), o
+
+    def head_major(q, k, v):
+        with context():
+            o = attention_head_major(*(jnp.swapaxes(x, 1, 2) for x in (q, k, v)),
+                                     causal=True, segment_ids=seg, impl="flash")
+        o = jnp.swapaxes(o, 1, 2)
+        return (o * ct).sum(), o
+
+    (_, want), g_want = jax.jit(jax.value_and_grad(rows_major, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    (_, got), g_got = jax.jit(jax.value_and_grad(head_major, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    assert np.array_equal(np.asarray(got), np.asarray(want)) and np.asarray(want).any()
+    for a, b in zip(g_got, g_want):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    # every other impl goes through `attention` itself
+    np.testing.assert_allclose(
+        np.asarray(jnp.swapaxes(attention_head_major(
+            *(jnp.swapaxes(x, 1, 2) for x in (q, k, v)), segment_ids=seg, impl="xla"), 1, 2)),
+        np.asarray(want), atol=2e-5, rtol=2e-5)

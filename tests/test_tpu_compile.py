@@ -304,24 +304,32 @@ _DENSE_STEP = {
     None: "14345d8a3cbb5701ae05ae6ed72c71171af81f87aca57866109ecb267e64f703",
     (1, 1, 2, 1, 1, 2): "dd35b02d6d1417352d22657f5af33b251483b34e949bb0de7bcd00e43f2470b5",
 }
+# the same of olmoe-1b-7b's step as `olmoe-train` builds it (one layer, batch 6), as
+# commit 8e69254 (the parent of PR 33, which gave ops/flash.py a second entry) lowers it
+_OLMOE_STEP = "36d2bc29f84e2c8a10813df1313e801532ecb0c08487bc3463c00e2e9b7fbab0"
 
 
-@pytest.mark.parametrize("mesh_shape,batch", [(None, 3), ((1, 1, 2, 1, 1, 2), 6)],
-                         ids=["one_chip", "fsdp2_tp2"])
-def test_dense_train_step_lowers_to_the_text_it_had_before_the_expert_layer(
-        v5e, mesh_shape, batch):
+@pytest.mark.parametrize("kwargs,want", [
+    (dict(batch=3), _DENSE_STEP[None]),
+    (dict(mesh_shape=(1, 1, 2, 1, 1, 2), batch=6), _DENSE_STEP[(1, 1, 2, 1, 1, 2)]),
+    (dict(batch=6, model="olmoe-1b-7b", n_layers=1), _OLMOE_STEP),
+], ids=["one_chip", "fsdp2_tp2", "olmoe"])
+def test_dense_train_step_lowers_to_the_text_it_had_before_the_expert_layer(v5e, kwargs, want):
     """One block serves dense and expert configurations (PR 26); for a
     dense one the lowered step is the text it was, which is what keeps
-    `m7b-train` and `m7b-train-4chip` where they are."""
+    `m7b-train` and `m7b-train-4chip` where they are. And one flash
+    path serves both of its entries (PR 33: `flash_attention` is its
+    transposes around the head-major one that CCA calls): the steps
+    that enter by the old one, OLMoE's too, lower to the text they had."""
     import hashlib
     import re
 
-    step, state, tokens = _train_step_at_mistral_widths(v5e, mesh_shape, batch=batch)
+    step, state, tokens = _train_step_at_mistral_widths(v5e, **kwargs)
     with mock.patch("jax.default_backend", return_value="tpu"):
         text = step.lower(state, tokens).as_text()
     assert "tpu_custom_call" in text
     text = re.sub(r'backend_config = "(?:[^"\\]|\\.)*"', 'backend_config = "-"', text)
-    assert hashlib.sha256(text.encode()).hexdigest() == _DENSE_STEP[mesh_shape]
+    assert hashlib.sha256(text.encode()).hexdigest() == want
 
 
 def test_expert_train_step_runs_nine_tiled_grouped_matmuls(v5e):
@@ -366,7 +374,8 @@ def test_zaya_share_train_step_runs_its_kernels_and_skips_the_rows_elsewhere(v5e
     """ZAYA1-8B as `zaya1-train` builds it (8 of 16 experts held, an
     eighth of the vocabulary; ONE layer and one sequence here, the
     cell's six and its batch are rehearsed in PERF.md), compiled for
-    the described chip: CCA's attention is the two flash kernels, the
+    the described chip: CCA's attention is the two flash kernels, its
+    mix is laid out with the tokens and a head's channels as the tile, the
     held experts' nine grouped matmuls are the kernels of
     ops/grouped_matmul.py with a group's whole [2048, 2048] weight
     matrix as one block, XLA's own ragged-dot kernel is not there, and
@@ -398,6 +407,25 @@ def test_zaya_share_train_step_runs_its_kernels_and_skips_the_rows_elsewhere(v5e
     assert re.search(r"f32\[1,4096,256\]", hlo)
     # 8 held experts' weights and no more: [1, 8, 2048, 2048], never 16
     assert "8,2048,2048]" in hlo and "16,2048,2048]" not in hlo
+    # CCA's mix holds its heads in a MAJOR dimension (PR 33): wherever an array under
+    # `cca.mix` has a head's channels in its lanes, the tokens are in the sublanes, never
+    # the 2, 8 or 10 heads (padded to the tile's 8 or 16); and nothing is moved between
+    # layouts: the parent had 12 `copy` instructions of activations under that scope in
+    # this step ([1, 4096, 10, 128] <-> [10, 1, 4096, 128] and channels-in-sublanes
+    # copies), and 0.8522 GiB of temporaries (what is still copied is the taps' weights,
+    # [heads, 2, 128, 128])
+    mix = [(shape, op) for shape, op, op_name in re.findall(
+        r"^\s*(?:ROOT )?%[\w.\-]+ = (\(.*?\)|\S+) ([\w\-]+)\([^\n]*op_name=\"([^\"]*)\"", hlo, re.M)
+        if re.search(r"(?:^|/)cca\.mix(?:/|$)", op_name)]
+    assert len(mix) > 50
+    assert not [shape for shape, op in mix if op in ("copy", "transpose") and "4096" in shape]
+    arrays = [([int(d) for d in dims.split(",")], [int(i) for i in order.split(",")])
+              for shape, _ in mix
+              for dims, order in re.findall(r"(?:bf16|f32)\[([\d,]+)\]\{([\d,]+)", shape)]
+    tiles = {(dims[order[1]], dims[order[0]]) for dims, order in arrays
+             if len(dims) >= 4 and 4096 in dims and dims[order[0]] != 4096}
+    assert tiles and all(rows == 4096 for rows, _ in tiles), tiles
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.8522 * 2 ** 30
 
 
 @pytest.mark.parametrize("cell,kwargs,temp_gib,tiles_at_16", [

@@ -230,6 +230,61 @@ def test_shift_tokens_is_zero_at_a_boundary_and_at_the_start():
     assert cca.shift_tokens(x, 0, seg) is x
 
 
+@pytest.mark.parametrize("axis", [1, 2, 3])
+def test_shift_tokens_shifts_along_the_axis_the_tokens_stand_on(axis):
+    """The head-major sublayer holds its tokens on axis 2: the shift, its
+    zero at the start and at a document's boundary (another one in each
+    row) follow the axis, whatever else the array holds."""
+    rows = jnp.arange(1.0, 13.0).reshape(2, 6)
+    seg = jnp.asarray([[0, 0, 0, 1, 1, 1], [0, 0, 1, 1, 1, 1]])
+    x = jnp.moveaxis(rows[:, :, None, None] * jnp.asarray([[1.0, -1.0], [2.0, 0.5], [3.0, 4.0]]),
+                     1, axis)                                   # [2, 6, 3, 2] with the 6 on `axis`
+    one = jnp.moveaxis(cca.shift_tokens(x, 1, seg, axis=axis), axis, 1)
+    assert one[0, :, 0, 0].tolist() == [0, 1, 2, 0, 4, 5]
+    assert one[1, :, 2, 1].tolist() == [0, 28, 0, 36, 40, 44]
+    two = jnp.moveaxis(cca.shift_tokens(x, 2, seg, axis=axis), axis, 1)
+    assert two[0, :, 1, 0].tolist() == [0, 0, 2, 0, 0, 8] and two[1, :, 0, 0].tolist() == [0, 0, 0, 0, 9, 10]
+    unpacked = jnp.moveaxis(cca.shift_tokens(x, 1, None, axis=axis), axis, 1)
+    assert unpacked[1, :, 0, 0].tolist() == [0, 7, 8, 9, 10, 11]
+    assert cca.shift_tokens(x, 0, seg, axis=axis) is x
+
+
+@pytest.mark.parametrize("what", ["forward", "gradients"])
+def test_cca_sublayer_over_packed_documents_is_the_references(what):
+    """Uneven documents, other ones in each row, four query heads a
+    key-value head and taps (3, 2): the sublayer with `segment_ids`
+    (positions restarting in every document) against the reference on
+    each row with its `segments`, in value and in the gradients of a
+    fixed cotangent with respect to the hidden state and every weight."""
+    cfg = dataclasses.replace(FP32, n_heads=8, n_kv_heads=2, conv_kernels=(3, 2))
+    assert cfg.n_heads // cfg.n_kv_heads == 4
+    lp = layer_of(seeded_params(cfg), 1)
+    lp = {k: lp[k] for k in ("ln1", *cca.attention_axes())}
+    h = jax.random.normal(jax.random.key(8), (B, S, cfg.d_model), jnp.float32)
+    ct = jax.random.normal(jax.random.key(9), (B, S, cfg.d_model), jnp.float32)
+    seg = jnp.asarray([np.repeat([0, 1, 2], [5, 12, 7]), np.repeat([0, 1, 2, 3], [1, 9, 3, 11])],
+                      jnp.int32)
+
+    def program(h, lp):
+        x = rms_norm(h, lp["ln1"], cfg.rms_eps)
+        return h + cca.cca_sublayer(x, lp, cfg, positions=llama.packed_positions(seg, S),
+                                    segment_ids=seg)
+
+    def reference(h, lp):
+        return jnp.stack([zaya_decoder.cca(h[b], lp, shape_of(cfg), seg[b]) for b in range(B)])
+
+    with jax.default_matmul_precision("highest"):
+        if what == "forward":
+            np.testing.assert_allclose(np.asarray(program(h, lp)), np.asarray(reference(h, lp)),
+                                       rtol=2e-5, atol=2e-5)
+            return
+        got = jax.grad(lambda h, lp: (program(h, lp) * ct).sum(), argnums=(0, 1))(h, lp)
+        want = jax.grad(lambda h, lp: (reference(h, lp) * ct).sum(), argnums=(0, 1))(h, lp)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
+        worst = float(jnp.abs(g - w).max()) / float(jnp.abs(w).max())
+        assert worst < 2e-4, (jax.tree_util.keystr(path), worst)
+
+
 # -- the share adds up ---------------------------------------------------------------
 
 
