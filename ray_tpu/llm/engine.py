@@ -238,8 +238,9 @@ class EngineConfig:
             raise ValueError(
                 "LLMEngine serves dense llama-family models; MoE serving "
                 "is not implemented (training-side MoE lives in models/moe.py; "
-                "Mixtral, OLMoE and ZAYA1, whose compressed convolutional attention "
-                "has no cache here either, are training-only)"
+                "Mixtral, OLMoE, ZAYA1, whose compressed convolutional attention "
+                "has no cache here either, and GLM-4.7-Flash, whose latent attention "
+                "would be served in its absorbed form, are training-only)"
             )
         # a prefill bucket longer than the context window can never be
         # used; clamping keeps bucket compilation bounded by the model

@@ -124,6 +124,19 @@ def _cca(config: LlamaConfig):
     return cca
 
 
+def _mla(config: LlamaConfig):
+    """models/mla.py when the configuration's attention is multi-head
+    latent attention (a `GlmLiteConfig`), else None. Such a configuration
+    may also have leading dense layers before its stack of expert layers
+    and a multi-token-prediction block after it: that module builds the
+    tree, `_trunk` and `loss_and_weight_fn` run it."""
+    if not hasattr(config, "kv_lora_rank"):
+        return None
+    from ray_tpu.models import mla
+
+    return mla
+
+
 def _carries_router_state(config: LlamaConfig) -> bool:
     """An MLP router adds the previous layer's state to its own: the
     layer scan then carries (hidden state, router state)."""
@@ -132,6 +145,9 @@ def _carries_router_state(config: LlamaConfig) -> bool:
 
 def logical_axes(config: LlamaConfig) -> Params:
     """Pytree (parallel to params) of logical-axis tuples."""
+    mla = _mla(config)
+    if mla is not None and mla.has_more_than_the_stack(config):
+        return mla.logical_axes(config)
     layer = {
         "ln1": ("layers", "norm"),
         "wq": ("layers", "embed", "heads"),
@@ -141,8 +157,9 @@ def logical_axes(config: LlamaConfig) -> Params:
         "ln2": ("layers", "norm"),
     }
     moe, cca = _moe(config), _cca(config)
-    if cca is not None:
-        layer = {"ln1": layer["ln1"], "ln2": layer["ln2"], **cca.attention_axes()}
+    if cca is not None or mla is not None:
+        layer = {"ln1": layer["ln1"], "ln2": layer["ln2"],
+                 **(cca or mla).attention_axes()}
     if moe is None:
         layer.update(
             w_gate=("layers", "embed", "mlp"),
@@ -165,6 +182,9 @@ def logical_axes(config: LlamaConfig) -> Params:
 
 def init_params(config: LlamaConfig, key: jax.Array) -> Params:
     c = config
+    mla = _mla(c)
+    if mla is not None and mla.has_more_than_the_stack(c):
+        return mla.init_params(c, key)
     keys = jax.random.split(key, 8)
     hd = c.head_dim
     L = c.n_layers
@@ -187,8 +207,8 @@ def init_params(config: LlamaConfig, key: jax.Array) -> Params:
             ffn["q_norm"] = jnp.ones((L, c.n_heads * hd), c.param_dtype)
             ffn["k_norm"] = jnp.ones((L, c.n_kv_heads * hd), c.param_dtype)
     cca = _cca(c)
-    if cca is not None:
-        attn = cca.attention_params(c, keys[1])
+    if cca is not None or mla is not None:
+        attn = (cca or mla).attention_params(c, keys[1])
     else:
         attn = {
             "wq": dense(keys[1], (c.d_model, c.n_heads * hd)),
@@ -238,15 +258,18 @@ def _block(
     sin: Optional[jax.Array],
     positions: jax.Array,
     segment_ids: Optional[jax.Array],
+    dense_ffn: bool = False,
 ) -> tuple[Any, Optional[Params]]:
     """One decoder layer -> (carry, the layer's statistics): the
     attention sublayer of the configuration's kind (full causal GQA with
     rotary, and the q/k RMSNorm when the configuration has it; or
-    compressed convolutional attention, models/cca.py), then the dense
-    SwiGLU or the expert layer (models/moe.py, whose statistics come
-    back; None for a dense layer) by the configuration's own kind."""
+    compressed convolutional attention, models/cca.py; or multi-head
+    latent attention, models/mla.py), then the dense SwiGLU or the
+    expert layer (models/moe.py, whose statistics come back; None for a
+    dense layer) by the configuration's own kind; `dense_ffn`: one of an
+    expert configuration's leading dense layers."""
     c = config
-    moe, cca = _moe(c), _cca(c)
+    moe, cca, mla = None if dense_ffn else _moe(c), _cca(c), _mla(c)
     carries_router = _carries_router_state(c)
     h, router_state = carry if carries_router else (carry, None)
     B, S, D = h.shape
@@ -258,13 +281,16 @@ def _block(
     # the full attention's and the dense MLP's: CCA's and the expert
     # layer's matmuls are the partitioner's to place.)
     mesh = current_mesh()
-    overlap = cca is None and mesh is not None and mesh.shape.get("tp", 1) > 1
+    overlap = (cca is None and mla is None and mesh is not None
+               and mesh.shape.get("tp", 1) > 1)
     if overlap:
         from ray_tpu.parallel.tp_overlap import ag_matmul, rs_matmul
 
     x = rms_norm(h, lp["ln1"], c.rms_eps)
     if cca is not None:
         h = h + cca.cca_sublayer(x, lp, c, positions=positions, segment_ids=segment_ids)
+    elif mla is not None:
+        h = h + mla.mla_sublayer(x, lp, c, positions=positions, segment_ids=segment_ids)
     else:
         if overlap:
             q, k, v = ag_matmul(x, [lp[n].astype(x.dtype) for n in ("wq", "wk", "wv")])
@@ -322,6 +348,31 @@ def hidden_states(
     return h
 
 
+def _remat(block, c: LlamaConfig):
+    """`block` rematerialised by the configuration's policy."""
+    if not c.remat:
+        return block
+    if c.remat_policy == "dots":
+        return jax.checkpoint(
+            block,
+            policy=jax.checkpoint_policies.save_from_both_policies(
+                jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+                # tp_rs_out: a row-parallel matmul's output where
+                # parallel/tp_overlap.py sums it (there the dot the
+                # first policy sees is only one chip's product)
+                # moe_gate, moe_up: the expert layer's first two
+                # grouped matmuls (models/moe.py), which are no
+                # dot_general either
+                jax.checkpoint_policies.save_only_these_names(
+                    "attn_out", "attn_lse", "tp_rs_out", "moe_gate", "moe_up"
+                ),
+            ),
+        )
+    if c.remat_policy == "full":
+        return jax.checkpoint(block)
+    raise ValueError(f"unknown remat_policy {c.remat_policy!r}; 'full' or 'dots'")
+
+
 def _decoder(
     params: Params,
     tokens: jax.Array,
@@ -332,6 +383,21 @@ def _decoder(
 ) -> tuple[jax.Array, Optional[Params]]:
     """`hidden_states` and the layers' statistics, each leaf stacked
     over the layers (None for a dense configuration)."""
+    h, stats, _ = _trunk(params, tokens, config, positions=positions, segment_ids=segment_ids)
+    return rms_norm(h, params["final_norm"], config.rms_eps), stats
+
+
+def _trunk(
+    params: Params,
+    tokens: jax.Array,
+    config: LlamaConfig,
+    *,
+    positions: Optional[jax.Array] = None,
+    segment_ids: Optional[jax.Array] = None,
+) -> tuple[jax.Array, Optional[Params], Any]:
+    """The layers, up to the last one's output BEFORE the final norm ->
+    (h [B, S, D], the layers' statistics, the rematerialised block the
+    stack ran: what a multi-token-prediction module runs once more)."""
     c = config
     B, S = tokens.shape
     if S > c.max_seq:
@@ -341,8 +407,10 @@ def _decoder(
         )
     if positions is None:
         positions = packed_positions(segment_ids, S)
+    mla = _mla(c)
     cos = sin = None
-    if _cca(c) is None:  # CCA rotates part of a head, from the positions themselves
+    # CCA rotates part of a head and MLA its decoupled part, from the positions themselves
+    if _cca(c) is None and mla is None:
         cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
 
     h = params["embed"].astype(c.dtype)[tokens]  # [B, S, D]
@@ -350,29 +418,15 @@ def _decoder(
     block = partial(
         _block, config=c, cos=cos, sin=sin, positions=positions, segment_ids=segment_ids
     )
-    if c.remat:
-        if c.remat_policy == "dots":
-            block = jax.checkpoint(
-                block,
-                policy=jax.checkpoint_policies.save_from_both_policies(
-                    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-                    # tp_rs_out: a row-parallel matmul's output where
-                    # parallel/tp_overlap.py sums it (there the dot the
-                    # first policy sees is only one chip's product)
-                    # moe_gate, moe_up: the expert layer's first two
-                    # grouped matmuls (models/moe.py), which are no
-                    # dot_general either
-                    jax.checkpoint_policies.save_only_these_names(
-                        "attn_out", "attn_lse", "tp_rs_out", "moe_gate", "moe_up"
-                    ),
-                ),
-            )
-        elif c.remat_policy == "full":
-            block = jax.checkpoint(block)
-        else:
-            raise ValueError(
-                f"unknown remat_policy {c.remat_policy!r}; 'full' or 'dots'"
-            )
+    layers = params["layers"]
+    if mla is not None and mla.has_more_than_the_stack(c):
+        # two kinds of block in one model: the leading dense layers run
+        # before the scan over the expert layers' stack
+        layers = mla.stack_of(params, c)
+        dense = _remat(partial(block, dense_ffn=True), c)
+        for i in range(c.first_dense_layers):
+            h, _ = dense(h, mla.dense_layer(params, i))
+    block = _remat(block, c)
 
     mesh = current_mesh()
     pp = mesh.shape.get("pp", 1) if mesh is not None else 1
@@ -397,16 +451,16 @@ def _decoder(
             return out
 
         h, stats = pipeline_apply(
-            mesh, stage, stack_stages(params["layers"], pp), h, n_micro=pp
+            mesh, stage, stack_stages(layers, pp), h, n_micro=pp
         ), None
     elif _carries_router_state(c):
         # nothing precedes the first layer's router: a state of zeros adds nothing
         state = jnp.zeros((B, S, c.router_hidden), jnp.float32)
-        (h, _), stats = jax.lax.scan(block, (h, state), params["layers"])
+        (h, _), stats = jax.lax.scan(block, (h, state), layers)
     else:
-        h, stats = jax.lax.scan(block, h, params["layers"])
+        h, stats = jax.lax.scan(block, h, layers)
 
-    return rms_norm(h, params["final_norm"], c.rms_eps), stats
+    return h, stats, block
 
 
 def output_weight(params: Params) -> jax.Array:
@@ -450,16 +504,21 @@ def loss_and_weight_fn(
     An expert configuration adds its two router losses (each the mean
     over layers, at the configuration's coefficients) to the loss and
     returns a third element, the layers' statistics (models/moe.py),
-    which train/step.py hands out with the step's metrics.
+    which train/step.py hands out with the step's metrics. A
+    configuration with a multi-token-prediction block (models/mla.py)
+    adds that head's loss at its weight; the statistics then carry the
+    block's row after the layers' and the two losses apart
+    (`loss_main`, `loss_mtp`).
 
     Uses the fused lm-head + CE (nn/layers.py fused_cross_entropy_loss):
     the [T, V] fp32 logits/softmax pipeline was ~36% of the flagship
     train step before fusion (round-5 profile)."""
     import os
 
-    h, stats = _decoder(
+    h_last, stats, block = _trunk(
         params, batch["tokens"], config, segment_ids=batch.get("segment_ids")
     )
+    h = rms_norm(h_last, params["final_norm"], config.rms_eps)
     # A/B probe hook (benchmarks). Read at TRACE time: flipping it in a
     # process that already compiled the step has no effect — set it in a
     # fresh process (the benchmark harnesses fork per variant).
@@ -472,6 +531,24 @@ def loss_and_weight_fn(
         )
     if stats is None:
         return loss, weight
+    mla = _mla(config)
+    if mla is not None and config.mtp_layers:
+        # a second prediction head (models/mla.py): the last layer's output merged
+        # with the next token's embedding, one more block, the SAME head on the
+        # targets shifted by one; the last position has no target there
+        targets, mask = batch["targets"], batch.get("mask")
+        m, mtp_stats = mla.mtp_hidden(params, h_last, targets, config, block)
+        with jax.named_scope("mtp.head"):
+            m = rms_norm(m, params["mtp"]["final_norm"], config.rms_eps)
+            ahead = jnp.pad(targets[:, 1:], ((0, 0), (0, 1)))
+            has_target = jnp.arange(targets.shape[1]) < targets.shape[1] - 1
+            if mask is not None:
+                has_target = has_target & (mask * jnp.pad(mask[:, 1:], ((0, 0), (0, 1))) > 0)
+            loss_mtp, _ = fused_cross_entropy_loss(
+                m, output_weight(params), ahead, jnp.broadcast_to(has_target, targets.shape))
+        stats = jax.tree.map(lambda a, b: jnp.concatenate([a, b[None]]), stats, mtp_stats)
+        stats.update(loss_main=loss, loss_mtp=loss_mtp)
+        loss = loss + config.mtp_loss_weight * loss_mtp
     router = (config.router_aux_coeff * stats["balance_loss"].mean()
               + config.router_z_coeff * stats["z_loss"].mean())
     return loss + router, weight, stats

@@ -1,4 +1,4 @@
-"""Sparse expert feed-forward layer (Mixtral, OLMoE, ZAYA1) for the one decoder.
+"""Sparse expert feed-forward layer (Mixtral, OLMoE, ZAYA1, GLM-4.7-Flash) for the one decoder.
 
 The reference has NO expert parallelism (SURVEY.md §2.4 — absent from
 python/ray/llm); this is a native capability. This module holds what an
@@ -43,6 +43,20 @@ models/cca.py, asks both):
     contribute zero to the output and to every gradient (they are not
     dropped pairs: another chip computes them) and are counted apart.
     No code stands in for the absent chips or their exchange.
+
+And two that GLM-4.7-Flash (models/mla.py) asks, in the DeepSeek-V3 form
+(arXiv:2412.19437, arXiv:2408.15664):
+
+  * `router_score` "sigmoid": each expert's score is the sigmoid of its
+    logit, by itself; the `top_k` experts with the largest score PLUS a
+    selection bias (`router_bias`, which takes no gradient and is no
+    part of the weight) are chosen; the weights are the chosen scores
+    themselves, renormalised over the chosen (`norm_topk_prob`) and
+    multiplied by `routed_scaling`;
+  * `shared_d_ff`: a shared expert of that width, a dense SwiGLU every
+    token runs, added to the routed sum. It is computed whole on every
+    chip of a deployment (and counted once where shares are added up),
+    under the named scope `shared.ffn`: it is no part of `moe.*`.
 """
 
 from __future__ import annotations
@@ -57,7 +71,7 @@ import jax.numpy as jnp
 
 from ray_tpu import obs
 from ray_tpu.models import llama
-from ray_tpu.nn.layers import init_dense, rms_norm
+from ray_tpu.nn.layers import init_dense, rms_norm, swiglu
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 
 Params = dict[str, Any]
@@ -81,6 +95,11 @@ class MoEConfig(llama.LlamaConfig):
     # `router_hidden`, plus the previous layer's state, then an MLP (ZAYA1)
     router_kind: str = "linear"
     router_hidden: int = 0
+    # "softmax" over all the experts' logits, or "sigmoid" of each by
+    # itself, chosen by score + `router_bias` (GLM-4.7-Flash; linear router)
+    router_score: str = "softmax"
+    routed_scaling: float = 1.0  # on the routed experts' weights
+    shared_d_ff: int = 0         # width of the shared expert (0: none)
     # this chip's share: experts first_expert_held .. + experts_held of
     # n_experts are in the parameters (None: all of them)
     experts_held: Optional[int] = None
@@ -95,13 +114,17 @@ class MoEConfig(llama.LlamaConfig):
         with the `top_k` experts a token runs and the router in place of
         the one MLP."""
         dense_mlp = 2 * self.d_model * self.d_ff * 3
-        routed = self.top_k * dense_mlp + 2 * self.d_model * self.n_experts
+        routed = (self.top_k * dense_mlp + 2 * self.d_model * self.n_experts
+                  + 2 * self.d_model * self.shared_d_ff * 3)
         return super().flops_per_token(seq_len) + self.n_layers * (routed - dense_mlp)
 
     def num_params(self) -> int:
         d, f, E, r = self.d_model, self.d_ff, self.n_experts, self.router_hidden
         router = d * E if self.router_kind == "linear" else d * r + 2 * r * r + r * E + 2 * r + E
-        ffn = self.n_held * 3 * d * f + router  # the experts held here + router
+        if self.router_score == "sigmoid":
+            router += E  # the selection bias
+        # the experts held here + the shared expert + router
+        ffn = self.n_held * 3 * d * f + 3 * d * self.shared_d_ff + router
         qk = d + self.n_kv_heads * self.head_dim if self.qk_norm else 0
         return super().num_params() + self.n_layers * (ffn + qk - 3 * d * f)
 
@@ -131,7 +154,12 @@ def expert_axes(config: Optional[MoEConfig] = None) -> Params:
         "w_up": ("layers", "expert", "embed", "mlp"),
         "w_down": ("layers", "expert", "mlp", "embed"),
     }
+    if config is not None and config.shared_d_ff:
+        axes.update(shared_gate=("layers", "embed", "mlp"), shared_up=("layers", "embed", "mlp"),
+                    shared_down=("layers", "mlp", "embed"))
     if config is None or config.router_kind == "linear":
+        if config is not None and config.router_score == "sigmoid":
+            axes["router_bias"] = ("layers", "expert")
         return {"router": ("layers", "embed", "expert"), **axes}
     return {
         "router_down": ("layers", "embed", None),
@@ -163,6 +191,8 @@ def expert_params(config: MoEConfig, key: jax.Array) -> Params:
 
     if c.router_kind == "linear":
         router = {"router": per_layer(keys[0], (c.d_model, E))}
+        if c.router_score == "sigmoid":
+            router["router_bias"] = jnp.zeros((L, E), c.param_dtype)
     else:
         r = c.router_hidden
         k_down, k1, k2, k3 = jax.random.split(keys[0], 4)
@@ -175,6 +205,11 @@ def expert_params(config: MoEConfig, key: jax.Array) -> Params:
             "router_w3": per_layer(k3, (r, E)),
             "router_bias": jnp.zeros((L, E), c.param_dtype),
         }
+    if c.shared_d_ff:
+        k_gate, k_up, k_down = jax.random.split(jax.random.fold_in(key, 7), 3)
+        router.update(shared_gate=per_layer(k_gate, (c.d_model, c.shared_d_ff)),
+                      shared_up=per_layer(k_up, (c.d_model, c.shared_d_ff)),
+                      shared_down=per_layer(k_down, (c.shared_d_ff, c.d_model)))
     return {
         **router,
         "w_gate": per_expert(keys[1], (c.d_model, c.d_ff)),
@@ -289,7 +324,13 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig,
     `pairs_elsewhere`: pairs routed to experts another chip holds, which
     are counted in `tokens_per_expert`, multiplied by nothing here and
     contribute zero; N * top_k less it is the rows the grouped matmuls
-    really multiplied.
+    really multiplied. With `top_k` > 1 a token may have some of its
+    pairs here and some elsewhere: each PAIR is held or elsewhere by its
+    own expert, the held ones sorted first, and `dropped_pairs` stays 0.
+    A sigmoid router's `balance_loss` takes a token's scores as shares
+    of their sum, and its `z_loss` is 0. The shared expert
+    (`shared_d_ff`) is no pair and is in none of the counts: every token
+    runs it, here, whole, whatever share of the routed experts is held.
 
     The router reads the compute-dtype stream but multiplies, takes its
     softmax and chooses in float32 (`highest`: a float32 matmul is one
@@ -312,16 +353,29 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig,
                 logits = jnp.einsum(
                     "nd,de->ne", xt.astype(jnp.float32), lp["router"].astype(jnp.float32),
                     precision=jax.lax.Precision.HIGHEST)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            probs = jnp.exp(logits - lse[:, None])  # [N, E]
-            if c.router_kind == "mlp":
-                # chosen by probability + bias; weighted by the probability alone
-                _, chosen = jax.lax.top_k(probs + lp["router_bias"].astype(jnp.float32), K)
-                w = jnp.take_along_axis(probs, chosen, axis=-1)
+            if c.router_score == "sigmoid":
+                # chosen by score + bias; weighted by the scores alone, renormalised
+                # over the chosen and scaled (arXiv:2412.19437, eq. 12-16)
+                scores = jax.nn.sigmoid(logits)
+                _, chosen = jax.lax.top_k(scores + lp["router_bias"].astype(jnp.float32), K)
+                w = jnp.take_along_axis(scores, chosen, axis=-1)
+                if c.norm_topk_prob:
+                    w = w / (w.sum(-1, keepdims=True) + 1e-20)
+                w = w * c.routed_scaling
+                # for the balance statistic: the scores as shares of a token's total;
+                # a sigmoid router has no partition function to hold down
+                probs, lse = scores / scores.sum(-1, keepdims=True), jnp.zeros((N,), jnp.float32)
             else:
-                w, chosen = jax.lax.top_k(probs, K)     # [N, K]
-            if c.norm_topk_prob:
-                w = w / w.sum(-1, keepdims=True)
+                lse = jax.nn.logsumexp(logits, axis=-1)
+                probs = jnp.exp(logits - lse[:, None])  # [N, E]
+                if c.router_kind == "mlp":
+                    # chosen by probability + bias; weighted by the probability alone
+                    _, chosen = jax.lax.top_k(probs + lp["router_bias"].astype(jnp.float32), K)
+                    w = jnp.take_along_axis(probs, chosen, axis=-1)
+                else:
+                    w, chosen = jax.lax.top_k(probs, K)     # [N, K]
+                if c.norm_topk_prob:
+                    w = w / w.sum(-1, keepdims=True)
             flat = chosen.reshape(N * K)
             counts = jnp.sum(flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :],
                              axis=0, dtype=jnp.int32)
@@ -363,4 +417,8 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig,
     }
     if tail:
         stats["pairs_elsewhere"] = N * K - sizes.sum()
-    return out.reshape(B, S, D), stats, router_state
+    out = out.reshape(B, S, D)
+    if c.shared_d_ff:
+        with jax.named_scope("shared.ffn"):
+            out = out + swiglu(x, lp["shared_gate"], lp["shared_up"], lp["shared_down"])
+    return out, stats, router_state

@@ -10,14 +10,18 @@ runs a sparse expert layer in place of its MLP for a MoEConfig
 top-8 of 64; ZAYA1-8B, `zaya1-8b`, with compressed convolutional
 attention in place of the block's own (models/cca.py), an MLP router
 whose state is carried from layer to layer and top-1 of 16 experts, of
-which a chip may hold a share. Every expert configuration is TRAINING
-only: the serving engine refuses a MoEConfig, ZAYA1 by name).
+which a chip may hold a share; GLM-4.7-Flash, `glm-4.7-flash`, with
+multi-head latent attention at heads of 256 (models/mla.py), a leading
+dense layer, sigmoid top-4 of 64 experts chosen with a selection bias,
+a shared expert and a multi-token-prediction block. Every expert
+configuration is TRAINING only: the serving engine refuses a MoEConfig,
+ZAYA1 and GLM-4.7-Flash by name).
 The registry gives users the same two entry points they expect:
 
   * `get_model_config("llama3-8b")` — named presets;
   * `config_from_hf(json.load(open("config.json")))` — map a HF
-    transformers config dict onto LlamaConfig/MoEConfig/ZayaConfig (no
-    downloads; weight conversion is a separate concern).
+    transformers config dict onto LlamaConfig/MoEConfig/ZayaConfig/
+    GlmLiteConfig (no downloads; weight conversion is a separate concern).
 """
 
 from __future__ import annotations
@@ -25,7 +29,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
-from ray_tpu.models import cca, llama, moe
+from ray_tpu.models import cca, llama, mla, moe
 
 _REGISTRY: dict[str, Any] = {}
 
@@ -82,6 +86,8 @@ for _name, _cfg in {
     "moe-tiny": moe.MOE_TINY,
     "zaya1-8b": cca.ZAYA1_8B,
     "zaya-tiny": cca.ZAYA_TINY,
+    "glm-4.7-flash": mla.GLM_4_7_FLASH,
+    "glm-lite-tiny": mla.GLM_LITE_TINY,
 }.items():
     register_model(_name, _cfg)
 
@@ -129,8 +135,53 @@ def _zaya_from_hf(hf: dict, **overrides) -> cca.ZayaConfig:
     return dataclasses.replace(cca.ZAYA1_8B, **fields)
 
 
+def _glm_lite_from_hf(hf: dict, **overrides) -> mla.GlmLiteConfig:
+    """`model_type` "glm4_moe_lite" (zai-org/GLM-4.7-Flash): MLA with the
+    explicit head sizes, `first_k_dense_replace` dense layers, then sigmoid
+    top-k routed experts beside `n_shared_experts` shared ones, and
+    `num_nextn_predict_layers` multi-token-prediction blocks. What this
+    decoder does not implement is refused by name."""
+    refused = {
+        f"rope_scaling {hf.get('rope_scaling')!r}": hf.get("rope_scaling") is not None,
+        f"n_group {hf.get('n_group')} (group-limited routing)": hf.get("n_group", 1) > 1,
+        f"topk_method {hf.get('topk_method')!r}": hf.get("topk_method", "noaux_tc") != "noaux_tc",
+        "attention_bias": bool(hf.get("attention_bias")),
+        f"hidden_act {hf.get('hidden_act')!r}": hf.get("hidden_act", "silu") != "silu",
+        f"partial_rotary_factor {hf.get('partial_rotary_factor')}":
+            hf.get("partial_rotary_factor", 1) != 1,
+        "q_lora_rank null": hf.get("q_lora_rank") is None,
+        f"{hf.get('num_nextn_predict_layers')} multi-token-prediction blocks":
+            hf.get("num_nextn_predict_layers", 0) > 1,
+        "key-value heads other than the query heads":
+            hf.get("num_key_value_heads", hf["num_attention_heads"]) != hf["num_attention_heads"],
+    }
+    if any(refused.values()):
+        raise ValueError("a glm4_moe_lite config with "
+                         + ", ".join(k for k, v in refused.items() if v) + " is not supported")
+    fields = dict(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"],
+        n_layers=hf["num_hidden_layers"], n_heads=hf["num_attention_heads"],
+        n_kv_heads=hf["num_attention_heads"], d_ff=hf["moe_intermediate_size"],
+        max_seq=hf["max_position_embeddings"], rope_theta=float(hf["rope_theta"]),
+        rms_eps=float(hf["rms_norm_eps"]),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        n_experts=hf["n_routed_experts"], top_k=hf["num_experts_per_tok"],
+        norm_topk_prob=bool(hf["norm_topk_prob"]),
+        routed_scaling=float(hf["routed_scaling_factor"]),
+        shared_d_ff=hf.get("n_shared_experts", 0) * hf["moe_intermediate_size"],
+        q_lora_rank=hf["q_lora_rank"], kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"], qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"], dense_d_ff=hf["intermediate_size"],
+        first_dense_layers=hf.get("first_k_dense_replace", 0),
+        mtp_layers=hf.get("num_nextn_predict_layers", 0),
+    )
+    fields.update(overrides)  # caller wins on collisions
+    return dataclasses.replace(mla.GLM_4_7_FLASH, **fields)
+
+
 def config_from_hf(hf: dict, **overrides):
-    """Map a HF `config.json` dict to a LlamaConfig/MoEConfig/ZayaConfig.
+    """Map a HF `config.json` dict to a LlamaConfig/MoEConfig/ZayaConfig/
+    GlmLiteConfig.
 
     Only architecture hyperparameters travel; framework knobs
     (dtype/remat/attention_impl) keep their TPU defaults unless
@@ -140,10 +191,13 @@ def config_from_hf(hf: dict, **overrides):
     `intermediate_size` is the width of one expert, q and k are
     normalised, and the top-k weights are renormalised only if
     `norm_topk_prob` says so. ZAYA1 (`model_type` "zaya"): see
-    `_zaya_from_hf`.
+    `_zaya_from_hf`. GLM-4.7-Flash (`model_type` "glm4_moe_lite", whose
+    heads are not hidden_size / heads wide): see `_glm_lite_from_hf`.
     """
     if hf.get("model_type") == "zaya":
         return _zaya_from_hf(hf, **overrides)
+    if hf.get("model_type") == "glm4_moe_lite":
+        return _glm_lite_from_hf(hf, **overrides)
     archs = set(hf.get("architectures", ()))
     is_olmoe = "OlmoeForCausalLM" in archs or (not archs and hf.get("model_type") == "olmoe")
     # the num_local_experts heuristic only applies to config dicts with NO

@@ -488,6 +488,26 @@ def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
     )(q, k, v, qseg, kseg)
 
 
+# What Mosaic scopes to one kernel by default on a TPU. The fused backward
+# holds the whole kv block: k, v, dk and dv double-buffered in the input
+# dtype, dk and dv again as float32 scratch. At head_dim 128 and 4096 keys
+# that is 12 MiB and fits; at head_dim 256 (MLA, models/mla.py) it is 24
+# MiB, and the kernel then states its own limit rather than lean on the
+# caller's compile options (a train step's 32 MiB, train/step.py).
+_DEFAULT_SCOPED_VMEM = 16 << 20
+_VMEM_HEADROOM = 8 << 20  # the [rows, sub_k] float32 products and Mosaic's own stack
+
+
+def _fused_bwd_params(block_q: int, block_k: int, D: int, F: int, itemsize: int):
+    """Compiler parameters of the fused backward: None (Mosaic's default)
+    where its blocks fit the default scoped VMEM, else the limit they need."""
+    kv = 4 * 2 * block_k * D * itemsize + 2 * block_k * D * 4
+    rows = 3 * 2 * F * block_q * D * itemsize + F * block_q * D * 4
+    if kv + rows <= _DEFAULT_SCOPED_VMEM:  # 13-14 MiB at head_dim 128, bf16, 4096 keys
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=kv + rows + _VMEM_HEADROOM)
+
+
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, do_ref,
                       lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
                       dk_scr, dv_scr, dq_scr, **statics):
@@ -554,6 +574,7 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
                 pltpu.VMEM((F * block_q, D), jnp.float32),
             ],
             interpret=interpret,
+            compiler_params=_fused_bwd_params(block_q, block_k, D, F, q.dtype.itemsize),
         )(q, k, v, qseg, kseg, do, lse, delta)
         return dq, dk, dv
 

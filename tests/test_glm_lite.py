@@ -1,0 +1,479 @@
+"""GLM-4.7-Flash through the one decoder (PR 34), at a small size on the
+CPU, seeded weights, against the plain reference
+(chipbench/reference/glm_lite_decoder.py, imported): the MLA sublayer
+alone, sigmoid top-k routing with its bias, renormalisation, scaling and
+the shared expert, the whole train path (the dense layer and the MTP
+block with it) in loss and gradients, MTP's shift, the shares that add
+up, `config_from_hf` on the catalog's config, and the kernels at the new
+shapes (flash at heads of 256, grouped matmul at K 2048 / N 1536)."""
+
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench.reference import glm_lite_decoder
+from ray_tpu.models import llama, mla, moe
+from ray_tpu.models.registry import config_from_hf, get_model_config
+from ray_tpu.nn.layers import rms_norm
+from ray_tpu.ops import grouped_matmul as gm
+from ray_tpu.ops.attention import xla_attention
+from ray_tpu.ops.flash import flash_attention
+
+FP32 = dataclasses.replace(mla.GLM_LITE_TINY, dtype=jnp.float32)
+B, S = 2, 24
+CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+
+
+def shape_of(cfg) -> dict:
+    """A GlmLiteConfig as the configuration file's dict (HF key names)."""
+    return {
+        "hidden_size": cfg.d_model, "num_attention_heads": cfg.n_heads,
+        "q_lora_rank": cfg.q_lora_rank, "kv_lora_rank": cfg.kv_lora_rank,
+        "qk_nope_head_dim": cfg.qk_nope_head_dim, "qk_rope_head_dim": cfg.qk_rope_head_dim,
+        "v_head_dim": cfg.v_head_dim, "rope_theta": cfg.rope_theta,
+        "rms_norm_eps": cfg.rms_eps, "n_routed_experts": cfg.n_held,
+        "published": {"n_routed_experts": cfg.n_experts},
+        "deployment": {"first_expert_held": cfg.first_expert_held},
+        "num_experts_per_tok": cfg.top_k, "norm_topk_prob": cfg.norm_topk_prob,
+        "routed_scaling_factor": cfg.routed_scaling,
+        "first_k_dense_replace": cfg.first_dense_layers,
+        "num_nextn_predict_layers": cfg.mtp_layers, "mtp_loss_weight": cfg.mtp_loss_weight,
+        "max_position_embeddings": cfg.max_seq, "num_hidden_layers": cfg.n_layers,
+        "tie_word_embeddings": cfg.tie_embeddings, "vocab_size": cfg.vocab_size,
+    }
+
+
+def seeded_params(cfg, seed=0):
+    """init_params, with the leaves that start at one or zero moved off
+    them, so that a test sees every norm and the selection bias."""
+    params = llama.init_params(cfg, jax.random.key(seed))
+    keys = iter(jax.random.split(jax.random.key(seed + 100), 64))
+
+    def spread(tree, names):
+        for name in names:
+            tree[name] = tree[name] + 0.2 * jax.random.normal(next(keys), tree[name].shape)
+
+    norms = ("ln1", "ln2", "q_a_norm", "kv_a_norm")
+    spread(params["layers"], norms)
+    spread(params["dense_layers"], norms)
+    spread(params["mtp"]["block"], norms)
+    spread(params["mtp"], ("enorm", "hnorm", "final_norm"))
+    spread(params, ("final_norm",))
+    bias = params["layers"]["router_bias"]
+    params["layers"]["router_bias"] = 0.1 * jax.random.normal(next(keys), bias.shape)
+    return params
+
+
+def layer_of(params, i):
+    layers = {**params["layers"], "router_bias": params["layers"]["router_bias"][:-1]}
+    return jax.tree.map(lambda x: x[i], layers)
+
+
+def skewed_tokens(cfg, seed=1):
+    rng = np.random.default_rng(seed)
+    p = 1.0 / np.arange(1, cfg.vocab_size + 1) ** 1.1
+    ids = rng.choice(cfg.vocab_size, size=(B, S + 1), p=p / p.sum())
+    return {"tokens": jnp.asarray(ids[:, :-1], jnp.int32),
+            "targets": jnp.asarray(ids[:, 1:], jnp.int32)}
+
+
+def worst_leaf(got, want, skip=("router_bias",)):
+    """{path: largest difference of a leaf over the leaf's own scale}."""
+    worst = {}
+    for path, g in jax.tree_util.tree_leaves_with_path(got):
+        w = want
+        for k in path:
+            w = w[k.key]
+        name = jax.tree_util.keystr(path)
+        if any(s in name for s in skip):
+            assert float(jnp.abs(g).max()) == 0.0 and float(jnp.abs(w).max()) == 0.0
+            continue
+        worst[name] = float(jnp.abs(g - w).max()) / max(float(jnp.abs(w).max()), 1e-12)
+    return worst
+
+
+# -- the sublayers against the reference ---------------------------------------
+
+
+@pytest.mark.parametrize("what", ["forward", "gradients"])
+def test_mla_sublayer_is_the_references(what):
+    """The attention half of a block alone: x -> x + MLA(RMSNorm(x)), the
+    one rotary key shared by the heads, forward and the gradients of the
+    input and of every weight."""
+    lp = layer_of(seeded_params(FP32), 1)
+    x = jax.random.normal(jax.random.key(3), (B, S, FP32.d_model), jnp.float32)
+    shape = shape_of(FP32)
+
+    def program(x, lp):
+        normed = rms_norm(x, lp["ln1"], FP32.rms_eps)
+        return x + mla.mla_sublayer(normed, lp, FP32, positions=jnp.arange(S), segment_ids=None)
+
+    def reference(x, lp):
+        return jnp.stack([glm_lite_decoder.mla(x[b], lp, shape) for b in range(B)])
+
+    with jax.default_matmul_precision("highest"):
+        if what == "forward":
+            np.testing.assert_allclose(np.asarray(program(x, lp)), np.asarray(reference(x, lp)),
+                                       rtol=2e-5, atol=2e-5)
+            return
+        probe = jax.random.normal(jax.random.key(4), (B, S, FP32.d_model))
+        got = jax.grad(lambda x, lp: (program(x, lp) * probe).sum(), argnums=(0, 1))(x, lp)
+        want = jax.grad(lambda x, lp: (reference(x, lp) * probe).sum(), argnums=(0, 1))(x, lp)
+    used = [k for k in mla.attention_axes()] + ["ln1"]
+    worst = worst_leaf({"x": got[0], **{k: got[1][k] for k in used}},
+                       {"x": want[0], **{k: want[1][k] for k in used}})
+    assert max(worst.values()) < 1e-4, worst
+
+
+def test_the_positions_reach_the_sublayer_through_the_rotary_channels_alone():
+    """Rotary is on q_rot and k_rot alone: with the queries' rotary
+    channels zero (the scores then read no k_rot) the sublayer does not
+    see the positions at all."""
+    lp = layer_of(seeded_params(FP32), 0)
+    x = jax.random.normal(jax.random.key(3), (B, S, FP32.d_model), jnp.float32)
+    run = lambda lp, pos: mla.mla_sublayer(x, lp, FP32, positions=pos, segment_ids=None)  # noqa: E731
+    assert not np.allclose(run(lp, jnp.arange(S)), run(lp, 3 * jnp.arange(S) + 5), atol=1e-4)
+    dn, dr, H = FP32.qk_nope_head_dim, FP32.qk_rope_head_dim, FP32.n_heads
+    wq = lp["wq_b"].reshape(-1, H, dn + dr).at[..., dn:].set(0.0).reshape(lp["wq_b"].shape)
+    blind = {**lp, "wq_b": wq}
+    np.testing.assert_allclose(run(blind, jnp.arange(S)), run(blind, 3 * jnp.arange(S) + 5),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_sigmoid_routing_bias_renormalisation_scaling_and_the_shared_expert():
+    """`moe_ffn` under a sigmoid router, by a per-token loop in numpy: the
+    bias chooses and is no part of the weight; the chosen scores are
+    renormalised over the chosen and scaled by 1.8; the shared expert is
+    added for every token; counts sum to top_k x tokens."""
+    lp = layer_of(seeded_params(FP32), 0)
+    x = jax.random.normal(jax.random.key(5), (B, S, FP32.d_model), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        out, stats, _ = moe.moe_ffn(x, lp, FP32)
+    f = lambda a: np.asarray(a, np.float64)  # noqa: E731
+    xt = f(x).reshape(B * S, -1)
+    silu = lambda a: a / (1.0 + np.exp(-a))  # noqa: E731
+    scores = 1.0 / (1.0 + np.exp(-(xt @ f(lp["router"]))))
+    want, counts = np.zeros_like(xt), np.zeros(FP32.n_experts, np.int64)
+    for t in range(B * S):
+        chosen = np.argsort(-(scores[t] + f(lp["router_bias"])))[:FP32.top_k]
+        w = FP32.routed_scaling * scores[t, chosen] / scores[t, chosen].sum()
+        for e, we in zip(chosen, w):
+            counts[e] += 1
+            want[t] += we * ((silu(xt[t] @ f(lp["w_gate"][e])) * (xt[t] @ f(lp["w_up"][e])))
+                             @ f(lp["w_down"][e]))
+        want[t] += ((silu(xt[t] @ f(lp["shared_gate"])) * (xt[t] @ f(lp["shared_up"])))
+                    @ f(lp["shared_down"]))
+    np.testing.assert_allclose(f(out).reshape(B * S, -1), want, rtol=2e-4, atol=2e-5)
+    assert stats["tokens_per_expert"].tolist() == counts.tolist()
+    assert int(stats["tokens_per_expert"].sum()) == FP32.top_k * B * S
+    assert int(stats["dropped_pairs"]) == 0 and float(stats["z_loss"]) == 0.0
+    # the bias alone moves the choice: with a large bias on one expert every token takes it,
+    # at a weight that is still its own score's share
+    tilted = {**lp, "router_bias": lp["router_bias"].at[3].set(10.0)}
+    _, tilted_stats, _ = moe.moe_ffn(x, tilted, FP32)
+    assert int(tilted_stats["tokens_per_expert"][3]) == B * S
+    # and takes no gradient
+    g = jax.grad(lambda b: moe.moe_ffn(x, {**lp, "router_bias": b}, FP32)[0].sum())(
+        lp["router_bias"])
+    assert float(jnp.abs(g).max()) == 0.0
+
+
+def test_old_router_kinds_keep_their_parameters_and_their_weights():
+    """A softmax router has no selection bias of this kind and no shared
+    expert; its top-k weights are not scaled."""
+    params = llama.init_params(moe.MOE_TINY, jax.random.key(0))
+    assert "router_bias" not in params["layers"] and "shared_gate" not in params["layers"]
+    assert set(moe.expert_axes(moe.MOE_TINY)) == {"router", "w_gate", "w_up", "w_down"}
+    assert set(moe.expert_axes(FP32)) == {"router", "router_bias", "w_gate", "w_up", "w_down",
+                                          "shared_gate", "shared_up", "shared_down"}
+
+
+# -- the whole train path --------------------------------------------------------
+
+
+@pytest.mark.parametrize("held", [None, (4, 2)], ids=["all_experts", "a_share"])
+def test_train_path_meets_the_reference_in_loss_and_gradients(held):
+    """llama.loss_fn (the one train path) on a GLM-4.7-Flash-kind
+    configuration, the dense layer and the MTP block with it, against the
+    plain reference: both losses, the tokens per expert of every block
+    (the MTP block's row last), and every gradient by its worst leaf."""
+    cfg = FP32 if held is None else dataclasses.replace(
+        FP32, experts_held=held[0], first_expert_held=held[1])
+    params, batch, shape = seeded_params(cfg), skewed_tokens(cfg), shape_of(cfg)
+    with jax.default_matmul_precision("highest"):
+        loss, weight, stats = llama.loss_and_weight_fn(params, batch, cfg)
+        got = jax.grad(lambda p: llama.loss_fn(p, batch, cfg))(params)
+        ref = glm_lite_decoder.loss_parts(params, batch["tokens"], batch["targets"], shape)
+        want = jax.grad(lambda p: glm_lite_decoder.loss(
+            p, batch["tokens"], batch["targets"], shape))(params)
+    assert float(weight) == B * S
+    for name in ("loss_main", "loss_mtp"):
+        assert float(stats[name]) == pytest.approx(float(ref[name]), rel=2e-6)
+    assert float(loss) == pytest.approx(float(ref["loss"]), rel=2e-6)
+    assert float(loss) == pytest.approx(
+        float(stats["loss_main"]) + cfg.mtp_loss_weight * float(stats["loss_mtp"]), rel=1e-6)
+    assert stats["tokens_per_expert"].shape == (cfg.n_expert_layers + 1, cfg.n_experts)
+    assert stats["tokens_per_expert"].tolist() == ref["tokens_per_expert"].tolist()
+    assert stats["tokens_per_expert"].sum(-1).tolist() == [cfg.top_k * B * S] * 3
+    assert int(stats["dropped_pairs"].sum()) == 0
+    if held is not None:
+        first, n = held[1], held[0]
+        elsewhere = cfg.top_k * B * S - stats["tokens_per_expert"][:, first:first + n].sum(-1)
+        assert stats["pairs_elsewhere"].tolist() == elsewhere.tolist()
+        assert 0 < int(elsewhere.sum()) < 3 * cfg.top_k * B * S
+    worst = worst_leaf(got, want)
+    assert len(worst) == len(jax.tree.leaves(params)) - 1
+    assert max(worst.values()) < 2e-4, sorted(worst.items(), key=lambda kv: -kv[1])[:3]
+
+
+def test_bf16_compute_stays_near_the_reference():
+    cfg = dataclasses.replace(FP32, dtype=jnp.bfloat16)
+    params, batch = seeded_params(cfg), skewed_tokens(cfg)
+    loss = llama.loss_fn(params, batch, cfg)
+    ref = glm_lite_decoder.loss(params, batch["tokens"], batch["targets"], shape_of(cfg))
+    assert float(loss) == pytest.approx(float(ref), rel=0.02)
+
+
+@pytest.mark.parametrize("remat_policy", ["dots", "full"])
+def test_remat_gives_the_same_gradients(remat_policy):
+    cfg = dataclasses.replace(FP32, remat=True, remat_policy=remat_policy)
+    params, batch = seeded_params(cfg), skewed_tokens(cfg)
+    got = jax.grad(lambda p: llama.loss_fn(p, batch, cfg))(params)
+    want = jax.grad(lambda p: llama.loss_fn(p, batch, FP32))(params)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=2e-6)
+
+
+def test_a_model_with_neither_dense_layer_nor_mtp_block_is_one_stack():
+    cfg = dataclasses.replace(FP32, first_dense_layers=0, mtp_layers=0, n_layers=2)
+    params = llama.init_params(cfg, jax.random.key(0))
+    assert set(params) == {"embed", "layers", "final_norm", "lm_head"}
+    assert params["layers"]["router_bias"].shape == (2, cfg.n_experts)
+    out = llama.loss_and_weight_fn(params, skewed_tokens(cfg), cfg)
+    assert "loss_mtp" not in out[2] and np.isfinite(float(out[0]))
+    with pytest.raises(ValueError, match="0 or 1"):
+        llama.init_params(dataclasses.replace(FP32, mtp_layers=2), jax.random.key(0))
+
+
+# -- MTP's shift, and causality ---------------------------------------------------
+
+
+def per_position_losses(params, batch, cfg):
+    """(main nll [B, S], MTP nll [B, S - 1]) of the program's own path, by
+    masking one position at a time out of neither: from the reference's
+    per-position form on the program's hidden states."""
+    h_last, _, block = llama._trunk(params, batch["tokens"], cfg)
+    m, _ = mla.mtp_hidden(params, h_last, batch["targets"], cfg, block)
+    head = params["lm_head"]
+
+    def nll(h, norm, targets):
+        lg = rms_norm(h, norm, cfg.rms_eps) @ head
+        logp = jax.nn.log_softmax(lg, axis=-1)
+        return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+
+    main = nll(h_last, params["final_norm"], batch["targets"])
+    ahead = nll(m[:, :-1], params["mtp"]["final_norm"], batch["targets"][:, 1:])
+    return main, ahead
+
+
+def test_mtp_predicts_the_token_after_next_and_the_last_position_weighs_nothing():
+    """The MTP head's loss is the mean over S - 1 positions of the nll of
+    t_{i+2} at position i; changing token t moves no MTP term before
+    t - 2 (term i reads tokens up to i + 1 and the target t_{i+2}); the
+    last position's logits reach no loss."""
+    params, batch = seeded_params(FP32), skewed_tokens(FP32)
+    with jax.default_matmul_precision("highest"):
+        _, _, stats = llama.loss_and_weight_fn(params, batch, FP32)
+        main, ahead = per_position_losses(params, batch, FP32)
+        assert float(stats["loss_mtp"]) == pytest.approx(float(ahead.mean()), rel=1e-5)
+        assert float(stats["loss_main"]) == pytest.approx(float(main.mean()), rel=1e-5)
+        # the sequence as ids 0 .. S: tokens are ids[:-1], targets ids[1:]; change id t
+        t = 13
+        ids = jnp.concatenate([batch["tokens"], batch["targets"][:, -1:]], axis=1)
+        ids = ids.at[:, t].set((ids[:, t] + 7) % FP32.vocab_size)
+        moved = {"tokens": ids[:, :-1], "targets": ids[:, 1:]}
+        main2, ahead2 = per_position_losses(params, moved, FP32)
+    # MTP term i reads ids 0 .. i + 1 and scores id i + 2: terms i <= t - 3 do not move
+    np.testing.assert_allclose(np.asarray(ahead2[:, :t - 2]), np.asarray(ahead[:, :t - 2]),
+                               rtol=1e-5, atol=1e-6)
+    assert not np.allclose(np.asarray(ahead2[:, t - 2]), np.asarray(ahead[:, t - 2]), atol=1e-4)
+    # the head's term i reads ids 0 .. i and scores id i + 1: terms i <= t - 2 do not move
+    np.testing.assert_allclose(np.asarray(main2[:, :t - 1]), np.asarray(main[:, :t - 1]),
+                               rtol=1e-5, atol=1e-6)
+    assert not np.allclose(np.asarray(main2[:, t - 1]), np.asarray(main[:, t - 1]), atol=1e-4)
+    # the last position: whatever its MTP logits are, the loss does not see them
+    h_last, _, block = llama._trunk(params, batch["tokens"], FP32)
+    m, _ = mla.mtp_hidden(params, h_last, batch["targets"], FP32, block)
+
+    def mtp_loss_given(m):
+        from ray_tpu.nn.layers import fused_cross_entropy_loss
+        targets = batch["targets"]
+        ahead_t = jnp.pad(targets[:, 1:], ((0, 0), (0, 1)))
+        has = jnp.broadcast_to(jnp.arange(S) < S - 1, targets.shape)
+        return fused_cross_entropy_loss(
+            rms_norm(m, params["mtp"]["final_norm"], FP32.rms_eps), params["lm_head"],
+            ahead_t, has)[0]
+
+    gm_ = jax.grad(mtp_loss_given)(m)
+    assert float(jnp.abs(gm_[:, -1]).max()) == 0.0 and float(jnp.abs(gm_[:, :-1]).max()) > 0.0
+
+
+# -- the share adds up ---------------------------------------------------------------
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_shares_of_the_experts_add_up_to_the_uncut_layer(dtype):
+    """Four shares of 2 of the 8 experts (the cell's eight shares of 8 of
+    64, small): the router and the shared expert are computed alike on
+    every chip and counted ONCE; the routed outputs of the shares, so
+    counted, sum to the uncut layer's, and so do the gradients of the
+    input; each share's counts are the uncut layer's, and what one share
+    computes the others count as elsewhere."""
+    whole = dataclasses.replace(FP32, dtype=dtype)
+    lp = layer_of(seeded_params(whole), 0)
+    x = jax.random.normal(jax.random.key(5), (B, S, whole.d_model), jnp.float32).astype(dtype)
+    no_shared = {k: (jnp.zeros_like(v) if k == "shared_down" else v) for k, v in lp.items()}
+
+    def run(cfg, lp):
+        out, vjp, stats = jax.vjp(lambda x: moe.moe_ffn(x, lp, cfg)[:2], x, has_aux=True)
+        return out, vjp(jnp.ones_like(out))[0], stats
+
+    def share(first, n, lp):
+        cfg = dataclasses.replace(whole, experts_held=n, first_expert_held=first)
+        return run(cfg, {**lp, **{k: lp[k][first:first + n] for k in ("w_gate", "w_up", "w_down")}})
+
+    full = run(whole, lp)
+    shared_alone = run(whole, {**lp, "w_down": jnp.zeros_like(lp["w_down"])})
+    routed = [share(first, 2, no_shared) for first in (0, 2, 4, 6)]
+    tol = dict(rtol=1e-5, atol=1e-5) if dtype == jnp.float32 else dict(rtol=0.03, atol=0.03)
+    for i in (0, 1):  # the output, and the gradient of the input
+        total = sum(np.asarray(r[i], np.float32) for r in routed) + np.asarray(
+            shared_alone[i], np.float32)
+        if i == 1:  # the router's own path to x is in every term: counted once
+            router_only = np.asarray(run(whole, {**no_shared, "w_down": jnp.zeros_like(
+                lp["w_down"])})[1], np.float32)
+            total = total - 4 * router_only
+        np.testing.assert_allclose(total, np.asarray(full[i], np.float32), **tol)
+    counts = full[2]["tokens_per_expert"]
+    assert int(counts.sum()) == whole.top_k * B * S
+    for first, (_, _, stats) in zip((0, 2, 4, 6), routed):
+        assert stats["tokens_per_expert"].tolist() == counts.tolist()
+        assert int(stats["pairs_elsewhere"]) == int(counts.sum() - counts[first:first + 2].sum())
+        assert int(stats["dropped_pairs"]) == 0
+    # a share with its shared expert is the share's routed part + the shared expert, whole
+    with_shared = share(2, 2, lp)
+    np.testing.assert_allclose(
+        np.asarray(with_shared[0], np.float32),
+        np.asarray(routed[1][0], np.float32) + np.asarray(shared_alone[0], np.float32), **tol)
+
+
+# -- the registry ------------------------------------------------------------------
+
+
+def catalog_config():
+    if os.path.exists(CATALOG):
+        for line in open(CATALOG):
+            row = json.loads(line)
+            if row["name"] == "GLM-4.7-Flash":
+                return row["config"]
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "chipbench", "configs", "glm-4.7-flash-train.json")
+    file = json.load(open(path))
+    return {**{k: v for k, v in file.items() if not isinstance(v, dict)}, **file["published"]}
+
+
+def test_config_from_hf_maps_the_catalogs_config_onto_the_preset():
+    cfg = config_from_hf(catalog_config())
+    assert cfg == get_model_config("glm-4.7-flash")
+    assert isinstance(cfg, mla.GlmLiteConfig) and cfg.head_dim == 256 != 2048 // 20
+    assert (cfg.n_expert_layers, cfg.first_dense_layers, cfg.mtp_layers) == (46, 1, 1)
+    assert (cfg.router_score, cfg.routed_scaling, cfg.shared_d_ff) == ("sigmoid", 1.8, 1536)
+    # 30B-A3B: every expert somewhere; 6 x the active parameters is most of a token's operations
+    assert 30.0e9 < dataclasses.replace(cfg).num_params() < 31.2e9
+    active = cfg.flops_per_token(1) / 2
+    assert 3.0e9 < active < 4.0e9
+    # the preset's tree has the sizes num_params counts, at a small depth
+    small = dataclasses.replace(mla.GLM_LITE_TINY, experts_held=4)
+    n = sum(x.size for x in jax.tree.leaves(jax.eval_shape(
+        lambda: llama.init_params(small, jax.random.key(0)))))
+    assert n == small.num_params()
+
+
+@pytest.mark.parametrize("key,value,names", [
+    ("rope_scaling", {"type": "yarn", "factor": 4.0}, "rope_scaling"),
+    ("n_group", 8, "group-limited"),
+    ("num_nextn_predict_layers", 2, "multi-token-prediction"),
+    ("attention_bias", True, "attention_bias"),
+])
+def test_config_from_hf_refuses_by_name_what_is_not_implemented(key, value, names):
+    with pytest.raises(ValueError, match=names):
+        config_from_hf({**catalog_config(), key: value})
+
+
+def test_engine_refuses_the_model_by_name():
+    from ray_tpu.llm.engine import EngineConfig
+
+    with pytest.raises(ValueError, match="GLM-4.7-Flash"):
+        EngineConfig(model="glm-lite-tiny")
+
+
+# -- the kernels at the new shapes -------------------------------------------------------
+
+
+@pytest.mark.parametrize("what", ["forward", "gradients"])
+def test_flash_at_heads_of_256_is_xla_attention(what):
+    """20 heads of 256, none shared: the flash kernels (interpret mode)
+    against `xla_attention`, forward and the three gradients, at a
+    sequence that takes two q blocks."""
+    b, s, h, d = 1, 1024, 2, 256
+    q, k, v = (jax.random.normal(jax.random.key(i), (b, s, h, d), jnp.float32) * 0.5
+               for i in (1, 2, 3))
+    probe = jax.random.normal(jax.random.key(4), (b, s, h, d), jnp.float32)
+    if what == "forward":
+        np.testing.assert_allclose(np.asarray(flash_attention(q, k, v, causal=True)),
+                                   np.asarray(xla_attention(q, k, v, causal=True)),
+                                   rtol=2e-4, atol=2e-4)
+        return
+    got = jax.grad(lambda *a: (flash_attention(*a, causal=True) * probe).sum(), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (xla_attention(*a, causal=True) * probe).sum(), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-3, atol=2e-3)
+
+
+def test_grouped_matmul_at_the_held_experts_shapes_with_a_tail():
+    """K 2048 / N 1536 (and the transpose), 8 groups, rows past the last
+    group: the tile rule accepts the shapes with the whole contraction
+    in one block, and the kernels (interpret mode, a smaller K and N of
+    the same ratio) leave the tail zero in the output and both gradients."""
+    for K, N in ((2048, 1536), (1536, 2048)):
+        for wgrad in (False, True):
+            t = gm.pick_tiles(32768, K, N, jnp.bfloat16, wgrad=wgrad)
+            assert t is not None and t.tm == 512
+            assert gm._vmem_bytes(t, 2, wgrad=wgrad) <= gm._VMEM_BUDGET
+        assert gm.pick_tiles(32768, K, N, jnp.bfloat16).tk == K  # a group's weights stay in VMEM
+    P, K, N, E = 1024, 256, 384, 8
+    sizes = jnp.asarray([100, 0, 156, 37, 64, 1, 90, 40], jnp.int32)  # 488 rows held of 1024
+    lhs = jax.random.normal(jax.random.key(0), (P, K), jnp.float32)
+    rhs = jax.random.normal(jax.random.key(1), (E, K, N), jnp.float32) / 16
+
+    def kernel(lhs, rhs):
+        return gm.grouped_matmul_pallas(lhs, rhs, sizes, interpret=True, tail=True)
+
+    def plain(lhs, rhs):
+        return jax.lax.ragged_dot(lhs, rhs, sizes, precision=jax.lax.Precision.HIGHEST)
+
+    held = int(sizes.sum())
+    got, want = kernel(lhs, rhs), plain(lhs, rhs)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-3)
+    assert float(jnp.abs(got[held:]).max()) == 0.0
+    probe = jax.random.normal(jax.random.key(2), (P, N), jnp.float32)
+    g_got = jax.grad(lambda a, b: (kernel(a, b) * probe).sum(), (0, 1))(lhs, rhs)
+    g_want = jax.grad(lambda a, b: (plain(a, b) * probe).sum(), (0, 1))(lhs, rhs)
+    for g, w in zip(g_got, g_want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=1e-3)
+    assert float(jnp.abs(g_got[0][held:]).max()) == 0.0

@@ -82,6 +82,31 @@ def test_flash_attention_compiles_for_v5e(v5e, grad):
              sharding=_one_chip(v5e))
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
+def test_flash_attention_compiles_at_heads_of_256_with_the_kernels_own_vmem(v5e, grad):
+    """MLA's shape (models/mla.py): 20 heads of 256, none shared, 4096
+    keys. The fused backward holds 24 MiB of kv blocks there, over the 16
+    MiB Mosaic scopes to a kernel by default: it states its own limit,
+    so it compiles with NO compile option of the caller's (a train step's
+    32 MiB would hide the need); at heads of 128 it states none, and the
+    kernel is the one it was."""
+    from ray_tpu.ops.flash import _fused_bwd_params, flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    def bwd(q, k, v):
+        return jax.grad(
+            lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    shape = ((2, 4096, 20, 256), _BF16)
+    _compile(bwd if grad else fwd, shape, shape, shape, sharding=_one_chip(v5e))
+    assert _fused_bwd_params(512, 4096, 256, 1, 2).vmem_limit_bytes == 34 << 20
+    for fold, block_q in ((1, 512), (2, 512), (4, 256)):
+        assert _fused_bwd_params(block_q, 4096, 128, fold, 2) is None
+
+
 @pytest.mark.parametrize("in_pipeline", [False, True], ids=["fsdp_tp", "pp_fsdp"])
 def test_flash_attention_compiles_under_a_mesh(v5e, in_pipeline):
     """A Mosaic kernel cannot be partitioned by the compiler: under a
@@ -307,20 +332,29 @@ _DENSE_STEP = {
 # the same of olmoe-1b-7b's step as `olmoe-train` builds it (one layer, batch 6), as
 # commit 8e69254 (the parent of PR 33, which gave ops/flash.py a second entry) lowers it
 _OLMOE_STEP = "36d2bc29f84e2c8a10813df1313e801532ecb0c08487bc3463c00e2e9b7fbab0"
+# the same of zaya1-8b's step as `zaya1-train` builds it (six layers, 8 of 16 experts and an
+# eighth of the vocabulary held, batch 2), as commit 21a2054 (the parent of PR 34, which gave
+# the block a third kind of attention, the expert layer a second kind of score and the
+# decoder blocks outside its scan) lowers it
+_ZAYA_STEP = "5c0e2e3ba71539f56323de421562ccae59c9377d2e3b1531e6a4a2459053d47d"
 
 
 @pytest.mark.parametrize("kwargs,want", [
     (dict(batch=3), _DENSE_STEP[None]),
     (dict(mesh_shape=(1, 1, 2, 1, 1, 2), batch=6), _DENSE_STEP[(1, 1, 2, 1, 1, 2)]),
     (dict(batch=6, model="olmoe-1b-7b", n_layers=1), _OLMOE_STEP),
-], ids=["one_chip", "fsdp2_tp2", "olmoe"])
+    (dict(batch=2, model="zaya1-8b", n_layers=6, vocab_size=32896, experts_held=8), _ZAYA_STEP),
+], ids=["one_chip", "fsdp2_tp2", "olmoe", "zaya"])
 def test_dense_train_step_lowers_to_the_text_it_had_before_the_expert_layer(v5e, kwargs, want):
     """One block serves dense and expert configurations (PR 26); for a
     dense one the lowered step is the text it was, which is what keeps
     `m7b-train` and `m7b-train-4chip` where they are. And one flash
     path serves both of its entries (PR 33: `flash_attention` is its
     transposes around the head-major one that CCA calls): the steps
-    that enter by the old one, OLMoE's too, lower to the text they had."""
+    that enter by the old one, OLMoE's too, lower to the text they had.
+    And PR 34's third kind of attention, sigmoid scores, shared expert,
+    dense layers before the scan and second head leave all four, ZAYA1's
+    with them, the text they had."""
     import hashlib
     import re
 
@@ -428,6 +462,57 @@ def test_zaya_share_train_step_runs_its_kernels_and_skips_the_rows_elsewhere(v5e
     assert compiled.memory_analysis().temp_size_in_bytes < 0.8522 * 2 ** 30
 
 
+def test_glm_lite_share_train_step_runs_mla_its_kernels_and_the_second_head(v5e):
+    """GLM-4.7-Flash as `glm47f-train` builds it (8 of 64 experts and an
+    eighth of the vocabulary held; the dense layer, ONE expert layer and
+    the MTP block and one sequence here, the cell's depth and batch are
+    rehearsed in PERF.md), compiled for the described chip: every
+    attention is MLA through the flash kernels at heads of 256, named
+    after the scope they are called in; the held experts' grouped matmuls
+    are the kernels of ops/grouped_matmul.py at [2048, 1536] with a
+    group's whole weight matrix as one block, in the scan's layer and in
+    the MTP block; XLA's own ragged-dot kernel is not there; the scopes
+    the cell's readers sum are in the compiled step; and the new
+    sublayers count their sites."""
+    from ray_tpu import obs
+
+    step, state, batch = _train_step_at_mistral_widths(
+        v5e, batch=1, model="glm-4.7-flash", n_layers=2, vocab_size=19456, experts_held=8)
+    before = obs.layer_counters()
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        compiled = step.lower(state, batch).compile()
+    after = obs.layer_counters()
+    engaged = {name: after.get(name, {"count": 0})["count"]
+               - before.get(name, {"count": 0})["count"]
+               for name in ("mla.attn", "moe.ffn", "cca.attn", "grouped_matmul.kernel",
+                            "grouped_matmul.ragged_dot")}
+    # the dense layer and the expert-layer kind of block, traced once for the scan and the
+    # MTP block alike (the rematerialised block is one function): two sites of MLA at least
+    assert engaged["mla.attn"] >= 2 and engaged["moe.ffn"] >= 1 and engaged["cca.attn"] == 0
+    assert engaged["grouped_matmul.kernel"] > 0 and engaged["grouped_matmul.ragged_dot"] == 0
+    hlo = compiled.as_text()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    grouped = sorted(re.sub(r"\.\d+$", "", k) for k in kernels if k.startswith("ragged-dot"))
+    assert grouped == (["ragged-dot-tiled"] * 6 + ["ragged-dot-tiled-dgrad"] * 6
+                       + ["ragged-dot-tiled-wgrad"] * 6), kernels
+    assert "ragged-dot-none" not in hlo
+    # what is no grouped matmul is flash, forward and backward at each of the three sites
+    rest = [k for k in kernels if not k.startswith("ragged-dot")]
+    assert len(rest) == 6 and all("mla.attend" in k for k in rest), kernels
+    assert re.search(r"bf16\[1,20,4096,256\]", hlo)
+    # 8 held experts' weights and no more, the router's 64 outputs whole
+    assert "8,2048,1536]" in hlo and "64,2048,1536]" not in hlo and "4096,64]" in hlo
+    op_names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for scope in ("mla.down", "mla.up", "mla.glue", "mla.attend", "mla.out", "shared.ffn",
+                  "moe.router", "moe.dispatch", "moe.experts", "moe.combine", "mtp.merge",
+                  "mtp.block", "mtp.head"):
+        assert any(re.search(r"(?:^|[/(])" + re.escape(scope) + r"(?:[/)]|$)", n)
+                   for n in op_names), scope
+    # the MTP block's own sublayers sit inside its scope
+    assert any("mtp.block" in n and "mla.attend" in n for n in op_names)
+    assert any("mtp.block" in n and "moe.experts" in n for n in op_names)
+
+
 @pytest.mark.parametrize("cell,kwargs,temp_gib,tiles_at_16", [
     ("m7b-train", dict(batch=3), 11.2, 37144),
     ("olmoe-train", dict(batch=6, model="olmoe-1b-7b", n_layers=1), 6.9, 30468),
@@ -483,8 +568,9 @@ def test_train_step_asks_for_vmem_only_of_a_chip_it_knows(monkeypatch):
 
 
 @pytest.mark.parametrize("P,E,K,N", [
-    (8192, 8, 4096, 14336), (8192, 8, 14336, 4096), (768, 4, 384, 128)],
-    ids=["mixtral_up", "mixtral_down", "rows_in_tiles_of_256"])
+    (8192, 8, 4096, 14336), (8192, 8, 14336, 4096), (768, 4, 384, 128),
+    (32768, 8, 2048, 1536), (32768, 8, 1536, 2048)],
+    ids=["mixtral_up", "mixtral_down", "rows_in_tiles_of_256", "glm_lite_up", "glm_lite_down"])
 def test_grouped_matmul_kernels_compile_wherever_the_tile_rule_accepts(v5e, P, E, K, N):
     """The three kernels of ops/grouped_matmul.py at shapes other than
     the cell's: Mixtral-8x7B's widths, where the contraction or the
