@@ -12,8 +12,9 @@ flash/ragged lineage to this framework. Design:
  * forward: online-softmax recurrence (running max `m`, normalizer
    `l`, fp32 accumulator) carried in scratch across the kv-block grid
    dim; the output block is revisited and written once per q-block;
- * causal: off-diagonal programs skip their compute via pl.when (the
-   block fetch still happens — compute, not bandwidth, dominates);
+ * causal: a kv block is walked in sub-tiles, and those wholly above
+   the diagonal are skipped (`_tiles_to_run`); the block fetch still
+   happens — compute, not bandwidth, dominates;
  * GQA folds naturally: kv BlockSpec index maps divide the q-head
    index by the group size;
  * backward: dQ accumulates over kv blocks; dK/dV accumulate over
@@ -44,15 +45,43 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# v5e-tuned (round-5 sweep at B=8/H=16/KVH=8/D=64). Two structural facts
-# drive the defaults:
-#  * the FUSED backward (whole kv sequence in one block, nk == 1) beats
-#    the two-kernel path at every sequence length once sub-tiling gives
-#    it back block-causal skipping: S=2048 7.06ms vs 8.74, S=4096
-#    12.4 vs 14.5 (fwd+bwd per layer; XLA attention 24.4 / 47.0);
-#  * VMEM bounds the fused block: dk/dv fp32 scratch is block_k*D*8
-#    bytes, so block_k caps at 4096 (S=8192: bk=4096 27.8ms, bk=8192
-#    fails to compile).
+# Measured on a v5e (PR 36). In the traced step of `m7b-train` (heads of
+# 128, 2 layers, batch 3 x 4096, causal) the forward kernel went from
+# 11.90 to 6.20 ms a step and the fused backward from 15.11 to 14.17;
+# the scan's four layers of `glm47f-train` (heads of 256) from 11.18 to
+# 9.18 and from 21.80 to 21.44 (PERF.md section 5 has every cell). The
+# choices below were made on a scratch loop that is NOT in the tree: wall
+# clock over one call of the kernels at [B, H, KVH, D] = [3, 32, 8, 128]
+# and [2, 20, 20, 256], 4096 keys, bf16, causal, forward / backward with
+# its XLA glue in ms; the parent read 6.09 / 8.43 and 2.93 / 6.10, this
+# file 3.23 / 8.00 and 2.44 / 5.99:
+#  * what bound the forward was the row statistics held as [rows, 1]
+#    (one useful lane of 128, and two cross-lane reductions a sub-tile):
+#    lane-dense [rows, 128] with the sum's reduction deferred to the
+#    last sub-tile took it from 6.10 to 3.46 at heads of 128 and from
+#    2.94 to 2.50 at 256;
+#  * the sub-tiles that run walked by a loop, two a trip, in place of one
+#    `pl.when` region each: 3.46 / 8.43 -> 3.23 / 8.00 and 2.50 / 6.08 ->
+#    2.44 / 5.99 (one a trip reads as the regions did); the forward
+#    alone / both kernels compile in 0.8 / 1.8 s at heads of 128 where
+#    the regions took 2.4 / 3.6;
+#  * the mask does NOT bind: left off the sub-tiles wholly below the
+#    diagonal (a second path a sub-tile) it read 3.30 / 8.11 and 2.43 /
+#    6.01, so every sub-tile that runs builds and applies it;
+#  * block_q 512 against sub-tiles of 512 keys reads fastest at both head
+#    sizes (one a trip: 3.46 / 8.43 and 2.50 / 6.08): 256 x 512 is the
+#    same at 128 (two heads fold into 512 rows) and 2.78 / 6.38 at 256;
+#    512 x 256 reads 5.07 / 10.40 and 2.68 / 7.22; 256 x 256 4.85 / 9.91
+#    and 3.03 / 7.87; 512 x 128 7.40 / 18.38; 1024 rows or 1024 keys do
+#    not fit the default scoped VMEM at 128;
+#  * scores held keys-major ([keys, rows]: the statistics reduced over
+#    sublanes, the accumulator [D, rows]) read 4.25 forward at 128
+#    against 3.31; in the backward 7.79 against 8.11 at 128 but 6.13
+#    against 6.03 at 256: not taken;
+#  * the FUSED backward (the whole kv sequence in one block, nk == 1, dq
+#    from the dk/dv kernel) is why the default kv block is the sequence;
+#    VMEM bounds it: dk/dv fp32 scratch is block_k*D*8 bytes, so block_k
+#    caps at 4096.
 DEFAULT_BLOCK_Q = 512
 MAX_BLOCK_K = 4096  # fused whole-sequence kv block, VMEM-capped
 NEG_INF = -1e30  # true -inf breeds NaN via (-inf) - (-inf)
@@ -95,21 +124,61 @@ def _round_up(x: int, m: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# forward kernel: grid (B, H, nq, nk), kv-block fastest
+# sub-tiles
 # ---------------------------------------------------------------------------
 
+_LANES = 128
+_SUB_K = 512  # keys a sub-tile (the header has the sweep)
 
-def _block_mask(i, k_base, Bq, Tk, *, causal, q_offset, sq_valid, sk_valid,
-                has_segments, kpad, qpad, qseg_ref, kseg):
-    """[Bq, Tk] validity mask for q-block i vs kv positions starting at
+
+def _sub_k(Bk: int) -> int:
+    """Keys a sub-tile: 512, or the whole block where 512 does not divide it."""
+    return _SUB_K if Bk % _SUB_K == 0 else Bk
+
+
+def _tiles_to_run(i, j, Bq, Bk, Tk, *, causal, q_offset):
+    """How many of the Bk // Tk sub-tiles of kv block `j` q block `i`
+    meets: those whose first key is not after the block's last row. The
+    rest lie wholly above the diagonal and are skipped; the ones that run
+    are a prefix. A Python int without a diagonal."""
+    if not causal:
+        return Bk // Tk
+    return jnp.clip(q_offset + (i + 1) * Bq - j * Bk + Tk - 1, 0, Bk) // Tk
+
+
+def _walk_tiles(n_run, tile):
+    """`tile(t)` over sub-tiles [0, n_run). Without a diagonal the count
+    is a Python int and the walk one straight-line region. Otherwise the
+    program id gives it, and the sub-tiles go two a trip, so that one's
+    matmuls can be issued beside the other's softmax (see the header)."""
+    if isinstance(n_run, int):
+        for t in range(n_run):
+            tile(t)
+        return
+
+    def pair(t, _):
+        tile(2 * t)
+        tile(2 * t + 1)
+
+    jax.lax.fori_loop(0, n_run // 2, pair, None)
+    pl.when(n_run % 2 == 1)(lambda: tile(n_run - 1))
+
+
+def _tile_start(t, Tk):
+    """First key of sub-tile t within its kv block, as a slice start Mosaic
+    can prove aligned when t is a loop index."""
+    return t * Tk if isinstance(t, int) else pl.multiple_of(t * Tk, Tk)
+
+
+def _block_mask(i, k_base, F, Bq, Tk, *, causal, q_offset, sq_valid, sk_valid,
+                kpad, qpad, qseg_ref, kseg):
+    """[F*Bq, Tk] validity mask for q-block i vs kv positions starting at
     k_base, or None.
 
     Every term depends only on the position WITHIN the q block, so with
-    head folding the folded [F*Bq, Tk] tile reuses one [Bq, Tk] mask
-    broadcast across the F stacked heads. Terms are STATICALLY gated:
-    each skipped term saves VPU passes over the tile and the kernel is
-    VPU-bound — on the common path (causal, no packing, no pad) only
-    the triangle compare survives.
+    head folding the folded tile reuses one [Bq, Tk] mask broadcast
+    across the F stacked heads. Terms are STATICALLY gated; `kseg` is
+    None without segments.
     """
     mask = None
     if causal or kpad:
@@ -127,17 +196,25 @@ def _block_mask(i, k_base, Bq, Tk, *, causal, q_offset, sq_valid, sk_valid,
     if causal:
         cm = q_pos >= k_pos
         mask = cm if mask is None else mask & cm
-    if has_segments:
+    if kseg is not None:
         sm = qseg_ref[0] == kseg  # [Bq,1] == [1,Tk]
         mask = sm if mask is None else mask & sm
-    return mask
-
-
-def _expand_mask(mask, F, Bq, Bk):
-    """Tile a [Bq, Bk] mask across the F folded heads -> [F*Bq, Bk]."""
     if mask is None or F == 1:
         return mask
-    return jnp.broadcast_to(mask[None], (F, Bq, Bk)).reshape(F * Bq, Bk)
+    return jnp.broadcast_to(mask[None], (F, Bq, Tk)).reshape(F * Bq, Tk)
+
+
+def _lanes(x, n):
+    """x [rows, 128] whose lanes hold one value a row -> [rows, n]."""
+    reps = -(-n // _LANES)
+    if reps > 1:
+        x = pltpu.repeat(x, reps, axis=1)
+    return x if x.shape[1] == n else x[:, :n]
+
+
+# ---------------------------------------------------------------------------
+# forward kernel: grid (B, H, nq, nk), kv-block fastest
+# ---------------------------------------------------------------------------
 
 
 def _fwd_kernel(
@@ -148,8 +225,8 @@ def _fwd_kernel(
     kseg_ref,   # [1, 1, Bk]
     o_ref,      # [1, F, Bq, D]   (revisited across kv blocks)
     lse_ref,    # [1, F, Bq, 1]
-    m_scr,      # [F*Bq, 1] fp32
-    l_scr,      # [F*Bq, 1] fp32
+    m_scr,      # [F*Bq, 128] fp32: the running max, the same in every lane
+    l_scr,      # [F*Bq, 128] fp32: the running sum's lane-wise partials
     acc_scr,    # [F*Bq, D] fp32
     *,
     scale: float,
@@ -158,7 +235,6 @@ def _fwd_kernel(
     sk_valid: int,
     has_segments: bool,
     kpad: bool,
-    sub_k: int = 512,
 ):
     i = pl.program_id(2)
     j = pl.program_id(3)
@@ -166,8 +242,7 @@ def _fwd_kernel(
     F, Bq, D = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
     Bk = k_ref.shape[2]
     rows = F * Bq
-    Tk = sub_k if Bk % sub_k == 0 else Bk  # sub-tiles must cover Bk exactly
-    nt = Bk // Tk
+    Tk = _sub_k(Bk)
 
     @pl.when(j == 0)
     def _():
@@ -175,72 +250,69 @@ def _fwd_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # The kv block is walked in sub-tiles of Tk with a PER-SUB-TILE
-    # causal skip: with the whole kv sequence in one block (the layout
-    # the fused backward wants), block-level skipping can't act and
-    # ~half the softmax VPU work lands on masked entries — sub-tiling
-    # restores causal-proportional cost while keeping nk == 1.
-    def tile(t: int):
-        lo = t * Tk
-        k_base = j * Bk + lo
-        run = True
-        if causal:
-            run = q_offset + (i + 1) * Bq - 1 >= k_base
+    # The kv block is walked in sub-tiles of Tk, those wholly above the
+    # diagonal skipped: with the whole kv sequence in one block (the
+    # layout the fused backward wants) block-level skipping can't act,
+    # and sub-tiling restores causal-proportional cost while keeping
+    # nk == 1.
+    def tile(t):
+        lo = _tile_start(t, Tk)
+        # matmuls stay in the INPUT dtype (bf16 on the training path)
+        # with fp32 ACCUMULATION: a v5e MXU runs bf16xbf16->f32 at full
+        # rate but f32xf32 several times slower. Softmax math stays fp32.
+        q = q_ref[0].reshape(rows, D)  # folded heads stacked along rows
+        k = k_ref[0, 0, pl.ds(lo, Tk)]
+        v = v_ref[0, 0, pl.ds(lo, Tk)]
+        s = jax.lax.dot_general(
+            q, k,
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [rows, Tk] fp32
+        if scale != 1.0:  # hot path pre-scales q; kernel mul only if not
+            s = s * scale
+        # the forward leaves padded rows be (they are cut off)
+        mask = _block_mask(
+            i, j * Bk + lo, F, Bq, Tk, causal=causal, q_offset=q_offset, sq_valid=0,
+            sk_valid=sk_valid, kpad=kpad, qpad=False, qseg_ref=qseg_ref,
+            kseg=kseg_ref[0, :, pl.ds(lo, Tk)] if has_segments else None)
+        if mask is not None:
+            s = jnp.where(mask, s, NEG_INF)
 
-        def body():
-            # matmuls stay in the INPUT dtype (bf16 on the training path)
-            # with fp32 ACCUMULATION: a v5e MXU runs bf16xbf16->f32 at full
-            # rate but f32xf32 several times slower — upcasting operands
-            # here was the single biggest flash-vs-XLA perf gap. Softmax
-            # math stays fp32.
-            q = q_ref[0].reshape(rows, D)  # folded heads stacked along rows
-            k = k_ref[0, 0, lo:lo + Tk]
-            v = v_ref[0, 0, lo:lo + Tk]
-            s = jax.lax.dot_general(
-                q, k,
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [rows, Tk] fp32
-            if scale != 1.0:  # hot path pre-scales q; kernel mul only if not
-                s = s * scale
-            mask = _expand_mask(
-                _block_mask(i, k_base, Bq, Tk, causal=causal,
-                            q_offset=q_offset, sq_valid=0, sk_valid=sk_valid,
-                            has_segments=has_segments, kpad=kpad, qpad=False,
-                            qseg_ref=qseg_ref,
-                            kseg=kseg_ref[0, :, lo:lo + Tk]),
-                F, Bq, Tk,
-            )
-            if mask is not None:
-                s = jnp.where(mask, s, NEG_INF)
+        # the row statistics ride all 128 lanes: the max is reduced across
+        # lanes here (a row's exp needs it), the sum's partials stay
+        # lane-wise until the last sub-tile (every lane of a row is
+        # rescaled by the same alpha)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, Tk))  # masked entries: exp(NEG_INF - m) == 0
+        alpha = jnp.exp(m_prev - m_new)
+        if Tk % _LANES == 0:
+            part = p[:, :_LANES]
+            for c in range(1, Tk // _LANES):
+                part = part + p[:, c * _LANES:(c + 1) * _LANES]
+        else:  # a ragged short block: the row's sum, kept in lane 0
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+            part = jnp.where(lane == 0, jnp.sum(p, axis=1, keepdims=True), 0.0)
+        m_scr[...] = m_new
+        l_scr[...] = l_scr[...] * alpha + part
+        acc_scr[...] = acc_scr[...] * _lanes(alpha, D) + jax.lax.dot_general(
+            p.astype(v.dtype), v,
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
-            m_prev = m_scr[...]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)  # masked entries: exp(NEG_INF - m) == 0
-            alpha = jnp.exp(m_prev - m_new)
-            m_scr[...] = m_new
-            l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-            acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-                p.astype(v.dtype), v,
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-
-        pl.when(run)(body)
-
-    for t in range(nt):
-        tile(t)
+    _walk_tiles(_tiles_to_run(i, j, Bq, Bk, Tk, causal=causal, q_offset=q_offset), tile)
 
     @pl.when(j == nk - 1)
     def _():
-        l = l_scr[...]
+        l = jnp.sum(l_scr[...], axis=1, keepdims=True)
         safe_l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> zeros
         o_ref[0] = (acc_scr[...] / safe_l).astype(o_ref.dtype).reshape(F, Bq, D)
         # fully-masked rows end with m ~= NEG_INF (and rows no tile ever
         # ran keep l == 0, m == NEG_INF), so lse lands at ~NEG_INF either
         # way — the "weigh nothing" value ring attention's blockwise
         # (o, lse) merge requires
-        lse_ref[0] = (m_scr[...] + jnp.log(safe_l)).reshape(F, Bq, 1)
+        lse_ref[0] = (m_scr[:, :1] + jnp.log(safe_l)).reshape(F, Bq, 1)
 
 
 # ---------------------------------------------------------------------------
@@ -271,11 +343,8 @@ def _dq_kernel(
     def _():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    run = True
-    if causal:
-        run = q_offset + (i + 1) * Bq - 1 >= j * Bk
-
-    @pl.when(run)
+    # the kv block is ONE sub-tile here: skipped where wholly above the diagonal
+    @pl.when(q_offset + (i + 1) * Bq > j * Bk if causal else True)
     def _():
         q = q_ref[0].reshape(rows, D)
         do = do_ref[0].reshape(rows, D)
@@ -292,13 +361,10 @@ def _dq_kernel(
             s = s * scale
         # explicit where: exp(s - lse) is garbage on fully-masked rows
         p = jnp.exp(s - lse)
-        mask = _expand_mask(
-            _block_mask(i, j * Bk, Bq, Bk, causal=causal,
-                        q_offset=q_offset, sq_valid=0, sk_valid=sk_valid,
-                        has_segments=has_segments, kpad=kpad, qpad=False,
-                        qseg_ref=qseg_ref, kseg=kseg_ref[0]),
-            F, Bq, Bk,
-        )
+        mask = _block_mask(
+            i, j * Bk, F, Bq, Bk, causal=causal, q_offset=q_offset, sq_valid=0,
+            sk_valid=sk_valid, kpad=kpad, qpad=False, qseg_ref=qseg_ref,
+            kseg=kseg_ref[0] if has_segments else None)
         if mask is not None:
             p = jnp.where(mask, p, 0.0)  # [rows, Bk]
         dp = jax.lax.dot_general(
@@ -346,7 +412,6 @@ def _dkv_kernel(
     fused_dq: bool = False,
     dq_ref=None,  # fused mode only: [1, F, Bq, D], written per (h, i)
     dq_scr=None,  # fused mode only: [F*Bq, D] fp32 (sub-tile accumulator)
-    sub_k: int = 512,
 ):
     # grid (B, nk, H/F, nq): q-blocks fastest, then the head groups
     # sharing this kv head; scratch accumulates until both inner dims
@@ -356,7 +421,7 @@ def _dkv_kernel(
     # sequence in one block) this kernel also emits dq — a q-block's dq
     # needs no cross-j accumulation then, which deletes the separate dq
     # kernel's full s/p/dp recompute. Like the forward, the kv block is
-    # walked in causally-skipped sub-tiles (see _fwd_kernel).
+    # walked in sub-tiles (see _fwd_kernel).
     jk = pl.program_id(1)
     h = pl.program_id(2)
     i = pl.program_id(3)
@@ -364,8 +429,7 @@ def _dkv_kernel(
     F, Bq, D = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
     Bk = k_ref.shape[2]
     rows = F * Bq
-    Tk = sub_k if Bk % sub_k == 0 else Bk
-    nt = Bk // Tk
+    Tk = _sub_k(Bk)
 
     @pl.when((h % group == 0) & (i == 0))
     def _():
@@ -375,63 +439,50 @@ def _dkv_kernel(
     if fused_dq:
         dq_scr[...] = jnp.zeros_like(dq_scr)  # every program owns its dq
 
-    def tile(t: int):
-        lo = t * Tk
-        k_base = jk * Bk + lo
-        run = True
-        if causal:
-            run = q_offset + (i + 1) * Bq - 1 >= k_base
-
-        def body():
-            # input-dtype matmuls, fp32 accumulation (see _fwd_kernel note)
-            k = k_ref[0, 0, lo:lo + Tk]
-            v = v_ref[0, 0, lo:lo + Tk]
-            q = q_ref[0].reshape(rows, D)
-            do = do_ref[0].reshape(rows, D)
-            lse = lse_ref[0].reshape(rows, 1)
-            delta = delta_ref[0].reshape(rows, 1)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
+    def tile(t):
+        lo = _tile_start(t, Tk)
+        # input-dtype matmuls, fp32 accumulation (see _fwd_kernel note)
+        k = k_ref[0, 0, pl.ds(lo, Tk)]
+        v = v_ref[0, 0, pl.ds(lo, Tk)]
+        q = q_ref[0].reshape(rows, D)
+        do = do_ref[0].reshape(rows, D)
+        lse = lse_ref[0].reshape(rows, 1)
+        delta = delta_ref[0].reshape(rows, 1)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [rows, Tk]
+        if scale != 1.0:
+            s = s * scale
+        p = jnp.exp(s - lse)
+        mask = _block_mask(
+            i, jk * Bk + lo, F, Bq, Tk, causal=causal, q_offset=q_offset, sq_valid=sq_valid,
+            sk_valid=sk_valid, kpad=kpad, qpad=qpad, qseg_ref=qseg_ref,
+            kseg=kseg_ref[0, :, pl.ds(lo, Tk)] if has_segments else None)
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
+        dv_scr[pl.ds(lo, Tk)] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [Tk, D]
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [rows, Tk]
+        ds = p * (dp - delta)
+        if scale != 1.0:
+            ds = ds * scale
+        dk_scr[pl.ds(lo, Tk)] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [Tk, D]
+        if fused_dq:
+            dq_scr[...] += jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # [rows, Tk]
-            if scale != 1.0:
-                s = s * scale
-            p = jnp.exp(s - lse)
-            mask = _expand_mask(
-                _block_mask(i, k_base, Bq, Tk, causal=causal,
-                            q_offset=q_offset, sq_valid=sq_valid,
-                            sk_valid=sk_valid, has_segments=has_segments,
-                            kpad=kpad, qpad=qpad, qseg_ref=qseg_ref,
-                            kseg=kseg_ref[0, :, lo:lo + Tk]),
-                F, Bq, Tk,
             )
-            if mask is not None:
-                p = jnp.where(mask, p, 0.0)
-            dv_scr[lo:lo + Tk] += jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [Tk, D]
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [rows, Tk]
-            ds = p * (dp - delta)
-            if scale != 1.0:
-                ds = ds * scale
-            dk_scr[lo:lo + Tk] += jax.lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [Tk, D]
-            if fused_dq:
-                dq_scr[...] += jax.lax.dot_general(
-                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
 
-        pl.when(run)(body)
-
-    for t in range(nt):
-        tile(t)
+    _walk_tiles(_tiles_to_run(i, jk, Bq, Bk, Tk, causal=causal, q_offset=q_offset), tile)
 
     if fused_dq:
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype).reshape(F, Bq, D)
@@ -480,8 +531,8 @@ def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
             jax.ShapeDtypeStruct((B, H, Sq_pad, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((F * block_q, 1), jnp.float32),
-            pltpu.VMEM((F * block_q, 1), jnp.float32),
+            pltpu.VMEM((F * block_q, _LANES), jnp.float32),
+            pltpu.VMEM((F * block_q, _LANES), jnp.float32),
             pltpu.VMEM((F * block_q, D), jnp.float32),
         ],
         interpret=interpret,

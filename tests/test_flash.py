@@ -23,62 +23,109 @@ def make_qkv(key, B, Sq, Sk, H, KVH, D, dtype=jnp.float32):
 
 
 @pytest.mark.parametrize(
-    "B,S,H,KVH,D,causal",
+    "B,S,H,KVH,D,causal,bq,bk",
     [
-        (2, 64, 4, 4, 32, True),     # MHA causal
-        (2, 64, 4, 2, 32, True),     # GQA
-        (1, 128, 8, 2, 64, True),    # deeper GQA, two q blocks at bq=64
-        (2, 64, 4, 2, 32, False),    # bidirectional
-        (1, 100, 4, 2, 32, True),    # non-divisible seq -> padding path
+        (2, 64, 4, 4, 32, True, 64, 64),     # MHA causal
+        (2, 64, 4, 2, 32, True, 64, 64),     # GQA
+        (1, 128, 8, 2, 64, True, 64, 64),    # deeper GQA, two q blocks at bq=64
+        (2, 64, 4, 2, 32, False, 64, 64),    # bidirectional
+        (1, 100, 4, 2, 32, True, 64, 64),    # non-divisible seq -> padding path
+        # the kv block walked in 512-wide sub-tiles, in one program some below the
+        # diagonal, some crossed by it, some skipped: heads of 64, 128 (two heads folded), 256
+        (1, 1536, 2, 1, 64, True, 512, None),
+        (1, 2048, 4, 2, 128, True, 512, None),
+        (1, 1536, 2, 2, 256, True, 512, None),
+        (1, 1024, 2, 1, 64, True, 256, None),   # two q blocks a sub-tile: the diagonal crosses it twice
+        # a padded edge inside a sub-tile (keys 1300..1535) and a sub-tile that is all
+        # padding, over two kv blocks; without a diagonal the edge alone is crossed
+        (1, 1300, 2, 1, 64, True, 512, 1024),
+        (1, 1300, 2, 1, 64, False, 512, 1024),
+        (1, 1024, 2, 2, 64, False, 512, None),  # no mask term at all, a static walk
     ],
 )
-def test_forward_matches_xla(B, S, H, KVH, D, causal):
+def test_forward_matches_xla(B, S, H, KVH, D, causal, bq, bk):
     q, k, v = make_qkv(jax.random.key(0), B, S, S, H, KVH, D)
     ref = xla_attention(q, k, v, causal=causal)
-    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
-def test_forward_bf16_tolerance():
-    q, k, v = make_qkv(jax.random.key(1), 2, 128, 128, 4, 2, 64, jnp.bfloat16)
+@pytest.mark.parametrize("B,S,H,KVH,D", [
+    (2, 128, 4, 2, 64),
+    (1, 1536, 4, 1, 128),  # sub-tiles below, on and above the diagonal, four heads folded
+    (1, 1024, 2, 2, 256),
+])
+def test_forward_bf16_tolerance(B, S, H, KVH, D):
+    q, k, v = make_qkv(jax.random.key(1), B, S, S, H, KVH, D, jnp.bfloat16)
     ref = xla_attention(q, k, v, causal=True).astype(jnp.float32)
     out = flash_attention(q, k, v, causal=True).astype(jnp.float32)
     np.testing.assert_allclose(out, ref, atol=2e-2, rtol=2e-2)
 
 
-def test_segment_ids_packing():
-    B, S, H, KVH, D = 2, 64, 4, 2, 32
+@pytest.mark.parametrize("S,cut,bq,bk", [
+    (64, 32, 32, 32),
+    # the boundary falls inside a sub-tile wholly below the diagonal (keys 0..511
+    # against rows 512..1535)
+    (1536, 200, 512, None),
+    (1536, 700, 512, None),
+])
+def test_segment_ids_packing(S, cut, bq, bk):
+    B, H, KVH, D = 2, 4, 2, 32
     q, k, v = make_qkv(jax.random.key(2), B, S, S, H, KVH, D)
     seg = jnp.concatenate(
-        [jnp.zeros((B, S // 2), jnp.int32), jnp.ones((B, S - S // 2), jnp.int32)],
+        [jnp.zeros((B, cut), jnp.int32), jnp.ones((B, S - cut), jnp.int32)],
         axis=1,
     )
     ref = xla_attention(q, k, v, causal=True, segment_ids=seg)
-    out = flash_attention(q, k, v, causal=True, segment_ids=seg, block_q=32, block_k=32)
+    out = flash_attention(q, k, v, causal=True, segment_ids=seg, block_q=bq, block_k=bk)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
-def test_q_offset_decode_window():
+@pytest.mark.parametrize("Sq,Sk,off,bq,bk", [
+    (16, 64, 48, 16, 16),
+    # rows 1024..1535 against 1536 keys in three sub-tiles: two below the diagonal, one on it
+    (512, 1536, 1024, 256, None),
+    # the diagonal off the sub-tiles' grid: rows 700..1211, two sub-tiles crossed
+    (512, 1536, 700, 512, None),
+])
+def test_q_offset_decode_window(Sq, Sk, off, bq, bk):
     """Short q attending into a longer kv prefix (chunked prefill shape)."""
     B, H, KVH, D = 1, 4, 2, 32
-    Sq, Sk, off = 16, 64, 48
     q, k, v = make_qkv(jax.random.key(3), B, Sq, Sk, H, KVH, D)
     ref = xla_attention(q, k, v, causal=True, q_offset=off)
-    out = flash_attention(q, k, v, causal=True, q_offset=off, block_q=16, block_k=16)
+    out = flash_attention(q, k, v, causal=True, q_offset=off, block_q=bq, block_k=bk)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    g_ref = jax.grad(lambda *a: jnp.sum(xla_attention(*a, causal=True, q_offset=off) ** 2),
+                     argnums=(0, 1, 2))(q, k, v)
+    g_out = jax.grad(lambda *a: jnp.sum(flash_attention(
+        *a, causal=True, q_offset=off, block_q=bq, block_k=bk) ** 2), argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(g_out, g_ref, "qkv"):
+        np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4, err_msg=f"d{name} mismatch")
 
 
-@pytest.mark.parametrize("KVH", [4, 2])
-def test_grads_match_xla(KVH):
-    B, S, H, D = 2, 64, 4, 32
+@pytest.mark.parametrize("S,H,KVH,D,causal,bq,bk", [
+    (64, 4, 4, 32, True, 32, 32),
+    (64, 4, 2, 32, True, 32, 32),
+    # the fused backward over 512-wide sub-tiles below, on and above the diagonal, at
+    # heads of 64, 128 (two heads folded) and 256
+    (1536, 2, 1, 64, True, 512, None),
+    (1536, 4, 2, 128, True, 512, None),
+    (1024, 2, 2, 256, True, 512, None),
+    # two kv blocks (dq and dk/dv apart), a padded edge inside a sub-tile and a
+    # sub-tile of padding alone, rows of padding in the last q block
+    (1300, 2, 1, 64, True, 512, 1024),
+    (1300, 2, 1, 64, False, 512, 1024),
+], ids=lambda x: str(x))
+def test_grads_match_xla(S, H, KVH, D, causal, bq, bk):
+    B = 2 if S < 1024 else 1
     q, k, v = make_qkv(jax.random.key(4), B, S, S, H, KVH, D)
 
     def loss_ref(q, k, v):
-        return jnp.sum(xla_attention(q, k, v, causal=True) ** 2)
+        return jnp.sum(xla_attention(q, k, v, causal=causal) ** 2)
 
     def loss_flash(q, k, v):
         return jnp.sum(
-            flash_attention(q, k, v, causal=True, block_q=32, block_k=32) ** 2
+            flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk) ** 2
         )
 
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
@@ -89,10 +136,16 @@ def test_grads_match_xla(KVH):
         )
 
 
-def test_grads_with_segments_and_padding():
-    B, S, H, KVH, D = 1, 100, 4, 2, 32  # non-divisible: padded blocks
+@pytest.mark.parametrize("S,cut,bq,bk", [
+    (100, 40, 32, 32),
+    # the boundary inside a sub-tile below the diagonal, the padded edge inside another
+    (1400, 200, 512, 1024),
+    (1536, 200, 512, None),
+])
+def test_grads_with_segments_and_padding(S, cut, bq, bk):
+    B, H, KVH, D = 1, 4, 2, 32  # non-divisible: padded blocks
     q, k, v = make_qkv(jax.random.key(5), B, S, S, H, KVH, D)
-    seg = (jnp.arange(S)[None, :] >= 40).astype(jnp.int32)
+    seg = (jnp.arange(S)[None, :] >= cut).astype(jnp.int32)
 
     def loss_ref(q, k, v):
         return jnp.sum(xla_attention(q, k, v, causal=True, segment_ids=seg) ** 2)
@@ -100,7 +153,7 @@ def test_grads_with_segments_and_padding():
     def loss_flash(q, k, v):
         return jnp.sum(
             flash_attention(
-                q, k, v, causal=True, segment_ids=seg, block_q=32, block_k=32
+                q, k, v, causal=True, segment_ids=seg, block_q=bq, block_k=bk
             ) ** 2
         )
 
@@ -143,25 +196,27 @@ def test_flash_under_jit_and_grad_jit():
     assert np.isfinite(np.asarray(g(q, k, v))).all()
 
 
-@pytest.mark.parametrize("nk_blocks", [1, 2])
-def test_fold_heads_parity(nk_blocks):
+@pytest.mark.parametrize("S,bq,nk_blocks", [(128, 32, 1), (128, 32, 2), (1024, 256, 1)],
+                         ids=["1", "2", "sub_tiles"])
+def test_fold_heads_parity(S, bq, nk_blocks):
     """Folded (F=G) and unfolded (F=1) kernels must agree bit-for-bit in
     fwd and grads, on both the fused (nk=1) and unfused (nk>1) backward
-    paths, with GQA group 4."""
-    B, S, H, KVH, D = 2, 128, 8, 2, 32
-    bk = 128 // nk_blocks
+    paths, with GQA group 4; and where the kv block is walked in
+    sub-tiles, whose mask is one [Bq, Tk] array for the four heads."""
+    B, H, KVH, D = 2, 8, 2, 32
+    bk = S // nk_blocks
     q, k, v = make_qkv(jax.random.key(7), B, S, S, H, KVH, D)
 
     def loss(fold):
         def f(q, k, v):
             return jnp.sum(
-                flash_attention(q, k, v, causal=True, block_q=32, block_k=bk,
+                flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
                                 fold_heads=fold) ** 2)
         return f
 
-    o1 = flash_attention(q, k, v, causal=True, block_q=32, block_k=bk,
+    o1 = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
                          fold_heads=1)
-    o4 = flash_attention(q, k, v, causal=True, block_q=32, block_k=bk,
+    o4 = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
                          fold_heads=4)
     np.testing.assert_allclose(o4, o1, atol=1e-6, rtol=1e-6)
     # grads: folding reorders the dk/dv reduction (one wide contraction
@@ -247,3 +302,62 @@ def test_head_major_entry_is_the_other_entry_without_its_transposes(cpu_devices,
         np.asarray(jnp.swapaxes(attention_head_major(
             *(jnp.swapaxes(x, 1, 2) for x in (q, k, v)), segment_ids=seg, impl="xla"), 1, 2)),
         np.asarray(want), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("Bq,Bk,Tk,q_offset,sq_valid,sk_valid", [
+    (512, 4096, 512, 0, 4096, 4096),   # the cells' shape: 36 of 64 sub-tiles run
+    (512, 1536, 512, 0, 1536, 1536),
+    (256, 1024, 512, 0, 1024, 1024),   # two q blocks a sub-tile
+    (512, 1024, 512, 0, 1300, 1300),   # two kv blocks, a padded edge, a sub-tile of padding
+    (256, 1536, 512, 1024, 512, 1536),  # q_offset on the grid
+    (512, 1536, 512, 700, 512, 1536),   # and off it
+    (32, 32, 32, 0, 100, 100),          # one sub-tile a block
+    (16, 16, 16, 48, 16, 64),
+])
+def test_sub_tiles_skipped_are_those_the_triangle_masks_whole(causal, Bq, Bk, Tk, q_offset,
+                                                              sq_valid, sk_valid):
+    """`_tiles_to_run` (one rule for the forward and the fused backward)
+    against the dense triangle: a sub-tile is skipped iff every entry of
+    it lies above the diagonal, and the ones that run are a prefix of the
+    kv block's sub-tiles."""
+    from ray_tpu.ops.flash import _tiles_to_run
+
+    nq, nk, nt = -(-sq_valid // Bq), -(-sk_valid // Bk), Bk // Tk
+    i, j = np.arange(nq)[:, None], np.arange(nk)[None, :]
+    n_run = np.broadcast_to(np.asarray(
+        _tiles_to_run(i, j, Bq, Bk, Tk, causal=causal, q_offset=q_offset)), (nq, nk))
+    rows, cols = np.arange(nq * Bq)[:, None], np.arange(nk * Bk)[None, :]
+    below = (rows + q_offset >= cols) if causal else np.ones((nq * Bq, nk * Bk), bool)
+    for a in range(nq):
+        for b in range(nk):
+            for t in range(nt):
+                tile = (slice(a * Bq, (a + 1) * Bq), slice(b * Bk + t * Tk, b * Bk + (t + 1) * Tk))
+                assert (t < n_run[a, b]) == below[tile].any(), (a, b, t)
+    if (Bq, Bk, sk_valid) == (512, 4096, 4096):
+        assert int(n_run.sum()) == (36 if causal else 64)
+
+
+def test_rows_with_nothing_to_attend_weigh_nothing():
+    """Ring attention's rotating kv blocks: rows whose segment meets no
+    key of the block. Where a sub-tile ran they hold a finite mean and
+    where none ran zeros, and either way `lse` is about NEG_INF, which
+    is what lets the blockwise merge give them no weight; the other rows
+    are the composite's. Over sub-tiles below and on the diagonal."""
+    from ray_tpu.ops.flash import NEG_INF
+
+    B, S, H, KVH, D = 1, 1024, 2, 1, 32
+    q, k, v = make_qkv(jax.random.key(8), B, S, S, H, KVH, D)
+    qseg = (jnp.arange(S)[None, :] >= 300).astype(jnp.int32)  # rows 0..299 are segment 0
+    kseg = jnp.ones((B, S), jnp.int32)                          # which no key has
+    for causal in (True, False):
+        o, lse = flash_attention(q, k, v, causal=causal, segment_ids=qseg, kv_segment_ids=kseg,
+                                 block_q=256, return_lse=True)
+        assert np.isfinite(np.asarray(o)).all()
+        assert (np.asarray(lse[:, :300]) < 0.9 * NEG_INF).all()
+        assert (np.asarray(lse[:, 300:]) > -1e4).all()
+        ref = xla_attention(q[:, 300:], k, v, causal=causal, q_offset=300)
+        np.testing.assert_allclose(o[:, 300:], ref, atol=2e-5, rtol=2e-5)
+    # keys wholly after the rows (a causal block from the future): no sub-tile runs
+    o, lse = flash_attention(q[:, :256], k, v, causal=True, q_offset=-1024, return_lse=True)
+    assert not np.asarray(o).any() and (np.asarray(lse) < 0.9 * NEG_INF).all()

@@ -64,8 +64,13 @@ _BF16, _I32 = jnp.bfloat16, jnp.int32
 _CACHE = ((KVH, NUM_PAGES * PAGE + PAGE, D), _BF16)
 
 
+@pytest.mark.parametrize("S", [1024, 4096])
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
-def test_flash_attention_compiles_for_v5e(v5e, grad):
+def test_flash_attention_compiles_for_v5e(v5e, grad, S):
+    """Heads of 128 at two and at eight sub-tiles a kv block: the walk over
+    the sub-tiles (a loop whose trip count the program id gives, two a
+    trip, and the odd one after it) and the lane-dense row statistics
+    are Mosaic's to accept, not the interpreter's."""
     from ray_tpu.ops.flash import flash_attention
 
     def fwd(q, k, v):
@@ -76,7 +81,7 @@ def test_flash_attention_compiles_for_v5e(v5e, grad):
             lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
         )(q, k, v)
 
-    B, S = 2, 1024
+    B = 2
     _compile(bwd if grad else fwd,
              ((B, S, H, D), _BF16), ((B, S, KVH, D), _BF16), ((B, S, KVH, D), _BF16),
              sharding=_one_chip(v5e))
