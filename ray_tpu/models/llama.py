@@ -26,7 +26,6 @@ import jax.numpy as jnp
 
 from ray_tpu.nn.layers import (
     apply_rope,
-    cross_entropy_loss,
     fused_cross_entropy_loss,
     init_dense,
     rms_norm,
@@ -286,49 +285,56 @@ def _block(
     if overlap:
         from ray_tpu.parallel.tp_overlap import ag_matmul, rs_matmul
 
-    x = rms_norm(h, lp["ln1"], c.rms_eps)
+    with jax.named_scope("block.norm"):
+        x = rms_norm(h, lp["ln1"], c.rms_eps)
     if cca is not None:
         h = h + cca.cca_sublayer(x, lp, c, positions=positions, segment_ids=segment_ids)
     elif mla is not None:
         h = h + mla.mla_sublayer(x, lp, c, positions=positions, segment_ids=segment_ids)
     else:
-        if overlap:
-            q, k, v = ag_matmul(x, [lp[n].astype(x.dtype) for n in ("wq", "wk", "wv")])
-            q = q.reshape(B, S, c.n_heads, hd)
-            k, v = k.reshape(B, S, c.n_kv_heads, hd), v.reshape(B, S, c.n_kv_heads, hd)
-        else:
-            q = jnp.einsum("bsd,dh->bsh", x, lp["wq"].astype(x.dtype)).reshape(
-                B, S, c.n_heads, hd)
-            k = jnp.einsum("bsd,dh->bsh", x, lp["wk"].astype(x.dtype)).reshape(
-                B, S, c.n_kv_heads, hd)
-            v = jnp.einsum("bsd,dh->bsh", x, lp["wv"].astype(x.dtype)).reshape(
-                B, S, c.n_kv_heads, hd)
-        if moe is not None and c.qk_norm:  # over the whole projected width, before rotary
-            q = rms_norm(q.reshape(B, S, -1), lp["q_norm"], c.rms_eps).reshape(q.shape)
-            k = rms_norm(k.reshape(B, S, -1), lp["k_norm"], c.rms_eps).reshape(k.shape)
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-        o = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=c.attention_impl)
-        # named so the "dots" remat policy can SAVE it: the policy recognizes
-        # dot_general outputs but not a pallas_call's, so without the name the
-        # backward pass re-runs the whole flash kernel forward (~25% of a
-        # train step) just to rebuild this tensor
-        o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
-        o, wo = o.reshape(B, S, c.n_heads * hd), lp["wo"].astype(x.dtype)
-        h = h + (rs_matmul(o, wo) if overlap else jnp.einsum("bsh,hd->bsd", o, wo))
+        with jax.named_scope("attn.qkv"):
+            if overlap:
+                q, k, v = ag_matmul(x, [lp[n].astype(x.dtype) for n in ("wq", "wk", "wv")])
+                q = q.reshape(B, S, c.n_heads, hd)
+                k, v = k.reshape(B, S, c.n_kv_heads, hd), v.reshape(B, S, c.n_kv_heads, hd)
+            else:
+                q = jnp.einsum("bsd,dh->bsh", x, lp["wq"].astype(x.dtype)).reshape(
+                    B, S, c.n_heads, hd)
+                k = jnp.einsum("bsd,dh->bsh", x, lp["wk"].astype(x.dtype)).reshape(
+                    B, S, c.n_kv_heads, hd)
+                v = jnp.einsum("bsd,dh->bsh", x, lp["wv"].astype(x.dtype)).reshape(
+                    B, S, c.n_kv_heads, hd)
+        with jax.named_scope("attn.rope"):
+            if moe is not None and c.qk_norm:  # over the whole projected width, before rotary
+                q = rms_norm(q.reshape(B, S, -1), lp["q_norm"], c.rms_eps).reshape(q.shape)
+                k = rms_norm(k.reshape(B, S, -1), lp["k_norm"], c.rms_eps).reshape(k.shape)
+            q = apply_rope(q, cos, sin, positions)
+            k = apply_rope(k, cos, sin, positions)
+        with jax.named_scope("attn.attend"):
+            o = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=c.attention_impl)
+            # named so the "dots" remat policy can SAVE it: the policy recognizes
+            # dot_general outputs but not a pallas_call's, so without the name the
+            # backward pass re-runs the whole flash kernel forward (~25% of a
+            # train step) just to rebuild this tensor
+            o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
+        with jax.named_scope("attn.out"):
+            o, wo = o.reshape(B, S, c.n_heads * hd), lp["wo"].astype(x.dtype)
+            h = h + (rs_matmul(o, wo) if overlap else jnp.einsum("bsh,hd->bsd", o, wo))
 
-    x = rms_norm(h, lp["ln2"], c.rms_eps)
+    with jax.named_scope("block.norm"):
+        x = rms_norm(h, lp["ln2"], c.rms_eps)
     if moe is not None:
         # the rings of tp_overlap.py are the dense MLP's: under tp > 1
         # the expert layer's matmuls are the partitioner's to place
         y, stats, router_state = moe.moe_ffn(x, lp, c, router_state)
         return ((h + y, router_state) if carries_router else h + y), stats
-    if not overlap:
-        return h + swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"]), None
-    # the MLP treats all tokens alike: it can keep the ring's own order
-    gate, up = ag_matmul(
-        x, (lp["w_gate"].astype(x.dtype), lp["w_up"].astype(x.dtype)), token_order=False)
-    return h + rs_matmul(jax.nn.silu(gate) * up, lp["w_down"].astype(x.dtype)), None
+    with jax.named_scope("dense.ffn"):
+        if not overlap:
+            return h + swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"]), None
+        # the MLP treats all tokens alike: it can keep the ring's own order
+        gate, up = ag_matmul(
+            x, (lp["w_gate"].astype(x.dtype), lp["w_up"].astype(x.dtype)), token_order=False)
+        return h + rs_matmul(jax.nn.silu(gate) * up, lp["w_down"].astype(x.dtype)), None
 
 
 def hidden_states(
@@ -411,54 +417,59 @@ def _trunk(
     cos = sin = None
     # CCA rotates part of a head and MLA its decoupled part, from the positions themselves
     if _cca(c) is None and mla is None:
-        cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
+        with jax.named_scope("attn.rope"):
+            cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
 
-    h = params["embed"].astype(c.dtype)[tokens]  # [B, S, D]
+    with jax.named_scope("embed"):
+        h = params["embed"].astype(c.dtype)[tokens]  # [B, S, D]
 
     block = partial(
         _block, config=c, cos=cos, sin=sin, positions=positions, segment_ids=segment_ids
     )
-    layers = params["layers"]
-    if mla is not None and mla.has_more_than_the_stack(c):
-        # two kinds of block in one model: the leading dense layers run
-        # before the scan over the expert layers' stack
-        layers = mla.stack_of(params, c)
-        dense = _remat(partial(block, dense_ffn=True), c)
-        for i in range(c.first_dense_layers):
-            h, _ = dense(h, mla.dense_layer(params, i))
-    block = _remat(block, c)
+    # the stack: under this name stand the scan's own slices and stacked writes and
+    # the residual adds that no sublayer's scope holds; every scope inside it wins
+    with jax.named_scope("block.stack"):
+        layers = params["layers"]
+        if mla is not None and mla.has_more_than_the_stack(c):
+            # two kinds of block in one model: the leading dense layers run
+            # before the scan over the expert layers' stack
+            layers = mla.stack_of(params, c)
+            dense = _remat(partial(block, dense_ffn=True), c)
+            for i in range(c.first_dense_layers):
+                h, _ = dense(h, mla.dense_layer(params, i))
+        block = _remat(block, c)
 
-    mesh = current_mesh()
-    pp = mesh.shape.get("pp", 1) if mesh is not None else 1
-    # an expert configuration is not pipelined: the stages hand on
-    # activations only, and its router losses and counts (and an MLP
-    # router's state) would be lost on the way; its layers run as the
-    # one scan below on any mesh
-    if pp > 1 and _moe(c) is None:
-        # pipeline the layer stack over the mesh `pp` axis (GPipe
-        # microbatch schedule inside this jitted program — see
-        # parallel/pipeline.py; reference PP is external vLLM stage
-        # actors, vllm_models.py:121)
-        if segment_ids is not None:
-            raise NotImplementedError("segment packing + pipeline parallelism")
-        if positions.ndim > 1:
-            # per-batch positions would need microbatching alongside h
-            raise NotImplementedError("batched positions + pipeline parallelism")
-        from ray_tpu.parallel.pipeline import pipeline_apply, stack_stages
+        mesh = current_mesh()
+        pp = mesh.shape.get("pp", 1) if mesh is not None else 1
+        # an expert configuration is not pipelined: the stages hand on
+        # activations only, and its router losses and counts (and an MLP
+        # router's state) would be lost on the way; its layers run as the
+        # one scan below on any mesh
+        if pp > 1 and _moe(c) is None:
+            # pipeline the layer stack over the mesh `pp` axis (GPipe
+            # microbatch schedule inside this jitted program — see
+            # parallel/pipeline.py; reference PP is external vLLM stage
+            # actors, vllm_models.py:121)
+            if segment_ids is not None:
+                raise NotImplementedError("segment packing + pipeline parallelism")
+            if positions.ndim > 1:
+                # per-batch positions would need microbatching alongside h
+                raise NotImplementedError("batched positions + pipeline parallelism")
+            from ray_tpu.parallel.pipeline import pipeline_apply, stack_stages
 
-        def stage(stage_params, x):
-            out, _ = jax.lax.scan(block, x, stage_params)
-            return out
+            def stage(stage_params, x):
+                out, _ = jax.lax.scan(block, x, stage_params)
+                return out
 
-        h, stats = pipeline_apply(
-            mesh, stage, stack_stages(layers, pp), h, n_micro=pp
-        ), None
-    elif _carries_router_state(c):
-        # nothing precedes the first layer's router: a state of zeros adds nothing
-        state = jnp.zeros((B, S, c.router_hidden), jnp.float32)
-        (h, _), stats = jax.lax.scan(block, (h, state), layers)
-    else:
-        h, stats = jax.lax.scan(block, h, layers)
+            h, stats = pipeline_apply(
+                mesh, stage, stack_stages(layers, pp), h, n_micro=pp
+            ), None
+        elif _carries_router_state(c):
+            # nothing precedes the first layer's router: a state of zeros adds nothing
+            state = jnp.zeros((B, S, c.router_hidden), jnp.float32)
+            (h, _), stats = jax.lax.scan(block, (h, state), layers)
+        else:
+            h, stats = jax.lax.scan(block, h, layers)
 
     return h, stats, block
 
@@ -513,19 +524,11 @@ def loss_and_weight_fn(
     Uses the fused lm-head + CE (nn/layers.py fused_cross_entropy_loss):
     the [T, V] fp32 logits/softmax pipeline was ~36% of the flagship
     train step before fusion (round-5 profile)."""
-    import os
-
     h_last, stats, block = _trunk(
         params, batch["tokens"], config, segment_ids=batch.get("segment_ids")
     )
-    h = rms_norm(h_last, params["final_norm"], config.rms_eps)
-    # A/B probe hook (benchmarks). Read at TRACE time: flipping it in a
-    # process that already compiled the step has no effect — set it in a
-    # fresh process (the benchmark harnesses fork per variant).
-    if os.environ.get("RAY_TPU_NAIVE_CE"):
-        logits = jnp.einsum("bsd,dv->bsv", h, output_weight(params).astype(config.dtype))
-        loss, weight = cross_entropy_loss(logits, batch["targets"], batch.get("mask"))
-    else:
+    with jax.named_scope("head"):
+        h = rms_norm(h_last, params["final_norm"], config.rms_eps)
         loss, weight = fused_cross_entropy_loss(
             h, output_weight(params), batch["targets"], batch.get("mask")
         )

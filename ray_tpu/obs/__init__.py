@@ -14,6 +14,10 @@ Three pieces:
    seconds (``obs.layer_counters()``), and a ``Span`` in the recorder's
    layer ring while ``obs.capture()`` is open; ``obs.compile_log()`` is
    the process's compiles and cache loads (utils/compile_cache.py);
+ * programs — ``obs.note_program(name, jitted)`` keeps what finds a
+   compiled program again (train/step.py notes ``train.step``);
+   ``obs.op_names()`` is its instructions with the named scopes each ran
+   under;
  * slo — serving SLO histograms (TTFT / TPOT / queue-wait / e2e +
    router dispatch latency) on the util/metrics Prometheus registry;
  * telemetry — the CLUSTER-WIDE metrics plane (import
@@ -37,10 +41,10 @@ from ray_tpu.obs.context import (
     new_context,
     use,
 )
+from ray_tpu.obs.programs import note as note_program, op_names
 from ray_tpu.obs.recorder import (
     Span,
     SpanRecorder,
-    clock_marker,
     clock_offset,
     get_recorder,
     layer_record,
@@ -79,12 +83,13 @@ __all__ = [
     "Span",
     "SpanRecorder",
     "capture",
-    "clock_marker",
     "clock_offset",
     "compile_log",
     "get_recorder",
     "layer_counters",
     "layer_record",
     "layer_span",
+    "note_program",
+    "op_names",
     "span",
 ]

@@ -220,11 +220,11 @@ class SpanRecorder:
         t0 = time.time()
         with self._layer_lock:
             self._captures += 1
-        clock_marker()
+        _clock_marker()
         try:
             yield spans
         finally:
-            clock_marker()
+            _clock_marker()
             with self._layer_lock:
                 self._captures -= 1
             spans.extend(self.layer_spans(since=t0))
@@ -447,11 +447,10 @@ def _annotation(name: str):
         return None
 
 
-def clock_marker() -> None:
+def _clock_marker() -> None:
     """Write one annotation whose name carries the recorder's clock
     (``time.time_ns()``) at the instant the annotation starts on the
-    profiler's. ``capture()`` writes one at each edge; call it yourself
-    after ``start_trace`` where no capture is used."""
+    profiler's. ``capture()`` writes one at each edge."""
     ann = _annotation(f"{CLOCK_MARKER}{time.time_ns()}")
     if ann is not None:
         with ann:

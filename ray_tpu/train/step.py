@@ -182,15 +182,20 @@ def make_train_step(
             )
             (loss_sum, w_sum, grad_sum), stats = jax.lax.scan(accum, zero, micro)
             loss = loss_sum / w_sum
-            grads = jax.tree.map(lambda g: g / w_sum, grad_sum)
+            with jax.named_scope("optim"):
+                grads = jax.tree.map(lambda g: g / w_sum, grad_sum)
 
-        updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        grad_norm = optax.global_norm(grads)
+        with jax.named_scope("optim"):
+            updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
+            grad_norm = optax.global_norm(grads)
         new_state = TrainState(params=params, opt_state=opt_state, step=state.step + 1)
         metrics = {"loss": loss, "grad_norm": grad_norm}
         if stats is not None:
             metrics["stats"] = stats
         return new_state, metrics
 
-    return jax.jit(step, donate_argnums=(0,), compiler_options=_compiler_options(mesh))
+    # noted for obs.op_names(): which block of the model an
+    # operation of the compiled step belongs to, asked after the fact
+    return obs.note_program("train.step", jax.jit(
+        step, donate_argnums=(0,), compiler_options=_compiler_options(mesh)))

@@ -462,3 +462,124 @@ def test_init_sharded_params_is_one_span_and_every_leaf_is_born_sharded(cpu_devi
     # sharded for real, not replicated eight times
     wq = params["layers"]["wq"]
     assert wq.addressable_shards[0].data.size * 8 == wq.size
+
+
+# -- the step's names and the program's record of its compiled step (PR 37) ------------
+
+# every scope a reader may book an operation to, and what each kind of block runs of them
+_EVERY_KIND = ("embed", "block.stack", "block.norm", "head", "optim")
+_GQA = ("attn.qkv", "attn.rope", "attn.attend", "attn.out")
+_MOE = ("moe.router", "moe.dispatch", "moe.experts", "moe.combine")
+_CCA = ("cca.proj", "cca.mix", "cca.attend", "cca.out")
+_MLA = ("mla.down", "mla.up", "mla.glue", "mla.attend", "mla.out")
+_MTP = ("mtp.merge", "mtp.block", "mtp.head")
+_LISTED = _EVERY_KIND + _GQA + _MOE + _CCA + _MLA + _MTP + ("dense.ffn", "shared.ffn")
+SCOPES_OF = {
+    "llama-tiny": _EVERY_KIND + _GQA + ("dense.ffn",),
+    "moe-tiny": _EVERY_KIND + _GQA + _MOE,
+    "zaya-tiny": _EVERY_KIND + _CCA + _MOE,
+    "glm-lite-tiny": _EVERY_KIND + _MLA + _MOE + _MTP + ("dense.ffn", "shared.ffn"),
+}
+
+
+def _tiny_step(model="llama-tiny", batch=2):
+    import optax
+
+    from ray_tpu.models.registry import get_model_config
+    from ray_tpu.train.step import TrainState, make_train_step
+
+    cfg, opt = get_model_config(model), optax.adamw(1e-3)
+    state = TrainState.create(llama.init_params(cfg, jax.random.key(0)), opt)
+    step = make_train_step(lambda p, b: llama.loss_and_weight_fn(p, b, cfg), opt)
+    tokens = jnp.zeros((batch, 32), jnp.int32)
+    return step, state, {"tokens": tokens, "targets": tokens}
+
+
+def _under(path, scope):
+    """`scope` as a whole component of an op_name path, bare or wrapped."""
+    import re
+
+    return re.search(r"(?<![^/(])" + re.escape(scope) + r"(?![^/)])", path) is not None
+
+
+@pytest.mark.parametrize("model", sorted(SCOPES_OF))
+def test_every_part_of_the_train_step_runs_under_a_name_of_the_models_own(model):
+    """Forward AND backward of each scope the kind of block runs, and no
+    matmul of the compiled step under none of them."""
+    from ray_tpu.obs.programs import parse_op_names
+
+    step, state, batch = _tiny_step(model)
+    names = parse_op_names(step.lower(state, batch).compile().as_text())
+    paths = {path for entries in names.values() for _, path, _ in entries if path}
+    for scope in SCOPES_OF[model]:
+        mine = [p for p in paths if _under(p, scope)]
+        assert any("transpose(" not in p for p in mine), f"{scope}: no forward op"
+        if scope != "optim":  # nothing differentiates the update
+            assert any("transpose(jvp(" in p for p in mine), f"{scope}: no backward op"
+    for scope in set(_LISTED) - set(SCOPES_OF[model]):
+        assert not any(_under(p, scope) for p in paths), f"{scope} in a {model} step"
+    matmuls = [(name, path) for name, entries in names.items()
+               for op, path, _ in entries[:1] if op in ("dot", "convolution")]
+    assert matmuls
+    # the stack's name is around every block: it is no name for a matmul inside one
+    sublayers = [s for s in _LISTED if s != "block.stack"]
+    for name, path in matmuls:
+        assert any(_under(path, s) for s in sublayers), f"{name} under no scope: {path!r}"
+
+
+def test_train_step_record_notes_abstract_values_at_the_first_call_and_lowers_when_asked():
+    from unittest import mock
+
+    step, state, batch = _tiny_step()
+    # noted when it is made; nothing to answer from before it has run
+    assert step._abstract is None and obs.op_names() is None
+    assert "stablehlo" in step.lower(state, batch).as_text()  # the jitted step's own
+    real = step._jitted
+    step._jitted = spy = mock.Mock(wraps=real)
+    state, _ = step(state, batch)
+    noted = step._abstract
+    leaves = jax.tree.leaves(noted)
+    assert leaves and all(isinstance(x, jax.ShapeDtypeStruct) for x in leaves)  # no array held
+    assert [x.shape for x in jax.tree.leaves(noted[1])] == [(2, 32), (2, 32)]
+    state, _ = step(state, batch)
+    assert step._abstract is noted and spy.call_count == 2 and spy.lower.call_count == 0
+    names = obs.op_names()
+    assert obs.op_names() == names
+    assert spy.lower.call_count == 1  # two requests, one lowering and compile
+    ran = real.lower(state, batch).compile().as_text()
+    assert set(names) == set(obs.programs.parse_op_names(ran))  # the step that ran
+    # a fusion carries the paths of its fused computation's instructions after its own
+    fusions = [e for e in names.values() if e[0][0] == "fusion"]
+    assert fusions and all(len(e) > 1 for e in fusions)
+    assert any(_under(path, "optim") for e in fusions for _, path, _ in e[1:])
+    # what an instruction reads, seen through a loop's tuple: an element the layer scan's
+    # body takes from its parameter reads what the loop was given, not the parameter
+    body = [e[0] for e in names.values() if e[0][0] == "get-tuple-element" and e[0][2]]
+    assert body and all(names[read][0][0] != "parameter" or not names[read][0][2]
+                        for _, _, (read,) in body)
+
+
+def test_a_second_train_step_replaces_the_first_record():
+    first, state, batch = _tiny_step()
+    first(state, batch)
+    had = obs.op_names()
+    assert had and any(_under(p, "dense.ffn") for e in had.values() for _, p, _ in e)
+    second, state, batch = _tiny_step("moe-tiny", batch=1)
+    assert obs.op_names() is None  # the newest step has not run yet
+    second(state, batch)
+    paths = [p for e in obs.op_names().values() for _, p, _ in e]
+    assert any(_under(p, "moe.experts") for p in paths)
+    assert not any(_under(p, "dense.ffn") for p in paths)
+    assert first.compiled() is not None  # the first keeps its own
+
+
+def test_a_step_called_inside_another_program_notes_shapes_without_a_placement():
+    step, state, batch = _tiny_step()
+    out = jax.eval_shape(lambda s, b: step(s, b)[1]["loss"], state, batch)
+    assert out.shape == () and step._abstract is not None
+    assert all(x.sharding is None for x in jax.tree.leaves(step._abstract))
+
+
+def test_obs_exports_the_record_and_no_clock_marker():
+    assert {"note_program", "op_names"} <= set(obs.__all__) and not hasattr(obs, "memory")
+    assert "clock_marker" not in obs.__all__ and not hasattr(obs, "clock_marker")
