@@ -56,7 +56,9 @@ axis or on major ones; `ops/attention.attention_head_major` hands q, k
 and v to the kernels as they are, and `cca.out` contracts (H, hd) of
 what comes back. XLA chooses the layout of whatever nothing pins, and
 for these arrays it chose channels-in-sublanes and back by turns, so
-`_head_major` pins the tile where the matmuls write.
+nn/layers.py::head_major, the one helper this module and models/llama.py
+share (its full attention has been head-major the same way since PR 38),
+pins the tile where the matmuls write.
 
 What the published config does not fix (no convolution or projection
 bias, which key-value head is the shifted one, the form of the q-k
@@ -74,11 +76,10 @@ from typing import Any, Optional
 import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
-from jax.experimental.layout import Layout, with_layout_constraint
 
 from ray_tpu import obs
 from ray_tpu.models import moe
-from ray_tpu.nn.layers import init_dense
+from ray_tpu.nn.layers import head_major, init_dense
 from ray_tpu.ops.attention import attention_head_major
 
 Params = dict[str, Any]
@@ -200,15 +201,6 @@ def shift_tokens(x: jax.Array, n: int, segment_ids: Optional[jax.Array],
     return jnp.where(same.reshape(along), y, jnp.zeros((), x.dtype))
 
 
-def _head_major(x: jax.Array) -> jax.Array:
-    """x [B, heads, S, hd], held in memory in that order: the tile is
-    (S, hd). Without it the compiler lays the mix out by what the next
-    operation would like (the tokens in the lanes for a shift, the
-    channels in the sublanes for the rotary's slices) and copies between
-    the two."""
-    return with_layout_constraint(x, Layout(major_to_minor=(0, 1, 2, 3)))
-
-
 def _mix_in_heads(u: jax.Array, w: jax.Array) -> jax.Array:
     """u [B, n, S, c] x w [n, c, d] -> [B, n, S, d] in float32: a matmul
     a head, batched over (B, n) so that the result comes out in the
@@ -216,7 +208,7 @@ def _mix_in_heads(u: jax.Array, w: jax.Array) -> jax.Array:
     itself: the CPU backend refuses the one `jnp.einsum` makes for
     bfloat16 operands and a float32 result.)"""
     w = jnp.broadcast_to(w, u.shape[:1] + w.shape)
-    return _head_major(jax.lax.dot_general(
+    return head_major(jax.lax.dot_general(
         u, w, (((3,), (2,)), ((0, 1), (0, 1))), preferred_element_type=_F32))
 
 
@@ -267,7 +259,7 @@ def cca_sublayer(x: jax.Array, lp: Params, c: ZayaConfig, *, positions: jax.Arra
     with obs.layer_span("cca.attn"):  # counts engaged sites, while tracing
         with jax.named_scope("cca.proj"):
             u_q, u_k, v = (
-                _head_major(jnp.einsum("bsd,dnh->bnsh", x, w.astype(dt).reshape(D, -1, hd)))
+                head_major(jnp.einsum("bsd,dnh->bnsh", x, w.astype(dt).reshape(D, -1, hd)))
                 for w in (lp["wq"], lp["wk"], jnp.concatenate([lp["wv1"], lp["wv2"]], axis=1)))
         with jax.named_scope("cca.mix"):
             # the second half of the value CHANNELS (not of the heads) reads token t - 1

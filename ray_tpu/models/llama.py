@@ -12,6 +12,27 @@ deployments/llm/vllm/vllm_engine.py): pure-functional jax with
   * bf16 compute / fp32 params+norms, fp32 softmax and loss,
   * per-layer rematerialization (`jax.checkpoint`) to trade MXU FLOPs
     for HBM.
+
+The layout of the attention sublayer (PR 38; CCA since PR 33, MLA since
+PR 34). q, k and v are HEAD-MAJOR, [B, heads, S, hd], from where the
+projections write them (the weight read as [D, heads, hd],
+`"bsd,dnh->bnsh"`) to where `wo` contracts (heads, hd) of what the
+kernel gives back (`"bhsk,hkd->bsd"`): on a TPU an array's last two
+dimensions are its tile, so the tile is (tokens, a head's channels) and
+always full, where [B, S, heads, hd] made 8 key-value heads the rows of
+a half-empty bfloat16 tile. The q/k norm and the rotary act on the last
+axis and on major ones (`_norm_over_heads`,
+nn/layers.py::apply_rope_head_major, whose halves change places on the
+MXU so that nothing is cut inside the 128 lanes), the flash kernels,
+whose own layout this is, take q, k and v as they are
+(ops/attention.attention_head_major), and nn/layers.py::head_major, the
+one helper this module and models/cca.py share, pins the tile where the
+matmuls write. Under `tp > 1` the rings of parallel/tp_overlap.py hand
+back and take [B, S, h] slabs in token order: one `swapaxes` a tensor
+after the ring and one before `rs_matmul` stand where the kernel
+wrapper's three transposes in and one out stood. models/llama_decode.py
+(serving: a cache laid out [.., S, heads, hd]) keeps `apply_rope` and
+its own layout.
 """
 
 from __future__ import annotations
@@ -25,14 +46,15 @@ import jax.ad_checkpoint
 import jax.numpy as jnp
 
 from ray_tpu.nn.layers import (
-    apply_rope,
+    apply_rope_head_major,
     fused_cross_entropy_loss,
+    head_major,
     init_dense,
     rms_norm,
     rope_frequencies,
     swiglu,
 )
-from ray_tpu.ops.attention import attention
+from ray_tpu.ops.attention import attention_head_major
 from ray_tpu.parallel.context import current_mesh
 
 Params = dict[str, Any]
@@ -248,6 +270,16 @@ def packed_positions(segment_ids: Optional[jax.Array], seq_len: int) -> jax.Arra
     return idx - seg_start
 
 
+def _norm_over_heads(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """`rms_norm` over the whole projected width of x [B, heads, S, hd]
+    (scale [heads * hd]): the mean runs over the head axis and the
+    channels, a major axis and the last one, so the tile stays (S, hd)."""
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x32), axis=(1, 3), keepdims=True)
+    scale = scale.astype(jnp.float32).reshape(x.shape[1], 1, x.shape[3])
+    return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
+
+
 def _block(
     carry,  # h [B, S, D]; (h, router state [B, S, R]) for an MLP router
     lp: Params,  # one layer's params (no leading layer dim)
@@ -261,9 +293,10 @@ def _block(
 ) -> tuple[Any, Optional[Params]]:
     """One decoder layer -> (carry, the layer's statistics): the
     attention sublayer of the configuration's kind (full causal GQA with
-    rotary, and the q/k RMSNorm when the configuration has it; or
-    compressed convolutional attention, models/cca.py; or multi-head
-    latent attention, models/mla.py), then the dense SwiGLU or the
+    rotary, and the q/k RMSNorm when the configuration has it, q, k and
+    v head-major from the projections to `wo`: the module's layout
+    paragraph; or compressed convolutional attention, models/cca.py; or
+    multi-head latent attention, models/mla.py), then the dense SwiGLU or the
     expert layer (models/moe.py, whose statistics come back; None for a
     dense layer) by the configuration's own kind; `dense_ffn`: one of an
     expert configuration's leading dense layers."""
@@ -292,34 +325,36 @@ def _block(
     elif mla is not None:
         h = h + mla.mla_sublayer(x, lp, c, positions=positions, segment_ids=segment_ids)
     else:
+        H, dt = c.n_heads, x.dtype
         with jax.named_scope("attn.qkv"):
+            ws = [lp[n].astype(dt) for n in ("wq", "wk", "wv")]
             if overlap:
-                q, k, v = ag_matmul(x, [lp[n].astype(x.dtype) for n in ("wq", "wk", "wv")])
-                q = q.reshape(B, S, c.n_heads, hd)
-                k, v = k.reshape(B, S, c.n_kv_heads, hd), v.reshape(B, S, c.n_kv_heads, hd)
+                # the ring hands back [B, S, h] slabs in token order: one swapaxes each
+                # (and no pin: under a mesh the layout is the compiler's, `head_major`)
+                q, k, v = (jnp.swapaxes(t.reshape(B, S, -1, hd), 1, 2) for t in ag_matmul(x, ws))
             else:
-                q = jnp.einsum("bsd,dh->bsh", x, lp["wq"].astype(x.dtype)).reshape(
-                    B, S, c.n_heads, hd)
-                k = jnp.einsum("bsd,dh->bsh", x, lp["wk"].astype(x.dtype)).reshape(
-                    B, S, c.n_kv_heads, hd)
-                v = jnp.einsum("bsd,dh->bsh", x, lp["wv"].astype(x.dtype)).reshape(
-                    B, S, c.n_kv_heads, hd)
+                q, k, v = (head_major(jnp.einsum("bsd,dnh->bnsh", x, w.reshape(D, -1, hd)))
+                           for w in ws)
         with jax.named_scope("attn.rope"):
             if moe is not None and c.qk_norm:  # over the whole projected width, before rotary
-                q = rms_norm(q.reshape(B, S, -1), lp["q_norm"], c.rms_eps).reshape(q.shape)
-                k = rms_norm(k.reshape(B, S, -1), lp["k_norm"], c.rms_eps).reshape(k.shape)
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
+                q = _norm_over_heads(q, lp["q_norm"], c.rms_eps)
+                k = _norm_over_heads(k, lp["k_norm"], c.rms_eps)
+            q = apply_rope_head_major(q, cos, sin, positions)
+            k = apply_rope_head_major(k, cos, sin, positions)
         with jax.named_scope("attn.attend"):
-            o = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=c.attention_impl)
+            o = attention_head_major(q, k, v, causal=True, segment_ids=segment_ids,
+                                     impl=c.attention_impl)
             # named so the "dots" remat policy can SAVE it: the policy recognizes
             # dot_general outputs but not a pallas_call's, so without the name the
             # backward pass re-runs the whole flash kernel forward (~25% of a
             # train step) just to rebuild this tensor
             o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
         with jax.named_scope("attn.out"):
-            o, wo = o.reshape(B, S, c.n_heads * hd), lp["wo"].astype(x.dtype)
-            h = h + (rs_matmul(o, wo) if overlap else jnp.einsum("bsh,hd->bsd", o, wo))
+            wo = lp["wo"].astype(dt)
+            if overlap:
+                h = h + rs_matmul(jnp.swapaxes(o, 1, 2).reshape(B, S, H * hd), wo)
+            else:
+                h = h + jnp.einsum("bhsk,hkd->bsd", o, wo.reshape(H, hd, D))
 
     with jax.named_scope("block.norm"):
         x = rms_norm(h, lp["ln2"], c.rms_eps)

@@ -12,6 +12,33 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
+
+from ray_tpu.parallel.context import current_mesh
+
+
+def head_major(x: jax.Array) -> jax.Array:
+    """x [B, heads, S, hd], held in memory in that order: the tile is
+    (S, hd), the tokens and a head's channels, and always full. On a TPU
+    an array's last two dimensions are its tile, 8 or 16 rows of 128
+    lanes: with [B, S, heads, hd] the heads are the tile's rows (8
+    key-value heads half padding in bfloat16) and every kernel that
+    wants [B, H, S, hd] is met by a transpose. The attention sublayers
+    (models/llama.py, cca.py, mla.py) keep q, k and v in this order from
+    where the projections write them to where `wo` contracts them; the
+    first two pin it here where a matmul writes: left to itself the
+    compiler lays such an array out by what the next operation would
+    like (the tokens in the lanes for a shift, the channels in the
+    sublanes for the rotary's slices) and copies between the two.
+
+    Under a mesh of several devices the layout is the compiler's: the
+    constraint has no partitioning rule, and the partitioner gathers the
+    whole array onto every device to apply it (26 all-gathers of arrays
+    the size of q in the fsdp 2 x tp 2 step; rehearsal, PR 38)."""
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1:
+        return x
+    return with_layout_constraint(x, Layout(major_to_minor=(0, 1, 2, 3)))
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
@@ -43,6 +70,61 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array, positions: jax.Arra
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
     return out.astype(x.dtype)
+
+
+def _swap_halves(x: jax.Array) -> jax.Array:
+    """[x2, x1] of x = [x1, x2] along the last axis of x [B, H, S, D], as
+    a product with the permutation matrix: exact (one term a sum; at the
+    highest precision, so for float32 operands too), and on the MXU,
+    where a slice or a concatenate that starts inside the 128
+    lanes is a pass of its own over half-empty tiles (PERF.md section 6,
+    PR 33 and PR 38). The batch is spelled as a BATCH dimension of the
+    product, as models/mla.py::_up does: the "dots" remat policy saves
+    every product without one, and this one is cheaper made again."""
+    D = x.shape[-1]
+    swap = jnp.roll(jnp.eye(D, dtype=x.dtype), D // 2, axis=1)
+    return jax.lax.dot_general(
+        x, jnp.broadcast_to(swap, (x.shape[0], D, D)), (((3,), (1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=x.dtype)
+
+
+@jax.custom_vjp
+def _rotate(x: jax.Array, cc: jax.Array, ss: jax.Array) -> jax.Array:
+    """x * [c, c] + [x2, x1] * [-s, s] in float32: `apply_rope`'s
+    x1 c - x2 s and x2 c + x1 s, product for product."""
+    return (x.astype(jnp.float32) * cc + _swap_halves(x).astype(jnp.float32) * ss).astype(x.dtype)
+
+
+def _rotate_fwd(x, cc, ss):
+    return _rotate(x, cc, ss), (cc, ss)
+
+
+def _rotate_bwd(tables, g):
+    # the rotation by the opposite angle, the swap made BEFORE the products as in
+    # the forward: what differentiating `apply_rope` gives, g1 c + g2 s and g2 c -
+    # g1 s rounded once (differentiating `_rotate` would round the swapped half to
+    # g's dtype on its way through the product)
+    cc, ss = tables
+    return _rotate(g, cc, -ss), jnp.zeros_like(cc), jnp.zeros_like(ss)
+
+
+_rotate.defvjp(_rotate_fwd, _rotate_bwd)
+
+
+def apply_rope_head_major(x: jax.Array, cos: jax.Array, sin: jax.Array,
+                          positions: jax.Array) -> jax.Array:
+    """`apply_rope` for x [B, H, S, D], the heads a major axis: the same
+    function, value for value and gradient for gradient. The tables
+    broadcast over a major axis and every operand is a full (S, D) tile:
+    nothing is cut at lane D / 2, the halves change places on the MXU
+    (`_swap_halves`), so one fused pass reads x and writes the result."""
+    c = cos[positions]  # [..., S, D/2]
+    s = sin[positions]
+    if c.ndim == 2:  # positions was [S]
+        c, s = c[None], s[None]
+    cc = jnp.concatenate([c, c], axis=-1)[:, None]  # [1 or B, 1, S, D]
+    ss = jnp.concatenate([-s, s], axis=-1)[:, None]
+    return _rotate(x, cc, ss)
 
 
 def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:

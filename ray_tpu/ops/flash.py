@@ -849,7 +849,10 @@ def flash_attention(
     fold_heads: Optional[int] = None,  # None = auto (largest safe divisor of G)
     return_lse: bool = False,
 ) -> "jax.Array | tuple[jax.Array, jax.Array]":
-    """Drop-in for ops.attention.xla_attention with O(S) memory.
+    """Drop-in for ops.attention.xla_attention with O(S) memory, for a
+    caller that holds [B, S, H, D] (ring attention, tests): q, k and v
+    are transposed to the kernels' [B, H, S, D] here and o back. The
+    models hold heads major and call `flash_attention_head_major`.
 
     kv_segment_ids: when the k/v block carries DIFFERENT segments than q
     (ring attention's rotating kv shards), pass them here; segment_ids
@@ -884,9 +887,12 @@ def flash_attention_head_major(
     segment_ids: Optional[jax.Array] = None,  # [B, S] (requires Sq == Sk)
 ) -> jax.Array:
     """`flash_attention` for a caller that holds the heads as a major
-    dimension already (models/cca.py): [B, H, Sq, D] in and out, the
-    kernels' own layout, so nothing is transposed on the way in or out.
-    `flash_attention` is its three transposes, this, and one back."""
+    dimension already (the attention sublayers of models/llama.py,
+    cca.py and mla.py): [B, H, Sq, D] in and out, the kernels' own
+    layout, so nothing is transposed on the way in or out.
+    `flash_attention` is its three transposes, this, and one back: the
+    path of the [B, S, H, D] callers that are left (ring attention's
+    per-shard calls, the kernel's tests), no model's."""
     _check_shapes(q.shape[1], k.shape[1], q.shape[2], k.shape[2], 0, segment_ids, None)
     o, _ = _flash_head_major(_fold_scale(q, None), k, v, causal=causal,
                              segment_ids=segment_ids)

@@ -326,22 +326,28 @@ def test_one_chip_train_step_never_asks_for_tp_overlap(v5e, monkeypatch):
     assert "tpu_custom_call" in with_module and "collective_permute" not in with_module
 
 
-# sha256 of the lowered train step of mistral-7b (2 layers, flash, AdamW), as
-# commit 5b629f1 (the parent of PR 26) lowers it, the flash kernels' serialized
-# bodies taken out (they embed source locations). A change that MEANS to alter
-# the dense step prints the new text's hash in the failure and replaces these.
+# sha256 of the lowered train step of mistral-7b (2 layers, flash, AdamW), as PR 38
+# (the full-attention sublayer head-major from its projections to `wo`) lowers it, the
+# flash kernels' serialized bodies taken out (they embed source locations); from commit
+# 5b629f1 (the parent of PR 26) to PR 37 it was 14345d8a... / dd35b02d.... A change
+# that MEANS to alter the dense step prints the new text's hash in the failure and
+# replaces these.
 _DENSE_STEP = {
-    None: "14345d8a3cbb5701ae05ae6ed72c71171af81f87aca57866109ecb267e64f703",
-    (1, 1, 2, 1, 1, 2): "dd35b02d6d1417352d22657f5af33b251483b34e949bb0de7bcd00e43f2470b5",
+    None: "e735d680c01a71bc9f75193edc03cd16e2d207738ff990ed5a6cb0e7dddeca3f",
+    (1, 1, 2, 1, 1, 2): "bdea6ab54b92ac603d3d65a9b55c170f53065ddf003ac3aa93407b36fb810b02",
 }
-# the same of olmoe-1b-7b's step as `olmoe-train` builds it (one layer, batch 6), as
-# commit 8e69254 (the parent of PR 33, which gave ops/flash.py a second entry) lowers it
-_OLMOE_STEP = "36d2bc29f84e2c8a10813df1313e801532ecb0c08487bc3463c00e2e9b7fbab0"
+# the same of olmoe-1b-7b's step as `olmoe-train` builds it (one layer, batch 6): the same
+# block with the q/k norm, so PR 38's text too (36d2bc29... from PR 33's parent to PR 37)
+_OLMOE_STEP = "9cbdafe7fcffbc7f1b855fa71c37223b22ce43b219d133479411c4ab59756fe8"
 # the same of zaya1-8b's step as `zaya1-train` builds it (six layers, 8 of 16 experts and an
 # eighth of the vocabulary held, batch 2), as commit 21a2054 (the parent of PR 34, which gave
 # the block a third kind of attention, the expert layer a second kind of score and the
 # decoder blocks outside its scan) lowers it
 _ZAYA_STEP = "5c0e2e3ba71539f56323de421562ccae59c9377d2e3b1531e6a4a2459053d47d"
+# the same of glm-4.7-flash's step as `glm47f-train` builds it (the dense layer, four expert
+# layers and the MTP block, 8 of 64 experts and an eighth of the vocabulary held, batch 2), as
+# commit 955060c (the parent of PR 38) lowers it: CCA and MLA bypass the branch PR 38 changed
+_GLM_LITE_STEP = "e02a2611a60b45b18eece4f59b1de7c10366f389bd73caa96155b744a2aa7826"
 
 
 @pytest.mark.parametrize("kwargs,want", [
@@ -349,7 +355,9 @@ _ZAYA_STEP = "5c0e2e3ba71539f56323de421562ccae59c9377d2e3b1531e6a4a2459053d47d"
     (dict(mesh_shape=(1, 1, 2, 1, 1, 2), batch=6), _DENSE_STEP[(1, 1, 2, 1, 1, 2)]),
     (dict(batch=6, model="olmoe-1b-7b", n_layers=1), _OLMOE_STEP),
     (dict(batch=2, model="zaya1-8b", n_layers=6, vocab_size=32896, experts_held=8), _ZAYA_STEP),
-], ids=["one_chip", "fsdp2_tp2", "olmoe", "zaya"])
+    (dict(batch=2, model="glm-4.7-flash", n_layers=5, vocab_size=19456, experts_held=8),
+     _GLM_LITE_STEP),
+], ids=["one_chip", "fsdp2_tp2", "olmoe", "zaya", "glm_lite"])
 def test_dense_train_step_lowers_to_the_text_it_had_before_the_expert_layer(v5e, kwargs, want):
     """One block serves dense and expert configurations (PR 26); for a
     dense one the lowered step is the text it was, which is what keeps
@@ -359,7 +367,10 @@ def test_dense_train_step_lowers_to_the_text_it_had_before_the_expert_layer(v5e,
     that enter by the old one, OLMoE's too, lower to the text they had.
     And PR 34's third kind of attention, sigmoid scores, shared expert,
     dense layers before the scan and second head leave all four, ZAYA1's
-    with them, the text they had."""
+    with them, the text they had. PR 38 MEANT to alter the three steps
+    that run the full-attention branch (head-major from the projections
+    to `wo`) and replaced their hashes; ZAYA1's and GLM-4.7-Flash's,
+    which bypass that branch, keep the text their parents gave them."""
     import hashlib
     import re
 
