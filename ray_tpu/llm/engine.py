@@ -239,8 +239,9 @@ class EngineConfig:
                 "LLMEngine serves dense llama-family models; MoE serving "
                 "is not implemented (training-side MoE lives in models/moe.py; "
                 "Mixtral, OLMoE, ZAYA1, whose compressed convolutional attention "
-                "has no cache here either, and GLM-4.7-Flash, whose latent attention "
-                "would be served in its absorbed form, are training-only)"
+                "has no cache here either, GLM-4.7-Flash, whose latent attention "
+                "would be served in its absorbed form, and Laguna, whose sliding-window "
+                "layers want a cache sized by layer type, are training-only)"
             )
         # a prefill bucket longer than the context window can never be
         # used; clamping keeps bucket compilation bounded by the model

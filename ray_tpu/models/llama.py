@@ -158,6 +158,19 @@ def _mla(config: LlamaConfig):
     return mla
 
 
+def _laguna(config: LlamaConfig):
+    """models/laguna.py when the configuration's layers differ in kind
+    and shape within one stack (a `LagunaConfig`: sliding-window and
+    full attention at unlike head counts), else None. That module builds
+    the tree and runs the layers (a scan over whole periods of kinds);
+    the head and the loss stay here."""
+    if not hasattr(config, "layer_types"):
+        return None
+    from ray_tpu.models import laguna
+
+    return laguna
+
+
 def _carries_router_state(config: LlamaConfig) -> bool:
     """An MLP router adds the previous layer's state to its own: the
     layer scan then carries (hidden state, router state)."""
@@ -166,6 +179,8 @@ def _carries_router_state(config: LlamaConfig) -> bool:
 
 def logical_axes(config: LlamaConfig) -> Params:
     """Pytree (parallel to params) of logical-axis tuples."""
+    if _laguna(config) is not None:
+        return _laguna(config).logical_axes(config)
     mla = _mla(config)
     if mla is not None and mla.has_more_than_the_stack(config):
         return mla.logical_axes(config)
@@ -203,6 +218,8 @@ def logical_axes(config: LlamaConfig) -> Params:
 
 def init_params(config: LlamaConfig, key: jax.Array) -> Params:
     c = config
+    if _laguna(c) is not None:
+        return _laguna(c).init_params(c, key)
     mla = _mla(c)
     if mla is not None and mla.has_more_than_the_stack(c):
         return mla.init_params(c, key)
@@ -299,7 +316,9 @@ def _block(
     multi-head latent attention, models/mla.py), then the dense SwiGLU or the
     expert layer (models/moe.py, whose statistics come back; None for a
     dense layer) by the configuration's own kind; `dense_ffn`: one of an
-    expert configuration's leading dense layers."""
+    expert configuration's leading dense layers. (A configuration whose
+    layers differ in kind within the stack, models/laguna.py, has a
+    block of its own beside this one.)"""
     c = config
     moe, cca, mla = None if dense_ffn else _moe(c), _cca(c), _mla(c)
     carries_router = _carries_router_state(c)
@@ -448,6 +467,10 @@ def _trunk(
         )
     if positions is None:
         positions = packed_positions(segment_ids, S)
+    if _laguna(c) is not None:
+        # layers of unlike kinds: that module's stack (no block of one kind to hand on)
+        return (*_laguna(c).trunk(params, tokens, c, positions=positions,
+                                  segment_ids=segment_ids), None)
     mla = _mla(c)
     cos = sin = None
     # CCA rotates part of a head and MLA its decoupled part, from the positions themselves

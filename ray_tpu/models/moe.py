@@ -1,4 +1,4 @@
-"""Sparse expert feed-forward layer (Mixtral, OLMoE, ZAYA1, GLM-4.7-Flash) for the one decoder.
+"""Sparse expert feed-forward layer (Mixtral, OLMoE, ZAYA1, GLM-4.7-Flash, Laguna) for the one decoder.
 
 The reference has NO expert parallelism (SURVEY.md §2.4 — absent from
 python/ray/llm); this is a native capability. This module holds what an
@@ -57,6 +57,11 @@ And two that GLM-4.7-Flash (models/mla.py) asks, in the DeepSeek-V3 form
     token runs, added to the routed sum. It is computed whole on every
     chip of a deployment (and counted once where shares are added up),
     under the named scope `shared.ffn`: it is no part of `moe.*`.
+
+Laguna (models/laguna.py) asks the same of a SOFTMAX router: top-10 of
+256 probabilities chosen by probability + the layer's `router_bias`
+(where the layer's parameters carry one), renormalised over the chosen
+and multiplied by `routed_scaling`, beside a shared expert.
 """
 
 from __future__ import annotations
@@ -98,7 +103,7 @@ class MoEConfig(llama.LlamaConfig):
     # "softmax" over all the experts' logits, or "sigmoid" of each by
     # itself, chosen by score + `router_bias` (GLM-4.7-Flash; linear router)
     router_score: str = "softmax"
-    routed_scaling: float = 1.0  # on the routed experts' weights
+    routed_scaling: float = 1.0  # on the routed experts' weights, either score
     shared_d_ff: int = 0         # width of the shared expert (0: none)
     # this chip's share: experts first_expert_held .. + experts_held of
     # n_experts are in the parameters (None: all of them)
@@ -368,14 +373,18 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig,
             else:
                 lse = jax.nn.logsumexp(logits, axis=-1)
                 probs = jnp.exp(logits - lse[:, None])  # [N, E]
-                if c.router_kind == "mlp":
+                if c.router_kind == "mlp" or "router_bias" in lp:
                     # chosen by probability + bias; weighted by the probability alone
+                    # (ZAYA1's MLP router; a linear one whose layer carries a selection
+                    # bias: Laguna, models/laguna.py)
                     _, chosen = jax.lax.top_k(probs + lp["router_bias"].astype(jnp.float32), K)
                     w = jnp.take_along_axis(probs, chosen, axis=-1)
                 else:
                     w, chosen = jax.lax.top_k(probs, K)     # [N, K]
                 if c.norm_topk_prob:
                     w = w / w.sum(-1, keepdims=True)
+                if c.routed_scaling != 1.0:
+                    w = w * c.routed_scaling
             flat = chosen.reshape(N * K)
             counts = jnp.sum(flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :],
                              axis=0, dtype=jnp.int32)

@@ -13,15 +13,22 @@ whose state is carried from layer to layer and top-1 of 16 experts, of
 which a chip may hold a share; GLM-4.7-Flash, `glm-4.7-flash`, with
 multi-head latent attention at heads of 256 (models/mla.py), a leading
 dense layer, sigmoid top-4 of 64 experts chosen with a selection bias,
-a shared expert and a multi-token-prediction block. Every expert
+a shared expert and a multi-token-prediction block; Laguna-S-2.1,
+`laguna-s-2.1`, trained, not served: sliding-window (512) and full
+attention layers of 72 and 48 heads of an explicit 128 in one stack
+(models/laguna.py), a headwise output gate, a rotary by layer type (yarn
+on half a head in the full layers), a leading dense layer, softmax
+top-10 of 256 experts chosen with a selection bias, weights renormalised
+and scaled, and a shared expert. Every expert
 configuration is TRAINING only: the serving engine refuses a MoEConfig,
-ZAYA1 and GLM-4.7-Flash by name).
+ZAYA1, GLM-4.7-Flash and Laguna by name).
 The registry gives users the same two entry points they expect:
 
   * `get_model_config("llama3-8b")` — named presets;
   * `config_from_hf(json.load(open("config.json")))` — map a HF
     transformers config dict onto LlamaConfig/MoEConfig/ZayaConfig/
-    GlmLiteConfig (no downloads; weight conversion is a separate concern).
+    GlmLiteConfig/LagunaConfig (no downloads; weight conversion is a
+    separate concern).
 """
 
 from __future__ import annotations
@@ -29,7 +36,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
-from ray_tpu.models import cca, llama, mla, moe
+from ray_tpu.models import cca, laguna, llama, mla, moe
 
 _REGISTRY: dict[str, Any] = {}
 
@@ -88,6 +95,8 @@ for _name, _cfg in {
     "zaya-tiny": cca.ZAYA_TINY,
     "glm-4.7-flash": mla.GLM_4_7_FLASH,
     "glm-lite-tiny": mla.GLM_LITE_TINY,
+    "laguna-s-2.1": laguna.LAGUNA_S_2_1,
+    "laguna-tiny": laguna.LAGUNA_TINY,
 }.items():
     register_model(_name, _cfg)
 
@@ -179,9 +188,86 @@ def _glm_lite_from_hf(hf: dict, **overrides) -> mla.GlmLiteConfig:
     return dataclasses.replace(mla.GLM_4_7_FLASH, **fields)
 
 
+def _rotary_from_hf(group: dict) -> laguna.Rotary:
+    kind = group.get("rope_type", "default")
+    if kind not in ("default", "yarn"):
+        raise ValueError(f"a laguna config with rope_type {kind!r} is not supported")
+    fields = dict(theta=float(group["rope_theta"]), rope_type=kind,
+                  partial=float(group.get("partial_rotary_factor", 1.0)))
+    if kind == "yarn":
+        fields.update(factor=float(group["factor"]),
+                      original_max=int(group["original_max_position_embeddings"]),
+                      beta_fast=float(group.get("beta_fast", 32)),
+                      beta_slow=float(group.get("beta_slow", 1)),
+                      attention_factor=float(group["attention_factor"]))
+    return laguna.Rotary(**fields)
+
+
+def _laguna_from_hf(hf: dict, **overrides) -> laguna.LagunaConfig:
+    """`model_type` "laguna" (poolside/Laguna-S-2.1): sliding-window and
+    full attention layers (`layer_types`) at their own head counts
+    (`num_attention_heads_per_layer`) and an explicit `head_dim`, a gate a
+    head on the attention's output, a rotary by layer type
+    (`rope_parameters`), dense layers where `mlp_layer_types` says
+    (leading ones only), softmax top-k routed experts beside a shared
+    one. What this decoder does not implement is refused by name; that
+    the stack is periodic (models/laguna.py::plan finds the period, of any
+    length, and a tail) is checked last."""
+    n = hf["num_hidden_layers"]
+    mlp = list(hf.get("mlp_layer_types") or ["dense" if l in hf.get("mlp_only_layers", ())
+                                             else "sparse" for l in range(n)])
+    first_dense = next((l for l, t in enumerate(mlp) if t != "dense"), n)
+    heads = list(hf.get("num_attention_heads_per_layer") or [hf["num_attention_heads"]] * n)
+    types = list(hf.get("layer_types") or ["full_attention"] * n)
+    refused = {
+        f"moe_router_logit_softcapping {hf.get('moe_router_logit_softcapping')}":
+            bool(hf.get("moe_router_logit_softcapping")),
+        "moe_apply_router_weight_on_input": bool(hf.get("moe_apply_router_weight_on_input")),
+        f"gating {hf.get('gating')!r} (per-head is implemented)":
+            hf.get("gating") != "per-head"
+            or any(g != "per_head" for g in hf.get("gating_types", ())),
+        "a dense layer after a sparse one": "dense" in mlp[first_dense:],
+        "layer_types other than full_attention / sliding_attention":
+            any(t not in (laguna.FULL, laguna.SLIDING) for t in types),
+        "layer_types / num_attention_heads_per_layer shorter than num_hidden_layers":
+            len(types) < n or len(heads) < n,
+        "attention_bias": bool(hf.get("attention_bias")),
+        f"decoder_sparse_step {hf.get('decoder_sparse_step')}":
+            hf.get("decoder_sparse_step", 1) != 1,
+        f"hidden_act {hf.get('hidden_act')!r}": hf.get("hidden_act", "silu") != "silu",
+    }
+    if any(refused.values()):
+        raise ValueError("a laguna config with "
+                         + ", ".join(k for k, v in refused.items() if v) + " is not supported")
+    rope = hf["rope_parameters"]
+    fields = dict(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"], n_layers=n,
+        n_heads=hf["num_attention_heads"], n_kv_heads=hf["num_key_value_heads"],
+        d_ff=hf["moe_intermediate_size"], max_seq=hf["max_position_embeddings"],
+        rope_theta=float(rope[laguna.FULL]["rope_theta"]), rms_eps=float(hf["rms_norm_eps"]),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        n_experts=hf["num_experts"], top_k=hf["num_experts_per_tok"],
+        norm_topk_prob=bool(hf["norm_topk_prob"]),
+        routed_scaling=float(hf.get("moe_routed_scaling_factor", 1.0)),
+        shared_d_ff=hf.get("shared_expert_intermediate_size", 0), head_dim=hf["head_dim"],
+        layer_types=tuple(types), heads_per_layer=tuple(heads),
+        sliding_window=hf["sliding_window"],
+        rope_full=_rotary_from_hf(rope[laguna.FULL]),
+        rope_sliding=_rotary_from_hf(rope[laguna.SLIDING]),
+        first_dense_layers=first_dense, dense_d_ff=hf["intermediate_size"],
+    )
+    fields.update(overrides)  # caller wins on collisions
+    config = dataclasses.replace(laguna.LAGUNA_S_2_1, **fields)
+    if laguna.plan(config)["periods"] < 2:
+        # it would run, every layer unrolled in one program: not what the scan is for
+        raise ValueError("a laguna config whose layer_types / num_attention_heads_per_layer "
+                         "never repeat (not periodic) is not supported")
+    return config
+
+
 def config_from_hf(hf: dict, **overrides):
     """Map a HF `config.json` dict to a LlamaConfig/MoEConfig/ZayaConfig/
-    GlmLiteConfig.
+    GlmLiteConfig/LagunaConfig.
 
     Only architecture hyperparameters travel; framework knobs
     (dtype/remat/attention_impl) keep their TPU defaults unless
@@ -193,11 +279,17 @@ def config_from_hf(hf: dict, **overrides):
     `norm_topk_prob` says so. ZAYA1 (`model_type` "zaya"): see
     `_zaya_from_hf`. GLM-4.7-Flash (`model_type` "glm4_moe_lite", whose
     heads are not hidden_size / heads wide): see `_glm_lite_from_hf`.
+    Laguna (`model_type` "laguna": `rope_parameters` by layer type, yarn
+    among them, an explicit `head_dim`, head counts by layer): see
+    `_laguna_from_hf`; for every OTHER family a `rope_scaling` and an
+    explicit `head_dim` that is not hidden_size / heads stay refused.
     """
     if hf.get("model_type") == "zaya":
         return _zaya_from_hf(hf, **overrides)
     if hf.get("model_type") == "glm4_moe_lite":
         return _glm_lite_from_hf(hf, **overrides)
+    if hf.get("model_type") == "laguna":
+        return _laguna_from_hf(hf, **overrides)
     archs = set(hf.get("architectures", ()))
     is_olmoe = "OlmoeForCausalLM" in archs or (not archs and hf.get("model_type") == "olmoe")
     # the num_local_experts heuristic only applies to config dicts with NO

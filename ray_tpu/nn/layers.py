@@ -8,10 +8,13 @@ everything stays jit/scan/shard_map-friendly.
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from ray_tpu.parallel.context import current_mesh
@@ -72,7 +75,7 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array, positions: jax.Arra
     return out.astype(x.dtype)
 
 
-def _swap_halves(x: jax.Array) -> jax.Array:
+def _swap_halves(x: jax.Array, rot: int | None = None) -> jax.Array:
     """[x2, x1] of x = [x1, x2] along the last axis of x [B, H, S, D], as
     a product with the permutation matrix: exact (one term a sum; at the
     highest precision, so for float32 operands too), and on the MXU,
@@ -80,32 +83,41 @@ def _swap_halves(x: jax.Array) -> jax.Array:
     lanes is a pass of its own over half-empty tiles (PERF.md section 6,
     PR 33 and PR 38). The batch is spelled as a BATCH dimension of the
     product, as models/mla.py::_up does: the "dots" remat policy saves
-    every product without one, and this one is cheaper made again."""
+    every product without one, and this one is cheaper made again.
+    `rot` < D (a rotary on part of a head): the halves of the FIRST
+    `rot` channels change places and the rest stay: the same product
+    with a block of the permutation, still nothing cut inside the lanes."""
     D = x.shape[-1]
-    swap = jnp.roll(jnp.eye(D, dtype=x.dtype), D // 2, axis=1)
+    if rot is None or rot == D:
+        swap = jnp.roll(jnp.eye(D, dtype=x.dtype), D // 2, axis=1)
+    else:
+        swap = jnp.eye(D, dtype=x.dtype).at[:rot, :rot].set(
+            jnp.roll(jnp.eye(rot, dtype=x.dtype), rot // 2, axis=1))
     return jax.lax.dot_general(
         x, jnp.broadcast_to(swap, (x.shape[0], D, D)), (((3,), (1,)), ((0,), (0,))),
         precision=jax.lax.Precision.HIGHEST, preferred_element_type=x.dtype)
 
 
-@jax.custom_vjp
-def _rotate(x: jax.Array, cc: jax.Array, ss: jax.Array) -> jax.Array:
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rotate(x: jax.Array, cc: jax.Array, ss: jax.Array, rot: int | None = None) -> jax.Array:
     """x * [c, c] + [x2, x1] * [-s, s] in float32: `apply_rope`'s
-    x1 c - x2 s and x2 c + x1 s, product for product."""
-    return (x.astype(jnp.float32) * cc + _swap_halves(x).astype(jnp.float32) * ss).astype(x.dtype)
+    x1 c - x2 s and x2 c + x1 s, product for product. With `rot` < D the
+    tables carry 1 and 0 over the channels that pass through."""
+    return (x.astype(jnp.float32) * cc
+            + _swap_halves(x, rot).astype(jnp.float32) * ss).astype(x.dtype)
 
 
-def _rotate_fwd(x, cc, ss):
-    return _rotate(x, cc, ss), (cc, ss)
+def _rotate_fwd(x, cc, ss, rot):
+    return _rotate(x, cc, ss, rot), (cc, ss)
 
 
-def _rotate_bwd(tables, g):
+def _rotate_bwd(rot, tables, g):
     # the rotation by the opposite angle, the swap made BEFORE the products as in
     # the forward: what differentiating `apply_rope` gives, g1 c + g2 s and g2 c -
     # g1 s rounded once (differentiating `_rotate` would round the swapped half to
     # g's dtype on its way through the product)
     cc, ss = tables
-    return _rotate(g, cc, -ss), jnp.zeros_like(cc), jnp.zeros_like(ss)
+    return _rotate(g, cc, -ss, rot), jnp.zeros_like(cc), jnp.zeros_like(ss)
 
 
 _rotate.defvjp(_rotate_fwd, _rotate_bwd)
@@ -125,6 +137,54 @@ def apply_rope_head_major(x: jax.Array, cos: jax.Array, sin: jax.Array,
     cc = jnp.concatenate([c, c], axis=-1)[:, None]  # [1 or B, 1, S, D]
     ss = jnp.concatenate([-s, s], axis=-1)[:, None]
     return _rotate(x, cc, ss)
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0) -> np.ndarray:
+    """YaRN's blended rotary frequencies [dim / 2] (arXiv:2309.00071, as
+    HF's `_compute_yarn_parameters` computes them, truncating): channel
+    pair i turns at 1 / theta^(2i / dim) where it makes more than
+    `beta_fast` turns over `original_max` positions (extrapolated, kept),
+    at that / `factor` where it makes fewer than `beta_slow`
+    (interpolated), and on a linear ramp between the two, whose ends are
+    the floor and the ceiling of the pairs that make exactly those
+    turns, clamped to [0, dim - 1]. The attention factor that goes with
+    it multiplies the tables (`rope_tables`), not the frequencies."""
+    def pair_of(turns):  # the (fractional) pair that makes `turns` turns over the original context
+        return dim * math.log(original_max / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low, high = max(math.floor(pair_of(beta_fast)), 0), min(math.ceil(pair_of(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extrapolated = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low) / (high - low), 0.0, 1.0)
+    return (extrapolated / factor * ramp + extrapolated * (1.0 - ramp)).astype(np.float32)
+
+
+def rope_tables(positions: jax.Array, inv_freq, scale: float = 1.0) -> tuple[jax.Array, jax.Array]:
+    """cos and sin of positions x inv_freq, times `scale` (YaRN's
+    attention factor), float32 [1 or B, S, rot / 2]: made from the
+    step's own positions ([S] or [B, S]), so no table of `max_seq` rows
+    (a million-position model's would be 0.5 GiB) is ever built."""
+    ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq, jnp.float32)
+    if ang.ndim == 2:
+        ang = ang[None]
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
+
+
+def rotate_head_major(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """The rotary of `rope_tables`' cos and sin [1 or B, S, rot / 2] on the
+    FIRST rot channels of every head of x [B, H, S, D], half-split
+    pairing within them (channel i with i + rot / 2); the other D - rot
+    pass through. `apply_rope_head_major`'s one fused pass: the tables
+    are padded with cos 1 and sin 0 over the channels that pass and the
+    swap is a block of the permutation (`_swap_halves`), so a rotary on
+    half a head cuts nothing at lane 64."""
+    D, rot = x.shape[-1], 2 * cos.shape[-1]
+    keep = cos.shape[:-1] + (D - rot,)
+    cc = jnp.concatenate([cos, cos, jnp.ones(keep, cos.dtype)], axis=-1)[:, None]
+    ss = jnp.concatenate([-sin, sin, jnp.zeros(keep, sin.dtype)], axis=-1)[:, None]
+    return _rotate(x, cc, ss, rot)
 
 
 def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:

@@ -36,12 +36,17 @@ def xla_attention(
     segment_ids: Optional[jax.Array] = None,  # [B, S] int, same for q/k when Sq==Sk
     q_offset: int | jax.Array = 0,
     softmax_scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
-    """Reference GQA attention. fp32 softmax, bf16 matmuls."""
+    """Reference GQA attention. fp32 softmax, bf16 matmuls. `window`: a
+    row sees the `window` keys up to and including its own (sliding-window
+    attention; a causal mask's)."""
     B, Sq, H, D = q.shape
     _, Sk, K, _ = k.shape
     group = _repeat_kv_heads(q, k)
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
+    if window is not None and not causal:
+        raise ValueError("a sliding window is a causal mask's")
 
     qg = q.reshape(B, Sq, K, group, D)
     scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k, preferred_element_type=jnp.float32)
@@ -52,6 +57,8 @@ def xla_attention(
         q_pos = jnp.arange(Sq)[:, None] + q_offset
         k_pos = jnp.arange(Sk)[None, :]
         mask = q_pos >= k_pos  # [Sq, Sk]
+        if window is not None:
+            mask = mask & (q_pos - k_pos < window)
         mask = mask[None, None, None, :, :]
     if segment_ids is not None:
         seg = segment_ids[:, :, None] == segment_ids[:, None, :]  # [B, Sq, Sk]
@@ -171,13 +178,22 @@ def attention_head_major(
     causal: bool = True,
     segment_ids: Optional[jax.Array] = None,
     impl: str = "xla",
+    window: Optional[int] = None,
 ) -> jax.Array:
     """`attention` for a caller whose heads are a major dimension, the
     tile (S, D): -> [B, H, S, D]. That is the flash kernels' own layout,
     so `impl="flash"` reaches them with no transpose on the way in or
-    out; every other `impl` is `attention` between its transposes."""
+    out; every other `impl` is `attention` between its transposes.
+    `window` (None: every key before the row): sliding-window attention,
+    which the flash kernels and the XLA composite implement."""
+    if window is not None and impl not in ("flash", "xla"):
+        raise ValueError(f"attention impl {impl!r} has no sliding window")
     if impl == "flash":
-        return _flash_over_mesh(q, k, v, segment_ids, head_axis=1, causal=causal)
+        return _flash_over_mesh(q, k, v, segment_ids, head_axis=1, causal=causal, window=window)
+    if window is not None:
+        o = xla_attention(*(jnp.swapaxes(x, 1, 2) for x in (q, k, v)), causal=causal,
+                          segment_ids=segment_ids, window=window)
+        return jnp.swapaxes(o, 1, 2)
     o = attention(*(jnp.swapaxes(x, 1, 2) for x in (q, k, v)), causal=causal,
                   segment_ids=segment_ids, impl=impl)
     return jnp.swapaxes(o, 1, 2)

@@ -12,9 +12,16 @@ flash/ragged lineage to this framework. Design:
  * forward: online-softmax recurrence (running max `m`, normalizer
    `l`, fp32 accumulator) carried in scratch across the kv-block grid
    dim; the output block is revisited and written once per q-block;
- * causal: a kv block is walked in sub-tiles, and those wholly above
-   the diagonal are skipped (`_tiles_to_run`); the block fetch still
-   happens — compute, not bandwidth, dominates;
+ * causal: a kv block is walked in sub-tiles of 512 keys, and of them a
+   RANGE runs (`_tiles_to_run`: a first sub-tile and a count): those
+   wholly above the diagonal are skipped, so without a window the range
+   is a prefix; under a sliding `window` (static; None = every key
+   before the row) those wholly below the window are skipped too, and
+   the mask cuts both edges. With the whole kv sequence in one block
+   the block fetch still happens (compute, not bandwidth, dominates);
+   with several kv blocks (sequences over 4096) a block wholly outside
+   the window is not fetched either: the index map names the block
+   already resident (`_kv_block_of`, `_q_block_of`);
  * GQA folds naturally: kv BlockSpec index maps divide the q-head
    index by the group size;
  * backward: dQ accumulates over kv blocks; dK/dV accumulate over
@@ -82,6 +89,23 @@ from jax.experimental.pallas import tpu as pltpu
 #    from the dk/dv kernel) is why the default kv block is the sequence;
 #    VMEM bounds it: dk/dv fp32 scratch is block_k*D*8 bytes, so block_k
 #    caps at 4096.
+#
+# The window (PR 39), measured on a v5e in the traced step of
+# `laguna-train` (heads of 128, 8 kv heads, 1 x 4096, block_q 512 against
+# sub-tiles of 512 keys): a sliding layer's kernels at 72 heads (groups of
+# 9, window 512) read 1.17 ms forward and 2.30 fused backward where a full
+# layer's at 48 heads (groups of 6) read 1.51 and 3.50, so a head costs
+# 16.3 / 31.9 us under the window against 31.6 / 72.9 without: 0.52 and
+# 0.44 of the causal walk where the sub-tile visits are 15 / 36 = 0.42
+# (each q block's trip count is 1 or 2 where the prefix had 1 to 8: the
+# loop's fixed part shows in the forward). Against the pairs INSIDE the
+# window the kernels are at 37% of their roofline and cannot pass 50: 512
+# rows under a window of 512 span 1023 keys = two sub-tiles where one
+# sub-tile's worth of pairs is required, whatever the sub-tile's size.
+# Only fewer ROWS a block would raise the ceiling (256 x 256: 768 keys
+# walked for 512 required, 67%), and by the sweep above 256 x 256 costs
+# 1.50 / 1.24 times 512 x 512 a visit: a loss forward, 7% backward. Not
+# tried on the chip.
 DEFAULT_BLOCK_Q = 512
 MAX_BLOCK_K = 4096  # fused whole-sequence kv block, VMEM-capped
 NEG_INF = -1e30  # true -inf breeds NaN via (-inf) - (-inf)
@@ -136,32 +160,75 @@ def _sub_k(Bk: int) -> int:
     return _SUB_K if Bk % _SUB_K == 0 else Bk
 
 
-def _tiles_to_run(i, j, Bq, Bk, Tk, *, causal, q_offset):
-    """How many of the Bk // Tk sub-tiles of kv block `j` q block `i`
-    meets: those whose first key is not after the block's last row. The
-    rest lie wholly above the diagonal and are skipped; the ones that run
-    are a prefix. A Python int without a diagonal."""
+def _tiles_to_run(i, j, Bq, Bk, Tk, *, causal, q_offset, window=None):
+    """Which of the Bk // Tk sub-tiles of kv block `j` q block `i` meets:
+    (first, how many). Those whose first key is after the block's last
+    row lie wholly above the diagonal and are skipped: without a window
+    the ones that run are a prefix (first is the Python int 0; so is the
+    count without a diagonal). Under a sliding `window` (key k visible to
+    row r when r - window < k <= r) those whose last key is at or before
+    the FIRST row's r - window lie wholly below the window and are
+    skipped too: a range. At 4096 keys, rows and sub-tiles of 512 and a
+    window of 512 that is the diagonal's sub-tile and the one before it,
+    15 visits of the causal walk's 36."""
     if not causal:
-        return Bk // Tk
-    return jnp.clip(q_offset + (i + 1) * Bq - j * Bk + Tk - 1, 0, Bk) // Tk
+        return 0, Bk // Tk
+    end = jnp.clip(q_offset + (i + 1) * Bq - j * Bk + Tk - 1, 0, Bk) // Tk
+    if window is None:
+        return 0, end
+    first = jnp.clip(q_offset + i * Bq - window + 1 - j * Bk, 0, Bk) // Tk
+    return first, jnp.maximum(end - first, 0)
 
 
-def _walk_tiles(n_run, tile):
-    """`tile(t)` over sub-tiles [0, n_run). Without a diagonal the count
-    is a Python int and the walk one straight-line region. Otherwise the
-    program id gives it, and the sub-tiles go two a trip, so that one's
-    matmuls can be issued beside the other's softmax (see the header)."""
+def _walk_tiles(run, tile):
+    """`tile(t)` over the sub-tiles `run` = (first, how many) of
+    `_tiles_to_run`. Without a diagonal the count is a Python int and the
+    walk one straight-line region. Otherwise the program id gives it, and
+    the sub-tiles go two a trip, so that one's matmuls can be issued
+    beside the other's softmax (see the header)."""
+    first, n_run = run
     if isinstance(n_run, int):
         for t in range(n_run):
             tile(t)
         return
+    # a prefix (first the int 0) keeps the parent's indices as they were
+    at = (lambda t: t) if isinstance(first, int) else (lambda t: first + t)
 
     def pair(t, _):
-        tile(2 * t)
-        tile(2 * t + 1)
+        tile(at(2 * t))
+        tile(at(2 * t + 1))
 
     jax.lax.fori_loop(0, n_run // 2, pair, None)
-    pl.when(n_run % 2 == 1)(lambda: tile(n_run - 1))
+    pl.when(n_run % 2 == 1)(lambda: tile(at(n_run - 1)))
+
+
+def _block_in_window(i, j, Bq, Bk, *, q_offset, window):
+    """Whether kv block `j` holds a key that some row of q block `i` sees
+    under the diagonal and the window (traced)."""
+    below = q_offset + (i + 1) * Bq > j * Bk
+    if window is None:
+        return below
+    return below & ((j + 1) * Bk > q_offset + i * Bq - window + 1)
+
+
+def _kv_block_of(i, j, Bq, Bk, n, *, q_offset, window):
+    """The kv block q block `i` fetches at grid step `j` under a window
+    (several kv blocks): `j` clamped to the blocks that hold a visible
+    key, so that a step wholly outside the window names the block already
+    resident and fetches nothing (it computes nothing either: the kernel
+    takes its range from the step's own `j`)."""
+    lo = jnp.maximum(q_offset + i * Bq - window + 1, 0) // Bk
+    hi = (q_offset + (i + 1) * Bq - 1) // Bk
+    return jnp.minimum(jnp.clip(j, lo, jnp.maximum(hi, lo)), n - 1)
+
+
+def _q_block_of(i, j, Bq, Bk, n, *, q_offset, window):
+    """`_kv_block_of` seen from the kv block: the q block the dk/dv kernel
+    fetches at grid step `i` of kv block `j`, clamped to the q blocks with
+    a row that sees one of its keys."""
+    lo = jnp.maximum(j * Bk - q_offset, 0) // Bq
+    hi = jnp.maximum((j + 1) * Bk + window - 2 - q_offset, 0) // Bq
+    return jnp.minimum(jnp.clip(i, lo, jnp.maximum(hi, lo)), n - 1)
 
 
 def _tile_start(t, Tk):
@@ -171,7 +238,7 @@ def _tile_start(t, Tk):
 
 
 def _block_mask(i, k_base, F, Bq, Tk, *, causal, q_offset, sq_valid, sk_valid,
-                kpad, qpad, qseg_ref, kseg):
+                kpad, qpad, qseg_ref, kseg, window=None):
     """[F*Bq, Tk] validity mask for q-block i vs kv positions starting at
     k_base, or None.
 
@@ -195,6 +262,8 @@ def _block_mask(i, k_base, F, Bq, Tk, *, causal, q_offset, sq_valid, sk_valid,
         mask = qm if mask is None else mask & qm
     if causal:
         cm = q_pos >= k_pos
+        if window is not None:  # both edges: the diagonal and the window's far side
+            cm = cm & (q_pos - k_pos < window)
         mask = cm if mask is None else mask & cm
     if kseg is not None:
         sm = qseg_ref[0] == kseg  # [Bq,1] == [1,Tk]
@@ -235,6 +304,7 @@ def _fwd_kernel(
     sk_valid: int,
     has_segments: bool,
     kpad: bool,
+    window: Optional[int] = None,
 ):
     i = pl.program_id(2)
     j = pl.program_id(3)
@@ -274,7 +344,7 @@ def _fwd_kernel(
         mask = _block_mask(
             i, j * Bk + lo, F, Bq, Tk, causal=causal, q_offset=q_offset, sq_valid=0,
             sk_valid=sk_valid, kpad=kpad, qpad=False, qseg_ref=qseg_ref,
-            kseg=kseg_ref[0, :, pl.ds(lo, Tk)] if has_segments else None)
+            kseg=kseg_ref[0, :, pl.ds(lo, Tk)] if has_segments else None, window=window)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
 
@@ -301,7 +371,8 @@ def _fwd_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    _walk_tiles(_tiles_to_run(i, j, Bq, Bk, Tk, causal=causal, q_offset=q_offset), tile)
+    _walk_tiles(_tiles_to_run(i, j, Bq, Bk, Tk, causal=causal, q_offset=q_offset,
+                              window=window), tile)
 
     @pl.when(j == nk - 1)
     def _():
@@ -331,6 +402,7 @@ def _dq_kernel(
     sk_valid: int,
     has_segments: bool,
     kpad: bool,
+    window: Optional[int] = None,
 ):
     i = pl.program_id(2)
     j = pl.program_id(3)
@@ -344,7 +416,9 @@ def _dq_kernel(
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
     # the kv block is ONE sub-tile here: skipped where wholly above the diagonal
-    @pl.when(q_offset + (i + 1) * Bq > j * Bk if causal else True)
+    # (or wholly below the window)
+    @pl.when(_block_in_window(i, j, Bq, Bk, q_offset=q_offset, window=window)
+             if causal else True)
     def _():
         q = q_ref[0].reshape(rows, D)
         do = do_ref[0].reshape(rows, D)
@@ -364,7 +438,7 @@ def _dq_kernel(
         mask = _block_mask(
             i, j * Bk, F, Bq, Bk, causal=causal, q_offset=q_offset, sq_valid=0,
             sk_valid=sk_valid, kpad=kpad, qpad=False, qseg_ref=qseg_ref,
-            kseg=kseg_ref[0] if has_segments else None)
+            kseg=kseg_ref[0] if has_segments else None, window=window)
         if mask is not None:
             p = jnp.where(mask, p, 0.0)  # [rows, Bk]
         dp = jax.lax.dot_general(
@@ -409,6 +483,7 @@ def _dkv_kernel(
     has_segments: bool,
     kpad: bool,
     qpad: bool,
+    window: Optional[int] = None,
     fused_dq: bool = False,
     dq_ref=None,  # fused mode only: [1, F, Bq, D], written per (h, i)
     dq_scr=None,  # fused mode only: [F*Bq, D] fp32 (sub-tile accumulator)
@@ -458,7 +533,7 @@ def _dkv_kernel(
         mask = _block_mask(
             i, jk * Bk + lo, F, Bq, Tk, causal=causal, q_offset=q_offset, sq_valid=sq_valid,
             sk_valid=sk_valid, kpad=kpad, qpad=qpad, qseg_ref=qseg_ref,
-            kseg=kseg_ref[0, :, pl.ds(lo, Tk)] if has_segments else None)
+            kseg=kseg_ref[0, :, pl.ds(lo, Tk)] if has_segments else None, window=window)
         if mask is not None:
             p = jnp.where(mask, p, 0.0)
         dv_scr[pl.ds(lo, Tk)] += jax.lax.dot_general(
@@ -482,7 +557,8 @@ def _dkv_kernel(
                 preferred_element_type=jnp.float32,
             )
 
-    _walk_tiles(_tiles_to_run(i, jk, Bq, Bk, Tk, causal=causal, q_offset=q_offset), tile)
+    _walk_tiles(_tiles_to_run(i, jk, Bq, Bk, Tk, causal=causal, q_offset=q_offset,
+                              window=window), tile)
 
     if fused_dq:
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype).reshape(F, Bq, D)
@@ -498,8 +574,18 @@ def _dkv_kernel(
 # ---------------------------------------------------------------------------
 
 
+def _kv_fetch(window, nk, block_q, block_k, q_offset):
+    """(i, j) -> the kv block a (q block, grid step) fetches: step `j`'s
+    own, or under a window over several kv blocks the nearest one that
+    holds a visible key (`_kv_block_of`)."""
+    if window is None or nk == 1:
+        return lambda i, j: j
+    return functools.partial(_kv_block_of, Bq=block_q, Bk=block_k, n=nk, q_offset=q_offset,
+                             window=window)
+
+
 def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
-              sk_valid, interpret, has_segments, fold):
+              sk_valid, interpret, has_segments, fold, window=None):
     B, H, Sq_pad, D = q.shape
     _, KVH, Sk_pad, _ = k.shape
     G = H // KVH
@@ -510,17 +596,18 @@ def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
         q_offset=q_offset, sk_valid=sk_valid,
-        has_segments=has_segments, kpad=sk_valid != Sk_pad,
+        has_segments=has_segments, kpad=sk_valid != Sk_pad, window=window,
     )
+    kv = _kv_fetch(window, nk, block_q, block_k, q_offset)
     return pl.pallas_call(
         kernel,
         grid=(B, HG, nq, nk),
         in_specs=[
             pl.BlockSpec((1, F, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h * F // G, j, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h * F // G, j, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h * F // G, kv(i, j), 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h * F // G, kv(i, j), 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, h, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda b, h, i, j: (b, 0, j)),
+            pl.BlockSpec((1, 1, block_k), lambda b, h, i, j: (b, 0, kv(i, j))),
         ],
         out_specs=[
             pl.BlockSpec((1, F, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
@@ -573,7 +660,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, do_ref,
 
 def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
               block_q, block_k, sq_valid, sk_valid, interpret, has_segments,
-              fold, dlse=None):
+              fold, dlse=None, window=None):
     B, H, Sq_pad, D = q.shape
     _, KVH, Sk_pad, _ = k.shape
     G = H // KVH
@@ -597,6 +684,7 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
                 _bwd_fused_kernel, scale=scale, causal=causal,
                 q_offset=q_offset, sq_valid=sq_valid, sk_valid=sk_valid,
                 group=G // F, has_segments=has_segments, kpad=kpad, qpad=qpad,
+                window=window,
             ),
             grid=(B, 1, HG, nq),  # q-blocks fastest, then groups per kv head
             in_specs=[
@@ -629,19 +717,20 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
         )(q, k, v, qseg, kseg, do, lse, delta)
         return dq, dk, dv
 
+    kv = _kv_fetch(window, nk, block_q, block_k, q_offset)
     dq = pl.pallas_call(
         functools.partial(
             _dq_kernel, scale=scale, causal=causal,
             q_offset=q_offset, sk_valid=sk_valid,
-            has_segments=has_segments, kpad=kpad,
+            has_segments=has_segments, kpad=kpad, window=window,
         ),
         grid=(B, HG, nq, nk),
         in_specs=[
             pl.BlockSpec((1, F, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h * F // G, j, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h * F // G, j, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h * F // G, kv(i, j), 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h * F // G, kv(i, j), 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, h, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda b, h, i, j: (b, 0, j)),
+            pl.BlockSpec((1, 1, block_k), lambda b, h, i, j: (b, 0, kv(i, j))),
             pl.BlockSpec((1, F, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, F, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, F, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
@@ -654,22 +743,27 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
         interpret=interpret,
     )(q, k, v, qseg, kseg, do, lse, delta)
 
+    # the q side of the dk/dv kernel under a window: a step whose rows see none of
+    # the kv block's keys names the q block already resident (`_q_block_of`)
+    qb = (lambda i, j: i) if window is None else functools.partial(
+        _q_block_of, Bq=block_q, Bk=block_k, n=nq, q_offset=q_offset, window=window)
     dk, dv = pl.pallas_call(
         functools.partial(
             _dkv_kernel, scale=scale, causal=causal,
             q_offset=q_offset, sq_valid=sq_valid, sk_valid=sk_valid,
             group=G // F, has_segments=has_segments, kpad=kpad, qpad=qpad,
+            window=window,
         ),
         grid=(B, nk, HG, nq),  # q-blocks fastest, then groups per kv head
         in_specs=[
-            pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, qb(i, j), 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, j, h, i: (b, h * F // G, j, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, j, h, i: (b, h * F // G, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, j, h, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, j, h, i: (b, qb(i, j), 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, j, h, i: (b, 0, j)),
-            pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, F, block_q, 1), lambda b, j, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, F, block_q, 1), lambda b, j, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, qb(i, j), 0)),
+            pl.BlockSpec((1, F, block_q, 1), lambda b, j, h, i: (b, h, qb(i, j), 0)),
+            pl.BlockSpec((1, F, block_q, 1), lambda b, j, h, i: (b, h, qb(i, j), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, D), lambda b, j, h, i: (b, h * F // G, j, 0)),
@@ -696,24 +790,24 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
 # ONE custom-vjp pair serves both public forms: flash_attention with
 # return_lse=False simply drops the lse output (its cotangent arrives
 # as zeros and `delta - 0` is a no-op in the backward).
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 8, 9, 10))
 def _flash_lse(scale, causal, q_offset, block_q, block_k, sq_valid, sk_valid,
-               interpret, has_segments, fold, q, k, v, qseg, kseg):
+               interpret, has_segments, fold, window, q, k, v, qseg, kseg):
     """(o, lse) with a DIFFERENTIABLE lse — ring attention merges
     per-block results through lse, so its cotangent must reach ds."""
     (o, lse), _ = _flash_lse_fwd(
         scale, causal, q_offset, block_q, block_k, sq_valid, sk_valid,
-        interpret, has_segments, fold, q, k, v, qseg, kseg,
+        interpret, has_segments, fold, window, q, k, v, qseg, kseg,
     )
     return o, lse
 
 
 def _flash_lse_fwd(scale, causal, q_offset, block_q, block_k, sq_valid,
-                   sk_valid, interpret, has_segments, fold, q, k, v, qseg,
+                   sk_valid, interpret, has_segments, fold, window, q, k, v, qseg,
                    kseg):
     o, lse = _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset,
                        block_q, block_k, sk_valid, interpret, has_segments,
-                       fold)
+                       fold, window)
     # named residuals: under jax.checkpoint, the backward re-runs this
     # whole kernel just to rebuild (o, lse) unless the remat policy can
     # SAVE them — the "dots" policy recognizes dot_general outputs, not a
@@ -724,12 +818,12 @@ def _flash_lse_fwd(scale, causal, q_offset, block_q, block_k, sq_valid,
 
 
 def _flash_lse_bwd(scale, causal, q_offset, block_q, block_k, sq_valid,
-                   sk_valid, interpret, has_segments, fold, residuals, cts):
+                   sk_valid, interpret, has_segments, fold, window, residuals, cts):
     do, dlse = cts
     q, k, v, qseg, kseg, o, lse = residuals
     dq, dk, dv = _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal,
                            q_offset, block_q, block_k, sq_valid, sk_valid,
-                           interpret, has_segments, fold, dlse=dlse)
+                           interpret, has_segments, fold, dlse=dlse, window=window)
     zero_seg = np.zeros(qseg.shape, dtype=jax.dtypes.float0)
     zero_kseg = np.zeros(kseg.shape, dtype=jax.dtypes.float0)
     return dq, dk, dv, zero_seg, zero_kseg
@@ -785,9 +879,12 @@ def _flash_head_major(
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     fold_heads: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> tuple[jax.Array, jax.Array]:
     """The kernels' own layout, which both public forms come down to:
     -> (o [B, H, Sq, D], lse [B, H, Sq_pad, 1])."""
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"a sliding window ({window}) is a causal mask's: window >= 1, causal")
     B, H, Sq, _ = qt.shape
     _, KVH, Sk, _ = kt.shape
     if interpret is None:
@@ -828,7 +925,7 @@ def _flash_head_major(
     fold = _fold_factor(H // KVH, bq, bk, fold_heads)
     # the scale is in q already: the kernels' own is 1
     statics = (1.0, causal, q_offset, bq, bk, Sq, Sk, interpret,
-               has_segments, fold)
+               has_segments, fold, window)
     o, lse = _flash_lse(*statics, qt, kt, vt, qseg, kseg)
     return o[:, :, :Sq, :], lse
 
@@ -848,6 +945,7 @@ def flash_attention(
     interpret: Optional[bool] = None,
     fold_heads: Optional[int] = None,  # None = auto (largest safe divisor of G)
     return_lse: bool = False,
+    window: Optional[int] = None,  # keys a row sees, itself among them (None: all before it)
 ) -> "jax.Array | tuple[jax.Array, jax.Array]":
     """Drop-in for ops.attention.xla_attention with O(S) memory, for a
     caller that holds [B, S, H, D] (ring attention, tests): q, k and v
@@ -870,7 +968,7 @@ def flash_attention(
     o, lse = _flash_head_major(
         qt, kt, vt, causal=causal, segment_ids=segment_ids,
         kv_segment_ids=kv_segment_ids, q_offset=q_offset, block_q=block_q,
-        block_k=block_k, interpret=interpret, fold_heads=fold_heads)
+        block_k=block_k, interpret=interpret, fold_heads=fold_heads, window=window)
     o = jnp.transpose(o, (0, 2, 1, 3))
     if return_lse:
         lse = jnp.transpose(lse[:, :, :Sq, 0], (0, 2, 1))  # [B, Sq, H]
@@ -885,6 +983,7 @@ def flash_attention_head_major(
     *,
     causal: bool = True,
     segment_ids: Optional[jax.Array] = None,  # [B, S] (requires Sq == Sk)
+    window: Optional[int] = None,
 ) -> jax.Array:
     """`flash_attention` for a caller that holds the heads as a major
     dimension already (the attention sublayers of models/llama.py,
@@ -895,5 +994,5 @@ def flash_attention_head_major(
     per-shard calls, the kernel's tests), no model's."""
     _check_shapes(q.shape[1], k.shape[1], q.shape[2], k.shape[2], 0, segment_ids, None)
     o, _ = _flash_head_major(_fold_scale(q, None), k, v, causal=causal,
-                             segment_ids=segment_ids)
+                             segment_ids=segment_ids, window=window)
     return o

@@ -304,9 +304,9 @@ def test_head_major_entry_is_the_other_entry_without_its_transposes(cpu_devices,
         np.asarray(want), atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("causal,window", [(True, None), (False, None), (True, 512), (True, 300)])
 @pytest.mark.parametrize("Bq,Bk,Tk,q_offset,sq_valid,sk_valid", [
-    (512, 4096, 512, 0, 4096, 4096),   # the cells' shape: 36 of 64 sub-tiles run
+    (512, 4096, 512, 0, 4096, 4096),   # the cells' shape: 36 of 64 sub-tiles run, 15 under a window of 512
     (512, 1536, 512, 0, 1536, 1536),
     (256, 1024, 512, 0, 1024, 1024),   # two q blocks a sub-tile
     (512, 1024, 512, 0, 1300, 1300),   # two kv blocks, a padded edge, a sub-tile of padding
@@ -315,27 +315,32 @@ def test_head_major_entry_is_the_other_entry_without_its_transposes(cpu_devices,
     (32, 32, 32, 0, 100, 100),          # one sub-tile a block
     (16, 16, 16, 48, 16, 64),
 ])
-def test_sub_tiles_skipped_are_those_the_triangle_masks_whole(causal, Bq, Bk, Tk, q_offset,
+def test_sub_tiles_skipped_are_those_the_triangle_masks_whole(causal, window, Bq, Bk, Tk, q_offset,
                                                               sq_valid, sk_valid):
     """`_tiles_to_run` (one rule for the forward and the fused backward)
     against the dense triangle: a sub-tile is skipped iff every entry of
-    it lies above the diagonal, and the ones that run are a prefix of the
-    kv block's sub-tiles."""
+    it lies above the diagonal (or, under a window, below the window), and
+    the ones that run are a range of the kv block's sub-tiles: a prefix
+    without a window."""
     from ray_tpu.ops.flash import _tiles_to_run
 
     nq, nk, nt = -(-sq_valid // Bq), -(-sk_valid // Bk), Bk // Tk
     i, j = np.arange(nq)[:, None], np.arange(nk)[None, :]
-    n_run = np.broadcast_to(np.asarray(
-        _tiles_to_run(i, j, Bq, Bk, Tk, causal=causal, q_offset=q_offset)), (nq, nk))
+    first, n_run = (np.broadcast_to(np.asarray(x), (nq, nk)) for x in
+                    _tiles_to_run(i, j, Bq, Bk, Tk, causal=causal, q_offset=q_offset, window=window))
+    if window is None:
+        assert not first.any()
     rows, cols = np.arange(nq * Bq)[:, None], np.arange(nk * Bk)[None, :]
     below = (rows + q_offset >= cols) if causal else np.ones((nq * Bq, nk * Bk), bool)
+    if window is not None:
+        below &= rows + q_offset - cols < window
     for a in range(nq):
         for b in range(nk):
             for t in range(nt):
                 tile = (slice(a * Bq, (a + 1) * Bq), slice(b * Bk + t * Tk, b * Bk + (t + 1) * Tk))
-                assert (t < n_run[a, b]) == below[tile].any(), (a, b, t)
+                assert (first[a, b] <= t < first[a, b] + n_run[a, b]) == below[tile].any(), (a, b, t)
     if (Bq, Bk, sk_valid) == (512, 4096, 4096):
-        assert int(n_run.sum()) == (36 if causal else 64)
+        assert int(n_run.sum()) == (64 if not causal else 36 if window is None else 15)
 
 
 def test_rows_with_nothing_to_attend_weigh_nothing():

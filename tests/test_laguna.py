@@ -1,0 +1,457 @@
+"""Laguna through the one decoder (PR 39), at a small size on the CPU,
+seeded weights, against the plain reference
+(chipbench/reference/laguna_decoder.py, imported): the attention
+sublayer of both kinds (the window, YaRN on part of a head, the gate),
+softmax top-k routing with its bias, renormalisation and scaling, the
+whole train path over a dense layer and two periods in loss and
+gradients, the shares that add up, `config_from_hf` on the catalog's
+config. (The flash kernels under a window: tests/test_flash_window.py.)"""
+
+import dataclasses
+import json
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench.reference import laguna_decoder
+from ray_tpu.models import laguna, llama, moe
+from ray_tpu.models.registry import config_from_hf, get_model_config
+from ray_tpu.nn import layers as nn_layers
+from ray_tpu.nn.layers import rms_norm
+
+FP32 = dataclasses.replace(laguna.LAGUNA_TINY, dtype=jnp.float32)
+B, S = 2, 40   # the window (24) shorter than the sequence
+CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+
+
+def rope_group(r: laguna.Rotary) -> dict:
+    return {"rope_theta": r.theta, "rope_type": r.rope_type, "factor": r.factor,
+            "original_max_position_embeddings": r.original_max, "beta_fast": r.beta_fast,
+            "beta_slow": r.beta_slow, "attention_factor": r.attention_factor,
+            "partial_rotary_factor": r.partial}
+
+
+def shape_of(cfg) -> dict:
+    """A LagunaConfig as the configuration file's dict (HF key names)."""
+    n = cfg.n_layers
+    return {
+        "hidden_size": cfg.d_model, "head_dim": cfg.head_dim,
+        "num_key_value_heads": cfg.n_kv_heads, "num_hidden_layers": n,
+        "num_attention_heads_per_layer": list(cfg.heads_per_layer[:n]),
+        "layer_types": list(cfg.layer_types[:n]), "sliding_window": cfg.sliding_window,
+        "mlp_layer_types": ["dense"] * cfg.first_dense_layers + ["sparse"] * cfg.n_expert_layers,
+        "rope_parameters": {laguna.FULL: rope_group(cfg.rope_full),
+                            laguna.SLIDING: rope_group(cfg.rope_sliding)},
+        "rms_norm_eps": cfg.rms_eps, "num_experts": cfg.n_held,
+        "published": {"num_experts": cfg.n_experts},
+        "deployment": {"first_expert_held": cfg.first_expert_held},
+        "num_experts_per_tok": cfg.top_k, "norm_topk_prob": cfg.norm_topk_prob,
+        "moe_routed_scaling_factor": cfg.routed_scaling,
+        "max_position_embeddings": cfg.max_seq, "tie_word_embeddings": cfg.tie_embeddings,
+        "vocab_size": cfg.vocab_size,
+    }
+
+
+def seeded_params(cfg, seed=0, bias=0.0):
+    """init_params with the norms moved off one, and the selection biases
+    at `bias` x a random table (0: the published forward)."""
+    params = llama.init_params(cfg, jax.random.key(seed))
+    keys = iter(jax.random.split(jax.random.key(seed + 100), 64))
+
+    def spread(tree):
+        for name in ("ln1", "ln2"):
+            tree[name] = tree[name] + 0.2 * jax.random.normal(next(keys), tree[name].shape)
+
+    spread(params["dense_layers"])
+    for group in ("period", "tail"):
+        for block in params["layers"].get(group, {}).values():
+            spread(block)
+    params["final_norm"] = params["final_norm"] + 0.2 * jax.random.normal(
+        next(keys), params["final_norm"].shape)
+    table = params["layers"]["router_bias"]
+    params["layers"]["router_bias"] = bias * jax.random.normal(next(keys), table.shape)
+    return params
+
+
+def block_of(params, position, period=0, bias_row=None):
+    lp = jax.tree.map(lambda w: w[period], params["layers"]["period"][str(position)])
+    if bias_row is not None:
+        lp["router_bias"] = params["layers"]["router_bias"][bias_row]
+    return lp
+
+
+def skewed_tokens(cfg, seed=1):
+    rng = np.random.default_rng(seed)
+    p = 1.0 / np.arange(1, cfg.vocab_size + 1) ** 1.1
+    ids = rng.choice(cfg.vocab_size, size=(B, S + 1), p=p / p.sum())
+    return {"tokens": jnp.asarray(ids[:, :-1], jnp.int32),
+            "targets": jnp.asarray(ids[:, 1:], jnp.int32)}
+
+
+def worst_leaf(got, want, skip=("router_bias",)):
+    """{path: largest difference of a leaf over the leaf's own scale}."""
+    worst = {}
+    for path, g in jax.tree_util.tree_leaves_with_path(got):
+        w = want
+        for k in path:
+            w = w[k.key]
+        name = jax.tree_util.keystr(path)
+        if any(s in name for s in skip):
+            assert float(jnp.abs(g).max()) == 0.0 and float(jnp.abs(w).max()) == 0.0
+            continue
+        worst[name] = float(jnp.abs(g - w).max()) / max(float(jnp.abs(w).max()), 1e-12)
+    return worst
+
+
+def tables_of(cfg, s):
+    pos = jnp.arange(s)
+    return {laguna.FULL: cfg.rope_full.tables(cfg.head_dim, pos),
+            laguna.SLIDING: cfg.rope_sliding.tables(cfg.head_dim, pos)}
+
+
+# -- the stack's plan and the tree ----------------------------------------------------
+
+
+def test_the_stack_is_cut_into_a_dense_layer_whole_periods_and_a_tail():
+    full = laguna.plan(laguna.LAGUNA_S_2_1)
+    assert full["dense"] == (laguna.FULL, 48) and full["periods"] == 11
+    assert full["period"] == [(laguna.SLIDING, 72)] * 3 + [(laguna.FULL, 48)]
+    assert full["tail"] == [(laguna.SLIDING, 72)] * 3
+    cell = dataclasses.replace(laguna.LAGUNA_S_2_1, n_layers=5, vocab_size=12544, experts_held=8)
+    assert laguna.plan(cell)["periods"] == 1 and not laguna.plan(cell)["tail"]
+    assert cell.num_params() == 811_018_240   # ISSUE 39's table: 811.0M
+    tiny = laguna.plan(FP32)
+    assert tiny["periods"] == 2 and len(tiny["period"]) == 4 and not tiny["tail"]
+    # the tree, its axes and the count agree, with a tail too
+    for cfg in (FP32, dataclasses.replace(FP32, n_layers=11)):
+        params = llama.init_params(cfg, jax.random.key(0))
+        axes = jax.tree.map(lambda a: 0, llama.logical_axes(cfg),
+                            is_leaf=lambda x: isinstance(x, tuple))
+        assert jax.tree.structure(params) == jax.tree.structure(axes)
+        assert sum(x.size for x in jax.tree.leaves(params)) == cfg.num_params()
+        assert params["layers"]["router_bias"].shape == (cfg.n_expert_layers, cfg.n_experts)
+    assert set(params["layers"]) == {"router_bias", "period", "tail"}
+    assert params["layers"]["period"]["0"]["wq"].shape == (2, 64, 6 * 16)
+    assert params["layers"]["period"]["3"]["wq"].shape == (2, 64, 4 * 16)
+    assert params["layers"]["tail"]["1"]["wg"].shape == (64, 6)
+
+
+# -- the attention sublayer against the reference ----------------------------------------
+
+
+@pytest.mark.parametrize("impl", ["xla", "flash"])
+@pytest.mark.parametrize("position,kind,heads", [(0, laguna.SLIDING, 6), (3, laguna.FULL, 4)])
+def test_attention_sublayer_is_the_references(position, kind, heads, impl):
+    """h -> h + gated attention of one kind: the window or not, the whole
+    head rotated or YaRN on half of it, forward and the gradients of the
+    input and of every weight."""
+    cfg = dataclasses.replace(FP32, attention_impl=impl)
+    lp = block_of(seeded_params(cfg), position)
+    h = jax.random.normal(jax.random.key(3), (B, S, cfg.d_model), jnp.float32)
+    shape = shape_of(cfg)
+
+    def program(h, lp):
+        return laguna.attention_sublayer(h, rms_norm(h, lp["ln1"], cfg.rms_eps), lp, cfg, kind=kind,
+                                         heads=heads, tables=tables_of(cfg, S), segment_ids=None)
+
+    def reference(h, lp):
+        return jnp.stack([laguna_decoder.attention(h[b], lp, shape, kind, heads) for b in range(B)])
+
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(np.asarray(program(h, lp)), np.asarray(reference(h, lp)),
+                                   rtol=2e-5, atol=2e-5)
+        probe = jax.random.normal(jax.random.key(4), h.shape)
+        got = jax.grad(lambda h, lp: (program(h, lp) * probe).sum(), (0, 1))(h, lp)
+        want = jax.grad(lambda h, lp: (reference(h, lp) * probe).sum(), (0, 1))(h, lp)
+    used = ("ln1", "wq", "wk", "wv", "wg", "wo")
+    worst = worst_leaf({"h": got[0], **{k: got[1][k] for k in used}},
+                       {"h": want[0], **{k: want[1][k] for k in used}})
+    assert max(worst.values()) < 1e-4, worst
+
+
+def test_a_token_600_back_reaches_a_full_layer_and_not_a_sliding_one():
+    """The window by itself, at the published 512: changing the input at
+    position 0 changes a sliding layer's output at positions 0 .. 511
+    and nowhere after; a full layer's, everywhere."""
+    cfg = dataclasses.replace(FP32, sliding_window=512)
+    params = seeded_params(cfg)
+    s = 640
+    h = jax.random.normal(jax.random.key(3), (1, s, cfg.d_model), jnp.float32)
+    moved = h.at[0, 0].add(1.0)
+    for position, kind, heads in ((0, laguna.SLIDING, 6), (3, laguna.FULL, 4)):
+        lp = block_of(params, position)
+
+        def run(h):
+            return laguna.attention_sublayer(
+                h, rms_norm(h, lp["ln1"], cfg.rms_eps), lp, cfg, kind=kind, heads=heads,
+                tables=tables_of(cfg, s), segment_ids=None) - h
+
+        diff = np.abs(np.asarray(run(moved) - run(h))).max(axis=-1)[0]
+        assert diff[:512].min() > 0
+        if kind == laguna.SLIDING:
+            assert diff[512:].max() == 0.0
+        else:
+            assert diff[512:].min() > 0
+
+
+def test_the_gate_is_one_sigmoid_a_head_and_token_on_the_attentions_output():
+    lp = block_of(seeded_params(FP32), 0)
+    h = jax.random.normal(jax.random.key(3), (B, S, FP32.d_model), jnp.float32)
+
+    def run(lp):
+        x = rms_norm(h, lp["ln1"], FP32.rms_eps)
+        return x, laguna.attention_sublayer(h, x, lp, FP32, kind=laguna.SLIDING, heads=6,
+                                            tables=tables_of(FP32, S), segment_ids=None) - h
+
+    with jax.default_matmul_precision("highest"):
+        x, gated = run(lp)
+        # a gate of 1/2 everywhere (wg = 0) halves what an open gate would give
+        _, half = run({**lp, "wg": jnp.zeros_like(lp["wg"])})
+        # head 2's gate shut (a large negative logit): its columns of wo see nothing
+        shut = lp["wg"].at[:, 2].set(-1e4 * jnp.sign(x[0, 0]) / FP32.d_model ** 0.5)
+        _, without = run({**lp, "wg": shut.at[:, 2].set(-1e4 * jnp.ones_like(shut[:, 2]))})
+    wo = lp["wo"].reshape(6, 16, -1)
+    assert float(jnp.abs(gated - half).max()) > 1e-3
+    g = jax.nn.sigmoid(jnp.einsum("bsd,dh->bsh", x, lp["wg"]))
+    assert g.shape == (B, S, 6)
+    # gated = sum_h g_h o_h wo_h and half = sum_h o_h wo_h / 2: with one head's wo alone they
+    # differ by the factor 2 g_h
+    for head in (0, 5):
+        only = {**lp, "wo": jnp.zeros_like(wo).at[head].set(wo[head]).reshape(lp["wo"].shape)}
+        with jax.default_matmul_precision("highest"):
+            _, a = run(only)
+            _, b = run({**only, "wg": jnp.zeros_like(lp["wg"])})
+        np.testing.assert_allclose(np.asarray(a), np.asarray(2 * g[..., head:head + 1] * b),
+                                   rtol=1e-4, atol=1e-6)
+    assert np.isfinite(np.asarray(without)).all()
+
+
+def test_yarn_is_hfs_function_and_turns_half_a_head():
+    """nn/layers.py::yarn_inv_freq against the reference's line-for-line
+    transcription of `_compute_yarn_parameters` at the published
+    parameters, the numbers ISSUE 39 spells out, and `rotate_head_major`
+    against the slices and concatenation it stands for."""
+    r = laguna.LAGUNA_S_2_1.rope_full
+    got = nn_layers.yarn_inv_freq(64, r.theta, r.factor, r.original_max, r.beta_fast, r.beta_slow)
+    want = laguna_decoder.yarn_parameters(64, 5e5, 128, 8192, 32, 1)
+    np.testing.assert_array_equal(got, want)
+    pair = lambda turns: 64 * math.log(8192 / (turns * 2 * math.pi)) / (2 * math.log(5e5))  # noqa: E731
+    low, high = math.floor(pair(32)), math.ceil(pair(1))
+    assert (low, high) == (9, 18)
+    base = 1.0 / 5e5 ** (np.arange(0, 64, 2, dtype=np.float32) / 64)
+    np.testing.assert_allclose(got[:low + 1], base[:low + 1], rtol=1e-6)       # extrapolated: kept
+    np.testing.assert_allclose(got[high:], base[high:] / 128, rtol=1e-6)       # interpolated
+    assert (got[low + 1:high] < base[low + 1:high]).all()
+    assert (got[low + 1:high] > base[low + 1:high] / 128).all()
+    x = jax.random.normal(jax.random.key(0), (2, 3, 16, 128))
+    cos, sin = r.tables(128, jnp.arange(16))
+    assert cos.shape == (1, 16, 32)
+    np.testing.assert_allclose(np.asarray(cos[0, 0]), r.attention_factor, rtol=1e-6)
+    c, s = cos[:, None], sin[:, None]
+    x1, x2 = x[..., :32], x[..., 32:64]
+    literal = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s, x[..., 64:]], axis=-1)
+    np.testing.assert_allclose(np.asarray(nn_layers.rotate_head_major(x, cos, sin)),
+                               np.asarray(literal), rtol=1e-6, atol=1e-6)
+    got_g = jax.grad(lambda x: (nn_layers.rotate_head_major(x, cos, sin) ** 2).sum())(x)
+    np.testing.assert_allclose(np.asarray(got_g), np.asarray(2 * r.attention_factor ** 2 * x.at[
+        ..., 64:].multiply(1 / r.attention_factor ** 2)), rtol=1e-4, atol=1e-5)
+    # the whole head (a sliding layer's) is `apply_rope_head_major` on tables of positions
+    cos_w, sin_w = laguna.LAGUNA_S_2_1.rope_sliding.tables(128, jnp.arange(16))
+    table = nn_layers.rope_frequencies(128, 16, 10000.0)
+    np.testing.assert_allclose(
+        np.asarray(nn_layers.rotate_head_major(x, cos_w, sin_w)),
+        np.asarray(nn_layers.apply_rope_head_major(x, *table, jnp.arange(16))),
+        rtol=1e-5, atol=1e-5)
+
+
+# -- the router -----------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("bias", [0.0, 0.05], ids=["zero_bias", "random_bias"])
+def test_softmax_routing_bias_renormalisation_and_scaling(bias):
+    """moe_ffn on a Laguna-kind block against the reference's expert half:
+    top-k of p + b, weights 2.5 x p / sum p, the shared expert; and the
+    old softmax configurations keep what they had."""
+    params = seeded_params(FP32, bias=bias)
+    lp = block_of(params, 1, bias_row=1)
+    x = jax.random.normal(jax.random.key(5), (B, S, FP32.d_model), jnp.float32)
+    shape = shape_of(FP32)
+    with jax.default_matmul_precision("highest"):
+        out, stats, _ = moe.moe_ffn(x, lp, FP32)
+        want = []
+        for b in range(B):
+            weights = laguna_decoder.route(x[b], lp, shape)
+            routed = sum(weights[:, e:e + 1] * laguna_decoder._swiglu(
+                x[b], lp["w_gate"][e], lp["w_up"][e], lp["w_down"][e])
+                for e in range(FP32.n_experts))
+            want.append(routed + laguna_decoder._swiglu(
+                x[b], lp["shared_gate"], lp["shared_up"], lp["shared_down"]))
+            np.testing.assert_allclose(np.asarray(weights.sum(-1)), FP32.routed_scaling, rtol=1e-5)
+            assert ((weights > 0).sum(-1) == FP32.top_k).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(jnp.stack(want)), rtol=2e-5, atol=2e-5)
+    assert int(stats["tokens_per_expert"].sum()) == FP32.top_k * B * S
+    if bias:
+        plain, _, _ = moe.moe_ffn(x, {k: v for k, v in lp.items() if k != "router_bias"}, FP32)
+        assert float(jnp.abs(plain - out).max()) > 1e-4   # the bias moved some choice
+
+
+# -- the whole train path ----------------------------------------------------------------
+
+
+@pytest.mark.parametrize("bias", [0.0, 0.05], ids=["zero_bias", "random_bias"])
+@pytest.mark.parametrize("held", [None, (4, 8)], ids=["all_experts", "a_share"])
+def test_train_path_meets_the_reference_in_loss_and_gradients(held, bias):
+    """llama.loss_fn (the one train path) on the dense layer and two
+    periods of four against the plain reference: the loss, the tokens per
+    expert of every block, and every gradient by its worst leaf."""
+    cfg = FP32 if held is None else dataclasses.replace(
+        FP32, experts_held=held[0], first_expert_held=held[1])
+    params, batch, shape = seeded_params(cfg, bias=bias), skewed_tokens(cfg), shape_of(cfg)
+    with jax.default_matmul_precision("highest"):
+        loss, weight, stats = llama.loss_and_weight_fn(params, batch, cfg)
+        got = jax.grad(lambda p: llama.loss_fn(p, batch, cfg))(params)
+        ref = laguna_decoder.loss_parts(params, batch["tokens"], batch["targets"], shape)
+        want = jax.grad(lambda p: laguna_decoder.loss(
+            p, batch["tokens"], batch["targets"], shape))(params)
+    assert float(weight) == B * S
+    assert float(loss) == pytest.approx(float(ref["loss"]), rel=2e-6)
+    assert stats["tokens_per_expert"].shape == (8, cfg.n_experts)
+    assert stats["tokens_per_expert"].tolist() == ref["tokens_per_expert"].tolist()
+    assert stats["tokens_per_expert"].sum(-1).tolist() == [cfg.top_k * B * S] * 8
+    assert int(stats["dropped_pairs"].sum()) == 0
+    if held is not None:
+        n, first = held
+        elsewhere = cfg.top_k * B * S - stats["tokens_per_expert"][:, first:first + n].sum(-1)
+        assert stats["pairs_elsewhere"].tolist() == elsewhere.tolist()
+        assert 0 < int(elsewhere.sum()) < 8 * cfg.top_k * B * S
+    worst = worst_leaf(got, want)
+    assert len(worst) == len(jax.tree.leaves(params)) - 1
+    assert max(worst.values()) < 2e-4, sorted(worst.items(), key=lambda kv: -kv[1])[:3]
+
+
+def test_a_tail_after_the_last_whole_period_runs_in_layer_order():
+    cfg = dataclasses.replace(FP32, n_layers=11)   # dense + 2 periods + sliding, sliding
+    params, batch, shape = seeded_params(cfg, bias=0.05), skewed_tokens(cfg), shape_of(cfg)
+    with jax.default_matmul_precision("highest"):
+        loss, _, stats = llama.loss_and_weight_fn(params, batch, cfg)
+        ref = laguna_decoder.loss_parts(params, batch["tokens"], batch["targets"], shape)
+    assert float(loss) == pytest.approx(float(ref["loss"]), rel=2e-6)
+    assert stats["tokens_per_expert"].tolist() == ref["tokens_per_expert"].tolist()
+
+
+def test_bf16_compute_stays_near_the_reference():
+    cfg = dataclasses.replace(FP32, dtype=jnp.bfloat16, attention_impl="flash", n_layers=5)
+    params, batch = seeded_params(cfg), skewed_tokens(cfg)
+    loss = llama.loss_fn(params, batch, cfg)
+    ref = laguna_decoder.loss(params, batch["tokens"], batch["targets"], shape_of(cfg))
+    assert float(loss) == pytest.approx(float(ref), rel=0.02)
+
+
+@pytest.mark.parametrize("remat_policy", ["dots", "full"])
+def test_remat_gives_the_same_gradients(remat_policy):
+    plain = dataclasses.replace(FP32, n_layers=5)   # the dense layer and one period
+    cfg = dataclasses.replace(plain, remat=True, remat_policy=remat_policy)
+    params, batch = seeded_params(cfg, bias=0.05), skewed_tokens(cfg)
+    got = jax.grad(lambda p: llama.loss_fn(p, batch, cfg))(params)
+    want = jax.grad(lambda p: llama.loss_fn(p, batch, plain))(params)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=2e-6)
+
+
+# -- the share adds up ---------------------------------------------------------------
+
+
+def test_thirty_two_shares_add_up_to_the_uncut_layer():
+    """The cell's deployment, small: 32 shares of 2 of 64 experts, top-10
+    softmax with a bias, weights renormalised x 2.5. The router and the
+    shared expert are computed alike on every chip and counted ONCE; the
+    shares' routed outputs, so counted, sum to the uncut layer's, and so
+    do the gradients of the input; every share counts what the uncut
+    layer counts, and what one computes the others count as elsewhere."""
+    whole = dataclasses.replace(FP32, n_experts=64, top_k=10)
+    key = jax.random.key(2)
+    lp = jax.tree.map(lambda w: w[0], moe.expert_params(dataclasses.replace(whole, n_layers=1), key))
+    lp["router_bias"] = 0.01 * jax.random.normal(jax.random.key(3), (64,))
+    x = jax.random.normal(jax.random.key(5), (B, S, whole.d_model), jnp.float32)
+    no_shared = {**lp, "shared_down": jnp.zeros_like(lp["shared_down"])}
+    experts = ("w_gate", "w_up", "w_down")
+
+    def run(cfg, lp):
+        out, vjp, stats = jax.vjp(lambda x: moe.moe_ffn(x, lp, cfg)[:2], x, has_aux=True)
+        return out, vjp(jnp.ones_like(out))[0], stats
+
+    def share(first, lp):
+        cfg = dataclasses.replace(whole, experts_held=2, first_expert_held=first)
+        return run(cfg, {**lp, **{k: lp[k][first:first + 2] for k in experts}})
+
+    with jax.default_matmul_precision("highest"):
+        full = run(whole, lp)
+        shared_alone = run(whole, {**lp, "w_down": jnp.zeros_like(lp["w_down"])})
+        router_only = run(whole, {**no_shared, "w_down": jnp.zeros_like(lp["w_down"])})
+        routed = [share(first, no_shared) for first in range(0, 64, 2)]
+    for i in (0, 1):   # the output, and the gradient of the input
+        total = sum(np.asarray(r[i]) for r in routed) + np.asarray(shared_alone[i])
+        if i == 1:  # the router's own path to x is in every term: counted once
+            total = total - 32 * np.asarray(router_only[1])
+        np.testing.assert_allclose(total, np.asarray(full[i]), rtol=2e-5, atol=2e-5)
+    counts = full[2]["tokens_per_expert"]
+    assert int(counts.sum()) == 10 * B * S
+    for first, (_, _, stats) in zip(range(0, 64, 2), routed):
+        assert stats["tokens_per_expert"].tolist() == counts.tolist()
+        assert int(stats["pairs_elsewhere"]) == int(counts.sum() - counts[first:first + 2].sum())
+        assert int(stats["dropped_pairs"]) == 0
+
+
+# -- the registry ------------------------------------------------------------------
+
+
+def catalog_config():
+    if os.path.exists(CATALOG):
+        for line in open(CATALOG):
+            row = json.loads(line)
+            if row["name"] == "Laguna-S-2.1":
+                return row["config"]
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "chipbench", "configs", "laguna-s-2.1-train.json")
+    file = json.load(open(path))
+    return {**{k: v for k, v in file.items() if k not in file["published"]}, **file["published"]}
+
+
+def test_config_from_hf_maps_the_catalogs_config_onto_the_preset():
+    cfg = config_from_hf(catalog_config())
+    assert cfg == get_model_config("laguna-s-2.1")
+    assert cfg.head_dim == 128 != cfg.d_model // cfg.n_heads
+    assert cfg.rope_full.attention_factor == 1.4852030263919618 and cfg.rope_full.partial == 0.5
+
+
+@pytest.mark.parametrize("key,value,names", [
+    ("moe_router_logit_softcapping", 30.0, "moe_router_logit_softcapping 30.0"),
+    ("moe_apply_router_weight_on_input", True, "moe_apply_router_weight_on_input"),
+    ("gating", True, "gating True"),
+    ("attention_bias", True, "attention_bias"),
+    ("num_attention_heads_per_layer", list(range(48, 96)), "never repeat"),
+    ("mlp_layer_types", ["dense", "sparse", "dense"] + ["sparse"] * 45, "dense layer after"),
+])
+def test_config_from_hf_refuses_by_name_what_is_not_implemented(key, value, names):
+    with pytest.raises(ValueError, match=names):
+        config_from_hf({**catalog_config(), key: value})
+
+
+def test_other_families_still_refuse_a_scaled_rotary_and_an_explicit_head_dim():
+    llama_like = {"architectures": ["LlamaForCausalLM"], "vocab_size": 100, "hidden_size": 64,
+                  "num_hidden_layers": 2, "num_attention_heads": 4, "intermediate_size": 128}
+    with pytest.raises(ValueError, match="rope_scaling"):
+        config_from_hf({**llama_like, "rope_scaling": {"rope_type": "yarn", "factor": 4}})
+    with pytest.raises(ValueError, match="head_dim"):
+        config_from_hf({**llama_like, "head_dim": 32})
+
+
+def test_engine_refuses_the_model_by_name():
+    from ray_tpu.llm.engine import EngineConfig
+
+    with pytest.raises(ValueError, match="Laguna"):
+        EngineConfig(model="laguna-tiny")

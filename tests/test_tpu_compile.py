@@ -529,6 +529,61 @@ def test_glm_lite_share_train_step_runs_mla_its_kernels_and_the_second_head(v5e)
     assert any("mtp.block" in n and "moe.experts" in n for n in op_names)
 
 
+def test_laguna_share_train_step_runs_window_and_full_kernels_head_major(v5e):
+    """Laguna-S-2.1 as `laguna-train` builds it (8 of 256 experts and an
+    eighth of the vocabulary held; the dense full-attention layer and ONE
+    sliding expert layer here, the cell's period of four is rehearsed in
+    PERF.md), compiled for the described chip: the sliding layer's
+    attention is the flash kernels under a window, named `swa.attend.N`,
+    the full layer's `attn.attend.N`, at 72 and 48 heads of an explicit
+    128; the held experts' nine grouped matmuls are the kernels of
+    ops/grouped_matmul.py at [3072, 1024]; every scope the cell's readers
+    sum is in the compiled step; q, k, v and o meet no transpose and no
+    copy at the kernel's door; no site falls back."""
+    from ray_tpu import obs
+
+    step, state, batch = _train_step_at_mistral_widths(
+        v5e, batch=1, model="laguna-s-2.1", n_layers=2, vocab_size=12544, experts_held=8)
+    before = obs.layer_counters()
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        compiled = step.lower(state, batch).compile()
+    after = obs.layer_counters()
+    engaged = {name: after.get(name, {"count": 0})["count"]
+               - before.get(name, {"count": 0})["count"]
+               for name in ("laguna.attn", "moe.ffn", "grouped_matmul.kernel",
+                            "grouped_matmul.ragged_dot", "tp_overlap.plain")}
+    assert engaged["laguna.attn"] >= 2 and engaged["moe.ffn"] >= 1
+    assert engaged["grouped_matmul.kernel"] > 0
+    assert engaged["grouped_matmul.ragged_dot"] == engaged["tp_overlap.plain"] == 0  # fallback_sites
+    hlo = compiled.as_text()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    grouped = sorted(re.sub(r"\.\d+$", "", k) for k in kernels if k.startswith("ragged-dot"))
+    assert grouped == (["ragged-dot-tiled"] * 3 + ["ragged-dot-tiled-dgrad"] * 3
+                       + ["ragged-dot-tiled-wgrad"] * 3), kernels
+    assert "ragged-dot-none" not in hlo
+    # what is no grouped matmul is flash: forward and backward of each kind, by its scope
+    rest = sorted(re.sub(r"\.\d+$", "", k) for k in kernels if not k.startswith("ragged-dot"))
+    assert rest == ["attn.attend"] * 2 + ["swa.attend"] * 2, kernels
+    assert re.search(r"bf16\[1,72,4096,128\]", hlo) and re.search(r"bf16\[1,48,4096,128\]", hlo)
+    # 8 held experts' weights and no more, the router's 256 outputs whole
+    assert "8,3072,1024]" in hlo and "256,3072,1024]" not in hlo and "4096,256]" in hlo
+    op_names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for scope in ("attn.qkv", "attn.rope", "attn.attend", "attn.gate", "attn.out", "swa.qkv",
+                  "swa.rope", "swa.attend", "swa.gate", "swa.out", "moe.router", "moe.dispatch",
+                  "moe.experts", "moe.combine", "shared.ffn", "dense.ffn", "block.norm",
+                  "block.stack", "head", "optim"):
+        assert any(re.search(r"(?:^|[/(])" + re.escape(scope) + r"(?:[/)]|$)", n)
+                   for n in op_names), scope
+    # head-major from the projections to `wo`: every [1, heads, 4096, 128] array has the
+    # tokens and a head's channels as its tile, and none of them, nor a [1, 4096, heads, 128]
+    # one, is the result of a copy or a transpose
+    moved = [shape for shape, op in re.findall(
+        r"^\s*(?:ROOT )?%[\w.\-]+ = (\S+) (copy|transpose)\(", hlo, re.M)
+        if re.search(r"\[1,(?:72|48|8),4096,128\]|\[1,4096,(?:72|48|8),128\]", shape)]
+    assert not moved, moved
+    assert set(re.findall(r"bf16\[1,(?:72|48|8),4096,128\]\{([\d,]+)", hlo)) == {"3,2,1,0"}
+
+
 @pytest.mark.parametrize("cell,kwargs,temp_gib,tiles_at_16", [
     ("m7b-train", dict(batch=3), 11.2, 37144),
     ("olmoe-train", dict(batch=6, model="olmoe-1b-7b", n_layers=1), 6.9, 30468),
