@@ -43,6 +43,16 @@ models/cca.py, asks both):
     contribute zero to the output and to every gradient (they are not
     dropped pairs: another chip computes them) and are counted apart.
     No code stands in for the absent chips or their exchange.
+    WHAT IS SIZED BY WHAT: the router, the two sorts, `inv` and the
+    counts see all N * top_k pairs; where the share is small enough
+    (`held_rows_bound`: a static bound C on the held rows, from N *
+    top_k, the share and nothing else), everything of model or expert
+    width after the sort (the gathered rows, gate / up / act / ys, the
+    kernels' grids, `_zero_tail`'s selects, the sum back into the
+    tokens) has C rows, not N * top_k. A step whose routing puts more
+    than C pairs on the held experts runs that block over all the rows
+    instead (`jax.lax.cond`, the same mathematics: no pair is lost
+    either way), and the statistic `compact` says which ran.
 
 And two that GLM-4.7-Flash (models/mla.py) asks, in the DeepSeek-V3 form
 (arXiv:2412.19437, arXiv:2408.15664):
@@ -295,6 +305,235 @@ def _pair_weights_bwd(inv, g):
 _pair_weights.defvjp(_pair_weights_fwd, _pair_weights_bwd)
 
 
+def _all_rows(xt, w, w_gate, w_up, w_down, order, inv, sizes, *, tail, dtype):
+    """The routed experts over ALL N * K pair rows -> [N, D]: what a
+    configuration that holds every expert (or a large share) runs, and
+    the branch a small share falls back to."""
+    with jax.named_scope("moe.dispatch"):
+        xs = _to_expert_order(xt, order, inv)
+    with jax.named_scope("moe.experts"):
+        # named for the remat policy (llama._decoder), which knows
+        # dot_general's outputs but not a grouped matmul's
+        name = jax.ad_checkpoint.checkpoint_name
+        gmm = functools.partial(grouped_matmul, group_sizes=sizes, tail=tail)
+        gate = name(gmm(xs, w_gate.astype(dtype)), "moe_gate")
+        up = name(gmm(xs, w_up.astype(dtype)), "moe_up")
+        # the router's weight goes on BEFORE the down projection (the
+        # same sum): the backward then needs no output of `w_down`,
+        # so that matmul is not run again to differentiate the weights
+        act = (jax.nn.silu(gate) * up).astype(jnp.float32)
+        act = (act * _pair_weights(w, order, inv)[:, None]).astype(dtype)
+        ys = gmm(act, w_down.astype(dtype))
+    with jax.named_scope("moe.combine"):
+        return _to_token_order(ys, order, inv)
+
+
+# -- a small share: everything after the sort over a bound on the HELD rows ----
+#
+# The held experts' pairs are sorted first, so where `sizes.sum() <= C` the
+# rows `order[:C]` are every held pair in expert order, then rows of pairs
+# routed elsewhere, which lie past the last group: no tile of a grouped
+# matmul visits them, `_zero_tail` makes them zero, and a zero row adds
+# nothing to its token. `tok` [C] is the token of each of those rows.
+
+# C = _SLACK x the pairs a uniform router would put on the held experts,
+# rounded up to the row tile `ops/grouped_matmul.py::pick_tiles` wants. At 2
+# the benchmark's share cells sit at 0.50-0.69 of C (`laguna-train` holds
+# 3.1-4.3% of its pairs against a uniform 3.125%, `glm47f-train` 11.1-14.2%
+# against 12.5%: the configurations' `lr_why`, summed over their blocks; ONE
+# block of `laguna-train` crossed C on 2 seeds of 30 inside a 10 s window:
+# PERF.md, PR 40); a routing past it is still exact, only slower.
+_SLACK = 2
+_ROW_TILE = 512
+
+
+def held_rows_bound(pairs: int, held: int, experts: int) -> Optional[int]:
+    """The static bound C on the rows of a share of `held` of `experts`
+    among `pairs` = N * top_k, or None where no compact path is built:
+    a share that would not halve the rows (ZAYA1's 8 of 16), or all the
+    experts."""
+    if held >= experts:
+        return None
+    uniform = -(-pairs * held // experts)
+    bound = min(pairs, -(-_SLACK * uniform // _ROW_TILE) * _ROW_TILE)
+    return bound if 2 * bound <= pairs else None
+
+
+def _fits(sizes, bound):
+    """Whether this step's held pairs lie in the first `bound` rows: the
+    branch's predicate, forward and backward, and the statistic `compact`."""
+    return sizes.sum() <= bound
+
+
+def _sum_of_held_rows(y, tok, n_tokens):
+    """y [C, D], tok [C] -> [N, D]: each token's rows summed in float32.
+    A product with the [N, C] 0/1 matrix of (token, row) on the MXU: the
+    products are exact, the sum is float32. (PERF.md, PR 40: against a
+    gather of N * K rows from a zero-padded [C + 1, D] table and a
+    scatter-add.)"""
+    hot = (jnp.arange(n_tokens, dtype=tok.dtype)[:, None] == tok[None, :]).astype(y.dtype)
+    exact = jax.lax.Precision.HIGHEST if y.dtype == jnp.float32 else None
+    return jnp.dot(hot, y, precision=exact, preferred_element_type=jnp.float32).astype(y.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _to_held_rows(xt, tok, n_tokens):
+    """xt [N, D] -> [C, D]: the token's row for each of the first C rows
+    of expert order."""
+    return xt[tok]
+
+
+def _to_held_rows_fwd(xt, tok, n_tokens):
+    return xt[tok], tok
+
+
+def _to_held_rows_bwd(n_tokens, tok, g):
+    with jax.named_scope("moe.dispatch"), jax.named_scope("moe.held"):
+        return _sum_of_held_rows(g, tok, n_tokens), None
+
+
+_to_held_rows.defvjp(_to_held_rows_fwd, _to_held_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _from_held_rows(y, tok, n_tokens):
+    """y [C, D] in expert order -> [N, D]: the held rows summed into
+    their tokens, in float32. `_to_held_rows`'s transpose."""
+    return _sum_of_held_rows(y, tok, n_tokens)
+
+
+def _from_held_rows_fwd(y, tok, n_tokens):
+    return _sum_of_held_rows(y, tok, n_tokens), tok
+
+
+def _from_held_rows_bwd(n_tokens, tok, g):
+    with jax.named_scope("moe.combine"), jax.named_scope("moe.held"):
+        return g[tok], None
+
+
+_from_held_rows.defvjp(_from_held_rows_fwd, _from_held_rows_bwd)
+
+
+@jax.custom_vjp
+def _held_pair_weights(w, rows, inv):
+    """w [N, K] -> [C]: the router weight of the pair at each of the
+    first C rows of expert order (`rows` = order[:C])."""
+    return w.reshape(-1)[rows]
+
+
+def _held_pair_weights_fwd(w, rows, inv):
+    return w.reshape(-1)[rows], inv
+
+
+def _held_pair_weights_bwd(inv, g):
+    # a pair whose row is past C reads the zero appended at C
+    bound = g.shape[0]
+    return jnp.append(g, jnp.zeros((1,), g.dtype))[jnp.minimum(inv, bound)], None, None
+
+
+_held_pair_weights.defvjp(_held_pair_weights_fwd, _held_pair_weights_bwd)
+
+
+def _held_gate_up(xt, w_gate, w_up, tok, sizes):
+    """-> gate, up [C, d_ff] of the held rows."""
+    with jax.named_scope("moe.dispatch"), jax.named_scope("moe.held"):
+        xs = _to_held_rows(xt, tok, xt.shape[0])
+    with jax.named_scope("moe.experts"), jax.named_scope("moe.held"):
+        gmm = functools.partial(grouped_matmul, group_sizes=sizes, tail=True)
+        return gmm(xs, w_gate), gmm(xs, w_up)
+
+
+def _held_down_sum(gate, up, w, w_down, rows, inv, tok, sizes):
+    """gate, up [C, d_ff] -> [N, D]: weighted, down, summed into the tokens."""
+    with jax.named_scope("moe.experts"), jax.named_scope("moe.held"):
+        act = (jax.nn.silu(gate) * up).astype(jnp.float32)
+        act = (act * _held_pair_weights(w, rows, inv)[:, None]).astype(gate.dtype)
+        ys = grouped_matmul(act, w_down, sizes, tail=True)
+    with jax.named_scope("moe.combine"), jax.named_scope("moe.held"):
+        return _from_held_rows(ys, tok, inv.shape[0])
+
+
+def _held_rows(bound, order, inv):
+    rows = order[:bound]
+    return rows, rows // inv.shape[1]
+
+
+def _held_or_all_fwd(bound, xt, w, w_gate, w_up, w_down, order, inv, sizes):
+    """-> (out [N, D], gate and up [C, d_ff] for the backward: zeros
+    where the block ran over all rows, which keeps nothing: its backward
+    runs gate and up again)."""
+    def held():
+        rows, tok = _held_rows(bound, order, inv)
+        gate, up = _held_gate_up(xt, w_gate, w_up, tok, sizes)
+        return _held_down_sum(gate, up, w, w_down, rows, inv, tok, sizes), gate, up
+
+    def every():
+        with jax.named_scope("moe.all"):
+            out = _all_rows(xt, w, w_gate, w_up, w_down, order, inv, sizes,
+                            tail=True, dtype=xt.dtype)
+        kept = jnp.zeros((bound, w_gate.shape[2]), xt.dtype)
+        return out, kept, kept
+
+    with jax.named_scope("moe.experts"):  # the `cond`'s own time and its predicate's
+        return jax.lax.cond(_fits(sizes, bound), held, every)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_or_all(bound, xt, w, w_gate, w_up, w_down, order, inv, sizes):
+    """The routed experts of a small share -> [N, D]: over the first
+    `bound` rows of expert order where the held pairs fit in them, over
+    all N * K rows where they do not. ONE custom VJP whose forward and
+    backward each branch, so that what is kept between them is what both
+    branches share (the arguments) and the held rows' gate and up
+    [C, d_ff]: differentiating a `cond` of two differentiable bodies
+    would keep the UNION of their residuals, [N * K, d_ff] zeros in the
+    branch that ran compact."""
+    return _held_or_all_fwd(bound, xt, w, w_gate, w_up, w_down, order, inv, sizes)[0]
+
+
+def _held_or_all_vjp_fwd(bound, xt, w, w_gate, w_up, w_down, order, inv, sizes):
+    out, gate, up = _held_or_all_fwd(bound, xt, w, w_gate, w_up, w_down, order, inv, sizes)
+    # named for the remat policy, as `_all_rows` names its own
+    name = jax.ad_checkpoint.checkpoint_name
+    return out, (xt, w, w_gate, w_up, w_down, order, inv, sizes,
+                 name(gate, "moe_gate"), name(up, "moe_up"))
+
+
+def _held_or_all_vjp_bwd(bound, res, g):
+    xt, w, w_gate, w_up, w_down, order, inv, sizes, gate, up = res
+
+    def held():
+        rows, tok = _held_rows(bound, order, inv)
+        # each stage's forward is traced for its transpose and dies unused: the
+        # kept gate and up stand in for the first's, the second's `ys` feeds nothing
+        _, down_sum_t = jax.vjp(
+            lambda gate, up, w, w_down: _held_down_sum(gate, up, w, w_down, rows, inv, tok, sizes),
+            gate, up, w, w_down)
+        d_gate, d_up, d_w, d_down = down_sum_t(g)
+        _, gate_up_t = jax.vjp(
+            lambda xt, w_gate, w_up: _held_gate_up(xt, w_gate, w_up, tok, sizes),
+            xt, w_gate, w_up)
+        d_xt, d_gate_w, d_up_w = gate_up_t((d_gate, d_up))
+        return d_xt, d_w, d_gate_w, d_up_w, d_down
+
+    def every():
+        with jax.named_scope("moe.all"):
+            _, all_rows_t = jax.vjp(
+                lambda *a: _all_rows(*a, order, inv, sizes, tail=True, dtype=xt.dtype),
+                xt, w, w_gate, w_up, w_down)
+            return all_rows_t(g)
+
+    with jax.named_scope("moe.experts"):
+        grads = jax.lax.cond(_fits(sizes, bound), held, every)
+    return (*grads, None, None, None)
+
+
+_held_or_all.defvjp(_held_or_all_vjp_fwd, _held_or_all_vjp_bwd)
+# one trace and one lowering a step program for the blocks of one shape, not one a block:
+# two bodies, forward and backward, are host seconds of every start (`setup_s`)
+_held_or_all_once = jax.jit(_held_or_all, static_argnums=(0,))
+
+
 def _mlp_router_logits(xt, lp: Params, c: MoEConfig, r_prev):
     """ZAYA1's router (arXiv:2511.17127), all in float32 at `highest`:
     r = xt W_down + gamma * r_prev (the previous layer's r, nothing
@@ -332,6 +571,13 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig,
     really multiplied. With `top_k` > 1 a token may have some of its
     pairs here and some elsewhere: each PAIR is held or elsewhere by its
     own expert, the held ones sorted first, and `dropped_pairs` stays 0.
+    Where the share is small enough for a bound C on its rows
+    (`held_rows_bound`, from N * top_k, the share and nothing else) the
+    block is BUILT with two bodies and adds `compact` (int32): 1 where
+    this step's held pairs fitted in C rows and everything after the
+    sort ran over C rows, 0 where they did not and it ran over all
+    N * top_k. The router, the sorts and every statistic come before
+    the branch and see all pairs either way.
     A sigmoid router's `balance_loss` takes a token's scores as shares
     of their sum, and its `z_loss` is 0. The shared expert
     (`shared_d_ff`) is no pair and is in none of the counts: every token
@@ -401,22 +647,16 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig,
             _, order = jax.lax.sort((flat, pairs), num_keys=1, is_stable=True)
             _, inv = jax.lax.sort((order, pairs), num_keys=1)
             inv = inv.reshape(N, K)
-            xs = _to_expert_order(xt, order, inv)
-        with jax.named_scope("moe.experts"):
-            # named for the remat policy (llama._decoder), which knows
-            # dot_general's outputs but not a grouped matmul's
-            name = jax.ad_checkpoint.checkpoint_name
-            gmm = functools.partial(grouped_matmul, group_sizes=sizes, tail=tail)
-            gate = name(gmm(xs, lp["w_gate"].astype(x.dtype)), "moe_gate")
-            up = name(gmm(xs, lp["w_up"].astype(x.dtype)), "moe_up")
-            # the router's weight goes on BEFORE the down projection (the
-            # same sum): the backward then needs no output of `w_down`,
-            # so that matmul is not run again to differentiate the weights
-            act = (jax.nn.silu(gate) * up).astype(jnp.float32)
-            act = (act * _pair_weights(w, order, inv)[:, None]).astype(x.dtype)
-            ys = gmm(act, lp["w_down"].astype(x.dtype))
-        with jax.named_scope("moe.combine"):
-            out = _to_token_order(ys, order, inv)
+        bound = held_rows_bound(N * K, held, E)
+        # counted while tracing: a site BUILT with the compact path, or without
+        with obs.layer_span("moe.full" if bound is None else "moe.compact"):
+            if bound is None:
+                out = _all_rows(xt, w, lp["w_gate"], lp["w_up"], lp["w_down"], order, inv, sizes,
+                                tail=tail, dtype=x.dtype)
+            else:
+                with jax.named_scope("moe.experts"):
+                    weights = [lp[k].astype(x.dtype) for k in ("w_gate", "w_up", "w_down")]
+                out = _held_or_all_once(bound, xt, w, *weights, order, inv, sizes)
     stats = {
         "tokens_per_expert": counts,
         "dropped_pairs": N * K - counts.sum(),
@@ -426,6 +666,8 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig,
     }
     if tail:
         stats["pairs_elsewhere"] = N * K - sizes.sum()
+    if bound is not None:
+        stats["compact"] = _fits(sizes, bound).astype(jnp.int32)
     out = out.reshape(B, S, D)
     if c.shared_d_ff:
         with jax.named_scope("shared.ffn"):

@@ -379,7 +379,10 @@ _weight_grad = _named(
 
 def _zero_tail(out, group_sizes):
     """Rows past the last group were written by no tile: zero, not what
-    the buffer held."""
+    the buffer held. A select over all P rows of `out`, forward and on
+    the input gradient: P is what the caller hands the grouped matmul,
+    N * top_k where the expert layer runs over all pair rows and the
+    bound on the held rows where it runs compact (models/moe.py)."""
     rows = jax.lax.broadcasted_iota(jnp.int32, (out.shape[0], 1), 0)
     return jnp.where(rows < group_sizes.sum(), out, jnp.zeros((), out.dtype))
 

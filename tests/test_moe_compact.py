@@ -1,0 +1,251 @@
+"""A small share of the experts: everything after the sort over a static
+bound C on the HELD rows (`moe.held_rows_bound`), and the same block over
+all N * top_k rows when a step's routing puts more than C pairs on the
+held experts. Oracle: the same `moe_ffn` with no compact path built (the
+bound taken away), which is the program every share ran before; the two
+differ by the order of a float32 sum of at most top_k terms."""
+
+import dataclasses
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu import obs
+from ray_tpu.models import moe
+
+D, F = 64, 128
+BASE = dataclasses.replace(moe.MOE_TINY, dtype=jnp.float32, d_model=D, d_ff=F, n_layers=1)
+# (experts, held, top_k, tokens): Laguna's, GLM-4.7-Flash's and ZAYA1's shares
+SHARES = {"laguna": (256, 8, 10, 256), "glm": (64, 8, 4, 512), "zaya": (16, 8, 1, 1024)}
+ROUTERS = {
+    "linear": dict(),
+    "sigmoid": dict(router_score="sigmoid", routed_scaling=1.8, shared_d_ff=F),
+    "biased_softmax": dict(routed_scaling=2.5, shared_d_ff=F),
+}
+STATS = ("tokens_per_expert", "dropped_pairs", "imbalance", "balance_loss", "z_loss",
+         "pairs_elsewhere")
+
+
+def _config(share, router="linear", **more):
+    E, held, K, _ = SHARES[share]
+    return dataclasses.replace(BASE, n_experts=E, top_k=K, experts_held=held,
+                               first_expert_held=held, **ROUTERS[router], **more)
+
+
+def _layer(cfg, router, seed=0):
+    lp = jax.tree.map(lambda a: a[0], moe.expert_params(cfg, jax.random.key(seed)))
+    if router != "linear":  # a selection bias that moves choices, as a balanced one does
+        lp["router_bias"] = 0.02 * jax.random.normal(jax.random.key(seed + 1), (cfg.n_experts,))
+    return lp
+
+
+def _tokens(share, seed=2):
+    return jax.random.normal(jax.random.key(seed), (2, SHARES[share][3] // 2, D))
+
+
+def _value_stats_grads(cfg, x, lp):
+    """(out, statistics, gradients of a scalar of `out` in x and in every
+    float leaf of the layer's parameters)."""
+    weight = jax.random.normal(jax.random.key(9), x.shape)
+
+    def f(x, lp):
+        out, stats, _ = moe.moe_ffn(x, lp, cfg)
+        return (out.astype(jnp.float32) * weight).sum(), (out, stats)
+
+    (_, (out, stats)), grads = jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))(x, lp)
+    return out, stats, grads
+
+
+def _without_the_compact_path():
+    return mock.patch.object(moe, "held_rows_bound", lambda *a: None)
+
+
+def _close(got, want, rtol):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.abs(got - want).max() <= rtol * max(np.abs(want).max(), 1e-30)
+
+
+def test_the_bound_follows_from_the_shapes():
+    # the benchmark's share cells: 2,560 of 40,960 and 8,192 of 32,768 rows
+    assert moe.held_rows_bound(4096 * 10, 8, 256) == 2560
+    assert moe.held_rows_bound(8192 * 4, 8, 64) == 8192
+    # a half share would not halve the rows, every expert held has no rows elsewhere
+    assert moe.held_rows_bound(8192, 8, 16) is None
+    assert moe.held_rows_bound(6 * 4096 * 8, 64, 64) is None
+    # a multiple of the kernels' row tile, never more than half the pairs
+    for pairs, held, experts in [(1280, 8, 256), (2048, 8, 64), (1024, 1, 64), (512, 1, 64)]:
+        bound = moe.held_rows_bound(pairs, held, experts)
+        assert bound is None or (bound % 512 == 0 and 2 * bound <= pairs
+                                 and bound >= 2 * pairs * held / experts)
+    assert moe.held_rows_bound(512, 1, 64) is None
+
+
+@pytest.mark.parametrize("router", sorted(ROUTERS))
+@pytest.mark.parametrize("share", sorted(SHARES))
+def test_compact_block_is_the_block_over_all_rows(share, router):
+    """Output, every statistic and every gradient (x, the three expert
+    weights, the router, the shared expert's) are the full path's."""
+    cfg = _config(share, router)
+    lp, x = _layer(cfg, router), _tokens(share)
+    before = obs.layer_counters().get("moe.compact", {"count": 0})["count"]
+    out, stats, grads = _value_stats_grads(cfg, x, lp)
+    built = obs.layer_counters().get("moe.compact", {"count": 0})["count"] - before
+    with _without_the_compact_path():
+        want_out, want_stats, want_grads = _value_stats_grads(cfg, x, lp)
+    E, held, K, N = SHARES[share]
+    if share == "zaya":
+        assert not built and "compact" not in stats
+    else:
+        assert built and int(stats["compact"]) == 1
+        assert 0 < N * K - int(stats["pairs_elsewhere"]) <= moe.held_rows_bound(N * K, held, E)
+    assert "compact" not in want_stats
+    for key in STATS:
+        np.testing.assert_array_equal(np.asarray(stats[key]), np.asarray(want_stats[key]), key)
+    assert int(stats["dropped_pairs"]) == 0 and int(stats["tokens_per_expert"].sum()) == N * K
+    _close(out, want_out, 1e-6)
+    _close(grads[0], want_grads[0], 1e-5)
+    assert set(grads[1]) == set(want_grads[1]) >= {"w_gate", "w_up", "w_down", "router"}
+    for name in grads[1]:
+        _close(grads[1][name], want_grads[1][name], 1e-5)
+    for name in ("w_gate", "w_up", "w_down", "router"):
+        assert np.abs(np.asarray(grads[1][name])).max() > 0, name
+
+
+def test_compact_block_in_bfloat16_rounds_a_float32_sum_as_the_full_one_does():
+    cfg = _config("glm", "sigmoid", dtype=jnp.bfloat16)
+    lp, x = _layer(cfg, "sigmoid"), _tokens("glm").astype(jnp.bfloat16)
+    out, stats, grads = _value_stats_grads(cfg, x, lp)
+    with _without_the_compact_path():
+        want_out, _, want_grads = _value_stats_grads(cfg, x, lp)
+    assert int(stats["compact"]) == 1 and out.dtype == jnp.bfloat16
+    _close(out, want_out, 2 ** -7)  # one bfloat16 rounding of a sum taken in another order
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        _close(got, want, 2 ** -6)
+
+
+def _two_kinds_of_token(cfg, n_held_tokens, n_tokens):
+    """A layer and tokens whose routing is known: the first `n_held_tokens`
+    tokens choose the top_k FIRST held experts, every other token top_k
+    experts that are not held."""
+    K, first = cfg.top_k, cfg.first_expert_held
+    lp = _layer(cfg, "linear")
+    v = jax.random.normal(jax.random.key(5), (D,))
+    v = v / jnp.linalg.norm(v)
+    router = 0.01 * np.asarray(lp["router"])
+    router[:, first:first + K] += 8.0 * np.asarray(v)[:, None]
+    elsewhere = (first + cfg.n_held) % cfg.n_experts
+    router[:, elsewhere:elsewhere + K] -= 8.0 * np.asarray(v)[:, None]
+    lp["router"] = jnp.asarray(router)
+    sign = jnp.where(jnp.arange(n_tokens) < n_held_tokens, 1.0, -1.0)
+    noise = 0.1 * jax.random.normal(jax.random.key(6), (n_tokens, D))
+    x = (sign[:, None] * v[None, :] + noise - (noise @ v)[:, None] * v[None, :])
+    return lp, x.reshape(2, n_tokens // 2, D)
+
+
+@pytest.mark.parametrize("held_tokens,compact", [(128, 1), (129, 0), (512, 0), (0, 1)],
+                         ids=["exactly_C", "C_plus_one_token", "every_pair_held", "none_held"])
+def test_a_routing_past_the_bound_runs_over_all_rows_and_loses_no_pair(held_tokens, compact):
+    """GLM's share (top-4, 8 of 64 held) over 512 tokens: C = 512 rows of
+    2,048. `held_tokens` tokens put all four pairs on held experts."""
+    cfg = _config("glm")
+    E, held, K, N = SHARES["glm"]
+    bound = moe.held_rows_bound(N * K, held, E)
+    assert bound == 512
+    lp, x = _two_kinds_of_token(cfg, held_tokens, N)
+    out, stats, grads = _value_stats_grads(cfg, x, lp)
+    assert N * K - int(stats["pairs_elsewhere"]) == held_tokens * K
+    assert int(stats["compact"]) == compact == int(held_tokens * K <= bound)
+    assert int(stats["dropped_pairs"]) == 0
+    with _without_the_compact_path():
+        want_out, want_stats, want_grads = _value_stats_grads(cfg, x, lp)
+    for key in STATS:
+        np.testing.assert_array_equal(np.asarray(stats[key]), np.asarray(want_stats[key]), key)
+    _close(out, want_out, 1e-6)
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        _close(got, want, 1e-5)
+    if held_tokens:  # the held tokens' rows are the experts' outputs, not zeros
+        assert np.abs(np.asarray(out).reshape(N, D)[:held_tokens]).min(axis=0).max() > 0
+    rest = np.asarray(out).reshape(N, D)[held_tokens:]
+    assert not rest.any()  # pairs elsewhere contribute zero
+
+
+@pytest.mark.parametrize("share,held,branches", [
+    ("laguna", 8, True), ("glm", 8, True), ("zaya", 8, False), ("glm", None, False)],
+    ids=["laguna", "glm", "half_share", "every_expert_held"])
+def test_only_a_small_share_traces_a_branch(share, held, branches):
+    """`experts_held is None` and a half share keep the program they had:
+    no `cond` anywhere in the layer, forward or backward, and no
+    `compact` statistic."""
+    cfg = dataclasses.replace(_config(share), experts_held=held,
+                              first_expert_held=0 if held is None else held)
+    lp, x = _layer(cfg, "linear"), _tokens(share)
+
+    def loss(x, lp):
+        out, stats, _ = moe.moe_ffn(x, lp, cfg)
+        return out.sum(), stats
+
+    text = str(jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))(x, lp))
+    assert ("cond[" in text) == branches
+    stats = jax.eval_shape(loss, x, lp)[1]
+    assert ("compact" in stats) == branches
+    if branches:
+        assert stats["compact"].shape == () and stats["compact"].dtype == jnp.int32
+
+
+def test_compact_path_keeps_no_array_of_all_pairs_between_forward_and_backward():
+    """What the forward hands the backward under the decoder's "dots"
+    policy: the held rows' gate and up [C, d_ff] by name and nothing of
+    N * top_k rows and model or expert width (a `cond` differentiated as
+    it stands would keep both branches' residuals, zeros in the one that
+    did not run)."""
+    from ray_tpu.models import llama
+
+    cfg = dataclasses.replace(_config("glm"), remat=True, remat_policy="dots")
+    E, held, K, N = SHARES["glm"]
+    bound = moe.held_rows_bound(N * K, held, E)
+    lp, x = _layer(cfg, "linear"), _tokens("glm")
+    block = llama._remat(lambda x, lp: moe.moe_ffn(x, lp, cfg)[0], cfg)
+    _, vjp = jax.vjp(block, x, lp)
+    kept = [leaf.shape for leaf in jax.tree.leaves(vjp) if hasattr(leaf, "shape")]
+    assert kept.count((bound, F)) == 2, kept
+    wide = [s for s in kept if len(s) >= 2 and s[0] in (N * K,) and s[-1] in (D, F)]
+    assert not wide and (N, K, D) not in kept, kept
+
+
+def test_compact_path_lowers_under_a_mesh():
+    """Under a mesh the grouped matmuls are `jax.lax.ragged_dot` and the
+    partitioner's: a train step of a small share over dp 2 x ep 2 x tp 2
+    meets the loss of the unsharded one and runs every block compact."""
+    import optax
+
+    from ray_tpu.models import llama
+    from ray_tpu.parallel.mesh import MeshSpec, make_mesh
+    from ray_tpu.parallel.sharding import default_rules, tree_shardings
+    from ray_tpu.train.step import TrainState, init_sharded_params, make_train_step
+
+    if len(jax.devices()) < 8:
+        pytest.skip("needs 8 virtual devices")
+    cfg = dataclasses.replace(moe.MOE_TINY, dtype=jnp.float32, n_experts=64, top_k=4,
+                              experts_held=8, first_expert_held=16)
+    mesh = make_mesh(MeshSpec(dp=2, ep=2, tp=2), devices=jax.devices()[:8])
+    rules = default_rules()
+    init = lambda: llama.init_params(cfg, jax.random.key(0))  # noqa: E731
+    params = init_sharded_params(init, llama.logical_axes(cfg), mesh, rules)
+    assert params["layers"]["w_gate"].shape[1] == 8 and "ep" in str(
+        params["layers"]["w_gate"].sharding.spec)
+    opt = optax.adamw(1e-3)
+    step = make_train_step(lambda p, b: llama.loss_and_weight_fn(p, b, cfg), opt,
+                           mesh=mesh, rules=rules)
+    toks = np.random.default_rng(2).integers(0, cfg.vocab_size, size=(8, 65)).astype(np.int32)
+    batch = {"tokens": jnp.asarray(toks[:, :-1]), "targets": jnp.asarray(toks[:, 1:])}
+    unsharded = float(llama.loss_fn(init(), batch, cfg))
+    batch = jax.device_put(
+        batch, tree_shardings(mesh, rules, jax.tree.map(lambda x: ("batch", "seq"), batch)))
+    _, metrics = step(TrainState.create(params, opt), batch)
+    assert abs(float(metrics["loss"]) - unsharded) < 1e-4 * unsharded
+    stats = metrics["stats"]
+    assert np.asarray(stats["compact"]).tolist() == [1] * cfg.n_layers
+    assert not np.asarray(stats["dropped_pairs"]).any()

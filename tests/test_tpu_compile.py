@@ -346,8 +346,10 @@ _OLMOE_STEP = "9cbdafe7fcffbc7f1b855fa71c37223b22ce43b219d133479411c4ab59756fe8"
 _ZAYA_STEP = "5c0e2e3ba71539f56323de421562ccae59c9377d2e3b1531e6a4a2459053d47d"
 # the same of glm-4.7-flash's step as `glm47f-train` builds it (the dense layer, four expert
 # layers and the MTP block, 8 of 64 experts and an eighth of the vocabulary held, batch 2), as
-# commit 955060c (the parent of PR 38) lowers it: CCA and MLA bypass the branch PR 38 changed
-_GLM_LITE_STEP = "e02a2611a60b45b18eece4f59b1de7c10366f389bd73caa96155b744a2aa7826"
+# PR 40 lowers it: replaced ON PURPOSE, its five expert blocks are built with the compact
+# path (8 of 64 held: a `cond` over 8,192 of 32,768 pair rows); from commit 955060c (the
+# parent of PR 38, whose branch CCA and MLA bypass) to PR 39 it was e02a2611...
+_GLM_LITE_STEP = "9ff87ef7aab4ca1e62011d66677f84dab2aee8ab9578e1f7c91d8d4309967dd1"
 
 
 @pytest.mark.parametrize("kwargs,want", [
@@ -370,7 +372,11 @@ def test_dense_train_step_lowers_to_the_text_it_had_before_the_expert_layer(v5e,
     with them, the text they had. PR 38 MEANT to alter the three steps
     that run the full-attention branch (head-major from the projections
     to `wo`) and replaced their hashes; ZAYA1's and GLM-4.7-Flash's,
-    which bypass that branch, keep the text their parents gave them."""
+    which bypass that branch, keep the text their parents gave them.
+    PR 40 MEANT to alter the steps of the SMALL shares (GLM-4.7-Flash's
+    hash replaced; Laguna's step is held by its own tests below): the
+    dense steps, OLMoE's (every expert held) and ZAYA1's (a half share:
+    no compact path is built) keep theirs."""
     import hashlib
     import re
 
@@ -501,7 +507,7 @@ def test_glm_lite_share_train_step_runs_mla_its_kernels_and_the_second_head(v5e)
     engaged = {name: after.get(name, {"count": 0})["count"]
                - before.get(name, {"count": 0})["count"]
                for name in ("mla.attn", "moe.ffn", "cca.attn", "grouped_matmul.kernel",
-                            "grouped_matmul.ragged_dot")}
+                            "grouped_matmul.ragged_dot", "moe.compact", "moe.full")}
     # the dense layer and the expert-layer kind of block, traced once for the scan and the
     # MTP block alike (the rematerialised block is one function): two sites of MLA at least
     assert engaged["mla.attn"] >= 2 and engaged["moe.ffn"] >= 1 and engaged["cca.attn"] == 0
@@ -509,8 +515,13 @@ def test_glm_lite_share_train_step_runs_mla_its_kernels_and_the_second_head(v5e)
     hlo = compiled.as_text()
     kernels = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
     grouped = sorted(re.sub(r"\.\d+$", "", k) for k in kernels if k.startswith("ragged-dot"))
-    assert grouped == (["ragged-dot-tiled"] * 6 + ["ragged-dot-tiled-dgrad"] * 6
-                       + ["ragged-dot-tiled-wgrad"] * 6), kernels
+    # 6 + 6 + 6 until PR 40: each of the two sites is now built with the compact path, the
+    # branch over the held rows with its nine, the branch over all rows with eleven (3
+    # forward, then gate and up again + 3 + 3 backward: it keeps nothing)
+    assert engaged["moe.compact"] >= 1 and engaged["moe.full"] == 0
+    assert grouped == (["ragged-dot-tiled"] * 2 * (3 + 3 + 2)
+                       + ["ragged-dot-tiled-dgrad"] * 2 * (3 + 3)
+                       + ["ragged-dot-tiled-wgrad"] * 2 * (3 + 3)), kernels
     assert "ragged-dot-none" not in hlo
     # what is no grouped matmul is flash, forward and backward at each of the three sites
     rest = [k for k in kernels if not k.startswith("ragged-dot")]
@@ -539,7 +550,11 @@ def test_laguna_share_train_step_runs_window_and_full_kernels_head_major(v5e):
     128; the held experts' nine grouped matmuls are the kernels of
     ops/grouped_matmul.py at [3072, 1024]; every scope the cell's readers
     sum is in the compiled step; q, k, v and o meet no transpose and no
-    copy at the kernel's door; no site falls back."""
+    copy at the kernel's door; no site falls back. Since PR 40 the expert
+    block is built with the compact path (`moe.compact`): a `cond` whose
+    one branch runs the nine kernels over the 2,560 held rows and whose
+    other, the same block over all 40,960, runs eleven (its backward
+    keeps nothing and runs gate and up again)."""
     from ray_tpu import obs
 
     step, state, batch = _train_step_at_mistral_widths(
@@ -551,15 +566,19 @@ def test_laguna_share_train_step_runs_window_and_full_kernels_head_major(v5e):
     engaged = {name: after.get(name, {"count": 0})["count"]
                - before.get(name, {"count": 0})["count"]
                for name in ("laguna.attn", "moe.ffn", "grouped_matmul.kernel",
-                            "grouped_matmul.ragged_dot", "tp_overlap.plain")}
+                            "grouped_matmul.ragged_dot", "tp_overlap.plain", "moe.compact",
+                            "moe.full")}
     assert engaged["laguna.attn"] >= 2 and engaged["moe.ffn"] >= 1
+    assert engaged["moe.compact"] >= 1 and engaged["moe.full"] == 0
     assert engaged["grouped_matmul.kernel"] > 0
     assert engaged["grouped_matmul.ragged_dot"] == engaged["tp_overlap.plain"] == 0  # fallback_sites
     hlo = compiled.as_text()
     kernels = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
     grouped = sorted(re.sub(r"\.\d+$", "", k) for k in kernels if k.startswith("ragged-dot"))
-    assert grouped == (["ragged-dot-tiled"] * 3 + ["ragged-dot-tiled-dgrad"] * 3
-                       + ["ragged-dot-tiled-wgrad"] * 3), kernels
+    # 3 + 3 + 3 until PR 40: now the branch over the held rows has those nine and the
+    # branch over all rows 3 forward, then gate and up again + 3 + 3 backward
+    assert grouped == (["ragged-dot-tiled"] * (3 + 3 + 2) + ["ragged-dot-tiled-dgrad"] * (3 + 3)
+                       + ["ragged-dot-tiled-wgrad"] * (3 + 3)), kernels
     assert "ragged-dot-none" not in hlo
     # what is no grouped matmul is flash: forward and backward of each kind, by its scope
     rest = sorted(re.sub(r"\.\d+$", "", k) for k in kernels if not k.startswith("ragged-dot"))
@@ -582,6 +601,72 @@ def test_laguna_share_train_step_runs_window_and_full_kernels_head_major(v5e):
         if re.search(r"\[1,(?:72|48|8),4096,128\]|\[1,4096,(?:72|48|8),128\]", shape)]
     assert not moved, moved
     assert set(re.findall(r"bf16\[1,(?:72|48|8),4096,128\]\{([\d,]+)", hlo)) == {"3,2,1,0"}
+
+
+def _called_from(computations: dict, name: str, seen=None) -> set:
+    """The computations `name` runs: itself, its fusions, loops, branches."""
+    seen = set() if seen is None else seen
+    if name in seen or name not in computations:
+        return seen
+    seen.add(name)
+    body = computations[name]
+    called = re.findall(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", body)
+    for group in re.findall(r"branch_computations=\{([^}]*)\}", body):
+        called += re.findall(r"%?([\w.\-]+)", group)
+    for callee in called:
+        _called_from(computations, callee, seen)
+    return seen
+
+
+def test_laguna_share_train_step_sizes_the_expert_layer_by_the_held_rows(v5e):
+    """The step of `laguna-train` as the cell builds it (the dense layer +
+    one period of four, 8 of 256 experts held, 1 x 4096), compiled for
+    the described chip (PR 40). Each of the four expert blocks branches
+    once forward and once backward (the forward's branch is not run again
+    to differentiate it); the branch over the held rows holds NO array of
+    the 40,960 pair rows at model or expert width ([40960, 3072],
+    [40960, 1024], [4096, 10 or 16, 3072]) and runs the block's nine
+    kernels over 2,560 rows; the other branch is today's block, whole;
+    every site is built compact and none falls back to `ragged_dot`; and
+    the step takes no more memory than its parent's 9.06 GiB of
+    arguments + 4.00 of temporaries (3.88: the branch over all rows keeps
+    its temporaries, the kept gate / up are [2560, 1024] a block)."""
+    from ray_tpu import obs
+
+    step, state, batch = _train_step_at_mistral_widths(
+        v5e, batch=1, model="laguna-s-2.1", n_layers=5, vocab_size=12544, experts_held=8)
+    before = obs.layer_counters()
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        compiled = step.lower(state, batch).compile()
+    after = obs.layer_counters()
+    engaged = {name: after.get(name, {"count": 0})["count"]
+               - before.get(name, {"count": 0})["count"]
+               for name in ("moe.compact", "moe.full", "grouped_matmul.kernel",
+                            "grouped_matmul.ragged_dot")}
+    assert engaged["moe.compact"] >= 4 and engaged["moe.full"] == 0
+    assert engaged["grouped_matmul.kernel"] > 0 and engaged["grouped_matmul.ragged_dot"] == 0
+    hlo = compiled.as_text()
+    computations = dict(re.findall(r"^%?([\w.\-]+) \([^\n]*\{\n(.*?)^\}", hlo, re.M | re.S))
+    branches = re.findall(
+        r" conditional\([^\n]*branch_computations=\{%?([\w.\-]+), %?([\w.\-]+)\}", hlo)
+    assert len(branches) == 2 * 4, branches
+    wide = re.compile(r"(?:bf16|f32)\[(?:40960,(?:3072|1024)|4096,1[06],3072)\]")
+    ran = []
+    for over_all_rows, over_held_rows in branches:  # `cond`: index 0 is the false branch
+        held = "\n".join(computations[c] for c in _called_from(computations, over_held_rows))
+        every = "\n".join(computations[c] for c in _called_from(computations, over_all_rows))
+        assert "moe.held" in held and "moe.all" not in held
+        assert "moe.all" in every and "moe.held" not in every
+        assert not wide.search(held), sorted(set(wide.findall(held)))
+        assert wide.search(every)
+        assert re.search(r"bf16\[2560,1024\]", held) and re.search(r"bf16\[2560,3072\]", held)
+        ran.append(tuple(len(re.findall(r"%(ragged-dot-tiled[\w\-]*)\.\d+ = ", text))
+                         for text in (held, every)))
+    # forward and backward: nine kernels over the held rows, eleven over all rows
+    assert sorted(ran) == [(3, 3)] * 4 + [(6, 8)] * 4, ran
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < 9.07 * 2 ** 30
+    assert memory.temp_size_in_bytes < 4.00 * 2 ** 30
 
 
 @pytest.mark.parametrize("cell,kwargs,temp_gib,tiles_at_16", [
