@@ -1,0 +1,116 @@
+"""The language model of Keye-VL-2.0-30B-A3B of the program's registry as
+ONE CHIP'S SHARE of a stated deployment: depth cut (every layer is
+alike), `num_experts` of the published experts held (from
+`deployment.first_expert_held`), `vocab_size` rows of the embedding and
+columns of the head held, and nothing else changed. Every width in the
+configuration file (the indexer's `sa_config` among them) must equal the
+registry entry's, and the registry entry must be at the file's
+`published` counts, or the run fails.
+
+The weights are what `llama.init_params` gives a key, the router's
+SELECTION BIASES (b of `top-k(p + b)`, which take no gradient and which
+no step moves) zero among them: `params["layers"]["router_bias"]`
+[layers, experts]. The indexer's weights take no gradient either (the
+auxiliary loss a deployment trains them by is not implemented): they
+stay what the init gave them.
+
+`balanced_bias` makes the table that the cell's runner puts in that
+parameter's place before the first step: the bias under which every
+expert of a layer sees as many of the run's own tokens as the next, the
+state the balancing of arXiv:2408.15664 holds a deployment in: this chip
+then holds an eighth of every layer's pairs. It is the rule of
+model_builders/registry_laguna.py (GLM's and ZAYA1's before it), ONE
+fixed rule with no option, at THIS router's constants; it is not
+imported from those builders because each reads its own module's
+constants (Laguna's also `cfg.n_expert_layers`, which a stack of layers
+that are all alike does not have): the sign rule (b_e up by a step where
+expert e saw fewer pairs than the mean, down where more), PASSES forward
+passes of the program's own loss function over fresh batches of the
+run's traffic, all layers at once, the step falling geometrically from
+STEP_FIRST to STEP_LAST; the last AVERAGED passes' tables are averaged.
+Its one program takes the weights, the table and the batch as
+ARGUMENTS, so it is compiled once for all seeds."""
+
+from __future__ import annotations
+
+import dataclasses
+
+# The rule's constants, fixed here and read from no file. A fresh
+# router's logits have unit variance, so a token's 128 probabilities are
+# exp(N(0, 1)) / 211: their mean 1/128 = 0.0078, the eighth largest about
+# 0.0219 (1.53 deviations up) and the eighth and ninth 1.4e-3 apart
+# (0.063 deviations: 1 / (128 x the normal density there)). The steps are
+# Laguna's and GLM's multiples of that gap, 2.5 and 0.075, and the table
+# can travel 0.05 in its 48 passes, as far as the chosen probabilities
+# spread.
+PASSES, AVERAGED = 48, 16
+STEP_FIRST, STEP_LAST = 3.5e-3, 1e-4
+FIRST_BATCH = 1 << 20  # the passes' batches: far from the steps' own (0, 1, 2, ...)
+
+# configuration-file key -> KeyeConfig attribute: what no cut may touch
+WIDTHS = {"hidden_size": "d_model", "head_dim": "head_dim", "num_attention_heads": "n_heads",
+          "num_key_value_heads": "n_kv_heads", "moe_intermediate_size": "d_ff",
+          "num_experts_per_tok": "top_k", "norm_topk_prob": "norm_topk_prob",
+          "rms_norm_eps": "rms_eps", "rope_theta": "rope_theta",
+          "max_position_embeddings": "max_seq", "tie_word_embeddings": "tie_embeddings",
+          "num_local_experts": "n_experts"}
+# `sa_config`'s key -> attribute
+INDEXER = {"indexer_num_heads": "indexer_heads", "indexer_head_dim": "indexer_head_dim",
+           "topk": "indexer_topk", "q_chunk_size": "index_chunk"}
+# configuration-file key -> attribute: what the share cuts, held to `published`
+COUNTS = {"num_hidden_layers": "n_layers", "num_experts": "n_experts", "vocab_size": "vocab_size"}
+
+
+def build(config: dict, **overrides):
+    """-> (KeyeConfig of the share, init(key) -> params, logical_axes tree)."""
+    from ray_tpu.models import llama
+    from ray_tpu.models.registry import get_model_config
+
+    full = get_model_config(config["registry_model"])
+    sa = config["sa_config"]
+    file_side = {**{k: config[k] for k in WIDTHS}, **config["published"],
+                 **{f"sa_config.{k}": sa[k] for k in INDEXER}}
+    program_side = {**{k: getattr(full, a) for k, a in {**WIDTHS, **COUNTS}.items()},
+                    **{f"sa_config.{k}": getattr(full, a) for k, a in INDEXER.items()}}
+    wrong = {k: (v, program_side[k]) for k, v in file_side.items() if v != program_side[k]}
+    unrun = {k: config[k] for k in ("attention_bias", "use_sliding_window", "sliding_window",
+                                    "mlp_only_layers") if config[k]}
+    if (wrong or unrun or full.router_score != "softmax" or not full.selection_bias
+            or sa["indexer_num_kv_heads"] != 1 or config["decoder_sparse_step"] != 1):
+        raise RuntimeError(f"{config['registry_model']} is not at the file's sizes "
+                           f"(file, program): {wrong}; not run: {unrun}")
+    cfg = dataclasses.replace(
+        full, n_layers=config["num_hidden_layers"], vocab_size=config["vocab_size"],
+        experts_held=config["num_experts"],
+        first_expert_held=config["deployment"]["first_expert_held"], **overrides)
+
+    def init(key):
+        return llama.init_params(cfg, key)
+
+    return cfg, init, llama.logical_axes(cfg)
+
+
+def balanced_bias(cfg, params, make):
+    """-> the selection biases, float32 [layers, experts], under which
+    `params` (the share `cfg`, as `build` gives them) route equal
+    numbers of the pairs of `make(i)` (the run's batches) to every
+    expert of a layer."""
+    import jax
+    import numpy as np
+
+    from ray_tpu.models import llama
+
+    @jax.jit
+    def counts(params, bias, batch):
+        layers = {**params["layers"], "router_bias": bias.astype(cfg.param_dtype)}
+        stats = llama.loss_and_weight_fn({**params, "layers": layers}, batch, cfg)[2]
+        return stats["tokens_per_expert"]
+
+    bias = np.zeros((cfg.n_layers, cfg.n_experts), np.float32)
+    kept = []
+    for i in range(PASSES):
+        seen = np.asarray(counts(params, bias, make(FIRST_BATCH + i)), np.float64)
+        step = STEP_FIRST * (STEP_LAST / STEP_FIRST) ** (i / (PASSES - 1))
+        bias = bias + np.float32(step) * np.sign(seen.mean(-1, keepdims=True) - seen)
+        kept.append(bias)
+    return np.mean(kept[-AVERAGED:], axis=0, dtype=np.float32)

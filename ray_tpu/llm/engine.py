@@ -240,8 +240,9 @@ class EngineConfig:
                 "is not implemented (training-side MoE lives in models/moe.py; "
                 "Mixtral, OLMoE, ZAYA1, whose compressed convolutional attention "
                 "has no cache here either, GLM-4.7-Flash, whose latent attention "
-                "would be served in its absorbed form, and Laguna, whose sliding-window "
-                "layers want a cache sized by layer type, are training-only)"
+                "would be served in its absorbed form, Laguna, whose sliding-window "
+                "layers want a cache sized by layer type, and Keye, whose indexer wants a "
+                "cache of its own keys and a selection in the ragged kernel, are training-only)"
             )
         # a prefill bucket longer than the context window can never be
         # used; clamping keeps bucket compilation bounded by the model

@@ -171,6 +171,17 @@ def _laguna(config: LlamaConfig):
     return laguna
 
 
+def _dsa(config: LlamaConfig):
+    """models/dsa.py when the configuration's attention runs over the keys
+    a learned indexer selects (a `KeyeConfig`), else None. No other
+    configuration loads that module."""
+    if not hasattr(config, "indexer_topk"):
+        return None
+    from ray_tpu.models import dsa
+
+    return dsa
+
+
 def _carries_router_state(config: LlamaConfig) -> bool:
     """An MLP router adds the previous layer's state to its own: the
     layer scan then carries (hidden state, router state)."""
@@ -192,10 +203,10 @@ def logical_axes(config: LlamaConfig) -> Params:
         "wo": ("layers", "heads", "embed"),
         "ln2": ("layers", "norm"),
     }
-    moe, cca = _moe(config), _cca(config)
-    if cca is not None or mla is not None:
+    moe, cca, dsa = _moe(config), _cca(config), _dsa(config)
+    if cca is not None or mla is not None or dsa is not None:
         layer = {"ln1": layer["ln1"], "ln2": layer["ln2"],
-                 **(cca or mla).attention_axes()}
+                 **(cca or mla or dsa).attention_axes()}
     if moe is None:
         layer.update(
             w_gate=("layers", "embed", "mlp"),
@@ -244,9 +255,9 @@ def init_params(config: LlamaConfig, key: jax.Array) -> Params:
         if c.qk_norm:
             ffn["q_norm"] = jnp.ones((L, c.n_heads * hd), c.param_dtype)
             ffn["k_norm"] = jnp.ones((L, c.n_kv_heads * hd), c.param_dtype)
-    cca = _cca(c)
-    if cca is not None or mla is not None:
-        attn = (cca or mla).attention_params(c, keys[1])
+    cca, dsa = _cca(c), _dsa(c)
+    if cca is not None or mla is not None or dsa is not None:
+        attn = (cca or mla or dsa).attention_params(c, keys[1])
     else:
         attn = {
             "wq": dense(keys[1], (c.d_model, c.n_heads * hd)),
@@ -313,14 +324,17 @@ def _block(
     rotary, and the q/k RMSNorm when the configuration has it, q, k and
     v head-major from the projections to `wo`: the module's layout
     paragraph; or compressed convolutional attention, models/cca.py; or
-    multi-head latent attention, models/mla.py), then the dense SwiGLU or the
+    multi-head latent attention, models/mla.py; or attention over the keys a
+    learned indexer selects, models/dsa.py, whose two counts join the
+    layer's statistics), then the dense SwiGLU or the
     expert layer (models/moe.py, whose statistics come back; None for a
     dense layer) by the configuration's own kind; `dense_ffn`: one of an
     expert configuration's leading dense layers. (A configuration whose
     layers differ in kind within the stack, models/laguna.py, has a
     block of its own beside this one.)"""
     c = config
-    moe, cca, mla = None if dense_ffn else _moe(c), _cca(c), _mla(c)
+    moe, cca, mla, dsa = None if dense_ffn else _moe(c), _cca(c), _mla(c), _dsa(c)
+    selected = {}
     carries_router = _carries_router_state(c)
     h, router_state = carry if carries_router else (carry, None)
     B, S, D = h.shape
@@ -332,7 +346,7 @@ def _block(
     # the full attention's and the dense MLP's: CCA's and the expert
     # layer's matmuls are the partitioner's to place.)
     mesh = current_mesh()
-    overlap = (cca is None and mla is None and mesh is not None
+    overlap = (cca is None and mla is None and dsa is None and mesh is not None
                and mesh.shape.get("tp", 1) > 1)
     if overlap:
         from ray_tpu.parallel.tp_overlap import ag_matmul, rs_matmul
@@ -343,6 +357,9 @@ def _block(
         h = h + cca.cca_sublayer(x, lp, c, positions=positions, segment_ids=segment_ids)
     elif mla is not None:
         h = h + mla.mla_sublayer(x, lp, c, positions=positions, segment_ids=segment_ids)
+    elif dsa is not None:
+        y, selected = dsa.dsa_sublayer(x, lp, c, positions=positions, segment_ids=segment_ids)
+        h = h + y
     else:
         H, dt = c.n_heads, x.dtype
         with jax.named_scope("attn.qkv"):
@@ -381,6 +398,7 @@ def _block(
         # the rings of tp_overlap.py are the dense MLP's: under tp > 1
         # the expert layer's matmuls are the partitioner's to place
         y, stats, router_state = moe.moe_ffn(x, lp, c, router_state)
+        stats = {**stats, **selected}
         return ((h + y, router_state) if carries_router else h + y), stats
     with jax.named_scope("dense.ffn"):
         if not overlap:
@@ -424,7 +442,10 @@ def _remat(block, c: LlamaConfig):
                 # grouped matmuls (models/moe.py), which are no
                 # dot_general either
                 jax.checkpoint_policies.save_only_these_names(
-                    "attn_out", "attn_lse", "tp_rs_out", "moe_gate", "moe_up"
+                    "attn_out", "attn_lse", "tp_rs_out", "moe_gate", "moe_up",
+                    # dsa_sel: the packed selection of models/dsa.py, which the
+                    # backward's kernels read and nothing should compute twice
+                    "dsa_sel",
                 ),
             ),
         )
@@ -473,8 +494,9 @@ def _trunk(
                                   segment_ids=segment_ids), None)
     mla = _mla(c)
     cos = sin = None
-    # CCA rotates part of a head and MLA its decoupled part, from the positions themselves
-    if _cca(c) is None and mla is None:
+    # CCA rotates part of a head, MLA its decoupled part and DSA two head sizes, from
+    # the positions themselves
+    if _cca(c) is None and mla is None and _dsa(c) is None:
         with jax.named_scope("attn.rope"):
             cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
 

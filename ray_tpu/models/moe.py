@@ -1,4 +1,4 @@
-"""Sparse expert feed-forward layer (Mixtral, OLMoE, ZAYA1, GLM-4.7-Flash, Laguna) for the one decoder.
+"""Sparse expert feed-forward layer (Mixtral, OLMoE, ZAYA1, GLM-4.7-Flash, Laguna, Keye) for the one decoder.
 
 The reference has NO expert parallelism (SURVEY.md §2.4 — absent from
 python/ray/llm); this is a native capability. This module holds what an
@@ -71,7 +71,10 @@ And two that GLM-4.7-Flash (models/mla.py) asks, in the DeepSeek-V3 form
 Laguna (models/laguna.py) asks the same of a SOFTMAX router: top-10 of
 256 probabilities chosen by probability + the layer's `router_bias`
 (where the layer's parameters carry one), renormalised over the chosen
-and multiplied by `routed_scaling`, beside a shared expert.
+and multiplied by `routed_scaling`, beside a shared expert. Keye-VL-2.0's
+language model (models/dsa.py) asks it of top-8 of 128 with no shared
+expert and no scaling, the bias a leaf of the layer's own parameters
+(`selection_bias`).
 """
 
 from __future__ import annotations
@@ -113,12 +116,21 @@ class MoEConfig(llama.LlamaConfig):
     # "softmax" over all the experts' logits, or "sigmoid" of each by
     # itself, chosen by score + `router_bias` (GLM-4.7-Flash; linear router)
     router_score: str = "softmax"
+    # a softmax linear router's layer carries a selection bias too: chosen by
+    # probability + `router_bias`, weighted by the probability alone (Keye, models/dsa.py)
+    selection_bias: bool = False
     routed_scaling: float = 1.0  # on the routed experts' weights, either score
     shared_d_ff: int = 0         # width of the shared expert (0: none)
     # this chip's share: experts first_expert_held .. + experts_held of
     # n_experts are in the parameters (None: all of them)
     experts_held: Optional[int] = None
     first_expert_held: int = 0
+
+    @property
+    def linear_router_bias(self) -> bool:
+        """Whether a LINEAR router's layer carries `router_bias` (an MLP
+        router's always does)."""
+        return self.router_score == "sigmoid" or self.selection_bias
 
     @property
     def n_held(self) -> int:
@@ -136,7 +148,7 @@ class MoEConfig(llama.LlamaConfig):
     def num_params(self) -> int:
         d, f, E, r = self.d_model, self.d_ff, self.n_experts, self.router_hidden
         router = d * E if self.router_kind == "linear" else d * r + 2 * r * r + r * E + 2 * r + E
-        if self.router_score == "sigmoid":
+        if self.linear_router_bias:
             router += E  # the selection bias
         # the experts held here + the shared expert + router
         ffn = self.n_held * 3 * d * f + 3 * d * self.shared_d_ff + router
@@ -173,7 +185,7 @@ def expert_axes(config: Optional[MoEConfig] = None) -> Params:
         axes.update(shared_gate=("layers", "embed", "mlp"), shared_up=("layers", "embed", "mlp"),
                     shared_down=("layers", "mlp", "embed"))
     if config is None or config.router_kind == "linear":
-        if config is not None and config.router_score == "sigmoid":
+        if config is not None and config.linear_router_bias:
             axes["router_bias"] = ("layers", "expert")
         return {"router": ("layers", "embed", "expert"), **axes}
     return {
@@ -206,7 +218,7 @@ def expert_params(config: MoEConfig, key: jax.Array) -> Params:
 
     if c.router_kind == "linear":
         router = {"router": per_layer(keys[0], (c.d_model, E))}
-        if c.router_score == "sigmoid":
+        if c.linear_router_bias:
             router["router_bias"] = jnp.zeros((L, E), c.param_dtype)
     else:
         r = c.router_hidden

@@ -19,47 +19,61 @@ attention layers of 72 and 48 heads of an explicit 128 in one stack
 (models/laguna.py), a headwise output gate, a rotary by layer type (yarn
 on half a head in the full layers), a leading dense layer, softmax
 top-10 of 256 experts chosen with a selection bias, weights renormalised
-and scaled, and a shared expert. Every expert
+and scaled, and a shared expert; the language model of
+Keye-VL-2.0-30B-A3B, `keye-vl-2.0-30b-a3b`, trained, not served: GQA 32 /
+4 at heads of an explicit 128 with an RMSNorm a head on q and k, over the
+2048 keys a query that a learned indexer of 16 heads of 64 selects
+(models/dsa.py, loaded only when one of its names is asked for), softmax
+top-8 of 128 experts chosen with a selection bias and renormalised; its
+vision tower and image or video inputs are refused by name. Every expert
 configuration is TRAINING only: the serving engine refuses a MoEConfig,
-ZAYA1, GLM-4.7-Flash and Laguna by name).
+ZAYA1, GLM-4.7-Flash, Laguna and Keye by name).
 The registry gives users the same two entry points they expect:
 
   * `get_model_config("llama3-8b")` — named presets;
   * `config_from_hf(json.load(open("config.json")))` — map a HF
     transformers config dict onto LlamaConfig/MoEConfig/ZayaConfig/
-    GlmLiteConfig/LagunaConfig (no downloads; weight conversion is a
-    separate concern).
+    GlmLiteConfig/LagunaConfig/KeyeConfig (no downloads; weight conversion
+    is a separate concern).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import importlib
 from typing import Any
 
 from ray_tpu.models import cca, laguna, llama, mla, moe
 
 _REGISTRY: dict[str, Any] = {}
+# name -> (module, attribute): presets of a module that only their own users load
+_ON_DEMAND = {"keye-vl-2.0-30b-a3b": ("ray_tpu.models.dsa", "KEYE_VL_2_30B_A3B"),
+              "keye-tiny": ("ray_tpu.models.dsa", "KEYE_TINY")}
 
 
 def register_model(name: str, config) -> None:
     key = name.lower()
-    if key in _REGISTRY:
+    if key in _REGISTRY or key in _ON_DEMAND:
         raise ValueError(f"model {name!r} already registered")
     _REGISTRY[key] = config
 
 
 def get_model_config(name: str):
     """Named preset lookup (case-insensitive); returns a frozen config."""
+    key = name.lower()
+    if key in _ON_DEMAND:
+        module, attribute = _ON_DEMAND[key]
+        return getattr(importlib.import_module(module), attribute)
     try:
-        return _REGISTRY[name.lower()]
+        return _REGISTRY[key]
     except KeyError:
         raise KeyError(
-            f"unknown model {name!r}; registered: {sorted(_REGISTRY)}"
+            f"unknown model {name!r}; registered: {list_models()}"
         ) from None
 
 
 def list_models() -> list[str]:
-    return sorted(_REGISTRY)
+    return sorted([*_REGISTRY, *_ON_DEMAND])
 
 
 # -- presets (architecture hyperparameters from the public model cards) ------
@@ -265,9 +279,59 @@ def _laguna_from_hf(hf: dict, **overrides) -> laguna.LagunaConfig:
     return config
 
 
+def _keye_from_hf(hf: dict, **overrides):
+    """`model_type` "KeyeVL2" (Kwai-Keye/Keye-VL-2.0-30B-A3B): the LANGUAGE
+    model's keys, at the top level or under `text_config`: GQA at an
+    explicit `head_dim` over the keys `sa_config`'s indexer selects, every
+    layer routed experts (softmax top-k, renormalised, no shared one).
+    What this decoder does not implement is refused by name: the vision
+    tower and image or video inputs (`mrope_section` is accepted for TEXT,
+    whose three position streams are equal, so that the sectioned rotary
+    is the plain one; position streams that differ are not run)."""
+    from ray_tpu.models import dsa
+
+    vision = {k: hf[k] for k in ("vision_config", "image_token_id", "video_token_id",
+                                 "vision_start_token_id") if hf.get(k) is not None}
+    hf = {**hf, **hf.get("text_config", {})}
+    sa = hf.get("sa_config") or {}
+    scaling = hf.get("rope_scaling") or {}
+    refused = {
+        "a vision tower or image / video inputs (" + ", ".join(vision) + "): position streams "
+        "that differ and a tower before the decoder are not implemented": bool(vision),
+        "no sa_config (the indexer's sizes)": not sa,
+        f"indexer_num_kv_heads {sa.get('indexer_num_kv_heads')} (one shared key is implemented)":
+            sa.get("indexer_num_kv_heads", 1) != 1,
+        f"rope_scaling type {scaling.get('rope_type', scaling.get('type'))!r}":
+            scaling.get("rope_type", scaling.get("type", "default")) != "default",
+        "a sliding window": bool(hf.get("use_sliding_window")) or hf.get("sliding_window") is not None,
+        "attention_bias": bool(hf.get("attention_bias")),
+        f"decoder_sparse_step {hf.get('decoder_sparse_step')}": hf.get("decoder_sparse_step", 1) != 1,
+        f"mlp_only_layers {hf.get('mlp_only_layers')}": bool(hf.get("mlp_only_layers")),
+        f"hidden_act {hf.get('hidden_act')!r}": hf.get("hidden_act", "silu") != "silu",
+        "a shared expert": bool(hf.get("shared_expert_intermediate_size")),
+    }
+    if any(refused.values()):
+        raise ValueError("a KeyeVL2 config with "
+                         + ", ".join(k for k, v in refused.items() if v) + " is not supported")
+    fields = dict(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"],
+        n_layers=hf["num_hidden_layers"], n_heads=hf["num_attention_heads"],
+        n_kv_heads=hf["num_key_value_heads"], d_ff=hf["moe_intermediate_size"],
+        max_seq=hf["max_position_embeddings"], rope_theta=float(hf["rope_theta"]),
+        rms_eps=float(hf["rms_norm_eps"]),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        n_experts=hf["num_experts"], top_k=hf["num_experts_per_tok"],
+        norm_topk_prob=bool(hf["norm_topk_prob"]), head_dim=hf["head_dim"],
+        indexer_heads=sa["indexer_num_heads"], indexer_head_dim=sa["indexer_head_dim"],
+        indexer_topk=sa["topk"], index_chunk=sa.get("q_chunk_size", 512),
+    )
+    fields.update(overrides)  # caller wins on collisions
+    return dataclasses.replace(dsa.KEYE_VL_2_30B_A3B, **fields)
+
+
 def config_from_hf(hf: dict, **overrides):
     """Map a HF `config.json` dict to a LlamaConfig/MoEConfig/ZayaConfig/
-    GlmLiteConfig/LagunaConfig.
+    GlmLiteConfig/LagunaConfig/KeyeConfig.
 
     Only architecture hyperparameters travel; framework knobs
     (dtype/remat/attention_impl) keep their TPU defaults unless
@@ -281,8 +345,10 @@ def config_from_hf(hf: dict, **overrides):
     heads are not hidden_size / heads wide): see `_glm_lite_from_hf`.
     Laguna (`model_type` "laguna": `rope_parameters` by layer type, yarn
     among them, an explicit `head_dim`, head counts by layer): see
-    `_laguna_from_hf`; for every OTHER family a `rope_scaling` and an
-    explicit `head_dim` that is not hidden_size / heads stay refused.
+    `_laguna_from_hf`. The language model of Keye-VL-2.0 (`model_type`
+    "KeyeVL2"): see `_keye_from_hf`. For every OTHER family a
+    `rope_scaling` and an explicit `head_dim` that is not hidden_size /
+    heads stay refused.
     """
     if hf.get("model_type") == "zaya":
         return _zaya_from_hf(hf, **overrides)
@@ -290,6 +356,8 @@ def config_from_hf(hf: dict, **overrides):
         return _glm_lite_from_hf(hf, **overrides)
     if hf.get("model_type") == "laguna":
         return _laguna_from_hf(hf, **overrides)
+    if hf.get("model_type") == "KeyeVL2":
+        return _keye_from_hf(hf, **overrides)
     archs = set(hf.get("architectures", ()))
     is_olmoe = "OlmoeForCausalLM" in archs or (not archs and hf.get("model_type") == "olmoe")
     # the num_local_experts heuristic only applies to config dicts with NO

@@ -37,10 +37,13 @@ def xla_attention(
     q_offset: int | jax.Array = 0,
     softmax_scale: Optional[float] = None,
     window: Optional[int] = None,
+    selection: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Reference GQA attention. fp32 softmax, bf16 matmuls. `window`: a
     row sees the `window` keys up to and including its own (sliding-window
-    attention; a causal mask's)."""
+    attention; a causal mask's). `selection`: which keys each row sees,
+    the same for every head, packed as the flash kernels take it
+    (ops/flash.py::pack_selection); ANDed with the other masks."""
     B, Sq, H, D = q.shape
     _, Sk, K, _ = k.shape
     group = _repeat_kv_heads(q, k)
@@ -64,6 +67,11 @@ def xla_attention(
         seg = segment_ids[:, :, None] == segment_ids[:, None, :]  # [B, Sq, Sk]
         seg = seg[:, None, None, :, :]
         mask = seg if mask is None else jnp.logical_and(mask, seg)
+    if selection is not None:
+        from ray_tpu.ops.flash import unpack_selection
+
+        sel = unpack_selection(selection, Sk)[:, None, None, :, :]
+        mask = sel if mask is None else jnp.logical_and(mask, sel)
     if mask is not None:
         scores = jnp.where(mask, scores, NEG_INF)
 
@@ -97,6 +105,8 @@ def _flash_over_mesh(q, k, v, segment_ids, *, head_axis: int = 2, **kw) -> jax.A
     )
     if all(mesh.shape[a] == 1 for a in auto):
         return flash_attention(q, k, v, segment_ids=segment_ids, **kw)
+    if kw.get("selection") is not None:
+        raise NotImplementedError("a selection of keys under a multi-device mesh")
     rules = current_rules()
     qspec, kvspec = (
         rules.spec(tuple(heads if i == head_axis else axis
@@ -179,20 +189,24 @@ def attention_head_major(
     segment_ids: Optional[jax.Array] = None,
     impl: str = "xla",
     window: Optional[int] = None,
+    selection: Optional[jax.Array] = None,
 ) -> jax.Array:
     """`attention` for a caller whose heads are a major dimension, the
     tile (S, D): -> [B, H, S, D]. That is the flash kernels' own layout,
     so `impl="flash"` reaches them with no transpose on the way in or
     out; every other `impl` is `attention` between its transposes.
     `window` (None: every key before the row): sliding-window attention,
-    which the flash kernels and the XLA composite implement."""
-    if window is not None and impl not in ("flash", "xla"):
-        raise ValueError(f"attention impl {impl!r} has no sliding window")
+    which the flash kernels and the XLA composite implement; so with
+    `selection` (None: none), the packed mask of a learned indexer
+    (models/dsa.py)."""
+    if (window is not None or selection is not None) and impl not in ("flash", "xla"):
+        raise ValueError(f"attention impl {impl!r} has no sliding window and no selection")
     if impl == "flash":
-        return _flash_over_mesh(q, k, v, segment_ids, head_axis=1, causal=causal, window=window)
-    if window is not None:
+        return _flash_over_mesh(q, k, v, segment_ids, head_axis=1, causal=causal,
+                                window=window, selection=selection)
+    if window is not None or selection is not None:
         o = xla_attention(*(jnp.swapaxes(x, 1, 2) for x in (q, k, v)), causal=causal,
-                          segment_ids=segment_ids, window=window)
+                          segment_ids=segment_ids, window=window, selection=selection)
         return jnp.swapaxes(o, 1, 2)
     o = attention(*(jnp.swapaxes(x, 1, 2) for x in (q, k, v)), causal=causal,
                   segment_ids=segment_ids, impl=impl)

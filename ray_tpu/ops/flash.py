@@ -30,6 +30,16 @@ flash/ragged lineage to this framework. Design:
    standard flash-2 recomputation from the stored log-sum-exp;
  * segment ids (packed sequences) and right-padding are handled by
    masking; fully-masked rows produce zeros (matching xla_attention);
+ * a SELECTION (`selection=`, None = none: the kernels as they were) is
+   the one mask that is data, not a function of positions: which keys
+   each query sees, the same for every head, computed by the model
+   (models/dsa.py: a learned indexer's top-k). It comes in packed, one
+   bit a (query, key) pair (`pack_selection`: 8 MiB at 8192 x 8192), in
+   the order the kernels read it: an int32 word a (query, kv block,
+   lane), whose bit b is key 128 b + lane of the block, so a sub-tile of
+   512 keys is four shifts of one [rows, 128] tile. It is ANDed into
+   the mask of every sub-tile the causal walk visits, forward and
+   backward, where it is a constant; no sub-tile is skipped for it;
  * off-TPU the same kernels run under the Pallas interpreter, so CPU
    tests exercise the real code path.
 
@@ -238,14 +248,15 @@ def _tile_start(t, Tk):
 
 
 def _block_mask(i, k_base, F, Bq, Tk, *, causal, q_offset, sq_valid, sk_valid,
-                kpad, qpad, qseg_ref, kseg, window=None):
+                kpad, qpad, qseg_ref, kseg, window=None, sel=None):
     """[F*Bq, Tk] validity mask for q-block i vs kv positions starting at
     k_base, or None.
 
     Every term depends only on the position WITHIN the q block, so with
     head folding the folded tile reuses one [Bq, Tk] mask broadcast
     across the F stacked heads. Terms are STATICALLY gated; `kseg` is
-    None without segments.
+    None without segments, `sel` (bool [Bq, Tk], `_sel_tile`) without a
+    selection.
     """
     mask = None
     if causal or kpad:
@@ -268,6 +279,8 @@ def _block_mask(i, k_base, F, Bq, Tk, *, causal, q_offset, sq_valid, sk_valid,
     if kseg is not None:
         sm = qseg_ref[0] == kseg  # [Bq,1] == [1,Tk]
         mask = sm if mask is None else mask & sm
+    if sel is not None:
+        mask = sel if mask is None else mask & sel
     if mask is None or F == 1:
         return mask
     return jnp.broadcast_to(mask[None], (F, Bq, Tk)).reshape(F * Bq, Tk)
@@ -279,6 +292,75 @@ def _lanes(x, n):
     if reps > 1:
         x = pltpu.repeat(x, reps, axis=1)
     return x if x.shape[1] == n else x[:, :n]
+
+
+# ---------------------------------------------------------------------------
+# a selection of keys, packed
+# ---------------------------------------------------------------------------
+
+
+def selection_block(Sk: int) -> int:
+    """The kv block a packed selection over `Sk` keys is laid out by: the
+    kernels' default (the whole padded sequence up to MAX_BLOCK_K)."""
+    return min(MAX_BLOCK_K, _round_up(Sk, 16))
+
+
+def _selection_layout(Sk: int) -> tuple[int, int, int]:
+    """(keys a kv block, kv blocks, bits of a word in use) of a packed selection."""
+    bk = selection_block(Sk)
+    return bk, -(-Sk // bk), -(-bk // _LANES)
+
+
+def pack_selection(mask: jax.Array) -> jax.Array:
+    """mask [B, Sq, Sk] bool (True: the query sees the key) -> int32
+    [B, Sq, kv blocks x 128]: word (q, j, lane) holds at bit b the key
+    `j * selection_block(Sk) + 128 b + lane` (at most 32 x 128 keys a
+    block). Keys past Sk are unseen."""
+    B, Sq, Sk = mask.shape
+    bk, nk, bits = _selection_layout(Sk)
+    mask = jnp.pad(mask, ((0, 0), (0, 0), (0, nk * bk - Sk))).reshape(B, Sq, nk, bk)
+    mask = jnp.pad(mask, ((0, 0), (0, 0), (0, 0), (0, bits * _LANES - bk)))
+    words = mask.reshape(B, Sq, nk, bits, _LANES).astype(jnp.uint32) << jnp.arange(
+        bits, dtype=jnp.uint32)[:, None]
+    # the bits of a word are disjoint: their sum is their union
+    return jax.lax.bitcast_convert_type(words.sum(axis=3, dtype=jnp.uint32),
+                                        jnp.int32).reshape(B, Sq, nk * _LANES)
+
+
+def unpack_selection(packed: jax.Array, Sk: int) -> jax.Array:
+    """`pack_selection`'s inverse -> bool [B, Sq, Sk]."""
+    B, Sq, _ = packed.shape
+    bk, nk, bits = _selection_layout(Sk)
+    words = jax.lax.bitcast_convert_type(packed, jnp.uint32).reshape(B, Sq, nk, 1, _LANES)
+    mask = (words >> jnp.arange(bits, dtype=jnp.uint32)[:, None]) & 1
+    return mask.reshape(B, Sq, nk, bits * _LANES)[..., :bk].reshape(B, Sq, nk * bk)[..., :Sk] != 0
+
+
+def _sel_tile(sel_ref, lo, Tk):
+    """The selection of the Tk keys from `lo` of the resident kv block ->
+    bool [Bq, Tk]; sel_ref [1, Bq, 128] is the block's words. `lo` is a
+    multiple of 128 wherever a block has more than one sub-tile."""
+    words = sel_ref[0]
+    first = lo // _LANES
+    parts = []
+    for u in range(-(-Tk // _LANES)):
+        bit = jax.lax.shift_right_logical(words, jnp.full_like(words, first + u)) & 1
+        parts.append(bit[:, :min(_LANES, Tk - u * _LANES)])
+    return (parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)) != 0
+
+
+def _sel_specs(sel, block_q: int, index_map) -> tuple:
+    """The input a packed selection adds to a call, after the others: its
+    words of (q block, kv block); nothing without one."""
+    return () if sel is None else (pl.BlockSpec((1, block_q, _LANES), index_map),)
+
+
+def _with_selection(kernel, at: int):
+    """`kernel` for a call whose input `at` is the packed selection: that
+    ref goes in as `sel_ref`, the others as they stood without it."""
+    def run(*refs, **statics):
+        return kernel(*refs[:at], *refs[at + 1:], sel_ref=refs[at], **statics)
+    return run
 
 
 # ---------------------------------------------------------------------------
@@ -305,6 +387,7 @@ def _fwd_kernel(
     has_segments: bool,
     kpad: bool,
     window: Optional[int] = None,
+    sel_ref=None,  # [1, Bq, 128] int32: the kv block's packed selection, or None
 ):
     i = pl.program_id(2)
     j = pl.program_id(3)
@@ -344,7 +427,8 @@ def _fwd_kernel(
         mask = _block_mask(
             i, j * Bk + lo, F, Bq, Tk, causal=causal, q_offset=q_offset, sq_valid=0,
             sk_valid=sk_valid, kpad=kpad, qpad=False, qseg_ref=qseg_ref,
-            kseg=kseg_ref[0, :, pl.ds(lo, Tk)] if has_segments else None, window=window)
+            kseg=kseg_ref[0, :, pl.ds(lo, Tk)] if has_segments else None, window=window,
+            sel=None if sel_ref is None else _sel_tile(sel_ref, lo, Tk))
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
 
@@ -403,6 +487,7 @@ def _dq_kernel(
     has_segments: bool,
     kpad: bool,
     window: Optional[int] = None,
+    sel_ref=None,
 ):
     i = pl.program_id(2)
     j = pl.program_id(3)
@@ -438,7 +523,8 @@ def _dq_kernel(
         mask = _block_mask(
             i, j * Bk, F, Bq, Bk, causal=causal, q_offset=q_offset, sq_valid=0,
             sk_valid=sk_valid, kpad=kpad, qpad=False, qseg_ref=qseg_ref,
-            kseg=kseg_ref[0] if has_segments else None, window=window)
+            kseg=kseg_ref[0] if has_segments else None, window=window,
+            sel=None if sel_ref is None else _sel_tile(sel_ref, 0, Bk))
         if mask is not None:
             p = jnp.where(mask, p, 0.0)  # [rows, Bk]
         dp = jax.lax.dot_general(
@@ -487,6 +573,7 @@ def _dkv_kernel(
     fused_dq: bool = False,
     dq_ref=None,  # fused mode only: [1, F, Bq, D], written per (h, i)
     dq_scr=None,  # fused mode only: [F*Bq, D] fp32 (sub-tile accumulator)
+    sel_ref=None,
 ):
     # grid (B, nk, H/F, nq): q-blocks fastest, then the head groups
     # sharing this kv head; scratch accumulates until both inner dims
@@ -533,7 +620,8 @@ def _dkv_kernel(
         mask = _block_mask(
             i, jk * Bk + lo, F, Bq, Tk, causal=causal, q_offset=q_offset, sq_valid=sq_valid,
             sk_valid=sk_valid, kpad=kpad, qpad=qpad, qseg_ref=qseg_ref,
-            kseg=kseg_ref[0, :, pl.ds(lo, Tk)] if has_segments else None, window=window)
+            kseg=kseg_ref[0, :, pl.ds(lo, Tk)] if has_segments else None, window=window,
+            sel=None if sel_ref is None else _sel_tile(sel_ref, lo, Tk))
         if mask is not None:
             p = jnp.where(mask, p, 0.0)
         dv_scr[pl.ds(lo, Tk)] += jax.lax.dot_general(
@@ -585,7 +673,7 @@ def _kv_fetch(window, nk, block_q, block_k, q_offset):
 
 
 def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
-              sk_valid, interpret, has_segments, fold, window=None):
+              sk_valid, interpret, has_segments, fold, window=None, sel=None):
     B, H, Sq_pad, D = q.shape
     _, KVH, Sk_pad, _ = k.shape
     G = H // KVH
@@ -600,7 +688,7 @@ def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
     )
     kv = _kv_fetch(window, nk, block_q, block_k, q_offset)
     return pl.pallas_call(
-        kernel,
+        kernel if sel is None else _with_selection(kernel, 5),
         grid=(B, HG, nq, nk),
         in_specs=[
             pl.BlockSpec((1, F, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
@@ -608,6 +696,7 @@ def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h * F // G, kv(i, j), 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, h, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, h, i, j: (b, 0, kv(i, j))),
+            *_sel_specs(sel, block_q, lambda b, h, i, j: (b, i, kv(i, j))),
         ],
         out_specs=[
             pl.BlockSpec((1, F, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
@@ -623,7 +712,7 @@ def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
             pltpu.VMEM((F * block_q, D), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, qseg, kseg)
+    )(q, k, v, qseg, kseg, *(() if sel is None else (sel,)))
 
 
 # What Mosaic scopes to one kernel by default on a TPU. The fused backward
@@ -660,7 +749,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, do_ref,
 
 def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
               block_q, block_k, sq_valid, sk_valid, interpret, has_segments,
-              fold, dlse=None, window=None):
+              fold, dlse=None, window=None, sel=None):
     B, H, Sq_pad, D = q.shape
     _, KVH, Sk_pad, _ = k.shape
     G = H // KVH
@@ -677,15 +766,18 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
         # lse cotangent: d s_ij += dlse_i * p_ij, i.e. ds = p*(dp - delta
         # + dlse) — folded into the delta the kernels already subtract
         delta = delta - dlse.astype(jnp.float32)
+    # a selection is one more input of each kernel, after the others
+    operands = (q, k, v, qseg, kseg, do, lse, delta) + (() if sel is None else (sel,))
+    selected = (lambda kernel: kernel) if sel is None else functools.partial(_with_selection, at=8)
 
     if nk == 1:
         dq, dk, dv = pl.pallas_call(
-            functools.partial(
+            selected(functools.partial(
                 _bwd_fused_kernel, scale=scale, causal=causal,
                 q_offset=q_offset, sq_valid=sq_valid, sk_valid=sk_valid,
                 group=G // F, has_segments=has_segments, kpad=kpad, qpad=qpad,
                 window=window,
-            ),
+            )),
             grid=(B, 1, HG, nq),  # q-blocks fastest, then groups per kv head
             in_specs=[
                 pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, i, 0)),
@@ -696,6 +788,7 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
                 pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, i, 0)),
                 pl.BlockSpec((1, F, block_q, 1), lambda b, j, h, i: (b, h, i, 0)),
                 pl.BlockSpec((1, F, block_q, 1), lambda b, j, h, i: (b, h, i, 0)),
+                *_sel_specs(sel, block_q, lambda b, j, h, i: (b, i, j)),
             ],
             out_specs=[
                 pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, i, 0)),
@@ -714,16 +807,16 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
             ],
             interpret=interpret,
             compiler_params=_fused_bwd_params(block_q, block_k, D, F, q.dtype.itemsize),
-        )(q, k, v, qseg, kseg, do, lse, delta)
+        )(*operands)
         return dq, dk, dv
 
     kv = _kv_fetch(window, nk, block_q, block_k, q_offset)
     dq = pl.pallas_call(
-        functools.partial(
+        selected(functools.partial(
             _dq_kernel, scale=scale, causal=causal,
             q_offset=q_offset, sk_valid=sk_valid,
             has_segments=has_segments, kpad=kpad, window=window,
-        ),
+        )),
         grid=(B, HG, nq, nk),
         in_specs=[
             pl.BlockSpec((1, F, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
@@ -734,6 +827,7 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
             pl.BlockSpec((1, F, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, F, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, F, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
+            *_sel_specs(sel, block_q, lambda b, h, i, j: (b, i, kv(i, j))),
         ],
         out_specs=pl.BlockSpec(
             (1, F, block_q, D), lambda b, h, i, j: (b, h, i, 0)
@@ -741,19 +835,19 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
         out_shape=jax.ShapeDtypeStruct((B, H, Sq_pad, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((F * block_q, D), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, qseg, kseg, do, lse, delta)
+    )(*operands)
 
     # the q side of the dk/dv kernel under a window: a step whose rows see none of
     # the kv block's keys names the q block already resident (`_q_block_of`)
     qb = (lambda i, j: i) if window is None else functools.partial(
         _q_block_of, Bq=block_q, Bk=block_k, n=nq, q_offset=q_offset, window=window)
     dk, dv = pl.pallas_call(
-        functools.partial(
+        selected(functools.partial(
             _dkv_kernel, scale=scale, causal=causal,
             q_offset=q_offset, sq_valid=sq_valid, sk_valid=sk_valid,
             group=G // F, has_segments=has_segments, kpad=kpad, qpad=qpad,
             window=window,
-        ),
+        )),
         grid=(B, nk, HG, nq),  # q-blocks fastest, then groups per kv head
         in_specs=[
             pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, qb(i, j), 0)),
@@ -764,6 +858,7 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
             pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, qb(i, j), 0)),
             pl.BlockSpec((1, F, block_q, 1), lambda b, j, h, i: (b, h, qb(i, j), 0)),
             pl.BlockSpec((1, F, block_q, 1), lambda b, j, h, i: (b, h, qb(i, j), 0)),
+            *_sel_specs(sel, block_q, lambda b, j, h, i: (b, qb(i, j), j)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, D), lambda b, j, h, i: (b, h * F // G, j, 0)),
@@ -778,7 +873,7 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, qseg, kseg, do, lse, delta)
+    )(*operands)
     return dq, dk, dv
 
 
@@ -792,41 +887,44 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
 # as zeros and `delta - 0` is a no-op in the backward).
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 8, 9, 10))
 def _flash_lse(scale, causal, q_offset, block_q, block_k, sq_valid, sk_valid,
-               interpret, has_segments, fold, window, q, k, v, qseg, kseg):
+               interpret, has_segments, fold, window, q, k, v, qseg, kseg, sel=None):
     """(o, lse) with a DIFFERENTIABLE lse — ring attention merges
-    per-block results through lse, so its cotangent must reach ds."""
+    per-block results through lse, so its cotangent must reach ds.
+    `sel`: the packed selection (None: none; no operand of the call then)."""
     (o, lse), _ = _flash_lse_fwd(
         scale, causal, q_offset, block_q, block_k, sq_valid, sk_valid,
-        interpret, has_segments, fold, window, q, k, v, qseg, kseg,
+        interpret, has_segments, fold, window, q, k, v, qseg, kseg, sel,
     )
     return o, lse
 
 
 def _flash_lse_fwd(scale, causal, q_offset, block_q, block_k, sq_valid,
                    sk_valid, interpret, has_segments, fold, window, q, k, v, qseg,
-                   kseg):
+                   kseg, sel=None):
     o, lse = _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset,
                        block_q, block_k, sk_valid, interpret, has_segments,
-                       fold, window)
+                       fold, window, sel)
     # named residuals: under jax.checkpoint, the backward re-runs this
     # whole kernel just to rebuild (o, lse) unless the remat policy can
     # SAVE them — the "dots" policy recognizes dot_general outputs, not a
     # pallas_call's (llama.py pairs this with save_only_these_names)
     o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
     lse = jax.ad_checkpoint.checkpoint_name(lse, "attn_lse")
-    return (o, lse), (q, k, v, qseg, kseg, o, lse)
+    return (o, lse), (q, k, v, qseg, kseg, o, lse, sel)
 
 
 def _flash_lse_bwd(scale, causal, q_offset, block_q, block_k, sq_valid,
                    sk_valid, interpret, has_segments, fold, window, residuals, cts):
     do, dlse = cts
-    q, k, v, qseg, kseg, o, lse = residuals
+    q, k, v, qseg, kseg, o, lse, sel = residuals
     dq, dk, dv = _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal,
                            q_offset, block_q, block_k, sq_valid, sk_valid,
-                           interpret, has_segments, fold, dlse=dlse, window=window)
+                           interpret, has_segments, fold, dlse=dlse, window=window, sel=sel)
     zero_seg = np.zeros(qseg.shape, dtype=jax.dtypes.float0)
     zero_kseg = np.zeros(kseg.shape, dtype=jax.dtypes.float0)
-    return dq, dk, dv, zero_seg, zero_kseg
+    # the selection is a constant of the backward: integers take no cotangent
+    zero_sel = None if sel is None else np.zeros(sel.shape, dtype=jax.dtypes.float0)
+    return dq, dk, dv, zero_seg, zero_kseg, zero_sel
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -880,6 +978,7 @@ def _flash_head_major(
     interpret: Optional[bool] = None,
     fold_heads: Optional[int] = None,
     window: Optional[int] = None,
+    selection: Optional[jax.Array] = None,  # `pack_selection`'s [B, Sq, kv blocks x 128]
 ) -> tuple[jax.Array, jax.Array]:
     """The kernels' own layout, which both public forms come down to:
     -> (o [B, H, Sq, D], lse [B, H, Sq_pad, 1])."""
@@ -887,6 +986,12 @@ def _flash_head_major(
         raise ValueError(f"a sliding window ({window}) is a causal mask's: window >= 1, causal")
     B, H, Sq, _ = qt.shape
     _, KVH, Sk, _ = kt.shape
+    if selection is not None:
+        words = (B, Sq, _selection_layout(Sk)[1] * _LANES)
+        if block_k is not None or selection.shape != words or selection.dtype != jnp.int32:
+            raise ValueError(
+                f"a selection is `pack_selection`'s int32 {words} at the default kv block: "
+                f"got {selection.dtype} {selection.shape}, block_k {block_k}")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
@@ -905,6 +1010,8 @@ def _flash_head_major(
     if Sk_pad != Sk:
         kt = jnp.pad(kt, ((0, 0), (0, 0), (0, Sk_pad - Sk), (0, 0)))
         vt = jnp.pad(vt, ((0, 0), (0, 0), (0, Sk_pad - Sk), (0, 0)))
+    if selection is not None and Sq_pad != Sq:  # a padded row sees nothing
+        selection = jnp.pad(selection, ((0, 0), (0, Sq_pad - Sq), (0, 0)))
 
     has_segments = segment_ids is not None or kv_segment_ids is not None
     if not has_segments:
@@ -926,7 +1033,7 @@ def _flash_head_major(
     # the scale is in q already: the kernels' own is 1
     statics = (1.0, causal, q_offset, bq, bk, Sq, Sk, interpret,
                has_segments, fold, window)
-    o, lse = _flash_lse(*statics, qt, kt, vt, qseg, kseg)
+    o, lse = _flash_lse(*statics, qt, kt, vt, qseg, kseg, selection)
     return o[:, :, :Sq, :], lse
 
 
@@ -946,6 +1053,7 @@ def flash_attention(
     fold_heads: Optional[int] = None,  # None = auto (largest safe divisor of G)
     return_lse: bool = False,
     window: Optional[int] = None,  # keys a row sees, itself among them (None: all before it)
+    selection: Optional[jax.Array] = None,  # packed: which keys each row sees (None: no such mask)
 ) -> "jax.Array | tuple[jax.Array, jax.Array]":
     """Drop-in for ops.attention.xla_attention with O(S) memory, for a
     caller that holds [B, S, H, D] (ring attention, tests): q, k and v
@@ -968,7 +1076,8 @@ def flash_attention(
     o, lse = _flash_head_major(
         qt, kt, vt, causal=causal, segment_ids=segment_ids,
         kv_segment_ids=kv_segment_ids, q_offset=q_offset, block_q=block_q,
-        block_k=block_k, interpret=interpret, fold_heads=fold_heads, window=window)
+        block_k=block_k, interpret=interpret, fold_heads=fold_heads, window=window,
+        selection=selection)
     o = jnp.transpose(o, (0, 2, 1, 3))
     if return_lse:
         lse = jnp.transpose(lse[:, :, :Sq, 0], (0, 2, 1))  # [B, Sq, H]
@@ -984,6 +1093,7 @@ def flash_attention_head_major(
     causal: bool = True,
     segment_ids: Optional[jax.Array] = None,  # [B, S] (requires Sq == Sk)
     window: Optional[int] = None,
+    selection: Optional[jax.Array] = None,
 ) -> jax.Array:
     """`flash_attention` for a caller that holds the heads as a major
     dimension already (the attention sublayers of models/llama.py,
@@ -994,5 +1104,5 @@ def flash_attention_head_major(
     per-shard calls, the kernel's tests), no model's."""
     _check_shapes(q.shape[1], k.shape[1], q.shape[2], k.shape[2], 0, segment_ids, None)
     o, _ = _flash_head_major(_fold_scale(q, None), k, v, causal=causal,
-                             segment_ids=segment_ids, window=window)
+                             segment_ids=segment_ids, window=window, selection=selection)
     return o

@@ -1,0 +1,415 @@
+"""The language model of Keye-VL-2.0 through the one decoder (PR 42), at a
+small size on the CPU (the sequence past `indexer_topk`, so that the
+selection bites), seeded weights, against the plain reference
+(chipbench/reference/keye_decoder.py, imported): the attention sublayer
+over the keys the indexer selects, the selected sets themselves, the tie
+rule, `topk` >= T as the full GQA sublayer, no gradient into the indexer
+and none through the selection, the whole train path in loss and
+gradients, the eight shares of a layer that add up, the train step by
+the registry's name, `config_from_hf` on the catalog's config, the
+refusals. (The flash kernels under a selection: tests/test_flash_selection.py.)"""
+
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from chipbench.reference import keye_decoder
+from ray_tpu.models import dsa, llama, moe
+from ray_tpu.models.registry import config_from_hf, get_model_config, list_models
+from ray_tpu.nn.layers import rms_norm
+from ray_tpu.ops.attention import attention_head_major
+from ray_tpu.ops.flash import unpack_selection
+
+FP32 = dataclasses.replace(dsa.KEYE_TINY, dtype=jnp.float32)
+B, S = 2, 64   # topk 16 and chunks of 16 queries: the first chunk computes no score
+CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+
+
+@pytest.fixture(autouse=True)
+def blocks_of_queries(monkeypatch):
+    monkeypatch.setattr(keye_decoder, "QUERY_BLOCK", 32)
+
+
+def shape_of(cfg) -> dict:
+    """A KeyeConfig as the configuration file's dict (HF key names)."""
+    return {
+        "hidden_size": cfg.d_model, "head_dim": cfg.head_dim,
+        "num_attention_heads": cfg.n_heads, "num_key_value_heads": cfg.n_kv_heads,
+        "num_hidden_layers": cfg.n_layers, "rope_theta": cfg.rope_theta,
+        "rms_norm_eps": cfg.rms_eps, "num_experts": cfg.n_held,
+        "sa_config": {"indexer_num_heads": cfg.indexer_heads,
+                      "indexer_head_dim": cfg.indexer_head_dim, "topk": cfg.indexer_topk},
+        "published": {"num_experts": cfg.n_experts},
+        "deployment": {"first_expert_held": cfg.first_expert_held},
+        "num_experts_per_tok": cfg.top_k, "norm_topk_prob": cfg.norm_topk_prob,
+        "max_position_embeddings": cfg.max_seq, "tie_word_embeddings": cfg.tie_embeddings,
+        "mlp_only_layers": [], "decoder_sparse_step": 1, "vocab_size": cfg.vocab_size,
+    }
+
+
+def seeded_params(cfg, seed=0, bias=0.0):
+    """init_params with the norms moved off their init and the selection
+    biases a random table at scale `bias`."""
+    p = llama.init_params(cfg, jax.random.key(seed))
+    keys = iter(jax.random.split(jax.random.key(seed + 100), 8))
+    layers = dict(p["layers"])
+    for n in ("ln1", "ln2", "q_norm", "k_norm", "idx_norm_w"):
+        layers[n] = 1 + 0.1 * jax.random.normal(next(keys), layers[n].shape)
+    layers["idx_norm_b"] = 0.1 * jax.random.normal(next(keys), layers["idx_norm_b"].shape)
+    layers["router_bias"] = bias * jax.random.normal(next(keys), layers["router_bias"].shape)
+    return {**p, "layers": layers}
+
+
+def batch_of(cfg, seed=1):
+    tok = jax.random.randint(jax.random.key(seed), (B, S + 1), 0, cfg.vocab_size)
+    return {"tokens": tok[:, :-1], "targets": tok[:, 1:]}
+
+
+def loss_and_grads(cfg, params, batch):
+    def f(p):
+        loss, _, stats = llama.loss_and_weight_fn(p, batch, cfg)
+        return loss, stats
+
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(jax.value_and_grad(f, has_aux=True))(params)
+
+
+def layer_of(params, l=0):
+    return jax.tree.map(lambda w: w[l], params["layers"])
+
+
+# -- the sublayer -----------------------------------------------------------------
+
+
+@pytest.mark.parametrize("impl", ["xla", "flash"])
+def test_attention_sublayer_is_the_references(impl):
+    cfg = dataclasses.replace(FP32, attention_impl=impl)
+    lp = layer_of(seeded_params(cfg))
+    h = jax.random.normal(jax.random.key(7), (B, S, cfg.d_model), jnp.float32)
+
+    def ours(h):
+        x = rms_norm(h, lp["ln1"], cfg.rms_eps)
+        return h + dsa.dsa_sublayer(x, lp, cfg, positions=jnp.arange(S), segment_ids=None)[0]
+
+    def theirs(h):
+        return jnp.stack([keye_decoder.attention(h[b], lp, shape_of(cfg))[0] for b in range(B)])
+
+    probe = jax.random.normal(jax.random.key(8), h.shape)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.value_and_grad(lambda h: (ours(h) * probe).sum()))(h)
+        want = jax.jit(jax.value_and_grad(lambda h: (theirs(h) * probe).sum()))(h)
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
+    np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want[1]), rtol=1e-4, atol=1e-5)
+
+
+def test_the_selected_sets_are_the_references():
+    """Key for key: the bisection and the packed mask against the
+    reference's stable sort, on the same index scores; 16 keys a query
+    from row 16 on, every key before it up to there."""
+    cfg = FP32
+    lp = layer_of(seeded_params(cfg))
+    x = jax.random.normal(jax.random.key(3), (B, S, cfg.d_model), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        packed, n_selected, _ = dsa.selection(x, lp, cfg, jnp.arange(S))
+        ours = np.asarray(unpack_selection(packed, S))
+        J, c = cfg.indexer_heads, cfg.indexer_head_dim
+        for b in range(B):
+            q_i = keye_decoder._rope((x[b] @ lp["idx_wq"]).reshape(S, J, c), cfg.rope_theta)
+            k_i = keye_decoder._rope(keye_decoder._layer_norm(
+                x[b] @ lp["idx_wk"], lp["idx_norm_w"], lp["idx_norm_b"])[:, None],
+                cfg.rope_theta)[:, 0]
+            theirs = keye_decoder.select(keye_decoder.index_scores(q_i, k_i, x[b] @ lp["idx_ww"]),
+                                         0, cfg.indexer_topk)
+            assert (ours[b] == np.asarray(theirs)).all()
+    per_row = ours.sum(-1)
+    assert (per_row == np.minimum(np.arange(S) + 1, cfg.indexer_topk)).all()
+    assert int(n_selected) == int(ours.sum()) == B * (16 * 17 // 2 + 48 * 16)
+    assert not np.triu(ours[0], 1).any()  # nothing above the diagonal
+
+
+def test_equal_scores_go_to_the_lower_key_and_are_counted():
+    scores = jnp.asarray([[3.0, 1.0, 1.0, 1.0, 1.0, 0.5, -0.0, 0.0],
+                          [5.0, 4.0, 3.0, 2.0, 1.0, 0.0, 9.0, 9.0],
+                          [2.0, 2.0, 7.0, 7.0, 7.0, 7.0, 7.0, 7.0],
+                          [-1.0, -2.0, -3.0, 0.0, 0.0, 0.0, 0.0, 0.0]])
+    visible = jnp.asarray([[True] * 8, [True] * 6 + [False] * 2, [True] * 8, [True] * 3 + [False] * 5])
+    chosen, ties = dsa.select_keys(scores, visible, 3)
+    assert chosen.astype(int).tolist() == [[1, 1, 1, 0, 0, 0, 0, 0],    # the cut falls on 1.0: keys 1, 2
+                                           [1, 1, 1, 0, 0, 0, 0, 0],    # unseen keys never, whatever they score
+                                           [0, 0, 1, 1, 1, 0, 0, 0],    # three of the six 7.0s, the lowest
+                                           [1, 1, 1, 0, 0, 0, 0, 0]]    # at most k visible: all of them
+    assert ties.tolist() == [True, False, True, False]
+    # -0.0 and 0.0 are one score: the lower key wins
+    chosen, ties = dsa.select_keys(jnp.asarray([[0.0, -0.0, 0.0, -1.0]]), jnp.ones((1, 4), bool), 2)
+    assert chosen.astype(int).tolist() == [[1, 1, 0, 0]] and ties.tolist() == [True]
+    # the reference's stable sort agrees, row for row
+    theirs = keye_decoder.select(jnp.where(visible, scores, -jnp.inf)[:, :8], 7, 3)
+    assert (np.asarray(theirs)[[0, 2]] == np.asarray(dsa.select_keys(scores, visible, 3)[0])[[0, 2]]).all()
+
+
+def test_topk_at_least_the_sequence_is_the_full_gqa_sublayer():
+    cfg = dataclasses.replace(FP32, indexer_topk=S)
+    lp = layer_of(seeded_params(cfg))
+    x = jax.random.normal(jax.random.key(3), (B, S, cfg.d_model), jnp.float32)
+    seen = {}
+
+    def spy(q, k, v, **kw):
+        seen.update(q=q, k=k, v=v)
+        return attention_head_major(q, k, v, **kw)
+
+    with jax.default_matmul_precision("highest"):
+        try:
+            dsa.attention_head_major = spy
+            out, stats = dsa.dsa_sublayer(x, lp, cfg, positions=jnp.arange(S), segment_ids=None)
+        finally:
+            dsa.attention_head_major = attention_head_major
+        o = attention_head_major(seen["q"], seen["k"], seen["v"], causal=True)
+        full = jnp.einsum("bhsk,hkd->bsd", o, lp["wo"].reshape(cfg.n_heads, cfg.head_dim, -1))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(full), rtol=1e-6, atol=1e-6)
+    assert int(stats["dsa_selected"]) == B * S * (S + 1) // 2 and int(stats["dsa_ties"]) == 0
+
+
+def test_no_gradient_reaches_the_indexer_and_none_passes_through_the_selection():
+    cfg = FP32
+    params, batch = seeded_params(cfg), batch_of(cfg)
+    (_, stats), grads = loss_and_grads(cfg, params, batch)
+    for n in ("idx_wq", "idx_wk", "idx_ww", "idx_norm_w", "idx_norm_b", "router_bias"):
+        assert not np.asarray(grads["layers"][n]).any(), n
+    assert np.asarray(grads["layers"]["wq"]).any()
+    assert stats["dsa_selected"].tolist() == [B * (16 * 17 // 2 + 48 * 16)] * cfg.n_layers
+    # the selection as a CONSTANT gives the sublayer's input the gradient it has with the indexer in
+    lp = layer_of(params)
+    x = jax.random.normal(jax.random.key(3), (B, S, cfg.d_model), jnp.float32)
+    pos = jnp.arange(S)
+    sel = dsa.selection(x, lp, cfg, pos)
+
+    def with_constant(x):
+        real = dsa.selection
+        try:
+            dsa.selection = lambda *a: sel
+            return dsa.dsa_sublayer(x, lp, cfg, positions=pos, segment_ids=None)[0].sum()
+        finally:
+            dsa.selection = real
+
+    whole = jax.jit(jax.grad(
+        lambda x: dsa.dsa_sublayer(x, lp, cfg, positions=pos, segment_ids=None)[0].sum()))(x)
+    np.testing.assert_array_equal(np.asarray(whole), np.asarray(jax.jit(jax.grad(with_constant))(x)))
+
+
+# -- the model ----------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("bias", [0.0, 0.02], ids=["zero_bias", "random_bias"])
+@pytest.mark.parametrize("held", [None, (2, 4)], ids=["all_experts", "a_share"])
+def test_train_path_meets_the_reference_in_loss_and_gradients(held, bias):
+    cfg = FP32 if held is None else dataclasses.replace(
+        FP32, first_expert_held=held[0], experts_held=held[1], vocab_size=256)
+    params, batch = seeded_params(cfg, bias=bias), batch_of(cfg)
+    (loss, stats), grads = loss_and_grads(cfg, params, batch)
+    shape = shape_of(cfg)
+    parts = keye_decoder.loss_parts(params, batch["tokens"], batch["targets"], shape)
+    assert float(loss) == pytest.approx(float(parts["loss"]), rel=1e-5)
+    assert stats["tokens_per_expert"].tolist() == parts["tokens_per_expert"].tolist()
+    assert stats["dsa_selected"].tolist() == parts["selected_pairs"].tolist()
+    want = jax.jit(jax.grad(
+        lambda p: keye_decoder.loss(p, batch["tokens"], batch["targets"], shape)))(params)
+    for path, g in jax.tree_util.tree_leaves_with_path(grads):
+        w = jax.tree_util.keystr(path)
+        ref = np.asarray(jax.tree_util.tree_leaves_with_path(want)[[jax.tree_util.keystr(p) for p, _ in
+               jax.tree_util.tree_leaves_with_path(want)].index(w)][1])
+        np.testing.assert_allclose(np.asarray(g), ref, rtol=2e-4, atol=2e-6, err_msg=w)
+
+
+def test_flash_and_bf16_compute_stay_near_the_reference():
+    cfg = dataclasses.replace(dsa.KEYE_TINY, attention_impl="flash")
+    params, batch = seeded_params(cfg), batch_of(cfg)
+    loss = llama.loss_and_weight_fn(params, batch, cfg)[0]
+    want = keye_decoder.loss(params, batch["tokens"], batch["targets"], shape_of(cfg))
+    assert float(loss) == pytest.approx(float(want), rel=5e-3)
+
+
+@pytest.mark.parametrize("remat_policy", ["dots", "full"])
+def test_remat_gives_the_same_gradients(remat_policy):
+    plain = FP32
+    cfg = dataclasses.replace(plain, remat=True, remat_policy=remat_policy)
+    params, batch = seeded_params(plain), batch_of(plain)
+    (a, _), ga = loss_and_grads(plain, params, batch)
+    (b, _), gb = loss_and_grads(cfg, params, batch)
+    assert float(a) == pytest.approx(float(b), rel=1e-6)
+    for x, y in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-5, atol=1e-7)
+
+
+def test_the_dots_policy_keeps_the_selection_and_nothing_else_of_the_indexer():
+    """What the backward of a rematerialised block reads of the indexer is
+    the packed mask, saved under its name: in the compiled step no index
+    score, no projection of the indexer and no second top-k is made again
+    for the backward, and none is differentiated."""
+    import re
+
+    cfg = dataclasses.replace(FP32, remat=True, remat_policy="dots")
+    params, batch = seeded_params(cfg), batch_of(cfg)
+    grad = jax.jit(jax.grad(lambda p: llama.loss_and_weight_fn(p, batch, cfg)[0]))
+    assert "dsa_sel" in str(jax.make_jaxpr(grad)(params))
+    names = set(re.findall(r'op_name="([^"]*)"', grad.lower(params).compile().as_text()))
+    indexer = [n for n in names if "dsa.select" in n or "dsa.index" in n]
+    assert indexer and any("dsa.attend" in n and "rematted_computation" in n for n in names)
+    assert not [n for n in indexer if "rematted_computation" in n or "transpose(" in n]
+
+
+def test_eight_shares_add_up_to_the_uncut_layer():
+    """The cell's deployment, small: 8 shares of 2 of 16 experts, top-8
+    softmax with a bias, renormalised. The attention sublayer, indexer and
+    all, and the router are computed alike on every chip and counted ONCE;
+    the shares' routed outputs sum to the uncut layer's, and that is the
+    uncut REFERENCE's whole layer; every share counts what the uncut
+    layer counts, and what one computes the others count as elsewhere."""
+    whole = dataclasses.replace(FP32, n_experts=16, top_k=8, n_layers=1)
+    params = seeded_params(whole, bias=0.01)
+    lp = layer_of(params)
+    h = jax.random.normal(jax.random.key(5), (B, S, whole.d_model), jnp.float32)
+    experts = ("w_gate", "w_up", "w_down")
+
+    def block(cfg, lp):
+        def f(h):
+            out, stats = llama._block(h, lp, config=cfg, cos=None, sin=None,
+                                      positions=jnp.arange(S), segment_ids=None)
+            return out, stats
+        def both(h):
+            out, vjp, stats = jax.vjp(f, h, has_aux=True)
+            return out, vjp(jnp.ones_like(out))[0], stats
+        return jax.jit(both)(h)
+
+    def share(first):
+        cfg = dataclasses.replace(whole, experts_held=2, first_expert_held=first)
+        return block(cfg, {**lp, **{k: lp[k][first:first + 2] for k in experts}})
+
+    with jax.default_matmul_precision("highest"):
+        full = block(whole, lp)
+        no_experts = block(whole, {**lp, "w_down": jnp.zeros_like(lp["w_down"])})
+        shares = [share(first) for first in range(0, 16, 2)]
+        theirs = []
+        for b in range(B):
+            mid, _ = keye_decoder.attention(h[b], lp, shape_of(whole))
+            theirs.append(keye_decoder.experts(mid, lp, shape_of(whole))[0])
+    for i in (0, 1):   # the output, and the gradient of the input
+        # everything but the routed experts is in every share: counted once
+        total = sum(np.asarray(s[i]) for s in shares) - 7 * np.asarray(no_experts[i])
+        np.testing.assert_allclose(total, np.asarray(full[i]), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(full[0]), np.asarray(jnp.stack(theirs)), rtol=2e-5, atol=2e-5)
+    counts = full[2]["tokens_per_expert"]
+    assert int(counts.sum()) == 8 * B * S
+    for first, (_, _, stats) in zip(range(0, 16, 2), shares):
+        assert stats["tokens_per_expert"].tolist() == counts.tolist()
+        assert int(stats["pairs_elsewhere"]) == int(counts.sum() - counts[first:first + 2].sum())
+        assert int(stats["dropped_pairs"]) == 0
+        assert int(stats["dsa_selected"]) == int(full[2]["dsa_selected"])
+
+
+def test_the_train_step_learns_a_batch_by_the_registrys_name():
+    from ray_tpu.train.step import TrainState, make_train_step
+
+    cfg = get_model_config("keye-tiny")
+    assert isinstance(cfg, dsa.KeyeConfig) and "keye-vl-2.0-30b-a3b" in list_models()
+    opt = optax.adamw(3e-3)
+    state = TrainState.create(llama.init_params(cfg, jax.random.key(0)), opt)
+    before = jax.tree.map(np.asarray, {k: state.params["layers"][k] for k in ("idx_wq", "idx_ww")})
+    step = make_train_step(lambda p, b: llama.loss_and_weight_fn(p, b, cfg), opt)
+    batch = batch_of(cfg)
+    losses = []
+    for _ in range(12):
+        state, m = step(state, batch)
+        losses.append(float(m["loss"]))
+    assert losses[-1] < 0.6 * losses[0], losses
+    assert m["stats"]["dsa_selected"].shape == (cfg.n_layers,) and "dsa_ties" in m["stats"]
+    # no gradient moves the indexer: only AdamW's decay touches it (1e-4 x the rate a step)
+    for k, w in before.items():
+        np.testing.assert_allclose(np.asarray(state.params["layers"][k]), w, rtol=1e-4)
+
+
+def test_packed_sequences_and_positions_by_row_are_refused_by_name():
+    cfg = FP32
+    params, batch = seeded_params(cfg), batch_of(cfg)
+    with pytest.raises(NotImplementedError, match="segment_ids"):
+        llama.loss_and_weight_fn(params, {**batch, "segment_ids": jnp.zeros((B, S), jnp.int32)}, cfg)
+
+
+def test_counts_of_parameters_and_operations_are_the_trees_and_the_issues():
+    cfg = FP32
+    assert cfg.num_params() == sum(x.size for x in jax.tree.leaves(llama.init_params(cfg, jax.random.key(0))))
+    full = get_model_config("keye-vl-2.0-30b-a3b")
+    share = dataclasses.replace(full, n_layers=6, vocab_size=19072, experts_held=16)
+    assert round(share.num_params() / 1e6, 1) == 659.5          # ISSUE 42: six layers of the share
+    assert round(full.num_params() / 1e9, 1) == 30.6
+    assert full.selected_keys(8192) == 1792.125 and full.selected_keys(2048) == 1024.5
+
+
+# -- the registry ------------------------------------------------------------------
+
+
+def catalog_config():
+    if os.path.exists(CATALOG):
+        for line in open(CATALOG):
+            row = json.loads(line)
+            if row["name"] == "Keye-VL-2.0-30B-A3B":
+                return row["config"]
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "chipbench", "configs", "keye-vl-2.0-30b-a3b-train.json")
+    file = json.load(open(path))
+    return {**{k: v for k, v in file.items() if k not in file["published"]}, **file["published"]}
+
+
+def test_config_from_hf_maps_the_catalogs_config_onto_the_preset():
+    cfg = config_from_hf(catalog_config())
+    assert cfg == get_model_config("keye-vl-2.0-30b-a3b")
+    assert cfg.head_dim == 128 != cfg.d_model // cfg.n_heads
+    assert (cfg.indexer_heads, cfg.indexer_head_dim, cfg.indexer_topk, cfg.index_chunk) == (16, 64, 2048, 512)
+    assert (cfg.n_experts, cfg.top_k, cfg.norm_topk_prob, cfg.selection_bias, cfg.shared_d_ff) == (
+        128, 8, True, True, 0)
+    # the language model's keys under `text_config`, as a checkpoint's config nests them
+    nested = {"model_type": "KeyeVL2", "text_config": catalog_config()}
+    assert config_from_hf(nested) == cfg
+
+
+@pytest.mark.parametrize("key,value,names", [
+    ("vision_config", {"depth": 27}, "vision tower or image / video inputs .vision_config."),
+    ("image_token_id", 151655, "image_token_id"),
+    ("sa_config", None, "no sa_config"),
+    ("sa_config", {"indexer_num_heads": 16, "indexer_head_dim": 64, "indexer_num_kv_heads": 2,
+                   "topk": 2048}, "indexer_num_kv_heads 2"),
+    ("rope_scaling", {"rope_type": "yarn", "factor": 4}, "rope_scaling type 'yarn'"),
+    ("use_sliding_window", True, "a sliding window"),
+    ("attention_bias", True, "attention_bias"),
+    ("mlp_only_layers", [0], "mlp_only_layers"),
+    ("decoder_sparse_step", 2, "decoder_sparse_step 2"),
+])
+def test_config_from_hf_refuses_by_name_what_is_not_implemented(key, value, names):
+    with pytest.raises(ValueError, match=names):
+        config_from_hf({**catalog_config(), key: value})
+
+
+def test_engine_refuses_the_model_by_name():
+    from ray_tpu.llm.engine import EngineConfig
+
+    with pytest.raises(ValueError, match="Keye"):
+        EngineConfig(model="keye-tiny")
+
+
+def test_no_other_configuration_loads_the_module():
+    """`ray_tpu.models.registry` knows the names and loads models/dsa.py
+    (and with it the Pallas kernels' module) only when one is asked for."""
+    import subprocess
+    import sys
+
+    code = ("import sys; from ray_tpu.models import llama, registry; "
+            "c = registry.get_model_config('olmoe-1b-7b'); llama.logical_axes(c); "
+            "assert 'ray_tpu.models.dsa' not in sys.modules; "
+            "registry.get_model_config('keye-tiny'); assert 'ray_tpu.models.dsa' in sys.modules")
+    subprocess.run([sys.executable, "-c", code], check=True,
+                   env={**os.environ, "JAX_PLATFORMS": "cpu"})

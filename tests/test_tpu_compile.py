@@ -225,7 +225,7 @@ def test_engine_programs_with_pallas_compile_for_v5e(v5e):
 
 
 def _train_step_at_mistral_widths(devices, mesh_shape=None, batch=3, *,
-                                  model="mistral-7b", n_layers=2, **overrides):
+                                  model="mistral-7b", n_layers=2, seq=4096, **overrides):
     """(jitted step, abstract state, abstract batch) of a 2-layer
     Mistral-7B-wide train step as chipbench's training cells build it,
     placed on the described devices: one chip, or a 6-axis mesh. With
@@ -269,7 +269,7 @@ def _train_step_at_mistral_widths(devices, mesh_shape=None, batch=3, *,
         transform_non_params=lambda _: scalar))
     state = TrainState(params=params, opt_state=opt_state,
                        step=jax.ShapeDtypeStruct((), _I32, sharding=scalar))
-    tokens = jax.ShapeDtypeStruct((batch, 4096), _I32, sharding=batch_sharding)
+    tokens = jax.ShapeDtypeStruct((batch, seq), _I32, sharding=batch_sharding)
     loss = llama.loss_fn if model == "mistral-7b" else llama.loss_and_weight_fn
     # what the step asks of the backend when it is built (its compile options) is
     # answered by the described chip, as it would be on one
