@@ -247,7 +247,7 @@ def _inv_freq(dim: int, theta: float) -> jax.Array:
 
 
 def selection(x: jax.Array, lp: Params, c: KeyeConfig, positions: jax.Array) -> tuple:
-    """x [B, S, D] -> (the packed selection int32 [B, S, kv blocks x 128],
+    """x [B, S, D] -> (the packed selection int32 [B, S, selection blocks x 128],
     the selected pairs (int32), the rows whose cut fell on equal scores
     (int32)). Nothing here takes a gradient."""
     B, S, D = x.shape

@@ -19,9 +19,10 @@ flash/ragged lineage to this framework. Design:
    before the row) those wholly below the window are skipped too, and
    the mask cuts both edges. With the whole kv sequence in one block
    the block fetch still happens (compute, not bandwidth, dominates);
-   with several kv blocks (sequences over 4096) a block wholly outside
-   the window is not fetched either: the index map names the block
-   already resident (`_kv_block_of`, `_q_block_of`);
+   with several kv blocks (a sequence whose k block is over the budget
+   of bytes, `default_block_k`) a block wholly outside the window is not
+   fetched either: the index map names the block already resident
+   (`_kv_block_of`, `_q_block_of`);
  * GQA folds naturally: kv BlockSpec index maps divide the q-head
    index by the group size;
  * backward: dQ accumulates over kv blocks; dK/dV accumulate over
@@ -35,9 +36,10 @@ flash/ragged lineage to this framework. Design:
    each query sees, the same for every head, computed by the model
    (models/dsa.py: a learned indexer's top-k). It comes in packed, one
    bit a (query, key) pair (`pack_selection`: 8 MiB at 8192 x 8192), in
-   the order the kernels read it: an int32 word a (query, kv block,
-   lane), whose bit b is key 128 b + lane of the block, so a sub-tile of
-   512 keys is four shifts of one [rows, 128] tile. It is ANDed into
+   the order the kernels read it: an int32 word a (query, block of 4096
+   keys, lane), whose bit b is key 128 b + lane of the block, so a
+   sub-tile of 512 keys is four shifts of one [rows, 128] tile; a kv
+   block holds one such block or several whole ones. It is ANDed into
    the mask of every sub-tile the causal walk visits, forward and
    backward, where it is a constant; no sub-tile is skipped for it;
  * off-TPU the same kernels run under the Pallas interpreter, so CPU
@@ -61,6 +63,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu import obs
 
 # Measured on a v5e (PR 36). In the traced step of `m7b-train` (heads of
 # 128, 2 layers, batch 3 x 4096, causal) the forward kernel went from
@@ -96,9 +100,35 @@ from jax.experimental.pallas import tpu as pltpu
 #    against 3.31; in the backward 7.79 against 8.11 at 128 but 6.13
 #    against 6.03 at 256: not taken;
 #  * the FUSED backward (the whole kv sequence in one block, nk == 1, dq
-#    from the dk/dv kernel) is why the default kv block is the sequence;
-#    VMEM bounds it: dk/dv fp32 scratch is block_k*D*8 bytes, so block_k
-#    caps at 4096.
+#    from the dk/dv kernel) is why the default kv block is the sequence
+#    wherever its bytes fit (`default_block_k`, PR 43 below).
+#
+# The kv block at 8192 keys (PR 43), measured on a v5e on a scratch loop
+# that is NOT in the tree: wall clock over twenty calls of each kernel call
+# alone at `keye-train-8k`'s shape, [B, H, KVH, D] = [1, 32, 4, 128], 8192
+# keys, bf16, causal, a packed selection of the top 2048 of each query's
+# visible keys (1,792 a query), ms a call = a layer (both orders of the
+# loop read the same to 0.01):
+#  (a) the parent's three kernels, two kv blocks of 4096: forward 5.40,
+#      the dq and dk/dv kernels 16.22: 21.62;
+#  (b) ONE kv block of 8192, two selection blocks wide: forward 3.97, the
+#      fused backward 9.54: 13.51. KEPT;
+#  (c) the fused backward at 8192 under the forward left at two blocks of
+#      4096: 5.40 + 9.54 = 14.94.
+# The backward computes s, p and dp once (five matmuls and one exponential
+# a pair where the two kernels ran seven and two) and walks 136 sub-tiles
+# a head where the dq kernel, a whole [512, 4096] block a tile, did 192
+# sub-tiles' worth. The forward gains what nobody had priced: with two kv
+# blocks every q block fetches k and v again (the block index goes 0, 1,
+# 0, 1: 2 GiB a layer, 2.6 ms at the HBM's rate), with one they stay
+# resident over the 8 heads and 16 q blocks of a kv head. o and lse are
+# bit for bit the same in both forms, dk and dv too; dq differs by one
+# bf16 rounding (0.0078 at a largest element of 44.5: its float32 sum runs
+# over sub-tiles where it ran over two blocks), and both stand 0.119 from
+# a float32 reference. The fused kernel states a 33 MiB limit (k, v, dk,
+# dv double-buffered and dk, dv again in float32: 24, the row blocks 1,
+# 8 spare), the bytes `glm47f-train` runs at 4096 keys x heads of 256;
+# the forward fits Mosaic's default 16.
 #
 # The window (PR 39), measured on a v5e in the traced step of
 # `laguna-train` (heads of 128, 8 kv heads, 1 x 4096, block_q 512 against
@@ -117,7 +147,16 @@ from jax.experimental.pallas import tpu as pltpu
 # 1.50 / 1.24 times 512 x 512 a visit: a loss forward, 7% backward. Not
 # tried on the chip.
 DEFAULT_BLOCK_Q = 512
-MAX_BLOCK_K = 4096  # fused whole-sequence kv block, VMEM-capped
+# The keys 32 bits x 128 lanes address: a block of a packed selection, the
+# most a kv block held before PR 43, and the kv block of a sequence over
+# the budget below.
+MAX_BLOCK_K = 4096
+# The default kv block is the whole padded sequence where ONE k block of it,
+# as VMEM holds it (head_dim padded to the 128 lanes), is at most this: 4096
+# keys x head_dim 256 in bf16, the largest fused backward a cell ran before
+# PR 43 (`glm47f-train`), and so 8192 keys at heads of 128 in bf16
+# (`keye-train-8k`). Whether more fits the core's 128 MiB was not tried.
+KV_BLOCK_BYTES = 2 << 20
 NEG_INF = -1e30  # true -inf breeds NaN via (-inf) - (-inf)
 
 
@@ -155,6 +194,19 @@ def _fold_factor(group: int, block_q: int, block_k: int,
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
+
+
+def default_block_k(Sk: int, D: int, itemsize: int) -> int:
+    """The kv block of a call that names none: the whole padded sequence
+    (nk == 1: the fused backward) where it is within MAX_BLOCK_K keys, as it
+    always was, or where a k block of it is within KV_BLOCK_BYTES, padded to
+    whole selection blocks; else MAX_BLOCK_K keys, and the dq and dk/dv
+    kernels apart."""
+    if Sk <= MAX_BLOCK_K:
+        return _round_up(Sk, 16)
+    whole = _round_up(Sk, MAX_BLOCK_K)
+    fits = whole * _round_up(D, _LANES) * itemsize <= KV_BLOCK_BYTES
+    return whole if fits else MAX_BLOCK_K
 
 
 # ---------------------------------------------------------------------------
@@ -300,8 +352,9 @@ def _lanes(x, n):
 
 
 def selection_block(Sk: int) -> int:
-    """The kv block a packed selection over `Sk` keys is laid out by: the
-    kernels' default (the whole padded sequence up to MAX_BLOCK_K)."""
+    """The keys a block of a packed selection over `Sk` keys holds: the whole
+    padded sequence up to MAX_BLOCK_K (32 bits x 128 lanes). A kv block is
+    one of them, or several whole ones (`default_block_k`)."""
     return min(MAX_BLOCK_K, _round_up(Sk, 16))
 
 
@@ -338,9 +391,17 @@ def unpack_selection(packed: jax.Array, Sk: int) -> jax.Array:
 
 def _sel_tile(sel_ref, lo, Tk):
     """The selection of the Tk keys from `lo` of the resident kv block ->
-    bool [Bq, Tk]; sel_ref [1, Bq, 128] is the block's words. `lo` is a
-    multiple of 128 wherever a block has more than one sub-tile."""
-    words = sel_ref[0]
+    bool [Bq, Tk]; sel_ref [1, Bq, selection blocks x 128] is the block's
+    words. `lo` is a multiple of 128 wherever a block has more than one
+    sub-tile, and a sub-tile lies in one selection block: a kv block of
+    several is walked in sub-tiles of 512, which divide 4096."""
+    if sel_ref.shape[2] == _LANES:
+        words = sel_ref[0]
+    else:  # the 128 lanes of the selection block the sub-tile falls in
+        at = lo // MAX_BLOCK_K * _LANES
+        words = sel_ref[0, :, pl.ds(at if isinstance(at, int) else pl.multiple_of(at, _LANES),
+                                    _LANES)]
+        lo = lo % MAX_BLOCK_K
     first = lo // _LANES
     parts = []
     for u in range(-(-Tk // _LANES)):
@@ -349,10 +410,13 @@ def _sel_tile(sel_ref, lo, Tk):
     return (parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)) != 0
 
 
-def _sel_specs(sel, block_q: int, index_map) -> tuple:
+def _sel_specs(sel, block_q: int, block_k: int, index_map) -> tuple:
     """The input a packed selection adds to a call, after the others: its
-    words of (q block, kv block); nothing without one."""
-    return () if sel is None else (pl.BlockSpec((1, block_q, _LANES), index_map),)
+    words of (q block, kv block), 128 a selection block of the kv block;
+    nothing without one."""
+    if sel is None:
+        return ()
+    return (pl.BlockSpec((1, block_q, -(-block_k // MAX_BLOCK_K) * _LANES), index_map),)
 
 
 def _with_selection(kernel, at: int):
@@ -696,7 +760,7 @@ def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h * F // G, kv(i, j), 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, h, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, h, i, j: (b, 0, kv(i, j))),
-            *_sel_specs(sel, block_q, lambda b, h, i, j: (b, i, kv(i, j))),
+            *_sel_specs(sel, block_q, block_k, lambda b, h, i, j: (b, i, kv(i, j))),
         ],
         out_specs=[
             pl.BlockSpec((1, F, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
@@ -718,9 +782,10 @@ def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
 # What Mosaic scopes to one kernel by default on a TPU. The fused backward
 # holds the whole kv block: k, v, dk and dv double-buffered in the input
 # dtype, dk and dv again as float32 scratch. At head_dim 128 and 4096 keys
-# that is 12 MiB and fits; at head_dim 256 (MLA, models/mla.py) it is 24
-# MiB, and the kernel then states its own limit rather than lean on the
-# caller's compile options (a train step's 32 MiB, train/step.py).
+# that is 12 MiB and fits; at head_dim 256 (MLA, models/mla.py), or at 8192
+# keys and head_dim 128 (models/dsa.py), it is 24 MiB, and the kernel then
+# states its own limit rather than lean on the caller's compile options (a
+# train step's 32 MiB, train/step.py).
 _DEFAULT_SCOPED_VMEM = 16 << 20
 _VMEM_HEADROOM = 8 << 20  # the [rows, sub_k] float32 products and Mosaic's own stack
 
@@ -728,6 +793,7 @@ _VMEM_HEADROOM = 8 << 20  # the [rows, sub_k] float32 products and Mosaic's own 
 def _fused_bwd_params(block_q: int, block_k: int, D: int, F: int, itemsize: int):
     """Compiler parameters of the fused backward: None (Mosaic's default)
     where its blocks fit the default scoped VMEM, else the limit they need."""
+    D = _round_up(D, _LANES)  # as VMEM holds a row
     kv = 4 * 2 * block_k * D * itemsize + 2 * block_k * D * 4
     rows = 3 * 2 * F * block_q * D * itemsize + F * block_q * D * 4
     if kv + rows <= _DEFAULT_SCOPED_VMEM:  # 13-14 MiB at head_dim 128, bf16, 4096 keys
@@ -788,7 +854,7 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
                 pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, i, 0)),
                 pl.BlockSpec((1, F, block_q, 1), lambda b, j, h, i: (b, h, i, 0)),
                 pl.BlockSpec((1, F, block_q, 1), lambda b, j, h, i: (b, h, i, 0)),
-                *_sel_specs(sel, block_q, lambda b, j, h, i: (b, i, j)),
+                *_sel_specs(sel, block_q, block_k, lambda b, j, h, i: (b, i, j)),
             ],
             out_specs=[
                 pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, i, 0)),
@@ -827,7 +893,7 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
             pl.BlockSpec((1, F, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, F, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, F, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
-            *_sel_specs(sel, block_q, lambda b, h, i, j: (b, i, kv(i, j))),
+            *_sel_specs(sel, block_q, block_k, lambda b, h, i, j: (b, i, kv(i, j))),
         ],
         out_specs=pl.BlockSpec(
             (1, F, block_q, D), lambda b, h, i, j: (b, h, i, 0)
@@ -858,7 +924,7 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
             pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, qb(i, j), 0)),
             pl.BlockSpec((1, F, block_q, 1), lambda b, j, h, i: (b, h, qb(i, j), 0)),
             pl.BlockSpec((1, F, block_q, 1), lambda b, j, h, i: (b, h, qb(i, j), 0)),
-            *_sel_specs(sel, block_q, lambda b, j, h, i: (b, qb(i, j), j)),
+            *_sel_specs(sel, block_q, block_k, lambda b, j, h, i: (b, qb(i, j), j)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, D), lambda b, j, h, i: (b, h * F // G, j, 0)),
@@ -917,9 +983,11 @@ def _flash_lse_bwd(scale, causal, q_offset, block_q, block_k, sq_valid,
                    sk_valid, interpret, has_segments, fold, window, residuals, cts):
     do, dlse = cts
     q, k, v, qseg, kseg, o, lse, sel = residuals
-    dq, dk, dv = _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal,
-                           q_offset, block_q, block_k, sq_valid, sk_valid,
-                           interpret, has_segments, fold, dlse=dlse, window=window, sel=sel)
+    # one span a call, WHILE TRACING, says which backward it took
+    with obs.layer_span("flash.bwd_fused" if k.shape[2] == block_k else "flash.bwd_split"):
+        dq, dk, dv = _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal,
+                               q_offset, block_q, block_k, sq_valid, sk_valid,
+                               interpret, has_segments, fold, dlse=dlse, window=window, sel=sel)
     zero_seg = np.zeros(qseg.shape, dtype=jax.dtypes.float0)
     zero_kseg = np.zeros(kseg.shape, dtype=jax.dtypes.float0)
     # the selection is a constant of the backward: integers take no cotangent
@@ -997,12 +1065,14 @@ def _flash_head_major(
 
     # pad sequence dims to block multiples (sublane-aligned blocks for
     # short test sequences). Default kv block = the whole padded
-    # sequence up to MAX_BLOCK_K: nk == 1 selects the fused backward,
-    # and in-kernel sub-tiling keeps causal skipping and VMEM bounded.
-    if block_k is None:
-        block_k = MAX_BLOCK_K
+    # sequence where its bytes fit (`default_block_k`): nk == 1 selects
+    # the fused backward, and in-kernel sub-tiling keeps causal skipping
+    # and the products' VMEM bounded.
     bq = min(block_q, _round_up(Sq, 16))
-    bk = min(block_k, _round_up(Sk, 16))
+    if block_k is None:
+        bk = default_block_k(Sk, qt.shape[-1], qt.dtype.itemsize)
+    else:
+        bk = min(block_k, _round_up(Sk, 16))
     Sq_pad = _round_up(Sq, bq)
     Sk_pad = _round_up(Sk, bk)
     if Sq_pad != Sq:

@@ -366,3 +366,80 @@ def test_rows_with_nothing_to_attend_weigh_nothing():
     # keys wholly after the rows (a causal block from the future): no sub-tile runs
     o, lse = flash_attention(q[:, :256], k, v, causal=True, q_offset=-1024, return_lse=True)
     assert not np.asarray(o).any() and (np.asarray(lse) < 0.9 * NEG_INF).all()
+
+
+# -- the kv block sized by its bytes (PR 43) -------------------------------------
+
+
+@pytest.mark.parametrize("D,itemsize,Sk,want", [
+    (128, 2, 8192, 8192),    # keye-train-8k: one kv block, the backward fused
+    (128, 2, 4096, 4096),    # every other cell: what the constant gave
+    (256, 2, 8192, 4096),    # MLA's heads at 8192 keys: over the budget, two kernels
+    (128, 4, 8192, 4096),    # float32 doubles a block's bytes
+    (64, 2, 8192, 8192),
+    (128, 2, 16384, 4096),   # a sequence over the budget keeps blocks of 4096
+    (64, 2, 16384, 4096),    # VMEM holds a row of 64 in the 128 lanes: no more keys than at 128
+    (128, 2, 5000, 8192),    # padded to whole selection blocks, as two blocks of 4096 were
+    (128, 2, 300, 304),      # a short sequence: itself, padded to the sublanes
+    (256, 4, 4096, 4096),    # within 4096 keys the block was and is the sequence, whatever its bytes
+], ids=lambda x: str(x))
+def test_default_kv_block_is_the_sequence_where_its_bytes_fit(D, itemsize, Sk, want):
+    from ray_tpu.ops.flash import default_block_k
+
+    assert default_block_k(Sk, D, itemsize) == want
+
+
+def _backwards_traced(run) -> tuple:
+    """`run()` -> (fused, split): the flash backwards it traced, by the path they took."""
+    from ray_tpu import obs
+
+    def counts():
+        got = obs.layer_counters()
+        return [got.get(n, {"count": 0})["count"] for n in ("flash.bwd_fused", "flash.bwd_split")]
+
+    before = counts()
+    run()
+    return tuple(a - b for a, b in zip(counts(), before))
+
+
+@pytest.mark.parametrize("bk,want", [(None, (1, 0)), (128, (0, 1))], ids=["fused", "split"])
+def test_the_backward_counts_the_path_it_took_once_a_traced_call(bk, want):
+    """`flash.bwd_fused` / `flash.bwd_split`: one layer span a backward
+    WHILE TRACING (chipbench's `fallback_sites.train` reads them); a call
+    of the compiled function counts nothing more."""
+    q, k, v = make_qkv(jax.random.key(0), 1, 256, 256, 2, 1, 32)
+    grad = jax.jit(jax.grad(lambda q, k, v: flash_attention(q, k, v, block_q=128, block_k=bk).sum(),
+                            argnums=(0, 1, 2)))
+    assert _backwards_traced(lambda: grad.lower(q, k, v)) == want
+    compiled = grad.lower(q, k, v).compile()
+    assert _backwards_traced(lambda: jax.block_until_ready(compiled(q, k, v))) == (0, 0)
+    forward = jax.jit(lambda q, k, v: flash_attention(q, k, v, block_q=128, block_k=bk))
+    assert _backwards_traced(lambda: forward.lower(q, k, v)) == (0, 0)
+
+
+@pytest.mark.parametrize("window,seg", [(None, False), (700, False), (None, True)],
+                         ids=["causal", "window", "segments"])
+def test_one_kv_block_over_4096_keys_matches_xla(monkeypatch, window, seg):
+    """4224 keys in ONE kv block of 8192 (the budget raised to the 4 MiB
+    that float32 at the 128 lanes needs; bf16 at heads of 128 fits the
+    module's own): seventeen sub-tiles a row block at most, the fused
+    backward, value and all three gradients, with no selection."""
+    from ray_tpu.ops import flash
+
+    monkeypatch.setattr(flash, "KV_BLOCK_BYTES", 4 << 20)
+    B, S, H, KVH, D = 1, 4224, 2, 1, 32
+    assert flash.default_block_k(S, D, 4) == 8192
+    q, k, v = make_qkv(jax.random.key(11), B, S, S, H, KVH, D)
+    q = q * 0.5
+    probe = jax.random.normal(jax.random.key(12), q.shape, jnp.float32)
+    segs = jnp.broadcast_to((jnp.arange(S) >= 1500).astype(jnp.int32), (B, S)) if seg else None
+    want = jax.value_and_grad(lambda *a: (xla_attention(
+        *a, causal=True, window=window, segment_ids=segs) * probe).sum(), (0, 1, 2))(q, k, v)
+    got = []
+    traced = _backwards_traced(lambda: got.append(jax.value_and_grad(lambda *a: (flash_attention(
+        *a, causal=True, window=window, segment_ids=segs) * probe).sum(), (0, 1, 2))(q, k, v)))
+    assert traced == (1, 0)
+    assert float(got[0][0]) == pytest.approx(float(want[0]), rel=1e-4, abs=1e-3)
+    for g, w, name in zip(got[0][1], want[1], "qkv"):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-3, atol=2e-4,
+                                   err_msg=f"d{name}")

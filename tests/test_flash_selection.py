@@ -1,11 +1,12 @@
 """The flash kernels under a selection of keys (PR 42; ops/flash.py,
 `selection=`), in interpret mode on the CPU against `xla_attention`
 under the same mask: value and all three gradients with the whole kv
-sequence in one block (the fused backward), over two kv blocks (the dq
-and dk/dv kernels apart: the cell's 8192 keys take this path), with
-folded heads, padded rows and keys, segment ids and a window beside it;
-the packed layout; and `selection=None` tracing to the kernels the
-parent traced."""
+sequence in one block (the fused backward), a kv block of TWO selection
+blocks (PR 43: the cell's 8192 keys at heads of 128 in bf16 take this
+path, fused), over two kv blocks (the dq and dk/dv kernels apart: a
+sequence over the budget of bytes), with folded heads, padded rows and
+keys, segment ids and a window beside it; the packed layout; and
+`selection=None` tracing to the kernels the parent traced."""
 
 import hashlib
 
@@ -17,6 +18,7 @@ import pytest
 from ray_tpu.ops import flash
 from ray_tpu.ops.attention import attention_head_major, xla_attention
 from ray_tpu.ops.flash import flash_attention, pack_selection, unpack_selection
+from test_flash import _backwards_traced  # (fused, split) backwards a call traced
 
 
 def _mask(b, s, sk, density=0.4, seed=5):
@@ -43,16 +45,24 @@ def _against_xla(shape, *, seg=False, window=None, d_tol=2e-3, **kw):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=d_tol, atol=2e-4)
 
 
+# float32 at heads of 32 is 4 MiB a k block of 8192 keys as VMEM holds it (the 128 lanes):
+# over the module's 2 MiB, so 4224 keys are two kv blocks of 4096 and the kernels apart;
+# under a budget of 4 MiB they are ONE kv block of two selection blocks, and the backward fused
 @pytest.mark.parametrize("case", [
     dict(shape=(1, 1024, 4, 1, 64), block_q=128),             # two sub-tiles of 512, heads folded
     dict(shape=(2, 300, 3, 1, 64)),                           # padded rows and keys, one ragged tile
     dict(shape=(1, 640, 2, 2, 64), block_q=128, seg=True),    # segments beside the selection
     dict(shape=(1, 1024, 2, 1, 64), block_q=256, window=300),  # a window beside it
-    dict(shape=(1, 4224, 2, 1, 32), block_q=256),             # two kv blocks: dq and dk/dv apart
-], ids=["fused_folded", "padded", "segments", "window", "two_kv_blocks"])
-def test_selections_against_xla_attention(case):
-    shape = case.pop("shape")
-    _against_xla(shape, **case)
+    dict(shape=(1, 4224, 2, 1, 32), block_q=256, took="split"),  # two kv blocks: dq and dk/dv apart
+    dict(shape=(1, 4224, 2, 1, 32), block_q=256, budget=4 << 20),  # one kv block, two selection blocks
+    dict(shape=(1, 4224, 2, 1, 32), block_q=256, budget=4 << 20, window=700, seg=True),
+], ids=["fused_folded", "padded", "segments", "window", "two_kv_blocks",
+        "two_selection_blocks_fused", "two_selection_blocks_window_segments"])
+def test_selections_against_xla_attention(case, monkeypatch):
+    shape, took = case.pop("shape"), case.pop("took", "fused")
+    if "budget" in case:
+        monkeypatch.setattr(flash, "KV_BLOCK_BYTES", case.pop("budget"))
+    assert _backwards_traced(lambda: _against_xla(shape, **case)) == (took == "fused", took == "split")
 
 
 def test_head_major_entry_takes_the_selection_for_both_impls():

@@ -18,8 +18,11 @@ def test_keye_share_train_step_runs_the_kernels_under_a_packed_selection(v5e):
     128 experts and an eighth of the vocabulary held, ONE sequence of 8192;
     two of the cell's layers here), compiled for the described chip: the
     attention is the flash kernels under the indexer's selection, named
-    `dsa.attend.N`: one forward and, over two kv blocks of 4096, the dq
-    and the dk/dv kernels apart; the selection reaches them as ONE packed
+    `dsa.attend.N`: one forward and ONE backward (PR 43: 8192 keys at
+    heads of 128 in bf16 are one kv block, two selection blocks wide, so
+    the backward is the fused kernel, which states the 33 MiB of VMEM its
+    blocks need; this compile is also the check that Mosaic accepts the
+    block); the selection reaches them as ONE packed
     int32 [1, 8192, 256] array a layer (8 MiB), stacked over the layers
     for the backward, which computes no index score and no top-k again;
     no [.., 8192, 8192] array of any type exists, the index scores are at
@@ -28,6 +31,7 @@ def test_keye_share_train_step_runs_the_kernels_under_a_packed_selection(v5e):
     the compact path; every scope the cell's readers sum is in the
     compiled step; no site falls back."""
     from ray_tpu import obs
+    from ray_tpu.ops.flash import _fused_bwd_params
 
     step, state, batch = _train_step_at_mistral_widths(
         v5e, batch=1, model="keye-vl-2.0-30b-a3b", n_layers=2, seq=8192, vocab_size=19072,
@@ -40,15 +44,19 @@ def test_keye_share_train_step_runs_the_kernels_under_a_packed_selection(v5e):
                - before.get(name, {"count": 0})["count"]
                for name in ("dsa.attn", "moe.ffn", "grouped_matmul.kernel",
                             "grouped_matmul.ragged_dot", "tp_overlap.plain", "moe.compact",
-                            "moe.full")}
+                            "moe.full", "flash.bwd_fused", "flash.bwd_split")}
     assert engaged["dsa.attn"] >= 1 and engaged["moe.ffn"] >= 1
     assert engaged["moe.compact"] >= 1 and engaged["moe.full"] == 0
     assert engaged["grouped_matmul.kernel"] > 0
     assert engaged["grouped_matmul.ragged_dot"] == engaged["tp_overlap.plain"] == 0  # fallback_sites
+    assert engaged["flash.bwd_fused"] >= 1 and engaged["flash.bwd_split"] == 0
     hlo = compiled.as_text()
     kernels = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
     flash = sorted(re.sub(r"\.\d+$", "", k) for k in kernels if not k.startswith("ragged-dot"))
-    assert flash == ["dsa.attend"] * 3, kernels   # forward, dq, dk/dv
+    assert flash == ["dsa.attend"] * 2, kernels   # forward, fused backward
+    # the fused backward's own limit: 24 MiB of kv blocks and scratch + 1 of row blocks + 8 spare
+    assert _fused_bwd_params(512, 8192, 128, 1, 2).vmem_limit_bytes == 33 << 20
+    assert len(re.findall(r'"scoped_memory_configs":\[\{[^}]*"size":"%d"' % (33 << 20), hlo)) == 1
     assert "ragged-dot-none" not in hlo
     assert any(k.startswith("ragged-dot-tiled-wgrad") for k in kernels)
     # the selection: packed, a layer's and the stack's; nothing [T, T], whatever its type
