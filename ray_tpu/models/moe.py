@@ -52,7 +52,16 @@ models/cca.py, asks both):
     tokens) has C rows, not N * top_k. A step whose routing puts more
     than C pairs on the held experts runs that block over all the rows
     instead (`jax.lax.cond`, the same mathematics: no pair is lost
-    either way), and the statistic `compact` says which ran.
+    either way), and the statistic `compact` says which ran. The sum of
+    the C rows back into the N tokens (`_sum_of_held_rows`: the
+    combine's forward, the dispatch's backward) is a product with the
+    [N, C] 0/1 matrix where that is small (`laguna-train`, [4096,
+    2560]) and, where it is not, the same product over the band of that
+    matrix where the ones lie, the rows brought into token order first:
+    work linear in the tokens (`glm47f-train`, `keye-train-8k`:
+    [8192, 8192] and [8192, 16384]). ONE form is built a site, chosen
+    from the shapes by the two costs (`_sum_is_linear`); the counters
+    `moe.sum.product` / `moe.sum.linear` say which.
 
 And two that GLM-4.7-Flash (models/mla.py) asks, in the DeepSeek-V3 form
 (arXiv:2412.19437, arXiv:2408.15664):
@@ -377,48 +386,133 @@ def _fits(sizes, bound):
     return sizes.sum() <= bound
 
 
-def _sum_of_held_rows(y, tok, n_tokens):
-    """y [C, D], tok [C] -> [N, D]: each token's rows summed in float32.
-    A product with the [N, C] 0/1 matrix of (token, row) on the MXU: the
-    products are exact, the sum is float32. (PERF.md, PR 40: against a
-    gather of N * K rows from a zero-padded [C + 1, D] table and a
-    scatter-add.)"""
-    hot = (jnp.arange(n_tokens, dtype=tok.dtype)[:, None] == tok[None, :]).astype(y.dtype)
-    exact = jax.lax.Precision.HIGHEST if y.dtype == jnp.float32 else None
-    return jnp.dot(hot, y, precision=exact, preferred_element_type=jnp.float32).astype(y.dtype)
+# The sum of the held rows into their tokens, y [C, D] -> [N, D], is the forward
+# of `moe.combine` and the backward of `moe.dispatch`. It has two forms and ONE
+# is built a site, chosen from the shapes while tracing (`_sum_is_linear`).
+
+
+def _sum_where_equal(tokens, of, rows):
+    """rows [R, D] -> [T, D]: row r summed into the token `of[r]` names, by a
+    product with the [T, R] 0/1 matrix of (token, row) on the MXU: the
+    products are exact, the sum is float32, rounded once to the rows' type."""
+    hot = (tokens[:, None] == of[None, :]).astype(rows.dtype)
+    exact = jax.lax.Precision.HIGHEST if rows.dtype == jnp.float32 else None
+    return jnp.dot(hot, rows, precision=exact,
+                   preferred_element_type=jnp.float32).astype(rows.dtype)
+
+
+def _sum_by_product(y, tok, pairs):
+    """That product over all N tokens and C rows: 2 N C D operations for a sum
+    of C rows, at the MXU's rate (93-97% of it), and quadratic in the tokens."""
+    return _sum_where_equal(jnp.arange(pairs[0], dtype=tok.dtype), tok, y)
+
+
+# tokens a window's product sums into: of 128 / 256 / 512 the best alone at
+# `keye-train-8k`'s shape (1.000 / 0.851 / 1.167 ms) and within 3% of it at
+# `glm47f-train`'s (0.388 / 0.401 / 0.546)
+_BAND_TOKENS = 256
+
+
+def _band(n_tokens: int, top_k: int, rows: int) -> tuple[int, int]:
+    """(tokens a block, rows a window): a block's rows in token order are one
+    run of at most block x top_k, and no window is longer than C."""
+    block = min(_BAND_TOKENS, n_tokens)
+    return block, min(rows, block * top_k)
+
+
+def _sum_by_band(y, tok, pairs):
+    """The same product over the BAND where its ones lie. With the rows in
+    token order (a sort of C keys and one gather of C rows) a block of
+    tokens owns one contiguous run of rows, so a window of `_band`'s length,
+    wherever the run starts, summed into the block's tokens is the block's
+    sum: 2 N (256 top_k) D operations and N top_k rows read, both linear in
+    the tokens. A window reaches into its neighbours' runs and past the held
+    pairs, whose rows name no token of the block (or are zero) and add
+    nothing."""
+    (n_tokens, top_k), (rows, width) = pairs, y.shape
+    block, window = _band(n_tokens, top_k, rows)
+    toks, perm = jax.lax.sort((tok, jnp.arange(rows, dtype=tok.dtype)), num_keys=1)
+    ys = y[perm]
+    firsts = jnp.arange(0, n_tokens, block, dtype=tok.dtype)   # a block's first token
+    # its run starts after the rows of every token before it; a window ends inside y
+    starts = jnp.sum(tok[None, :] < firsts[:, None], axis=1, dtype=tok.dtype)
+    starts = jnp.minimum(starts, rows - window)
+
+    def sum_of_block(at):
+        start, first = at
+        return _sum_where_equal(first + jnp.arange(block, dtype=tok.dtype),
+                                jax.lax.dynamic_slice(toks, (start,), (window,)),
+                                jax.lax.dynamic_slice(ys, (start, 0), (window, width)))
+
+    # a loop, not a batch: the window is read where the product wants it and never kept
+    out = jax.lax.map(sum_of_block, (starts, firsts))
+    return out.reshape(-1, width)[:n_tokens]
+
+
+# Measured alone on a v5e, bf16, ms a call with the sort and the gather in it
+# (PERF.md, PR 44; (N, top_k, C, D)): the product 2.876 / 1.466 / 0.353 and the band
+# 0.851 / 0.401 / 0.406 at (8192, 8, 16384, 2048) `keye-train-8k`, (8192, 4, 8192,
+# 2048) `glm47f-train`, (4096, 10, 2560, 3072) `laguna-train`, where 256 x 10 rows
+# are all of C and the band IS the product, in a loop. The product ran at 93-97% of
+# the MXU's 197 TFLOP/s; the band moved the bytes reckoned below at 510-760 GB/s.
+_PRODUCT_FLOPS = 0.95 * 197e12
+_BAND_BYTES_PER_S = 510e9
+
+
+def _sum_is_linear(n_tokens: int, top_k: int, rows: int, width: int, itemsize: int) -> bool:
+    """Whether the band sums C = `rows` rows of `width` into N tokens sooner
+    than the product, from the two costs the shapes give: the product's
+    2 N C D operations at the MXU's rate, the band's bytes (every window,
+    the rows gathered into token order and written, the sums written) at
+    the rate it was measured to move them."""
+    product_s = 2 * n_tokens * rows * width / _PRODUCT_FLOPS
+    block, window = _band(n_tokens, top_k, rows)
+    windows = -(-n_tokens // block) * window
+    band_s = (windows + 2 * rows + n_tokens) * width * itemsize / _BAND_BYTES_PER_S
+    return band_s < product_s
+
+
+def _sum_of_held_rows(y, tok, pairs):
+    """y [C, D], tok [C] -> [N, D]: each token's rows summed in float32 and
+    rounded once to y's type. `pairs` = (N, top_k): the tokens, and the most
+    rows one of them can have among the C."""
+    linear = _sum_is_linear(*pairs, *y.shape, y.dtype.itemsize)
+    # counted while tracing, as `moe.compact` is: the form a site was BUILT with
+    with obs.layer_span("moe.sum.linear" if linear else "moe.sum.product"):
+        return (_sum_by_band if linear else _sum_by_product)(y, tok, pairs)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _to_held_rows(xt, tok, n_tokens):
+def _to_held_rows(xt, tok, pairs):
     """xt [N, D] -> [C, D]: the token's row for each of the first C rows
     of expert order."""
     return xt[tok]
 
 
-def _to_held_rows_fwd(xt, tok, n_tokens):
+def _to_held_rows_fwd(xt, tok, pairs):
     return xt[tok], tok
 
 
-def _to_held_rows_bwd(n_tokens, tok, g):
+def _to_held_rows_bwd(pairs, tok, g):
     with jax.named_scope("moe.dispatch"), jax.named_scope("moe.held"):
-        return _sum_of_held_rows(g, tok, n_tokens), None
+        return _sum_of_held_rows(g, tok, pairs), None
 
 
 _to_held_rows.defvjp(_to_held_rows_fwd, _to_held_rows_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _from_held_rows(y, tok, n_tokens):
+def _from_held_rows(y, tok, pairs):
     """y [C, D] in expert order -> [N, D]: the held rows summed into
     their tokens, in float32. `_to_held_rows`'s transpose."""
-    return _sum_of_held_rows(y, tok, n_tokens)
+    return _sum_of_held_rows(y, tok, pairs)
 
 
-def _from_held_rows_fwd(y, tok, n_tokens):
-    return _sum_of_held_rows(y, tok, n_tokens), tok
+def _from_held_rows_fwd(y, tok, pairs):
+    return _sum_of_held_rows(y, tok, pairs), tok
 
 
-def _from_held_rows_bwd(n_tokens, tok, g):
+def _from_held_rows_bwd(pairs, tok, g):
     with jax.named_scope("moe.combine"), jax.named_scope("moe.held"):
         return g[tok], None
 
@@ -446,10 +540,10 @@ def _held_pair_weights_bwd(inv, g):
 _held_pair_weights.defvjp(_held_pair_weights_fwd, _held_pair_weights_bwd)
 
 
-def _held_gate_up(xt, w_gate, w_up, tok, sizes):
+def _held_gate_up(xt, w_gate, w_up, tok, pairs, sizes):
     """-> gate, up [C, d_ff] of the held rows."""
     with jax.named_scope("moe.dispatch"), jax.named_scope("moe.held"):
-        xs = _to_held_rows(xt, tok, xt.shape[0])
+        xs = _to_held_rows(xt, tok, pairs)
     with jax.named_scope("moe.experts"), jax.named_scope("moe.held"):
         gmm = functools.partial(grouped_matmul, group_sizes=sizes, tail=True)
         return gmm(xs, w_gate), gmm(xs, w_up)
@@ -462,7 +556,7 @@ def _held_down_sum(gate, up, w, w_down, rows, inv, tok, sizes):
         act = (act * _held_pair_weights(w, rows, inv)[:, None]).astype(gate.dtype)
         ys = grouped_matmul(act, w_down, sizes, tail=True)
     with jax.named_scope("moe.combine"), jax.named_scope("moe.held"):
-        return _from_held_rows(ys, tok, inv.shape[0])
+        return _from_held_rows(ys, tok, inv.shape)
 
 
 def _held_rows(bound, order, inv):
@@ -476,7 +570,7 @@ def _held_or_all_fwd(bound, xt, w, w_gate, w_up, w_down, order, inv, sizes):
     runs gate and up again)."""
     def held():
         rows, tok = _held_rows(bound, order, inv)
-        gate, up = _held_gate_up(xt, w_gate, w_up, tok, sizes)
+        gate, up = _held_gate_up(xt, w_gate, w_up, tok, inv.shape, sizes)
         return _held_down_sum(gate, up, w, w_down, rows, inv, tok, sizes), gate, up
 
     def every():
@@ -523,7 +617,7 @@ def _held_or_all_vjp_bwd(bound, res, g):
             gate, up, w, w_down)
         d_gate, d_up, d_w, d_down = down_sum_t(g)
         _, gate_up_t = jax.vjp(
-            lambda xt, w_gate, w_up: _held_gate_up(xt, w_gate, w_up, tok, sizes),
+            lambda xt, w_gate, w_up: _held_gate_up(xt, w_gate, w_up, tok, inv.shape, sizes),
             xt, w_gate, w_up)
         d_xt, d_gate_w, d_up_w = gate_up_t((d_gate, d_up))
         return d_xt, d_w, d_gate_w, d_up_w, d_down

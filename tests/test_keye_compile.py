@@ -28,8 +28,11 @@ def test_keye_share_train_step_runs_the_kernels_under_a_packed_selection(v5e):
     no [.., 8192, 8192] array of any type exists, the index scores are at
     most [1, 16, 512, 8192] float32 a chunk; the held experts' grouped
     matmuls are the kernels of ops/grouped_matmul.py at [2048, 768] on
-    the compact path; every scope the cell's readers sum is in the
-    compiled step; no site falls back."""
+    the compact path, whose sums of the 16,384 held rows into the 8192
+    tokens are built in the LINEAR form at every site (PR 44: no
+    [8192, 16384] one-hot matrix is an operand or a result of anything);
+    every scope the cell's readers sum is in the compiled step; no site
+    falls back."""
     from ray_tpu import obs
     from ray_tpu.ops.flash import _fused_bwd_params
 
@@ -38,15 +41,18 @@ def test_keye_share_train_step_runs_the_kernels_under_a_packed_selection(v5e):
         experts_held=16)
     before = obs.layer_counters()
     with mock.patch("jax.default_backend", return_value="tpu"):
-        compiled = step.lower(state, batch).compile()
+        lowered = step.lower(state, batch)
+        compiled = lowered.compile()
     after = obs.layer_counters()
     engaged = {name: after.get(name, {"count": 0})["count"]
                - before.get(name, {"count": 0})["count"]
                for name in ("dsa.attn", "moe.ffn", "grouped_matmul.kernel",
                             "grouped_matmul.ragged_dot", "tp_overlap.plain", "moe.compact",
-                            "moe.full", "flash.bwd_fused", "flash.bwd_split")}
+                            "moe.full", "flash.bwd_fused", "flash.bwd_split",
+                            "moe.sum.linear", "moe.sum.product")}
     assert engaged["dsa.attn"] >= 1 and engaged["moe.ffn"] >= 1
     assert engaged["moe.compact"] >= 1 and engaged["moe.full"] == 0
+    assert engaged["moe.sum.linear"] >= 2 and engaged["moe.sum.product"] == 0   # combine, dispatch
     assert engaged["grouped_matmul.kernel"] > 0
     assert engaged["grouped_matmul.ragged_dot"] == engaged["tp_overlap.plain"] == 0  # fallback_sites
     assert engaged["flash.bwd_fused"] >= 1 and engaged["flash.bwd_split"] == 0
@@ -62,6 +68,9 @@ def test_keye_share_train_step_runs_the_kernels_under_a_packed_selection(v5e):
     # the selection: packed, a layer's and the stack's; nothing [T, T], whatever its type
     assert re.search(r"s32\[1,8192,256\]", hlo) and re.search(r"s32\[2,1,8192,256\]", hlo)
     assert not re.search(r"\[(?:\d+,)*8192,8192\]", hlo)
+    # the tokens x the held rows: lowered or compiled, no such matrix; a band's block is 256 tokens
+    assert not re.search(r"\[(?:\d+,)*8192,16384\]", hlo) and "8192x16384x" not in lowered.as_text()
+    assert re.search(r"pred\[256,2048\]", hlo) and re.search(r"f32\[256,2048\]", hlo)
     keys = {int(k) for k in re.findall(r"f32\[(?:1,)?16,512,(\d+)\]", hlo)}   # a chunk's scores
     assert keys and max(keys) == 8192 and min(keys) > 2048
     assert re.search(r"bf16\[1,32,8192,128\]", hlo) and re.search(r"bf16\[1,4,8192,128\]", hlo)

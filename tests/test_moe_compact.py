@@ -3,8 +3,14 @@ bound C on the HELD rows (`moe.held_rows_bound`), and the same block over
 all N * top_k rows when a step's routing puts more than C pairs on the
 held experts. Oracle: the same `moe_ffn` with no compact path built (the
 bound taken away), which is the program every share ran before; the two
-differ by the order of a float32 sum of at most top_k terms."""
+differ by the order of a float32 sum of at most top_k terms.
 
+The sum of the held rows into their tokens has TWO forms, chosen from
+(N, C, D) alone (`moe._sum_is_linear`, PR 44): every case of the compact
+path runs over both, the form forced through that private rule, and the
+linear form is held to the one-hot product directly."""
+
+import contextlib
 import dataclasses
 from unittest import mock
 
@@ -63,6 +69,37 @@ def _without_the_compact_path():
     return mock.patch.object(moe, "held_rows_bound", lambda *a: None)
 
 
+FORMS = {"product": False, "linear": True}
+
+
+def _sites(name):
+    return obs.layer_counters().get(name, {"count": 0})["count"]
+
+
+@contextlib.contextmanager
+def _built_with(form):
+    """Every site traced inside sums the held rows in ONE form, whatever the
+    rule says of its shape; the blocks' inner jit keeps one trace a shape
+    of the function it wraps, so it wraps a fresh one here. Yields
+    `only_that_form_was_built()`."""
+    before = {name: _sites("moe.sum." + name) for name in FORMS}
+
+    def only_that_form_was_built():
+        built = {name: _sites("moe.sum." + name) - before[name] for name in FORMS}
+        return built.pop(form) > 0 and not any(built.values())
+
+    with mock.patch.object(moe, "_sum_is_linear", lambda *shape: FORMS[form]), \
+            mock.patch.object(moe, "_held_or_all_once",
+                              jax.jit(lambda *a: moe._held_or_all(*a), static_argnums=(0,))):
+        yield only_that_form_was_built
+
+
+@pytest.fixture(params=sorted(FORMS))
+def form(request):
+    with _built_with(request.param) as only_that_form_was_built:
+        yield only_that_form_was_built
+
+
 def _close(got, want, rtol):
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     assert np.abs(got - want).max() <= rtol * max(np.abs(want).max(), 1e-30)
@@ -83,23 +120,104 @@ def test_the_bound_follows_from_the_shapes():
     assert moe.held_rows_bound(512, 1, 64) is None
 
 
+@pytest.mark.parametrize("shape,linear", [
+    ((4096, 10, 2560, 3072), False), ((8192, 4, 8192, 2048), True),
+    ((8192, 8, 16384, 2048), True)], ids=["laguna-train", "glm47f-train", "keye-train-8k"])
+def test_the_form_of_the_sum_follows_from_the_shapes(shape, linear):
+    """(N, top_k, C, D) of the benchmark's three small shares in bf16, each
+    as the two forms were measured alone on the chip (PERF.md, PR 44), and
+    nothing but the shapes: the rule takes no configuration."""
+    assert moe._sum_is_linear(*shape, itemsize=2) is linear
+    # the product where a window is most of C, linear past the measured points
+    assert not moe._sum_is_linear(512, 4, 512, 64, itemsize=4)
+    assert moe._sum_is_linear(16384, 8, 32768, 2048, itemsize=2)
+
+
+def _rows_of_a_share(routing, dtype, C=1536, N=576, most=4, seed=3):
+    """y [C, D] in expert order and the token of each row: tokens with 0, 1,
+    .. `most` held rows, then (past the held pairs) rows of pairs routed
+    elsewhere, which `_zero_tail` made zero; N is no multiple of the band's
+    block of tokens and the last window is pushed back inside y."""
+    rng = np.random.default_rng(seed)
+    held = {"half_of_C": C // 2, "exactly_C": C, "none_held": 0}[routing]
+    per_token = np.zeros(N, np.int64)
+    per_token[: held // most] = most             # `most` rows each ...
+    per_token[held // most: held // most + held % most] = 1   # ... and one each for the rest
+    per_token = rng.permutation(per_token)       # the other tokens have none
+    tok = rng.permutation(np.repeat(np.arange(N), per_token))
+    elsewhere = rng.permutation(np.repeat(np.arange(N), most - per_token))[: C - held]
+    tok = np.concatenate([tok, elsewhere]).astype(np.int32)   # a token has `most` pairs in all
+    y = rng.standard_normal((C, D)).astype(np.float32) * np.exp(rng.uniform(-3, 3, (C, 1)))
+    y[held:] = 0
+    return jnp.asarray(y, dtype), jnp.asarray(tok), per_token, (N, most)
+
+
+def _summed(direction, y, tok, pairs):
+    if direction == "from_held_rows_forward":
+        return moe._from_held_rows(y, tok, pairs)
+    xt = jnp.zeros((pairs[0], D), y.dtype)
+    (d_xt,) = jax.vjp(lambda xt: moe._to_held_rows(xt, tok, pairs), xt)[1](y)
+    return d_xt
+
+
+@pytest.mark.parametrize("routing", ["half_of_C", "exactly_C", "none_held"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("direction", ["from_held_rows_forward", "to_held_rows_backward"])
+def test_linear_sum_of_the_held_rows_is_the_one_hot_product(direction, dtype, routing):
+    """Both directions of the pair (`moe.combine`'s forward, `moe.dispatch`'s
+    backward): the linear form sums the same rows in float32 and rounds once,
+    so it differs from the product by the order of at most `most` float32
+    additions; a token with no held row reads exact zero, a token with one
+    reads that row, rows past the held pairs add nothing."""
+    y, tok, per_token, pairs = _rows_of_a_share(routing, dtype)
+    N = pairs[0]
+    got, sums = {}, {}
+    for name in FORMS:
+        with _built_with(name) as only_that_form_was_built:
+            got[name] = np.asarray(_summed(direction, y, tok, pairs), np.float32)
+            assert only_that_form_was_built()
+            # the same rows' float32 sum, the form's own order, before the one rounding
+            sums[name] = _summed(direction, y.astype(jnp.float32), tok, pairs)
+    lin, prod = got["linear"], got["product"]
+    assert lin.shape == (N, D) and not lin[per_token == 0].any()
+    rows = np.asarray(y, np.float32)[: int(per_token.sum())]
+    toks = np.asarray(tok)[: len(rows)]
+    for t in np.flatnonzero(per_token == 1)[:8]:
+        np.testing.assert_array_equal(lin[t], rows[toks == t][0])
+    magnitude = np.zeros((N, D))
+    np.add.at(magnitude, toks, np.abs(rows, dtype=np.float64))
+    reordering = 8 * np.finfo(np.float32).eps * magnitude   # of at most eight additions
+    f32 = {name: np.asarray(s, np.float32) for name, s in sums.items()}
+    assert (np.abs(f32["linear"].astype(np.float64) - f32["product"]) <= reordering).all()
+    if dtype == "float32":
+        np.testing.assert_array_equal(lin, f32["linear"])
+        assert routing == "none_held" or np.abs(lin).max() > 1
+    else:   # ONE rounding of the float32 sum: no bfloat16 accumulation
+        np.testing.assert_array_equal(lin, np.asarray(sums["linear"].astype(jnp.bfloat16),
+                                                      np.float32))
+        same = f32["linear"] == f32["product"]
+        assert same.mean() > 0.9
+        np.testing.assert_array_equal(lin[same], prod[same])
+
+
 @pytest.mark.parametrize("router", sorted(ROUTERS))
 @pytest.mark.parametrize("share", sorted(SHARES))
-def test_compact_block_is_the_block_over_all_rows(share, router):
+def test_compact_block_is_the_block_over_all_rows(share, router, form):
     """Output, every statistic and every gradient (x, the three expert
-    weights, the router, the shared expert's) are the full path's."""
+    weights, the router, the shared expert's) are the full path's, with
+    either form of the sum of the held rows."""
     cfg = _config(share, router)
     lp, x = _layer(cfg, router), _tokens(share)
-    before = obs.layer_counters().get("moe.compact", {"count": 0})["count"]
+    before = _sites("moe.compact")
     out, stats, grads = _value_stats_grads(cfg, x, lp)
-    built = obs.layer_counters().get("moe.compact", {"count": 0})["count"] - before
+    built = _sites("moe.compact") - before
     with _without_the_compact_path():
         want_out, want_stats, want_grads = _value_stats_grads(cfg, x, lp)
     E, held, K, N = SHARES[share]
     if share == "zaya":
         assert not built and "compact" not in stats
     else:
-        assert built and int(stats["compact"]) == 1
+        assert built and int(stats["compact"]) == 1 and form()
         assert 0 < N * K - int(stats["pairs_elsewhere"]) <= moe.held_rows_bound(N * K, held, E)
     assert "compact" not in want_stats
     for key in STATS:
@@ -114,13 +232,13 @@ def test_compact_block_is_the_block_over_all_rows(share, router):
         assert np.abs(np.asarray(grads[1][name])).max() > 0, name
 
 
-def test_compact_block_in_bfloat16_rounds_a_float32_sum_as_the_full_one_does():
+def test_compact_block_in_bfloat16_rounds_a_float32_sum_as_the_full_one_does(form):
     cfg = _config("glm", "sigmoid", dtype=jnp.bfloat16)
     lp, x = _layer(cfg, "sigmoid"), _tokens("glm").astype(jnp.bfloat16)
     out, stats, grads = _value_stats_grads(cfg, x, lp)
     with _without_the_compact_path():
         want_out, _, want_grads = _value_stats_grads(cfg, x, lp)
-    assert int(stats["compact"]) == 1 and out.dtype == jnp.bfloat16
+    assert int(stats["compact"]) == 1 and out.dtype == jnp.bfloat16 and form()
     _close(out, want_out, 2 ** -7)  # one bfloat16 rounding of a sum taken in another order
     for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
         _close(got, want, 2 ** -6)
@@ -147,7 +265,7 @@ def _two_kinds_of_token(cfg, n_held_tokens, n_tokens):
 
 @pytest.mark.parametrize("held_tokens,compact", [(128, 1), (129, 0), (512, 0), (0, 1)],
                          ids=["exactly_C", "C_plus_one_token", "every_pair_held", "none_held"])
-def test_a_routing_past_the_bound_runs_over_all_rows_and_loses_no_pair(held_tokens, compact):
+def test_a_routing_past_the_bound_runs_over_all_rows_and_loses_no_pair(held_tokens, compact, form):
     """GLM's share (top-4, 8 of 64 held) over 512 tokens: C = 512 rows of
     2,048. `held_tokens` tokens put all four pairs on held experts."""
     cfg = _config("glm")
@@ -158,7 +276,7 @@ def test_a_routing_past_the_bound_runs_over_all_rows_and_loses_no_pair(held_toke
     out, stats, grads = _value_stats_grads(cfg, x, lp)
     assert N * K - int(stats["pairs_elsewhere"]) == held_tokens * K
     assert int(stats["compact"]) == compact == int(held_tokens * K <= bound)
-    assert int(stats["dropped_pairs"]) == 0
+    assert int(stats["dropped_pairs"]) == 0 and form()
     with _without_the_compact_path():
         want_out, want_stats, want_grads = _value_stats_grads(cfg, x, lp)
     for key in STATS:
@@ -195,7 +313,7 @@ def test_only_a_small_share_traces_a_branch(share, held, branches):
         assert stats["compact"].shape == () and stats["compact"].dtype == jnp.int32
 
 
-def test_compact_path_keeps_no_array_of_all_pairs_between_forward_and_backward():
+def test_compact_path_keeps_no_array_of_all_pairs_between_forward_and_backward(form):
     """What the forward hands the backward under the decoder's "dots"
     policy: the held rows' gate and up [C, d_ff] by name and nothing of
     N * top_k rows and model or expert width (a `cond` differentiated as
@@ -210,15 +328,16 @@ def test_compact_path_keeps_no_array_of_all_pairs_between_forward_and_backward()
     block = llama._remat(lambda x, lp: moe.moe_ffn(x, lp, cfg)[0], cfg)
     _, vjp = jax.vjp(block, x, lp)
     kept = [leaf.shape for leaf in jax.tree.leaves(vjp) if hasattr(leaf, "shape")]
-    assert kept.count((bound, F)) == 2, kept
+    assert kept.count((bound, F)) == 2 and form(), kept
     wide = [s for s in kept if len(s) >= 2 and s[0] in (N * K,) and s[-1] in (D, F)]
     assert not wide and (N, K, D) not in kept, kept
 
 
-def test_compact_path_lowers_under_a_mesh():
+def test_compact_path_lowers_under_a_mesh(form):
     """Under a mesh the grouped matmuls are `jax.lax.ragged_dot` and the
-    partitioner's: a train step of a small share over dp 2 x ep 2 x tp 2
-    meets the loss of the unsharded one and runs every block compact."""
+    partitioner's, as is either form of the sum of the held rows: a train
+    step of a small share over dp 2 x ep 2 x tp 2 meets the loss of the
+    unsharded one and runs every block compact."""
     import optax
 
     from ray_tpu.models import llama
@@ -247,5 +366,5 @@ def test_compact_path_lowers_under_a_mesh():
     _, metrics = step(TrainState.create(params, opt), batch)
     assert abs(float(metrics["loss"]) - unsharded) < 1e-4 * unsharded
     stats = metrics["stats"]
-    assert np.asarray(stats["compact"]).tolist() == [1] * cfg.n_layers
+    assert np.asarray(stats["compact"]).tolist() == [1] * cfg.n_layers and form()
     assert not np.asarray(stats["dropped_pairs"]).any()

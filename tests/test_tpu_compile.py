@@ -348,8 +348,10 @@ _ZAYA_STEP = "5c0e2e3ba71539f56323de421562ccae59c9377d2e3b1531e6a4a2459053d47d"
 # layers and the MTP block, 8 of 64 experts and an eighth of the vocabulary held, batch 2), as
 # PR 40 lowers it: replaced ON PURPOSE, its five expert blocks are built with the compact
 # path (8 of 64 held: a `cond` over 8,192 of 32,768 pair rows); from commit 955060c (the
-# parent of PR 38, whose branch CCA and MLA bypass) to PR 39 it was e02a2611...
-_GLM_LITE_STEP = "9ff87ef7aab4ca1e62011d66677f84dab2aee8ab9578e1f7c91d8d4309967dd1"
+# parent of PR 38, whose branch CCA and MLA bypass) to PR 39 it was e02a2611...; and as
+# PR 44 lowers it: replaced ON PURPOSE again, the sum of its 8,192 held rows into 8,192
+# tokens is the band where it was the [8192, 8192] one-hot product (9ff87ef7... from PR 40)
+_GLM_LITE_STEP = "a3bebfc76d0379f05c0b4184981fd00c826b8233b90f4b9505e2848a85d87357"
 
 
 @pytest.mark.parametrize("kwargs,want", [
@@ -376,7 +378,10 @@ def test_dense_train_step_lowers_to_the_text_it_had_before_the_expert_layer(v5e,
     PR 40 MEANT to alter the steps of the SMALL shares (GLM-4.7-Flash's
     hash replaced; Laguna's step is held by its own tests below): the
     dense steps, OLMoE's (every expert held) and ZAYA1's (a half share:
-    no compact path is built) keep theirs."""
+    no compact path is built) keep theirs. PR 44 MEANT to alter the
+    small shares whose [N, C] is large (GLM-4.7-Flash's hash replaced
+    again; Keye's step is held by tests/test_keye_compile.py): the four
+    above never reach the sum of the held rows and keep theirs."""
     import hashlib
     import re
 
@@ -627,8 +632,10 @@ def test_laguna_share_train_step_sizes_the_expert_layer_by_the_held_rows(v5e):
     the 40,960 pair rows at model or expert width ([40960, 3072],
     [40960, 1024], [4096, 10 or 16, 3072]) and runs the block's nine
     kernels over 2,560 rows; the other branch is today's block, whole;
-    every site is built compact and none falls back to `ragged_dot`; and
-    the step takes no more memory than its parent's 9.06 GiB of
+    every site is built compact, with the sum of the held rows into
+    their tokens as the one-hot product (PR 44: 256 tokens x top-10 rows
+    are all of C here, the band would be the product in a loop), and none
+    falls back to `ragged_dot`; and the step takes no more memory than its parent's 9.06 GiB of
     arguments + 4.00 of temporaries (3.88: the branch over all rows keeps
     its temporaries, the kept gate / up are [2560, 1024] a block)."""
     from ray_tpu import obs
@@ -642,8 +649,10 @@ def test_laguna_share_train_step_sizes_the_expert_layer_by_the_held_rows(v5e):
     engaged = {name: after.get(name, {"count": 0})["count"]
                - before.get(name, {"count": 0})["count"]
                for name in ("moe.compact", "moe.full", "grouped_matmul.kernel",
-                            "grouped_matmul.ragged_dot")}
+                            "grouped_matmul.ragged_dot", "moe.sum.product", "moe.sum.linear")}
     assert engaged["moe.compact"] >= 4 and engaged["moe.full"] == 0
+    # at [4096, 2560] the sum of the held rows stays the one-hot product (PR 44)
+    assert engaged["moe.sum.product"] >= 2 and engaged["moe.sum.linear"] == 0
     assert engaged["grouped_matmul.kernel"] > 0 and engaged["grouped_matmul.ragged_dot"] == 0
     hlo = compiled.as_text()
     computations = dict(re.findall(r"^%?([\w.\-]+) \([^\n]*\{\n(.*?)^\}", hlo, re.M | re.S))
