@@ -26,12 +26,42 @@ os.environ.setdefault("RAY_TPU_NUM_CPUS", "8")
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
+# Under pytest-xdist every worker that lets `serve.run` start the HTTP proxy binds its
+# default port, and two of them at once collide on 8000 (ROADMAP D8's flaky list:
+# test_llm_disagg.py and test_llm_kvtier.py against test_serve.py). No test addresses
+# the default port, so each worker has its own.
+_worker = os.environ.get("PYTEST_XDIST_WORKER", "")[2:]   # "gw3" -> "3"
+if _worker.isdigit():
+    from ray_tpu.serve.config import HTTPOptions  # noqa: E402
+
+    assert HTTPOptions.__init__.__defaults__ == ("127.0.0.1", 8000, "")
+    HTTPOptions.__init__.__defaults__ = ("127.0.0.1", 8001 + int(_worker), "")
+
 
 @pytest.fixture(scope="session")
 def cpu_devices():
     devs = jax.devices("cpu")
     assert len(devs) == 8, f"expected 8 virtual cpu devices, got {len(devs)}"
     return devs
+
+
+@pytest.fixture
+def backwards_traced():
+    """`backwards_traced(run)` -> (fused, split): the flash backwards that
+    `run()` traced, by the path they took (tests/test_flash.py,
+    tests/test_flash_selection.py: a test imports from no test module)."""
+    from ray_tpu import obs
+
+    def counts():
+        got = obs.layer_counters()
+        return [got.get(n, {"count": 0})["count"] for n in ("flash.bwd_fused", "flash.bwd_split")]
+
+    def traced(run) -> tuple:
+        before = counts()
+        run()
+        return tuple(a - b for a, b in zip(counts(), before))
+
+    return traced
 
 
 # Cases of tests/chipbench/test_chipbench_zaya.py that spell out the FOUR cells the

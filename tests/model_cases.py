@@ -1,0 +1,331 @@
+"""What the model files (tests/test_zaya.py, test_glm_lite.py,
+test_laguna.py, test_keye.py, test_moe.py) and
+tests/test_model_contract.py share. No test lives here (pytest does not
+collect the file).
+
+A model is a row of `MODELS`: its tiny preset in float32, its plain
+reference, its `shape_of` (the reference reads the configuration file's
+key names) and the names of the leaves that `init_params` leaves at one
+or zero. Everything else is written once: the tokens, the seeded
+parameters, the worst leaf of two gradient trees, and the train path and
+the reference's taken ONCE a process for one configuration, under
+`jax.jit` (`train_path`, `reference_path`: a whole-model gradient taken
+bare is traced operation by operation, four to five times the seconds).
+The next model costs a row and a `shape_of`."""
+
+import contextlib
+import dataclasses
+import functools
+import json
+import os
+import types
+from typing import Callable
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from chipbench.reference import glm_lite_decoder, keye_decoder, laguna_decoder, zaya_decoder
+from ray_tpu.models import cca, dsa, laguna, llama, mla
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+
+
+# -- the batch, the parameters, the worst leaf ----------------------------------------
+
+
+def skewed_tokens(cfg, batch, seq, seed=1, power=1.1) -> dict:
+    """Zipf-like tokens: a few ids make most of the batch, as the
+    benchmark's traffic does, so the experts' groups are uneven."""
+    rng = np.random.default_rng(seed)
+    p = 1.0 / np.arange(1, cfg.vocab_size + 1) ** power
+    ids = rng.choice(cfg.vocab_size, size=(batch, seq + 1), p=p / p.sum())
+    return {"tokens": jnp.asarray(ids[:, :-1], jnp.int32),
+            "targets": jnp.asarray(ids[:, 1:], jnp.int32)}
+
+
+def uniform_tokens(cfg, batch, seq, seed=1) -> dict:
+    tok = jax.random.randint(jax.random.key(seed), (batch, seq + 1), 0, cfg.vocab_size)
+    return {"tokens": tok[:, :-1], "targets": tok[:, 1:]}
+
+
+def spread(tree: dict, scales: dict, keys) -> None:
+    """Move the named leaves off the one or zero they start at, each by its
+    scale x a normal table from the next key, so that a test sees them."""
+    for name, scale in scales.items():
+        tree[name] = tree[name] + scale * jax.random.normal(next(keys), tree[name].shape)
+
+
+def worst_leaf(got, want, skip=("router_bias",)) -> dict:
+    """{path: largest difference of a leaf over the leaf's own scale}; a
+    leaf named in `skip` takes no gradient on either side."""
+    worst = {}
+    for path, g in jax.tree_util.tree_leaves_with_path(got):
+        w = want
+        for k in path:
+            w = w[k.key]
+        name = jax.tree_util.keystr(path)
+        if any(s in name for s in skip):
+            assert float(jnp.abs(g).max()) == 0.0 and float(jnp.abs(w).max()) == 0.0
+            continue
+        worst[name] = float(jnp.abs(g - w).max()) / max(float(jnp.abs(w).max()), 1e-12)
+    return worst
+
+
+# -- a model's row -----------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class Model:
+    name: str                      # the id of its cases
+    fp32: object                   # the tiny preset, computed in float32
+    batch: int
+    seq: int
+    reference: types.ModuleType    # chipbench/reference/<...>_decoder.py
+    shape_of: Callable             # a configuration as the reference reads it
+    norms: Callable                # params -> [(subtree, {leaf: scale})], in the keys' order
+    n_keys: int                    # how many keys the seed is split into
+    bias: float                    # the selection biases' scale where a test names none
+    preset: str                    # the registry's name of the published configuration
+    tiny: str                      # the registry's name of the tiny preset
+    refused_as: str                # what the engine's refusal names
+    catalog: str                   # the catalog's name of the configuration
+    config_file: str               # chipbench/configs/<...>, where the catalog is absent
+    facts: dict                    # {field: value} of the published preset
+    # what the contract's cases differ by from model to model: every row states each
+    remat_plain: dict              # the remat cases' plain configuration, over fp32
+    remat_bias: float              # the selection biases' scale under the remat cases
+    remat_tol: dict                # the remat gradients against the plain ones
+    bf16: dict                     # the bf16 case's configuration, over fp32
+    bf16_rel: float                # its loss against the reference's
+    tokens: Callable               # (cfg, batch, seq) -> the batch
+    reference_set_up: Callable     # a context around the reference's calls
+
+    def batch_of(self, cfg) -> dict:
+        return self.tokens(cfg, self.batch, self.seq)
+
+
+@functools.lru_cache(maxsize=None)
+def _seeded(model: Model, cfg, bias, seed):
+    params = llama.init_params(cfg, jax.random.key(seed))
+    keys = iter(jax.random.split(jax.random.key(seed + 100), model.n_keys))
+    for tree, scales in model.norms(params):
+        spread(tree, scales, keys)
+    table = params["layers"]["router_bias"]
+    params["layers"]["router_bias"] = bias * jax.random.normal(next(keys), table.shape)
+    return params
+
+
+def seeded_params(model: Model, cfg, bias=None, seed=0):
+    """init_params with the leaves that start at one or zero moved off
+    them (a norm's scale at one would hide the norm) and the selection
+    biases at `bias` x a random table (0: the published forward). Made
+    once a configuration; the caller's copy of the tree is its own."""
+    params = _seeded(model, cfg, model.bias if bias is None else bias, seed)
+    return jax.tree.map(lambda x: x, params)
+
+
+def _once_a_configuration(made):
+    """`made(model, cfg, bias)` once a process for one configuration and
+    scale of the selection biases (None: the model's own)."""
+    cached = functools.lru_cache(maxsize=None)(made)
+    return functools.wraps(made)(lambda model, cfg, bias=None: cached(
+        model, cfg, model.bias if bias is None else bias))
+
+
+@_once_a_configuration
+def train_path(model: Model, cfg, bias) -> types.SimpleNamespace:
+    """llama.loss_and_weight_fn (the one train path) on the model's seeded
+    parameters and batch: loss, weight, stats and every gradient, from
+    one jitted program at the matmuls' highest precision."""
+    params, batch = seeded_params(model, cfg, bias), model.batch_of(cfg)
+
+    def f(p):
+        loss, weight, stats = llama.loss_and_weight_fn(p, batch, cfg)
+        return loss, (weight, stats)
+
+    with jax.default_matmul_precision("highest"):
+        (loss, (weight, stats)), grads = jax.jit(jax.value_and_grad(f, has_aux=True))(params)
+    return types.SimpleNamespace(params=params, batch=batch, loss=loss, weight=weight,
+                                 stats=stats, grads=grads)
+
+
+@_once_a_configuration
+def reference_path(model: Model, cfg, bias) -> types.SimpleNamespace:
+    """The plain reference on the same parameters and batch: its
+    `loss_parts` and the gradients of its loss, from one jitted program."""
+    params, batch, shape = seeded_params(model, cfg, bias), model.batch_of(cfg), model.shape_of(cfg)
+
+    def f(p):
+        parts = model.reference.loss_parts(p, batch["tokens"], batch["targets"], shape)
+        return parts["loss"], parts
+
+    with model.reference_set_up(), jax.default_matmul_precision("highest"):
+        (_, parts), grads = jax.jit(jax.value_and_grad(f, has_aux=True))(params)
+    return types.SimpleNamespace(parts=parts, grads=grads)
+
+
+def catalog_config(model: Model) -> dict:
+    """The published configuration: the catalog's row, or where there is no
+    catalog the benchmark's configuration file with its published values
+    put back."""
+    if os.path.exists(CATALOG):
+        for line in open(CATALOG):
+            row = json.loads(line)
+            if row["name"] == model.catalog:
+                return row["config"]
+    file = json.load(open(os.path.join(REPO, "chipbench", "configs", model.config_file)))
+    return {**{k: v for k, v in file.items() if k not in file["published"]}, **file["published"]}
+
+
+# -- the rows ------------------------------------------------------------------------------
+
+
+def zaya_shape(cfg) -> dict:
+    """A ZayaConfig as the configuration file's dict (HF key names)."""
+    return {
+        "hidden_size": cfg.d_model, "num_attention_heads": cfg.n_heads,
+        "num_key_value_heads": cfg.n_kv_heads, "head_dim": cfg.head_dim,
+        "cca_time0": cfg.conv_kernels[0], "cca_time1": cfg.conv_kernels[1],
+        "partial_rotary_factor": cfg.rotary_fraction,
+        "rope_parameters": {"hybrid": {"rope_theta": cfg.rope_theta}},
+        "rms_norm_eps": cfg.rms_eps, "router_hidden_size": cfg.router_hidden,
+        "num_experts": cfg.n_held, "published": {"num_experts": cfg.n_experts},
+        "deployment": {"first_expert_held": cfg.first_expert_held},
+        "num_experts_per_tok": cfg.top_k, "max_position_embeddings": cfg.max_seq,
+        "num_hidden_layers": cfg.n_layers, "tie_word_embeddings": cfg.tie_embeddings,
+        "vocab_size": cfg.vocab_size,
+    }
+
+
+def glm_lite_shape(cfg) -> dict:
+    """A GlmLiteConfig as the configuration file's dict (HF key names)."""
+    return {
+        "hidden_size": cfg.d_model, "num_attention_heads": cfg.n_heads,
+        "q_lora_rank": cfg.q_lora_rank, "kv_lora_rank": cfg.kv_lora_rank,
+        "qk_nope_head_dim": cfg.qk_nope_head_dim, "qk_rope_head_dim": cfg.qk_rope_head_dim,
+        "v_head_dim": cfg.v_head_dim, "rope_theta": cfg.rope_theta,
+        "rms_norm_eps": cfg.rms_eps, "n_routed_experts": cfg.n_held,
+        "published": {"n_routed_experts": cfg.n_experts},
+        "deployment": {"first_expert_held": cfg.first_expert_held},
+        "num_experts_per_tok": cfg.top_k, "norm_topk_prob": cfg.norm_topk_prob,
+        "routed_scaling_factor": cfg.routed_scaling,
+        "first_k_dense_replace": cfg.first_dense_layers,
+        "num_nextn_predict_layers": cfg.mtp_layers, "mtp_loss_weight": cfg.mtp_loss_weight,
+        "max_position_embeddings": cfg.max_seq, "num_hidden_layers": cfg.n_layers,
+        "tie_word_embeddings": cfg.tie_embeddings, "vocab_size": cfg.vocab_size,
+    }
+
+
+def _rope_group(r: laguna.Rotary) -> dict:
+    return {"rope_theta": r.theta, "rope_type": r.rope_type, "factor": r.factor,
+            "original_max_position_embeddings": r.original_max, "beta_fast": r.beta_fast,
+            "beta_slow": r.beta_slow, "attention_factor": r.attention_factor,
+            "partial_rotary_factor": r.partial}
+
+
+def laguna_shape(cfg) -> dict:
+    """A LagunaConfig as the configuration file's dict (HF key names)."""
+    n = cfg.n_layers
+    return {
+        "hidden_size": cfg.d_model, "head_dim": cfg.head_dim,
+        "num_key_value_heads": cfg.n_kv_heads, "num_hidden_layers": n,
+        "num_attention_heads_per_layer": list(cfg.heads_per_layer[:n]),
+        "layer_types": list(cfg.layer_types[:n]), "sliding_window": cfg.sliding_window,
+        "mlp_layer_types": ["dense"] * cfg.first_dense_layers + ["sparse"] * cfg.n_expert_layers,
+        "rope_parameters": {laguna.FULL: _rope_group(cfg.rope_full),
+                            laguna.SLIDING: _rope_group(cfg.rope_sliding)},
+        "rms_norm_eps": cfg.rms_eps, "num_experts": cfg.n_held,
+        "published": {"num_experts": cfg.n_experts},
+        "deployment": {"first_expert_held": cfg.first_expert_held},
+        "num_experts_per_tok": cfg.top_k, "norm_topk_prob": cfg.norm_topk_prob,
+        "moe_routed_scaling_factor": cfg.routed_scaling,
+        "max_position_embeddings": cfg.max_seq, "tie_word_embeddings": cfg.tie_embeddings,
+        "vocab_size": cfg.vocab_size,
+    }
+
+
+def keye_shape(cfg) -> dict:
+    """A KeyeConfig as the configuration file's dict (HF key names)."""
+    return {
+        "hidden_size": cfg.d_model, "head_dim": cfg.head_dim,
+        "num_attention_heads": cfg.n_heads, "num_key_value_heads": cfg.n_kv_heads,
+        "num_hidden_layers": cfg.n_layers, "rope_theta": cfg.rope_theta,
+        "rms_norm_eps": cfg.rms_eps, "num_experts": cfg.n_held,
+        "sa_config": {"indexer_num_heads": cfg.indexer_heads,
+                      "indexer_head_dim": cfg.indexer_head_dim, "topk": cfg.indexer_topk},
+        "published": {"num_experts": cfg.n_experts},
+        "deployment": {"first_expert_held": cfg.first_expert_held},
+        "num_experts_per_tok": cfg.top_k, "norm_topk_prob": cfg.norm_topk_prob,
+        "max_position_embeddings": cfg.max_seq, "tie_word_embeddings": cfg.tie_embeddings,
+        "mlp_only_layers": [], "decoder_sparse_step": 1, "vocab_size": cfg.vocab_size,
+    }
+
+
+_LN = {"ln1": 0.2, "ln2": 0.2}
+_MLA_NORMS = {**_LN, "q_a_norm": 0.2, "kv_a_norm": 0.2}
+_REMAT_TOL = dict(rtol=1e-4, atol=2e-6)
+
+
+def _laguna_norms(params) -> list:
+    blocks = [block for group in ("period", "tail")
+              for block in params["layers"].get(group, {}).values()]
+    return [(params["dense_layers"], _LN), *((block, _LN) for block in blocks),
+            (params, {"final_norm": 0.2})]
+
+
+ZAYA = Model(
+    name="zaya", fp32=dataclasses.replace(cca.ZAYA_TINY, dtype=jnp.float32), batch=2, seq=24,
+    reference=zaya_decoder, shape_of=zaya_shape, n_keys=8, bias=0.05,
+    # the temperature, the router's carried scale and its norm beside the block's norms
+    norms=lambda p: [(p["layers"], {"temp": 0.3, "router_gamma": 0.5, "router_norm": 0.3, **_LN})],
+    preset="zaya1-8b", tiny="zaya-tiny", refused_as="ZAYA1", catalog="ZAYA1-8B",
+    config_file="zaya1-8b-train.json", facts={"head_dim": 128},
+    remat_plain={}, remat_bias=0.05, remat_tol=_REMAT_TOL, bf16={}, bf16_rel=0.02,
+    tokens=skewed_tokens, reference_set_up=contextlib.nullcontext,
+)
+GLM_LITE = Model(
+    name="glm_lite", fp32=dataclasses.replace(mla.GLM_LITE_TINY, dtype=jnp.float32),
+    batch=2, seq=24, reference=glm_lite_decoder, shape_of=glm_lite_shape, n_keys=64, bias=0.1,
+    norms=lambda p: [(p["layers"], _MLA_NORMS), (p["dense_layers"], _MLA_NORMS),
+                     (p["mtp"]["block"], _MLA_NORMS),
+                     (p["mtp"], {"enorm": 0.2, "hnorm": 0.2, "final_norm": 0.2}),
+                     (p, {"final_norm": 0.2})],
+    preset="glm-4.7-flash", tiny="glm-lite-tiny", refused_as="GLM-4.7-Flash",
+    catalog="GLM-4.7-Flash", config_file="glm-4.7-flash-train.json",
+    facts={"head_dim": 256, "n_expert_layers": 46, "first_dense_layers": 1, "mtp_layers": 1,
+           "router_score": "sigmoid", "routed_scaling": 1.8, "shared_d_ff": 1536},
+    remat_plain={}, remat_bias=0.1, remat_tol=_REMAT_TOL, bf16={}, bf16_rel=0.02,
+    tokens=skewed_tokens, reference_set_up=contextlib.nullcontext,
+)
+LAGUNA = Model(
+    name="laguna", fp32=dataclasses.replace(laguna.LAGUNA_TINY, dtype=jnp.float32),
+    batch=2, seq=40,   # the window (24) shorter than the sequence
+    reference=laguna_decoder, shape_of=laguna_shape, n_keys=64, bias=0.0, norms=_laguna_norms,
+    preset="laguna-s-2.1", tiny="laguna-tiny", refused_as="Laguna", catalog="Laguna-S-2.1",
+    config_file="laguna-s-2.1-train.json",
+    facts={"head_dim": 128, "rope_full.attention_factor": 1.4852030263919618,
+           "rope_full.partial": 0.5},
+    remat_plain=dict(n_layers=5), remat_bias=0.05,   # the dense layer and one period
+    remat_tol=_REMAT_TOL, bf16=dict(attention_impl="flash", n_layers=5), bf16_rel=0.02,
+    tokens=skewed_tokens, reference_set_up=contextlib.nullcontext,
+)
+KEYE = Model(
+    name="keye", fp32=dataclasses.replace(dsa.KEYE_TINY, dtype=jnp.float32),
+    batch=2, seq=64,   # topk 16 and chunks of 16 queries: the first chunk computes no score
+    reference=keye_decoder, shape_of=keye_shape, n_keys=8, bias=0.0,
+    norms=lambda p: [(p["layers"], {"ln1": 0.1, "ln2": 0.1, "q_norm": 0.1, "k_norm": 0.1,
+                                    "idx_norm_w": 0.1, "idx_norm_b": 0.1})],
+    preset="keye-vl-2.0-30b-a3b", tiny="keye-tiny", refused_as="Keye",
+    catalog="Keye-VL-2.0-30B-A3B", config_file="keye-vl-2.0-30b-a3b-train.json",
+    facts={"head_dim": 128, "indexer_heads": 16, "indexer_head_dim": 64, "indexer_topk": 2048,
+           "index_chunk": 512, "n_experts": 128, "top_k": 8, "norm_topk_prob": True,
+           "selection_bias": True, "shared_d_ff": 0},
+    remat_plain={}, remat_bias=0.0, remat_tol=dict(rtol=1e-5, atol=1e-7),
+    bf16=dict(attention_impl="flash"), bf16_rel=5e-3, tokens=uniform_tokens,
+    # the reference walks its queries in blocks: two of them at this size
+    reference_set_up=lambda: mock.patch.object(keye_decoder, "QUERY_BLOCK", 32),
+)
+MODELS = (ZAYA, GLM_LITE, LAGUNA, KEYE)

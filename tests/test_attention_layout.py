@@ -89,8 +89,10 @@ def test_head_major_block_is_the_token_major_block(config, positions, segments, 
     def ref(h, lp):
         return reference_block(h, lp, c, **kw)
 
-    got = (block(h, lp), jax.grad(scalar(block), argnums=(0, 1))(h, lp))
-    want = (ref(h, lp), jax.grad(scalar(ref), argnums=(0, 1))(h, lp))
+    def value_and_grads(f):   # one jitted program a side: bare, a configuration's first case is 50-65 s
+        return jax.jit(lambda h, lp: (f(h, lp), jax.grad(scalar(f), argnums=(0, 1))(h, lp)))
+
+    got, want = value_and_grads(block)(h, lp), value_and_grads(ref)(h, lp)
     names = ["value", "d h"] + [f"d {k}" for k in sorted(lp)]
     for name, g, w in zip(names, jax.tree.leaves(got), jax.tree.leaves(want)):
         assert g.shape == w.shape and g.dtype == w.dtype, name

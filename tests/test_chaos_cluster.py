@@ -191,8 +191,10 @@ def test_node_drain_stops_admission_and_deregisters():
         c.wait_for_nodes(2)
         client = c.client()
         n1_addr = tuple(c.nodes["n1"].addr)
+        # the client's wait is the other waits' of this test: under the lane's load the
+        # answer to a drain has taken more than the 10 s this call once gave it
         r = client.pool.get(n1_addr).call(
-            "drain", {"timeout_s": 15.0}, timeout=10
+            "drain", {"timeout_s": 15.0}, timeout=60
         )
         assert r["ok"]
         # drain flag reaches the GCS view, then the node deregisters

@@ -389,37 +389,24 @@ def test_default_kv_block_is_the_sequence_where_its_bytes_fit(D, itemsize, Sk, w
     assert default_block_k(Sk, D, itemsize) == want
 
 
-def _backwards_traced(run) -> tuple:
-    """`run()` -> (fused, split): the flash backwards it traced, by the path they took."""
-    from ray_tpu import obs
-
-    def counts():
-        got = obs.layer_counters()
-        return [got.get(n, {"count": 0})["count"] for n in ("flash.bwd_fused", "flash.bwd_split")]
-
-    before = counts()
-    run()
-    return tuple(a - b for a, b in zip(counts(), before))
-
-
 @pytest.mark.parametrize("bk,want", [(None, (1, 0)), (128, (0, 1))], ids=["fused", "split"])
-def test_the_backward_counts_the_path_it_took_once_a_traced_call(bk, want):
+def test_the_backward_counts_the_path_it_took_once_a_traced_call(backwards_traced, bk, want):
     """`flash.bwd_fused` / `flash.bwd_split`: one layer span a backward
     WHILE TRACING (chipbench's `fallback_sites.train` reads them); a call
     of the compiled function counts nothing more."""
     q, k, v = make_qkv(jax.random.key(0), 1, 256, 256, 2, 1, 32)
     grad = jax.jit(jax.grad(lambda q, k, v: flash_attention(q, k, v, block_q=128, block_k=bk).sum(),
                             argnums=(0, 1, 2)))
-    assert _backwards_traced(lambda: grad.lower(q, k, v)) == want
+    assert backwards_traced(lambda: grad.lower(q, k, v)) == want
     compiled = grad.lower(q, k, v).compile()
-    assert _backwards_traced(lambda: jax.block_until_ready(compiled(q, k, v))) == (0, 0)
+    assert backwards_traced(lambda: jax.block_until_ready(compiled(q, k, v))) == (0, 0)
     forward = jax.jit(lambda q, k, v: flash_attention(q, k, v, block_q=128, block_k=bk))
-    assert _backwards_traced(lambda: forward.lower(q, k, v)) == (0, 0)
+    assert backwards_traced(lambda: forward.lower(q, k, v)) == (0, 0)
 
 
 @pytest.mark.parametrize("window,seg", [(None, False), (700, False), (None, True)],
                          ids=["causal", "window", "segments"])
-def test_one_kv_block_over_4096_keys_matches_xla(monkeypatch, window, seg):
+def test_one_kv_block_over_4096_keys_matches_xla(monkeypatch, backwards_traced, window, seg):
     """4224 keys in ONE kv block of 8192 (the budget raised to the 4 MiB
     that float32 at the 128 lanes needs; bf16 at heads of 128 fits the
     module's own): seventeen sub-tiles a row block at most, the fused
@@ -436,7 +423,7 @@ def test_one_kv_block_over_4096_keys_matches_xla(monkeypatch, window, seg):
     want = jax.value_and_grad(lambda *a: (xla_attention(
         *a, causal=True, window=window, segment_ids=segs) * probe).sum(), (0, 1, 2))(q, k, v)
     got = []
-    traced = _backwards_traced(lambda: got.append(jax.value_and_grad(lambda *a: (flash_attention(
+    traced = backwards_traced(lambda: got.append(jax.value_and_grad(lambda *a: (flash_attention(
         *a, causal=True, window=window, segment_ids=segs) * probe).sum(), (0, 1, 2))(q, k, v)))
     assert traced == (1, 0)
     assert float(got[0][0]) == pytest.approx(float(want[0]), rel=1e-4, abs=1e-3)

@@ -18,7 +18,6 @@ import pytest
 from ray_tpu.ops import flash
 from ray_tpu.ops.attention import attention_head_major, xla_attention
 from ray_tpu.ops.flash import flash_attention, pack_selection, unpack_selection
-from test_flash import _backwards_traced  # (fused, split) backwards a call traced
 
 
 def _mask(b, s, sk, density=0.4, seed=5):
@@ -58,11 +57,11 @@ def _against_xla(shape, *, seg=False, window=None, d_tol=2e-3, **kw):
     dict(shape=(1, 4224, 2, 1, 32), block_q=256, budget=4 << 20, window=700, seg=True),
 ], ids=["fused_folded", "padded", "segments", "window", "two_kv_blocks",
         "two_selection_blocks_fused", "two_selection_blocks_window_segments"])
-def test_selections_against_xla_attention(case, monkeypatch):
+def test_selections_against_xla_attention(case, monkeypatch, backwards_traced):
     shape, took = case.pop("shape"), case.pop("took", "fused")
     if "budget" in case:
         monkeypatch.setattr(flash, "KV_BLOCK_BYTES", case.pop("budget"))
-    assert _backwards_traced(lambda: _against_xla(shape, **case)) == (took == "fused", took == "split")
+    assert backwards_traced(lambda: _against_xla(shape, **case)) == (took == "fused", took == "split")
 
 
 def test_head_major_entry_takes_the_selection_for_both_impls():

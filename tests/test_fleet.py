@@ -270,9 +270,24 @@ def test_noisy_neighbor_paying_tenant_green():
         threads = [threading.Thread(target=flood) for _ in range(4)]
         for th in threads:
             th.start()
-        time.sleep(0.5)  # the flood saturates max_num_seqs=2
+        engine = mgr.replicas("tiny")[0].engine
+
+        def saturated():
+            """The flood holds both of max_num_seqs=2 rows. Waited for,
+            not slept for: under a loaded host the four threads need
+            more than half a second to start and be scheduled, and all
+            three paying requests then ran through an idle engine with
+            nothing to preempt."""
+            deadline = time.monotonic() + 60
+            while time.monotonic() < deadline:
+                if sum(r.tenant == "batch" for r in list(engine.running)) >= 2:
+                    return True
+                time.sleep(0.002)
+            return False
+
         try:
             for _ in range(3):
+                assert saturated()
                 out = mgr.collect(
                     mgr.submit("gold", "tiny", PROMPT, GREEDY), timeout_s=120
                 )

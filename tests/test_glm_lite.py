@@ -4,12 +4,12 @@ CPU, seeded weights, against the plain reference
 alone, sigmoid top-k routing with its bias, renormalisation, scaling and
 the shared expert, the whole train path (the dense layer and the MTP
 block with it) in loss and gradients, MTP's shift, the shares that add
-up, `config_from_hf` on the catalog's config, and the kernels at the new
-shapes (flash at heads of 256, grouped matmul at K 2048 / N 1536)."""
+up, the preset's counts, and the kernels at the new shapes (flash at heads
+of 256, grouped matmul at K 2048 / N 1536). (Remat, bf16, `config_from_hf`
+and the engine's refusal: tests/test_model_contract.py.)"""
 
 import dataclasses
-import json
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -17,84 +17,20 @@ import numpy as np
 import pytest
 
 from chipbench.reference import glm_lite_decoder
+from model_cases import GLM_LITE, reference_path, seeded_params, train_path, worst_leaf
 from ray_tpu.models import llama, mla, moe
-from ray_tpu.models.registry import config_from_hf, get_model_config
+from ray_tpu.models.registry import get_model_config
 from ray_tpu.nn.layers import rms_norm
 from ray_tpu.ops import grouped_matmul as gm
 from ray_tpu.ops.attention import xla_attention
 from ray_tpu.ops.flash import flash_attention
 
-FP32 = dataclasses.replace(mla.GLM_LITE_TINY, dtype=jnp.float32)
-B, S = 2, 24
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-
-
-def shape_of(cfg) -> dict:
-    """A GlmLiteConfig as the configuration file's dict (HF key names)."""
-    return {
-        "hidden_size": cfg.d_model, "num_attention_heads": cfg.n_heads,
-        "q_lora_rank": cfg.q_lora_rank, "kv_lora_rank": cfg.kv_lora_rank,
-        "qk_nope_head_dim": cfg.qk_nope_head_dim, "qk_rope_head_dim": cfg.qk_rope_head_dim,
-        "v_head_dim": cfg.v_head_dim, "rope_theta": cfg.rope_theta,
-        "rms_norm_eps": cfg.rms_eps, "n_routed_experts": cfg.n_held,
-        "published": {"n_routed_experts": cfg.n_experts},
-        "deployment": {"first_expert_held": cfg.first_expert_held},
-        "num_experts_per_tok": cfg.top_k, "norm_topk_prob": cfg.norm_topk_prob,
-        "routed_scaling_factor": cfg.routed_scaling,
-        "first_k_dense_replace": cfg.first_dense_layers,
-        "num_nextn_predict_layers": cfg.mtp_layers, "mtp_loss_weight": cfg.mtp_loss_weight,
-        "max_position_embeddings": cfg.max_seq, "num_hidden_layers": cfg.n_layers,
-        "tie_word_embeddings": cfg.tie_embeddings, "vocab_size": cfg.vocab_size,
-    }
-
-
-def seeded_params(cfg, seed=0):
-    """init_params, with the leaves that start at one or zero moved off
-    them, so that a test sees every norm and the selection bias."""
-    params = llama.init_params(cfg, jax.random.key(seed))
-    keys = iter(jax.random.split(jax.random.key(seed + 100), 64))
-
-    def spread(tree, names):
-        for name in names:
-            tree[name] = tree[name] + 0.2 * jax.random.normal(next(keys), tree[name].shape)
-
-    norms = ("ln1", "ln2", "q_a_norm", "kv_a_norm")
-    spread(params["layers"], norms)
-    spread(params["dense_layers"], norms)
-    spread(params["mtp"]["block"], norms)
-    spread(params["mtp"], ("enorm", "hnorm", "final_norm"))
-    spread(params, ("final_norm",))
-    bias = params["layers"]["router_bias"]
-    params["layers"]["router_bias"] = 0.1 * jax.random.normal(next(keys), bias.shape)
-    return params
+FP32, B, S = GLM_LITE.fp32, GLM_LITE.batch, GLM_LITE.seq
 
 
 def layer_of(params, i):
     layers = {**params["layers"], "router_bias": params["layers"]["router_bias"][:-1]}
     return jax.tree.map(lambda x: x[i], layers)
-
-
-def skewed_tokens(cfg, seed=1):
-    rng = np.random.default_rng(seed)
-    p = 1.0 / np.arange(1, cfg.vocab_size + 1) ** 1.1
-    ids = rng.choice(cfg.vocab_size, size=(B, S + 1), p=p / p.sum())
-    return {"tokens": jnp.asarray(ids[:, :-1], jnp.int32),
-            "targets": jnp.asarray(ids[:, 1:], jnp.int32)}
-
-
-def worst_leaf(got, want, skip=("router_bias",)):
-    """{path: largest difference of a leaf over the leaf's own scale}."""
-    worst = {}
-    for path, g in jax.tree_util.tree_leaves_with_path(got):
-        w = want
-        for k in path:
-            w = w[k.key]
-        name = jax.tree_util.keystr(path)
-        if any(s in name for s in skip):
-            assert float(jnp.abs(g).max()) == 0.0 and float(jnp.abs(w).max()) == 0.0
-            continue
-        worst[name] = float(jnp.abs(g - w).max()) / max(float(jnp.abs(w).max()), 1e-12)
-    return worst
 
 
 # -- the sublayers against the reference ---------------------------------------
@@ -105,9 +41,9 @@ def test_mla_sublayer_is_the_references(what):
     """The attention half of a block alone: x -> x + MLA(RMSNorm(x)), the
     one rotary key shared by the heads, forward and the gradients of the
     input and of every weight."""
-    lp = layer_of(seeded_params(FP32), 1)
+    lp = layer_of(seeded_params(GLM_LITE, FP32), 1)
     x = jax.random.normal(jax.random.key(3), (B, S, FP32.d_model), jnp.float32)
-    shape = shape_of(FP32)
+    shape = GLM_LITE.shape_of(FP32)
 
     def program(x, lp):
         normed = rms_norm(x, lp["ln1"], FP32.rms_eps)
@@ -118,12 +54,12 @@ def test_mla_sublayer_is_the_references(what):
 
     with jax.default_matmul_precision("highest"):
         if what == "forward":
-            np.testing.assert_allclose(np.asarray(program(x, lp)), np.asarray(reference(x, lp)),
-                                       rtol=2e-5, atol=2e-5)
+            np.testing.assert_allclose(np.asarray(jax.jit(program)(x, lp)),
+                                       np.asarray(jax.jit(reference)(x, lp)), rtol=2e-5, atol=2e-5)
             return
         probe = jax.random.normal(jax.random.key(4), (B, S, FP32.d_model))
-        got = jax.grad(lambda x, lp: (program(x, lp) * probe).sum(), argnums=(0, 1))(x, lp)
-        want = jax.grad(lambda x, lp: (reference(x, lp) * probe).sum(), argnums=(0, 1))(x, lp)
+        got = jax.jit(jax.grad(lambda x, lp: (program(x, lp) * probe).sum(), argnums=(0, 1)))(x, lp)
+        want = jax.jit(jax.grad(lambda x, lp: (reference(x, lp) * probe).sum(), argnums=(0, 1)))(x, lp)
     used = [k for k in mla.attention_axes()] + ["ln1"]
     worst = worst_leaf({"x": got[0], **{k: got[1][k] for k in used}},
                        {"x": want[0], **{k: want[1][k] for k in used}})
@@ -134,7 +70,7 @@ def test_the_positions_reach_the_sublayer_through_the_rotary_channels_alone():
     """Rotary is on q_rot and k_rot alone: with the queries' rotary
     channels zero (the scores then read no k_rot) the sublayer does not
     see the positions at all."""
-    lp = layer_of(seeded_params(FP32), 0)
+    lp = layer_of(seeded_params(GLM_LITE, FP32), 0)
     x = jax.random.normal(jax.random.key(3), (B, S, FP32.d_model), jnp.float32)
     run = lambda lp, pos: mla.mla_sublayer(x, lp, FP32, positions=pos, segment_ids=None)  # noqa: E731
     assert not np.allclose(run(lp, jnp.arange(S)), run(lp, 3 * jnp.arange(S) + 5), atol=1e-4)
@@ -150,7 +86,7 @@ def test_sigmoid_routing_bias_renormalisation_scaling_and_the_shared_expert():
     bias chooses and is no part of the weight; the chosen scores are
     renormalised over the chosen and scaled by 1.8; the shared expert is
     added for every token; counts sum to top_k x tokens."""
-    lp = layer_of(seeded_params(FP32), 0)
+    lp = layer_of(seeded_params(GLM_LITE, FP32), 0)
     x = jax.random.normal(jax.random.key(5), (B, S, FP32.d_model), jnp.float32)
     with jax.default_matmul_precision("highest"):
         out, stats, _ = moe.moe_ffn(x, lp, FP32)
@@ -204,13 +140,8 @@ def test_train_path_meets_the_reference_in_loss_and_gradients(held):
     (the MTP block's row last), and every gradient by its worst leaf."""
     cfg = FP32 if held is None else dataclasses.replace(
         FP32, experts_held=held[0], first_expert_held=held[1])
-    params, batch, shape = seeded_params(cfg), skewed_tokens(cfg), shape_of(cfg)
-    with jax.default_matmul_precision("highest"):
-        loss, weight, stats = llama.loss_and_weight_fn(params, batch, cfg)
-        got = jax.grad(lambda p: llama.loss_fn(p, batch, cfg))(params)
-        ref = glm_lite_decoder.loss_parts(params, batch["tokens"], batch["targets"], shape)
-        want = jax.grad(lambda p: glm_lite_decoder.loss(
-            p, batch["tokens"], batch["targets"], shape))(params)
+    ours, theirs = train_path(GLM_LITE, cfg), reference_path(GLM_LITE, cfg)
+    loss, weight, stats, ref = ours.loss, ours.weight, ours.stats, theirs.parts
     assert float(weight) == B * S
     for name in ("loss_main", "loss_mtp"):
         assert float(stats[name]) == pytest.approx(float(ref[name]), rel=2e-6)
@@ -226,27 +157,9 @@ def test_train_path_meets_the_reference_in_loss_and_gradients(held):
         elsewhere = cfg.top_k * B * S - stats["tokens_per_expert"][:, first:first + n].sum(-1)
         assert stats["pairs_elsewhere"].tolist() == elsewhere.tolist()
         assert 0 < int(elsewhere.sum()) < 3 * cfg.top_k * B * S
-    worst = worst_leaf(got, want)
-    assert len(worst) == len(jax.tree.leaves(params)) - 1
+    worst = worst_leaf(ours.grads, theirs.grads)
+    assert len(worst) == len(jax.tree.leaves(ours.params)) - 1
     assert max(worst.values()) < 2e-4, sorted(worst.items(), key=lambda kv: -kv[1])[:3]
-
-
-def test_bf16_compute_stays_near_the_reference():
-    cfg = dataclasses.replace(FP32, dtype=jnp.bfloat16)
-    params, batch = seeded_params(cfg), skewed_tokens(cfg)
-    loss = llama.loss_fn(params, batch, cfg)
-    ref = glm_lite_decoder.loss(params, batch["tokens"], batch["targets"], shape_of(cfg))
-    assert float(loss) == pytest.approx(float(ref), rel=0.02)
-
-
-@pytest.mark.parametrize("remat_policy", ["dots", "full"])
-def test_remat_gives_the_same_gradients(remat_policy):
-    cfg = dataclasses.replace(FP32, remat=True, remat_policy=remat_policy)
-    params, batch = seeded_params(cfg), skewed_tokens(cfg)
-    got = jax.grad(lambda p: llama.loss_fn(p, batch, cfg))(params)
-    want = jax.grad(lambda p: llama.loss_fn(p, batch, FP32))(params)
-    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=2e-6)
 
 
 def test_a_model_with_neither_dense_layer_nor_mtp_block_is_one_stack():
@@ -254,7 +167,7 @@ def test_a_model_with_neither_dense_layer_nor_mtp_block_is_one_stack():
     params = llama.init_params(cfg, jax.random.key(0))
     assert set(params) == {"embed", "layers", "final_norm", "lm_head"}
     assert params["layers"]["router_bias"].shape == (2, cfg.n_experts)
-    out = llama.loss_and_weight_fn(params, skewed_tokens(cfg), cfg)
+    out = llama.loss_and_weight_fn(params, GLM_LITE.batch_of(cfg), cfg)
     assert "loss_mtp" not in out[2] and np.isfinite(float(out[0]))
     with pytest.raises(ValueError, match="0 or 1"):
         llama.init_params(dataclasses.replace(FP32, mtp_layers=2), jax.random.key(0))
@@ -263,6 +176,7 @@ def test_a_model_with_neither_dense_layer_nor_mtp_block_is_one_stack():
 # -- MTP's shift, and causality ---------------------------------------------------
 
 
+@functools.partial(jax.jit, static_argnames="cfg")
 def per_position_losses(params, batch, cfg):
     """(main nll [B, S], MTP nll [B, S - 1]) of the program's own path, by
     masking one position at a time out of neither: from the reference's
@@ -286,9 +200,9 @@ def test_mtp_predicts_the_token_after_next_and_the_last_position_weighs_nothing(
     t_{i+2} at position i; changing token t moves no MTP term before
     t - 2 (term i reads tokens up to i + 1 and the target t_{i+2}); the
     last position's logits reach no loss."""
-    params, batch = seeded_params(FP32), skewed_tokens(FP32)
+    params, batch = seeded_params(GLM_LITE, FP32), GLM_LITE.batch_of(FP32)
     with jax.default_matmul_precision("highest"):
-        _, _, stats = llama.loss_and_weight_fn(params, batch, FP32)
+        stats = train_path(GLM_LITE, FP32).stats   # of these parameters and this batch
         main, ahead = per_position_losses(params, batch, FP32)
         assert float(stats["loss_mtp"]) == pytest.approx(float(ahead.mean()), rel=1e-5)
         assert float(stats["loss_main"]) == pytest.approx(float(main.mean()), rel=1e-5)
@@ -335,7 +249,7 @@ def test_shares_of_the_experts_add_up_to_the_uncut_layer(dtype):
     input; each share's counts are the uncut layer's, and what one share
     computes the others count as elsewhere."""
     whole = dataclasses.replace(FP32, dtype=dtype)
-    lp = layer_of(seeded_params(whole), 0)
+    lp = layer_of(seeded_params(GLM_LITE, whole), 0)
     x = jax.random.normal(jax.random.key(5), (B, S, whole.d_model), jnp.float32).astype(dtype)
     no_shared = {k: (jnp.zeros_like(v) if k == "shared_down" else v) for k, v in lp.items()}
 
@@ -375,24 +289,9 @@ def test_shares_of_the_experts_add_up_to_the_uncut_layer(dtype):
 # -- the registry ------------------------------------------------------------------
 
 
-def catalog_config():
-    if os.path.exists(CATALOG):
-        for line in open(CATALOG):
-            row = json.loads(line)
-            if row["name"] == "GLM-4.7-Flash":
-                return row["config"]
-    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                        "chipbench", "configs", "glm-4.7-flash-train.json")
-    file = json.load(open(path))
-    return {**{k: v for k, v in file.items() if not isinstance(v, dict)}, **file["published"]}
-
-
-def test_config_from_hf_maps_the_catalogs_config_onto_the_preset():
-    cfg = config_from_hf(catalog_config())
-    assert cfg == get_model_config("glm-4.7-flash")
-    assert isinstance(cfg, mla.GlmLiteConfig) and cfg.head_dim == 256 != 2048 // 20
-    assert (cfg.n_expert_layers, cfg.first_dense_layers, cfg.mtp_layers) == (46, 1, 1)
-    assert (cfg.router_score, cfg.routed_scaling, cfg.shared_d_ff) == ("sigmoid", 1.8, 1536)
+def test_counts_of_parameters_and_operations_are_30b_a3bs_and_the_trees():
+    cfg = get_model_config("glm-4.7-flash")
+    assert isinstance(cfg, mla.GlmLiteConfig)
     # 30B-A3B: every expert somewhere; 6 x the active parameters is most of a token's operations
     assert 30.0e9 < dataclasses.replace(cfg).num_params() < 31.2e9
     active = cfg.flops_per_token(1) / 2
@@ -402,24 +301,6 @@ def test_config_from_hf_maps_the_catalogs_config_onto_the_preset():
     n = sum(x.size for x in jax.tree.leaves(jax.eval_shape(
         lambda: llama.init_params(small, jax.random.key(0)))))
     assert n == small.num_params()
-
-
-@pytest.mark.parametrize("key,value,names", [
-    ("rope_scaling", {"type": "yarn", "factor": 4.0}, "rope_scaling"),
-    ("n_group", 8, "group-limited"),
-    ("num_nextn_predict_layers", 2, "multi-token-prediction"),
-    ("attention_bias", True, "attention_bias"),
-])
-def test_config_from_hf_refuses_by_name_what_is_not_implemented(key, value, names):
-    with pytest.raises(ValueError, match=names):
-        config_from_hf({**catalog_config(), key: value})
-
-
-def test_engine_refuses_the_model_by_name():
-    from ray_tpu.llm.engine import EngineConfig
-
-    with pytest.raises(ValueError, match="GLM-4.7-Flash"):
-        EngineConfig(model="glm-lite-tiny")
 
 
 # -- the kernels at the new shapes -------------------------------------------------------

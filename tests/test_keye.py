@@ -6,11 +6,11 @@ over the keys the indexer selects, the selected sets themselves, the tie
 rule, `topk` >= T as the full GQA sublayer, no gradient into the indexer
 and none through the selection, the whole train path in loss and
 gradients, the eight shares of a layer that add up, the train step by
-the registry's name, `config_from_hf` on the catalog's config, the
-refusals. (The flash kernels under a selection: tests/test_flash_selection.py.)"""
+the registry's name. (The flash kernels under a selection:
+tests/test_flash_selection.py; remat, flash and bf16, `config_from_hf`, its
+refusals and the engine's: tests/test_model_contract.py.)"""
 
 import dataclasses
-import json
 import os
 
 import jax
@@ -20,64 +20,19 @@ import optax
 import pytest
 
 from chipbench.reference import keye_decoder
-from ray_tpu.models import dsa, llama, moe
+from model_cases import KEYE, catalog_config, reference_path, seeded_params, train_path
+from ray_tpu.models import dsa, llama
 from ray_tpu.models.registry import config_from_hf, get_model_config, list_models
 from ray_tpu.nn.layers import rms_norm
 from ray_tpu.ops.attention import attention_head_major
 from ray_tpu.ops.flash import unpack_selection
 
-FP32 = dataclasses.replace(dsa.KEYE_TINY, dtype=jnp.float32)
-B, S = 2, 64   # topk 16 and chunks of 16 queries: the first chunk computes no score
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+FP32, B, S = KEYE.fp32, KEYE.batch, KEYE.seq
 
 
 @pytest.fixture(autouse=True)
 def blocks_of_queries(monkeypatch):
     monkeypatch.setattr(keye_decoder, "QUERY_BLOCK", 32)
-
-
-def shape_of(cfg) -> dict:
-    """A KeyeConfig as the configuration file's dict (HF key names)."""
-    return {
-        "hidden_size": cfg.d_model, "head_dim": cfg.head_dim,
-        "num_attention_heads": cfg.n_heads, "num_key_value_heads": cfg.n_kv_heads,
-        "num_hidden_layers": cfg.n_layers, "rope_theta": cfg.rope_theta,
-        "rms_norm_eps": cfg.rms_eps, "num_experts": cfg.n_held,
-        "sa_config": {"indexer_num_heads": cfg.indexer_heads,
-                      "indexer_head_dim": cfg.indexer_head_dim, "topk": cfg.indexer_topk},
-        "published": {"num_experts": cfg.n_experts},
-        "deployment": {"first_expert_held": cfg.first_expert_held},
-        "num_experts_per_tok": cfg.top_k, "norm_topk_prob": cfg.norm_topk_prob,
-        "max_position_embeddings": cfg.max_seq, "tie_word_embeddings": cfg.tie_embeddings,
-        "mlp_only_layers": [], "decoder_sparse_step": 1, "vocab_size": cfg.vocab_size,
-    }
-
-
-def seeded_params(cfg, seed=0, bias=0.0):
-    """init_params with the norms moved off their init and the selection
-    biases a random table at scale `bias`."""
-    p = llama.init_params(cfg, jax.random.key(seed))
-    keys = iter(jax.random.split(jax.random.key(seed + 100), 8))
-    layers = dict(p["layers"])
-    for n in ("ln1", "ln2", "q_norm", "k_norm", "idx_norm_w"):
-        layers[n] = 1 + 0.1 * jax.random.normal(next(keys), layers[n].shape)
-    layers["idx_norm_b"] = 0.1 * jax.random.normal(next(keys), layers["idx_norm_b"].shape)
-    layers["router_bias"] = bias * jax.random.normal(next(keys), layers["router_bias"].shape)
-    return {**p, "layers": layers}
-
-
-def batch_of(cfg, seed=1):
-    tok = jax.random.randint(jax.random.key(seed), (B, S + 1), 0, cfg.vocab_size)
-    return {"tokens": tok[:, :-1], "targets": tok[:, 1:]}
-
-
-def loss_and_grads(cfg, params, batch):
-    def f(p):
-        loss, _, stats = llama.loss_and_weight_fn(p, batch, cfg)
-        return loss, stats
-
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(jax.value_and_grad(f, has_aux=True))(params)
 
 
 def layer_of(params, l=0):
@@ -90,7 +45,7 @@ def layer_of(params, l=0):
 @pytest.mark.parametrize("impl", ["xla", "flash"])
 def test_attention_sublayer_is_the_references(impl):
     cfg = dataclasses.replace(FP32, attention_impl=impl)
-    lp = layer_of(seeded_params(cfg))
+    lp = layer_of(seeded_params(KEYE, cfg))
     h = jax.random.normal(jax.random.key(7), (B, S, cfg.d_model), jnp.float32)
 
     def ours(h):
@@ -98,7 +53,8 @@ def test_attention_sublayer_is_the_references(impl):
         return h + dsa.dsa_sublayer(x, lp, cfg, positions=jnp.arange(S), segment_ids=None)[0]
 
     def theirs(h):
-        return jnp.stack([keye_decoder.attention(h[b], lp, shape_of(cfg))[0] for b in range(B)])
+        shape = KEYE.shape_of(cfg)
+        return jnp.stack([keye_decoder.attention(h[b], lp, shape)[0] for b in range(B)])
 
     probe = jax.random.normal(jax.random.key(8), h.shape)
     with jax.default_matmul_precision("highest"):
@@ -113,7 +69,7 @@ def test_the_selected_sets_are_the_references():
     reference's stable sort, on the same index scores; 16 keys a query
     from row 16 on, every key before it up to there."""
     cfg = FP32
-    lp = layer_of(seeded_params(cfg))
+    lp = layer_of(seeded_params(KEYE, cfg))
     x = jax.random.normal(jax.random.key(3), (B, S, cfg.d_model), jnp.float32)
     with jax.default_matmul_precision("highest"):
         packed, n_selected, _ = dsa.selection(x, lp, cfg, jnp.arange(S))
@@ -155,7 +111,7 @@ def test_equal_scores_go_to_the_lower_key_and_are_counted():
 
 def test_topk_at_least_the_sequence_is_the_full_gqa_sublayer():
     cfg = dataclasses.replace(FP32, indexer_topk=S)
-    lp = layer_of(seeded_params(cfg))
+    lp = layer_of(seeded_params(KEYE, cfg))
     x = jax.random.normal(jax.random.key(3), (B, S, cfg.d_model), jnp.float32)
     seen = {}
 
@@ -177,8 +133,8 @@ def test_topk_at_least_the_sequence_is_the_full_gqa_sublayer():
 
 def test_no_gradient_reaches_the_indexer_and_none_passes_through_the_selection():
     cfg = FP32
-    params, batch = seeded_params(cfg), batch_of(cfg)
-    (_, stats), grads = loss_and_grads(cfg, params, batch)
+    ours = train_path(KEYE, cfg)
+    params, stats, grads = ours.params, ours.stats, ours.grads
     for n in ("idx_wq", "idx_wk", "idx_ww", "idx_norm_w", "idx_norm_b", "router_bias"):
         assert not np.asarray(grads["layers"][n]).any(), n
     assert np.asarray(grads["layers"]["wq"]).any()
@@ -210,40 +166,16 @@ def test_no_gradient_reaches_the_indexer_and_none_passes_through_the_selection()
 def test_train_path_meets_the_reference_in_loss_and_gradients(held, bias):
     cfg = FP32 if held is None else dataclasses.replace(
         FP32, first_expert_held=held[0], experts_held=held[1], vocab_size=256)
-    params, batch = seeded_params(cfg, bias=bias), batch_of(cfg)
-    (loss, stats), grads = loss_and_grads(cfg, params, batch)
-    shape = shape_of(cfg)
-    parts = keye_decoder.loss_parts(params, batch["tokens"], batch["targets"], shape)
+    ours, theirs = train_path(KEYE, cfg, bias), reference_path(KEYE, cfg, bias)
+    loss, stats, grads, parts, want = ours.loss, ours.stats, ours.grads, theirs.parts, theirs.grads
     assert float(loss) == pytest.approx(float(parts["loss"]), rel=1e-5)
     assert stats["tokens_per_expert"].tolist() == parts["tokens_per_expert"].tolist()
     assert stats["dsa_selected"].tolist() == parts["selected_pairs"].tolist()
-    want = jax.jit(jax.grad(
-        lambda p: keye_decoder.loss(p, batch["tokens"], batch["targets"], shape)))(params)
     for path, g in jax.tree_util.tree_leaves_with_path(grads):
         w = jax.tree_util.keystr(path)
         ref = np.asarray(jax.tree_util.tree_leaves_with_path(want)[[jax.tree_util.keystr(p) for p, _ in
                jax.tree_util.tree_leaves_with_path(want)].index(w)][1])
         np.testing.assert_allclose(np.asarray(g), ref, rtol=2e-4, atol=2e-6, err_msg=w)
-
-
-def test_flash_and_bf16_compute_stay_near_the_reference():
-    cfg = dataclasses.replace(dsa.KEYE_TINY, attention_impl="flash")
-    params, batch = seeded_params(cfg), batch_of(cfg)
-    loss = llama.loss_and_weight_fn(params, batch, cfg)[0]
-    want = keye_decoder.loss(params, batch["tokens"], batch["targets"], shape_of(cfg))
-    assert float(loss) == pytest.approx(float(want), rel=5e-3)
-
-
-@pytest.mark.parametrize("remat_policy", ["dots", "full"])
-def test_remat_gives_the_same_gradients(remat_policy):
-    plain = FP32
-    cfg = dataclasses.replace(plain, remat=True, remat_policy=remat_policy)
-    params, batch = seeded_params(plain), batch_of(plain)
-    (a, _), ga = loss_and_grads(plain, params, batch)
-    (b, _), gb = loss_and_grads(cfg, params, batch)
-    assert float(a) == pytest.approx(float(b), rel=1e-6)
-    for x, y in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-5, atol=1e-7)
 
 
 def test_the_dots_policy_keeps_the_selection_and_nothing_else_of_the_indexer():
@@ -254,7 +186,7 @@ def test_the_dots_policy_keeps_the_selection_and_nothing_else_of_the_indexer():
     import re
 
     cfg = dataclasses.replace(FP32, remat=True, remat_policy="dots")
-    params, batch = seeded_params(cfg), batch_of(cfg)
+    params, batch = seeded_params(KEYE, cfg), KEYE.batch_of(cfg)
     grad = jax.jit(jax.grad(lambda p: llama.loss_and_weight_fn(p, batch, cfg)[0]))
     assert "dsa_sel" in str(jax.make_jaxpr(grad)(params))
     names = set(re.findall(r'op_name="([^"]*)"', grad.lower(params).compile().as_text()))
@@ -271,7 +203,7 @@ def test_eight_shares_add_up_to_the_uncut_layer():
     uncut REFERENCE's whole layer; every share counts what the uncut
     layer counts, and what one computes the others count as elsewhere."""
     whole = dataclasses.replace(FP32, n_experts=16, top_k=8, n_layers=1)
-    params = seeded_params(whole, bias=0.01)
+    params = seeded_params(KEYE, whole, bias=0.01)
     lp = layer_of(params)
     h = jax.random.normal(jax.random.key(5), (B, S, whole.d_model), jnp.float32)
     experts = ("w_gate", "w_up", "w_down")
@@ -296,8 +228,8 @@ def test_eight_shares_add_up_to_the_uncut_layer():
         shares = [share(first) for first in range(0, 16, 2)]
         theirs = []
         for b in range(B):
-            mid, _ = keye_decoder.attention(h[b], lp, shape_of(whole))
-            theirs.append(keye_decoder.experts(mid, lp, shape_of(whole))[0])
+            mid, _ = keye_decoder.attention(h[b], lp, KEYE.shape_of(whole))
+            theirs.append(keye_decoder.experts(mid, lp, KEYE.shape_of(whole))[0])
     for i in (0, 1):   # the output, and the gradient of the input
         # everything but the routed experts is in every share: counted once
         total = sum(np.asarray(s[i]) for s in shares) - 7 * np.asarray(no_experts[i])
@@ -321,7 +253,7 @@ def test_the_train_step_learns_a_batch_by_the_registrys_name():
     state = TrainState.create(llama.init_params(cfg, jax.random.key(0)), opt)
     before = jax.tree.map(np.asarray, {k: state.params["layers"][k] for k in ("idx_wq", "idx_ww")})
     step = make_train_step(lambda p, b: llama.loss_and_weight_fn(p, b, cfg), opt)
-    batch = batch_of(cfg)
+    batch = KEYE.batch_of(cfg)
     losses = []
     for _ in range(12):
         state, m = step(state, batch)
@@ -335,7 +267,7 @@ def test_the_train_step_learns_a_batch_by_the_registrys_name():
 
 def test_packed_sequences_and_positions_by_row_are_refused_by_name():
     cfg = FP32
-    params, batch = seeded_params(cfg), batch_of(cfg)
+    params, batch = seeded_params(KEYE, cfg), KEYE.batch_of(cfg)
     with pytest.raises(NotImplementedError, match="segment_ids"):
         llama.loss_and_weight_fn(params, {**batch, "segment_ids": jnp.zeros((B, S), jnp.int32)}, cfg)
 
@@ -353,52 +285,10 @@ def test_counts_of_parameters_and_operations_are_the_trees_and_the_issues():
 # -- the registry ------------------------------------------------------------------
 
 
-def catalog_config():
-    if os.path.exists(CATALOG):
-        for line in open(CATALOG):
-            row = json.loads(line)
-            if row["name"] == "Keye-VL-2.0-30B-A3B":
-                return row["config"]
-    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                        "chipbench", "configs", "keye-vl-2.0-30b-a3b-train.json")
-    file = json.load(open(path))
-    return {**{k: v for k, v in file.items() if k not in file["published"]}, **file["published"]}
-
-
-def test_config_from_hf_maps_the_catalogs_config_onto_the_preset():
-    cfg = config_from_hf(catalog_config())
-    assert cfg == get_model_config("keye-vl-2.0-30b-a3b")
-    assert cfg.head_dim == 128 != cfg.d_model // cfg.n_heads
-    assert (cfg.indexer_heads, cfg.indexer_head_dim, cfg.indexer_topk, cfg.index_chunk) == (16, 64, 2048, 512)
-    assert (cfg.n_experts, cfg.top_k, cfg.norm_topk_prob, cfg.selection_bias, cfg.shared_d_ff) == (
-        128, 8, True, True, 0)
-    # the language model's keys under `text_config`, as a checkpoint's config nests them
-    nested = {"model_type": "KeyeVL2", "text_config": catalog_config()}
-    assert config_from_hf(nested) == cfg
-
-
-@pytest.mark.parametrize("key,value,names", [
-    ("vision_config", {"depth": 27}, "vision tower or image / video inputs .vision_config."),
-    ("image_token_id", 151655, "image_token_id"),
-    ("sa_config", None, "no sa_config"),
-    ("sa_config", {"indexer_num_heads": 16, "indexer_head_dim": 64, "indexer_num_kv_heads": 2,
-                   "topk": 2048}, "indexer_num_kv_heads 2"),
-    ("rope_scaling", {"rope_type": "yarn", "factor": 4}, "rope_scaling type 'yarn'"),
-    ("use_sliding_window", True, "a sliding window"),
-    ("attention_bias", True, "attention_bias"),
-    ("mlp_only_layers", [0], "mlp_only_layers"),
-    ("decoder_sparse_step", 2, "decoder_sparse_step 2"),
-])
-def test_config_from_hf_refuses_by_name_what_is_not_implemented(key, value, names):
-    with pytest.raises(ValueError, match=names):
-        config_from_hf({**catalog_config(), key: value})
-
-
-def test_engine_refuses_the_model_by_name():
-    from ray_tpu.llm.engine import EngineConfig
-
-    with pytest.raises(ValueError, match="Keye"):
-        EngineConfig(model="keye-tiny")
+def test_config_from_hf_reads_the_language_models_keys_under_text_config():
+    """As a checkpoint's config nests them."""
+    nested = {"model_type": "KeyeVL2", "text_config": catalog_config(KEYE)}
+    assert config_from_hf(nested) == get_model_config("keye-vl-2.0-30b-a3b")
 
 
 def test_no_other_configuration_loads_the_module():

@@ -4,13 +4,12 @@ seeded weights, against the plain reference
 sublayer of both kinds (the window, YaRN on part of a head, the gate),
 softmax top-k routing with its bias, renormalisation and scaling, the
 whole train path over a dense layer and two periods in loss and
-gradients, the shares that add up, `config_from_hf` on the catalog's
-config. (The flash kernels under a window: tests/test_flash_window.py.)"""
+gradients, the shares that add up. (The flash kernels under a window:
+tests/test_flash_window.py; remat, bf16, `config_from_hf` and the engine's
+refusal: tests/test_model_contract.py.)"""
 
 import dataclasses
-import json
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -18,63 +17,13 @@ import numpy as np
 import pytest
 
 from chipbench.reference import laguna_decoder
+from model_cases import LAGUNA, reference_path, seeded_params, train_path, worst_leaf
 from ray_tpu.models import laguna, llama, moe
-from ray_tpu.models.registry import config_from_hf, get_model_config
+from ray_tpu.models.registry import config_from_hf
 from ray_tpu.nn import layers as nn_layers
 from ray_tpu.nn.layers import rms_norm
 
-FP32 = dataclasses.replace(laguna.LAGUNA_TINY, dtype=jnp.float32)
-B, S = 2, 40   # the window (24) shorter than the sequence
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-
-
-def rope_group(r: laguna.Rotary) -> dict:
-    return {"rope_theta": r.theta, "rope_type": r.rope_type, "factor": r.factor,
-            "original_max_position_embeddings": r.original_max, "beta_fast": r.beta_fast,
-            "beta_slow": r.beta_slow, "attention_factor": r.attention_factor,
-            "partial_rotary_factor": r.partial}
-
-
-def shape_of(cfg) -> dict:
-    """A LagunaConfig as the configuration file's dict (HF key names)."""
-    n = cfg.n_layers
-    return {
-        "hidden_size": cfg.d_model, "head_dim": cfg.head_dim,
-        "num_key_value_heads": cfg.n_kv_heads, "num_hidden_layers": n,
-        "num_attention_heads_per_layer": list(cfg.heads_per_layer[:n]),
-        "layer_types": list(cfg.layer_types[:n]), "sliding_window": cfg.sliding_window,
-        "mlp_layer_types": ["dense"] * cfg.first_dense_layers + ["sparse"] * cfg.n_expert_layers,
-        "rope_parameters": {laguna.FULL: rope_group(cfg.rope_full),
-                            laguna.SLIDING: rope_group(cfg.rope_sliding)},
-        "rms_norm_eps": cfg.rms_eps, "num_experts": cfg.n_held,
-        "published": {"num_experts": cfg.n_experts},
-        "deployment": {"first_expert_held": cfg.first_expert_held},
-        "num_experts_per_tok": cfg.top_k, "norm_topk_prob": cfg.norm_topk_prob,
-        "moe_routed_scaling_factor": cfg.routed_scaling,
-        "max_position_embeddings": cfg.max_seq, "tie_word_embeddings": cfg.tie_embeddings,
-        "vocab_size": cfg.vocab_size,
-    }
-
-
-def seeded_params(cfg, seed=0, bias=0.0):
-    """init_params with the norms moved off one, and the selection biases
-    at `bias` x a random table (0: the published forward)."""
-    params = llama.init_params(cfg, jax.random.key(seed))
-    keys = iter(jax.random.split(jax.random.key(seed + 100), 64))
-
-    def spread(tree):
-        for name in ("ln1", "ln2"):
-            tree[name] = tree[name] + 0.2 * jax.random.normal(next(keys), tree[name].shape)
-
-    spread(params["dense_layers"])
-    for group in ("period", "tail"):
-        for block in params["layers"].get(group, {}).values():
-            spread(block)
-    params["final_norm"] = params["final_norm"] + 0.2 * jax.random.normal(
-        next(keys), params["final_norm"].shape)
-    table = params["layers"]["router_bias"]
-    params["layers"]["router_bias"] = bias * jax.random.normal(next(keys), table.shape)
-    return params
+FP32, B, S = LAGUNA.fp32, LAGUNA.batch, LAGUNA.seq
 
 
 def block_of(params, position, period=0, bias_row=None):
@@ -82,29 +31,6 @@ def block_of(params, position, period=0, bias_row=None):
     if bias_row is not None:
         lp["router_bias"] = params["layers"]["router_bias"][bias_row]
     return lp
-
-
-def skewed_tokens(cfg, seed=1):
-    rng = np.random.default_rng(seed)
-    p = 1.0 / np.arange(1, cfg.vocab_size + 1) ** 1.1
-    ids = rng.choice(cfg.vocab_size, size=(B, S + 1), p=p / p.sum())
-    return {"tokens": jnp.asarray(ids[:, :-1], jnp.int32),
-            "targets": jnp.asarray(ids[:, 1:], jnp.int32)}
-
-
-def worst_leaf(got, want, skip=("router_bias",)):
-    """{path: largest difference of a leaf over the leaf's own scale}."""
-    worst = {}
-    for path, g in jax.tree_util.tree_leaves_with_path(got):
-        w = want
-        for k in path:
-            w = w[k.key]
-        name = jax.tree_util.keystr(path)
-        if any(s in name for s in skip):
-            assert float(jnp.abs(g).max()) == 0.0 and float(jnp.abs(w).max()) == 0.0
-            continue
-        worst[name] = float(jnp.abs(g - w).max()) / max(float(jnp.abs(w).max()), 1e-12)
-    return worst
 
 
 def tables_of(cfg, s):
@@ -150,9 +76,9 @@ def test_attention_sublayer_is_the_references(position, kind, heads, impl):
     head rotated or YaRN on half of it, forward and the gradients of the
     input and of every weight."""
     cfg = dataclasses.replace(FP32, attention_impl=impl)
-    lp = block_of(seeded_params(cfg), position)
+    lp = block_of(seeded_params(LAGUNA, cfg), position)
     h = jax.random.normal(jax.random.key(3), (B, S, cfg.d_model), jnp.float32)
-    shape = shape_of(cfg)
+    shape = LAGUNA.shape_of(cfg)
 
     def program(h, lp):
         return laguna.attention_sublayer(h, rms_norm(h, lp["ln1"], cfg.rms_eps), lp, cfg, kind=kind,
@@ -161,12 +87,16 @@ def test_attention_sublayer_is_the_references(position, kind, heads, impl):
     def reference(h, lp):
         return jnp.stack([laguna_decoder.attention(h[b], lp, shape, kind, heads) for b in range(B)])
 
+    probe = jax.random.normal(jax.random.key(4), h.shape)
+
+    def value_and_grads(f):
+        return jax.jit(jax.value_and_grad(
+            lambda h, lp: (lambda out: ((out * probe).sum(), out))(f(h, lp)), (0, 1), has_aux=True))
+
     with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(np.asarray(program(h, lp)), np.asarray(reference(h, lp)),
-                                   rtol=2e-5, atol=2e-5)
-        probe = jax.random.normal(jax.random.key(4), h.shape)
-        got = jax.grad(lambda h, lp: (program(h, lp) * probe).sum(), (0, 1))(h, lp)
-        want = jax.grad(lambda h, lp: (reference(h, lp) * probe).sum(), (0, 1))(h, lp)
+        (_, out), got = value_and_grads(program)(h, lp)
+        (_, ref), want = value_and_grads(reference)(h, lp)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
     used = ("ln1", "wq", "wk", "wv", "wg", "wo")
     worst = worst_leaf({"h": got[0], **{k: got[1][k] for k in used}},
                        {"h": want[0], **{k: want[1][k] for k in used}})
@@ -178,7 +108,7 @@ def test_a_token_600_back_reaches_a_full_layer_and_not_a_sliding_one():
     position 0 changes a sliding layer's output at positions 0 .. 511
     and nowhere after; a full layer's, everywhere."""
     cfg = dataclasses.replace(FP32, sliding_window=512)
-    params = seeded_params(cfg)
+    params = seeded_params(LAGUNA, cfg)
     s = 640
     h = jax.random.normal(jax.random.key(3), (1, s, cfg.d_model), jnp.float32)
     moved = h.at[0, 0].add(1.0)
@@ -199,7 +129,7 @@ def test_a_token_600_back_reaches_a_full_layer_and_not_a_sliding_one():
 
 
 def test_the_gate_is_one_sigmoid_a_head_and_token_on_the_attentions_output():
-    lp = block_of(seeded_params(FP32), 0)
+    lp = block_of(seeded_params(LAGUNA, FP32), 0)
     h = jax.random.normal(jax.random.key(3), (B, S, FP32.d_model), jnp.float32)
 
     def run(lp):
@@ -276,10 +206,10 @@ def test_softmax_routing_bias_renormalisation_and_scaling(bias):
     """moe_ffn on a Laguna-kind block against the reference's expert half:
     top-k of p + b, weights 2.5 x p / sum p, the shared expert; and the
     old softmax configurations keep what they had."""
-    params = seeded_params(FP32, bias=bias)
+    params = seeded_params(LAGUNA, FP32, bias=bias)
     lp = block_of(params, 1, bias_row=1)
     x = jax.random.normal(jax.random.key(5), (B, S, FP32.d_model), jnp.float32)
-    shape = shape_of(FP32)
+    shape = LAGUNA.shape_of(FP32)
     with jax.default_matmul_precision("highest"):
         out, stats, _ = moe.moe_ffn(x, lp, FP32)
         want = []
@@ -310,13 +240,8 @@ def test_train_path_meets_the_reference_in_loss_and_gradients(held, bias):
     expert of every block, and every gradient by its worst leaf."""
     cfg = FP32 if held is None else dataclasses.replace(
         FP32, experts_held=held[0], first_expert_held=held[1])
-    params, batch, shape = seeded_params(cfg, bias=bias), skewed_tokens(cfg), shape_of(cfg)
-    with jax.default_matmul_precision("highest"):
-        loss, weight, stats = llama.loss_and_weight_fn(params, batch, cfg)
-        got = jax.grad(lambda p: llama.loss_fn(p, batch, cfg))(params)
-        ref = laguna_decoder.loss_parts(params, batch["tokens"], batch["targets"], shape)
-        want = jax.grad(lambda p: laguna_decoder.loss(
-            p, batch["tokens"], batch["targets"], shape))(params)
+    ours, theirs = train_path(LAGUNA, cfg, bias), reference_path(LAGUNA, cfg, bias)
+    loss, weight, stats, ref = ours.loss, ours.weight, ours.stats, theirs.parts
     assert float(weight) == B * S
     assert float(loss) == pytest.approx(float(ref["loss"]), rel=2e-6)
     assert stats["tokens_per_expert"].shape == (8, cfg.n_experts)
@@ -328,38 +253,20 @@ def test_train_path_meets_the_reference_in_loss_and_gradients(held, bias):
         elsewhere = cfg.top_k * B * S - stats["tokens_per_expert"][:, first:first + n].sum(-1)
         assert stats["pairs_elsewhere"].tolist() == elsewhere.tolist()
         assert 0 < int(elsewhere.sum()) < 8 * cfg.top_k * B * S
-    worst = worst_leaf(got, want)
-    assert len(worst) == len(jax.tree.leaves(params)) - 1
+    worst = worst_leaf(ours.grads, theirs.grads)
+    assert len(worst) == len(jax.tree.leaves(ours.params)) - 1
     assert max(worst.values()) < 2e-4, sorted(worst.items(), key=lambda kv: -kv[1])[:3]
 
 
 def test_a_tail_after_the_last_whole_period_runs_in_layer_order():
     cfg = dataclasses.replace(FP32, n_layers=11)   # dense + 2 periods + sliding, sliding
-    params, batch, shape = seeded_params(cfg, bias=0.05), skewed_tokens(cfg), shape_of(cfg)
+    params, batch = seeded_params(LAGUNA, cfg, bias=0.05), LAGUNA.batch_of(cfg)
+    shape = LAGUNA.shape_of(cfg)
     with jax.default_matmul_precision("highest"):
-        loss, _, stats = llama.loss_and_weight_fn(params, batch, cfg)
+        loss, _, stats = jax.jit(lambda p: llama.loss_and_weight_fn(p, batch, cfg))(params)
         ref = laguna_decoder.loss_parts(params, batch["tokens"], batch["targets"], shape)
     assert float(loss) == pytest.approx(float(ref["loss"]), rel=2e-6)
     assert stats["tokens_per_expert"].tolist() == ref["tokens_per_expert"].tolist()
-
-
-def test_bf16_compute_stays_near_the_reference():
-    cfg = dataclasses.replace(FP32, dtype=jnp.bfloat16, attention_impl="flash", n_layers=5)
-    params, batch = seeded_params(cfg), skewed_tokens(cfg)
-    loss = llama.loss_fn(params, batch, cfg)
-    ref = laguna_decoder.loss(params, batch["tokens"], batch["targets"], shape_of(cfg))
-    assert float(loss) == pytest.approx(float(ref), rel=0.02)
-
-
-@pytest.mark.parametrize("remat_policy", ["dots", "full"])
-def test_remat_gives_the_same_gradients(remat_policy):
-    plain = dataclasses.replace(FP32, n_layers=5)   # the dense layer and one period
-    cfg = dataclasses.replace(plain, remat=True, remat_policy=remat_policy)
-    params, batch = seeded_params(cfg, bias=0.05), skewed_tokens(cfg)
-    got = jax.grad(lambda p: llama.loss_fn(p, batch, cfg))(params)
-    want = jax.grad(lambda p: llama.loss_fn(p, batch, plain))(params)
-    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=2e-6)
 
 
 # -- the share adds up ---------------------------------------------------------------
@@ -380,7 +287,7 @@ def test_thirty_two_shares_add_up_to_the_uncut_layer():
     no_shared = {**lp, "shared_down": jnp.zeros_like(lp["shared_down"])}
     experts = ("w_gate", "w_up", "w_down")
 
-    def run(cfg, lp):
+    def run(cfg, lp):   # bare: 32 shares are 32 configurations, a compile each under jit
         out, vjp, stats = jax.vjp(lambda x: moe.moe_ffn(x, lp, cfg)[:2], x, has_aux=True)
         return out, vjp(jnp.ones_like(out))[0], stats
 
@@ -409,38 +316,6 @@ def test_thirty_two_shares_add_up_to_the_uncut_layer():
 # -- the registry ------------------------------------------------------------------
 
 
-def catalog_config():
-    if os.path.exists(CATALOG):
-        for line in open(CATALOG):
-            row = json.loads(line)
-            if row["name"] == "Laguna-S-2.1":
-                return row["config"]
-    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                        "chipbench", "configs", "laguna-s-2.1-train.json")
-    file = json.load(open(path))
-    return {**{k: v for k, v in file.items() if k not in file["published"]}, **file["published"]}
-
-
-def test_config_from_hf_maps_the_catalogs_config_onto_the_preset():
-    cfg = config_from_hf(catalog_config())
-    assert cfg == get_model_config("laguna-s-2.1")
-    assert cfg.head_dim == 128 != cfg.d_model // cfg.n_heads
-    assert cfg.rope_full.attention_factor == 1.4852030263919618 and cfg.rope_full.partial == 0.5
-
-
-@pytest.mark.parametrize("key,value,names", [
-    ("moe_router_logit_softcapping", 30.0, "moe_router_logit_softcapping 30.0"),
-    ("moe_apply_router_weight_on_input", True, "moe_apply_router_weight_on_input"),
-    ("gating", True, "gating True"),
-    ("attention_bias", True, "attention_bias"),
-    ("num_attention_heads_per_layer", list(range(48, 96)), "never repeat"),
-    ("mlp_layer_types", ["dense", "sparse", "dense"] + ["sparse"] * 45, "dense layer after"),
-])
-def test_config_from_hf_refuses_by_name_what_is_not_implemented(key, value, names):
-    with pytest.raises(ValueError, match=names):
-        config_from_hf({**catalog_config(), key: value})
-
-
 def test_other_families_still_refuse_a_scaled_rotary_and_an_explicit_head_dim():
     llama_like = {"architectures": ["LlamaForCausalLM"], "vocab_size": 100, "hidden_size": 64,
                   "num_hidden_layers": 2, "num_attention_heads": 4, "intermediate_size": 128}
@@ -448,10 +323,3 @@ def test_other_families_still_refuse_a_scaled_rotary_and_an_explicit_head_dim():
         config_from_hf({**llama_like, "rope_scaling": {"rope_type": "yarn", "factor": 4}})
     with pytest.raises(ValueError, match="head_dim"):
         config_from_hf({**llama_like, "head_dim": 32})
-
-
-def test_engine_refuses_the_model_by_name():
-    from ray_tpu.llm.engine import EngineConfig
-
-    with pytest.raises(ValueError, match="Laguna"):
-        EngineConfig(model="laguna-tiny")

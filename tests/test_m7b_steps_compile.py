@@ -1,0 +1,120 @@
+"""The dense steps (Mistral-7B wide, 2 layers) lowered and compiled for a
+described v5e, once each (tests/v5e_steps.py): `m7b-train`'s on one chip
+at batch 3 and `m7b-train-4chip`'s under fsdp 2 x tp 2 at batch 6. The
+text each lowers to, no trace of the overlap path without a mesh, the
+`tp` transfers under their matmuls with one, and the VMEM their
+operations are given. The cells stand two or three a file by their
+compiles' seconds (ROADMAP D8)."""
+
+import re
+import sys
+
+import pytest
+
+from v5e_steps import Step, matmul_tiles, train_step, v5e  # noqa: F401 - a fixture
+
+MESH = (1, 1, 2, 1, 1, 2)
+# sha256 of the lowered train step of mistral-7b (2 layers, flash, AdamW), as PR 38
+# (the full-attention sublayer head-major from its projections to `wo`) lowers it, the
+# flash kernels' serialized bodies taken out (they embed source locations); from commit
+# 5b629f1 (the parent of PR 26) to PR 37 it was 14345d8a... / dd35b02d.... A change
+# that MEANS to alter the dense step prints the new text's hash in the failure and
+# replaces these.
+_DENSE_STEP = {
+    None: "e735d680c01a71bc9f75193edc03cd16e2d207738ff990ed5a6cb0e7dddeca3f",
+    MESH: "bdea6ab54b92ac603d3d65a9b55c170f53065ddf003ac3aa93407b36fb810b02",
+}
+
+
+@pytest.mark.parametrize("mesh_shape,batch", [(None, 3), (MESH, 6)],
+                         ids=["one_chip", "fsdp2_tp2"])
+def test_dense_train_step_lowers_to_the_text_it_had_before_the_expert_layer(
+        v5e, mesh_shape, batch):
+    """One block serves dense and expert configurations (PR 26); for a
+    dense one the lowered step is the text it was, which is what keeps
+    `m7b-train` and `m7b-train-4chip` where they are. And one flash
+    path serves both of its entries (PR 33: `flash_attention` is its
+    transposes around the head-major one that CCA calls): the steps
+    that enter by the old one, OLMoE's too, lower to the text they had.
+    And PR 34's third kind of attention, sigmoid scores, shared expert,
+    dense layers before the scan and second head leave all four, ZAYA1's
+    with them, the text they had. PR 38 MEANT to alter the three steps
+    that run the full-attention branch (head-major from the projections
+    to `wo`) and replaced their hashes; ZAYA1's and GLM-4.7-Flash's,
+    which bypass that branch, keep the text their parents gave them.
+    PR 40 MEANT to alter the steps of the SMALL shares (GLM-4.7-Flash's
+    hash replaced; Laguna's step is held by its own tests): the dense
+    steps, OLMoE's (every expert held) and ZAYA1's (a half share: no
+    compact path is built) keep theirs. PR 44 MEANT to alter the small
+    shares whose [N, C] is large (GLM-4.7-Flash's hash replaced again;
+    Keye's step is held by its own test): the four others never reach
+    the sum of the held rows and keep theirs. The expert steps' cases
+    stand with their steps: OLMoE's and ZAYA1's in
+    tests/test_olmoe_zaya1_keye_steps_compile.py, GLM-4.7-Flash's in
+    tests/test_glm47f_laguna_steps_compile.py."""
+    assert train_step(v5e, mesh_shape, batch=batch).lowered_hash() == _DENSE_STEP[mesh_shape]
+
+
+def test_one_chip_train_step_never_asks_for_tp_overlap(v5e, monkeypatch):
+    """No mesh: `_block` takes the plain einsums and does not even import
+    parallel/tp_overlap.py — the lowered step is the same text with the
+    module loaded and with its import made to fail (a FRESH trace, round
+    the memo)."""
+    import ray_tpu.parallel.tp_overlap  # noqa: F401 - loaded
+
+    with_module = train_step(v5e, batch=3).lowered_text
+    monkeypatch.setitem(sys.modules, "ray_tpu.parallel.tp_overlap", None)
+    with pytest.raises(ImportError):
+        import ray_tpu.parallel.tp_overlap  # noqa: F401,F811
+    assert Step(v5e, batch=3).lowered_text == with_module
+    assert "tpu_custom_call" in with_module and "collective_permute" not in with_module
+
+
+def test_tp_matmuls_of_the_train_step_overlap_their_transfers(v5e):
+    """The fsdp 2 x tp 2 train step of `m7b-train-4chip` (2 layers):
+    neither layer scan, forward or backward, waits for an all-reduce of
+    the residual stream; the blocks travel by collective-permute, which
+    the compiler starts before a matmul and finishes after it."""
+    step = train_step(v5e, MESH, batch=6)
+    hlo, computations = step.hlo, step.computations
+    assert "tpu_custom_call" in hlo
+    bodies = [computations[name] for name in set(re.findall(r"body=%?([\w.\-]+)", hlo))
+              if "tpu_custom_call" in computations[name]]  # the two layer scans
+    assert len(bodies) == 2
+    for body in bodies:
+        assert not re.search(r"= bf16\[\d+,4096,4096\]\S* all-reduce(-start)?\(", body)
+        # scheduled text: a matmul fusion between each block's start and its done
+        matmuls = [m.start() for m in re.finditer(r" fusion\([^\n]*calls=%?([\w.\-]+)", body)
+                   if " convolution(" in computations[m.group(1)]]
+        blocks = list(re.finditer(
+            r"%([\w.\-]+) = \(bf16\[3,2048,4096\][^=]*? collective-permute-start\(", body))
+        assert len(blocks) >= 4, "two gathers and two scatters a layer and direction"
+        for start in blocks:
+            done = body.index(f" collective-permute-done(%{start.group(1)})")
+            assert any(start.start() < at < done for at in matmuls), start.group(1)
+
+
+@pytest.mark.parametrize("mesh_shape,batch,temp_gib,tiles_at_16", [
+    (None, 3, 11.2, 37144),
+    (MESH, 6, 5.4, 10532),
+], ids=["m7b_train", "m7b_train_4chip"])
+def test_train_steps_compile_with_the_vmem_their_operations_are_given(
+        v5e, mesh_shape, batch, temp_gib, tiles_at_16):
+    """train/step.py gives one operation of the step 32 MiB of a v5e core's
+    VMEM where XLA's default is 16, which is what the matmul fusions are
+    tiled for (the head's weight gradient with the optimizer's update in
+    it first of all: 84 x 8 x 13 tiles in `m7b-train`, 84 x 4 x 10 now).
+    Every cell's step (2 layers under the mesh), compiled for the
+    described chip: its matmul fusions are cut into fewer than half the
+    tiles they have at 16 MiB; the temporaries stay where they were
+    (10.98, 6.62 and 5.11 GiB at 16 MiB: past 11.2 `m7b-train`
+    rematerialises); and what the limit is bought with is still there:
+    XLA keeps whole arrays in the VMEM no operation claims, and the expert
+    layer's token gathers read their 96 MiB table [24576, 2048] from it,
+    five times as fast as from HBM. From 40 MiB the table no longer fits
+    and `olmoe-train` loses what its matmuls gain (PERF.md, PR 29). The
+    dense cells' two cases; `olmoe-train`'s stands with its step in
+    tests/test_olmoe_zaya1_keye_steps_compile.py."""
+    step = train_step(v5e, mesh_shape, batch=batch)
+    assert 0 < matmul_tiles(step.hlo) < 0.5 * tiles_at_16
+    assert step.memory.temp_size_in_bytes < temp_gib * 2 ** 30

@@ -1,0 +1,100 @@
+"""What every model that goes through the one decoder holds, a case a
+model: a row of tests/model_cases.py::MODELS. Rematerialisation gives the
+gradients it is not there to change, bf16 compute stays near the plain
+reference, `config_from_hf` maps the catalog's config onto the preset and
+refuses by name what is not implemented, and the engine refuses the model
+by name. What a model holds of its own (its sublayers, its routing, its
+train path against its reference, its shares) stands in its own file."""
+
+import dataclasses
+import operator
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from model_cases import (GLM_LITE, KEYE, LAGUNA, MODELS, Model, catalog_config, seeded_params,
+                         train_path)
+from ray_tpu.models import llama
+from ray_tpu.models.registry import config_from_hf, get_model_config
+
+by_name = pytest.mark.parametrize("model", MODELS, ids=lambda m: m.name)
+
+
+@pytest.mark.parametrize("remat_policy", ["dots", "full"])
+@by_name
+def test_remat_gives_the_same_gradients(model, remat_policy):
+    """The loss and every gradient of the rematerialised train path are
+    the plain one's, on the parameters the model's own file gave this test
+    before it was one: the selection biases a random table at the row's
+    `remat_bias` (ZAYA1 0.05, GLM-4.7-Flash 0.1, Laguna 0.05 over the
+    dense layer and one period, Keye 0), at the model's own tolerance.
+    The plain gradients are made once for both policies, and where the
+    bias is the model's own they are its train-path test's too."""
+    plain = dataclasses.replace(model.fp32, **model.remat_plain)
+    cfg = dataclasses.replace(plain, remat=True, remat_policy=remat_policy)
+    want, got = train_path(model, plain, model.remat_bias), train_path(model, cfg, model.remat_bias)
+    assert float(got.loss) == pytest.approx(float(want.loss), rel=1e-6)
+    for g, w in zip(jax.tree.leaves(got.grads), jax.tree.leaves(want.grads)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), **model.remat_tol)
+
+
+@by_name
+def test_bf16_compute_stays_near_the_reference(model):
+    """The loss in bfloat16 (Laguna's and Keye's through the flash
+    kernels, interpreted) against the plain reference's on the same
+    bfloat16 parameters."""
+    cfg = dataclasses.replace(model.fp32, dtype=jnp.bfloat16, **model.bf16)
+    params, batch = seeded_params(model, cfg), model.batch_of(cfg)
+    loss = jax.jit(lambda p: llama.loss_fn(p, batch, cfg))(params)
+    with model.reference_set_up():
+        ref = model.reference.loss(params, batch["tokens"], batch["targets"], model.shape_of(cfg))
+    assert float(loss) == pytest.approx(float(ref), rel=model.bf16_rel)
+
+
+@by_name
+def test_config_from_hf_maps_the_catalogs_config_onto_the_preset(model):
+    """The catalog's config is the registry's preset, of the model's own
+    configuration class, with a head wider or narrower than d_model /
+    heads and the published values the row names."""
+    cfg = config_from_hf(catalog_config(model))
+    assert cfg == get_model_config(model.preset) and type(cfg) is type(model.fp32)
+    assert cfg.head_dim != cfg.d_model // cfg.n_heads
+    for field, value in model.facts.items():
+        assert operator.attrgetter(field)(cfg) == value, field
+
+
+@pytest.mark.parametrize("model,key,value,names", [
+    (GLM_LITE, "rope_scaling", {"type": "yarn", "factor": 4.0}, "rope_scaling"),
+    (GLM_LITE, "n_group", 8, "group-limited"),
+    (GLM_LITE, "num_nextn_predict_layers", 2, "multi-token-prediction"),
+    (GLM_LITE, "attention_bias", True, "attention_bias"),
+    (LAGUNA, "moe_router_logit_softcapping", 30.0, "moe_router_logit_softcapping 30.0"),
+    (LAGUNA, "moe_apply_router_weight_on_input", True, "moe_apply_router_weight_on_input"),
+    (LAGUNA, "gating", True, "gating True"),
+    (LAGUNA, "attention_bias", True, "attention_bias"),
+    (LAGUNA, "num_attention_heads_per_layer", list(range(48, 96)), "never repeat"),
+    (LAGUNA, "mlp_layer_types", ["dense", "sparse", "dense"] + ["sparse"] * 45, "dense layer after"),
+    (KEYE, "vision_config", {"depth": 27}, "vision tower or image / video inputs .vision_config."),
+    (KEYE, "image_token_id", 151655, "image_token_id"),
+    (KEYE, "sa_config", None, "no sa_config"),
+    (KEYE, "sa_config", {"indexer_num_heads": 16, "indexer_head_dim": 64,
+                         "indexer_num_kv_heads": 2, "topk": 2048}, "indexer_num_kv_heads 2"),
+    (KEYE, "rope_scaling", {"rope_type": "yarn", "factor": 4}, "rope_scaling type 'yarn'"),
+    (KEYE, "use_sliding_window", True, "a sliding window"),
+    (KEYE, "attention_bias", True, "attention_bias"),
+    (KEYE, "mlp_only_layers", [0], "mlp_only_layers"),
+    (KEYE, "decoder_sparse_step", 2, "decoder_sparse_step 2"),
+], ids=lambda v: v.name if isinstance(v, Model) else None)
+def test_config_from_hf_refuses_by_name_what_is_not_implemented(model, key, value, names):
+    with pytest.raises(ValueError, match=names):
+        config_from_hf({**catalog_config(model), key: value})
+
+
+@by_name
+def test_engine_refuses_the_model_by_name(model):
+    from ray_tpu.llm.engine import EngineConfig
+
+    with pytest.raises(ValueError, match=model.refused_as):
+        EngineConfig(model=model.tiny)

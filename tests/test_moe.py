@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from chipbench.reference import olmoe_decoder
+from model_cases import skewed_tokens, spread
 from ray_tpu.models import llama, moe
 
 FP32 = dataclasses.replace(moe.MOE_TINY, dtype=jnp.float32)
@@ -42,21 +43,15 @@ def _shape(cfg: moe.MoEConfig) -> dict:
 def _params(cfg, seed=0):
     """Seeded random weights, the norm scales too (ones would hide them)."""
     params = llama.init_params(cfg, jax.random.key(seed))
-    for i, name in enumerate(("ln1", "ln2", "q_norm", "k_norm")):
-        if name in params["layers"]:
-            leaf = params["layers"][name]
-            params["layers"][name] = 1 + 0.2 * jax.random.normal(
-                jax.random.key(100 + i), leaf.shape)
+    names = ("ln1", "ln2", "q_norm", "k_norm")
+    keys = iter(jax.random.key(100 + i) for i, name in enumerate(names)
+                if name in params["layers"])
+    spread(params["layers"], {n: 0.2 for n in names if n in params["layers"]}, keys)
     return params
 
 
-def _skewed_batch(cfg, batch=2, seq=33, seed=1):
-    """Zipf-like tokens: a few ids make most of the batch, as the
-    benchmark's traffic does, so the groups are uneven."""
-    p = 1.0 / np.arange(1, cfg.vocab_size + 1) ** 1.5
-    toks = np.random.default_rng(seed).choice(
-        cfg.vocab_size, size=(batch, seq), p=p / p.sum()).astype(np.int32)
-    return {"tokens": jnp.asarray(toks[:, :-1]), "targets": jnp.asarray(toks[:, 1:])}
+def _skewed_batch(cfg, batch=2):
+    return skewed_tokens(cfg, batch, 32, power=1.5)
 
 
 def _naive_moe(x, lp, cfg):
@@ -127,8 +122,8 @@ def test_train_path_meets_the_reference_in_loss_and_gradients(norm_topk_prob):
     params, batch = _params(cfg), _skewed_batch(cfg)
     with jax.default_matmul_precision("highest"):
         loss, grads = jax.jit(jax.value_and_grad(lambda p: llama.loss_fn(p, batch, cfg)))(params)
-    ref_loss, ref_grads = jax.value_and_grad(
-        lambda p: olmoe_decoder.loss(p, batch["tokens"], batch["targets"], _shape(cfg)))(params)
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
+        lambda p: olmoe_decoder.loss(p, batch["tokens"], batch["targets"], _shape(cfg))))(params)
     assert abs(float(loss) - float(ref_loss)) <= LOSS_RTOL * float(ref_loss)
     worst = jax.tree.map(
         lambda g, r: float(jnp.abs(g - r).max() / jnp.abs(r).max()), grads, ref_grads)
@@ -157,9 +152,9 @@ def test_qk_norm_meets_the_reference_and_is_used():
         h = params["embed"][tokens]
         lp = jax.tree.map(lambda x: x[0], params["layers"])
         cos, sin = llama.rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
-        block = lambda lp: llama._block(
+        block = jax.jit(lambda lp: llama._block(
             h, lp, config=cfg, cos=cos, sin=sin,
-            positions=jnp.arange(tokens.shape[1]), segment_ids=None)[0][0]
+            positions=jnp.arange(tokens.shape[1]), segment_ids=None)[0][0])
         out = block(lp)
         ref = olmoe_decoder.attention(h[0], lp, _shape(cfg))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)

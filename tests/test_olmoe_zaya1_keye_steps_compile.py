@@ -1,0 +1,198 @@
+"""The steps of `olmoe-train`, `zaya1-train` and `keye-train-8k` for a
+described v5e (tests/v5e_steps.py), each compiled ONCE. OLMoE-1B-7B's
+(one layer, batch 6): the text it lowers to, its nine tiled grouped
+matmuls, the VMEM its operations are given. ZAYA1-8B's (8 of 16 experts
+and an eighth of the vocabulary held): the text the cell's six layers
+lower to, and one layer compiled: CCA through the flash kernels, its mix
+laid out head-major, the held experts' kernels. The language model of
+Keye-VL-2.0's (16 of 128 experts held, ONE sequence of 8192) at two
+layers: the flash kernels under a packed selection. The cells stand two
+or three a file by their compiles' seconds (ROADMAP D8), not by their
+kind."""
+
+import re
+
+from v5e_steps import grouped_kernels, matmul_tiles, train_step, v5e  # noqa: F401 - a fixture
+
+OLMOE = dict(batch=6, model="olmoe-1b-7b", n_layers=1)
+# sha256 of the lowered step of olmoe-1b-7b as `olmoe-train` builds it (one layer, batch 6):
+# the dense steps' block with the q/k norm, so PR 38's text too (36d2bc29... from PR 33's
+# parent to PR 37); the account of every hash is tests/test_m7b_steps_compile.py's
+_OLMOE_STEP = "9cbdafe7fcffbc7f1b855fa71c37223b22ce43b219d133479411c4ab59756fe8"
+ZAYA_SHARE = dict(model="zaya1-8b", vocab_size=32896, experts_held=8)
+# sha256 of the lowered step of zaya1-8b as `zaya1-train` builds it (six layers, 8 of 16
+# experts and an eighth of the vocabulary held, batch 2), as commit 21a2054 (the parent of PR
+# 34, which gave the block a third kind of attention, the expert layer a second kind of score
+# and the decoder blocks outside its scan) lowers it; the account of every hash is
+# tests/test_m7b_steps_compile.py's
+_ZAYA_STEP = "5c0e2e3ba71539f56323de421562ccae59c9377d2e3b1531e6a4a2459053d47d"
+KEYE = dict(batch=1, model="keye-vl-2.0-30b-a3b", n_layers=2, seq=8192, vocab_size=19072,
+            experts_held=16)
+
+
+def test_olmoe_train_step_lowers_to_the_text_it_had(v5e):
+    """The case `olmoe` of the dense steps' test
+    (tests/test_m7b_steps_compile.py): OLMoE's step enters flash by the
+    old entry and holds every expert, so PR 33, 34, 40 and 44 left it the
+    text it had, and PR 38 MEANT to alter it."""
+    assert train_step(v5e, **OLMOE).lowered_hash() == _OLMOE_STEP
+
+
+def test_expert_train_step_runs_nine_tiled_grouped_matmuls(v5e):
+    """The OLMoE step of `olmoe-train` (one layer, batch 6) compiled for
+    the described chip: its grouped matmuls are the kernels of
+    ops/grouped_matmul.py, nine of them (forward, input and weight
+    gradient of gate, up and down: none recomputed under remat), under
+    names a profile's reader classes as the expert layer's
+    (`^kernel:ragged-dot` in chipbench/trace_names), and XLA's own
+    512 x 512 x 512 kernel is gone. One tile schedule a layer and
+    direction, not one a call."""
+    step = train_step(v5e, **OLMOE)
+    engaged = step.engaged("grouped_matmul.kernel", "grouped_matmul.ragged_dot")
+    assert engaged["grouped_matmul.kernel"] > 0 and engaged["grouped_matmul.ragged_dot"] == 0
+    hlo, kernels = step.hlo, step.kernels
+    grouped = grouped_kernels(kernels)
+    assert grouped == (["ragged-dot-tiled"] * 3 + ["ragged-dot-tiled-dgrad"] * 3
+                       + ["ragged-dot-tiled-wgrad"] * 3), kernels
+    assert "ragged-dot-none" not in hlo and "ragged-dot-metadata" not in hlo
+    # what is no grouped matmul is flash: forward, and backward
+    assert len(kernels) - len(grouped) == 2, kernels
+    # the schedule's three comparisons of visits with groups: one schedule
+    # forward and one backward, where one a call would be nine
+    assert len(re.findall(r"pred\[447,64\]\S* compare\(", hlo)) <= 2 * 3
+    # 7.37 GiB at the parent: past 8 the compiler rematerialises the head
+    assert step.memory.temp_size_in_bytes < 7.6 * 2 ** 30
+
+
+def test_olmoe_train_step_compiles_with_the_vmem_its_operations_are_given(v5e):
+    """`olmoe-train`'s case of the dense steps' test in
+    tests/test_m7b_steps_compile.py: fewer than half the 30,468 tiles its
+    matmul fusions have at 16 MiB, the temporaries under 6.9 GiB (6.62 at
+    16 MiB), and what the limit is bought with: the expert layer's token
+    gathers read their 96 MiB table [24576, 2048] from the VMEM no
+    operation claims."""
+    temp_gib, tiles_at_16 = 6.9, 30468
+    step = train_step(v5e, **OLMOE)
+    hlo = step.hlo
+    assert 0 < matmul_tiles(hlo) < 0.5 * tiles_at_16
+    assert step.memory.temp_size_in_bytes < temp_gib * 2 ** 30
+    in_vmem = [name for name, body in re.findall(
+        r"^%(fused_computation[.\d]*) \([^\n]*\{\n(.*?)^\}", hlo, re.M | re.S)
+        if re.search(r"= bf16\[24576,2048\]\{[^}]*S\(1\)\} parameter\(0\)", body)
+        and " gather(" in body]
+    assert len(in_vmem) >= 2, in_vmem
+
+
+def test_zaya_train_step_lowers_to_the_text_it_had(v5e):
+    """The case `zaya` of the dense steps' test
+    (tests/test_m7b_steps_compile.py): CCA bypasses the full-attention
+    branch PR 38 altered, and a half share builds no compact path (PR 40,
+    PR 44), so the step keeps the text PR 34's parent gave it."""
+    assert train_step(v5e, batch=2, n_layers=6, **ZAYA_SHARE).lowered_hash() == _ZAYA_STEP
+
+
+def test_zaya_share_train_step_runs_its_kernels_and_skips_the_rows_elsewhere(v5e):
+    """ZAYA1-8B as `zaya1-train` builds it (8 of 16 experts held, an
+    eighth of the vocabulary; ONE layer and one sequence here, the
+    cell's six and its batch are rehearsed in PERF.md), compiled for
+    the described chip: CCA's attention is the two flash kernels, its
+    mix is laid out with the tokens and a head's channels as the tile, the
+    held experts' nine grouped matmuls are the kernels of
+    ops/grouped_matmul.py with a group's whole [2048, 2048] weight
+    matrix as one block, XLA's own ragged-dot kernel is not there, and
+    both new sublayers count their sites."""
+    step = train_step(v5e, batch=1, n_layers=1, **ZAYA_SHARE)
+    engaged = step.engaged("cca.attn", "moe.ffn", "grouped_matmul.kernel",
+                           "grouped_matmul.ragged_dot")
+    assert engaged["cca.attn"] > 0 and engaged["moe.ffn"] > 0
+    assert engaged["grouped_matmul.kernel"] > 0 and engaged["grouped_matmul.ragged_dot"] == 0
+    hlo, kernels = step.hlo, step.kernels
+    assert grouped_kernels(kernels) == (["ragged-dot-tiled"] * 3 + ["ragged-dot-tiled-dgrad"] * 3
+                                        + ["ragged-dot-tiled-wgrad"] * 3), kernels
+    assert "ragged-dot-none" not in hlo
+    # what is no grouped matmul is flash, named after the scope it is called in
+    rest = [k for k in kernels if not k.startswith("ragged-dot")]
+    assert len(rest) == 2 and all(k.startswith("cca.attend") for k in rest), kernels
+    # the router's state leaves the forward scan beside the hidden state
+    assert re.search(r"f32\[1,4096,256\]", hlo)
+    # 8 held experts' weights and no more: [1, 8, 2048, 2048], never 16
+    assert "8,2048,2048]" in hlo and "16,2048,2048]" not in hlo
+    # CCA's mix holds its heads in a MAJOR dimension (PR 33): wherever an array under
+    # `cca.mix` has a head's channels in its lanes, the tokens are in the sublanes, never
+    # the 2, 8 or 10 heads (padded to the tile's 8 or 16); and nothing is moved between
+    # layouts: the parent had 12 `copy` instructions of activations under that scope in
+    # this step ([1, 4096, 10, 128] <-> [10, 1, 4096, 128] and channels-in-sublanes
+    # copies), and 0.8522 GiB of temporaries (what is still copied is the taps' weights,
+    # [heads, 2, 128, 128])
+    mix = [(shape, op) for shape, op, op_name in re.findall(
+        r"^\s*(?:ROOT )?%[\w.\-]+ = (\(.*?\)|\S+) ([\w\-]+)\([^\n]*op_name=\"([^\"]*)\"", hlo, re.M)
+        if re.search(r"(?:^|/)cca\.mix(?:/|$)", op_name)]
+    assert len(mix) > 50
+    assert not [shape for shape, op in mix if op in ("copy", "transpose") and "4096" in shape]
+    arrays = [([int(d) for d in dims.split(",")], [int(i) for i in order.split(",")])
+              for shape, _ in mix
+              for dims, order in re.findall(r"(?:bf16|f32)\[([\d,]+)\]\{([\d,]+)", shape)]
+    tiles = {(dims[order[1]], dims[order[0]]) for dims, order in arrays
+             if len(dims) >= 4 and 4096 in dims and dims[order[0]] != 4096}
+    assert tiles and all(rows == 4096 for rows, _ in tiles), tiles
+    assert step.memory.temp_size_in_bytes < 0.8522 * 2 ** 30
+
+
+def test_keye_share_train_step_runs_the_kernels_under_a_packed_selection(v5e):
+    """The language model of Keye-VL-2.0 as `keye-train-8k` builds it (16 of
+    128 experts and an eighth of the vocabulary held, ONE sequence of 8192;
+    two of the cell's layers here), compiled for the described chip: the
+    attention is the flash kernels under the indexer's selection, named
+    `dsa.attend.N`: one forward and ONE backward (PR 43: 8192 keys at
+    heads of 128 in bf16 are one kv block, two selection blocks wide, so
+    the backward is the fused kernel, which states the 33 MiB of VMEM its
+    blocks need; this compile is also the check that Mosaic accepts the
+    block); the selection reaches them as ONE packed
+    int32 [1, 8192, 256] array a layer (8 MiB), stacked over the layers
+    for the backward, which computes no index score and no top-k again;
+    no [.., 8192, 8192] array of any type exists, the index scores are at
+    most [1, 16, 512, 8192] float32 a chunk; the held experts' grouped
+    matmuls are the kernels of ops/grouped_matmul.py at [2048, 768] on
+    the compact path, whose sums of the 16,384 held rows into the 8192
+    tokens are built in the LINEAR form at every site (PR 44: no
+    [8192, 16384] one-hot matrix is an operand or a result of anything);
+    every scope the cell's readers sum is in the compiled step; no site
+    falls back."""
+    from ray_tpu.ops.flash import _fused_bwd_params
+
+    step = train_step(v5e, **KEYE)
+    engaged = step.engaged("dsa.attn", "moe.ffn", "grouped_matmul.kernel",
+                           "grouped_matmul.ragged_dot", "tp_overlap.plain", "moe.compact",
+                           "moe.full", "flash.bwd_fused", "flash.bwd_split",
+                           "moe.sum.linear", "moe.sum.product")
+    assert engaged["dsa.attn"] >= 1 and engaged["moe.ffn"] >= 1
+    assert engaged["moe.compact"] >= 1 and engaged["moe.full"] == 0
+    assert engaged["moe.sum.linear"] >= 2 and engaged["moe.sum.product"] == 0   # combine, dispatch
+    assert engaged["grouped_matmul.kernel"] > 0
+    assert engaged["grouped_matmul.ragged_dot"] == engaged["tp_overlap.plain"] == 0  # fallback_sites
+    assert engaged["flash.bwd_fused"] >= 1 and engaged["flash.bwd_split"] == 0
+    hlo, kernels = step.hlo, step.kernels
+    flash = sorted(re.sub(r"\.\d+$", "", k) for k in kernels if not k.startswith("ragged-dot"))
+    assert flash == ["dsa.attend"] * 2, kernels   # forward, fused backward
+    # the fused backward's own limit: 24 MiB of kv blocks and scratch + 1 of row blocks + 8 spare
+    assert _fused_bwd_params(512, 8192, 128, 1, 2).vmem_limit_bytes == 33 << 20
+    assert len(re.findall(r'"scoped_memory_configs":\[\{[^}]*"size":"%d"' % (33 << 20), hlo)) == 1
+    assert "ragged-dot-none" not in hlo
+    assert any(k.startswith("ragged-dot-tiled-wgrad") for k in kernels)
+    # the selection: packed, a layer's and the stack's; nothing [T, T], whatever its type
+    assert re.search(r"s32\[1,8192,256\]", hlo) and re.search(r"s32\[2,1,8192,256\]", hlo)
+    assert not re.search(r"\[(?:\d+,)*8192,8192\]", hlo)
+    # the tokens x the held rows: lowered or compiled, no such matrix; a band's block is 256 tokens
+    assert not re.search(r"\[(?:\d+,)*8192,16384\]", hlo) and "8192x16384x" not in step.lowered_text
+    assert re.search(r"pred\[256,2048\]", hlo) and re.search(r"f32\[256,2048\]", hlo)
+    keys = {int(k) for k in re.findall(r"f32\[(?:1,)?16,512,(\d+)\]", hlo)}   # a chunk's scores
+    assert keys and max(keys) == 8192 and min(keys) > 2048
+    assert re.search(r"bf16\[1,32,8192,128\]", hlo) and re.search(r"bf16\[1,4,8192,128\]", hlo)
+    assert "16,2048,768]" in hlo and "128,2048,768]" not in hlo and "8192,128]" in hlo
+    for scope in ("dsa.qkv", "dsa.norm", "dsa.rope", "dsa.index.proj", "dsa.index.scores",
+                  "dsa.select", "dsa.attend", "dsa.out", "moe.router", "moe.dispatch",
+                  "moe.experts", "moe.combine", "block.norm", "block.stack", "head", "optim"):
+        assert step.has_scope(scope), scope
+    # nothing of the indexer is made again for the backward, and nothing of it is differentiated
+    indexer = [n for n in step.op_names if "dsa.select" in n or "dsa.index" in n]
+    assert indexer and not [n for n in indexer if "rematted_computation" in n or "transpose(" in n]

@@ -2,7 +2,7 @@
 matmuls against the plain einsum (whose sum over `tp` is what the
 compiler's all-reduce gives), forward and in every gradient, and a
 llama step under fsdp x tp against the same step on one device. What
-the compiler makes of them for the chip is tests/test_tpu_compile.py."""
+the compiler makes of them for the chip is tests/test_m7b_steps_compile.py."""
 
 import dataclasses
 

@@ -7,6 +7,7 @@ gradients, causality through both convolutions and the value shift
 and the grouped matmul whose trailing rows no tile visits."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -14,55 +15,16 @@ import numpy as np
 import pytest
 
 from chipbench.reference import zaya_decoder
+from model_cases import ZAYA, reference_path, seeded_params, train_path, worst_leaf
 from ray_tpu.models import cca, llama, moe
 from ray_tpu.nn.layers import rms_norm
 from ray_tpu.ops import grouped_matmul as gm
 
-FP32 = dataclasses.replace(cca.ZAYA_TINY, dtype=jnp.float32)
-B, S = 2, 24
-
-
-def shape_of(cfg) -> dict:
-    """A ZayaConfig as the configuration file's dict (HF key names)."""
-    return {
-        "hidden_size": cfg.d_model, "num_attention_heads": cfg.n_heads,
-        "num_key_value_heads": cfg.n_kv_heads, "head_dim": cfg.head_dim,
-        "cca_time0": cfg.conv_kernels[0], "cca_time1": cfg.conv_kernels[1],
-        "partial_rotary_factor": cfg.rotary_fraction,
-        "rope_parameters": {"hybrid": {"rope_theta": cfg.rope_theta}},
-        "rms_norm_eps": cfg.rms_eps, "router_hidden_size": cfg.router_hidden,
-        "num_experts": cfg.n_held, "published": {"num_experts": cfg.n_experts},
-        "deployment": {"first_expert_held": cfg.first_expert_held},
-        "num_experts_per_tok": cfg.top_k, "max_position_embeddings": cfg.max_seq,
-        "num_hidden_layers": cfg.n_layers, "tie_word_embeddings": cfg.tie_embeddings,
-        "vocab_size": cfg.vocab_size,
-    }
-
-
-def seeded_params(cfg, seed=0):
-    """init_params, with the leaves that start at one or zero moved off
-    them, so that a test sees the temperature, the router's carried
-    scale, its norm and its selection bias."""
-    params = llama.init_params(cfg, jax.random.key(seed))
-    keys = iter(jax.random.split(jax.random.key(seed + 100), 8))
-    layers = params["layers"]
-    for name, spread in (("temp", 0.3), ("router_gamma", 0.5), ("router_norm", 0.3),
-                         ("ln1", 0.2), ("ln2", 0.2)):
-        layers[name] = layers[name] + spread * jax.random.normal(next(keys), layers[name].shape)
-    layers["router_bias"] = 0.05 * jax.random.normal(next(keys), layers["router_bias"].shape)
-    return params
+FP32, B, S = ZAYA.fp32, ZAYA.batch, ZAYA.seq
 
 
 def layer_of(params, i):
     return jax.tree.map(lambda x: x[i], params["layers"])
-
-
-def skewed_tokens(cfg, seed=1):
-    rng = np.random.default_rng(seed)
-    p = 1.0 / np.arange(1, cfg.vocab_size + 1) ** 1.1
-    ids = rng.choice(cfg.vocab_size, size=(B, S + 1), p=p / p.sum())
-    return {"tokens": jnp.asarray(ids[:, :-1], jnp.int32),
-            "targets": jnp.asarray(ids[:, 1:], jnp.int32)}
 
 
 # -- the sublayers against the reference ---------------------------------------
@@ -71,12 +33,21 @@ def skewed_tokens(cfg, seed=1):
 @pytest.mark.parametrize("kernels", [(2, 2), (3, 2)])
 def test_cca_sublayer_is_the_references(kernels):
     cfg = dataclasses.replace(FP32, conv_kernels=kernels)
-    lp = layer_of(seeded_params(cfg), 1)
+    lp = layer_of(seeded_params(ZAYA, cfg), 1)
     h = jax.random.normal(jax.random.key(2), (B, S, cfg.d_model), jnp.float32)
-    x = rms_norm(h, lp["ln1"], cfg.rms_eps)
-    got = h + cca.cca_sublayer(x, lp, cfg, positions=jnp.arange(S), segment_ids=None)
+
+    @jax.jit
+    def program(h, lp):
+        x = rms_norm(h, lp["ln1"], cfg.rms_eps)
+        return h + cca.cca_sublayer(x, lp, cfg, positions=jnp.arange(S), segment_ids=None)
+
+    @jax.jit
+    def reference(h, lp):
+        return jnp.stack([zaya_decoder.cca(h[b], lp, ZAYA.shape_of(cfg)) for b in range(B)])
+
+    got = program(h, lp)
     with jax.default_matmul_precision("highest"):
-        want = jnp.stack([zaya_decoder.cca(h[b], lp, shape_of(cfg)) for b in range(B)])
+        want = reference(h, lp)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
@@ -84,8 +55,8 @@ def test_router_state_is_carried_through_three_layers():
     """moe_ffn three times, each handed the state the one before gave:
     the states and the counts are the reference's, and the carried
     state matters (without it the third layer's state is another)."""
-    cfg, shape = FP32, shape_of(FP32)
-    params = seeded_params(cfg)
+    cfg, shape = FP32, ZAYA.shape_of(FP32)
+    params = seeded_params(ZAYA, cfg)
     x = jax.random.normal(jax.random.key(3), (1, S, cfg.d_model), jnp.float32)
     state, ref_state = None, jnp.zeros((S, cfg.router_hidden))
     with jax.default_matmul_precision("highest"):
@@ -103,7 +74,7 @@ def test_router_state_is_carried_through_three_layers():
 
 def test_top1_weight_is_the_probability_and_the_bias_only_chooses():
     cfg = FP32
-    lp = layer_of(seeded_params(cfg), 0)
+    lp = layer_of(seeded_params(ZAYA, cfg), 0)
     x = jax.random.normal(jax.random.key(4), (1, S, cfg.d_model), jnp.float32)
     plain = moe.moe_ffn(x, {**lp, "router_bias": jnp.zeros(cfg.n_experts)}, cfg)[1]
     # a bias that forces expert 3: every token goes there, weighted by p_3 < 1
@@ -112,7 +83,8 @@ def test_top1_weight_is_the_probability_and_the_bias_only_chooses():
     assert stats["tokens_per_expert"].tolist() == [0, 0, 0, S]
     assert plain["tokens_per_expert"].tolist() != [0, 0, 0, S]
     with jax.default_matmul_precision("highest"):
-        _, probs, _ = zaya_decoder.route(x[0], lp, shape_of(cfg), jnp.zeros((S, cfg.router_hidden)))
+        _, probs, _ = zaya_decoder.route(x[0], lp, ZAYA.shape_of(cfg),
+                                         jnp.zeros((S, cfg.router_hidden)))
         y = (jax.nn.silu(x[0] @ lp["w_gate"][3]) * (x[0] @ lp["w_up"][3])) @ lp["w_down"][3]
     np.testing.assert_allclose(np.asarray(forced[0]), np.asarray(probs[:, 3:4] * y),
                                rtol=1e-4, atol=1e-5)
@@ -133,13 +105,8 @@ def test_train_path_meets_the_reference_in_loss_and_gradients(held):
     its worst leaf."""
     cfg = FP32 if held is None else dataclasses.replace(
         FP32, experts_held=held[0], first_expert_held=held[1])
-    params, batch, shape = seeded_params(cfg), skewed_tokens(cfg), shape_of(cfg)
-    with jax.default_matmul_precision("highest"):
-        loss, _, stats = llama.loss_and_weight_fn(params, batch, cfg)
-        got = jax.grad(lambda p: llama.loss_fn(p, batch, cfg))(params)
-        ref = zaya_decoder.loss_parts(params, batch["tokens"], batch["targets"], shape)
-        want = jax.grad(lambda p: zaya_decoder.loss(
-            p, batch["tokens"], batch["targets"], shape))(params)
+    ours, theirs = train_path(ZAYA, cfg), reference_path(ZAYA, cfg)
+    loss, stats, ref = ours.loss, ours.stats, theirs.parts
     assert float(loss) == pytest.approx(float(ref["loss"]), rel=2e-6)
     assert stats["tokens_per_expert"].tolist() == ref["tokens_per_expert"].tolist()
     assert int(stats["dropped_pairs"].sum()) == 0
@@ -148,40 +115,15 @@ def test_train_path_meets_the_reference_in_loss_and_gradients(held):
         elsewhere = B * S - stats["tokens_per_expert"][:, first:first + n].sum(-1)
         assert stats["pairs_elsewhere"].tolist() == elsewhere.tolist()
         assert 0 < int(elsewhere.sum()) < cfg.n_layers * B * S
-    worst = {}
-    for path, g in jax.tree_util.tree_leaves_with_path(got):
-        w = want
-        for k in path:
-            w = w[k.key]
-        scale = float(jnp.abs(w).max())
-        worst[jax.tree_util.keystr(path)] = float(jnp.abs(g - w).max()) / max(scale, 1e-12)
-        if "router_bias" in jax.tree_util.keystr(path):
-            assert scale == 0.0 and float(jnp.abs(g).max()) == 0.0
-            worst.pop(jax.tree_util.keystr(path))
+    worst = worst_leaf(ours.grads, theirs.grads)   # the selection bias takes no gradient
+    assert len(worst) == len(jax.tree.leaves(ours.params)) - 1
     assert max(worst.values()) < 2e-4, sorted(worst.items(), key=lambda kv: -kv[1])[:3]
-
-
-def test_bf16_compute_stays_near_the_reference():
-    cfg = dataclasses.replace(FP32, dtype=jnp.bfloat16)
-    params, batch = seeded_params(cfg), skewed_tokens(cfg)
-    loss = llama.loss_fn(params, batch, cfg)
-    ref = zaya_decoder.loss(params, batch["tokens"], batch["targets"], shape_of(cfg))
-    assert float(loss) == pytest.approx(float(ref), rel=0.02)
-
-
-@pytest.mark.parametrize("remat_policy", ["dots", "full"])
-def test_remat_gives_the_same_gradients(remat_policy):
-    cfg = dataclasses.replace(FP32, remat=True, remat_policy=remat_policy)
-    params, batch = seeded_params(cfg), skewed_tokens(cfg)
-    got = jax.grad(lambda p: llama.loss_fn(p, batch, cfg))(params)
-    want = jax.grad(lambda p: llama.loss_fn(p, batch, FP32))(params)
-    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=2e-6)
 
 
 # -- causality, through both convolutions and the value shift ------------------
 
 
+@functools.partial(jax.jit, static_argnames="cfg")
 def hidden(params, tokens, cfg, segment_ids=None):
     return llama.hidden_states(params, tokens, cfg, segment_ids=segment_ids)
 
@@ -189,7 +131,7 @@ def hidden(params, tokens, cfg, segment_ids=None):
 @pytest.mark.parametrize("kernels", [(2, 2), (3, 3)])
 def test_changing_token_t_moves_nothing_before_t(kernels):
     cfg = dataclasses.replace(FP32, conv_kernels=kernels)
-    params, tokens = seeded_params(cfg), skewed_tokens(cfg)["tokens"]
+    params, tokens = seeded_params(ZAYA, cfg), ZAYA.batch_of(cfg)["tokens"]
     t = 11
     base = hidden(params, tokens, cfg)
     moved = hidden(params, tokens.at[:, t].set((tokens[:, t] + 7) % cfg.vocab_size), cfg)
@@ -206,7 +148,7 @@ def test_nothing_crosses_a_document_boundary(kernels):
     causal mask alone would pass the first's last tokens through the
     convolutions and the value shift."""
     cfg = dataclasses.replace(FP32, conv_kernels=kernels)
-    params, tokens = seeded_params(cfg), skewed_tokens(cfg)["tokens"]
+    params, tokens = seeded_params(ZAYA, cfg), ZAYA.batch_of(cfg)["tokens"]
     cut = 10
     segments = jnp.asarray(np.r_[np.zeros(cut), np.ones(S - cut)][None].repeat(B, 0), jnp.int32)
     packed = hidden(params, tokens, cfg, segments)
@@ -258,7 +200,7 @@ def test_cca_sublayer_over_packed_documents_is_the_references(what):
     fixed cotangent with respect to the hidden state and every weight."""
     cfg = dataclasses.replace(FP32, n_heads=8, n_kv_heads=2, conv_kernels=(3, 2))
     assert cfg.n_heads // cfg.n_kv_heads == 4
-    lp = layer_of(seeded_params(cfg), 1)
+    lp = layer_of(seeded_params(ZAYA, cfg), 1)
     lp = {k: lp[k] for k in ("ln1", *cca.attention_axes())}
     h = jax.random.normal(jax.random.key(8), (B, S, cfg.d_model), jnp.float32)
     ct = jax.random.normal(jax.random.key(9), (B, S, cfg.d_model), jnp.float32)
@@ -271,15 +213,15 @@ def test_cca_sublayer_over_packed_documents_is_the_references(what):
                                     segment_ids=seg)
 
     def reference(h, lp):
-        return jnp.stack([zaya_decoder.cca(h[b], lp, shape_of(cfg), seg[b]) for b in range(B)])
+        return jnp.stack([zaya_decoder.cca(h[b], lp, ZAYA.shape_of(cfg), seg[b]) for b in range(B)])
 
     with jax.default_matmul_precision("highest"):
         if what == "forward":
-            np.testing.assert_allclose(np.asarray(program(h, lp)), np.asarray(reference(h, lp)),
-                                       rtol=2e-5, atol=2e-5)
+            np.testing.assert_allclose(np.asarray(jax.jit(program)(h, lp)),
+                                       np.asarray(jax.jit(reference)(h, lp)), rtol=2e-5, atol=2e-5)
             return
-        got = jax.grad(lambda h, lp: (program(h, lp) * ct).sum(), argnums=(0, 1))(h, lp)
-        want = jax.grad(lambda h, lp: (reference(h, lp) * ct).sum(), argnums=(0, 1))(h, lp)
+        got = jax.jit(jax.grad(lambda h, lp: (program(h, lp) * ct).sum(), argnums=(0, 1)))(h, lp)
+        want = jax.jit(jax.grad(lambda h, lp: (reference(h, lp) * ct).sum(), argnums=(0, 1)))(h, lp)
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
         worst = float(jnp.abs(g - w).max()) / float(jnp.abs(w).max())
         assert worst < 2e-4, (jax.tree_util.keystr(path), worst)
@@ -296,27 +238,29 @@ def test_two_shares_of_the_experts_add_up_to_the_uncut_layer(dtype):
     so do the gradients of the input; each share's counts are the uncut
     layer's, and what one share computes the other counts as elsewhere."""
     whole = dataclasses.replace(FP32, dtype=dtype)
-    lp = layer_of(seeded_params(whole), 0)
+    lp = layer_of(seeded_params(ZAYA, whole), 0)
     x = jax.random.normal(jax.random.key(5), (B, S, whole.d_model), jnp.float32).astype(dtype)
     state = 0.1 * jax.random.normal(jax.random.key(6), (B, S, whole.router_hidden))
 
     def share(first, n):
         cfg = dataclasses.replace(whole, experts_held=n, first_expert_held=first)
         held = {**lp, **{k: lp[k][first:first + n] for k in ("w_gate", "w_up", "w_down")}}
+        return run(cfg, held)
+
+    @functools.partial(jax.jit, static_argnames="cfg")
+    def run(cfg, lp):
         out, vjp, (stats, r) = jax.vjp(
-            lambda x: (lambda o, s, r: (o, (s, r)))(*moe.moe_ffn(x, held, cfg, state)),
+            lambda x: (lambda o, s, r: (o, (s, r)))(*moe.moe_ffn(x, lp, cfg, state)),
             x, has_aux=True)
         return out, vjp(jnp.ones_like(out))[0], stats, r
 
-    full, full_vjp, (full_stats, full_r) = jax.vjp(
-        lambda x: (lambda o, s, r: (o, (s, r)))(*moe.moe_ffn(x, lp, whole, state)),
-        x, has_aux=True)
+    full, full_dx, full_stats, full_r = run(whole, lp)
     a, b = share(0, 2), share(2, 2)
     tol = dict(rtol=1e-5, atol=1e-5) if dtype == jnp.float32 else dict(rtol=0.02, atol=0.02)
     np.testing.assert_allclose(np.asarray(a[0] + b[0], np.float32),
                                np.asarray(full, np.float32), **tol)
     np.testing.assert_allclose(np.asarray(a[1] + b[1], np.float32),
-                               np.asarray(full_vjp(jnp.ones_like(full))[0], np.float32), **tol)
+                               np.asarray(full_dx, np.float32), **tol)
     for _, _, stats, r in (a, b):
         assert stats["tokens_per_expert"].tolist() == full_stats["tokens_per_expert"].tolist()
         assert np.array_equal(np.asarray(r), np.asarray(full_r))
@@ -330,7 +274,7 @@ def test_a_share_must_lie_inside_the_experts():
     cfg = dataclasses.replace(FP32, experts_held=3, first_expert_held=2)
     x = jnp.zeros((1, 4, cfg.d_model))
     with pytest.raises(ValueError, match="held of 4"):
-        moe.moe_ffn(x, layer_of(seeded_params(FP32), 0), cfg)
+        moe.moe_ffn(x, layer_of(seeded_params(ZAYA, FP32), 0), cfg)
 
 
 def test_a_share_of_a_linear_router_model_adds_up_too():
