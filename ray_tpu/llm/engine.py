@@ -244,6 +244,12 @@ class EngineConfig:
                 "layers want a cache sized by layer type, and Keye, whose indexer wants a "
                 "cache of its own keys and a selection in the ragged kernel, are training-only)"
             )
+        if hasattr(self.model, "linear_key_dim"):
+            raise ValueError(
+                "LLMEngine serves llama-family models with a key-value cache; Olmo-Hybrid's "
+                "linear-attention layers carry a recurrent state a head (models/olmo_hybrid.py), "
+                "a second kind of state beside the pages, which no cache manager here holds: "
+                "it is training-only")
         # a prefill bucket longer than the context window can never be
         # used; clamping keeps bucket compilation bounded by the model
         self.max_prefill_len = min(self.max_prefill_len, self.model.max_seq)

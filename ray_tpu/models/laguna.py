@@ -131,6 +131,8 @@ class LagunaConfig(moe.MoEConfig):
     attn_gate: str = "per-head"
     first_dense_layers: int = 1
     dense_d_ff: int = 12288
+    # models/llama.py's seam: the module that builds this tree and runs these layers
+    stack_module: str = "ray_tpu.models.laguna"
 
     def kinds(self) -> list:
         """[(type, heads)] of the `n_layers` layers this configuration runs."""

@@ -38,6 +38,7 @@ its own layout.
 from __future__ import annotations
 
 import dataclasses
+import importlib
 from functools import partial
 from typing import Any, Optional
 
@@ -158,17 +159,19 @@ def _mla(config: LlamaConfig):
     return mla
 
 
-def _laguna(config: LlamaConfig):
-    """models/laguna.py when the configuration's layers differ in kind
-    and shape within one stack (a `LagunaConfig`: sliding-window and
-    full attention at unlike head counts), else None. That module builds
-    the tree and runs the layers (a scan over whole periods of kinds);
-    the head and the loss stay here."""
+def _own_stack(config: LlamaConfig):
+    """The module that builds the tree and runs the layers of a
+    configuration whose layers differ in kind within one stack
+    (`layer_types`), else None: models/laguna.py (sliding-window and full
+    attention at unlike head counts over an expert layer, a scan over
+    whole periods of kinds) or models/olmo_hybrid.py (gated-delta-rule
+    linear attention and full attention over a dense SwiGLU). The
+    configuration names its module (`stack_module`), which has
+    `logical_axes(c)`, `init_params(c, key)` and `trunk(params, tokens, c,
+    positions=, segment_ids=)`; the head and the loss stay here."""
     if not hasattr(config, "layer_types"):
         return None
-    from ray_tpu.models import laguna
-
-    return laguna
+    return importlib.import_module(config.stack_module)
 
 
 def _dsa(config: LlamaConfig):
@@ -190,8 +193,8 @@ def _carries_router_state(config: LlamaConfig) -> bool:
 
 def logical_axes(config: LlamaConfig) -> Params:
     """Pytree (parallel to params) of logical-axis tuples."""
-    if _laguna(config) is not None:
-        return _laguna(config).logical_axes(config)
+    if _own_stack(config) is not None:
+        return _own_stack(config).logical_axes(config)
     mla = _mla(config)
     if mla is not None and mla.has_more_than_the_stack(config):
         return mla.logical_axes(config)
@@ -229,8 +232,8 @@ def logical_axes(config: LlamaConfig) -> Params:
 
 def init_params(config: LlamaConfig, key: jax.Array) -> Params:
     c = config
-    if _laguna(c) is not None:
-        return _laguna(c).init_params(c, key)
+    if _own_stack(c) is not None:
+        return _own_stack(c).init_params(c, key)
     mla = _mla(c)
     if mla is not None and mla.has_more_than_the_stack(c):
         return mla.init_params(c, key)
@@ -330,8 +333,8 @@ def _block(
     expert layer (models/moe.py, whose statistics come back; None for a
     dense layer) by the configuration's own kind; `dense_ffn`: one of an
     expert configuration's leading dense layers. (A configuration whose
-    layers differ in kind within the stack, models/laguna.py, has a
-    block of its own beside this one.)"""
+    layers differ in kind within the stack, models/laguna.py or
+    models/olmo_hybrid.py, has a block of its own beside this one.)"""
     c = config
     moe, cca, mla, dsa = None if dense_ffn else _moe(c), _cca(c), _mla(c), _dsa(c)
     selected = {}
@@ -488,10 +491,10 @@ def _trunk(
         )
     if positions is None:
         positions = packed_positions(segment_ids, S)
-    if _laguna(c) is not None:
+    if _own_stack(c) is not None:
         # layers of unlike kinds: that module's stack (no block of one kind to hand on)
-        return (*_laguna(c).trunk(params, tokens, c, positions=positions,
-                                  segment_ids=segment_ids), None)
+        return (*_own_stack(c).trunk(params, tokens, c, positions=positions,
+                                     segment_ids=segment_ids), None)
     mla = _mla(c)
     cos = sin = None
     # CCA rotates part of a head, MLA its decoupled part and DSA two head sizes, from

