@@ -449,6 +449,11 @@ def _remat(block, c: LlamaConfig):
                     # dsa_sel: the packed selection of models/dsa.py, which the
                     # backward's kernels read and nothing should compute twice
                     "dsa_sel",
+                    # gdn_out, gdn_states: what ops/gated_delta.py's forward kernel
+                    # writes, o, the chunks' starting states and their inverses, which
+                    # is all its backward kernel reads beside the inputs: the rule runs
+                    # twice a layer (forward, backward), not three times
+                    "gdn_out", "gdn_states",
                 ),
             ),
         )

@@ -4,7 +4,7 @@ attention layers in one stack, over a dense SwiGLU.
 What Olmo-Hybrid-7B (allenai/Olmo-Hybrid-7B, `model_type` olmo_hybrid)
 adds to the one decoder of models/llama.py: `OlmoHybridConfig`; the
 linear-attention sublayer `gdn_sublayer` (the recurrence itself is
-ops/gated_delta.py's); the full-attention sublayer without a rotary; and
+ops/gated_delta.py's kernels, forward and backward); the full-attention sublayer without a rotary; and
 a parameter tree and a layer stack whose blocks differ in KIND. The
 head, the loss and the train step are models/llama.py's, which hands
 `logical_axes`, `init_params` and the trunk to the module the
@@ -21,7 +21,8 @@ A LINEAR layer's mixer (u the sublayer's input, h one of
   beta = sigmoid(u Wb) a head, doubled under `allow_neg_eigval`;
   g = -exp(A_log) softplus(u Wa + dt_bias) a head, float32;
   S_t = exp(g_t) (I - beta_t k_t k_t^T) S_{t-1} + beta_t k_t v_t^T,
-  o_t = S_t^T q_t (ops/gated_delta.py, in chunks, float32);
+  o_t = S_t^T q_t (ops/gated_delta.py: Pallas kernels over chunks of 64,
+  the state in VMEM, float32);
   y = RMSNorm_dv(o; one learned [dv] weight) SiLU(u Wg); out = y Wo.
 
 (A published checkpoint's `conv1d.weight` [channels, 1, K] holds tap j at

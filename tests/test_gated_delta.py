@@ -1,9 +1,12 @@
-"""ops/gated_delta.py on the CPU: the chunked gated delta rule against the
-position-by-position rule, forward and every gradient (`jax.grad` of the
-plain recurrence), at sequences of several chunks and at one that is no
-multiple of the chunk; the write strength up to 1 and up to 2
-(`linear_allow_neg_eigval`); what the rule reduces to when a gate is
-switched off; and that nothing it builds grows with T x T."""
+"""ops/gated_delta.py on the CPU: the chunked gated delta rule, its Pallas
+kernels under the interpreter, against the position-by-position rule,
+forward and every gradient (`jax.grad` of the plain recurrence), at
+sequences of several chunks, of several grid steps and at ones that are no
+multiple of the chunk, at head sizes that fill no tile and at the 7B's;
+the write strength up to 1 and up to 2 (`linear_allow_neg_eigval`); what
+the rule reduces to when a gate is switched off; and that it is kernels
+all the way: one `pallas_call` forward, two for a gradient, no loop over
+chunks or positions outside them and nothing that grows with T x T."""
 
 import jax
 import jax.numpy as jnp
@@ -36,9 +39,10 @@ def recurrent_gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.
     return jnp.moveaxis(o, 0, 2)
 
 
-def inputs(T, beta_max, seed=0):
+def inputs(T, beta_max, seed=0, shape=(B, H, DK, DV)):
     """Unit keys and queries as the sublayer makes them, a log decay of a
     few percent a position, beta in (0, beta_max)."""
+    B, H, DK, DV = shape
     ks = jax.random.split(jax.random.key(seed), 6)
     q, k = (jax.random.normal(kk, (B, H, T, DK)) for kk in ks[:2])
     q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / DK ** 0.5
@@ -49,8 +53,13 @@ def inputs(T, beta_max, seed=0):
     return (q, k, v, g, beta), jax.random.normal(ks[5], (B, H, T, DV))
 
 
-# 192 = three whole chunks of 64; 150 = two and 22 positions; 40 = less than one
-SHAPES = pytest.mark.parametrize("T", [192, 150, 40])
+def grads_of(rule, args, w):
+    return jax.jit(jax.grad(lambda *a: (rule(*a) * w).sum(), argnums=(0, 1, 2, 3, 4)))(*args)
+
+
+# 192 = three whole chunks of 64; 150 = two and 22 positions; 40 = less than one; 600 = nine
+# and 24 positions, three grid steps of the kernels: the state is carried from step to step
+SHAPES = pytest.mark.parametrize("T", [192, 150, 40, 600])
 EIGVAL = pytest.mark.parametrize("beta_max", [1.0, 2.0], ids=["beta_to_1", "neg_eigval_beta_to_2"])
 
 
@@ -71,16 +80,73 @@ def test_chunked_backward_is_jax_grad_of_the_plain_recurrence(T, beta_max):
     against reverse-mode through the scan over positions, with and
     without the block's `jax.checkpoint` around the rule."""
     args, w = inputs(T, beta_max)
-
-    def grads(rule):
-        return jax.jit(jax.grad(lambda *a: (rule(*a) * w).sum(), argnums=(0, 1, 2, 3, 4)))(*args)
-
-    want = grads(recurrent_gated_delta_rule)
+    want = grads_of(recurrent_gated_delta_rule, args, w)
     for rule in (gd.gated_delta_rule, jax.checkpoint(gd.gated_delta_rule)):
-        for name, g, r in zip("q k v g beta".split(), grads(rule), want):
+        for name, g, r in zip("q k v g beta".split(), grads_of(rule, args, w), want):
             scale = float(jnp.abs(r).max())
             np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-4, atol=1e-5 * scale,
                                        err_msg=name)
+
+
+@pytest.mark.parametrize("shape,T", [((1, 2, 96, 192), 330), ((1, 1, 128, 256), 130)],
+                         ids=["keys_96_values_192_padded_to_the_lanes", "whole_lanes"])
+def test_forward_and_backward_at_head_sizes_of_a_tile_and_more(shape, T):
+    """The 7B's heads (96 / 192: the kernels stage them into 128 / 256
+    lanes, and the state is two lane tiles wide) and heads that need no
+    padding, at a T that is no multiple of 64 and more than one grid step."""
+    args, w = inputs(T, 2.0, seed=3, shape=shape)
+    np.testing.assert_allclose(np.asarray(gd.gated_delta_rule(*args)),
+                               np.asarray(recurrent_gated_delta_rule(*args)), rtol=2e-5, atol=2e-6)
+    want = grads_of(recurrent_gated_delta_rule, args, w)
+    for name, g, r in zip("q k v g beta".split(), grads_of(gd.gated_delta_rule, args, w), want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-4,
+                                   atol=1e-5 * float(jnp.abs(r).max()), err_msg=name)
+
+
+def test_the_remat_policys_names_save_what_the_backward_reads():
+    """models/llama.py::_remat saves `gdn_out` and `gdn_states` by name: with
+    them kept the rematerialised backward runs no second forward kernel (one
+    `pallas_call` in the backward's jaxpr, the backward kernel), without them
+    two; the gradients are the same either way."""
+    args, w = inputs(150, 2.0)
+    saved = jax.checkpoint(gd.gated_delta_rule, policy=jax.checkpoint_policies.save_only_these_names(
+        "gdn_out", "gdn_states"))
+    nothing = jax.checkpoint(gd.gated_delta_rule)
+    for a, b in zip(grads_of(saved, args, w), grads_of(nothing, args, w)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def backward_kernels(rule):
+        _, pull = jax.vjp(rule, *args)
+        return kernels_and_loops(jax.make_jaxpr(pull)(w).jaxpr)[0]
+
+    assert backward_kernels(saved) == 1 and backward_kernels(nothing) == 2
+
+
+def test_what_the_forward_hands_the_backward_is_the_states_and_the_inverses_without_their_zeros():
+    """The forward kernel reads q, k, v where they stand ([B, H, T, d]: no
+    reshape of them stands before it) and writes, beside o, the state each
+    chunk started from and each pair's inverse as its two diagonal blocks
+    side by side, [B x H, T / 128, 64, 128]: chunk c's (I + A)^-1 is half
+    c mod 2 of pair c div 2, and the zeros off a pair's diagonal are not
+    kept."""
+    T = 512   # whole grid steps of the kernels: the wrapper's padding is not this test's
+    (q, k, v, g, beta), _ = inputs(T, 2.0)
+    gb = jnp.stack([a.reshape(B * H, T // 128, 128) for a in (g, beta)], axis=2)
+    o, states, solves = gd.gated_delta_fwd(q, k, v, gb, interpret=True)
+    assert o.shape == v.shape and states.shape == (B * H, T // 64, DK, DV)
+    assert solves.shape == (B * H, T // 128, 64, 128)
+    np.testing.assert_array_equal(np.asarray(states[:, 0]), 0.0)
+    K, c = np.asarray(k, np.float64), np.cumsum(np.asarray(g, np.float64).reshape(B, H, -1, 64), -1)
+    for b, h, chunk in ((0, 0, 0), (1, 2, 3), (0, 1, 5)):
+        Kc, cc = K[b, h, chunk * 64:(chunk + 1) * 64], c[b, h, chunk]
+        A = np.tril(np.asarray(beta, np.float64)[b, h, chunk * 64:(chunk + 1) * 64, None]
+                    * (Kc @ Kc.T) * np.exp(cc[:, None] - cc[None, :]), -1)
+        half = solves[b * H + h, chunk // 2, :, (chunk % 2) * 64:(chunk % 2 + 1) * 64]
+        np.testing.assert_allclose(np.asarray(half), np.linalg.inv(np.eye(64) + A), atol=2e-5)
+    jaxpr = jax.make_jaxpr(gd.gated_delta_rule)(q, k, v, g, beta).jaxpr
+    call = jaxpr.eqns[-1]
+    assert call.primitive.name == "custom_vjp_call" and call.outvars == jaxpr.outvars
+    assert call.invars[:3] == jaxpr.invars[:3]                  # the caller's arrays themselves
 
 
 def test_no_decay_and_full_writes_are_the_plain_delta_rule():
@@ -127,24 +193,36 @@ def test_a_position_reads_nothing_after_it():
     assert float(jnp.abs(again[:, :, 100:] - base[:, :, 100:]).max()) > 1e-3
 
 
-def test_nothing_it_builds_is_t_by_t_and_the_loop_runs_over_chunks():
-    """At 1,024 positions the largest array of forward and backward is a
-    small multiple of the inputs (the chunks' [C, C] products: T x C), and
-    the only loops are over T / CHUNK = 16 chunks: no loop over positions."""
+def kernels_and_loops(jaxpr, sizes=None):
+    """(`pallas_call`s, loops outside them) of a jaxpr, every sub-jaxpr but the
+    kernels' own walked; `sizes` collects the sizes of what the equations make."""
+    kernels, loops = 0, []
+    for eqn in jaxpr.eqns:
+        if sizes is not None:
+            sizes.extend(v.aval.size for v in eqn.outvars if hasattr(v.aval, "size"))
+        if eqn.primitive.name == "pallas_call":
+            kernels += 1
+            continue
+        if eqn.primitive.name in ("scan", "while"):
+            loops.append(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            k, l = kernels_and_loops(sub, sizes)
+            kernels, loops = kernels + k, loops + l
+    return kernels, loops
+
+
+def test_nothing_it_builds_is_t_by_t_and_the_chunks_are_walked_inside_the_kernels():
+    """At 1,024 positions a forward is ONE `pallas_call` and a gradient two
+    (the forward that hands out the chunks' states, the backward), no
+    `scan` or `while` stands outside them (the walk over the chunks is the
+    kernels' grid), and the largest array of forward and backward is a
+    small multiple of the inputs (the states the chunks start from:
+    T / 64 x dk x dv a head), nothing T x T."""
     T = 1024
     args, w = inputs(T, 2.0)
+    assert kernels_and_loops(jax.make_jaxpr(gd.gated_delta_rule)(*args).jaxpr) == (1, [])
+    sizes = []
     jaxpr = jax.make_jaxpr(jax.grad(lambda *a: (gd.gated_delta_rule(*a) * w).sum(),
                                     argnums=(0, 1, 2, 3, 4)))(*args)
-    sizes, lengths = [], []
-
-    def walk(j):
-        for eqn in j.eqns:
-            sizes.extend(v.aval.size for v in eqn.outvars if hasattr(v.aval, "size"))
-            if eqn.primitive.name == "scan":
-                lengths.append(eqn.params["length"])
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-
-    walk(jaxpr.jaxpr)
+    assert kernels_and_loops(jaxpr.jaxpr, sizes) == (2, [])
     assert max(sizes) <= B * H * T * max(gd.CHUNK, DK + DV) * 2 < B * H * T * T
-    assert lengths and set(lengths) == {T // gd.CHUNK}
