@@ -4,7 +4,9 @@ attention layers in one stack, over a dense SwiGLU.
 What Olmo-Hybrid-7B (allenai/Olmo-Hybrid-7B, `model_type` olmo_hybrid)
 adds to the one decoder of models/llama.py: `OlmoHybridConfig`; the
 linear-attention sublayer `gdn_sublayer` (the recurrence itself is
-ops/gated_delta.py's kernels, forward and backward); the full-attention sublayer without a rotary; and
+ops/gated_delta.py's kernels, forward and backward, and what stands
+between the projections and it, ops/gdn_conv.py's); the full-attention
+sublayer without a rotary; and
 a parameter tree and a layer stack whose blocks differ in KIND. The
 head, the loss and the train step are models/llama.py's, which hands
 `logical_axes`, `init_params` and the trunk to the module the
@@ -17,7 +19,9 @@ A LINEAR layer's mixer (u the sublayer's input, h one of
   a causal depthwise convolution of `conv_kernel` taps over time on
   every channel of q~, k~ and v~ (tap j on position t - j: nothing
   ahead of t, zeros before the sequence, no bias), then SiLU;
-  q = q~ / |q~| / sqrt(dk), k = k~ / |k~| a head; v = v~;
+  q = q~ / |q~| / sqrt(dk), k = k~ / |k~| a head; v = v~ (float32 from
+  the projection's output on; ops/gdn_conv.py: the whole chain one
+  Pallas kernel a tensor forward and one backward);
   beta = sigmoid(u Wb) a head, doubled under `allow_neg_eigval`;
   g = -exp(A_log) softplus(u Wa + dt_bias) a head, float32;
   S_t = exp(g_t) (I - beta_t k_t k_t^T) S_{t-1} + beta_t k_t v_t^T,
@@ -79,11 +83,11 @@ from ray_tpu.ops.attention import attention_head_major
 # the position-by-position reference (chipbench/runners/train_reference_checked.py): a
 # kernel that replaces it is bound to the same name
 from ray_tpu.ops.gated_delta import gated_delta_rule
+from ray_tpu.ops.gdn_conv import gdn_conv
 
 Params = dict[str, Any]
 FULL, LINEAR = "full_attention", "linear_attention"
 _F32 = jnp.float32
-_L2_EPS = 1e-6  # fla's l2norm: x / sqrt(sum x^2 + eps)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -268,24 +272,14 @@ def init_params(c: OlmoHybridConfig, key: jax.Array) -> Params:
 # -- the sublayers ------------------------------------------------------------------
 
 
-def causal_conv(x: jax.Array, taps: jax.Array) -> jax.Array:
-    """x [B, heads, S, d] float32, taps [K, heads x d] -> sum_j taps[j] x
-    x[t - j], zeros before the sequence: K shifted multiply-adds, nothing
-    ahead of t."""
-    B, H, S, d = x.shape
-    taps = taps.astype(_F32).reshape(-1, H, 1, d)
-    y = x * taps[0]
-    for j in range(1, taps.shape[0]):
-        y = y + jnp.pad(x, ((0, 0), (0, 0), (j, 0), (0, 0)))[:, :, :S] * taps[j]
-    return y
-
-
 def gdn_sublayer(x: jax.Array, lp: Params, c: OlmoHybridConfig, *, positions: jax.Array,
                  segment_ids: Optional[jax.Array]) -> jax.Array:
     """The sublayer's input x [B, S, D] -> the linear-attention mixer's
     output [B, S, D] (the module's docstring has the equations;
     `positions` are not read: the state carries the order). Named scopes
-    on the device ops, forward and backward: `gdn.proj`, `gdn.conv`,
+    on the device ops, forward and backward: `gdn.proj`, `gdn.conv` (six
+    kernels a layer, `gdn_conv_fwd` / `gdn_conv_bwd` for each of q, k and
+    v, and the sum of the taps' gradients over a register's sublanes),
     `gdn.gates`, `gdn.scan`, `gdn.norm`, `gdn.out`."""
     if segment_ids is not None:
         raise NotImplementedError(
@@ -304,10 +298,9 @@ def gdn_sublayer(x: jax.Array, lp: Params, c: OlmoHybridConfig, *, positions: ja
             a, b = (jnp.einsum("bsd,dh->bhs", x.astype(_F32), lp[n].astype(dt).astype(_F32))
                     for n in ("wa", "wb"))
         with jax.named_scope("gdn.conv"):
-            q, k, v = (jax.nn.silu(causal_conv(t.astype(_F32), lp[n]))
-                       for t, n in ((q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
-            q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + _L2_EPS) * dk ** -0.5
-            k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + _L2_EPS)
+            q = gdn_conv(q, lp["conv_q"], scale=dk ** -0.5)
+            k = gdn_conv(k, lp["conv_k"], scale=1.0)
+            v = gdn_conv(v, lp["conv_v"])
         with jax.named_scope("gdn.gates"):
             beta = jax.nn.sigmoid(b) * (2.0 if c.allow_neg_eigval else 1.0)
             g = (-jnp.exp(lp["A_log"].astype(_F32))[:, None]

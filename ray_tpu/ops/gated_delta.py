@@ -71,7 +71,9 @@ ONE path, no option: off the TPU the same kernels run under the Pallas
 interpreter, as ops/flash.py's do. The kernels read q, k, v and write o
 and the gradients where they stand, [B, H, T, d] (a grid step's index
 map finds its head: no reshape or copy stands between the caller's
-operations and the kernels). A step stages its blocks into VMEM scratch
+operations and the kernels; in models/olmo_hybrid.py those are
+ops/gdn_conv.py's kernels, which write float32 q, k, v and read dq, dk,
+dv in the same place). A step stages its blocks into VMEM scratch
 as wide as whole lanes (keys of 96 -> 128, values of 192 -> 256: zero
 columns of k and v leave zero rows and columns of S), and a sequence that
 is no multiple of a block of pairs is padded with positions that write

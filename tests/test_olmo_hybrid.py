@@ -25,6 +25,8 @@ from chipbench.tools.olmo_hybrid_wrong import PRECISION_ONLY, VARIANTS, olmo3_ro
 from model_cases import OLMO_HYBRID, reference_path, seeded_params, train_path, worst_leaf
 from ray_tpu.models import llama, olmo_hybrid as oh
 from ray_tpu.models.registry import get_model_config, list_models
+# the jax.numpy convolution the model ran until PR 48: the reference of ops/gdn_conv.py's kernels
+from test_gdn_conv import causal_conv
 
 FP32, B, S = OLMO_HYBRID.fp32, OLMO_HYBRID.batch, OLMO_HYBRID.seq
 SHAPE = OLMO_HYBRID.shape_of(FP32)
@@ -85,13 +87,13 @@ def test_the_decay_starts_as_flas_does():
 def test_the_causal_convolution_sees_nothing_ahead_of_t_and_is_the_references():
     x = jax.random.normal(jax.random.key(0), (B, 3, S, 12))
     taps = jax.random.normal(jax.random.key(1), (4, 36))
-    got = oh.causal_conv(x, taps)
+    got = causal_conv(x, taps)
     for b in range(B):
         flat = jnp.swapaxes(x[b], 0, 1).reshape(S, 36)                  # [S, heads x d]
         want = ref.conv(flat, taps).reshape(S, 3, 12)
         np.testing.assert_allclose(np.asarray(jnp.swapaxes(got[b], 0, 1)), np.asarray(want),
                                    rtol=1e-6, atol=1e-6)
-    again = oh.causal_conv(x.at[:, :, 70:].add(1.0), taps)
+    again = causal_conv(x.at[:, :, 70:].add(1.0), taps)
     assert float(jnp.abs(again[:, :, :70] - got[:, :, :70]).max()) == 0.0
     assert float(jnp.abs(again[:, :, 70] - got[:, :, 70]).max()) > 0.0
     # the first position sees zeros before the sequence: tap 0 alone

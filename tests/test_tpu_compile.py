@@ -235,6 +235,28 @@ def test_grouped_matmul_kernels_compile_wherever_the_tile_rule_accepts(v5e, P, E
     assert hlo.count('custom_call_target="tpu_custom_call"') == 3
 
 
+@pytest.mark.parametrize("T", [4096, 100], ids=["whole_blocks_of_512", "one_block_of_a_tile_padded"])
+@pytest.mark.parametrize("d,scale", [(96, 96 ** -0.5), (192, None), (64, 1.0)],
+                         ids=["keys_of_96_normed", "values_of_192", "half_a_lane_tile_normed"])
+def test_gdn_conv_kernels_compile_for_v5e(v5e, d, scale, T):
+    """ops/gdn_conv.py's two kernels at the 7B's linear heads, bfloat16
+    in: a tap is a load at a static offset along the sublanes, and a head
+    of 96 or 192 fills no whole number of lane tiles; both are Mosaic's
+    to accept, not the interpreter's. The step that holds them compiled
+    whole is tests/test_olmo_hybrid_step_compile.py's."""
+    from ray_tpu.ops.gdn_conv import gdn_conv
+
+    def value_and_grads(x, taps, ct):
+        out, pull = jax.vjp(lambda x, taps: gdn_conv(x, taps, scale), x, taps)
+        return (out,) + pull(ct)
+
+    # the wrapper asks the backend whether to interpret: answered as on the chip
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        hlo = compile_kernel(value_and_grads, ((1, 30, T, d), _BF16), ((4, 30 * d), jnp.float32),
+                             ((1, 30, T, d), jnp.float32), sharding=one_chip(v5e))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+
+
 def test_chip_smoke_runs_no_phase_without_a_tpu():
     p = subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py")],
