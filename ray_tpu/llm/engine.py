@@ -231,6 +231,13 @@ class EngineConfig:
             self.model = get_model_config(self.model)
         from ray_tpu.models.moe import MoEConfig
 
+        if hasattr(self.model, "mamba_heads"):
+            raise ValueError(
+                "LLMEngine serves llama-family models with a key-value cache; Nemotron-H's "
+                "Mamba-2 layers carry a state-space state and a convolution's last taps a "
+                "sequence (models/nemotron_h.py), a second kind of state beside the pages, "
+                "which no cache manager here holds, and its experts are training-only: it is "
+                "training-only (and the published model's block-diffusion decode is not built)")
         if isinstance(self.model, MoEConfig):
             # the serving decoder is the dense llama path; accepting a
             # MoEConfig (a LlamaConfig subclass) would silently serve a

@@ -1,4 +1,4 @@
-"""Sparse expert feed-forward layer (Mixtral, OLMoE, ZAYA1, GLM-4.7-Flash, Laguna, Keye) for the one decoder.
+"""Sparse expert feed-forward layer (Mixtral, OLMoE, ZAYA1, GLM-4.7-Flash, Laguna, Keye, Nemotron-H) for the one decoder.
 
 The reference has NO expert parallelism (SURVEY.md §2.4 — absent from
 python/ray/llm); this is a native capability. This module holds what an
@@ -15,10 +15,16 @@ gathered into that order, gate / up / down run as grouped matmuls over
 the ragged groups, each row weighted by the router on the way, and the
 rows go back to token order, where a token's are summed. Both
 permutations are gathers, forward and backward (custom VJPs below).
+An expert's FORM is the configuration's (`expert_act`): "swiglu", down(
+silu(gate x) * up x), three matrices and nine grouped matmuls a layer
+forward and backward, or "relu2" (Nemotron-H, models/nemotron_h.py),
+down(relu(up x)^2), two matrices and six: there is then no `w_gate`
+(and no `shared_gate`) leaf, and None stands where the gate stood in
+every function below, both custom VJPs among them.
 
 Which kernel multiplies is `ops/grouped_matmul.py`'s to say, from what
-it can observe; this module calls `grouped_matmul` three times and
-knows nothing of the choice. On a TPU with no multi-device mesh it is
+it can observe; this module calls `grouped_matmul` three times (twice
+for experts of two matrices) and knows nothing of the choice. On a TPU with no multi-device mesh it is
 the Pallas kernels there (`ragged-dot-tiled*` in a profile, tiles
 chosen from the shapes: the one-chip training cell). Everywhere else it
 is `jax.lax.ragged_dot`: on the CPU, and under a mesh, where the expert
@@ -72,7 +78,8 @@ And two that GLM-4.7-Flash (models/mla.py) asks, in the DeepSeek-V3 form
     part of the weight) are chosen; the weights are the chosen scores
     themselves, renormalised over the chosen (`norm_topk_prob`) and
     multiplied by `routed_scaling`;
-  * `shared_d_ff`: a shared expert of that width, a dense SwiGLU every
+  * `shared_d_ff`: a shared expert of that width, a dense SwiGLU (or,
+    under `expert_act` "relu2", the routed experts' own form) every
     token runs, added to the routed sum. It is computed whole on every
     chip of a deployment (and counted once where shares are added up),
     under the named scope `shared.ffn`: it is no part of `moe.*`.
@@ -106,7 +113,8 @@ Params = dict[str, Any]
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig(llama.LlamaConfig):
-    """`d_ff` is the width of ONE expert."""
+    """`d_ff` is the width of ONE expert (of `expert_matrices` matrices:
+    `expert_act`)."""
 
     n_experts: int = 8
     top_k: int = 2
@@ -130,6 +138,9 @@ class MoEConfig(llama.LlamaConfig):
     selection_bias: bool = False
     routed_scaling: float = 1.0  # on the routed experts' weights, either score
     shared_d_ff: int = 0         # width of the shared expert (0: none)
+    # an expert's form, routed and shared alike: "swiglu" = down(silu(gate x) * up x), three
+    # matrices; "relu2" = down(relu(up x)^2), two and no `w_gate` leaf (Nemotron-H)
+    expert_act: str = "swiglu"
     # this chip's share: experts first_expert_held .. + experts_held of
     # n_experts are in the parameters (None: all of them)
     experts_held: Optional[int] = None
@@ -145,13 +156,19 @@ class MoEConfig(llama.LlamaConfig):
     def n_held(self) -> int:
         return self.n_experts if self.experts_held is None else self.experts_held
 
+    @property
+    def expert_matrices(self) -> int:
+        """Matrices of one expert, by its form (`expert_act`)."""
+        return _EXPERT_MATRICES[self.expert_act]
+
     def flops_per_token(self, seq_len: int) -> float:
         """Forward FLOPs a token requires: the dense decoder's count
-        with the `top_k` experts a token runs and the router in place of
-        the one MLP."""
+        with the `top_k` experts a token runs (each of the matrices its
+        form has) and the router in place of the one MLP."""
         dense_mlp = 2 * self.d_model * self.d_ff * 3
-        routed = (self.top_k * dense_mlp + 2 * self.d_model * self.n_experts
-                  + 2 * self.d_model * self.shared_d_ff * 3)
+        m = self.expert_matrices
+        routed = (self.top_k * 2 * self.d_model * self.d_ff * m + 2 * self.d_model * self.n_experts
+                  + 2 * self.d_model * self.shared_d_ff * m)
         return super().flops_per_token(seq_len) + self.n_layers * (routed - dense_mlp)
 
     def num_params(self) -> int:
@@ -160,9 +177,13 @@ class MoEConfig(llama.LlamaConfig):
         if self.linear_router_bias:
             router += E  # the selection bias
         # the experts held here + the shared expert + router
-        ffn = self.n_held * 3 * d * f + 3 * d * self.shared_d_ff + router
+        m = self.expert_matrices
+        ffn = self.n_held * m * d * f + m * d * self.shared_d_ff + router
         qk = d + self.n_kv_heads * self.head_dim if self.qk_norm else 0
         return super().num_params() + self.n_layers * (ffn + qk - 3 * d * f)
+
+
+_EXPERT_MATRICES = {"swiglu": 3, "relu2": 2}
 
 
 MOE_TINY = MoEConfig(
@@ -193,6 +214,8 @@ def expert_axes(config: Optional[MoEConfig] = None) -> Params:
     if config is not None and config.shared_d_ff:
         axes.update(shared_gate=("layers", "embed", "mlp"), shared_up=("layers", "embed", "mlp"),
                     shared_down=("layers", "mlp", "embed"))
+    if config is not None and config.expert_act == "relu2":   # two matrices: no gate
+        axes = {k: v for k, v in axes.items() if k not in ("w_gate", "shared_gate")}
     if config is None or config.router_kind == "linear":
         if config is not None and config.linear_router_bias:
             axes["router_bias"] = ("layers", "expert")
@@ -246,6 +269,10 @@ def expert_params(config: MoEConfig, key: jax.Array) -> Params:
         router.update(shared_gate=per_layer(k_gate, (c.d_model, c.shared_d_ff)),
                       shared_up=per_layer(k_up, (c.d_model, c.shared_d_ff)),
                       shared_down=per_layer(k_down, (c.shared_d_ff, c.d_model)))
+    if c.expert_act == "relu2":   # two matrices an expert, routed and shared: no gate
+        router.pop("shared_gate", None)
+        return {**router, "w_up": per_expert(keys[2], (c.d_model, c.d_ff)),
+                "w_down": per_expert(keys[3], (c.d_ff, c.d_model))}
     return {
         **router,
         "w_gate": per_expert(keys[1], (c.d_model, c.d_ff)),
@@ -326,10 +353,18 @@ def _pair_weights_bwd(inv, g):
 _pair_weights.defvjp(_pair_weights_fwd, _pair_weights_bwd)
 
 
+def _activation(gate, up):
+    """An expert's hidden row from its first matmuls' outputs: silu(gate)
+    * up, or relu(up)^2 where the expert has no gate (`gate` None)."""
+    return jnp.square(jax.nn.relu(up)) if gate is None else jax.nn.silu(gate) * up
+
+
 def _all_rows(xt, w, w_gate, w_up, w_down, order, inv, sizes, *, tail, dtype):
     """The routed experts over ALL N * K pair rows -> [N, D]: what a
     configuration that holds every expert (or a large share) runs, and
-    the branch a small share falls back to."""
+    the branch a small share falls back to. `w_gate` None: experts of
+    two matrices (`expert_act` "relu2"), four grouped matmuls fewer a
+    layer and step."""
     with jax.named_scope("moe.dispatch"):
         xs = _to_expert_order(xt, order, inv)
     with jax.named_scope("moe.experts"):
@@ -337,12 +372,12 @@ def _all_rows(xt, w, w_gate, w_up, w_down, order, inv, sizes, *, tail, dtype):
         # dot_general's outputs but not a grouped matmul's
         name = jax.ad_checkpoint.checkpoint_name
         gmm = functools.partial(grouped_matmul, group_sizes=sizes, tail=tail)
-        gate = name(gmm(xs, w_gate.astype(dtype)), "moe_gate")
+        gate = None if w_gate is None else name(gmm(xs, w_gate.astype(dtype)), "moe_gate")
         up = name(gmm(xs, w_up.astype(dtype)), "moe_up")
         # the router's weight goes on BEFORE the down projection (the
         # same sum): the backward then needs no output of `w_down`,
         # so that matmul is not run again to differentiate the weights
-        act = (jax.nn.silu(gate) * up).astype(jnp.float32)
+        act = _activation(gate, up).astype(jnp.float32)
         act = (act * _pair_weights(w, order, inv)[:, None]).astype(dtype)
         ys = gmm(act, w_down.astype(dtype))
     with jax.named_scope("moe.combine"):
@@ -541,19 +576,20 @@ _held_pair_weights.defvjp(_held_pair_weights_fwd, _held_pair_weights_bwd)
 
 
 def _held_gate_up(xt, w_gate, w_up, tok, pairs, sizes):
-    """-> gate, up [C, d_ff] of the held rows."""
+    """-> gate, up [C, d_ff] of the held rows (gate None where the
+    experts have none)."""
     with jax.named_scope("moe.dispatch"), jax.named_scope("moe.held"):
         xs = _to_held_rows(xt, tok, pairs)
     with jax.named_scope("moe.experts"), jax.named_scope("moe.held"):
         gmm = functools.partial(grouped_matmul, group_sizes=sizes, tail=True)
-        return gmm(xs, w_gate), gmm(xs, w_up)
+        return None if w_gate is None else gmm(xs, w_gate), gmm(xs, w_up)
 
 
 def _held_down_sum(gate, up, w, w_down, rows, inv, tok, sizes):
     """gate, up [C, d_ff] -> [N, D]: weighted, down, summed into the tokens."""
     with jax.named_scope("moe.experts"), jax.named_scope("moe.held"):
-        act = (jax.nn.silu(gate) * up).astype(jnp.float32)
-        act = (act * _held_pair_weights(w, rows, inv)[:, None]).astype(gate.dtype)
+        act = _activation(gate, up).astype(jnp.float32)
+        act = (act * _held_pair_weights(w, rows, inv)[:, None]).astype(up.dtype)
         ys = grouped_matmul(act, w_down, sizes, tail=True)
     with jax.named_scope("moe.combine"), jax.named_scope("moe.held"):
         return _from_held_rows(ys, tok, inv.shape)
@@ -577,8 +613,8 @@ def _held_or_all_fwd(bound, xt, w, w_gate, w_up, w_down, order, inv, sizes):
         with jax.named_scope("moe.all"):
             out = _all_rows(xt, w, w_gate, w_up, w_down, order, inv, sizes,
                             tail=True, dtype=xt.dtype)
-        kept = jnp.zeros((bound, w_gate.shape[2]), xt.dtype)
-        return out, kept, kept
+        kept = jnp.zeros((bound, w_up.shape[2]), xt.dtype)
+        return out, None if w_gate is None else kept, kept
 
     with jax.named_scope("moe.experts"):  # the `cond`'s own time and its predicate's
         return jax.lax.cond(_fits(sizes, bound), held, every)
@@ -757,11 +793,13 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig,
         # counted while tracing: a site BUILT with the compact path, or without
         with obs.layer_span("moe.full" if bound is None else "moe.compact"):
             if bound is None:
-                out = _all_rows(xt, w, lp["w_gate"], lp["w_up"], lp["w_down"], order, inv, sizes,
-                                tail=tail, dtype=x.dtype)
+                out = _all_rows(xt, w, lp.get("w_gate"), lp["w_up"], lp["w_down"], order, inv,
+                                sizes, tail=tail, dtype=x.dtype)
             else:
                 with jax.named_scope("moe.experts"):
-                    weights = [lp[k].astype(x.dtype) for k in ("w_gate", "w_up", "w_down")]
+                    # (an expert of two matrices has no `w_gate`: None all the way down)
+                    weights = [None if k not in lp else lp[k].astype(x.dtype)
+                               for k in ("w_gate", "w_up", "w_down")]
                 out = _held_or_all_once(bound, xt, w, *weights, order, inv, sizes)
     stats = {
         "tokens_per_expert": counts,
@@ -777,5 +815,10 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig,
     out = out.reshape(B, S, D)
     if c.shared_d_ff:
         with jax.named_scope("shared.ffn"):
-            out = out + swiglu(x, lp["shared_gate"], lp["shared_up"], lp["shared_down"])
+            if c.expert_act == "relu2":
+                hidden = jnp.einsum("bsd,df->bsf", x, lp["shared_up"].astype(x.dtype))
+                out = out + jnp.einsum("bsf,fd->bsd", jnp.square(jax.nn.relu(hidden)),
+                                       lp["shared_down"].astype(x.dtype))
+            else:
+                out = out + swiglu(x, lp["shared_gate"], lp["shared_up"], lp["shared_down"])
     return out, stats, router_state

@@ -33,13 +33,20 @@ gated-delta-rule linear-attention layers (30 heads, keys of 96, values of
 192, a causal convolution of 4; models/olmo_hybrid.py and
 ops/gated_delta.py, loaded only when one of its names is asked for) to one
 full-attention layer without a rotary, each over a dense SwiGLU, each
-sublayer's output normed.
+sublayer's output normed; and for the causal tower of
+Nemotron-Labs-TwoTower-30B-A3B, `nemotron-twotower-30b-a3b`, trained, not
+served: Mamba-2 state-space mixers (64 heads of 64, a state of 128, 8
+groups; models/nemotron_h.py, ops/ssd.py and ops/gdn_conv.py's kernels
+with a bias), 128 relu^2 experts of two matrices (sigmoid top-6, one
+shared) and GQA 32 / 2 without a rotary, ONE sublayer a layer by the
+published pattern string; its second (denoiser) tower and diffusion
+objective are not built.
 The registry gives users the same two entry points they expect:
 
   * `get_model_config("llama3-8b")` — named presets;
   * `config_from_hf(json.load(open("config.json")))` — map a HF
     transformers config dict onto LlamaConfig/MoEConfig/ZayaConfig/
-    GlmLiteConfig/LagunaConfig/KeyeConfig/OlmoHybridConfig (no downloads;
+    GlmLiteConfig/LagunaConfig/KeyeConfig/OlmoHybridConfig/NemotronHConfig (no downloads;
     weight conversion is a separate concern).
 """
 
@@ -56,7 +63,10 @@ _REGISTRY: dict[str, Any] = {}
 _ON_DEMAND = {"keye-vl-2.0-30b-a3b": ("ray_tpu.models.dsa", "KEYE_VL_2_30B_A3B"),
               "keye-tiny": ("ray_tpu.models.dsa", "KEYE_TINY"),
               "olmo-hybrid-7b": ("ray_tpu.models.olmo_hybrid", "OLMO_HYBRID_7B"),
-              "olmo-hybrid-tiny": ("ray_tpu.models.olmo_hybrid", "OLMO_HYBRID_TINY")}
+              "olmo-hybrid-tiny": ("ray_tpu.models.olmo_hybrid", "OLMO_HYBRID_TINY"),
+              "nemotron-twotower-30b-a3b": ("ray_tpu.models.nemotron_h",
+                                            "NEMOTRON_TWOTOWER_30B_A3B"),
+              "nemotron-h-tiny": ("ray_tpu.models.nemotron_h", "NEMOTRON_H_TINY")}
 
 
 def register_model(name: str, config) -> None:
@@ -383,9 +393,59 @@ def _olmo_hybrid_from_hf(hf: dict, **overrides):
     return config
 
 
+def _nemotron_h_from_hf(hf: dict, **overrides):
+    """`model_type` "nemotron_h" (the causal tower of
+    nvidia/Nemotron-Labs-TwoTower-30B-A3B-Base-BF16): Mamba-2 mixers,
+    relu^2 experts behind a sigmoid router and attention layers without a
+    rotary, one sublayer a layer by `hybrid_override_pattern`. What this
+    decoder does not implement is refused by name (the published model's
+    second tower and its diffusion objective have no key to refuse: they
+    are not built, models/nemotron_h.py)."""
+    from ray_tpu.models import nemotron_h as nh
+
+    pattern, n = hf["hybrid_override_pattern"], hf["num_hidden_layers"]
+    refused = {
+        "dense MLP layers (`-` in hybrid_override_pattern)": "-" in pattern[:n],
+        "layer kinds other than M, E, * and -": bool(set(pattern) - set("ME*-")),
+        "hybrid_override_pattern shorter than num_hidden_layers": len(pattern) < n,
+        "a bias (attention_bias, mlp_bias, use_bias or mamba_proj_bias)": any(
+            hf.get(k) for k in ("attention_bias", "mlp_bias", "use_bias", "mamba_proj_bias")),
+        "a convolution without a bias": not hf.get("use_conv_bias", True),
+        f"n_group {hf.get('n_group')} / topk_group {hf.get('topk_group')} (groups of experts)":
+            hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1,
+        "a sliding window": hf.get("sliding_window") is not None,
+        f"mlp_hidden_act {hf.get('mlp_hidden_act')!r}": hf.get("mlp_hidden_act") != "relu2",
+        f"mamba_hidden_act {hf.get('mamba_hidden_act')!r}": hf.get("mamba_hidden_act") != "silu",
+        f"n_shared_experts {hf.get('n_shared_experts')}": hf.get("n_shared_experts") != 1,
+        f"time_step_limit {hf.get('time_step_limit')} (a clamp on the step)":
+            list(hf.get("time_step_limit") or [0, None]) not in ([0, None], [0.0, None]),
+    }
+    if any(refused.values()):
+        raise ValueError("a nemotron_h config with "
+                         + ", ".join(k for k, v in refused.items() if v) + " is not supported")
+    fields = dict(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"], n_layers=n,
+        n_heads=hf["num_attention_heads"], n_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf["head_dim"], d_ff=hf["moe_intermediate_size"],
+        shared_d_ff=hf["moe_shared_expert_intermediate_size"],
+        max_seq=hf["max_position_embeddings"], rms_eps=float(hf["layer_norm_epsilon"]),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        n_experts=hf["n_routed_experts"], top_k=hf["num_experts_per_tok"],
+        norm_topk_prob=bool(hf["norm_topk_prob"]),
+        routed_scaling=float(hf["routed_scaling_factor"]), pattern=pattern,
+        mamba_heads=hf["mamba_num_heads"], mamba_head_dim=hf["mamba_head_dim"],
+        ssm_groups=hf["n_groups"], ssm_state=hf["ssm_state_size"],
+        conv_kernel=hf["conv_kernel"], chunk_size=hf["chunk_size"],
+        time_step_min=hf["time_step_min"], time_step_max=hf["time_step_max"],
+        time_step_floor=hf["time_step_floor"], published_layers=n,
+    )
+    fields.update(overrides)  # caller wins on collisions
+    return dataclasses.replace(nh.NEMOTRON_TWOTOWER_30B_A3B, **fields)
+
+
 def config_from_hf(hf: dict, **overrides):
     """Map a HF `config.json` dict to a LlamaConfig/MoEConfig/ZayaConfig/
-    GlmLiteConfig/LagunaConfig/KeyeConfig/OlmoHybridConfig.
+    GlmLiteConfig/LagunaConfig/KeyeConfig/OlmoHybridConfig/NemotronHConfig.
 
     Only architecture hyperparameters travel; framework knobs
     (dtype/remat/attention_impl) keep their TPU defaults unless
@@ -402,7 +462,9 @@ def config_from_hf(hf: dict, **overrides):
     `_laguna_from_hf`. The language model of Keye-VL-2.0 (`model_type`
     "KeyeVL2"): see `_keye_from_hf`. Olmo-Hybrid (`model_type`
     "olmo_hybrid": linear-attention layers beside full ones, no rotary):
-    see `_olmo_hybrid_from_hf`. For every OTHER family a
+    see `_olmo_hybrid_from_hf`. Nemotron-H (`model_type` "nemotron_h": Mamba-2
+    mixers, relu^2 experts, attention without a rotary): see
+    `_nemotron_h_from_hf`. For every OTHER family a
     `rope_scaling` and an explicit `head_dim` that is not hidden_size /
     heads stay refused.
     """
@@ -416,6 +478,8 @@ def config_from_hf(hf: dict, **overrides):
         return _keye_from_hf(hf, **overrides)
     if hf.get("model_type") == "olmo_hybrid":
         return _olmo_hybrid_from_hf(hf, **overrides)
+    if hf.get("model_type") == "nemotron_h":
+        return _nemotron_h_from_hf(hf, **overrides)
     archs = set(hf.get("architectures", ()))
     is_olmoe = "OlmoeForCausalLM" in archs or (not archs and hf.get("model_type") == "olmoe")
     # the num_local_experts heuristic only applies to config dicts with NO

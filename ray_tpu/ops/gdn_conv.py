@@ -5,7 +5,9 @@ A gated-delta-rule mixer (models/olmo_hybrid.py) takes each of its q, k
 and v projections x [B, H, T, d] (the matmul's bfloat16 output) through
 
     float32 -> a causal depthwise convolution of K taps over time
-    (pre_t = sum_j taps[j] x_{t-j}, zeros before the sequence, no bias)
+    (pre_t = sum_j taps[j] x_{t-j}, zeros before the sequence; no bias
+    there: a Mamba-2 mixer, models/nemotron_h.py, hands one a channel,
+    pre_t + b, through the same two kernels)
     -> SiLU -> for q and k an L2 norm over the head's d channels and a
     scale (s / sqrt(sum s^2 + eps) x scale),
 
@@ -43,6 +45,12 @@ apart, and are summed over the sublanes and the batch outside.
 Under the block's `jax.checkpoint` the forward kernel runs again in the
 backward, as the fusions it replaced did: float32 q, k, v of three layers
 held across the backward would be 567 MB.
+
+A BIAS rides as one more row of a head's taps, [H, K + 1, d]: the forward
+adds it to the pre-activation, the backward sums dpre alone into that
+row's gradient where a tap's sums dpre x_{t-j} (its x is 1). Without a
+bias both kernels are traced as they were (the lowered text of a model
+that has none is unchanged).
 
 ONE path, no option: off the TPU the same kernels run under the Pallas
 interpreter, as ops/gated_delta.py's do. Any d, any number of taps up to
@@ -108,12 +116,19 @@ def _weighted(shifted, taps):
     return acc
 
 
-def _fwd_kernel(x_ref, before_ref, taps_ref, y_ref, x_scr, *, scale, sub):
+def _split_bias(taps, bias):
+    """A head's rows [K (+ 1), d] -> (its K taps, its bias [1, d] or None)."""
+    return (taps[:-1], taps[-1:]) if bias else (taps, None)
+
+
+def _fwd_kernel(x_ref, before_ref, taps_ref, y_ref, x_scr, *, scale, sub, bias=False):
     rows = y_ref.shape[0]
     _stage(x_scr, x_ref, before_ref, pl.program_id(1) == 0)
-    taps = taps_ref[...]
+    taps, b = _split_bias(taps_ref[...], bias)
     for at in range(0, rows, sub):
         pre = _weighted(_taps_back(x_scr, at, taps.shape[0], sub), taps)
+        if bias:
+            pre = pre + b
         s = pre * jax.nn.sigmoid(pre)
         if scale is not None:
             s = s * jax.lax.rsqrt(jnp.sum(s * s, axis=-1, keepdims=True) + L2_EPS) * scale
@@ -121,18 +136,20 @@ def _fwd_kernel(x_ref, before_ref, taps_ref, y_ref, x_scr, *, scale, sub):
 
 
 def _bwd_kernel(x_ref, before_ref, taps_ref, dy_ref, dx_ref, dtaps_ref, x_scr, d_scr, *,
-                scale, sub):
+                scale, sub, bias=False):
     rows = dy_ref.shape[0]
     i, blocks = pl.program_id(1), pl.num_programs(1)     # step i holds block blocks - 1 - i
     _stage(x_scr, x_ref, before_ref, i == blocks - 1)
     # the rows of dpre after this block: the first rows of the block the step before held
     d_scr[rows:, :] = jnp.where(i == 0, 0.0, d_scr[:_HALO, :])
-    taps = taps_ref[...]
+    taps, b = _split_bias(taps_ref[...], bias)
     K = taps.shape[0]
-    sums = [jnp.zeros(dtaps_ref.shape[1:], _F32)] * K
+    sums = [jnp.zeros(dtaps_ref.shape[1:], _F32)] * (K + bias)
     for at in reversed(range(0, rows, sub)):
         shifted = _taps_back(x_scr, at, K, sub)
         pre = _weighted(shifted, taps)
+        if bias:
+            pre = pre + b
         sig = jax.nn.sigmoid(pre)
         ds = dy_ref[at:at + sub, :]
         if scale is not None:
@@ -144,14 +161,16 @@ def _bwd_kernel(x_ref, before_ref, taps_ref, dy_ref, dx_ref, dtaps_ref, x_scr, d
         ahead = [d_scr[at + j:at + j + sub, :] for j in range(K)]     # dpre_{t+j}
         dx_ref[at:at + sub, :] = _weighted(ahead, taps).astype(dx_ref.dtype)
         # a tap's gradient, 8 sublanes apart: adds of whole registers, no reduction in the walk
-        sums = [acc + (dpre * back).reshape(sub // _HALO, _HALO, -1).sum(axis=0)
-                for acc, back in zip(sums, shifted)]
+        # (the bias's row, the last, sums dpre alone)
+        sums = [acc + (dpre if back is None else dpre * back).reshape(
+                    sub // _HALO, _HALO, -1).sum(axis=0)
+                for acc, back in zip(sums, shifted + [None] * bias)]
 
     @pl.when(i == 0)
     def _():
         dtaps_ref[...] = jnp.zeros_like(dtaps_ref)
 
-    for j in range(K):
+    for j in range(K + bias):
         dtaps_ref[j] += sums[j]
 
 
@@ -178,14 +197,14 @@ _SEQUENTIAL = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")
 # a jitted function of its own, forward and backward each: a model's layers share ONE trace of
 # a kernel's body a shape, and the compiled step names the kernels after these functions
 # (ops/gated_delta.py has what tracing a body a layer and pass cost a start-up)
-@functools.partial(jax.jit, static_argnames=("scale", "rows", "interpret"))
-def gdn_conv_fwd(x, taps, scale, rows, interpret):
-    """x [B, H, T, d], T whole blocks of `rows`; taps [H, K, d] float32 ->
-    [B, H, T, d] float32."""
+@functools.partial(jax.jit, static_argnames=("scale", "rows", "interpret", "bias"))
+def gdn_conv_fwd(x, taps, scale, rows, interpret, bias=False):
+    """x [B, H, T, d], T whole blocks of `rows`; taps [H, K, d] float32
+    ([H, K + 1, d] with `bias`: the last row) -> [B, H, T, d] float32."""
     B, H, T, d = x.shape
     block, before, head_taps = _specs(rows, H, d, taps.shape[1], T // rows, reverse=False)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, sub=min(_tile_rows(d), rows)),
+        functools.partial(_fwd_kernel, scale=scale, sub=min(_tile_rows(d), rows), bias=bias),
         grid=(B * H, T // rows),
         in_specs=[block, before, head_taps],
         out_specs=block,
@@ -196,15 +215,15 @@ def gdn_conv_fwd(x, taps, scale, rows, interpret):
     )(x, x, taps)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "rows", "interpret"))
-def gdn_conv_bwd(x, taps, dy, scale, rows, interpret):
-    """-> (dx as x, the taps' gradients [B x H, K, 8, d] float32: to be
-    summed over the batch and the 8)."""
+@functools.partial(jax.jit, static_argnames=("scale", "rows", "interpret", "bias"))
+def gdn_conv_bwd(x, taps, dy, scale, rows, interpret, bias=False):
+    """-> (dx as x, the gradients of the taps' rows [B x H, K (+ 1), 8, d]
+    float32: to be summed over the batch and the 8)."""
     B, H, T, d = x.shape
     K = taps.shape[1]
     block, before, head_taps = _specs(rows, H, d, K, T // rows, reverse=True)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, scale=scale, sub=min(_tile_rows(d), rows)),
+        functools.partial(_bwd_kernel, scale=scale, sub=min(_tile_rows(d), rows), bias=bias),
         grid=(B * H, T // rows),
         in_specs=[block, before, head_taps, block],
         out_specs=[block, pl.BlockSpec((None, K, _HALO, d), lambda bh, i: (bh, 0, 0, 0))],
@@ -216,18 +235,19 @@ def gdn_conv_bwd(x, taps, dy, scale, rows, interpret):
     )(x, x, taps, dy)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _chain(scale, rows, interpret, x, taps):
-    return gdn_conv_fwd(x, taps, scale, rows, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _chain(scale, rows, interpret, bias, x, taps):
+    """`taps` [H, K, d], or with `bias` [H, K + 1, d]: the bias the last of a head's rows."""
+    return gdn_conv_fwd(x, taps, scale, rows, interpret, bias=bias)
 
 
-def _chain_fwd(scale, rows, interpret, x, taps):
-    return gdn_conv_fwd(x, taps, scale, rows, interpret), (x, taps)
+def _chain_fwd(scale, rows, interpret, bias, x, taps):
+    return gdn_conv_fwd(x, taps, scale, rows, interpret, bias=bias), (x, taps)
 
 
-def _chain_bwd(scale, rows, interpret, residuals, dy):
+def _chain_bwd(scale, rows, interpret, bias, residuals, dy):
     x, taps = residuals
-    dx, dtaps = gdn_conv_bwd(x, taps, dy, scale, rows, interpret)
+    dx, dtaps = gdn_conv_bwd(x, taps, dy, scale, rows, interpret, bias=bias)
     H, K, d = taps.shape
     return dx, dtaps.reshape(-1, H, K, _HALO, d).sum(axis=(0, 3))
 
@@ -235,22 +255,28 @@ def _chain_bwd(scale, rows, interpret, residuals, dy):
 _chain.defvjp(_chain_fwd, _chain_bwd)
 
 
-def gdn_conv(x: jax.Array, taps: jax.Array, scale: Optional[float] = None) -> jax.Array:
+def gdn_conv(x: jax.Array, taps: jax.Array, scale: Optional[float] = None,
+             bias: Optional[jax.Array] = None) -> jax.Array:
     """x [B, H, T, d] (any float dtype), taps [K, H x d] (tap j on position
-    t - j) -> SiLU of the causal depthwise convolution, [B, H, T, d]
-    float32; with `scale` (q: d ** -0.5, k: 1.0), its L2 norm over d times
-    `scale`. The module's docstring has the kernels. One layer span a call
-    site WHILE TRACING (`gdn_conv.kernel`) counts the sites."""
+    t - j), `bias` [H x d] or None -> SiLU of the causal depthwise
+    convolution (+ the bias), [B, H, T, d] float32; with `scale` (q:
+    d ** -0.5, k: 1.0), its L2 norm over d times `scale`. The module's
+    docstring has the kernels. One layer span a call site WHILE TRACING
+    (`gdn_conv.kernel`) counts the sites."""
     B, H, T, d = x.shape
     K = taps.shape[0]
     if K - 1 > _HALO:
         raise NotImplementedError(f"{K} taps: the kernels carry {_HALO} rows beside a block")
+    if bias is not None and scale is not None:
+        raise NotImplementedError("a bias under the L2 norm: no mixer has both")
     tile = _tile_rows(d)
     rows = min(_ROWS, -(-T // tile) * tile)
     short = -(-T // rows) * rows - T
     if short:
         x = jnp.pad(x, ((0, 0), (0, 0), (0, short), (0, 0)))
     head_taps = taps.astype(_F32).reshape(K, H, d).swapaxes(0, 1)
+    if bias is not None:   # one more row of a head's taps
+        head_taps = jnp.concatenate([head_taps, bias.astype(_F32).reshape(H, 1, d)], axis=1)
     with obs.layer_span("gdn_conv.kernel"):
-        y = _chain(scale, rows, jax.default_backend() != "tpu", x, head_taps)
+        y = _chain(scale, rows, jax.default_backend() != "tpu", bias is not None, x, head_taps)
     return y[:, :, :T] if short else y

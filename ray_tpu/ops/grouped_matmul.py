@@ -90,13 +90,25 @@ def _vmem_bytes(t: Tiles, itemsize: int, *, wgrad: bool) -> int:
     return 2 * itemsize * blocks + 2 * 4 * result + itemsize * transposed
 
 
+def _blocks(width: int) -> list:
+    """The blocks a dimension of `width` may be cut into: the multiples of a
+    lane tile (128) that divide it; a width that none divides goes WHOLE
+    where it is a multiple of half a lane tile (an expert of 1,856 = 14.5
+    tiles, Nemotron-H's: a block that is an array's whole dimension needs no
+    alignment, and Mosaic pads the last half tile); else none."""
+    if width % 128:
+        return [width] if width % 64 == 0 else []
+    return [t for t in range(128, width + 1, 128) if width % t == 0]
+
+
 def pick_tiles(P: int, K: int, N: int, dtype, *, wgrad: bool = False) -> Optional[Tiles]:
     """Tiles for `[P, K] x [E, K, N]` (or, `wgrad`, `[K, P] x [P, N]`),
     or None where no tile divides the shapes.
 
     Row tiles of 512 (256 or 128 where 512 does not divide P), cut into
     sub-blocks of `_CUT` rows at group edges. Of the
-    (tk, tn) that divide K and N in multiples of a lane tile and fit
+    (tk, tn) that divide K and N in multiples of a lane tile (`_blocks`:
+    or take a width of whole half tiles whole) and fit
     the VMEM budget, the pair that moves the fewest elements through
     HBM, the walk's re-reads counted: `lhs` once per N block; forward,
     a group's weights once if the contraction is ONE block (they stay
@@ -109,7 +121,7 @@ def pick_tiles(P: int, K: int, N: int, dtype, *, wgrad: bool = False) -> Optiona
         return None
     itemsize = jnp.dtype(dtype).itemsize
     tm = next((t for t in (512, 256, 128) if P % t == 0), None)
-    if tm is None or K % 128 or N % 128:
+    if tm is None or not (_blocks(K) and _blocks(N)):
         return None
 
     def moved(t: Tiles) -> int:
@@ -118,9 +130,7 @@ def pick_tiles(P: int, K: int, N: int, dtype, *, wgrad: bool = False) -> Optiona
             return P * K * tiles_n + P * N * tiles_k
         return P * K * tiles_n + (0 if tiles_k == 1 else (P // tm) * K * N)
 
-    fitting = [t for tk in range(128, K + 1, 128) if K % tk == 0
-               for tn in range(128, N + 1, 128) if N % tn == 0
-               for t in [Tiles(tm, tk, tn)]
+    fitting = [t for tk in _blocks(K) for tn in _blocks(N) for t in [Tiles(tm, tk, tn)]
                if _vmem_bytes(t, itemsize, wgrad=wgrad) <= _VMEM_BUDGET]
     return min(fitting, key=lambda t: (moved(t), -t.tk * t.tn), default=None)
 

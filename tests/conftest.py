@@ -133,10 +133,32 @@ _SEVEN_CELLS_AND_PR_42S_TAIL = (
     "test_every_cell_keeps_what_it_reported_and_the_end_to_end_metrics_are_as_they_were")
 
 
+# PR 49 added the ninth cell, `twotower-train-8k`, a SECOND cell on the traffic file
+# zipf_tokens_8k and a FOURTH small share. Two cases of tests/chipbench/test_chipbench_keye.py
+# spell out what was there before it: keye-train-8k as the ONE cell on that traffic (the last
+# line of its manifest test) and `moe_compact_pct`'s list as ENDING with it.
+# tests/chipbench/test_chipbench_nemotron_h.py carries every assertion of both for any number
+# of cells (`test_keyes_cell_is_as_it_entered_but_no_longer_alone_on_its_traffic`, and its own
+# `test_joined_metric_keeps_its_entry_and_its_cells_in_their_order[moe_compact_pct]`).
+_EIGHT_CELLS_AND_ONE_CELL_ON_THE_8K_TRAFFIC = {
+    ("test_chipbench_keye.py", "test_manifest_is_well_formed_with_the_cell"): None,
+    ("test_chipbench_keye.py",
+     "test_joined_metric_keeps_its_entry_and_its_cells_in_their_order"): "moe_compact_pct",
+}
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
         name = getattr(item, "originalname", None)
         file = os.path.basename(str(item.fspath))
+        if (file, name) in _EIGHT_CELLS_AND_ONE_CELL_ON_THE_8K_TRAFFIC:
+            only = _EIGHT_CELLS_AND_ONE_CELL_ON_THE_8K_TRAFFIC[(file, name)]
+            if only is None or item.callspec.params.get("name") == only:
+                item.add_marker(pytest.mark.skip(
+                    reason="spells out keye-train-8k as the one cell on zipf_tokens_8k (or the "
+                           "last of the small shares), as before PR 49; "
+                           "test_chipbench_nemotron_h.py carries its assertions for any number"))
+                continue
         if (file, name) == _SEVEN_CELLS_AND_PR_42S_TAIL:
             item.add_marker(pytest.mark.skip(
                 reason="spells out a list that ends with laguna-train and the tail PR 42 left; "

@@ -27,8 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from chipbench.reference import (glm_lite_decoder, keye_decoder, laguna_decoder,
-                                 olmo_hybrid_decoder, zaya_decoder)
-from ray_tpu.models import cca, dsa, laguna, llama, mla, olmo_hybrid
+                                 nemotron_h_decoder, olmo_hybrid_decoder, zaya_decoder)
+from ray_tpu.models import cca, dsa, laguna, llama, mla, nemotron_h, olmo_hybrid
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -282,6 +282,26 @@ def olmo_hybrid_shape(cfg) -> dict:
     }
 
 
+def nemotron_h_shape(cfg) -> dict:
+    """A NemotronHConfig as the configuration file's dict (HF key names)."""
+    return {
+        "hidden_size": cfg.d_model, "num_attention_heads": cfg.n_heads,
+        "num_key_value_heads": cfg.n_kv_heads, "head_dim": cfg.head_dim,
+        "num_hidden_layers": cfg.n_layers, "hybrid_override_pattern": cfg.pattern,
+        "mamba_num_heads": cfg.mamba_heads, "mamba_head_dim": cfg.mamba_head_dim,
+        "n_groups": cfg.ssm_groups, "ssm_state_size": cfg.ssm_state,
+        "conv_kernel": cfg.conv_kernel, "chunk_size": cfg.chunk_size, "use_conv_bias": True,
+        "mamba_proj_bias": False, "layer_norm_epsilon": cfg.rms_eps,
+        "moe_intermediate_size": cfg.d_ff, "moe_shared_expert_intermediate_size": cfg.shared_d_ff,
+        "n_routed_experts": cfg.n_held, "published": {"n_routed_experts": cfg.n_experts},
+        "deployment": {"first_expert_held": cfg.first_expert_held}, "n_shared_experts": 1,
+        "n_group": 1, "topk_group": 1, "num_experts_per_tok": cfg.top_k,
+        "norm_topk_prob": cfg.norm_topk_prob, "routed_scaling_factor": cfg.routed_scaling,
+        "max_position_embeddings": cfg.max_seq, "tie_word_embeddings": cfg.tie_embeddings,
+        "vocab_size": cfg.vocab_size,
+    }
+
+
 _LN = {"ln1": 0.2, "ln2": 0.2}
 _MLA_NORMS = {**_LN, "q_a_norm": 0.2, "kv_a_norm": 0.2}
 _REMAT_TOL = dict(rtol=1e-4, atol=2e-6)
@@ -395,4 +415,28 @@ OLMO_HYBRID = Model(
     bf16=dict(attention_impl="flash"), bf16_rel=0.02,
     tokens=skewed_tokens, reference_set_up=contextlib.nullcontext,
 )
-MODELS = (ZAYA, GLM_LITE, LAGUNA, KEYE, OLMO_HYBRID)
+def _nemotron_h_norms(params) -> list:
+    layers = params["layers"]
+    return [(layers["mamba"], {"ln": 0.2, "norm": 0.2, "A_log": 0.3, "dt_bias": 0.3, "D": 0.3}),
+            (layers["attention"], {"ln": 0.2}), (layers["experts"], {"ln": 0.2}),
+            (params, {"final_norm": 0.2})]
+
+
+NEMOTRON_H = Model(
+    name="nemotron_h",
+    fp32=dataclasses.replace(nemotron_h.NEMOTRON_H_TINY, dtype=jnp.float32),
+    batch=2, seq=40,   # two chunks of 16 and 8 positions more: no multiple of the chunk
+    reference=nemotron_h_decoder, shape_of=nemotron_h_shape, n_keys=16, bias=0.05,
+    norms=_nemotron_h_norms, preset="nemotron-twotower-30b-a3b", tiny="nemotron-h-tiny",
+    refused_as="Nemotron-H", catalog="Nemotron-Labs-TwoTower-30B-A3B-Base-BF16",
+    config_file="nemotron-twotower-30b-a3b-train.json",
+    facts={"head_dim": 128, "mamba_heads": 64, "mamba_head_dim": 64, "ssm_groups": 8,
+           "ssm_state": 128, "conv_kernel": 4, "chunk_size": 128, "n_experts": 128, "top_k": 6,
+           "router_score": "sigmoid", "routed_scaling": 2.5, "shared_d_ff": 3712, "d_ff": 1856,
+           "expert_act": "relu2", "n_kv_heads": 2, "rope_theta": 0.0, "published_layers": 52,
+           "pattern": "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"},
+    remat_plain={}, remat_bias=0.05, remat_tol=_REMAT_TOL,
+    bf16=dict(attention_impl="flash"), bf16_rel=0.02,
+    tokens=skewed_tokens, reference_set_up=contextlib.nullcontext,
+)
+MODELS = (ZAYA, GLM_LITE, LAGUNA, KEYE, OLMO_HYBRID, NEMOTRON_H)

@@ -199,3 +199,65 @@ def test_a_call_site_counts_itself_while_tracing_and_too_many_taps_are_refused()
     assert obs.layer_counters()["gdn_conv.kernel"]["count"] == before + 1
     with pytest.raises(NotImplementedError, match="10 taps"):
         gc.gdn_conv(x, jnp.zeros((10, H * 24)))
+
+
+# -- a bias a channel (a Mamba-2 mixer's convolution, models/nemotron_h.py) ---------------
+
+
+def chain_biased(x, taps, bias):
+    """SiLU(conv(x) + b): the chain with the bias [H x d] on the pre-activation."""
+    B, heads, S, d = x.shape
+    return jax.nn.silu(causal_conv(x.astype(F32), taps) + bias.astype(F32).reshape(heads, 1, d))
+
+
+def biased_grads(fn, x, taps, bias, w):
+    return jax.jit(jax.grad(lambda x, t, b: (fn(x, t, b) * w).sum(), argnums=(0, 1, 2)))(
+        x, taps, bias)
+
+
+@pytest.mark.parametrize("T", [1024, 1100, 40])
+@pytest.mark.parametrize("d", [128, 96], ids=["heads_of_128", "heads_of_96"])
+def test_a_bias_is_added_before_silu_and_takes_its_gradient(T, d):
+    """Forward, and all THREE gradients (the input's, the taps', the
+    bias's: the sum of dpre over every position of a channel), against
+    reverse mode through the jax.numpy chain; batch 2."""
+    x, taps, w = inputs(T, d, B=2)
+    bias = jax.random.uniform(jax.random.key(4), (H * d,), minval=-0.5, maxval=0.5)
+    got = gc.gdn_conv(x, taps, bias=bias)
+    assert got.shape == x.shape and got.dtype == F32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(chain_biased(x, taps, bias)),
+                               rtol=1e-5, atol=1e-6)
+    fn = lambda x, t, b: gc.gdn_conv(x, t, bias=b)  # noqa: E731
+    for name, g, r in zip(("dx", "dtaps", "dbias"), biased_grads(fn, x, taps, bias, w),
+                          biased_grads(chain_biased, x, taps, bias, w)):
+        assert g.shape == r.shape and g.dtype == r.dtype, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-4,
+                                   atol=2e-5 * float(jnp.abs(r).max()), err_msg=name)
+
+
+def test_a_bias_under_checkpoint_a_bfloat16_input_and_the_refusal_under_a_norm():
+    x, taps, w = inputs(600, 128, jnp.bfloat16)
+    bias = jax.random.uniform(jax.random.key(4), (H * 128,), minval=-0.5, maxval=0.5)
+    fn = lambda x, t, b: gc.gdn_conv(x, t, bias=b)  # noqa: E731
+    plain = biased_grads(fn, x, taps, bias, w)
+    for a, b in zip(biased_grads(jax.checkpoint(fn), x, taps, bias, w), plain):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert plain[0].dtype == jnp.bfloat16 and plain[2].dtype == bias.dtype
+    np.testing.assert_allclose(np.asarray(plain[2]),
+                               np.asarray(biased_grads(chain_biased, x, taps, bias, w)[2]),
+                               rtol=2e-4, atol=2e-5 * float(jnp.abs(plain[2]).max()))
+    with pytest.raises(NotImplementedError, match="a bias under the L2 norm"):
+        gc.gdn_conv(x, taps, 1.0, bias=bias)
+
+
+def test_a_zero_bias_is_no_bias_and_without_one_the_program_is_the_parents():
+    """A zero bias changes no output; a call without a bias traces the
+    kernels with the arguments they always had (no `bias` in their
+    parameters): the lowered text of a model that has none is unchanged."""
+    x, taps, _ = inputs(1024, 128)
+    np.testing.assert_array_equal(np.asarray(gc.gdn_conv(x, taps, bias=jnp.zeros(H * 128))),
+                                  np.asarray(gc.gdn_conv(x, taps)))
+    text = jax.jit(lambda x, t: gc.gdn_conv(x, t)).lower(x, taps).as_text()
+    assert "gdn_conv_fwd" in text
+    assert kernels_of(jax.make_jaxpr(lambda x, t, b: gc.gdn_conv(x, t, bias=b))(
+        x, taps, jnp.zeros(H * 128)).jaxpr) == 1

@@ -119,7 +119,14 @@ P_CELL = 6 * 4096 * 8  # olmoe-train: 24,576 tokens a step, 8 experts each
     ((8192, 14336, 4096), False, Tiles(512, 512, 4096)),
     # rows no 512 divides
     ((768, 128, 128), False, Tiles(256, 128, 128)),
-], ids=["gate_up", "down", "wgrad_gate_up", "wgrad_down", "mixtral_up", "mixtral_down", "rows_768"])
+    # Nemotron-H's experts of 1,856 = 14.5 lane tiles (PR 49): the width no tile divides goes
+    # WHOLE, the other dimension in the largest blocks that then fit VMEM
+    ((6144, 2688, 1856), False, Tiles(512, 896, 1856)),
+    ((6144, 1856, 2688), False, Tiles(512, 1856, 896)),
+    ((6144, 2688, 1856), True, Tiles(512, 896, 1856)),
+    ((6144, 1856, 2688), True, Tiles(512, 1856, 896)),
+], ids=["gate_up", "down", "wgrad_gate_up", "wgrad_down", "mixtral_up", "mixtral_down", "rows_768",
+        "relu2_up", "relu2_down", "wgrad_relu2_up", "wgrad_relu2_down"])
 def test_tile_rule_at_the_shapes_it_was_measured_for(shape, wgrad, want):
     got = pick_tiles(*shape, jnp.bfloat16, wgrad=wgrad)
     assert got == want
@@ -199,3 +206,33 @@ def test_on_a_tpu_a_mesh_or_an_undivided_shape_takes_ragged_dot():
         with parallel_context(Mesh(devices[..., :1, :], MESH_AXES)):
             _, spans = _spans(lambda: _traced_primitives(*_operands()))
         assert spans == (1, 0)
+
+
+@pytest.mark.parametrize("K,N", [(256, 192), (192, 256)], ids=["n_of_one_and_a_half_tiles",
+                                                                "k_of_one_and_a_half_tiles"])
+def test_a_width_of_whole_half_tiles_goes_whole_and_multiplies_as_ragged_dot(K, N):
+    """A width that is no multiple of a lane tile but of half of one (an
+    expert of 1,856) is ONE block of every kernel: value and both
+    gradients under the interpreter against `jax.lax.ragged_dot`, with a
+    tail of rows that belong to no group."""
+    P, E = 512, 3
+    assert pick_tiles(P, K, N, jnp.float32) is not None and pick_tiles(P, N, K, jnp.float32)
+    assert pick_tiles(P, K, N, jnp.float32, wgrad=True) is not None
+    ks = jax.random.split(jax.random.key(0), 3)
+    lhs, rhs = jax.random.normal(ks[0], (P, K)), jax.random.normal(ks[1], (E, K, N)) / K ** 0.5
+    ct = jax.random.normal(ks[2], (P, N))
+    sizes = jnp.asarray([200, 0, 250], jnp.int32)   # 62 rows past the last group
+
+    def kernel(lhs, rhs):
+        return (grouped_matmul_pallas(lhs, rhs, sizes, interpret=True, tail=True) * ct).sum()
+
+    def plain(lhs, rhs):
+        with jax.default_matmul_precision("highest"):
+            return (jax.lax.ragged_dot(lhs, rhs, sizes) * ct).sum()
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(kernel, argnums=(0, 1))(lhs, rhs)
+    want = jax.value_and_grad(plain, argnums=(0, 1))(lhs, rhs)
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
+    for g, w in zip(got[1], want[1]):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=1e-4)

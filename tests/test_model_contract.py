@@ -14,8 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from model_cases import (GLM_LITE, KEYE, LAGUNA, MODELS, OLMO_HYBRID, Model, catalog_config,
-                         seeded_params, train_path)
+from model_cases import (GLM_LITE, KEYE, LAGUNA, MODELS, NEMOTRON_H, OLMO_HYBRID, Model,
+                         catalog_config, seeded_params, train_path)
 from ray_tpu.models import llama
 from ray_tpu.models.registry import config_from_hf, get_model_config
 
@@ -96,6 +96,16 @@ def test_config_from_hf_maps_the_catalogs_config_onto_the_preset(model):
     (OLMO_HYBRID, "num_hidden_layers", 6, "does not end on a whole period"),
     (OLMO_HYBRID, "attention_bias", True, "attention_bias"),
     (OLMO_HYBRID, "sliding_window", 4096, "a sliding window"),
+    (NEMOTRON_H, "hybrid_override_pattern", "M-M*" * 13, "dense MLP layers"),
+    (NEMOTRON_H, "hybrid_override_pattern", "MEMX" * 13, "layer kinds other than M, E"),
+    (NEMOTRON_H, "num_hidden_layers", 60, "shorter than num_hidden_layers"),
+    (NEMOTRON_H, "mamba_proj_bias", True, "a bias"),
+    (NEMOTRON_H, "attention_bias", True, "a bias"),
+    (NEMOTRON_H, "use_conv_bias", False, "a convolution without a bias"),
+    (NEMOTRON_H, "n_group", 8, "n_group 8"),
+    (NEMOTRON_H, "sliding_window", 4096, "a sliding window"),
+    (NEMOTRON_H, "mlp_hidden_act", "silu", "mlp_hidden_act 'silu'"),
+    (NEMOTRON_H, "time_step_limit", [0.0, 0.5], "a clamp on the step"),
 ], ids=lambda v: v.name if isinstance(v, Model) else None)
 def test_config_from_hf_refuses_by_name_what_is_not_implemented(model, key, value, names):
     with pytest.raises(ValueError, match=names):

@@ -275,3 +275,108 @@ def test_moe_sharded_over_expert_axis():
     state, metrics = step(state, batch)
     assert abs(float(metrics["loss"]) - unsharded) < 1e-4 * unsharded
     assert not np.asarray(metrics["stats"]["dropped_pairs"]).any()
+
+
+# -- experts of TWO matrices: down(relu(up x)^2), `expert_act` "relu2" (Nemotron-H) --------
+
+
+RELU2 = dataclasses.replace(FP32, n_layers=1, d_model=64, d_ff=128, n_experts=16, top_k=3,
+                            router_score="sigmoid", routed_scaling=2.5, shared_d_ff=192,
+                            expert_act="relu2")
+# (experts held, first held, tokens): every expert (all rows), half of them (all rows with a
+# tail of pairs routed elsewhere), a small share (the compact path: both custom VJPs' bodies)
+RELU2_SHARES = {"all": (None, 0, 64), "half": (8, 8, 64), "small": (2, 4, 1024)}
+
+
+def _dense_relu2(x, lp, cfg):
+    """The layer in its dense form, differentiable: every held expert on
+    every token, masked by the routing; the shared expert on all."""
+    xt = x.reshape(-1, x.shape[-1])
+    with jax.default_matmul_precision("highest"):
+        scores = jax.nn.sigmoid(xt @ lp["router"])
+        biased = scores + lp["router_bias"]
+        kth = jnp.sort(biased, axis=-1)[:, -cfg.top_k][:, None]
+        w = jnp.where(biased >= kth, scores, 0.0)
+        w = w / (w.sum(-1, keepdims=True) + 1e-20) * cfg.routed_scaling
+        first = cfg.first_expert_held
+        held = w[:, first:first + cfg.n_held]
+        hidden = jnp.square(jax.nn.relu(jnp.einsum("nd,edf->enf", xt, lp["w_up"])))
+        out = jnp.einsum("enf,efd,ne->nd", hidden, lp["w_down"], held)
+        out = out + jnp.square(jax.nn.relu(xt @ lp["shared_up"])) @ lp["shared_down"]
+    return out.reshape(x.shape)
+
+
+@pytest.mark.parametrize("share", sorted(RELU2_SHARES))
+def test_relu2_experts_are_the_dense_form_in_value_and_every_gradient(share):
+    """Dropless; no `w_gate` and no `shared_gate` leaf; the output and the
+    gradients in x, both expert matrices, the router and the shared
+    expert against `jax.grad` of the dense form: over all rows, over all
+    rows with a tail, and over the held rows' bound (both custom VJPs)."""
+    held, first, tokens = RELU2_SHARES[share]
+    cfg = dataclasses.replace(RELU2, experts_held=held, first_expert_held=first)
+    lp = jax.tree.map(lambda a: a[0], moe.expert_params(cfg, jax.random.key(0)))
+    assert "w_gate" not in lp and "shared_gate" not in lp
+    assert set(moe.expert_axes(cfg)) == set(lp)
+    lp["router_bias"] = 0.02 * jax.random.normal(jax.random.key(1), (cfg.n_experts,))
+    x = jax.random.normal(jax.random.key(2), (2, tokens // 2, cfg.d_model))
+    weight = jax.random.normal(jax.random.key(9), x.shape)
+
+    def program(x, lp):
+        out, stats, _ = moe.moe_ffn(x, lp, cfg)
+        return (out * weight).sum(), (out, stats)
+
+    (_, (out, stats)), grads = jax.jit(jax.value_and_grad(program, argnums=(0, 1),
+                                                          has_aux=True))(x, lp)
+    want_out = _dense_relu2(x, lp, cfg)
+    want = jax.grad(lambda x, lp: (_dense_relu2(x, lp, cfg) * weight).sum(), argnums=(0, 1))(x, lp)
+    assert int(stats["dropped_pairs"]) == 0
+    assert int(stats["tokens_per_expert"].sum()) == tokens * cfg.top_k
+    assert ("compact" in stats) == (share == "small")
+    if share == "small":
+        assert int(stats["compact"]) == 1
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want_out), rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(grads[0]), np.asarray(want[0]), rtol=2e-4, atol=2e-4)
+    for name in ("w_up", "w_down", "router", "shared_up", "shared_down"):
+        scale = float(jnp.abs(want[1][name]).max())
+        assert scale > 0, name
+        np.testing.assert_allclose(np.asarray(grads[1][name]), np.asarray(want[1][name]),
+                                   rtol=2e-4, atol=2e-5 * scale, err_msg=name)
+
+
+def test_a_small_relu2_share_past_its_bound_runs_over_all_rows():
+    """Every token on the held experts: the block's other branch, in both
+    custom VJPs, is the dense form too."""
+    cfg = dataclasses.replace(RELU2, experts_held=2, first_expert_held=4)
+    lp = jax.tree.map(lambda a: a[0], moe.expert_params(cfg, jax.random.key(0)))
+    lp["router_bias"] = jnp.zeros((cfg.n_experts,)).at[4:6].set(10.0)   # every token chooses 4, 5
+    x = jax.random.normal(jax.random.key(2), (2, 512, cfg.d_model))
+
+    def program(x, lp):
+        out, stats, _ = moe.moe_ffn(x, lp, cfg)
+        return out.sum(), stats
+
+    (_, stats), grads = jax.jit(jax.value_and_grad(program, argnums=(0, 1), has_aux=True))(x, lp)
+    want = jax.grad(lambda x, lp: _dense_relu2(x, lp, cfg).sum(), argnums=(0, 1))(x, lp)
+    assert int(stats["compact"]) == 0 and int(stats["dropped_pairs"]) == 0
+    for name in ("w_up", "w_down"):
+        np.testing.assert_allclose(np.asarray(grads[1][name]), np.asarray(want[1][name]),
+                                   rtol=2e-4, atol=2e-5 * float(jnp.abs(want[1][name]).max()))
+    np.testing.assert_allclose(np.asarray(grads[0]), np.asarray(want[0]), rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("act,matrices", [("swiglu", 3), ("relu2", 2)])
+def test_the_counts_read_the_experts_form(act, matrices):
+    """`num_params` is the tree's own count and `flops_per_token` two a
+    matmul parameter a token meets, whatever the expert is."""
+    cfg = dataclasses.replace(RELU2, expert_act=act, n_layers=2, experts_held=4)
+    params = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.key(0)))
+    assert cfg.num_params() == sum(a.size for a in jax.tree.leaves(params))
+    assert cfg.expert_matrices == matrices
+    d, f = cfg.d_model, cfg.d_ff
+    dense = dataclasses.replace(llama.LLAMA_TINY, **{k: getattr(cfg, k) for k in (
+        "vocab_size", "d_model", "n_layers", "n_heads", "n_kv_heads", "d_ff", "max_seq")})
+    per_layer = (cfg.top_k * matrices * 2 * d * f + 2 * d * cfg.n_experts
+                 + matrices * 2 * d * cfg.shared_d_ff - 3 * 2 * d * f)
+    assert cfg.flops_per_token(64) == dense.flops_per_token(64) + cfg.n_layers * per_layer
+    with pytest.raises(KeyError):
+        dataclasses.replace(cfg, expert_act="gelu").num_params()

@@ -212,8 +212,11 @@ def test_train_step_asks_for_vmem_only_of_a_chip_it_knows(monkeypatch):
 
 @pytest.mark.parametrize("P,E,K,N", [
     (8192, 8, 4096, 14336), (8192, 8, 14336, 4096), (768, 4, 384, 128),
-    (32768, 8, 2048, 1536), (32768, 8, 1536, 2048)],
-    ids=["mixtral_up", "mixtral_down", "rows_in_tiles_of_256", "glm_lite_up", "glm_lite_down"])
+    (32768, 8, 2048, 1536), (32768, 8, 1536, 2048),
+    # Nemotron-H's experts of 1,856 = 14.5 lane tiles, a block of every kernel whole (PR 49)
+    (6144, 8, 2688, 1856), (6144, 8, 1856, 2688)],
+    ids=["mixtral_up", "mixtral_down", "rows_in_tiles_of_256", "glm_lite_up", "glm_lite_down",
+         "relu2_up_1856", "relu2_down_1856"])
 def test_grouped_matmul_kernels_compile_wherever_the_tile_rule_accepts(v5e, P, E, K, N):
     """The three kernels of ops/grouped_matmul.py at shapes other than
     the cell's: Mixtral-8x7B's widths, where the contraction or the
