@@ -63,6 +63,11 @@ ALLOWLIST = Allowlist({
         "worker main(): intentional forever-park; the daemon kills the "
         "process when its lease ends"
     ),
+    ("ops/ssd.py", "_bwd_kernel", "wait"): (
+        "a Pallas DMA descriptor's wait, traced into the kernel (a "
+        "semaphore wait on the device for a copy the same step started): "
+        "no host thread parks on it"
+    ),
 })
 
 SCAN_DIRS = (

@@ -454,6 +454,9 @@ def _remat(block, c: LlamaConfig):
                     # is all its backward kernel reads beside the inputs: the rule runs
                     # twice a layer (forward, backward), not three times
                     "gdn_out", "gdn_states",
+                    # ssd_out, ssd_states: likewise ops/ssd.py's forward kernel's y and
+                    # the chunks' starting states
+                    "ssd_out", "ssd_states",
                 ),
             ),
         )

@@ -5,8 +5,9 @@ What the causal tower of Nemotron-Labs-TwoTower-30B-A3B
 (nvidia/Nemotron-Labs-TwoTower-30B-A3B-Base-BF16, `model_type`
 nemotron_h) adds to the one decoder of models/llama.py:
 `NemotronHConfig`; the state-space sublayer `mamba_sublayer` (the scan
-itself is ops/ssd.py's, the convolution with its bias and SiLU
-ops/gdn_conv.py's kernels); an attention sublayer without a rotary at
+itself is ops/ssd.py's two Pallas kernels, which read the convolution's
+output where ops/gdn_conv.py's kernels, its bias and SiLU among them,
+leave it); an attention sublayer without a rotary at
 32 query heads over 2 key-value heads; the expert layer of
 models/moe.py with experts of TWO matrices (`expert_act` "relu2"); and
 a parameter tree and a stack built from the PATTERN STRING
@@ -33,7 +34,12 @@ published config carries unused; `ssm_groups` G = 8, `ssm_state` N =
   dt = softplus(dt + dt_bias) a head (`time_step_limit` (0, inf): no
   clamp); A = -exp(A_log) a head;
   H_t = exp(dt_t A) H_{t-1} + dt_t x_t B_t^T from H = 0 (H [64, 128] a
-  head), y_t = H_t C_t + D x_t (ops/ssd.py, chunks of `chunk_size`);
+  head), y_t = H_t C_t + D x_t (ops/ssd.py, chunks of `chunk_size`: the
+  convolution's [B, 48, S, 128] float32 is the kernels' `xbc` as it
+  stands, x's 32 heads of 128 two heads of 64 side by side, then B's 8
+  groups, then C's; y comes back token-major [B, S, 4096], as the gated
+  norm reads it, and the backward writes dx, dB and dC into one array
+  the convolution's backward reads);
   y = GroupRMSNorm(y * SiLU(z)) over groups of 4,096 / 8 = 512 with one
   learned [4,096] weight: the gate BEFORE the norm (the other reading,
   the norm first, is refused likewise); out = y W_out.
@@ -107,9 +113,11 @@ from ray_tpu.models import llama, moe
 from ray_tpu.nn.layers import head_major, init_dense, rms_norm
 from ray_tpu.ops.attention import attention_head_major
 from ray_tpu.ops.gdn_conv import gdn_conv
-# by THIS name the benchmark's runner finds the scan the sublayer runs and holds it alone to
-# the position-by-position reference: a kernel that replaces it is bound to the same name
-from ray_tpu.ops.ssd import ssd_scan
+# by the name `ssd_scan` the benchmark's runner finds the scan and holds it alone to the
+# position-by-position reference; the sublayer runs the SAME two kernels through
+# `ssd_scan_lanes`, which takes the convolution's array as it stands where `ssd_scan` builds
+# it from the plain [B, heads, S, P] arrays (tests/test_ssd.py holds the two to the bit)
+from ray_tpu.ops.ssd import ssd_scan, ssd_scan_lanes  # noqa: F401 - `ssd_scan` is read by name
 
 Params = dict[str, Any]
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
@@ -347,13 +355,15 @@ def mamba_sublayer(u: jax.Array, lp: Params, c: NemotronHConfig, *,
     the device ops, forward and backward: `ssm.proj`, `ssm.conv` (one
     `gdn_conv_fwd` / `gdn_conv_bwd` kernel over the 48 heads of 128 and
     the sum of the taps' and the bias's gradients), `ssm.gates`,
-    `ssm.scan`, `ssm.norm`, `ssm.out`."""
+    `ssm.scan` (one `ssd_scan_fwd` / `ssd_scan_bwd` kernel and the [B,
+    heads, S] arithmetic of dt A and its gradients), `ssm.norm`,
+    `ssm.out`."""
     if segment_ids is not None:
         raise NotImplementedError(
             "segment_ids (packed documents) under a Mamba layer: a state reset and a "
             "convolution that stops at a document's boundary are not implemented")
     B, S, D = u.shape
-    H, P, G, N, dt_ = c.mamba_heads, c.mamba_head_dim, c.ssm_groups, c.ssm_state, u.dtype
+    P, G, N, dt_ = c.mamba_head_dim, c.ssm_groups, c.ssm_state, u.dtype
     inner, wide = c.mamba_inner, c.conv_channels
     if inner % _CONV_HEAD or _CONV_HEAD % P or G * N % _CONV_HEAD or (
             N % _CONV_HEAD and _CONV_HEAD % N):
@@ -374,14 +384,12 @@ def mamba_sublayer(u: jax.Array, lp: Params, c: NemotronHConfig, *,
             dt = jax.nn.softplus(dt + lp["dt_bias"].astype(_F32)[:, None])
             A = -jnp.exp(lp["A_log"].astype(_F32))
         with jax.named_scope("ssm.scan"):
-            at = inner // _CONV_HEAD
-            # a head of the convolution holds 128 / P heads of x, or N / 128 of them one group
-            x = xBC[:, :at].reshape(B, at, S, _CONV_HEAD // P, P).swapaxes(2, 3).reshape(B, H, S, P)
-            Bm, Cm = (_group_major(xBC[:, a:a + G * N // _CONV_HEAD], G, N)
-                      for a in (at, at + G * N // _CONV_HEAD))
-            y = ssd_scan(x, dt, A, Bm, Cm, lp["D"], chunk=c.chunk_size)   # [B, H, S, P] float32
+            # the convolution's heads of 128 as lane blocks of N (ops/ssd.py's layout: x's
+            # blocks, then B's, then C's): at a state of 128 the array as it stands
+            y = ssd_scan_lanes(_lane_blocks(xBC, N), dt, A, lp["D"], head_dim=P,
+                               chunk=c.chunk_size)                  # [B, S, inner] float32
         with jax.named_scope("ssm.norm"):
-            y = y.swapaxes(1, 2).reshape(B, S, G, inner // G) \
+            y = y.reshape(B, S, G, inner // G) \
                 * jax.nn.silu(z.astype(_F32)).reshape(B, S, G, inner // G)
             y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True) + c.rms_eps)
             y = (y.reshape(B, S, inner) * lp["norm"].astype(_F32)).astype(dt_)
@@ -389,14 +397,14 @@ def mamba_sublayer(u: jax.Array, lp: Params, c: NemotronHConfig, *,
             return jnp.einsum("bsk,kd->bsd", y, lp["w_out"].astype(dt_))
 
 
-def _group_major(v: jax.Array, groups: int, state: int) -> jax.Array:
-    """[B, groups x state / 128, S, 128] -> [B, groups, S, state]."""
+def _lane_blocks(v: jax.Array, width: int) -> jax.Array:
+    """[B, heads, S, d] -> [B, heads x d / width, S, width], the channels in their order."""
     B, heads, S, d = v.shape
-    if state == d:
+    if width == d:
         return v
-    if state > d:   # a group's state spans whole heads
-        return v.reshape(B, groups, state // d, S, d).swapaxes(2, 3).reshape(B, groups, S, state)
-    return v.reshape(B, heads, S, d // state, state).swapaxes(2, 3).reshape(B, groups, S, state)
+    if width > d:   # a block spans whole heads
+        return v.reshape(B, -1, width // d, S, d).swapaxes(2, 3).reshape(B, -1, S, width)
+    return v.reshape(B, heads, S, d // width, width).swapaxes(2, 3).reshape(B, -1, S, width)
 
 
 def attention_sublayer(u: jax.Array, lp: Params, c: NemotronHConfig, *,

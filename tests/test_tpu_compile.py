@@ -260,6 +260,29 @@ def test_gdn_conv_kernels_compile_for_v5e(v5e, d, scale, T):
     assert hlo.count('custom_call_target="tpu_custom_call"') == 2
 
 
+@pytest.mark.parametrize("T", [8192, 300], ids=["whole_chunks", "three_chunks_padded"])
+def test_ssd_scan_kernels_compile_for_v5e(v5e, T):
+    """ops/ssd.py's two kernels at `twotower-train-8k`'s Mamba layer (64
+    heads of 64 in 8 groups, a state of 128, chunks of 128) on the
+    convolution's float32 [1, 48, T, 128]: the transposes that turn rows
+    to columns, the products that contract over a chunk's positions, the
+    [16, 128] block of dt over a and the backward's three DMAs into one
+    output are Mosaic's to accept, not the interpreter's. The step that
+    holds them compiled whole is tests/test_twotower_step_compile.py's."""
+    from ray_tpu.ops.ssd import ssd_scan_lanes
+
+    def value_and_grads(xbc, dt, A, D, ct):
+        out, pull = jax.vjp(lambda *a: ssd_scan_lanes(*a, head_dim=64), xbc, dt, A, D)
+        return (out,) + pull(ct)
+
+    f32 = jnp.float32
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        hlo = compile_kernel(value_and_grads, ((1, 48, T, 128), f32), ((1, 64, T), f32),
+                             ((64,), f32), ((64,), f32), ((1, T, 4096), f32),
+                             sharding=one_chip(v5e))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+
+
 def test_chip_smoke_runs_no_phase_without_a_tpu():
     p = subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py")],

@@ -5,7 +5,9 @@ experts with 8 of 128 held, one GQA 32 / 2 attention layer; an eighth of the
 vocabulary, 1 x 8192) as the cell builds it, which is also the guard that
 ops/gdn_conv.py's kernels lower through Mosaic WITH a bias at 48 heads of
 128 and ops/grouped_matmul.py's at an expert width of 1,856 (14.5 lane
-tiles, taken whole) where no chip is at hand. A file of the cell's own
+tiles, taken whole) where no chip is at hand, and that ops/ssd.py's two
+kernels stand in the step where the convolution's leave their arrays. A
+file of the cell's own
 (PR 45's layout: a full-width compile is 100 s alone and takes every core;
 ROADMAP D8)."""
 
@@ -15,9 +17,10 @@ from v5e_steps import grouped_kernels, train_step, v5e  # noqa: F401 - a fixture
 
 TWOTOWER = dict(batch=1, model="nemotron-twotower-30b-a3b", n_layers=9, seq=8192,
                 vocab_size=16384, experts_held=8)
-# sha256 of the lowered step as `twotower-train-8k` builds it, as PR 49 lowers it (the account
-# of every hash is tests/test_m7b_steps_compile.py's; the kernels' own bodies are not in it)
-_TWOTOWER_STEP = "57743e4e9d6fc087620320a59bfaf995bda3b29588216687970e879ca28ef8fd"
+# sha256 of the lowered step as `twotower-train-8k` builds it, as PR 50 lowers it: the scan as
+# ops/ssd.py's two kernels (the account of every hash is tests/test_m7b_steps_compile.py's; the
+# kernels' own bodies are not in it)
+_TWOTOWER_STEP = "c20579051ac080188375891c4ff66911fe9c79bf7489a0436ff603fce5571905"
 GIB = 2 ** 30
 
 
@@ -26,37 +29,47 @@ def test_twotower_train_step_lowers_to_the_text_it_had(v5e):
 
 
 def test_twotower_train_step_fits_the_chip_with_its_kernels_scopes_and_sites(v5e):
-    """With the remat policy "dots" as it is (rehearsal (a) of ISSUE 49: no
-    named residual of the scan is kept, the scan runs again under a block's
-    `jax.checkpoint`) the step is 7.45 GiB of arguments (666,963,456
-    parameters x 12 B) + 6.82 of temporaries, inside the chip's 15.75. The
+    """With the remat policy "dots" and what ops/ssd.py's forward kernel
+    writes saved by name (`ssd_out`, `ssd_states`: 128 + 128 MiB a Mamba
+    layer) the step is 7.45 GiB of arguments (666,963,456 parameters x 12
+    B) + 7.70 of temporaries, inside the chip's 15.75 (6.82 before PR 50,
+    when nothing of the scan was kept and its masks were temporaries). The
     Pallas kernels: the attention layer's flash forward and its fused
     backward at 32 / 2 heads of 128, named after their scope; the
     convolution's `gdn_conv_fwd` / `gdn_conv_bwd` under `ssm.conv` (a Mamba
     layer's forward, its forward again in the backward, its transpose: the
-    pair of layers the stack scans counted once a body); the expert layers'
-    grouped matmuls, every one a `ragged-dot-tiled*` of
-    ops/grouped_matmul.py and none XLA's own `ragged-dot-none`, with no
-    `w_gate`: an expert is two matrices. The loops left are the stack's scan
-    over (`ME` x 2) and the state-space scan's over the 64 chunks; nothing
-    is [8192, 8192]; every scope the cell's readers sum is in the compiled
-    step; each sublayer counts its site; q, k and v go head-major from the
-    projections to `wo` with no copy or transpose."""
+    pair of layers the stack scans counted once a body); the scan's
+    `ssd_scan_fwd` / `ssd_scan_bwd` under `ssm.scan`, ONE forward and ONE
+    backward a body and no forward a second time, reading the
+    convolution's [1, 48, 8192, 128] where it stands and writing dx, dB and
+    dC into one array of that shape; the expert layers' grouped matmuls,
+    every one a `ragged-dot-tiled*` of ops/grouped_matmul.py and none XLA's
+    own `ragged-dot-none`, with no `w_gate`: an expert is two matrices. The
+    loops left are the stack's scan over (`ME` x 2), forward and backward,
+    and the experts' bands': none walks the 64 chunks, no [.., 128, 128]
+    float32 mask and no chunked state is an array of the step, no copy or
+    transpose of x stands under `ssm.scan`; nothing is [8192, 8192]; every
+    scope the cell's readers sum is in the compiled step; each sublayer
+    counts its site; q, k and v go head-major from the projections to `wo`
+    with no copy or transpose."""
     step = train_step(v5e, **TWOTOWER)
-    engaged = step.engaged("ssm.mixer", "gdn_conv.kernel", "moe.ffn", "moe.compact", "moe.full",
-                           "moe.sum.linear", "flash.bwd_fused", "flash.bwd_split",
+    engaged = step.engaged("ssm.mixer", "gdn_conv.kernel", "ssd_scan.kernel", "moe.ffn",
+                           "moe.compact", "moe.full", "moe.sum.linear", "flash.bwd_fused",
+                           "flash.bwd_split",
                            "tp_overlap.plain", "grouped_matmul.ragged_dot", "grouped_matmul.kernel")
     assert engaged["ssm.mixer"] >= 1 and engaged["gdn_conv.kernel"] >= 1 and engaged["moe.ffn"] >= 1
+    assert engaged["ssd_scan.kernel"] >= 1
     assert engaged["moe.compact"] >= 1 and engaged["moe.full"] == 0 and engaged["moe.sum.linear"] >= 1
     assert engaged["flash.bwd_fused"] == 1 and engaged["grouped_matmul.kernel"] >= 6
     assert engaged["flash.bwd_split"] == engaged["tp_overlap.plain"] == 0   # fallback_sites
     assert engaged["grouped_matmul.ragged_dot"] == 0
     assert step.memory.argument_size_in_bytes < 7.46 * GIB
-    assert step.memory.temp_size_in_bytes < 7.0 * GIB
+    assert step.memory.temp_size_in_bytes < 7.8 * GIB
     assert (step.memory.argument_size_in_bytes + step.memory.temp_size_in_bytes) < 15.75 * GIB
     hlo, kernels = step.hlo, step.kernels
     names = sorted(re.sub(r"\.\d+$", "", k) for k in kernels if not k.startswith("ragged-dot"))
-    assert names == ["attn.attend"] * 2 + ["gdn_conv_bwd"] * 3 + ["gdn_conv_fwd"] * 6, names
+    assert names == (["attn.attend"] * 2 + ["gdn_conv_bwd"] * 3 + ["gdn_conv_fwd"] * 6
+                     + ["ssd_scan_bwd"] * 3 + ["ssd_scan_fwd"] * 3), names
     grouped = set(grouped_kernels(kernels))
     assert grouped == {"ragged-dot-tiled", "ragged-dot-tiled-dgrad", "ragged-dot-tiled-wgrad"}
     assert "ragged-dot-none" not in hlo and "w_gate" not in step.lowered_text
@@ -65,8 +78,19 @@ def test_twotower_train_step_fits_the_chip_with_its_kernels_scopes_and_sites(v5e
     assert len(conv) == 9 and all("48,8192,128" in line for line in conv)
     assert re.search(r"bf16\[1,32,8192,128\]", hlo) and re.search(r"bf16\[1,2,8192,128\]", hlo)
     assert not re.search(r"\[(?:\d+,)*8192,8192\]", hlo)
-    # the carried state of the scan, [1, 8 groups, 8 heads, 64, 128] float32, inside a loop
-    assert re.search(r"f32\[1,8,8,64,128\]", hlo) and re.search(r"f32\[64,1,8,8,64,128\]", hlo)
+    scan = [line for line in hlo.splitlines()
+            if "tpu_custom_call" in line and re.search(r'op_name="[^"]*ssm\.scan', line)]
+    assert len(scan) == 6 and all("f32[1,48,8192,128]" in line for line in scan)
+    # no loop over the 64 chunks (the jax.numpy form's carried [1, 8, 8, 64, 128] and its 64
+    # stacked states), no mask or chunked array of it, in HBM
+    assert not re.search(r"f32\[1,8,8,64,128\]|f32\[64,1,8,8,64,128\]", hlo)
+    assert not re.search(r"f32\[(?:\d+,){2,}128,128\]", hlo)
+    assert not [line for line in hlo.splitlines() if " while(" in line and "ssm." in line]
+    in_scan = [(shape, op) for shape, op, rest in re.findall(
+        r"^\s*(?:ROOT )?%[\w.\-]+ = (\S+) (copy|transpose|concatenate|pad)\((.*)$", hlo, re.M)
+        if "ssm.scan" in rest
+        and re.search(r"f32\[1,(64,8192,64|32,8192,128|48,8192,128)\]", shape)]
+    assert not in_scan, in_scan
     for scope in ("ssm.proj", "ssm.conv", "ssm.gates", "ssm.scan", "ssm.norm", "ssm.out",
                   "attn.qkv", "attn.attend", "attn.out", "moe.router", "moe.dispatch",
                   "moe.experts", "moe.combine", "shared.ffn", "block.norm", "block.stack", "embed",
