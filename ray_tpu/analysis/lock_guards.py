@@ -36,6 +36,13 @@ GUARD_FRACTION = 0.75  # share of accesses that must hold L
 # (file, owner.attr, function) -> justification. The function key is the
 # OUTERMOST enclosing def (nested helpers inherit their parent's audit).
 ALLOWLIST = Allowlist({
+    ("obs/recorder.py", "SpanRecorder._layer_counts", "layer_counters"): (
+        "the layer counters are read without the lock by design (the "
+        "docstring says so): a scrape or LLMServer.stats() must not wait "
+        "on a span that is ending; list(dict.items()) is one GIL-atomic "
+        "copy and a cell's three numbers are only ever added to, so the "
+        "worst a reader sees is a count whose seconds land an instant later"
+    ),
     ("serve/router.py", "Router._replicas", "_refresh"): (
         "advisory staleness fast-path on the dispatch hot path: "
         "GIL-atomic reads; a stale value costs one redundant refresh RPC "

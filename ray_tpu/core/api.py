@@ -98,6 +98,7 @@ def init(
     # layer span runtime.init: what a process's start-up pays before it
     # can submit anything (chipbench's setup_runtime_s.train reads it)
     with obs.layer_span("runtime.init"):
+        obs.watch_gc()  # the collector's pauses, counted as host.gc; gone at shutdown()
         if address is not None:
             if address.startswith("ray://"):
                 address = address[len("ray://"):]
@@ -125,6 +126,7 @@ def init(
 
 
 def shutdown() -> None:
+    obs.unwatch_gc()
     if _CLUSTER[0] is not None:
         _CLUSTER[0].close()
         _CLUSTER[0] = None

@@ -14,6 +14,14 @@ Three pieces:
    seconds (``obs.layer_counters()``), and a ``Span`` in the recorder's
    layer ring while ``obs.capture()`` is open; ``obs.compile_log()`` is
    the process's compiles and cache loads (utils/compile_cache.py);
+ * the step's host timeline — for ``train.step`` (the step's call, which
+   ``note_program``'s wrapper times), ``train.report`` and ``host.gc``
+   (the collector's pauses, ``obs.watch_gc()``) the recorder keeps every
+   use, traced or not (``obs.layer_timeline(name)``);
+   ``obs.step_timeline()`` is a row a step (dispatch, wait, report,
+   between, the thread's and the other threads' CPU, pauses) and
+   ``obs.slow_steps()`` the steps over 1.2 x the median with one cause
+   each;
  * programs — ``obs.note_program(name, jitted)`` keeps what finds a
    compiled program again (train/step.py notes ``train.step``);
    ``obs.op_names()`` is its instructions with the named scopes each ran
@@ -50,12 +58,22 @@ from ray_tpu.obs.recorder import (
     layer_record,
     layer_span,
     span,
+    unwatch_gc,
+    watch_gc,
 )
+from ray_tpu.obs.steps import slow_steps, step_timeline
 
 
 def layer_counters() -> dict:
-    """{name: {"count", "busy_s"}} of this process's layer spans; no lock."""
+    """{name: {"count", "busy_s", "max_s"}} of this process's layer spans; no lock."""
     return get_recorder().layer_counters()
+
+
+def layer_timeline(name: str, since: float = 0.0) -> list:
+    """[(start, end, extra)] of the last 4,096 uses of a timeline name
+    (``train.step``, ``train.report``, ``host.gc``) that started at or
+    after ``since``, kept whether or not a capture was open."""
+    return get_recorder().layer_timeline(name, since)
 
 
 def capture():
@@ -89,7 +107,12 @@ __all__ = [
     "layer_counters",
     "layer_record",
     "layer_span",
+    "layer_timeline",
     "note_program",
     "op_names",
+    "slow_steps",
     "span",
+    "step_timeline",
+    "unwatch_gc",
+    "watch_gc",
 ]

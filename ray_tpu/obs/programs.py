@@ -11,6 +11,10 @@ of its arguments (abstract values: no array is kept alive, nothing is
 lowered). Only when asked (`op_names`) is the function lowered and
 compiled at those values, once, through the persistent cache (the
 program that ran is loaded, not built again), and the result kept.
+
+The wrapper is also the one place every call of the program passes, so
+it is where the HOST's part of a call is timed: each call runs under
+the layer span of the noted name (`train.step`).
 """
 
 from __future__ import annotations
@@ -19,15 +23,25 @@ import re
 import threading
 from typing import Any, Optional
 
+from ray_tpu.obs.recorder import layer_span
+
 _NOTED: dict = {}  # name -> the newest NotedProgram noted under it
 
 
 class NotedProgram:
     """`jitted` with its first call's abstract arguments noted. Calls,
-    `.lower` and every other attribute are the jitted function's own."""
+    `.lower` and every other attribute are the jitted function's own.
 
-    def __init__(self, jitted):
+    A call runs under the layer span `name`: the HOST's part of it, the
+    arguments flattened and the program dispatched (and, at a first
+    call, traced, compiled or loaded). On an asynchronous backend the
+    span ends when the program is enqueued, long before the device is
+    done: it is no measure of the step, only of what the caller's
+    thread paid to start it."""
+
+    def __init__(self, jitted, name: str):
         self._jitted = jitted
+        self._name = name
         self._abstract: Optional[tuple] = None
         self._compiled = None
         self._lock = threading.Lock()  # two readers, one compile
@@ -35,7 +49,8 @@ class NotedProgram:
     def __call__(self, *args):
         if self._abstract is None:  # all that a call pays
             self._abstract = _abstract_values(args)
-        return self._jitted(*args)
+        with layer_span(self._name):
+            return self._jitted(*args)
 
     def __getattr__(self, attr: str) -> Any:
         jitted = self.__dict__.get("_jitted")  # absent while copy or pickle rebuild the object
@@ -71,7 +86,7 @@ def _abstract_values(args: tuple) -> tuple:
 
 def note(name: str, jitted) -> NotedProgram:
     """Note `jitted` as this process's newest program of that name."""
-    noted = _NOTED[name] = NotedProgram(jitted)
+    noted = _NOTED[name] = NotedProgram(jitted, name)
     return noted
 
 

@@ -21,12 +21,19 @@ profiler's clock) plus two always-on numbers per name (count, busy
 seconds). Only while a ``capture()`` is open do they also become
 ``Span``s, in a bounded ring of their own — never in the per-request
 store, so they cannot evict a request.
+
+The timeline is the third: for the few names that ask for it
+(``TIMELINE_NAMES``: a train step's call, its report, the collector's
+pauses) the last ``TIMELINE_MAX`` uses are kept as ``(start, end,
+extra)`` whether a capture is open or not, so that "why was that step
+slow" has an answer in a run nobody traced (``obs/steps.py`` reads it).
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import gc
 import sys
 import threading
 import time
@@ -34,6 +41,20 @@ from collections import OrderedDict, deque
 from typing import Optional
 
 from ray_tpu.obs import context as trace_context
+
+try:
+    import resource
+except ImportError:  # no getrusage on this platform: the switches read None
+    resource = None
+_RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)
+
+TIMELINE_MAX = 4096  # events kept a timeline name; the oldest go first, counted
+GC_NAME = "host.gc"
+GC_KEEP_S = 1e-3     # a collection of generation 0 is kept in the ring only if longer
+# the names whose every use is kept (registered here, in code: no configuration
+# asks for a timeline); those in CLOCKED_NAMES also take the host's clocks at entry
+TIMELINE_NAMES = ("train.step", "train.report", GC_NAME)
+CLOCKED_NAMES = ("train.step",)
 
 
 @dataclasses.dataclass
@@ -78,15 +99,20 @@ class SpanRecorder:
         self._by_request: dict[str, str] = {}  # request_id -> trace_id
         self.num_dropped_traces = 0
         self.num_dropped_spans = 0
-        # layer spans: name -> [count, busy_s], added to under
-        # _layer_lock (two additions) and READ WITHOUT IT; the ring
-        # fills only while a capture is open
+        # layer spans: name -> [count, busy_s, max_s], added to under
+        # _layer_lock (two additions and a compare) and READ WITHOUT IT;
+        # the ring fills only while a capture is open
         self._layer_lock = threading.Lock()
         self._layer_counts: dict[str, list] = {}
         self._layers: "deque[Span]" = deque(maxlen=max_layer_spans)
         self._captures = 0
         self._layer_local = threading.local()
         self.num_dropped_layer_spans = 0
+        # the timeline: name -> ring of (start, end, extra), always on
+        # for the names that were asked for (keep_timeline)
+        self._timelines: "dict[str, deque]" = {}
+        self._clocked: set = set()
+        self.num_dropped_timeline_events: dict[str, int] = {}
 
     # -- writes ---------------------------------------------------------------
 
@@ -193,15 +219,46 @@ class SpanRecorder:
             self._layers.clear()
             self._layer_counts.clear()
             self.num_dropped_layer_spans = 0
+            for name, ring in self._timelines.items():
+                ring.clear()
+                self.num_dropped_timeline_events[name] = 0
 
     # -- layer spans ----------------------------------------------------------
 
     def layer_counters(self) -> dict:
-        """{name: {"count", "busy_s"}} of every layer span used in this
-        process, capture or not. Takes no lock: a reader may see the
-        count of a span whose seconds land an instant later."""
-        return {name: {"count": c[0], "busy_s": c[1]}
+        """{name: {"count", "busy_s", "max_s"}} of every layer span used
+        in this process, capture or not (``max_s``: the longest single
+        use, the tail a sum cannot show). Takes no lock: a reader may
+        see the count of a span whose seconds land an instant later."""
+        return {name: {"count": c[0], "busy_s": c[1], "max_s": c[2]}
                 for name, c in list(self._layer_counts.items())}
+
+    def keep_timeline(self, name: str, clocks: bool = False,
+                      size: int = TIMELINE_MAX) -> None:
+        """From now on keep every use of the layer span ``name`` as
+        ``(start, end, extra)`` in a ring of the last ``size``, capture
+        or not; with ``clocks`` its ``extra`` is ``host_clocks()`` at
+        the span's entry."""
+        with self._layer_lock:
+            if name not in self._timelines:
+                self._timelines[name] = deque(maxlen=max(1, int(size)))
+                self.num_dropped_timeline_events[name] = 0
+            if clocks:
+                self._clocked.add(name)
+
+    def layer_timeline(self, name: str, since: float = 0.0) -> list:
+        """[(start, end, extra)] of the kept uses of ``name`` that
+        started at or after ``since`` (time.time()), oldest first;
+        ``extra`` is None, the clocks at entry (a clocked name) or what
+        the recording site gave. [] for a name that keeps no timeline."""
+        while True:
+            try:
+                with self._layer_lock:
+                    kept = list(self._timelines.get(name, ()))
+                break
+            except RuntimeError:  # the collector's hook appended meanwhile: it takes no lock
+                continue
+        return [e for e in kept if e[0] >= since]
 
     def layer_spans(self, since: float = 0.0) -> list[Span]:
         """Captured layer spans that ended at or after ``since``
@@ -229,16 +286,30 @@ class SpanRecorder:
                 self._captures -= 1
             spans.extend(self.layer_spans(since=t0))
 
+    def _count(self, name: str, start: float, end: float) -> None:
+        cell = self._layer_counts.get(name)
+        if cell is None:
+            cell = self._layer_counts[name] = [0, 0.0, 0.0]
+        seconds = max(0.0, end - start)
+        cell[0] += 1
+        cell[1] += seconds
+        if seconds > cell[2]:
+            cell[2] = seconds
+
+    def _keep(self, name: str, event: tuple) -> None:
+        ring = self._timelines[name]
+        if len(ring) == ring.maxlen:
+            self.num_dropped_timeline_events[name] += 1
+        ring.append(event)
+
     def _layer_done(self, name: str, start: float, end: float,
                     ids: Optional[tuple], attrs: Optional[dict],
-                    status: str = "ok") -> None:
+                    status: str = "ok", extra=None) -> None:
         with self._layer_lock:
-            cell = self._layer_counts.get(name)
-            first = cell is None
-            if first:
-                cell = self._layer_counts[name] = [0, 0.0]
-            cell[0] += 1
-            cell[1] += max(0.0, end - start)
+            first = name not in self._layer_counts
+            self._count(name, start, end)
+            if name in self._timelines:
+                self._keep(name, (start, end, extra))
             if ids is not None:
                 if len(self._layers) == self._layers.maxlen:
                     self.num_dropped_layer_spans += 1
@@ -386,6 +457,8 @@ class SpanRecorder:
 
 
 _RECORDER = SpanRecorder()
+for _name in TIMELINE_NAMES:
+    _RECORDER.keep_timeline(_name, clocks=_name in CLOCKED_NAMES)
 
 
 def get_recorder() -> SpanRecorder:
@@ -474,7 +547,7 @@ class LayerSpan:
     filled inside the block (a lock wait is known only once the lock is
     held); they reach the recorded ``Span``, not the profiler's event."""
 
-    __slots__ = ("name", "attrs", "start", "_ctx", "_rec", "_ann", "_ids")
+    __slots__ = ("name", "attrs", "start", "_ctx", "_rec", "_ann", "_ids", "_clocks")
 
     def __init__(self, name: str, ctx, attrs: Optional[dict],
                  rec: SpanRecorder):
@@ -485,6 +558,7 @@ class LayerSpan:
         self._rec = rec
         self._ann = None
         self._ids = None  # (trace_id, span_id, parent_id) while captured
+        self._clocks = None  # host_clocks() at entry, for a clocked timeline name
 
     def __enter__(self) -> "LayerSpan":
         rec = self._rec
@@ -505,6 +579,8 @@ class LayerSpan:
         self._ann = _annotation(self.name)
         if self._ann is not None:
             self._ann.__enter__()
+        if self.name in rec._clocked:
+            self._clocks = host_clocks()
         self.start = time.time()
         return self
 
@@ -517,7 +593,7 @@ class LayerSpan:
             stack.pop()
         self._rec._layer_done(
             self.name, self.start, end, self._ids, self.attrs,
-            "ok" if exc_type is None else "error",
+            "ok" if exc_type is None else "error", self._clocks,
         )
 
 
@@ -531,7 +607,9 @@ def layer_span(name: str, ctx: Optional[trace_context.TraceContext] = None,
             sp.attrs["rows"] = n
 
     Always: an annotation of that name in the profiler's trace, and
-    count + busy seconds under ``layer_counters()[name]``. While a
+    count, busy seconds and the longest use under
+    ``layer_counters()[name]``; for a timeline name (``TIMELINE_NAMES``)
+    also ``(start, end, extra)`` in ``layer_timeline(name)``. While a
     ``capture()`` is open also a ``Span`` in the recorder's layer ring,
     whose parent is ``ctx`` (work done for one request: that request's
     TraceContext) or else the layer span enclosing it on this thread."""
@@ -550,10 +628,68 @@ def layer_record(name: str, start: float, end: Optional[float] = None,
     rec._layer_done(name, start, time.time() if end is None else end, ids, attrs)
 
 
+# -- the host's clocks and the collector's pauses -----------------------------
+
+
+def host_clocks() -> tuple:
+    """(thread id, this thread's CPU seconds, the process's CPU seconds,
+    this thread's involuntary context switches or None, the collector's
+    seconds so far): what a clocked timeline name takes at entry. Over
+    the interval between two entries of one thread the differences say
+    how long that thread ran, how long the process's OTHER threads ran
+    (process less thread), whether the kernel took the thread off a core,
+    and how long the collector held everything. A few microseconds."""
+    switches = None
+    if _RUSAGE_THREAD is not None:
+        switches = resource.getrusage(_RUSAGE_THREAD).ru_nivcsw
+    cell = _RECORDER._layer_counts.get(GC_NAME)
+    return (threading.get_ident(), time.thread_time(), time.process_time(), switches,
+            cell[1] if cell is not None else 0.0)
+
+
+_GC_STARTED = [0.0]
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """gc.callbacks hook: every collection adds to the layer counter
+    ``host.gc``; one of generation 1 or 2, or any longer than GC_KEEP_S,
+    is also kept in that name's timeline with its generation. The
+    interpreter runs one collection at a time and none inside this hook,
+    and the collection may have begun inside ``_layer_done`` on this very
+    thread, so nothing here takes the recorder's lock: one writer, and
+    readers that take none either."""
+    if phase == "start":
+        _GC_STARTED[0] = time.time()
+        return
+    start, end = _GC_STARTED[0], time.time()
+    _RECORDER._count(GC_NAME, start, end)
+    generation = info.get("generation", 0)
+    if generation or end - start > GC_KEEP_S:
+        _RECORDER._keep(GC_NAME, (start, end, {"generation": generation}))
+
+
+def watch_gc() -> None:
+    """Install the collector's hook, once however often this is called
+    (``ray_tpu.init()``, a train worker entering its loop). It only
+    watches: nothing here rests, freezes or tunes the collector."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+        from ray_tpu.util.metrics import register_collector
+
+        register_collector(_export_layer_counters)
+
+
+def unwatch_gc() -> None:
+    """Remove the hook (``ray_tpu.shutdown()``); ``host.gc``'s counter
+    and timeline stay, as every layer counter outlives the runtime."""
+    while _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+
+
 def _export_layer_counters() -> None:
     """util/metrics collector: mirror the layer counters into the
     registry (``/metrics``) when it is read, not on every span."""
-    from ray_tpu.util.metrics import Counter
+    from ray_tpu.util.metrics import Counter, Gauge
 
     counts = _RECORDER.layer_counters()
     n = Counter("obs_layer_spans_total",
@@ -561,9 +697,13 @@ def _export_layer_counters() -> None:
     busy = Counter("obs_layer_busy_seconds_total",
                    description="seconds inside layer spans, by span name",
                    tag_keys=("name",))
+    longest = Gauge("obs_layer_max_seconds",
+                    description="the longest single layer span, by span name",
+                    tag_keys=("name",))
     for name, c in counts.items():
         n.set_total(c["count"], {"name": name})
         busy.set_total(c["busy_s"], {"name": name})
+        longest.set(c["max_s"], {"name": name})
 
 
 register_metrics = _export_layer_counters  # scripts/check_metrics.py hook

@@ -128,6 +128,7 @@ class _TrainWorker:
     def run(self, fn: Callable, config: dict,
             fit_started: Optional[float] = None) -> str:
         session_mod._set_session(self.ctx)
+        obs.watch_gc()  # a worker process of a cluster never ran init()
         if fit_started is not None:
             # layer span train.worker_start: fit() (or this attempt)
             # began at `fit_started` on the controller; it ends here, as

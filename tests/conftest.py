@@ -147,10 +147,29 @@ _EIGHT_CELLS_AND_ONE_CELL_ON_THE_8K_TRAFFIC = {
 }
 
 
+# PR 51 appended eight per-layer metrics of the step's HOST timeline that all nine training
+# cells report. Three tests of the two newest cells hold the cell's reported set to exactly
+# what it entered with, or the END of `per_layer` to that cell's own metrics (nine cases).
+# tests/chipbench/test_chipbench_step_timeline.py runs each of them, every assertion, on the
+# manifest less what PR 51 appended (`test_an_earlier_cells_test_holds_on_the_manifest_...`),
+# and holds the eight to the end of the list itself.
+_THE_SETS_AND_THE_TAIL_BEFORE_PR_51 = {
+    ("test_chipbench_nemotron_h.py", "test_manifest_is_well_formed_with_the_cell"),
+    ("test_chipbench_nemotron_h.py", "test_new_metric_is_this_cells_alone_and_moves_train_tok_s"),
+    ("test_chipbench_olmo_hybrid.py", "test_manifest_is_well_formed_with_the_cell"),
+}
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
         name = getattr(item, "originalname", None)
         file = os.path.basename(str(item.fspath))
+        if (file, name) in _THE_SETS_AND_THE_TAIL_BEFORE_PR_51:
+            item.add_marker(pytest.mark.skip(
+                reason="holds the cell's reported set, or the end of per_layer, to what it was "
+                       "before PR 51 appended the host timeline's metrics; "
+                       "test_chipbench_step_timeline.py runs it on the manifest less those"))
+            continue
         if (file, name) in _EIGHT_CELLS_AND_ONE_CELL_ON_THE_8K_TRAFFIC:
             only = _EIGHT_CELLS_AND_ONE_CELL_ON_THE_8K_TRAFFIC[(file, name)]
             if only is None or item.callspec.params.get("name") == only:

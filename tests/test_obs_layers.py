@@ -140,6 +140,7 @@ def test_layer_span_is_a_host_event_in_a_real_profiler_trace(tmp_path):
     markers place the recorder's times on the profiler's clock."""
     f = jax.jit(lambda x: x * 2 + 1)
     f(jnp.ones(8)).block_until_ready()
+    noted = obs.programs.NotedProgram(f, "train.step")  # as make_train_step's step is
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 2
@@ -149,6 +150,7 @@ def test_layer_span_is_a_host_event_in_a_real_profiler_trace(tmp_path):
             with obs.layer_span("t.traced"):
                 f(jnp.ones(8)).block_until_ready()
                 time.sleep(0.01)
+            noted(jnp.ones(8)).block_until_ready()
     finally:
         jax.profiler.stop_trace()
     path, = glob.glob(os.path.join(tmp_path, "plugins", "profile", "*", "*.xplane.pb"))
@@ -162,6 +164,10 @@ def test_layer_span_is_a_host_event_in_a_real_profiler_trace(tmp_path):
     span, = [s for s in spans if s.name == "t.traced"]
     assert abs(span.start + offset - seen[0][0]) < 1e-3
     assert abs(span.duration_s - seen[0][1]) < 1e-3
+    # the step's call is a host event of the program's own, on the same clock
+    called, = [(s, d) for name, s, d in host if name == "train.step"]
+    span, = [s for s in spans if s.name == "train.step"]
+    assert abs(span.start + offset - called[0]) < 1e-3
 
 
 # -- the compile log ------------------------------------------------------------
@@ -536,6 +542,7 @@ def test_train_step_record_notes_abstract_values_at_the_first_call_and_lowers_wh
     assert "stablehlo" in step.lower(state, batch).as_text()  # the jitted step's own
     real = step._jitted
     step._jitted = spy = mock.Mock(wraps=real)
+    began, calls = time.time(), obs.layer_counters().get("train.step", {"count": 0})["count"]
     state, _ = step(state, batch)
     noted = step._abstract
     leaves = jax.tree.leaves(noted)
@@ -543,6 +550,11 @@ def test_train_step_record_notes_abstract_values_at_the_first_call_and_lowers_wh
     assert [x.shape for x in jax.tree.leaves(noted[1])] == [(2, 32), (2, 32)]
     state, _ = step(state, batch)
     assert step._abstract is noted and spy.call_count == 2 and spy.lower.call_count == 0
+    # each call ran under the layer span of the noted name, and the timeline kept both
+    assert obs.layer_counters()["train.step"]["count"] == calls + 2
+    kept = obs.layer_timeline("train.step", since=began)
+    assert len(kept) == 2 and all(end >= start and len(clocks) == 5
+                                  for start, end, clocks in kept)
     names = obs.op_names()
     assert obs.op_names() == names
     assert spy.lower.call_count == 1  # two requests, one lowering and compile
@@ -582,4 +594,313 @@ def test_a_step_called_inside_another_program_notes_shapes_without_a_placement()
 
 def test_obs_exports_the_record_and_no_clock_marker():
     assert {"note_program", "op_names"} <= set(obs.__all__) and not hasattr(obs, "memory")
+    assert {"layer_timeline", "step_timeline", "slow_steps", "watch_gc", "unwatch_gc"} \
+        <= set(obs.__all__)
     assert "clock_marker" not in obs.__all__ and not hasattr(obs, "clock_marker")
+
+
+# -- the step's host timeline (PR 51) -----------------------------------------------
+
+
+def test_timeline_keeps_registered_names_outside_a_capture_and_only_those():
+    rec = SpanRecorder()
+    rec.keep_timeline("t.kept")
+    before = time.time()
+    for name in ("t.kept", "t.other", "t.kept"):
+        with layer_span(name, recorder=rec):
+            pass
+    kept = rec.layer_timeline("t.kept")
+    assert len(kept) == 2 and all(before <= a <= b and extra is None for a, b, extra in kept)
+    assert rec.layer_timeline("t.other") == [] and rec.layer_spans() == []
+    assert rec.layer_timeline("t.kept", since=kept[1][0]) == kept[1:]
+    # the process's recorder keeps the step's call, its report and the collector's pauses
+    assert set(obs.get_recorder()._timelines) == {"train.step", "train.report", "host.gc"}
+
+
+def test_a_clocked_timeline_name_takes_the_hosts_clocks_at_entry():
+    rec = SpanRecorder()
+    rec.keep_timeline("t.clocked", clocks=True)
+    for _ in range(2):
+        with layer_span("t.clocked", recorder=rec):
+            sum(i * i for i in range(20000))  # the thread runs: its CPU clock moves
+    (_, _, first), (_, _, second) = rec.layer_timeline("t.clocked")
+    assert first[0] == second[0] == threading.get_ident()
+    assert second[1] > first[1] and second[2] >= first[2] + (second[1] - first[1]) - 1e-3
+    assert second[3] is None or second[3] >= first[3]
+    assert second[4] >= first[4] >= 0.0
+
+
+def test_layer_counters_carry_the_longest_use():
+    rec = SpanRecorder()
+    for seconds in (0.001, 0.02, 0.002):
+        with layer_span("t.tail", recorder=rec):
+            time.sleep(seconds)
+    got = rec.layer_counters()["t.tail"]
+    assert got["count"] == 3 and 0.02 <= got["max_s"] < got["busy_s"]
+    layer_record("t.elsewhere", time.time() - 0.5, recorder=rec)
+    assert rec.layer_counters()["t.elsewhere"]["max_s"] >= 0.5
+
+
+def test_the_longest_use_reaches_the_metrics_registry():
+    from ray_tpu.util.metrics import prometheus_text
+
+    with obs.layer_span("t.exported_tail"):
+        pass
+    assert 'ray_tpu_obs_layer_max_seconds{name="t.exported_tail"}' in prometheus_text()
+
+
+def test_timeline_ring_drops_the_oldest_and_counts_the_drops():
+    rec = SpanRecorder()
+    rec.keep_timeline("t.ring", size=4)
+    for i in range(7):
+        with layer_span("t.ring", recorder=rec) as sp:
+            sp.attrs["i"] = i
+    kept = rec.layer_timeline("t.ring")
+    assert len(kept) == 4 and rec.num_dropped_timeline_events["t.ring"] == 3
+    assert [a for a, _, _ in kept] == sorted(a for a, _, _ in kept)
+    assert rec.layer_counters()["t.ring"]["count"] == 7  # a drop loses no count
+    rec.clear()
+    assert rec.layer_timeline("t.ring") == [] and rec.num_dropped_timeline_events["t.ring"] == 0
+
+
+def _script(steps, disturbed=None, where=None, disturb=None, report=True):
+    """A loop as a user's: `steps` steps of call (2 ms under `train.step`), wait (3 ms),
+    report, between (1 ms); `disturb()` runs once, in step `disturbed`, at `where`.
+    -> (when the loop began, the start of the disturbed step's call)."""
+    from ray_tpu.train import session
+
+    began, marked = time.time(), None
+    for i in range(steps):
+        hit = disturb if i == disturbed else None
+        with obs.layer_span("train.step") as sp:
+            time.sleep(0.002)
+            if hit and where == "dispatch":
+                hit()
+        if i == disturbed:
+            marked = sp.start
+        time.sleep(0.003)
+        if hit and where == "wait":
+            hit()
+        if report:
+            session.report({"step": i, "slow": bool(hit and where == "report")})
+        time.sleep(0.001)
+        if hit and where == "between":
+            hit()  # before the NEXT step's call: the input side of this step's period
+    return began, marked
+
+
+class _Mailbox:
+    """A report queue whose `put` holds a report marked slow for 60 ms."""
+
+    def put(self, rep):
+        if rep["metrics"]["slow"]:
+            time.sleep(0.06)
+
+
+@pytest.fixture
+def scripted_session():
+    from ray_tpu.train import session
+
+    session._set_session(session.TrainContext(0, 1, "", _Mailbox()))
+    yield
+    session._clear_session()
+
+
+def test_step_timeline_under_a_real_trainer_has_four_segments_that_sum_to_the_period(tmp_path):
+    import ray_tpu
+    from ray_tpu.core import runtime as rt
+    from ray_tpu.train import JaxTrainer, RunConfig, session
+
+    began = []
+
+    def loop():
+        began.append(time.time())
+        for i in range(5):
+            with obs.layer_span("train.step"):
+                time.sleep(0.01)
+            time.sleep(0.005)
+            session.report({"step": i})
+            time.sleep(0.002)
+
+    if rt.is_initialized():
+        rt.shutdown_runtime()
+    ray_tpu.init(num_cpus=2)
+    try:
+        result = JaxTrainer(loop, run_config=RunConfig(name="timeline",
+                                                       storage_path=str(tmp_path))).fit()
+        assert result.error is None
+    finally:
+        ray_tpu.shutdown()
+    rows = obs.step_timeline(since=began[0])  # read after shutdown(), as the benchmark does
+    assert len(rows) == 5
+    for r in rows[:-1]:
+        assert r["dispatch_s"] >= 0.01 and r["wait_s"] >= 0.005 and r["between_s"] >= 0.002
+        assert r["report_s"] > 0
+        total = r["dispatch_s"] + r["wait_s"] + r["report_s"] + r["between_s"]
+        assert abs(total - r["period_s"]) < 1e-6
+        assert 0 <= r["thread_cpu_s"] < r["period_s"] and r["other_cpu_s"] >= 0
+        assert r["gc_s"] >= 0 and r["compile_s"] == 0
+    last = rows[-1]
+    assert last["period_s"] is None and last["between_s"] is None and last["report_s"] > 0
+    assert obs.step_timeline(since=began[0], until=rows[2]["start"]) == rows[:3]
+
+
+def _big_graph():
+    return [[i] for i in range(400_000)]
+
+
+def _new_shape():
+    jax.jit(lambda x: jnp.tanh(x) @ x.T)(jnp.ones((7, 13))).block_until_ready()
+
+
+@pytest.mark.parametrize("cause,where,how", [
+    ("gc", "dispatch", "collect"),
+    ("report", "report", None),
+    ("between", "between", "sleep"),
+    ("dispatch", "dispatch", "sleep"),
+    ("compile", "dispatch", "compile"),
+    ("wait", "wait", "sleep"),
+])
+def test_slow_steps_names_one_cause(cause, where, how, scripted_session, monkeypatch):
+    import gc
+
+    from ray_tpu.obs import recorder
+    from ray_tpu.utils.compile_cache import start_compile_log
+
+    # the kernel's count of preemptions is this machine's weather: without it a sleep in
+    # the caller's wait can only read `wait`
+    monkeypatch.setattr(recorder, "_RUSAGE_THREAD", None)
+    start_compile_log()
+    held = _big_graph() if how == "collect" else None
+    disturb = {"collect": gc.collect, "sleep": lambda: time.sleep(0.06),
+               "compile": _new_shape, None: lambda: None}[how]
+    obs.watch_gc()
+    try:
+        began, marked = _script(9, disturbed=5, where=where, disturb=disturb)
+    finally:
+        obs.unwatch_gc()
+    del held
+    rows = obs.step_timeline(since=began)
+    assert len(rows) == 9
+    slow = {s["start"]: s for s in obs.slow_steps(since=began)}
+    assert marked in slow, [r["period_s"] for r in rows]
+    found = slow[marked]
+    assert found["cause"] == cause and found["excess_s"] > 0.01
+    assert found["period_s"] > 1.2 * found["median_s"]
+    if cause == "gc":
+        assert found["gc_s"] >= 0.5 * found["excess_s"] and found["gc_generation"] == 2
+    elif cause == "compile":
+        assert found["compile_s"] > 0
+    else:
+        assert found["segment"] == where + "_s"
+    # the same rows handed in read the same
+    assert [s["start"] for s in obs.slow_steps(rows=rows)] == sorted(slow)
+
+
+def _rows(periods, **disturbed):
+    """Hand-built rows of a steady loop: 100 ms periods, 1 / 97 / 1 / 1 ms segments."""
+    rows = [{"start": 10.0 + 0.1 * i, "dispatch_s": 0.001, "wait_s": p - 0.003,
+             "report_s": 0.001, "between_s": 0.001, "period_s": p, "thread_cpu_s": 0.004,
+             "other_cpu_s": 0.002, "nivcsw": 0, "gc_s": 0.0, "gc_generation": None,
+             "compile_s": 0.0} for i, p in enumerate(periods)]
+    rows[3].update(disturbed)
+    return rows
+
+
+@pytest.mark.parametrize("clocks,cause", [
+    (dict(nivcsw=3), "preempted"),                          # off a core, and it ran no longer
+    (dict(nivcsw=3, thread_cpu_s=0.05), "wait"),            # it ran: nothing took it off
+    (dict(other_cpu_s=0.09), "other_threads"),              # the other threads burned the excess
+    (dict(other_cpu_s=0.05), "wait"),                       # not enough to explain it
+    (dict(nivcsw=None, thread_cpu_s=None, other_cpu_s=None, gc_s=None), "wait"),
+    (dict(gc_s=0.03), "wait"),                              # a third of the excess: not the cause
+    (dict(gc_s=0.05, gc_generation=2), "gc"),
+    (dict(compile_s=0.2, gc_s=0.08), "compile"),            # the stated order: compile first
+])
+def test_slow_steps_reads_the_clocks_in_its_stated_order(clocks, cause):
+    rows = _rows([0.1, 0.1, 0.1, 0.18, 0.1, 0.1], **clocks)
+    slow, = obs.slow_steps(rows=rows)
+    assert slow["start"] == rows[3]["start"] and slow["segment"] == "wait_s"
+    assert slow["cause"] == cause and abs(slow["excess_s"] - 0.08) < 1e-9
+    assert obs.slow_steps(rows=_rows([0.1] * 6)) == []
+    assert obs.slow_steps(rows=rows, factor=2.0) == []
+    # a run whose EVERY step is slow is a median, not a stall
+    assert obs.slow_steps(rows=_rows([0.18] * 6)) == []
+
+
+def test_a_step_with_no_report_before_the_next_is_still_a_row():
+    began, _ = _script(4, report=False)
+    rows = obs.step_timeline(since=began)
+    assert len(rows) == 4
+    for r in rows[:-1]:
+        assert r["wait_s"] is None and r["report_s"] is None
+        assert abs(r["dispatch_s"] + r["between_s"] - r["period_s"]) < 1e-9
+    # and its slow step is named by the segment that is left
+    began, marked = _script(8, disturbed=4, where="wait", disturb=lambda: time.sleep(0.06),
+                            report=False)
+    slow = {s["start"]: s for s in obs.slow_steps(since=began)}
+    assert slow[marked]["cause"] == "between"
+
+
+def test_the_collectors_hook_is_installed_once_and_gone_after_shutdown():
+    import gc
+
+    import ray_tpu
+    from ray_tpu.core import runtime as rt
+    from ray_tpu.obs.recorder import _on_gc
+
+    if rt.is_initialized():
+        rt.shutdown_runtime()
+    ray_tpu.init(num_cpus=1)
+    try:
+        ray_tpu.init(num_cpus=1, ignore_reinit_error=True)
+        obs.watch_gc()  # a train worker entering its loop
+        assert gc.callbacks.count(_on_gc) == 1
+        before = dict(obs.layer_counters()["host.gc"])
+        began = time.time()
+        held = _big_graph()
+        gc.collect()
+        del held
+        gc.collect(0)
+        after = obs.layer_counters()["host.gc"]
+        assert after["count"] >= before["count"] + 2 and after["busy_s"] > before["busy_s"]
+        assert after["max_s"] >= 0.001
+        kept = obs.layer_timeline("host.gc", since=began)
+        assert any(extra == {"generation": 2} and b - a >= 0.001 for a, b, extra in kept)
+        # a quick collection of the youngest generation is counted and not kept
+        assert not any(extra["generation"] == 0 and b - a <= 0.001 for a, b, extra in kept)
+    finally:
+        ray_tpu.shutdown()
+    assert _on_gc not in gc.callbacks
+    count = obs.layer_counters()["host.gc"]["count"]  # the counter outlives the runtime
+    gc.collect()
+    assert obs.layer_counters()["host.gc"]["count"] == count
+
+
+def test_a_checkpoint_save_and_the_wait_for_it_are_layer_spans(tmp_path):
+    """`train.checkpoint.save` / `.wait`: what a save costs the loop's thread, and with
+    `max_s` its worst (the operator's surface: `obs.layer_counters()`, `/metrics`)."""
+    import numpy as np
+
+    from ray_tpu.train import checkpoint
+
+    class Writer:  # an async save still in flight for 20 ms
+        def wait_until_finished(self):
+            time.sleep(0.02)
+
+    def counts():
+        got = obs.layer_counters()
+        return [got.get(n, {"count": 0})["count"]
+                for n in ("train.checkpoint.save", "train.checkpoint.wait")]
+
+    before = counts()
+    staged = tmp_path / "sharded.tmp"
+    staged.mkdir()
+    with obs.capture() as spans:
+        checkpoint.Checkpoint.from_state({"w": np.arange(3.0)}, str(tmp_path / "plain"))
+        checkpoint._PendingSave(Writer(), str(staged), str(tmp_path / "sharded")) \
+            .wait_until_finished()
+    assert [a - b for a, b in zip(counts(), before)] == [1, 1]
+    assert [s.attrs for s in spans if s.name == "train.checkpoint.save"] == [{"sharded": False}]
+    assert obs.layer_counters()["train.checkpoint.wait"]["max_s"] >= 0.02
+    assert os.path.isdir(tmp_path / "plain") and os.path.isdir(tmp_path / "sharded")
