@@ -7,7 +7,8 @@ nemotron_h) adds to the one decoder of models/llama.py:
 `NemotronHConfig`; the state-space sublayer `mamba_sublayer` (the scan
 itself is ops/ssd.py's two Pallas kernels, which read the convolution's
 output where ops/gdn_conv.py's kernels, its bias and SiLU among them,
-leave it); an attention sublayer without a rotary at
+leave it, and the gated norm after it ops/gated_norm.py's two, which read
+the scan's where it leaves it); an attention sublayer without a rotary at
 32 query heads over 2 key-value heads; the expert layer of
 models/moe.py with experts of TWO matrices (`expert_act` "relu2"); and
 a parameter tree and a stack built from the PATTERN STRING
@@ -42,7 +43,9 @@ published config carries unused; `ssm_groups` G = 8, `ssm_state` N =
   the convolution's backward reads);
   y = GroupRMSNorm(y * SiLU(z)) over groups of 4,096 / 8 = 512 with one
   learned [4,096] weight: the gate BEFORE the norm (the other reading,
-  the norm first, is refused likewise); out = y W_out.
+  the norm first, is refused likewise; ops/gated_norm.py, one pass forward
+  and one backward on y and z as they stand, whose dy the scan's backward
+  kernel reads as it is written); out = y W_out.
 
 `E`: logits u W_r in float32 [128]; s = sigmoid(logits); the `top_k`
 largest of s + `router_bias` (`n_group` 1: no groups); weights s of the
@@ -112,6 +115,7 @@ from ray_tpu import obs
 from ray_tpu.models import llama, moe
 from ray_tpu.nn.layers import head_major, init_dense, rms_norm
 from ray_tpu.ops.attention import attention_head_major
+from ray_tpu.ops.gated_norm import gated_norm
 from ray_tpu.ops.gdn_conv import gdn_conv
 # by the name `ssd_scan` the benchmark's runner finds the scan and holds it alone to the
 # position-by-position reference; the sublayer runs the SAME two kernels through
@@ -356,13 +360,14 @@ def mamba_sublayer(u: jax.Array, lp: Params, c: NemotronHConfig, *,
     `gdn_conv_fwd` / `gdn_conv_bwd` kernel over the 48 heads of 128 and
     the sum of the taps' and the bias's gradients), `ssm.gates`,
     `ssm.scan` (one `ssd_scan_fwd` / `ssd_scan_bwd` kernel and the [B,
-    heads, S] arithmetic of dt A and its gradients), `ssm.norm`,
-    `ssm.out`."""
+    heads, S] arithmetic of dt A and its gradients), `ssm.norm` (one
+    `gated_norm_fwd` / `gated_norm_bwd` kernel and the sum of the weight's
+    gradient over 8 sublanes), `ssm.out`."""
     if segment_ids is not None:
         raise NotImplementedError(
             "segment_ids (packed documents) under a Mamba layer: a state reset and a "
             "convolution that stops at a document's boundary are not implemented")
-    B, S, D = u.shape
+    D = u.shape[2]
     P, G, N, dt_ = c.mamba_head_dim, c.ssm_groups, c.ssm_state, u.dtype
     inner, wide = c.mamba_inner, c.conv_channels
     if inner % _CONV_HEAD or _CONV_HEAD % P or G * N % _CONV_HEAD or (
@@ -389,10 +394,7 @@ def mamba_sublayer(u: jax.Array, lp: Params, c: NemotronHConfig, *,
             y = ssd_scan_lanes(_lane_blocks(xBC, N), dt, A, lp["D"], head_dim=P,
                                chunk=c.chunk_size)                  # [B, S, inner] float32
         with jax.named_scope("ssm.norm"):
-            y = y.reshape(B, S, G, inner // G) \
-                * jax.nn.silu(z.astype(_F32)).reshape(B, S, G, inner // G)
-            y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True) + c.rms_eps)
-            y = (y.reshape(B, S, inner) * lp["norm"].astype(_F32)).astype(dt_)
+            y = gated_norm(y, z, lp["norm"], groups=G, eps=c.rms_eps)   # [B, S, inner] as z
         with jax.named_scope("ssm.out"):
             return jnp.einsum("bsk,kd->bsd", y, lp["w_out"].astype(dt_))
 

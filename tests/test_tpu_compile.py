@@ -283,6 +283,28 @@ def test_ssd_scan_kernels_compile_for_v5e(v5e, T):
     assert hlo.count('custom_call_target="tpu_custom_call"') == 2
 
 
+@pytest.mark.parametrize("T", [8192, 300], ids=["whole_blocks_of_128", "three_blocks_padded"])
+def test_gated_norm_kernels_compile_for_v5e(v5e, T):
+    """ops/gated_norm.py's two kernels at `twotower-train-8k`'s Mamba
+    layer: y float32 and z bfloat16 [1, T, 4096] in 8 groups of 512. A
+    group as a static slice of four lane tiles, tiles of 16 rows of a
+    bfloat16 block, the reductions over a group's lanes and the VMEM the
+    calls state (the backward's five row blocks are 14 MiB double-buffered)
+    are Mosaic's to accept, not the interpreter's. The step that holds
+    them compiled whole is tests/test_twotower_step_compile.py's."""
+    from ray_tpu.ops.gated_norm import gated_norm
+
+    def value_and_grads(y, z, weight, ct):
+        out, pull = jax.vjp(lambda *a: gated_norm(*a, groups=8, eps=1e-5), y, z, weight)
+        return (out,) + pull(ct)
+
+    f32 = jnp.float32
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        hlo = compile_kernel(value_and_grads, ((1, T, 4096), f32), ((1, T, 4096), _BF16),
+                             ((4096,), f32), ((1, T, 4096), _BF16), sharding=one_chip(v5e))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+
+
 def test_chip_smoke_runs_no_phase_without_a_tpu():
     p = subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py")],

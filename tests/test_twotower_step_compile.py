@@ -6,7 +6,8 @@ vocabulary, 1 x 8192) as the cell builds it, which is also the guard that
 ops/gdn_conv.py's kernels lower through Mosaic WITH a bias at 48 heads of
 128 and ops/grouped_matmul.py's at an expert width of 1,856 (14.5 lane
 tiles, taken whole) where no chip is at hand, and that ops/ssd.py's two
-kernels stand in the step where the convolution's leave their arrays. A
+kernels stand in the step where the convolution's leave their arrays, and
+ops/gated_norm.py's two where the scan's leave theirs. A
 file of the cell's own
 (PR 45's layout: a full-width compile is 100 s alone and takes every core;
 ROADMAP D8)."""
@@ -17,10 +18,11 @@ from v5e_steps import grouped_kernels, train_step, v5e  # noqa: F401 - a fixture
 
 TWOTOWER = dict(batch=1, model="nemotron-twotower-30b-a3b", n_layers=9, seq=8192,
                 vocab_size=16384, experts_held=8)
-# sha256 of the lowered step as `twotower-train-8k` builds it, as PR 50 lowers it: the scan as
-# ops/ssd.py's two kernels (the account of every hash is tests/test_m7b_steps_compile.py's; the
-# kernels' own bodies are not in it)
-_TWOTOWER_STEP = "c20579051ac080188375891c4ff66911fe9c79bf7489a0436ff603fce5571905"
+# sha256 of the lowered step as `twotower-train-8k` builds it, as PR 52 lowers it: the Mamba
+# mixers' gated group norm as ops/gated_norm.py's two kernels where five lines of jax.numpy
+# stood (the account of every hash is tests/test_m7b_steps_compile.py's; the kernels' own
+# bodies are not in it)
+_TWOTOWER_STEP = "7413445373a7c50080b2b604d7983ff5aeacbacd23d47b29cc7968aa747fe49a"
 GIB = 2 ** 30
 
 
@@ -32,8 +34,9 @@ def test_twotower_train_step_fits_the_chip_with_its_kernels_scopes_and_sites(v5e
     """With the remat policy "dots" and what ops/ssd.py's forward kernel
     writes saved by name (`ssd_out`, `ssd_states`: 128 + 128 MiB a Mamba
     layer) the step is 7.45 GiB of arguments (666,963,456 parameters x 12
-    B) + 7.70 of temporaries, inside the chip's 15.75 (6.82 before PR 50,
-    when nothing of the scan was kept and its masks were temporaries). The
+    B) + 7.65 of temporaries, inside the chip's 15.75 (7.70 before PR 52,
+    6.82 before PR 50, when nothing of the scan was kept and its masks were
+    temporaries). The
     Pallas kernels: the attention layer's flash forward and its fused
     backward at 32 / 2 heads of 128, named after their scope; the
     convolution's `gdn_conv_fwd` / `gdn_conv_bwd` under `ssm.conv` (a Mamba
@@ -42,7 +45,12 @@ def test_twotower_train_step_fits_the_chip_with_its_kernels_scopes_and_sites(v5e
     `ssd_scan_fwd` / `ssd_scan_bwd` under `ssm.scan`, ONE forward and ONE
     backward a body and no forward a second time, reading the
     convolution's [1, 48, 8192, 128] where it stands and writing dx, dB and
-    dC into one array of that shape; the expert layers' grouped matmuls,
+    dC into one array of that shape; the gated norm's `gated_norm_fwd` /
+    `gated_norm_bwd` under `ssm.norm` (forward, forward again under the
+    block's checkpoint, backward), on y and z [1, 8192, 4096] as they
+    stand: nothing else under that scope touches an array of 8,192 rows (no
+    copy, transpose or reshape to (8 groups, 512)), and the backward's dy
+    is `ssd_scan_bwd`'s operand itself; the expert layers' grouped matmuls,
     every one a `ragged-dot-tiled*` of ops/grouped_matmul.py and none XLA's
     own `ragged-dot-none`, with no `w_gate`: an expert is two matrices. The
     loops left are the stack's scan over (`ME` x 2), forward and backward,
@@ -53,12 +61,12 @@ def test_twotower_train_step_fits_the_chip_with_its_kernels_scopes_and_sites(v5e
     counts its site; q, k and v go head-major from the projections to `wo`
     with no copy or transpose."""
     step = train_step(v5e, **TWOTOWER)
-    engaged = step.engaged("ssm.mixer", "gdn_conv.kernel", "ssd_scan.kernel", "moe.ffn",
-                           "moe.compact", "moe.full", "moe.sum.linear", "flash.bwd_fused",
-                           "flash.bwd_split",
+    engaged = step.engaged("ssm.mixer", "gdn_conv.kernel", "ssd_scan.kernel", "gated_norm.kernel",
+                           "moe.ffn", "moe.compact", "moe.full", "moe.sum.linear",
+                           "flash.bwd_fused", "flash.bwd_split",
                            "tp_overlap.plain", "grouped_matmul.ragged_dot", "grouped_matmul.kernel")
     assert engaged["ssm.mixer"] >= 1 and engaged["gdn_conv.kernel"] >= 1 and engaged["moe.ffn"] >= 1
-    assert engaged["ssd_scan.kernel"] >= 1
+    assert engaged["ssd_scan.kernel"] >= 1 and engaged["gated_norm.kernel"] >= 1
     assert engaged["moe.compact"] >= 1 and engaged["moe.full"] == 0 and engaged["moe.sum.linear"] >= 1
     assert engaged["flash.bwd_fused"] == 1 and engaged["grouped_matmul.kernel"] >= 6
     assert engaged["flash.bwd_split"] == engaged["tp_overlap.plain"] == 0   # fallback_sites
@@ -68,7 +76,8 @@ def test_twotower_train_step_fits_the_chip_with_its_kernels_scopes_and_sites(v5e
     assert (step.memory.argument_size_in_bytes + step.memory.temp_size_in_bytes) < 15.75 * GIB
     hlo, kernels = step.hlo, step.kernels
     names = sorted(re.sub(r"\.\d+$", "", k) for k in kernels if not k.startswith("ragged-dot"))
-    assert names == (["attn.attend"] * 2 + ["gdn_conv_bwd"] * 3 + ["gdn_conv_fwd"] * 6
+    assert names == (["attn.attend"] * 2 + ["gated_norm_bwd"] * 3 + ["gated_norm_fwd"] * 6
+                     + ["gdn_conv_bwd"] * 3 + ["gdn_conv_fwd"] * 6
                      + ["ssd_scan_bwd"] * 3 + ["ssd_scan_fwd"] * 3), names
     grouped = set(grouped_kernels(kernels))
     assert grouped == {"ragged-dot-tiled", "ragged-dot-tiled-dgrad", "ragged-dot-tiled-wgrad"}
@@ -91,6 +100,25 @@ def test_twotower_train_step_fits_the_chip_with_its_kernels_scopes_and_sites(v5e
         if "ssm.scan" in rest
         and re.search(r"f32\[1,(64,8192,64|32,8192,128|48,8192,128)\]", shape)]
     assert not in_scan, in_scan
+    # under `ssm.norm` an array of 8,192 rows is a kernel's operand or output and nothing else's:
+    # XLA's form of the norm split the lanes into (8 groups, 512) and copied what it was given
+    assert "f32[1024,8,8,512]" not in hlo
+    in_norm = [(shape, op) for shape, op, rest in re.findall(
+        r"^\s*(?:ROOT )?%[\w.\-]+ = (\(.*?\)|\S+) ([\w\-]+)\((.*)$", hlo, re.M)
+        if re.search(r'op_name="[^"]*ssm\.norm', rest) and "8192" in shape
+        and op not in ("custom-call", "get-tuple-element")]
+    assert not in_norm, in_norm
+    norm = [line for line in hlo.splitlines()
+            if "tpu_custom_call" in line and re.search(r'op_name="[^"]*ssm\.norm', line)]
+    assert len(norm) == 9 and all("f32[1,8192,4096]" in line for line in norm)
+    # the backward's dy goes to the scan's backward kernel as it is written: the operand IS the
+    # kernel's first output
+    dys = re.findall(r"%ssd_scan_bwd[\w.]* = [^\n]*custom-call\([^)]*?(%[\w.\-]+)\), custom_call_target",
+                     hlo)
+    assert len(dys) == 3
+    for dy in dys:
+        assert re.search(re.escape(dy) + r" = f32\[1,8192,4096\]\S* get-tuple-element\(%gated_norm_bwd"
+                         r"[\w.]*\), index=0", hlo), dy
     for scope in ("ssm.proj", "ssm.conv", "ssm.gates", "ssm.scan", "ssm.norm", "ssm.out",
                   "attn.qkv", "attn.attend", "attn.out", "moe.router", "moe.dispatch",
                   "moe.experts", "moe.combine", "shared.ffn", "block.norm", "block.stack", "embed",
