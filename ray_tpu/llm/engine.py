@@ -247,7 +247,7 @@ class EngineConfig:
                 "is not implemented (training-side MoE lives in models/moe.py; "
                 "Mixtral, OLMoE, ZAYA1, whose compressed convolutional attention "
                 "has no cache here either, GLM-4.7-Flash, whose latent attention "
-                "would be served in its absorbed form, Laguna, whose sliding-window "
+                "would be served in its absorbed form, Laguna and Mellum2, whose sliding-window "
                 "layers want a cache sized by layer type, and Keye, whose indexer wants a "
                 "cache of its own keys and a selection in the ragged kernel, are training-only)"
             )

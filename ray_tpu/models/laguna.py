@@ -1,21 +1,38 @@
-"""Laguna: sliding-window and full attention layers of unlike shape in one
-stack, a headwise output gate, a rotary by layer type.
+"""The typed stack: sliding-window and full attention layers in one stack,
+each kind with its own mask, rotary and (where the model has them) head
+count; a headwise output gate or none; an RMSNorm a head on q and k or
+none. TWO models run through it and it stays one module (ROADMAP D2):
 
-What Laguna-S-2.1 (poolside/Laguna-S-2.1, `model_type` laguna) adds to
-the one decoder of models/llama.py: `LagunaConfig`; the attention
-sublayer `attention_sublayer`; and a parameter tree and a layer stack
-whose blocks differ in SHAPE. The head, the loss and the train step are
-models/llama.py's, which hands `logical_axes`, `init_params` and the
-trunk to this module when the configuration is a `LagunaConfig`; the
-expert layer (softmax top-k chosen with a selection bias, renormalised
-and scaled weights, a shared expert, a share of the experts held) is
-models/moe.py's.
+  * Laguna-S-2.1 (poolside/Laguna-S-2.1, `model_type` laguna): 72 heads
+    in a sliding layer and 48 in a full one, the gate, yarn on HALF a head
+    of the full layers, a leading dense layer, a shared expert, scaled
+    weights;
+  * Mellum2-12B-A2.5B (JetBrains/Mellum2-12B-A2.5B-Instruct, `model_type`
+    mellum): ONE head count (32 / 4: `heads_per_layer` empty, the period
+    found by type alone), no gate (`attn_gate` "none": no `wg` leaf, no
+    `*.gate` scope), an RMSNorm a head before the rotary (`qk_head_norm`:
+    leaves `q_norm`, `k_norm` [head_dim], scopes `swa.norm` / `attn.norm`),
+    yarn over the WHOLE head, no dense layer, no shared expert, unscaled
+    renormalised weights. Its "MTP head" (a reader's summary names one;
+    the config has no key, size or equation of it) is NOT built.
+
+What the stack adds to the one decoder of models/llama.py: `LagunaConfig`;
+the attention sublayer `attention_sublayer`; and a parameter tree and a
+layer stack whose blocks differ in SHAPE. The head, the loss and the
+train step are models/llama.py's, which hands `logical_axes`,
+`init_params` and the trunk to this module when the configuration is a
+`LagunaConfig`; the expert layer (softmax top-k chosen with a selection
+bias, renormalised and scaled weights, a shared expert, a share of the
+experts held) is models/moe.py's.
 
 THE LAYER. With x = RMSNorm(hidden), H_l the layer's own head count
 (`heads_per_layer`), 8 key-value heads, heads of `head_dim` (explicit:
 not d_model / heads), no bias:
 
   q = x Wq [H_l heads], k = x Wk, v = x Wv [kv heads];
+  with `qk_head_norm`: q_h <- RMSNorm(q_h) w_q, k_h <- RMSNorm(k_h) w_k
+  over the channels of each head, one learned [head_dim] each, before
+  the rotary;
   rotary on q and k by the layer's TYPE (`layer_types`):
     sliding_attention: every channel of a head, inv_freq theta^(-2i/hd);
     full_attention: the FIRST `partial` x hd channels (half-split pairing
@@ -25,8 +42,9 @@ not d_model / heads), no bias:
   causal softmax attention at 1 / sqrt(hd); a sliding layer's row sees
   the `sliding_window` keys up to its own (ops/flash.py walks only the
   sub-tiles the window meets);
-  g = sigmoid(x Wg) [H_l]: one number a head and token, o_h <- g_h o_h
-  (the headwise gate of arXiv:2505.06708, after attention, before Wo);
+  with `attn_gate` "per-head": g = sigmoid(x Wg) [H_l]: one number a head
+  and token, o_h <- g_h o_h (the headwise gate of arXiv:2505.06708,
+  after attention, before Wo); with "none" o goes to Wo as it is;
   hidden += concat(o) Wo.
 
 Then the dense SwiGLU (`dense_d_ff`) in the `first_dense_layers` leading
@@ -55,12 +73,14 @@ balances it writes one array; no gradient reaches it and no step moves
 it), "period": {"0": .., "1": ..} (a period's blocks by position, leaves
 stacked over the periods), "tail": {"0": ..} (unstacked; absent where
 the periods hold every layer)}. A block's leaves: ln1, wq, wk, wv, wg
-[D, H_l], wo, ln2 and the dense SwiGLU's or models/moe.py's.
+[D, H_l] (a gated model's), q_norm, k_norm [head_dim] (a normed one's),
+wo, ln2 and the dense SwiGLU's or models/moe.py's.
 
 Trained, not served: the engine refuses every expert configuration.
 Refused by name in models/registry.py: router logit soft-capping, the
-router's weight applied on the input, a gate other than per head, a
-stack that is not periodic.
+router's weight applied on the input, a gate other than per head or none,
+a bias, a dense layer after a sparse one, a rope type other than default
+or yarn, a stack that is not periodic.
 """
 
 from __future__ import annotations
@@ -119,8 +139,9 @@ class Rotary:
 class LagunaConfig(moe.MoEConfig):
     """`layer_types` and `heads_per_layer` are the PUBLISHED lists; a
     configuration cut in depth (`n_layers` smaller) runs their first
-    `n_layers` entries. `d_ff` is the width of one routed expert,
-    `n_heads` the config's `num_attention_heads` (the full layers')."""
+    `n_layers` entries. An empty `heads_per_layer` is `n_heads` in every
+    layer. `d_ff` is the width of one routed expert, `n_heads` the
+    config's `num_attention_heads` (the full layers')."""
 
     head_dim: int = 128           # explicit: 3072 / 48 is 64
     layer_types: tuple = ()
@@ -128,7 +149,8 @@ class LagunaConfig(moe.MoEConfig):
     sliding_window: int = 512
     rope_full: Rotary = Rotary(500000.0)
     rope_sliding: Rotary = Rotary(10000.0)
-    attn_gate: str = "per-head"
+    attn_gate: str = "per-head"   # or "none"
+    qk_head_norm: bool = False    # an RMSNorm a head on q and k, before the rotary
     first_dense_layers: int = 1
     dense_d_ff: int = 12288
     # models/llama.py's seam: the module that builds this tree and runs these layers
@@ -136,10 +158,11 @@ class LagunaConfig(moe.MoEConfig):
 
     def kinds(self) -> list:
         """[(type, heads)] of the `n_layers` layers this configuration runs."""
-        if not (len(self.layer_types) >= self.n_layers <= len(self.heads_per_layer)):
+        heads = self.heads_per_layer or (self.n_heads,) * len(self.layer_types)
+        if not (len(self.layer_types) >= self.n_layers <= len(heads)):
             raise ValueError(f"{self.n_layers} layers, but layer_types / heads_per_layer name "
-                             f"{len(self.layer_types)} / {len(self.heads_per_layer)}")
-        return list(zip(self.layer_types[:self.n_layers], self.heads_per_layer[:self.n_layers]))
+                             f"{len(self.layer_types)} / {len(heads)}")
+        return list(zip(self.layer_types[:self.n_layers], heads[:self.n_layers]))
 
     @property
     def n_expert_layers(self) -> int:
@@ -147,7 +170,9 @@ class LagunaConfig(moe.MoEConfig):
 
     def _block_matmul_params(self, heads: int, dense: bool, experts: int) -> int:
         d, hd = self.d_model, self.head_dim
-        attn = d * hd * (2 * heads + 2 * self.n_kv_heads) + d * heads
+        attn = d * hd * (2 * heads + 2 * self.n_kv_heads)
+        if self.attn_gate == "per-head":
+            attn += d * heads
         if dense:
             return attn + 3 * d * self.dense_d_ff
         return attn + d * self.n_experts + 3 * d * (experts * self.d_ff + self.shared_d_ff)
@@ -169,8 +194,9 @@ class LagunaConfig(moe.MoEConfig):
 
     def num_params(self) -> int:
         d = self.d_model
+        norms = 2 * d + (2 * self.head_dim if self.qk_head_norm else 0)
         blocks = sum(self._block_matmul_params(heads, l < self.first_dense_layers, self.n_held)
-                     + 2 * d for l, (_, heads) in enumerate(self.kinds()))
+                     + norms for l, (_, heads) in enumerate(self.kinds()))
         head = 0 if self.tie_embeddings else d * self.vocab_size
         return self.vocab_size * d + d + head + blocks + self.n_expert_layers * self.n_experts
 
@@ -201,6 +227,33 @@ LAGUNA_TINY = dataclasses.replace(
     rope_full=dataclasses.replace(LAGUNA_S_2_1.rope_full, original_max=32, factor=8.0),
     dense_d_ff=96,
 )
+# JetBrains/Mellum2-12B-A2.5B-Instruct config.json (the catalog's row): 28 layers, sliding x 3
+# then full, seven times, all at 32 / 4 heads of 128; every layer 64 routed experts of width 896,
+# 8 a token, renormalised; `intermediate_size` 7168 is used by no layer
+_MELLUM2_PERIOD = (SLIDING,) * 3 + (FULL,)
+MELLUM2_12B_A2_5B = LagunaConfig(
+    vocab_size=98304, d_model=2304, n_layers=28, n_heads=32, n_kv_heads=4, d_ff=896,
+    max_seq=131072, rope_theta=500000.0, rms_eps=1e-6, tie_embeddings=False,
+    n_experts=64, top_k=8, norm_topk_prob=True, qk_norm=False,
+    router_aux_coeff=0.0, router_z_coeff=0.0, router_score="softmax", routed_scaling=1.0,
+    shared_d_ff=0, head_dim=128, layer_types=_MELLUM2_PERIOD * 7, heads_per_layer=(),
+    sliding_window=1024,
+    rope_full=Rotary(500000.0, rope_type="yarn", factor=16.0, original_max=8192,
+                     beta_fast=32.0, beta_slow=1.0, attention_factor=1.2772588722239782),
+    rope_sliding=Rotary(500000.0),
+    attn_gate="none", qk_head_norm=True, first_dense_layers=0, dense_d_ff=7168,
+)
+# two periods of four, small: groups of 4 query heads, the window shorter than the sequence,
+# positions past yarn's original length, a ramp over pairs 0-4 of 8 (theta 50: 5e5 would end
+# it at pair 1 of so small a head)
+MELLUM2_TINY = dataclasses.replace(
+    MELLUM2_12B_A2_5B, vocab_size=512, d_model=64, n_layers=8, n_heads=8, n_kv_heads=2, d_ff=32,
+    max_seq=256, remat=False, n_experts=16, top_k=4, head_dim=16,
+    layer_types=_MELLUM2_PERIOD * 2, sliding_window=24,
+    rope_full=dataclasses.replace(MELLUM2_12B_A2_5B.rope_full, theta=50.0, original_max=32,
+                                  factor=8.0),
+    rope_sliding=Rotary(50.0), dense_d_ff=96,
+)
 
 
 # -- the stack's plan ----------------------------------------------------------
@@ -223,14 +276,18 @@ def plan(c: LagunaConfig) -> dict:
             "tail": rest[periods * p:]}
 
 
-def _attention_axes() -> Params:
-    return {"wq": ("layers", "embed", "heads"), "wk": ("layers", "embed", "kv_heads"),
-            "wv": ("layers", "embed", "kv_heads"), "wg": ("layers", "embed", "heads"),
-            "wo": ("layers", "heads", "embed")}
+def _attention_axes(c: LagunaConfig) -> Params:
+    axes = {"wq": ("layers", "embed", "heads"), "wk": ("layers", "embed", "kv_heads"),
+            "wv": ("layers", "embed", "kv_heads"), "wo": ("layers", "heads", "embed")}
+    if c.attn_gate == "per-head":
+        axes["wg"] = ("layers", "embed", "heads")
+    if c.qk_head_norm:
+        axes.update(q_norm=("layers", "norm"), k_norm=("layers", "norm"))
+    return axes
 
 
 def _block_axes(c: LagunaConfig, dense: bool, stacked: bool = True) -> Params:
-    axes = {"ln1": ("layers", "norm"), **_attention_axes(), "ln2": ("layers", "norm")}
+    axes = {"ln1": ("layers", "norm"), **_attention_axes(c), "ln2": ("layers", "norm")}
     if dense:
         axes.update(w_gate=("layers", "embed", "mlp"), w_up=("layers", "embed", "mlp"),
                     w_down=("layers", "mlp", "embed"))
@@ -268,10 +325,13 @@ def _block_params(c: LagunaConfig, key: jax.Array, heads: int, n: int, dense: bo
         "wq": per_layer(keys[0], (d, heads * hd)),
         "wk": per_layer(keys[1], (d, c.n_kv_heads * hd)),
         "wv": per_layer(keys[2], (d, c.n_kv_heads * hd)),
-        "wg": per_layer(keys[3], (d, heads)),
         "wo": per_layer(keys[4], (heads * hd, d)),
         "ln2": jnp.ones((n, d), c.param_dtype),
     }
+    if c.attn_gate == "per-head":
+        block["wg"] = per_layer(keys[3], (d, heads))
+    if c.qk_head_norm:
+        block.update({name: jnp.ones((n, hd), c.param_dtype) for name in ("q_norm", "k_norm")})
     if dense:
         block.update(w_gate=per_layer(keys[5], (d, c.dense_d_ff)),
                      w_up=per_layer(keys[6], (d, c.dense_d_ff)),
@@ -317,8 +377,9 @@ def attention_sublayer(h: jax.Array, x: jax.Array, lp: Params, c: LagunaConfig, 
     """hidden h and x = RMSNorm(h) [B, S, D] -> h + the sublayer. The
     equations and the layout are the module's docstring. Named scopes on
     the device ops, forward and backward: a full layer's `attn.qkv`,
-    `attn.rope`, `attn.attend`, `attn.gate`, `attn.out`; a sliding
-    layer's `swa.*`, so the two kinds' kernels can be told apart."""
+    `attn.norm` (a normed model's), `attn.rope`, `attn.attend`,
+    `attn.gate` (a gated model's), `attn.out`; a sliding layer's `swa.*`,
+    so the two kinds' kernels can be told apart."""
     B, S, D = x.shape
     hd, dt = c.head_dim, x.dtype
     sliding = kind == SLIDING
@@ -329,6 +390,9 @@ def attention_sublayer(h: jax.Array, x: jax.Array, lp: Params, c: LagunaConfig, 
             q, k, v = (head_major(jnp.einsum("bsd,dnh->bnsh", x,
                                              lp[n].astype(dt).reshape(D, -1, hd)))
                        for n in ("wq", "wk", "wv"))
+        if c.qk_head_norm:
+            with jax.named_scope(f"{scope}.norm"):
+                q, k = rms_norm(q, lp["q_norm"], c.rms_eps), rms_norm(k, lp["k_norm"], c.rms_eps)
         with jax.named_scope(f"{scope}.rope"):
             q, k = rotate_head_major(q, cos, sin), rotate_head_major(k, cos, sin)
         with jax.named_scope(f"{scope}.attend"):
@@ -337,10 +401,11 @@ def attention_sublayer(h: jax.Array, x: jax.Array, lp: Params, c: LagunaConfig, 
                                      window=c.sliding_window if sliding else None)
             # saved by the "dots" remat policy, as llama._block's is
             o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
-        with jax.named_scope(f"{scope}.gate"):
-            # one number a head and token; the product stands where `wo` reads o
-            g = jax.nn.sigmoid(jnp.einsum("bsd,dh->bhs", x, lp["wg"].astype(dt)).astype(_F32))
-            o = (o.astype(_F32) * g[..., None]).astype(dt)
+        if c.attn_gate == "per-head":
+            with jax.named_scope(f"{scope}.gate"):
+                # one number a head and token; the product stands where `wo` reads o
+                g = jax.nn.sigmoid(jnp.einsum("bsd,dh->bhs", x, lp["wg"].astype(dt)).astype(_F32))
+                o = (o.astype(_F32) * g[..., None]).astype(dt)
         with jax.named_scope(f"{scope}.out"):
             return h + jnp.einsum("bhsk,hkd->bsd", o, lp["wo"].astype(dt).reshape(heads, hd, D))
 
@@ -369,8 +434,8 @@ def trunk(params: Params, tokens: jax.Array, c: LagunaConfig, *, positions: jax.
     """The layers, up to the last one's output before the final norm ->
     (h [B, S, D], the expert layers' statistics, leaves stacked over them
     in layer order)."""
-    if c.attn_gate != "per-head":
-        raise ValueError(f"attention gate {c.attn_gate!r}: per-head is implemented")
+    if c.attn_gate not in ("per-head", "none"):
+        raise ValueError(f"attention gate {c.attn_gate!r}: per-head or none")
     p = plan(c)
     with jax.named_scope("attn.rope"):
         tables = {FULL: c.rope_full.tables(c.head_dim, positions)}

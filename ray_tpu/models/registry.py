@@ -19,7 +19,13 @@ attention layers of 72 and 48 heads of an explicit 128 in one stack
 (models/laguna.py), a headwise output gate, a rotary by layer type (yarn
 on half a head in the full layers), a leading dense layer, softmax
 top-10 of 256 experts chosen with a selection bias, weights renormalised
-and scaled, and a shared expert; the language model of
+and scaled, and a shared expert; Mellum2-12B-A2.5B, `mellum2-12b-a2.5b`,
+trained, not served, through that same module: three sliding-window
+(1024) layers to one full layer at ONE head count (GQA 32 / 4 of an
+explicit 128), an RMSNorm a head on q and k, no gate, yarn over the whole
+head of the full layers, no dense layer, softmax top-8 of 64 experts
+chosen with a selection bias and renormalised, no shared expert; the
+language model of
 Keye-VL-2.0-30B-A3B, `keye-vl-2.0-30b-a3b`, trained, not served: GQA 32 /
 4 at heads of an explicit 128 with an RMSNorm a head on q and k, over the
 2048 keys a query that a learned indexer of 16 heads of 64 selects
@@ -27,7 +33,7 @@ Keye-VL-2.0-30B-A3B, `keye-vl-2.0-30b-a3b`, trained, not served: GQA 32 /
 top-8 of 128 experts chosen with a selection bias and renormalised; its
 vision tower and image or video inputs are refused by name. Every expert
 configuration is TRAINING only: the serving engine refuses a MoEConfig,
-ZAYA1, GLM-4.7-Flash, Laguna and Keye by name), and which runs a stack of
+ZAYA1, GLM-4.7-Flash, Laguna, Mellum2 and Keye by name), and which runs a stack of
 its own for Olmo-Hybrid-7B, `olmo-hybrid-7b`, trained, not served: three
 gated-delta-rule linear-attention layers (30 heads, keys of 96, values of
 192, a causal convolution of 4; models/olmo_hybrid.py and
@@ -129,6 +135,8 @@ for _name, _cfg in {
     "glm-lite-tiny": mla.GLM_LITE_TINY,
     "laguna-s-2.1": laguna.LAGUNA_S_2_1,
     "laguna-tiny": laguna.LAGUNA_TINY,
+    "mellum2-12b-a2.5b": laguna.MELLUM2_12B_A2_5B,
+    "mellum2-tiny": laguna.MELLUM2_TINY,
 }.items():
     register_model(_name, _cfg)
 
@@ -220,10 +228,10 @@ def _glm_lite_from_hf(hf: dict, **overrides) -> mla.GlmLiteConfig:
     return dataclasses.replace(mla.GLM_4_7_FLASH, **fields)
 
 
-def _rotary_from_hf(group: dict) -> laguna.Rotary:
+def _rotary_from_hf(group: dict, family: str = "laguna") -> laguna.Rotary:
     kind = group.get("rope_type", "default")
     if kind not in ("default", "yarn"):
-        raise ValueError(f"a laguna config with rope_type {kind!r} is not supported")
+        raise ValueError(f"a {family} config with rope_type {kind!r} is not supported")
     fields = dict(theta=float(group["rope_theta"]), rope_type=kind,
                   partial=float(group.get("partial_rotary_factor", 1.0)))
     if kind == "yarn":
@@ -236,7 +244,10 @@ def _rotary_from_hf(group: dict) -> laguna.Rotary:
 
 
 def _laguna_from_hf(hf: dict, **overrides) -> laguna.LagunaConfig:
-    """`model_type` "laguna" (poolside/Laguna-S-2.1): sliding-window and
+    """`model_type` "laguna" (poolside/Laguna-S-2.1), through the stack
+    models/laguna.py as it runs now (Laguna's kind of it: head counts by
+    layer, the gate, a leading dense layer, a shared expert;
+    `_mellum_from_hf` maps the other): sliding-window and
     full attention layers (`layer_types`) at their own head counts
     (`num_attention_heads_per_layer`) and an explicit `head_dim`, a gate a
     head on the attention's output, a rotary by layer type
@@ -294,6 +305,59 @@ def _laguna_from_hf(hf: dict, **overrides) -> laguna.LagunaConfig:
         # it would run, every layer unrolled in one program: not what the scan is for
         raise ValueError("a laguna config whose layer_types / num_attention_heads_per_layer "
                          "never repeat (not periodic) is not supported")
+    return config
+
+
+def _mellum_from_hf(hf: dict, **overrides) -> laguna.LagunaConfig:
+    """`model_type` "mellum" (JetBrains/Mellum2-12B-A2.5B-Instruct) onto
+    models/laguna.py's stack: sliding-window and full attention layers
+    (`layer_types`: what is read; `max_window_layers` says nothing beside
+    it) at ONE head count and an explicit `head_dim`, an RMSNorm a head on
+    q and k (ASSUMED from the Qwen3-MoE lineage of the config's keys: it
+    has none for it), no gate, a rotary by layer type (`rope_parameters`),
+    every layer sparse (leading dense ones would run; none is published),
+    softmax top-k experts renormalised by `norm_topk_prob`, no shared
+    expert. What the stack does not implement is refused by name."""
+    n = hf["num_hidden_layers"]
+    mlp = list(hf.get("mlp_layer_types") or ["sparse"] * n)
+    first_dense = next((l for l, t in enumerate(mlp) if t != "dense"), n)
+    types = list(hf.get("layer_types") or ["full_attention"] * n)
+    rope = hf["rope_parameters"]
+    refused = {
+        f"gating {hf.get('gating')!r} (an output gate)": bool(hf.get("gating")),
+        "attention_bias": bool(hf.get("attention_bias")),
+        "a dense layer after a sparse one (mlp_layer_types not all sparse but leading dense)":
+            "dense" in mlp[first_dense:],
+        "layer_types other than full_attention / sliding_attention":
+            any(t not in (laguna.FULL, laguna.SLIDING) for t in types),
+        "layer_types shorter than num_hidden_layers": len(types) < n,
+        "use_sliding_window false beside sliding layer_types":
+            not hf.get("use_sliding_window", True) and laguna.SLIDING in types,
+        "num_attention_heads_per_layer": bool(hf.get("num_attention_heads_per_layer")),
+        "a shared expert": bool(hf.get("shared_expert_intermediate_size")),
+        f"hidden_act {hf.get('hidden_act')!r}": hf.get("hidden_act", "silu") != "silu",
+    }
+    if any(refused.values()):
+        raise ValueError("a mellum config with "
+                         + ", ".join(k for k, v in refused.items() if v) + " is not supported")
+    fields = dict(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"], n_layers=n,
+        n_heads=hf["num_attention_heads"], n_kv_heads=hf["num_key_value_heads"],
+        d_ff=hf["moe_intermediate_size"], max_seq=hf["max_position_embeddings"],
+        rope_theta=float(rope[laguna.FULL]["rope_theta"]), rms_eps=float(hf["rms_norm_eps"]),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        n_experts=hf["num_experts"], top_k=hf["num_experts_per_tok"],
+        norm_topk_prob=bool(hf["norm_topk_prob"]), head_dim=hf["head_dim"],
+        layer_types=tuple(types), sliding_window=hf["sliding_window"],
+        rope_full=_rotary_from_hf(rope[laguna.FULL], "mellum"),
+        rope_sliding=_rotary_from_hf(rope[laguna.SLIDING], "mellum"),
+        first_dense_layers=first_dense, dense_d_ff=hf["intermediate_size"],
+    )
+    fields.update(overrides)  # caller wins on collisions
+    config = dataclasses.replace(laguna.MELLUM2_12B_A2_5B, **fields)
+    if laguna.plan(config)["periods"] < 2:
+        raise ValueError("a mellum config whose layer_types never repeat (not periodic) "
+                         "is not supported")
     return config
 
 
@@ -459,7 +523,9 @@ def config_from_hf(hf: dict, **overrides):
     heads are not hidden_size / heads wide): see `_glm_lite_from_hf`.
     Laguna (`model_type` "laguna": `rope_parameters` by layer type, yarn
     among them, an explicit `head_dim`, head counts by layer): see
-    `_laguna_from_hf`. The language model of Keye-VL-2.0 (`model_type`
+    `_laguna_from_hf`. Mellum2 (`model_type` "mellum": the same stack at one
+    head count, a norm a head, no gate): see `_mellum_from_hf`. The language
+    model of Keye-VL-2.0 (`model_type`
     "KeyeVL2"): see `_keye_from_hf`. Olmo-Hybrid (`model_type`
     "olmo_hybrid": linear-attention layers beside full ones, no rotary):
     see `_olmo_hybrid_from_hf`. Nemotron-H (`model_type` "nemotron_h": Mamba-2
@@ -474,6 +540,8 @@ def config_from_hf(hf: dict, **overrides):
         return _glm_lite_from_hf(hf, **overrides)
     if hf.get("model_type") == "laguna":
         return _laguna_from_hf(hf, **overrides)
+    if hf.get("model_type") == "mellum":
+        return _mellum_from_hf(hf, **overrides)
     if hf.get("model_type") == "KeyeVL2":
         return _keye_from_hf(hf, **overrides)
     if hf.get("model_type") == "olmo_hybrid":

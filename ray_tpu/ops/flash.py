@@ -146,6 +146,38 @@ from ray_tpu import obs
 # walked for 512 required, 67%), and by the sweep above 256 x 256 costs
 # 1.50 / 1.24 times 512 x 512 a visit: a loss forward, 7% backward. Not
 # tried on the chip.
+#
+# The kv block at 16,384 keys (PR 53): what PR 43 left untried, READ and
+# not tuned, from the traced step of `mellum2-train-16k` on a v5e (heads of
+# 128, GQA 32 / 4, ONE sequence of 16,384, bf16; `python3 -m
+# chipbench.tools.step_table`, seed 3000000007; ms a kernel and step). A k
+# block of the whole sequence would be 4 MiB, over KV_BLOCK_BYTES, so the kv
+# block is MAX_BLOCK_K, four of them, one head a program (`_fold_rows_cap`
+# 512) and the backward the dq and the dk/dv kernels apart.
+#  * The full layer: forward 19.41, dq 25.13, dk/dv 29.37: 73.9 ms where the
+#    causal pairs need 39.1 at the MXU's peak, 52.9% (the fused kernels at
+#    8,192 keys under a selection read 34% of ITS pairs, PR 43); the forward
+#    is 4.9 x PR 43's 3.97 ms at 8,192 keys for 4.0 x the pairs; the backward
+#    runs seven matmuls a pair where the fused kernel runs five.
+#  * A sliding layer (window 1,024): forward 4.27, dq 12.54-12.94, dk/dv
+#    5.92-6.14: 22.7-23.4 ms where the pairs INSIDE the window need 4.87,
+#    20.6%. The dq kernel takes a whole [512, 4096] kv block a grid step,
+#    one or two a q block where 1,535 keys are visible, and costs twice the
+#    dk/dv kernel, which walks sub-tiles; the forward visits three or four
+#    sub-tiles a q block where two sub-tiles' worth of pairs is required.
+#  * k and v fetched, against the 32 MiB a layer they hold (2 x 4 heads x
+#    16,384 x 128 x 2 B), by the index maps: without a window every (head, q
+#    block) fetches all four kv blocks, those above the diagonal too (fetched,
+#    not computed): 32 x 32 x 4 x 2 MiB = 8 GiB in the forward and 8 GiB in
+#    the dq kernel, 256 times what they hold, 10 ms each at the HBM's rate
+#    where nothing hides them; under the window `_kv_block_of` names the
+#    resident block, a head fetches each kv block once and the pair at a
+#    block's edge a few times more: about 20 MiB a head, 0.65 GiB a layer and
+#    kernel, 20 times what they hold.
+# What a larger KV_BLOCK_BYTES (16,384 keys in ONE block are 48 MiB of the
+# core's 128 as the fused backward holds them), a dq kernel that walks
+# sub-tiles, or a kv fetch clamped to the diagonal would give is a
+# `perf_opt`'s to measure: the cell is there to hold whatever it changes.
 DEFAULT_BLOCK_Q = 512
 # The keys 32 bits x 128 lanes address: a block of a packed selection, the
 # most a kv block held before PR 43, and the kv block of a sequence over
@@ -155,7 +187,8 @@ MAX_BLOCK_K = 4096
 # as VMEM holds it (head_dim padded to the 128 lanes), is at most this: 4096
 # keys x head_dim 256 in bf16, the largest fused backward a cell ran before
 # PR 43 (`glm47f-train`), and so 8192 keys at heads of 128 in bf16
-# (`keye-train-8k`). Whether more fits the core's 128 MiB was not tried.
+# (`keye-train-8k`). Whether more fits the core's 128 MiB was not tried; what
+# 16,384 keys cost over four blocks of MAX_BLOCK_K is PR 53's note above.
 KV_BLOCK_BYTES = 2 << 20
 NEG_INF = -1e30  # true -inf breeds NaN via (-inf) - (-inf)
 

@@ -1,5 +1,5 @@
 """What the model files (tests/test_zaya.py, test_glm_lite.py,
-test_laguna.py, test_keye.py, test_olmo_hybrid.py, test_moe.py) and
+test_laguna.py (Laguna and Mellum2: one stack), test_keye.py, test_olmo_hybrid.py, test_moe.py) and
 tests/test_model_contract.py share. No test lives here (pytest does not
 collect the file).
 
@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench.reference import (glm_lite_decoder, keye_decoder, laguna_decoder,
+from chipbench.reference import (glm_lite_decoder, keye_decoder, laguna_decoder, mellum2_decoder,
                                  nemotron_h_decoder, olmo_hybrid_decoder, zaya_decoder)
 from ray_tpu.models import cca, dsa, laguna, llama, mla, nemotron_h, olmo_hybrid
 
@@ -250,6 +250,13 @@ def laguna_shape(cfg) -> dict:
     }
 
 
+def mellum2_shape(cfg) -> dict:
+    """A LagunaConfig of Mellum2's kind as the configuration file's dict (HF key names)."""
+    shape = laguna_shape(cfg)
+    del shape["num_attention_heads_per_layer"], shape["moe_routed_scaling_factor"]
+    return {**shape, "num_attention_heads": cfg.n_heads}
+
+
 def keye_shape(cfg) -> dict:
     """A KeyeConfig as the configuration file's dict (HF key names)."""
     return {
@@ -307,11 +314,19 @@ _MLA_NORMS = {**_LN, "q_a_norm": 0.2, "kv_a_norm": 0.2}
 _REMAT_TOL = dict(rtol=1e-4, atol=2e-6)
 
 
+def _typed_blocks(params) -> list:
+    return [block for group in ("period", "tail")
+            for block in params["layers"].get(group, {}).values()]
+
+
 def _laguna_norms(params) -> list:
-    blocks = [block for group in ("period", "tail")
-              for block in params["layers"].get(group, {}).values()]
-    return [(params["dense_layers"], _LN), *((block, _LN) for block in blocks),
+    return [(params["dense_layers"], _LN), *((block, _LN) for block in _typed_blocks(params)),
             (params, {"final_norm": 0.2})]
+
+
+def _mellum2_norms(params) -> list:
+    normed = {**_LN, "q_norm": 0.2, "k_norm": 0.2}
+    return [*((block, normed) for block in _typed_blocks(params)), (params, {"final_norm": 0.2})]
 
 
 ZAYA = Model(
@@ -349,6 +364,23 @@ LAGUNA = Model(
     remat_plain=dict(n_layers=5), remat_bias=0.05,   # the dense layer and one period
     remat_tol=_REMAT_TOL, bf16=dict(attention_impl="flash", n_layers=5), bf16_rel=0.02,
     tokens=skewed_tokens, reference_set_up=contextlib.nullcontext,
+)
+MELLUM2 = Model(
+    name="mellum2", fp32=dataclasses.replace(laguna.MELLUM2_TINY, dtype=jnp.float32),
+    batch=2, seq=64,   # the window (24) shorter than the sequence, four times yarn's original 16
+    reference=mellum2_decoder, shape_of=mellum2_shape, n_keys=64, bias=0.0, norms=_mellum2_norms,
+    preset="mellum2-12b-a2.5b", tiny="mellum2-tiny", refused_as="Mellum2",
+    catalog="Mellum2-12B-A2.5B-Instruct", config_file="mellum2-12b-a2.5b-train.json",
+    facts={"head_dim": 128, "rope_full.attention_factor": 1.2772588722239782,
+           "rope_full.partial": 1.0, "rope_full.factor": 16.0, "rope_sliding.theta": 500000.0,
+           "attn_gate": "none", "qk_head_norm": True, "heads_per_layer": (),
+           "first_dense_layers": 0, "shared_d_ff": 0, "routed_scaling": 1.0, "n_experts": 64,
+           "top_k": 8, "d_ff": 896, "sliding_window": 1024, "n_kv_heads": 4},
+    remat_plain=dict(n_layers=4), remat_bias=0.05,   # one period
+    remat_tol=_REMAT_TOL, bf16=dict(attention_impl="flash", n_layers=4), bf16_rel=0.02,
+    tokens=skewed_tokens,
+    # the reference walks its queries in blocks: four of them at this size
+    reference_set_up=lambda: mock.patch.object(mellum2_decoder, "QUERY_BLOCK", 16),
 )
 KEYE = Model(
     name="keye", fp32=dataclasses.replace(dsa.KEYE_TINY, dtype=jnp.float32),
@@ -439,4 +471,4 @@ NEMOTRON_H = Model(
     bf16=dict(attention_impl="flash"), bf16_rel=0.02,
     tokens=skewed_tokens, reference_set_up=contextlib.nullcontext,
 )
-MODELS = (ZAYA, GLM_LITE, LAGUNA, KEYE, OLMO_HYBRID, NEMOTRON_H)
+MODELS = (ZAYA, GLM_LITE, LAGUNA, MELLUM2, KEYE, OLMO_HYBRID, NEMOTRON_H)

@@ -53,11 +53,21 @@ def test_window_of_512_at_4096_keys_is_xla_attention(heads):
     dict(shape=(1, 512, 2, 2, 64), window=700, block_q=128, q_offset=512, sk=1024),
     dict(shape=(2, 300, 3, 1, 64), window=64),                                  # padded rows and keys
     dict(shape=(1, 256, 2, 2, 64), window=1, block_q=128),                      # a row sees itself alone
+    # PR 53, `mellum2-train-16k`'s walk made small: FOUR kv blocks (keys 1,024, blocks of 256, as
+    # 16,384 keys make four of 4,096), the dq and dk/dv kernels apart, groups of 8 query heads a
+    # key head, a window inside one kv block's width (192) and one that spans two and three (320)
+    dict(shape=(1, 1024, 8, 1, 64), window=192, block_q=128, block_k=256),
+    dict(shape=(1, 1024, 8, 1, 64), window=320, block_q=128, block_k=256),
 ], ids=["undivided", "segments_kv_blocks", "q_offset_kv_blocks", "q_offset_fused", "padded",
-        "window_of_one"])
-def test_windows_against_xla_attention(case):
+        "window_of_one", "four_kv_blocks_group_of_8_window_192",
+        "four_kv_blocks_group_of_8_window_320"])
+def test_windows_against_xla_attention(case, backwards_traced):
+    case = dict(case)
     shape = case.pop("shape")
-    _against_xla(shape, **case)
+    fused, split = backwards_traced(lambda: _against_xla(shape, **case))
+    # several kv blocks take the dq and the dk/dv kernels apart; one takes the fused kernel
+    several = case.get("block_k", 1 << 30) < case.get("sk", shape[1])
+    assert (fused, split) == ((0, 1) if several else (1, 0))
 
 
 def test_the_range_walk_visits_15_of_the_causal_walks_36_sub_tiles():

@@ -6,8 +6,12 @@ compiled: MLA through the flash kernels at heads of 256, the held
 experts' kernels on the compact path, the second head. Laguna-S-2.1's
 (the dense layer + one period of three sliding and one full expert
 layers, 8 of 256 experts held, 1 x 4096) at the cell's five layers, both
-of its tests reading that one text. The cells stand two or three a file
-by their compiles' seconds (ROADMAP D8), not by their kind."""
+of its tests reading that one text. Mellum2-12B-A2.5B's (PR 53: one
+period of three sliding and one full layer through the same
+models/laguna.py, 8 of 64 experts and an eighth of the vocabulary held,
+1 x 16,384) at the cell's four layers, lowered and compiled once for both
+of its tests. The cells stand two or three a file by their compiles'
+seconds (ROADMAP D8), not by their kind.""" 
 
 import re
 
@@ -24,6 +28,11 @@ GLM_SHARE = dict(model="glm-4.7-flash", vocab_size=19456, experts_held=8)
 # the account of every hash is tests/test_m7b_steps_compile.py's
 _GLM_LITE_STEP = "a3bebfc76d0379f05c0b4184981fd00c826b8233b90f4b9505e2848a85d87357"
 LAGUNA = dict(batch=1, model="laguna-s-2.1", n_layers=5, vocab_size=12544, experts_held=8)
+MELLUM2 = dict(batch=1, model="mellum2-12b-a2.5b", n_layers=4, seq=16384, vocab_size=12288,
+               experts_held=8)
+# sha256 of the lowered step of mellum2-12b-a2.5b as `mellum2-train-16k` builds it (PR 53: the
+# rehearsal's rung (b)); rung (a), 16 held and a quarter of the vocabulary, lowered to 8e98e744...
+_MELLUM2_STEP = "a0a2e204465b7f4b8138cd94b51485d95ebcd8e36613a481dfaede246ebf0d1d"
 
 
 def test_glm_lite_train_step_lowers_to_the_text_it_had(v5e):
@@ -178,3 +187,39 @@ def test_laguna_share_train_step_sizes_the_expert_layer_by_the_held_rows(v5e):
     assert sorted(ran) == [(3, 3)] * 4 + [(6, 8)] * 4, ran
     assert step.memory.argument_size_in_bytes < 9.07 * 2 ** 30
     assert step.memory.temp_size_in_bytes < 4.00 * 2 ** 30
+
+
+def test_mellum2_train_step_lowers_to_the_text_it_had_over_four_kv_blocks(v5e):
+    """The tenth cell's step, LOWERED for the described chip and not compiled
+    (its compile is 60 s of every core, and the lane has none to spare:
+    ROADMAP D8; the rehearsal's compile is in the configuration file's
+    `reduced`, and the kernels at these shapes compile in
+    tests/test_tpu_compile.py): the text PR 53 lowered, so that a later
+    change to the typed stack, to flash's kv-block rule or to the compact
+    path that means to leave this cell alone shows it here; and what the
+    lowering itself counts: the SAME module as Laguna's (`laguna.attn`),
+    16,384 keys as four kv blocks so that every layer's backward is the dq
+    and the dk/dv kernels apart (`flash.bwd_split` 4, which
+    `fallback_sites.train` books as fallen back: the cell's subject,
+    PERF.md section 6, PR 53), the expert blocks built compact over C =
+    32,768 of 131,072 pair rows with the band for their sum, no site on
+    `ragged_dot`."""
+    from ray_tpu import obs
+
+    sites = ("laguna.attn", "moe.ffn", "grouped_matmul.kernel", "grouped_matmul.ragged_dot",
+             "moe.compact", "moe.full", "flash.bwd_fused", "flash.bwd_split", "moe.sum.linear",
+             "moe.sum.product")
+    count = lambda: {n: obs.layer_counters().get(n, {"count": 0})["count"] for n in sites}  # noqa: E731
+    before = count()
+    step = train_step(v5e, **MELLUM2)
+    assert step.lowered_hash() == _MELLUM2_STEP
+    engaged = {n: c - before[n] for n, c in count().items()}
+    assert engaged["laguna.attn"] >= 4 and engaged["moe.ffn"] >= 4
+    assert engaged["moe.compact"] >= 4 and engaged["moe.full"] == 0
+    assert engaged["grouped_matmul.kernel"] > 0 and engaged["grouped_matmul.ragged_dot"] == 0
+    assert (engaged["flash.bwd_fused"], engaged["flash.bwd_split"]) == (0, 4)
+    assert engaged["moe.sum.linear"] >= 2 and engaged["moe.sum.product"] == 0
+    text = step.lowered_text
+    # 8 held experts' weights and no more, the router's 64 outputs whole, q at one head count
+    assert "8x2304x896x" in text and "64x2304x896x" not in text and "2304x64x" in text
+    assert "1x32x16384x128xbf16" in text and "1x4x16384x128xbf16" in text

@@ -1,6 +1,9 @@
-"""Laguna through the one decoder (PR 39), at a small size on the CPU,
-seeded weights, against the plain reference
-(chipbench/reference/laguna_decoder.py, imported): the attention
+"""The typed stack (models/laguna.py) through the one decoder, at a small
+size on the CPU, seeded weights, each of its two models against its own
+plain reference: Laguna (PR 39; chipbench/reference/laguna_decoder.py)
+and Mellum2 (PR 53; chipbench/reference/mellum2_decoder.py: one head
+count, a norm a head, no gate, yarn on the whole head, no dense layer, no
+shared expert), as cases of the same tests where the stack is shared: the attention
 sublayer of both kinds (the window, YaRN on part of a head, the gate),
 softmax top-k routing with its bias, renormalisation and scaling, the
 whole train path over a dense layer and two periods in loss and
@@ -16,14 +19,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench.reference import laguna_decoder
-from model_cases import LAGUNA, reference_path, seeded_params, train_path, worst_leaf
+from chipbench.reference import laguna_decoder, mellum2_decoder
+from chipbench.tools import mellum2_wrong
+from model_cases import LAGUNA, MELLUM2, reference_path, seeded_params, train_path, worst_leaf
 from ray_tpu.models import laguna, llama, moe
 from ray_tpu.models.registry import config_from_hf
 from ray_tpu.nn import layers as nn_layers
 from ray_tpu.nn.layers import rms_norm
 
 FP32, B, S = LAGUNA.fp32, LAGUNA.batch, LAGUNA.seq
+M_FP32 = MELLUM2.fp32
 
 
 def block_of(params, position, period=0, bias_row=None):
@@ -66,25 +71,69 @@ def test_the_stack_is_cut_into_a_dense_layer_whole_periods_and_a_tail():
     assert params["layers"]["tail"]["1"]["wg"].shape == (64, 6)
 
 
+def test_a_model_of_one_head_count_is_cut_into_periods_by_type_alone():
+    """Mellum2 through the same plan: no dense layer, `heads_per_layer`
+    empty (32 everywhere), the period of four found from the types; the
+    tree has no gate and a norm a head; the counts are ISSUE 53's (its
+    table leaves the 4 x 64 selection biases out)."""
+    full = laguna.plan(laguna.MELLUM2_12B_A2_5B)
+    assert full["dense"] is None and full["periods"] == 7 and not full["tail"]
+    assert full["period"] == [(laguna.SLIDING, 32)] * 3 + [(laguna.FULL, 32)]
+    assert laguna.MELLUM2_12B_A2_5B.num_params() == 12_149_924_864   # 12.15B, "12B"
+    cell = dict(n_layers=4, vocab_size=24576, experts_held=16)
+    rung_a = dataclasses.replace(laguna.MELLUM2_12B_A2_5B, **cell)
+    rung_b = dataclasses.replace(rung_a, vocab_size=12288, experts_held=8)
+    assert laguna.plan(rung_a)["periods"] == 1 and not laguna.plan(rung_a)["tail"]
+    assert rung_a.num_params() - 4 * 64 == 595_154_176
+    assert rung_b.num_params() - 4 * 64 == 340_350_208
+    # 2.44B a token: the name's "A2.5B" (attention, router, 8 experts, both tables)
+    active = dataclasses.replace(laguna.MELLUM2_12B_A2_5B, experts_held=8).num_params()
+    assert round(active / 1e9, 2) == 2.44
+    for cfg in (M_FP32, dataclasses.replace(M_FP32, n_layers=6)):   # a tail of two sliding
+        params = llama.init_params(cfg, jax.random.key(0))
+        axes = jax.tree.map(lambda a: 0, llama.logical_axes(cfg),
+                            is_leaf=lambda x: isinstance(x, tuple))
+        assert jax.tree.structure(params) == jax.tree.structure(axes)
+        assert sum(x.size for x in jax.tree.leaves(params)) == cfg.num_params()
+        assert "dense_layers" not in params
+    assert set(params["layers"]) == {"router_bias", "period", "tail"}
+    block = params["layers"]["period"]["3"]
+    assert "wg" not in block and "shared_up" not in block
+    assert block["wq"].shape == (1, 64, 8 * 16) and block["wk"].shape == (1, 64, 2 * 16)
+    assert block["q_norm"].shape == block["k_norm"].shape == (1, 16)
+    assert params["layers"]["tail"]["1"]["q_norm"].shape == (16,)
+
+
 # -- the attention sublayer against the reference ----------------------------------------
 
 
-@pytest.mark.parametrize("impl", ["xla", "flash"])
-@pytest.mark.parametrize("position,kind,heads", [(0, laguna.SLIDING, 6), (3, laguna.FULL, 4)])
-def test_attention_sublayer_is_the_references(position, kind, heads, impl):
-    """h -> h + gated attention of one kind: the window or not, the whole
-    head rotated or YaRN on half of it, forward and the gradients of the
-    input and of every weight."""
-    cfg = dataclasses.replace(FP32, attention_impl=impl)
-    lp = block_of(seeded_params(LAGUNA, cfg), position)
+@pytest.mark.parametrize("model,position,kind,heads,impl", [
+    (LAGUNA, 0, laguna.SLIDING, 6, "xla"), (LAGUNA, 3, laguna.FULL, 4, "xla"),
+    (LAGUNA, 0, laguna.SLIDING, 6, "flash"), (LAGUNA, 3, laguna.FULL, 4, "flash"),
+    # Mellum2 through the kernels alone: the train path's cases hold its `xla` form whole
+    (MELLUM2, 0, laguna.SLIDING, 8, "flash"), (MELLUM2, 3, laguna.FULL, 8, "flash")],
+    ids=lambda v: getattr(v, "name", None))
+def test_attention_sublayer_is_the_references(model, position, kind, heads, impl):
+    """h -> h + (gated) attention of one kind: the window or not, the whole
+    head rotated or YaRN on half of it (Laguna) or on all of it past its
+    original length (Mellum2), a norm a head or none, groups of 4 query
+    heads a key head, forward and the gradients of the input and of every
+    weight."""
+    cfg = dataclasses.replace(model.fp32, attention_impl=impl)
+    lp = block_of(seeded_params(model, cfg), position)
+    B, S = model.batch, model.seq
     h = jax.random.normal(jax.random.key(3), (B, S, cfg.d_model), jnp.float32)
-    shape = LAGUNA.shape_of(cfg)
+    shape = model.shape_of(cfg)
 
     def program(h, lp):
         return laguna.attention_sublayer(h, rms_norm(h, lp["ln1"], cfg.rms_eps), lp, cfg, kind=kind,
                                          heads=heads, tables=tables_of(cfg, S), segment_ids=None)
 
     def reference(h, lp):
+        if model is MELLUM2:
+            with model.reference_set_up():
+                return jnp.stack([mellum2_decoder.attention(h[b], lp, shape, kind)
+                                  for b in range(B)])
         return jnp.stack([laguna_decoder.attention(h[b], lp, shape, kind, heads) for b in range(B)])
 
     probe = jax.random.normal(jax.random.key(4), h.shape)
@@ -97,7 +146,8 @@ def test_attention_sublayer_is_the_references(position, kind, heads, impl):
         (_, out), got = value_and_grads(program)(h, lp)
         (_, ref), want = value_and_grads(reference)(h, lp)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
-    used = ("ln1", "wq", "wk", "wv", "wg", "wo")
+    used = [k for k in ("ln1", "wq", "wk", "wv", "wg", "q_norm", "k_norm", "wo") if k in lp]
+    assert ("wg" in used) == (model is LAGUNA) and ("q_norm" in used) == (model is MELLUM2)
     worst = worst_leaf({"h": got[0], **{k: got[1][k] for k in used}},
                        {"h": want[0], **{k: want[1][k] for k in used}})
     assert max(worst.values()) < 1e-4, worst
@@ -198,28 +248,76 @@ def test_yarn_is_hfs_function_and_turns_half_a_head():
         rtol=1e-5, atol=1e-5)
 
 
+def test_yarn_on_a_whole_head_past_its_original_length():
+    """Mellum2's full layers: yarn on all 128 channels (64 pairs, where
+    Laguna's full layers turn 32), factor 16 over 8,192, at positions up to
+    16,383: twice the original length, where the plain table would be out
+    of range and `laguna-train` (positions 0-4,095) never looks."""
+    r = laguna.MELLUM2_12B_A2_5B.rope_full
+    assert (r.partial, r.factor, r.original_max) == (1.0, 16.0, 8192)
+    assert r.attention_factor == pytest.approx(0.1 * math.log(16) + 1, rel=1e-12)
+    got = nn_layers.yarn_inv_freq(128, r.theta, r.factor, r.original_max, r.beta_fast, r.beta_slow)
+    np.testing.assert_array_equal(got, mellum2_decoder.yarn_parameters(128, 5e5, 16, 8192, 32, 1))
+    pair = lambda turns: 128 * math.log(8192 / (turns * 2 * math.pi)) / (2 * math.log(5e5))  # noqa: E731
+    low, high = math.floor(pair(32)), math.ceil(pair(1))
+    assert (low, high) == (18, 35)   # twice Laguna's 9 and 18: the ramp is by the pair's share
+    base = 1.0 / 5e5 ** (np.arange(0, 128, 2, dtype=np.float32) / 128)
+    np.testing.assert_allclose(got[:low + 1], base[:low + 1], rtol=1e-6)       # extrapolated: kept
+    np.testing.assert_allclose(got[high:], base[high:] / 16, rtol=1e-6)        # interpolated
+    # the ramp computed on 64 channels (the one-thing-wrong table's row) is another table
+    half = mellum2_wrong.yarn_ramp_on_half_the_head(128, 5e5, 16, 8192, 32, 1)
+    assert np.abs(half / got - 1).max() > 0.5
+    # the program's tables at the cell's positions are the reference's, to float32's rounding
+    # of an angle of thousands of radians, and not the plain rotary's
+    pos = jnp.arange(16384)
+    cos, sin = r.tables(128, pos)
+    ref_cos, ref_sin = mellum2_decoder.rope_tables(
+        MELLUM2.shape_of(laguna.MELLUM2_12B_A2_5B)["rope_parameters"][laguna.FULL], 128, 16384)
+    assert cos.shape == (1, 16384, 64)
+    np.testing.assert_allclose(np.asarray(cos[0]), np.asarray(ref_cos), atol=2e-3)
+    np.testing.assert_allclose(np.asarray(sin[0]), np.asarray(ref_sin), atol=2e-3)
+    plain_cos, _ = laguna.MELLUM2_12B_A2_5B.rope_sliding.tables(128, pos)
+    late = np.abs(np.asarray(cos[0, 8192:, high:]) - np.asarray(plain_cos[0, 8192:, high:]))
+    assert late.max() > 0.25   # the interpolated pairs turn 16 times slower
+    np.testing.assert_allclose(np.asarray(cos[0, :, 0]) ** 2 + np.asarray(sin[0, :, 0]) ** 2,
+                               r.attention_factor ** 2, rtol=1e-5)
+    # all 128 channels turn: nothing passes through
+    x = jax.random.normal(jax.random.key(0), (1, 2, 16, 128))
+    c, s = cos[:, None, 9000:9016], sin[:, None, 9000:9016]
+    lo, hi = x[..., :64], x[..., 64:]
+    literal = jnp.concatenate([lo * c - hi * s, hi * c + lo * s], -1)
+    np.testing.assert_allclose(np.asarray(nn_layers.rotate_head_major(x, c[:, 0], s[:, 0])),
+                               np.asarray(literal), rtol=1e-6, atol=1e-6)
+
+
 # -- the router -----------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("bias", [0.0, 0.05], ids=["zero_bias", "random_bias"])
-def test_softmax_routing_bias_renormalisation_and_scaling(bias):
-    """moe_ffn on a Laguna-kind block against the reference's expert half:
-    top-k of p + b, weights 2.5 x p / sum p, the shared expert; and the
-    old softmax configurations keep what they had."""
-    params = seeded_params(LAGUNA, FP32, bias=bias)
+@pytest.mark.parametrize("model,bias", [(LAGUNA, 0.0), (LAGUNA, 0.05), (MELLUM2, 0.05)],
+                         ids=["laguna-zero_bias", "laguna-random_bias", "mellum2-random_bias"])
+def test_softmax_routing_bias_renormalisation_and_scaling(model, bias):
+    """moe_ffn on a block of either kind against its reference's expert
+    half: top-k of p + b, weights 2.5 x p / sum p and the shared expert
+    (Laguna) or p / sum p and none (Mellum2); and the old softmax
+    configurations keep what they had."""
+    FP32, B, S, decoder = model.fp32, model.batch, model.seq, model.reference
+    params = seeded_params(model, FP32, bias=bias)
     lp = block_of(params, 1, bias_row=1)
     x = jax.random.normal(jax.random.key(5), (B, S, FP32.d_model), jnp.float32)
-    shape = LAGUNA.shape_of(FP32)
+    shape = model.shape_of(FP32)
+    assert (FP32.shared_d_ff, FP32.routed_scaling) == ((32, 2.5) if model is LAGUNA else (0, 1.0))
     with jax.default_matmul_precision("highest"):
         out, stats, _ = moe.moe_ffn(x, lp, FP32)
         want = []
         for b in range(B):
-            weights = laguna_decoder.route(x[b], lp, shape)
-            routed = sum(weights[:, e:e + 1] * laguna_decoder._swiglu(
+            weights = decoder.route(x[b], lp, shape)
+            routed = sum(weights[:, e:e + 1] * decoder._swiglu(
                 x[b], lp["w_gate"][e], lp["w_up"][e], lp["w_down"][e])
                 for e in range(FP32.n_experts))
-            want.append(routed + laguna_decoder._swiglu(
-                x[b], lp["shared_gate"], lp["shared_up"], lp["shared_down"]))
+            if FP32.shared_d_ff:
+                routed = routed + decoder._swiglu(
+                    x[b], lp["shared_gate"], lp["shared_up"], lp["shared_down"])
+            want.append(routed)
             np.testing.assert_allclose(np.asarray(weights.sum(-1)), FP32.routed_scaling, rtol=1e-5)
             assert ((weights > 0).sum(-1) == FP32.top_k).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(jnp.stack(want)), rtol=2e-5, atol=2e-5)
@@ -232,15 +330,23 @@ def test_softmax_routing_bias_renormalisation_and_scaling(bias):
 # -- the whole train path ----------------------------------------------------------------
 
 
-@pytest.mark.parametrize("bias", [0.0, 0.05], ids=["zero_bias", "random_bias"])
-@pytest.mark.parametrize("held", [None, (4, 8)], ids=["all_experts", "a_share"])
-def test_train_path_meets_the_reference_in_loss_and_gradients(held, bias):
-    """llama.loss_fn (the one train path) on the dense layer and two
-    periods of four against the plain reference: the loss, the tokens per
-    expert of every block, and every gradient by its worst leaf."""
+@pytest.mark.parametrize("model,held,bias", [
+    (LAGUNA, None, 0.0), (LAGUNA, None, 0.05), (LAGUNA, (4, 8), 0.0), (LAGUNA, (4, 8), 0.05),
+    # Mellum2's one: a share under a random table (its published forward whole is held in loss
+    # by the contract's bf16 case and in every mechanism by the one-thing-wrong table below;
+    # each more case compiles the stack's scan and the reference's eight layers again)
+    (MELLUM2, (4, 8), 0.05)],
+    ids=lambda v: getattr(v, "name", None) or {None: "all_experts", (4, 8): "a_share",
+                                               0.0: "zero_bias", 0.05: "random_bias"}[v])
+def test_train_path_meets_the_reference_in_loss_and_gradients(model, held, bias):
+    """llama.loss_fn (the one train path) on two periods of four (under
+    Laguna's dense layer) against the plain reference: the loss, the
+    tokens per expert of every block, and every gradient by its worst
+    leaf."""
+    FP32, B, S = model.fp32, model.batch, model.seq
     cfg = FP32 if held is None else dataclasses.replace(
         FP32, experts_held=held[0], first_expert_held=held[1])
-    ours, theirs = train_path(LAGUNA, cfg, bias), reference_path(LAGUNA, cfg, bias)
+    ours, theirs = train_path(model, cfg, bias), reference_path(model, cfg, bias)
     loss, weight, stats, ref = ours.loss, ours.weight, ours.stats, theirs.parts
     assert float(weight) == B * S
     assert float(loss) == pytest.approx(float(ref["loss"]), rel=2e-6)
@@ -311,6 +417,82 @@ def test_thirty_two_shares_add_up_to_the_uncut_layer():
         assert stats["tokens_per_expert"].tolist() == counts.tolist()
         assert int(stats["pairs_elsewhere"]) == int(counts.sum() - counts[first:first + 2].sum())
         assert int(stats["dropped_pairs"]) == 0
+
+
+def test_the_eight_shares_of_mellum2s_layer_add_up_to_the_uncut_layer():
+    """The cell's deployment (ISSUE 53's rung (b)), small: 64 experts, top-8
+    softmax with a bias, weights renormalised, no shared expert, over 8
+    chips of 8 experts (rung (a)'s 16 of 64 would sit on `held_rows_bound`'s
+    own edge, 2 C = the pairs, and still be built compact). The router is
+    computed alike on every chip and counted ONCE; the shares' outputs sum
+    to the uncut layer's, and so do the gradients of the input; every
+    share counts what the uncut layer counts."""
+    whole, held = dataclasses.replace(M_FP32, n_experts=64, top_k=8), 8
+    B, S = MELLUM2.batch, MELLUM2.seq
+    lp = jax.tree.map(lambda w: w[0], moe.expert_params(dataclasses.replace(whole, n_layers=1),
+                                                        jax.random.key(2)))
+    lp["router_bias"] = 0.01 * jax.random.normal(jax.random.key(3), (64,))
+    x = jax.random.normal(jax.random.key(5), (B, S, whole.d_model), jnp.float32)
+    experts = ("w_gate", "w_up", "w_down")
+    assert moe.held_rows_bound(B * S * 8, 8, 64) == 512   # C: 2 x a uniform router's 128 rows
+    assert moe.held_rows_bound(B * S * 8, 16, 64) == B * S * 8 // 2
+    assert moe.held_rows_bound(16384 * 8, 8, 64) == 32768   # the cell's C
+
+    def run(cfg, lp):
+        out, vjp, stats = jax.vjp(lambda x: moe.moe_ffn(x, lp, cfg)[:2], x, has_aux=True)
+        return out, vjp(jnp.ones_like(out))[0], stats
+
+    def share(first):
+        cfg = dataclasses.replace(whole, experts_held=held, first_expert_held=first)
+        return run(cfg, {**lp, **{k: lp[k][first:first + held] for k in experts}})
+
+    with jax.default_matmul_precision("highest"):
+        full = run(whole, lp)
+        router_only = run(whole, {**lp, "w_down": jnp.zeros_like(lp["w_down"])})
+        shares = [share(first) for first in range(0, 64, held)]
+    n = len(shares)
+    np.testing.assert_allclose(sum(np.asarray(r[0]) for r in shares), np.asarray(full[0]),
+                               rtol=2e-5, atol=2e-5)
+    # the router's own path to x is in every share's gradient: counted once
+    np.testing.assert_allclose(sum(np.asarray(r[1]) for r in shares)
+                               - (n - 1) * np.asarray(router_only[1]), np.asarray(full[1]),
+                               rtol=2e-5, atol=2e-5)
+    counts = full[2]["tokens_per_expert"]
+    assert int(counts.sum()) == 8 * B * S
+    for first, (_, _, stats) in zip(range(0, 64, held), shares):
+        assert stats["tokens_per_expert"].tolist() == counts.tolist()
+        assert int(stats["pairs_elsewhere"]) == int(counts.sum() - counts[first:first + held].sum())
+        assert int(stats["dropped_pairs"]) == 0 and "compact" in stats
+
+
+@pytest.mark.parametrize("wrong", list(mellum2_wrong.VARIANTS))
+def test_one_thing_wrong_moves_the_tiny_loss_or_gradient(wrong):
+    """The cell's one-thing-wrong table (chipbench/tools/mellum2_wrong.py:
+    the same patches of the reference), at the tiny size in float32 over
+    one period: each row moves the loss or some leaf's gradient beyond the
+    train path's own tolerances (2e-6 and 2e-4, which the program meets:
+    `test_train_path_meets_the_reference_in_loss_and_gradients`), so a
+    program that computed so would fail here."""
+    cfg = dataclasses.replace(M_FP32, n_layers=4)
+    sound = reference_path(MELLUM2, cfg)
+    params, batch, shape = seeded_params(MELLUM2, cfg), MELLUM2.batch_of(cfg), MELLUM2.shape_of(cfg)
+
+    def f(p):   # a function a case: `jax.jit` keeps one trace a function, patched or not
+        return mellum2_decoder.loss_parts(p, batch["tokens"], batch["targets"], shape)["loss"]
+
+    with mellum2_wrong.VARIANTS[wrong](), MELLUM2.reference_set_up(), \
+            jax.default_matmul_precision("highest"):
+        moved = abs(float(jax.jit(f)(params)) / float(sound.parts["loss"]) - 1)
+        if wrong not in mellum2_wrong.PRECISION_ONLY:
+            # a mechanism is no rounding: it moves the loss a hundred tolerances (2.6e-4 to
+            # 1.5e-2 here), and the forward alone says so
+            assert moved > 2e-4, moved
+            return
+        # a precision may leave the loss where it was by luck (3.6e-7 here): the gradient
+        grads = jax.jit(jax.grad(f))(params)
+    worst = max(worst_leaf(jax.tree.map(lambda g: g.astype(jnp.float32), grads),
+                           sound.grads).values())
+    assert moved > 2e-6 or worst > 2e-4, (moved, worst)
 
 
 # -- the registry ------------------------------------------------------------------

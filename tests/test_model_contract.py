@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from model_cases import (GLM_LITE, KEYE, LAGUNA, MODELS, NEMOTRON_H, OLMO_HYBRID, Model,
+from model_cases import (GLM_LITE, KEYE, LAGUNA, MELLUM2, MODELS, NEMOTRON_H, OLMO_HYBRID, Model,
                          catalog_config, seeded_params, train_path)
 from ray_tpu.models import llama
 from ray_tpu.models.registry import config_from_hf, get_model_config
@@ -29,7 +29,8 @@ def test_remat_gives_the_same_gradients(model, remat_policy):
     the plain one's, on the parameters the model's own file gave this test
     before it was one: the selection biases a random table at the row's
     `remat_bias` (ZAYA1 0.05, GLM-4.7-Flash 0.1, Laguna 0.05 over the
-    dense layer and one period, Keye 0), at the model's own tolerance.
+    dense layer and one period, Mellum2 0.05 over one period, Keye 0), at
+    the model's own tolerance.
     The plain gradients are made once for both policies, and where the
     bias is the model's own they are its train-path test's too."""
     plain = dataclasses.replace(model.fp32, **model.remat_plain)
@@ -42,8 +43,8 @@ def test_remat_gives_the_same_gradients(model, remat_policy):
 
 @by_name
 def test_bf16_compute_stays_near_the_reference(model):
-    """The loss in bfloat16 (Laguna's and Keye's through the flash
-    kernels, interpreted) against the plain reference's on the same
+    """The loss in bfloat16 (Laguna's, Mellum2's and Keye's through the
+    flash kernels, interpreted) against the plain reference's on the same
     bfloat16 parameters."""
     cfg = dataclasses.replace(model.fp32, dtype=jnp.bfloat16, **model.bf16)
     params, batch = seeded_params(model, cfg), model.batch_of(cfg)
@@ -78,6 +79,19 @@ def test_config_from_hf_maps_the_catalogs_config_onto_the_preset(model):
     (LAGUNA, "attention_bias", True, "attention_bias"),
     (LAGUNA, "num_attention_heads_per_layer", list(range(48, 96)), "never repeat"),
     (LAGUNA, "mlp_layer_types", ["dense", "sparse", "dense"] + ["sparse"] * 45, "dense layer after"),
+    (MELLUM2, "gating", "per-head", "gating 'per-head' .an output gate."),
+    (MELLUM2, "attention_bias", True, "attention_bias"),
+    (MELLUM2, "mlp_layer_types", ["sparse", "dense"] + ["sparse"] * 26, "dense layer after"),
+    (MELLUM2, "rope_parameters", {"full_attention": {"rope_type": "llama3", "rope_theta": 5e5},
+                                  "sliding_attention": {"rope_type": "default", "rope_theta": 5e5}},
+     "mellum config with rope_type 'llama3'"),
+    (MELLUM2, "layer_types", ["sliding_attention", "linear_attention"] * 14,
+     "layer_types other than full_attention / sliding_attention"),
+    (MELLUM2, "layer_types", ["full_attention"] * 14 + ["sliding_attention"] * 14, "never repeat"),
+    (MELLUM2, "use_sliding_window", False, "use_sliding_window false"),
+    (MELLUM2, "num_attention_heads_per_layer", [32] * 28, "num_attention_heads_per_layer"),
+    (MELLUM2, "shared_expert_intermediate_size", 896, "a shared expert"),
+    (MELLUM2, "hidden_act", "gelu", "hidden_act 'gelu'"),
     (KEYE, "vision_config", {"depth": 27}, "vision tower or image / video inputs .vision_config."),
     (KEYE, "image_token_id", 151655, "image_token_id"),
     (KEYE, "sa_config", None, "no sa_config"),

@@ -58,6 +58,36 @@ def test_flash_attention_compiles_for_v5e(v5e, grad, S):
              sharding=one_chip(v5e))
 
 
+@pytest.mark.parametrize("window", [1024, None], ids=["window_1024", "full"])
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
+def test_flash_attention_compiles_at_16384_keys_over_four_kv_blocks(v5e, grad, window):
+    """`mellum2-train-16k`'s shape (PR 53): 32 / 4 heads of 128, ONE
+    sequence of 16,384 keys. A k block of the whole sequence would be 4 MiB,
+    over `KV_BLOCK_BYTES`: the kv block is `MAX_BLOCK_K`, four of them, one
+    head a program (groups of 8 at a kv block of 4,096 fold no further),
+    and the backward is the dq and the dk/dv kernels apart, three kernels
+    in all; under the window the index maps clamp to the blocks that hold
+    a visible key (`_kv_block_of`, `_q_block_of`), which the interpreter
+    takes on trust and Mosaic does not."""
+    from ray_tpu.ops import flash
+
+    assert flash.default_block_k(16384, 128, 2) == flash.MAX_BLOCK_K == 4096
+    assert flash.default_block_k(8192, 128, 2) == 8192
+    assert flash._fold_factor(8, 512, 4096, None) == 1
+
+    def fwd(q, k, v):
+        return flash.flash_attention_head_major(q, k, v, causal=True, window=window)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    with mock.patch("jax.default_backend", return_value="tpu"):   # flash's interpret switch
+        hlo = compile_kernel(bwd if grad else fwd, ((1, 32, 16384, 128), _BF16),
+                             ((1, 4, 16384, 128), _BF16), ((1, 4, 16384, 128), _BF16),
+                             sharding=one_chip(v5e))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == (3 if grad else 1)
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
 def test_flash_attention_compiles_at_heads_of_256_with_the_kernels_own_vmem(v5e, grad):
     """MLA's shape (models/mla.py): 20 heads of 256, none shared, 4096
@@ -214,9 +244,11 @@ def test_train_step_asks_for_vmem_only_of_a_chip_it_knows(monkeypatch):
     (8192, 8, 4096, 14336), (8192, 8, 14336, 4096), (768, 4, 384, 128),
     (32768, 8, 2048, 1536), (32768, 8, 1536, 2048),
     # Nemotron-H's experts of 1,856 = 14.5 lane tiles, a block of every kernel whole (PR 49)
-    (6144, 8, 2688, 1856), (6144, 8, 1856, 2688)],
+    (6144, 8, 2688, 1856), (6144, 8, 1856, 2688),
+    # Mellum2's experts: 2,048 rows each of 8 held, K 2304 / N 896 = 18 and 7 lane tiles (PR 53)
+    (32768, 8, 2304, 896), (32768, 8, 896, 2304)],
     ids=["mixtral_up", "mixtral_down", "rows_in_tiles_of_256", "glm_lite_up", "glm_lite_down",
-         "relu2_up_1856", "relu2_down_1856"])
+         "relu2_up_1856", "relu2_down_1856", "mellum2_up_896", "mellum2_down_896"])
 def test_grouped_matmul_kernels_compile_wherever_the_tile_rule_accepts(v5e, P, E, K, N):
     """The three kernels of ops/grouped_matmul.py at shapes other than
     the cell's: Mixtral-8x7B's widths, where the contraction or the
