@@ -22,6 +22,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import time
+import zlib
 from collections import deque
 from typing import Any, Optional
 
@@ -903,7 +904,7 @@ class LLMEngine:
         req.trace = trace or trace_context.current() or trace_context.new_context()
         req.t_queue_start = req.arrival
         key = self._root_key if sp.seed is None else jax.random.key(sp.seed)
-        req._key = jax.random.fold_in(key, hash(rid) & 0x7FFFFFFF)
+        req._key = jax.random.fold_in(key, zlib.crc32(str(rid).encode()) & 0x7FFFFFFF)
         self.requests[rid] = req
         self.waiting.append(req)
         if self.kvfetch is not None:

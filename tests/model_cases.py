@@ -1,5 +1,7 @@
 """What the model files (tests/test_zaya.py, test_glm_lite.py,
-test_laguna.py (Laguna and Mellum2: one stack), test_keye.py, test_olmo_hybrid.py, test_moe.py) and
+test_laguna.py (Laguna and Mellum2: one stack), test_keye.py,
+test_olmo_hybrid.py, test_nemotron_h.py, test_moe.py), the files of their
+train paths (tests/test_contract_<model>.py) and
 tests/test_model_contract.py share. No test lives here (pytest does not
 collect the file).
 
@@ -7,11 +9,16 @@ A model is a row of `MODELS`: its tiny preset in float32, its plain
 reference, its `shape_of` (the reference reads the configuration file's
 key names) and the names of the leaves that `init_params` leaves at one
 or zero. Everything else is written once: the tokens, the seeded
-parameters, the worst leaf of two gradient trees, and the train path and
-the reference's taken ONCE a process for one configuration, under
-`jax.jit` (`train_path`, `reference_path`: a whole-model gradient taken
-bare is traced operation by operation, four to five times the seconds).
-The next model costs a row and a `shape_of`."""
+parameters, the worst leaf of two gradient trees, the train path and the
+reference's taken ONCE a process for one configuration, under `jax.jit`
+(`train_path`, `reference_path`: a whole-model gradient taken bare is
+traced operation by operation, four to five times the seconds), and the
+contract's two cases that compile that path (`contract_cases`). Under
+`--dist loadfile` a process is a FILE, so whatever reads a
+configuration's path stands in one file, tests/test_contract_<model>.py:
+the model's train path against its reference, what else reads its
+statistics or gradients, and the row's remat and bf16 cases. The next
+model costs a row, a `shape_of` and such a file."""
 
 import contextlib
 import dataclasses
@@ -25,6 +32,7 @@ from unittest import mock
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from chipbench.reference import (glm_lite_decoder, keye_decoder, laguna_decoder, mellum2_decoder,
                                  nemotron_h_decoder, olmo_hybrid_decoder, zaya_decoder)
@@ -68,10 +76,12 @@ def worst_leaf(got, want, skip=("router_bias",)) -> dict:
         for k in path:
             w = w[k.key]
         name = jax.tree_util.keystr(path)
+        # on the host: jax.numpy taken bare compiles each operation for each leaf's shape
+        g, w = np.asarray(g), np.asarray(w)
         if any(s in name for s in skip):
-            assert float(jnp.abs(g).max()) == 0.0 and float(jnp.abs(w).max()) == 0.0
+            assert not g.any() and not w.any()
             continue
-        worst[name] = float(jnp.abs(g - w).max()) / max(float(jnp.abs(w).max()), 1e-12)
+        worst[name] = float(np.abs(g - w).max()) / max(float(np.abs(w).max()), 1e-12)
     return worst
 
 
@@ -110,6 +120,9 @@ class Model:
 
 @functools.lru_cache(maxsize=None)
 def _seeded(model: Model, cfg, bias, seed):
+    # taken bare on purpose: the tree is made operation by operation, each compiled once a
+    # shape and PROCESS, so a file's second configuration costs 0.2 s where the first cost
+    # 5-28; under `jax.jit` every configuration is a program of its own (13 s for GLM's)
     params = llama.init_params(cfg, jax.random.key(seed))
     keys = iter(jax.random.split(jax.random.key(seed + 100), model.n_keys))
     for tree, scales in model.norms(params):
@@ -141,7 +154,11 @@ def _once_a_configuration(made):
 def train_path(model: Model, cfg, bias) -> types.SimpleNamespace:
     """llama.loss_and_weight_fn (the one train path) on the model's seeded
     parameters and batch: loss, weight, stats and every gradient, from
-    one jitted program at the matmuls' highest precision."""
+    one jitted program at the matmuls' highest precision. Once a
+    configuration and bias a PROCESS: its callers (a model's train-path
+    case, whatever reads that path's statistics, the remat cases' plain
+    side) stand in one file, tests/test_contract_<model>.py, because a
+    second file is a second process and compiles it again."""
     params, batch = seeded_params(model, cfg, bias), model.batch_of(cfg)
 
     def f(p):
@@ -168,6 +185,52 @@ def reference_path(model: Model, cfg, bias) -> types.SimpleNamespace:
     with model.reference_set_up(), jax.default_matmul_precision("highest"):
         (_, parts), grads = jax.jit(jax.value_and_grad(f, has_aux=True))(params)
     return types.SimpleNamespace(parts=parts, grads=grads)
+
+
+def contract_cases(*models) -> tuple:
+    """(test_remat_gives_the_same_gradients,
+    test_bf16_compute_stays_near_the_reference) for these rows: the
+    contract's two cases that compile the train path, made here once and
+    collected by the file that holds the rows' train-path cases
+    (tests/test_contract_<model>.py), so that one process compiles a
+    configuration's plain path for both."""
+    by_name = pytest.mark.parametrize("model", models, ids=lambda m: m.name)
+
+    @pytest.mark.parametrize("remat_policy", ["dots", "full"])
+    @by_name
+    def test_remat_gives_the_same_gradients(model, remat_policy):
+        """The loss and every gradient of the rematerialised train path are
+        the plain one's, on the parameters the model's own file gave this test
+        before it was one: the selection biases a random table at the row's
+        `remat_bias` (ZAYA1 0.05, GLM-4.7-Flash 0.1, Laguna 0.05 over the
+        dense layer and one period, Mellum2 0.05 over one period, Keye 0), at
+        the model's own tolerance.
+        The plain gradients are made once for both policies, and where the
+        configuration and the bias are its train-path case's they are that
+        case's too."""
+        plain = dataclasses.replace(model.fp32, **model.remat_plain)
+        cfg = dataclasses.replace(plain, remat=True, remat_policy=remat_policy)
+        want = train_path(model, plain, model.remat_bias)
+        got = train_path(model, cfg, model.remat_bias)
+        assert float(got.loss) == pytest.approx(float(want.loss), rel=1e-6)
+        for g, w in zip(jax.tree.leaves(got.grads), jax.tree.leaves(want.grads)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), **model.remat_tol)
+
+    @by_name
+    def test_bf16_compute_stays_near_the_reference(model):
+        """The loss in bfloat16 (Laguna's, Mellum2's and Keye's through the
+        flash kernels, interpreted) against the plain reference's on the same
+        bfloat16 parameters."""
+        cfg = dataclasses.replace(model.fp32, dtype=jnp.bfloat16, **model.bf16)
+        params, batch = seeded_params(model, cfg), model.batch_of(cfg)
+        loss = jax.jit(lambda p: llama.loss_fn(p, batch, cfg))(params)
+        shape = model.shape_of(cfg)
+        with model.reference_set_up():   # one program: bare, each of its operations is compiled alone
+            ref = jax.jit(lambda p: model.reference.loss(p, batch["tokens"], batch["targets"],
+                                                         shape))(params)
+        assert float(loss) == pytest.approx(float(ref), rel=model.bf16_rel)
+
+    return test_remat_gives_the_same_gradients, test_bf16_compute_stays_near_the_reference
 
 
 def catalog_config(model: Model) -> dict:

@@ -331,15 +331,28 @@ def test_overload_sheds_429_with_retry_after_then_drains_503(serve_instance):
     model_id = "tiny-chaos-overload"
     llm = LLMConfig(
         model_id=model_id,
-        engine=_tiny_engine_config(max_num_seqs=2),
+        # one token a round, the plain decode: on the engine's default, pipelined path
+        # this flood cannot be made to fill the queue from a test. A round sleeps holding
+        # the runner's lock, which the admission check takes too, and the loop takes the
+        # lock back as soon as it has let it go, so arrivals are admitted about as fast as
+        # requests END: with chunks of up to 64 tokens, of one token (CHUNK_BUCKETS patched
+        # to (1,)), with requests of 24, 48 or 384 tokens and rounds of 0.1 or 0.2 s the
+        # first 429 came after 6.6-111 s and in three of eight runs not in 120 s (PR 54's
+        # runs; ROADMAP D8 has what the server needs for it). A token a round and a
+        # delivery a round the checks come in about one a round, against requests of 24
+        # rounds: the queue is full within seconds
+        engine=_tiny_engine_config(max_num_seqs=2, pipeline_decode=False, decode_chunk=1),
         admission=AdmissionConfig(max_queue_depth=3),
     )
     handle = build_openai_app(llm, name="chaos_overload", route_prefix=None)
-    # slow each engine round deterministically so the flood builds a real
-    # queue instead of racing the scheduler (0.2s/round + a gated 24-wide
-    # burst: under machine load a 16-wide/0.02s burst sometimes drained
-    # without ever exceeding max_queue_depth=3 — a flaky acceptance gate;
-    # at 0.2s/round the engine cannot drain inside the burst window)
+    # slow each engine round so that an accepted request holds its slot for
+    # seconds (24 tokens at 0.2 s a round and a token a round), and flood
+    # until a request HAS been shed (the event this test means) rather than
+    # with one burst that has to win a race: a round sleeps holding the
+    # runner's lock, which the admission check takes too, so the checks
+    # come in about one a round, and with chunks of 64 tokens a round the
+    # queue never reached max_queue_depth=3: "overload never shed"
+    # (ROADMAP D8's flaky list)
     chaos.install(chaos.FaultSchedule(5, [
         chaos.FaultSpec(chaos.DELAY_RPC, site="llm.engine.step",
                         delay_s=0.2),
@@ -351,24 +364,34 @@ def test_overload_sheds_429_with_retry_after_then_drains_503(serve_instance):
     import threading as _threading
 
     start_gate = _threading.Barrier(24, timeout=60)
+    shed = _threading.Event()
+    deadline = time.monotonic() + 120
 
     def one(i):
-        if i < 24:  # the flood; later singles (post-drain probe) skip the gate
-            start_gate.wait()
-        # 48 tokens at 0.2s/round: accepted requests occupy the engine for
-        # seconds, so the queue cannot drain mid-burst however the GIL
-        # staggers the arrivals — shedding is structural, not a race win
         return handle.options(method_name="completions").remote(
-            {"prompt": f"p{i}", "max_tokens": 48 if i < 24 else 4,
+            {"prompt": f"p{i}", "max_tokens": 24 if i < 24 else 4,
              "temperature": 0.0}
         ).result(timeout_s=180)
 
+    def flood(i):
+        """Submit again and again until SOME submitter has been shed: 24
+        submitters against two slots and a queue of three fill the queue
+        however the arrivals are staggered, and an accepted request holds
+        its submitter until it is served."""
+        start_gate.wait()
+        mine = []
+        while not shed.is_set() and time.monotonic() < deadline:
+            mine.append(one(i))
+            if mine[-1].get("error", {}).get("code") == 429:
+                shed.set()
+        return mine
+
     with concurrent.futures.ThreadPoolExecutor(24) as ex:
-        outs = list(ex.map(one, range(24)))
+        outs = [o for mine in ex.map(flood, range(24)) for o in mine]
     chaos.uninstall()
+    assert shed.is_set(), "overload never shed in 120 s of 24 submitters"
     accepted = [o for o in outs if "choices" in o]
     rejected = [o for o in outs if o.get("error", {}).get("code") == 429]
-    assert rejected, "overload never shed"
     assert accepted, "everything shed"
     for o in rejected:
         assert o["error"]["type"] == "rate_limit_error"

@@ -13,11 +13,18 @@ from ray_tpu.dag import CompiledDAG, InputNode, MultiOutputNode
 from ray_tpu.dag.nodes import allreduce_bind
 
 
-@pytest.fixture(autouse=True)
+@pytest.fixture(autouse=True, scope="module")
 def rt():
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(num_cpus=32)
+    """A runtime of the file's own, shut down with it. Under `--dist
+    loadfile` an earlier file may have left one on this worker with fewer
+    CPUs than this file's actors take and with its own actors still
+    holding them (ROADMAP D8's flaky list:
+    `test_execute_overflow_raises_not_deadlocks`, `test_teardown_frees_actor`)."""
+    if ray_tpu.is_initialized():
+        ray_tpu.shutdown()
+    ray_tpu.init(num_cpus=32)
     yield
+    ray_tpu.shutdown()
 
 
 @ray_tpu.remote

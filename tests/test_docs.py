@@ -1,5 +1,7 @@
 """The documents name only files that exist: a path a PR deletes and
-leaves cited in README.md or PERF.md fails here."""
+leaves cited in README.md or PERF.md fails here, and so does a test file
+a PR moves and leaves cited in ROADMAP.md or in pytest.ini's comment
+(their `tests/*.py` alone: the roadmap also tells of files that went)."""
 
 import os
 import re
@@ -25,10 +27,18 @@ def cited_paths(text):
                 yield word
 
 
-@pytest.mark.parametrize("doc", ["README.md", "PERF.md"])
-def test_every_cited_path_exists(doc):
+def cited_test_files(text):
+    """`tests/<...>.py` wherever it stands, back-quoted or in a comment."""
+    return re.findall(r"\btests/[\w/]+\.py\b", text)
+
+
+@pytest.mark.parametrize("doc,cited", [
+    pytest.param(doc, cited, id=doc) for doc, cited in (
+        ("README.md", cited_paths), ("PERF.md", cited_paths),
+        ("ROADMAP.md", cited_test_files), ("pytest.ini", cited_test_files))])
+def test_every_cited_path_exists(doc, cited):
     with open(os.path.join(REPO, doc)) as f:
-        paths = sorted(set(cited_paths(f.read())))
+        paths = sorted(set(cited(f.read())))
     assert paths, f"{doc} cites no path: the pattern has rotted"
     # a bare name is a root script, or a module of a directory the
     # sentence has named

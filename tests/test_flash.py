@@ -10,8 +10,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.ops.attention import xla_attention
-from ray_tpu.ops.flash import flash_attention
+from ray_tpu.ops import attention as _attention
+from ray_tpu.ops import flash as _flash
+
+# One program a shape and set of options for the composite and for the kernels' wrapper:
+# taken bare, every operation of the composite (and of its gradient) is compiled alone for
+# each case's shapes, which was most of this file's seconds. The options are static, as the
+# models pass them; `test_flash_under_jit_and_grad_jit` is the jit of a caller's own.
+_MASK_OPTIONS = ("causal", "q_offset", "softmax_scale", "window")
+xla_attention = jax.jit(_attention.xla_attention, static_argnames=_MASK_OPTIONS)
+flash_attention = jax.jit(_flash.flash_attention, static_argnames=_MASK_OPTIONS + (
+    "block_q", "block_k", "interpret", "fold_heads", "return_lse"))
 
 
 def make_qkv(key, B, Sq, Sk, H, KVH, D, dtype=jnp.float32):
@@ -33,7 +42,7 @@ def make_qkv(key, B, Sq, Sk, H, KVH, D, dtype=jnp.float32):
         # the kv block walked in 512-wide sub-tiles, in one program some below the
         # diagonal, some crossed by it, some skipped: heads of 64, 128 (two heads folded), 256
         (1, 1536, 2, 1, 64, True, 512, None),
-        (1, 2048, 4, 2, 128, True, 512, None),
+        (1, 1536, 4, 2, 128, True, 512, None),
         (1, 1536, 2, 2, 256, True, 512, None),
         (1, 1024, 2, 1, 64, True, 256, None),   # two q blocks a sub-tile: the diagonal crosses it twice
         # a padded edge inside a sub-tile (keys 1300..1535) and a sub-tile that is all
@@ -95,10 +104,10 @@ def test_q_offset_decode_window(Sq, Sk, off, bq, bk):
     ref = xla_attention(q, k, v, causal=True, q_offset=off)
     out = flash_attention(q, k, v, causal=True, q_offset=off, block_q=bq, block_k=bk)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
-    g_ref = jax.grad(lambda *a: jnp.sum(xla_attention(*a, causal=True, q_offset=off) ** 2),
-                     argnums=(0, 1, 2))(q, k, v)
-    g_out = jax.grad(lambda *a: jnp.sum(flash_attention(
-        *a, causal=True, q_offset=off, block_q=bq, block_k=bk) ** 2), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.jit(jax.grad(lambda *a: jnp.sum(xla_attention(*a, causal=True, q_offset=off) ** 2),
+                             argnums=(0, 1, 2)))(q, k, v)
+    g_out = jax.jit(jax.grad(lambda *a: jnp.sum(flash_attention(
+        *a, causal=True, q_offset=off, block_q=bq, block_k=bk) ** 2), argnums=(0, 1, 2)))(q, k, v)
     for a, b, name in zip(g_out, g_ref, "qkv"):
         np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4, err_msg=f"d{name} mismatch")
 
@@ -128,8 +137,8 @@ def test_grads_match_xla(S, H, KVH, D, causal, bq, bk):
             flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk) ** 2
         )
 
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    g_out = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
+    g_out = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
     for a, b, name in zip(g_out, g_ref, "qkv"):
         np.testing.assert_allclose(
             a, b, atol=5e-4, rtol=5e-4, err_msg=f"d{name} mismatch"
@@ -157,8 +166,8 @@ def test_grads_with_segments_and_padding(S, cut, bq, bk):
             ) ** 2
         )
 
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    g_out = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
+    g_out = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
     for a, b, name in zip(g_out, g_ref, "qkv"):
         np.testing.assert_allclose(
             a, b, atol=5e-4, rtol=5e-4, err_msg=f"d{name} mismatch"
@@ -221,8 +230,8 @@ def test_fold_heads_parity(S, bq, nk_blocks):
     np.testing.assert_allclose(o4, o1, atol=1e-6, rtol=1e-6)
     # grads: folding reorders the dk/dv reduction (one wide contraction
     # vs sequential adds) — identical math, f32 rounding differs
-    g1 = jax.grad(loss(1), argnums=(0, 1, 2))(q, k, v)
-    g4 = jax.grad(loss(4), argnums=(0, 1, 2))(q, k, v)
+    g1 = jax.jit(jax.grad(loss(1), argnums=(0, 1, 2)))(q, k, v)
+    g4 = jax.jit(jax.grad(loss(4), argnums=(0, 1, 2)))(q, k, v)
     for a, b, name in zip(g4, g1, "qkv"):
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4,
                                    err_msg=f"d{name} fold mismatch")
@@ -423,7 +432,7 @@ def test_one_kv_block_over_4096_keys_matches_xla(monkeypatch, backwards_traced, 
     want = jax.value_and_grad(lambda *a: (xla_attention(
         *a, causal=True, window=window, segment_ids=segs) * probe).sum(), (0, 1, 2))(q, k, v)
     got = []
-    traced = backwards_traced(lambda: got.append(jax.value_and_grad(lambda *a: (flash_attention(
+    traced = backwards_traced(lambda: got.append(jax.value_and_grad(lambda *a: (flash.flash_attention(
         *a, causal=True, window=window, segment_ids=segs) * probe).sum(), (0, 1, 2))(q, k, v)))
     assert traced == (1, 0)
     assert float(got[0][0]) == pytest.approx(float(want[0]), rel=1e-4, abs=1e-3)

@@ -29,10 +29,12 @@ def _against_xla(shape, *, window, seg=False, sk=None, **kw):
         segs = jnp.broadcast_to((jnp.arange(s) >= s // 3).astype(jnp.int32)
                                 + (jnp.arange(s) >= 2 * s // 3), (b, s))
     xla = {key: kw[key] for key in ("q_offset",) if key in kw}
-    got = jax.value_and_grad(lambda *a: (flash_attention(
-        *a, window=window, segment_ids=segs, **kw) * probe).sum(), (0, 1, 2))(q, k, v)
-    want = jax.value_and_grad(lambda *a: (xla_attention(
-        *a, window=window, segment_ids=segs, **xla) * probe).sum(), (0, 1, 2))(q, k, v)
+    # one program each: taken bare, every operation of the composite's value and gradient is
+    # compiled alone for this case's shapes
+    got = jax.jit(jax.value_and_grad(lambda *a: (flash_attention(
+        *a, window=window, segment_ids=segs, **kw) * probe).sum(), (0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.value_and_grad(lambda *a: (xla_attention(
+        *a, window=window, segment_ids=segs, **xla) * probe).sum(), (0, 1, 2)))(q, k, v)
     assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-4, abs=1e-3)
     for g, w in zip(got[1], want[1]):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-3, atol=2e-4)

@@ -285,13 +285,26 @@ def test_noisy_neighbor_paying_tenant_green():
                 time.sleep(0.002)
             return False
 
+        def preempted_batch():
+            return any(k[1] == "batch" and k[2] == "priority"
+                       for k in preemption_counter().series())
+
         try:
-            for _ in range(3):
+            # at least three paying requests, and on until one of them HAS
+            # preempted a batch row (the event this test means), under a
+            # deadline: between `saturated()` and the paying request's
+            # admission a decode chunk can finish both batch rows, and then
+            # that request preempts nothing however the host is loaded
+            deadline = time.monotonic() + 120
+            served = 0
+            while served < 3 or not preempted_batch():
+                assert time.monotonic() < deadline, "no batch row preempted in 120 s"
                 assert saturated()
                 out = mgr.collect(
                     mgr.submit("gold", "tiny", PROMPT, GREEDY), timeout_s=120
                 )
                 assert out.finished
+                served += 1
         finally:
             stop.set()
             for th in threads:

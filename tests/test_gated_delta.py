@@ -8,6 +8,8 @@ the rule reduces to when a gate is switched off; and that it is kernels
 all the way: one `pallas_call` forward, two for a gradient, no loop over
 chunks or positions outside them and nothing that grows with T x T."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,6 +21,7 @@ B, H, DK, DV = 2, 3, 12, 24   # neither head size fills a tile
 HI = jax.lax.Precision.HIGHEST
 
 
+@jax.jit
 def recurrent_gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                                beta: jax.Array) -> jax.Array:
     """The rule as written, one position at a time (a `lax.scan` over T):
@@ -53,8 +56,20 @@ def inputs(T, beta_max, seed=0, shape=(B, H, DK, DV)):
     return (q, k, v, g, beta), jax.random.normal(ks[5], (B, H, T, DV))
 
 
+@functools.lru_cache(maxsize=None)
+def _grads_program(rule):
+    return jax.jit(jax.grad(lambda w, *a: (rule(*a) * w).sum(), argnums=(1, 2, 3, 4, 5)))
+
+
 def grads_of(rule, args, w):
-    return jax.jit(jax.grad(lambda *a: (rule(*a) * w).sum(), argnums=(0, 1, 2, 3, 4)))(*args)
+    """Every gradient of sum(rule(*args) * w), from ONE program a rule and
+    shape: `w` is an argument, so the cases that differ in their values
+    alone (the two write strengths) compile it once."""
+    return _grads_program(rule)(w, *args)
+
+
+RULE = jax.jit(gd.gated_delta_rule)
+CHECKPOINTED = jax.checkpoint(gd.gated_delta_rule)   # one function: one program a shape
 
 
 # 192 = three whole chunks of 64; 150 = two and 22 positions; 40 = less than one; 600 = nine
@@ -67,7 +82,7 @@ EIGVAL = pytest.mark.parametrize("beta_max", [1.0, 2.0], ids=["beta_to_1", "neg_
 @EIGVAL
 def test_chunked_forward_is_the_position_by_position_rule(T, beta_max):
     args, _ = inputs(T, beta_max)
-    got, want = jax.jit(gd.gated_delta_rule)(*args), recurrent_gated_delta_rule(*args)
+    got, want = RULE(*args), recurrent_gated_delta_rule(*args)
     assert got.shape == (B, H, T, DV) and got.dtype == jnp.float32
     # float32 both ways; the orders of summation differ
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6)
@@ -81,7 +96,7 @@ def test_chunked_backward_is_jax_grad_of_the_plain_recurrence(T, beta_max):
     without the block's `jax.checkpoint` around the rule."""
     args, w = inputs(T, beta_max)
     want = grads_of(recurrent_gated_delta_rule, args, w)
-    for rule in (gd.gated_delta_rule, jax.checkpoint(gd.gated_delta_rule)):
+    for rule in (gd.gated_delta_rule, CHECKPOINTED):
         for name, g, r in zip("q k v g beta".split(), grads_of(rule, args, w), want):
             scale = float(jnp.abs(r).max())
             np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-4, atol=1e-5 * scale,

@@ -9,6 +9,8 @@ before it and the first block zeros, forward, and a block's last rows the
 gradients after it, backward; that it is kernels all the way and that a
 call site counts itself."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -33,10 +35,12 @@ def causal_conv(x: jax.Array, taps: jax.Array) -> jax.Array:
     return y
 
 
+@functools.partial(jax.jit, static_argnums=2)
 def chain(x: jax.Array, taps: jax.Array, scale=None) -> jax.Array:
     """What `gdn_sublayer` did with a projection until PR 48, operation for
     operation: float32, the convolution, SiLU, and for q and k the L2 norm
-    over a head's channels and the scale."""
+    over a head's channels and the scale. One program a shape and scale:
+    taken bare, each of its operations is compiled alone."""
     s = jax.nn.silu(causal_conv(x.astype(F32), taps))
     if scale is not None:
         s = s * jax.lax.rsqrt(jnp.sum(s * s, -1, keepdims=True) + gc.L2_EPS) * scale

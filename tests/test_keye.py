@@ -3,12 +3,13 @@ small size on the CPU (the sequence past `indexer_topk`, so that the
 selection bites), seeded weights, against the plain reference
 (chipbench/reference/keye_decoder.py, imported): the attention sublayer
 over the keys the indexer selects, the selected sets themselves, the tie
-rule, `topk` >= T as the full GQA sublayer, no gradient into the indexer
-and none through the selection, the whole train path in loss and
-gradients, the eight shares of a layer that add up, the train step by
-the registry's name. (The flash kernels under a selection:
-tests/test_flash_selection.py; remat, flash and bf16, `config_from_hf`, its
-refusals and the engine's: tests/test_model_contract.py.)"""
+rule, `topk` >= T as the full GQA sublayer, what the `dots` policy keeps
+of the indexer, the eight shares of a layer that add up, the train step
+by the registry's name. (The flash kernels under a selection:
+tests/test_flash_selection.py; no gradient into the indexer and none
+through the selection, the whole train path in loss and gradients, remat,
+flash and bf16: tests/test_contract_keye.py; `config_from_hf`, its refusals
+and the engine's: tests/test_model_contract.py.)"""
 
 import dataclasses
 import os
@@ -20,7 +21,7 @@ import optax
 import pytest
 
 from chipbench.reference import keye_decoder
-from model_cases import KEYE, catalog_config, reference_path, seeded_params, train_path
+from model_cases import KEYE, catalog_config, seeded_params
 from ray_tpu.models import dsa, llama
 from ray_tpu.models.registry import config_from_hf, get_model_config, list_models
 from ray_tpu.nn.layers import rms_norm
@@ -131,51 +132,7 @@ def test_topk_at_least_the_sequence_is_the_full_gqa_sublayer():
     assert int(stats["dsa_selected"]) == B * S * (S + 1) // 2 and int(stats["dsa_ties"]) == 0
 
 
-def test_no_gradient_reaches_the_indexer_and_none_passes_through_the_selection():
-    cfg = FP32
-    ours = train_path(KEYE, cfg)
-    params, stats, grads = ours.params, ours.stats, ours.grads
-    for n in ("idx_wq", "idx_wk", "idx_ww", "idx_norm_w", "idx_norm_b", "router_bias"):
-        assert not np.asarray(grads["layers"][n]).any(), n
-    assert np.asarray(grads["layers"]["wq"]).any()
-    assert stats["dsa_selected"].tolist() == [B * (16 * 17 // 2 + 48 * 16)] * cfg.n_layers
-    # the selection as a CONSTANT gives the sublayer's input the gradient it has with the indexer in
-    lp = layer_of(params)
-    x = jax.random.normal(jax.random.key(3), (B, S, cfg.d_model), jnp.float32)
-    pos = jnp.arange(S)
-    sel = dsa.selection(x, lp, cfg, pos)
-
-    def with_constant(x):
-        real = dsa.selection
-        try:
-            dsa.selection = lambda *a: sel
-            return dsa.dsa_sublayer(x, lp, cfg, positions=pos, segment_ids=None)[0].sum()
-        finally:
-            dsa.selection = real
-
-    whole = jax.jit(jax.grad(
-        lambda x: dsa.dsa_sublayer(x, lp, cfg, positions=pos, segment_ids=None)[0].sum()))(x)
-    np.testing.assert_array_equal(np.asarray(whole), np.asarray(jax.jit(jax.grad(with_constant))(x)))
-
-
 # -- the model ----------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("bias", [0.0, 0.02], ids=["zero_bias", "random_bias"])
-@pytest.mark.parametrize("held", [None, (2, 4)], ids=["all_experts", "a_share"])
-def test_train_path_meets_the_reference_in_loss_and_gradients(held, bias):
-    cfg = FP32 if held is None else dataclasses.replace(
-        FP32, first_expert_held=held[0], experts_held=held[1], vocab_size=256)
-    ours, theirs = train_path(KEYE, cfg, bias), reference_path(KEYE, cfg, bias)
-    loss, stats, grads, parts, want = ours.loss, ours.stats, ours.grads, theirs.parts, theirs.grads
-    assert float(loss) == pytest.approx(float(parts["loss"]), rel=1e-5)
-    assert stats["tokens_per_expert"].tolist() == parts["tokens_per_expert"].tolist()
-    assert stats["dsa_selected"].tolist() == parts["selected_pairs"].tolist()
-    for path, g in jax.tree_util.tree_leaves_with_path(grads):
-        w = jax.tree_util.keystr(path)
-        ref = np.asarray(jax.tree_util.tree_leaves_with_path(want)[[jax.tree_util.keystr(p) for p, _ in
-               jax.tree_util.tree_leaves_with_path(want)].index(w)][1])
-        np.testing.assert_allclose(np.asarray(g), ref, rtol=2e-4, atol=2e-6, err_msg=w)
 
 
 def test_the_dots_policy_keeps_the_selection_and_nothing_else_of_the_indexer():

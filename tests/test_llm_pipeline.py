@@ -101,6 +101,20 @@ def test_pipelined_stop_token_mid_chunk():
     assert got == ref[:4] and got[-1] == stop_tok
 
 
+def test_a_requests_key_is_the_same_in_every_process():
+    """A seeded request samples from its seed and its id, and the id's
+    share is a digest that does not differ by process: `hash(rid)` does
+    (PYTHONHASHSEED), which made the reference of the case above another
+    sequence in every run, one that sometimes held the stop token before
+    its fourth place (ROADMAP D8's flaky list, PR 54)."""
+    import zlib
+
+    eng = _engine(False)
+    rid = eng.add_request([5, 6, 7], SamplingParams(max_tokens=1, seed=42), request_id="req-a")
+    want = jax.random.fold_in(jax.random.key(42), zlib.crc32(b"req-a") & 0x7FFFFFFF)
+    assert (jax.random.key_data(eng.requests[rid]._key) == jax.random.key_data(want)).all()
+
+
 def test_pipelined_eos_and_max_tokens_terminations(prompts):
     """Natural EOS stops (ignore_eos=False) and max_tokens walls land
     identically; finish_reason survives the pipelined bookkeeping."""

@@ -1,57 +1,25 @@
-"""What every model that goes through the one decoder holds, a case a
-model: a row of tests/model_cases.py::MODELS. Rematerialisation gives the
-gradients it is not there to change, bf16 compute stays near the plain
-reference, `config_from_hf` maps the catalog's config onto the preset and
-refuses by name what is not implemented, and the engine refuses the model
-by name. What a model holds of its own (its sublayers, its routing, its
-train path against its reference, its shares) stands in its own file."""
+"""What every model that goes through the one decoder holds and that
+compiles nothing, a case a model: a row of tests/model_cases.py::MODELS.
+`config_from_hf` maps the catalog's config onto the preset and refuses by
+name what is not implemented, and the engine refuses the model by name.
+The contract's two cases that compile the train path (rematerialisation
+gives the gradients it is not there to change, bf16 compute stays near
+the plain reference) are `model_cases.contract_cases`, and stand a file a
+model beside that model's train path against its reference
+(tests/test_contract_<model>.py: one process compiles a configuration
+once, and a file is about 150 s alone at most; ROADMAP D8). What a model
+holds of its own (its sublayers, its routing, its shares) stands in its
+own file."""
 
-import dataclasses
 import operator
 
-import jax
-import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from model_cases import (GLM_LITE, KEYE, LAGUNA, MELLUM2, MODELS, NEMOTRON_H, OLMO_HYBRID, Model,
-                         catalog_config, seeded_params, train_path)
-from ray_tpu.models import llama
+                         catalog_config)
 from ray_tpu.models.registry import config_from_hf, get_model_config
 
 by_name = pytest.mark.parametrize("model", MODELS, ids=lambda m: m.name)
-
-
-@pytest.mark.parametrize("remat_policy", ["dots", "full"])
-@by_name
-def test_remat_gives_the_same_gradients(model, remat_policy):
-    """The loss and every gradient of the rematerialised train path are
-    the plain one's, on the parameters the model's own file gave this test
-    before it was one: the selection biases a random table at the row's
-    `remat_bias` (ZAYA1 0.05, GLM-4.7-Flash 0.1, Laguna 0.05 over the
-    dense layer and one period, Mellum2 0.05 over one period, Keye 0), at
-    the model's own tolerance.
-    The plain gradients are made once for both policies, and where the
-    bias is the model's own they are its train-path test's too."""
-    plain = dataclasses.replace(model.fp32, **model.remat_plain)
-    cfg = dataclasses.replace(plain, remat=True, remat_policy=remat_policy)
-    want, got = train_path(model, plain, model.remat_bias), train_path(model, cfg, model.remat_bias)
-    assert float(got.loss) == pytest.approx(float(want.loss), rel=1e-6)
-    for g, w in zip(jax.tree.leaves(got.grads), jax.tree.leaves(want.grads)):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), **model.remat_tol)
-
-
-@by_name
-def test_bf16_compute_stays_near_the_reference(model):
-    """The loss in bfloat16 (Laguna's, Mellum2's and Keye's through the
-    flash kernels, interpreted) against the plain reference's on the same
-    bfloat16 parameters."""
-    cfg = dataclasses.replace(model.fp32, dtype=jnp.bfloat16, **model.bf16)
-    params, batch = seeded_params(model, cfg), model.batch_of(cfg)
-    loss = jax.jit(lambda p: llama.loss_fn(p, batch, cfg))(params)
-    with model.reference_set_up():
-        ref = model.reference.loss(params, batch["tokens"], batch["targets"], model.shape_of(cfg))
-    assert float(loss) == pytest.approx(float(ref), rel=model.bf16_rel)
 
 
 @by_name

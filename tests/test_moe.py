@@ -328,7 +328,8 @@ def test_relu2_experts_are_the_dense_form_in_value_and_every_gradient(share):
     (_, (out, stats)), grads = jax.jit(jax.value_and_grad(program, argnums=(0, 1),
                                                           has_aux=True))(x, lp)
     want_out = _dense_relu2(x, lp, cfg)
-    want = jax.grad(lambda x, lp: (_dense_relu2(x, lp, cfg) * weight).sum(), argnums=(0, 1))(x, lp)
+    want = jax.jit(jax.grad(lambda x, lp: (_dense_relu2(x, lp, cfg) * weight).sum(),
+                            argnums=(0, 1)))(x, lp)   # bare: an operation a compile
     assert int(stats["dropped_pairs"]) == 0
     assert int(stats["tokens_per_expert"].sum()) == tokens * cfg.top_k
     assert ("compact" in stats) == (share == "small")
@@ -356,7 +357,7 @@ def test_a_small_relu2_share_past_its_bound_runs_over_all_rows():
         return out.sum(), stats
 
     (_, stats), grads = jax.jit(jax.value_and_grad(program, argnums=(0, 1), has_aux=True))(x, lp)
-    want = jax.grad(lambda x, lp: _dense_relu2(x, lp, cfg).sum(), argnums=(0, 1))(x, lp)
+    want = jax.jit(jax.grad(lambda x, lp: _dense_relu2(x, lp, cfg).sum(), argnums=(0, 1)))(x, lp)
     assert int(stats["compact"]) == 0 and int(stats["dropped_pairs"]) == 0
     for name in ("w_up", "w_down"):
         np.testing.assert_allclose(np.asarray(grads[1][name]), np.asarray(want[1][name]),

@@ -2,13 +2,13 @@
 against the plain reference (chipbench/reference/nemotron_h_decoder.py,
 which imports nothing of the program and runs the scan position by
 position): the stack's plan from the pattern string, the counts by hand,
-the family's initialisation, each sublayer, logits, loss and every
-gradient of the one train path at the tiny preset with all three kinds of
-layer, each reading of the equations NOT taken told from the one taken
-(the changes are chipbench/tools/nemotron_h_wrong.py's, defined there
-once), the sixteen shares that add up to the uncut layer, and the
-refusals by name. What every model holds alike is
-tests/test_model_contract.py's (a row of model_cases.MODELS)."""
+the family's initialisation, each sublayer, the sixteen shares that add
+up to the uncut layer, and the refusals by name. (Logits, loss and every
+gradient of the one train path at the tiny preset with all three kinds
+of layer, each reading of the equations NOT taken told from the one
+taken, remat and bf16: tests/test_contract_nemotron_h.py; what every model
+holds alike and compiles nothing: tests/test_model_contract.py, a row of
+model_cases.MODELS.)"""
 
 import dataclasses
 import subprocess
@@ -20,8 +20,7 @@ import numpy as np
 import pytest
 
 from chipbench.reference import nemotron_h_decoder as ref
-from chipbench.tools.nemotron_h_wrong import PRECISION_ONLY, VARIANTS
-from model_cases import NEMOTRON_H, reference_path, seeded_params, train_path, worst_leaf
+from model_cases import NEMOTRON_H, seeded_params
 from ray_tpu.models import llama, moe, nemotron_h as nh
 from ray_tpu.models.registry import get_model_config
 
@@ -148,39 +147,6 @@ def test_expert_sublayer_is_the_references_with_a_random_selection_bias():
     assert float(jnp.abs(got - jnp.stack([w[0] for w in want])).max()) < 1e-5
     np.testing.assert_array_equal(np.asarray(stats["tokens_per_expert"]),
                                   np.asarray(sum(w[1].sum(0) for w in want)))
-
-
-def test_train_path_meets_the_reference_in_logits_loss_routing_and_gradients():
-    """The one train path (llama.loss_and_weight_fn through the stack's
-    scan over `ME*` x 2 and its unrolled tail) in float32 against the
-    reference: the loss, every expert layer's counts, every gradient leaf
-    (the selection bias takes none on either side), the logits."""
-    ours, theirs = train_path(NEMOTRON_H, FP32), reference_path(NEMOTRON_H, FP32)
-    assert float(ours.loss) == pytest.approx(float(theirs.parts["loss"]), rel=2e-6)
-    np.testing.assert_array_equal(np.asarray(ours.stats["tokens_per_expert"]),
-                                  np.asarray(theirs.parts["tokens_per_expert"]))
-    assert int(ours.stats["dropped_pairs"].sum()) == 0
-    worst = worst_leaf(ours.grads, theirs.grads)
-    assert len(worst) == len(jax.tree.leaves(ours.params)) - 1 and max(worst.values()) < 2e-4, worst
-    shape = NEMOTRON_H.shape_of(FP32)
-    with HIGHEST:
-        logits = jax.jit(lambda p, t: llama.forward(p, t, FP32))(ours.params, ours.batch["tokens"])
-    want = jnp.stack([ref.logits(ours.params, ours.batch["tokens"][b], shape) for b in range(2)])
-    assert float(jnp.abs(logits - want).max()) < 2e-5 * float(jnp.abs(want).max())
-
-
-@pytest.mark.parametrize("name", [n for n in VARIANTS if n not in PRECISION_ONLY],
-                         ids=lambda n: n.replace(" ", "_"))
-def test_each_reading_not_taken_is_told_from_the_one_taken(name):
-    """The program's loss against the reference changed in ONE thing (the
-    changes of the cell's one-thing-wrong table,
-    chipbench/tools/nemotron_h_wrong.py): far outside what the sound
-    comparison leaves (2e-6)."""
-    ours = train_path(NEMOTRON_H, FP32)
-    with VARIANTS[name]():
-        wrong = ref.loss(ours.params, ours.batch["tokens"], ours.batch["targets"],
-                         NEMOTRON_H.shape_of(FP32))
-    assert not abs(float(wrong) - float(ours.loss)) <= 1e-4 * float(ours.loss), name
 
 
 def test_sixteen_shares_of_the_experts_add_up_to_the_uncut_layer():

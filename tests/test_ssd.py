@@ -10,6 +10,7 @@ layouts to the bit; one forward and one backward kernel under the model's
 remat policy; nothing T x T in the traced program."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -43,8 +44,11 @@ def lane_blocks(x, Bm, Cm):
     return jnp.concatenate([blocks, Bm, Cm], axis=1)
 
 
+@jax.jit
 def by_position(x, dt, A, Bm, Cm, D):
-    """The reference's recurrence on the program's layout, a sequence at a time."""
+    """The reference's recurrence on the program's layout, a sequence at a
+    time; one program a shape (taken bare, every operation of it and of
+    its gradient is compiled alone)."""
     with jax.default_matmul_precision("highest"):
         rows = lambda a: jnp.moveaxis(a, 0, 1)  # noqa: E731
         return jnp.stack([rows(reference.recurrence(rows(x[b]), rows(dt[b]), A, rows(Bm[b]),
@@ -65,15 +69,23 @@ def test_the_chunked_scan_is_the_recurrence(T, chunk):
 @pytest.mark.parametrize("checkpoint", [False, True], ids=["plain", "checkpoint"])
 @pytest.mark.parametrize("T,chunk", [(48, 16), (41, 16)])
 def test_every_gradient_is_the_recurrences(T, chunk, checkpoint):
-    args = inputs(T, seed=1)
-    w = jax.random.normal(jax.random.key(9), (B, H, T, P))
+    args, w, want = _recurrences_gradients(T)
     scan = lambda *a: ssd_scan(*a, chunk=chunk)  # noqa: E731
     if checkpoint:
         scan = jax.checkpoint(scan)
     got = jax.jit(jax.grad(lambda *a: jnp.sum(scan(*a) * w), argnums=range(6)))(*args)
-    want = jax.grad(lambda *a: jnp.sum(by_position(*a) * w), argnums=range(6))(*args)
     for g, r in zip(got, want):
         close(g, r, 5e-5)
+
+
+@functools.lru_cache(maxsize=None)
+def _recurrences_gradients(T):
+    """(inputs, cotangent, the recurrence's six gradients) at T: made once for
+    the plain and the checkpointed case."""
+    args = inputs(T, seed=1)
+    w = jax.random.normal(jax.random.key(9), (B, H, T, P))
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(by_position(*a) * w), argnums=range(6)))(*args)
+    return args, w, want
 
 
 def test_the_cells_block_and_every_gradient_dA_and_dD_among_them():
@@ -104,8 +116,9 @@ def test_the_edges_of_the_decay(case):
         args = (x, dt, A, Bm, Cm, D)
         assert float(jnp.exp(jnp.max(dt * A[None, :, None]) * 16)) == 0.0
         close(ssd_scan(*args, chunk=16), by_position(*args))
-        got = jax.grad(lambda *a: jnp.sum(ssd_scan(*a, chunk=16) ** 2), argnums=range(6))(*args)
-        want = jax.grad(lambda *a: jnp.sum(by_position(*a) ** 2), argnums=range(6))(*args)
+        got = jax.jit(jax.grad(lambda *a: jnp.sum(ssd_scan(*a, chunk=16) ** 2),
+                               argnums=range(6)))(*args)
+        want = jax.jit(jax.grad(lambda *a: jnp.sum(by_position(*a) ** 2), argnums=range(6)))(*args)
         for g, r in zip(got, want):
             assert bool(jnp.all(jnp.isfinite(g)))
             close(g, r, 5e-5)

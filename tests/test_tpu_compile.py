@@ -9,8 +9,9 @@ head_dim 128, 16-row pages). Interpret mode cannot see what this sees —
 refused its unaligned row window. Nothing runs: a compile that passes
 is not a chip run. The train steps of the benchmark's cells, compiled
 whole, stand in tests/test_m7b_steps_compile.py,
-tests/test_olmoe_zaya1_keye_steps_compile.py and
-tests/test_glm47f_laguna_steps_compile.py; the described chip and the
+tests/test_zaya1_keye_steps_compile.py,
+tests/test_glm47f_laguna_steps_compile.py and
+tests/test_olmo_hybrid_twotower_steps_compile.py; the described chip and the
 steps built for it are tests/v5e_steps.py's.
 
 Plus the two host-side contracts of the bring-up: `chip_smoke.py` runs
@@ -278,7 +279,7 @@ def test_gdn_conv_kernels_compile_for_v5e(v5e, d, scale, T):
     in: a tap is a load at a static offset along the sublanes, and a head
     of 96 or 192 fills no whole number of lane tiles; both are Mosaic's
     to accept, not the interpreter's. The step that holds them compiled
-    whole is tests/test_olmo_hybrid_step_compile.py's."""
+    whole is tests/test_olmo_hybrid_twotower_steps_compile.py's."""
     from ray_tpu.ops.gdn_conv import gdn_conv
 
     def value_and_grads(x, taps, ct):
@@ -300,7 +301,7 @@ def test_ssd_scan_kernels_compile_for_v5e(v5e, T):
     to columns, the products that contract over a chunk's positions, the
     [16, 128] block of dt over a and the backward's three DMAs into one
     output are Mosaic's to accept, not the interpreter's. The step that
-    holds them compiled whole is tests/test_twotower_step_compile.py's."""
+    holds them compiled whole is tests/test_olmo_hybrid_twotower_steps_compile.py's."""
     from ray_tpu.ops.ssd import ssd_scan_lanes
 
     def value_and_grads(xbc, dt, A, D, ct):
@@ -323,7 +324,7 @@ def test_gated_norm_kernels_compile_for_v5e(v5e, T):
     bfloat16 block, the reductions over a group's lanes and the VMEM the
     calls state (the backward's five row blocks are 14 MiB double-buffered)
     are Mosaic's to accept, not the interpreter's. The step that holds
-    them compiled whole is tests/test_twotower_step_compile.py's."""
+    them compiled whole is tests/test_olmo_hybrid_twotower_steps_compile.py's."""
     from ray_tpu.ops.gated_norm import gated_norm
 
     def value_and_grads(y, z, weight, ct):

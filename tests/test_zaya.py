@@ -1,10 +1,12 @@
 """ZAYA1 through the one decoder (PR 32), at a small size on the CPU,
 seeded weights, against the plain reference
 (chipbench/reference/zaya_decoder.py, imported): the CCA sublayer
-alone, the router's carried state, the whole train path in loss and
-gradients, causality through both convolutions and the value shift
-(and across a document boundary), the share of experts that adds up,
-and the grouped matmul whose trailing rows no tile visits."""
+alone, the router's carried state, causality through both convolutions
+and the value shift (and across a document boundary), the share of
+experts that adds up, and the grouped matmul whose trailing rows no tile
+visits. (The whole train path in loss and gradients, remat and bf16:
+tests/test_contract_zaya.py; `config_from_hf` and the engine's refusal:
+tests/test_model_contract.py.)"""
 
 import dataclasses
 import functools
@@ -15,7 +17,7 @@ import numpy as np
 import pytest
 
 from chipbench.reference import zaya_decoder
-from model_cases import ZAYA, reference_path, seeded_params, train_path, worst_leaf
+from model_cases import ZAYA, seeded_params
 from ray_tpu.models import cca, llama, moe
 from ray_tpu.nn.layers import rms_norm
 from ray_tpu.ops import grouped_matmul as gm
@@ -92,32 +94,6 @@ def test_top1_weight_is_the_probability_and_the_bias_only_chooses():
     g = jax.grad(lambda b: moe.moe_ffn(x, {**lp, "router_bias": b}, cfg)[0].sum())(
         lp["router_bias"])
     assert float(jnp.abs(g).max()) == 0.0
-
-
-# -- the whole train path --------------------------------------------------------
-
-
-@pytest.mark.parametrize("held", [None, (2, 1)], ids=["all_experts", "a_share"])
-def test_train_path_meets_the_reference_in_loss_and_gradients(held):
-    """llama.loss_fn (the one train path) on a ZAYA1-kind configuration
-    against the plain reference, on seeded weights and skewed tokens: the
-    loss, the tokens per expert of every layer, and every gradient by
-    its worst leaf."""
-    cfg = FP32 if held is None else dataclasses.replace(
-        FP32, experts_held=held[0], first_expert_held=held[1])
-    ours, theirs = train_path(ZAYA, cfg), reference_path(ZAYA, cfg)
-    loss, stats, ref = ours.loss, ours.stats, theirs.parts
-    assert float(loss) == pytest.approx(float(ref["loss"]), rel=2e-6)
-    assert stats["tokens_per_expert"].tolist() == ref["tokens_per_expert"].tolist()
-    assert int(stats["dropped_pairs"].sum()) == 0
-    if held is not None:
-        first, n = held[1], held[0]
-        elsewhere = B * S - stats["tokens_per_expert"][:, first:first + n].sum(-1)
-        assert stats["pairs_elsewhere"].tolist() == elsewhere.tolist()
-        assert 0 < int(elsewhere.sum()) < cfg.n_layers * B * S
-    worst = worst_leaf(ours.grads, theirs.grads)   # the selection bias takes no gradient
-    assert len(worst) == len(jax.tree.leaves(ours.params)) - 1
-    assert max(worst.values()) < 2e-4, sorted(worst.items(), key=lambda kv: -kv[1])[:3]
 
 
 # -- causality, through both convolutions and the value shift ------------------

@@ -1,22 +1,37 @@
-"""The described chip and the train steps built for it: what
-tests/test_m7b_steps_compile.py,
-tests/test_olmoe_zaya1_keye_steps_compile.py,
-tests/test_glm47f_laguna_steps_compile.py and tests/test_tpu_compile.py
-share. No test lives here (pytest does not collect the file).
+"""The described chip and the train steps built for it: what the four
+step-compile files (tests/test_m7b_steps_compile.py,
+tests/test_zaya1_keye_steps_compile.py,
+tests/test_glm47f_laguna_steps_compile.py,
+tests/test_olmo_hybrid_twotower_steps_compile.py) and
+tests/test_tpu_compile.py share. No test lives here (pytest does not
+collect the file).
 
-A full-width train step takes one to two minutes to compile for the
-described v5e, and the `v5e` fixture turns the persistent compile cache
-off, so nothing shares a compile unless the tests do: `train_step` keeps
-ONE record a process for one set of arguments, and a record makes its
-lowered text, its compiled step and what is read of them once each, on
-first request. A test that needs a FRESH trace (an import made to fail, a
-rule of models/moe.py patched) builds a `Step` of its own and goes round
-the memo. The cells' tests stand two or three cells a file, grouped by
-their compiles' seconds, so that `--dist loadfile` gives the compiles to
-three workers: one file for all of them was 84% of the lane's wall on
-one worker, and a file a cell put seven all-core compiles at once into
-the lane's tail, beside the cluster tests whose RPCs time out in seconds
-(pytest-xdist starts the files with the most cases first; ROADMAP D8).
+A full-width train step takes 30-105 s of every core to compile for the
+described v5e (and 2-10 s to lower), and the `v5e` fixture turns the
+persistent compile cache off, so nothing shares a compile unless the
+tests do: `train_step` keeps ONE record a process for one set of
+arguments, and a record makes its lowered text, its compiled step and
+what is read of them once each, on first request. A test that needs a
+FRESH trace (an import made to fail, a rule of models/moe.py patched)
+builds a `Step` of its own and goes round the memo. A step is COMPILED
+only for a fact the lowered module cannot show: the temporaries' and
+arguments' bytes, the VMEM an operation is given, the tiles of a fusion,
+a layout, a copy or a transpose, `.remat`, a branch's own computations, a
+transfer started before a matmul and done after it. Which kernels stand
+at how many sites under which scope at which shapes, and that XLA's own
+ragged dot is not there, the lowered module says (`lowered_kernels`,
+`lowered_op_names`, `has_scope(.., lowered=True)`, `engaged`, which the
+TRACE counts): GLM-4.7-Flash's step is read so and compiled by no test
+(PR 54), Laguna-S-2.1's is compiled at two of the cell's five layers and
+Keye-VL-2.0's at one of its two (what is read of those compiles holds at
+either depth; 286 and 265 CPU s where the cell's depth takes 585 and
+443). The eight compiles left stand in four files of 110-160 s alone
+each, balanced by their compiles' measured seconds (ROADMAP D8 has the
+table), so that `--dist loadfile` gives them to four workers: one file
+for all of them was 84% of the lane's wall on one worker, and a file a
+cell put every all-core compile at once into the lane's tail
+(pytest-xdist starts the files with the most cases first, so these start
+last whatever their names).
 Only one process at a time may load the TPU's library unless
 `ALLOW_MULTIPLE_LIBTPU_LOAD=1` is set, as the driver's command sets it
 (pytest.ini has the command): under several workers without it, every
@@ -39,8 +54,11 @@ import pytest
 def v5e():
     """Devices of a described v5e:2x2, with the persistent compile cache
     off around the module: an entry written for a described chip cannot
-    be read back without one, and the next compile would warn. The
-    module's records go with it: a file's steps are its own."""
+    be read back without one, and the next compile would warn. XLA's
+    optimisation passes, which tests/conftest.py turns off for the lane's
+    CPU programs, are ON around the module: what is read of a compile for
+    the chip is the optimised step. The module's records go with it: a
+    file's steps are its own."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -49,12 +67,14 @@ def v5e():
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - no TPU compiler in this installation
         pytest.skip(f"cannot describe a v5e topology here: {e!r}")
-    was = jax.config.jax_enable_compilation_cache
+    was = jax.config.jax_enable_compilation_cache, jax.config.read("jax_disable_most_optimizations")
     jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_disable_most_optimizations", False)
     compilation_cache.reset_cache()
     yield topo.devices
     _STEPS.clear()
-    jax.config.update("jax_enable_compilation_cache", was)
+    jax.config.update("jax_enable_compilation_cache", was[0])
+    jax.config.update("jax_disable_most_optimizations", was[1])
     compilation_cache.reset_cache()
 
 
@@ -131,9 +151,10 @@ class Step:
     """One train step built for the described devices, with what the tests
     read of it, each made once and on first request: the lowered text, the
     compiled step, its text, its memory analysis, and how often each site
-    of `obs.layer_counters()` was counted over the lowering and the
-    compile. Code that asks `jax.default_backend()` while it is traced
-    (flash's interpret switch) is answered "tpu"."""
+    of `obs.layer_counters()` was counted over the lowering (a compile
+    runs none of the program's Python). Code that asks
+    `jax.default_backend()` while it is traced (flash's interpret switch)
+    is answered "tpu"."""
 
     def __init__(self, devices, mesh_shape=None, **kwargs):
         self.step, self.state, self.batch = train_step_at_mistral_widths(
@@ -162,7 +183,8 @@ class Step:
     @functools.cached_property
     def compiled(self):
         lowered = self.lowered
-        return self._counting(lowered.compile)
+        with mock.patch("jax.default_backend", return_value="tpu"):
+            return lowered.compile()
 
     @functools.cached_property
     def hlo(self) -> str:
@@ -173,9 +195,48 @@ class Step:
         return self.compiled.memory_analysis()
 
     def engaged(self, *names) -> dict:
-        """{site: times counted while the step was lowered and compiled}."""
-        self.compiled
+        """{site: times counted while the step was traced}: the lowering
+        counts them, a compile runs no Python of the program's."""
+        self.lowered
         return {name: self._counted.get(name, 0) for name in names}
+
+    @functools.cached_property
+    def _lowered_locations(self) -> tuple:
+        """(the lowered text with its locations, {location: the name stack it was traced under})."""
+        text = self.lowered.as_text(debug_info=True)
+        return text, dict(re.findall(r'^#loc(\d+) = loc\("([^"]*)"', text, re.M))
+
+    @functools.cached_property
+    def lowered_op_names(self) -> set:
+        """The name stacks of the LOWERED module's operations: `op_names` without a compile."""
+        return set(self._lowered_locations[1].values())
+
+    @functools.cached_property
+    def lowered_kernels(self) -> list:
+        """The Pallas kernels of the LOWERED module by the names the compiled
+        step gives them, a site each: a kernel called where it stands is
+        named after the scope it was traced under (`mla.attend`), one inside
+        a jitted function of its own after that function, at every call of
+        it (`ragged-dot-tiled`, the module's numbering taken off)."""
+        text, names = self._lowered_locations
+        functions = re.split(r"^  func\.func ", text, flags=re.M)[1:]
+        at = r'custom_call @tpu_custom_call\([^\n]*loc\(#loc(\d+)\)$'
+        # every kernel's location is a plain `#locN = loc("name stack"...)` line: another
+        # form of it (a callsite, a fused location) is jax's printer changed, not a kernel gone
+        assert text.count("@tpu_custom_call(") == len(re.findall(at, text, re.M)), \
+            "a tpu_custom_call whose line does not end in loc(#locN)"
+        unnamed = {n: re.findall(rf"^#loc{n} = .*$", text, re.M)
+                   for n in re.findall(at, text, re.M) if n not in names}
+        assert not unnamed, f"kernel locations that are no loc(\"name\"...): {unnamed}"
+        own = {re.match(r'\w+ @"?([\w.\-]+)"?\(', f).group(1) for f in functions
+               if any(names[at_] == "pallas_call" for at_ in re.findall(at, f, re.M))}
+        sites = []
+        for site in re.finditer(at + r'|call @"?([\w.\-]+)"?\(', text, re.M):
+            if site.group(2) in own:
+                sites.append(re.sub(r"_\d+$", "", site.group(2)))
+            elif site.group(1) and names[site.group(1)] != "pallas_call":
+                sites.append(names[site.group(1)].split("/")[-2])
+        return sites
 
     @functools.cached_property
     def kernels(self) -> list:
@@ -191,10 +252,11 @@ class Step:
     def op_names(self) -> set:
         return set(re.findall(r'op_name="([^"]*)"', self.hlo))
 
-    def has_scope(self, scope: str) -> bool:
-        """Whether an operation of the compiled step was traced under the named scope."""
+    def has_scope(self, scope: str, lowered=False) -> bool:
+        """Whether an operation of the compiled step (`lowered`: of the
+        lowered module) was traced under the named scope."""
         at = re.compile(r"(?:^|[/(])" + re.escape(scope) + r"(?:[/)]|$)")
-        return any(at.search(n) for n in self.op_names)
+        return any(at.search(n) for n in (self.lowered_op_names if lowered else self.op_names))
 
     def lowered_hash(self) -> str:
         """sha256 of the lowered text, the kernels' serialized bodies taken
