@@ -250,7 +250,9 @@ class EngineConfig:
                 "has no cache here either, GLM-4.7-Flash, whose latent attention "
                 "would be served in its absorbed form, Laguna and Mellum2, whose sliding-window "
                 "layers want a cache sized by layer type, and Keye, whose indexer wants a "
-                "cache of its own keys and a selection in the ragged kernel, are training-only)"
+                "cache of its own keys and a selection in the ragged kernel, and SDAR, which "
+                "decodes a block of positions in several denoising steps and no token at a "
+                "time, are training-only)"
             )
         if hasattr(self.model, "linear_key_dim"):
             raise ValueError(

@@ -1,7 +1,7 @@
 """The typed stack: sliding-window and full attention layers in one stack,
 each kind with its own mask, rotary and (where the model has them) head
 count; a headwise output gate or none; an RMSNorm a head on q and k or
-none. TWO models run through it and it stays one module (ROADMAP D2):
+none. THREE models run through it and it stays one module (ROADMAP D2):
 
   * Laguna-S-2.1 (poolside/Laguna-S-2.1, `model_type` laguna): 72 heads
     in a sliding layer and 48 in a full one, the gate, yarn on HALF a head
@@ -14,7 +14,15 @@ none. TWO models run through it and it stays one module (ROADMAP D2):
     leaves `q_norm`, `k_norm` [head_dim], scopes `swa.norm` / `attn.norm`),
     yarn over the WHOLE head, no dense layer, no shared expert, unscaled
     renormalised weights. Its "MTP head" (a reader's summary names one;
-    the config has no key, size or equation of it) is NOT built.
+    the config has no key, size or equation of it) is NOT built;
+  * SDAR-30B-A3B (JetLM/SDAR-30B-A3B-Chat, `model_type` sdar_moe): the
+    Qwen3-MoE block, every layer FULL attention at 32 / 4 heads of 128
+    with the norm a head, no window, no gate, no yarn, 128 experts of
+    768, 8 a token; what it adds is no layer but how it is TRAINED, by
+    block diffusion (`diffusion_block`: models/block_diffusion.py runs
+    this stack over a clean and a noised copy of every sequence, and the
+    attention below then runs under ops/flash.py's `blockdiff` mask in
+    place of the causal one).
 
 What the stack adds to the one decoder of models/llama.py: `LagunaConfig`;
 the attention sublayer `attention_sublayer`; and a parameter tree and a
@@ -153,6 +161,9 @@ class LagunaConfig(moe.MoEConfig):
     qk_head_norm: bool = False    # an RMSNorm a head on q and k, before the rotary
     first_dense_layers: int = 1
     dense_d_ff: int = 12288
+    # trained by block diffusion (models/block_diffusion.py) where not 0: the length of a
+    # block of positions
+    diffusion_block: int = 0
     # models/llama.py's seam: the module that builds this tree and runs these layers
     stack_module: str = "ray_tpu.models.laguna"
 
@@ -253,6 +264,27 @@ MELLUM2_TINY = dataclasses.replace(
     rope_full=dataclasses.replace(MELLUM2_12B_A2_5B.rope_full, theta=50.0, original_max=32,
                                   factor=8.0),
     rope_sliding=Rotary(50.0), dense_d_ff=96,
+)
+# JetLM/SDAR-30B-A3B-Chat config.json (the catalog's row): 48 layers alike, full attention at
+# 32 / 4 heads of 128, 128 routed experts of width 768, 8 a token, renormalised; no window
+# (`use_sliding_window` false), no rope scaling; `intermediate_size` 6144 is used by no layer
+# (`mlp_only_layers` empty). Blocks of 4 positions: the SDAR release's default, no key of the config
+SDAR_30B_A3B = LagunaConfig(
+    vocab_size=151936, d_model=2048, n_layers=48, n_heads=32, n_kv_heads=4, d_ff=768,
+    max_seq=32768, rope_theta=1000000.0, rms_eps=1e-6, tie_embeddings=False,
+    n_experts=128, top_k=8, norm_topk_prob=True, qk_norm=False,
+    router_aux_coeff=0.0, router_z_coeff=0.0, router_score="softmax", routed_scaling=1.0,
+    shared_d_ff=0, head_dim=128, layer_types=(FULL,) * 48, heads_per_layer=(),
+    sliding_window=0, rope_full=Rotary(1000000.0), rope_sliding=Rotary(1000000.0),
+    attn_gate="none", qk_head_norm=True, first_dense_layers=0, dense_d_ff=6144,
+    diffusion_block=4,
+)
+# four layers, small: groups of 4 query heads, blocks of 4 in a sequence of 40 (two copies: 80
+# rows, no multiple of a tile)
+SDAR_TINY = dataclasses.replace(
+    SDAR_30B_A3B, vocab_size=512, d_model=64, n_layers=4, n_heads=8, n_kv_heads=2, d_ff=32,
+    max_seq=256, remat=False, n_experts=16, top_k=4, head_dim=16, layer_types=(FULL,) * 4,
+    rope_full=Rotary(50.0), rope_sliding=Rotary(50.0), dense_d_ff=96,
 )
 
 
@@ -398,7 +430,11 @@ def attention_sublayer(h: jax.Array, x: jax.Array, lp: Params, c: LagunaConfig, 
         with jax.named_scope(f"{scope}.attend"):
             o = attention_head_major(q, k, v, causal=True, segment_ids=segment_ids,
                                      impl=c.attention_impl,
-                                     window=c.sliding_window if sliding else None)
+                                     window=c.sliding_window if sliding else None,
+                                     # trained by block diffusion, the rows are a clean and a
+                                     # noised copy of a sequence (a window beside it is refused)
+                                     blockdiff=(S // 2, c.diffusion_block)
+                                     if c.diffusion_block else None)
             # saved by the "dots" remat policy, as llama._block's is
             o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
         if c.attn_gate == "per-head":

@@ -185,6 +185,19 @@ def _dsa(config: LlamaConfig):
     return dsa
 
 
+def _block_diffusion(config: LlamaConfig):
+    """models/block_diffusion.py when the configuration is trained by
+    block diffusion (`diffusion_block` not 0: a clean and a noised copy of
+    every sequence under a mask of blocks, a weighted denoising loss), else
+    None: the seam at which the step's OBJECTIVE is chosen. The layers stay
+    the configuration's own."""
+    if not getattr(config, "diffusion_block", 0):
+        return None
+    from ray_tpu.models import block_diffusion
+
+    return block_diffusion
+
+
 def _carries_router_state(config: LlamaConfig) -> bool:
     """An MLP router adds the previous layer's state to its own: the
     layer scan then carries (hidden state, router state)."""
@@ -614,7 +627,14 @@ def loss_and_weight_fn(
 
     Uses the fused lm-head + CE (nn/layers.py fused_cross_entropy_loss):
     the [T, V] fp32 logits/softmax pipeline was ~36% of the flagship
-    train step before fusion (round-5 profile)."""
+    train step before fusion (round-5 profile).
+
+    A configuration trained by block diffusion has another objective
+    (models/block_diffusion.py: it reads `tokens` alone, and the step
+    count train/step.py hands in as `batch["step"]` for its key)."""
+    diffusion = _block_diffusion(config)
+    if diffusion is not None:
+        return diffusion.loss_and_weight(params, batch, config)
     h_last, stats, block = _trunk(
         params, batch["tokens"], config, segment_ids=batch.get("segment_ids")
     )

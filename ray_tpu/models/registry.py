@@ -24,7 +24,15 @@ trained, not served, through that same module: three sliding-window
 (1024) layers to one full layer at ONE head count (GQA 32 / 4 of an
 explicit 128), an RMSNorm a head on q and k, no gate, yarn over the whole
 head of the full layers, no dense layer, softmax top-8 of 64 experts
-chosen with a selection bias and renormalised, no shared expert; the
+chosen with a selection bias and renormalised, no shared expert;
+SDAR-30B-A3B, `sdar-30b-a3b`, through that same module, TRAINED BY BLOCK
+DIFFUSION, not served, not generated from: 48 full-attention layers alike
+at GQA 32 / 4 of an explicit 128 with the norm a head, no window, softmax
+top-8 of 128 experts of width 768 renormalised, no shared expert; a
+training step runs a clean and a noised copy of every sequence under a
+mask of blocks of 4 positions (ops/flash.py's `blockdiff`) and takes a
+weighted denoising loss over the masked positions
+(models/block_diffusion.py); the
 language model of
 Keye-VL-2.0-30B-A3B, `keye-vl-2.0-30b-a3b`, trained, not served: GQA 32 /
 4 at heads of an explicit 128 with an RMSNorm a head on q and k, over the
@@ -33,7 +41,7 @@ Keye-VL-2.0-30B-A3B, `keye-vl-2.0-30b-a3b`, trained, not served: GQA 32 /
 top-8 of 128 experts chosen with a selection bias and renormalised; its
 vision tower and image or video inputs are refused by name. Every expert
 configuration is TRAINING only: the serving engine refuses a MoEConfig,
-ZAYA1, GLM-4.7-Flash, Laguna, Mellum2 and Keye by name), and which runs a stack of
+ZAYA1, GLM-4.7-Flash, Laguna, Mellum2, SDAR and Keye by name), and which runs a stack of
 its own for Olmo-Hybrid-7B, `olmo-hybrid-7b`, trained, not served: three
 gated-delta-rule linear-attention layers (30 heads, keys of 96, values of
 192, a causal convolution of 4; models/olmo_hybrid.py and
@@ -137,6 +145,8 @@ for _name, _cfg in {
     "laguna-tiny": laguna.LAGUNA_TINY,
     "mellum2-12b-a2.5b": laguna.MELLUM2_12B_A2_5B,
     "mellum2-tiny": laguna.MELLUM2_TINY,
+    "sdar-30b-a3b": laguna.SDAR_30B_A3B,
+    "sdar-tiny": laguna.SDAR_TINY,
 }.items():
     register_model(_name, _cfg)
 
@@ -361,6 +371,48 @@ def _mellum_from_hf(hf: dict, **overrides) -> laguna.LagunaConfig:
     return config
 
 
+def _sdar_from_hf(hf: dict, **overrides) -> laguna.LagunaConfig:
+    """`model_type` "sdar_moe" (JetLM/SDAR-30B-A3B-Chat) onto
+    models/laguna.py's stack: the Qwen3-MoE block in every layer, full
+    attention at ONE head count and an explicit `head_dim`, an RMSNorm a
+    head on q and k (ASSUMED from the lineage, as Mellum2's and Keye's),
+    the plain rotary at `rope_theta`, softmax top-k experts renormalised
+    by `norm_topk_prob`, no shared expert; trained by block diffusion in
+    blocks of 4 positions (ASSUMED: the release's default; the config has
+    no key for the block length or the noise schedule, and an override
+    `diffusion_block=` names another). What the stack does not implement
+    is refused by name."""
+    n = hf["num_hidden_layers"]
+    scaling = hf.get("rope_scaling") or {}
+    refused = {
+        f"rope_scaling type {scaling.get('rope_type', scaling.get('type'))!r}":
+            scaling.get("rope_type", scaling.get("type", "default")) != "default",
+        "a sliding window": bool(hf.get("use_sliding_window")) or hf.get("sliding_window") is not None,
+        "attention_bias": bool(hf.get("attention_bias")),
+        f"decoder_sparse_step {hf.get('decoder_sparse_step')}": hf.get("decoder_sparse_step", 1) != 1,
+        f"mlp_only_layers {hf.get('mlp_only_layers')}": bool(hf.get("mlp_only_layers")),
+        f"hidden_act {hf.get('hidden_act')!r}": hf.get("hidden_act", "silu") != "silu",
+        "a shared expert": bool(hf.get("shared_expert_intermediate_size")),
+    }
+    if any(refused.values()):
+        raise ValueError("a sdar_moe config with "
+                         + ", ".join(k for k, v in refused.items() if v) + " is not supported")
+    rotary = laguna.Rotary(float(hf["rope_theta"]))
+    fields = dict(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"], n_layers=n,
+        n_heads=hf["num_attention_heads"], n_kv_heads=hf["num_key_value_heads"],
+        d_ff=hf["moe_intermediate_size"], max_seq=hf["max_position_embeddings"],
+        rope_theta=float(hf["rope_theta"]), rms_eps=float(hf["rms_norm_eps"]),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        n_experts=hf["num_experts"], top_k=hf["num_experts_per_tok"],
+        norm_topk_prob=bool(hf["norm_topk_prob"]), head_dim=hf["head_dim"],
+        layer_types=(laguna.FULL,) * n, rope_full=rotary, rope_sliding=rotary,
+        dense_d_ff=hf["intermediate_size"],
+    )
+    fields.update(overrides)  # caller wins on collisions
+    return dataclasses.replace(laguna.SDAR_30B_A3B, **fields)
+
+
 def _keye_from_hf(hf: dict, **overrides):
     """`model_type` "KeyeVL2" (Kwai-Keye/Keye-VL-2.0-30B-A3B): the LANGUAGE
     model's keys, at the top level or under `text_config`: GQA at an
@@ -524,7 +576,9 @@ def config_from_hf(hf: dict, **overrides):
     Laguna (`model_type` "laguna": `rope_parameters` by layer type, yarn
     among them, an explicit `head_dim`, head counts by layer): see
     `_laguna_from_hf`. Mellum2 (`model_type` "mellum": the same stack at one
-    head count, a norm a head, no gate): see `_mellum_from_hf`. The language
+    head count, a norm a head, no gate): see `_mellum_from_hf`. SDAR
+    (`model_type` "sdar_moe": that stack's full layers alone, trained by
+    block diffusion): see `_sdar_from_hf`. The language
     model of Keye-VL-2.0 (`model_type`
     "KeyeVL2"): see `_keye_from_hf`. Olmo-Hybrid (`model_type`
     "olmo_hybrid": linear-attention layers beside full ones, no rotary):
@@ -542,6 +596,8 @@ def config_from_hf(hf: dict, **overrides):
         return _laguna_from_hf(hf, **overrides)
     if hf.get("model_type") == "mellum":
         return _mellum_from_hf(hf, **overrides)
+    if hf.get("model_type") == "sdar_moe":
+        return _sdar_from_hf(hf, **overrides)
     if hf.get("model_type") == "KeyeVL2":
         return _keye_from_hf(hf, **overrides)
     if hf.get("model_type") == "olmo_hybrid":

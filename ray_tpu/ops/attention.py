@@ -38,12 +38,16 @@ def xla_attention(
     softmax_scale: Optional[float] = None,
     window: Optional[int] = None,
     selection: Optional[jax.Array] = None,
+    blockdiff: Optional[tuple] = None,
 ) -> jax.Array:
     """Reference GQA attention. fp32 softmax, bf16 matmuls. `window`: a
     row sees the `window` keys up to and including its own (sliding-window
     attention; a causal mask's). `selection`: which keys each row sees,
     the same for every head, packed as the flash kernels take it
-    (ops/flash.py::pack_selection); ANDed with the other masks."""
+    (ops/flash.py::pack_selection); ANDed with the other masks.
+    `blockdiff` = (L, beta): the block-diffusion mask over 2L rows and 2L
+    keys, a clean copy and a noised copy after it (`block_diffusion_mask`;
+    `causal` then says nothing)."""
     B, Sq, H, D = q.shape
     _, Sk, K, _ = k.shape
     group = _repeat_kv_heads(q, k)
@@ -56,6 +60,11 @@ def xla_attention(
     scores = scores * scale
 
     mask = None
+    if blockdiff is not None:
+        if (Sq, Sk) != (2 * blockdiff[0],) * 2 or window is not None or segment_ids is not None:
+            raise ValueError(f"block diffusion {blockdiff}: 2L rows and keys, no window, no "
+                             f"segments; got {Sq} x {Sk}")
+        causal, mask = False, block_diffusion_mask(*blockdiff)[None, None, None]
     if causal:
         q_pos = jnp.arange(Sq)[:, None] + q_offset
         k_pos = jnp.arange(Sk)[None, :]
@@ -78,6 +87,19 @@ def xla_attention(
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
     return out.reshape(B, Sq, H, D)
+
+
+def block_diffusion_mask(L: int, beta: int) -> jax.Array:
+    """bool [2L, 2L], row sees key: rows and keys 0 .. L-1 are the clean
+    copy of a sequence, L .. 2L-1 its noised copy (row L + i is position
+    i), blocks of `beta` positions. Clean -> clean: the key's block is not
+    after the row's (the row's own block whole: not causal inside it);
+    clean -> noised: never; noised -> clean: the key's block is BEFORE the
+    row's; noised -> noised: the same block, both ways."""
+    at = jnp.arange(2 * L)
+    noised, block = at >= L, (at % L) // beta
+    rn, kn, rb, kb = noised[:, None], noised[None, :], block[:, None], block[None, :]
+    return jnp.where(kn, rn & (kb == rb), jnp.where(rn, kb < rb, kb <= rb))
 
 
 def _flash_over_mesh(q, k, v, segment_ids, *, head_axis: int = 2, **kw) -> jax.Array:
@@ -105,8 +127,9 @@ def _flash_over_mesh(q, k, v, segment_ids, *, head_axis: int = 2, **kw) -> jax.A
     )
     if all(mesh.shape[a] == 1 for a in auto):
         return flash_attention(q, k, v, segment_ids=segment_ids, **kw)
-    if kw.get("selection") is not None:
-        raise NotImplementedError("a selection of keys under a multi-device mesh")
+    if kw.get("selection") is not None or kw.get("blockdiff") is not None:
+        raise NotImplementedError(
+            "a selection of keys or a block-diffusion mask under a multi-device mesh")
     rules = current_rules()
     qspec, kvspec = (
         rules.spec(tuple(heads if i == head_axis else axis
@@ -190,6 +213,7 @@ def attention_head_major(
     impl: str = "xla",
     window: Optional[int] = None,
     selection: Optional[jax.Array] = None,
+    blockdiff: Optional[tuple] = None,
 ) -> jax.Array:
     """`attention` for a caller whose heads are a major dimension, the
     tile (S, D): -> [B, H, S, D]. That is the flash kernels' own layout,
@@ -198,15 +222,19 @@ def attention_head_major(
     `window` (None: every key before the row): sliding-window attention,
     which the flash kernels and the XLA composite implement; so with
     `selection` (None: none), the packed mask of a learned indexer
-    (models/dsa.py)."""
-    if (window is not None or selection is not None) and impl not in ("flash", "xla"):
-        raise ValueError(f"attention impl {impl!r} has no sliding window and no selection")
+    (models/dsa.py); so with `blockdiff` (None: none), the block-diffusion
+    mask over a clean and a noised copy (models/block_diffusion.py)."""
+    masked = window is not None or selection is not None or blockdiff is not None
+    if masked and impl not in ("flash", "xla"):
+        raise ValueError(f"attention impl {impl!r} has no sliding window and no selection, "
+                         "nor a block-diffusion mask")
     if impl == "flash":
         return _flash_over_mesh(q, k, v, segment_ids, head_axis=1, causal=causal,
-                                window=window, selection=selection)
-    if window is not None or selection is not None:
+                                window=window, selection=selection, blockdiff=blockdiff)
+    if masked:
         o = xla_attention(*(jnp.swapaxes(x, 1, 2) for x in (q, k, v)), causal=causal,
-                          segment_ids=segment_ids, window=window, selection=selection)
+                          segment_ids=segment_ids, window=window, selection=selection,
+                          blockdiff=blockdiff)
         return jnp.swapaxes(o, 1, 2)
     o = attention(*(jnp.swapaxes(x, 1, 2) for x in (q, k, v)), causal=causal,
                   segment_ids=segment_ids, impl=impl)

@@ -42,6 +42,25 @@ flash/ragged lineage to this framework. Design:
    block holds one such block or several whole ones. It is ANDed into
    the mask of every sub-tile the causal walk visits, forward and
    backward, where it is a constant; no sub-tile is skipped for it;
+ * BLOCK DIFFUSION (`blockdiff=(L, beta)`, static; None: the kernels as
+   they were) is a fourth kind of mask, a function of the two positions
+   alone, so it costs no bytes: 2L rows, a clean copy of a sequence of L
+   and a noised copy after it, row L + i at position i. A clean row sees
+   the clean keys up to the end of its own block of `beta` positions
+   (NOT causal inside the block); a noised row sees the clean keys of the
+   blocks BEFORE its own, and the noised keys of its own block, both
+   ways. The kernels run the 2L rows against the L CLEAN keys alone,
+   where each row's visible keys are a prefix (`_visible_end`), so the
+   walk is the causal walk's prefix of sub-tiles, the kv sequence is one
+   block up to 8,192 keys at heads of 128 and the backward the fused
+   kernel; the noised keys a noised row sees are the `beta` of its own
+   block, a [beta, beta] product a block in plain jax.numpy, merged with
+   the kernels' (o, lse) by the log-sum-exp (`_block_diffusion`). One
+   walk over 2L x 2L would visit the same prefixes and one more sub-tile
+   a noised q block (L (L + beta) visible pairs a head either way), over
+   four kv blocks of 4,096 at L = 8,192 with the dq and dk/dv kernels
+   apart: seven matmuls a pair for five, and k and v fetched again for
+   every q block (PR 53's note below);
  * off-TPU the same kernels run under the Pallas interpreter, so CPU
    tests exercise the real code path.
 
@@ -178,6 +197,18 @@ from ray_tpu import obs
 # core's 128 as the fused backward holds them), a dq kernel that walks
 # sub-tiles, or a kv fetch clamped to the diagonal would give is a
 # `perf_opt`'s to measure: the cell is there to hold whatever it changes.
+#
+# The block-diffusion mask (PR 55), READ and not tuned, from the traced step of
+# `sdar-train-8k` on a v5e (heads of 128, GQA 32 / 4, 2L = 16,384 rows against the L = 8,192
+# clean keys in ONE kv block, bf16, blocks of 4; `python3 -m chipbench.tools.step_table`, seed
+# 3000000019; ms a layer): forward 7.20, fused backward 17.47, for 272 sub-tile visits a head:
+# twice PR 43's 3.97 / 9.54 at 136 visits under a selection, 79.2% of what the L (L + 4)
+# visible pairs need at the MXU's peak. The noised blocks' own keys, merged OUTSIDE the kernels
+# (`_block_diffusion`, the scope `flash.blockdiff_merge`), cost 8.8 ms a layer of XLA's slices,
+# copies and converts (call 6 of PR 55, the same seed): a block of 4 rows is half a tile of 8
+# sublanes. One more sub-tile a noised q block inside the kernels (a
+# second k / v input that holds the same global rows as the q block) would be about 1.5 ms a
+# layer in their place: ROADMAP S18(a).
 DEFAULT_BLOCK_Q = 512
 # The keys 32 bits x 128 lanes address: a block of a packed selection, the
 # most a kv block held before PR 43, and the kv block of a sequence over
@@ -255,7 +286,21 @@ def _sub_k(Bk: int) -> int:
     return _SUB_K if Bk % _SUB_K == 0 else Bk
 
 
-def _tiles_to_run(i, j, Bq, Bk, Tk, *, causal, q_offset, window=None):
+def _visible_end(i, Bq, blockdiff):
+    """One past the last CLEAN key some row of q block `i` sees under
+    `blockdiff` = (L, beta) (traced): rows r < L are the clean copy (row r
+    sees the keys up to the end of r's block), rows L + p the noised copy
+    (the keys of the blocks before p's). Every row's visible keys are a
+    prefix, so a q block's are the longest of its rows'; a q block may
+    hold the last clean rows and the first noised ones."""
+    L, beta = blockdiff
+    first, last = i * Bq, i * Bq + Bq - 1
+    clean = jnp.where(first < L, (jnp.minimum(last, L - 1) // beta + 1) * beta, 0)
+    noised = jnp.where(last >= L, jnp.maximum(last - L, 0) // beta * beta, 0)
+    return jnp.minimum(jnp.maximum(clean, noised), L)  # rows past 2L are padding
+
+
+def _tiles_to_run(i, j, Bq, Bk, Tk, *, causal, q_offset, window=None, blockdiff=None):
     """Which of the Bk // Tk sub-tiles of kv block `j` q block `i` meets:
     (first, how many). Those whose first key is after the block's last
     row lie wholly above the diagonal and are skipped: without a window
@@ -265,7 +310,10 @@ def _tiles_to_run(i, j, Bq, Bk, Tk, *, causal, q_offset, window=None):
     the FIRST row's r - window lie wholly below the window and are
     skipped too: a range. At 4096 keys, rows and sub-tiles of 512 and a
     window of 512 that is the diagonal's sub-tile and the one before it,
-    15 visits of the causal walk's 36."""
+    15 visits of the causal walk's 36. Under `blockdiff` the ones that
+    run are the prefix that holds a key before `_visible_end`."""
+    if blockdiff is not None:
+        return 0, jnp.clip(_visible_end(i, Bq, blockdiff) - j * Bk + Tk - 1, 0, Bk) // Tk
     if not causal:
         return 0, Bk // Tk
     end = jnp.clip(q_offset + (i + 1) * Bq - j * Bk + Tk - 1, 0, Bk) // Tk
@@ -306,6 +354,16 @@ def _block_in_window(i, j, Bq, Bk, *, q_offset, window):
     return below & ((j + 1) * Bk > q_offset + i * Bq - window + 1)
 
 
+def _blockdiff_kv_block_of(i, j, Bq, Bk, *, blockdiff):
+    """`_kv_block_of` under `blockdiff`: `j` clamped to the kv blocks that
+    hold a key before `_visible_end`. Several kv blocks under this mask
+    (this clamp, the dq kernel's skip) are run by NO cell: L = 8,192 clean
+    keys at heads of 128 are one kv block and the fused backward. The form
+    is held by tests/test_flash_blockdiff.py's `split_*` cases alone, for
+    a sequence over 8,192 (ISSUE 55 asked for both backward forms)."""
+    return jnp.minimum(j, jnp.maximum(_visible_end(i, Bq, blockdiff) - 1, 0) // Bk)
+
+
 def _kv_block_of(i, j, Bq, Bk, n, *, q_offset, window):
     """The kv block q block `i` fetches at grid step `j` under a window
     (several kv blocks): `j` clamped to the blocks that hold a visible
@@ -333,7 +391,7 @@ def _tile_start(t, Tk):
 
 
 def _block_mask(i, k_base, F, Bq, Tk, *, causal, q_offset, sq_valid, sk_valid,
-                kpad, qpad, qseg_ref, kseg, window=None, sel=None):
+                kpad, qpad, qseg_ref, kseg, window=None, sel=None, blockdiff=None):
     """[F*Bq, Tk] validity mask for q-block i vs kv positions starting at
     k_base, or None.
 
@@ -344,9 +402,9 @@ def _block_mask(i, k_base, F, Bq, Tk, *, causal, q_offset, sq_valid, sk_valid,
     selection.
     """
     mask = None
-    if causal or kpad:
+    if causal or kpad or blockdiff is not None:
         k_pos = k_base + jax.lax.broadcasted_iota(jnp.int32, (1, Tk), 1)
-    if causal or qpad:
+    if causal or qpad or blockdiff is not None:
         q_pos = (
             q_offset + i * Bq
             + jax.lax.broadcasted_iota(jnp.int32, (Bq, 1), 0)
@@ -361,6 +419,13 @@ def _block_mask(i, k_base, F, Bq, Tk, *, causal, q_offset, sq_valid, sk_valid,
         if window is not None:  # both edges: the diagonal and the window's far side
             cm = cm & (q_pos - k_pos < window)
         mask = cm if mask is None else mask & cm
+    if blockdiff is not None:
+        # a row's visible clean keys are a prefix: one limit a row, one compare a pair
+        L, beta = blockdiff
+        noised = q_pos >= L
+        block = jax.lax.div(jnp.where(noised, q_pos - L, q_pos), jnp.int32(beta))
+        bm = k_pos < (block + jnp.where(noised, 0, 1)) * beta
+        mask = bm if mask is None else mask & bm
     if kseg is not None:
         sm = qseg_ref[0] == kseg  # [Bq,1] == [1,Tk]
         mask = sm if mask is None else mask & sm
@@ -484,6 +549,7 @@ def _fwd_kernel(
     has_segments: bool,
     kpad: bool,
     window: Optional[int] = None,
+    blockdiff: Optional[tuple] = None,
     sel_ref=None,  # [1, Bq, 128] int32: the kv block's packed selection, or None
 ):
     i = pl.program_id(2)
@@ -525,7 +591,7 @@ def _fwd_kernel(
             i, j * Bk + lo, F, Bq, Tk, causal=causal, q_offset=q_offset, sq_valid=0,
             sk_valid=sk_valid, kpad=kpad, qpad=False, qseg_ref=qseg_ref,
             kseg=kseg_ref[0, :, pl.ds(lo, Tk)] if has_segments else None, window=window,
-            sel=None if sel_ref is None else _sel_tile(sel_ref, lo, Tk))
+            sel=None if sel_ref is None else _sel_tile(sel_ref, lo, Tk), blockdiff=blockdiff)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
 
@@ -553,7 +619,7 @@ def _fwd_kernel(
         )
 
     _walk_tiles(_tiles_to_run(i, j, Bq, Bk, Tk, causal=causal, q_offset=q_offset,
-                              window=window), tile)
+                              window=window, blockdiff=blockdiff), tile)
 
     @pl.when(j == nk - 1)
     def _():
@@ -584,6 +650,7 @@ def _dq_kernel(
     has_segments: bool,
     kpad: bool,
     window: Optional[int] = None,
+    blockdiff: Optional[tuple] = None,
     sel_ref=None,
 ):
     i = pl.program_id(2)
@@ -598,9 +665,15 @@ def _dq_kernel(
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
     # the kv block is ONE sub-tile here: skipped where wholly above the diagonal
-    # (or wholly below the window)
-    @pl.when(_block_in_window(i, j, Bq, Bk, q_offset=q_offset, window=window)
-             if causal else True)
+    # (or wholly below the window, or past the last key a block-diffusion row sees)
+    if blockdiff is not None:
+        needed = _visible_end(i, Bq, blockdiff) > j * Bk
+    elif causal:
+        needed = _block_in_window(i, j, Bq, Bk, q_offset=q_offset, window=window)
+    else:
+        needed = True
+
+    @pl.when(needed)
     def _():
         q = q_ref[0].reshape(rows, D)
         do = do_ref[0].reshape(rows, D)
@@ -621,7 +694,7 @@ def _dq_kernel(
             i, j * Bk, F, Bq, Bk, causal=causal, q_offset=q_offset, sq_valid=0,
             sk_valid=sk_valid, kpad=kpad, qpad=False, qseg_ref=qseg_ref,
             kseg=kseg_ref[0] if has_segments else None, window=window,
-            sel=None if sel_ref is None else _sel_tile(sel_ref, 0, Bk))
+            sel=None if sel_ref is None else _sel_tile(sel_ref, 0, Bk), blockdiff=blockdiff)
         if mask is not None:
             p = jnp.where(mask, p, 0.0)  # [rows, Bk]
         dp = jax.lax.dot_general(
@@ -667,6 +740,7 @@ def _dkv_kernel(
     kpad: bool,
     qpad: bool,
     window: Optional[int] = None,
+    blockdiff: Optional[tuple] = None,
     fused_dq: bool = False,
     dq_ref=None,  # fused mode only: [1, F, Bq, D], written per (h, i)
     dq_scr=None,  # fused mode only: [F*Bq, D] fp32 (sub-tile accumulator)
@@ -718,7 +792,7 @@ def _dkv_kernel(
             i, jk * Bk + lo, F, Bq, Tk, causal=causal, q_offset=q_offset, sq_valid=sq_valid,
             sk_valid=sk_valid, kpad=kpad, qpad=qpad, qseg_ref=qseg_ref,
             kseg=kseg_ref[0, :, pl.ds(lo, Tk)] if has_segments else None, window=window,
-            sel=None if sel_ref is None else _sel_tile(sel_ref, lo, Tk))
+            sel=None if sel_ref is None else _sel_tile(sel_ref, lo, Tk), blockdiff=blockdiff)
         if mask is not None:
             p = jnp.where(mask, p, 0.0)
         dv_scr[pl.ds(lo, Tk)] += jax.lax.dot_general(
@@ -743,7 +817,7 @@ def _dkv_kernel(
             )
 
     _walk_tiles(_tiles_to_run(i, jk, Bq, Bk, Tk, causal=causal, q_offset=q_offset,
-                              window=window), tile)
+                              window=window, blockdiff=blockdiff), tile)
 
     if fused_dq:
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype).reshape(F, Bq, D)
@@ -759,10 +833,13 @@ def _dkv_kernel(
 # ---------------------------------------------------------------------------
 
 
-def _kv_fetch(window, nk, block_q, block_k, q_offset):
+def _kv_fetch(window, nk, block_q, block_k, q_offset, blockdiff=None):
     """(i, j) -> the kv block a (q block, grid step) fetches: step `j`'s
-    own, or under a window over several kv blocks the nearest one that
-    holds a visible key (`_kv_block_of`)."""
+    own, or under a window or a block-diffusion mask over several kv
+    blocks the nearest one that holds a visible key (`_kv_block_of`)."""
+    if blockdiff is not None and nk > 1:
+        return functools.partial(_blockdiff_kv_block_of, Bq=block_q, Bk=block_k,
+                                 blockdiff=blockdiff)
     if window is None or nk == 1:
         return lambda i, j: j
     return functools.partial(_kv_block_of, Bq=block_q, Bk=block_k, n=nk, q_offset=q_offset,
@@ -770,7 +847,7 @@ def _kv_fetch(window, nk, block_q, block_k, q_offset):
 
 
 def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
-              sk_valid, interpret, has_segments, fold, window=None, sel=None):
+              sk_valid, interpret, has_segments, fold, window=None, sel=None, blockdiff=None):
     B, H, Sq_pad, D = q.shape
     _, KVH, Sk_pad, _ = k.shape
     G = H // KVH
@@ -782,8 +859,9 @@ def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
         _fwd_kernel, scale=scale, causal=causal,
         q_offset=q_offset, sk_valid=sk_valid,
         has_segments=has_segments, kpad=sk_valid != Sk_pad, window=window,
+        blockdiff=blockdiff,
     )
-    kv = _kv_fetch(window, nk, block_q, block_k, q_offset)
+    kv = _kv_fetch(window, nk, block_q, block_k, q_offset, blockdiff)
     return pl.pallas_call(
         kernel if sel is None else _with_selection(kernel, 5),
         grid=(B, HG, nq, nk),
@@ -848,7 +926,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, do_ref,
 
 def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
               block_q, block_k, sq_valid, sk_valid, interpret, has_segments,
-              fold, dlse=None, window=None, sel=None):
+              fold, dlse=None, window=None, sel=None, blockdiff=None):
     B, H, Sq_pad, D = q.shape
     _, KVH, Sk_pad, _ = k.shape
     G = H // KVH
@@ -875,7 +953,7 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
                 _bwd_fused_kernel, scale=scale, causal=causal,
                 q_offset=q_offset, sq_valid=sq_valid, sk_valid=sk_valid,
                 group=G // F, has_segments=has_segments, kpad=kpad, qpad=qpad,
-                window=window,
+                window=window, blockdiff=blockdiff,
             )),
             grid=(B, 1, HG, nq),  # q-blocks fastest, then groups per kv head
             in_specs=[
@@ -909,12 +987,12 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
         )(*operands)
         return dq, dk, dv
 
-    kv = _kv_fetch(window, nk, block_q, block_k, q_offset)
+    kv = _kv_fetch(window, nk, block_q, block_k, q_offset, blockdiff)
     dq = pl.pallas_call(
         selected(functools.partial(
             _dq_kernel, scale=scale, causal=causal,
             q_offset=q_offset, sk_valid=sk_valid,
-            has_segments=has_segments, kpad=kpad, window=window,
+            has_segments=has_segments, kpad=kpad, window=window, blockdiff=blockdiff,
         )),
         grid=(B, HG, nq, nk),
         in_specs=[
@@ -945,7 +1023,7 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
             _dkv_kernel, scale=scale, causal=causal,
             q_offset=q_offset, sq_valid=sq_valid, sk_valid=sk_valid,
             group=G // F, has_segments=has_segments, kpad=kpad, qpad=qpad,
-            window=window,
+            window=window, blockdiff=blockdiff,
         )),
         grid=(B, nk, HG, nq),  # q-blocks fastest, then groups per kv head
         in_specs=[
@@ -984,25 +1062,25 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
 # ONE custom-vjp pair serves both public forms: flash_attention with
 # return_lse=False simply drops the lse output (its cotangent arrives
 # as zeros and `delta - 0` is a no-op in the backward).
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11))
 def _flash_lse(scale, causal, q_offset, block_q, block_k, sq_valid, sk_valid,
-               interpret, has_segments, fold, window, q, k, v, qseg, kseg, sel=None):
+               interpret, has_segments, fold, window, blockdiff, q, k, v, qseg, kseg, sel=None):
     """(o, lse) with a DIFFERENTIABLE lse — ring attention merges
     per-block results through lse, so its cotangent must reach ds.
     `sel`: the packed selection (None: none; no operand of the call then)."""
     (o, lse), _ = _flash_lse_fwd(
         scale, causal, q_offset, block_q, block_k, sq_valid, sk_valid,
-        interpret, has_segments, fold, window, q, k, v, qseg, kseg, sel,
+        interpret, has_segments, fold, window, blockdiff, q, k, v, qseg, kseg, sel,
     )
     return o, lse
 
 
 def _flash_lse_fwd(scale, causal, q_offset, block_q, block_k, sq_valid,
-                   sk_valid, interpret, has_segments, fold, window, q, k, v, qseg,
+                   sk_valid, interpret, has_segments, fold, window, blockdiff, q, k, v, qseg,
                    kseg, sel=None):
     o, lse = _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset,
                        block_q, block_k, sk_valid, interpret, has_segments,
-                       fold, window, sel)
+                       fold, window, sel, blockdiff)
     # named residuals: under jax.checkpoint, the backward re-runs this
     # whole kernel just to rebuild (o, lse) unless the remat policy can
     # SAVE them — the "dots" policy recognizes dot_general outputs, not a
@@ -1013,14 +1091,15 @@ def _flash_lse_fwd(scale, causal, q_offset, block_q, block_k, sq_valid,
 
 
 def _flash_lse_bwd(scale, causal, q_offset, block_q, block_k, sq_valid,
-                   sk_valid, interpret, has_segments, fold, window, residuals, cts):
+                   sk_valid, interpret, has_segments, fold, window, blockdiff, residuals, cts):
     do, dlse = cts
     q, k, v, qseg, kseg, o, lse, sel = residuals
     # one span a call, WHILE TRACING, says which backward it took
     with obs.layer_span("flash.bwd_fused" if k.shape[2] == block_k else "flash.bwd_split"):
         dq, dk, dv = _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal,
                                q_offset, block_q, block_k, sq_valid, sk_valid,
-                               interpret, has_segments, fold, dlse=dlse, window=window, sel=sel)
+                               interpret, has_segments, fold, dlse=dlse, window=window, sel=sel,
+                               blockdiff=blockdiff)
     zero_seg = np.zeros(qseg.shape, dtype=jax.dtypes.float0)
     zero_kseg = np.zeros(kseg.shape, dtype=jax.dtypes.float0)
     # the selection is a constant of the backward: integers take no cotangent
@@ -1080,6 +1159,7 @@ def _flash_head_major(
     fold_heads: Optional[int] = None,
     window: Optional[int] = None,
     selection: Optional[jax.Array] = None,  # `pack_selection`'s [B, Sq, kv blocks x 128]
+    blockdiff: Optional[tuple] = None,  # (L, beta): 2L rows against the L clean keys
 ) -> tuple[jax.Array, jax.Array]:
     """The kernels' own layout, which both public forms come down to:
     -> (o [B, H, Sq, D], lse [B, H, Sq_pad, 1])."""
@@ -1087,6 +1167,15 @@ def _flash_head_major(
         raise ValueError(f"a sliding window ({window}) is a causal mask's: window >= 1, causal")
     B, H, Sq, _ = qt.shape
     _, KVH, Sk, _ = kt.shape
+    if blockdiff is not None:
+        L, beta = blockdiff
+        if (causal or window is not None or selection is not None or segment_ids is not None
+                or kv_segment_ids is not None or q_offset or beta < 1 or L % beta
+                or Sq != 2 * L or Sk != L):
+            raise ValueError(
+                f"a block-diffusion mask {blockdiff} stands alone (no diagonal, window, "
+                f"selection, segments or offset) over 2L rows and L keys in whole blocks: "
+                f"got {Sq} rows, {Sk} keys")
     if selection is not None:
         words = (B, Sq, _selection_layout(Sk)[1] * _LANES)
         if block_k is not None or selection.shape != words or selection.dtype != jnp.int32:
@@ -1135,9 +1224,69 @@ def _flash_head_major(
     fold = _fold_factor(H // KVH, bq, bk, fold_heads)
     # the scale is in q already: the kernels' own is 1
     statics = (1.0, causal, q_offset, bq, bk, Sq, Sk, interpret,
-               has_segments, fold, window)
+               has_segments, fold, window, blockdiff)
     o, lse = _flash_lse(*statics, qt, kt, vt, qseg, kseg, selection)
     return o[:, :, :Sq, :], lse
+
+
+def blockdiff_tiles(L: int, beta: int, block_q: int = DEFAULT_BLOCK_Q, head_dim: int = _LANES,
+                    itemsize: int = 2) -> dict:
+    """What the walk under `blockdiff` = (L, beta) costs a head, counted
+    as the kernels count it (`_visible_end`, sub-tiles of `_SUB_K` keys,
+    q blocks of `block_q` rows): {"visited": (q block, sub-tile) visits of
+    the 2L rows over the L clean keys, "causal": the visits of a causal
+    walk over 2L rows and 2L keys, "visible_pairs": L (L + beta), the
+    pairs the mask leaves visible, the noised blocks' own among them}."""
+    bq = min(block_q, _round_up(2 * L, 16))
+    tk = _sub_k(default_block_k(L, head_dim, itemsize))
+    q_blocks = np.arange(-(-2 * L // bq))
+    with jax.ensure_compile_time_eval():  # shapes' arithmetic: a number, also under a trace
+        ends = np.asarray(_visible_end(q_blocks, bq, blockdiff=(L, beta)))  # every q block
+    causal = np.minimum(-(-(q_blocks + 1) * bq // tk), -(-2 * L // tk))
+    return {"visited": int((-(-ends // tk)).sum()), "causal": int(causal.sum()),
+            "visible_pairs": L * (L + beta)}
+
+
+def _block_diffusion(q, k, v, blockdiff, **blocks):
+    """Attention of 2L rows [clean copy; noised copy] under the
+    block-diffusion mask `blockdiff` = (L, beta): q [B, H, 2L, D], k and v
+    [B, KVH, 2L, D], head-major, -> o [B, H, 2L, D]. Every row against the
+    CLEAN keys through the kernels (a prefix a row: `_flash_head_major`
+    under `blockdiff`, which also gives each row's log-sum-exp); a noised
+    row's own block of `beta` noised keys, both ways, as one [beta, beta]
+    product a block in float32, merged by the log-sum-exp: softmax over
+    the union of two key sets is each set's softmax weighed by
+    exp(its lse - the common max). A noised row of block 0 sees no clean
+    key: the kernels give it lse ~ NEG_INF, which weighs nothing.
+    `blocks`: `block_q` / `block_k` of the kernels' call (the tests')."""
+    L, beta = blockdiff
+    B, H, S, D = q.shape
+    KVH, G, K = k.shape[1], H // k.shape[1], L // beta
+    if S != 2 * L or k.shape[2] != S or L % beta:
+        raise ValueError(f"block diffusion {blockdiff}: 2L rows and 2L keys in whole blocks, "
+                         f"got {S} rows, {k.shape[2]} keys")
+    q = _fold_scale(q, None)
+    # the kernels' own name in a trace and in the compiled step (`flash.blockdiff.N`); a
+    # table of the step books them to the model's scope around this call
+    with jax.named_scope("flash.blockdiff"):
+        o1, lse1 = _flash_head_major(q, k[:, :, :L], v[:, :, :L], causal=False,
+                                     segment_ids=None, blockdiff=blockdiff, **blocks)
+    f32 = jnp.float32
+    # everything outside the kernels under a scope of its own, forward and backward: a table
+    # of the step (chipbench/step_scopes/sdar.json) reads what the merge costs apart
+    with jax.named_scope("flash.blockdiff_merge"):
+        # a block's own [beta, beta] product in float32: a thousandth of the kernels' pairs
+        qn = q[:, :, L:].reshape(B, KVH, G, K, beta, D).astype(f32)
+        kn, vn = (x[:, :, L:].reshape(B, KVH, K, beta, D).astype(f32) for x in (k, v))
+        s2 = jnp.einsum("bkgnid,bknjd->bkgnij", qn, kn)
+        lse_n = lse1[:, :, L:2 * L, 0].reshape(B, KVH, G, K, beta)
+        m = jnp.maximum(lse_n, s2.max(-1))
+        w1 = jnp.exp(lse_n - m)  # the clean keys' whole weight
+        p2 = jnp.exp(s2 - m[..., None])
+        own = jnp.einsum("bkgnij,bknjd->bkgnid", p2, vn)
+        clean = o1[:, :, L:].reshape(B, KVH, G, K, beta, D).astype(f32)
+        on = (w1[..., None] * clean + own) / (w1 + p2.sum(-1))[..., None]
+        return jnp.concatenate([o1[:, :, :L], on.astype(o1.dtype).reshape(B, H, L, D)], axis=2)
 
 
 def flash_attention(
@@ -1197,6 +1346,7 @@ def flash_attention_head_major(
     segment_ids: Optional[jax.Array] = None,  # [B, S] (requires Sq == Sk)
     window: Optional[int] = None,
     selection: Optional[jax.Array] = None,
+    blockdiff: Optional[tuple] = None,  # (L, beta): the block-diffusion mask over 2L rows
 ) -> jax.Array:
     """`flash_attention` for a caller that holds the heads as a major
     dimension already (the attention sublayers of models/llama.py,
@@ -1204,8 +1354,13 @@ def flash_attention_head_major(
     layout, so nothing is transposed on the way in or out.
     `flash_attention` is its three transposes, this, and one back: the
     path of the [B, S, H, D] callers that are left (ring attention's
-    per-shard calls, the kernel's tests), no model's."""
+    per-shard calls, the kernel's tests), no model's. `blockdiff` (None:
+    none; `causal` then says nothing): `_block_diffusion`."""
     _check_shapes(q.shape[1], k.shape[1], q.shape[2], k.shape[2], 0, segment_ids, None)
+    if blockdiff is not None:
+        if segment_ids is not None or window is not None or selection is not None:
+            raise ValueError("a block-diffusion mask stands alone: no segments, window, selection")
+        return _block_diffusion(q, k, v, blockdiff)
     o, _ = _flash_head_major(_fold_scale(q, None), k, v, causal=causal,
                              segment_ids=segment_ids, window=window, selection=selection)
     return o

@@ -117,6 +117,14 @@ def make_train_step(
     masked batches match the unaccumulated result; scalar-returning loss
     fns get uniform weights (exact only when every microbatch has the same
     number of valid tokens).
+
+    A batch that is a dict reaches loss_fn with one more entry, `step`:
+    how many times loss_fn was evaluated before (int32 scalar: the
+    optimizer's step count, and under grad_accum that count x grad_accum +
+    the microbatch's index, so no two microbatches are handed the same),
+    which is what an objective that draws its own noise folds into its key
+    (models/block_diffusion.py). An objective that does not read it
+    compiles to the program it always was.
     """
     if mesh is not None and rules is None:
         from ray_tpu.parallel.sharding import default_rules
@@ -157,16 +165,22 @@ def make_train_step(
                 ),
                 batch,
             )
+        def counted(batch, index=None):
+            if not isinstance(batch, dict):
+                return batch
+            return {**batch, "step": state.step if index is None
+                    else state.step * grad_accum + index}
+
         if grad_accum == 1:
-            loss, _, grads, stats = compute_grads(state.params, batch)
+            loss, _, grads, stats = compute_grads(state.params, counted(batch))
         else:
             micro = jax.tree.map(
                 lambda x: x.reshape((grad_accum, x.shape[0] // grad_accum) + x.shape[1:]),
                 batch,
             )
 
-            def accum(carry, mb):
-                loss_i, w, g, stats_i = compute_grads(state.params, mb)
+            def accum(carry, indexed):
+                loss_i, w, g, stats_i = compute_grads(state.params, counted(*indexed))
                 acc_loss, acc_w, acc_g = carry
                 new = (
                     acc_loss + loss_i * w,
@@ -180,7 +194,8 @@ def make_train_step(
                 jnp.zeros((), jnp.float32),
                 jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), state.params),
             )
-            (loss_sum, w_sum, grad_sum), stats = jax.lax.scan(accum, zero, micro)
+            (loss_sum, w_sum, grad_sum), stats = jax.lax.scan(
+                accum, zero, (micro, jnp.arange(grad_accum, dtype=state.step.dtype)))
             loss = loss_sum / w_sum
             with jax.named_scope("optim"):
                 grads = jax.tree.map(lambda g: g / w_sum, grad_sum)

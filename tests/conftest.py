@@ -202,6 +202,28 @@ _NINE_CELLS_ONE_WINDOW_CELL_AND_8K_OF_TRAFFIC = {
 }
 
 
+# PR 55 added the eleventh cell, `sdar-train-8k`, and seven metrics after PR 53's five. Each
+# case of tests/chipbench/test_chipbench_mellum2.py below takes PR 53's entries off the
+# manifest and holds what is left to the nine-cell benchmark (or finds PR 53's five at the end
+# of `per_layer`). That file is the accepted benchmark's (`paths` of BENCHMARK.json) and a PR
+# may not edit it, so the cases cannot be rewritten to read the manifest of their own day:
+# they are skipped here and tests/chipbench/test_chipbench_sdar.py runs each, every assertion
+# and every parameter, on the manifest less what PR 55 appended
+# (`test_mellum2s_test_holds_on_the_manifest_less_what_pr_55_appended`). That file's own
+# cases read the manifest AS PR 55 LEFT IT (`as_this_pr_left_it`), so the next cell needs no
+# third list here.
+_TEN_CELLS_AND_PR_53S_TAIL = {
+    ("test_chipbench_mellum2.py", "test_nothing_the_parent_had_is_changed_but_by_the_cell_appended"),
+    ("test_chipbench_mellum2.py", "test_joined_metric_keeps_its_entry_and_its_cells_in_their_order"),
+    ("test_chipbench_mellum2.py",
+     "test_the_attention_familys_list_keeps_twotower_and_gains_this_cell"),
+    ("test_chipbench_mellum2.py",
+     "test_the_host_timelines_eight_are_reported_by_every_training_cell"),
+    ("test_chipbench_mellum2.py",
+     "test_twotowers_new_metric_is_as_it_entered_on_the_manifest_less_what_came_later"),
+}
+
+
 # The one case of the lane that holds what a JITTED program computes to what the same
 # functions give taken bare, BIT FOR BIT (a runner's parameters, initialised inside its
 # program, against `init_params` called operation by operation): the unoptimised CPU programs
@@ -221,6 +243,12 @@ def _optimised_where_two_programs_are_held_bit_for_bit(request):
         return
     was = jax.config.read("jax_disable_most_optimizations")
     jax.config.update("jax_disable_most_optimizations", False)
+    # the side taken bare runs operation by operation, and an operation's executable is kept a
+    # PROCESS: one that an earlier file of this worker compiled without the passes (a tiny
+    # model's [512, 64] tables have the same shapes in every model's tests) would be used again
+    # here, whatever the flag says now (PR 55: a new file moved pytest-xdist's order, and the
+    # case failed in the lane where it passed alone)
+    jax.clear_caches()
     yield
     jax.config.update("jax_disable_most_optimizations", was)
 
@@ -229,6 +257,12 @@ def pytest_collection_modifyitems(items):
     for item in items:
         name = getattr(item, "originalname", None)
         file = os.path.basename(str(item.fspath))
+        if (file, name) in _TEN_CELLS_AND_PR_53S_TAIL:
+            item.add_marker(pytest.mark.skip(
+                reason="spells out ten cells or per_layer ending with PR 53's five, as before "
+                       "PR 55; test_chipbench_sdar.py runs it on the manifest less what PR 55 "
+                       "appended"))
+            continue
         if (file, name) in _NINE_CELLS_ONE_WINDOW_CELL_AND_8K_OF_TRAFFIC:
             only = _NINE_CELLS_ONE_WINDOW_CELL_AND_8K_OF_TRAFFIC[(file, name)]
             if only is None or item.callspec.params.get(only[0]) == only[1]:

@@ -1,5 +1,5 @@
 """What the model files (tests/test_zaya.py, test_glm_lite.py,
-test_laguna.py (Laguna and Mellum2: one stack), test_keye.py,
+test_laguna.py (Laguna, Mellum2 and SDAR: one stack), test_keye.py,
 test_olmo_hybrid.py, test_nemotron_h.py, test_moe.py), the files of their
 train paths (tests/test_contract_<model>.py) and
 tests/test_model_contract.py share. No test lives here (pytest does not
@@ -35,8 +35,10 @@ import numpy as np
 import pytest
 
 from chipbench.reference import (glm_lite_decoder, keye_decoder, laguna_decoder, mellum2_decoder,
-                                 nemotron_h_decoder, olmo_hybrid_decoder, zaya_decoder)
-from ray_tpu.models import cca, dsa, laguna, llama, mla, nemotron_h, olmo_hybrid
+                                 nemotron_h_decoder, olmo_hybrid_decoder, sdar_decoder,
+                                 zaya_decoder)
+from ray_tpu.models import (block_diffusion, cca, dsa, laguna, llama, mla, nemotron_h,
+                            olmo_hybrid)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -320,6 +322,22 @@ def mellum2_shape(cfg) -> dict:
     return {**shape, "num_attention_heads": cfg.n_heads}
 
 
+def sdar_shape(cfg) -> dict:
+    """A LagunaConfig of SDAR's kind as the configuration file's dict (HF key names)."""
+    return {
+        "hidden_size": cfg.d_model, "head_dim": cfg.head_dim,
+        "num_attention_heads": cfg.n_heads, "num_key_value_heads": cfg.n_kv_heads,
+        "num_hidden_layers": cfg.n_layers, "rope_theta": cfg.rope_full.theta,
+        "rms_norm_eps": cfg.rms_eps, "num_experts": cfg.n_held,
+        "published": {"num_experts": cfg.n_experts},
+        "deployment": {"first_expert_held": cfg.first_expert_held},
+        "num_experts_per_tok": cfg.top_k, "norm_topk_prob": cfg.norm_topk_prob,
+        "max_position_embeddings": cfg.max_seq, "tie_word_embeddings": cfg.tie_embeddings,
+        "vocab_size": cfg.vocab_size,
+        "block_diffusion": {"block_length": cfg.diffusion_block, "eps": block_diffusion.EPS},
+    }
+
+
 def keye_shape(cfg) -> dict:
     """A KeyeConfig as the configuration file's dict (HF key names)."""
     return {
@@ -445,6 +463,23 @@ MELLUM2 = Model(
     # the reference walks its queries in blocks: four of them at this size
     reference_set_up=lambda: mock.patch.object(mellum2_decoder, "QUERY_BLOCK", 16),
 )
+SDAR = Model(
+    name="sdar", fp32=dataclasses.replace(laguna.SDAR_TINY, dtype=jnp.float32),
+    batch=2, seq=40,   # ten blocks of 4; two copies: 80 rows, no multiple of a tile of 32
+    reference=sdar_decoder, shape_of=sdar_shape, n_keys=64, bias=0.0, norms=_mellum2_norms,
+    preset="sdar-30b-a3b", tiny="sdar-tiny", refused_as="SDAR", catalog="SDAR-30B-A3B-Chat",
+    config_file="sdar-30b-a3b-train.json",
+    facts={"head_dim": 128, "diffusion_block": 4,
+           "layer_types": ("full_attention",) * 48, "rope_full.theta": 1000000.0,
+           "rope_full.rope_type": "default", "rope_full.partial": 1.0, "attn_gate": "none",
+           "qk_head_norm": True, "heads_per_layer": (), "first_dense_layers": 0, "shared_d_ff": 0,
+           "routed_scaling": 1.0, "n_experts": 128, "top_k": 8, "d_ff": 768, "n_kv_heads": 4,
+           "max_seq": 32768},
+    remat_plain={}, remat_bias=0.05, remat_tol=_REMAT_TOL,
+    bf16=dict(attention_impl="flash"), bf16_rel=0.02, tokens=skewed_tokens,
+    # the reference walks its queries in blocks: five of them at this size
+    reference_set_up=lambda: mock.patch.object(sdar_decoder, "QUERY_BLOCK", 16),
+)
 KEYE = Model(
     name="keye", fp32=dataclasses.replace(dsa.KEYE_TINY, dtype=jnp.float32),
     batch=2, seq=64,   # topk 16 and chunks of 16 queries: the first chunk computes no score
@@ -534,4 +569,4 @@ NEMOTRON_H = Model(
     bf16=dict(attention_impl="flash"), bf16_rel=0.02,
     tokens=skewed_tokens, reference_set_up=contextlib.nullcontext,
 )
-MODELS = (ZAYA, GLM_LITE, LAGUNA, MELLUM2, KEYE, OLMO_HYBRID, NEMOTRON_H)
+MODELS = (ZAYA, GLM_LITE, LAGUNA, MELLUM2, SDAR, KEYE, OLMO_HYBRID, NEMOTRON_H)

@@ -1,4 +1,5 @@
-"""The steps of `glm47f-train`, `laguna-train`, `mellum2-train-16k` and
+"""The steps of `glm47f-train`, `laguna-train`, `mellum2-train-16k`,
+`sdar-train-8k` (PR 55: lowered; its compile is a case marked slow) and
 `olmoe-train` for a described v5e (tests/v5e_steps.py). GLM-4.7-Flash's (8
 of 64 experts and an eighth of the vocabulary held) LOWERED at the cell's
 depth and batch, once for all of its cases: the text it had, and MLA
@@ -55,6 +56,14 @@ MELLUM2 = dict(batch=1, model="mellum2-12b-a2.5b", n_layers=4, seq=16384, vocab_
 # sha256 of the lowered step of mellum2-12b-a2.5b as `mellum2-train-16k` builds it (PR 53: the
 # rehearsal's rung (b)); rung (a), 16 held and a quarter of the vocabulary, lowered to 8e98e744...
 _MELLUM2_STEP = "a0a2e204465b7f4b8138cd94b51485d95ebcd8e36613a481dfaede246ebf0d1d"
+# `sdar-train-8k`'s step (PR 55: the rehearsal's rung (a)): 4 full layers of the same module
+# trained by block diffusion, 16 of 128 experts and Keye's eighth of the vocabulary held, ONE
+# sequence of 8,192 tokens = 16,384 rows
+SDAR = dict(batch=1, model="sdar-30b-a3b", n_layers=4, seq=8192, vocab_size=19072,
+            experts_held=16)
+SDAR_SCOPES = ("diff.corrupt", "diff.loss", "attn.qkv", "attn.norm", "attn.rope", "attn.attend",
+               "flash.blockdiff", "attn.out", "moe.router", "moe.dispatch", "moe.experts",
+               "moe.combine", "block.norm", "block.stack", "embed", "head", "optim")
 OLMOE = dict(batch=6, model="olmoe-1b-7b", n_layers=1)
 # sha256 of the lowered step of olmoe-1b-7b as `olmoe-train` builds it (one layer, batch 6):
 # the dense steps' block with the q/k norm, so PR 38's text too (36d2bc29... from PR 33's
@@ -279,6 +288,61 @@ def test_mellum2_train_step_lowers_to_the_text_it_had_over_four_kv_blocks(v5e):
     # 8 held experts' weights and no more, the router's 64 outputs whole, q at one head count
     assert "8x2304x896x" in text and "64x2304x896x" not in text and "2304x64x" in text
     assert "1x32x16384x128xbf16" in text and "1x4x16384x128xbf16" in text
+
+
+def test_sdar_train_step_lowers_to_the_masked_kernels_and_the_fused_backward(v5e):
+    """The eleventh cell's step, LOWERED for the described chip (its compile
+    is 60 s of every core: the case marked slow below, and the rehearsal in
+    the configuration file's `reduced`): the SAME module as Laguna's and
+    Mellum2's (`laguna.attn`), the flash kernels under the block-diffusion
+    mask at one site a direction of the layer scan under a name of their
+    own, 2L = 16,384 rows against the L = 8,192 clean keys in ONE kv block,
+    so the backward is the fused kernel and `fallback_sites.train` reads 0;
+    the expert blocks built compact over both copies' rows; no site on
+    `ragged_dot`; the head on the L noised rows alone."""
+    from ray_tpu import obs
+
+    sites = ("laguna.attn", "moe.ffn", "grouped_matmul.kernel", "grouped_matmul.ragged_dot",
+             "moe.compact", "moe.full", "flash.bwd_fused", "flash.bwd_split")
+    count = lambda: {n: obs.layer_counters().get(n, {"count": 0})["count"] for n in sites}  # noqa: E731
+    before = count()
+    step = train_step(v5e, **SDAR)
+    kernels = step.lowered_kernels
+    engaged = {n: c - before[n] for n, c in count().items()}
+    # the expert layer's `cond`: nine kernels over the held rows, eleven over all rows
+    assert kernels.count("flash.blockdiff") == 2 and len(grouped_kernels(kernels)) == 20
+    assert engaged["laguna.attn"] >= 1 and engaged["moe.compact"] >= 1 and engaged["moe.full"] == 0
+    assert engaged["grouped_matmul.kernel"] > 0 and engaged["grouped_matmul.ragged_dot"] == 0
+    assert (engaged["flash.bwd_fused"], engaged["flash.bwd_split"]) == (1, 0)
+    text = step.lowered_text
+    # both copies' rows through the layers, the clean keys alone through the kernels, the
+    # noised rows alone through the head, 16 held experts' weights and the router's 128 outputs
+    assert "1x32x16384x128xbf16" in text and "1x4x8192x128xbf16" in text
+    assert "8192x19072xf32" in text and "16384x19072xf32" not in text
+    assert "16x2048x768x" in text and "128x2048x768x" not in text and "2048x128x" in text
+
+
+@pytest.mark.parametrize("scope", SDAR_SCOPES)
+def test_sdar_train_step_keeps_every_scope_its_readers_sum(v5e, scope):
+    assert train_step(v5e, **SDAR).has_scope(scope, lowered=True), scope
+
+
+@pytest.mark.slow
+def test_sdar_train_step_compiles_with_mosaics_kernels_and_no_remat_of_the_compilers(v5e):
+    """The rehearsal of ISSUE 55's rungs, outside the tier-1 clock (60 s of
+    every core): rung (a) COMPILED for the described v5e with the remat
+    policy "dots" as it is: the masked flash kernels are Mosaic's (two
+    `tpu_custom_call`s named `flash.blockdiff.N`: the forward and the fused
+    backward of the layer scan), NONE of the compiler's own
+    rematerialisations, and the bytes the configuration file's `reduced`
+    states (arguments 5.10 GiB, temporaries 11.45 by the compiler's count,
+    which holds both bodies of the expert layer's `cond`)."""
+    step = train_step(v5e, **SDAR)
+    masked = [k for k in step.kernels if k.startswith("flash.blockdiff")]
+    assert len(masked) == 2 and len(grouped_kernels(step.kernels)) == 20
+    assert not re.findall(r"%([\w.\-]+\.remat[\w.\-]*) = ", step.hlo)
+    assert step.memory.argument_size_in_bytes < 5.2 * 2 ** 30
+    assert step.memory.temp_size_in_bytes < 11.6 * 2 ** 30
 
 
 def test_olmoe_train_step_lowers_to_the_text_it_had(v5e):

@@ -15,8 +15,8 @@ import operator
 
 import pytest
 
-from model_cases import (GLM_LITE, KEYE, LAGUNA, MELLUM2, MODELS, NEMOTRON_H, OLMO_HYBRID, Model,
-                         catalog_config)
+from model_cases import (GLM_LITE, KEYE, LAGUNA, MELLUM2, MODELS, NEMOTRON_H, OLMO_HYBRID, SDAR,
+                         Model, catalog_config)
 from ray_tpu.models.registry import config_from_hf, get_model_config
 
 by_name = pytest.mark.parametrize("model", MODELS, ids=lambda m: m.name)
@@ -60,6 +60,14 @@ def test_config_from_hf_maps_the_catalogs_config_onto_the_preset(model):
     (MELLUM2, "num_attention_heads_per_layer", [32] * 28, "num_attention_heads_per_layer"),
     (MELLUM2, "shared_expert_intermediate_size", 896, "a shared expert"),
     (MELLUM2, "hidden_act", "gelu", "hidden_act 'gelu'"),
+    (SDAR, "rope_scaling", {"rope_type": "yarn", "factor": 4}, "rope_scaling type 'yarn'"),
+    (SDAR, "use_sliding_window", True, "a sliding window"),
+    (SDAR, "sliding_window", 4096, "a sliding window"),
+    (SDAR, "attention_bias", True, "attention_bias"),
+    (SDAR, "mlp_only_layers", [0], "mlp_only_layers"),
+    (SDAR, "decoder_sparse_step", 2, "decoder_sparse_step 2"),
+    (SDAR, "hidden_act", "gelu", "hidden_act 'gelu'"),
+    (SDAR, "shared_expert_intermediate_size", 768, "a shared expert"),
     (KEYE, "vision_config", {"depth": 27}, "vision tower or image / video inputs .vision_config."),
     (KEYE, "image_token_id", 151655, "image_token_id"),
     (KEYE, "sa_config", None, "no sa_config"),

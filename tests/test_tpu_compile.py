@@ -90,6 +90,36 @@ def test_flash_attention_compiles_at_16384_keys_over_four_kv_blocks(v5e, grad, w
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
+def test_flash_attention_compiles_under_the_block_diffusion_mask(v5e, grad):
+    """`sdar-train-8k`'s shape (PR 55): 32 / 4 heads of 128, 2L = 16,384 rows
+    (a clean and a noised copy of ONE sequence of 8,192) under `blockdiff`
+    (8192, 4). The kernels run the 16,384 rows against the 8,192 CLEAN
+    keys: one kv block (the fused backward, which states its own VMEM
+    limit at these bytes, as at `keye-train-8k`), a trip count a q block
+    from `_visible_end`, a row's limit from an integer division inside
+    the kernel: Mosaic's to accept, not the interpreter's. One kernel
+    forward, one backward; the noised blocks' own [4, 4] products are
+    XLA's."""
+    from ray_tpu.ops import flash
+
+    assert flash.default_block_k(8192, 128, 2) == 8192
+    assert flash._fused_bwd_params(512, 8192, 128, 1, 2) is not None
+
+    def fwd(q, k, v):
+        return flash.flash_attention_head_major(q, k, v, blockdiff=(8192, 4))
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    with mock.patch("jax.default_backend", return_value="tpu"):   # flash's interpret switch
+        hlo = compile_kernel(bwd if grad else fwd, ((1, 32, 16384, 128), _BF16),
+                             ((1, 4, 16384, 128), _BF16), ((1, 4, 16384, 128), _BF16),
+                             sharding=one_chip(v5e))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == (2 if grad else 1)
+    assert "flash.blockdiff" in hlo
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
 def test_flash_attention_compiles_at_heads_of_256_with_the_kernels_own_vmem(v5e, grad):
     """MLA's shape (models/mla.py): 20 heads of 256, none shared, 4096
     keys. The fused backward holds 24 MiB of kv blocks there, over the 16
