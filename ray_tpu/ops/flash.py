@@ -28,7 +28,11 @@ flash/ragged lineage to this framework. Design:
  * backward: dQ accumulates over kv blocks; dK/dV accumulate over
    (q-heads in the group x q-blocks) with the grid ordered so the
    kv-block output is revisited until the group finishes — the
-   standard flash-2 recomputation from the stored log-sum-exp;
+   standard flash-2 recomputation from the stored log-sum-exp. With the
+   whole kv sequence in ONE block (the default wherever a k block is
+   within KV_BLOCK_BYTES: every cell since PR 56) the dk/dv kernel
+   emits dq too, one s / p / dp a pair (the FUSED backward); the two
+   kernels apart are a longer sequence's;
  * segment ids (packed sequences) and right-padding are handled by
    masking; fully-masked rows produce zeros (matching xla_attention);
  * a SELECTION (`selection=`, None = none: the kernels as they were) is
@@ -52,15 +56,13 @@ flash/ragged lineage to this framework. Design:
    ways. The kernels run the 2L rows against the L CLEAN keys alone,
    where each row's visible keys are a prefix (`_visible_end`), so the
    walk is the causal walk's prefix of sub-tiles, the kv sequence is one
-   block up to 8,192 keys at heads of 128 and the backward the fused
+   block up to 16,384 keys at heads of 128 and the backward the fused
    kernel; the noised keys a noised row sees are the `beta` of its own
    block, a [beta, beta] product a block in plain jax.numpy, merged with
    the kernels' (o, lse) by the log-sum-exp (`_block_diffusion`). One
    walk over 2L x 2L would visit the same prefixes and one more sub-tile
    a noised q block (L (L + beta) visible pairs a head either way), over
-   four kv blocks of 4,096 at L = 8,192 with the dq and dk/dv kernels
-   apart: seven matmuls a pair for five, and k and v fetched again for
-   every q block (PR 53's note below);
+   16,384 keys: one kv block too since PR 56, which was not tried;
  * off-TPU the same kernels run under the Pallas interpreter, so CPU
    tests exercise the real code path.
 
@@ -166,37 +168,47 @@ from ray_tpu import obs
 # 1.50 / 1.24 times 512 x 512 a visit: a loss forward, 7% backward. Not
 # tried on the chip.
 #
-# The kv block at 16,384 keys (PR 53): what PR 43 left untried, READ and
-# not tuned, from the traced step of `mellum2-train-16k` on a v5e (heads of
-# 128, GQA 32 / 4, ONE sequence of 16,384, bf16; `python3 -m
-# chipbench.tools.step_table`, seed 3000000007; ms a kernel and step). A k
-# block of the whole sequence would be 4 MiB, over KV_BLOCK_BYTES, so the kv
-# block is MAX_BLOCK_K, four of them, one head a program (`_fold_rows_cap`
-# 512) and the backward the dq and the dk/dv kernels apart.
-#  * The full layer: forward 19.41, dq 25.13, dk/dv 29.37: 73.9 ms where the
-#    causal pairs need 39.1 at the MXU's peak, 52.9% (the fused kernels at
-#    8,192 keys under a selection read 34% of ITS pairs, PR 43); the forward
-#    is 4.9 x PR 43's 3.97 ms at 8,192 keys for 4.0 x the pairs; the backward
-#    runs seven matmuls a pair where the fused kernel runs five.
-#  * A sliding layer (window 1,024): forward 4.27, dq 12.54-12.94, dk/dv
-#    5.92-6.14: 22.7-23.4 ms where the pairs INSIDE the window need 4.87,
-#    20.6%. The dq kernel takes a whole [512, 4096] kv block a grid step,
-#    one or two a q block where 1,535 keys are visible, and costs twice the
-#    dk/dv kernel, which walks sub-tiles; the forward visits three or four
-#    sub-tiles a q block where two sub-tiles' worth of pairs is required.
-#  * k and v fetched, against the 32 MiB a layer they hold (2 x 4 heads x
-#    16,384 x 128 x 2 B), by the index maps: without a window every (head, q
-#    block) fetches all four kv blocks, those above the diagonal too (fetched,
-#    not computed): 32 x 32 x 4 x 2 MiB = 8 GiB in the forward and 8 GiB in
-#    the dq kernel, 256 times what they hold, 10 ms each at the HBM's rate
-#    where nothing hides them; under the window `_kv_block_of` names the
-#    resident block, a head fetches each kv block once and the pair at a
-#    block's edge a few times more: about 20 MiB a head, 0.65 GiB a layer and
-#    kernel, 20 times what they hold.
-# What a larger KV_BLOCK_BYTES (16,384 keys in ONE block are 48 MiB of the
-# core's 128 as the fused backward holds them), a dq kernel that walks
-# sub-tiles, or a kv fetch clamped to the diagonal would give is a
-# `perf_opt`'s to measure: the cell is there to hold whatever it changes.
+# The kv block at 16,384 keys (PR 56), measured on a v5e in the traced step of
+# `mellum2-train-16k` (heads of 128, GQA 32 / 4, ONE sequence of 16,384, bf16,
+# three layers under a window of 1,024 and one full; `python3 -m
+# chipbench.tools.step_table`, seed 3000000007, parent and change in one call;
+# ms a kernel and step). PR 53 ran FOUR kv blocks of MAX_BLOCK_K (a k block of
+# the whole sequence is 4 MiB, KV_BLOCK_BYTES was 2) and read what that cost;
+# since PR 56 the budget is 4 MiB and the sequence is ONE block:
+#  * the full layer: forward 19.41 -> 13.53, backward 54.50 (dq 25.13 + dk/dv
+#    29.37, seven matmuls a pair) -> 33.37 fused (five): 73.9 -> 46.9 ms, 52.9
+#    -> 83.3% of what the causal pairs need at the MXU's peak. 528 sub-tile
+#    visits a head x 32 heads: 0.80 us a visit forward, 1.98 backward (PR 43
+#    read 0.91 / 2.19 at 8,192 keys under a selection). Over four blocks every
+#    (head, q block) fetched all four, those above the diagonal too, 8 GiB a
+#    kernel and step for the 32 MiB k and v hold; over one a kv head's block
+#    stays resident for its 8 q heads and 32 q blocks;
+#  * a sliding layer: forward 4.27 -> 2.89-2.91, backward 18.5-19.1 (dq
+#    12.54-12.94, a WHOLE [512, 4096] block a grid step, + dk/dv 5.92-6.14) ->
+#    6.24-6.27 fused: 22.7-23.4 -> 9.13-9.17 ms, 20.6 -> 51.7% of what the pairs
+#    INSIDE the window need. 93 visits a head where 64 sub-tiles' worth is
+#    required (rows of 512 under 1,024 keys span 1,535): it cannot pass 69;
+#  * VMEM, stated by the kernels themselves (`_fwd_params`,
+#    `_fused_bwd_params`): the forward 25.25 MiB (k and v double-buffered 16,
+#    the rows and scratch 1.25, 8 spare), the fused backward 57 (k, v, dk, dv
+#    double-buffered 32, dk and dv in float32 16, the rows 1, 8 spare) of the
+#    core's 128; both compile and run with no option of the caller's;
+#  * the step 336.8 -> 268.4 ms busy, 48,190 -> 60,338 tokens/s (two pairs of
+#    untraced windows), `fallback_sites.train` 8 -> 0.
+# The same budget makes one block of 8,192 keys at heads of 256 or in float32
+# and of 16,384 at heads of 64 (the same 4 MiB as VMEM holds them): no cell
+# runs them; they compile for a described v5e (57 / 49.75 / 57 MiB stated) and
+# were not timed. Over the budget (32,768 keys at heads of 128; ring
+# attention's longer chunks) the kv block is MAX_BLOCK_K and the backward the
+# dq and dk/dv kernels apart, with `_kv_block_of` / `_q_block_of` under a
+# window: since PR 56 that form is run by NO cell and held by its tests alone
+# (tests/test_flash_window.py's four-kv-block cases, tests/test_tpu_compile.py
+# at an explicit `block_k=4096`, tests/test_flash_selection.py's
+# `two_kv_blocks`), as `_blockdiff_kv_block_of` is. What it costs there is
+# what PR 53 read: a dq kernel that takes a whole kv block a grid step (twice
+# the dk/dv kernel under a window) and k and v fetched again for every q block
+# without one. Whether 32,768 keys in one block (8 MiB a k block, 105 MiB as
+# the fused backward holds them) fit the core's 128 was not tried.
 #
 # The block-diffusion mask (PR 55), READ and not tuned, from the traced step of
 # `sdar-train-8k` on a v5e (heads of 128, GQA 32 / 4, 2L = 16,384 rows against the L = 8,192
@@ -215,18 +227,19 @@ DEFAULT_BLOCK_Q = 512
 # the budget below.
 MAX_BLOCK_K = 4096
 # The default kv block is the whole padded sequence where ONE k block of it,
-# as VMEM holds it (head_dim padded to the 128 lanes), is at most this: 4096
-# keys x head_dim 256 in bf16, the largest fused backward a cell ran before
-# PR 43 (`glm47f-train`), and so 8192 keys at heads of 128 in bf16
-# (`keye-train-8k`). Whether more fits the core's 128 MiB was not tried; what
-# 16,384 keys cost over four blocks of MAX_BLOCK_K is PR 53's note above.
-KV_BLOCK_BYTES = 2 << 20
+# as VMEM holds it (head_dim padded to the 128 lanes), is at most this: 16,384
+# keys at heads of 128 in bf16 (`mellum2-train-16k`, PR 56: measured above,
+# the fused backward at 57 MiB of the core's 128). It was 2 MiB from PR 43 to
+# PR 55 (8192 keys at heads of 128, `keye-train-8k`; 4096 x 256,
+# `glm47f-train`): every call within that chooses the block it chose.
+KV_BLOCK_BYTES = 4 << 20
 NEG_INF = -1e30  # true -inf breeds NaN via (-inf) - (-inf)
 
 
 def _fold_rows_cap(block_k: int) -> int:
     """VMEM-safe rows-per-program for a given kv block (measured: rows
-    1024 compiles at bk<=2048, only 512 at bk=4096)."""
+    1024 compiles at bk<=2048, only 512 at bk=4096; 512, one head a
+    program, is what 8192 and 16,384 keys run too: PR 43, PR 56)."""
     return 1024 if block_k <= 2048 else 512
 
 
@@ -360,7 +373,8 @@ def _blockdiff_kv_block_of(i, j, Bq, Bk, *, blockdiff):
     (this clamp, the dq kernel's skip) are run by NO cell: L = 8,192 clean
     keys at heads of 128 are one kv block and the fused backward. The form
     is held by tests/test_flash_blockdiff.py's `split_*` cases alone, for
-    a sequence over 8,192 (ISSUE 55 asked for both backward forms)."""
+    a sequence over the budget (16,384 keys since PR 56; ISSUE 55 asked for
+    both backward forms)."""
     return jnp.minimum(j, jnp.maximum(_visible_end(i, Bq, blockdiff) - 1, 0) // Bk)
 
 
@@ -833,6 +847,45 @@ def _dkv_kernel(
 # ---------------------------------------------------------------------------
 
 
+# What Mosaic scopes to one kernel by default on a TPU. The fused backward
+# holds the whole kv block: k, v, dk and dv double-buffered in the input
+# dtype, dk and dv again as float32 scratch. At head_dim 128 and 4096 keys
+# that is 12 MiB and fits; at head_dim 256 (MLA, models/mla.py), or at 8192
+# keys and head_dim 128 (models/dsa.py), it is 24 MiB, at 16,384 keys and
+# head_dim 128 (Mellum2's sequence, PR 56) 48, where the forward's k and v
+# double-buffered are the default's whole 16: the kernel then states its own
+# limit rather than lean on the caller's compile options (a train step's
+# 32 MiB, train/step.py).
+_DEFAULT_SCOPED_VMEM = 16 << 20
+_VMEM_HEADROOM = 8 << 20  # the [rows, sub_k] float32 products and Mosaic's own stack
+
+
+def _vmem_params(blocks: int):
+    """None (Mosaic's default) where a kernel's blocks fit the default scoped
+    VMEM, else the limit they need."""
+    if blocks <= _DEFAULT_SCOPED_VMEM:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=blocks + _VMEM_HEADROOM)
+
+
+def _fwd_params(block_q: int, block_k: int, D: int, F: int, itemsize: int):
+    """Compiler parameters of the forward: k and v double-buffered, q and o
+    double-buffered, the float32 scratch (m, l, the accumulator)."""
+    D = _round_up(D, _LANES)  # as VMEM holds a row
+    kv = 2 * 2 * block_k * D * itemsize
+    rows = 2 * 2 * F * block_q * D * itemsize + F * block_q * (2 * _LANES + D) * 4
+    return _vmem_params(kv + rows)  # 9.25 MiB at head_dim 128, bf16, 8192 keys
+
+
+def _fused_bwd_params(block_q: int, block_k: int, D: int, F: int, itemsize: int):
+    """Compiler parameters of the fused backward: None (Mosaic's default)
+    where its blocks fit the default scoped VMEM, else the limit they need."""
+    D = _round_up(D, _LANES)  # as VMEM holds a row
+    kv = 4 * 2 * block_k * D * itemsize + 2 * block_k * D * 4
+    rows = 3 * 2 * F * block_q * D * itemsize + F * block_q * D * 4
+    return _vmem_params(kv + rows)  # 13-14 MiB at head_dim 128, bf16, 4096 keys
+
+
 def _kv_fetch(window, nk, block_q, block_k, q_offset, blockdiff=None):
     """(i, j) -> the kv block a (q block, grid step) fetches: step `j`'s
     own, or under a window or a block-diffusion mask over several kv
@@ -887,29 +940,8 @@ def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
             pltpu.VMEM((F * block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=_fwd_params(block_q, block_k, D, F, q.dtype.itemsize),
     )(q, k, v, qseg, kseg, *(() if sel is None else (sel,)))
-
-
-# What Mosaic scopes to one kernel by default on a TPU. The fused backward
-# holds the whole kv block: k, v, dk and dv double-buffered in the input
-# dtype, dk and dv again as float32 scratch. At head_dim 128 and 4096 keys
-# that is 12 MiB and fits; at head_dim 256 (MLA, models/mla.py), or at 8192
-# keys and head_dim 128 (models/dsa.py), it is 24 MiB, and the kernel then
-# states its own limit rather than lean on the caller's compile options (a
-# train step's 32 MiB, train/step.py).
-_DEFAULT_SCOPED_VMEM = 16 << 20
-_VMEM_HEADROOM = 8 << 20  # the [rows, sub_k] float32 products and Mosaic's own stack
-
-
-def _fused_bwd_params(block_q: int, block_k: int, D: int, F: int, itemsize: int):
-    """Compiler parameters of the fused backward: None (Mosaic's default)
-    where its blocks fit the default scoped VMEM, else the limit they need."""
-    D = _round_up(D, _LANES)  # as VMEM holds a row
-    kv = 4 * 2 * block_k * D * itemsize + 2 * block_k * D * 4
-    rows = 3 * 2 * F * block_q * D * itemsize + F * block_q * D * 4
-    if kv + rows <= _DEFAULT_SCOPED_VMEM:  # 13-14 MiB at head_dim 128, bf16, 4096 keys
-        return None
-    return pltpu.CompilerParams(vmem_limit_bytes=kv + rows + _VMEM_HEADROOM)
 
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, do_ref,

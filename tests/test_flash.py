@@ -5,6 +5,8 @@ against torch reference impls); here the Pallas kernels run under the
 interpreter so CPU CI exercises the real code path.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -377,18 +379,22 @@ def test_rows_with_nothing_to_attend_weigh_nothing():
     assert not np.asarray(o).any() and (np.asarray(lse) < 0.9 * NEG_INF).all()
 
 
-# -- the kv block sized by its bytes (PR 43) -------------------------------------
+# -- the kv block sized by its bytes (PR 43; 4 MiB since PR 56) -------------------
 
 
 @pytest.mark.parametrize("D,itemsize,Sk,want", [
     (128, 2, 8192, 8192),    # keye-train-8k: one kv block, the backward fused
     (128, 2, 4096, 4096),    # every other cell: what the constant gave
-    (256, 2, 8192, 4096),    # MLA's heads at 8192 keys: over the budget, two kernels
-    (128, 4, 8192, 4096),    # float32 doubles a block's bytes
+    (256, 2, 8192, 8192),    # MLA's heads at 8192 keys: the bytes of 16,384 keys at heads of 128
+    (128, 4, 8192, 8192),    # float32 doubles a block's bytes: 4 MiB, the budget itself
     (64, 2, 8192, 8192),
-    (128, 2, 16384, 4096),   # a sequence over the budget keeps blocks of 4096
-    (64, 2, 16384, 4096),    # VMEM holds a row of 64 in the 128 lanes: no more keys than at 128
+    (128, 2, 16384, 16384),  # mellum2-train-16k (PR 56): one kv block of 4 MiB, the backward fused
+    (64, 2, 16384, 16384),   # VMEM holds a row of 64 in the 128 lanes: as many keys as at 128
+    (128, 2, 32768, 4096),   # a sequence over the budget keeps blocks of 4096, the kernels apart
+    (256, 2, 16384, 4096),
+    (128, 4, 16384, 4096),
     (128, 2, 5000, 8192),    # padded to whole selection blocks, as two blocks of 4096 were
+    (128, 2, 12289, 16384),
     (128, 2, 300, 304),      # a short sequence: itself, padded to the sublanes
     (256, 4, 4096, 4096),    # within 4096 keys the block was and is the sequence, whatever its bytes
 ], ids=lambda x: str(x))
@@ -396,6 +402,28 @@ def test_default_kv_block_is_the_sequence_where_its_bytes_fit(D, itemsize, Sk, w
     from ray_tpu.ops.flash import default_block_k
 
     assert default_block_k(Sk, D, itemsize) == want
+
+
+@pytest.mark.parametrize("kernel", ["forward", "fused_backward"])
+@pytest.mark.parametrize("D,itemsize,Sk,forward,fused_backward", [
+    (128, 2, 4096, None, None),    # nine cells' kernels: Mosaic's default, the programs they were
+    (128, 2, 8192, None, 33),      # keye / twotower / sdar: 24 + 1 + 8
+    (256, 2, 4096, None, 34),      # glm47f-train: 24 + 2 + 8
+    (128, 2, 16384, 25.25, 57),    # mellum2-train-16k (PR 56): 16 + 1.25 + 8 and 48 + 1 + 8
+    (128, 4, 8192, 25.75, 49.75),
+], ids=lambda x: str(x))
+def test_a_kernel_states_its_vmem_only_where_its_blocks_pass_the_default(
+        kernel, D, itemsize, Sk, forward, fused_backward):
+    """`_fwd_params` / `_fused_bwd_params` at one kv block of `Sk` keys, one
+    head of 512 rows a program, in MiB: None (no compiler parameter: the
+    kernel the cells below 16,384 keys compiled before PR 56) where the
+    blocks fit the 16 MiB Mosaic scopes by default, else the blocks + 8."""
+    from ray_tpu.ops import flash
+
+    fn, want = {"forward": (flash._fwd_params, forward),
+                "fused_backward": (flash._fused_bwd_params, fused_backward)}[kernel]
+    got = fn(512, flash.default_block_k(Sk, D, itemsize), D, 1, itemsize)
+    assert (got and got.vmem_limit_bytes / 2 ** 20) == want
 
 
 @pytest.mark.parametrize("bk,want", [(None, (1, 0)), (128, (0, 1))], ids=["fused", "split"])
@@ -415,22 +443,49 @@ def test_the_backward_counts_the_path_it_took_once_a_traced_call(backwards_trace
 
 @pytest.mark.parametrize("window,seg", [(None, False), (700, False), (None, True)],
                          ids=["causal", "window", "segments"])
-def test_one_kv_block_over_4096_keys_matches_xla(monkeypatch, backwards_traced, window, seg):
-    """4224 keys in ONE kv block of 8192 (the budget raised to the 4 MiB
-    that float32 at the 128 lanes needs; bf16 at heads of 128 fits the
-    module's own): seventeen sub-tiles a row block at most, the fused
-    backward, value and all three gradients, with no selection."""
+def test_one_kv_block_over_4096_keys_matches_xla(backwards_traced, window, seg):
+    """4224 keys in ONE kv block of 8192 (float32 at the 128 lanes: the 4 MiB
+    the module's own budget admits since PR 56): seventeen sub-tiles a row
+    block at most, the fused backward, value and all three gradients, with
+    no selection."""
     from ray_tpu.ops import flash
 
-    monkeypatch.setattr(flash, "KV_BLOCK_BYTES", 4 << 20)
-    B, S, H, KVH, D = 1, 4224, 2, 1, 32
-    assert flash.default_block_k(S, D, 4) == 8192
+    assert flash.default_block_k(4224, 32, 4) == 8192
+    _one_kv_block_against_xla(backwards_traced, 4224, 2, window, seg)
+
+
+@pytest.mark.parametrize("window", [None, 1024], ids=["causal", "window_1024"])
+def test_one_kv_block_over_8192_keys_matches_xla(monkeypatch, backwards_traced, window):
+    """PR 56, `mellum2-train-16k`'s walk at a size the lane affords: 8,320
+    keys in ONE kv block of 12,288 = three selection blocks' width (float32
+    at the 128 lanes: the budget raised to the 6 MiB that needs; bf16 at
+    16,384 keys fits the module's own), two query heads a key head, the
+    cell's window of 1,024 over sub-tiles of 512: up to seventeen sub-tiles
+    a row block without the window and three or four under it, the fused
+    backward, value and all three gradients."""
+    from ray_tpu.ops import flash
+
+    monkeypatch.setattr(flash, "KV_BLOCK_BYTES", 6 << 20)
+    assert flash.default_block_k(8320, 32, 4) == 12288
+    _one_kv_block_against_xla(backwards_traced, 8320, 2, window, False)
+
+
+# ONE function object a reference: a fresh lambda a case would be a fresh compile a case
+@functools.partial(jax.jit, static_argnames=("window",))
+def _xla_value_and_grads(q, k, v, probe, segs, window):
+    return jax.value_and_grad(lambda *a: (xla_attention(
+        *a, causal=True, window=window, segment_ids=segs) * probe).sum(), (0, 1, 2))(q, k, v)
+
+
+def _one_kv_block_against_xla(backwards_traced, S, H, window, seg):
+    from ray_tpu.ops import flash
+
+    B, KVH, D = 1, 1, 32
     q, k, v = make_qkv(jax.random.key(11), B, S, S, H, KVH, D)
     q = q * 0.5
     probe = jax.random.normal(jax.random.key(12), q.shape, jnp.float32)
     segs = jnp.broadcast_to((jnp.arange(S) >= 1500).astype(jnp.int32), (B, S)) if seg else None
-    want = jax.value_and_grad(lambda *a: (xla_attention(
-        *a, causal=True, window=window, segment_ids=segs) * probe).sum(), (0, 1, 2))(q, k, v)
+    want = _xla_value_and_grads(q, k, v, probe, segs, window)
     got = []
     traced = backwards_traced(lambda: got.append(jax.value_and_grad(lambda *a: (flash.flash_attention(
         *a, causal=True, window=window, segment_ids=segs) * probe).sum(), (0, 1, 2))(q, k, v)))

@@ -47,16 +47,17 @@ def _against_xla(shape, *, seg=False, window=None, d_tol=2e-3, **kw):
 
 
 # float32 at heads of 32 is 4 MiB a k block of 8192 keys as VMEM holds it (the 128 lanes):
-# over the module's 2 MiB, so 4224 keys are two kv blocks of 4096 and the kernels apart;
-# under a budget of 4 MiB they are ONE kv block of two selection blocks, and the backward fused
+# the module's own budget since PR 56, so 4224 keys are ONE kv block of two selection blocks
+# and the backward fused; under the 2 MiB it was before, they are two kv blocks of 4096 and
+# the kernels apart (the form a selection over more keys than the budget admits still takes)
 @pytest.mark.parametrize("case", [
     dict(shape=(1, 1024, 4, 1, 64), block_q=128),             # two sub-tiles of 512, heads folded
     dict(shape=(2, 300, 3, 1, 64)),                           # padded rows and keys, one ragged tile
     dict(shape=(1, 640, 2, 2, 64), block_q=128, seg=True),    # segments beside the selection
     dict(shape=(1, 1024, 2, 1, 64), block_q=256, window=300),  # a window beside it
-    dict(shape=(1, 4224, 2, 1, 32), block_q=256, took="split"),  # two kv blocks: dq and dk/dv apart
-    dict(shape=(1, 4224, 2, 1, 32), block_q=256, budget=4 << 20),  # one kv block, two selection blocks
-    dict(shape=(1, 4224, 2, 1, 32), block_q=256, budget=4 << 20, window=700, seg=True),
+    dict(shape=(1, 4224, 2, 1, 32), block_q=256, budget=2 << 20, took="split"),  # two kv blocks
+    dict(shape=(1, 4224, 2, 1, 32), block_q=256),  # one kv block, two selection blocks
+    dict(shape=(1, 4224, 2, 1, 32), block_q=256, window=700, seg=True),
 ], ids=["fused_folded", "padded", "segments", "window", "two_kv_blocks",
         "two_selection_blocks_fused", "two_selection_blocks_window_segments"])
 def test_selections_against_xla_attention(case, monkeypatch, backwards_traced):

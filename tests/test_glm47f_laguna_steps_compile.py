@@ -55,7 +55,10 @@ MELLUM2 = dict(batch=1, model="mellum2-12b-a2.5b", n_layers=4, seq=16384, vocab_
                experts_held=8)
 # sha256 of the lowered step of mellum2-12b-a2.5b as `mellum2-train-16k` builds it (PR 53: the
 # rehearsal's rung (b)); rung (a), 16 held and a quarter of the vocabulary, lowered to 8e98e744...
-_MELLUM2_STEP = "a0a2e204465b7f4b8138cd94b51485d95ebcd8e36613a481dfaede246ebf0d1d"
+# Replaced ON PURPOSE by PR 56: its 16,384 keys are ONE kv block, so each of its four layers'
+# backward is one fused kernel where the dq and the dk/dv kernels stood, and the kernels carry
+# their VMEM limits (a0a2e204... from PR 53 to PR 55)
+_MELLUM2_STEP = "535bbc499fda02b7ef3ffc17619d2629ed5f07516436cae359a007a5d3f8fbb5"
 # `sdar-train-8k`'s step (PR 55: the rehearsal's rung (a)): 4 full layers of the same module
 # trained by block diffusion, 16 of 128 experts and Keye's eighth of the vocabulary held, ONE
 # sequence of 8,192 tokens = 16,384 rows
@@ -254,7 +257,7 @@ def test_laguna_share_train_step_sizes_the_expert_layer_by_the_held_rows(v5e):
     assert step.memory.temp_size_in_bytes < 2.58 * 2 ** 30
 
 
-def test_mellum2_train_step_lowers_to_the_text_it_had_over_four_kv_blocks(v5e):
+def test_mellum2_train_step_lowers_to_one_kv_block_and_the_fused_backward(v5e):
     """The tenth cell's step, LOWERED for the described chip and not compiled
     (its compile is 60 s of every core, and the lane has none to spare:
     ROADMAP D8; the rehearsal's compile is in the configuration file's
@@ -263,10 +266,12 @@ def test_mellum2_train_step_lowers_to_the_text_it_had_over_four_kv_blocks(v5e):
     change to the typed stack, to flash's kv-block rule or to the compact
     path that means to leave this cell alone shows it here; and what the
     lowering itself counts: the SAME module as Laguna's (`laguna.attn`),
-    16,384 keys as four kv blocks so that every layer's backward is the dq
-    and the dk/dv kernels apart (`flash.bwd_split` 4, which
-    `fallback_sites.train` books as fallen back: the cell's subject,
-    PERF.md section 6, PR 53), the expert blocks built compact over C =
+    16,384 keys as ONE kv block since PR 56 (4 MiB a k block, within
+    `KV_BLOCK_BYTES`), so that every layer's backward is the fused kernel
+    (`flash.bwd_fused` 4 and no `flash.bwd_split`: `fallback_sites.train`
+    reads 0 where it read 4 a lowering; PERF.md section 6, PR 56), two
+    kernels a layer kind and direction of the scan where three stood, the
+    expert blocks built compact over C =
     32,768 of 131,072 pair rows with the band for their sum, no site on
     `ragged_dot`."""
     from ray_tpu import obs
@@ -282,7 +287,10 @@ def test_mellum2_train_step_lowers_to_the_text_it_had_over_four_kv_blocks(v5e):
     assert engaged["laguna.attn"] >= 4 and engaged["moe.ffn"] >= 4
     assert engaged["moe.compact"] >= 4 and engaged["moe.full"] == 0
     assert engaged["grouped_matmul.kernel"] > 0 and engaged["grouped_matmul.ragged_dot"] == 0
-    assert (engaged["flash.bwd_fused"], engaged["flash.bwd_split"]) == (0, 4)
+    assert (engaged["flash.bwd_fused"], engaged["flash.bwd_split"]) == (4, 0)
+    # a forward and a fused backward a layer: three window layers and the full one
+    kernels = step.lowered_kernels
+    assert (kernels.count("swa.attend"), kernels.count("attn.attend")) == (6, 2)
     assert engaged["moe.sum.linear"] >= 2 and engaged["moe.sum.product"] == 0
     text = step.lowered_text
     # 8 held experts' weights and no more, the router's 64 outputs whole, q at one head count

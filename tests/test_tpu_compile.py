@@ -61,32 +61,43 @@ def test_flash_attention_compiles_for_v5e(v5e, grad, S):
 
 @pytest.mark.parametrize("window", [1024, None], ids=["window_1024", "full"])
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
-def test_flash_attention_compiles_at_16384_keys_over_four_kv_blocks(v5e, grad, window):
-    """`mellum2-train-16k`'s shape (PR 53): 32 / 4 heads of 128, ONE
-    sequence of 16,384 keys. A k block of the whole sequence would be 4 MiB,
-    over `KV_BLOCK_BYTES`: the kv block is `MAX_BLOCK_K`, four of them, one
-    head a program (groups of 8 at a kv block of 4,096 fold no further),
-    and the backward is the dq and the dk/dv kernels apart, three kernels
-    in all; under the window the index maps clamp to the blocks that hold
-    a visible key (`_kv_block_of`, `_q_block_of`), which the interpreter
-    takes on trust and Mosaic does not."""
+@pytest.mark.parametrize("block_k", [None, 4096], ids=["one_kv_block", "four_kv_blocks"])
+def test_flash_attention_compiles_at_16384_keys(v5e, grad, window, block_k):
+    """`mellum2-train-16k`'s shape: 32 / 4 heads of 128, ONE sequence of
+    16,384 keys. Since PR 56 a k block of the whole sequence, 4 MiB, is
+    within `KV_BLOCK_BYTES`: ONE kv block, one head a program (groups of 8
+    at a kv block over 2,048 fold no further), one kernel forward and the
+    FUSED backward, two in all, and both state their own VMEM (the forward
+    holds k and v double-buffered, 16 MiB, Mosaic's whole default; the
+    backward 48 MiB of kv blocks): they compile with NO compile option of
+    the caller's. At an explicit `block_k=4096` the form PR 53 ran, which
+    no cell runs now (a sequence over the budget would: 32,768 keys): four
+    kv blocks, the dq and the dk/dv kernels apart, three kernels in all;
+    under the window the index maps clamp to the blocks that hold a visible
+    key (`_kv_block_of`, `_q_block_of`), which the interpreter takes on
+    trust and Mosaic does not."""
     from ray_tpu.ops import flash
 
-    assert flash.default_block_k(16384, 128, 2) == flash.MAX_BLOCK_K == 4096
-    assert flash.default_block_k(8192, 128, 2) == 8192
-    assert flash._fold_factor(8, 512, 4096, None) == 1
+    assert flash.default_block_k(16384, 128, 2) == 16384
+    assert flash.default_block_k(32768, 128, 2) == flash.MAX_BLOCK_K == 4096
+    assert flash._fold_factor(8, 512, 16384, None) == flash._fold_factor(8, 512, 4096, None) == 1
+    # tests/test_flash.py has the bytes (25.25 / 57 MiB); over four kv blocks the forward states none
+    assert flash._fwd_params(512, 16384, 128, 1, 2) is not None
+    assert flash._fused_bwd_params(512, 16384, 128, 1, 2) is not None
+    assert flash._fwd_params(512, 4096, 128, 1, 2) is None
 
-    def fwd(q, k, v):
-        return flash.flash_attention_head_major(q, k, v, causal=True, window=window)
+    def fwd(q, k, v):  # [B, S, H, D]: the entry that takes a kv block by hand
+        return flash.flash_attention(q, k, v, causal=True, window=window, block_k=block_k)
 
     def bwd(q, k, v):
         return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
 
     with mock.patch("jax.default_backend", return_value="tpu"):   # flash's interpret switch
-        hlo = compile_kernel(bwd if grad else fwd, ((1, 32, 16384, 128), _BF16),
-                             ((1, 4, 16384, 128), _BF16), ((1, 4, 16384, 128), _BF16),
+        hlo = compile_kernel(bwd if grad else fwd, ((1, 16384, 32, 128), _BF16),
+                             ((1, 16384, 4, 128), _BF16), ((1, 16384, 4, 128), _BF16),
                              sharding=one_chip(v5e))
-    assert hlo.count('custom_call_target="tpu_custom_call"') == (3 if grad else 1)
+    kernels = (2 if block_k is None else 3) if grad else 1
+    assert hlo.count('custom_call_target="tpu_custom_call"') == kernels
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
