@@ -4,8 +4,8 @@ What ZAYA1 (Zyphra/ZAYA1-8B; the ZAYA1 report, arXiv:2511.17127) adds to
 the one decoder of models/llama.py: `ZayaConfig`, and the attention
 sublayer `cca_sublayer` with its parameters and their logical axes. The
 block, the layer scan, the head and the loss are models/llama.py's,
-which calls `cca_sublayer` in place of its own attention when the
-configuration is a `ZayaConfig`; the expert layer with its MLP router
+which runs `cca_sublayer` as the row "cca" of its `MIXERS`, the kind a
+`ZayaConfig` names; the expert layer with its MLP router
 and the share of experts held is models/moe.py's.
 
 CCA (arXiv:2510.04476) runs the whole attention in a latent narrower
@@ -56,7 +56,7 @@ axis or on major ones; `ops/attention.attention_head_major` hands q, k
 and v to the kernels as they are, and `cca.out` contracts (H, hd) of
 what comes back. XLA chooses the layout of whatever nothing pins, and
 for these arrays it chose channels-in-sublanes and back by turns, so
-nn/layers.py::head_major, the one helper this module and models/llama.py
+nn/layers.py::head_major, the one helper this module and models/gqa.py
 share (its full attention has been head-major the same way since PR 38),
 pins the tile where the matmuls write.
 
@@ -71,7 +71,7 @@ selection bias are left out (the bias is a parameter that stays zero).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional
+from typing import Any, ClassVar, Optional
 
 import jax
 import jax.ad_checkpoint
@@ -93,6 +93,7 @@ class ZayaConfig(moe.MoEConfig):
     / `n_kv_heads` heads of `latent_head_dim` make the latent; `d_ff` is
     the width of one expert."""
 
+    mixer: ClassVar[str] = "cca"
     latent_head_dim: int = 128
     conv_kernels: tuple = (2, 2)     # taps of the depthwise and of the grouped convolution
     rotary_fraction: float = 0.5     # of each head's channels, from the first
@@ -159,13 +160,13 @@ def attention_axes() -> Params:
     }
 
 
-def attention_params(config: ZayaConfig, key: jax.Array) -> Params:
-    """CCA's weights of every layer, stacked over layers. A convolution's
+def attention_params(config: ZayaConfig, keys: jax.Array) -> Params:
+    """CCA's weights of every layer, stacked (drawn from `keys[0]`). A convolution's
     taps are in time order: the LAST tap multiplies the current token."""
     c = config
     L, d, hd, H, G = c.n_layers, c.d_model, c.head_dim, c.n_heads, c.n_kv_heads
     k0, k1 = c.conv_kernels
-    keys = jax.random.split(key, 7)
+    keys = jax.random.split(keys[0], 7)
 
     def per_layer(k, shape, scale=None):
         return jax.vmap(lambda kk: init_dense(kk, shape, c.param_dtype, scale))(
@@ -279,7 +280,7 @@ def cca_sublayer(x: jax.Array, lp: Params, c: ZayaConfig, *, positions: jax.Arra
         with jax.named_scope("cca.attend"):
             o = attention_head_major(q, k, v, causal=True, segment_ids=segment_ids,
                                      impl=c.attention_impl)
-            # saved by the "dots" remat policy, as llama._block's is
+            # saved by the "dots" remat policy, as models/gqa.py's is
             o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
         with jax.named_scope("cca.out"):
             return jnp.einsum("bhsk,hkd->bsd", o, lp["wo"].astype(dt).reshape(H, hd, D))

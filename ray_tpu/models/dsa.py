@@ -6,8 +6,8 @@ What Keye-VL-2.0-30B-A3B's language model (Kwai-Keye/Keye-VL-2.0-30B-A3B,
 code computes it) adds to the one decoder of models/llama.py:
 `KeyeConfig`, and the attention sublayer `dsa_sublayer` with its
 parameters and their logical axes. The block, the layer scan, the head
-and the loss are models/llama.py's, which calls `dsa_sublayer` in place
-of its own attention when the configuration is a `KeyeConfig`; the
+and the loss are models/llama.py's, which runs `dsa_sublayer` as the row
+"dsa" of its `MIXERS`, the kind a `KeyeConfig` names; the
 expert layer (softmax top-8 of 128 chosen with a selection bias,
 renormalised, a share of the experts held) is models/moe.py's.
 
@@ -40,7 +40,7 @@ softmax(I) with the main attention's probabilities: NOT implemented,
 the flash kernels do not give those out).
 
 HOW IT RUNS. q, k, v and o are head-major from the projections to `wo`
-(models/llama.py's layout paragraph). The index scores are walked in
+(models/gqa.py's layout paragraph). The index scores are walked in
 chunks of `index_chunk` queries (the config's `q_chunk_size`; it changes
 no result): a chunk's scores are [B, J, chunk, keys up to the chunk's
 last row] float32 for one fused pass and [B, chunk, keys] after it, so
@@ -55,7 +55,7 @@ the chunk: exact, no sort), the cut among equal scores by a running
 count taken only where a row's cut fell on equal scores. The selection
 crosses to the attention kernels PACKED, one bit a (query, key) pair
 (ops/flash.py::pack_selection: 8 MiB a layer at 8192 tokens), is saved
-for the backward under the name `dsa_sel` (models/llama.py::_remat) and
+for the backward under the name `dsa_sel` (its row of `llama.MIXERS`) and
 is a constant there. The kernels visit every sub-tile under the
 diagonal and mask inside it.
 
@@ -71,7 +71,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Optional
+from typing import Any, ClassVar, Optional
 
 import jax
 import jax.ad_checkpoint
@@ -92,6 +92,7 @@ class KeyeConfig(moe.MoEConfig):
     """The attention's own sizes; the expert layer's are `MoEConfig`'s.
     `d_ff` is the width of one expert."""
 
+    mixer: ClassVar[str] = "dsa"
     head_dim: int = 128            # explicit: 2048 / 32 is 64
     indexer_heads: int = 16
     indexer_head_dim: int = 64
@@ -167,12 +168,12 @@ def attention_axes() -> Params:
     }
 
 
-def attention_params(config: KeyeConfig, key: jax.Array) -> Params:
-    """The sublayer's weights of every layer, stacked over layers."""
+def attention_params(config: KeyeConfig, keys: jax.Array) -> Params:
+    """The sublayer's weights of every layer, stacked (drawn from `keys[0]`)."""
     c = config
     L, d, hd = c.n_layers, c.d_model, c.head_dim
     J, ihd = c.indexer_heads, c.indexer_head_dim
-    keys = jax.random.split(key, 7)
+    keys = jax.random.split(keys[0], 7)
 
     def per_layer(k, shape):
         return jax.vmap(lambda kk: init_dense(kk, shape, c.param_dtype))(jax.random.split(k, L))
@@ -319,7 +320,7 @@ def dsa_sublayer(x: jax.Array, lp: Params, c: KeyeConfig, *, positions: jax.Arra
         sel = jax.ad_checkpoint.checkpoint_name(sel, "dsa_sel")
         with jax.named_scope("dsa.attend"):
             o = attention_head_major(q, k, v, causal=True, impl=c.attention_impl, selection=sel)
-            # saved by the "dots" remat policy, as llama._block's is
+            # saved by the "dots" remat policy, as models/gqa.py's is
             o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
         with jax.named_scope("dsa.out"):
             out = jnp.einsum("bhsk,hkd->bsd", o, lp["wo"].astype(dt).reshape(H, hd, D))

@@ -117,6 +117,7 @@ from ray_tpu.ops.attention import attention_head_major
 Params = dict[str, Any]
 FULL, SLIDING = "full_attention", "sliding_attention"
 _F32 = jnp.float32
+REMAT_SAVES = ()   # beside llama._remat's own: this stack's kernels are the flash kernels
 
 
 @dataclasses.dataclass(frozen=True)
@@ -321,8 +322,7 @@ def _attention_axes(c: LagunaConfig) -> Params:
 def _block_axes(c: LagunaConfig, dense: bool, stacked: bool = True) -> Params:
     axes = {"ln1": ("layers", "norm"), **_attention_axes(c), "ln2": ("layers", "norm")}
     if dense:
-        axes.update(w_gate=("layers", "embed", "mlp"), w_up=("layers", "embed", "mlp"),
-                    w_down=("layers", "mlp", "embed"))
+        axes.update(llama.DENSE_FFN_AXES)
     else:
         axes.update(moe.expert_axes(c))
     return axes if stacked else {k: v[1:] for k, v in axes.items()}
@@ -435,7 +435,7 @@ def attention_sublayer(h: jax.Array, x: jax.Array, lp: Params, c: LagunaConfig, 
                                      # noised copy of a sequence (a window beside it is refused)
                                      blockdiff=(S // 2, c.diffusion_block)
                                      if c.diffusion_block else None)
-            # saved by the "dots" remat policy, as llama._block's is
+            # saved by the "dots" remat policy, as models/gqa.py's is
             o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
         if c.attn_gate == "per-head":
             with jax.named_scope(f"{scope}.gate"):
@@ -491,9 +491,8 @@ def trunk(params: Params, tokens: jax.Array, c: LagunaConfig, *, positions: jax.
     # writes; every block's operations stand under a scope of their own inside it
     with jax.named_scope("block.stack"):
         if p["dense"] is not None:
-            dense = block_of(*p["dense"], dense=True)
-            for i in range(c.first_dense_layers):
-                h, _ = dense(h, jax.tree.map(lambda w: w[i], params["dense_layers"]))
+            h = llama.run_dense_layers(h, params, c.first_dense_layers,
+                                       block_of(*p["dense"], dense=True))
         blocks = [block_of(kind, heads) for kind, heads in p["period"]]
 
         def period(h, xs):

@@ -13,49 +13,26 @@ deployments/llm/vllm/vllm_engine.py): pure-functional jax with
   * per-layer rematerialization (`jax.checkpoint`) to trade MXU FLOPs
     for HBM.
 
-The layout of the attention sublayer (PR 38; CCA since PR 33, MLA since
-PR 34). q, k and v are HEAD-MAJOR, [B, heads, S, hd], from where the
-projections write them (the weight read as [D, heads, hd],
-`"bsd,dnh->bnsh"`) to where `wo` contracts (heads, hd) of what the
-kernel gives back (`"bhsk,hkd->bsd"`): on a TPU an array's last two
-dimensions are its tile, so the tile is (tokens, a head's channels) and
-always full, where [B, S, heads, hd] made 8 key-value heads the rows of
-a half-empty bfloat16 tile. The q/k norm and the rotary act on the last
-axis and on major ones (`_norm_over_heads`,
-nn/layers.py::apply_rope_head_major, whose halves change places on the
-MXU so that nothing is cut inside the 128 lanes), the flash kernels,
-whose own layout this is, take q, k and v as they are
-(ops/attention.attention_head_major), and nn/layers.py::head_major, the
-one helper this module and models/cca.py share, pins the tile where the
-matmuls write. Under `tp > 1` the rings of parallel/tp_overlap.py hand
-back and take [B, S, h] slabs in token order: one `swapaxes` a tensor
-after the ring and one before `rs_matmul` stand where the kernel
-wrapper's three transposes in and one out stood. models/llama_decode.py
-(serving: a cache laid out [.., S, heads, hd]) keeps `apply_rope` and
-its own layout.
+The attention sublayer is a row of `MIXERS`, the kind the configuration's
+class names (`LlamaConfig.mixer`): a module a kind, the llama family's
+own models/gqa.py, which holds the layout paragraph. Leading DENSE layers
+before a stack of expert layers and a multi-token-prediction block after
+it (`first_dense_layers`, `mtp_layers`) are this module's too.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import importlib
 from functools import partial
-from typing import Any, Optional
+from typing import Any, ClassVar, Optional
 
 import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
 
-from ray_tpu.nn.layers import (
-    apply_rope_head_major,
-    fused_cross_entropy_loss,
-    head_major,
-    init_dense,
-    rms_norm,
-    rope_frequencies,
-    swiglu,
-)
-from ray_tpu.ops.attention import attention_head_major
+from ray_tpu.nn.layers import fused_cross_entropy_loss, init_dense, rms_norm, swiglu
 from ray_tpu.parallel.context import current_mesh
 
 Params = dict[str, Any]
@@ -83,6 +60,8 @@ class LlamaConfig:
     remat_policy: str = "dots"
     attention_impl: str = "xla"
     tie_embeddings: bool = False
+    # the kind of the attention sublayer, a key of `MIXERS`: the class's, not a field
+    mixer: ClassVar[str] = "gqa"
 
     @property
     def head_dim(self) -> int:
@@ -135,28 +114,54 @@ def _moe(config: LlamaConfig):
     return moe
 
 
-def _cca(config: LlamaConfig):
-    """models/cca.py when the configuration's attention is compressed
-    convolutional attention (a `ZayaConfig`), else None: the seam at
-    which the block's attention sublayer is chosen, at trace time."""
-    if not hasattr(config, "conv_kernels"):
-        return None
-    from ray_tpu.models import cca
+@dataclasses.dataclass(frozen=True)
+class Mixer:
+    """What the stack needs to know of one kind of attention sublayer. Its
+    module (loaded for a configuration of the kind, never for another's)
+    has `attention_axes()`, `attention_params(c, keys)` and the sublayer,
+    `(x, lp, c, *, positions, segment_ids, **once)` -> what it adds to the
+    hidden state, or that and the layer's statistics."""
 
-    return cca
+    module: str
+    sublayer: str
+    # `module.<once>(c)` -> keyword arguments of the sublayer made ONCE, outside the layer scan
+    once: Optional[str] = None
+    # the rings of parallel/tp_overlap.py are its, and with it the dense FFN's: under tp > 1
+    # its sublayer is told `overlap=True` (another kind's matmuls are the partitioner's)
+    tp_rings: bool = False
+    # the scope of its last matmul, which the residual add fuses into and is traced under
+    # (None: under the stack's own name)
+    adds_under: Optional[str] = None
+    # what `_remat` saves of it by name: the flash kernels' output and log-sum-exp, which
+    # are no dot_general's, and what else its backward reads
+    saves: tuple = ("attn_out", "attn_lse")
+
+    def load(self):
+        return importlib.import_module(self.module)
 
 
-def _mla(config: LlamaConfig):
-    """models/mla.py when the configuration's attention is multi-head
-    latent attention (a `GlmLiteConfig`), else None. Such a configuration
-    may also have leading dense layers before its stack of expert layers
-    and a multi-token-prediction block after it: that module builds the
-    tree, `_trunk` and `loss_and_weight_fn` run it."""
-    if not hasattr(config, "kv_lora_rank"):
-        return None
-    from ray_tpu.models import mla
+MIXERS = {
+    "gqa": Mixer("ray_tpu.models.gqa", "gqa_sublayer", once="rotary_tables", tp_rings=True,
+                 adds_under="attn.out"),
+    "cca": Mixer("ray_tpu.models.cca", "cca_sublayer"),
+    "mla": Mixer("ray_tpu.models.mla", "mla_sublayer"),
+    # dsa_sel: the packed selection, which the backward reads and nothing computes twice
+    "dsa": Mixer("ray_tpu.models.dsa", "dsa_sublayer", saves=("attn_out", "attn_lse", "dsa_sel")),
+}
+# saved too, and no mixer's. tp_rs_out: a row-parallel matmul's output where
+# parallel/tp_overlap.py sums it (there the dot the first policy sees is only one chip's
+# product); moe_gate, moe_up: the expert layer's first two grouped matmuls (models/moe.py),
+# which are no dot_general either
+_NO_MIXER_SAVES = ("tp_rs_out", "moe_gate", "moe_up")
 
-    return mla
+
+def _mixer(config: LlamaConfig) -> Mixer:
+    """The row of the configuration's kind: the seam at which the block's
+    attention sublayer is chosen, at trace time."""
+    try:
+        return MIXERS[config.mixer]
+    except KeyError:
+        raise ValueError(f"unknown mixer kind {config.mixer!r}; one of {sorted(MIXERS)}") from None
 
 
 def _own_stack(config: LlamaConfig):
@@ -167,22 +172,12 @@ def _own_stack(config: LlamaConfig):
     whole periods of kinds) or models/olmo_hybrid.py (gated-delta-rule
     linear attention and full attention over a dense SwiGLU). The
     configuration names its module (`stack_module`), which has
-    `logical_axes(c)`, `init_params(c, key)` and `trunk(params, tokens, c,
-    positions=, segment_ids=)`; the head and the loss stay here."""
+    `logical_axes(c)`, `init_params(c, key)`, `trunk(params, tokens, c,
+    positions=, segment_ids=)` and `REMAT_SAVES` (the names its own
+    kernels write for `_remat`); the head and the loss stay here."""
     if not hasattr(config, "layer_types"):
         return None
     return importlib.import_module(config.stack_module)
-
-
-def _dsa(config: LlamaConfig):
-    """models/dsa.py when the configuration's attention runs over the keys
-    a learned indexer selects (a `KeyeConfig`), else None. No other
-    configuration loads that module."""
-    if not hasattr(config, "indexer_topk"):
-        return None
-    from ray_tpu.models import dsa
-
-    return dsa
 
 
 def _block_diffusion(config: LlamaConfig):
@@ -204,31 +199,53 @@ def _carries_router_state(config: LlamaConfig) -> bool:
     return getattr(config, "router_kind", "linear") == "mlp"
 
 
+DENSE_FFN_AXES = {"w_gate": ("layers", "embed", "mlp"), "w_up": ("layers", "embed", "mlp"),
+                  "w_down": ("layers", "mlp", "embed")}
+
+
+def stacked_dense(key: jax.Array, n: int, shape: tuple, dtype, scale=None) -> jax.Array:
+    """`n` fan-in-initialised matrices of `shape`, each from a key of its own, stacked."""
+    return jax.vmap(lambda k: init_dense(k, shape, dtype, scale))(jax.random.split(key, n))
+
+
+def _beside_the_stack(config: LlamaConfig) -> tuple[int, int]:
+    """(leading dense layers before the stack of expert layers, multi-token-
+    prediction blocks after it) of a configuration with the fields, whatever
+    its attention: `first_dense_layers` (a SwiGLU of width `dense_d_ff`
+    under the same attention) and `mtp_layers` (`mtp_loss_weight`)."""
+    n_mtp = getattr(config, "mtp_layers", 0)
+    if n_mtp not in (0, 1):
+        raise ValueError(f"{n_mtp} multi-token-prediction blocks: 0 or 1 are implemented")
+    return getattr(config, "first_dense_layers", 0), n_mtp
+
+
+def _stack_config(c: LlamaConfig, n_layers: int) -> LlamaConfig:
+    """`n_layers` blocks of the expert-layer kind and nothing beside them:
+    what `logical_axes` and `init_params` build as one stack."""
+    return dataclasses.replace(c, n_layers=n_layers, first_dense_layers=0, mtp_layers=0)
+
+
 def logical_axes(config: LlamaConfig) -> Params:
     """Pytree (parallel to params) of logical-axis tuples."""
     if _own_stack(config) is not None:
         return _own_stack(config).logical_axes(config)
-    mla = _mla(config)
-    if mla is not None and mla.has_more_than_the_stack(config):
-        return mla.logical_axes(config)
-    layer = {
-        "ln1": ("layers", "norm"),
-        "wq": ("layers", "embed", "heads"),
-        "wk": ("layers", "embed", "kv_heads"),
-        "wv": ("layers", "embed", "kv_heads"),
-        "wo": ("layers", "heads", "embed"),
-        "ln2": ("layers", "norm"),
-    }
-    moe, cca, dsa = _moe(config), _cca(config), _dsa(config)
-    if cca is not None or mla is not None or dsa is not None:
-        layer = {"ln1": layer["ln1"], "ln2": layer["ln2"],
-                 **(cca or mla or dsa).attention_axes()}
+    n_dense, n_mtp = _beside_the_stack(config)
+    if n_dense or n_mtp:
+        axes = logical_axes(_stack_config(config, config.n_layers - n_dense))
+        if n_dense:
+            axes["dense_layers"] = {
+                "ln1": ("layers", "norm"), **_mixer(config).load().attention_axes(),
+                "ln2": ("layers", "norm"), **DENSE_FFN_AXES}
+        if n_mtp:
+            block = {k: v[1:] for k, v in axes["layers"].items() if k != "router_bias"}
+            axes["mtp"] = {"enorm": ("norm",), "hnorm": ("norm",), "eh_proj": (None, "embed"),
+                           "block": block, "final_norm": ("norm",)}
+        return axes
+    layer = {"ln1": ("layers", "norm"), **_mixer(config).load().attention_axes(),
+             "ln2": ("layers", "norm")}
+    moe = _moe(config)
     if moe is None:
-        layer.update(
-            w_gate=("layers", "embed", "mlp"),
-            w_up=("layers", "embed", "mlp"),
-            w_down=("layers", "mlp", "embed"),
-        )
+        layer.update(DENSE_FFN_AXES)
     else:
         layer.update(moe.expert_axes(config))
         if config.qk_norm:
@@ -244,43 +261,38 @@ def logical_axes(config: LlamaConfig) -> Params:
 
 
 def init_params(config: LlamaConfig, key: jax.Array) -> Params:
+    """The tree. `params["layers"]` is the stack the layer scan runs over.
+    With layers beside it (`_beside_the_stack`; `n_layers` counts the dense
+    and the expert layers together, as `num_hidden_layers` does):
+    `params["dense_layers"]`, the leading blocks, run before the scan;
+    `params["mtp"]`, two norms, the [2 d_model, d_model] merge, one more
+    block of the stack's kind and its own final norm. The selection
+    biases of EVERY expert block, the MTP block's last, are one table,
+    `params["layers"]["router_bias"]` [expert layers + 1, n_experts]: the
+    one state of the model that a balancing rule moves and no gradient
+    does, and whoever balances it (chipbench's model builder sets it
+    once, before the first step) writes one array."""
     c = config
     if _own_stack(c) is not None:
         return _own_stack(c).init_params(c, key)
-    mla = _mla(c)
-    if mla is not None and mla.has_more_than_the_stack(c):
-        return mla.init_params(c, key)
+    n_dense, n_mtp = _beside_the_stack(c)
+    if n_dense or n_mtp:
+        return _with_layers_beside(c, key, n_dense, n_mtp)
     keys = jax.random.split(key, 8)
-    hd = c.head_dim
-    L = c.n_layers
-
-    def dense(k, shape):
-        # init per-layer with distinct keys folded over the layer axis
-        ks = jax.random.split(k, L)
-        return jax.vmap(lambda kk: init_dense(kk, shape, c.param_dtype))(ks)
-
+    hd, L = c.head_dim, c.n_layers
     moe = _moe(c)
     if moe is None:
         ffn = {
-            "w_gate": dense(keys[5], (c.d_model, c.d_ff)),
-            "w_up": dense(keys[6], (c.d_model, c.d_ff)),
-            "w_down": dense(keys[7], (c.d_ff, c.d_model)),
+            "w_gate": stacked_dense(keys[5], L, (c.d_model, c.d_ff), c.param_dtype),
+            "w_up": stacked_dense(keys[6], L, (c.d_model, c.d_ff), c.param_dtype),
+            "w_down": stacked_dense(keys[7], L, (c.d_ff, c.d_model), c.param_dtype),
         }
     else:
         ffn = moe.expert_params(c, keys[5])
         if c.qk_norm:
             ffn["q_norm"] = jnp.ones((L, c.n_heads * hd), c.param_dtype)
             ffn["k_norm"] = jnp.ones((L, c.n_kv_heads * hd), c.param_dtype)
-    cca, dsa = _cca(c), _dsa(c)
-    if cca is not None or mla is not None or dsa is not None:
-        attn = (cca or mla or dsa).attention_params(c, keys[1])
-    else:
-        attn = {
-            "wq": dense(keys[1], (c.d_model, c.n_heads * hd)),
-            "wk": dense(keys[2], (c.d_model, c.n_kv_heads * hd)),
-            "wv": dense(keys[3], (c.d_model, c.n_kv_heads * hd)),
-            "wo": dense(keys[4], (c.n_heads * hd, c.d_model)),
-        }
+    attn = _mixer(c).load().attention_params(c, keys[1:5])
     params: Params = {
         "embed": init_dense(keys[0], (c.vocab_size, c.d_model), c.param_dtype, scale=1.0),
         "layers": {
@@ -295,6 +307,36 @@ def init_params(config: LlamaConfig, key: jax.Array) -> Params:
         params["lm_head"] = init_dense(
             jax.random.fold_in(key, 99), (c.d_model, c.vocab_size), c.param_dtype
         )
+    return params
+
+
+def _with_layers_beside(c: LlamaConfig, key: jax.Array, nd: int, n_mtp: int) -> Params:
+    """`init_params`: the expert layers' stack as a stack alone is made,
+    the `nd` dense layers and the MTP module."""
+    d, dt = c.d_model, c.param_dtype
+    k_dense, k_mtp, k_merge = jax.random.split(jax.random.fold_in(key, 47), 3)
+    params = init_params(_stack_config(c, c.n_layers - nd), key)
+    if nd:
+        k_attn, k_gate, k_up, k_down = jax.random.split(k_dense, 4)
+        params["dense_layers"] = {
+            "ln1": jnp.ones((nd, d), dt),
+            # (the first of the attention's keys: a kind that draws one a matrix wants three more)
+            **_mixer(c).load().attention_params(_stack_config(c, nd), k_attn[None]),
+            "ln2": jnp.ones((nd, d), dt),
+            "w_gate": stacked_dense(k_gate, nd, (d, c.dense_d_ff), dt),
+            "w_up": stacked_dense(k_up, nd, (d, c.dense_d_ff), dt),
+            "w_down": stacked_dense(k_down, nd, (c.dense_d_ff, d), dt),
+        }
+    if n_mtp:
+        block = init_params(_stack_config(c, 1), k_mtp)["layers"]
+        bias = block.pop("router_bias")
+        params["layers"]["router_bias"] = jnp.concatenate(
+            [params["layers"]["router_bias"], bias])
+        params["mtp"] = {
+            "enorm": jnp.ones((d,), dt), "hnorm": jnp.ones((d,), dt),
+            "eh_proj": init_dense(k_merge, (2 * d, d), dt),
+            "block": jax.tree.map(lambda w: w[0], block), "final_norm": jnp.ones((d,), dt),
+        }
     return params
 
 
@@ -314,105 +356,52 @@ def packed_positions(segment_ids: Optional[jax.Array], seq_len: int) -> jax.Arra
     return idx - seg_start
 
 
-def _norm_over_heads(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
-    """`rms_norm` over the whole projected width of x [B, heads, S, hd]
-    (scale [heads * hd]): the mean runs over the head axis and the
-    channels, a major axis and the last one, so the tile stays (S, hd)."""
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x32), axis=(1, 3), keepdims=True)
-    scale = scale.astype(jnp.float32).reshape(x.shape[1], 1, x.shape[3])
-    return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
-
-
 def _block(
     carry,  # h [B, S, D]; (h, router state [B, S, R]) for an MLP router
     lp: Params,  # one layer's params (no leading layer dim)
     *,
     config: LlamaConfig,
-    cos: Optional[jax.Array],
-    sin: Optional[jax.Array],
     positions: jax.Array,
     segment_ids: Optional[jax.Array],
+    once: Optional[dict] = None,
     dense_ffn: bool = False,
 ) -> tuple[Any, Optional[Params]]:
     """One decoder layer -> (carry, the layer's statistics): the
-    attention sublayer of the configuration's kind (full causal GQA with
-    rotary, and the q/k RMSNorm when the configuration has it, q, k and
-    v head-major from the projections to `wo`: the module's layout
-    paragraph; or compressed convolutional attention, models/cca.py; or
-    multi-head latent attention, models/mla.py; or attention over the keys a
-    learned indexer selects, models/dsa.py, whose two counts join the
-    layer's statistics), then the dense SwiGLU or the
-    expert layer (models/moe.py, whose statistics come back; None for a
-    dense layer) by the configuration's own kind; `dense_ffn`: one of an
-    expert configuration's leading dense layers. (A configuration whose
-    layers differ in kind within the stack, models/laguna.py or
+    attention sublayer of the configuration's kind (its row of `MIXERS`;
+    `once`: what the row's module made outside the layer scan; a
+    sublayer's own statistics join the layer's), then the dense SwiGLU
+    or the expert layer (models/moe.py, whose statistics come back; None
+    for a dense layer) by the configuration's own kind; `dense_ffn`: one
+    of an expert configuration's leading dense layers. (A configuration
+    whose layers differ in kind within the stack, models/laguna.py or
     models/olmo_hybrid.py, has a block of its own beside this one.)"""
     c = config
-    moe, cca, mla, dsa = None if dense_ffn else _moe(c), _cca(c), _mla(c), _dsa(c)
-    selected = {}
+    moe, mixer = None if dense_ffn else _moe(c), _mixer(c)
     carries_router = _carries_router_state(c)
     h, router_state = carry if carries_router else (carry, None)
-    B, S, D = h.shape
-    hd = c.head_dim
     # Under a mesh with tp > 1 the residual stream h stays sharded over
     # `tp` along the tokens and the four matmul sites gather and scatter
     # it inside themselves (parallel/tp_overlap.py); otherwise, and
-    # always in llama_decode.py, the plain einsums below. (The rings are
-    # the full attention's and the dense MLP's: CCA's and the expert
-    # layer's matmuls are the partitioner's to place.)
+    # always in llama_decode.py, the plain einsums. (The rings are a
+    # kind's by its row and the dense MLP's with it: the expert layer's
+    # matmuls are the partitioner's to place.)
     mesh = current_mesh()
-    overlap = (cca is None and mla is None and dsa is None and mesh is not None
-               and mesh.shape.get("tp", 1) > 1)
+    overlap = mixer.tp_rings and mesh is not None and mesh.shape.get("tp", 1) > 1
     if overlap:
         from ray_tpu.parallel.tp_overlap import ag_matmul, rs_matmul
 
     with jax.named_scope("block.norm"):
         x = rms_norm(h, lp["ln1"], c.rms_eps)
-    if cca is not None:
-        h = h + cca.cca_sublayer(x, lp, c, positions=positions, segment_ids=segment_ids)
-    elif mla is not None:
-        h = h + mla.mla_sublayer(x, lp, c, positions=positions, segment_ids=segment_ids)
-    elif dsa is not None:
-        y, selected = dsa.dsa_sublayer(x, lp, c, positions=positions, segment_ids=segment_ids)
+    told = {"overlap": True} if overlap else {}
+    y = getattr(mixer.load(), mixer.sublayer)(
+        x, lp, c, positions=positions, segment_ids=segment_ids, **(once or {}), **told)
+    y, selected = y if isinstance(y, tuple) else (y, {})
+    with jax.named_scope(mixer.adds_under) if mixer.adds_under else contextlib.nullcontext():
         h = h + y
-    else:
-        H, dt = c.n_heads, x.dtype
-        with jax.named_scope("attn.qkv"):
-            ws = [lp[n].astype(dt) for n in ("wq", "wk", "wv")]
-            if overlap:
-                # the ring hands back [B, S, h] slabs in token order: one swapaxes each
-                # (and no pin: under a mesh the layout is the compiler's, `head_major`)
-                q, k, v = (jnp.swapaxes(t.reshape(B, S, -1, hd), 1, 2) for t in ag_matmul(x, ws))
-            else:
-                q, k, v = (head_major(jnp.einsum("bsd,dnh->bnsh", x, w.reshape(D, -1, hd)))
-                           for w in ws)
-        with jax.named_scope("attn.rope"):
-            if moe is not None and c.qk_norm:  # over the whole projected width, before rotary
-                q = _norm_over_heads(q, lp["q_norm"], c.rms_eps)
-                k = _norm_over_heads(k, lp["k_norm"], c.rms_eps)
-            q = apply_rope_head_major(q, cos, sin, positions)
-            k = apply_rope_head_major(k, cos, sin, positions)
-        with jax.named_scope("attn.attend"):
-            o = attention_head_major(q, k, v, causal=True, segment_ids=segment_ids,
-                                     impl=c.attention_impl)
-            # named so the "dots" remat policy can SAVE it: the policy recognizes
-            # dot_general outputs but not a pallas_call's, so without the name the
-            # backward pass re-runs the whole flash kernel forward (~25% of a
-            # train step) just to rebuild this tensor
-            o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
-        with jax.named_scope("attn.out"):
-            wo = lp["wo"].astype(dt)
-            if overlap:
-                h = h + rs_matmul(jnp.swapaxes(o, 1, 2).reshape(B, S, H * hd), wo)
-            else:
-                h = h + jnp.einsum("bhsk,hkd->bsd", o, wo.reshape(H, hd, D))
 
     with jax.named_scope("block.norm"):
         x = rms_norm(h, lp["ln2"], c.rms_eps)
     if moe is not None:
-        # the rings of tp_overlap.py are the dense MLP's: under tp > 1
-        # the expert layer's matmuls are the partitioner's to place
         y, stats, router_state = moe.moe_ffn(x, lp, c, router_state)
         stats = {**stats, **selected}
         return ((h + y, router_state) if carries_router else h + y), stats
@@ -438,8 +427,17 @@ def hidden_states(
     The training loss pairs this with nn.layers.fused_cross_entropy_loss
     so the [T, V] logits never exist as a stored fp32 tensor; serving
     keeps using forward() -> logits."""
-    h, _ = _decoder(params, tokens, config, positions=positions, segment_ids=segment_ids)
-    return h
+    h, _, _ = _trunk(params, tokens, config, positions=positions, segment_ids=segment_ids)
+    return rms_norm(h, params["final_norm"], config.rms_eps)
+
+
+def remat_saves(c: LlamaConfig) -> set:
+    """The names `_remat`'s "dots" policy saves: every row's of `MIXERS`, no
+    mixer's, and what the kernels of the configuration's own `stack_module`
+    write. (A name no program carries changes nothing of it: PERF.md, PR 47.)"""
+    stack = _own_stack(c)
+    names = {n for row in MIXERS.values() for n in row.saves} | set(_NO_MIXER_SAVES)
+    return names if stack is None else names | set(stack.REMAT_SAVES)
 
 
 def _remat(block, c: LlamaConfig):
@@ -451,45 +449,12 @@ def _remat(block, c: LlamaConfig):
             block,
             policy=jax.checkpoint_policies.save_from_both_policies(
                 jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-                # tp_rs_out: a row-parallel matmul's output where
-                # parallel/tp_overlap.py sums it (there the dot the
-                # first policy sees is only one chip's product)
-                # moe_gate, moe_up: the expert layer's first two
-                # grouped matmuls (models/moe.py), which are no
-                # dot_general either
-                jax.checkpoint_policies.save_only_these_names(
-                    "attn_out", "attn_lse", "tp_rs_out", "moe_gate", "moe_up",
-                    # dsa_sel: the packed selection of models/dsa.py, which the
-                    # backward's kernels read and nothing should compute twice
-                    "dsa_sel",
-                    # gdn_out, gdn_states: what ops/gated_delta.py's forward kernel
-                    # writes, o, the chunks' starting states and their inverses, which
-                    # is all its backward kernel reads beside the inputs: the rule runs
-                    # twice a layer (forward, backward), not three times
-                    "gdn_out", "gdn_states",
-                    # ssd_out, ssd_states: likewise ops/ssd.py's forward kernel's y and
-                    # the chunks' starting states
-                    "ssd_out", "ssd_states",
-                ),
+                jax.checkpoint_policies.save_only_these_names(*remat_saves(c)),
             ),
         )
     if c.remat_policy == "full":
         return jax.checkpoint(block)
     raise ValueError(f"unknown remat_policy {c.remat_policy!r}; 'full' or 'dots'")
-
-
-def _decoder(
-    params: Params,
-    tokens: jax.Array,
-    config: LlamaConfig,
-    *,
-    positions: Optional[jax.Array] = None,
-    segment_ids: Optional[jax.Array] = None,
-) -> tuple[jax.Array, Optional[Params]]:
-    """`hidden_states` and the layers' statistics, each leaf stacked
-    over the layers (None for a dense configuration)."""
-    h, stats, _ = _trunk(params, tokens, config, positions=positions, segment_ids=segment_ids)
-    return rms_norm(h, params["final_norm"], config.rms_eps), stats
 
 
 def _trunk(
@@ -516,31 +481,24 @@ def _trunk(
         # layers of unlike kinds: that module's stack (no block of one kind to hand on)
         return (*_own_stack(c).trunk(params, tokens, c, positions=positions,
                                      segment_ids=segment_ids), None)
-    mla = _mla(c)
-    cos = sin = None
-    # CCA rotates part of a head, MLA its decoupled part and DSA two head sizes, from
-    # the positions themselves
-    if _cca(c) is None and mla is None and _dsa(c) is None:
-        with jax.named_scope("attn.rope"):
-            cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
+    mixer = _mixer(c)
+    once = getattr(mixer.load(), mixer.once)(c) if mixer.once else None
 
     with jax.named_scope("embed"):
         h = params["embed"].astype(c.dtype)[tokens]  # [B, S, D]
 
-    block = partial(
-        _block, config=c, cos=cos, sin=sin, positions=positions, segment_ids=segment_ids
-    )
+    block = partial(_block, config=c, positions=positions, segment_ids=segment_ids, once=once)
     # the stack: under this name stand the scan's own slices and stacked writes and
     # the residual adds that no sublayer's scope holds; every scope inside it wins
     with jax.named_scope("block.stack"):
         layers = params["layers"]
-        if mla is not None and mla.has_more_than_the_stack(c):
-            # two kinds of block in one model: the leading dense layers run
-            # before the scan over the expert layers' stack
-            layers = mla.stack_of(params, c)
-            dense = _remat(partial(block, dense_ffn=True), c)
-            for i in range(c.first_dense_layers):
-                h, _ = dense(h, mla.dense_layer(params, i))
+        n_dense, n_mtp = _beside_the_stack(c)
+        if n_dense or n_mtp:
+            # two kinds of block in one model: the leading dense layers run before
+            # the scan over the expert layers' stack, which reads ITS rows of the
+            # selection-bias table
+            layers = {**layers, "router_bias": layers["router_bias"][:c.n_layers - n_dense]}
+            h = run_dense_layers(h, params, n_dense, _remat(partial(block, dense_ffn=True), c))
         block = _remat(block, c)
 
         mesh = current_mesh()
@@ -576,6 +534,40 @@ def _trunk(
             h, stats = jax.lax.scan(block, h, layers)
 
     return h, stats, block
+
+
+def run_dense_layers(h: jax.Array, params: Params, n: int, dense_block) -> jax.Array:
+    """The `n` leading dense layers, one after another: `dense_block(h,
+    layer params) -> (h, None)`, the stack's own block with a dense FFN,
+    rematerialised as the stack's (this module's and models/laguna.py's)."""
+    for i in range(n):
+        h, _ = dense_block(h, jax.tree.map(lambda w: w[i], params["dense_layers"]))
+    return h
+
+
+def mtp_hidden(params: Params, h: jax.Array, next_tokens: jax.Array, c: LlamaConfig,
+               block) -> tuple[jax.Array, Params]:
+    """The MTP module (arXiv:2412.19437 section 2.2) up to its own final
+    norm (which, with the second pass of the head, is
+    `loss_and_weight_fn`'s): m_i = W_eh [RMSNorm_e(Emb(t_{i+1})) ;
+    RMSNorm_h(h_i)], then the block. h [B, S, D]: the last layer's output
+    BEFORE the model's final norm; next_tokens [B, S]: t_{i+1} (the
+    batch's targets); `block(h, layer params) -> (h, statistics)`: the
+    block of the expert-layer kind, rematerialised as the stack's. ->
+    (the block's output, whose logits at i, through the module's own final
+    norm and the model's SAME head, predict t_{i+2}; the last position has
+    no target and weighs 0; the block's statistics)."""
+    mp, d = params["mtp"], c.d_model
+    with jax.named_scope("mtp.merge"):
+        e = rms_norm(params["embed"].astype(c.dtype)[next_tokens], mp["enorm"], c.rms_eps)
+        hn = rms_norm(h, mp["hnorm"], c.rms_eps)
+        w = mp["eh_proj"].astype(c.dtype)
+        # [e ; hn] W_eh as two products over the two halves of W_eh's rows
+        m = (jnp.einsum("bsd,de->bse", e, w[:d]) + jnp.einsum("bsd,de->bse", hn, w[d:]))
+    with jax.named_scope("mtp.block"):
+        lp = {**mp["block"], "router_bias": params["layers"]["router_bias"][-1]}
+        m, stats = block(m, lp)
+    return m, stats
 
 
 def output_weight(params: Params) -> jax.Array:
@@ -620,14 +612,13 @@ def loss_and_weight_fn(
     over layers, at the configuration's coefficients) to the loss and
     returns a third element, the layers' statistics (models/moe.py),
     which train/step.py hands out with the step's metrics. A
-    configuration with a multi-token-prediction block (models/mla.py)
+    configuration with a multi-token-prediction block (`mtp_hidden`)
     adds that head's loss at its weight; the statistics then carry the
     block's row after the layers' and the two losses apart
     (`loss_main`, `loss_mtp`).
 
-    Uses the fused lm-head + CE (nn/layers.py fused_cross_entropy_loss):
-    the [T, V] fp32 logits/softmax pipeline was ~36% of the flagship
-    train step before fusion (round-5 profile).
+    Uses the fused lm-head + CE (nn/layers.py fused_cross_entropy_loss),
+    so the [T, V] fp32 logits and softmax never exist as stored tensors.
 
     A configuration trained by block diffusion has another objective
     (models/block_diffusion.py: it reads `tokens` alone, and the step
@@ -645,13 +636,12 @@ def loss_and_weight_fn(
         )
     if stats is None:
         return loss, weight
-    mla = _mla(config)
-    if mla is not None and config.mtp_layers:
-        # a second prediction head (models/mla.py): the last layer's output merged
+    if _beside_the_stack(config)[1]:
+        # a second prediction head (`mtp_hidden`): the last layer's output merged
         # with the next token's embedding, one more block, the SAME head on the
         # targets shifted by one; the last position has no target there
         targets, mask = batch["targets"], batch.get("mask")
-        m, mtp_stats = mla.mtp_hidden(params, h_last, targets, config, block)
+        m, mtp_stats = mtp_hidden(params, h_last, targets, config, block)
         with jax.named_scope("mtp.head"):
             m = rms_norm(m, params["mtp"]["final_norm"], config.rms_eps)
             ahead = jnp.pad(targets[:, 1:], ((0, 0), (0, 1)))

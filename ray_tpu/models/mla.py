@@ -2,15 +2,14 @@
 
 What GLM-4.7-Flash (zai-org/GLM-4.7-Flash, `model_type` glm4_moe_lite: the
 DeepSeek-V2/V3 forms, arXiv:2405.04434 and arXiv:2412.19437) adds to the
-one decoder of models/llama.py: `GlmLiteConfig`; the attention sublayer
-`mla_sublayer` with its parameters and their logical axes; and the two
-things that make its parameter tree more than one stack of blocks:
-leading DENSE layers before the expert layers, and a multi-token-
-prediction (MTP) block after them. The block, the layer scan, the head
-and the loss are models/llama.py's, which calls `mla_sublayer` in place
-of its own attention when the configuration is a `GlmLiteConfig`; the
-sigmoid router with its selection bias, the shared expert and the share
-of experts held are models/moe.py's.
+one decoder of models/llama.py: `GlmLiteConfig` and the attention sublayer
+`mla_sublayer` with its parameters and their logical axes, the row "mla"
+of `llama.MIXERS`. The block, the layer scan, the head and the loss are
+models/llama.py's, and so are the leading DENSE layers before the expert
+layers and the multi-token-prediction (MTP) block after them, which any
+expert configuration with the fields may have (`llama.init_params` has
+the tree). The sigmoid router with its selection bias, the shared expert
+and the share of experts held are models/moe.py's.
 
 MLA, with x = RMSNorm(hidden), H heads, `q_lora_rank` r_q, `kv_lora_rank`
 r_kv, a head's un-rotated channels d_n, rotary channels d_r, value
@@ -41,26 +40,6 @@ arithmetic): the projections write head-major (the weight read as
 halves of W_kvb's columns (cutting the small weight, not the
 activation), and the kernel takes q, k and v as they are.
 
-THE TREE. `n_layers` counts the dense and the expert layers together, as
-`num_hidden_layers` does. `params["layers"]` is the EXPERT layers' stack
-(what the layer scan runs over); `params["dense_layers"]` the
-`first_dense_layers` leading blocks (the same attention, a dense SwiGLU
-of width `dense_d_ff`), run before the scan; `params["mtp"]` the MTP
-module: two norms, the [2 d_model, d_model] merge, one more block of the
-expert-layer kind and its own final norm. The selection biases of EVERY
-expert block, the MTP block's last, are one table,
-`params["layers"]["router_bias"]` [expert layers + 1, n_experts]: it is
-the one state of the model that a balancing rule moves and no gradient
-does, and whoever balances it (chipbench's model builder sets it once,
-before the first step) writes one array.
-
-MTP (arXiv:2412.19437 section 2.2), with h_i the last layer's output
-BEFORE the final norm and t_{i+1} the next token:
-m_i = W_eh [RMSNorm_e(Emb(t_{i+1})) ; RMSNorm_h(h_i)], the block, the
-module's own final norm, then the model's SAME head; the logits at i
-predict t_{i+2}; the last position has no target and weighs 0;
-loss = loss_main + `mtp_loss_weight` x loss_mtp.
-
 Not implemented, and refused by name in models/registry.py: yarn-scaled
 rotary (`rope_scaling`), group-limited routing (`n_group` > 1). The
 update rule that moves the selection bias between steps is a training
@@ -70,14 +49,14 @@ recipe's and is left out: the bias is a parameter that no step moves.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional
+from typing import Any, ClassVar, Optional
 
 import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
 
 from ray_tpu import obs
-from ray_tpu.models import llama, moe
+from ray_tpu.models import moe
 from ray_tpu.nn.layers import init_dense, rms_norm
 from ray_tpu.ops.attention import attention_head_major
 
@@ -93,6 +72,7 @@ class GlmLiteConfig(moe.MoEConfig):
     of one routed expert, `n_layers` the dense and expert layers
     together."""
 
+    mixer: ClassVar[str] = "mla"
     q_lora_rank: int = 768
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 192
@@ -180,13 +160,13 @@ def attention_axes() -> Params:
     }
 
 
-def attention_params(config: GlmLiteConfig, key: jax.Array) -> Params:
-    """MLA's weights of `config.n_layers` layers, stacked over layers."""
+def attention_params(config: GlmLiteConfig, keys: jax.Array) -> Params:
+    """MLA's weights of `config.n_layers` layers, stacked (drawn from `keys[0]`)."""
     c = config
     L, d, H = c.n_layers, c.d_model, c.n_heads
     rq, rkv, dn, dr, dv = (c.q_lora_rank, c.kv_lora_rank, c.qk_nope_head_dim,
                            c.qk_rope_head_dim, c.v_head_dim)
-    keys = jax.random.split(key, 5)
+    keys = jax.random.split(keys[0], 5)
 
     def per_layer(k, shape):
         return jax.vmap(lambda kk: init_dense(kk, shape, c.param_dtype))(jax.random.split(k, L))
@@ -261,106 +241,7 @@ def mla_sublayer(x: jax.Array, lp: Params, c: GlmLiteConfig, *, positions: jax.A
         with jax.named_scope("mla.attend"):
             o = attention_head_major(q, k, v, causal=True, segment_ids=segment_ids,
                                      impl=c.attention_impl)
-            # saved by the "dots" remat policy, as llama._block's is
+            # saved by the "dots" remat policy, as models/gqa.py's is
             o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
         with jax.named_scope("mla.out"):
             return jnp.einsum("bhsk,hkd->bsd", o, lp["wo"].astype(dt).reshape(H, dv, D))
-
-
-# -- the tree: dense layers before the stack, the MTP module after it ---------
-
-
-def has_more_than_the_stack(c: GlmLiteConfig) -> bool:
-    return bool(c.first_dense_layers or c.mtp_layers)
-
-
-def _stack_config(c: GlmLiteConfig, n_layers: int) -> GlmLiteConfig:
-    """`n_layers` blocks of the expert-layer kind and nothing beside them:
-    what models/llama.py builds as one stack."""
-    if c.mtp_layers not in (0, 1):
-        raise ValueError(f"{c.mtp_layers} multi-token-prediction blocks: 0 or 1 are implemented")
-    return dataclasses.replace(c, n_layers=n_layers, first_dense_layers=0, mtp_layers=0)
-
-
-def logical_axes(c: GlmLiteConfig) -> Params:
-    """Of the whole tree `init_params` makes."""
-    axes = llama.logical_axes(_stack_config(c, c.n_expert_layers))
-    if c.first_dense_layers:
-        axes["dense_layers"] = {
-            "ln1": ("layers", "norm"), **attention_axes(), "ln2": ("layers", "norm"),
-            "w_gate": ("layers", "embed", "mlp"), "w_up": ("layers", "embed", "mlp"),
-            "w_down": ("layers", "mlp", "embed"),
-        }
-    if c.mtp_layers:
-        block = {k: v[1:] for k, v in axes["layers"].items() if k != "router_bias"}
-        axes["mtp"] = {"enorm": ("norm",), "hnorm": ("norm",), "eh_proj": (None, "embed"),
-                       "block": block, "final_norm": ("norm",)}
-    return axes
-
-
-def init_params(c: GlmLiteConfig, key: jax.Array) -> Params:
-    """The whole tree (the module's docstring): the expert layers' stack
-    as models/llama.py makes it, the dense layers and the MTP module."""
-    d, nd = c.d_model, c.first_dense_layers
-    k_dense, k_mtp, k_merge = jax.random.split(jax.random.fold_in(key, 47), 3)
-    params = llama.init_params(_stack_config(c, c.n_expert_layers), key)
-    if nd:
-        k_attn, k_gate, k_up, k_down = jax.random.split(k_dense, 4)
-
-        def dense(k, shape):
-            return jax.vmap(lambda kk: init_dense(kk, shape, c.param_dtype))(
-                jax.random.split(k, nd))
-
-        params["dense_layers"] = {
-            "ln1": jnp.ones((nd, d), c.param_dtype),
-            **attention_params(_stack_config(c, nd), k_attn),
-            "ln2": jnp.ones((nd, d), c.param_dtype),
-            "w_gate": dense(k_gate, (d, c.dense_d_ff)),
-            "w_up": dense(k_up, (d, c.dense_d_ff)),
-            "w_down": dense(k_down, (c.dense_d_ff, d)),
-        }
-    if c.mtp_layers:
-        block = llama.init_params(_stack_config(c, 1), k_mtp)["layers"]
-        bias = block.pop("router_bias")
-        params["layers"]["router_bias"] = jnp.concatenate(
-            [params["layers"]["router_bias"], bias])
-        params["mtp"] = {
-            "enorm": jnp.ones((d,), c.param_dtype),
-            "hnorm": jnp.ones((d,), c.param_dtype),
-            "eh_proj": init_dense(k_merge, (2 * d, d), c.param_dtype),
-            "block": jax.tree.map(lambda w: w[0], block),
-            "final_norm": jnp.ones((d,), c.param_dtype),
-        }
-    return params
-
-
-def stack_of(params: Params, c: GlmLiteConfig) -> Params:
-    """The expert layers' stack with ITS rows of the selection-bias table."""
-    layers = params["layers"]
-    return {**layers, "router_bias": layers["router_bias"][:c.n_expert_layers]}
-
-
-def dense_layer(params: Params, i: int) -> Params:
-    return jax.tree.map(lambda w: w[i], params["dense_layers"])
-
-
-def mtp_hidden(params: Params, h: jax.Array, next_tokens: jax.Array, c: GlmLiteConfig,
-               block) -> tuple[jax.Array, Params]:
-    """The MTP module up to its own final norm (which, with the second
-    pass of the head, is llama.loss_and_weight_fn's). h [B, S, D]: the
-    last layer's output before the model's final norm; next_tokens
-    [B, S]: t_{i+1} (the batch's targets); `block(h, layer params) ->
-    (h, statistics)`: models/llama.py's block of the expert-layer kind,
-    rematerialised as the stack's. -> (the block's output, whose logits
-    at i predict t_{i+2}; the block's statistics)."""
-    mp, d = params["mtp"], c.d_model
-    with jax.named_scope("mtp.merge"):
-        e = rms_norm(params["embed"].astype(c.dtype)[next_tokens], mp["enorm"], c.rms_eps)
-        hn = rms_norm(h, mp["hnorm"], c.rms_eps)
-        w = mp["eh_proj"].astype(c.dtype)
-        # [e ; hn] W_eh as two products over the two halves of W_eh's rows
-        m = (jnp.einsum("bsd,de->bse", e, w[:d]) + jnp.einsum("bsd,de->bse", hn, w[d:]))
-    with jax.named_scope("mtp.block"):
-        lp = {**mp["block"], "router_bias": params["layers"]["router_bias"][-1]}
-        m, stats = block(m, lp)
-    return m, stats

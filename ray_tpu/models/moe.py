@@ -368,7 +368,7 @@ def _all_rows(xt, w, w_gate, w_up, w_down, order, inv, sizes, *, tail, dtype):
     with jax.named_scope("moe.dispatch"):
         xs = _to_expert_order(xt, order, inv)
     with jax.named_scope("moe.experts"):
-        # named for the remat policy (llama._decoder), which knows
+        # named for the remat policy (llama._remat), which knows
         # dot_general's outputs but not a grouped matmul's
         name = jax.ad_checkpoint.checkpoint_name
         gmm = functools.partial(grouped_matmul, group_sizes=sizes, tail=tail)

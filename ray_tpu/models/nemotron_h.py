@@ -128,6 +128,9 @@ MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
 # a kind's group of stacked leaves under params["layers"]
 GROUP = {MAMBA: "mamba", EXPERTS: "experts", ATTENTION: "attention"}
 _F32 = jnp.float32
+# saved by llama._remat's "dots" policy beside its own names: ops/ssd.py's forward kernel's y
+# and the chunks' starting states, which is all its backward kernel reads beside the inputs
+REMAT_SAVES = ("ssd_out", "ssd_states")
 _CONV_HEAD = 128   # the convolution's channels go through ops/gdn_conv.py as heads of 128
 
 
@@ -412,7 +415,7 @@ def _lane_blocks(v: jax.Array, width: int) -> jax.Array:
 def attention_sublayer(u: jax.Array, lp: Params, c: NemotronHConfig, *,
                        segment_ids: Optional[jax.Array]) -> jax.Array:
     """u [B, S, D] -> the attention mixer's output: GQA without a rotary,
-    head-major from the projections to `wo` (models/llama.py's layout).
+    head-major from the projections to `wo` (models/gqa.py's layout).
     Scopes `attn.qkv`, `attn.attend`, `attn.out`."""
     B, S, D = u.shape
     hd, dt = c.head_dim, u.dtype
@@ -422,7 +425,7 @@ def attention_sublayer(u: jax.Array, lp: Params, c: NemotronHConfig, *,
     with jax.named_scope("attn.attend"):
         o = attention_head_major(q, k, v, causal=True, segment_ids=segment_ids,
                                  impl=c.attention_impl)
-        # saved by the "dots" remat policy, as llama._block's is
+        # saved by the "dots" remat policy, as models/gqa.py's is
         o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
     with jax.named_scope("attn.out"):
         return jnp.einsum("bhsk,hkd->bsd", o, lp["wo"].astype(dt).reshape(c.n_heads, hd, D))

@@ -34,7 +34,7 @@ index K - 1 - j.)
 
 A FULL layer's mixer: q, k, v = u Wq, u Wk, u Wv at `n_heads` heads of
 d_model / n_heads, an RMSNorm over the whole projected width of q and
-of k (llama._norm_over_heads), causal softmax attention at
+of k (gqa.norm_over_heads), causal softmax attention at
 1 / sqrt(head_dim), Wo. NO rotary (`rope_theta` null in the published
 config): position reaches a full layer through the linear layers' state.
 
@@ -76,7 +76,7 @@ import jax.ad_checkpoint
 import jax.numpy as jnp
 
 from ray_tpu import obs
-from ray_tpu.models import laguna, llama
+from ray_tpu.models import gqa, laguna, llama
 from ray_tpu.nn.layers import head_major, init_dense, rms_norm, swiglu
 from ray_tpu.ops.attention import attention_head_major
 # by THIS name the benchmark's runner finds the rule the sublayer runs and holds it alone to
@@ -88,6 +88,10 @@ from ray_tpu.ops.gdn_conv import gdn_conv
 Params = dict[str, Any]
 FULL, LINEAR = "full_attention", "linear_attention"
 _F32 = jnp.float32
+# saved by llama._remat's "dots" policy beside its own names: what ops/gated_delta.py's forward
+# kernel writes (o, the chunks' starting states and their inverses), which is all its backward
+# kernel reads beside the inputs: the rule runs twice a layer (forward, backward), not three times
+REMAT_SAVES = ("gdn_out", "gdn_states")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -325,12 +329,12 @@ def full_sublayer(x: jax.Array, lp: Params, c: OlmoHybridConfig, *, positions: j
         q, k, v = (head_major(jnp.einsum("bsd,dnh->bnsh", x, lp[n].astype(dt).reshape(D, H, hd)))
                    for n in ("wq", "wk", "wv"))
     with jax.named_scope("attn.rope"):
-        q = llama._norm_over_heads(q, lp["q_norm"], c.rms_eps)
-        k = llama._norm_over_heads(k, lp["k_norm"], c.rms_eps)
+        q = gqa.norm_over_heads(q, lp["q_norm"], c.rms_eps)
+        k = gqa.norm_over_heads(k, lp["k_norm"], c.rms_eps)
     with jax.named_scope("attn.attend"):
         o = attention_head_major(q, k, v, causal=True, segment_ids=segment_ids,
                                  impl=c.attention_impl)
-        # saved by the "dots" remat policy, as llama._block's is
+        # saved by the "dots" remat policy, as models/gqa.py's is
         o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
     with jax.named_scope("attn.out"):
         return jnp.einsum("bhsk,hkd->bsd", o, lp["wo"].astype(dt).reshape(H, hd, D))

@@ -559,6 +559,14 @@ def _nemotron_h_from_hf(hf: dict, **overrides):
     return dataclasses.replace(nh.NEMOTRON_TWOTOWER_30B_A3B, **fields)
 
 
+# `model_type` -> the family's mapping (any other: the llama / mixtral / OLMoE one below)
+_FROM_HF = {
+    "zaya": _zaya_from_hf, "glm4_moe_lite": _glm_lite_from_hf, "laguna": _laguna_from_hf,
+    "mellum": _mellum_from_hf, "sdar_moe": _sdar_from_hf, "KeyeVL2": _keye_from_hf,
+    "olmo_hybrid": _olmo_hybrid_from_hf, "nemotron_h": _nemotron_h_from_hf,
+}
+
+
 def config_from_hf(hf: dict, **overrides):
     """Map a HF `config.json` dict to a LlamaConfig/MoEConfig/ZayaConfig/
     GlmLiteConfig/LagunaConfig/KeyeConfig/OlmoHybridConfig/NemotronHConfig.
@@ -588,22 +596,9 @@ def config_from_hf(hf: dict, **overrides):
     `rope_scaling` and an explicit `head_dim` that is not hidden_size /
     heads stay refused.
     """
-    if hf.get("model_type") == "zaya":
-        return _zaya_from_hf(hf, **overrides)
-    if hf.get("model_type") == "glm4_moe_lite":
-        return _glm_lite_from_hf(hf, **overrides)
-    if hf.get("model_type") == "laguna":
-        return _laguna_from_hf(hf, **overrides)
-    if hf.get("model_type") == "mellum":
-        return _mellum_from_hf(hf, **overrides)
-    if hf.get("model_type") == "sdar_moe":
-        return _sdar_from_hf(hf, **overrides)
-    if hf.get("model_type") == "KeyeVL2":
-        return _keye_from_hf(hf, **overrides)
-    if hf.get("model_type") == "olmo_hybrid":
-        return _olmo_hybrid_from_hf(hf, **overrides)
-    if hf.get("model_type") == "nemotron_h":
-        return _nemotron_h_from_hf(hf, **overrides)
+    family = _FROM_HF.get(hf.get("model_type"))
+    if family is not None:
+        return family(hf, **overrides)
     archs = set(hf.get("architectures", ()))
     is_olmoe = "OlmoeForCausalLM" in archs or (not archs and hf.get("model_type") == "olmoe")
     # the num_local_experts heuristic only applies to config dicts with NO

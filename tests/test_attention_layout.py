@@ -1,4 +1,4 @@
-"""The full-attention (GQA) sublayer of models/llama.py::_block is
+"""The full-attention (GQA) sublayer (models/gqa.py, run by models/llama.py::_block) is
 HEAD-MAJOR, [B, heads, S, hd], from where its projections write q, k and
 v to where `wo` contracts what the kernel gives back (PR 38). Held here,
 on the CPU at small shapes: the block against the [B, S, H, hd]
@@ -76,7 +76,7 @@ def _segments(kind):
 def test_head_major_block_is_the_token_major_block(config, positions, segments, impl):
     c = dataclasses.replace(config, attention_impl=impl)
     cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
-    kw = dict(cos=cos, sin=sin, positions=_positions(positions), segment_ids=_segments(segments))
+    kw = dict(positions=_positions(positions), segment_ids=_segments(segments))
     h, lp = _rand(1, (B, S, c.d_model)), _layer(c)
     ct = _rand(2, (B, S, c.d_model))
 
@@ -84,10 +84,10 @@ def test_head_major_block_is_the_token_major_block(config, positions, segments, 
         return lambda h, lp: jnp.vdot(ct, f(h, lp))
 
     def block(h, lp):
-        return llama._block(h, lp, config=c, **kw)[0]
+        return llama._block(h, lp, config=c, once={"cos": cos, "sin": sin}, **kw)[0]
 
     def ref(h, lp):
-        return reference_block(h, lp, c, **kw)
+        return reference_block(h, lp, c, cos=cos, sin=sin, **kw)
 
     def value_and_grads(f):   # one jitted program a side: bare, a configuration's first case is 50-65 s
         return jax.jit(lambda h, lp: (f(h, lp), jax.grad(scalar(f), argnums=(0, 1))(h, lp)))
@@ -148,7 +148,7 @@ def _block_jaxpr(c, mesh=None):
     h, lp = _rand(1, (4, S, c.d_model)), _layer(c)
 
     def block(h, lp):
-        return llama._block(h, lp, config=c, cos=cos, sin=sin,
+        return llama._block(h, lp, config=c, once={"cos": cos, "sin": sin},
                             positions=jnp.arange(S, dtype=jnp.int32), segment_ids=None)[0]
 
     if mesh is None:

@@ -18,7 +18,7 @@ import pytest
 
 from model_cases import (GLM_LITE, contract_cases, reference_path, seeded_params, train_path,
                          worst_leaf)
-from ray_tpu.models import llama, mla
+from ray_tpu.models import llama
 from ray_tpu.nn.layers import rms_norm
 
 FP32, B, S = GLM_LITE.fp32, GLM_LITE.batch, GLM_LITE.seq
@@ -63,7 +63,7 @@ def per_position_losses(params, batch, cfg):
     masking one position at a time out of neither: from the reference's
     per-position form on the program's hidden states."""
     h_last, _, block = llama._trunk(params, batch["tokens"], cfg)
-    m, _ = mla.mtp_hidden(params, h_last, batch["targets"], cfg, block)
+    m, _ = llama.mtp_hidden(params, h_last, batch["targets"], cfg, block)
     head = params["lm_head"]
 
     def nll(h, norm, targets):
@@ -103,7 +103,7 @@ def test_mtp_predicts_the_token_after_next_and_the_last_position_weighs_nothing(
     assert not np.allclose(np.asarray(main2[:, t - 1]), np.asarray(main[:, t - 1]), atol=1e-4)
     # the last position: whatever its MTP logits are, the loss does not see them
     h_last, _, block = llama._trunk(params, batch["tokens"], FP32)
-    m, _ = mla.mtp_hidden(params, h_last, batch["targets"], FP32, block)
+    m, _ = llama.mtp_hidden(params, h_last, batch["targets"], FP32, block)
 
     def mtp_loss_given(m):
         from ray_tpu.nn.layers import fused_cross_entropy_loss

@@ -64,6 +64,10 @@ _MELLUM2_STEP = "535bbc499fda02b7ef3ffc17619d2629ed5f07516436cae359a007a5d3f8fbb
 # sequence of 8,192 tokens = 16,384 rows
 SDAR = dict(batch=1, model="sdar-30b-a3b", n_layers=4, seq=8192, vocab_size=19072,
             experts_held=16)
+# sha256 of that step's lowered text (the only step under the block-diffusion objective), as PR
+# 56's tree lowers it: recorded on the parent of PR 57 before that PR moved the dense prefix
+# out of models/mla.py and the mixer kinds of models/llama.py into one table, and held by it
+_SDAR_STEP = "523e528c994020deed402fd1a0262c26191211b59da7a4a25466be6deea8cbc1"
 SDAR_SCOPES = ("diff.corrupt", "diff.loss", "attn.qkv", "attn.norm", "attn.rope", "attn.attend",
                "flash.blockdiff", "attn.out", "moe.router", "moe.dispatch", "moe.experts",
                "moe.combine", "block.norm", "block.stack", "embed", "head", "optim")
@@ -328,6 +332,12 @@ def test_sdar_train_step_lowers_to_the_masked_kernels_and_the_fused_backward(v5e
     assert "1x32x16384x128xbf16" in text and "1x4x8192x128xbf16" in text
     assert "8192x19072xf32" in text and "16384x19072xf32" not in text
     assert "16x2048x768x" in text and "128x2048x768x" not in text and "2048x128x" in text
+
+
+def test_sdar_train_step_lowers_to_the_text_it_had(v5e):
+    """The lowering the case above counted its sites over (tests/v5e_steps.py's
+    memo; that case stands first because it counts them), hashed."""
+    assert train_step(v5e, **SDAR).lowered_hash() == _SDAR_STEP
 
 
 @pytest.mark.parametrize("scope", SDAR_SCOPES)

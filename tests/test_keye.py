@@ -167,7 +167,7 @@ def test_eight_shares_add_up_to_the_uncut_layer():
 
     def block(cfg, lp):
         def f(h):
-            out, stats = llama._block(h, lp, config=cfg, cos=None, sin=None,
+            out, stats = llama._block(h, lp, config=cfg,
                                       positions=jnp.arange(S), segment_ids=None)
             return out, stats
         def both(h):

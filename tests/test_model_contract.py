@@ -9,7 +9,10 @@ model beside that model's train path against its reference
 (tests/test_contract_<model>.py: one process compiles a configuration
 once, and a file is about 150 s alone at most; ROADMAP D8). What a model
 holds of its own (its sublayers, its routing, its shares) stands in its
-own file."""
+own file. Two sections compile little and stand here because the file has
+the seconds (PR 57; 16 s alone): what a checkpoint and the benchmark's
+builders depend on, every tiny preset's seeded tree and axes by digest;
+and the table of mixer kinds, models/llama.py::MIXERS."""
 
 import operator
 
@@ -108,3 +111,141 @@ def test_engine_refuses_the_model_by_name(model):
 
     with pytest.raises(ValueError, match=model.refused_as):
         EngineConfig(model=model.tiny)
+
+
+# -- what a checkpoint and the benchmark's builders depend on ---------------------------
+
+TINY_PRESETS = ("llama-tiny", "moe-tiny", "zaya-tiny", "glm-lite-tiny", "laguna-tiny",
+                "mellum2-tiny", "sdar-tiny", "keye-tiny", "olmo-hybrid-tiny", "nemotron-h-tiny")
+# preset -> sha256 of (every leaf's path, shape and dtype), of the leaves' bytes in the paths'
+# order, and of (every leaf's path and logical axes), as PR 56's tree (the parent of PR 57,
+# which moved WHERE models/llama.py reads a configuration's mixer kind) makes them from
+# `jax.random.key(0)`. A change that MEANS to re-lay a tree or to re-seed it replaces the
+# row the failure prints, and says which checkpoints and which of chipbench/model_builders/
+# it strands.
+_TINY_TREES = {
+    "llama-tiny": (
+        "12b08c6c51a76ff0251b3e92682f1ef08b9986c07ae4a434207aa8c82de3c153",
+        "9a9c6362dd44c666ce2392c6acc9698dec124a6238099fd6c2c120494f029680",
+        "8736df3e24f3fa7b0490ae88dc7a958d3b480a655d337fd962468b0c459f6faf"),
+    "moe-tiny": (
+        "7aeb9feaf95ef262e98e7230ec460727587a726a4f628658598597c0130226cf",
+        "a887c5ea8bb05269ef9a153497f9818031703c1a3de694ac9f1e6a9e647a7e6e",
+        "f57d08af42b0d76afa9ba2238e3db3c2e819a47a7edf57d7fb1674e800fa2f55"),
+    "zaya-tiny": (
+        "b7cbeab411ee54d93f0a016802e9874f3482094ee86a138a42b176c3e7a7756a",
+        "e8eabfd27228ec25db5ce30edeb7770998591fc2ad8ee083726bc6b112e66696",
+        "6120e80a2c378ce5ee31dd1d53ecdf6f4d8aedd2a80c5e2b04b0b5a113633270"),
+    "glm-lite-tiny": (
+        "28b7837aef8f0d02338b7ba84ed0fd73301bf94285c8f5976ebf02bc799e96e0",
+        "52b0a91d3a3c345239f080a3a9b5eb9c3f898c986159a3d04534787f17c67097",
+        "5cbf29a078309f324d7170650e8ec739dfcf64dea59effd80e2960105c848e92"),
+    "laguna-tiny": (
+        "a95e98c36291f4581c5d767b42c51b82bad0f83ab713659ddd7a239e73d44f52",
+        "9431b972d8e83c6e49a1df9e67bec343f3cd2028b5d961337d27d9900681d6a3",
+        "4f7dab57d16b5a9d86272e5321b59792efb5de31c3a531f0189a2af24087e860"),
+    "mellum2-tiny": (
+        "c479e5b9eea4d392ebe2f6ff566f774982d187419b089eac57540cf3b6a02fd4",
+        "8b838beefbfa4bf303d7da6e7d03b9fb0e44971b1c23c2a8a9cd583ccf8823dd",
+        "defdadba64045e0053d1be78e7f5c705b84ccf8d5f23c9418f0bd25b262b22c3"),
+    "sdar-tiny": (
+        "893e1bcb2882a8905b59e9539017cb341cdc027d0874286be58fc06919966c5c",
+        "aee5115c885a1fd37cfcadd7d7df6d8fd4e284793df111d6fcbb19a1a26dd7a2",
+        "1310c1e985b82d17467a557c5ce35875ac7339d364569f4cabef8a137f7efa44"),
+    "keye-tiny": (
+        "58d2517c13280885c48c870bbae5a02f627d2f747fef51ea52bd865d02fb5547",
+        "0f1f3a59478442e29c43c51afeed7157db4fe000efc8e54f20a8906f1ebc918b",
+        "01915fdbc53afa9a84b042181d82c7068fcea09e1037f82c2e7c58e66503cd20"),
+    "olmo-hybrid-tiny": (
+        "a5148aec9e24195339afb077982f0b26834cfee3682c10ad9a20222b8c569404",
+        "d8f097c9ce50c236c6751b87b18a2b3ff0d7589a39f15f27726d95e842661f96",
+        "0b0970885095f40edc77f077244510d697c95d0a3cb4db43c3a7953dbe6c5f94"),
+    "nemotron-h-tiny": (
+        "67e89bb9c55041d4558b8ea1be5da6602f28da2643a74de9296380f7bf998aa5",
+        "8f5a4d6708400392896714483bd6f1efe07b9539983a511dc806c2f6cdf0e9e0",
+        "52a3880e1e46087ffda639c86f1502f768136575a2e141cd49c78becc9d52f90"),
+}
+
+
+def _tree_digests(cfg) -> tuple:
+    import hashlib
+
+    import jax
+    import numpy as np
+
+    from ray_tpu.models import llama
+
+    def by_path(tree, **kw):
+        return sorted((jax.tree_util.keystr(p), v)
+                      for p, v in jax.tree_util.tree_leaves_with_path(tree, **kw))
+
+    def digest(lines):
+        return hashlib.sha256("\n".join(lines).encode()).hexdigest()
+
+    leaves = [(p, np.asarray(v)) for p, v in by_path(llama.init_params(cfg, jax.random.key(0)))]
+    values = hashlib.sha256()
+    for _, v in leaves:
+        values.update(np.ascontiguousarray(v).tobytes())
+    axes = by_path(llama.logical_axes(cfg), is_leaf=lambda a: isinstance(a, tuple))
+    return (digest(f"{p} {v.shape} {v.dtype}" for p, v in leaves), values.hexdigest(),
+            digest(f"{p} {a}" for p, a in axes))
+
+
+@pytest.mark.parametrize("preset", TINY_PRESETS)
+def test_tiny_preset_seeds_the_tree_and_names_the_axes_it_did(preset):
+    """`init_params(c, key(0))` gives the tree it gave (paths, shapes,
+    dtypes, every leaf's bytes) and `logical_axes(c)` the axes."""
+    got = _tree_digests(get_model_config(preset))
+    assert got == _TINY_TREES.get(preset), f'    "{preset}": {got!r},'
+
+
+# -- the table of mixer kinds (models/llama.py::MIXERS) ---------------------------------
+
+
+def test_every_registered_configuration_names_a_row_of_the_table():
+    """The kind is the configuration CLASS's: no field, so a
+    configuration's repr, equality and hash are what they were."""
+    import dataclasses
+
+    from ray_tpu.models import llama
+    from ray_tpu.models.registry import list_models
+
+    kinds = {"LlamaConfig": "gqa", "MoEConfig": "gqa", "ZayaConfig": "cca",
+             "GlmLiteConfig": "mla", "KeyeConfig": "dsa"}
+    for name in list_models():
+        cfg = get_model_config(name)
+        assert llama._mixer(cfg) is llama.MIXERS[cfg.mixer], name
+        assert "mixer" not in {f.name for f in dataclasses.fields(cfg)}, name
+        assert "mixer" not in repr(cfg), name
+        if type(cfg).__name__ in kinds:   # the others' stacks are their `stack_module`'s
+            assert cfg.mixer == kinds[type(cfg).__name__], name
+    assert set(llama.MIXERS) == set(kinds.values())
+
+
+def test_an_unknown_mixer_kind_is_refused_by_name():
+    from ray_tpu.models import llama
+
+    class Odd(llama.LlamaConfig):
+        mixer = "odd"
+
+    import jax
+
+    odd = Odd(vocab_size=64, d_model=16, n_layers=1, n_heads=2, n_kv_heads=1, d_ff=16, max_seq=8)
+    for build in (llama.logical_axes, lambda c: llama.init_params(c, jax.random.key(0))):
+        with pytest.raises(ValueError, match="unknown mixer kind 'odd'.*cca.*dsa.*gqa.*mla"):
+            build(odd)
+
+
+def test_the_names_remat_can_save_are_the_ten_it_saved():
+    """Every row's, the names that are no mixer's, and what the two
+    `stack_module` modules with kernels of their own declare."""
+    from ray_tpu.models import laguna, llama, nemotron_h, olmo_hybrid
+
+    saved = set()
+    for cfg in (llama.LLAMA_TINY, laguna.LAGUNA_TINY, olmo_hybrid.OLMO_HYBRID_TINY,
+                nemotron_h.NEMOTRON_H_TINY):
+        saved |= llama.remat_saves(cfg)
+    assert llama.remat_saves(llama.LLAMA_TINY) == {
+        "attn_out", "attn_lse", "tp_rs_out", "moe_gate", "moe_up", "dsa_sel"}
+    assert saved == {"attn_out", "attn_lse", "tp_rs_out", "moe_gate", "moe_up", "dsa_sel",
+                     "gdn_out", "gdn_states", "ssd_out", "ssd_states"}

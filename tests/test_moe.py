@@ -151,9 +151,10 @@ def test_qk_norm_meets_the_reference_and_is_used():
     with jax.default_matmul_precision("highest"):
         h = params["embed"][tokens]
         lp = jax.tree.map(lambda x: x[0], params["layers"])
-        cos, sin = llama.rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
+        from ray_tpu.models import gqa
+
         block = jax.jit(lambda lp: llama._block(
-            h, lp, config=cfg, cos=cos, sin=sin,
+            h, lp, config=cfg, once=gqa.rotary_tables(cfg),
             positions=jnp.arange(tokens.shape[1]), segment_ids=None)[0][0])
         out = block(lp)
         ref = olmoe_decoder.attention(h[0], lp, _shape(cfg))

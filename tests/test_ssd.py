@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from chipbench.reference import nemotron_h_decoder as reference
-from ray_tpu.models import llama
+from ray_tpu.models import llama, nemotron_h
 from ray_tpu.ops.ssd import ssd_scan, ssd_scan_lanes
 
 B, H, G, P, N = 2, 8, 2, 16, 32
@@ -193,12 +193,13 @@ def _kernels(eqns):
     ("full", ["ssd_scan_fwd", "ssd_scan_fwd", "ssd_scan_bwd"])])
 def test_under_the_models_remat_policy_the_scan_runs_twice_and_not_three_times(policy, kernels):
     """models/llama.py::_remat's "dots" policy saves what the forward kernel
-    writes by name (`ssd_out`, `ssd_states`): the traced gradient of a
+    writes by name (`ssd_out`, `ssd_states`: models/nemotron_h.py's
+    `REMAT_SAVES`, read for a configuration of that stack): the traced gradient of a
     rematerialised block holds ONE forward and ONE backward kernel a scan;
     a policy that saves nothing runs the forward once more."""
     T = 32
     args = inputs(T, seed=8)
-    c = dataclasses.replace(llama.LLAMA_TINY, remat=True, remat_policy=policy)
+    c = dataclasses.replace(nemotron_h.NEMOTRON_H_TINY, remat=True, remat_policy=policy)
     block = llama._remat(lambda *a: jnp.tanh(ssd_scan(*a, chunk=16)), c)
     jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(block(*a)), argnums=range(6)))(*args)
     assert _kernels(_walk(jaxpr.jaxpr, [])) == kernels

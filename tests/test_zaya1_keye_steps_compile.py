@@ -27,6 +27,12 @@ KEYE = dict(batch=1, model="keye-vl-2.0-30b-a3b", n_layers=1, seq=8192, vocab_si
             experts_held=16)
 # two of the cell's layers, LOWERED only: the stack's scan is there from two layers on
 KEYE_2 = {**KEYE, "n_layers": 2}
+# sha256 of the lowered step of `keye-train-8k` (the only step through models/dsa.py) at ONE
+# layer and at two, as PR 56's tree lowers them: recorded on the parent of PR 57 before that PR
+# moved WHERE models/llama.py reads a configuration's mixer kind, and held by it letter for
+# letter; the account of every hash is tests/test_m7b_steps_compile.py's
+_KEYE_STEP = {1: "12fa5d1b38d079ceea5913523724c34bb744ac0e1fed3af239c1f092b492f5e5",
+              2: "f8c66c23431e2ba4d86f335fd38082030c967c1da2c2699d33dfe5c7d8ac637e"}
 KEYE_SCOPES = (
     "dsa.qkv", "dsa.norm", "dsa.rope", "dsa.index.proj", "dsa.index.scores", "dsa.select",
     "dsa.attend", "dsa.out", "moe.router", "moe.dispatch", "moe.experts", "moe.combine",
@@ -86,6 +92,13 @@ def test_zaya_share_train_step_runs_its_kernels_and_skips_the_rows_elsewhere(v5e
              if len(dims) >= 4 and 4096 in dims and dims[order[0]] != 4096}
     assert tiles and all(rows == 4096 for rows, _ in tiles), tiles
     assert step.memory.temp_size_in_bytes < 0.8522 * 2 ** 30
+
+
+@pytest.mark.parametrize("n_layers", [1, 2], ids=["one_layer", "two_layers"])
+def test_keye_train_step_lowers_to_the_text_it_had(v5e, n_layers):
+    """The lowerings the two cases below read (tests/v5e_steps.py's memo: no
+    compile and no lowering of this case's own), hashed."""
+    assert train_step(v5e, **{**KEYE, "n_layers": n_layers}).lowered_hash() == _KEYE_STEP[n_layers]
 
 
 def test_keye_share_train_step_runs_the_kernels_under_a_packed_selection(v5e):
