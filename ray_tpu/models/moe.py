@@ -676,8 +676,30 @@ _held_or_all.defvjp(_held_or_all_vjp_fwd, _held_or_all_vjp_bwd)
 _held_or_all_once = jax.jit(_held_or_all, static_argnums=(0,))
 
 
+def _of_chosen(values, chosen):
+    """values [N, E], chosen int [N, K] -> values[n, chosen[n, k]] [N, K]:
+    `jnp.take_along_axis(values, chosen, axis=-1)` value for value and
+    gradient for gradient (one element selected and zeros added to it:
+    exact), as a compare and a sum over the E columns and not a gather.
+    On the chip a gather of single elements fetches them one by one and
+    its transpose scatters them one by one: 1.34 ms for the 131,072 of
+    `mellum2-train-16k`'s [16384, 64] and 0.89 ms back, where the
+    router's matmul takes 0.09 (PERF.md section 6, PR 59). Either
+    direction of this is one fused pass over [N, K, E]. With ONE expert
+    a token (ZAYA1) the gather stays: 8,192 single elements are 0.08 ms
+    and their scatter nothing, and without it the compiler packs
+    `zaya1-train`'s step, the cell nearest the device's memory, 0.08 GiB
+    worse (8.112 -> 8.190 GiB of temporaries: rehearsal, PR 59)."""
+    if chosen.shape[1] == 1:
+        return jnp.take_along_axis(values, chosen, axis=-1)
+    picked = chosen[:, :, None] == jnp.arange(values.shape[1], dtype=chosen.dtype)
+    return jnp.sum(jnp.where(picked, values[:, None, :], 0.0), axis=-1)
+
+
 def _mlp_router_logits(xt, lp: Params, c: MoEConfig, r_prev):
-    """ZAYA1's router (arXiv:2511.17127), all in float32 at `highest`:
+    """ZAYA1's router (arXiv:2511.17127), all in float32 at `highest`
+    (the first product reads the bfloat16 stream as it stands, as
+    `moe_ffn`'s does; the three after it multiply two float32 operands):
     r = xt W_down + gamma * r_prev (the previous layer's r, nothing
     before the first layer); logits = W_3 gelu(W_2 gelu(W_1 RMSNorm(r))).
     -> (logits [N, E], r [N, router_hidden])."""
@@ -727,7 +749,16 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig,
 
     The router reads the compute-dtype stream but multiplies, takes its
     softmax and chooses in float32 (`highest`: a float32 matmul is one
-    bf16 pass on the chip otherwise).
+    bf16 pass on the chip otherwise). The cast of a bfloat16 stream
+    costs nothing there: at `highest` the compiler multiplies the rows
+    AS THEY STAND by the float32 weight's three bfloat16 terms, three
+    passes and no split of the rows, at the speed the rows are read
+    (PERF.md section 6, PR 59: 0.084 ms at [16384, 2304] x [2304, 64],
+    and the same split spelled by hand, with `reduce_precision`, 0.083;
+    spelled with `astype` the compiler drops the round trip as excess
+    precision and multiplies ONE term). What a router that weighs by a
+    score it did not choose by (score + bias chose) pays for is the
+    pick of the chosen scores, which is `_of_chosen` and no gather.
     """
     B, S, D = x.shape
     E, K = c.n_experts, c.top_k
@@ -751,7 +782,7 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig,
                 # over the chosen and scaled (arXiv:2412.19437, eq. 12-16)
                 scores = jax.nn.sigmoid(logits)
                 _, chosen = jax.lax.top_k(scores + lp["router_bias"].astype(jnp.float32), K)
-                w = jnp.take_along_axis(scores, chosen, axis=-1)
+                w = _of_chosen(scores, chosen)
                 if c.norm_topk_prob:
                     w = w / (w.sum(-1, keepdims=True) + 1e-20)
                 w = w * c.routed_scaling
@@ -766,7 +797,7 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig,
                     # (ZAYA1's MLP router; a linear one whose layer carries a selection
                     # bias: Laguna, models/laguna.py)
                     _, chosen = jax.lax.top_k(probs + lp["router_bias"].astype(jnp.float32), K)
-                    w = jnp.take_along_axis(probs, chosen, axis=-1)
+                    w = _of_chosen(probs, chosen)
                 else:
                     w, chosen = jax.lax.top_k(probs, K)     # [N, K]
                 if c.norm_topk_prob:

@@ -41,7 +41,9 @@ GLM_SHARE = dict(model="glm-4.7-flash", vocab_size=19456, experts_held=8)
 # PR 44 lowers it: replaced ON PURPOSE again, the sum of its 8,192 held rows into 8,192
 # tokens is the band where it was the [8192, 8192] one-hot product (9ff87ef7... from PR 40);
 # the account of every hash is tests/test_m7b_steps_compile.py's
-_GLM_LITE_STEP = "a3bebfc76d0379f05c0b4184981fd00c826b8233b90f4b9505e2848a85d87357"
+# Replaced ON PURPOSE by PR 59: the chosen experts' scores are picked by a compare and a sum
+# (`moe._of_chosen`) where `take_along_axis` gathered them one by one (a3bebfc7... from PR 44)
+_GLM_LITE_STEP = "7f65243ac098a39ff7a983de4c15a7a9912c29efc2b5a1c04700b0fdab105fdd"
 LAGUNA = dict(batch=1, model="laguna-s-2.1", n_layers=5, vocab_size=12544, experts_held=8)
 # the dense full-attention layer and ONE sliding expert layer: what is compiled (46 s of every
 # core alone where the cell's five layers take 105, 286 CPU s where they take 585: PR 54)
@@ -50,7 +52,9 @@ LAGUNA_2 = {**LAGUNA, "n_layers": 2}
 # models/llama.py's seam (`stack_module`, PR 46), lowered by PR 46 AND by its parent (5c794fa)
 # to the same text, and by PR 47, which adds two names to `llama._remat`'s list that no other
 # program carries, and by PR 48, which touches nothing another model imports
-_LAGUNA_STEP = "0b2bb23b3f4879e8be615653809d840670112e13163f44f4d7c7ca8e81733150"
+# Replaced ON PURPOSE by PR 59: the chosen experts' scores are picked by a compare and a sum
+# (`moe._of_chosen`) where `take_along_axis` gathered them one by one (0b2bb23b... from PR 46)
+_LAGUNA_STEP = "c6f0f50ee150e38f36c591c67336462f603fb9c59d2fc82f76b0f72f19a59192"
 MELLUM2 = dict(batch=1, model="mellum2-12b-a2.5b", n_layers=4, seq=16384, vocab_size=12288,
                experts_held=8)
 # sha256 of the lowered step of mellum2-12b-a2.5b as `mellum2-train-16k` builds it (PR 53: the
@@ -58,7 +62,9 @@ MELLUM2 = dict(batch=1, model="mellum2-12b-a2.5b", n_layers=4, seq=16384, vocab_
 # Replaced ON PURPOSE by PR 56: its 16,384 keys are ONE kv block, so each of its four layers'
 # backward is one fused kernel where the dq and the dk/dv kernels stood, and the kernels carry
 # their VMEM limits (a0a2e204... from PR 53 to PR 55)
-_MELLUM2_STEP = "535bbc499fda02b7ef3ffc17619d2629ed5f07516436cae359a007a5d3f8fbb5"
+# Replaced ON PURPOSE by PR 59: the chosen experts' scores are picked by a compare and a sum
+# (`moe._of_chosen`) where `take_along_axis` gathered them one by one (535bbc49... from PR 56)
+_MELLUM2_STEP = "73b53db5e2728a4b19b834c46ed01893374a0247a5553540189f1fe8b148ffe2"
 # `sdar-train-8k`'s step (PR 55: the rehearsal's rung (a)): 4 full layers of the same module
 # trained by block diffusion, 16 of 128 experts and Keye's eighth of the vocabulary held, ONE
 # sequence of 8,192 tokens = 16,384 rows
@@ -67,7 +73,9 @@ SDAR = dict(batch=1, model="sdar-30b-a3b", n_layers=4, seq=8192, vocab_size=1907
 # sha256 of that step's lowered text (the only step under the block-diffusion objective), as PR
 # 56's tree lowers it: recorded on the parent of PR 57 before that PR moved the dense prefix
 # out of models/mla.py and the mixer kinds of models/llama.py into one table, and held by it
-_SDAR_STEP = "523e528c994020deed402fd1a0262c26191211b59da7a4a25466be6deea8cbc1"
+# Replaced ON PURPOSE by PR 59: the chosen experts' scores are picked by a compare and a sum
+# (`moe._of_chosen`) where `take_along_axis` gathered them one by one (523e528c... from PR 56)
+_SDAR_STEP = "c4789e037a6e312ce814a82b1cd4f63c7d1544b3720ce6f2992587ae89415a22"
 SDAR_SCOPES = ("diff.corrupt", "diff.loss", "attn.qkv", "attn.norm", "attn.rope", "attn.attend",
                "flash.blockdiff", "attn.out", "moe.router", "moe.dispatch", "moe.experts",
                "moe.combine", "block.norm", "block.stack", "embed", "head", "optim")
@@ -435,3 +443,23 @@ def test_laguna_share_train_step_holds_the_scope_its_readers_sum(v5e, scope):
     of the file's other cases of this step: tests/v5e_steps.py's memo), a
     case a scope."""
     assert train_step(v5e, **LAGUNA_2).has_scope(scope), scope
+
+
+ROUTED_STEPS = {
+    "glm47f-train": dict(batch=2, n_layers=5, **GLM_SHARE), "laguna-train": LAGUNA,
+    "mellum2-train-16k": MELLUM2, "sdar-train-8k": SDAR,
+}
+
+
+@pytest.mark.parametrize("cell", sorted(ROUTED_STEPS))
+def test_a_cells_router_gathers_no_score_and_scatters_none(v5e, cell):
+    """No operation of the LOWERED step (the file's one lowering of it) is a
+    gather or a scatter traced under `moe.router`: the chosen experts'
+    scores are picked by a compare and a sum (`moe._of_chosen`, PR 59). As
+    `take_along_axis` they were a gather of single elements, 1.34 ms a
+    call of `mellum2-train-16k`'s 131,072 and 0.89 ms its transpose, 14.3
+    of the step's 268 ms, which PR 53 to PR 58 read as the router's matmul."""
+    names = train_step(v5e, **ROUTED_STEPS[cell]).lowered_op_names
+    routed = [n for n in names if "moe.router" in n]
+    assert any(n.endswith("/top_k") for n in routed), routed[:5]
+    assert not [n for n in routed if re.search(r"/(gather|scatter[\w\-]*)$", n)]

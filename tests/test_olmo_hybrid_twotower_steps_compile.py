@@ -35,7 +35,9 @@ TWOTOWER = dict(batch=1, model="nemotron-twotower-30b-a3b", n_layers=9, seq=8192
 # mixers' gated group norm as ops/gated_norm.py's two kernels where five lines of jax.numpy
 # stood (the account of every hash is tests/test_m7b_steps_compile.py's; the kernels' own
 # bodies are not in it)
-_TWOTOWER_STEP = "7413445373a7c50080b2b604d7983ff5aeacbacd23d47b29cc7968aa747fe49a"
+# Replaced ON PURPOSE by PR 59: the chosen experts' scores are picked by a compare and a sum
+# (`moe._of_chosen`) where `take_along_axis` gathered them one by one (74134453... from PR 52)
+_TWOTOWER_STEP = "4d64810dea13ffe57e409b4649a29f8183abf349520183f8abd774034150f933"
 GIB = 2 ** 30
 OLMO_HYBRID_SCOPES = (
     "gdn.proj", "gdn.conv", "gdn.gates", "gdn.scan", "gdn.norm", "gdn.out", "attn.qkv",

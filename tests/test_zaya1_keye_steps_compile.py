@@ -31,8 +31,11 @@ KEYE_2 = {**KEYE, "n_layers": 2}
 # layer and at two, as PR 56's tree lowers them: recorded on the parent of PR 57 before that PR
 # moved WHERE models/llama.py reads a configuration's mixer kind, and held by it letter for
 # letter; the account of every hash is tests/test_m7b_steps_compile.py's
-_KEYE_STEP = {1: "12fa5d1b38d079ceea5913523724c34bb744ac0e1fed3af239c1f092b492f5e5",
-              2: "f8c66c23431e2ba4d86f335fd38082030c967c1da2c2699d33dfe5c7d8ac637e"}
+# Replaced ON PURPOSE by PR 59: the chosen experts' scores are picked by a compare and a sum
+# (`moe._of_chosen`) where `take_along_axis` gathered them one by one (12fa5d1b... and
+# f8c66c23... from PR 56)
+_KEYE_STEP = {1: "133628740f26af0d14f1ee4f62afc95cc9fd1467332396e8614b5d7e983d197a",
+              2: "af7fa4c6b53ed7327509d39d9993e9998ebe48ce3f2224e8522dba895cf0049a"}
 KEYE_SCOPES = (
     "dsa.qkv", "dsa.norm", "dsa.rope", "dsa.index.proj", "dsa.index.scores", "dsa.select",
     "dsa.attend", "dsa.out", "moe.router", "moe.dispatch", "moe.experts", "moe.combine",
