@@ -398,8 +398,15 @@ def _all_rows(xt, w, w_gate, w_up, w_down, order, inv, sizes, *, tail, dtype):
 # 3.1-4.3% of its pairs against a uniform 3.125%, `glm47f-train` 11.1-14.2%
 # against 12.5%: the configurations' `lr_why`, summed over their blocks; ONE
 # block of `laguna-train` crossed C on 2 seeds of 30 inside a 10 s window:
-# PERF.md, PR 40); a routing past it is still exact, only slower.
+# PERF.md, PR 40); a routing past it is still exact, only slower. A share
+# UNDER a thirty-second takes _SLACK_SMALL: the rows that ONE repeated token
+# brings (Zipf traffic's first: about 1,190 of a sequence's 8,192, which a
+# router sends to an expert whole) are then most of a uniform share, 1,639
+# rows at `solar-open2-train-8k`'s 8 of 320, and at 2 two such blocks on the
+# held experts crossed C on 3 seeds of 38 inside a 10 s window, 5-19 steps
+# of 40 at 0.273 s for 0.251 (PERF.md, PR 60); at 4 it takes five.
 _SLACK = 2
+_SLACK_SMALL = 4
 _ROW_TILE = 512
 
 
@@ -411,7 +418,8 @@ def held_rows_bound(pairs: int, held: int, experts: int) -> Optional[int]:
     if held >= experts:
         return None
     uniform = -(-pairs * held // experts)
-    bound = min(pairs, -(-_SLACK * uniform // _ROW_TILE) * _ROW_TILE)
+    slack = _SLACK if 32 * held >= experts else _SLACK_SMALL
+    bound = min(pairs, -(-slack * uniform // _ROW_TILE) * _ROW_TILE)
     return bound if 2 * bound <= pairs else None
 
 

@@ -54,13 +54,19 @@ groups; models/nemotron_h.py, ops/ssd.py and ops/gdn_conv.py's kernels
 with a bias), 128 relu^2 experts of two matrices (sigmoid top-6, one
 shared) and GQA 32 / 2 without a rotary, ONE sublayer a layer by the
 published pattern string; its second (denoiser) tower and diffusion
-objective are not built.
+objective are not built; and for Solar-Open2-250B, `solar-open2-250b`,
+trained, not served: three Kimi-Delta-Attention layers (a delta rule
+whose decay is a vector over the key's channels, 64 heads of 128;
+models/solar_open2.py and ops/kda.py) to one gated GQA 64 / 8 layer
+without a rotary, each over 320 sigmoid-routed experts of 1280 (top-8)
+and a shared one.
 The registry gives users the same two entry points they expect:
 
   * `get_model_config("llama3-8b")` — named presets;
   * `config_from_hf(json.load(open("config.json")))` — map a HF
     transformers config dict onto LlamaConfig/MoEConfig/ZayaConfig/
-    GlmLiteConfig/LagunaConfig/KeyeConfig/OlmoHybridConfig/NemotronHConfig (no downloads;
+    GlmLiteConfig/LagunaConfig/KeyeConfig/OlmoHybridConfig/NemotronHConfig/
+    SolarOpen2Config (no downloads;
     weight conversion is a separate concern).
 """
 
@@ -80,7 +86,9 @@ _ON_DEMAND = {"keye-vl-2.0-30b-a3b": ("ray_tpu.models.dsa", "KEYE_VL_2_30B_A3B")
               "olmo-hybrid-tiny": ("ray_tpu.models.olmo_hybrid", "OLMO_HYBRID_TINY"),
               "nemotron-twotower-30b-a3b": ("ray_tpu.models.nemotron_h",
                                             "NEMOTRON_TWOTOWER_30B_A3B"),
-              "nemotron-h-tiny": ("ray_tpu.models.nemotron_h", "NEMOTRON_H_TINY")}
+              "nemotron-h-tiny": ("ray_tpu.models.nemotron_h", "NEMOTRON_H_TINY"),
+              "solar-open2-250b": ("ray_tpu.models.solar_open2", "SOLAR_OPEN2_250B"),
+              "solar-open2-tiny": ("ray_tpu.models.solar_open2", "SOLAR_OPEN2_TINY")}
 
 
 def register_model(name: str, config) -> None:
@@ -559,11 +567,66 @@ def _nemotron_h_from_hf(hf: dict, **overrides):
     return dataclasses.replace(nh.NEMOTRON_TWOTOWER_30B_A3B, **fields)
 
 
+def _solar_open2_from_hf(hf: dict, **overrides):
+    """`model_type` "solar_open2" (upstage/Solar-Open2-250B): Kimi-Delta-
+    Attention layers (`linear_attn_config`) and gated GQA layers without a
+    rotary (`gqa_layers`), every layer over a sigmoid-routed expert layer
+    with a shared expert. What this decoder does not implement is refused
+    by name; that the stack ends on a whole period is checked last."""
+    from ray_tpu.models import solar_open2 as so
+
+    n = hf["num_hidden_layers"]
+    lin = hf.get("linear_attn_config") or {}
+    refused = {
+        "use_rope (the GQA layers run without a rotary)": bool(hf.get("use_rope")),
+        "kda_use_full_proj (one full matrix for the decay in place of the low-rank pair)":
+            bool(hf.get("kda_use_full_proj")),
+        "use_gqa_gate false (a GQA layer without its output gate)":
+            not hf.get("use_gqa_gate", False),
+        "kda_allow_neg_eigval false (beta = sigmoid, not 2 x sigmoid)":
+            not hf.get("kda_allow_neg_eigval", False),
+        f"first_k_dense_replace {hf.get('first_k_dense_replace')} (a leading dense layer)":
+            bool(hf.get("first_k_dense_replace")),
+        f"linear_attn_config.num_kv_heads {lin.get('num_kv_heads')} (grouped keys under KDA)":
+            lin.get("num_kv_heads") not in (None, lin.get("num_heads")),
+        f"n_shared_experts {hf.get('n_shared_experts')}": hf.get("n_shared_experts") != 1,
+        f"n_group {hf.get('n_group')} / topk_group {hf.get('topk_group')} (groups of experts)":
+            hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1,
+        "a sliding window": hf.get("sliding_window") is not None,
+        "attention_bias": bool(hf.get("attention_bias")),
+        f"hidden_act {hf.get('hidden_act')!r}": hf.get("hidden_act", "silu") != "silu",
+        "gqa_layers beyond num_hidden_layers' reach or none at all":
+            not any(l < n for l in hf.get("gqa_layers") or ()),
+    }
+    if any(refused.values()):
+        raise ValueError("a solar_open2 config with "
+                         + ", ".join(k for k, v in refused.items() if v) + " is not supported")
+    fields = dict(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"], n_layers=n,
+        n_heads=hf["num_attention_heads"], n_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf["head_dim"], d_ff=hf["moe_intermediate_size"],
+        shared_d_ff=hf["moe_intermediate_size"] * hf["n_shared_experts"],
+        max_seq=hf["max_position_embeddings"], rms_eps=float(hf["rms_norm_eps"]),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        n_experts=hf["n_routed_experts"], top_k=hf["num_experts_per_tok"],
+        norm_topk_prob=bool(hf["norm_topk_prob"]),
+        routed_scaling=float(hf["routed_scaling_factor"]),
+        gqa_layers=tuple(hf["gqa_layers"]),
+        kda_heads=lin["num_heads"], kda_head_dim=lin["head_dim"], kda_rank=lin["head_dim"],
+        conv_kernel=lin["short_conv_kernel_size"],
+    )
+    fields.update(overrides)  # caller wins on collisions
+    config = dataclasses.replace(so.SOLAR_OPEN2_250B, **fields)
+    so.logical_axes(config)  # raises where the stack does not end on a whole period
+    return config
+
+
 # `model_type` -> the family's mapping (any other: the llama / mixtral / OLMoE one below)
 _FROM_HF = {
     "zaya": _zaya_from_hf, "glm4_moe_lite": _glm_lite_from_hf, "laguna": _laguna_from_hf,
     "mellum": _mellum_from_hf, "sdar_moe": _sdar_from_hf, "KeyeVL2": _keye_from_hf,
     "olmo_hybrid": _olmo_hybrid_from_hf, "nemotron_h": _nemotron_h_from_hf,
+    "solar_open2": _solar_open2_from_hf,
 }
 
 
@@ -592,7 +655,9 @@ def config_from_hf(hf: dict, **overrides):
     "olmo_hybrid": linear-attention layers beside full ones, no rotary):
     see `_olmo_hybrid_from_hf`. Nemotron-H (`model_type` "nemotron_h": Mamba-2
     mixers, relu^2 experts, attention without a rotary): see
-    `_nemotron_h_from_hf`. For every OTHER family a
+    `_nemotron_h_from_hf`. Solar-Open2 (`model_type` "solar_open2": KDA layers
+    beside gated GQA ones over sigmoid-routed experts): see
+    `_solar_open2_from_hf`. For every OTHER family a
     `rope_scaling` and an explicit `head_dim` that is not hidden_size /
     heads stay refused.
     """

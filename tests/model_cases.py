@@ -1,6 +1,6 @@
 """What the model files (tests/test_zaya.py, test_glm_lite.py,
 test_laguna.py (Laguna, Mellum2 and SDAR: one stack), test_keye.py,
-test_olmo_hybrid.py, test_nemotron_h.py, test_moe.py), the files of their
+test_olmo_hybrid.py, test_nemotron_h.py, test_solar_open2.py, test_moe.py), the files of their
 train paths (tests/test_contract_<model>.py) and
 tests/test_model_contract.py share. No test lives here (pytest does not
 collect the file).
@@ -36,9 +36,9 @@ import pytest
 
 from chipbench.reference import (glm_lite_decoder, keye_decoder, laguna_decoder, mellum2_decoder,
                                  nemotron_h_decoder, olmo_hybrid_decoder, sdar_decoder,
-                                 zaya_decoder)
+                                 solar_open2_decoder, zaya_decoder)
 from ray_tpu.models import (block_diffusion, cca, dsa, laguna, llama, mla, nemotron_h,
-                            olmo_hybrid)
+                            olmo_hybrid, solar_open2)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -390,6 +390,27 @@ def nemotron_h_shape(cfg) -> dict:
     }
 
 
+def solar_open2_shape(cfg) -> dict:
+    """A SolarOpen2Config as the configuration file's dict (HF key names): the
+    head counts and experts HELD under their published keys."""
+    return {
+        "hidden_size": cfg.d_model, "num_attention_heads": cfg.n_heads,
+        "num_key_value_heads": cfg.n_kv_heads, "head_dim": cfg.head_dim,
+        "num_hidden_layers": cfg.n_layers, "gqa_layers": list(cfg.gqa_layers),
+        "use_rope": False, "use_gqa_gate": True, "kda_use_full_proj": False,
+        "kda_allow_neg_eigval": True, "first_k_dense_replace": 0,
+        "linear_attn_config": {"short_conv_kernel_size": cfg.conv_kernel,
+                               "head_dim": cfg.kda_head_dim, "num_heads": cfg.kda_heads,
+                               "num_kv_heads": None},
+        "moe_intermediate_size": cfg.d_ff, "rms_norm_eps": cfg.rms_eps,
+        "n_routed_experts": cfg.n_held, "published": {"n_routed_experts": cfg.n_experts},
+        "deployment": {"first_expert_held": cfg.first_expert_held}, "n_shared_experts": 1,
+        "num_experts_per_tok": cfg.top_k, "norm_topk_prob": cfg.norm_topk_prob,
+        "routed_scaling_factor": cfg.routed_scaling, "max_position_embeddings": cfg.max_seq,
+        "tie_word_embeddings": cfg.tie_embeddings, "vocab_size": cfg.vocab_size,
+    }
+
+
 _LN = {"ln1": 0.2, "ln2": 0.2}
 _MLA_NORMS = {**_LN, "q_a_norm": 0.2, "kv_a_norm": 0.2}
 _REMAT_TOL = dict(rtol=1e-4, atol=2e-6)
@@ -569,4 +590,29 @@ NEMOTRON_H = Model(
     bf16=dict(attention_impl="flash"), bf16_rel=0.02,
     tokens=skewed_tokens, reference_set_up=contextlib.nullcontext,
 )
-MODELS = (ZAYA, GLM_LITE, LAGUNA, MELLUM2, SDAR, KEYE, OLMO_HYBRID, NEMOTRON_H)
+
+
+def _solar_open2_norms(params) -> list:
+    period = params["layers"]["period"]
+    kda = {**_LN, "o_norm": 0.2, "A_log": 0.3, "dt_bias": 0.3, "g_bias": 0.3}
+    return [(period["0"], _LN), *((period[j], kda) for j in "123"), (params, {"final_norm": 0.2})]
+
+
+SOLAR_OPEN2 = Model(
+    name="solar_open2",
+    fp32=dataclasses.replace(solar_open2.SOLAR_OPEN2_TINY, dtype=jnp.float32),
+    batch=2, seq=150,   # two chunks of 64 and 22 positions more: no multiple of the chunk
+    reference=solar_open2_decoder, shape_of=solar_open2_shape, n_keys=32, bias=0.05,
+    norms=_solar_open2_norms, preset="solar-open2-250b", tiny="solar-open2-tiny",
+    refused_as="Solar-Open2", catalog="Solar-Open2-250B",
+    config_file="solar-open2-250b-train.json",
+    facts={"head_dim": 128, "kda_heads": 64, "kda_head_dim": 128, "kda_rank": 128,
+           "conv_kernel": 4, "n_heads": 64,
+           "n_kv_heads": 8, "n_experts": 320, "top_k": 8, "router_score": "sigmoid",
+           "routed_scaling": 1.0, "shared_d_ff": 1280, "d_ff": 1280, "rope_theta": 0.0,
+           "gqa_layers": tuple(range(0, 48, 4))},
+    remat_plain={}, remat_bias=0.05, remat_tol=_REMAT_TOL,
+    bf16=dict(attention_impl="flash"), bf16_rel=0.02,
+    tokens=skewed_tokens, reference_set_up=contextlib.nullcontext,
+)
+MODELS = (ZAYA, GLM_LITE, LAGUNA, MELLUM2, SDAR, KEYE, OLMO_HYBRID, NEMOTRON_H, SOLAR_OPEN2)

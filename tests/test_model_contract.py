@@ -19,7 +19,7 @@ import operator
 import pytest
 
 from model_cases import (GLM_LITE, KEYE, LAGUNA, MELLUM2, MODELS, NEMOTRON_H, OLMO_HYBRID, SDAR,
-                         Model, catalog_config)
+                         SOLAR_OPEN2, Model, catalog_config)
 from ray_tpu.models.registry import config_from_hf, get_model_config
 
 by_name = pytest.mark.parametrize("model", MODELS, ids=lambda m: m.name)
@@ -99,6 +99,19 @@ def test_config_from_hf_maps_the_catalogs_config_onto_the_preset(model):
     (NEMOTRON_H, "sliding_window", 4096, "a sliding window"),
     (NEMOTRON_H, "mlp_hidden_act", "silu", "mlp_hidden_act 'silu'"),
     (NEMOTRON_H, "time_step_limit", [0.0, 0.5], "a clamp on the step"),
+    (SOLAR_OPEN2, "use_rope", True, "use_rope"),
+    (SOLAR_OPEN2, "kda_use_full_proj", True, "kda_use_full_proj"),
+    (SOLAR_OPEN2, "use_gqa_gate", False, "use_gqa_gate false"),
+    (SOLAR_OPEN2, "kda_allow_neg_eigval", False, "kda_allow_neg_eigval false"),
+    (SOLAR_OPEN2, "first_k_dense_replace", 1, "first_k_dense_replace 1"),
+    (SOLAR_OPEN2, "linear_attn_config", {"short_conv_kernel_size": 4, "head_dim": 128,
+                                         "num_heads": 64, "num_kv_heads": 8},
+     "linear_attn_config.num_kv_heads 8"),
+    (SOLAR_OPEN2, "n_shared_experts", 2, "n_shared_experts 2"),
+    (SOLAR_OPEN2, "n_group", 8, "n_group 8"),
+    (SOLAR_OPEN2, "num_hidden_layers", 6, "does not end on a whole period"),
+    (SOLAR_OPEN2, "attention_bias", True, "attention_bias"),
+    (SOLAR_OPEN2, "sliding_window", 4096, "a sliding window"),
 ], ids=lambda v: v.name if isinstance(v, Model) else None)
 def test_config_from_hf_refuses_by_name_what_is_not_implemented(model, key, value, names):
     with pytest.raises(ValueError, match=names):
@@ -116,7 +129,8 @@ def test_engine_refuses_the_model_by_name(model):
 # -- what a checkpoint and the benchmark's builders depend on ---------------------------
 
 TINY_PRESETS = ("llama-tiny", "moe-tiny", "zaya-tiny", "glm-lite-tiny", "laguna-tiny",
-                "mellum2-tiny", "sdar-tiny", "keye-tiny", "olmo-hybrid-tiny", "nemotron-h-tiny")
+                "mellum2-tiny", "sdar-tiny", "keye-tiny", "olmo-hybrid-tiny", "nemotron-h-tiny",
+                "solar-open2-tiny")
 # preset -> sha256 of (every leaf's path, shape and dtype), of the leaves' bytes in the paths'
 # order, and of (every leaf's path and logical axes), as PR 56's tree (the parent of PR 57,
 # which moved WHERE models/llama.py reads a configuration's mixer kind) makes them from
@@ -164,6 +178,11 @@ _TINY_TREES = {
         "67e89bb9c55041d4558b8ea1be5da6602f28da2643a74de9296380f7bf998aa5",
         "8f5a4d6708400392896714483bd6f1efe07b9539983a511dc806c2f6cdf0e9e0",
         "52a3880e1e46087ffda639c86f1502f768136575a2e141cd49c78becc9d52f90"),
+    # as PR 60 made it (models/solar_open2.py)
+    "solar-open2-tiny": (
+        "68c72f7f46fb31463d62f89cb6787a5de75e2a1aad916f2fa82e468228a1deea",
+        "013de14d5186164a99cdb501970d4cd72dea75228b657d71f8b31cf524e70b47",
+        "69b588ab7eb833ded4f755ccebe8d5077159400fb02bd720fefc16fa2e44a20d"),
 }
 
 

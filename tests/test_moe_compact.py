@@ -109,6 +109,9 @@ def test_the_bound_follows_from_the_shapes():
     # the benchmark's share cells: 2,560 of 40,960 and 8,192 of 32,768 rows
     assert moe.held_rows_bound(4096 * 10, 8, 256) == 2560
     assert moe.held_rows_bound(8192 * 4, 8, 64) == 8192
+    # a share under a thirty-second (`solar-open2-train-8k`'s fortieth) takes 4 x the uniform
+    # 1,639 rows, where one repeated token's 1,190 rows are most of a share
+    assert moe.held_rows_bound(8192 * 8, 8, 320) == 6656
     # a half share would not halve the rows, every expert held has no rows elsewhere
     assert moe.held_rows_bound(8192, 8, 16) is None
     assert moe.held_rows_bound(6 * 4096 * 8, 64, 64) is None
