@@ -57,7 +57,8 @@ published pattern string; its second (denoiser) tower and diffusion
 objective are not built; and for Solar-Open2-250B, `solar-open2-250b`,
 trained, not served: three Kimi-Delta-Attention layers (a delta rule
 whose decay is a vector over the key's channels, 64 heads of 128;
-models/solar_open2.py and ops/kda.py) to one gated GQA 64 / 8 layer
+models/solar_open2.py over ops/kda.py's two Pallas kernels a layer) to
+one gated GQA 64 / 8 layer
 without a rotary, each over 320 sigmoid-routed experts of 1280 (top-8)
 and a shared one.
 The registry gives users the same two entry points they expect:

@@ -4,8 +4,10 @@ one stack, every layer over a sparse expert layer.
 What Solar-Open2-250B (upstage/Solar-Open2-250B, `model_type`
 solar_open2) adds to the one decoder of models/llama.py:
 `SolarOpen2Config`; the linear-attention sublayer `kda_sublayer` (the
-recurrence itself is ops/kda.py's chunked rule; what stands between the
-projections and it, ops/gdn_conv.py's kernels, Olmo-Hybrid's); the
+recurrence itself is ops/kda.py's chunked rule, two Pallas kernels a
+layer that read q, k, v and g where the convolution and the gates wrote
+them; what stands between the projections and it, ops/gdn_conv.py's
+kernels, Olmo-Hybrid's); the
 softmax sublayer `gqa_sublayer` without a rotary and with an elementwise
 output gate; and a parameter tree and a layer stack whose blocks differ
 in KIND over models/moe.py's expert layer. The head, the loss and the
@@ -130,10 +132,12 @@ Params = dict[str, Any]
 GQA, KDA = "gqa", "kda"
 _F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
-# beside llama._remat's own names: ops/kda.py names nothing, so NOTHING of a chunk's arrays is
-# kept for the backward and the rule runs again under a block's `jax.checkpoint` (the
-# rehearsal's step (a) fits as it is; chipbench/configs/solar-open2-250b-train.json, `reduced`)
-REMAT_SAVES = ()
+# saved by llama._remat's "dots" policy beside its own names: what ops/kda.py's forward kernel
+# writes (o; the chunks' starting states and the pairs' inverses: 32 + 64 + 16 MiB a layer at
+# [1, 8, 8192, 128]), so that under a block's `jax.checkpoint` the rule runs twice a layer,
+# forward and backward, and no forward again (tests/test_solar_open2_step_compile.py holds the
+# step's memory with them kept under the chip's)
+REMAT_SAVES = ("kda_out", "kda_states")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -347,7 +351,8 @@ def kda_sublayer(u: jax.Array, lp: Params, c: SolarOpen2Config, *,
     Named scopes on the device ops, forward and backward: `kda.proj` (q,
     k, v, beta's logits and both low-rank pairs), `kda.conv` (six kernels
     a layer, `gdn_conv_fwd` / `gdn_conv_bwd` for each of q, k and v),
-    `kda.gates` (beta and the log decay), `kda.scan` (ops/kda.py),
+    `kda.gates` (beta and the log decay), `kda.scan` (ops/kda.py's
+    kernels, `kda_fwd` / `kda_bwd`),
     `kda.norm` (the sigmoid-gated norm), `kda.out`."""
     if segment_ids is not None:
         raise NotImplementedError(

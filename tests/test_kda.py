@@ -1,11 +1,15 @@
 """ops/kda.py on the CPU: the chunked rule of Kimi Delta Attention (a delta
-rule whose decay is a vector over the key's channels) against the
-position-by-position rule, forward and every gradient (`jax.grad` of the
-plain recurrence) at 1e-5 relative in float32, at sequences of several
-chunks and at ones that are no multiple of the chunk; under decays so
-strong that the naive split (k . e^c)(k . e^-c)^T overflows within ONE
-chunk; what it reduces to when the decay is the same on every channel
-(ops/gated_delta.py's rule); and that nothing in it grows with T x T."""
+rule whose decay is a vector over the key's channels), its two Pallas
+kernels under the interpreter, against the position-by-position rule,
+forward and every gradient (`jax.grad` of the plain recurrence) at 1e-5
+relative in float32, at sequences of several chunks, of several grid steps
+and at ones that are no multiple of a block; under decays so strong that
+the naive split (k . e^c)(k . e^-c)^T overflows within ONE chunk; what it
+reduces to when the decay is the same on every channel
+(ops/gated_delta.py's rule); what the forward hands the backward and that
+the remat policy's names save it; and that it is kernels all the way:
+one `pallas_call` forward, two for a gradient, no loop over chunks or
+positions outside them and nothing that grows with T x T."""
 
 import functools
 
@@ -17,6 +21,7 @@ from jax.extend.core import Literal
 
 from ray_tpu.ops import gated_delta as gd
 from ray_tpu.ops import kda
+from test_gated_delta import kernels_and_loops   # (`pallas_call`s, loops outside them)
 
 B, H, DK, DV = 2, 3, 12, 24   # neither head size fills a tile
 HI = jax.lax.Precision.HIGHEST
@@ -78,8 +83,9 @@ def assert_close(got, want, name="", rel=1e-5):
 RULE = jax.jit(kda.kda_rule)
 CHECKPOINTED = jax.checkpoint(kda.kda_rule)   # one function: one program a shape
 
-# 192 = three whole chunks of 64; 150 = two and 22 positions; 40 = less than one
-SHAPES = pytest.mark.parametrize("T", [192, 150, 40])
+# 192 = three whole chunks of 64; 150 = two and 22 positions; 40 = less than one; 330 = five
+# and 10 positions, three grid steps of the kernels: the state is carried from step to step
+SHAPES = pytest.mark.parametrize("T", [192, 150, 40, 330])
 
 
 @SHAPES
@@ -92,9 +98,9 @@ def test_chunked_forward_is_the_position_by_position_rule(T):
 
 @SHAPES
 def test_chunked_backward_is_jax_grad_of_the_plain_recurrence(T):
-    """q, k, v, g and beta each: the transpose of the scan over chunks
-    against reverse-mode through the scan over positions, with and
-    without the block's `jax.checkpoint` around the rule."""
+    """q, k, v, g and beta each: the backward kernel against reverse-mode
+    through the scan over positions, with and without the block's
+    `jax.checkpoint` around the rule (which runs the forward kernel again)."""
     args, w = inputs(T)
     want = grads_of(recurrent_kda_rule, args, w)
     for rule in (kda.kda_rule, CHECKPOINTED):
@@ -152,24 +158,50 @@ def _largest_exp_operands(jaxpr, consts, args, found):
             inner = eqn.params["jaxpr"]
             out = _largest_exp_operands(inner.jaxpr, inner.consts, vals, found)
         else:
-            assert " exp " not in str(eqn.params.get("jaxpr", "")), eqn.primitive  # the scan
+            assert " exp " not in str(eqn.params.get("jaxpr", "")), eqn.primitive
             out = eqn.primitive.bind(*vals, **eqn.params)
             out = out if eqn.primitive.multiple_results else [out]
         env.update(zip(eqn.outvars, out))
     return [v.val if isinstance(v, Literal) else env[v] for v in jaxpr.outvars]
 
 
+def _exps_in(jaxpr):
+    """How many `exp` equations a jaxpr holds, every sub-jaxpr walked (a
+    `pallas_call`'s kernel and its `pl.when` branches among them)."""
+    return sum((eqn.primitive.name == "exp")
+               + sum(_exps_in(sub) for sub in jax.core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)
+
+
 def test_every_exponent_formed_is_of_a_number_that_is_not_positive():
-    """Read off the jaxpr: each `exp` of the chunked rule is evaluated on
-    the strong decays, and its operand's largest value is <= 0 (the
-    scan's body forms none: all of them stand before it)."""
-    args, _ = inputs(192, seed=1)
+    """Every `exp` of the kernels is `kda._decays`'s, which the kernels' own
+    jaxprs show (walked into the `pallas_call`s: forward and backward hold
+    exactly as many `exp` as that function forms for each of a grid step's
+    pairs, and the rule holds none outside them), and that function takes
+    values, not refs: read off ITS jaxpr, each `exp` is evaluated on the
+    strong decays' every pair of chunks, and its operand's largest value
+    is <= 0. Not by the clamp alone: the sums of g themselves are."""
+    args, w = inputs(192, seed=1)
     args = strong(args)
-    closed = jax.make_jaxpr(kda.kda_rule)(*args)
-    found = []
-    out, = _largest_exp_operands(closed.jaxpr, closed.consts, list(args), found)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(kda.kda_rule(*args)))
-    assert len(found) >= 6 and max(found) <= 0.0, found
+    g = jnp.pad(args[3], ((0, 0), (0, 0), (0, 64), (0, 128 - DK)))       # two pairs, lane-wide
+    pairs = g.reshape(-1, 128, 128)
+    closed = jax.make_jaxpr(kda._decays)(pairs[0], kda._SUMS)
+    formed = _exps_in(closed.jaxpr)
+    assert formed == 2 + len(kda._HALVES)
+    for pair in pairs:
+        found = []
+        out = _largest_exp_operands(closed.jaxpr, closed.consts, [pair, jnp.asarray(kda._SUMS)],
+                                    found)
+        assert len(found) == formed and max(found) <= 0.0, found
+        assert all(float(x.min()) >= 0.0 and float(x.max()) <= 1.0 for x in out)
+        sums = np.asarray(kda._SUMS, np.float64) @ np.asarray(pair, np.float64)
+        assert sums.max() <= 0.0 and sums.min() < -88.0    # unclamped; e^88 is float32's largest
+    block = min(kda._BLOCK, 2)    # 192 positions are two pairs: one grid step or two
+    forward = jax.make_jaxpr(kda.kda_rule)(*args).jaxpr
+    assert _exps_in(forward) == block * formed
+    gradient = jax.make_jaxpr(jax.grad(lambda *a: (kda.kda_rule(*a) * w).sum(),
+                                       argnums=(0, 1, 2, 3, 4)))(*args).jaxpr
+    assert _exps_in(gradient) == 2 * block * formed
 
 
 def test_with_one_decay_a_head_it_is_the_gated_delta_rule():
@@ -197,11 +229,97 @@ def test_the_mean_decay_is_another_function():
     assert np.abs(mean - want).max() > 100 * 1e-5 * np.abs(want).max()
 
 
+def test_the_remat_policys_names_save_what_the_backward_reads():
+    """models/solar_open2.py lists `kda_out` and `kda_states` in
+    `REMAT_SAVES`, which llama._remat saves by name: with them kept the
+    rematerialised backward runs no second forward kernel (one `pallas_call`
+    in the backward's jaxpr, the backward kernel), without them two; the
+    gradients are the same either way."""
+    from ray_tpu.models import solar_open2
+
+    args, w = inputs(150)
+    saved = jax.checkpoint(kda.kda_rule, policy=jax.checkpoint_policies.save_only_these_names(
+        *solar_open2.REMAT_SAVES))
+    for a, b in zip(grads_of(saved, args, w), grads_of(CHECKPOINTED, args, w)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def backward_kernels(rule):
+        _, pull = jax.vjp(rule, *args)
+        return kernels_and_loops(jax.make_jaxpr(pull)(w).jaxpr)[0]
+
+    assert backward_kernels(saved) == 1 and backward_kernels(CHECKPOINTED) == 2
+
+
+def test_what_the_forward_hands_the_backward_is_the_states_and_the_inverses_without_their_zeros():
+    """The forward kernel reads q, k, v and g where they stand ([B, H, T, d]:
+    no reshape of them stands before it) and writes, beside o, the state each
+    chunk started from, TRANSPOSED [dv, dk] (the position-by-position rule's
+    state after the positions before the chunk), and each pair's inverse as
+    its two diagonal blocks side by side, [B x H, T / 128, 64, 128]: chunk
+    c's (I + A)^-1 is half c mod 2 of pair c div 2, and the zeros off a
+    pair's diagonal are not kept."""
+    T = 512   # whole grid steps of the kernels: the wrapper's padding is not this test's
+    (q, k, v, g, beta), _ = inputs(T)
+    o, states, solves = kda.kda_fwd(q, k, v, g, beta.reshape(B * H, T // 128, 1, 128),
+                                    interpret=True)
+    assert o.shape == v.shape and states.shape == (B * H, T // 64, DV, DK)
+    assert solves.shape == (B * H, T // 128, 64, 128)
+    np.testing.assert_array_equal(np.asarray(states[:, 0]), 0.0)
+    f64 = lambda a: np.asarray(a, np.float64)  # noqa: E731
+    for b, h, chunk in ((0, 0, 0), (1, 2, 3), (0, 1, 5)):
+        at = slice(chunk * 64, (chunk + 1) * 64)
+        Kc, bc, c = f64(k)[b, h, at], f64(beta)[b, h, at], np.cumsum(f64(g)[b, h, at], 0)
+        M = np.einsum("id,jd,ijd->ij", Kc, Kc, np.exp(np.minimum(c[:, None] - c[None], 0.0)))
+        half = solves[b * H + h, chunk // 2, :, (chunk % 2) * 64:(chunk % 2 + 1) * 64]
+        np.testing.assert_allclose(np.asarray(half), np.linalg.inv(
+            np.eye(64) + np.tril(bc[:, None] * M, -1)), atol=2e-5)
+        S = np.zeros((DK, DV))
+        for t in range(chunk * 64):
+            S = np.exp(f64(g)[b, h, t])[:, None] * S
+            k_t, v_t, b_t = f64(k)[b, h, t], f64(v)[b, h, t], f64(beta)[b, h, t]
+            S = S + np.outer(k_t, b_t * (v_t - k_t @ S))
+        np.testing.assert_allclose(np.asarray(states[b * H + h, chunk]), S.T, atol=2e-5)
+    jaxpr = jax.make_jaxpr(kda.kda_rule)(q, k, v, g, beta).jaxpr
+    call = jaxpr.eqns[-1]
+    assert call.primitive.name == "custom_vjp_call" and call.outvars == jaxpr.outvars
+    assert call.invars[:4] == jaxpr.invars[:4]                  # the caller's arrays themselves
+
+
+def test_no_write_is_pure_decay_a_channel():
+    """beta = 0: the state only decays, and from zero it stays zero; with
+    one write at position 0 and none after, o_t = sum over the channels of
+    q_td exp(g_1d + .. + g_td) k_0d x what position 0 stored: each channel
+    of the key forgets at its own rate."""
+    (q, k, v, g, _), _ = inputs(150)
+    assert float(jnp.abs(RULE(q, k, v, g, jnp.zeros((B, H, 150)))).max()) == 0.0
+    beta = jnp.zeros((B, H, 150)).at[:, :, 0].set(1.0)
+    got = RULE(q, k, v, g, beta)
+    decay = jnp.exp(jnp.cumsum(g.at[:, :, 0].set(0.0), axis=2))                 # [B, H, T, dk]
+    stored = jnp.einsum("bhtk,bhtk,bhk->bht", q, decay, k[:, :, 0])[..., None] * v[:, :, :1]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(stored), rtol=1e-4, atol=1e-6)
+
+
+def test_a_position_reads_nothing_after_it():
+    args, _ = inputs(150)
+    base = RULE(*args)
+    moved = [a.at[:, :, 100:].add(-0.5 if name == "g" else 1.0) for name, a in zip(NAMES, args)]
+    again = RULE(*moved)
+    assert float(jnp.abs(again[:, :, :100] - base[:, :, :100]).max()) == 0.0
+    assert float(jnp.abs(again[:, :, 100:] - base[:, :, 100:]).max()) > 1e-3
+
+
 def test_nothing_grows_with_the_square_of_the_sequence():
-    """No array of the lowered rule has T x T elements: the largest is the
-    columns' four references, [T / 64, 4, 64, dk] a head."""
+    """At 1,024 positions a forward is ONE `pallas_call` and a gradient two
+    (the forward that hands out the chunks' states, the backward), no `scan`
+    or `while` stands outside them (the walk over the chunks is the kernels'
+    grid), and the largest array of forward and backward is the states the
+    chunks start from, [T / 64, dv, dk] a head, or the constant matrix of
+    the sums: nothing T x T."""
     T = 1024
-    args, _ = inputs(T, shape=(1, 1, DK, DV))
-    sizes = [np.prod(v.aval.shape) for eqn in jax.make_jaxpr(kda.kda_rule)(*args).jaxpr.eqns
-             for v in eqn.outvars if hasattr(v.aval, "shape")]
-    assert max(sizes) <= T * 4 * max(DK, DV, 64) < T * T
+    args, w = inputs(T, shape=(1, 1, DK, DV))
+    assert kernels_and_loops(jax.make_jaxpr(kda.kda_rule)(*args).jaxpr) == (1, [])
+    sizes = []
+    jaxpr = jax.make_jaxpr(jax.grad(lambda *a: (kda.kda_rule(*a) * w).sum(),
+                                    argnums=(0, 1, 2, 3, 4)))(*args)
+    assert kernels_and_loops(jaxpr.jaxpr, sizes) == (2, [])
+    assert max(sizes) <= max(T * max(DK, DV, 64), kda._SUMS.size) < T * T

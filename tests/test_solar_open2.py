@@ -12,6 +12,7 @@ every model holds alike and compiles nothing: tests/test_model_contract.py,
 a row of model_cases.MODELS.)"""
 
 import dataclasses
+import re
 import subprocess
 import sys
 
@@ -259,11 +260,15 @@ def test_the_train_step_learns_a_batch_by_the_registrys_name():
     assert losses[-1] < 0.85 * losses[0] and metrics["stats"]["tokens_per_expert"].shape == (4, 40)
 
 
-def test_the_dots_policy_keeps_the_flash_output_and_names_nothing_of_the_rule():
-    """`llama.remat_saves` gains no name by this stack (ops/kda.py names
-    nothing: the rule runs again under a block's `jax.checkpoint`), and the
-    rule's operations stand under `kda.scan`."""
-    assert so.REMAT_SAVES == () and llama.remat_saves(FP32) == llama.remat_saves(llama.LLAMA_TINY)
+def test_the_dots_policy_keeps_the_flash_output_and_what_the_rules_forward_kernel_writes():
+    """`llama.remat_saves` gains the two names of ops/kda.py's forward kernel
+    by this stack (`kda_out`; `kda_states`: the chunks' starting states and the
+    pairs' inverses), so under a block's `jax.checkpoint` the lowered gradient
+    calls `kda_fwd` once a KDA layer and not again in the backward, `kda_bwd`
+    once; the rule's operations stand under `kda.scan`, and no triangular
+    solve or loop over the chunks is left of the jax.numpy form."""
+    assert so.REMAT_SAVES == ("kda_out", "kda_states")
+    assert llama.remat_saves(FP32) == llama.remat_saves(llama.LLAMA_TINY) | set(so.REMAT_SAVES)
     cfg = dataclasses.replace(FP32, remat=True, n_layers=4)
     params = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.key(0)))
     batch = jax.eval_shape(lambda: SOLAR_OPEN2.batch_of(cfg))
@@ -272,7 +277,11 @@ def test_the_dots_policy_keeps_the_flash_output_and_names_nothing_of_the_rule():
     for scope in ("kda.proj", "kda.conv", "kda.gates", "kda.scan", "kda.norm", "kda.out",
                   "attn.qkv", "attn.attend", "attn.gate", "attn.out", "moe.router", "shared.ffn"):
         assert f"/{scope}/" in text, scope
-    assert "triangular_solve" in text or "triangular-solve" in text
+    assert "triangular_solve" not in text and "triangular-solve" not in text
+    # with arguments: the constant 0 / 1 matrix of the kernels' sums is hoisted into a function
+    # of no argument that takes the jitted function's name
+    calls = re.findall(r"call @(kda_fwd|kda_bwd)\w*\(%", text)
+    assert sorted(calls) == ["kda_bwd"] * 3 + ["kda_fwd"] * 3, calls
 
 
 def test_what_is_not_implemented_is_refused_by_name():

@@ -334,6 +334,31 @@ def test_gdn_conv_kernels_compile_for_v5e(v5e, d, scale, T):
     assert hlo.count('custom_call_target="tpu_custom_call"') == 2
 
 
+@pytest.mark.parametrize("shape,T", [((1, 8, 128, 128), 8192), ((2, 8, 16, 16), 300)],
+                         ids=["the_cells_heads_whole_blocks", "the_tiny_presets_heads_padded"])
+def test_kda_kernels_compile_for_v5e(v5e, shape, T):
+    """ops/kda.py's two kernels at `solar-open2-train-8k`'s KDA layer (8
+    heads of 128 x 128 over 8,192 positions, float32: one lane tile, nothing
+    staged) and at heads of 16 over a sequence that is no block (staged into
+    lane-wide scratch, padded): the products that contract over a pair's
+    positions (the transposed constant of the sums times 1,024 rows among
+    them), the transposes of the pairs' inverses and the state kept
+    transposed are Mosaic's to accept, not the interpreter's. The step that
+    holds them compiled whole is tests/test_solar_open2_step_compile.py's."""
+    from ray_tpu.ops.kda import kda_rule
+
+    def value_and_grads(q, k, v, g, beta, ct):
+        out, pull = jax.vjp(kda_rule, q, k, v, g, beta)
+        return (out,) + pull(ct)
+
+    f32, (B, H, dk, dv) = jnp.float32, shape
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        hlo = compile_kernel(value_and_grads, ((B, H, T, dk), f32), ((B, H, T, dk), f32),
+                             ((B, H, T, dv), f32), ((B, H, T, dk), f32), ((B, H, T), f32),
+                             ((B, H, T, dv), f32), sharding=one_chip(v5e))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+
+
 @pytest.mark.parametrize("T", [8192, 300], ids=["whole_chunks", "three_chunks_padded"])
 def test_ssd_scan_kernels_compile_for_v5e(v5e, T):
     """ops/ssd.py's two kernels at `twotower-train-8k`'s Mamba layer (64
