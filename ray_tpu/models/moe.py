@@ -61,12 +61,18 @@ models/cca.py, asks both):
     either way), and the statistic `compact` says which ran. The sum of
     the C rows back into the N tokens (`_sum_of_held_rows`: the
     combine's forward, the dispatch's backward) is a product with the
-    [N, C] 0/1 matrix where that is small (`laguna-train`, [4096,
-    2560]) and, where it is not, the same product over the band of that
-    matrix where the ones lie, the rows brought into token order first:
-    work linear in the tokens (`glm47f-train`, `keye-train-8k`:
-    [8192, 8192] and [8192, 16384]). ONE form is built a site, chosen
-    from the shapes by the two costs (`_sum_is_linear`); the counters
+    [N, C] 0/1 matrix where that is small (no cell since PR 63: tiny
+    shapes) and, where it is not, the same product over the BAND of that
+    matrix where the ones lie, the rows brought into token order first: a
+    block of 256 tokens owns one run of rows, and a window of W = 256 C / N
+    rows (the slack times the rows a block owns on average: 256 or 512 in
+    the seven cells, where 256 x top_k = 1,024-2,560 stood until PR 63)
+    from the run's start is the block's sum; a block whose run is longer
+    takes as many windows as it needs, counted on the device, so any
+    routing is exact and a skewed one only slower (the statistic
+    `band_trips`: the most windows a block took, 1 when W was enough).
+    Work linear in the tokens. ONE form is built a site, chosen from the
+    shapes by the two costs (`_sum_is_linear`); the counters
     `moe.sum.product` / `moe.sum.linear` say which.
 
 And two that GLM-4.7-Flash (models/mla.py) asks, in the DeepSeek-V3 form
@@ -435,77 +441,122 @@ def _fits(sizes, bound):
 
 
 def _sum_where_equal(tokens, of, rows):
-    """rows [R, D] -> [T, D]: row r summed into the token `of[r]` names, by a
-    product with the [T, R] 0/1 matrix of (token, row) on the MXU: the
-    products are exact, the sum is float32, rounded once to the rows' type."""
+    """rows [R, D] -> float32 [T, D]: row r summed into the token `of[r]` names, by a
+    product with the [T, R] 0/1 matrix of (token, row) on the MXU: the products are
+    exact and the sum is float32; the caller rounds it to the rows' type, once."""
     hot = (tokens[:, None] == of[None, :]).astype(rows.dtype)
     exact = jax.lax.Precision.HIGHEST if rows.dtype == jnp.float32 else None
-    return jnp.dot(hot, rows, precision=exact,
-                   preferred_element_type=jnp.float32).astype(rows.dtype)
+    return jnp.dot(hot, rows, precision=exact, preferred_element_type=jnp.float32)
 
 
 def _sum_by_product(y, tok, pairs):
     """That product over all N tokens and C rows: 2 N C D operations for a sum
     of C rows, at the MXU's rate (93-97% of it), and quadratic in the tokens."""
-    return _sum_where_equal(jnp.arange(pairs[0], dtype=tok.dtype), tok, y)
+    return _sum_where_equal(jnp.arange(pairs[0], dtype=tok.dtype), tok, y).astype(y.dtype)
 
 
 # tokens a window's product sums into: of 128 / 256 / 512 the best alone at
 # `keye-train-8k`'s shape (1.000 / 0.851 / 1.167 ms) and within 3% of it at
-# `glm47f-train`'s (0.388 / 0.401 / 0.546)
+# `glm47f-train`'s (0.388 / 0.401 / 0.546), with ONE window of 256 x top_k rows a
+# block (PERF.md, PR 44; not taken again under PR 63's windows)
 _BAND_TOKENS = 256
+# a window is a multiple of the MXU's 128 rows. Of 128 / 256 / 384 / 512 alone, ms a call
+# on a uniform router's rows (PR 63, step (0)): 1.828 / 1.754 / 1.676 / 1.664 at
+# `mellum2-train-16k`'s shape (a block owns 256 rows on average), 0.563 / 0.536 / 0.507 /
+# 0.505 at `keye-train-8k`'s (256), 0.261 / 0.247 / 0.276 / 0.302 at `glm47f-train`'s (128),
+# 0.402 / 0.452 / 0.514 / 0.558 at `solar-open2-train-8k`'s (51): the slack times the
+# average run, rounded up, is the best or within 0.05 ms of it at every shape
+_BAND_ROWS = 128
 
 
 def _band(n_tokens: int, top_k: int, rows: int) -> tuple[int, int]:
-    """(tokens a block, rows a window): a block's rows in token order are one
-    run of at most block x top_k, and no window is longer than C."""
+    """(tokens a block, rows a window W). With the rows in token order a block's
+    rows are one run; the C rows bound a STEP's held rows by the slack
+    (`held_rows_bound`), so block x C / N bounds an average BLOCK's by the same
+    slack: that is the window, rounded up to the MXU's rows, and never more than
+    block x top_k (every pair of the block held) or C."""
     block = min(_BAND_TOKENS, n_tokens)
-    return block, min(rows, block * top_k)
+    mean = -(-block * rows // n_tokens)
+    return block, min(block * top_k, rows, -(-mean // _BAND_ROWS) * _BAND_ROWS)
+
+
+def _runs(tok, n_tokens: int, block: int):
+    """-> (first row, rows) of each block of `block` tokens, with the rows in token
+    order: a block's run starts after the rows of every token before it. Rows that
+    name no token (`_held_rows`: N) are in no run."""
+    edges = jnp.minimum(jnp.arange(0, n_tokens + block, block, dtype=tok.dtype), n_tokens)
+    ends = jnp.sum(tok[None, :] < edges[:, None], axis=1, dtype=tok.dtype)
+    return ends[:-1], ends[1:] - ends[:-1]
+
+
+def _band_trips(tok, pairs, rows: int):
+    """The most windows any block's run takes (1: every run fits its window)."""
+    block, window = _band(*pairs, rows)
+    return jnp.maximum(-(-_runs(tok, pairs[0], block)[1].max() // window), 1)
 
 
 def _sum_by_band(y, tok, pairs):
     """The same product over the BAND where its ones lie. With the rows in
     token order (a sort of C keys and one gather of C rows) a block of
-    tokens owns one contiguous run of rows, so a window of `_band`'s length,
-    wherever the run starts, summed into the block's tokens is the block's
-    sum: 2 N (256 top_k) D operations and N top_k rows read, both linear in
-    the tokens. A window reaches into its neighbours' runs and past the held
-    pairs, whose rows name no token of the block (or are zero) and add
-    nothing."""
+    tokens owns one contiguous run of rows, so windows of `_band`'s W rows
+    from where the run starts, as many as the run is long (ONE, unless the
+    routing gave the block more than the slack times its share: the trip count
+    is read on the device), summed into the block's tokens in float32 and
+    rounded once are the block's sum: about 2 N W D operations and N W / 256
+    rows read, both linear in the tokens. The last window reaches into the next
+    blocks' runs, whose rows name no token of this block and add nothing, and
+    past row C into W rows gathered for that and named for no token: a window is
+    never pushed back over rows an earlier one summed."""
     (n_tokens, top_k), (rows, width) = pairs, y.shape
     block, window = _band(n_tokens, top_k, rows)
     toks, perm = jax.lax.sort((tok, jnp.arange(rows, dtype=tok.dtype)), num_keys=1)
-    ys = y[perm]
+    ys = y[jnp.pad(perm, (0, window))]
+    toks = jnp.pad(toks, (0, window), constant_values=n_tokens)
+    starts, runs = _runs(tok, n_tokens, block)
     firsts = jnp.arange(0, n_tokens, block, dtype=tok.dtype)   # a block's first token
-    # its run starts after the rows of every token before it; a window ends inside y
-    starts = jnp.sum(tok[None, :] < firsts[:, None], axis=1, dtype=tok.dtype)
-    starts = jnp.minimum(starts, rows - window)
 
     def sum_of_block(at):
-        start, first = at
-        return _sum_where_equal(first + jnp.arange(block, dtype=tok.dtype),
-                                jax.lax.dynamic_slice(toks, (start,), (window,)),
-                                jax.lax.dynamic_slice(ys, (start, 0), (window, width)))
+        start, first, trips = at
+        tokens = first + jnp.arange(block, dtype=tok.dtype)
+
+        def add_window(j, total):
+            row = start + j * window
+            return total + _sum_where_equal(
+                tokens, jax.lax.dynamic_slice(toks, (row,), (window,)),
+                jax.lax.dynamic_slice(ys, (row, 0), (window, width)))
+
+        total = jax.lax.fori_loop(0, trips, add_window, jnp.zeros((block, width), jnp.float32))
+        return total.astype(y.dtype)
 
     # a loop, not a batch: the window is read where the product wants it and never kept
-    out = jax.lax.map(sum_of_block, (starts, firsts))
+    out = jax.lax.map(sum_of_block, (starts, firsts, -(-runs // window)))
     return out.reshape(-1, width)[:n_tokens]
 
 
-# Measured alone on a v5e, bf16, ms a call with the sort and the gather in it
-# (PERF.md, PR 44; (N, top_k, C, D)): the product 2.876 / 1.466 / 0.353 and the band
-# 0.851 / 0.401 / 0.406 at (8192, 8, 16384, 2048) `keye-train-8k`, (8192, 4, 8192,
-# 2048) `glm47f-train`, (4096, 10, 2560, 3072) `laguna-train`, where 256 x 10 rows
-# are all of C and the band IS the product, in a loop. The product ran at 93-97% of
-# the MXU's 197 TFLOP/s; the band moved the bytes reckoned below at 510-760 GB/s.
+# Measured alone on a v5e, bf16, ms a call with the sort and the gather in it, a uniform
+# router's rows (PERF.md section 6, PR 63, step (0); (N, top_k, C, D) -> W): the product /
+# the band with ONE window of 256 x top_k rows a block (PR 44's) / the band as built:
+#   (16384, 8, 32768, 2304) -> 512  `mellum2-train-16k`     12.900 / 2.600 / 1.664
+#   (16384, 8, 32768, 2048) -> 512  `sdar-train-8k`         11.503 / 2.368 / 1.513
+#   (8192, 8, 16384, 2048)  -> 512  `keye-train-8k`          2.850 / 0.714 / 0.505
+#   (8192, 8, 6656, 4096)   -> 256  `solar-open2-train-8k`   2.330 / 0.886 / 0.452
+#   (8192, 6, 6144, 2688)   -> 256  `twotower-train-8k`      1.425 / 0.491 / 0.282
+#   (8192, 4, 8192, 2048)   -> 256  `glm47f-train`           1.445 / 0.324 / 0.247
+#   (4096, 10, 2560, 3072)  -> 256  `laguna-train`           0.334 / 0.387 / 0.155
+# (`laguna-train`'s one window was all of C, the product in a loop, so it built the
+# product until PR 63). The sort, the gather into token order and the runs are 0.04-0.12 ms
+# of a call up to C = 16,384 and 0.89-1.00 at 32,768, where the gather passes 300 MB; a
+# block whose run takes a second window adds the window's 5-8 us, a block of 256 x top_k
+# rows 0.02-0.03 ms to a call. The product ran at 97-98% of the MXU's 197 TFLOP/s; the band
+# moved the bytes reckoned below at 310-546 GB/s, slowest at the two largest shapes.
 _PRODUCT_FLOPS = 0.95 * 197e12
-_BAND_BYTES_PER_S = 510e9
+_BAND_BYTES_PER_S = 310e9
 
 
 def _sum_is_linear(n_tokens: int, top_k: int, rows: int, width: int, itemsize: int) -> bool:
     """Whether the band sums C = `rows` rows of `width` into N tokens sooner
     than the product, from the two costs the shapes give: the product's
-    2 N C D operations at the MXU's rate, the band's bytes (every window,
+    2 N C D operations at the MXU's rate, the band's bytes (a window a block,
     the rows gathered into token order and written, the sums written) at
     the rate it was measured to move them."""
     product_s = 2 * n_tokens * rows * width / _PRODUCT_FLOPS
@@ -525,15 +576,21 @@ def _sum_of_held_rows(y, tok, pairs):
         return (_sum_by_band if linear else _sum_by_product)(y, tok, pairs)
 
 
+def _rows_of_tokens(xt, tok):
+    """xt[tok], a row past the held pairs (`tok` = N there) reading the last token's:
+    no tile of a grouped matmul visits it and `_zero_tail` makes what comes of it zero."""
+    return xt.at[tok].get(mode="clip")
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _to_held_rows(xt, tok, pairs):
     """xt [N, D] -> [C, D]: the token's row for each of the first C rows
-    of expert order."""
-    return xt[tok]
+    of expert order (any row where `tok` names no token: `_rows_of_tokens`)."""
+    return _rows_of_tokens(xt, tok)
 
 
 def _to_held_rows_fwd(xt, tok, pairs):
-    return xt[tok], tok
+    return _rows_of_tokens(xt, tok), tok
 
 
 def _to_held_rows_bwd(pairs, tok, g):
@@ -557,7 +614,7 @@ def _from_held_rows_fwd(y, tok, pairs):
 
 def _from_held_rows_bwd(pairs, tok, g):
     with jax.named_scope("moe.combine"), jax.named_scope("moe.held"):
-        return g[tok], None
+        return _rows_of_tokens(g, tok), None
 
 
 _from_held_rows.defvjp(_from_held_rows_fwd, _from_held_rows_bwd)
@@ -603,9 +660,13 @@ def _held_down_sum(gate, up, w, w_down, rows, inv, tok, sizes):
         return _from_held_rows(ys, tok, inv.shape)
 
 
-def _held_rows(bound, order, inv):
+def _held_rows(bound, order, inv, sizes):
+    """-> (the pair, the token) of each of the first C rows of expert order. Past the
+    held pairs a row names N, which is no token: it sorts after every token's rows
+    and is in no block's run (`_sum_by_band`), and no product sums it."""
     rows = order[:bound]
-    return rows, rows // inv.shape[1]
+    held = jnp.arange(bound, dtype=sizes.dtype) < sizes.sum()
+    return rows, jnp.where(held, rows // inv.shape[1], inv.shape[0])
 
 
 def _held_or_all_fwd(bound, xt, w, w_gate, w_up, w_down, order, inv, sizes):
@@ -613,7 +674,7 @@ def _held_or_all_fwd(bound, xt, w, w_gate, w_up, w_down, order, inv, sizes):
     where the block ran over all rows, which keeps nothing: its backward
     runs gate and up again)."""
     def held():
-        rows, tok = _held_rows(bound, order, inv)
+        rows, tok = _held_rows(bound, order, inv, sizes)
         gate, up = _held_gate_up(xt, w_gate, w_up, tok, inv.shape, sizes)
         return _held_down_sum(gate, up, w, w_down, rows, inv, tok, sizes), gate, up
 
@@ -653,7 +714,7 @@ def _held_or_all_vjp_bwd(bound, res, g):
     xt, w, w_gate, w_up, w_down, order, inv, sizes, gate, up = res
 
     def held():
-        rows, tok = _held_rows(bound, order, inv)
+        rows, tok = _held_rows(bound, order, inv, sizes)
         # each stage's forward is traced for its transpose and dies unused: the
         # kept gate and up stand in for the first's, the second's `ys` feeds nothing
         _, down_sum_t = jax.vjp(
@@ -748,8 +809,11 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig,
     block is BUILT with two bodies and adds `compact` (int32): 1 where
     this step's held pairs fitted in C rows and everything after the
     sort ran over C rows, 0 where they did not and it ran over all
-    N * top_k. The router, the sorts and every statistic come before
-    the branch and see all pairs either way.
+    N * top_k; and `band_trips` (int32): the most windows any block of
+    256 tokens took in the sum of the held rows into their tokens (`_band`;
+    1 where every block's run of rows fitted the window the shapes give, 0
+    where the step ran over all rows). The router, the sorts and every
+    statistic come before the branch and see all pairs either way.
     A sigmoid router's `balance_loss` takes a token's scores as shares
     of their sum, and its `z_loss` is 0. The shared expert
     (`shared_d_ff`) is no pair and is in none of the counts: every token
@@ -850,7 +914,10 @@ def moe_ffn(x: jax.Array, lp: Params, c: MoEConfig,
     if tail:
         stats["pairs_elsewhere"] = N * K - sizes.sum()
     if bound is not None:
-        stats["compact"] = _fits(sizes, bound).astype(jnp.int32)
+        fits = _fits(sizes, bound)
+        trips = _band_trips(_held_rows(bound, order, inv, sizes)[1], inv.shape, bound)
+        stats.update(compact=fits.astype(jnp.int32),
+                     band_trips=jnp.where(fits, trips, 0).astype(jnp.int32))
     out = out.reshape(B, S, D)
     if c.shared_d_ff:
         with jax.named_scope("shared.ffn"):

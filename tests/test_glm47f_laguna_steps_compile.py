@@ -43,7 +43,11 @@ GLM_SHARE = dict(model="glm-4.7-flash", vocab_size=19456, experts_held=8)
 # the account of every hash is tests/test_m7b_steps_compile.py's
 # Replaced ON PURPOSE by PR 59: the chosen experts' scores are picked by a compare and a sum
 # (`moe._of_chosen`) where `take_along_axis` gathered them one by one (a3bebfc7... from PR 44)
-_GLM_LITE_STEP = "7f65243ac098a39ff7a983de4c15a7a9912c29efc2b5a1c04700b0fdab105fdd"
+# Replaced ON PURPOSE by PR 63: the band that sums the held rows into their tokens takes a
+# window of 256 x C / N rows a block (256 where 1,024 stood) and as many windows as a block's run is
+# long (`moe._sum_by_band`: a `fori_loop` inside `lax.map`), rows past the held pairs name no
+# token (`moe._held_rows`), and the layer's statistics carry `band_trips` (7f65243a... from PR 59)
+_GLM_LITE_STEP = "48e7f6e3c9784587b3858e0b3e2b8d892e68987f0596877f0b894c1ad7110458"
 LAGUNA = dict(batch=1, model="laguna-s-2.1", n_layers=5, vocab_size=12544, experts_held=8)
 # the dense full-attention layer and ONE sliding expert layer: what is compiled (46 s of every
 # core alone where the cell's five layers take 105, 286 CPU s where they take 585: PR 54)
@@ -54,7 +58,13 @@ LAGUNA_2 = {**LAGUNA, "n_layers": 2}
 # program carries, and by PR 48, which touches nothing another model imports
 # Replaced ON PURPOSE by PR 59: the chosen experts' scores are picked by a compare and a sum
 # (`moe._of_chosen`) where `take_along_axis` gathered them one by one (0b2bb23b... from PR 46)
-_LAGUNA_STEP = "c6f0f50ee150e38f36c591c67336462f603fb9c59d2fc82f76b0f72f19a59192"
+# Replaced ON PURPOSE by PR 63: the band that sums the held rows into their tokens takes a
+# window of 256 x C / N rows a block (256 where all 2,560 rows of C stood) and as many windows as a block's run is
+# long (`moe._sum_by_band`: a `fori_loop` inside `lax.map`), rows past the held pairs name no
+# token (`moe._held_rows`), and the layer's statistics carry `band_trips` (c6f0f50e... from PR 59); with that
+# window `moe._sum_is_linear` prices the band under the one-hot product here too (0.155 against
+# 0.334 ms a call alone on the chip), so every site of this step is built with the band
+_LAGUNA_STEP = "515df84d7243688e3e125aa2e136cb4315aebc01ca08289eb09035eb5f0c57bd"
 MELLUM2 = dict(batch=1, model="mellum2-12b-a2.5b", n_layers=4, seq=16384, vocab_size=12288,
                experts_held=8)
 # sha256 of the lowered step of mellum2-12b-a2.5b as `mellum2-train-16k` builds it (PR 53: the
@@ -64,7 +74,11 @@ MELLUM2 = dict(batch=1, model="mellum2-12b-a2.5b", n_layers=4, seq=16384, vocab_
 # their VMEM limits (a0a2e204... from PR 53 to PR 55)
 # Replaced ON PURPOSE by PR 59: the chosen experts' scores are picked by a compare and a sum
 # (`moe._of_chosen`) where `take_along_axis` gathered them one by one (535bbc49... from PR 56)
-_MELLUM2_STEP = "73b53db5e2728a4b19b834c46ed01893374a0247a5553540189f1fe8b148ffe2"
+# Replaced ON PURPOSE by PR 63: the band that sums the held rows into their tokens takes a
+# window of 256 x C / N rows a block (512 where 2,048 stood) and as many windows as a block's run is
+# long (`moe._sum_by_band`: a `fori_loop` inside `lax.map`), rows past the held pairs name no
+# token (`moe._held_rows`), and the layer's statistics carry `band_trips` (73b53db5... from PR 59)
+_MELLUM2_STEP = "632b53853c1eda7c7efe04cc7c61a08b06fdf6f360a65872b84715d83a6a9786"
 # `sdar-train-8k`'s step (PR 55: the rehearsal's rung (a)): 4 full layers of the same module
 # trained by block diffusion, 16 of 128 experts and Keye's eighth of the vocabulary held, ONE
 # sequence of 8,192 tokens = 16,384 rows
@@ -75,7 +89,11 @@ SDAR = dict(batch=1, model="sdar-30b-a3b", n_layers=4, seq=8192, vocab_size=1907
 # out of models/mla.py and the mixer kinds of models/llama.py into one table, and held by it
 # Replaced ON PURPOSE by PR 59: the chosen experts' scores are picked by a compare and a sum
 # (`moe._of_chosen`) where `take_along_axis` gathered them one by one (523e528c... from PR 56)
-_SDAR_STEP = "c4789e037a6e312ce814a82b1cd4f63c7d1544b3720ce6f2992587ae89415a22"
+# Replaced ON PURPOSE by PR 63: the band that sums the held rows into their tokens takes a
+# window of 256 x C / N rows a block (512 where 2,048 stood) and as many windows as a block's run is
+# long (`moe._sum_by_band`: a `fori_loop` inside `lax.map`), rows past the held pairs name no
+# token (`moe._held_rows`), and the layer's statistics carry `band_trips` (c4789e03... from PR 59)
+_SDAR_STEP = "a08ccfd51ea4a512ae82a62d220ac2e83c5b7fc464545809aeda22f1696122f3"
 SDAR_SCOPES = ("diff.corrupt", "diff.loss", "attn.qkv", "attn.norm", "attn.rope", "attn.attend",
                "flash.blockdiff", "attn.out", "moe.router", "moe.dispatch", "moe.experts",
                "moe.combine", "block.norm", "block.stack", "embed", "head", "optim")
@@ -232,9 +250,10 @@ def test_laguna_share_train_step_sizes_the_expert_layer_by_the_held_rows(v5e):
     expert width ([40960, 3072], [40960, 1024], [4096, 10 or 16, 3072])
     and runs the block's nine kernels over 2,560 rows; the other branch is
     today's block, whole; every site is built compact, with the sum of the
-    held rows into their tokens as the one-hot product (PR 44: 256 tokens
-    x top-10 rows are all of C here, the band would be the product in a
-    loop), and none falls back to `ragged_dot`; and the two layers take no
+    held rows into their tokens as the BAND (PR 63: a window of 256 x 2560 /
+    4096 -> 256 rows a block of 256 tokens; from PR 44 to PR 62 the one-hot
+    product, because 256 tokens x top-10 rows were all of C and the band
+    the product in a loop), and none falls back to `ragged_dot`; and the two layers take no
     more memory than 4.28 GiB of arguments + 2.50 of temporaries (my
     compile, PR 54; the cell's five layers 9.06 + 3.88, PR 40: the
     branch over all rows keeps its temporaries, the kept gate / up are
@@ -244,8 +263,8 @@ def test_laguna_share_train_step_sizes_the_expert_layer_by_the_held_rows(v5e):
     engaged = step.engaged("moe.compact", "moe.full", "grouped_matmul.kernel",
                            "grouped_matmul.ragged_dot", "moe.sum.product", "moe.sum.linear")
     assert engaged["moe.compact"] >= 1 and engaged["moe.full"] == 0
-    # at [4096, 2560] the sum of the held rows stays the one-hot product (PR 44)
-    assert engaged["moe.sum.product"] >= 2 and engaged["moe.sum.linear"] == 0
+    # at [4096, 2560] the sum of the held rows is the band of 256-row windows (PR 63)
+    assert engaged["moe.sum.linear"] >= 2 and engaged["moe.sum.product"] == 0
     assert engaged["grouped_matmul.kernel"] > 0 and engaged["grouped_matmul.ragged_dot"] == 0
     hlo, computations = step.hlo, step.computations
     branches = re.findall(

@@ -12,6 +12,7 @@ linear form is held to the one-hot product directly."""
 
 import contextlib
 import dataclasses
+import functools
 from unittest import mock
 
 import jax
@@ -124,16 +125,19 @@ def test_the_bound_follows_from_the_shapes():
 
 
 @pytest.mark.parametrize("shape,linear", [
-    ((4096, 10, 2560, 3072), False), ((8192, 4, 8192, 2048), True),
-    ((8192, 8, 16384, 2048), True)], ids=["laguna-train", "glm47f-train", "keye-train-8k"])
+    ((4096, 10, 2560, 3072), True), ((8192, 4, 8192, 2048), True),
+    ((8192, 8, 16384, 2048), True), ((16384, 8, 32768, 2304), True),
+    ((8192, 8, 6656, 4096), True), ((512, 4, 512, 64), False), ((1024, 4, 1024, 64), False)],
+    ids=["laguna-train", "glm47f-train", "keye-train-8k", "mellum2-train-16k",
+         "solar-open2-train-8k", "tiny", "this_file_s_band"])
 def test_the_form_of_the_sum_follows_from_the_shapes(shape, linear):
-    """(N, top_k, C, D) of the benchmark's three small shares in bf16, each
-    as the two forms were measured alone on the chip (PERF.md, PR 44), and
-    nothing but the shapes: the rule takes no configuration."""
+    """(N, top_k, C, D) of the benchmark's small shares in bf16, each as the two forms
+    were measured alone on the chip (PERF.md, PR 63: with a window of 256 C / N rows the
+    band is 2.2 times the product's speed in `laguna-train`, whose one window of 256 x
+    top_k rows was all of C and lost to it by 0.353 to 0.406 ms in PR 44, and 4.6 to 7.8
+    times in the other six), and nothing but the shapes: the rule takes no configuration.
+    The product where the whole [N, C] matrix is a few MXU passes."""
     assert moe._sum_is_linear(*shape, itemsize=2) is linear
-    # the product where a window is most of C, linear past the measured points
-    assert not moe._sum_is_linear(512, 4, 512, 64, itemsize=4)
-    assert moe._sum_is_linear(16384, 8, 32768, 2048, itemsize=2)
 
 
 def _rows_of_a_share(routing, dtype, C=1536, N=576, most=4, seed=3):
@@ -203,6 +207,130 @@ def test_linear_sum_of_the_held_rows_is_the_one_hot_product(direction, dtype, ro
         np.testing.assert_array_equal(lin[same], prod[same])
 
 
+# -- the band's window follows a block's run (PR 63) --------------------------------------
+#
+# 1,024 tokens = four blocks of 256, top-4, C = 1,024 rows: W = 256 x C / N = 256 rows, a
+# quarter of the 256 x top_k rows a block owns at worst.
+BAND = dict(N=1024, K=4, C=1024, W=256)
+ROUTINGS = ("uniform", "zipf", "adversarial", "run_ends_at_C", "empty_block",
+            "past_the_held_pairs")
+
+
+def _routed(routing, dtype, seed=11):
+    """tok [C] as `moe._held_rows` hands it (the rows in EXPERT order, so in no order of
+    tokens; N, no token, past the held pairs), y [C, D] with zeros there, and the held rows
+    of each block of 256 tokens."""
+    N, K, C = BAND["N"], BAND["K"], BAND["C"]
+    rng = np.random.default_rng(seed)
+    blocks = N // 256
+
+    def drawn(n, p=None, tokens=N):   # n of the tokens' K pairs each, no pair twice
+        p = None if p is None else np.repeat(p, K) / (K * p.sum())
+        return rng.choice(tokens * K, size=n, replace=False, p=p) // K
+
+    if routing in ("uniform", "past_the_held_pairs"):
+        tok = drawn(C // 2 if routing == "uniform" else 300)
+    elif routing == "zipf":   # the early tokens take most pairs: block 0 holds several windows
+        tok = drawn(C // 2, 1.0 / np.arange(1, N + 1) ** 1.1)
+    elif routing == "adversarial":   # EVERY pair of block 1 held, 256 x top_k rows, and no other
+        tok = np.repeat(np.arange(256, 512), K)
+    elif routing == "run_ends_at_C":   # every row held; the last block's run is the last 724
+        tok = np.concatenate([drawn(300, tokens=N - 256), N - 256 + drawn(C - 300, tokens=256)])
+    elif routing == "empty_block":
+        tok = drawn(C // 2)
+        tok = tok[(tok < 512) | (tok >= 768)]
+    tok = rng.permutation(tok)
+    held = len(tok)
+    runs = np.bincount(tok // 256, minlength=blocks)
+    tok = np.concatenate([tok, np.full(C - held, N)]).astype(np.int32)
+    y = rng.standard_normal((C, D)).astype(np.float32) * np.exp(rng.uniform(-3, 3, (C, 1)))
+    y[held:] = 0
+    return jnp.asarray(y, dtype), jnp.asarray(tok), runs
+
+
+@functools.cache
+def _jitted_sum(direction, form):
+    """One trace a (direction, form) and dtype for every routing: the shapes are the same."""
+    return jax.jit(lambda y, tok: _summed(direction, y, tok, (BAND["N"], BAND["K"])))
+
+
+def _sum_in(form, direction, y, tok):
+    with mock.patch.object(moe, "_sum_is_linear", lambda *shape: FORMS[form]):
+        return _jitted_sum(direction, form)(y, tok)
+
+
+@pytest.mark.parametrize("direction", ["from_held_rows_forward", "to_held_rows_backward",
+                                       "the_gathers"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("routing", ROUTINGS)
+def test_the_band_follows_a_blocks_run(routing, dtype, direction):
+    """The band whose window is the slack times a block's AVERAGE run, a block whose run is
+    longer taking more windows, against the one-hot product: whatever the routing the same
+    rows are summed, each once (a window pushed back inside y, as the one window of 256 x
+    top_k rows was, would sum the rows of `run_ends_at_C`'s last block twice), in float32
+    and rounded once; the statistic says how many windows the longest run took."""
+    N, K, C, W = BAND["N"], BAND["K"], BAND["C"], BAND["W"]
+    assert moe._band(N, K, C) == (256, W)
+    y, tok, runs = _routed(routing, dtype)
+    trips = int(moe._band_trips(tok, (N, K), C))
+    assert trips == max(1, -(-runs.max() // W))
+    assert trips == {"uniform": 1, "adversarial": K * 256 // W, "empty_block": 1,
+                     "past_the_held_pairs": 1}.get(routing, trips)
+    assert routing not in ("zipf", "run_ends_at_C") or trips > 1
+    assert (runs.min() == 0) == (routing in ("adversarial", "empty_block"))
+    if routing == "run_ends_at_C":   # the last run ends where y ends, and passes two windows
+        assert runs.sum() == C and runs[-1] > 2 * W
+    if direction == "the_gathers":   # a row that names no token reads the last token's
+        clipped = np.minimum(np.asarray(tok), N - 1)
+        xt = jax.random.normal(jax.random.key(3), (N, D)).astype(y.dtype)
+        rows = moe._to_held_rows(xt, tok, (N, K))
+        np.testing.assert_array_equal(np.asarray(rows, np.float32),
+                                      np.asarray(xt, np.float32)[clipped])
+        (d_y,) = jax.vjp(lambda y: moe._from_held_rows(y, tok, (N, K)), y)[1](xt)
+        np.testing.assert_array_equal(np.asarray(d_y, np.float32),
+                                      np.asarray(xt, np.float32)[clipped])
+        return
+    band = np.asarray(_sum_in("linear", direction, y, tok), np.float32)
+    prod = np.asarray(_sum_in("product", direction, y, tok), np.float32)
+    held = int(runs.sum())
+    rows, toks = np.asarray(y, np.float32)[:held], np.asarray(tok)[:held]
+    want, magnitude = np.zeros((N, D)), np.zeros((N, D))
+    np.add.at(want, toks, rows.astype(np.float64))
+    np.add.at(magnitude, toks, np.abs(rows, dtype=np.float64))
+    assert band.shape == (N, D) and not band[magnitude.sum(axis=1) == 0].any()
+    if dtype == "float32":   # the order of at most top_k float32 additions
+        assert (np.abs(band - want) <= 8 * np.finfo(np.float32).eps * magnitude).all()
+        assert (np.abs(band - prod) <= 8 * np.finfo(np.float32).eps * magnitude).all()
+        assert held == 0 or np.abs(band).max() > 1
+    else:   # ONE rounding of a float32 sum: a bfloat16 accumulation would read 2^-8 a row
+        assert (np.abs(band - want) <= 2.0 ** -8 * np.abs(want) + 1e-30).all()
+        assert (band == prod).mean() > 0.99
+
+
+CELLS = {   # (N, top_k, held, experts, D) -> W
+    "mellum2-train-16k": ((16384, 8, 8, 64, 2304), 512),
+    "sdar-train-8k": ((16384, 8, 16, 128, 2048), 512),
+    "keye-train-8k": ((8192, 8, 16, 128, 2048), 512),
+    "solar-open2-train-8k": ((8192, 8, 8, 320, 4096), 256),
+    "twotower-train-8k": ((8192, 6, 8, 128, 2688), 256),
+    "glm47f-train": ((8192, 4, 8, 64, 2048), 256),
+    "laguna-train": ((4096, 10, 8, 256, 3072), 256),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_the_window_follows_from_the_shapes(cell):
+    """W = 256 x C / N, the slack times the rows a block of 256 tokens owns on average,
+    rounded up to 128: a function of N, top_k and C and of nothing else; an eighth to a
+    quarter of the 256 x top_k rows (or C) the window was."""
+    (N, K, held, E, _), W = CELLS[cell]
+    C = moe.held_rows_bound(N * K, held, E)
+    assert moe._band(N, K, C) == (256, W)
+    slack = 2 if 32 * held >= E else 4
+    assert W % 128 == 0 and W - 128 < slack * 256 * K * held / E <= W
+    assert W <= min(C, 256 * K) // 4
+
+
 @pytest.mark.parametrize("router", sorted(ROUTERS))
 @pytest.mark.parametrize("share", sorted(SHARES))
 def test_compact_block_is_the_block_over_all_rows(share, router, form):
@@ -221,6 +349,7 @@ def test_compact_block_is_the_block_over_all_rows(share, router, form):
         assert not built and "compact" not in stats
     else:
         assert built and int(stats["compact"]) == 1 and form()
+        assert 1 <= int(stats["band_trips"]) <= K   # no block needs more than 256 x top_k rows
         assert 0 < N * K - int(stats["pairs_elsewhere"]) <= moe.held_rows_bound(N * K, held, E)
     assert "compact" not in want_stats
     for key in STATS:
@@ -279,6 +408,8 @@ def test_a_routing_past_the_bound_runs_over_all_rows_and_loses_no_pair(held_toke
     out, stats, grads = _value_stats_grads(cfg, x, lp)
     assert N * K - int(stats["pairs_elsewhere"]) == held_tokens * K
     assert int(stats["compact"]) == compact == int(held_tokens * K <= bound)
+    # W = 256 of C = 512 rows: the 128 held tokens are ONE block's, its run all 512 rows
+    assert int(stats["band_trips"]) == {128: 2, 0: 1}.get(held_tokens, 0)
     assert int(stats["dropped_pairs"]) == 0 and form()
     with _without_the_compact_path():
         want_out, want_stats, want_grads = _value_stats_grads(cfg, x, lp)
@@ -311,7 +442,7 @@ def test_only_a_small_share_traces_a_branch(share, held, branches):
     text = str(jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))(x, lp))
     assert ("cond[" in text) == branches
     stats = jax.eval_shape(loss, x, lp)[1]
-    assert ("compact" in stats) == branches
+    assert ("compact" in stats) == ("band_trips" in stats) == branches
     if branches:
         assert stats["compact"].shape == () and stats["compact"].dtype == jnp.int32
 

@@ -37,7 +37,11 @@ TWOTOWER = dict(batch=1, model="nemotron-twotower-30b-a3b", n_layers=9, seq=8192
 # bodies are not in it)
 # Replaced ON PURPOSE by PR 59: the chosen experts' scores are picked by a compare and a sum
 # (`moe._of_chosen`) where `take_along_axis` gathered them one by one (74134453... from PR 52)
-_TWOTOWER_STEP = "4d64810dea13ffe57e409b4649a29f8183abf349520183f8abd774034150f933"
+# Replaced ON PURPOSE by PR 63: the band that sums the held rows into their tokens takes a
+# window of 256 x C / N rows a block (256 where 1,536 stood) and as many windows as a block's run is
+# long (`moe._sum_by_band`: a `fori_loop` inside `lax.map`), rows past the held pairs name no
+# token (`moe._held_rows`), and the layer's statistics carry `band_trips` (4d64810d... from PR 59)
+_TWOTOWER_STEP = "3d7cf20932955b265f7c9275051d7df28c80af6906ed3bc5884d05a7d794086d"
 GIB = 2 ** 30
 OLMO_HYBRID_SCOPES = (
     "gdn.proj", "gdn.conv", "gdn.gates", "gdn.scan", "gdn.norm", "gdn.out", "attn.qkv",

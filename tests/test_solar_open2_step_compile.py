@@ -80,3 +80,25 @@ def test_solar_open2_train_step_runs_its_kernels_and_counts_its_sites(v5e):
     assert "triangular-solve" not in hlo and "TriangularSolve" not in hlo
     assert not re.search(r"\[(?:\d+,)*8192,8192\]", hlo)
     assert re.search(r"bf16\[1,8,8192,128\]", hlo)
+
+
+def test_solar_open2_train_step_sums_the_held_rows_by_windows_of_a_blocks_run(v5e):
+    """PR 63: each of the four expert layers' two sums of the 6,656 held rows into the 8,192
+    tokens (the combine's forward, the dispatch's backward) is the band whose window is 256 x
+    6656 / 8192 -> 256 rows a block of 256 tokens, where 256 x top-8 = 2,048 stood (a block
+    owns 51 on average): a [256, 256] 0/1 matrix a window and no [256, 2048] one, and under
+    each sum's scope a loop over a block's windows INSIDE the loop over the blocks, eight of
+    each; neither the tokens x the held rows nor a product of them anywhere."""
+    step = train_step(v5e, **SOLAR_OPEN2)
+    engaged = step.engaged("moe.sum.linear", "moe.sum.product")
+    assert engaged["moe.sum.linear"] >= 2 and engaged["moe.sum.product"] == 0
+    hlo = step.hlo
+    assert re.search(r"pred\[256,256\]", hlo) and not re.search(r"pred\[256,2048\]", hlo)
+    assert not re.search(r"\[(?:\d+,)*8192,6656\]", hlo)
+    loops = [name for _, name in re.findall(
+        r'= (\([^\n]*?\)) while\([^\n]*op_name="([^"]*)"', hlo) if "moe.held" in name]
+    over_windows = [name for name in loops if name.endswith("moe.held/while/body/closed_call/while")]
+    over_blocks = [name for name in loops if name.endswith("moe.held/while")]
+    assert len(over_windows) == len(over_blocks) == 8 and len(loops) == 16, loops
+    for scope in ("moe.combine/moe.held", "moe.dispatch/moe.held"):
+        assert sum(scope in name for name in over_windows) == 4, (scope, over_windows)

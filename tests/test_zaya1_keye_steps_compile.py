@@ -34,8 +34,12 @@ KEYE_2 = {**KEYE, "n_layers": 2}
 # Replaced ON PURPOSE by PR 59: the chosen experts' scores are picked by a compare and a sum
 # (`moe._of_chosen`) where `take_along_axis` gathered them one by one (12fa5d1b... and
 # f8c66c23... from PR 56)
-_KEYE_STEP = {1: "133628740f26af0d14f1ee4f62afc95cc9fd1467332396e8614b5d7e983d197a",
-              2: "af7fa4c6b53ed7327509d39d9993e9998ebe48ce3f2224e8522dba895cf0049a"}
+# Replaced ON PURPOSE by PR 63: the band that sums the held rows into their tokens takes a
+# window of 256 x C / N rows a block (512 where 2,048 stood) and as many windows as a block's run is
+# long (`moe._sum_by_band`: a `fori_loop` inside `lax.map`), rows past the held pairs name no
+# token (`moe._held_rows`), and the layer's statistics carry `band_trips` (13362874... and af7fa4c6... from PR 59)
+_KEYE_STEP = {1: "4cca719d6401ac54c329e79b464c2d583402b158c33940c99638cacf0d477701",
+              2: "be336956e9a9962727836eefee16815dec8af725e64488c8cdf145bb19f12445"}
 KEYE_SCOPES = (
     "dsa.qkv", "dsa.norm", "dsa.rope", "dsa.index.proj", "dsa.index.scores", "dsa.select",
     "dsa.attend", "dsa.out", "moe.router", "moe.dispatch", "moe.experts", "moe.combine",
@@ -153,8 +157,10 @@ def test_keye_share_train_step_runs_the_kernels_under_a_packed_selection(v5e):
     assert "tensor<2x1x8192x256xi32>" in train_step(v5e, **KEYE_2).lowered_text
     assert not re.search(r"\[(?:\d+,)*8192,8192\]", hlo)
     # the tokens x the held rows: lowered or compiled, no such matrix; a band's block is 256 tokens
+    # and its window 256 x 16384 / 8192 = 512 rows (PR 63: no window of 256 x top-8 rows)
     assert not re.search(r"\[(?:\d+,)*8192,16384\]", hlo) and "8192x16384x" not in step.lowered_text
-    assert re.search(r"pred\[256,2048\]", hlo) and re.search(r"f32\[256,2048\]", hlo)
+    assert re.search(r"pred\[256,512\]", hlo) and re.search(r"f32\[256,2048\]", hlo)
+    assert not re.search(r"pred\[256,2048\]", hlo) and re.search(r"bf16\[512,2048\]", hlo)
     keys = {int(k) for k in re.findall(r"f32\[(?:1,)?16,512,(\d+)\]", hlo)}   # a chunk's scores
     assert keys and max(keys) == 8192 and min(keys) > 2048
     assert re.search(r"bf16\[1,32,8192,128\]", hlo) and re.search(r"bf16\[1,4,8192,128\]", hlo)
