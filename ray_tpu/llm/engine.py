@@ -239,6 +239,13 @@ class EngineConfig:
                 "sequence (models/nemotron_h.py), a second kind of state beside the pages, "
                 "which no cache manager here holds, and its experts are training-only: it is "
                 "training-only (and the published model's block-diffusion decode is not built)")
+        if hasattr(self.model, "kda_heads") and hasattr(self.model, "kv_lora_rank"):
+            raise ValueError(
+                "LLMEngine serves llama-family models with a key-value cache; Kimi-Linear's "
+                "Kimi-Delta-Attention layers carry a recurrent state a head and a convolution's "
+                "last taps a sequence and its latent-attention layers a latent cache "
+                "(models/kimi_linear.py): two kinds of state in one manager, which is not built, "
+                "and its experts are training-only: it is training-only")
         if hasattr(self.model, "kda_heads"):
             raise ValueError(
                 "LLMEngine serves llama-family models with a key-value cache; Solar-Open2's "

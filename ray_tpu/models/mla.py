@@ -24,8 +24,21 @@ channels d_v:
           q = [q_nope ; q_rot], k = [k_nope ; k_rot];
   attend  causal softmax attention, scale 1 / sqrt(d_n + d_r), through
           ops/attention.attention_head_major: the flash kernel the other
-          configurations use, at heads of d_n + d_r = d_v (256 here);
+          configurations use, at keys of d_n + d_r and values of d_v
+          (256 and 256 here);
   out     hidden += concat(o) W_o  ([H d_v] -> d_model).
+
+Two options, for a model of the family that is not GLM's (Kimi-Linear's
+MLA layers, models/kimi_linear.py; both read from the configuration, and
+GLM-4.7-Flash's lowered step is what it was without them):
+`q_lora_rank` 0 (the published `q_lora_rank` null): the query has NO
+latent, `[q_nope ; q_rot] = x W_q` is ONE matrix (the leaf `wq` in place
+of `wq_a`, `q_a_norm`, `wq_b`) and no norm; `mla_rope` false (the
+published `mla_use_nope` true): NO rotary on q_rot or k_rot, the d_r
+channels join the scores as they are projected and the ONE k_rot is
+still shared by all heads. d_v need not be d_n + d_r: ops/flash.py's
+kernels take values at a width of their own (keys of 192 beside values of
+128, the DeepSeek-V3 shape).
 
 This is the form a model is TRAINED in: keys and values are materialised
 a head. (Serving would absorb W_kvb into the query and output sides and
@@ -82,6 +95,7 @@ class GlmLiteConfig(moe.MoEConfig):
     first_dense_layers: int = 1
     mtp_layers: int = 1            # multi-token-prediction blocks: 0 or 1
     mtp_loss_weight: float = 0.3
+    mla_rope: bool = True          # false: no rotary on the d_r channels (`mla_use_nope`)
 
     @property
     def head_dim(self) -> int:
@@ -94,7 +108,7 @@ class GlmLiteConfig(moe.MoEConfig):
 
     def _attention_params(self) -> int:
         d, H, rq, rkv = self.d_model, self.n_heads, self.q_lora_rank, self.kv_lora_rank
-        return (d * rq + rq * H * self.head_dim + d * (rkv + self.qk_rope_head_dim)
+        return (query_params(self) + d * (rkv + self.qk_rope_head_dim)
                 + rkv * H * (self.qk_nope_head_dim + self.v_head_dim)
                 + H * self.v_head_dim * d)
 
@@ -146,13 +160,24 @@ GLM_LITE_TINY = dataclasses.replace(
 )
 
 
-def attention_axes() -> Params:
+def query_params(c) -> int:
+    """Matmul parameters of the query's projection: through the latent, or
+    ONE matrix where there is none (`q_lora_rank` 0)."""
+    wide = c.n_heads * (c.qk_nope_head_dim + c.qk_rope_head_dim)
+    return c.d_model * c.q_lora_rank + c.q_lora_rank * wide if c.q_lora_rank else c.d_model * wide
+
+
+def attention_axes(config=None) -> Params:
     """Logical axes of the leaves `attention_params` makes. The latents
-    are narrow and stay whole; the heads divide as the other attentions'."""
+    are narrow and stay whole; the heads divide as the other attentions'.
+    `config` (None: a query with a latent) says whether the query has one."""
+    if config is not None and not config.q_lora_rank:
+        query = {"wq": ("layers", "embed", "heads")}
+    else:
+        query = {"wq_a": ("layers", "embed", None), "q_a_norm": ("layers", "norm"),
+                 "wq_b": ("layers", None, "heads")}
     return {
-        "wq_a": ("layers", "embed", None),
-        "q_a_norm": ("layers", "norm"),
-        "wq_b": ("layers", None, "heads"),
+        **query,
         "wkv_a": ("layers", "embed", None),
         "kv_a_norm": ("layers", "norm"),
         "wkv_b": ("layers", None, "heads"),
@@ -171,10 +196,14 @@ def attention_params(config: GlmLiteConfig, keys: jax.Array) -> Params:
     def per_layer(k, shape):
         return jax.vmap(lambda kk: init_dense(kk, shape, c.param_dtype))(jax.random.split(k, L))
 
+    if rq:
+        query = {"wq_a": per_layer(keys[0], (d, rq)),
+                 "q_a_norm": jnp.ones((L, rq), c.param_dtype),
+                 "wq_b": per_layer(keys[1], (rq, H * (dn + dr)))}
+    else:  # no latent: one matrix
+        query = {"wq": per_layer(keys[0], (d, H * (dn + dr)))}
     return {
-        "wq_a": per_layer(keys[0], (d, rq)),
-        "q_a_norm": jnp.ones((L, rq), c.param_dtype),
-        "wq_b": per_layer(keys[1], (rq, H * (dn + dr))),
+        **query,
         "wkv_a": per_layer(keys[2], (d, rkv + dr)),
         "kv_a_norm": jnp.ones((L, rkv), c.param_dtype),
         "wkv_b": per_layer(keys[3], (rkv, H * (dn + dv))),
@@ -218,24 +247,28 @@ def mla_sublayer(x: jax.Array, lp: Params, c: GlmLiteConfig, *, positions: jax.A
     B, S, D = x.shape
     H, rkv, dt = c.n_heads, c.kv_lora_rank, x.dtype
     dn, dr, dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
-    if c.attention_impl == "flash" and dn + dr != dv:
-        raise ValueError(f"the flash kernel wants keys and values of one width, not "
-                         f"{dn + dr} and {dv}")
     with obs.layer_span("mla.attn"):  # counts engaged sites, while tracing
         with jax.named_scope("mla.down"):
-            c_q = rms_norm(jnp.einsum("bsd,dr->bsr", x, lp["wq_a"].astype(dt)),
-                           lp["q_a_norm"], c.rms_eps)
+            if c.q_lora_rank:
+                c_q = rms_norm(jnp.einsum("bsd,dr->bsr", x, lp["wq_a"].astype(dt)),
+                               lp["q_a_norm"], c.rms_eps)
             kv_a = jnp.einsum("bsd,dr->bsr", x, lp["wkv_a"].astype(dt))
             c_kv = rms_norm(kv_a[..., :rkv], lp["kv_a_norm"], c.rms_eps)
         with jax.named_scope("mla.up"):
-            q = _up(c_q, lp["wq_b"].astype(dt).reshape(-1, H, dn + dr))
+            if c.q_lora_rank:
+                q = _up(c_q, lp["wq_b"].astype(dt).reshape(-1, H, dn + dr))
+            else:  # no latent: the query's ONE matrix, written head-major
+                q = jnp.einsum("bsd,dhk->bhsk", x, lp["wq"].astype(dt).reshape(D, H, dn + dr))
             w_kv = lp["wkv_b"].astype(dt).reshape(rkv, H, dn + dv)
             k_nope, v = _up(c_kv, w_kv[..., :dn]), _up(c_kv, w_kv[..., dn:])
         with jax.named_scope("mla.glue"):
-            q_rot = _rope(q[..., dn:].astype(_F32), positions, c.rope_theta)
-            q = jnp.concatenate([q[..., :dn], q_rot.astype(dt)], axis=-1)
-            # ONE rotary key a token, shared by all the heads
-            k_rot = _rope(kv_a[:, None, :, rkv:].astype(_F32), positions, c.rope_theta)
+            if c.mla_rope:
+                q_rot = _rope(q[..., dn:].astype(_F32), positions, c.rope_theta)
+                q = jnp.concatenate([q[..., :dn], q_rot.astype(dt)], axis=-1)
+                k_rot = _rope(kv_a[:, None, :, rkv:].astype(_F32), positions, c.rope_theta)
+            else:
+                k_rot = kv_a[:, None, :, rkv:]
+            # ONE such key a token (rotated or not), shared by all the heads
             k = jnp.concatenate(
                 [k_nope, jnp.broadcast_to(k_rot.astype(dt), (B, H, S, dr))], axis=-1)
         with jax.named_scope("mla.attend"):

@@ -60,14 +60,21 @@ whose decay is a vector over the key's channels, 64 heads of 128;
 models/solar_open2.py over ops/kda.py's two Pallas kernels a layer) to
 one gated GQA 64 / 8 layer
 without a rotary, each over 320 sigmoid-routed experts of 1280 (top-8)
-and a shared one.
+and a shared one; and for Kimi-Linear-48B-A3B, `kimi-linear-48b-a3b`,
+trained, not served: Kimi-Delta-Attention layers at 32 heads of 128 (beta
+NOT doubled) three to one latent-attention (MLA) layer without a rotary
+and without a query latent, keys of 192 beside values of 128
+(models/kimi_linear.py over models/solar_open2.py's KDA sublayer,
+models/mla.py's MLA sublayer and ops/flash.py at a value width of its own),
+a leading dense layer of 9216, then 256 sigmoid-routed experts of 1024
+(top-8, x 2.446) and a shared one; the stack ends inside a period.
 The registry gives users the same two entry points they expect:
 
   * `get_model_config("llama3-8b")` — named presets;
   * `config_from_hf(json.load(open("config.json")))` — map a HF
     transformers config dict onto LlamaConfig/MoEConfig/ZayaConfig/
     GlmLiteConfig/LagunaConfig/KeyeConfig/OlmoHybridConfig/NemotronHConfig/
-    SolarOpen2Config (no downloads;
+    SolarOpen2Config/KimiLinearConfig (no downloads;
     weight conversion is a separate concern).
 """
 
@@ -89,7 +96,9 @@ _ON_DEMAND = {"keye-vl-2.0-30b-a3b": ("ray_tpu.models.dsa", "KEYE_VL_2_30B_A3B")
                                             "NEMOTRON_TWOTOWER_30B_A3B"),
               "nemotron-h-tiny": ("ray_tpu.models.nemotron_h", "NEMOTRON_H_TINY"),
               "solar-open2-250b": ("ray_tpu.models.solar_open2", "SOLAR_OPEN2_250B"),
-              "solar-open2-tiny": ("ray_tpu.models.solar_open2", "SOLAR_OPEN2_TINY")}
+              "solar-open2-tiny": ("ray_tpu.models.solar_open2", "SOLAR_OPEN2_TINY"),
+              "kimi-linear-48b-a3b": ("ray_tpu.models.kimi_linear", "KIMI_LINEAR_48B_A3B"),
+              "kimi-linear-tiny": ("ray_tpu.models.kimi_linear", "KIMI_LINEAR_TINY")}
 
 
 def register_model(name: str, config) -> None:
@@ -584,8 +593,6 @@ def _solar_open2_from_hf(hf: dict, **overrides):
             bool(hf.get("kda_use_full_proj")),
         "use_gqa_gate false (a GQA layer without its output gate)":
             not hf.get("use_gqa_gate", False),
-        "kda_allow_neg_eigval false (beta = sigmoid, not 2 x sigmoid)":
-            not hf.get("kda_allow_neg_eigval", False),
         f"first_k_dense_replace {hf.get('first_k_dense_replace')} (a leading dense layer)":
             bool(hf.get("first_k_dense_replace")),
         f"linear_attn_config.num_kv_heads {lin.get('num_kv_heads')} (grouped keys under KDA)":
@@ -615,10 +622,80 @@ def _solar_open2_from_hf(hf: dict, **overrides):
         gqa_layers=tuple(hf["gqa_layers"]),
         kda_heads=lin["num_heads"], kda_head_dim=lin["head_dim"], kda_rank=lin["head_dim"],
         conv_kernel=lin["short_conv_kernel_size"],
+        kda_neg_eigval=bool(hf.get("kda_allow_neg_eigval", False)),  # fla's default: false
     )
     fields.update(overrides)  # caller wins on collisions
     config = dataclasses.replace(so.SOLAR_OPEN2_250B, **fields)
     so.logical_axes(config)  # raises where the stack does not end on a whole period
+    return config
+
+
+def _kimi_linear_from_hf(hf: dict, **overrides):
+    """`model_type` "kimi_linear" (moonshotai/Kimi-Linear-48B-A3B-Instruct):
+    Kimi-Delta-Attention layers (`linear_attn_config.kda_layers`) and
+    latent-attention layers without a rotary and without a query latent
+    (`full_attn_layers`, numbered from 1), leading dense layers, then
+    sigmoid-routed experts with a shared one. What this decoder does not
+    implement is refused by name."""
+    from ray_tpu.models import kimi_linear as kl
+
+    n = hf["num_hidden_layers"]
+    lin = hf.get("linear_attn_config") or {}
+    numbered = set(lin.get("kda_layers") or ()) | set(lin.get("full_attn_layers") or ())
+    refused = {
+        "mla_use_nope false (a rotary on the latent-attention layers' 64 channels)":
+            not hf.get("mla_use_nope", False),
+        f"rope_scaling {hf.get('rope_scaling')!r}": hf.get("rope_scaling") is not None,
+        f"q_lora_rank {hf.get('q_lora_rank')} (a query with a latent under this stack)":
+            hf.get("q_lora_rank") is not None,
+        f"num_expert_group {hf.get('num_expert_group')} / topk_group {hf.get('topk_group')} "
+        "(groups of experts)":
+            hf.get("num_expert_group", 1) != 1 or hf.get("topk_group", 1) != 1,
+        f"num_nextn_predict_layers {hf.get('num_nextn_predict_layers')} (multi-token prediction)":
+            bool(hf.get("num_nextn_predict_layers")),
+        f"moe_layer_freq {hf.get('moe_layer_freq')} (dense layers among the expert layers)":
+            hf.get("moe_layer_freq", 1) != 1,
+        f"moe_router_activation_func {hf.get('moe_router_activation_func')!r}":
+            hf.get("moe_router_activation_func") != "sigmoid",
+        f"num_shared_experts {hf.get('num_shared_experts')}": hf.get("num_shared_experts") != 1,
+        f"num_key_value_heads {hf.get('num_key_value_heads')} (grouped keys under MLA)":
+            hf.get("num_key_value_heads", hf["num_attention_heads"]) != hf["num_attention_heads"],
+        f"linear_attn_config.num_kv_heads {lin.get('num_kv_heads')} (grouped keys under KDA)":
+            lin.get("num_kv_heads") not in (None, lin.get("num_heads")),
+        "kda_layers and full_attn_layers that do not number every layer once":
+            numbered != set(range(1, max(numbered, default=0) + 1))
+            or len(numbered) != len(lin.get("kda_layers") or ()) + len(lin.get("full_attn_layers") or ())
+            or n > len(numbered),
+        "no expert layer (first_k_dense_replace reaches the last layer)":
+            hf.get("first_k_dense_replace", 0) >= n,
+        "attention_bias": bool(hf.get("attention_bias")),
+        f"hidden_act {hf.get('hidden_act')!r}": hf.get("hidden_act", "silu") != "silu",
+    }
+    if any(refused.values()):
+        raise ValueError("a kimi_linear config with "
+                         + ", ".join(k for k, v in refused.items() if v) + " is not supported")
+    fields = dict(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"], n_layers=n,
+        n_heads=hf["num_attention_heads"], n_kv_heads=hf["num_attention_heads"],
+        d_ff=hf["moe_intermediate_size"],
+        shared_d_ff=hf["moe_intermediate_size"] * hf["num_shared_experts"],
+        dense_d_ff=hf["intermediate_size"], first_dense_layers=hf.get("first_k_dense_replace", 0),
+        max_seq=hf.get("model_max_length", hf.get("max_position_embeddings", 8192)),
+        rope_theta=float(hf.get("rope_theta", 10000.0)), rms_eps=float(hf["rms_norm_eps"]),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        n_experts=hf["num_experts"], top_k=hf["num_experts_per_token"],
+        norm_topk_prob=bool(hf["moe_renormalize"]),
+        routed_scaling=float(hf["routed_scaling_factor"]),
+        mla_layers=tuple(lin["full_attn_layers"]),
+        kda_heads=lin["num_heads"], kda_head_dim=lin["head_dim"], kda_rank=lin["head_dim"],
+        conv_kernel=lin["short_conv_kernel_size"],
+        kda_neg_eigval=bool(hf.get("kda_allow_neg_eigval", False)),
+        kv_lora_rank=hf["kv_lora_rank"], qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"], v_head_dim=hf["v_head_dim"],
+    )
+    fields.update(overrides)  # caller wins on collisions
+    config = dataclasses.replace(kl.KIMI_LINEAR_48B_A3B, **fields)
+    kl.logical_axes(config)  # raises where the layers cannot be laid out
     return config
 
 
@@ -627,7 +704,7 @@ _FROM_HF = {
     "zaya": _zaya_from_hf, "glm4_moe_lite": _glm_lite_from_hf, "laguna": _laguna_from_hf,
     "mellum": _mellum_from_hf, "sdar_moe": _sdar_from_hf, "KeyeVL2": _keye_from_hf,
     "olmo_hybrid": _olmo_hybrid_from_hf, "nemotron_h": _nemotron_h_from_hf,
-    "solar_open2": _solar_open2_from_hf,
+    "solar_open2": _solar_open2_from_hf, "kimi_linear": _kimi_linear_from_hf,
 }
 
 
@@ -658,7 +735,9 @@ def config_from_hf(hf: dict, **overrides):
     mixers, relu^2 experts, attention without a rotary): see
     `_nemotron_h_from_hf`. Solar-Open2 (`model_type` "solar_open2": KDA layers
     beside gated GQA ones over sigmoid-routed experts): see
-    `_solar_open2_from_hf`. For every OTHER family a
+    `_solar_open2_from_hf`. Kimi-Linear (`model_type` "kimi_linear": KDA layers
+    beside latent-attention layers without a rotary, a dense layer, experts):
+    see `_kimi_linear_from_hf`. For every OTHER family a
     `rope_scaling` and an explicit `head_dim` that is not hidden_size /
     heads stay refused.
     """

@@ -33,9 +33,10 @@ A KDA mixer (u the normed input, H = `kda_heads` heads, d =
   every channel of each (tap j on position t - j, zeros before the
   sequence, no bias), then SiLU; q and k L2-normalised a head, q scaled
   by d^-1/2 (float32 from the projection's output on; ops/gdn_conv.py);
-  beta = 2 sigmoid(u Wb) a head (`kda_allow_neg_eigval` true, the ONE
-  form built: the state's transition may then reflect; false is refused
-  by name where a configuration is read);
+  beta = 2 sigmoid(u Wb) a head (`kda_allow_neg_eigval` true,
+  `kda_neg_eigval` here: the state's transition may then reflect; false
+  is beta = sigmoid(u Wb), Kimi-Linear's, models/kimi_linear.py: the
+  kernels read beta as data either way);
   g = -exp(A_log[h]) softplus((u Wf1) Wf2 + dt_bias), a VECTOR of d log
   decays a head and position (Wf1 [D, r], Wf2 [r, H x d]:
   `kda_use_full_proj` false read as this low-rank pair, fla's `f_proj`;
@@ -154,6 +155,7 @@ class SolarOpen2Config(moe.MoEConfig):
     kda_head_dim: int = 128
     kda_rank: int = 128           # the low-rank pairs' inner width (fla: the head's width)
     conv_kernel: int = 4
+    kda_neg_eigval: bool = True   # beta doubled (`kda_allow_neg_eigval`)
     # models/llama.py's seam: the module that builds this tree and runs these layers
     stack_module: str = "ray_tpu.models.solar_open2"
     first_dense_layers = 0        # what laguna.plan reads: every layer has experts
@@ -373,7 +375,9 @@ def kda_sublayer(u: jax.Array, lp: Params, c: SolarOpen2Config, *,
             k = gdn_conv(k, lp["conv_k"], scale=1.0)
             v = gdn_conv(v, lp["conv_v"])
         with jax.named_scope("kda.gates"):
-            beta = 2.0 * jax.nn.sigmoid(b)
+            beta = jax.nn.sigmoid(b)
+            if c.kda_neg_eigval:
+                beta = 2.0 * beta
             g = (-jnp.exp(lp["A_log"].astype(_F32))[:, None, None]
                  * jax.nn.softplus(f + lp["dt_bias"].astype(_F32).reshape(H, 1, d)))
         with jax.named_scope("kda.scan"):

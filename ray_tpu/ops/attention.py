@@ -86,7 +86,7 @@ def xla_attention(
 
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
-    return out.reshape(B, Sq, H, D)
+    return out.reshape(B, Sq, H, v.shape[-1])  # the values' own width, D where a head has one
 
 
 def block_diffusion_mask(L: int, beta: int) -> jax.Array:

@@ -63,6 +63,16 @@ flash/ragged lineage to this framework. Design:
    walk over 2L x 2L would visit the same prefixes and one more sub-tile
    a noised q block (L (L + beta) visible pairs a head either way), over
    16,384 keys: one kv block too since PR 56, which was not tried;
+ * VALUES MAY HAVE A WIDTH OF THEIR OWN (PR 64): q and k share `D`, v has
+   `Dv`, and o, do and dv follow v while dq and dk follow q: every kernel
+   reads the two widths from its refs' shapes, and v's blocks, the forward's
+   accumulator and dv's scratch stand at `Dv` (nothing of a value is padded
+   to the keys' width in HBM). MLA's DeepSeek-V3 shape, keys of 128 + 64
+   beside values of 128 (models/mla.py under models/kimi_linear.py), runs
+   8,192 keys as ONE kv block (192 is 256 lanes in VMEM: a k block 4 MiB)
+   and the fused backward at 45.75 MiB stated. Where Dv == D the traced
+   jaxprs are what they were (tests/test_flash_selection.py's hashes);
+   block diffusion at unlike widths is refused by name;
  * off-TPU the same kernels run under the Pallas interpreter, so CPU
    tests exercise the real code path.
 
@@ -547,14 +557,14 @@ def _with_selection(kernel, at: int):
 def _fwd_kernel(
     q_ref,      # [1, F, Bq, D]  (F q-heads sharing this kv head)
     k_ref,      # [1, 1, Bk, D]
-    v_ref,      # [1, 1, Bk, D]
+    v_ref,      # [1, 1, Bk, Dv]  (Dv: the values' own width, D where they are the keys')
     qseg_ref,   # [1, Bq, 1]
     kseg_ref,   # [1, 1, Bk]
-    o_ref,      # [1, F, Bq, D]   (revisited across kv blocks)
+    o_ref,      # [1, F, Bq, Dv]  (revisited across kv blocks)
     lse_ref,    # [1, F, Bq, 1]
     m_scr,      # [F*Bq, 128] fp32: the running max, the same in every lane
     l_scr,      # [F*Bq, 128] fp32: the running sum's lane-wise partials
-    acc_scr,    # [F*Bq, D] fp32
+    acc_scr,    # [F*Bq, Dv] fp32
     *,
     scale: float,
     causal: bool,
@@ -570,7 +580,7 @@ def _fwd_kernel(
     j = pl.program_id(3)
     nk = pl.num_programs(3)
     F, Bq, D = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-    Bk = k_ref.shape[2]
+    Bk, Dv = k_ref.shape[2], v_ref.shape[3]
     rows = F * Bq
     Tk = _sub_k(Bk)
 
@@ -626,7 +636,7 @@ def _fwd_kernel(
             part = jnp.where(lane == 0, jnp.sum(p, axis=1, keepdims=True), 0.0)
         m_scr[...] = m_new
         l_scr[...] = l_scr[...] * alpha + part
-        acc_scr[...] = acc_scr[...] * _lanes(alpha, D) + jax.lax.dot_general(
+        acc_scr[...] = acc_scr[...] * _lanes(alpha, Dv) + jax.lax.dot_general(
             p.astype(v.dtype), v,
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -639,7 +649,7 @@ def _fwd_kernel(
     def _():
         l = jnp.sum(l_scr[...], axis=1, keepdims=True)
         safe_l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> zeros
-        o_ref[0] = (acc_scr[...] / safe_l).astype(o_ref.dtype).reshape(F, Bq, D)
+        o_ref[0] = (acc_scr[...] / safe_l).astype(o_ref.dtype).reshape(F, Bq, Dv)
         # fully-masked rows end with m ~= NEG_INF (and rows no tile ever
         # ran keep l == 0, m == NEG_INF), so lse lands at ~NEG_INF either
         # way — the "weigh nothing" value ring attention's blockwise
@@ -690,7 +700,7 @@ def _dq_kernel(
     @pl.when(needed)
     def _():
         q = q_ref[0].reshape(rows, D)
-        do = do_ref[0].reshape(rows, D)
+        do = do_ref[0].reshape(rows, do_ref.shape[3])
         lse = lse_ref[0].reshape(rows, 1)
         delta = delta_ref[0].reshape(rows, 1)
         k = k_ref[0, 0]
@@ -733,16 +743,16 @@ def _dq_kernel(
 def _dkv_kernel(
     q_ref,      # [1, F, Bq, D]
     k_ref,      # [1, 1, Bk, D]  (resident across the h-group and q blocks)
-    v_ref,      # [1, 1, Bk, D]
+    v_ref,      # [1, 1, Bk, Dv]
     qseg_ref,   # [1, Bq, 1]
     kseg_ref,   # [1, 1, Bk]
-    do_ref,     # [1, F, Bq, D]
+    do_ref,     # [1, F, Bq, Dv]
     lse_ref,    # [1, F, Bq, 1]
     delta_ref,  # [1, F, Bq, 1]
     dk_ref,     # [1, 1, Bk, D]  (revisited: written once per kv block)
-    dv_ref,
+    dv_ref,     # [1, 1, Bk, Dv]
     dk_scr,     # [Bk, D] fp32
-    dv_scr,
+    dv_scr,     # [Bk, Dv] fp32
     *,
     scale: float,
     causal: bool,
@@ -792,7 +802,7 @@ def _dkv_kernel(
         k = k_ref[0, 0, pl.ds(lo, Tk)]
         v = v_ref[0, 0, pl.ds(lo, Tk)]
         q = q_ref[0].reshape(rows, D)
-        do = do_ref[0].reshape(rows, D)
+        do = do_ref[0].reshape(rows, do_ref.shape[3])
         lse = lse_ref[0].reshape(rows, 1)
         delta = delta_ref[0].reshape(rows, 1)
         s = jax.lax.dot_general(
@@ -812,7 +822,7 @@ def _dkv_kernel(
         dv_scr[pl.ds(lo, Tk)] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # [Tk, D]
+        )  # [Tk, Dv]
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -868,22 +878,30 @@ def _vmem_params(blocks: int):
     return pltpu.CompilerParams(vmem_limit_bytes=blocks + _VMEM_HEADROOM)
 
 
-def _fwd_params(block_q: int, block_k: int, D: int, F: int, itemsize: int):
+def _fwd_params(block_q: int, block_k: int, D: int, F: int, itemsize: int,
+                Dv: Optional[int] = None):
     """Compiler parameters of the forward: k and v double-buffered, q and o
-    double-buffered, the float32 scratch (m, l, the accumulator)."""
-    D = _round_up(D, _LANES)  # as VMEM holds a row
-    kv = 2 * 2 * block_k * D * itemsize
-    rows = 2 * 2 * F * block_q * D * itemsize + F * block_q * (2 * _LANES + D) * 4
-    return _vmem_params(kv + rows)  # 9.25 MiB at head_dim 128, bf16, 8192 keys
+    double-buffered, the float32 scratch (m, l, the accumulator). `D` is q's
+    and k's width, `Dv` v's and o's (None: D)."""
+    D, Dv = (_round_up(x, _LANES) for x in (D, D if Dv is None else Dv))  # as VMEM holds a row
+    kv = 2 * block_k * (D + Dv) * itemsize
+    rows = 2 * F * block_q * (D + Dv) * itemsize + F * block_q * (2 * _LANES + Dv) * 4
+    # 9.25 MiB at head_dim 128, bf16, 8192 keys; 13.5 at keys of 192 (256 lanes) and values of 128
+    return _vmem_params(kv + rows)
 
 
-def _fused_bwd_params(block_q: int, block_k: int, D: int, F: int, itemsize: int):
+def _fused_bwd_params(block_q: int, block_k: int, D: int, F: int, itemsize: int,
+                      Dv: Optional[int] = None):
     """Compiler parameters of the fused backward: None (Mosaic's default)
-    where its blocks fit the default scoped VMEM, else the limit they need."""
-    D = _round_up(D, _LANES)  # as VMEM holds a row
-    kv = 4 * 2 * block_k * D * itemsize + 2 * block_k * D * 4
-    rows = 3 * 2 * F * block_q * D * itemsize + F * block_q * D * 4
-    return _vmem_params(kv + rows)  # 13-14 MiB at head_dim 128, bf16, 4096 keys
+    where its blocks fit the default scoped VMEM, else the limit they need:
+    k, dk (at `D`) and v, dv (at `Dv`) double-buffered and dk, dv again in
+    float32; q, dq and do double-buffered, dq again in float32."""
+    D, Dv = (_round_up(x, _LANES) for x in (D, D if Dv is None else Dv))  # as VMEM holds a row
+    kv = 2 * 2 * block_k * (D + Dv) * itemsize + block_k * (D + Dv) * 4
+    rows = 2 * F * block_q * (2 * D + Dv) * itemsize + F * block_q * D * 4
+    # 13-14 MiB at head_dim 128, bf16, 4096 keys; 8192 keys of 192 (256 lanes in VMEM) under
+    # values of 128 (models/kimi_linear.py's MLA layer): 36 + 1.75, 45.75 MiB stated
+    return _vmem_params(kv + rows)
 
 
 def _kv_fetch(window, nk, block_q, block_k, q_offset, blockdiff=None):
@@ -903,6 +921,7 @@ def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
               sk_valid, interpret, has_segments, fold, window=None, sel=None, blockdiff=None):
     B, H, Sq_pad, D = q.shape
     _, KVH, Sk_pad, _ = k.shape
+    Dv = v.shape[3]  # the values' (and o's) own width: D wherever a head's are the keys'
     G = H // KVH
     F = fold  # q-heads stacked per program (divides G)
     HG = H // F
@@ -921,26 +940,26 @@ def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, F, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h * F // G, kv(i, j), 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h * F // G, kv(i, j), 0)),
+            pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, i, j: (b, h * F // G, kv(i, j), 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, h, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, h, i, j: (b, 0, kv(i, j))),
             *_sel_specs(sel, block_q, block_k, lambda b, h, i, j: (b, i, kv(i, j))),
         ],
         out_specs=[
-            pl.BlockSpec((1, F, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, F, block_q, Dv), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, F, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Sq_pad, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Sq_pad, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, Sq_pad, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((F * block_q, _LANES), jnp.float32),
             pltpu.VMEM((F * block_q, _LANES), jnp.float32),
-            pltpu.VMEM((F * block_q, D), jnp.float32),
+            pltpu.VMEM((F * block_q, Dv), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_fwd_params(block_q, block_k, D, F, q.dtype.itemsize),
+        compiler_params=_fwd_params(block_q, block_k, D, F, q.dtype.itemsize, Dv),
     )(q, k, v, qseg, kseg, *(() if sel is None else (sel,)))
 
 
@@ -961,6 +980,7 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
               fold, dlse=None, window=None, sel=None, blockdiff=None):
     B, H, Sq_pad, D = q.shape
     _, KVH, Sk_pad, _ = k.shape
+    Dv = v.shape[3]  # of v, o, do and dv; q, k, dq and dk keep D
     G = H // KVH
     F = fold
     HG = H // F
@@ -991,10 +1011,10 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
             in_specs=[
                 pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, i, 0)),
                 pl.BlockSpec((1, 1, block_k, D), lambda b, j, h, i: (b, h * F // G, j, 0)),
-                pl.BlockSpec((1, 1, block_k, D), lambda b, j, h, i: (b, h * F // G, j, 0)),
+                pl.BlockSpec((1, 1, block_k, Dv), lambda b, j, h, i: (b, h * F // G, j, 0)),
                 pl.BlockSpec((1, block_q, 1), lambda b, j, h, i: (b, i, 0)),
                 pl.BlockSpec((1, 1, block_k), lambda b, j, h, i: (b, 0, j)),
-                pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, i, 0)),
+                pl.BlockSpec((1, F, block_q, Dv), lambda b, j, h, i: (b, h, i, 0)),
                 pl.BlockSpec((1, F, block_q, 1), lambda b, j, h, i: (b, h, i, 0)),
                 pl.BlockSpec((1, F, block_q, 1), lambda b, j, h, i: (b, h, i, 0)),
                 *_sel_specs(sel, block_q, block_k, lambda b, j, h, i: (b, i, j)),
@@ -1002,20 +1022,20 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
             out_specs=[
                 pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, i, 0)),
                 pl.BlockSpec((1, 1, block_k, D), lambda b, j, h, i: (b, h * F // G, j, 0)),
-                pl.BlockSpec((1, 1, block_k, D), lambda b, j, h, i: (b, h * F // G, j, 0)),
+                pl.BlockSpec((1, 1, block_k, Dv), lambda b, j, h, i: (b, h * F // G, j, 0)),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((B, H, Sq_pad, D), q.dtype),
                 jax.ShapeDtypeStruct((B, KVH, Sk_pad, D), k.dtype),
-                jax.ShapeDtypeStruct((B, KVH, Sk_pad, D), v.dtype),
+                jax.ShapeDtypeStruct((B, KVH, Sk_pad, Dv), v.dtype),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_k, D), jnp.float32),
-                pltpu.VMEM((block_k, D), jnp.float32),
+                pltpu.VMEM((block_k, Dv), jnp.float32),
                 pltpu.VMEM((F * block_q, D), jnp.float32),
             ],
             interpret=interpret,
-            compiler_params=_fused_bwd_params(block_q, block_k, D, F, q.dtype.itemsize),
+            compiler_params=_fused_bwd_params(block_q, block_k, D, F, q.dtype.itemsize, Dv),
         )(*operands)
         return dq, dk, dv
 
@@ -1030,10 +1050,10 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
         in_specs=[
             pl.BlockSpec((1, F, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h * F // G, kv(i, j), 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h * F // G, kv(i, j), 0)),
+            pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, i, j: (b, h * F // G, kv(i, j), 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, h, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, h, i, j: (b, 0, kv(i, j))),
-            pl.BlockSpec((1, F, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, F, block_q, Dv), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, F, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, F, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
             *_sel_specs(sel, block_q, block_k, lambda b, h, i, j: (b, i, kv(i, j))),
@@ -1061,25 +1081,25 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
         in_specs=[
             pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, qb(i, j), 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, j, h, i: (b, h * F // G, j, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, j, h, i: (b, h * F // G, j, 0)),
+            pl.BlockSpec((1, 1, block_k, Dv), lambda b, j, h, i: (b, h * F // G, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, j, h, i: (b, qb(i, j), 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, j, h, i: (b, 0, j)),
-            pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, qb(i, j), 0)),
+            pl.BlockSpec((1, F, block_q, Dv), lambda b, j, h, i: (b, h, qb(i, j), 0)),
             pl.BlockSpec((1, F, block_q, 1), lambda b, j, h, i: (b, h, qb(i, j), 0)),
             pl.BlockSpec((1, F, block_q, 1), lambda b, j, h, i: (b, h, qb(i, j), 0)),
             *_sel_specs(sel, block_q, block_k, lambda b, j, h, i: (b, qb(i, j), j)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, D), lambda b, j, h, i: (b, h * F // G, j, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, j, h, i: (b, h * F // G, j, 0)),
+            pl.BlockSpec((1, 1, block_k, Dv), lambda b, j, h, i: (b, h * F // G, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, KVH, Sk_pad, D), k.dtype),
-            jax.ShapeDtypeStruct((B, KVH, Sk_pad, D), v.dtype),
+            jax.ShapeDtypeStruct((B, KVH, Sk_pad, Dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         interpret=interpret,
     )(*operands)
@@ -1179,7 +1199,7 @@ def _fold_scale(q: jax.Array, softmax_scale: Optional[float]) -> jax.Array:
 def _flash_head_major(
     qt: jax.Array,  # [B, H, Sq, D], the softmax scale folded in
     kt: jax.Array,  # [B, KVH, Sk, D]
-    vt: jax.Array,  # [B, KVH, Sk, D]
+    vt: jax.Array,  # [B, KVH, Sk, Dv]: a width of its own (Dv = D wherever a head has one)
     *,
     causal: bool,
     segment_ids: Optional[jax.Array],
@@ -1194,7 +1214,7 @@ def _flash_head_major(
     blockdiff: Optional[tuple] = None,  # (L, beta): 2L rows against the L clean keys
 ) -> tuple[jax.Array, jax.Array]:
     """The kernels' own layout, which both public forms come down to:
-    -> (o [B, H, Sq, D], lse [B, H, Sq_pad, 1])."""
+    -> (o [B, H, Sq, Dv], lse [B, H, Sq_pad, 1])."""
     if window is not None and (not causal or window < 1):
         raise ValueError(f"a sliding window ({window}) is a causal mask's: window >= 1, causal")
     B, H, Sq, _ = qt.shape
@@ -1297,6 +1317,9 @@ def _block_diffusion(q, k, v, blockdiff, **blocks):
     if S != 2 * L or k.shape[2] != S or L % beta:
         raise ValueError(f"block diffusion {blockdiff}: 2L rows and 2L keys in whole blocks, "
                          f"got {S} rows, {k.shape[2]} keys")
+    if v.shape[3] != D:
+        raise ValueError(f"block diffusion over values of a width of their own ({v.shape[3]} "
+                         f"beside keys of {D}) is not implemented")
     q = _fold_scale(q, None)
     # the kernels' own name in a trace and in the compiled step (`flash.blockdiff.N`); a
     # table of the step books them to the model's scope around this call
@@ -1372,7 +1395,7 @@ def flash_attention(
 def flash_attention_head_major(
     q: jax.Array,  # [B, H, Sq, D]
     k: jax.Array,  # [B, KVH, Sk, D]
-    v: jax.Array,  # [B, KVH, Sk, D]
+    v: jax.Array,  # [B, KVH, Sk, Dv]
     *,
     causal: bool = True,
     segment_ids: Optional[jax.Array] = None,  # [B, S] (requires Sq == Sk)

@@ -1,6 +1,7 @@
 """What the model files (tests/test_zaya.py, test_glm_lite.py,
 test_laguna.py (Laguna, Mellum2 and SDAR: one stack), test_keye.py,
-test_olmo_hybrid.py, test_nemotron_h.py, test_solar_open2.py, test_moe.py), the files of their
+test_olmo_hybrid.py, test_nemotron_h.py, test_solar_open2.py, test_kimi_linear.py, test_moe.py),
+the files of their
 train paths (tests/test_contract_<model>.py) and
 tests/test_model_contract.py share. No test lives here (pytest does not
 collect the file).
@@ -34,10 +35,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench.reference import (glm_lite_decoder, keye_decoder, laguna_decoder, mellum2_decoder,
-                                 nemotron_h_decoder, olmo_hybrid_decoder, sdar_decoder,
-                                 solar_open2_decoder, zaya_decoder)
-from ray_tpu.models import (block_diffusion, cca, dsa, laguna, llama, mla, nemotron_h,
+from chipbench.reference import (glm_lite_decoder, keye_decoder, kimi_linear_decoder,
+                                 laguna_decoder, mellum2_decoder, nemotron_h_decoder,
+                                 olmo_hybrid_decoder, sdar_decoder, solar_open2_decoder,
+                                 zaya_decoder)
+from ray_tpu.models import (block_diffusion, cca, dsa, kimi_linear, laguna, llama, mla, nemotron_h,
                             olmo_hybrid, solar_open2)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -411,6 +413,31 @@ def solar_open2_shape(cfg) -> dict:
     }
 
 
+def kimi_linear_shape(cfg) -> dict:
+    """A KimiLinearConfig as the configuration file's dict (HF key names): the
+    experts HELD under their published key, the layers numbered from 1."""
+    every = max(cfg.n_layers, max(cfg.mla_layers))
+    return {
+        "hidden_size": cfg.d_model, "num_attention_heads": cfg.n_heads,
+        "num_key_value_heads": cfg.n_kv_heads, "num_hidden_layers": cfg.n_layers,
+        "intermediate_size": cfg.dense_d_ff, "first_k_dense_replace": cfg.first_dense_layers,
+        "q_lora_rank": None, "kv_lora_rank": cfg.kv_lora_rank, "mla_use_nope": True,
+        "qk_nope_head_dim": cfg.qk_nope_head_dim, "qk_rope_head_dim": cfg.qk_rope_head_dim,
+        "v_head_dim": cfg.v_head_dim, "rope_theta": cfg.rope_theta,
+        "linear_attn_config": {
+            "short_conv_kernel_size": cfg.conv_kernel, "head_dim": cfg.kda_head_dim,
+            "num_heads": cfg.kda_heads, "full_attn_layers": list(cfg.mla_layers),
+            "kda_layers": [l for l in range(1, every + 1) if l not in cfg.mla_layers]},
+        "moe_intermediate_size": cfg.d_ff, "rms_norm_eps": cfg.rms_eps, "moe_layer_freq": 1,
+        "num_experts": cfg.n_held, "published": {"num_experts": cfg.n_experts},
+        "deployment": {"first_expert_held": cfg.first_expert_held}, "num_shared_experts": 1,
+        "num_expert_group": 1, "topk_group": 1, "num_nextn_predict_layers": 0,
+        "num_experts_per_token": cfg.top_k, "moe_renormalize": cfg.norm_topk_prob,
+        "routed_scaling_factor": cfg.routed_scaling, "model_max_length": cfg.max_seq,
+        "tie_word_embeddings": cfg.tie_embeddings, "vocab_size": cfg.vocab_size,
+    }
+
+
 _LN = {"ln1": 0.2, "ln2": 0.2}
 _MLA_NORMS = {**_LN, "q_a_norm": 0.2, "kv_a_norm": 0.2}
 _REMAT_TOL = dict(rtol=1e-4, atol=2e-6)
@@ -615,4 +642,33 @@ SOLAR_OPEN2 = Model(
     bf16=dict(attention_impl="flash"), bf16_rel=0.02,
     tokens=skewed_tokens, reference_set_up=contextlib.nullcontext,
 )
-MODELS = (ZAYA, GLM_LITE, LAGUNA, MELLUM2, SDAR, KEYE, OLMO_HYBRID, NEMOTRON_H, SOLAR_OPEN2)
+
+
+def _kimi_linear_norms(params) -> list:
+    kda = {**_LN, "o_norm": 0.2, "A_log": 0.3, "dt_bias": 0.3, "g_bias": 0.3}
+    blocks = [params["dense_layers"], *_typed_blocks(params)]
+    return [*((b, kda if "wb" in b else {**_LN, "kv_a_norm": 0.2}) for b in blocks),
+            (params, {"final_norm": 0.2})]
+
+
+KIMI_LINEAR = Model(
+    name="kimi_linear",
+    fp32=dataclasses.replace(kimi_linear.KIMI_LINEAR_TINY, dtype=jnp.float32),
+    batch=2, seq=150,   # two chunks of 64 and 22 positions more: no multiple of the chunk
+    reference=kimi_linear_decoder, shape_of=kimi_linear_shape, n_keys=32, bias=0.05,
+    norms=_kimi_linear_norms, preset="kimi-linear-48b-a3b", tiny="kimi-linear-tiny",
+    refused_as="Kimi-Linear", catalog="Kimi-Linear-48B-A3B-Instruct",
+    config_file="kimi-linear-48b-a3b-train.json",
+    facts={"head_dim": 192, "kda_heads": 32, "kda_head_dim": 128, "kda_rank": 128,
+           "conv_kernel": 4, "kda_neg_eigval": False, "n_heads": 32, "n_kv_heads": 32,
+           "q_lora_rank": 0, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+           "qk_rope_head_dim": 64, "v_head_dim": 128, "mla_rope": False, "n_experts": 256,
+           "top_k": 8, "router_score": "sigmoid", "routed_scaling": 2.446, "shared_d_ff": 1024,
+           "d_ff": 1024, "dense_d_ff": 9216, "first_dense_layers": 1, "max_seq": 1048576,
+           "mla_layers": (4, 8, 12, 16, 20, 24, 27)},
+    remat_plain={}, remat_bias=0.05, remat_tol=_REMAT_TOL,
+    bf16=dict(attention_impl="flash"), bf16_rel=0.02,
+    tokens=skewed_tokens, reference_set_up=contextlib.nullcontext,
+)
+MODELS = (ZAYA, GLM_LITE, LAGUNA, MELLUM2, SDAR, KEYE, OLMO_HYBRID, NEMOTRON_H, SOLAR_OPEN2,
+          KIMI_LINEAR)

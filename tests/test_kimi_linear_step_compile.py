@@ -1,0 +1,87 @@
+"""The step of `kimi-linear-train-8k` for a described v5e (tests/v5e_steps.py),
+compiled ONCE, in a file of its cell's own (PR 45's rule): Kimi-Linear-48B-A3B's
+layers 1-5 (a dense KDA layer, then KDA, KDA, NoPE MLA, KDA over top-8 of 256
+experts with 8 held and a shared one; ALL 32 heads of both mixers, an eighth of
+the vocabulary, 1 x 8192) as the cell builds it. What it holds is what the
+lowered module cannot show: that the step FITS (6.73 GiB of arguments + its
+temporaries of 15.75, with the remat policy "dots" keeping what ops/kda.py's
+forward kernel writes at 32 heads: 448 MiB a KDA layer), which is also the guard
+that ops/flash.py's kernels lower through Mosaic at keys of 192 beside values of
+128 over ONE kv block of 8,192 keys (the fused backward at its own 45.75 MiB of
+VMEM), ops/kda.py's at 32 heads (a grid four times Solar-Open2's) and
+ops/grouped_matmul.py's at K 2304 / N 1024 where no chip is at hand. One
+compile, about 80 s of every core."""
+
+import re
+
+import pytest
+
+from v5e_steps import grouped_kernels, train_step, v5e  # noqa: F401 - a fixture
+
+KIMI_LINEAR = dict(batch=1, model="kimi-linear-48b-a3b", n_layers=5, seq=8192, vocab_size=20480,
+                   experts_held=8)
+GIB = 2 ** 30
+SCOPES = ("kda.proj", "kda.conv", "kda.gates", "kda.scan", "kda.norm", "kda.out", "mla.down",
+          "mla.up", "mla.glue", "mla.attend", "mla.out", "dense.ffn", "moe.router", "moe.dispatch",
+          "moe.experts", "moe.combine", "shared.ffn", "block.norm", "block.stack", "embed", "head",
+          "optim")
+
+
+# sha256 of the lowered step of kimi-linear-48b-a3b as `kimi-linear-train-8k` builds it, as PR 64
+# lowers it and as every run of PR 64 on the chip ran it (the rehearsal before call 1 and the
+# tree after the last call hash alike). A change that MEANS to move the step replaces the hash
+# and says what moved.
+_KIMI_LINEAR_STEP = "f45b27c501e203ccd335d8d726344a2ae0935f0128e89e300257bf8e72c31b0b"
+
+
+def test_kimi_linear_lowered_step_is_the_one_the_chip_ran(v5e):
+    assert train_step(v5e, **KIMI_LINEAR).lowered_hash() == _KIMI_LINEAR_STEP
+
+
+def test_kimi_linear_train_step_fits_the_chip(v5e):
+    """602,450,816 parameters x 12 B = 6.73 GiB of arguments; the
+    temporaries with `kda_out` and `kda_states` of four KDA layers at 32
+    heads saved: under the chip's 15.75 GiB (the rehearsal of ISSUE 64's
+    step 3; the configuration file's `reduced` has the table)."""
+    memory = train_step(v5e, **KIMI_LINEAR).memory
+    assert 6.72 * GIB < memory.argument_size_in_bytes < 6.75 * GIB
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0 * GIB < 15.75 * GIB
+    # over a quarter of the chip by the arguments alone: the benchmark's floor
+    assert memory.argument_size_in_bytes > 0.25 * 16 * GIB
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_kimi_linear_train_step_has_every_scope_its_readers_sum(v5e, scope):
+    assert train_step(v5e, **KIMI_LINEAR).has_scope(scope)
+
+
+def test_kimi_linear_train_step_runs_its_kernels_and_counts_its_sites(v5e):
+    """The Pallas kernels Mosaic took: the MLA layer's flash forward and its
+    FUSED backward at 32 heads, keys of 192 and values of 128 over ONE kv
+    block of 8,192 keys, named after their scope (no value padded to 192: dv
+    is [1, 32, 8192, 128]); `gdn_conv_fwd` / `gdn_conv_bwd` under `kda.conv`
+    (q, k and v of each of FOUR KDA layers forward, forward again in the
+    backward and backward); `kda_fwd` x 4 and `kda_bwd` x 4 under `kda.scan`
+    at [1, 32, 8192, 128]; the grouped matmuls of four expert layers and no
+    `ragged-dot-none`."""
+    step = train_step(v5e, **KIMI_LINEAR)
+    engaged = step.engaged("kda.attn", "mla.attn", "kda.rule", "kda.kernel", "gdn_conv.kernel",
+                           "moe.compact", "moe.full", "flash.bwd_fused", "flash.bwd_split",
+                           "grouped_matmul.ragged_dot", "grouped_matmul.kernel")
+    assert engaged["kda.attn"] >= 4 and engaged["kda.kernel"] >= 4 and engaged["mla.attn"] >= 1
+    assert engaged["gdn_conv.kernel"] >= 12 and engaged["moe.compact"] >= 4
+    assert engaged["flash.bwd_fused"] == 1 and engaged["grouped_matmul.kernel"] > 0
+    assert engaged["moe.full"] == engaged["flash.bwd_split"] == 0
+    assert engaged["grouped_matmul.ragged_dot"] == 0   # fallback_sites
+    hlo, kernels = step.hlo, step.kernels
+    names = sorted(re.sub(r"\.\d+$", "", k) for k in kernels if not k.startswith("ragged-dot"))
+    assert names == (["gdn_conv_bwd"] * 12 + ["gdn_conv_fwd"] * 24 + ["kda_bwd"] * 4
+                     + ["kda_fwd"] * 4 + ["mla.attend"] * 2), names
+    grouped = grouped_kernels(kernels)
+    assert grouped and all(k.startswith("ragged-dot-tiled") for k in grouped), grouped
+    attend = [line for line in hlo.splitlines() if "tpu_custom_call" in line
+              and re.search(r'op_name="[^"]*mla\.attend', line)]
+    assert len(attend) == 2
+    # the kernels' own operands: keys of 192, values of 128, and nothing of a value at 192
+    assert all("bf16[1,32,8192,192]" in line and "bf16[1,32,8192,128]" in line for line in attend)
+    assert not re.search(r"\[(?:\d+,)*8192,8192\]", hlo)

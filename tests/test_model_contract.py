@@ -18,8 +18,8 @@ import operator
 
 import pytest
 
-from model_cases import (GLM_LITE, KEYE, LAGUNA, MELLUM2, MODELS, NEMOTRON_H, OLMO_HYBRID, SDAR,
-                         SOLAR_OPEN2, Model, catalog_config)
+from model_cases import (GLM_LITE, KEYE, KIMI_LINEAR, LAGUNA, MELLUM2, MODELS, NEMOTRON_H,
+                         OLMO_HYBRID, SDAR, SOLAR_OPEN2, Model, catalog_config)
 from ray_tpu.models.registry import config_from_hf, get_model_config
 
 by_name = pytest.mark.parametrize("model", MODELS, ids=lambda m: m.name)
@@ -102,7 +102,8 @@ def test_config_from_hf_maps_the_catalogs_config_onto_the_preset(model):
     (SOLAR_OPEN2, "use_rope", True, "use_rope"),
     (SOLAR_OPEN2, "kda_use_full_proj", True, "kda_use_full_proj"),
     (SOLAR_OPEN2, "use_gqa_gate", False, "use_gqa_gate false"),
-    (SOLAR_OPEN2, "kda_allow_neg_eigval", False, "kda_allow_neg_eigval false"),
+    # (`kda_allow_neg_eigval` false is no refusal since PR 64: beta = sigmoid is Kimi-Linear's form)
+    (KIMI_LINEAR, "mla_use_nope", False, "mla_use_nope false"),
     (SOLAR_OPEN2, "first_k_dense_replace", 1, "first_k_dense_replace 1"),
     (SOLAR_OPEN2, "linear_attn_config", {"short_conv_kernel_size": 4, "head_dim": 128,
                                          "num_heads": 64, "num_kv_heads": 8},
@@ -112,10 +113,37 @@ def test_config_from_hf_maps_the_catalogs_config_onto_the_preset(model):
     (SOLAR_OPEN2, "num_hidden_layers", 6, "does not end on a whole period"),
     (SOLAR_OPEN2, "attention_bias", True, "attention_bias"),
     (SOLAR_OPEN2, "sliding_window", 4096, "a sliding window"),
+    (KIMI_LINEAR, "rope_scaling", {"type": "yarn", "factor": 4.0}, "rope_scaling"),
+    (KIMI_LINEAR, "num_expert_group", 8, "num_expert_group 8"),
+    (KIMI_LINEAR, "topk_group", 2, "topk_group 2 .groups of experts."),
+    (KIMI_LINEAR, "num_nextn_predict_layers", 1, "num_nextn_predict_layers 1"),
+    (KIMI_LINEAR, "q_lora_rank", 768, "q_lora_rank 768"),
+    (KIMI_LINEAR, "moe_layer_freq", 2, "moe_layer_freq 2"),
+    (KIMI_LINEAR, "moe_router_activation_func", "softmax", "moe_router_activation_func 'softmax'"),
+    (KIMI_LINEAR, "num_shared_experts", 2, "num_shared_experts 2"),
+    (KIMI_LINEAR, "num_key_value_heads", 8, "num_key_value_heads 8"),
+    (KIMI_LINEAR, "attention_bias", True, "attention_bias"),
+    (KIMI_LINEAR, "hidden_act", "gelu", "hidden_act 'gelu'"),
+    (KIMI_LINEAR, "linear_attn_config", {"short_conv_kernel_size": 4, "head_dim": 128,
+                                         "num_heads": 32, "kda_layers": [1, 2, 3],
+                                         "full_attn_layers": [3, 4]},
+     "do not number every layer once"),
 ], ids=lambda v: v.name if isinstance(v, Model) else None)
 def test_config_from_hf_refuses_by_name_what_is_not_implemented(model, key, value, names):
     with pytest.raises(ValueError, match=names):
         config_from_hf({**catalog_config(model), key: value})
+
+
+def test_beta_not_doubled_is_read_from_a_solar_open2_config_and_runs():
+    """`kda_allow_neg_eigval` false was refused by name until PR 64 built the
+    form (Kimi-Linear's): it is now the configuration's `kda_neg_eigval`, and
+    the published true stays the preset's."""
+    published = catalog_config(SOLAR_OPEN2)
+    assert config_from_hf(published).kda_neg_eigval is True
+    assert config_from_hf({**published, "kda_allow_neg_eigval": False}).kda_neg_eigval is False
+    assert config_from_hf(catalog_config(KIMI_LINEAR)).kda_neg_eigval is False
+    assert config_from_hf({**catalog_config(KIMI_LINEAR),
+                           "kda_allow_neg_eigval": True}).kda_neg_eigval is True
 
 
 @by_name
@@ -130,7 +158,7 @@ def test_engine_refuses_the_model_by_name(model):
 
 TINY_PRESETS = ("llama-tiny", "moe-tiny", "zaya-tiny", "glm-lite-tiny", "laguna-tiny",
                 "mellum2-tiny", "sdar-tiny", "keye-tiny", "olmo-hybrid-tiny", "nemotron-h-tiny",
-                "solar-open2-tiny")
+                "solar-open2-tiny", "kimi-linear-tiny")
 # preset -> sha256 of (every leaf's path, shape and dtype), of the leaves' bytes in the paths'
 # order, and of (every leaf's path and logical axes), as PR 56's tree (the parent of PR 57,
 # which moved WHERE models/llama.py reads a configuration's mixer kind) makes them from
@@ -183,6 +211,11 @@ _TINY_TREES = {
         "68c72f7f46fb31463d62f89cb6787a5de75e2a1aad916f2fa82e468228a1deea",
         "013de14d5186164a99cdb501970d4cd72dea75228b657d71f8b31cf524e70b47",
         "69b588ab7eb833ded4f755ccebe8d5077159400fb02bd720fefc16fa2e44a20d"),
+    # as PR 64 made it (models/kimi_linear.py)
+    "kimi-linear-tiny": (
+        "928f16eb4d7586535037741561032a71e42a5e931d82595ed3d1a2dfdf4c4502",
+        "957d5d954833a08f386ebc0aec461583d2e5d10603a88e97b8ee84aa939e70d0",
+        "c2167b5181965f594d25913eae02763733c066af57fb8a1c4c80fa6840325c5a"),
 }
 
 

@@ -38,6 +38,17 @@ def test_solar_open2_train_step_fits_the_chip_with_what_the_rules_forward_hands_
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14.5 * GIB < 15.75 * GIB
 
 
+# sha256 of the lowered step of solar-open2-250b as `solar-open2-train-8k` builds it, as PR 63's
+# tree (the parent of PR 64) lowers it: PR 64 gave the KDA sublayer an option (`kda_neg_eigval`:
+# beta doubled or not) and ops/flash.py a value width of its own, and neither is work in this
+# step. A change that MEANS to move the step replaces the hash and says what moved.
+_SOLAR_OPEN2_STEP = "b5b5635a4bbab467cfe530607b600fe26d3f6e664ab2cf2d676a686e5a949511"
+
+
+def test_solar_open2_lowered_step_is_text_for_text_the_parents(v5e):
+    assert train_step(v5e, **SOLAR_OPEN2).lowered_hash() == _SOLAR_OPEN2_STEP
+
+
 @pytest.mark.parametrize("scope", SCOPES)
 def test_solar_open2_train_step_has_every_scope_its_readers_sum(v5e, scope):
     assert train_step(v5e, **SOLAR_OPEN2).has_scope(scope)

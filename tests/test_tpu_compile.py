@@ -155,6 +155,35 @@ def test_flash_attention_compiles_at_heads_of_256_with_the_kernels_own_vmem(v5e,
         assert _fused_bwd_params(block_q, 4096, 128, fold, 2) is None
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
+def test_flash_attention_compiles_at_keys_of_192_beside_values_of_128(v5e, grad):
+    """`kimi-linear-train-8k`'s MLA layer (PR 64; the DeepSeek-V3 shape): 32
+    heads, none shared, keys of 128 + 64 = 192 and values of 128, ONE
+    sequence of 8,192 keys in ONE kv block (a k block is 4 MiB as VMEM holds
+    its 256 lanes). A last dimension of 192 is Mosaic's to accept, not the
+    interpreter's; v, o and dv keep 128 in HBM (nothing of a value is padded
+    to the keys' width); one kernel forward and the FUSED backward, which
+    states its own 45.75 MiB."""
+    from ray_tpu.ops import flash
+
+    assert flash.default_block_k(8192, 192, 2) == 8192
+    assert flash._fused_bwd_params(512, 8192, 192, 1, 2, 128).vmem_limit_bytes == int(45.75 * 2 ** 20)
+
+    def fwd(q, k, v):
+        return flash.flash_attention_head_major(q, k, v, causal=True)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    with mock.patch("jax.default_backend", return_value="tpu"):   # flash's interpret switch
+        hlo = compile_kernel(bwd if grad else fwd, ((1, 32, 8192, 192), _BF16),
+                             ((1, 32, 8192, 192), _BF16), ((1, 32, 8192, 128), _BF16),
+                             sharding=one_chip(v5e))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == (2 if grad else 1)
+    kernels = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert all("bf16[1,32,8192,192]" in line and "bf16[1,32,8192,128]" in line for line in kernels)
+
+
 @pytest.mark.parametrize("in_pipeline", [False, True], ids=["fsdp_tp", "pp_fsdp"])
 def test_flash_attention_compiles_under_a_mesh(v5e, in_pipeline):
     """A Mosaic kernel cannot be partitioned by the compiler: under a
