@@ -33,7 +33,12 @@ chunk at a time, on the rows of its half. T is made by block-recursive
 inversion, all on the MXU: the inverse of the 2 x 2 diagonal blocks is
 I - A there, and a level that doubles the blocks is T <- T - T A_off T
 with A_off the part of A that joins two neighbouring blocks (five levels
-to 64), the levels of a step's pairs interleaved.
+to 64), the levels of a step's pairs interleaved. A_off has rows in the
+blocks' later halves alone, and so have both products, so from blocks of
+8 positions up they run over those 64 rows (`_later`, `_spread`: seven
+tiles of 128^3 a pair where all rows made ten; `gated_delta_fwd` 4.02 ->
+3.29 ms a layer at [1, 30, 4096, 96 / 192], the same bits out; my chip
+run, PR 65, call 2). ops/kda.py imports the inverse and the two helpers.
 
 THE FORWARD KERNEL has a grid of (batch x heads, blocks of `_BLOCK`
 pairs), the second axis sequential, and S [dk, dv] float32 in a VMEM
@@ -105,7 +110,7 @@ _PAIR = 2 * CHUNK
 # positions, for 1.3% of the rule alone (5.87 / 12.56 ms a layer against 5.94 / 12.73 at
 # [1, 30, 4096, 96 / 192]; 1 pair a step is 15% slower; PERF.md section 6, PR 47, call 3)
 _BLOCK = 2
-_LANES = 128
+_LANES, _SUBLANES = 128, 8
 _F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
 _NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
@@ -137,21 +142,47 @@ def _indices(n):
             jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
 
 
+def _later(x, h):
+    """The rows of the later halves of the blocks of 2h, [_PAIR, n] ->
+    [_PAIR / 2, n]: the only rows a level that joins halves of h positions
+    has (its mask keeps i in the later half, j in the earlier), and a
+    product costs the MXU by its rows. Whole sublane tiles move (h >= 8);
+    under that the rows pass whole."""
+    if h < _SUBLANES:
+        return x
+    return jnp.concatenate([x[s:s + h] for s in range(h, x.shape[0], 2 * h)], axis=0)
+
+
+def _spread(y, h):
+    """`_later`'s rows back where they stood, zeros in the earlier halves."""
+    if h < _SUBLANES:
+        return y
+    zeros = jnp.zeros((h, y.shape[1]), y.dtype)
+    return jnp.concatenate([part for s in range(0, y.shape[0], h)
+                            for part in (zeros, y[s:s + h])], axis=0)
+
+
 def _inverses(systems):
     """(I + A)^-1 of each strictly lower-triangular, block-diagonal A
     [n, n] (blocks of CHUNK): the inverses of the 2 x 2 diagonal blocks
     are I - A there, and a level doubles the blocks,
     [[T1, 0], [-T2 A21 T1, T2]] with every block of a level at once:
-    T <- T - T A_off T, A_off what of A joins two neighbours. The levels
-    are the outer loop: the matrices' chains of products stand
+    T <- T - T A_off T, A_off what of A joins two neighbours of h
+    positions. A_off has rows in the later halves alone and T is
+    block-diagonal at that level, so A_off T and T (A_off T) have them
+    there too: both products run over `_later`'s rows (half a tile each
+    from h = 8 up; seven tiles a matrix where all rows made ten). The
+    levels are the outer loop: the matrices' chains of products stand
     interleaved in the program."""
     r, c = _indices(_PAIR)
     inverses = [jnp.where(r == c, 1.0, 0.0) - jnp.where((r >> 1) == (c >> 1), A, 0.0)
                 for A in systems]
     for level in range(1, _LOG_CHUNK):
+        h = 1 << level
         joins = ((r >> (level + 1)) == (c >> (level + 1))) & ((r >> level) != (c >> level))
-        inverses = [T - _dot(T, _dot(jnp.where(joins, A, 0.0), T, _NN), _NN)
-                    for A, T in zip(systems, inverses)]
+        joined = [_spread(_dot(_later(jnp.where(joins, A, 0.0), h), T, _NN), h)
+                  for A, T in zip(systems, inverses)]
+        inverses = [T - _spread(_dot(_later(T, h), X, _NN), h) for T, X in zip(inverses, joined)]
     return inverses
 
 

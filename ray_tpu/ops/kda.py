@@ -59,10 +59,34 @@ the constant 0 / 1 matrix `_SUMS` with g, and the backward's dg ONE product
 with its transpose. A factor that underflows to 0 stands for a product that
 is smaller still. That algebra shares nothing with the scalar rule's
 kernels but the chunk size, the pairs and the inverse (imported from
-ops/gated_delta.py as they stand), so it is a module of its own
-(`olmo-hybrid-train`'s lowered step is untouched); with g constant over a
+ops/gated_delta.py), so it is a module of its own; with g constant over a
 head's channels it IS the scalar rule, and tests/test_kda.py holds the two
 to each other.
+
+A PRODUCT COSTS THE MXU BY ITS ROWS AND ITS bf16 PASSES, so no row and no
+pass is multiplied that the trace knows to be zero (PR 65). (1) `_SUMS` is
+handed to the MXU as bfloat16, which holds 0 and 1 exactly, and the other
+operand as its three bfloat16 terms (`_sum_dot`): three passes accumulated
+in float32, which are `highest`'s six less the three that multiply the
+zero terms of a matrix that has ONE, so the same sums to the order of an
+addition. No product of two float32 arrays drops a pass. (2) A level's
+mask keeps the rows of its blocks' later halves alone, so its products run
+over those 64 rows (`_later`) and are put back (`_spread`): whole sublane
+tiles from 8 positions up, and under that through a VMEM scratch by
+strided loads and stores along the sublanes (`_short_rows`). (3) The
+inverse's levels from 8 positions up run over the same rows
+(ops/gated_delta.py::`_inverses`). A pair's products, in tiles of 128^3
+at six passes (tests/test_kda.py counts them off the kernels' jaxprs):
+
+                                              kda_fwd      kda_bwd
+    `_decays`: `_SUMS` x g at three passes       4            4
+    dg: `_SUMS`^T x the cotangents at three      -            4
+    the six levels of `_Pair`                    6            3
+    the levels' cotangents (drows, dcols)        -           12
+    `_inverses`: five levels                     7      read from `solves`
+    B T / B^T dO                                 1            1
+    what reads the state, both halves            5           11
+    a pair                                  23 (was 33)  35 (was 50.5)
 
 THE KERNELS work on PAIRS of chunks, as ops/gated_delta.py's do: what of a
 chunk reads no state (the sums, the levels' products, A and its inverse by
@@ -92,9 +116,11 @@ columns' four references alone as 128 MiB a layer, three times
 decides, and it fits). Under a policy that saves none of it the forward
 kernel runs once more in the backward.
 
-Everything is float32 with the matmuls at `highest` precision: the decay,
-the solve and the carried state never see bfloat16, and the decay is
-applied position by position and channel by channel. No array is [T, T].
+Everything is float32 and every product of two float32 arrays is at
+`highest` precision: the decay, the solve and the carried state never see
+bfloat16 (the three bfloat16 terms of the sums' operand are all 24 bits of
+the float32 they split), and the decay is applied position by position and
+channel by channel. No array is [T, T].
 
 ONE path, no option: off the TPU the same kernels run under the Pallas
 interpreter. Head sizes that fill no lane tile (the tiny preset's 16, the
@@ -103,17 +129,20 @@ chosen from the shape (128 needs none), and a sequence that is no multiple
 of a block of pairs is padded with positions that write nothing (k = v =
 0, beta = 0, g = 0) and read nothing.
 
-AGAINST THE SCALAR RULE'S KERNELS (ROADMAP D14; my chip runs, PR 61, calls
-1 and 3, profiler traces). The kernels are MXU-bound: a pair's forward is
-about 33 products of 128^3 at six bf16 passes, 3.18 ms a layer at
-[1, 8, 8192, 128] and 4.00 backward, where the jax.numpy form took 22.6 for
-forward, forward again and transpose. At `olmo-hybrid-train`'s shape (30
-heads of 96 x 192 over 4,096 positions, g broadcast over the channels)
-they take 6.36 + 8.90 = 15.3 ms a layer where `gated_delta_fwd` / `_bwd`
-take 4.02 + 4.42 = 8.4: 1.8 x, + 20 ms of that cell's 210 ms step, ten
-times its bound. One decay a head factors out of ONE product as a mask;
-six levels of products a pair are what a decay a channel costs, so the
-scalar module stays and nothing routes `olmo-hybrid-train` through here.
+AGAINST THE SCALAR RULE'S KERNELS (ROADMAP D14; my chip runs, profiler
+traces, the rule alone under `jax.checkpoint` with the names saved). The
+kernels are MXU-bound: a pair's forward was 33 products of 128^3 at six
+bf16 passes, 3.18 ms a layer at [1, 8, 8192, 128] and 4.00 backward (PR 61,
+calls 1 and 3; the jax.numpy form took 22.6 for forward, forward again and
+transpose), and is 23 since PR 65: 2.37 + 3.01 at 8 heads, 9.49 + 12.04 at
+[1, 32, 8192, 128] where 12.71 + 16.00 stood (PR 65, calls 1 and 2). At
+`olmo-hybrid-train`'s shape (30 heads of 96 x 192 over 4,096 positions, g
+broadcast over the channels) PR 61 read 6.36 + 8.90 = 15.3 ms a layer
+where `gated_delta_fwd` / `_bwd` took 4.02 + 4.42 = 8.4 (3.29 + 4.42 since
+PR 65): 1.8 x, + 20 ms of that cell's 210 ms step, ten times its bound.
+One decay a head factors out of ONE product as a mask; six levels of
+products a pair are what a decay a channel costs, so the scalar module
+stays and nothing routes `olmo-hybrid-train` through here.
 """
 
 from __future__ import annotations
@@ -128,9 +157,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu import obs
-from ray_tpu.ops.gated_delta import (_NN, _NT, _TN, _PAIR, _SEQUENTIAL, _col, _dot, _half,
-                                     _in_pair, _indices, _inverses, _packed, _row, _stack,
+from ray_tpu.ops.gated_delta import (_NN, _NT, _TN, _PAIR, _SEQUENTIAL, _SUBLANES, _col, _dot,
+                                     _half, _in_pair, _indices, _inverses, _packed, _row, _stack,
                                      _unpacked)
+from ray_tpu.ops.gated_delta import _later as _later_tiles, _spread as _spread_tiles
 
 # fla's: 64 positions. 8,192 tokens are 128 chunks, 64 pairs; a chunk's solve is 64 x 64
 CHUNK = 64
@@ -138,12 +168,12 @@ CHUNK = 64
 # later half against its earlier half, the decays relative to the later half's first row
 _HALVES = (32, 16, 8, 4, 2, 1)
 # pairs a grid step walks: 2, as ops/gated_delta.py's `_BLOCK` (which has what a longer unrolled
-# body costs): forward + backward 3.37 + 4.40 ms a layer at [1, 8, 8192, 128] against 3.99 + 4.56
-# at 1 pair a step (my chip run, PR 61, call 1, profiler trace; both with every level's
-# products over all rows: `_later` took them to 3.18 + 4.00, call 3)
+# body costs): forward + backward 2.37 + 3.01 ms a layer at [1, 8, 8192, 128] against 3.10 + 3.17
+# at 1 pair a step; 4 pairs read 2.17 + 2.98 for three times the seconds to compile a kernel
+# (my chip run, PR 65, call 3, profiler trace)
 _BLOCK = 2
-_LANES, _SUBLANES = 128, 8
-_F32 = jnp.float32
+_LANES = 128
+_F32, _BF16 = jnp.float32, jnp.bfloat16
 _LOG_CHUNK = CHUNK.bit_length() - 1
 
 
@@ -153,18 +183,33 @@ def _sum_matrices() -> np.ndarray:
     included), c_C - c (from after i to the chunk's end) and, a level of
     `_HALVES`, |c_i - c_r| with r the first row of the later half of i's
     block of 2h: g summed over (r, i] for i in the later half and over
-    (i, r] in the earlier."""
+    (i, r] in the earlier. bfloat16 holds 0 and 1 exactly."""
     i, t = np.indices((_PAIR, _PAIR))
     same = (i // CHUNK) == (t // CHUNK)
     mats = [same & (t <= i), same & (t > i)]
     for h in _HALVES:
         r = i // (2 * h) * (2 * h) + h
         mats.append(np.where(i >= r, (t > r) & (t <= i), (t > i) & (t <= r)))
-    return np.concatenate(mats).astype(np.float32)
+    return np.concatenate(mats).astype(_BF16)
 
 
 _SUMS = _sum_matrices()
 _N_SUMS = _SUMS.shape[0] // _PAIR
+
+
+def _sum_dot(sums, x):
+    """sums x with sums of 0 / 1 in bfloat16 and x float32, to float32's
+    last bit: x is the sum of three bfloat16 terms (8 bits each, all 24 of
+    float32's), sums has ONE term, so three passes of the MXU accumulated
+    in float32 are the six that `highest` makes of two float32 arrays, less
+    the three that multiply zeros."""
+    total = None
+    for _ in range(3):
+        term = x.astype(_BF16)
+        x = x - term.astype(_F32)
+        part = jax.lax.dot_general(sums, term, (_NN, ((), ())), preferred_element_type=_F32)
+        total = part if total is None else total + part
+    return total
 
 
 def _decays(g, sums):
@@ -172,7 +217,7 @@ def _decays(g, sums):
     (e^c, e^{c_C - c}, [X_h for h in `_HALVES`]), each [_PAIR, dk] in
     (0, 1]: an exponent is a sum of g, clamped at 0 against the products'
     rounding."""
-    exponents = _dot(sums, g, _NN)
+    exponents = _sum_dot(sums, g)
     parts = [jnp.exp(jnp.minimum(exponents[n * _PAIR:(n + 1) * _PAIR], 0.0))
              for n in range(_N_SUMS)]
     return parts[0], parts[1], parts[2:]
@@ -184,23 +229,43 @@ def _level_mask(r, c, h):
     return ((r >> lh) == (c >> lh) + 1) & (((r >> lh) & 1) == 1)
 
 
-def _later(x, h):
-    """The rows of a level's later halves, [_PAIR, n] -> [_PAIR / 2, n]: the
-    only rows of the level's products that its mask keeps, and a product
-    costs the MXU by its rows. Whole sublane tiles move (h >= 8); under
-    that a level multiplies every row."""
-    if h < _SUBLANES:
-        return x
-    return jnp.concatenate([x[s:s + h] for s in range(h, x.shape[0], 2 * h)], axis=0)
+def _short_rows(h):
+    """The later halves' rows of a level under a sublane tile's 8
+    positions, as h strided slices of a [_PAIR, n] ref: slice j holds row
+    h + j of every block of 2h."""
+    return [pl.ds(h + j, _PAIR // (2 * h), stride=2 * h) for j in range(h)]
 
 
-def _spread(y, h):
-    """`_later`'s rows back where they stood, zeros in the earlier halves."""
-    if h < _SUBLANES:
-        return y
-    zeros = jnp.zeros((h, y.shape[1]), y.dtype)
-    return jnp.concatenate([part for s in range(0, y.shape[0], h)
-                            for part in (zeros, y[s:s + h])], axis=0)
+def _later(x, h, scr):
+    """The rows of a level's later halves, [_PAIR, n] -> [_PAIR / 2, n]:
+    the only rows of the level's products that its mask keeps, and a
+    product costs the MXU by its rows. From 8 positions up whole sublane
+    tiles move (ops/gated_delta.py's); under that x goes through the VMEM
+    scratch `scr` and comes back by strided loads along the sublanes, slice
+    by slice (`_short_rows`: another order of the same rows, which
+    `_spread` undoes)."""
+    if h >= _SUBLANES:
+        return _later_tiles(x, h)
+    scr[:, :x.shape[1]] = x
+    return jnp.concatenate([scr[rows, :x.shape[1]] for rows in _short_rows(h)], axis=0)
+
+
+def _spread(y, h, scr):
+    """`_later`'s rows back where they stood. From 8 positions up the
+    earlier halves' rows are zeros; under that they are whatever `scr`
+    held, for the level's mask to drop (`_later_rows`)."""
+    if h >= _SUBLANES:
+        return _spread_tiles(y, h)
+    n = y.shape[0] // h
+    for j, rows in enumerate(_short_rows(h)):
+        scr[rows, :y.shape[1]] = y[j * n:(j + 1) * n]
+    return scr[:, :y.shape[1]]
+
+
+def _later_rows(h):
+    """[_PAIR, 1]: i in the later half of its block of 2h."""
+    i = jax.lax.broadcasted_iota(jnp.int32, (_PAIR, 1), 0)
+    return ((i >> (h.bit_length() - 1)) & 1) == 1
 
 
 class _Pair:
@@ -211,7 +276,7 @@ class _Pair:
     its end; X the levels' decays; kb = beta k; B = tril(M(Q)) and, if
     `system`, A [n, n], block-diagonal; T is set by `_pairs`."""
 
-    def __init__(self, q, k, g, b_row, sums, system):
+    def __init__(self, q, k, g, b_row, sums, system, short):
         r, c = _indices(_PAIR)
         same = (r >> _LOG_CHUNK) == (c >> _LOG_CHUNK)
         self.eye = r == c
@@ -224,20 +289,20 @@ class _Pair:
         self.B = jnp.where(self.eye, jnp.sum(q * k, axis=1, keepdims=True), 0.0)
         self.A = jnp.zeros_like(self.B) if system else None
         for h, mask, X in zip(_HALVES, self.masks, self.X):
-            rows = _later(q * X, h)
+            rows = _later(q * X, h, short[0])
             n = rows.shape[0]
-            M = _dot(_stack(rows, _later(self.kb * X, h)) if system else rows, k * X, _NT)
-            self.B = jnp.where(mask, _spread(M[:n], h), self.B)
+            M = _dot(_stack(rows, _later(self.kb * X, h, short[1])) if system else rows, k * X, _NT)
+            self.B = jnp.where(mask, _spread(M[:n], h, short[2]), self.B)
             if system:
-                self.A = jnp.where(mask, _spread(M[n:], h), self.A)
+                self.A = jnp.where(mask, _spread(M[n:], h, short[3]), self.A)
 
 
-def _pairs(q_ref, k_ref, g_ref, b_ref, sums_ref, block, solves_ref=None):
+def _pairs(q_ref, k_ref, g_ref, b_ref, sums_ref, block, short, solves_ref=None):
     """The block's pairs, their inverses made level by level together, or
     read where the forward wrote them."""
     at = lambda p: pl.ds(p * _PAIR, _PAIR)  # noqa: E731
     pairs = [_Pair(q_ref[at(p), :], k_ref[at(p), :], g_ref[at(p), :], b_ref[p], sums_ref[...],
-                   system=solves_ref is None) for p in range(block)]
+                   system=solves_ref is None, short=short) for p in range(block)]
     if solves_ref is None:
         solves = _inverses([m.A for m in pairs])
     else:
@@ -269,8 +334,9 @@ def _staged(refs, scratch, first=()):
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, sums_ref, o_ref, states_ref, solves_ref,
                 s_scr, *scratch, block):
     dv, dk = states_ref.shape[-2:]
+    short, scratch = scratch[:_SHORT_FWD], scratch[_SHORT_FWD:]
     q_src, k_src, g_src, v_src = _staged((q_ref, k_ref, g_ref, v_ref), iter(scratch), (s_scr,))
-    pairs = _pairs(q_src, k_src, g_src, b_ref, sums_ref, block)
+    pairs = _pairs(q_src, k_src, g_src, b_ref, sums_ref, block, short)
     BTs = [_dot(m.B, m.T, _NN) for m in pairs]
     for p, (m, BT) in enumerate(zip(pairs, BTs)):
         solves_ref[p] = _packed(m.T)
@@ -288,12 +354,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, sums_ref, o_ref, states_ref, 
             s_scr[...] = e[CHUNK - 1:] * S + _dot(U_BU[:CHUNK], k * m.d[rows], _TN)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, sums_ref, states_ref, solves_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, s_scr, ds_scr, *scratch, block):
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, sums_ref, sums_t_ref, states_ref, solves_ref,
+                do_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref, s_scr, ds_scr, *scratch, block):
     dv, dk = states_ref.shape[-2:]
+    short, scratch = scratch[:_SHORT_BWD], scratch[_SHORT_BWD:]
     q_src, k_src, g_src, v_src, do_src = _staged((q_ref, k_ref, g_ref, v_ref, do_ref),
                                                  iter(scratch), (s_scr, ds_scr))
-    pairs = _pairs(q_src, k_src, g_src, b_ref, sums_ref, block, solves_ref)
+    pairs = _pairs(q_src, k_src, g_src, b_ref, sums_ref, block, short, solves_ref)
     at_end = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, 1), 0) == CHUNK - 1
     for p in reversed(range(block)):
         m, both = pairs[p], pl.ds(p * _PAIR, _PAIR)
@@ -334,16 +401,19 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, sums_ref, states_ref, solves_
         dsums = [whole["dc"], whole["ds"]]
         for h, mask, X in zip(_HALVES, m.masks, m.X):
             qx, kbx, kx = q * X, m.kb * X, k * X
-            dM = _stack(_later(jnp.where(mask, whole["dB"], 0.0), h),
-                        _later(jnp.where(mask, whole["dA"], 0.0), h))
+            dM = _stack(_later(jnp.where(mask, whole["dB"], 0.0), h, short[0]),
+                        _later(jnp.where(mask, whole["dA"], 0.0), h, short[1]))
             n = dM.shape[0] // 2
-            drows, dcols = _dot(dM, kx, _NN), _dot(dM, _stack(_later(qx, h), _later(kbx, h)), _TN)
-            dqx, dkbx = _spread(drows[:n], h), _spread(drows[n:], h)
+            rows = _stack(_later(qx, h, short[2]), _later(kbx, h, short[3]))
+            drows, dcols = _dot(dM, kx, _NN), _dot(dM, rows, _TN)
+            dqx, dkbx = _spread(drows[:n], h, short[4]), _spread(drows[n:], h, short[5])
+            if h < _SUBLANES:   # what the scratch held in the earlier halves' rows
+                dqx, dkbx = (jnp.where(_later_rows(h), d, 0.0) for d in (dqx, dkbx))
             dq, dkb, dk_ = dq + dqx * X, dkb + dkbx * X, dk_ + dcols * X
             dsums.append(dqx * qx + dkbx * kbx + dcols * kx)
         dq_ref[both, :] = dq[:, :dk]
         dk_ref[both, :] = (dk_ + m.b * dkb)[:, :dk]
-        dg_ref[both, :] = _dot(sums_ref[...], _stack(*dsums), _TN)[:, :dk]
+        dg_ref[both, :] = _sum_dot(sums_t_ref[...], _stack(*dsums))[:, :dk]
         db_ref[p] = _row(whole["dbeta"] + jnp.sum(dkb * k, axis=1, keepdims=True), m.eye)
 
 
@@ -360,18 +430,26 @@ def _specs(block, H, dk, dv, blocks, reverse):
             pl.BlockSpec((None, None, rows, dv), at_head),
             pl.BlockSpec((None, block, 1, _PAIR), at),
             pl.BlockSpec(_SUMS.shape, lambda bh, i: (0, 0)),
+            pl.BlockSpec(_SUMS.shape[::-1], lambda bh, i: (0, 0)),
             pl.BlockSpec((None, 2 * block, dv, dk), at),
             pl.BlockSpec((None, block, CHUNK, _PAIR), at))
 
 
-def _scratch(block, dk, dv, keys, values, states):
+# the scratch the levels under 8 positions move their rows through (`_later`, `_spread`): the
+# forward's two stacked operands and its two products, the backward's four and two
+_SHORT_FWD, _SHORT_BWD = 4, 6
+
+
+def _scratch(block, dk, dv, keys, values, states, short):
     """VMEM scratch of a kernel: `states` matrices [dv, dk] as wide as
-    whole lanes, then a block as wide as whole lanes for each of `keys`
+    whole lanes, `short` pairs' worth of rows for the levels under a
+    sublane tile, then a block as wide as whole lanes for each of `keys`
     arrays as wide as k and `values` as wide as v, where the head size
     fills no lane tile (`_staged`)."""
     wide = lambda d: -(-d // _LANES) * _LANES  # noqa: E731
     rows = block * _PAIR
     return ([pltpu.VMEM((wide(dv), wide(dk)), _F32)] * states
+            + [pltpu.VMEM((_PAIR, max(wide(dk), _PAIR)), _F32)] * short
             + [pltpu.VMEM((rows, wide(dk)), _F32)] * (keys if dk % _LANES else 0)
             + [pltpu.VMEM((rows, wide(dv)), _F32)] * (values if dv % _LANES else 0))
 
@@ -389,7 +467,7 @@ def kda_fwd(q, k, v, g, b, interpret):
     B, H, T, dk = q.shape
     dv, P = v.shape[-1], b.shape[1]
     block = min(_BLOCK, P)
-    qk, vo, beta, sums, states, solves = _specs(block, H, dk, dv, P // block, reverse=False)
+    qk, vo, beta, sums, _, states, solves = _specs(block, H, dk, dv, P // block, reverse=False)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, block=block),
         grid=(B * H, P // block),
@@ -398,7 +476,7 @@ def kda_fwd(q, k, v, g, b, interpret):
         out_shape=[jax.ShapeDtypeStruct(v.shape, _F32),
                    jax.ShapeDtypeStruct((B * H, 2 * P, dv, dk), _F32),
                    jax.ShapeDtypeStruct((B * H, P, CHUNK, _PAIR), _F32)],
-        scratch_shapes=_scratch(block, dk, dv, keys=3, values=1, states=1),
+        scratch_shapes=_scratch(block, dk, dv, keys=3, values=1, states=1, short=_SHORT_FWD),
         compiler_params=_SEQUENTIAL,
         interpret=interpret,
     )(q, k, v, g, b, _SUMS)
@@ -409,17 +487,17 @@ def kda_bwd(q, k, v, g, b, states, solves, do, interpret):
     B, H, T, dk = q.shape
     dv, P = v.shape[-1], b.shape[1]
     block = min(_BLOCK, P)
-    qk, vo, beta, sums, st, sv = _specs(block, H, dk, dv, P // block, reverse=True)
+    qk, vo, beta, sums, sums_t, st, sv = _specs(block, H, dk, dv, P // block, reverse=True)
     return pl.pallas_call(
         functools.partial(_bwd_kernel, block=block),
         grid=(B * H, P // block),
-        in_specs=[qk, qk, vo, qk, beta, sums, st, sv, vo],
+        in_specs=[qk, qk, vo, qk, beta, sums, sums_t, st, sv, vo],
         out_specs=[qk, qk, vo, qk, beta],
         out_shape=[jax.ShapeDtypeStruct(a.shape, _F32) for a in (q, k, v, g, b)],
-        scratch_shapes=_scratch(block, dk, dv, keys=3, values=2, states=2),
+        scratch_shapes=_scratch(block, dk, dv, keys=3, values=2, states=2, short=_SHORT_BWD),
         compiler_params=_SEQUENTIAL,
         interpret=interpret,
-    )(q, k, v, g, b, _SUMS, states, solves, do)
+    )(q, k, v, g, b, _SUMS, _SUMS.T, states, solves, do)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))

@@ -164,6 +164,56 @@ def test_what_the_forward_hands_the_backward_is_the_states_and_the_inverses_with
     assert call.invars[:3] == jaxpr.invars[:3]                  # the caller's arrays themselves
 
 
+def _inverses_over_all_rows(A):
+    """The block doubling as it stood before PR 65: T <- T - T (A_off T), every row multiplied."""
+    r, c = gd._indices(gd._PAIR)
+    T = jnp.where(r == c, 1.0, 0.0) - jnp.where((r >> 1) == (c >> 1), A, 0.0)
+    for level in range(1, gd._LOG_CHUNK):
+        joins = ((r >> (level + 1)) == (c >> (level + 1))) & ((r >> level) != (c >> level))
+        T = T - gd._dot(T, gd._dot(jnp.where(joins, A, 0.0), T, gd._NN), gd._NN)
+    return T
+
+
+@pytest.mark.parametrize("scale", [0.05, 0.5], ids=["weak", "strong"])
+def test_the_inverse_over_the_later_halves_rows_is_the_inverse(scale):
+    """`_inverses` multiplies, at the levels of 8, 16 and 32 positions, the
+    later halves' rows alone (`_later` / `_spread`): of a random strictly
+    lower-triangular A, block-diagonal in chunks of 64, it gives
+    (I + A)^-1 as close to numpy's float64 inverse as the form over all
+    rows does, and the same matrix as that form to the rounding of a sum,
+    with exact zeros off the diagonal blocks."""
+    n = gd._PAIR
+    r, c = np.indices((n, n))
+    keep = (r // gd.CHUNK == c // gd.CHUNK) & (r > c)
+    systems = [jnp.asarray(np.where(keep, scale * np.random.default_rng(seed).standard_normal(
+        (n, n)), 0.0), jnp.float32) for seed in (0, 1)]
+    got = jax.jit(gd._inverses)(systems)
+    for A, T in zip(systems, got):
+        want = np.linalg.inv(np.eye(n) + np.asarray(A, np.float64))
+        before = np.asarray(jax.jit(_inverses_over_all_rows)(A), np.float64)
+        T = np.asarray(T, np.float64)
+        size = np.abs(want).max()
+        assert np.abs(T - want).max() <= max(1.5 * np.abs(before - want).max(), 1e-6 * size)
+        assert np.abs(T - before).max() <= 1e-5 * size
+        np.testing.assert_array_equal(T[r // gd.CHUNK != c // gd.CHUNK], 0.0)
+        np.testing.assert_array_equal(T[r < c], 0.0)
+
+
+def test_later_and_spread_are_each_others_inverse_on_the_later_halves():
+    """From 8 positions up `_later` takes the later half of every block of
+    2h, in order, and `_spread` puts the rows back with zeros between;
+    under a sublane tile both pass their argument whole."""
+    x = jnp.arange(gd._PAIR * 3, dtype=jnp.float32).reshape(gd._PAIR, 3)
+    i = np.arange(gd._PAIR)
+    for h in (32, 16, 8):
+        later = (i // h) % 2 == 1
+        np.testing.assert_array_equal(np.asarray(gd._later(x, h)), np.asarray(x)[later])
+        np.testing.assert_array_equal(np.asarray(gd._spread(gd._later(x, h), h)),
+                                      np.where(later[:, None], np.asarray(x), 0.0))
+    for h in (4, 2, 1):
+        assert gd._later(x, h) is x and gd._spread(x, h) is x
+
+
 def test_no_decay_and_full_writes_are_the_plain_delta_rule():
     """g = 0 and beta = 1: S_t = S_{t-1} + k_t (v_t - S_{t-1}^T k_t)^T, which
     with unit keys stores v_t exactly under k_t."""

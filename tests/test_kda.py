@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.extend.core import Literal
 
 from ray_tpu.ops import gated_delta as gd
@@ -202,6 +204,119 @@ def test_every_exponent_formed_is_of_a_number_that_is_not_positive():
     gradient = jax.make_jaxpr(jax.grad(lambda *a: (kda.kda_rule(*a) * w).sum(),
                                        argnums=(0, 1, 2, 3, 4)))(*args).jaxpr
     assert _exps_in(gradient) == 2 * block * formed
+
+
+def _products(jaxpr, found):
+    """Every `dot_general` of a jaxpr, sub-jaxprs walked (a `pallas_call`'s
+    kernel among them), as (rows, contraction, columns, bf16 passes): six
+    for two float32 arrays at `highest`, one for two bfloat16 arrays."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            a, b = (v.aval for v in eqn.invars)
+            (contract, _), _ = eqn.params["dimension_numbers"]
+            K = int(np.prod([a.shape[i] for i in contract]))
+            kinds = {(jnp.dtype(jnp.float32), HI): 6, (jnp.dtype(jnp.bfloat16), None): 1}
+            precision = eqn.params["precision"]
+            precision = precision[0] if isinstance(precision, tuple) else precision
+            assert a.dtype == b.dtype and (a.dtype, precision) in kinds, (a, b, precision)
+            found.append((a.size // K, K, b.size // K, kinds[a.dtype, precision]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _products(sub, found)
+    return found
+
+
+def _kernel_jaxpr(kernel):
+    """The jaxpr of `kda_fwd` / `kda_bwd` at heads of 128 over ONE grid step (`_BLOCK` pairs)."""
+    T = kda._BLOCK * 128
+    x = jnp.zeros((1, 1, T, 128))
+    b = jnp.zeros((1, kda._BLOCK, 1, 128))
+    if kernel == "kda_fwd":
+        return jax.make_jaxpr(functools.partial(kda.kda_fwd, interpret=True))(x, x, x, x, b)
+    states, solves = jnp.zeros((1, 2 * kda._BLOCK, 128, 128)), jnp.zeros((1, kda._BLOCK, 64, 128))
+    return jax.make_jaxpr(functools.partial(kda.kda_bwd, interpret=True))(
+        x, x, x, x, b, states, solves, x)
+
+
+@pytest.mark.parametrize("kernel,limit", [("kda_fwd", 144), ("kda_bwd", 216)])
+def test_a_pairs_products_multiply_no_row_and_no_pass_known_to_be_zero(kernel, limit):
+    """What a pair of chunks costs the MXU, read off the kernel's own jaxpr:
+    every `dot_general`'s rows x contraction x columns in tiles of 128^3,
+    times its bfloat16 passes. PR 65 took the forward from 198 passes to
+    138 and the backward from 303 to 210 (the 0 / 1 sums at three passes
+    where `highest` spends six, the inverse's levels from 8 positions up
+    and every level of halves over the later halves' rows alone); a change
+    that puts rows or passes back fails here with the table."""
+    found = _products(_kernel_jaxpr(kernel).jaxpr, [])
+    table = {}
+    for product in found:
+        table[product] = table.get(product, 0) + 1
+    passes = sum(m * k * n * p for m, k, n, p in found) / 128 ** 3 / kda._BLOCK
+    lines = "\n".join(f"{count:3d} x [{m}, {k}] x [{k}, {n}] at {p} passes"
+                      for (m, k, n, p), count in sorted(table.items()))
+    assert passes <= limit, f"{kernel}: {passes} bf16 passes a pair over {limit}\n{lines}"
+    # three of a sum's products are one float32 product of the 0 / 1 matrix: never at `highest`
+    assert not any(m == kda._SUMS.shape[0] and p == 6 for m, k, n, p in found), lines
+
+
+def test_the_sums_three_bfloat16_terms_are_highests_exponents():
+    """`_sum_dot`: `_SUMS` in bfloat16 (0 and 1 are exact) times g as its
+    three bfloat16 terms, which ARE g (hi + mid + lo bit for bit),
+    accumulated in float32. On a pair with g = -30 on every third channel
+    the exponents are within 1 ulp of the float64 sums everywhere (a whole
+    multiple of 30, exactly, on the fast channels) and of what `highest`
+    makes of two float32 arrays wherever that is itself within 1 ulp of
+    them (a float32 sum of 64 terms rounds 63 times; three sums of 8-bit
+    terms round twice), and never further from the float64 sums than it."""
+    (_, _, _, g, _), _ = inputs(128, seed=5, shape=(1, 1, 128, 128))
+    g = strong((0, 0, 0, g, 0))[3][0, 0]
+    hi = g.astype(jnp.bfloat16)
+    mid = (g - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    lo = (g - hi.astype(jnp.float32) - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))  # noqa: E731
+    np.testing.assert_array_equal((f32(hi) + f32(mid)) + f32(lo), np.asarray(g))
+    assert kda._SUMS.dtype == jnp.bfloat16
+    sums = np.asarray(kda._SUMS, np.float64)
+    assert set(np.unique(sums)) == {0.0, 1.0}
+    got = np.asarray(jax.jit(kda._sum_dot)(jnp.asarray(kda._SUMS), g), np.float64)
+    highest = np.asarray(jax.lax.dot_general(
+        jnp.asarray(kda._SUMS, jnp.float32), g, (((1,), (0,)), ((), ())), precision=HI), np.float64)
+    exact = sums @ np.asarray(g, np.float64)
+    ulp = np.spacing(np.abs(exact).astype(np.float32)).astype(np.float64)
+    assert (np.abs(got - exact) <= ulp).all()
+    fast = np.arange(128) % 3 == 0
+    np.testing.assert_array_equal(got[:, fast], exact[:, fast])
+    np.testing.assert_array_equal(got[:, fast], highest[:, fast])
+    near = np.abs(highest - exact) <= ulp
+    assert near.mean() > 0.9 and (np.abs(got - highest)[near] <= 2 * ulp[near]).all()
+    assert np.abs(got - exact).max() <= np.abs(highest - exact).max()
+
+
+@pytest.mark.parametrize("h", [4, 2, 1])
+def test_a_short_levels_strided_rows_and_their_write_are_each_others_inverse(h):
+    """Under a sublane tile's 8 positions `_later` reads the later halves'
+    rows of every block of 2h through a VMEM scratch by h strided loads
+    (`_short_rows`: slice j is row h + j of every block) and `_spread`
+    writes them back through the same slices: under the interpreter the 64
+    rows are exactly the later halves', each once, and the way back puts
+    each where it stood and touches no earlier half's row."""
+    x = jnp.arange(128 * 128, dtype=jnp.float32).reshape(128, 128) + 1.0
+
+    def kernel(x_ref, rows_ref, back_ref, scr, out_scr):
+        out_scr[...] = jnp.full_like(out_scr, -1.0)
+        rows = kda._later(x_ref[...], h, scr)
+        rows_ref[...] = rows
+        back_ref[...] = kda._spread(rows, h, out_scr)
+
+    rows, back = pl.pallas_call(
+        kernel, out_shape=[jax.ShapeDtypeStruct((64, 128), jnp.float32),
+                           jax.ShapeDtypeStruct((128, 128), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((128, 128), jnp.float32)] * 2, interpret=True)(x)
+    i = np.arange(128)
+    later = (i // h) % 2 == 1
+    assert sorted(np.asarray(rows)[:, 0]) == sorted(np.asarray(x)[later, 0])
+    np.testing.assert_array_equal(np.asarray(rows)[:128 // (2 * h)], np.asarray(x)[h::2 * h])
+    np.testing.assert_array_equal(np.asarray(back), np.where(later[:, None], np.asarray(x), -1.0))
+    np.testing.assert_array_equal(np.asarray(kda._later_rows(h))[:, 0], later)
 
 
 def test_with_one_decay_a_head_it_is_the_gated_delta_rule():

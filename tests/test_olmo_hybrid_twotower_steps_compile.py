@@ -28,6 +28,9 @@ OLMO_HYBRID = dict(batch=1, model="olmo-hybrid-7b", n_layers=4, vocab_size=12544
 # lowers it: the rule as two `pallas_call`s a layer (PR 47) and the convolution, SiLU and L2
 # norms before it as two a tensor (the account of every hash is
 # tests/test_m7b_steps_compile.py's; the kernels' own bodies are not in it)
+# PR 65 changed `gated_delta_fwd`'s BODY alone (`_inverses` multiplies the later halves' rows
+# from blocks of 8 positions up; the same bits out): its operands and shapes, which is what the
+# lowered text holds of a kernel, are the parent's, so the hash stands unedited
 _OLMO_HYBRID_STEP = "68b139dadb3f7426e556122e7adf2d0c859bcfe78b76edb72e3d609a81dcf6f8"
 TWOTOWER = dict(batch=1, model="nemotron-twotower-30b-a3b", n_layers=9, seq=8192,
                 vocab_size=16384, experts_held=8)

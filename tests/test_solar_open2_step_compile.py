@@ -42,7 +42,11 @@ def test_solar_open2_train_step_fits_the_chip_with_what_the_rules_forward_hands_
 # tree (the parent of PR 64) lowers it: PR 64 gave the KDA sublayer an option (`kda_neg_eigval`:
 # beta doubled or not) and ops/flash.py a value width of its own, and neither is work in this
 # step. A change that MEANS to move the step replaces the hash and says what moved.
-_SOLAR_OPEN2_STEP = "b5b5635a4bbab467cfe530607b600fe26d3f6e664ab2cf2d676a686e5a949511"
+# Replaced ON PURPOSE by PR 65: ops/kda.py's kernels take the constant 0 / 1 matrix of the sums as
+# bfloat16 [1024, 128] where float32 stood, and `kda_bwd` its transpose [128, 1024] as one more
+# operand (three bf16 passes a sum where `highest` spent six); the kernels' own bodies are not
+# in the hash (b5b5635a... from PR 63)
+_SOLAR_OPEN2_STEP = "c95f2a04b352a7a9c75ba96d8971c09cf980f7374f8f989c2a7d75a8c26c9f6c"
 
 
 def test_solar_open2_lowered_step_is_text_for_text_the_parents(v5e):
