@@ -232,6 +232,13 @@ class EngineConfig:
             self.model = get_model_config(self.model)
         from ray_tpu.models.moe import MoEConfig
 
+        if hasattr(self.model, "residual_multiplier") and hasattr(self.model, "mamba_heads"):
+            raise ValueError(
+                "LLMEngine serves llama-family models with a key-value cache; Granite 4.0-H's "
+                "Mamba-2 layers carry a state-space state and a convolution's last taps a "
+                "sequence (models/granite_hybrid.py), a second kind of state beside the pages, "
+                "which no cache manager here holds (continuous batching over that state is "
+                "not built): it is training-only")
         if hasattr(self.model, "mamba_heads"):
             raise ValueError(
                 "LLMEngine serves llama-family models with a key-value cache; Nemotron-H's "

@@ -578,6 +578,15 @@ def output_weight(params: Params) -> jax.Array:
     return w_out
 
 
+def _head_input(h: jax.Array, config: LlamaConfig) -> jax.Array:
+    """The normed last hidden state as the head reads it: divided by the
+    configuration's `logits_scaling` where it has one (models/granite_hybrid.py:
+    logits = h W / 8), here and not on the logits, which the fused loss
+    never stores; any other configuration's h as it stands."""
+    scaling = getattr(config, "logits_scaling", 1.0)
+    return h if scaling == 1.0 else h * (1.0 / scaling)
+
+
 def forward(
     params: Params,
     tokens: jax.Array,  # [B, S] int32
@@ -591,7 +600,7 @@ def forward(
         params, tokens, config, positions=positions, segment_ids=segment_ids
     )
     w_out = output_weight(params)
-    return jnp.einsum("bsd,dv->bsv", h, w_out.astype(config.dtype))
+    return jnp.einsum("bsd,dv->bsv", _head_input(h, config), w_out.astype(config.dtype))
 
 
 def loss_fn(
@@ -630,7 +639,7 @@ def loss_and_weight_fn(
         params, batch["tokens"], config, segment_ids=batch.get("segment_ids")
     )
     with jax.named_scope("head"):
-        h = rms_norm(h_last, params["final_norm"], config.rms_eps)
+        h = _head_input(rms_norm(h_last, params["final_norm"], config.rms_eps), config)
         loss, weight = fused_cross_entropy_loss(
             h, output_weight(params), batch["targets"], batch.get("mask")
         )

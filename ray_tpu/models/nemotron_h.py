@@ -95,9 +95,17 @@ is bidirectional inside a block, conditioning on this tower) and its
 diffusion objective, of which the published config gives no size and no
 equation: this module is the causal tower, trained under next-token
 cross-entropy; `-` (dense MLP) layers of the family's other models;
-packed documents (`segment_ids`) under a Mamba layer (a state reset and
-a convolution that stops at a boundary); serving (the engine refuses the
-model by name: a state-space state beside the pages).
+serving (the engine refuses the model by name: a state-space state
+beside the pages).
+
+PACKED DOCUMENTS (`segment_ids` [B, S]; PR 66, for models/granite_hybrid.py,
+which runs this module's `mamba_sublayer` and `attention_sublayer` as they
+stand): a Mamba layer hands the ids to its convolution (ops/gdn_conv.py: tap
+j of position t reads t - j only inside t's document) and to its scan
+(ops/ssd.py: the state a position reads holds nothing of an earlier
+document, H_{t-1} taken as 0 where the document changes), an attention
+layer to the flash kernels' mask; an expert layer reads no other position.
+Without ids every sublayer is traced as it was.
 """
 
 from __future__ import annotations
@@ -365,11 +373,9 @@ def mamba_sublayer(u: jax.Array, lp: Params, c: NemotronHConfig, *,
     `ssm.scan` (one `ssd_scan_fwd` / `ssd_scan_bwd` kernel and the [B,
     heads, S] arithmetic of dt A and its gradients), `ssm.norm` (one
     `gated_norm_fwd` / `gated_norm_bwd` kernel and the sum of the weight's
-    gradient over 8 sublanes), `ssm.out`."""
-    if segment_ids is not None:
-        raise NotImplementedError(
-            "segment_ids (packed documents) under a Mamba layer: a state reset and a "
-            "convolution that stops at a document's boundary are not implemented")
+    gradient over 8 sublanes), `ssm.out`. With `segment_ids` [B, S] (packed
+    documents) the convolution and the scan stop at a document's boundary;
+    the projections, the gates and the norm read one position each."""
     D = u.shape[2]
     P, G, N, dt_ = c.mamba_head_dim, c.ssm_groups, c.ssm_state, u.dtype
     inner, wide = c.mamba_inner, c.conv_channels
@@ -387,7 +393,8 @@ def mamba_sublayer(u: jax.Array, lp: Params, c: NemotronHConfig, *,
             # float32 out of the matmul: the step's logits are not rounded to the compute type
             dt = jnp.einsum("bsd,dh->bhs", u.astype(_F32), w_in[:, inner + wide:].astype(_F32))
         with jax.named_scope("ssm.conv"):
-            xBC = gdn_conv(xBC, lp["conv"], bias=lp["conv_bias"])       # [B, wide / 128, S, 128]
+            xBC = gdn_conv(xBC, lp["conv"], bias=lp["conv_bias"],
+                           segment_ids=segment_ids)                 # [B, wide / 128, S, 128]
         with jax.named_scope("ssm.gates"):
             dt = jax.nn.softplus(dt + lp["dt_bias"].astype(_F32)[:, None])
             A = -jnp.exp(lp["A_log"].astype(_F32))
@@ -395,7 +402,7 @@ def mamba_sublayer(u: jax.Array, lp: Params, c: NemotronHConfig, *,
             # the convolution's heads of 128 as lane blocks of N (ops/ssd.py's layout: x's
             # blocks, then B's, then C's): at a state of 128 the array as it stands
             y = ssd_scan_lanes(_lane_blocks(xBC, N), dt, A, lp["D"], head_dim=P,
-                               chunk=c.chunk_size)                  # [B, S, inner] float32
+                               chunk=c.chunk_size, segment_ids=segment_ids)   # [B, S, inner] f32
         with jax.named_scope("ssm.norm"):
             y = gated_norm(y, z, lp["norm"], groups=G, eps=c.rms_eps)   # [B, S, inner] as z
         with jax.named_scope("ssm.out"):

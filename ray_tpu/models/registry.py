@@ -67,14 +67,20 @@ and without a query latent, keys of 192 beside values of 128
 (models/kimi_linear.py over models/solar_open2.py's KDA sublayer,
 models/mla.py's MLA sublayer and ops/flash.py at a value width of its own),
 a leading dense layer of 9216, then 256 sigmoid-routed experts of 1024
-(top-8, x 2.446) and a shared one; the stack ends inside a period.
+(top-8, x 2.446) and a shared one; the stack ends inside a period; and for
+Granite 4.0-H Micro, `granite-4.0-h-micro`, trained, not served: nine
+Mamba-2 mixers (64 heads of 64 in ONE group, a state of 128) to one GQA
+32 / 8 layer without a rotary at scale 1 / 64, each over a dense SwiGLU of
+8192, four muP multipliers and a tied table (models/granite_hybrid.py over
+models/nemotron_h.py's two sublayers), trained on packed documents
+(`segment_ids` through ops/ssd.py, ops/gdn_conv.py and ops/flash.py).
 The registry gives users the same two entry points they expect:
 
   * `get_model_config("llama3-8b")` — named presets;
   * `config_from_hf(json.load(open("config.json")))` — map a HF
     transformers config dict onto LlamaConfig/MoEConfig/ZayaConfig/
     GlmLiteConfig/LagunaConfig/KeyeConfig/OlmoHybridConfig/NemotronHConfig/
-    SolarOpen2Config/KimiLinearConfig (no downloads;
+    SolarOpen2Config/KimiLinearConfig/GraniteHybridConfig (no downloads;
     weight conversion is a separate concern).
 """
 
@@ -98,7 +104,9 @@ _ON_DEMAND = {"keye-vl-2.0-30b-a3b": ("ray_tpu.models.dsa", "KEYE_VL_2_30B_A3B")
               "solar-open2-250b": ("ray_tpu.models.solar_open2", "SOLAR_OPEN2_250B"),
               "solar-open2-tiny": ("ray_tpu.models.solar_open2", "SOLAR_OPEN2_TINY"),
               "kimi-linear-48b-a3b": ("ray_tpu.models.kimi_linear", "KIMI_LINEAR_48B_A3B"),
-              "kimi-linear-tiny": ("ray_tpu.models.kimi_linear", "KIMI_LINEAR_TINY")}
+              "kimi-linear-tiny": ("ray_tpu.models.kimi_linear", "KIMI_LINEAR_TINY"),
+              "granite-4.0-h-micro": ("ray_tpu.models.granite_hybrid", "GRANITE_4_H_MICRO"),
+              "granite-hybrid-tiny": ("ray_tpu.models.granite_hybrid", "GRANITE_HYBRID_TINY")}
 
 
 def register_model(name: str, config) -> None:
@@ -699,12 +707,65 @@ def _kimi_linear_from_hf(hf: dict, **overrides):
     return config
 
 
+def _granite_hybrid_from_hf(hf: dict, **overrides):
+    """`model_type` "granitemoehybrid" (ibm-granite/granite-4.0-h-micro):
+    Mamba-2 mixers (B and C in `mamba_n_groups` groups) and GQA layers
+    without a rotary by `layer_types`, each over a dense SwiGLU
+    (`shared_intermediate_size`), four muP multipliers, a tied table. What
+    models/granite_hybrid.py does not implement is refused by name: the
+    family's members with routed experts among it."""
+    from ray_tpu.models import granite_hybrid as gh
+
+    heads, groups = hf["mamba_n_heads"], hf["mamba_n_groups"]
+    refused = {
+        f"num_local_experts {hf.get('num_local_experts')} (routed experts)":
+            bool(hf.get("num_local_experts")) or bool(hf.get("num_experts_per_tok")),
+        f"position_embedding_type {hf.get('position_embedding_type')!r} (a rotary)":
+            hf.get("position_embedding_type") != "nope",
+        f"mamba_n_groups {groups}, which does not divide the {heads} heads":
+            groups < 1 or heads % groups != 0,
+        "a bias (attention_bias or mamba_proj_bias)":
+            bool(hf.get("attention_bias")) or bool(hf.get("mamba_proj_bias")),
+        "a convolution without a bias": not hf.get("mamba_conv_bias", True),
+        f"layer types other than mamba and attention ({sorted(set(hf['layer_types']))})":
+            bool(set(hf["layer_types"]) - set(gh.KINDS)),
+        "layer_types shorter than num_hidden_layers":
+            len(hf["layer_types"]) < hf["num_hidden_layers"],
+        f"hidden_act {hf.get('hidden_act')!r}": hf.get("hidden_act") != "silu",
+        f"normalization_function {hf.get('normalization_function')!r}":
+            hf.get("normalization_function", "rmsnorm") != "rmsnorm",
+        "rope_scaling": hf.get("rope_scaling") is not None,
+        f"mamba_expand {hf.get('mamba_expand')}, which is not heads x head width / hidden":
+            hf["mamba_expand"] * hf["hidden_size"] != heads * hf["mamba_d_head"],
+    }
+    if any(refused.values()):
+        raise ValueError("a granitemoehybrid config with "
+                         + ", ".join(k for k, v in refused.items() if v) + " is not supported")
+    fields = dict(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"], n_layers=hf["num_hidden_layers"],
+        n_heads=hf["num_attention_heads"], n_kv_heads=hf["num_key_value_heads"],
+        d_ff=hf["shared_intermediate_size"], max_seq=hf["max_position_embeddings"],
+        rope_theta=float(hf.get("rope_theta", 10000.0)), rms_eps=float(hf["rms_norm_eps"]),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", True)),
+        published_types=tuple(hf["layer_types"]), published_layers=hf["num_hidden_layers"],
+        mamba_heads=heads, mamba_head_dim=hf["mamba_d_head"], ssm_groups=groups,
+        ssm_state=hf["mamba_d_state"], conv_kernel=hf["mamba_d_conv"],
+        embedding_multiplier=float(hf["embedding_multiplier"]),
+        residual_multiplier=float(hf["residual_multiplier"]),
+        attention_multiplier=float(hf["attention_multiplier"]),
+        logits_scaling=float(hf["logits_scaling"]),
+    )
+    fields.update(overrides)  # caller wins on collisions
+    return dataclasses.replace(gh.GRANITE_4_H_MICRO, **fields)
+
+
 # `model_type` -> the family's mapping (any other: the llama / mixtral / OLMoE one below)
 _FROM_HF = {
     "zaya": _zaya_from_hf, "glm4_moe_lite": _glm_lite_from_hf, "laguna": _laguna_from_hf,
     "mellum": _mellum_from_hf, "sdar_moe": _sdar_from_hf, "KeyeVL2": _keye_from_hf,
     "olmo_hybrid": _olmo_hybrid_from_hf, "nemotron_h": _nemotron_h_from_hf,
     "solar_open2": _solar_open2_from_hf, "kimi_linear": _kimi_linear_from_hf,
+    "granitemoehybrid": _granite_hybrid_from_hf,
 }
 
 
@@ -737,7 +798,10 @@ def config_from_hf(hf: dict, **overrides):
     beside gated GQA ones over sigmoid-routed experts): see
     `_solar_open2_from_hf`. Kimi-Linear (`model_type` "kimi_linear": KDA layers
     beside latent-attention layers without a rotary, a dense layer, experts):
-    see `_kimi_linear_from_hf`. For every OTHER family a
+    see `_kimi_linear_from_hf`. Granite 4.0-H (`model_type` "granitemoehybrid":
+    Mamba-2 mixers in one group and GQA without a rotary, each over a dense
+    SwiGLU, muP multipliers, a tied table): see `_granite_hybrid_from_hf`. For
+    every OTHER family a
     `rope_scaling` and an explicit `head_dim` that is not hidden_size /
     heads stay refused.
     """

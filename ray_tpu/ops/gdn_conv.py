@@ -52,6 +52,19 @@ row's gradient where a tap's sums dpre x_{t-j} (its x is 1). Without a
 bias both kernels are traced as they were (the lowered text of a model
 that has none is unchanged).
 
+PACKED DOCUMENTS (`segment_ids`; a Mamba-2 mixer trained on documents
+packed into one sequence, models/granite_hybrid.py): tap j of position t
+reads position t - j only where both lie in one document. What a row
+needs is ONE number, its distance from its document's first position
+(`_since`, no more than K - 1), which both kernels read as a third
+array [B, T, d] in x's dtype, a sequence's heads sharing it (in bfloat16 a
+quarter of what a step writes): the forward zeroes a tap's load where
+the distance is under the tap's number; the backward does so making the
+pre-activation again, and gives position t's dx tap j's term only
+where t + j is that far inside its document (the distances of the rows
+after a block are carried in VMEM beside dpre's). Without `segment_ids`
+both kernels are traced as they were.
+
 ONE path, no option: off the TPU the same kernels run under the Pallas
 interpreter, as ops/gated_delta.py's do. Any d, any number of taps up to
 9 (the rows kept beside a block are 8), any T (a sequence that is no
@@ -102,10 +115,15 @@ def _stage(x_scr, x_ref, before_ref, at_start):
     x_scr[:_HALO, :] = jnp.where(at_start, 0.0, before)
 
 
-def _taps_back(x_scr, at, K, sub):
+def _taps_back(x_scr, at, K, sub, since=None):
     """[x_{t-j} for each tap j], each [sub, d], of the `sub` rows at block
-    row `at`: a load a tap, j rows back."""
-    return [x_scr[at + _HALO - j:at + _HALO - j + sub, :] for j in range(K)]
+    row `at`: a load a tap, j rows back; with `since` [sub, d] (a row's
+    positions since its document's first), zeros where t - j lies in the
+    document before."""
+    back = [x_scr[at + _HALO - j:at + _HALO - j + sub, :] for j in range(K)]
+    if since is None:
+        return back
+    return back[:1] + [jnp.where(since >= j, back[j], 0.0) for j in range(1, K)]
 
 
 def _weighted(shifted, taps):
@@ -121,12 +139,14 @@ def _split_bias(taps, bias):
     return (taps[:-1], taps[-1:]) if bias else (taps, None)
 
 
-def _fwd_kernel(x_ref, before_ref, taps_ref, y_ref, x_scr, *, scale, sub, bias=False):
+def _fwd_kernel(x_ref, before_ref, taps_ref, *rest, scale, sub, bias=False, docs=False):
+    since_ref, y_ref, x_scr = rest if docs else (None, *rest)
     rows = y_ref.shape[0]
     _stage(x_scr, x_ref, before_ref, pl.program_id(1) == 0)
     taps, b = _split_bias(taps_ref[...], bias)
     for at in range(0, rows, sub):
-        pre = _weighted(_taps_back(x_scr, at, taps.shape[0], sub), taps)
+        since = since_ref[at:at + sub, :].astype(_F32) if docs else None
+        pre = _weighted(_taps_back(x_scr, at, taps.shape[0], sub, since), taps)
         if bias:
             pre = pre + b
         s = pre * jax.nn.sigmoid(pre)
@@ -135,18 +155,21 @@ def _fwd_kernel(x_ref, before_ref, taps_ref, y_ref, x_scr, *, scale, sub, bias=F
         y_ref[at:at + sub, :] = s
 
 
-def _bwd_kernel(x_ref, before_ref, taps_ref, dy_ref, dx_ref, dtaps_ref, x_scr, d_scr, *,
-                scale, sub, bias=False):
+def _bwd_kernel(x_ref, before_ref, taps_ref, dy_ref, *rest, scale, sub, bias=False, docs=False):
+    since_ref, dx_ref, dtaps_ref, x_scr, d_scr, s_scr = rest if docs else (None, *rest, None)
     rows = dy_ref.shape[0]
     i, blocks = pl.program_id(1), pl.num_programs(1)     # step i holds block blocks - 1 - i
     _stage(x_scr, x_ref, before_ref, i == blocks - 1)
     # the rows of dpre after this block: the first rows of the block the step before held
     d_scr[rows:, :] = jnp.where(i == 0, 0.0, d_scr[:_HALO, :])
+    if docs:   # and those rows' positions since their document's first, carried likewise
+        s_scr[rows:, :] = jnp.where(i == 0, 0.0, s_scr[:_HALO, :])
+        s_scr[:rows, :] = since_ref[...].astype(_F32)
     taps, b = _split_bias(taps_ref[...], bias)
     K = taps.shape[0]
     sums = [jnp.zeros(dtaps_ref.shape[1:], _F32)] * (K + bias)
     for at in reversed(range(0, rows, sub)):
-        shifted = _taps_back(x_scr, at, K, sub)
+        shifted = _taps_back(x_scr, at, K, sub, s_scr[at:at + sub, :] if docs else None)
         pre = _weighted(shifted, taps)
         if bias:
             pre = pre + b
@@ -159,6 +182,9 @@ def _bwd_kernel(x_ref, before_ref, taps_ref, dy_ref, dx_ref, dtaps_ref, x_scr, d
         dpre = ds * (sig * (1.0 + pre * (1.0 - sig)))
         d_scr[at:at + sub, :] = dpre
         ahead = [d_scr[at + j:at + j + sub, :] for j in range(K)]     # dpre_{t+j}
+        if docs:   # position t + j read tap j of t only from inside its own document
+            ahead = ahead[:1] + [jnp.where(s_scr[at + j:at + j + sub, :] >= j, ahead[j], 0.0)
+                                 for j in range(1, K)]
         dx_ref[at:at + sub, :] = _weighted(ahead, taps).astype(dx_ref.dtype)
         # a tap's gradient, 8 sublanes apart: adds of whole registers, no reduction in the walk
         # (the bias's row, the last, sums dpre alone)
@@ -197,70 +223,107 @@ _SEQUENTIAL = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")
 # a jitted function of its own, forward and backward each: a model's layers share ONE trace of
 # a kernel's body a shape, and the compiled step names the kernels after these functions
 # (ops/gated_delta.py has what tracing a body a layer and pass cost a start-up)
+def _with_documents(kernel, since, rows, H, d, blocks, reverse):
+    """(the kernel told whether it has the ref, the ref's spec, its operand): with `since`
+    None both kernels are traced as they were, operand for operand. `since` [B, T, d] is
+    shared by a sequence's heads: a step sees its block's rows."""
+    if since is None:
+        return kernel, [], ()
+    step = (lambda i: blocks - 1 - i) if reverse else (lambda i: i)
+    spec = pl.BlockSpec((None, rows, d), lambda bh, i: (bh // H, step(i), 0))
+    return functools.partial(kernel, docs=True), [spec], (since,)
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "rows", "interpret", "bias"))
-def gdn_conv_fwd(x, taps, scale, rows, interpret, bias=False):
+def gdn_conv_fwd(x, taps, scale, rows, interpret, bias=False, since=None):
     """x [B, H, T, d], T whole blocks of `rows`; taps [H, K, d] float32
-    ([H, K + 1, d] with `bias`: the last row) -> [B, H, T, d] float32."""
+    ([H, K + 1, d] with `bias`: the last row); since None or [B, T, d]
+    (`_since`) -> [B, H, T, d] float32."""
     B, H, T, d = x.shape
     block, before, head_taps = _specs(rows, H, d, taps.shape[1], T // rows, reverse=False)
-    return pl.pallas_call(
+    kernel, spec, since = _with_documents(
         functools.partial(_fwd_kernel, scale=scale, sub=min(_tile_rows(d), rows), bias=bias),
+        since, rows, H, d, T // rows, reverse=False)
+    return pl.pallas_call(
+        kernel,
         grid=(B * H, T // rows),
-        in_specs=[block, before, head_taps],
+        in_specs=[block, before, head_taps] + spec,
         out_specs=block,
         out_shape=jax.ShapeDtypeStruct(x.shape, _F32),
         scratch_shapes=[_scratch(rows, d)],
         compiler_params=_SEQUENTIAL,
         interpret=interpret,
-    )(x, x, taps)
+    )(x, x, taps, *since)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "rows", "interpret", "bias"))
-def gdn_conv_bwd(x, taps, dy, scale, rows, interpret, bias=False):
+def gdn_conv_bwd(x, taps, dy, scale, rows, interpret, bias=False, since=None):
     """-> (dx as x, the gradients of the taps' rows [B x H, K (+ 1), 8, d]
     float32: to be summed over the batch and the 8)."""
     B, H, T, d = x.shape
     K = taps.shape[1]
     block, before, head_taps = _specs(rows, H, d, K, T // rows, reverse=True)
-    return pl.pallas_call(
+    kernel, spec, since = _with_documents(
         functools.partial(_bwd_kernel, scale=scale, sub=min(_tile_rows(d), rows), bias=bias),
+        since, rows, H, d, T // rows, reverse=True)
+    return pl.pallas_call(
+        kernel,
         grid=(B * H, T // rows),
-        in_specs=[block, before, head_taps, block],
+        in_specs=[block, before, head_taps, block] + spec,
         out_specs=[block, pl.BlockSpec((None, K, _HALO, d), lambda bh, i: (bh, 0, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
                    jax.ShapeDtypeStruct((B * H, K, _HALO, d), _F32)],
-        scratch_shapes=[_scratch(rows, d)] * 2,
+        scratch_shapes=[_scratch(rows, d)] * (2 + len(since)),
         compiler_params=_SEQUENTIAL,
         interpret=interpret,
-    )(x, x, taps, dy)
+    )(x, x, taps, dy, *since)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-def _chain(scale, rows, interpret, bias, x, taps):
+def _chain(scale, rows, interpret, bias, x, taps, since):
     """`taps` [H, K, d], or with `bias` [H, K + 1, d]: the bias the last of a head's rows."""
-    return gdn_conv_fwd(x, taps, scale, rows, interpret, bias=bias)
+    return gdn_conv_fwd(x, taps, scale, rows, interpret, bias=bias, since=since)
 
 
-def _chain_fwd(scale, rows, interpret, bias, x, taps):
-    return gdn_conv_fwd(x, taps, scale, rows, interpret, bias=bias), (x, taps)
+def _chain_fwd(scale, rows, interpret, bias, x, taps, since):
+    return gdn_conv_fwd(x, taps, scale, rows, interpret, bias=bias, since=since), (x, taps, since)
 
 
 def _chain_bwd(scale, rows, interpret, bias, residuals, dy):
-    x, taps = residuals
-    dx, dtaps = gdn_conv_bwd(x, taps, dy, scale, rows, interpret, bias=bias)
+    x, taps, since = residuals
+    dx, dtaps = gdn_conv_bwd(x, taps, dy, scale, rows, interpret, bias=bias, since=since)
     H, K, d = taps.shape
-    return dx, dtaps.reshape(-1, H, K, _HALO, d).sum(axis=(0, 3))
+    # the documents are no argument to differentiate by: None or zeros
+    return (dx, dtaps.reshape(-1, H, K, _HALO, d).sum(axis=(0, 3)),
+            None if since is None else jnp.zeros_like(since))
 
 
 _chain.defvjp(_chain_fwd, _chain_bwd)
 
 
+def _since(segment_ids: jax.Array, K: int, short: int, d: int, dtype) -> jax.Array:
+    """segment_ids [B, T] -> [B, T + short, d] in `dtype`: a position's
+    distance from its document's first position (the first of the sequence,
+    or one whose id differs from the position before it), no more than K -
+    1 (whole numbers under 9: exact in any float dtype), down a head's d
+    lanes as the kernels compare it with a tap's number."""
+    B, T = segment_ids.shape
+    at = jnp.arange(T, dtype=jnp.int32)
+    starts = jnp.pad(segment_ids[:, 1:] != segment_ids[:, :-1], ((0, 0), (1, 0)))
+    since = jnp.minimum(at - jax.lax.cummax(jnp.where(starts, at, 0), axis=1), K - 1)
+    since = jnp.pad(since, ((0, 0), (0, short)))
+    return jnp.broadcast_to(since[:, :, None], (B, T + short, d)).astype(dtype)
+
+
 def gdn_conv(x: jax.Array, taps: jax.Array, scale: Optional[float] = None,
-             bias: Optional[jax.Array] = None) -> jax.Array:
+             bias: Optional[jax.Array] = None,
+             segment_ids: Optional[jax.Array] = None) -> jax.Array:
     """x [B, H, T, d] (any float dtype), taps [K, H x d] (tap j on position
-    t - j), `bias` [H x d] or None -> SiLU of the causal depthwise
-    convolution (+ the bias), [B, H, T, d] float32; with `scale` (q:
-    d ** -0.5, k: 1.0), its L2 norm over d times `scale`. The module's
+    t - j), `bias` [H x d] or None, `segment_ids` [B, T] or None -> SiLU
+    of the causal depthwise convolution (+ the bias), [B, H, T, d] float32;
+    with `scale` (q: d ** -0.5, k: 1.0), its L2 norm over d times `scale`;
+    with `segment_ids` (packed documents) tap j of position t reads
+    position t - j only where both lie in one document. The module's
     docstring has the kernels. One layer span a call site WHILE TRACING
     (`gdn_conv.kernel`) counts the sites."""
     B, H, T, d = x.shape
@@ -277,6 +340,8 @@ def gdn_conv(x: jax.Array, taps: jax.Array, scale: Optional[float] = None,
     head_taps = taps.astype(_F32).reshape(K, H, d).swapaxes(0, 1)
     if bias is not None:   # one more row of a head's taps
         head_taps = jnp.concatenate([head_taps, bias.astype(_F32).reshape(H, 1, d)], axis=1)
+    since = None if segment_ids is None else _since(segment_ids, K, short, d, x.dtype)
     with obs.layer_span("gdn_conv.kernel"):
-        y = _chain(scale, rows, jax.default_backend() != "tpu", bias is not None, x, head_taps)
+        y = _chain(scale, rows, jax.default_backend() != "tpu", bias is not None, x, head_taps,
+                   since)
     return y[:, :, :T] if short else y

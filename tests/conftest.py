@@ -224,6 +224,22 @@ _TEN_CELLS_AND_PR_53S_TAIL = {
 }
 
 
+# PR 66 added the fourteenth cell, `granite-h-micro-train-packed`, the FIRST cell on a second
+# generator (generators/packed_zipf_docs.py: documents packed end to end). One case of
+# tests/chipbench/test_chipbench_step.py holds every training cell to the one generator there
+# was, `zipf_tokens`, in one function with everything else it holds of a cell; that file is the
+# accepted benchmark's and may not be edited, so the new cell's case is skipped here and
+# tests/chipbench/test_chipbench_granite_hybrid.py carries its every other assertion for that
+# cell (`test_manifest_is_well_formed_with_the_cell`: chips, `reduced`, `source`, `why`, the
+# configuration file's keys, no "TO FILL", what it reports, the two end-to-end metrics). Every
+# OTHER cell's case runs as it did.
+_ONE_GENERATOR = {
+    ("test_chipbench_step.py",
+     "test_training_cell_is_well_formed_and_reports_what_it_did_and_the_new_metrics"):
+        ("name", "granite-h-micro-train-packed"),
+}
+
+
 # The one case of the lane that holds what a JITTED program computes to what the same
 # functions give taken bare, BIT FOR BIT (a runner's parameters, initialised inside its
 # program, against `init_params` called operation by operation): the unoptimised CPU programs
@@ -257,6 +273,14 @@ def pytest_collection_modifyitems(items):
     for item in items:
         name = getattr(item, "originalname", None)
         file = os.path.basename(str(item.fspath))
+        if (file, name) in _ONE_GENERATOR:
+            only = _ONE_GENERATOR[(file, name)]
+            if item.callspec.params.get(only[0]) == only[1]:
+                item.add_marker(pytest.mark.skip(
+                    reason="holds every training cell to the generator zipf_tokens, as before "
+                           "PR 66; test_chipbench_granite_hybrid.py carries its other assertions "
+                           "for the cell on packed documents"))
+                continue
         if (file, name) in _TEN_CELLS_AND_PR_53S_TAIL:
             item.add_marker(pytest.mark.skip(
                 reason="spells out ten cells or per_layer ending with PR 53's five, as before "

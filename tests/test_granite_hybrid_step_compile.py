@@ -1,0 +1,74 @@
+"""The step of `granite-h-micro-train-packed` for a described v5e
+(tests/v5e_steps.py), compiled ONCE, in a file of its cell's own (PR 45's
+rule): granite-4.0-h-micro's first period (5 Mamba-2 layers, 1 NoPE GQA
+layer, 4 Mamba-2 layers; ALL 64 Mamba heads in ONE group, an eighth of the
+tied table, 1 x 8192 under remat "full") as the cell builds it, on a batch
+with `segment_ids` and `mask`. What it holds is what the lowered module
+cannot show: that the step FITS (8.63 GiB of arguments, the compiler's peak
+under the chip's 15.75), which is also the guard that ops/ssd.py's kernels
+lower through Mosaic at a group of 64 heads (with the VMEM they ask for, 31 and
+41 MiB: tests/test_ssd_documents.py; at the default 16 the compile is refused)
+with the documents' rows, ops/gdn_conv.py's with the distances, and
+ops/flash.py's under `segment_ids` at heads of 64, where no chip is at hand.
+One compile, about 45 s of every core."""
+
+import re
+
+import pytest
+
+from v5e_steps import Step, v5e  # noqa: F401 - a fixture
+
+GRANITE = dict(batch=1, model="granite-4.0-h-micro", n_layers=10, seq=8192, vocab_size=12544,
+               remat_policy="full")
+GIB = 2 ** 30
+SCOPES = ("ssm.proj", "ssm.conv", "ssm.gates", "ssm.scan", "ssm.norm", "ssm.out", "attn.qkv",
+          "attn.attend", "attn.out", "dense.ffn", "block.norm", "block.stack", "embed", "head",
+          "optim")
+_STEP = []
+
+
+def packed_step(devices) -> Step:
+    """The file's one record: the cell's step on a PACKED batch (the shared
+    builder's batch has tokens and targets alone)."""
+    if not _STEP:
+        step = Step(devices, **GRANITE)
+        tokens = step.batch["tokens"]
+        step.batch = {**step.batch, "segment_ids": tokens, "mask": tokens}
+        _STEP.append(step)
+    return _STEP[0]
+
+
+def test_granite_hybrid_train_step_fits_the_chip(v5e):
+    """772,160,448 parameters x 12 B = 8.63 GiB of arguments; the compiler's
+    own peak 14.28 GiB of the chip's 15.75 (the rehearsal of ISSUE 66's step
+    3 (b): remat "full"; under "dots" the same step is refused at 19.58).
+    `temp_size_in_bytes` reads 8.39 GiB, of which the compiler's own report
+    calls more than half fragmentation: the peak is what has to fit."""
+    memory = packed_step(v5e).memory
+    assert 8.62 * GIB < memory.argument_size_in_bytes < 8.64 * GIB
+    assert memory.peak_memory_in_bytes < 14.6 * GIB < 15.75 * GIB
+    # over a quarter of the chip by the arguments alone: the benchmark's floor
+    assert memory.argument_size_in_bytes > 0.25 * 16 * GIB
+
+
+def test_granite_hybrid_train_step_runs_the_kernels_where_the_readers_look(v5e):
+    """Nine Mamba layers in two scans and one attention layer: a kernel is
+    ONE site a scan's body, forward, the forward made again under remat
+    "full", and backward; no kernel is XLA's own rematerialisation's; the
+    Mamba block is traced ONCE for both scans (one counted site), and the
+    attention layer's backward is the fused kernel (`fallback_sites.train` 0)."""
+    step = packed_step(v5e)
+    kernels = [re.sub(r"\.\d+$", "", k) for k in step.kernels]
+    for name, sites in (("ssd_scan_fwd", 4), ("ssd_scan_bwd", 2), ("gdn_conv_fwd", 4),
+                        ("gdn_conv_bwd", 2), ("gated_norm_fwd", 4), ("gated_norm_bwd", 2)):
+        assert kernels.count(name) == sites, (name, kernels.count(name))
+    assert sum(k.startswith("attn.attend") for k in kernels) == 3   # forward twice, backward fused
+    assert not re.search(r"\.remat\d* = ", step.hlo)
+    assert step.engaged("ssd_scan.kernel", "gdn_conv.kernel", "flash.bwd_fused",
+                        "flash.bwd_split") == {"ssd_scan.kernel": 1, "gdn_conv.kernel": 1,
+                                               "flash.bwd_fused": 1, "flash.bwd_split": 0}
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_granite_hybrid_train_step_has_every_scope_its_readers_sum(v5e, scope):
+    assert packed_step(v5e).has_scope(scope)

@@ -158,7 +158,7 @@ def test_engine_refuses_the_model_by_name(model):
 
 TINY_PRESETS = ("llama-tiny", "moe-tiny", "zaya-tiny", "glm-lite-tiny", "laguna-tiny",
                 "mellum2-tiny", "sdar-tiny", "keye-tiny", "olmo-hybrid-tiny", "nemotron-h-tiny",
-                "solar-open2-tiny", "kimi-linear-tiny")
+                "solar-open2-tiny", "kimi-linear-tiny", "granite-hybrid-tiny")
 # preset -> sha256 of (every leaf's path, shape and dtype), of the leaves' bytes in the paths'
 # order, and of (every leaf's path and logical axes), as PR 56's tree (the parent of PR 57,
 # which moved WHERE models/llama.py reads a configuration's mixer kind) makes them from
@@ -216,6 +216,11 @@ _TINY_TREES = {
         "928f16eb4d7586535037741561032a71e42a5e931d82595ed3d1a2dfdf4c4502",
         "957d5d954833a08f386ebc0aec461583d2e5d10603a88e97b8ee84aa939e70d0",
         "c2167b5181965f594d25913eae02763733c066af57fb8a1c4c80fa6840325c5a"),
+    # as PR 66 made it (models/granite_hybrid.py: a group a segment of the stack)
+    "granite-hybrid-tiny": (
+        "716fc6642bc42035dfe4251ee3feb98d1e633118406f1d24a7ff17aba93ac31d",
+        "347552f9372c76dfa9932f926b43bcb7dc582d330d00ca7e7d67873977dc328f",
+        "16349c76bf23155566812f73c29cd6d7f6188fbdd012b735600d4f95aed4441a"),
 }
 
 

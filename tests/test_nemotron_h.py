@@ -197,9 +197,15 @@ def test_the_train_step_learns_a_batch_by_the_registrys_name():
 
 def test_what_is_not_implemented_is_refused_by_name():
     params, x = seeded_params(NEMOTRON_H, FP32), stream()
-    with pytest.raises(NotImplementedError, match="packed documents.*Mamba layer"):
-        nh.mamba_sublayer(x, layer_of(params, nh.MAMBA), FP32,
-                          segment_ids=jnp.zeros(x.shape[:2], jnp.int32))
+    # packed documents under a Mamba layer were refused here until PR 66 built them
+    # (tests/test_ssd_documents.py, tests/test_gdn_conv_documents.py, tests/test_granite_hybrid.py):
+    # ONE document is the sublayer without ids, two are not
+    lp, ids = layer_of(params, nh.MAMBA), jnp.zeros(x.shape[:2], jnp.int32)
+    plain = nh.mamba_sublayer(x, lp, FP32, segment_ids=None)
+    np.testing.assert_allclose(nh.mamba_sublayer(x, lp, FP32, segment_ids=ids), plain, atol=1e-5)
+    two = nh.mamba_sublayer(x, lp, FP32, segment_ids=ids.at[:, 17:].set(1))
+    np.testing.assert_allclose(two[:, :17], plain[:, :17], atol=1e-5)
+    assert float(jnp.abs(two[:, 17:] - plain[:, 17:]).max()) > 1e-3
     with pytest.raises(NotImplementedError, match=r"dense MLP layer \(-\) is not"):
         dataclasses.replace(FP32, pattern="M-E*M-E*").layer_types
     with pytest.raises(ValueError, match="the pattern names 12"):
