@@ -58,11 +58,20 @@ flash/ragged lineage to this framework. Design:
    walk is the causal walk's prefix of sub-tiles, the kv sequence is one
    block up to 16,384 keys at heads of 128 and the backward the fused
    kernel; the noised keys a noised row sees are the `beta` of its own
-   block, a [beta, beta] product a block in plain jax.numpy, merged with
-   the kernels' (o, lse) by the log-sum-exp (`_block_diffusion`). One
-   walk over 2L x 2L would visit the same prefixes and one more sub-tile
-   a noised q block (L (L + beta) visible pairs a head either way), over
-   16,384 keys: one kv block too since PR 56, which was not tried;
+   block, merged OUTSIDE the kernels with their (o, lse) by the
+   log-sum-exp, in float32 (`_block_diffusion`, `_blockdiff_merge`). Which
+   text merges them follows from `beta` against the tile of 8 rows (PR
+   67): blocks that are NOT whole tiles (the cell's 4; 1, 2) on the
+   [B, KVH, G, L, D] arrays as they lie, each row handed its block's keys
+   in `beta` arrays of the keys' own shape (`_own_rows`: a 0/1 product a
+   tile of 128 rows), a score a multiply and a lane reduction, the
+   backward written out (`_merge_own_blocks`); blocks of whole tiles (8,
+   16, 32: the tests') as a [beta, beta] product a block on the
+   [.., L / beta, beta, D] view, which was every block's text until then.
+   One walk over 2L x 2L would visit the same prefixes and one more
+   sub-tile a noised q block (L (L + beta) visible pairs a head either
+   way), over 16,384 keys: one kv block too since PR 56, which was not
+   tried;
  * VALUES MAY HAVE A WIDTH OF THEIR OWN (PR 64): q and k share `D`, v has
    `Dv`, and o, do and dv follow v while dq and dk follow q: every kernel
    reads the two widths from its refs' shapes, and v's blocks, the forward's
@@ -227,10 +236,33 @@ from ray_tpu import obs
 # twice PR 43's 3.97 / 9.54 at 136 visits under a selection, 79.2% of what the L (L + 4)
 # visible pairs need at the MXU's peak. The noised blocks' own keys, merged OUTSIDE the kernels
 # (`_block_diffusion`, the scope `flash.blockdiff_merge`), cost 8.8 ms a layer of XLA's slices,
-# copies and converts (call 6 of PR 55, the same seed): a block of 4 rows is half a tile of 8
-# sublanes. One more sub-tile a noised q block inside the kernels (a
-# second k / v input that holds the same global rows as the q block) would be about 1.5 ms a
-# layer in their place: ROADMAP S18(a).
+# copies and converts (call 6 of PR 55, the same seed) while every block's text was a
+# [.., 2048, 4, 128] view: a block of 4 rows is half a tile of 8 sublanes.
+#
+# The merge on the arrays as they lie (PR 67; v5e, the cell's shape, blocks of 4; ms a LAYER,
+# forward + forward again under remat + backward). ALONE (`jit(value_and_grad)` of the merge on
+# a scratch loop that is NOT in the tree, call 1; about 1.5 of each number is the loop's own
+# loss): the view 10.22; shifted rows on the VPU as ONE text over [B, KVH, G, L, D], seven
+# masked offsets 19.57, the block's four rows brought to every row 13.41 (XLA moves the
+# reshape [KVH, G] -> H onto the kv rows' broadcast and writes every broadcast out); the same
+# walked q head by q head 8.92; a block-diagonal mask over tiles of T rows on the MXU at
+# `HIGHEST`, T = 128 8.53, T = 32 7.79: NEITHER spelling under the 4.5 ISSUE 67 set as its line.
+# What the step then showed, table by table (`python3 -m chipbench.tools.step_table`, seed
+# 3000000019, ms a step of four layers under the scope): the view 35.4-35.5; T = 32 31.8; the
+# four rows behind a barrier with the backward written out 41.8, because XLA held the merge's
+# row statistics in the kernels' [B, H, S, 1] layout of the log-sum-exp; with the log-sum-exp
+# behind the barrier too 26.4; the rows picked by 0/1 products in place of shifts and selects
+# 25.5 as one product for the four arrays, 21.6 as a product an array; the eight sums over the
+# group (dk_j, dv_j) as two reductions of four operands each, a pass over q and one over dO where
+# each sum was a pass, 20.3; the noised half set into the 2L rows by a select where a
+# concatenate first copied the clean half out, 19.4: KEPT, 4.85 ms a layer, `blockdiff_merge_pct`
+# 5.9, the step 343.1 -> 329.3 ms busy, 23,610 -> 24,580 tokens/s in four pairs. What is left
+# is passes over [1, 32, 8192, 128] arrays at 0.1-0.55 ms each that XLA does not fuse further:
+# the scores and the weighted values twice (remat), the backward's row sums, dq and do1, the
+# two reductions over the group, and 1.2 ms of placing halves into 2L
+# rows (the select, dq's and do1's pads). One more sub-tile a noised q block inside the
+# kernels (a second k / v input that holds the same global rows as the q block) would be
+# about 1.5 ms a layer in their place: ROADMAP S18(a), now worth 3.3 ms a layer and not 7.
 DEFAULT_BLOCK_Q = 512
 # The keys 32 bits x 128 lanes address: a block of a packed selection, the
 # most a kv block held before PR 43, and the kv block of a sequence over
@@ -1299,21 +1331,192 @@ def blockdiff_tiles(L: int, beta: int, block_q: int = DEFAULT_BLOCK_Q, head_dim:
             "visible_pairs": L * (L + beta)}
 
 
+_SUBLANES = 8  # rows of a float32 (8, 128) tile: what a block-diffusion block is held against
+
+
+def _own_rows_tile(L: int, beta: int) -> int:
+    """Rows a tile of `_own_rows`' selection: the most that are whole blocks
+    of `beta`, divide L and fill the MXU's 128 at most (128 at the cell's
+    L = 8,192; all of a sequence of up to 128 rows)."""
+    return max(t for t in range(beta, max(beta, min(L, _LANES)) + 1, beta) if L % t == 0)
+
+
+def _own_rows(x, beta: int) -> list:
+    """x [B, KVH, L, D] -> `beta` float32 arrays of x's shape: row t of the
+    j-th holds x's row beta (t // beta) + j, the j-th row of t's OWN block,
+    so a row meets each key of its block at its own place in a whole
+    (8, 128) tile; at no point a [.., L / beta, beta, D] view, whose rows
+    would be half a tile padded. The rows are picked by a constant 0/1
+    matrix over tiles of T rows (`_own_rows_tile`) on the MXU: shifts along
+    L with selects by t % beta give the same arrays and read 1.2 ms a layer
+    more in the cell's step (26.4 -> 21.6 ms a step, PR 67, calls 8 and 10).
+    EXACT: a 0/1 matrix times a bfloat16 array in ONE pass with float32
+    accumulation adds zeros to one value; a float32 array (the tests', and
+    the cotangents on the way back through this function's transpose) is no
+    bfloat16 value and takes `HIGHEST`, which the bfloat16 operands of the
+    forward do not pay for."""
+    B, KVH, L, D = x.shape
+    T = _own_rows_tile(L, beta)
+    t = np.arange(T)
+    pick = t[None, None, :] == (t // beta * beta)[None, :, None] + np.arange(beta)[:, None, None]
+    # one product a tile and array: the tiles are the product's BATCH, so each [T, T] x [T, D]
+    # leaves its rows where they lie, [.., T, D] (one product for all `beta` arrays is split
+    # into them again by a copy each), and none is a product WITHOUT a batch dimension, the
+    # kind the layers' remat policy "dots" saves
+    one_pass = x.dtype == jnp.bfloat16  # chosen from what arrives: see above
+    N = L // T
+    tiles = x.reshape(B, KVH, N, T, D)
+    return [jnp.einsum("bknts,bknsd->bkntd",
+                       jnp.broadcast_to(jnp.asarray(pick[j], x.dtype), (B, KVH, N, T, T)), tiles,
+                       preferred_element_type=jnp.float32,
+                       precision=None if one_pass else jax.lax.Precision.HIGHEST
+                       ).reshape(B, KVH, L, D) for j in range(beta)]
+
+
+def _sums_over_group(terms: list) -> list:
+    """[B, KVH, G, L, D] arrays summed over their group, as ONE reduction of
+    several operands: one pass over what they share (q, or dO), where a sum
+    each is a pass each."""
+    zeros = (jnp.zeros((), terms[0].dtype),) * len(terms)
+    return list(jax.lax.reduce(tuple(terms), zeros,
+                               lambda a, b: tuple(x + y for x, y in zip(a, b)), dimensions=(2,)))
+
+
+def _merge_weights(q32, kj, lse):
+    """(w1, [p_j], w1 + sum p_j): the clean keys' whole weight, each own
+    key's, and their sum, all under the common max. q32 [B, KVH, G, L, D]
+    float32, kj `_own_rows` of the keys as [B, KVH, 1, L, D], lse
+    [B, KVH, G, L]. A score is a multiply and a lane reduction in float32:
+    exact products of what arrived bfloat16, no pass of the MXU."""
+    s = [(q32 * kk).sum(-1) for kk in kj]
+    m = functools.reduce(jnp.maximum, s, lse)
+    w1 = jnp.exp(lse - m)
+    p = [jnp.exp(sj - m) for sj in s]
+    return w1, p, functools.reduce(jnp.add, p, w1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _merge_own_blocks(beta, qn, kn, vn, o1n, lse):
+    """The noised rows' own-block keys merged into the kernels' (o1n, lse)
+    over the clean keys, on the arrays AS THEY LIE: qn and o1n
+    [B, KVH, G, L, D] (the noised half of the 2L rows, the q heads of a kv
+    head apart), kn and vn [B, KVH, L, D], lse [B, KVH, G, L] float32 ->
+    [B, KVH, G, L, D] in o1n's dtype. Row t:
+
+        s_j = q[t] . k[beta (t // beta) + j],  m = max(lse, max_j s_j)
+        w1 = exp(lse - m),  p_j = exp(s_j - m)
+        o = (w1 o1[t] + sum_j p_j v[beta (t // beta) + j]) / (w1 + sum_j p_j)
+
+    in float32 on the VPU. The backward is written out below because what
+    autodiff makes of this text reads 1.2 ms a layer more alone at the
+    cell's shape (7.33 against 6.12 ms, PR 67, calls 4 and 5)."""
+    return _merge_own_blocks_fwd(beta, qn, kn, vn, o1n, lse)[0]
+
+
+def _merge_own_blocks_fwd(beta, qn, kn, vn, o1n, lse):
+    f32 = jnp.float32
+    kj, vj = ([y[:, :, None] for y in _own_rows(x, beta)] for x in (kn, vn))
+    w1, p, z = _merge_weights(qn.astype(f32), kj, lse)
+    own = functools.reduce(jnp.add, (pj[..., None] * vv for pj, vv in zip(p, vj)))
+    on = (w1[..., None] * o1n.astype(f32) + own) / z[..., None]
+    # the backward makes the weights again from these; under the layers' remat this forward
+    # is run again in the backward pass and nothing here outlives its layer
+    return on.astype(o1n.dtype), (qn, kn, kj, vj, o1n, lse)
+
+
+def _merge_own_blocks_bwd(beta, residuals, g):
+    """With a = w1 / z and b_j = p_j / z, the softmax over the clean keys'
+    log-sum-exp and the block's own scores: o = a o1 + sum_j b_j v_j, so
+    do1 = a g, dv_j = sum_G b_j g, and with da = g . o1, db_j = g . v_j,
+    delta = a da + sum_j b_j db_j: dlse = a (da - delta), ds_j = b_j (db_j -
+    delta), dq = sum_j ds_j k_j, dk_j = sum_G ds_j q. The own rows'
+    cotangents go back to their rows through `_own_rows`' transpose in
+    float32 and are rounded once."""
+    qn, kn, kj, vj, o1n, lse = residuals
+    f32 = jnp.float32
+    to_rows = jax.linear_transpose(lambda x: _own_rows(x, beta),
+                                   jax.ShapeDtypeStruct(kn.shape, f32))
+    q32, o32, g32 = qn.astype(f32), o1n.astype(f32), g.astype(f32)
+    w1, p, z = _merge_weights(q32, kj, lse)
+    a, b = w1 / z, [pj / z for pj in p]
+    da = (g32 * o32).sum(-1)
+    db = [(g32 * vv).sum(-1) for vv in vj]
+    delta = functools.reduce(jnp.add, (bj * dbj for bj, dbj in zip(b, db)), a * da)
+    ds = [bj * (dbj - delta) for bj, dbj in zip(b, db)]
+    dq = functools.reduce(jnp.add, (dsj[..., None] * kk for dsj, kk in zip(ds, kj)))
+    dk = to_rows(_sums_over_group([dsj[..., None] * q32 for dsj in ds]))[0]
+    dv = to_rows(_sums_over_group([bj[..., None] * g32 for bj in b]))[0]
+    return (dq.astype(qn.dtype), dk.astype(kn.dtype), dv.astype(kn.dtype),
+            (a[..., None] * g32).astype(o1n.dtype), a * (da - delta))
+
+
+_merge_own_blocks.defvjp(_merge_own_blocks_fwd, _merge_own_blocks_bwd)
+
+
+def _blockdiff_merge(q, k, v, o1, lse1, blockdiff):
+    """The noised rows' own blocks merged into the kernels' (o1, lse1) over
+    the clean keys: everything of `_block_diffusion` outside the kernels, in
+    float32. Which text a shape takes follows from `beta` and the (8, 128)
+    tile alone, no option of the caller's, and `obs.layer_counters()` says
+    which was traced (`flash.blockdiff_merge_tiled` / `_view`)."""
+    L, beta = blockdiff
+    B, H, S, D = q.shape
+    KVH, G, K = k.shape[1], H // k.shape[1], L // beta
+    if beta % _SUBLANES:
+        # a block is NOT whole sublane tiles (the cell's beta = 4; 1, 2): any
+        # [.., L / beta, beta, D] view of q, k, v or o is a padded layout and a copy into and out
+        # of it (8.8 ms a layer at the cell's shape, PR 55). So the arrays stay as they lie and
+        # each row is handed its block's keys and values in arrays of its own (`_own_rows`).
+        # What goes in stands behind a barrier. The 5-D views of q and o1: without it XLA moves
+        # the reshape [KVH, G] -> H onto the own rows' broadcast over the group and then WRITES
+        # each broadcast out, sixteen float32 [B, H, L, D] arrays a layer (PR 67, call 1: 13.4 ms
+        # alone where the view read 10.2). The log-sum-exp: the kernels hold it [B, H, S, 1], one
+        # lane of 128 used, and without the barrier XLA computes the merge's row statistics and
+        # dlse in THAT layout (nine copies into it and one fusion, 3.9 ms a layer of the step,
+        # PR 67, call 7; the parent's text paid 0.9 of the same kind, `multiply_add_fusion.71`)
+        with obs.layer_span("flash.blockdiff_merge_tiled"):  # counted WHILE TRACING
+            q5, o5, lse = jax.lax.optimization_barrier(
+                (q.reshape(B, KVH, G, S, D), o1.reshape(B, KVH, G, S, D),
+                 lse1[:, :, L:S, 0].reshape(B, KVH, G, L)))
+            on = _merge_own_blocks(beta, q5[:, :, :, L:], k[:, :, L:], v[:, :, L:],
+                                   o5[:, :, :, L:], lse)
+            # the noised half set into the 2L rows by a select: as a concatenate XLA first
+            # copies the clean half out (`slice`, 64 MiB a layer more)
+            noised = (jnp.arange(S, dtype=jnp.int32) >= L)[:, None]
+            o = jnp.where(noised, jnp.pad(on, ((0, 0),) * 3 + ((L, 0), (0, 0))), o5)
+            return jax.lax.optimization_barrier(o).reshape(B, H, S, D)
+    # blocks of whole sublane tiles (beta 8, 16, 32: the tests'; no cell): the [.., beta, D] view
+    # is whole (8, 128) tiles already and a block's own [beta, beta] product one einsum, at
+    # `HIGHEST` (p, and the backward's dp and ds, are no bfloat16 values cast up)
+    with obs.layer_span("flash.blockdiff_merge_view"):
+        f32, hi = jnp.float32, jax.lax.Precision.HIGHEST
+        qn = q[:, :, L:].reshape(B, KVH, G, K, beta, D).astype(f32)
+        kn, vn = (x[:, :, L:].reshape(B, KVH, K, beta, D).astype(f32) for x in (k, v))
+        s2 = jnp.einsum("bkgnid,bknjd->bkgnij", qn, kn, precision=hi)
+        lse_n = lse1[:, :, L:2 * L, 0].reshape(B, KVH, G, K, beta)
+        m = jnp.maximum(lse_n, s2.max(-1))
+        w1 = jnp.exp(lse_n - m)  # the clean keys' whole weight
+        p2 = jnp.exp(s2 - m[..., None])
+        own = jnp.einsum("bkgnij,bknjd->bkgnid", p2, vn, precision=hi)
+        clean = o1[:, :, L:].reshape(B, KVH, G, K, beta, D).astype(f32)
+        on = (w1[..., None] * clean + own) / (w1 + p2.sum(-1))[..., None]
+        return jnp.concatenate([o1[:, :, :L], on.astype(o1.dtype).reshape(B, H, L, D)], axis=2)
+
+
 def _block_diffusion(q, k, v, blockdiff, **blocks):
     """Attention of 2L rows [clean copy; noised copy] under the
     block-diffusion mask `blockdiff` = (L, beta): q [B, H, 2L, D], k and v
     [B, KVH, 2L, D], head-major, -> o [B, H, 2L, D]. Every row against the
     CLEAN keys through the kernels (a prefix a row: `_flash_head_major`
     under `blockdiff`, which also gives each row's log-sum-exp); a noised
-    row's own block of `beta` noised keys, both ways, as one [beta, beta]
-    product a block in float32, merged by the log-sum-exp: softmax over
+    row's own block of `beta` noised keys, both ways, in float32 outside
+    them (`_blockdiff_merge`), merged by the log-sum-exp: softmax over
     the union of two key sets is each set's softmax weighed by
     exp(its lse - the common max). A noised row of block 0 sees no clean
     key: the kernels give it lse ~ NEG_INF, which weighs nothing.
     `blocks`: `block_q` / `block_k` of the kernels' call (the tests')."""
     L, beta = blockdiff
-    B, H, S, D = q.shape
-    KVH, G, K = k.shape[1], H // k.shape[1], L // beta
+    S, D = q.shape[2:]
     if S != 2 * L or k.shape[2] != S or L % beta:
         raise ValueError(f"block diffusion {blockdiff}: 2L rows and 2L keys in whole blocks, "
                          f"got {S} rows, {k.shape[2]} keys")
@@ -1326,22 +1529,10 @@ def _block_diffusion(q, k, v, blockdiff, **blocks):
     with jax.named_scope("flash.blockdiff"):
         o1, lse1 = _flash_head_major(q, k[:, :, :L], v[:, :, :L], causal=False,
                                      segment_ids=None, blockdiff=blockdiff, **blocks)
-    f32 = jnp.float32
     # everything outside the kernels under a scope of its own, forward and backward: a table
     # of the step (chipbench/step_scopes/sdar.json) reads what the merge costs apart
     with jax.named_scope("flash.blockdiff_merge"):
-        # a block's own [beta, beta] product in float32: a thousandth of the kernels' pairs
-        qn = q[:, :, L:].reshape(B, KVH, G, K, beta, D).astype(f32)
-        kn, vn = (x[:, :, L:].reshape(B, KVH, K, beta, D).astype(f32) for x in (k, v))
-        s2 = jnp.einsum("bkgnid,bknjd->bkgnij", qn, kn)
-        lse_n = lse1[:, :, L:2 * L, 0].reshape(B, KVH, G, K, beta)
-        m = jnp.maximum(lse_n, s2.max(-1))
-        w1 = jnp.exp(lse_n - m)  # the clean keys' whole weight
-        p2 = jnp.exp(s2 - m[..., None])
-        own = jnp.einsum("bkgnij,bknjd->bkgnid", p2, vn)
-        clean = o1[:, :, L:].reshape(B, KVH, G, K, beta, D).astype(f32)
-        on = (w1[..., None] * clean + own) / (w1 + p2.sum(-1))[..., None]
-        return jnp.concatenate([o1[:, :, :L], on.astype(o1.dtype).reshape(B, H, L, D)], axis=2)
+        return _blockdiff_merge(q, k, v, o1, lse1, blockdiff)
 
 
 def flash_attention(

@@ -93,7 +93,10 @@ SDAR = dict(batch=1, model="sdar-30b-a3b", n_layers=4, seq=8192, vocab_size=1907
 # window of 256 x C / N rows a block (512 where 2,048 stood) and as many windows as a block's run is
 # long (`moe._sum_by_band`: a `fori_loop` inside `lax.map`), rows past the held pairs name no
 # token (`moe._held_rows`), and the layer's statistics carry `band_trips` (c4789e03... from PR 59)
-_SDAR_STEP = "a08ccfd51ea4a512ae82a62d220ac2e83c5b7fc464545809aeda22f1696122f3"
+# Replaced ON PURPOSE by PR 67: the noised blocks' own keys are merged on the arrays as they lie
+# (`flash._blockdiff_merge`: `_own_rows`' 0/1 products, `_merge_own_blocks` and its written
+# backward behind barriers) where [.., 2048, 4, 128] views stood (a08ccfd5... from PR 63)
+_SDAR_STEP = "4ac46fcb1438fcab28156aebbc45b56ae4c458533da2ff129ec58a765cc5eb51"
 SDAR_SCOPES = ("diff.corrupt", "diff.loss", "attn.qkv", "attn.norm", "attn.rope", "attn.attend",
                "flash.blockdiff", "attn.out", "moe.router", "moe.dispatch", "moe.experts",
                "moe.combine", "block.norm", "block.stack", "embed", "head", "optim")
@@ -342,7 +345,8 @@ def test_sdar_train_step_lowers_to_the_masked_kernels_and_the_fused_backward(v5e
     from ray_tpu import obs
 
     sites = ("laguna.attn", "moe.ffn", "grouped_matmul.kernel", "grouped_matmul.ragged_dot",
-             "moe.compact", "moe.full", "flash.bwd_fused", "flash.bwd_split")
+             "moe.compact", "moe.full", "flash.bwd_fused", "flash.bwd_split",
+             "flash.blockdiff_merge_tiled", "flash.blockdiff_merge_view")
     count = lambda: {n: obs.layer_counters().get(n, {"count": 0})["count"] for n in sites}  # noqa: E731
     before = count()
     step = train_step(v5e, **SDAR)
@@ -353,6 +357,10 @@ def test_sdar_train_step_lowers_to_the_masked_kernels_and_the_fused_backward(v5e
     assert engaged["laguna.attn"] >= 1 and engaged["moe.compact"] >= 1 and engaged["moe.full"] == 0
     assert engaged["grouped_matmul.kernel"] > 0 and engaged["grouped_matmul.ragged_dot"] == 0
     assert (engaged["flash.bwd_fused"], engaged["flash.bwd_split"]) == (1, 0)
+    # PR 67: the noised blocks of 4 rows are merged on the arrays as they lie at every traced
+    # site (the layer scan traces a block once: the traces' count, not the layers'), none on
+    # the `[.., 4, 128]` view: a step that silently kept the parent's text would say so here
+    assert engaged["flash.blockdiff_merge_tiled"] > 0 and engaged["flash.blockdiff_merge_view"] == 0
     text = step.lowered_text
     # both copies' rows through the layers, the clean keys alone through the kernels, the
     # noised rows alone through the head, 16 held experts' weights and the router's 128 outputs
@@ -381,7 +389,9 @@ def test_sdar_train_step_compiles_with_mosaics_kernels_and_no_remat_of_the_compi
     backward of the layer scan), NONE of the compiler's own
     rematerialisations, and the bytes the configuration file's `reduced`
     states (arguments 5.10 GiB, temporaries 11.45 by the compiler's count,
-    which holds both bodies of the expert layer's `cond`)."""
+    which holds both bodies of the expert layer's `cond`; 11.55 since PR 67:
+    the noised blocks' own rows of k and v stand in float32 while a layer's
+    backward runs, eight arrays of 16 MiB)."""
     step = train_step(v5e, **SDAR)
     masked = [k for k in step.kernels if k.startswith("flash.blockdiff")]
     assert len(masked) == 2 and len(grouped_kernels(step.kernels)) == 20

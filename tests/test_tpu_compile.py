@@ -109,8 +109,10 @@ def test_flash_attention_compiles_under_the_block_diffusion_mask(v5e, grad):
     limit at these bytes, as at `keye-train-8k`), a trip count a q block
     from `_visible_end`, a row's limit from an integer division inside
     the kernel: Mosaic's to accept, not the interpreter's. One kernel
-    forward, one backward; the noised blocks' own [4, 4] products are
-    XLA's."""
+    forward, one backward; the noised blocks' own keys are merged outside
+    them in jax.numpy on the arrays as they lie (`flash._blockdiff_merge`,
+    PR 67: XLA's multiplies and lane reductions, and `_own_rows`' 0/1
+    products; no [.., 4, 128] view)."""
     from ray_tpu.ops import flash
 
     assert flash.default_block_k(8192, 128, 2) == 8192
