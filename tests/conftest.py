@@ -32,8 +32,74 @@ os.environ.setdefault("RAY_TPU_NUM_CPUS", "8")
 # compiles for the described chip: what is read of a TPU compile is the optimised step.
 os.environ.setdefault("JAX_DISABLE_MOST_OPTIMIZATIONS", "1")
 
+import shutil  # noqa: E402
+import signal  # noqa: E402
+import tempfile  # noqa: E402
+
 import jax  # noqa: E402
 import pytest  # noqa: E402
+
+
+# ONE CPU program is compiled ONCE A RUN: the lane compiles the same tiny models' programs
+# hundreds of times (every `LLMEngine` a case builds makes its `jax.jit`s anew, so jax's
+# in-memory cache never hits across engines, and six workers each compile what the others
+# have), so jax's persistent cache stands in one directory a run, made by the process that
+# owns the run (pytest-xdist's controller, which hands it to its workers; without xdist the
+# one process) and removed when the run ends: no state outlives a run, and a run's seconds do
+# not depend on the run before it. Set through `jax.config`, not the environment: the cluster
+# tests' subprocesses and chipbench/tools/aa.py's children must not inherit it. The PROGRAM's
+# rule is as it was (ray_tpu/utils/compile_cache.py: no CPU cache in a process pinned to the
+# CPU, because XLA:CPU reloads its entries with machine-feature complaints on stderr): pytest
+# captures a case's stderr. tests/v5e_steps.py's `v5e` fixture turns the cache off round a
+# module that compiles for the described chip; a case that counts "compiled" and "loaded"
+# (tests/test_obs_layers.py, tests/test_tpu_compile.py) sets a directory of its own.
+_LANE_CACHE = pytest.StashKey[str]()
+
+
+def pytest_configure(config):
+    if hasattr(config, "workerinput"):
+        path = config.workerinput["lane_compile_cache"]
+    else:
+        path = config.stash[_LANE_CACHE] = tempfile.mkdtemp(prefix="ray_tpu_lane_xla_")
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_configure_node(node):
+    node.workerinput["lane_compile_cache"] = node.config.stash[_LANE_CACHE]
+
+
+def pytest_unconfigure(config):
+    if _LANE_CACHE in config.stash:
+        shutil.rmtree(config.stash[_LANE_CACHE], ignore_errors=True)
+
+
+# A case that waits forever fails ALONE: a lost answer (ROADMAP D8's flaky tail) costs one
+# case and five minutes, not the lane's clock. No pytest-timeout here, so the alarm is the
+# process's own; a case marked `slow` (a full-width compile takes longer) has none.
+CASE_LIMIT_S = 300
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_call(item):
+    if item.get_closest_marker("slow"):
+        yield
+        return
+
+    def out_of_time(signum, frame):
+        raise TimeoutError(f"{item.nodeid} was still running after {CASE_LIMIT_S} s "
+                           f"(tests/conftest.py: CASE_LIMIT_S)")
+
+    was = signal.signal(signal.SIGALRM, out_of_time)
+    signal.setitimer(signal.ITIMER_REAL, CASE_LIMIT_S)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, was)
+
 
 # Under pytest-xdist every worker that lets `serve.run` start the HTTP proxy binds its
 # default port, and two of them at once collide on 8000 (ROADMAP D8's flaky list:

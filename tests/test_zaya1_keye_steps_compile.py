@@ -1,20 +1,25 @@
 """The steps of `zaya1-train` and `keye-train-8k` for a described v5e
-(tests/v5e_steps.py), each compiled ONCE. ZAYA1-8B's (8 of 16 experts
-and an eighth of the vocabulary held): the text the cell's six layers
-lower to, and one layer compiled: CCA through the flash kernels, its mix
-laid out head-major, the held experts' kernels. The language model of
-Keye-VL-2.0's (16 of 128 experts held, ONE sequence of 8192) at ONE
-layer compiled: the flash kernels under a packed selection; and two
-layers lowered, for what the stack's scan hands its backward. The
-compiled steps stand in four files of about 110-160 s alone each,
-balanced by their compiles' measured seconds and not by kind (ROADMAP
-D8; this one: 67 + 46 s of compiles, PR 54)."""
+(tests/v5e_steps.py). ZAYA1-8B's (8 of 16 experts and an eighth of the
+vocabulary held): the text the cell's six layers lower to, and one layer:
+CCA through the flash kernels, the held experts' kernels. The language
+model of Keye-VL-2.0's (16 of 128 experts held, ONE sequence of 8192) at
+ONE layer: the flash kernels under a packed selection; and two layers, for
+what the stack's scan hands its backward. THE LANE READS THE LOWERED MODULES
+(PR 68: one lowering a step, no compile): the hashes, the kernels by site,
+the traced sites, the shapes, every scope the cell's readers sum. What only
+a compile shows is ONE case a step marked `slow`
+(`python -m pytest -m slow tests/test_zaya1_keye_steps_compile.py`, 54 and
+47 s alone on this sandbox, PR 68): CCA's mix laid out head-major with no
+copy, ZAYA1's temporaries, the 33 MiB of VMEM Keye's fused backward is
+given, the band's blocks as they are compiled, the scopes that outlive
+XLA's fusion. Every PR's run of the two cells on the chip shows the same
+(`train_tok_s`, `hbm_peak_gib.train`, the step's table by scope)."""
 
 import re
 
 import pytest
 
-from v5e_steps import grouped_kernels, train_step, v5e  # noqa: F401 - a fixture
+from v5e_steps import grouped_kernels, scopes_lost, train_step, v5e  # noqa: F401 - a fixture
 
 ZAYA_SHARE = dict(model="zaya1-8b", vocab_size=32896, experts_held=8)
 # sha256 of the lowered step of zaya1-8b as `zaya1-train` builds it (six layers, 8 of 16
@@ -54,31 +59,51 @@ def test_zaya_train_step_lowers_to_the_text_it_had(v5e):
     assert train_step(v5e, batch=2, n_layers=6, **ZAYA_SHARE).lowered_hash() == _ZAYA_STEP
 
 
+ZAYA_GROUPED = (["ragged-dot-tiled"] * 3 + ["ragged-dot-tiled-dgrad"] * 3
+                + ["ragged-dot-tiled-wgrad"] * 3)
+
+
 def test_zaya_share_train_step_runs_its_kernels_and_skips_the_rows_elsewhere(v5e):
     """ZAYA1-8B as `zaya1-train` builds it (8 of 16 experts held, an
     eighth of the vocabulary; ONE layer and one sequence here, the
-    cell's six and its batch are rehearsed in PERF.md), compiled for
-    the described chip: CCA's attention is the two flash kernels, its
-    mix is laid out with the tokens and a head's channels as the tile, the
+    cell's six and its batch are rehearsed in PERF.md), LOWERED for
+    the described chip: CCA's attention is the two flash kernels, the
     held experts' nine grouped matmuls are the kernels of
     ops/grouped_matmul.py with a group's whole [2048, 2048] weight
-    matrix as one block, XLA's own ragged-dot kernel is not there, and
-    both new sublayers count their sites."""
+    matrix as one block, `lax.ragged_dot` is not there, and both new
+    sublayers count their sites. How CCA's mix is laid out, and the
+    temporaries, are the slow case's."""
     step = train_step(v5e, batch=1, n_layers=1, **ZAYA_SHARE)
     engaged = step.engaged("cca.attn", "moe.ffn", "grouped_matmul.kernel",
                            "grouped_matmul.ragged_dot")
     assert engaged["cca.attn"] > 0 and engaged["moe.ffn"] > 0
     assert engaged["grouped_matmul.kernel"] > 0 and engaged["grouped_matmul.ragged_dot"] == 0
-    hlo, kernels = step.hlo, step.kernels
-    assert grouped_kernels(kernels) == (["ragged-dot-tiled"] * 3 + ["ragged-dot-tiled-dgrad"] * 3
-                                        + ["ragged-dot-tiled-wgrad"] * 3), kernels
-    assert "ragged-dot-none" not in hlo
+    text, kernels = step.lowered_text, step.lowered_kernels
+    assert grouped_kernels(kernels) == ZAYA_GROUPED, kernels
+    assert "ragged_dot" not in text   # `lax.ragged_dot`, which compiles to XLA's ragged-dot-none
     # what is no grouped matmul is flash, named after the scope it is called in
+    rest = [k for k in kernels if not k.startswith("ragged-dot")]
+    assert rest == ["cca.attend"] * 2, kernels
+    # the router's state beside the hidden state
+    assert "1x4096x256xf32" in text
+    # 8 held experts' weights and no more: [1, 8, 2048, 2048], never 16
+    assert "8x2048x2048x" in text and "16x2048x2048x" not in text
+
+
+@pytest.mark.slow
+def test_zaya_share_train_step_compiles_with_its_mix_head_major_and_no_copy(v5e):
+    """The same one-layer step COMPILED, outside the tier-1 clock: the
+    kernels Mosaic took stand at the lowered module's sites under their
+    names, CCA's mix is laid out with the tokens and a head's channels as
+    the tile, XLA's own ragged-dot kernel is not there."""
+    step = train_step(v5e, batch=1, n_layers=1, **ZAYA_SHARE)
+    hlo, kernels = step.hlo, step.kernels
+    assert grouped_kernels(kernels) == ZAYA_GROUPED, kernels
+    assert "ragged-dot-none" not in hlo
     rest = [k for k in kernels if not k.startswith("ragged-dot")]
     assert len(rest) == 2 and all(k.startswith("cca.attend") for k in rest), kernels
     # the router's state leaves the forward scan beside the hidden state
     assert re.search(r"f32\[1,4096,256\]", hlo)
-    # 8 held experts' weights and no more: [1, 8, 2048, 2048], never 16
     assert "8,2048,2048]" in hlo and "16,2048,2048]" not in hlo
     # CCA's mix holds its heads in a MAJOR dimension (PR 33): wherever an array under
     # `cca.mix` has a head's channels in its lanes, the tokens are in the sublanes, never
@@ -111,26 +136,23 @@ def test_keye_train_step_lowers_to_the_text_it_had(v5e, n_layers):
 def test_keye_share_train_step_runs_the_kernels_under_a_packed_selection(v5e):
     """The language model of Keye-VL-2.0 as `keye-train-8k` builds it (16 of
     128 experts and an eighth of the vocabulary held, ONE sequence of 8192;
-    ONE of the cell's layers here: 265 CPU s where two, which the stack
-    scans, cost 443, PR 54), compiled for the described chip: the
+    ONE of the cell's layers here), LOWERED for the described chip: the
     attention is the flash kernels under the indexer's selection, named
-    `dsa.attend.N`: one forward and ONE backward (PR 43: 8192 keys at
+    `dsa.attend`: one forward and ONE backward (PR 43: 8192 keys at
     heads of 128 in bf16 are one kv block, two selection blocks wide, so
     the backward is the fused kernel, which states the 33 MiB of VMEM its
-    blocks need; this compile is also the check that Mosaic accepts the
-    block); the selection reaches them as ONE packed
+    blocks need; that Mosaic accepts the block is the slow case's and the
+    chip's); the selection reaches them as ONE packed
     int32 [1, 8192, 256] array a layer (8 MiB), stacked over the layers
-    for the backward (read from two layers as they are LOWERED: the scan
+    for the backward (read from two layers: the scan
     hands its backward a [2, 1, 8192, 256] int32), which computes no index
     score and no top-k again;
-    no [.., 8192, 8192] array of any type exists, the index scores are at
-    most [1, 16, 512, 8192] float32 a chunk; the held experts' grouped
+    no [.., 8192, 8192] array of any type exists; the held experts' grouped
     matmuls are the kernels of ops/grouped_matmul.py at [2048, 768] on
     the compact path, whose sums of the 16,384 held rows into the 8192
     tokens are built in the LINEAR form at every site (PR 44: no
     [8192, 16384] one-hot matrix is an operand or a result of anything);
-    every scope the cell's readers sum is in the compiled step; no site
-    falls back."""
+    no site falls back."""
     from ray_tpu.ops.flash import _fused_bwd_params
 
     step = train_step(v5e, **KEYE)
@@ -144,35 +166,63 @@ def test_keye_share_train_step_runs_the_kernels_under_a_packed_selection(v5e):
     assert engaged["grouped_matmul.kernel"] > 0
     assert engaged["grouped_matmul.ragged_dot"] == engaged["tp_overlap.plain"] == 0  # fallback_sites
     assert engaged["flash.bwd_fused"] >= 1 and engaged["flash.bwd_split"] == 0
-    hlo, kernels = step.hlo, step.kernels
-    flash = sorted(re.sub(r"\.\d+$", "", k) for k in kernels if not k.startswith("ragged-dot"))
+    text, kernels = step.lowered_text, step.lowered_kernels
+    flash = sorted(k for k in kernels if not k.startswith("ragged-dot"))
     assert flash == ["dsa.attend"] * 2, kernels   # forward, fused backward
     # the fused backward's own limit: 24 MiB of kv blocks and scratch + 1 of row blocks + 8 spare
     assert _fused_bwd_params(512, 8192, 128, 1, 2).vmem_limit_bytes == 33 << 20
+    assert len(re.findall(r"scoped_memory_configs[^}]*size\\22: %d\}" % (33 << 20), text)) == 1
+    assert "ragged_dot" not in text   # `lax.ragged_dot`, which compiles to XLA's ragged-dot-none
+    assert "ragged-dot-tiled-wgrad" in kernels
+    # the selection: packed, a layer's and the stack's; nothing [T, T], whatever its type
+    assert "1x8192x256xi32" in text
+    assert "tensor<2x1x8192x256xi32>" in train_step(v5e, **KEYE_2).lowered_text
+    assert "8192x8192x" not in text
+    # the tokens x the held rows: no such matrix; a band's block is 256 tokens and its window
+    # 256 x 16384 / 8192 = 512 rows (PR 63: no window of 256 x top-8 rows)
+    assert "8192x16384x" not in text
+    assert "256x512xi1" in text and "256x2048xi1" not in text
+    assert "1x32x8192x128xbf16" in text and "1x4x8192x128xbf16" in text
+    assert "16x2048x768x" in text and "128x2048x768x" not in text
+    # nothing of the indexer is made again for the backward, and nothing of it is differentiated
+    indexer = [n for n in step.lowered_op_names if "dsa.select" in n or "dsa.index" in n]
+    assert indexer and not [n for n in indexer if "rematted_computation" in n or "transpose(" in n]
+
+
+@pytest.mark.parametrize("scope", KEYE_SCOPES)
+def test_keye_share_train_step_holds_the_scope_its_readers_sum(v5e, scope):
+    """A scope the cell's readers sum is in the LOWERED step (the one lowering
+    of the file's other cases of this step: tests/v5e_steps.py's memo), a
+    case a scope; that it outlives the compile is the slow case's."""
+    assert train_step(v5e, **KEYE).has_scope(scope, lowered=True), scope
+
+
+@pytest.mark.slow
+def test_keye_share_train_step_compiles_with_the_vmem_its_fused_backward_states(v5e):
+    """The same one-layer step COMPILED, outside the tier-1 clock (265 CPU s
+    where two layers, which the stack scans, cost 443, PR 54): this compile
+    is the check that Mosaic accepts the fused backward's block at the 33 MiB
+    of VMEM it states; the kernels stand at the lowered module's sites under
+    their names; the index scores are at most [1, 16, 512, 8192] float32 a
+    chunk; the band's blocks and windows as they are compiled; nothing of
+    the indexer is made again for the backward; every scope the cell's
+    readers sum outlives the compile."""
+    step = train_step(v5e, **KEYE)
+    hlo, kernels = step.hlo, step.kernels
+    flash = sorted(re.sub(r"\.\d+$", "", k) for k in kernels if not k.startswith("ragged-dot"))
+    assert flash == ["dsa.attend"] * 2, kernels   # forward, fused backward
     assert len(re.findall(r'"scoped_memory_configs":\[\{[^}]*"size":"%d"' % (33 << 20), hlo)) == 1
     assert "ragged-dot-none" not in hlo
     assert any(k.startswith("ragged-dot-tiled-wgrad") for k in kernels)
-    # the selection: packed, a layer's and the stack's; nothing [T, T], whatever its type
     assert re.search(r"s32\[1,8192,256\]", hlo)
-    assert "tensor<2x1x8192x256xi32>" in train_step(v5e, **KEYE_2).lowered_text
     assert not re.search(r"\[(?:\d+,)*8192,8192\]", hlo)
-    # the tokens x the held rows: lowered or compiled, no such matrix; a band's block is 256 tokens
-    # and its window 256 x 16384 / 8192 = 512 rows (PR 63: no window of 256 x top-8 rows)
-    assert not re.search(r"\[(?:\d+,)*8192,16384\]", hlo) and "8192x16384x" not in step.lowered_text
+    assert not re.search(r"\[(?:\d+,)*8192,16384\]", hlo)
     assert re.search(r"pred\[256,512\]", hlo) and re.search(r"f32\[256,2048\]", hlo)
     assert not re.search(r"pred\[256,2048\]", hlo) and re.search(r"bf16\[512,2048\]", hlo)
     keys = {int(k) for k in re.findall(r"f32\[(?:1,)?16,512,(\d+)\]", hlo)}   # a chunk's scores
     assert keys and max(keys) == 8192 and min(keys) > 2048
     assert re.search(r"bf16\[1,32,8192,128\]", hlo) and re.search(r"bf16\[1,4,8192,128\]", hlo)
     assert "16,2048,768]" in hlo and "128,2048,768]" not in hlo and "8192,128]" in hlo
-    # nothing of the indexer is made again for the backward, and nothing of it is differentiated
     indexer = [n for n in step.op_names if "dsa.select" in n or "dsa.index" in n]
     assert indexer and not [n for n in indexer if "rematted_computation" in n or "transpose(" in n]
-
-
-@pytest.mark.parametrize("scope", KEYE_SCOPES)
-def test_keye_share_train_step_holds_the_scope_its_readers_sum(v5e, scope):
-    """A scope the cell's readers sum is in the COMPILED step (the one compile
-    of the file's other cases of this step: tests/v5e_steps.py's memo), a
-    case a scope."""
-    assert train_step(v5e, **KEYE).has_scope(scope), scope
+    assert not scopes_lost(step, KEYE_SCOPES)

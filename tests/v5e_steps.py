@@ -1,41 +1,49 @@
-"""The described chip and the train steps built for it: what the four
+"""The described chip and the train steps built for it: what the seven
 step-compile files (tests/test_m7b_steps_compile.py,
 tests/test_zaya1_keye_steps_compile.py,
 tests/test_glm47f_laguna_steps_compile.py,
-tests/test_olmo_hybrid_twotower_steps_compile.py) and
-tests/test_tpu_compile.py share. No test lives here (pytest does not
-collect the file).
+tests/test_olmo_hybrid_twotower_steps_compile.py,
+tests/test_solar_open2_step_compile.py,
+tests/test_kimi_linear_step_compile.py,
+tests/test_granite_hybrid_step_compile.py) and tests/test_tpu_compile.py
+share. No test lives here (pytest does not collect the file).
 
-A full-width train step takes 30-105 s of every core to compile for the
-described v5e (and 2-10 s to lower), and the `v5e` fixture turns the
-persistent compile cache off, so nothing shares a compile unless the
-tests do: `train_step` keeps ONE record a process for one set of
-arguments, and a record makes its lowered text, its compiled step and
-what is read of them once each, on first request. A test that needs a
-FRESH trace (an import made to fail, a rule of models/moe.py patched)
-builds a `Step` of its own and goes round the memo. A step is COMPILED
-only for a fact the lowered module cannot show: the temporaries' and
-arguments' bytes, the VMEM an operation is given, the tiles of a fusion,
-a layout, a copy or a transpose, `.remat`, a branch's own computations, a
-transfer started before a matmul and done after it. Which kernels stand
-at how many sites under which scope at which shapes, and that XLA's own
-ragged dot is not there, the lowered module says (`lowered_kernels`,
-`lowered_op_names`, `has_scope(.., lowered=True)`, `engaged`, which the
-TRACE counts): GLM-4.7-Flash's step is read so and compiled by no test
-(PR 54), Laguna-S-2.1's is compiled at two of the cell's five layers and
-Keye-VL-2.0's at one of its two (what is read of those compiles holds at
-either depth; 286 and 265 CPU s where the cell's depth takes 585 and
-443). The eight compiles left stand in four files of 110-160 s alone
-each, balanced by their compiles' measured seconds (ROADMAP D8 has the
-table), so that `--dist loadfile` gives them to four workers: one file
-for all of them was 84% of the lane's wall on one worker, and a file a
-cell put every all-core compile at once into the lane's tail
-(pytest-xdist starts the files with the most cases first, so these start
-last whatever their names).
+A full-width train step takes 30-80 s alone to compile for the described
+v5e (several times that beside five other workers) and 1-9 s to lower, and the `v5e` fixture turns the
+persistent compile cache off, so THE TIER-1 LANE COMPILES NO STEP (PR 68;
+tests/test_step_files_layout.py holds the files to it): a case of the lane
+reads the LOWERED module, and what only a compile shows is one case a step
+marked `slow`. The lowered module says which kernels stand at how many
+sites under which scope at which shapes (`lowered_kernels`), which name
+stacks its operations carry (`lowered_op_names`, `has_scope(..,
+lowered=True)`), that XLA's own ragged dot is not there, how often a site
+was traced (`engaged`, which the TRACE counts), what the step asks of the
+compiler (`compiler_options`), the text's hash, and the bytes of the
+step's arguments (`argument_bytes`, summed from its abstract inputs). A
+COMPILE shows the temporaries' bytes and that arguments + temporaries fit
+the chip, the VMEM an operation is given, the tiles of a fusion, a layout,
+a copy or a transpose, `.remat`, a branch's own computations, a transfer
+started before a matmul and done after it, the kernels' names in the
+compiled text, and which scopes outlive XLA's fusion (`scopes_lost`).
+Those are also what every PR's run of the cell on the chip shows
+(`hbm_step_gib.train`, `hbm_peak_gib.train`, the step's table by scope,
+`step_unscoped_pct`): a builder who edits ray_tpu/ops/, ray_tpu/models/ or
+ray_tpu/train/ runs `python -m pytest -m slow tests/test_<cell>_step(s)_compile.py`
+for every cell whose step the edit moves, or that cell on the chip
+(ROADMAP D8, rule (a)).
+
+`train_step` keeps ONE record a process for one set of arguments, and a
+record makes its lowered text, its compiled step and what is read of them
+once each, on first request: a file's lane cases share one lowering, its
+slow cases one compile. A test that needs a FRESH trace (an import made to
+fail, a rule of models/moe.py patched) builds a `Step` of its own and goes
+round the memo. Laguna-S-2.1's step is compiled at two of the cell's five
+layers and Keye-VL-2.0's at one of its two (what is read of those compiles
+holds at either depth).
 Only one process at a time may load the TPU's library unless
 `ALLOW_MULTIPLE_LIBTPU_LOAD=1` is set, as the driver's command sets it
 (pytest.ini has the command): under several workers without it, every
-worker but the first to describe the chip SKIPS its compile files. It is
+worker but the first to describe the chip SKIPS its step files. It is
 set in no file of the repository (on-chip-measurement guide, section 2)."""
 
 import functools
@@ -192,7 +200,25 @@ class Step:
 
     @functools.cached_property
     def memory(self):
-        return self.compiled.memory_analysis()
+        memory = self.compiled.memory_analysis()
+        # the lane reads `argument_bytes` in this number's place: wherever a step is compiled,
+        # the two are held to each other
+        assert abs(memory.argument_size_in_bytes - self.argument_bytes) < 2 ** 20
+        return memory
+
+    @functools.cached_property
+    def argument_bytes(self) -> int:
+        """What one device holds of the step's arguments (state and batch),
+        summed from its abstract inputs: `memory.argument_size_in_bytes`
+        without a compile (`memory` holds the two to each other)."""
+        return sum(math.prod(x.sharding.shard_shape(x.shape)) * x.dtype.itemsize
+                   for x in jax.tree.leaves((self.state, self.batch)))
+
+    @property
+    def compiler_options(self) -> dict:
+        """What the step asks of the compiler when it is compiled (train/step.py's
+        VMEM limit), as the LOWERED step carries it."""
+        return dict(self.lowered._lowering._compiler_options_kvs)
 
     def engaged(self, *names) -> dict:
         """{site: times counted while the step was traced}: the lowering
@@ -278,6 +304,13 @@ def train_step(devices, mesh_shape=None, **kwargs) -> Step:
     if key not in _STEPS:
         _STEPS[key] = Step(devices, mesh_shape, **kwargs)
     return _STEPS[key]
+
+
+def scopes_lost(step: Step, scopes) -> list:
+    """The scopes among `scopes` that no operation of the COMPILED step was
+    traced under: XLA fused their operations into another's or eliminated
+    them, and a trace's readers find nothing."""
+    return [scope for scope in scopes if not step.has_scope(scope)]
 
 
 def matmul_tiles(hlo: str) -> int:
