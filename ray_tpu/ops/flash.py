@@ -34,7 +34,28 @@ flash/ragged lineage to this framework. Design:
    emits dq too, one s / p / dp a pair (the FUSED backward); the two
    kernels apart are a longer sequence's;
  * segment ids (packed sequences) and right-padding are handled by
-   masking; fully-masked rows produce zeros (matching xla_attention);
+   masking; fully-masked rows produce zeros (matching xla_attention).
+   Where the kv sequence is ONE block (every cell), segment ids also bound
+   the RANGE (PR 69): a q block's walk starts at the first sub-tile that
+   may hold a key of its rows' documents and ends where it ended (the
+   diagonal, the block's end; under a window the later of the two firsts),
+   and every sub-tile that runs is masked as before, so only sub-tiles the
+   mask zeroes whole are left out. The first sub-tile is a table [B x q
+   blocks] made once a call in XLA from the two sides' ids
+   (`_doc_first_tiles`: the first sub-tile whose range of ids meets the q
+   block's) and read by the forward and the fused backward from SMEM, one
+   more input of theirs (`_doc_specs`, `_with_documents`; counted
+   `flash.doc_walk` a call built with it). It is a function of the ids
+   alone, not of positions: `kv_segment_ids` beside a `q_offset` (ring
+   attention's kv shards) take the same table. EXACT for ids that do not
+   decrease along the sequence (what every packer emits: the sub-tile that
+   holds the first key of the document of the q block's first row), a
+   superset of the live sub-tiles for any other ids. Over SEVERAL kv blocks
+   (the forward, the dq and the dk/dv kernels of a sequence over the
+   budget, run by no cell) the walk stays positional and the mask does all
+   of it, as before: their traced programs under segment ids are the
+   parent's (tests/test_flash_window.py, tests/test_flash_selection.py:
+   `kv_blocks`). `segment_tiles` counts the visits as the kernels do;
  * a SELECTION (`selection=`, None = none: the kernels as they were) is
    the one mask that is data, not a function of positions: which keys
    each query sees, the same for every head, computed by the model
@@ -93,6 +114,7 @@ log-sum-exp and delta [B, H, Sq, 1].
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Optional
@@ -263,6 +285,31 @@ from ray_tpu import obs
 # rows (the select, dq's and do1's pads). One more sub-tile a noised q block inside the
 # kernels (a second k / v input that holds the same global rows as the q block) would be
 # about 1.5 ms a layer in their place: ROADMAP S18(a), now worth 3.3 ms a layer and not 7.
+#
+# The documents' range (PR 69), step 0 and the choice: the kernels ALONE at
+# `granite-h-micro-train-packed`'s shape, [B, H, KVH, S, D] = [1, 32, 8, 8192, 64], bf16, causal,
+# ONE kv block, 16 q blocks of 512 rows x 16 sub-tiles of 512 keys, 136 causal visits a head; a
+# scratch loop that is NOT in the tree, ms a call from a profiler trace (median of five; forward /
+# fused backward), v5e, calls 1 and 2 of PR 69. Documents: ONE (nothing to skip); the benchmark's
+# generator (`packed_zipf_docs`, log-normal, median 600) on eight seeds, 3-15 documents a sequence,
+# 41-136 visits; 128 documents of 64 tokens (16 visits: the diagonal's sub-tile alone).
+#  * the PARENT (the walk positional, the documents in the mask alone): 4.906 / 8.588 under
+#    EVERY one of the ten sets of documents, to 0.0003 ms;
+#  * the table read from SMEM (this file): one document 4.744 / 8.595; the eight seeds 1.600-4.744
+#    / 2.720-8.595, mean 2.537 / 4.475 at a mean of 69 visits (41 visits 1.600 / 2.720, 52 1.966
+#    / 3.409, 68 2.497 / 4.403, 98 3.489 / 6.252); 16 visits 0.791 / 1.230. To 0.01 ms a straight
+#    line in the visits: forward 0.26 + 0.0330 a visit, backward 0.25 + 0.0614, i.e. 1.03 and
+#    1.92 us a head and visit, and a fixed 0.5 us for each of the 512 programs a call (the q
+#    block's fetch, dq's zeros and write, the pipeline's step; dk and dv, zeroed and written a kv
+#    head whatever is skipped, are in it);
+#  * the same first sub-tile REDUCED IN THE KERNEL from the blocks it holds (least and greatest id
+#    of `qseg_ref`, the first key of `kseg_ref` inside them by a compare, an iota and a min:
+#    two vector reductions to scalars the loop's bounds wait on): 4.920 / 8.835 at one document,
+#    2.159 / 3.649 at 52 visits, 0.990 / 1.468 at 16: 0.18-0.20 / 0.24 ms a call more at every
+#    count, 0.35-0.47 us a program. NOT kept: the table is 16 integers and a scalar load.
+# Over the generator's documents at large (0.437 of the causal visits, `segment_tiles` over 100
+# sequences) the lines give 2.22 / 3.90 ms: the cell's three calls a step (the forward twice under
+# remat "full") 18.4 -> 8.3 ms.
 DEFAULT_BLOCK_Q = 512
 # The keys 32 bits x 128 lanes address: a block of a packed selection, the
 # most a kv block held before PR 43, and the kv block of a sequence over
@@ -355,7 +402,8 @@ def _visible_end(i, Bq, blockdiff):
     return jnp.minimum(jnp.maximum(clean, noised), L)  # rows past 2L are padding
 
 
-def _tiles_to_run(i, j, Bq, Bk, Tk, *, causal, q_offset, window=None, blockdiff=None):
+def _tiles_to_run(i, j, Bq, Bk, Tk, *, causal, q_offset, window=None, blockdiff=None,
+                  doc_first=None):
     """Which of the Bk // Tk sub-tiles of kv block `j` q block `i` meets:
     (first, how many). Those whose first key is after the block's last
     row lie wholly above the diagonal and are skipped: without a window
@@ -366,16 +414,54 @@ def _tiles_to_run(i, j, Bq, Bk, Tk, *, causal, q_offset, window=None, blockdiff=
     skipped too: a range. At 4096 keys, rows and sub-tiles of 512 and a
     window of 512 that is the diagonal's sub-tile and the one before it,
     15 visits of the causal walk's 36. Under `blockdiff` the ones that
-    run are the prefix that holds a key before `_visible_end`."""
+    run are the prefix that holds a key before `_visible_end`. Under
+    segment ids over ONE kv block `doc_first` (traced; `_doc_first_tiles`'
+    entry of this row and q block) is one more bound on the range's start:
+    no sub-tile before it holds a key of a document of the q block's rows,
+    so the walk starts at the later of the two firsts and ends where it
+    ended."""
     if blockdiff is not None:
         return 0, jnp.clip(_visible_end(i, Bq, blockdiff) - j * Bk + Tk - 1, 0, Bk) // Tk
-    if not causal:
-        return 0, Bk // Tk
-    end = jnp.clip(q_offset + (i + 1) * Bq - j * Bk + Tk - 1, 0, Bk) // Tk
-    if window is None:
+    first, end = 0, Bk // Tk
+    if causal:
+        end = jnp.clip(q_offset + (i + 1) * Bq - j * Bk + Tk - 1, 0, Bk) // Tk
+        if window is not None:
+            first = jnp.clip(q_offset + i * Bq - window + 1 - j * Bk, 0, Bk) // Tk
+    if doc_first is not None:
+        first = doc_first if isinstance(first, int) else jnp.maximum(first, doc_first)
+    if isinstance(first, int):
         return 0, end
-    first = jnp.clip(q_offset + i * Bq - window + 1 - j * Bk, 0, Bk) // Tk
     return first, jnp.maximum(end - first, 0)
+
+
+def _doc_first_tiles(qseg, kseg, Bq, Tk, sq_valid, sk_valid):
+    """qseg [B, Sq_pad], kseg [B, Sk_pad] (the two sides' segment ids over
+    ONE kv block, padded) -> int32 [B * nq]: for row b and q block i, at
+    b * nq + i, the first sub-tile of `Tk` keys that MAY hold a key some row
+    of the q block sees under the segments: the first whose ids' range [its
+    least, its greatest] meets the q block's; Sk_pad // Tk where none does.
+    A function of the two sides' ids alone, never of positions, so it holds
+    whatever offset q stands at (ring attention's kv shards). A sub-tile
+    that holds a visible pair passes the test, so no live sub-tile lies
+    before the entry for ANY ids; for ids that do not decrease along the
+    sequence (every packer's) it is exact: the sub-tile that holds the
+    first key of the document of the q block's first row. Made once a call
+    in XLA (two reshapes, four reductions, a compare over [B, nq, tiles]);
+    the kernels read their entry from SMEM. Padding is left out of both
+    ranges, so a padded last block keeps its real rows' entry."""
+    B = qseg.shape[0]
+    lo, hi = jnp.iinfo(jnp.int32).min, jnp.iinfo(jnp.int32).max
+
+    def ranges(seg, valid, block):  # -> least and greatest id a block of real positions
+        real = jnp.arange(seg.shape[1], dtype=jnp.int32) < valid
+        blocks = lambda x: x.reshape(B, -1, block)
+        return (blocks(jnp.where(real, seg, hi)).min(-1), blocks(jnp.where(real, seg, lo)).max(-1))
+
+    q_lo, q_hi = ranges(qseg, sq_valid, Bq)   # [B, nq]
+    k_lo, k_hi = ranges(kseg, sk_valid, Tk)   # [B, tiles]
+    may = (k_hi[:, None, :] >= q_lo[:, :, None]) & (k_lo[:, None, :] <= q_hi[:, :, None])
+    first = jnp.where(may.any(-1), jnp.argmax(may, axis=-1), may.shape[-1])
+    return first.astype(jnp.int32).reshape(-1)
 
 
 def _walk_tiles(run, tile):
@@ -573,12 +659,25 @@ def _sel_specs(sel, block_q: int, block_k: int, index_map) -> tuple:
     return (pl.BlockSpec((1, block_q, -(-block_k // MAX_BLOCK_K) * _LANES), index_map),)
 
 
-def _with_selection(kernel, at: int):
-    """`kernel` for a call whose input `at` is the packed selection: that
-    ref goes in as `sel_ref`, the others as they stood without it."""
+def _doc_specs(first) -> tuple:
+    """The input the documents' table adds to a call, after the others and
+    the selection's: the whole table in SMEM, one scalar load a program;
+    nothing without one."""
+    return () if first is None else (pl.BlockSpec(memory_space=pltpu.SMEM),)
+
+
+def _with_input(kernel, at: int, name: str):
+    """`kernel` for a call with one more input at `at`: that ref goes in by
+    `name`, the others as they stood without it."""
     def run(*refs, **statics):
-        return kernel(*refs[:at], *refs[at + 1:], sel_ref=refs[at], **statics)
+        return kernel(*refs[:at], *refs[at + 1:], **{name: refs[at]}, **statics)
     return run
+
+
+# the packed selection goes in as `sel_ref`; the documents' table of first sub-tiles
+# (`_doc_first_tiles`, whole in SMEM) as `first_ref`
+_with_selection = functools.partial(_with_input, name="sel_ref")
+_with_documents = functools.partial(_with_input, name="first_ref")
 
 
 # ---------------------------------------------------------------------------
@@ -607,6 +706,7 @@ def _fwd_kernel(
     window: Optional[int] = None,
     blockdiff: Optional[tuple] = None,
     sel_ref=None,  # [1, Bq, 128] int32: the kv block's packed selection, or None
+    first_ref=None,  # SMEM [B * nq] int32: `_doc_first_tiles` (segments over ONE kv block), or None
 ):
     i = pl.program_id(2)
     j = pl.program_id(3)
@@ -674,8 +774,10 @@ def _fwd_kernel(
             preferred_element_type=jnp.float32,
         )
 
+    doc_first = None if first_ref is None else first_ref[
+        pl.program_id(0) * pl.num_programs(2) + i]
     _walk_tiles(_tiles_to_run(i, j, Bq, Bk, Tk, causal=causal, q_offset=q_offset,
-                              window=window, blockdiff=blockdiff), tile)
+                              window=window, blockdiff=blockdiff, doc_first=doc_first), tile)
 
     @pl.when(j == nk - 1)
     def _():
@@ -801,6 +903,7 @@ def _dkv_kernel(
     dq_ref=None,  # fused mode only: [1, F, Bq, D], written per (h, i)
     dq_scr=None,  # fused mode only: [F*Bq, D] fp32 (sub-tile accumulator)
     sel_ref=None,
+    first_ref=None,  # fused mode only, as the forward's
 ):
     # grid (B, nk, H/F, nq): q-blocks fastest, then the head groups
     # sharing this kv head; scratch accumulates until both inner dims
@@ -872,8 +975,9 @@ def _dkv_kernel(
                 preferred_element_type=jnp.float32,
             )
 
+    doc_first = None if first_ref is None else first_ref[pl.program_id(0) * nq + i]
     _walk_tiles(_tiles_to_run(i, jk, Bq, Bk, Tk, causal=causal, q_offset=q_offset,
-                              window=window, blockdiff=blockdiff), tile)
+                              window=window, blockdiff=blockdiff, doc_first=doc_first), tile)
 
     if fused_dq:
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype).reshape(F, Bq, D)
@@ -950,7 +1054,8 @@ def _kv_fetch(window, nk, block_q, block_k, q_offset, blockdiff=None):
 
 
 def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
-              sk_valid, interpret, has_segments, fold, window=None, sel=None, blockdiff=None):
+              sk_valid, interpret, has_segments, fold, window=None, sel=None, blockdiff=None,
+              first=None):
     B, H, Sq_pad, D = q.shape
     _, KVH, Sk_pad, _ = k.shape
     Dv = v.shape[3]  # the values' (and o's) own width: D wherever a head's are the keys'
@@ -966,8 +1071,12 @@ def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
         blockdiff=blockdiff,
     )
     kv = _kv_fetch(window, nk, block_q, block_k, q_offset, blockdiff)
+    if sel is not None:
+        kernel = _with_selection(kernel, 5)
+    if first is not None:  # after the selection, where there is one
+        kernel = _with_documents(kernel, 5 + (sel is not None))
     return pl.pallas_call(
-        kernel if sel is None else _with_selection(kernel, 5),
+        kernel,
         grid=(B, HG, nq, nk),
         in_specs=[
             pl.BlockSpec((1, F, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
@@ -976,6 +1085,7 @@ def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
             pl.BlockSpec((1, block_q, 1), lambda b, h, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, h, i, j: (b, 0, kv(i, j))),
             *_sel_specs(sel, block_q, block_k, lambda b, h, i, j: (b, i, kv(i, j))),
+            *_doc_specs(first),
         ],
         out_specs=[
             pl.BlockSpec((1, F, block_q, Dv), lambda b, h, i, j: (b, h, i, 0)),
@@ -992,7 +1102,7 @@ def _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset, block_q, block_k,
         ],
         interpret=interpret,
         compiler_params=_fwd_params(block_q, block_k, D, F, q.dtype.itemsize, Dv),
-    )(q, k, v, qseg, kseg, *(() if sel is None else (sel,)))
+    )(q, k, v, qseg, kseg, *(x for x in (sel, first) if x is not None))
 
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, do_ref,
@@ -1009,7 +1119,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, do_ref,
 
 def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
               block_q, block_k, sq_valid, sk_valid, interpret, has_segments,
-              fold, dlse=None, window=None, sel=None, blockdiff=None):
+              fold, dlse=None, window=None, sel=None, blockdiff=None, first=None):
     B, H, Sq_pad, D = q.shape
     _, KVH, Sk_pad, _ = k.shape
     Dv = v.shape[3]  # of v, o, do and dv; q, k, dq and dk keep D
@@ -1032,13 +1142,16 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
     selected = (lambda kernel: kernel) if sel is None else functools.partial(_with_selection, at=8)
 
     if nk == 1:
+        fused = selected(functools.partial(
+            _bwd_fused_kernel, scale=scale, causal=causal,
+            q_offset=q_offset, sq_valid=sq_valid, sk_valid=sk_valid,
+            group=G // F, has_segments=has_segments, kpad=kpad, qpad=qpad,
+            window=window, blockdiff=blockdiff,
+        ))
+        if first is not None:  # the documents' table: the last input, the fused kernel's alone
+            fused, operands = _with_documents(fused, len(operands)), operands + (first,)
         dq, dk, dv = pl.pallas_call(
-            selected(functools.partial(
-                _bwd_fused_kernel, scale=scale, causal=causal,
-                q_offset=q_offset, sq_valid=sq_valid, sk_valid=sk_valid,
-                group=G // F, has_segments=has_segments, kpad=kpad, qpad=qpad,
-                window=window, blockdiff=blockdiff,
-            )),
+            fused,
             grid=(B, 1, HG, nq),  # q-blocks fastest, then groups per kv head
             in_specs=[
                 pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, i, 0)),
@@ -1050,6 +1163,7 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
                 pl.BlockSpec((1, F, block_q, 1), lambda b, j, h, i: (b, h, i, 0)),
                 pl.BlockSpec((1, F, block_q, 1), lambda b, j, h, i: (b, h, i, 0)),
                 *_sel_specs(sel, block_q, block_k, lambda b, j, h, i: (b, i, j)),
+                *_doc_specs(first),
             ],
             out_specs=[
                 pl.BlockSpec((1, F, block_q, D), lambda b, j, h, i: (b, h, i, 0)),
@@ -1148,47 +1262,58 @@ def _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal, q_offset,
 # as zeros and `delta - 0` is a no-op in the backward).
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11))
 def _flash_lse(scale, causal, q_offset, block_q, block_k, sq_valid, sk_valid,
-               interpret, has_segments, fold, window, blockdiff, q, k, v, qseg, kseg, sel=None):
+               interpret, has_segments, fold, window, blockdiff, q, k, v, qseg, kseg, sel=None,
+               first=None):
     """(o, lse) with a DIFFERENTIABLE lse — ring attention merges
     per-block results through lse, so its cotangent must reach ds.
-    `sel`: the packed selection (None: none; no operand of the call then)."""
+    `sel`: the packed selection, `first`: the documents' table of first
+    sub-tiles (None: none; no operand of the call then)."""
     (o, lse), _ = _flash_lse_fwd(
         scale, causal, q_offset, block_q, block_k, sq_valid, sk_valid,
-        interpret, has_segments, fold, window, blockdiff, q, k, v, qseg, kseg, sel,
+        interpret, has_segments, fold, window, blockdiff, q, k, v, qseg, kseg, sel, first,
     )
     return o, lse
 
 
+def _doc_walk(first):
+    """One span a kernel call built with the documents' range, WHILE TRACING
+    (`flash.doc_walk`, beside `flash.bwd_fused`); nothing without it."""
+    return contextlib.nullcontext() if first is None else obs.layer_span("flash.doc_walk")
+
+
 def _flash_lse_fwd(scale, causal, q_offset, block_q, block_k, sq_valid,
                    sk_valid, interpret, has_segments, fold, window, blockdiff, q, k, v, qseg,
-                   kseg, sel=None):
-    o, lse = _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset,
-                       block_q, block_k, sk_valid, interpret, has_segments,
-                       fold, window, sel, blockdiff)
+                   kseg, sel=None, first=None):
+    with _doc_walk(first):
+        o, lse = _fwd_call(q, k, v, qseg, kseg, scale, causal, q_offset,
+                           block_q, block_k, sk_valid, interpret, has_segments,
+                           fold, window, sel, blockdiff, first)
     # named residuals: under jax.checkpoint, the backward re-runs this
     # whole kernel just to rebuild (o, lse) unless the remat policy can
     # SAVE them — the "dots" policy recognizes dot_general outputs, not a
     # pallas_call's (llama.py pairs this with save_only_these_names)
     o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
     lse = jax.ad_checkpoint.checkpoint_name(lse, "attn_lse")
-    return (o, lse), (q, k, v, qseg, kseg, o, lse, sel)
+    return (o, lse), (q, k, v, qseg, kseg, o, lse, sel, first)
 
 
 def _flash_lse_bwd(scale, causal, q_offset, block_q, block_k, sq_valid,
                    sk_valid, interpret, has_segments, fold, window, blockdiff, residuals, cts):
     do, dlse = cts
-    q, k, v, qseg, kseg, o, lse, sel = residuals
+    q, k, v, qseg, kseg, o, lse, sel, first = residuals
     # one span a call, WHILE TRACING, says which backward it took
-    with obs.layer_span("flash.bwd_fused" if k.shape[2] == block_k else "flash.bwd_split"):
+    with obs.layer_span("flash.bwd_fused" if k.shape[2] == block_k else "flash.bwd_split"), \
+            _doc_walk(first):
         dq, dk, dv = _bwd_call(q, k, v, qseg, kseg, o, lse, do, scale, causal,
                                q_offset, block_q, block_k, sq_valid, sk_valid,
                                interpret, has_segments, fold, dlse=dlse, window=window, sel=sel,
-                               blockdiff=blockdiff)
+                               blockdiff=blockdiff, first=first)
     zero_seg = np.zeros(qseg.shape, dtype=jax.dtypes.float0)
     zero_kseg = np.zeros(kseg.shape, dtype=jax.dtypes.float0)
     # the selection is a constant of the backward: integers take no cotangent
-    zero_sel = None if sel is None else np.zeros(sel.shape, dtype=jax.dtypes.float0)
-    return dq, dk, dv, zero_seg, zero_kseg, zero_sel
+    zero_sel, zero_first = (None if x is None else np.zeros(x.shape, dtype=jax.dtypes.float0)
+                            for x in (sel, first))
+    return dq, dk, dv, zero_seg, zero_kseg, zero_sel, zero_first
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -1309,7 +1434,10 @@ def _flash_head_major(
     # the scale is in q already: the kernels' own is 1
     statics = (1.0, causal, q_offset, bq, bk, Sq, Sk, interpret,
                has_segments, fold, window, blockdiff)
-    o, lse = _flash_lse(*statics, qt, kt, vt, qseg, kseg, selection)
+    # segment ids over ONE kv block: the walk starts at the q block's own documents
+    first = (_doc_first_tiles(qseg2, kseg2, bq, _sub_k(bk), Sq, Sk)
+             if has_segments and Sk_pad == bk else None)
+    o, lse = _flash_lse(*statics, qt, kt, vt, qseg, kseg, selection, first)
     return o[:, :, :Sq, :], lse
 
 
@@ -1329,6 +1457,32 @@ def blockdiff_tiles(L: int, beta: int, block_q: int = DEFAULT_BLOCK_Q, head_dim:
     causal = np.minimum(-(-(q_blocks + 1) * bq // tk), -(-2 * L // tk))
     return {"visited": int((-(-ends // tk)).sum()), "causal": int(causal.sum()),
             "visible_pairs": L * (L + beta)}
+
+
+def segment_tiles(segment_ids, block_q: int = DEFAULT_BLOCK_Q, head_dim: int = _LANES,
+                  itemsize: int = 2, window: Optional[int] = None) -> dict:
+    """What the causal walk costs a head under `segment_ids` [B, S] (q and
+    kv share them), counted as the kernels count it (`_doc_first_tiles`,
+    `_tiles_to_run`, q blocks of `block_q` rows, the sub-tiles of the kv
+    block `default_block_k` gives keys of `head_dim`): {"visited": the (q
+    block, sub-tile) visits summed over the B rows, "causal": the visits
+    of the same walk without the documents' bound}. Over several kv blocks
+    the walk takes no bound from the documents and the two are equal."""
+    ids = np.asarray(segment_ids, np.int32)
+    B, S = ids.shape
+    bq, bk = min(block_q, _round_up(S, 16)), default_block_k(S, head_dim, itemsize)
+    tk = _sub_k(bk)
+    nq, nk = -(-S // bq), -(-S // bk)
+    run = functools.partial(_tiles_to_run, np.arange(nq)[:, None], np.arange(nk)[None, :], bq, bk,
+                            tk, causal=True, q_offset=0, window=window)
+    with jax.ensure_compile_time_eval():  # shapes' arithmetic: a number, also under a trace
+        causal = B * int(np.asarray(run()[1]).sum())
+        if nk > 1:
+            return {"visited": causal, "causal": causal}
+        pad = lambda n, fill: np.pad(ids, ((0, 0), (0, n - S)), constant_values=fill)
+        first = _doc_first_tiles(pad(nq * bq, -1), pad(bk, -2), bq, tk, S, S)
+        visited = int(np.asarray(run(doc_first=np.asarray(first).reshape(B, nq, 1))[1]).sum())
+    return {"visited": visited, "causal": causal}
 
 
 _SUBLANES = 8  # rows of a float32 (8, 128) tile: what a block-diffusion block is held against

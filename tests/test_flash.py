@@ -79,6 +79,11 @@ def test_forward_bf16_tolerance(B, S, H, KVH, D):
     # against rows 512..1535)
     (1536, 200, 512, None),
     (1536, 700, 512, None),
+    # PR 69: four sub-tiles and eight q blocks in ONE kv block, where the walk of the second
+    # document's q blocks starts at the sub-tile of its first key (tests/test_flash_documents.py
+    # has the walk's own cases): the boundary inside a sub-tile and on a sub-tile's edge
+    (2048, 700, 256, None),
+    (2048, 1024, 256, None),
 ])
 def test_segment_ids_packing(S, cut, bq, bk):
     B, H, KVH, D = 2, 4, 2, 32
@@ -152,6 +157,11 @@ def test_grads_match_xla(S, H, KVH, D, causal, bq, bk):
     # the boundary inside a sub-tile below the diagonal, the padded edge inside another
     (1400, 200, 512, 1024),
     (1536, 200, 512, None),
+    # PR 69: the documents' range over ONE kv block with a padded edge (the last q block's
+    # padded rows and the last sub-tile's padded keys are in neither side's range of ids),
+    # and the same boundary over four kv blocks, where the walk stays positional
+    (2000, 700, 256, None),
+    (2000, 768, 256, 512),
 ])
 def test_grads_with_segments_and_padding(S, cut, bq, bk):
     B, H, KVH, D = 1, 4, 2, 32  # non-divisible: padded blocks

@@ -62,15 +62,22 @@ def test_granite_hybrid_train_step_runs_the_kernels_where_the_readers_look(v5e):
     LOWERED: a kernel is ONE site a scan's body, forward, the forward made
     again under remat "full", and backward; the Mamba block is traced ONCE
     for both scans (one counted site), and the attention layer's backward is
-    the fused kernel (`fallback_sites.train` 0)."""
+    the fused kernel (`fallback_sites.train` 0). Since PR 69 each of the
+    layer's THREE kernel calls is built with the documents' range: 8,192
+    keys at heads of 64 are one kv block, so under `segment_ids` a q block's
+    walk starts at its own documents' first sub-tile (`flash.doc_walk`:
+    counted where a call is built, which is the forward traced twice, once
+    as the layer's value and once for its derivative, and the fused
+    backward once: the three sites of `attn.attend`)."""
     step = packed_step(v5e)
     kernels = step.lowered_kernels
     for name, sites in KERNEL_SITES:
         assert kernels.count(name) == sites, (name, kernels.count(name))
     assert kernels.count("attn.attend") == 3   # forward twice, backward fused
     assert step.engaged("ssd_scan.kernel", "gdn_conv.kernel", "flash.bwd_fused",
-                        "flash.bwd_split") == {"ssd_scan.kernel": 1, "gdn_conv.kernel": 1,
-                                               "flash.bwd_fused": 1, "flash.bwd_split": 0}
+                        "flash.bwd_split", "flash.doc_walk") == {
+        "ssd_scan.kernel": 1, "gdn_conv.kernel": 1, "flash.bwd_fused": 1, "flash.bwd_split": 0,
+        "flash.doc_walk": 3}
 
 
 @pytest.mark.parametrize("scope", SCOPES)

@@ -132,6 +132,35 @@ def test_flash_attention_compiles_under_the_block_diffusion_mask(v5e, grad):
     assert "flash.blockdiff" in hlo
 
 
+@pytest.mark.parametrize("window", [None, 1024], ids=["causal", "window_1024"])
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
+def test_flash_attention_compiles_under_packed_documents(v5e, grad, window):
+    """`granite-h-micro-train-packed`'s shape (PR 69): 32 / 8 heads of 64,
+    ONE sequence of 8,192 keys in one kv block, under `segment_ids`. The
+    walk's FIRST sub-tile comes from a table made in XLA and read from
+    SMEM (`flash._doc_first_tiles`, one more input of the forward and of
+    the fused backward; under a window the later of the two firsts): a
+    whole array in scalar memory and a loop that starts where a scalar
+    load says are Mosaic's to accept, not the interpreter's. One kernel
+    forward, the fused one backward."""
+    from ray_tpu.ops import flash
+
+    assert flash.default_block_k(8192, 64, 2) == 8192
+
+    def fwd(q, k, v, ids):
+        return flash.flash_attention_head_major(q, k, v, causal=True, segment_ids=ids,
+                                                window=window)
+
+    def bwd(q, k, v, ids):
+        return jax.grad(lambda *a: fwd(*a, ids).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    with mock.patch("jax.default_backend", return_value="tpu"):   # flash's interpret switch
+        hlo = compile_kernel(bwd if grad else fwd, ((1, 32, 8192, 64), _BF16),
+                             ((1, 8, 8192, 64), _BF16), ((1, 8, 8192, 64), _BF16),
+                             ((1, 8192), _I32), sharding=one_chip(v5e))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == (2 if grad else 1)
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
 def test_flash_attention_compiles_at_heads_of_256_with_the_kernels_own_vmem(v5e, grad):
     """MLA's shape (models/mla.py): 20 heads of 256, none shared, 4096
